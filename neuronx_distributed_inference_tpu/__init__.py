@@ -7,10 +7,6 @@ Trainium). See SURVEY.md at the repo root for the component-by-component map.
 
 __version__ = "0.1.0"
 
-from .compat import ensure_jax_compat as _ensure_jax_compat
-
-_ensure_jax_compat()
-
 from .config import (ChunkedPrefillConfig, InferenceConfig, MoEConfig,
                      OnDeviceSamplingConfig, SpeculationConfig, TpuConfig,
                      load_pretrained_config)

@@ -1,4 +1,4 @@
-"""error-paths: the serving surface raises ONLY the typed taxonomy.
+"""error-paths: the serving surface raises ONLY the typed hierarchy.
 
 Port of the PR-2 ``scripts/check_error_paths.py`` checker: any
 ``raise ValueError(...)`` / ``raise RuntimeError(...)`` in the serving
@@ -63,7 +63,7 @@ def banned_raises(tree: ast.AST) -> List[Tuple[int, str]]:
 class ErrorPathsPass(Pass):
     name = "error-paths"
     description = ("serving surface raises only the typed resilience "
-                   "taxonomy (no bare ValueError/RuntimeError)")
+                   "hierarchy (no bare ValueError/RuntimeError)")
     default_paths = DEFAULT_PATHS
 
     def run(self, ctx: LintContext,
@@ -73,7 +73,7 @@ class ErrorPathsPass(Pass):
             for lineno, name in banned_raises(sf.tree):
                 findings.append(Finding(
                     self.name, sf.rel, lineno,
-                    f"raise {name}(...) — use the typed taxonomy in "
+                    f"raise {name}(...) — use the typed hierarchy in "
                     "neuronx_distributed_inference_tpu/resilience/"
                     "errors.py"))
         return findings

@@ -1,61 +1,24 @@
-"""Guarded compatibility shims for older jax releases.
-
-The library targets current jax (``jax.shard_map``, ``jax.sharding.set_mesh``
-/ ``get_abstract_mesh``, ``jax_num_cpu_devices``); some deployment images pin
-an older jax where those live elsewhere or do not exist. Every shim is
-hasattr/except-guarded — on a current jax this module is a no-op — and
-:func:`ensure_jax_compat` runs once at package import so CLI subprocesses
-(``inference_demo``, ``bench.py``) get the same surface the test conftest
-provides.
-"""
+"""The CPU stand-in for the accelerator: N virtual devices and
+interpret-mode Pallas kernels, for runs that check sharding and control
+flow without a chip (``__graft_entry__.py``, the lint scripts,
+``inference_demo --on-cpu``, ``bench.py``'s report modes)."""
 
 from __future__ import annotations
 
-import os
-
 import jax
 
-__all__ = ["ensure_jax_compat", "force_cpu_devices"]
+from .ops.kernel_mode import request_interpret
 
-
-def ensure_jax_compat() -> None:
-    """Alias new-jax entry points onto an older jax. Idempotent."""
-    if not hasattr(jax.sharding, "set_mesh"):
-        # older jax: Mesh is itself a context manager that activates the
-        # mesh for bare-PartitionSpec sharding constraints
-        jax.sharding.set_mesh = lambda mesh: mesh
-    if not hasattr(jax.sharding, "get_abstract_mesh"):
-        from jax._src import mesh as _mesh_lib
-
-        def _get_abstract_mesh():
-            m = _mesh_lib.thread_resources.env.physical_mesh
-            return None if m.empty else m
-
-        jax.sharding.get_abstract_mesh = _get_abstract_mesh
-    if not hasattr(jax, "shard_map"):
-        # older jax: shard_map lives in jax.experimental and spells the
-        # replication-check kwarg check_rep rather than check_vma
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        def _shard_map_compat(f, **kw):
-            if "check_vma" in kw:
-                kw["check_rep"] = kw.pop("check_vma")
-            return _shard_map(f, **kw)
-
-        jax.shard_map = _shard_map_compat
+__all__ = ["force_cpu_devices"]
 
 
 def force_cpu_devices(n: int = 8) -> None:
-    """Point jax at ``n`` virtual CPU devices (call before backend init)."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}").strip()
+    """Point jax at ``n`` virtual CPU devices and ask for interpret-mode
+    kernels. Call before the backend initializes: once it has, the
+    platform/device-count updates raise and the live backend stays."""
+    request_interpret()
     try:
         jax.config.update("jax_platforms", "cpu")
         jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        # older jax: the XLA_FLAGS fallback above provides the devices
-        pass
     except RuntimeError:
         pass  # backend already initialized; nothing more we can do

@@ -369,7 +369,6 @@ class TpuConfig:
     cast_type: str = "config"                 # or "as-declared"
     save_sharded_checkpoint: bool = False
     skip_sharding: bool = False
-    compile_cache_dir: Optional[str] = None
     seed: int = 0
 
     # note: unknown kwargs warn (reference: models/config.py:639-640) — handled

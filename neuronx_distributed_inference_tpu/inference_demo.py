@@ -203,7 +203,7 @@ def _run_inference(args) -> int:
             moe_config=(MoEConfig(moe_tkg_ep_degree=args.moe_tkg_ep_degree)
                         if args.moe_tkg_ep_degree is not None else None),
             output_logits=args.check_accuracy_mode == "logit-matching",
-            compile_cache_dir=args.compiled_model_path, seed=args.seed)
+            seed=args.seed)
         kw.update(over)
         return TpuConfig(**kw)
 

@@ -28,7 +28,7 @@ bucket-ladder jit-cache miss (or a tracing crash) in production.
 from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import os
@@ -42,6 +42,7 @@ from ..config import InferenceConfig, TpuConfig
 from ..ops import attention as attn_ops
 from ..ops import decode_attention
 from ..ops import flash_attention
+from ..ops import kernel_mode
 from ..ops import sampling as sampling_ops
 from ..ops.normalization import layer_norm, rms_norm
 from ..ops.rope import RopeConfig, apply_rope, rope_cos_sin
@@ -600,11 +601,20 @@ def init_param_tree(specs: Dict[str, Any], key: jax.Array,
     for path, ps in flat:
         pstr = "/".join(str(p) for p in path)
         k = jax.random.fold_in(key, zlib.crc32(pstr.encode()) & 0x7FFFFFFF)
-        x = ps.initializer(k)
-        if mesh is not None:
-            x = jax.device_put(x, NamedSharding(mesh, ps.pspec))
-        leaves.append(x)
+        if mesh is None:
+            leaves.append(ps.initializer(k))
+        else:
+            # each leaf is BORN on its sharding: every device generates its
+            # own shard (partitionable threefry — same bits as the
+            # unsharded draw), nothing is staged whole on the default device
+            leaves.append(_sharded_initializer(
+                ps, NamedSharding(mesh, ps.pspec))(k))
     return jax.tree.unflatten(treedef, leaves)
+
+
+@lru_cache(maxsize=512)
+def _sharded_initializer(ps: ParamSpec, sharding: NamedSharding):
+    return jax.jit(ps.initializer, out_shardings=sharding)
 
 
 def init_params(spec: DecoderSpec, key: jax.Array,
@@ -1068,28 +1078,34 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
         # each row's LIVE pages through the block table — the gather path
         # below materializes the whole table per layer per token. Default-on
         # for single-token paged decode (decode_kernel None/True).
-        use_pkernel = (h.shape[1] == 1
-                       and not spec.alibi
-                       and spec.decode_kernel is not False
-                       and decode_attention.supports(spec, 1)
-                       and (k_full.dtype == dtype
-                            or decode_attention.quantized_cache_ok(
-                                k_full.dtype.name)))
-        if use_pkernel:
-            if spec.layer_pattern is not None:
-                win = jnp.where(is_local, spec.sliding_window, 0)
-            else:
-                win = jnp.asarray(spec.sliding_window, jnp.int32)
-            kernel_out = decode_attention.paged_dispatch(
-                q[:, 0], k_full, v_full, k[:, 0], v[:, 0], li,
-                positions[:, 0], block_table, scale=spec.scale, window=win,
-                soft_cap=spec.attn_soft_cap, sink=sink,
-                kv_scale=spec.kv_scale,
-                interpret=jax.default_backend() != "tpu")
-            if kernel_out is None:
-                use_pkernel = False
-            else:
-                attn_out = kernel_out[:, None]
+        use_pkernel = False
+        if h.shape[1] == 1:
+            # every decline leaves a note (ops/kernel_mode.py): a decode
+            # graph on the full-table gather path is never a silent choice
+            declined = ("alibi" if spec.alibi
+                        else "decode_kernel=False" if spec.decode_kernel is False
+                        else "" if decode_attention.supports(spec, 1)
+                        else "unsupported geometry (mla / head_dim / "
+                             "attn_chunk)")
+            if not declined:
+                if spec.layer_pattern is not None:
+                    win = jnp.where(is_local, spec.sliding_window, 0)
+                else:
+                    win = jnp.asarray(spec.sliding_window, jnp.int32)
+                kernel_out = decode_attention.paged_dispatch(
+                    q[:, 0], k_full, v_full, k[:, 0], v[:, 0], li,
+                    positions[:, 0], block_table, scale=spec.scale,
+                    window=win, soft_cap=spec.attn_soft_cap, sink=sink,
+                    kv_scale=spec.kv_scale,
+                    interpret=kernel_mode.pallas_interpret())
+                if kernel_out is None:
+                    declined = "kv heads not shardable over the mp axes"
+                else:
+                    use_pkernel = True
+                    attn_out = kernel_out[:, None]
+            kernel_mode.note("paged_decode",
+                             "xla" if declined else kernel_mode.kernel_path(),
+                             declined)
         if not use_pkernel:
             k_all = kv.dequantize_kv(
                 bkv.gather_block_kv(bkv.read_layer(k_full, li), block_table),
@@ -1119,7 +1135,12 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
             kernel_out = flash_attention.dispatch_prefill(
                 q, k, v, scale=spec.scale, causal=True,
                 window=spec.sliding_window, soft_cap=spec.attn_soft_cap,
-                interpret=jax.default_backend() != "tpu")
+                interpret=kernel_mode.pallas_interpret())
+            kernel_mode.note(
+                "flash_prefill",
+                "xla" if kernel_out is None else kernel_mode.kernel_path(),
+                "heads not shardable over the mp axes"
+                if kernel_out is None else "")
         if kernel_out is not None:
             attn_out = kernel_out
         else:
@@ -1180,9 +1201,6 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                       and not spec.rolling_window
                       and identity_seq_ids
                       and h.shape[0] == k_full.shape[1]
-                      and (k_full.dtype == dtype
-                           or decode_attention.quantized_cache_ok(
-                               k_full.dtype.name))
                       and not spec.flash_decoding)
         if use_kernel and spec.decode_kernel is None:
             # auto admission (reference analog: flash-strategy heuristics,
@@ -1210,11 +1228,14 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                 positions[:, 0], scale=spec.scale, window=win,
                 soft_cap=spec.attn_soft_cap, sink=sink,
                 kv_scale=spec.kv_scale,
-                interpret=jax.default_backend() != "tpu")
+                interpret=kernel_mode.pallas_interpret())
             if kernel_out is None:        # heads not shardable on this mesh
                 use_kernel = False
+                kernel_mode.note("decode", "xla",
+                                 "kv heads not shardable over the mp axes")
             else:
                 attn_out = kernel_out[:, None]
+                kernel_mode.note("decode", kernel_mode.kernel_path())
         if not use_kernel:
             # native-layout reads: K transposed (B, H, D, S), V (B, H, S,
             # D) — each attention einsum contracts its operand in place

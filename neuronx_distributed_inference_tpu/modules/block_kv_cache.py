@@ -66,12 +66,12 @@ def block_cache_pspec() -> P:
 
 
 def init_block_cache(spec: BlockKVSpec, mesh: Optional[Mesh] = None):
-    if mesh is not None:
-        sharding = NamedSharding(mesh, block_cache_pspec())
-        zeros = lambda: jax.device_put(jnp.zeros(spec.shape, spec.dtype), sharding)
-    else:
-        zeros = lambda: jnp.zeros(spec.shape, spec.dtype)
-    return {"k": zeros(), "v": zeros()}
+    # born on its sharding: at tp=4 each chip allocates its quarter of the
+    # pool, nothing is staged whole on the default device first
+    sharding = (NamedSharding(mesh, block_cache_pspec())
+                if mesh is not None else None)
+    return {name: jnp.zeros(spec.shape, spec.dtype, device=sharding)
+            for name in ("k", "v")}
 
 
 # ---------------------------------------------------------------------------
@@ -346,7 +346,8 @@ class BlockAllocator:
 class NativeBlockAllocator:
     """ctypes wrapper over the C++ allocator (native/block_allocator.cpp) —
     same interface and identical block-id sequences as :class:`BlockAllocator`
-    (asserted by tests). Used automatically when the native library builds."""
+    (asserted by tests). The default allocator (``NXDI_TPU_NATIVE=0``
+    selects the Python one)."""
 
     MAX_BLOCKS = 65536
 
@@ -357,7 +358,7 @@ class NativeBlockAllocator:
         self._ct = ctypes
         self._lib = native.load_library()
         if self._lib is None:
-            raise ImportError("native library unavailable")
+            raise ImportError("native library disabled (NXDI_TPU_NATIVE=0)")
         self.block_size = block_size
         self.num_blocks = num_blocks
         self.enable_prefix_caching = enable_prefix_caching
@@ -388,21 +389,16 @@ class NativeBlockAllocator:
         return list(out[:n]), int(cached.value)
 
     def probe(self, token_ids: Sequence[int]) -> Tuple[int, List[int]]:
-        """Read-only prefix-warmth probe (see :meth:`BlockAllocator.probe`).
-        Returns cold (0, []) under a pre-probe ``libnxdi_native.so`` that
-        was built before ``nxdi_alloc_probe`` existed — warmth ordering is
-        an optimization, never a correctness dependency."""
+        """Read-only prefix-warmth probe (see :meth:`BlockAllocator.probe`)."""
         if not self.enable_prefix_caching:
-            return 0, []
-        fn = getattr(self._lib, "nxdi_alloc_probe", None)
-        if fn is None:  # pragma: no cover - stale cached library
             return 0, []
         ct = self._ct
         toks = np.ascontiguousarray(np.asarray(token_ids, np.int64))
         max_out = max(1, len(toks) // self.block_size)
         out = (ct.c_int * max_out)()
-        cached = fn(self._h, toks.ctypes.data_as(ct.POINTER(ct.c_int64)),
-                    len(toks), out, max_out)
+        cached = self._lib.nxdi_alloc_probe(
+            self._h, toks.ctypes.data_as(ct.POINTER(ct.c_int64)),
+            len(toks), out, max_out)
         return int(cached), list(out[:cached // self.block_size])
 
     def extend(self, blocks: List[int], new_len: int) -> List[int]:
@@ -430,10 +426,11 @@ class NativeBlockAllocator:
 
 def make_block_allocator(num_blocks: int, block_size: int,
                          enable_prefix_caching: bool = True):
-    """Prefer the native C++ allocator; fall back to the Python one
-    (NXDI_TPU_NATIVE=0 forces the fallback)."""
+    """The native C++ allocator, or the Python one under NXDI_TPU_NATIVE=0.
+    A native build that fails raises (native.NativeBuildError): which
+    allocator runs is a choice, not an accident of the toolchain."""
     from .. import native
-    if native.native_enabled() and native.load_library() is not None:
+    if native.native_enabled():
         return NativeBlockAllocator(num_blocks, block_size,
                                     enable_prefix_caching)
     return BlockAllocator(num_blocks, block_size, enable_prefix_caching)

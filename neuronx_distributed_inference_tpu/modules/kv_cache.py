@@ -110,10 +110,9 @@ def init_cache(spec: KVCacheSpec, mesh: Optional[Mesh] = None,
                flash_decoding: bool = False):
     """Zero-initialized {'k','v'} cache, device-placed with the cache sharding."""
     def zeros(shape, pspec):
-        x = jnp.zeros(shape, spec.dtype)
-        if mesh is not None:
-            x = jax.device_put(x, NamedSharding(mesh, pspec))
-        return x
+        return jnp.zeros(shape, spec.dtype,
+                         device=(NamedSharding(mesh, pspec)
+                                 if mesh is not None else None))
 
     return {"k": zeros(spec.k_shape, k_pspec(flash_decoding)),
             "v": zeros(spec.v_shape, v_pspec(flash_decoding))}

@@ -163,10 +163,9 @@ def init_ssm_state(s: SSMSpec, Ls: int, batch: int, dtype, mesh=None
     pspecs = ssm_state_pspecs(s)
     out = {}
     for k, (shape, dt) in ssm_state_shapes(s, Ls, batch, dtype).items():
-        x = jnp.zeros(shape, dt)
-        if mesh is not None:
-            x = jax.device_put(x, NamedSharding(mesh, pspecs[k]))
-        out[k] = x
+        out[k] = jnp.zeros(shape, dt,
+                           device=(NamedSharding(mesh, pspecs[k])
+                                   if mesh is not None else None))
     return out
 
 

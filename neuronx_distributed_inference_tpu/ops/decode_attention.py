@@ -37,6 +37,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.3819763e38
 
+#: largest scalar-prefetch operand (layer, window, lens, block table) the paged
+#: kernel is asked to stage. v5e SMEM is 1 MiB and the kernel's own scoped
+#: use takes a few KiB of it: compiling for the v5e topology, 1,040,408 B
+#: went through and 1,044,744 B was refused (RESOURCE_EXHAUSTED, space=smem).
+PAGED_TABLE_SMEM_BYTES = 1_040_408
+
 
 def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, nk_ref, nv_ref, sink_ref,
                    o_ref, acc_ref, m_ref, l_ref, *,
@@ -282,8 +288,7 @@ def dispatch(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     b, hq, d = q.shape
     hkv = k_cache.shape[2]
     mp_axes = tuple(a for a in ("ep", "tp")
-                    if mesh is not None and a in mesh.axis_names
-                    and mesh.shape[a] > 1)
+                    if a in mesh.axis_names and mesh.shape[a] > 1)
     mp = 1
     for a in mp_axes:
         mp *= mesh.shape[a]
@@ -294,8 +299,8 @@ def dispatch(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
         # caller to take the head-sharded XLA path instead
         return None
     dp_axes = tuple(a for a in ("dp",)
-                    if mesh is not None and a in mesh.axis_names
-                    and mesh.shape[a] > 1 and b % mesh.shape[a] == 0)
+                    if a in mesh.axis_names and mesh.shape[a] > 1
+                    and b % mesh.shape[a] == 0)
     if not mp_axes and not dp_axes:
         return decode_attention_stacked(
             q, k_cache, v_cache, new_k, new_v, layer, lens, scale=scale,
@@ -435,12 +440,23 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     q (B, Hq, D); k_pages/v_pages (L, N, Bs, Hkv, D); new_k/new_v
     (B, Hkv, D); lens (B,) prior lengths; block_table (B, max_blocks)
     logical→physical page map (entry 0 = null page). Returns (B, Hq, D).
+
+    The WHOLE table rides scalar prefetch into SMEM — 4·(2 + B + B·max_blocks)
+    bytes — and a v5e core has 1 MiB of it (:data:`PAGED_TABLE_SMEM_BYTES`).
+    64 rows × 4,096 blocks does not fit; a caller that needs such tables
+    must split the batch or page the table (ROADMAP B2's long contexts).
     """
     b, hq, d = q.shape
     hkv = k_pages.shape[3]
     bs = k_pages.shape[2]
     mb = block_table.shape[1]
     g = hq // hkv
+    table_bytes = 4 * (2 + b + b * mb)
+    if table_bytes > PAGED_TABLE_SMEM_BYTES:
+        raise ValueError(
+            f"paged decode kernel: block table of {b} rows x {mb} blocks "
+            f"needs {table_bytes} B of SMEM scalar prefetch, over the "
+            f"{PAGED_TABLE_SMEM_BYTES} B a v5e core can hold")
 
     vmem_budget = 4 * 1024 * 1024
     max_nh = max(1, min(8, vmem_budget // (bs * d * 2 * 2 * 2)))
@@ -534,8 +550,7 @@ def paged_dispatch(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     b = q.shape[0]
     hkv = k_pages.shape[3]
     mp_axes = tuple(a for a in ("ep", "tp")
-                    if mesh is not None and a in mesh.axis_names
-                    and mesh.shape[a] > 1)
+                    if a in mesh.axis_names and mesh.shape[a] > 1)
     mp = 1
     for a in mp_axes:
         mp *= mesh.shape[a]
@@ -544,8 +559,8 @@ def paged_dispatch(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     # batch rows split over dp (pages stay replicated across dp — the
     # block cache has no dp axis, block_cache_pspec)
     dp_axes = tuple(a for a in ("dp",)
-                    if mesh is not None and a in mesh.axis_names
-                    and mesh.shape[a] > 1 and b % mesh.shape[a] == 0)
+                    if a in mesh.axis_names and mesh.shape[a] > 1
+                    and b % mesh.shape[a] == 0)
     if not mp_axes and not dp_axes:
         return paged_decode_attention(
             q, k_pages, v_pages, new_k, new_v, layer, lens, block_table,
@@ -593,42 +608,3 @@ def supports(spec, phase_t: int) -> bool:
     chunked local layers take the XLA path)."""
     return (phase_t == 1 and spec.mla is None
             and spec.head_dim in (64, 128) and spec.attn_chunk == 0)
-
-
-@functools.lru_cache(maxsize=None)
-def quantized_cache_ok(cache_dtype_name: str) -> bool:
-    """Whether Mosaic on this backend can stream cache blocks of the given
-    (non-compute) dtype — fp8 KV caches (reference analog: the TKG kernel
-    running over the fp8 KV cache, kv_cache_manager.py:636-692). Probed
-    once with an AOT compile of a tiny kernel; CPU interpret always works."""
-    if cache_dtype_name in ("bfloat16", "float32", "float16"):
-        return True
-    if jax.default_backend() != "tpu":
-        return True          # tests run the interpret path
-    try:
-        sds = jax.ShapeDtypeStruct
-        dt = jnp.dtype(cache_dtype_name)
-        # probe BOTH kernels: q (B=1, Hq=4, D) over a 1-kv-head cache —
-        # new_k/new_v carry Hkv=1 like the cache
-        fn = functools.partial(decode_attention_stacked, scale=1.0,
-                               kv_scale=None)
-        jax.jit(fn).lower(
-            sds((1, 4, 128), jnp.bfloat16),
-            sds((1, 1, 1, 128, 256), dt),
-            sds((1, 1, 1, 256, 128), dt),
-            sds((1, 1, 128), jnp.bfloat16),
-            sds((1, 1, 128), jnp.bfloat16),
-            sds((), jnp.int32), sds((1,), jnp.int32)).compile()
-        pfn = functools.partial(paged_decode_attention, scale=1.0,
-                                kv_scale=None)
-        jax.jit(pfn).lower(
-            sds((1, 4, 128), jnp.bfloat16),
-            sds((1, 4, 64, 1, 128), dt),
-            sds((1, 4, 64, 1, 128), dt),
-            sds((1, 1, 128), jnp.bfloat16),
-            sds((1, 1, 128), jnp.bfloat16),
-            sds((), jnp.int32), sds((1,), jnp.int32),
-            sds((1, 2), jnp.int32)).compile()
-        return True
-    except Exception:         # Mosaic rejects the dtype on this TPU gen
-        return False

@@ -162,8 +162,7 @@ def dispatch_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     mesh = jax.sharding.get_abstract_mesh()
     hq, hkv = q.shape[2], k.shape[2]
     mp_axes = tuple(a for a in ("ep", "tp")
-                    if mesh is not None and a in mesh.axis_names
-                    and mesh.shape[a] > 1)
+                    if a in mesh.axis_names and mesh.shape[a] > 1)
     mp = 1
     for a in mp_axes:
         mp *= mesh.shape[a]
@@ -171,8 +170,8 @@ def dispatch_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     # would all-gather the dp-sharded prefill activations and compute the
     # kernel dp-times redundantly
     dp_axes = tuple(a for a in ("dp",)
-                    if mesh is not None and a in mesh.axis_names
-                    and mesh.shape[a] > 1 and q.shape[0] % mesh.shape[a] == 0)
+                    if a in mesh.axis_names and mesh.shape[a] > 1
+                    and q.shape[0] % mesh.shape[a] == 0)
     if mp == 1 and not dp_axes:
         return flash_attention(q, k, v, scale=scale, causal=causal,
                                window=window, soft_cap=soft_cap,

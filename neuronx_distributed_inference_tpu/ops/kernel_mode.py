@@ -1,0 +1,60 @@
+"""How the Pallas kernels run, and which attention path each graph took.
+
+Two rules, both explicit:
+
+* **Interpret mode is asked for, never inferred.** A kernel call site passes
+  ``interpret=pallas_interpret()``, which is True only when the process
+  requested it (:func:`request_interpret` — the test conftest and
+  ``compat.force_cpu_devices`` do; the serving path on a TPU never does).
+  Nothing consults the process's default backend: a process whose TPU
+  failed to come up fails at the first kernel compile instead of quietly
+  emulating it.
+* **A declined kernel leaves a record.** Call sites :func:`note` the path
+  they took (``pallas`` / ``pallas-interpret`` / ``xla`` + why) at trace
+  time; the application collects the notes of its own graphs
+  (:func:`recording`) and serves them in ``warmup_state()["kernels"]`` —
+  i.e. ``/v1/debug/state`` and the precompile report.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import contextvars
+import os
+from typing import Optional, Set, Tuple
+
+INTERPRET_ENV = "NXDI_TPU_PALLAS_INTERPRET"
+
+_SINK: contextvars.ContextVar[Optional[Set[Tuple[str, str, str]]]] = \
+    contextvars.ContextVar("nxdi_kernel_notes", default=None)
+
+
+def request_interpret() -> None:
+    """Ask for interpret-mode Pallas kernels in this process and every
+    child it starts (the request rides the environment)."""
+    os.environ[INTERPRET_ENV] = "1"
+
+
+def pallas_interpret() -> bool:
+    return os.environ.get(INTERPRET_ENV) == "1"
+
+
+def kernel_path() -> str:
+    """The label a call site notes when it takes its kernel."""
+    return "pallas-interpret" if pallas_interpret() else "pallas"
+
+
+@contextlib.contextmanager
+def recording(sink: Set[Tuple[str, str, str]]):
+    """Collect the :func:`note` calls of everything traced in the body."""
+    token = _SINK.set(sink)
+    try:
+        yield
+    finally:
+        _SINK.reset(token)
+
+
+def note(site: str, path: str, reason: str = "") -> None:
+    sink = _SINK.get()
+    if sink is not None:
+        sink.add((site, path, reason))

@@ -205,7 +205,7 @@ def sample_dp(logits: jnp.ndarray, config, sampling_params, key,
     if mesh is None:
         mesh = jax.sharding.get_abstract_mesh()
     b = logits.shape[0]
-    if (mesh is None or "dp" not in getattr(mesh, "axis_names", ())
+    if ("dp" not in mesh.axis_names
             or mesh.shape["dp"] <= 1 or b % mesh.shape["dp"] != 0):
         return sample(logits, config, sampling_params, key)
     from jax.sharding import PartitionSpec as P

@@ -214,7 +214,7 @@ def quantized_row_parallel(x: jnp.ndarray, w: Any, *, dtype: str,
 
     require_supported_dtype(dtype)
     mesh = jax.sharding.get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    if not mesh.axis_names:
         return qlinear(x, w)
     mp_axes = _live_axes(mesh, AXIS_MP)
     g = math.prod(mesh.shape[a] for a in mp_axes)

@@ -149,8 +149,12 @@ def single_device_mesh() -> Mesh:
     return build_mesh(MeshConfig())
 
 
-def mesh_from_config(tpu_config) -> Mesh:
-    """Build mesh from a TpuConfig's parallelism degrees."""
+def mesh_from_config(tpu_config,
+                     devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
+    """Build mesh from a TpuConfig's parallelism degrees, over ``devices``
+    (default: the first ``tp_degree`` of ``jax.devices()``). Several
+    replicas in one process each name their own devices — left to the
+    default they would all land on device 0."""
     # attention-DP / CP / EP all subdivide the tp rank set in the reference
     # (tp_degree counts ALL ranks; cp/dp/ep are groupings of them:
     # attention_process_groups.py:36-163, moe_v2.py:135-161). Here tp axis =
@@ -163,7 +167,7 @@ def mesh_from_config(tpu_config) -> Mesh:
         raise ValueError(f"tp_degree {tpu_config.tp_degree} not divisible by "
                          f"cp*dp*ep = {shrink}")
     return build_mesh(MeshConfig(tp=tpu_config.tp_degree // shrink, cp=cp, dp=dp,
-                                 ep=ep))
+                                 ep=ep), devices)
 
 
 # ---------------------------------------------------------------------------
@@ -173,11 +177,11 @@ def mesh_from_config(tpu_config) -> Mesh:
 def shard_constraint(x, *spec):
     """``with_sharding_constraint`` that no-ops outside a mesh context —
     the shared helper for model code (traced under jit with a mesh active;
-    plain-eager tests run without one)."""
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except (ValueError, RuntimeError):
+    plain-eager tests run without one). Inside a mesh a constraint that
+    cannot be applied raises: an unsharded activation is not a fallback."""
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def named(mesh: Mesh, *spec) -> NamedSharding:

@@ -1,8 +1,8 @@
-"""Serving resilience layer: typed failure taxonomy, deterministic
+"""Serving resilience layer: typed failure hierarchy, deterministic
 fault injection, and recompute-preemption policies.
 
 The serving adapters (``serving.py``) and the paged cache manager
-(``modules/block_kv_cache.py``) raise ONLY exceptions from this taxonomy at
+(``modules/block_kv_cache.py``) raise ONLY exceptions from this hierarchy at
 their public boundaries (enforced by the ``error-paths`` pass of
 ``scripts/nxdi_lint.py``, a
 tier-1 lint). Every recovery path — transactional admission rollback,

@@ -1,4 +1,4 @@
-"""Typed failure taxonomy for the serving surface.
+"""Typed failure hierarchy for the serving surface.
 
 Everything the engine adapters and the paged KV cache manager raise at
 their public boundaries derives from :class:`ServingError`, so an engine
@@ -9,7 +9,7 @@ the step (:class:`StepFailure` — host state is rolled back before it
 propagates).
 
 Each class also subclasses the builtin it replaced (``ValueError`` /
-``RuntimeError`` / ``TimeoutError``) so pre-taxonomy callers written
+``RuntimeError`` / ``TimeoutError``) so pre-hierarchy callers written
 against the old ad-hoc raises keep working unchanged.
 """
 
@@ -26,7 +26,7 @@ __all__ = [
 
 
 class ServingError(Exception):
-    """Base of the serving failure taxonomy. :attr:`seq_ids` carries the
+    """Base of the serving failure hierarchy. :attr:`seq_ids` carries the
     affected sequence ids when the failure is attributable to specific
     rows (empty otherwise), so engines never have to parse messages.
 

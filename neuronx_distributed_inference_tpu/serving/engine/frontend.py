@@ -55,7 +55,7 @@ Client-gone behaviour: when an SSE write fails (peer reset / closed), the
 front end cancels the request through the engine — blocks are reclaimed
 and the stream finishes "cancelled" — so a dead client can never pin KV.
 
-Errors map onto the typed taxonomy: QueueOverflow -> 429,
+Errors map onto the typed hierarchy: QueueOverflow -> 429,
 AdmissionError -> 400, unknown ids -> 404, closed engine -> 503.
 """
 

@@ -336,7 +336,7 @@ class ServingEngine:
         idle) so SSE writers and new submits interleave.
 
         An UNEXPECTED exception (not part of the :class:`ServingError`
-        taxonomy — an engine bug, a broken adapter hook) must not kill
+        hierarchy — an engine bug, a broken adapter hook) must not kill
         the loop bare with every client stream left hanging: it is
         wrapped into an unrecoverable :class:`StepFailure`, the
         post-mortem is dumped (``debug_dump_dir``) and every stream

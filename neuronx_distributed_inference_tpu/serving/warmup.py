@@ -10,10 +10,10 @@ the first request lands. Every first-seen graph is timed into
 ``nxdi_compile_seconds{kind,bucket}`` and classified through jax's
 compilation-cache monitoring events: a real XLA build increments
 ``nxdi_jit_compiles_total``, a persistent-cache load (N replicas share
-``jax_compilation_cache_dir`` — models/application.py sets it, the test
-suite's conftest has the pattern) counts as ``nxdi_jit_cache_hits_total``
-instead. That split is what makes the ROADMAP item-5 pin ("a second
-replica compiles nothing") fall out of the counters.
+the one cache directory utils/compile_cache.py resolves) counts as
+``nxdi_jit_cache_hits_total`` instead. That split is what makes the
+ROADMAP item-5 pin ("a second replica compiles nothing") fall out of the
+counters.
 
 After the walk the application enters **declared steady state**
 (:meth:`~..models.application.CausalLMApplication.declare_steady_state`):
@@ -102,9 +102,12 @@ _MONITOR = _CompileCacheMonitor()
 # ---------------------------------------------------------------------------
 def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
     """The warm plan of a paged application: the unified ragged row
-    ladder across every block-table width bucket, the fused decode loop,
-    and the speculative verify widths — the exact shape set the serving
-    adapters dispatch (serving/ragged/path.py, serving/adapter.py)."""
+    ladder across every block-table width bucket, the two-phase ``paged``
+    graph at T=1 and the ctx-bucket chunk widths (what the default
+    ``PagedEngineAdapter(app)`` — and a ragged adapter shed back to
+    two-phase — dispatches), the fused decode loop, and the speculative
+    verify widths: the exact shape set the serving adapters dispatch
+    (serving/ragged/path.py, serving/adapter.py)."""
     cfg = app.tpu_config
     b = cfg.batch_size
     if widths is None:
@@ -134,11 +137,23 @@ def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
                             np.ones((b,), np.int32),
                             np.zeros((b,), np.int32), **kw)
 
+        def paged_thunk(w, bt=bt, **kw):
+            app._run_paged(np.zeros((b, w), np.int32),
+                           np.zeros((b, w), np.int32),
+                           np.full((b, w), -1, np.int32), bt,
+                           np.zeros((b,), np.int32), **kw)
+
         for w in sorted(widths):
             plan.append(("ragged", w, lambda w=w, bt=bt: ragged_thunk(w, bt)))
             if lora_kw is not None:
                 plan.append(("ragged_lora", w,
                              lambda w=w, bt=bt: ragged_thunk(w, bt, **lora_kw)))
+            if w == 1 or w in app.ctx_buckets:
+                plan.append(("paged", w,
+                             lambda w=w, bt=bt: paged_thunk(w, bt)))
+                if lora_kw is not None:
+                    plan.append(("paged_lora", w, lambda w=w, bt=bt:
+                                 paged_thunk(w, bt, **lora_kw)))
         if chunk > 1:
             plan.append(("paged_loop", chunk, lambda bt=bt: app._run_paged_loop(
                 np.zeros((b,), np.int32), np.zeros((b,), np.int32), bt,
@@ -269,6 +284,8 @@ def precompile(app, *, registry=None, widths: Optional[Sequence[int]] = None,
         "total_seconds": total,
         "cache_monitored": monitored,
         "graphs": graphs,
+        # which attention path each traced graph took (ops/kernel_mode.py)
+        "kernels": app.warmup_state()["kernels"],
     }
     app._warmup_report = report
     if declare_steady:

@@ -20,13 +20,13 @@ Per-graph results land in the metrics registry when one is live
 ``nxdi_graph_peak_bytes``, labels ``kind``+``bucket``) and in the returned
 report dict (schema ``nxdi-graph-report-v1``), which also carries a static
 roofline estimate per bucket: arithmetic intensity, the
-compute-vs-memory-bound verdict, and the estimated step time under the
-assumed peak flops / HBM bandwidth (``NXDI_TPU_PEAK_TFLOPS``, default 197
-— v5e bf16; ``NXDI_TPU_HBM_GBPS``, default 819).
+compute-vs-memory-bound verdict, and the estimated step time projected
+onto the published peaks of a named ``device_kind`` (utils/device.py
+``DEVICE_PEAKS``; default v5e — 197 TFLOP/s bf16, 819 GB/s HBM).
 
 ``bench.py --graph-report`` drives this on the tiny synthetic model and
 commits the artifact (``artifacts/graph_report_r08.json``) so cold-start
-and graph-size regressions show up in BENCH_* rounds with no hardware.
+and graph-size regressions show up as a diff with no hardware.
 
 Compiling through fresh ``jax.jit`` wrappers keeps the application's own
 jit cache keys untouched — running the observatory can never change what
@@ -46,11 +46,10 @@ dimension that makes the quantized-collective win census-visible). The
 census lands per graph in the report, in the
 ``nxdi_graph_collectives_total`` / ``nxdi_graph_collective_bytes``
 gauges (labels ``kind``+``comm``+``dtype``), and in a third roofline
-leg: the estimated collective wire time under ``NXDI_TPU_ICI_GBPS``
-(default 200 GB/s — v5e ICI) and ``NXDI_TPU_DCN_GBPS`` (default 25
-GB/s; axes named by the ``parallel.mesh.Topology`` spec — by default
-``dp``, the outermost axis — are priced at DCN, everything else at
-ICI), upgrading the per-graph verdict to compute- vs memory- vs
+leg: the estimated collective wire time at the table's ICI bandwidth
+(v5e: 200 GB/s) and its assumed per-chip DCN share (25 GB/s; axes named
+by the ``parallel.mesh.Topology`` spec — by default ``dp``, the
+outermost axis — are priced at DCN, everything else at ICI), upgrading the per-graph verdict to compute- vs memory- vs
 **comm**-bound — the regime EQuARX (PAPERS.md arxiv 2506.17615) shows
 dominates DCN-scale decode. The leg also reports ``comm_bytes_saved``:
 wire bytes the sub-fp32 payloads avoid relative to an fp32 exchange of
@@ -84,6 +83,7 @@ import numpy as np
 from . import metrics as tmetrics
 from .registry import get_registry
 from ..parallel.mesh import Topology, topology_from_env
+from ..utils.device import V5E, device_peaks
 
 __all__ = ["analyze_app", "census_collectives", "aggregate_census",
            "comm_roofline_seconds", "mesh_comm_labels",
@@ -589,27 +589,23 @@ def _hlo_text(compiled) -> Optional[str]:
         return None
 
 
-def analyze_app(app, registry=None, hbm_gbps: Optional[float] = None,
-                peak_tflops: Optional[float] = None,
-                ici_gbps: Optional[float] = None,
-                dcn_gbps: Optional[float] = None) -> Dict[str, Any]:
+def analyze_app(app, registry=None,
+                device_kind: str = V5E) -> Dict[str, Any]:
     """AOT-compile every bucket-ladder graph of ``app`` and return the
     graph report (see module docstring). Gauges are recorded on
     ``registry`` (default: the process-global one) when it is enabled.
+    The roofline legs are a PROJECTION onto ``device_kind``'s published
+    peaks (utils/device.py; an unknown kind raises) — the report names
+    the kind under ``assumptions``, whatever backend compiled the graphs.
 
     On a multi-device mesh the partitioned HLO of each graph is censused
     for collectives (per-graph ``collectives`` + the third roofline leg);
     on a single-device mesh the census is a guard — any collective in an
     unsharded graph raises RuntimeError."""
     reg = registry if registry is not None else get_registry()
-    if hbm_gbps is None:
-        hbm_gbps = float(os.environ.get("NXDI_TPU_HBM_GBPS", "819"))
-    if peak_tflops is None:
-        peak_tflops = float(os.environ.get("NXDI_TPU_PEAK_TFLOPS", "197"))
-    if ici_gbps is None:
-        ici_gbps = float(os.environ.get("NXDI_TPU_ICI_GBPS", "200"))
-    if dcn_gbps is None:
-        dcn_gbps = float(os.environ.get("NXDI_TPU_DCN_GBPS", "25"))
+    peaks = device_peaks(device_kind)
+    hbm_gbps, peak_tflops = peaks.hbm_gbps, peaks.bf16_tflops
+    ici_gbps, dcn_gbps = peaks.ici_gbps, peaks.dcn_gbps
     if app.params is None:
         raise ValueError("load_weights() or init_random_weights() first")
     if app.cache is None:
@@ -639,29 +635,23 @@ def analyze_app(app, registry=None, hbm_gbps: Optional[float] = None,
                 f"collectives: {aggregate_census(census)} — a "
                 "shard_map/psum leaked into the unsharded path")
         coll_bytes = sum(e["bytes"] for e in census) if census else 0
-        roofline = None
-        if peak_tflops > 0 and hbm_gbps > 0:
-            # a zero assumption means "unknown chip" — the static
-            # flops/bytes/compile data is still valid without a roofline
-            t_compute = flops / (peak_tflops * 1e12)
-            t_memory = bytes_acc / (hbm_gbps * 1e9)
-            t_comm = (comm_roofline_seconds(census, ici_gbps, dcn_gbps)
-                      if census else 0.0)
-            saved = (sum(_wire_bytes_saved(e) for e in census)
-                     if census else 0.0)
-            legs = {"compute": t_compute, "memory": t_memory,
-                    "comm": t_comm}
-            bound = max(legs, key=legs.get)
-            roofline = {
-                "est_step_ms": round(max(legs.values()) * 1e3, 6),
-                "bound": bound,
-                "t_compute_ms": round(t_compute * 1e3, 6),
-                "t_memory_ms": round(t_memory * 1e3, 6),
-                "t_comm_ms": round(t_comm * 1e3, 6),
-                # wire bytes the quantized (sub-fp32) payloads avoid vs
-                # an fp32 exchange of the same shapes — 0 on fp32 graphs
-                "comm_bytes_saved": int(round(saved)),
-            }
+        t_compute = flops / (peak_tflops * 1e12)
+        t_memory = bytes_acc / (hbm_gbps * 1e9)
+        t_comm = (comm_roofline_seconds(census, ici_gbps, dcn_gbps)
+                  if census else 0.0)
+        saved = (sum(_wire_bytes_saved(e) for e in census)
+                 if census else 0.0)
+        legs = {"compute": t_compute, "memory": t_memory, "comm": t_comm}
+        roofline = {
+            "est_step_ms": round(max(legs.values()) * 1e3, 6),
+            "bound": max(legs, key=legs.get),
+            "t_compute_ms": round(t_compute * 1e3, 6),
+            "t_memory_ms": round(t_memory * 1e3, 6),
+            "t_comm_ms": round(t_comm * 1e3, 6),
+            # wire bytes the quantized (sub-fp32) payloads avoid vs
+            # an fp32 exchange of the same shapes — 0 on fp32 graphs
+            "comm_bytes_saved": int(round(saved)),
+        }
         graph: Dict[str, Any] = {
             "kind": kind,
             "bucket": bucket,
@@ -707,7 +697,8 @@ def analyze_app(app, registry=None, hbm_gbps: Optional[float] = None,
                  "axes": {a: int(s) for a, s in
                           zip(mesh.axis_names, mesh.devices.shape)
                           if int(s) > 1}},
-        "assumptions": {"hbm_gbps": hbm_gbps, "peak_tflops": peak_tflops,
+        "assumptions": {"device_kind": device_kind,
+                        "hbm_gbps": hbm_gbps, "peak_tflops": peak_tflops,
                         "ici_gbps": ici_gbps, "dcn_gbps": dcn_gbps},
         "graphs": graphs,
         "totals": {

@@ -130,6 +130,7 @@ def check_accuracy_logits(app, hf_model, input_ids: np.ndarray,
 
     max_err, num_div, first = 0.0, 0, None
     checked = 0
+    compared = {}        # (step, row) -> the (V,) logits held to the golden
     for step in range(min(max_t, len(step_logits))):
         atol, rtol = (tol_map or {}).get(step, (divergence_difference_tol, 0.0))
         for i in range(b):
@@ -141,6 +142,7 @@ def check_accuracy_logits(app, hf_model, input_ids: np.ndarray,
             else:
                 ours = step_logits[step][i, -1, :]
             v = min(ours.shape[-1], golden.shape[-1])
+            compared[(step, i)] = np.asarray(ours[:v])
             err = np.abs(ours[:v] - golden[:v])
             max_err = max(max_err, float(err.max()))
             div = err > (atol + rtol * np.abs(golden[:v]))
@@ -151,4 +153,5 @@ def check_accuracy_logits(app, hf_model, input_ids: np.ndarray,
                     first = step
     return AccuracyReport(passed=num_div == 0, mode="logit-matching",
                           num_tokens_checked=checked, num_divergences=num_div,
-                          first_divergence_index=first, max_error=max_err)
+                          first_divergence_index=first, max_error=max_err,
+                          details={"logits": compared})

@@ -11,7 +11,6 @@ the reference's assert_close semantics."""
 from __future__ import annotations
 
 import dataclasses
-import os
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -22,18 +21,10 @@ def init_cpu_env(num_devices: int = 8) -> int:
     """Force the virtual-CPU backend with ``num_devices`` devices
     (reference: init_cpu_env's gloo world + NXD_CPU_MODE). Must run before
     the JAX backend initializes; returns the device count actually live."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={num_devices}"
-        ).strip()
     import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_num_cpu_devices", num_devices)
-    except RuntimeError:
-        pass
+
+    from ..compat import force_cpu_devices
+    force_cpu_devices(num_devices)
     return len(jax.devices())
 
 

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Micro-bench: Pallas flash prefill kernel (causal DMA elision) vs the XLA
-attention path on the real chip — the VERDICT r3 win-or-delete data.
+attention path on the real chip — the win-or-delete data.
 Prints one JSON line per (seq, window)."""
 import json
 import os

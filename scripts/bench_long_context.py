@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Long-context prefill benchmark on the real chip (VERDICT r4 ask #5):
+"""Long-context prefill benchmark on the real chip:
 8k-token windowed context encoding on the bench model geometry — prefill
 tokens/s and wall time, printed as one JSON line."""
 import json

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Measure the XLA decode-MoE path against the HBM roofline on the real
-chip (VERDICT r4 ask #10; reference analog: the moe_token_gen NKI kernel of
+chip (reference analog: the moe_token_gen NKI kernel of
 SURVEY §2.10 — this measurement decides whether a Pallas token-gen MoE
 kernel is warranted).
 

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Micro-bench: ragged paged decode kernel vs the XLA gather path on the
-real chip (VERDICT r3 ask: show the kernel beating the gather path at
+real chip (show the kernel beating the gather path at
 max_blocks >= 4x live length). Prints one JSON line per configuration."""
 import json
 import os

@@ -259,14 +259,10 @@ def _tiny_engine():
 
 
 def main(argv=None) -> int:
-    import jax
-
     from neuronx_distributed_inference_tpu import telemetry
+    from neuronx_distributed_inference_tpu.compat import force_cpu_devices
 
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass  # backend already initialized
+    force_cpu_devices(1)
     telemetry.enable()
     try:
         text = scrape_frontend(_tiny_engine())

@@ -31,7 +31,7 @@ plumbing; all CPU-mesh compiles, no execution):
   * ``moe_tkg_dp2ep2tp2`` — hybrid-MoE decode (``tkg_experts_local``
     reshard — the PR-5 remat surface), dp2 x ep2 x tp2 (8 devices)
   * ``paged_decode_dp2tp2`` / ``paged_loop_dp2tp2`` — the serving/paged
-    step + fused decode loop on a mesh (VERDICT weak #6: first compiled
+    step + fused decode loop on a mesh (first compiled
     coverage of the paged path on multi-device)
   * ``cb_decode_dp2tp2``  — continuous-batching decode step
   * ``paged_spec_verify_dp2tp2`` — the speculative ragged k+1-wide
@@ -342,10 +342,8 @@ def load_golden(path: Path) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 def _setup_jax():
-    from neuronx_distributed_inference_tpu.compat import (ensure_jax_compat,
-                                                          force_cpu_devices)
+    from neuronx_distributed_inference_tpu.compat import force_cpu_devices
     force_cpu_devices(8)
-    ensure_jax_compat()
     import jax
     if len(jax.devices()) < 8:
         print(f"check_spmd_sharding: SKIP — need 8 virtual CPU devices, "

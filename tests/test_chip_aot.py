@@ -1,0 +1,171 @@
+"""The serving graphs compile for the chip — checked without one.
+
+``jax.experimental.topologies.get_topology_desc(platform="tpu",
+topology_name="v5e:2x2")`` hands back four abstract v5e devices while the
+process itself stays on the CPU backend; lowering a jitted function over
+``ShapeDtypeStruct`` arguments sharded on them runs XLA:TPU and Mosaic from
+the installed libtpu. This is what keeps "the kernels compile, and stay
+compiled" true between chip runs (``chip_smoke.py`` is the run itself):
+
+  * Llama-3.2-1B widths (the smoke's geometry, depth 2), tp=1 and tp=4:
+    ``paged_forward_step`` at T=1 and one prefill width,
+    ``paged_decode_loop`` and ``paged_ragged_step``, kernels compiled for
+    real — the T=1 graphs must hold a Mosaic custom call, and the tp=4
+    compile log no involuntary full rematerialization;
+  * OLMoE-1B-7B widths (ROADMAP B1, depth 1): the same graphs lower, so
+    the first benchmark cell starts from a graph known to compile.
+"""
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+import chip_smoke
+from neuronx_distributed_inference_tpu.config import TpuConfig
+from neuronx_distributed_inference_tpu.models import model_base
+from neuronx_distributed_inference_tpu.models.family import get_family
+from neuronx_distributed_inference_tpu.modules.block_kv_cache import (
+    BlockKVSpec, block_cache_pspec)
+from neuronx_distributed_inference_tpu.ops import kernel_mode
+from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
+from neuronx_distributed_inference_tpu.parallel.mesh import (MeshConfig,
+                                                             build_mesh)
+from neuronx_distributed_inference_tpu.telemetry import observatory
+
+MOSAIC = 'custom_call_target="tpu_custom_call"'
+
+# allenai/OLMoE-1B-7B-0125-Instruct config.json (model-configs catalog)
+OLMOE_1B_7B = dict(
+    model_type="olmoe", hidden_size=2048, intermediate_size=1024,
+    num_hidden_layers=16, num_attention_heads=16, num_key_value_heads=16,
+    num_experts=64, num_experts_per_tok=8, norm_topk_prob=False,
+    vocab_size=50304, rms_norm_eps=1e-5, rope_theta=10000.0,
+    max_position_embeddings=4096, hidden_act="silu",
+    tie_word_embeddings=False)
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no libtpu in this environment
+        pytest.skip(f"no TPU compiler available here: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def as_on_the_chip(monkeypatch):
+    """Undo the two things conftest sets for CPU tests that a serving
+    process on a chip does not have: the interpret-mode request, and
+    float32 ("highest") matmul precision — under which Mosaic refuses the
+    bf16 ragged_dot kernel of the MoE prefill outright."""
+    monkeypatch.delenv(kernel_mode.INTERPRET_ENV, raising=False)
+    # a CPU process cannot load a TPU executable back, so caching these
+    # would only fill the suite's cache directory
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
+    try:
+        with jax.default_matmul_precision("default"):
+            yield
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          floor)
+
+
+def _compile_serving_graphs(hf_attrs, layers, tp, devices, serve, only=None):
+    """Lower + compile the paged serving graph family (or the ``only``
+    subset) for ``devices`` and return {name: compiled text}."""
+    mesh = build_mesh(MeshConfig(tp=tp), devices)
+    tcfg = TpuConfig(tp_degree=tp, dtype="bfloat16", enable_bucketing=True,
+                     is_block_kv_layout=True, is_prefix_caching=True, **serve)
+    family = get_family(hf_attrs["model_type"])
+    icfg = family.config_cls(tcfg, **dict(hf_attrs,
+                                          num_hidden_layers=layers))
+    spec = family.build_spec(icfg, tp_degree=tp)
+
+    def sds(shape, dtype, pspec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, pspec))
+
+    params = jax.tree.map(lambda ps: sds(ps.shape, ps.dtype, ps.pspec),
+                          model_base.decoder_param_specs(spec),
+                          is_leaf=lambda x: isinstance(x, ParamSpec))
+    bspec = BlockKVSpec(
+        num_layers=spec.num_layers, num_blocks=tcfg.pa_num_blocks + 1,
+        block_size=tcfg.pa_block_size, num_kv_heads=spec.gqa.num_kv_heads,
+        head_dim=spec.head_dim, dtype=spec.kv_dtype)
+    cache = {k: sds(bspec.shape, bspec.dtype, block_cache_pspec())
+             for k in ("k", "v")}
+    b, mb = tcfg.batch_size, bspec.blocks_for(tcfg.seq_len)
+    i32 = jnp.int32
+    rng = sds((2,), jnp.uint32)
+    width = tcfg.context_encoding_buckets[0]
+
+    def rows(w):
+        return (sds((b, w), i32),) * 3 + (sds((b, mb), i32),)
+
+    graphs = {
+        "paged_t1": (partial(model_base.paged_forward_step, spec, tcfg),
+                     (*rows(1), sds((b,), i32), None, rng)),
+        "paged_prefill": (partial(model_base.paged_forward_step, spec, tcfg),
+                          (*rows(width), sds((b,), i32), None, rng)),
+        "decode_loop": (partial(model_base.paged_decode_loop, spec, tcfg,
+                                num_steps=4),
+                        (sds((b,), i32), sds((b,), i32), sds((b, mb), i32),
+                         None, rng)),
+        "ragged_w1": (partial(model_base.paged_ragged_step, spec, tcfg),
+                      (*rows(1), sds((b,), i32), sds((b,), i32), None, rng)),
+        "ragged_prefill": (partial(model_base.paged_ragged_step, spec, tcfg),
+                           (*rows(width), sds((b,), i32), sds((b,), i32),
+                            None, rng)),
+    }
+    out = {}
+    with jax.sharding.set_mesh(mesh):
+        for name, (fn, args) in graphs.items():
+            if only is not None and name not in only:
+                continue
+            out[name] = jax.jit(fn, donate_argnums=(1,)).lower(
+                params, cache, *args).compile().as_text()
+    return out
+
+
+@pytest.mark.parametrize("tp", [1, 4])
+def test_llama_1b_serving_graphs_compile_for_v5e(v5e_devices, tp):
+    counts = {"spmd_warnings": 0, "involuntary_remat": 0}
+    with observatory.capture_compiler_stderr(counts, tee=False):
+        texts = _compile_serving_graphs(
+            chip_smoke.LLAMA_3_2_1B, 2, tp, v5e_devices, chip_smoke.SERVE,
+            only=("paged_t1", "paged_prefill", "decode_loop", "ragged_w1"))
+    # the decode graphs hold the kernel — read from the executable
+    for name in ("paged_t1", "decode_loop", "ragged_w1"):
+        assert MOSAIC in texts[name], f"{name}: no Mosaic custom call"
+    # wide rows have no kernel yet (ROADMAP A3): the full-table gather
+    assert MOSAIC not in texts["paged_prefill"]
+    assert counts["involuntary_remat"] == 0
+
+
+def test_olmoe_1b_7b_serving_graphs_compile_for_v5e(v5e_devices):
+    texts = _compile_serving_graphs(
+        OLMOE_1B_7B, 1, 1, v5e_devices,
+        dict(batch_size=8, seq_len=2048, pa_block_size=32, pa_num_blocks=512,
+             context_encoding_buckets=[128]),
+        only=("ragged_w1", "ragged_prefill"))
+    assert MOSAIC in texts["ragged_w1"]
+
+
+def test_without_the_request_nothing_is_interpreted(v5e_devices,
+                                                    monkeypatch):
+    """The other side of the rule: with interpret mode requested the same
+    lowering holds NO Mosaic call — so the request must never reach a
+    serving process on a chip (chip_smoke.py refuses to run under it)."""
+    monkeypatch.setenv(kernel_mode.INTERPRET_ENV, "1")
+    texts = _compile_serving_graphs(chip_smoke.LLAMA_3_2_1B, 1, 1,
+                                    v5e_devices[:1], chip_smoke.SERVE,
+                                    only=("paged_t1",))
+    assert MOSAIC not in texts["paged_t1"]

@@ -1,4 +1,4 @@
-"""Serving resilience layer: typed failure taxonomy, transactional
+"""Serving resilience layer: typed failure hierarchy, transactional
 admission, recompute preemption under KV pressure, per-request budgets,
 and the deterministic fault-injection harness.
 
@@ -128,10 +128,10 @@ def _kv_state(app):
 
 
 # ---------------------------------------------------------------------------
-# taxonomy + harness mechanics (no device work)
+# hierarchy + harness mechanics (no device work)
 # ---------------------------------------------------------------------------
 
-def test_taxonomy_subclasses_builtins():
+def test_error_types_subclass_builtins():
     # the whole family is catchable as ServingError...
     for exc in (AdmissionError, SequenceStateError, ConfigurationError,
                 CapacityError, KVCacheStateError, DeadlineExceeded,

@@ -1,5 +1,5 @@
-"""CPU-mesh tier-1 coverage for the SERVING path (ROADMAP item 2a start;
-VERDICT weak #6): block-KV + continuous-batching decode driven through
+"""CPU-mesh tier-1 coverage for the SERVING path (ROADMAP item 2a start):
+block-KV + continuous-batching decode driven through
 ``PagedEngineAdapter`` over a dp2 x tp2 mesh of virtual CPU devices.
 
 Correctness gate mirrors test_parallelism.py: sharded execution must

@@ -93,6 +93,7 @@ def test_precompile_report_and_debug_surface(warm_app):
     graph exactly once, and surfaces through ``warmup_state()`` (the
     ``/v1/debug/state["warmup"]`` payload) with steady state declared."""
     rep = warm_app._warmup_report
+    ws = warm_app.warmup_state()
     assert rep["schema"] == WARMUP_SCHEMA
     assert rep["n_graphs"] == len(rep["graphs"]) >= len(WARM_WIDTHS)
     assert (rep["n_compiles"] + rep["n_cache_loads"] + rep["n_warm_hits"]
@@ -100,9 +101,19 @@ def test_precompile_report_and_debug_surface(warm_app):
     assert rep["total_seconds"] > 0
     for g in rep["graphs"]:
         assert g["outcome"] in ("compile", "cache_load", "warm")
-        assert g["seconds"] >= 0 and g["kind"] == "ragged"
-    assert sorted(g["bucket"] for g in rep["graphs"]) == sorted(WARM_WIDTHS)
-    ws = warm_app.warmup_state()
+        assert g["seconds"] >= 0 and g["kind"] in ("ragged", "paged")
+    assert sorted(g["bucket"] for g in rep["graphs"]
+                  if g["kind"] == "ragged") == sorted(WARM_WIDTHS)
+    # the two-phase graph the DEFAULT adapter dispatches is warmed too:
+    # T=1 plus whichever warm widths are ctx buckets (none of these are)
+    assert [g["bucket"] for g in rep["graphs"]
+            if g["kind"] == "paged"] == [1]
+    # every traced T=1 graph noted which attention path it took — this
+    # toy's head_dim 16 is outside the kernel's geometry, and says so
+    assert rep["kernels"] == [
+        {"site": "paged_decode", "path": "xla",
+         "reason": "unsupported geometry (mla / head_dim / attn_chunk)"}]
+    assert ws["kernels"] == rep["kernels"]
     assert ws["steady_state"] is True
     assert ws["graphs_seen"] >= rep["n_graphs"]
     assert ws["precompile"]["n_graphs"] == rep["n_graphs"]
@@ -128,7 +139,8 @@ def test_second_replica_compiles_nothing():
     # counters tell the same story: no compile series on replica 2 ...
     c2 = reg2.get(tmetrics.JIT_COMPILES_TOTAL)
     assert c2 is None or c2.get(kind="ragged", bucket="1") == 0
-    assert (reg2.get(tmetrics.JIT_CACHE_HITS_TOTAL).get(kind="ragged")
+    hits2 = reg2.get(tmetrics.JIT_CACHE_HITS_TOTAL)
+    assert (hits2.get(kind="ragged") + hits2.get(kind="paged")
             == rep2["n_graphs"])
     # ... but cold-start truth per graph regardless: compile_seconds is
     # set for every first-seen signature, build or load
