@@ -1818,11 +1818,6 @@ def _tpu_bench_main(device):
     icfg = LlamaInferenceConfig(tcfg, **hf_attrs)
     mesh = build_mesh(MeshConfig(tp=1))
     app = CausalLMApplication(None, icfg, LlamaFamily, mesh=mesh)
-    # pin the app itself to the no-op registry: its _tel_end hook syncs
-    # (block_until_ready) after every _run_* call, which would serialize the
-    # async-chained dispatch trains the slope methodology below depends on.
-    # Host-only counters (bucket selections) still reach `reg`.
-    app.telemetry = telemetry.NULL_REGISTRY
     app.init_random_weights(seed=0)
     app.init_cache()
 

@@ -1,0 +1,7 @@
+"""Device idle time while the host was in the ``sched`` class of spans."""
+
+from harness import host_spans
+
+
+def read(ctx):
+    return host_spans.idle_share(ctx, "sched")

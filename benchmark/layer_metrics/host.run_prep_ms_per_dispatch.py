@@ -1,0 +1,9 @@
+"""Host seconds inside ``run.<kind>`` (``run.paged`` for the default adapter), per
+dispatch; None where the program has no such counter (a data-only
+``counter_ratio`` would read 0 there)."""
+
+from harness import host_spans
+
+
+def read(ctx):
+    return host_spans.per_dispatch_ms(ctx, "run_prep_s")

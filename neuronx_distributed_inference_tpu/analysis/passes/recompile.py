@@ -83,9 +83,12 @@ def _static_argnames(call: ast.Call) -> Set[str]:
 
 
 def _partial_root(call: ast.Call) -> Optional[Tuple[str, Set[str]]]:
-    """``partial(X, ..., kw=...)`` → (dotted X, baked kwarg names)."""
+    """``partial(X, ..., kw=...)`` → (dotted X, baked kwarg names); the
+    application's ``_named_partial`` (a partial that keeps X's name for the
+    XLA module) counts as one."""
     if not (isinstance(call, ast.Call)
-            and (dotted(call.func) or "").rsplit(".", 1)[-1] == "partial"
+            and (dotted(call.func) or "").rsplit(".", 1)[-1]
+            in ("partial", "_named_partial")
             and call.args):
         return None
     name = dotted(call.args[0])

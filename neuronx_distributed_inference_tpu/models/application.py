@@ -53,6 +53,17 @@ SPECULATION_MODEL_TAG = "speculation_model"
 FUSED_SPECULATION_MODEL_TAG = "fused_speculation_model"
 
 
+def _named_partial(fn, *args, **kw):
+    """``functools.partial`` that keeps ``fn``'s name: ``jax.jit`` names the
+    XLA module after it (``jit_paged_forward_step`` on the profiler's
+    ``XLA Modules`` line, not ``jit__unknown``). Only the name is copied —
+    a ``__wrapped__`` would make jit resolve ``donate_argnums`` against the
+    unbound signature."""
+    bound = partial(fn, *args, **kw)
+    bound.__name__ = bound.__qualname__ = fn.__name__
+    return bound
+
+
 class CausalLMApplication:
     """Compile/load/run a causal LM on a TPU mesh."""
 
@@ -250,18 +261,19 @@ class CausalLMApplication:
         return repl, cache_sh
 
     def _jit_prefill(self):
-        fn = partial(model_base.context_encoding_step, self.spec, self.tpu_config)
+        fn = _named_partial(model_base.context_encoding_step, self.spec,
+                            self.tpu_config)
         return jax.jit(fn, donate_argnums=(1,))
 
     def _jit_decode(self, kv_bucket: Optional[int] = None):
-        fn = partial(model_base.token_generation_step, self.spec,
-                     self.tpu_config, kv_view=kv_bucket)
+        fn = _named_partial(model_base.token_generation_step, self.spec,
+                            self.tpu_config, kv_view=kv_bucket)
         return jax.jit(fn, donate_argnums=(1,))
 
     def _jit_decode_loop(self, num_steps: int,
                          kv_bucket: Optional[int] = None):
-        fn = partial(model_base.decode_loop, self.spec, self.tpu_config,
-                     kv_view=kv_bucket)
+        fn = _named_partial(model_base.decode_loop, self.spec,
+                            self.tpu_config, kv_view=kv_bucket)
         return jax.jit(fn, static_argnames=("num_steps",), donate_argnums=(1,))
 
     def _check_decode_fits(self, needed: int):
@@ -301,8 +313,8 @@ class CausalLMApplication:
                     else (bucket, None)
                 self._compiled[key] = self._jit_decode_loop(steps, kv_bucket)
             elif tag == "windowed_cte":
-                fn = partial(model_base.token_generation_multi, self.spec,
-                             self.tpu_config)
+                fn = _named_partial(model_base.token_generation_multi,
+                                    self.spec, self.tpu_config)
                 self._compiled[key] = jax.jit(fn, donate_argnums=(1,))
             else:
                 raise KeyError(tag)
@@ -388,35 +400,24 @@ class CausalLMApplication:
     def telemetry(self, reg):
         self._telemetry_override = reg
 
-    def _tel_start(self):
-        """perf_counter() when telemetry OR the flight recorder is live,
-        else None (the sentinel keeps the disabled path free of timing
-        work AND of the device sync in :meth:`_tel_end`)."""
-        if self.telemetry.enabled or trace_mod.get_recorder().enabled:
-            return time.perf_counter()
-        return None
-
-    def _tel_end(self, kind: str, t0, out, n_rows: int):
-        """Observe one _run_* call: host-prep (entry → dispatch return) vs
-        device wait (block_until_ready). Runs strictly OUTSIDE traced code;
-        the sync only happens when telemetry is enabled. The flight
-        recorder gets a ``run.<kind>`` slice covering the HOST window only
-        (entry → dispatch return) — recording never adds a device sync."""
-        if t0 is None:
-            return
-        t1 = time.perf_counter()
-        rec = trace_mod.get_recorder()
-        if rec.enabled:
-            rec.complete(f"run.{kind}", t0, cat="app", t1=t1, rows=n_rows)
+    @contextlib.contextmanager
+    def _run_span(self, kind: str, n_rows: int):
+        """``with self._run_span(kind, rows):`` around the host side of one
+        _run_* call (entry -> the return of the asynchronous dispatch, RNG
+        split included): a ``run.<kind>`` flight-recorder slice (and so a
+        profiler TraceMe) and one ``nxdi_run_seconds{part="host"}``
+        observation. Strictly OUTSIDE traced code, and it NEVER syncs the
+        device: the blocking wait is measured where it really happens
+        (``fetch.tokens``), device time belongs to the profiler's trace.
+        With telemetry and recorder off the span is the shared no-op."""
         tel = self.telemetry
-        if not tel.enabled:
-            return
-        jax.block_until_ready(out["tokens"])
-        t2 = time.perf_counter()
-        hist = tmetrics.run_seconds_histogram(tel)
-        hist.observe(t1 - t0, kind=kind, part="host")
-        hist.observe(t2 - t1, kind=kind, part="device")
-        tmetrics.device_sampled_rows_counter(tel).inc(n_rows, kind=kind)
+        t0 = time.perf_counter()
+        with trace_mod.get_recorder().span(f"run.{kind}", cat="app",
+                                           rows=n_rows):
+            yield
+        if tel.enabled:
+            tmetrics.run_seconds_histogram(tel).observe(
+                time.perf_counter() - t0, kind=kind, part="host")
 
     def _note_jit(self, kind: str, bucket, sig):
         """Recompile accounting: the first time a (kind, bucket, shape)
@@ -536,30 +537,31 @@ class CausalLMApplication:
             # boundary like _run_decode does
             raise ValueError("non-identity seq_ids require "
                              "is_continuous_batching=True")
-        t0 = self._tel_start()
-        position_ids = np.broadcast_to(np.arange(s, dtype=np.int32), (b, s))
-        fn = self.get_compiled(CONTEXT_ENCODING_MODEL_TAG, s)
-        self._note_jit("prefill", s, (b, s))
-        if sampling_params is None:
-            sampling_params = self._default_sampling_params(b)
-        if self.snapshot.enabled:
-            self.snapshot.save_step({"input_ids": input_ids,
-                                     "position_ids": position_ids,
-                                     "seq_ids": seq_ids,
-                                     "seq_lens": seq_lens},
-                                    weights=self.params)
-        if image_mask is not None:
-            image_mask = jnp.asarray(np.asarray(image_mask, bool))
-        if rope_position_ids is not None:
-            rope_position_ids = jnp.asarray(rope_position_ids)
-        with self._mesh_ctx():
-            out = fn(self.params, self.cache, jnp.asarray(input_ids),
-                     jnp.asarray(position_ids), jnp.asarray(seq_ids),
-                     jnp.asarray(seq_lens), sampling_params, self._next_rng(),
-                     adapter_ids, self.replacements, image_embeds, image_mask,
-                     rope_position_ids, deepstack_embeds)
-        self.cache = out["cache"]
-        self._tel_end("prefill", t0, out, b)
+        with self._run_span("prefill", b):
+            position_ids = np.broadcast_to(np.arange(s, dtype=np.int32),
+                                           (b, s))
+            fn = self.get_compiled(CONTEXT_ENCODING_MODEL_TAG, s)
+            self._note_jit("prefill", s, (b, s))
+            if sampling_params is None:
+                sampling_params = self._default_sampling_params(b)
+            if self.snapshot.enabled:
+                self.snapshot.save_step({"input_ids": input_ids,
+                                         "position_ids": position_ids,
+                                         "seq_ids": seq_ids,
+                                         "seq_lens": seq_lens},
+                                        weights=self.params)
+            if image_mask is not None:
+                image_mask = jnp.asarray(np.asarray(image_mask, bool))
+            if rope_position_ids is not None:
+                rope_position_ids = jnp.asarray(rope_position_ids)
+            with self._mesh_ctx():
+                out = fn(self.params, self.cache, jnp.asarray(input_ids),
+                         jnp.asarray(position_ids), jnp.asarray(seq_ids),
+                         jnp.asarray(seq_lens), sampling_params,
+                         self._next_rng(), adapter_ids, self.replacements,
+                         image_embeds, image_mask, rope_position_ids,
+                         deepstack_embeds)
+            self.cache = out["cache"]
         return out
 
     def _run_prefill_windowed(self, input_ids: np.ndarray,
@@ -631,27 +633,26 @@ class CausalLMApplication:
             # silently read the wrong rows — reject at the boundary
             raise ValueError("non-identity seq_ids require "
                              "is_continuous_batching=True")
-        t0 = self._tel_start()
-        needed = int(np.max(np.asarray(position_ids))) + input_ids.shape[1]
-        self._check_decode_fits(needed)
-        kv_bucket = self._kv_bucket(needed) or 0
-        fn = self.get_compiled(TOKEN_GENERATION_MODEL_TAG, kv_bucket)
-        self._note_jit("decode", kv_bucket, input_ids.shape)
-        if sampling_params is None:
-            sampling_params = self._default_sampling_params(b)
-        if self.snapshot.enabled:
-            self.snapshot.save_step({"input_ids": input_ids,
-                                     "position_ids": position_ids,
-                                     "seq_ids": seq_ids})
-        if rope_position_ids is not None:
-            rope_position_ids = jnp.asarray(rope_position_ids)
-        with self._mesh_ctx():
-            out = fn(self.params, self.cache, jnp.asarray(input_ids),
-                     jnp.asarray(position_ids), jnp.asarray(seq_ids),
-                     sampling_params, self._next_rng(), adapter_ids,
-                     self.replacements, rope_position_ids)
-        self.cache = out["cache"]
-        self._tel_end("decode", t0, out, b * input_ids.shape[1])
+        with self._run_span("decode", b * input_ids.shape[1]):
+            needed = int(np.max(np.asarray(position_ids))) + input_ids.shape[1]
+            self._check_decode_fits(needed)
+            kv_bucket = self._kv_bucket(needed) or 0
+            fn = self.get_compiled(TOKEN_GENERATION_MODEL_TAG, kv_bucket)
+            self._note_jit("decode", kv_bucket, input_ids.shape)
+            if sampling_params is None:
+                sampling_params = self._default_sampling_params(b)
+            if self.snapshot.enabled:
+                self.snapshot.save_step({"input_ids": input_ids,
+                                         "position_ids": position_ids,
+                                         "seq_ids": seq_ids})
+            if rope_position_ids is not None:
+                rope_position_ids = jnp.asarray(rope_position_ids)
+            with self._mesh_ctx():
+                out = fn(self.params, self.cache, jnp.asarray(input_ids),
+                         jnp.asarray(position_ids), jnp.asarray(seq_ids),
+                         sampling_params, self._next_rng(), adapter_ids,
+                         self.replacements, rope_position_ids)
+            self.cache = out["cache"]
         return out
 
     def _run_decode_loop(self, first_tokens: np.ndarray, positions: np.ndarray,
@@ -668,24 +669,23 @@ class CausalLMApplication:
             # so non-identity seq_ids would silently read the wrong rows
             raise ValueError("non-identity seq_ids require "
                              "is_continuous_batching=True")
-        t0 = self._tel_start()
-        needed = int(np.max(np.asarray(positions))) + num_steps
-        self._check_decode_fits(needed)
-        loop_bucket = (num_steps, self._kv_bucket(needed))
-        fn = self.get_compiled("decode_loop", loop_bucket)
-        self._note_jit("decode_loop", loop_bucket, first_tokens.shape)
-        if sampling_params is None:
-            sampling_params = self._default_sampling_params(b)
-        if rope_position_ids is not None:
-            rope_position_ids = jnp.asarray(rope_position_ids)
-        with self._mesh_ctx():
-            out = fn(self.params, self.cache, jnp.asarray(first_tokens),
-                     jnp.asarray(positions), jnp.asarray(seq_ids),
-                     sampling_params, self._next_rng(), num_steps=num_steps,
-                     adapter_ids=adapter_ids,
-                     rope_position_ids=rope_position_ids)
-        self.cache = out["cache"]
-        self._tel_end("decode_loop", t0, out, b * num_steps)
+        with self._run_span("decode_loop", b * num_steps):
+            needed = int(np.max(np.asarray(positions))) + num_steps
+            self._check_decode_fits(needed)
+            loop_bucket = (num_steps, self._kv_bucket(needed))
+            fn = self.get_compiled("decode_loop", loop_bucket)
+            self._note_jit("decode_loop", loop_bucket, first_tokens.shape)
+            if sampling_params is None:
+                sampling_params = self._default_sampling_params(b)
+            if rope_position_ids is not None:
+                rope_position_ids = jnp.asarray(rope_position_ids)
+            with self._mesh_ctx():
+                out = fn(self.params, self.cache, jnp.asarray(first_tokens),
+                         jnp.asarray(positions), jnp.asarray(seq_ids),
+                         sampling_params, self._next_rng(),
+                         num_steps=num_steps, adapter_ids=adapter_ids,
+                         rope_position_ids=rope_position_ids)
+            self.cache = out["cache"]
         return out
 
     # ------------------------------------------------------------------
@@ -1164,7 +1164,8 @@ class PagedCausalLMApplication(CausalLMApplication):
         return self
 
     def _jit_paged(self):
-        fn = partial(model_base.paged_forward_step, self.spec, self.tpu_config)
+        fn = _named_partial(model_base.paged_forward_step, self.spec,
+                            self.tpu_config)
         return jax.jit(fn, donate_argnums=(1,))
 
     # -- positionally coupled sampling (ops/sampling.coupled_sample) -------
@@ -1200,8 +1201,8 @@ class PagedCausalLMApplication(CausalLMApplication):
         return jnp.asarray(np.maximum(np.asarray(adapter_ids, np.int32), 0))
 
     def _jit_paged_loop(self, num_steps: int):
-        fn = partial(model_base.paged_decode_loop, self.spec, self.tpu_config,
-                     num_steps=num_steps)
+        fn = _named_partial(model_base.paged_decode_loop, self.spec,
+                            self.tpu_config, num_steps=num_steps)
         return jax.jit(fn, donate_argnums=(1,))
 
     def _run_paged_loop(self, first_tokens, positions, block_table,
@@ -1212,29 +1213,27 @@ class PagedCausalLMApplication(CausalLMApplication):
         # index past the block table (mirrors _run_decode_loop's guard)
         self._check_decode_fits(
             int(np.max(np.asarray(positions))) + num_steps)
-        t0 = self._tel_start()
-        key = ("paged_loop", num_steps)
-        if key not in self._compiled:
-            self._compiled[key] = self._jit_paged_loop(num_steps)
-        aids = self._lora_adapter_ids(adapter_ids)
-        self._note_jit("paged_loop", num_steps,
-                       (first_tokens.shape[0], block_table.shape[1],
-                        aids is not None))
-        if sampling_params is None:
-            sampling_params = self._default_sampling_params(
-                first_tokens.shape[0])
-        seeds = self._stream_seeds(row_seeds, first_tokens.shape[0])
-        kw = {"row_seeds": seeds} if seeds is not None else {}
-        if aids is not None:
-            kw["adapter_ids"] = aids
-        with self._mesh_ctx():
-            out = self._compiled[key](
-                self.params, self.cache, jnp.asarray(first_tokens),
-                jnp.asarray(positions), jnp.asarray(block_table),
-                sampling_params, self._next_rng(), **kw)
-        self.cache = out["cache"]
-        self._tel_end("paged_loop", t0, out,
-                      first_tokens.shape[0] * num_steps)
+        with self._run_span("paged_loop", first_tokens.shape[0] * num_steps):
+            key = ("paged_loop", num_steps)
+            if key not in self._compiled:
+                self._compiled[key] = self._jit_paged_loop(num_steps)
+            aids = self._lora_adapter_ids(adapter_ids)
+            self._note_jit("paged_loop", num_steps,
+                           (first_tokens.shape[0], block_table.shape[1],
+                            aids is not None))
+            if sampling_params is None:
+                sampling_params = self._default_sampling_params(
+                    first_tokens.shape[0])
+            seeds = self._stream_seeds(row_seeds, first_tokens.shape[0])
+            kw = {"row_seeds": seeds} if seeds is not None else {}
+            if aids is not None:
+                kw["adapter_ids"] = aids
+            with self._mesh_ctx():
+                out = self._compiled[key](
+                    self.params, self.cache, jnp.asarray(first_tokens),
+                    jnp.asarray(positions), jnp.asarray(block_table),
+                    sampling_params, self._next_rng(), **kw)
+            self.cache = out["cache"]
         return out
 
     def get_compiled(self, tag: str, bucket: int = 0):
@@ -1247,13 +1246,13 @@ class PagedCausalLMApplication(CausalLMApplication):
 
     # -- speculative serving graphs (serving/speculation/) -----------------
     def _jit_spec_draft(self, num_steps: int):
-        fn = partial(model_base.paged_spec_draft_loop, self.spec,
-                     self.tpu_config, num_steps=num_steps)
+        fn = _named_partial(model_base.paged_spec_draft_loop, self.spec,
+                            self.tpu_config, num_steps=num_steps)
         return jax.jit(fn, donate_argnums=(1,))
 
     def _jit_spec_verify(self, want_hidden: bool):
-        fn = partial(model_base.paged_spec_verify, self.spec,
-                     self.tpu_config, want_hidden=want_hidden)
+        fn = _named_partial(model_base.paged_spec_verify, self.spec,
+                            self.tpu_config, want_hidden=want_hidden)
         return jax.jit(fn, donate_argnums=(1,))
 
     def _run_spec_draft(self, first_tokens, positions, block_table, widths,
@@ -1265,30 +1264,28 @@ class PagedCausalLMApplication(CausalLMApplication):
         every KV write."""
         self._check_decode_fits(
             int(np.max(np.asarray(positions) + np.asarray(widths) - 1)))
-        t0 = self._tel_start()
-        key = ("spec_draft", num_steps)
-        if key not in self._compiled:
-            self._compiled[key] = self._jit_spec_draft(num_steps)
-        aids = self._lora_adapter_ids(adapter_ids)
-        self._note_jit("spec_draft", num_steps,
-                       (first_tokens.shape[0], block_table.shape[1],
-                        aids is not None))
-        if sampling_params is None:
-            sampling_params = self._default_sampling_params(
-                first_tokens.shape[0])
-        seeds = self._stream_seeds(row_seeds, first_tokens.shape[0])
-        kw = {"row_seeds": seeds} if seeds is not None else {}
-        if aids is not None:
-            kw["adapter_ids"] = aids
-        with self._mesh_ctx():
-            out = self._compiled[key](
-                self.params, self.cache, jnp.asarray(first_tokens),
-                jnp.asarray(positions), jnp.asarray(block_table),
-                jnp.asarray(widths), sampling_params, self._next_rng(),
-                **kw)
-        self.cache = out["cache"]
-        self._tel_end("spec_draft", t0, out,
-                      first_tokens.shape[0] * num_steps)
+        with self._run_span("spec_draft", first_tokens.shape[0] * num_steps):
+            key = ("spec_draft", num_steps)
+            if key not in self._compiled:
+                self._compiled[key] = self._jit_spec_draft(num_steps)
+            aids = self._lora_adapter_ids(adapter_ids)
+            self._note_jit("spec_draft", num_steps,
+                           (first_tokens.shape[0], block_table.shape[1],
+                            aids is not None))
+            if sampling_params is None:
+                sampling_params = self._default_sampling_params(
+                    first_tokens.shape[0])
+            seeds = self._stream_seeds(row_seeds, first_tokens.shape[0])
+            kw = {"row_seeds": seeds} if seeds is not None else {}
+            if aids is not None:
+                kw["adapter_ids"] = aids
+            with self._mesh_ctx():
+                out = self._compiled[key](
+                    self.params, self.cache, jnp.asarray(first_tokens),
+                    jnp.asarray(positions), jnp.asarray(block_table),
+                    jnp.asarray(widths), sampling_params, self._next_rng(),
+                    **kw)
+            self.cache = out["cache"]
         return out
 
     def _run_spec_verify(self, input_ids, position_ids, slot_mapping,
@@ -1303,36 +1300,35 @@ class PagedCausalLMApplication(CausalLMApplication):
         self._check_decode_fits(
             int(np.max(np.asarray(position_ids)[:, 0]
                        + np.asarray(widths))))
-        t0 = self._tel_start()
-        key = ("spec_verify", input_ids.shape[1], want_hidden)
-        if key not in self._compiled:
-            self._compiled[key] = self._jit_spec_verify(want_hidden)
-        aids = self._lora_adapter_ids(adapter_ids)
-        self._note_jit("spec_verify", input_ids.shape[1],
-                       (input_ids.shape, block_table.shape,
-                        aids is not None))
-        seeds = self._stream_seeds(row_seeds, input_ids.shape[0])
-        kw = {}
-        if seeds is not None:
-            if sampling_params is None:
-                sampling_params = self._default_sampling_params(
-                    input_ids.shape[0])
-            kw = {"sampling_params": sampling_params, "row_seeds": seeds}
-        if aids is not None:
-            kw["adapter_ids"] = aids
-        with self._mesh_ctx():
-            out = self._compiled[key](
-                self.params, self.cache, jnp.asarray(input_ids),
-                jnp.asarray(position_ids), jnp.asarray(slot_mapping),
-                jnp.asarray(block_table), jnp.asarray(widths), **kw)
-        self.cache = out["cache"]
-        self._tel_end("spec_verify", t0, out, input_ids.shape[0])
+        with self._run_span("spec_verify", input_ids.shape[0]):
+            key = ("spec_verify", input_ids.shape[1], want_hidden)
+            if key not in self._compiled:
+                self._compiled[key] = self._jit_spec_verify(want_hidden)
+            aids = self._lora_adapter_ids(adapter_ids)
+            self._note_jit("spec_verify", input_ids.shape[1],
+                           (input_ids.shape, block_table.shape,
+                            aids is not None))
+            seeds = self._stream_seeds(row_seeds, input_ids.shape[0])
+            kw = {}
+            if seeds is not None:
+                if sampling_params is None:
+                    sampling_params = self._default_sampling_params(
+                        input_ids.shape[0])
+                kw = {"sampling_params": sampling_params, "row_seeds": seeds}
+            if aids is not None:
+                kw["adapter_ids"] = aids
+            with self._mesh_ctx():
+                out = self._compiled[key](
+                    self.params, self.cache, jnp.asarray(input_ids),
+                    jnp.asarray(position_ids), jnp.asarray(slot_mapping),
+                    jnp.asarray(block_table), jnp.asarray(widths), **kw)
+            self.cache = out["cache"]
         return out
 
     # -- ragged unified dispatch (serving/ragged/) -------------------------
     def _jit_ragged(self, want_hidden: bool):
-        fn = partial(model_base.paged_ragged_step, self.spec,
-                     self.tpu_config, want_hidden=want_hidden)
+        fn = _named_partial(model_base.paged_ragged_step, self.spec,
+                            self.tpu_config, want_hidden=want_hidden)
         return jax.jit(fn, donate_argnums=(1,))
 
     def _run_ragged(self, input_ids, position_ids, slot_mapping,
@@ -1347,30 +1343,29 @@ class PagedCausalLMApplication(CausalLMApplication):
         self._check_decode_fits(
             int(np.max(np.asarray(position_ids)[:, 0]
                        + np.asarray(widths))))
-        t0 = self._tel_start()
-        key = ("ragged", input_ids.shape[1], want_hidden)
-        if key not in self._compiled:
-            self._compiled[key] = self._jit_ragged(want_hidden)
-        aids = self._lora_adapter_ids(adapter_ids)
-        self._note_jit("ragged", input_ids.shape[1],
-                       (input_ids.shape, block_table.shape,
-                        aids is not None))
-        if sampling_params is None:
-            sampling_params = self._default_sampling_params(
-                input_ids.shape[0])
-        seeds = self._stream_seeds(row_seeds, input_ids.shape[0])
-        kw = {"row_seeds": seeds} if seeds is not None else {}
-        if aids is not None:
-            kw["adapter_ids"] = aids
-        with self._mesh_ctx():
-            out = self._compiled[key](
-                self.params, self.cache, jnp.asarray(input_ids),
-                jnp.asarray(position_ids), jnp.asarray(slot_mapping),
-                jnp.asarray(block_table), jnp.asarray(widths),
-                jnp.asarray(emit_modes), sampling_params, self._next_rng(),
-                **kw)
-        self.cache = out["cache"]
-        self._tel_end("ragged", t0, out, input_ids.shape[0])
+        with self._run_span("ragged", input_ids.shape[0]):
+            key = ("ragged", input_ids.shape[1], want_hidden)
+            if key not in self._compiled:
+                self._compiled[key] = self._jit_ragged(want_hidden)
+            aids = self._lora_adapter_ids(adapter_ids)
+            self._note_jit("ragged", input_ids.shape[1],
+                           (input_ids.shape, block_table.shape,
+                            aids is not None))
+            if sampling_params is None:
+                sampling_params = self._default_sampling_params(
+                    input_ids.shape[0])
+            seeds = self._stream_seeds(row_seeds, input_ids.shape[0])
+            kw = {"row_seeds": seeds} if seeds is not None else {}
+            if aids is not None:
+                kw["adapter_ids"] = aids
+            with self._mesh_ctx():
+                out = self._compiled[key](
+                    self.params, self.cache, jnp.asarray(input_ids),
+                    jnp.asarray(position_ids), jnp.asarray(slot_mapping),
+                    jnp.asarray(block_table), jnp.asarray(widths),
+                    jnp.asarray(emit_modes), sampling_params, self._next_rng(),
+                    **kw)
+            self.cache = out["cache"]
         return out
 
     def _bt_width(self, b: int) -> int:
@@ -1388,27 +1383,28 @@ class PagedCausalLMApplication(CausalLMApplication):
     def _run_paged(self, input_ids, position_ids, slot_mapping, block_table,
                    last_idx, sampling_params=None, row_seeds=None,
                    adapter_ids=None):
-        t0 = self._tel_start()
-        fn = self.get_compiled("paged_forward")
-        aids = self._lora_adapter_ids(adapter_ids)
-        # one jitted graph serves every paged call; the shape signature
-        # (prefill width x table width) is what distinguishes compiles
-        self._note_jit("paged", input_ids.shape[1],
-                       (input_ids.shape, block_table.shape,
-                        aids is not None))
-        if sampling_params is None:
-            sampling_params = self._default_sampling_params(input_ids.shape[0])
-        seeds = self._stream_seeds(row_seeds, input_ids.shape[0])
-        kw = {"row_seeds": seeds} if seeds is not None else {}
-        if aids is not None:
-            kw["adapter_ids"] = aids
-        with self._mesh_ctx():
-            out = fn(self.params, self.cache, jnp.asarray(input_ids),
-                     jnp.asarray(position_ids), jnp.asarray(slot_mapping),
-                     jnp.asarray(block_table), jnp.asarray(last_idx),
-                     sampling_params, self._next_rng(), **kw)
-        self.cache = out["cache"]
-        self._tel_end("paged", t0, out, input_ids.shape[0])
+        with self._run_span("paged", input_ids.shape[0]):
+            fn = self.get_compiled("paged_forward")
+            aids = self._lora_adapter_ids(adapter_ids)
+            # one jitted graph serves every paged call; the shape signature
+            # (prefill width x table width) is what distinguishes compiles
+            self._note_jit("paged", input_ids.shape[1],
+                           (input_ids.shape, block_table.shape,
+                            aids is not None))
+            if sampling_params is None:
+                sampling_params = self._default_sampling_params(
+                    input_ids.shape[0])
+            seeds = self._stream_seeds(row_seeds, input_ids.shape[0])
+            kw = {"row_seeds": seeds} if seeds is not None else {}
+            if aids is not None:
+                kw["adapter_ids"] = aids
+            with self._mesh_ctx():
+                out = fn(self.params, self.cache, jnp.asarray(input_ids),
+                         jnp.asarray(position_ids),
+                         jnp.asarray(slot_mapping), jnp.asarray(block_table),
+                         jnp.asarray(last_idx),
+                         sampling_params, self._next_rng(), **kw)
+            self.cache = out["cache"]
         return out
 
     def warmup(self):

@@ -898,9 +898,17 @@ def _row_parallel_out(spec: DecoderSpec, x, w, phase: str):
 
 def _mlp_block(spec: DecoderSpec, x_in, layer_w, mlp_kind, adapter_ids,
                phase: str = "prefill"):
-    """The MLP / MoE half of a layer (GLU, plain 2-layer, or routed MoE)."""
+    """The MLP / MoE half of a layer (GLU, plain 2-layer, or routed MoE),
+    under the profiler scope ``moe`` (router, expert matmuls, combine) or
+    ``mlp``."""
     if mlp_kind == "moe":
-        return moe_block(spec.moe, x_in, layer_w, phase=phase)
+        with jax.named_scope("moe"):
+            return moe_block(spec.moe, x_in, layer_w, phase=phase)
+    with jax.named_scope("mlp"):
+        return _dense_mlp(spec, x_in, layer_w, adapter_ids, phase)
+
+
+def _dense_mlp(spec: DecoderSpec, x_in, layer_w, adapter_ids, phase: str):
     if spec.act == "xielu":
         # Apertus xIELU with LEARNED per-layer alphas (reference:
         # contrib/models/Apertus-8B-Instruct-2509; HF XIELUActivation):
@@ -950,6 +958,7 @@ def _mlp_block(spec: DecoderSpec, x_in, layer_w, mlp_kind, adapter_ids,
     return y
 
 
+@jax.named_scope("attn")
 def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                 is_local, seq_ids, positions, phase: str, *,
                 identity_seq_ids=False, arange_positions=False,
@@ -1674,6 +1683,7 @@ def fold_mixed_prefill(spec: DecoderSpec, scratch_cache, cache, seq_lens,
 # Step graphs
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def _embed(spec: DecoderSpec, params, input_ids, position_ids=None):
     h = params["embed"][input_ids]        # sharded-vocab gather; XLA SPMD handles
     if spec.embed_scale is not None:
@@ -1689,6 +1699,7 @@ def _embed(spec: DecoderSpec, params, input_ids, position_ids=None):
     return _shard(h, AXIS_DP, None, None)
 
 
+@jax.named_scope("lm_head")
 def _lm_head(spec: DecoderSpec, params, hidden):
     h = (hidden if spec.skip_final_norm else
          _norm(spec, hidden, params["final_norm"], params.get("final_norm_b")))

@@ -114,6 +114,7 @@ def stream_keys(stream_seed: int, seeds: jnp.ndarray,
         seeds.astype(jnp.int32), positions.astype(jnp.int32))
 
 
+@jax.named_scope("sample")
 def coupled_sample(logits: jnp.ndarray,
                    config: OnDeviceSamplingConfig,
                    sampling_params: Optional[jnp.ndarray],
@@ -166,6 +167,7 @@ def coupled_sample(logits: jnp.ndarray,
     return toks
 
 
+@jax.named_scope("sample")      # sample_dp lands here too: the one scope
 def sample(logits: jnp.ndarray, config: Optional[OnDeviceSamplingConfig],
            sampling_params: Optional[jnp.ndarray] = None,
            key: Optional[jax.Array] = None) -> jnp.ndarray:

@@ -760,14 +760,11 @@ class _EngineAdapterBase:
     # -- fetch helpers (the ONLY places that block on device output) -------
     def _fetch_rows(self, out, b: int) -> np.ndarray:
         t0 = time.perf_counter()
-        toks = np.asarray(out["tokens"])
-        t1 = time.perf_counter()
+        with _get_recorder().span("fetch.tokens", cat="adapter",
+                                  engine=self.engine_name, rows=b):
+            toks = np.asarray(out["tokens"])
         self.host_stats["blocking_fetches"] += 1
-        self.host_stats["blocked_s"] += t1 - t0
-        rec = _get_recorder()
-        if rec.enabled:
-            rec.complete("fetch.tokens", t0, cat="adapter", t1=t1,
-                         engine=self.engine_name, rows=b)
+        self.host_stats["blocked_s"] += time.perf_counter() - t0
         return toks.reshape(toks.shape[0], -1)[:b]
 
     # -- public decode surface ---------------------------------------------
@@ -2230,19 +2227,31 @@ class PagedEngineAdapter(_EngineAdapterBase):
         row_tenant = _common_tenant(_meta_tenant(chunks[s].meta)
                                     for s in seq_list)
         cache_before = self.app.cache
-        t0_chunk = time.perf_counter()
+        rec = _get_recorder()
+        # one slice over pack + dispatch + final-chunk fetch (a failed
+        # dispatch closes it too; its error event follows on the timeline)
+        span = rec.span(
+            "dispatch.prefill_chunk", cat="adapter",
+            engine=self.engine_name, seq_ids=list(seq_list),
+            rows=len(rows), tokens=sum(n for _, _, n, _ in rows),
+            final_seq_ids=[s for _, s in final_rows],
+            tenant=row_tenant) if rec.enabled else rec.span(
+                "dispatch.prefill_chunk")      # the shared no-op
         try:
-            if _FAULTS.active:
-                _FAULTS.fire("prefill_chunk")
-            packed = self._pack_prefill_rows(rows)
-            out = self._dispatch_prefill_chunk(packed,
-                                               fetch=bool(final_rows))
-            # materialize INSIDE the try (dispatch is asynchronous): a
-            # genuine device failure surfacing at the fetch must still be
-            # wrapped and rolled back here. Intermediate-only dispatches
-            # fetch nothing — their samples are discarded unmaterialized.
-            new = (self._fetch_prefill_tokens(out) if final_rows
-                   else None)
+            with span:
+                if _FAULTS.active:
+                    _FAULTS.fire("prefill_chunk")
+                packed = self._pack_prefill_rows(rows)
+                span.set(width=int(packed[0].shape[1]))
+                out = self._dispatch_prefill_chunk(packed,
+                                                   fetch=bool(final_rows))
+                # materialize INSIDE the try (dispatch is asynchronous): a
+                # genuine device failure surfacing at the fetch must still
+                # be wrapped and rolled back here. Intermediate-only
+                # dispatches fetch nothing — their samples are discarded
+                # unmaterialized.
+                new = (self._fetch_prefill_tokens(out) if final_rows
+                       else None)
         except ServingError as e:
             self._abort_prefill_rows(seq_list)
             _trace_error(e)                # attach a timeline id in place
@@ -2255,14 +2264,6 @@ class PagedEngineAdapter(_EngineAdapterBase):
                 "prefilled sequence packed in it was rolled back",
                 phase="prefill", seq_ids=seq_list,
                 retry_safe=self.app.cache is cache_before)) from e
-        rec = _get_recorder()
-        if rec.enabled:
-            rec.complete("dispatch.prefill_chunk", t0_chunk, cat="adapter",
-                         engine=self.engine_name, seq_ids=list(seq_list),
-                         rows=len(rows), width=int(packed[0].shape[1]),
-                         tokens=sum(n for _, _, n, _ in rows),
-                         final_seq_ids=[s for _, s in final_rows],
-                         tenant=row_tenant)
         bs = self.app.kv_mgr.spec.block_size
         for s, _, n, _ in rows:
             chunks[s].done += n
@@ -2357,14 +2358,11 @@ class PagedEngineAdapter(_EngineAdapterBase):
         """Materialize a final-chunk dispatch's sampled tokens (the one
         blocking sync of a packed admission; async-prefetched)."""
         t0 = time.perf_counter()
-        toks = np.asarray(out["tokens"])
-        t1 = time.perf_counter()
+        with _get_recorder().span("fetch.tokens", cat="adapter",
+                                  engine=self.engine_name, phase="prefill"):
+            toks = np.asarray(out["tokens"])
         self.host_stats["prefill_blocking_fetches"] += 1
-        self.host_stats["prefill_blocked_s"] += t1 - t0
-        rec = _get_recorder()
-        if rec.enabled:
-            rec.complete("fetch.tokens", t0, cat="adapter", t1=t1,
-                         engine=self.engine_name, phase="prefill")
+        self.host_stats["prefill_blocked_s"] += time.perf_counter() - t0
         return toks.reshape(toks.shape[0], -1)
 
     def _drop_unwritten(self, sid):

@@ -63,11 +63,13 @@ from __future__ import annotations
 
 import asyncio
 import json
+import time
 from typing import Any, Dict, Optional, Tuple
 
 from ...resilience.errors import (AdmissionError, ConfigurationError,
                                   QueueOverflow, ServingError)
 from ...telemetry import get_registry
+from ...telemetry import metrics as tmetrics
 from ...telemetry.trace import get_recorder
 from .scheduler import ServingEngine
 from .streams import TokenStream
@@ -405,6 +407,12 @@ class ServingFrontend:
             async for tok in source:
                 writer.write(self._sse_event(
                     {"token": tok, "index": idx}))
+                # front-door lag: put() -> this write (a replay attach
+                # re-reads old tokens: not a lag)
+                t_put = None if replay else stream.take_put_time(idx)
+                if t_put is not None:
+                    tmetrics.sse_lag_histogram(get_registry()).observe(
+                        time.perf_counter() - t_put)
                 idx += 1
                 await writer.drain()
             done: Dict[str, Any] = {"done": True,

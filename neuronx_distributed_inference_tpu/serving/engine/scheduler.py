@@ -295,9 +295,12 @@ class ServingEngine:
         recorder enabled each stage lands as a ``pass.*`` complete slice
         on the trace timeline (stable names: ``pass.expire``,
         ``pass.preempt``, ``pass.admit``, ``pass.dispatch``; the adapter
-        adds ``dispatch.*``/``fetch.*`` inside the dispatch slice)."""
+        adds ``dispatch.*``/``fetch.*`` inside the dispatch slice), every
+        slice tagged with this pass's sequence number (``pass_id``)."""
         now = time.perf_counter()
         rec = _get_recorder()            # disabled: span() is a no-op CM
+        if rec.enabled:
+            rec.next_pass()
         if self.degradation is not None:
             # close the loop BEFORE this pass's admission so a tightened
             # weight/shed applies to the work it is about to schedule
@@ -359,10 +362,16 @@ class ServingEngine:
                     phase="engine", retry_safe=False)
                 self._fatal(err)
                 raise err from e
+            # with the four pass.* phases these two close the loop
+            # thread's timeline: whatever ran between two passes ran inside
+            # one of them (SSE writers, HTTP reads, other GIL holders)
+            rec = _get_recorder()
             if delivered or self.has_work:
-                await asyncio.sleep(0)
+                with rec.span("loop.yield", cat="engine"):
+                    await asyncio.sleep(0)
             else:
-                await asyncio.sleep(idle_sleep_s)
+                with rec.span("loop.idle", cat="engine"):
+                    await asyncio.sleep(idle_sleep_s)
 
     def close(self) -> None:
         """Stop :meth:`run_forever` and fail over remaining work: queued

@@ -24,9 +24,11 @@ blocks. Tokens delivered before the cancel stay valid.
 from __future__ import annotations
 
 import asyncio
-from typing import Any, Callable, List, Optional
+import time
+from typing import Any, Callable, Dict, List, Optional
 
 from ...resilience.errors import Cancelled
+from ...telemetry.registry import get_registry
 
 __all__ = ["TokenStream"]
 
@@ -49,6 +51,10 @@ class TokenStream:
         self.tenant = tenant
         self._tokens: List[int] = []
         self._cursor = 0              # consumer position (drain/aiter)
+        # token index -> perf_counter() of its put(); stamped only while
+        # the metrics registry is enabled, taken by the SSE writer
+        # (``nxdi_sse_lag_seconds``)
+        self._put_at: Dict[int, float] = {}
         self.finish_reason: Optional[str] = None
         self.error: Optional[BaseException] = None
         self._event: Optional[asyncio.Event] = None
@@ -59,6 +65,8 @@ class TokenStream:
         if self.finish_reason is not None:
             return                    # late token after cancel/expiry: drop
         self._tokens.append(int(token))
+        if get_registry().enabled:
+            self._put_at[len(self._tokens) - 1] = time.perf_counter()
         self._wake()
 
     def finish(self, reason: str,
@@ -80,6 +88,11 @@ class TokenStream:
         """Count of delivered tokens — O(1); the scheduler's per-token
         budget checks use this instead of copying ``tokens``."""
         return len(self._tokens)
+
+    def take_put_time(self, index: int) -> Optional[float]:
+        """``perf_counter()`` at which token ``index`` was put, once (None
+        when the registry was off at the put, or it was taken already)."""
+        return self._put_at.pop(index, None)
 
     def tokens_from(self, start: int) -> List[int]:
         """Tokens from index ``start`` on, without copying the whole

@@ -523,13 +523,11 @@ class RaggedDispatchPath:
         """The ONE blocking sync of a ragged engine step."""
         ad = self.adapter
         t0 = time.perf_counter()
-        toks = np.asarray(out["tokens"])[:b]
-        n_emit = np.asarray(out["num_emitted"])[:b]
-        t1 = time.perf_counter()
+        with _get_recorder().span("fetch.tokens", cat="adapter",
+                                  engine=ad.engine_name, rows=b,
+                                  phase="ragged"):
+            toks = np.asarray(out["tokens"])[:b]
+            n_emit = np.asarray(out["num_emitted"])[:b]
         ad.host_stats["blocking_fetches"] += 1
-        ad.host_stats["blocked_s"] += t1 - t0
-        rec = _get_recorder()
-        if rec.enabled:
-            rec.complete("fetch.tokens", t0, cat="adapter", t1=t1,
-                         engine=ad.engine_name, rows=b, phase="ragged")
+        ad.host_stats["blocked_s"] += time.perf_counter() - t0
         return toks, n_emit

@@ -56,10 +56,14 @@ GRAPH_COLLECTIVES_TOTAL = "nxdi_graph_collectives_total"    # kind, comm
 GRAPH_COLLECTIVE_BYTES = "nxdi_graph_collective_bytes"      # kind, comm
 
 # -- application hot paths (models/application.py) --------------------------
-# kind: prefill|decode|decode_loop|paged ; part: host|device
+# kind: prefill|decode|decode_loop|paged|... ; part: host (the only one:
+# telemetry never syncs the device — device time belongs to the profiler)
 RUN_SECONDS = "nxdi_run_seconds"
 GENERATED_TOKENS_TOTAL = "nxdi_generated_tokens_total"      # engine=cb|paged
-DEVICE_SAMPLED_ROWS_TOTAL = "nxdi_device_sampled_rows_total"  # kind
+
+# -- host timeline (telemetry/trace.py, serving/engine/frontend.py) ---------
+HOST_SECONDS_TOTAL = "nxdi_host_seconds_total"             # span, under
+SSE_LAG_SECONDS = "nxdi_sse_lag_seconds"
 
 # -- jit / bucketing (models/application.py, modules/autobucketing.py) ------
 JIT_COMPILES_TOTAL = "nxdi_jit_compiles_total"        # kind, bucket
@@ -324,7 +328,8 @@ def graph_collective_bytes_gauge(reg):
 def run_seconds_histogram(reg):
     return reg.histogram(
         RUN_SECONDS,
-        "Application _run_* wall time, split host-prep vs device wait (s)",
+        "Application _run_* host wall time, entry to the return of the "
+        "asynchronous dispatch (s); part is always host — no device sync",
         labels=("kind", "part"), buckets=DEFAULT_LATENCY_BUCKETS)
 
 
@@ -335,12 +340,24 @@ def generated_tokens_counter(reg):
                        labels=("engine",))
 
 
-def device_sampled_rows_counter(reg):
+def host_seconds_counter(reg):
     return reg.counter(
-        DEVICE_SAMPLED_ROWS_TOTAL,
-        "Rows sampled per device forward (includes pad rows; the gap to "
-        "nxdi_generated_tokens_total is engine pad waste)",
-        labels=("kind",))
+        HOST_SECONDS_TOTAL,
+        "Host seconds spent inside flight-recorder slices, by stable span "
+        "name (pass.*, loop.*, run.*, fetch.tokens, dispatch.*) and the "
+        "span open around it on the same thread (under; empty at the "
+        "top); nested spans each count their own whole duration, so a "
+        "span's self time is its seconds minus those under it",
+        labels=("span", "under"))
+
+
+def sse_lag_histogram(reg):
+    return reg.histogram(
+        SSE_LAG_SECONDS,
+        "TokenStream.put of a token to the writer.write of its SSE event "
+        "(s): the part of a token gap neither the step nor the pass "
+        "explains",
+        buckets=DEFAULT_LATENCY_BUCKETS)
 
 
 def jit_compiles_counter(reg):

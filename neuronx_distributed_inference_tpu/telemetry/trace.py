@@ -21,11 +21,27 @@ Event **names are a stable contract** exactly like the metric names in
 The canonical set lives in :data:`ENGINE_PASS_PHASES` /
 :data:`ADAPTER_EVENTS` / :data:`APP_EVENTS`.
 
+One timeline with the profiler: while the recorder is enabled every
+:meth:`FlightRecorder.span` also enters a ``jax.profiler.TraceAnnotation``
+of the same name (stat ``pass_id``), so inside a ``jax.profiler`` session
+each slice is a TraceMe event on the ``/host:CPU`` plane of the same
+``.xplane.pb`` as the device events — the ring can wrap, the profiler's
+file holds its whole session. And where a slice closes
+(:meth:`FlightRecorder.complete`) its duration is added to
+``nxdi_host_seconds_total{span=<name>,under=<parent>}`` when a metrics
+registry is live: host seconds per span over any window, read from two
+registry snapshots. ``under`` is the span that was open around it on the
+same thread ("" at the top), so a reader gets a span's SELF time as its
+own seconds minus those recorded under it — e.g. the default paged adapter
+runs its prefill dispatches inside ``pass.admit``, a deferring one inside
+``pass.dispatch``; the label tells them apart, names alone cannot.
+
 Disabled by default with the PR-1 zero-cost contract: the module-global
 recorder is a shared no-op (:data:`NULL_RECORDER`); instrumented call
 sites pay one attribute check (``rec.enabled``) and never touch device
 state — recording can change neither jit cache keys nor token streams
-(pinned bit-identical by ``tests/test_flight_recorder.py``). When the ring
+(pinned bit-identical by ``tests/test_flight_recorder.py``); the disabled
+recorder imports nothing from ``jax``. When the ring
 wraps, dropped events are counted (:attr:`FlightRecorder.dropped` plus the
 ``nxdi_trace_events_dropped_total{ring="trace"}`` counter when a live
 metrics registry is installed) so a post-mortem states its own truncation
@@ -44,7 +60,8 @@ from .registry import get_registry
 
 __all__ = [
     "ENGINE_PASS_PHASES", "ENGINE_EVENTS", "ADAPTER_EVENTS", "APP_EVENTS",
-    "FLEET_EVENTS", "DEGRADE_EVENTS", "WARMUP_EVENTS", "EVENT_NAMES",
+    "LOOP_EVENTS", "FLEET_EVENTS", "DEGRADE_EVENTS", "WARMUP_EVENTS",
+    "EVENT_NAMES",
     "FlightRecorder", "NullFlightRecorder", "NULL_RECORDER",
     "get_recorder", "set_recorder", "enable_recorder", "disable_recorder",
 ]
@@ -53,6 +70,13 @@ __all__ = [
 #: stage (serving/engine/scheduler.py). STABLE names.
 ENGINE_PASS_PHASES = ("pass.expire", "pass.preempt", "pass.admit",
                       "pass.dispatch")
+
+#: The serving loop's time OUTSIDE a pass (``ServingEngine.run_forever``):
+#: with the four pass phases these partition the loop thread's time.
+#:   ``loop.yield``   ``await asyncio.sleep(0)`` with work pending: SSE
+#:                    writers, HTTP reads, whoever else holds the GIL
+#:   ``loop.idle``    the idle nap (nothing queued or running)
+LOOP_EVENTS = ("loop.yield", "loop.idle")
 
 #: Other engine-lane events (serving/engine/scheduler.py). STABLE names.
 #:   ``stream.deliver``         tokens routed to request streams
@@ -78,10 +102,12 @@ ADAPTER_EVENTS = ("dispatch.decode", "dispatch.decode_loop",
 
 #: Application events (models/application.py). STABLE names.
 #:   ``run.<kind>``   host window of one _run_* call (entry -> dispatch
-#:                    return; asynchronous — excludes device wait)
+#:                    return, RNG split included; asynchronous — excludes
+#:                    device wait, which is ``fetch.tokens``)
 #:   ``compile``      first-time (kind, bucket, shape) graph build
 APP_EVENTS = ("run.prefill", "run.decode", "run.decode_loop", "run.paged",
-              "run.paged_loop", "compile")
+              "run.paged_loop", "run.ragged", "run.spec_draft",
+              "run.spec_verify", "compile")
 
 #: Fleet-layer events (serving/fleet/). STABLE names.
 #:   ``fleet.route``    one request routed to a replica (request_id,
@@ -136,9 +162,10 @@ TRACE_EVENTS = ("trace.begin", "trace.admit", "trace.requeue",
 #:                           victims' trace lanes)
 WARMUP_EVENTS = ("compile.unexpected",)
 
-EVENT_NAMES = (ENGINE_PASS_PHASES + ENGINE_EVENTS + ADAPTER_EVENTS
-               + APP_EVENTS + FLEET_EVENTS + TRACE_EVENTS
+EVENT_NAMES = (ENGINE_PASS_PHASES + LOOP_EVENTS + ENGINE_EVENTS
+               + ADAPTER_EVENTS + APP_EVENTS + FLEET_EVENTS + TRACE_EVENTS
                + DEGRADE_EVENTS + WARMUP_EVENTS)
+_EVENT_SET = frozenset(EVENT_NAMES)
 
 #: Category -> Chrome trace tid lane (deterministic ordering in the UI).
 _CAT_TIDS = {"engine": 1, "adapter": 2, "app": 3, "error": 4, "fleet": 5,
@@ -147,9 +174,12 @@ _CAT_TIDS = {"engine": 1, "adapter": 2, "app": 3, "error": 4, "fleet": 5,
 
 class _TraceSpan:
     """Context manager handed out by :meth:`FlightRecorder.span`: records
-    one complete event over the ``with`` body."""
+    one complete event over the ``with`` body, and is a profiler
+    ``TraceAnnotation`` of the same name while it is open. ``set()`` adds
+    args that are only known inside the body (they reach the recorded
+    event, not the annotation)."""
 
-    __slots__ = ("_rec", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_rec", "_name", "_cat", "_args", "_t0", "_ann", "_under")
 
     def __init__(self, rec: "FlightRecorder", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -158,13 +188,31 @@ class _TraceSpan:
         self._cat = cat
         self._args = args
 
+    def set(self, **args) -> None:
+        self._args.update(args)
+
     def __enter__(self) -> "_TraceSpan":
+        rec = self._rec
+        stack = rec._open_spans()
+        self._under = stack[-1] if stack else ""
+        stack.append(self._name)
+        self._ann = rec._annotate(self._name, self._args)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._rec.complete(self._name, self._t0, cat=self._cat,
-                           **self._args)
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        stack = self._rec._open_spans()
+        # newest entry of this name: LIFO but for spans held open across
+        # an ``await`` (loop.yield / loop.idle of two engines on one loop)
+        for i in range(len(stack) - 1, -1, -1):
+            if stack[i] == self._name:
+                del stack[i]
+                break
+        self._rec._close(self._name, self._t0, t1, self._cat, self._args,
+                         self._under)
 
 
 class FlightRecorder:
@@ -183,6 +231,38 @@ class FlightRecorder:
         self._ids = itertools.count()
         self.dropped = 0
         self._dropped_flushed = 0      # high-water mark already counted
+        #: sequence number of the scheduling pass in progress (None before
+        #: the first): every span opened under it carries it as ``pass_id``
+        self.pass_id: Optional[int] = None
+        self._local = threading.local()    # per-thread stack of open spans
+        try:                       # the ENABLED recorder alone touches jax
+            from jax.profiler import TraceAnnotation
+        except ImportError:        # pragma: no cover - jax is a hard dep
+            TraceAnnotation = None
+        self._trace_annotation = TraceAnnotation
+
+    def next_pass(self) -> int:
+        """Open the next scheduling pass (``ServingEngine.run_pass``)."""
+        self.pass_id = 0 if self.pass_id is None else self.pass_id + 1
+        return self.pass_id
+
+    def _open_spans(self) -> List[str]:
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
+
+    def _annotate(self, name: str, args: Dict[str, Any]):
+        """Enter a profiler TraceMe for a span (a cheap no-op outside a
+        ``jax.profiler`` session); returns it for the matching exit."""
+        if self._trace_annotation is None:
+            return None
+        pid = args.get("pass_id")
+        ann = (self._trace_annotation(name) if pid is None
+               else self._trace_annotation(name, pass_id=pid))
+        ann.__enter__()
+        return ann
 
     # -- recording ---------------------------------------------------------
     def _push(self, ev: Dict[str, Any]) -> str:
@@ -232,12 +312,31 @@ class FlightRecorder:
         to now); returns its event id."""
         if t1 is None:
             t1 = time.perf_counter()
+        stack = self._open_spans()
+        return self._close(name, t0, t1, cat, args,
+                           stack[-1] if stack else "")
+
+    def _close(self, name: str, t0: float, t1: float, cat: str,
+               args: Dict[str, Any], under: str) -> str:
+        """The ONE place a slice's duration is known: record it, and count
+        its host seconds by span and parent (label sets bounded by the
+        stable names)."""
+        reg = get_registry()
+        if reg.enabled:
+            from . import metrics as tmetrics
+            tmetrics.host_seconds_counter(reg).inc(
+                max(t1 - t0, 0.0),
+                span=name if name in _EVENT_SET else "other",
+                under=under if under in _EVENT_SET else "")
         return self._push({"name": name, "cat": cat, "ph": "X",
                            "ts": t0, "dur": t1 - t0, "args": args})
 
     def span(self, name: str, cat: str = "engine", **args) -> _TraceSpan:
         """``with rec.span("pass.admit"): ...`` — one complete event over
-        the body."""
+        the body, a profiler TraceMe while it is open, tagged with the
+        ``pass_id`` of the scheduling pass in progress."""
+        if self.pass_id is not None:
+            args.setdefault("pass_id", self.pass_id)
         return _TraceSpan(self, name, cat, args)
 
     def error(self, err: BaseException, cat: str = "error", **args):
@@ -367,6 +466,9 @@ class NullFlightRecorder:
 
 class _NullSpanCM:
     __slots__ = ()
+
+    def set(self, **args):
+        pass
 
     def __enter__(self):
         return self

@@ -42,9 +42,3 @@ def profile_generate(app, input_ids, log_dir: str = "profiles",
     out["profile_dir"] = log_dir
     out["profiled_wall_s"] = time.perf_counter() - t0
     return out
-
-
-def annotate(name: str):
-    """Named trace region (shows up in the profiler timeline)."""
-    import jax
-    return jax.profiler.TraceAnnotation(name)

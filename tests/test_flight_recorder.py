@@ -157,6 +157,31 @@ def test_jsonl_export_parses():
     assert objs[1]["dur"] >= 0.0
 
 
+GOLDEN_EVENT_NAMES = (
+    "pass.expire", "pass.preempt", "pass.admit", "pass.dispatch",
+    "loop.yield", "loop.idle",
+    "stream.deliver", "admission.headroom",
+    "dispatch.decode", "dispatch.decode_loop", "dispatch.prefill_chunk",
+    "dispatch.ragged", "fetch.tokens", "preempt",
+    "run.prefill", "run.decode", "run.decode_loop", "run.paged",
+    "run.paged_loop", "run.ragged", "run.spec_draft", "run.spec_verify",
+    "compile",
+    "fleet.route", "fleet.drain", "kv.spill", "kv.restore", "handoff.send",
+    "handoff.recv", "fleet.all_dead", "fleet.scale_up", "fleet.scale_down",
+    "trace.begin", "trace.admit", "trace.requeue", "trace.emit",
+    "degrade.enter", "degrade.exit", "compile.unexpected")
+
+
+def test_event_names_golden():
+    """The stable-name contract, spelled out: a rename or a removal is a
+    breaking change and must edit this list (and the README table)."""
+    assert trace_mod.EVENT_NAMES == GOLDEN_EVENT_NAMES
+    readme = (REPO / "README.md").read_text()
+    for name in GOLDEN_EVENT_NAMES:
+        if not name.startswith("run."):          # one `run.<kind>` row
+            assert f"`{name}`" in readme, name
+
+
 # ---------------------------------------------------------------------------
 # closed-loop engine run: golden event names + bit-identity pin
 # ---------------------------------------------------------------------------
@@ -188,7 +213,8 @@ def test_engine_trace_golden_phases_and_disabled_bit_identity(paged_app):
     # golden-pinned stable phase/event names (README "Flight recorder")
     for want in ("pass.expire", "pass.preempt", "pass.admit",
                  "pass.dispatch", "dispatch.prefill_chunk",
-                 "dispatch.decode", "fetch.tokens", "stream.deliver"):
+                 "dispatch.decode", "run.paged", "fetch.tokens",
+                 "stream.deliver"):
         assert want in names, f"missing stable event {want!r}"
     # every recorded name is from the stable contract (errors prefixed)
     for n in names:

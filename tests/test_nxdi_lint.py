@@ -90,12 +90,15 @@ def test_donation_red_on_doctored_application(tmp_path):
     the dispatch consumed it, before the rebind — the retry_safe=False
     state-loss class as a lint finding."""
     src = (PKG / "models" / "application.py").read_text()
-    anchor = ('        self.cache = out["cache"]\n'
-              '        self._tel_end("paged", t0, out, input_ids.shape[0])')
-    assert anchor in src
+    head = ('                         jnp.asarray(last_idx),\n'
+            '                         sampling_params, self._next_rng(), '
+            '**kw)\n')
+    anchor = head + '            self.cache = out["cache"]\n'
+    assert src.count(anchor) == 1
     doctored = src.replace(
         anchor,
-        '        jax.block_until_ready(self.cache)   # doctored\n' + anchor)
+        head + '            jax.block_until_ready(self.cache)   # doctored\n'
+        '            self.cache = out["cache"]\n')
     bad = tmp_path / "application_doctored.py"
     bad.write_text(doctored)
     ctx = analysis.LintContext(tmp_path)

@@ -309,25 +309,29 @@ def test_cb_adapter_records_serving_metrics(live_registry):
     assert len(spans) == 2
     ev_names = [e["name"] for e in spans[0]["events"]]
     assert ev_names[0] == "first_token" and "released" in ev_names
-    # run_seconds split host/device recorded at the app boundary
+    # run_seconds: the HOST side of every _run_* call at the app boundary;
+    # there is no device part — telemetry never syncs the device (C7)
     run = reg.get(tmetrics.RUN_SECONDS)
     assert run.count(kind="prefill", part="host") == 2
-    assert run.count(kind="prefill", part="device") == 2
-    assert run.count(kind="decode", part="device") == 6
+    assert run.count(kind="decode", part="host") == 6
+    assert {s["labels"]["part"] for s in run._snapshot()} == {"host"}
     assert reg.get(tmetrics.GENERATED_TOKENS_TOTAL).get(engine="cb") > 0
-    # app-level row accounting is a separate metric (includes pad rows)
-    assert reg.get(tmetrics.DEVICE_SAMPLED_ROWS_TOTAL).get(kind="prefill") > 0
-    assert reg.get(tmetrics.DEVICE_SAMPLED_ROWS_TOTAL).get(kind="decode") > 0
     # the whole thing renders as valid Prometheus text
     types, samples = _parse_prometheus(reg.render_prometheus())
     assert types[tmetrics.REQUEST_TTFT_SECONDS] == "histogram"
     assert types[tmetrics.JIT_COMPILES_TOTAL] == "counter"
 
 
-def test_paged_adapter_records_kv_occupancy(live_registry):
+def test_paged_adapter_records_kv_occupancy(live_registry, monkeypatch):
     reg = live_registry
     app = _paged_app()
     eng = PagedEngineAdapter(app)
+    # enabling the registry adds NO device sync: any block_until_ready on
+    # the serving path from here on is the instrument's, and fails the test
+    import jax
+    syncs = []
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: syncs.append(x) or x)
     rng = np.random.default_rng(0)
     p1 = rng.integers(1, 500, size=9).tolist()
     eng.add_requests([0], [p1])
@@ -345,8 +349,10 @@ def test_paged_adapter_records_kv_occupancy(live_registry):
     assert reg.get(tmetrics.REQUEST_TTFT_SECONDS).count(engine="paged", tenant="") == 1
     assert reg.get(tmetrics.DECODE_STEP_SECONDS).count(engine="paged") == 3
     run = reg.get(tmetrics.RUN_SECONDS)
-    assert run.count(kind="paged", part="device") >= 4
-    assert run.sum(kind="paged", part="device") > 0.0
+    assert run.count(kind="paged", part="host") >= 4
+    assert run.sum(kind="paged", part="host") > 0.0
+    assert run.count(kind="paged", part="device") == 0
+    assert syncs == []
     # paged graph: one compile for the prefill width, repeat shapes hit
     compiles = reg.get(tmetrics.JIT_COMPILES_TOTAL)
     assert sum(s["value"] for s in compiles._snapshot()
@@ -447,7 +453,7 @@ def test_disabled_telemetry_is_bit_identical_and_keeps_cache_keys():
     assert base[4] == live[4]                       # identical jit cache keys
     # and the instrumented run actually recorded something
     assert app.telemetry.get(tmetrics.RUN_SECONDS).count(
-        kind="prefill", part="device") == 1
+        kind="prefill", part="host") == 1
 
 
 def test_disabled_adapters_add_no_metric_keys():
