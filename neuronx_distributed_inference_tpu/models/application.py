@@ -1168,6 +1168,18 @@ class PagedCausalLMApplication(CausalLMApplication):
                             self.tpu_config)
         return jax.jit(fn, donate_argnums=(1,))
 
+    @property
+    def prefill_row_buckets(self) -> List[int]:
+        """Row ladder of a two-phase prefill-chunk dispatch, two rungs
+        ``[r_min, batch_size]``: a chunk carrying ``r_min`` prompts or
+        fewer runs ``r_min`` rows, anything more the full batch. ``r_min``
+        is 1, or the extent of the mesh axis the batch rows shard over
+        ("dp"), the smallest row count that axis divides. Prefill chunks
+        ONLY: decode, spec-verify and ragged dispatches pad to
+        ``batch_buckets`` (the full batch)."""
+        full = self.tpu_config.batch_size
+        return sorted({min(self.mesh.shape[AXIS_DP], full), full})
+
     # -- positionally coupled sampling (ops/sampling.coupled_sample) -------
     def _coupled_sampling(self) -> bool:
         sc = self.tpu_config.on_device_sampling_config
@@ -1183,7 +1195,9 @@ class PagedCausalLMApplication(CausalLMApplication):
         if not self._coupled_sampling():
             return None
         if row_seeds is None:
-            return jnp.zeros((batch,), jnp.int32)
+            # a host buffer, not jnp.zeros: that is a device program per
+            # row count, and the warm-up walk dispatches step programs only
+            row_seeds = np.zeros((batch,), np.int32)
         return jnp.asarray(row_seeds, jnp.int32)
 
     def _lora_adapter_ids(self, adapter_ids):
@@ -1408,9 +1422,12 @@ class PagedCausalLMApplication(CausalLMApplication):
         return out
 
     def warmup(self):
-        """AOT-compile the paged graph at each shape it will run: the prefill
-        window (ctx bucket or chunk width) and the T=1 decode step. Dummy
-        calls write nothing (all slots negative → dropped)."""
+        """AOT-compile the paged graph at each shape it will run: the T=1
+        decode step at the full batch, and every prefill window (ctx bucket
+        or chunk width) at both rungs of ``prefill_row_buckets`` — the
+        full batch (``generate()``, a packed chunk) and ``r_min`` rows (a
+        chunk carrying one prompt). Dummy calls write nothing (all slots
+        negative → dropped)."""
         if self.params is None:
             self.init_random_weights()
         if not hasattr(self, "kv_mgr") or self.cache is None:
@@ -1421,29 +1438,22 @@ class PagedCausalLMApplication(CausalLMApplication):
         if (cfg.is_chunked_prefill and cfg.chunked_prefill_config is not None):
             widths.add(cfg.chunked_prefill_config.kernel_q_tile_size)
         widths.update(self.ctx_buckets)
-        bt = np.zeros((b, self.max_blocks), np.int32)   # null block only
-        for w in sorted(widths):
-            self._run_paged(np.zeros((b, w), np.int32),
-                            np.zeros((b, w), np.int32),
-                            np.full((b, w), -1, np.int32), bt,
-                            np.zeros((b,), np.int32))
-        # 2-D table-width buckets: warm every (prefill width x table
-        # width) pair plus the chunked decode loop at every width — the
-        # shapes generate() actually runs
+        # 2-D table-width buckets: every (rows x prefill width x table
+        # width) triple plus the chunked decode loop at every table width —
+        # the shapes generate() and the serving adapter actually run
         chunk = max(cfg.decode_chunk_tokens, 1)
-        for tw in self._bt_buckets[:-1]:
-            bt_n = np.zeros((b, tw), np.int32)
+        for tw in self._bt_buckets:
             for w in sorted(widths):
-                self._run_paged(np.zeros((b, w), np.int32),
-                                np.zeros((b, w), np.int32),
-                                np.full((b, w), -1, np.int32), bt_n,
-                                np.zeros((b,), np.int32))
+                for rows in ([b] if w == 1 else self.prefill_row_buckets):
+                    self._run_paged(np.zeros((rows, w), np.int32),
+                                    np.zeros((rows, w), np.int32),
+                                    np.full((rows, w), -1, np.int32),
+                                    np.zeros((rows, tw), np.int32),  # null block
+                                    np.zeros((rows,), np.int32))
             if chunk > 1:
                 self._run_paged_loop(np.zeros((b,), np.int32),
-                                     np.zeros((b,), np.int32), bt_n, chunk)
-        if chunk > 1:
-            self._run_paged_loop(np.zeros((b,), np.int32),
-                                 np.zeros((b,), np.int32), bt, chunk)
+                                     np.zeros((b,), np.int32),
+                                     np.zeros((b, tw), np.int32), chunk)
         return self
 
     def generate(self, input_ids: np.ndarray,

@@ -56,7 +56,8 @@ def get_target_bucket(buckets: List[int], length: int,
     """Smallest bucket >= length (reference: model_wrapper.py:831-921).
 
     ``kind`` tags the selection for telemetry ("ctx"/"tkg"/"batch"/
-    "block_table"); host-side only, a no-op while telemetry is disabled."""
+    "block_table"/"prefill_rows"); host-side only, a no-op while telemetry
+    is disabled."""
     for b in buckets:
         if b >= length:
             if kind is not None:

@@ -63,6 +63,14 @@ Chunked, packed, schedulable prefill — paged adapter only (see README
     batch of skewed-length prompts no longer pads every row to the longest
     suffix — reclaimed pad waste is reported via ``nxdi_prefill_pad_waste``
     and ``nxdi_prefill_chunks_total``.
+  * a chunk dispatch's shape is (rows, width): width the smallest ctx
+    bucket covering the longest chunk packed, rows the smallest rung of
+    the application's ``prefill_row_buckets`` covering the prompts packed —
+    two rungs, ``[r_min, batch_size]`` (``r_min`` is 1, or the "dp" mesh
+    extent), so a chunk that carries one prompt runs one row, not
+    ``batch_size`` copies of it; the choice is counted as
+    ``nxdi_bucket_selected_total{kind="prefill_rows"}``. Decode,
+    spec-verify and ragged dispatches keep the full batch.
   * ``prefill_budget_tokens`` defers prefill to the scheduler:
     ``add_requests`` only admits (block allocation + chunk state) and
     returns ``{}``; each ``step()``/``step_many()`` then runs AT MOST ONE
@@ -2304,7 +2312,9 @@ class PagedEngineAdapter(_EngineAdapterBase):
         """Build the ragged packed-chunk inputs: one row per sequence,
         positions at each row's own suffix offset, slots through its own
         block table; width = smallest ctx bucket covering the longest
-        chunk, batch padded by repeating row 0 (the usual invariant)."""
+        chunk, rows = smallest rung of ``app.prefill_row_buckets`` covering
+        the sequences packed (``r_min`` rows for one prompt, the full batch
+        for more), padded by repeating row 0 (the usual invariant)."""
         from ..modules.block_kv_cache import slots_from_table
         app = self.app
         b = len(rows)
@@ -2329,8 +2339,8 @@ class PagedEngineAdapter(_EngineAdapterBase):
         aids = self._lora_aids(sids)
         if aids is not None:
             aids = np.asarray(aids, np.int32)
-        pad_to = autobucketing.get_target_bucket(app.batch_buckets, b,
-                                                 kind="batch")
+        pad_to = autobucketing.get_target_bucket(app.prefill_row_buckets, b,
+                                                 kind="prefill_rows")
         if pad_to > b:
             seeds = _repeat_row0(seeds, pad_to)
             if aids is not None:

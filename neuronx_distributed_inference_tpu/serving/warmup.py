@@ -15,6 +15,17 @@ the one cache directory utils/compile_cache.py resolves) counts as
 ROADMAP item-5 pin ("a second replica compiles nothing") fall out of the
 counters.
 
+**The report names programs by ``(kind, bucket)``, and
+``benchmark/run.py`` labels the programs of a trace by that pair**
+(``<kind>.w<bucket>``): its calibration calls ``precompile(app,
+widths=[w])`` per width inside a profiler session and expects every entry
+of ``graphs`` to be a distinct compiled program that ran exactly once. So
+no two entries of one report share a pair, the walk dispatches nothing
+but the step programs, ``("paged", 1)`` is the full-batch decode step and
+``("paged", w)`` for ``w > 1`` the ``r_min``-row program a prefill chunk
+carrying one prompt runs (its full-batch twin is ``("paged_pack", w)``):
+pinned by tests/test_prefill_rows.py.
+
 After the walk the application enters **declared steady state**
 (:meth:`~..models.application.CausalLMApplication.declare_steady_state`):
 any later first-seen signature is a tracked incident — the
@@ -102,12 +113,15 @@ _MONITOR = _CompileCacheMonitor()
 # ---------------------------------------------------------------------------
 def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
     """The warm plan of a paged application: the unified ragged row
-    ladder across every block-table width bucket, the two-phase ``paged``
-    graph at T=1 and the ctx-bucket chunk widths (what the default
-    ``PagedEngineAdapter(app)`` — and a ragged adapter shed back to
-    two-phase — dispatches), the fused decode loop, and the speculative
-    verify widths: the exact shape set the serving adapters dispatch
-    (serving/ragged/path.py, serving/adapter.py)."""
+    ladder across every block-table width bucket, the two-phase graph at
+    T=1 (``paged``, the full-batch decode step) and at the ctx-bucket
+    chunk widths on both rungs of ``app.prefill_row_buckets`` (``paged``
+    is the ``r_min``-row program a chunk carrying one prompt runs,
+    ``paged_pack`` the full-batch one a packed chunk and ``generate()``
+    run) — what the default ``PagedEngineAdapter(app)``, and a ragged
+    adapter shed back to two-phase, dispatches — the fused decode loop,
+    and the speculative verify widths: the exact shape set the serving
+    adapters dispatch (serving/ragged/path.py, serving/adapter.py)."""
     cfg = app.tpu_config
     b = cfg.batch_size
     if widths is None:
@@ -124,6 +138,15 @@ def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
     lora_kw = ({"adapter_ids": np.zeros((b,), np.int32)}
                if app.spec.lora is not None else None)
     plan: List[tuple] = []
+
+    def paged_thunk(w, rows, tw, lora=False):
+        kw = {"adapter_ids": np.zeros((rows,), np.int32)} if lora else {}
+        app._run_paged(np.zeros((rows, w), np.int32),
+                       np.zeros((rows, w), np.int32),
+                       np.full((rows, w), -1, np.int32),
+                       np.zeros((rows, tw), np.int32),
+                       np.zeros((rows,), np.int32), **kw)
+
     for tw in bt_widths:
         bt = np.zeros((b, tw), np.int32)        # null block only: no writes
 
@@ -137,23 +160,24 @@ def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
                             np.ones((b,), np.int32),
                             np.zeros((b,), np.int32), **kw)
 
-        def paged_thunk(w, bt=bt, **kw):
-            app._run_paged(np.zeros((b, w), np.int32),
-                           np.zeros((b, w), np.int32),
-                           np.full((b, w), -1, np.int32), bt,
-                           np.zeros((b,), np.int32), **kw)
-
         for w in sorted(widths):
             plan.append(("ragged", w, lambda w=w, bt=bt: ragged_thunk(w, bt)))
             if lora_kw is not None:
                 plan.append(("ragged_lora", w,
                              lambda w=w, bt=bt: ragged_thunk(w, bt, **lora_kw)))
             if w == 1 or w in app.ctx_buckets:
-                plan.append(("paged", w,
-                             lambda w=w, bt=bt: paged_thunk(w, bt)))
-                if lora_kw is not None:
-                    plan.append(("paged_lora", w, lambda w=w, bt=bt:
-                                 paged_thunk(w, bt, **lora_kw)))
+                # ("paged", w): the decode step at the full batch (w == 1),
+                # the r_min-row chunk program (w > 1); the full-batch chunk
+                # program is a kind of its own, so that no two entries of
+                # the report share a (kind, bucket) pair
+                rungs = [b] if w == 1 else app.prefill_row_buckets
+                for kind, rows in zip(("paged", "paged_pack"), rungs):
+                    plan.append((kind, w, lambda w=w, rows=rows, tw=tw:
+                                 paged_thunk(w, rows, tw)))
+                    if lora_kw is not None:
+                        plan.append((kind + "_lora", w,
+                                     lambda w=w, rows=rows, tw=tw:
+                                     paged_thunk(w, rows, tw, lora=True)))
         if chunk > 1:
             plan.append(("paged_loop", chunk, lambda bt=bt: app._run_paged_loop(
                 np.zeros((b,), np.int32), np.zeros((b,), np.int32), bt,

@@ -169,10 +169,12 @@ def test_packed_mixed_lengths_match_individual(paged_app):
     padded = eng.host_stats["prefill_padded_tokens"]
     real = eng.host_stats["prefill_real_tokens"]
     assert real == len(P_SHORT) + len(P_LONG)
-    # every dispatch runs at the 16-wide ctx bucket padded to 2 rows (this
-    # app has a single bucket per axis); the strict pad-waste reduction vs
-    # monolithic over a real ladder is pinned by bench.py --prefill-overhead
-    assert padded == 5 * 2 * 16
+    # every dispatch runs at the 16-wide ctx bucket (this app's only one);
+    # the first carries both rows and runs the full batch of 2, the other
+    # four carry row 1 alone and run one row (app.prefill_row_buckets). The
+    # strict pad-waste reduction vs monolithic over a real width ladder is
+    # pinned by bench.py --prefill-overhead
+    assert padded == 2 * 16 + 4 * 1 * 16
     for _ in range(3):
         for s, t in eng.step().items():
             got[s].append(t)
