@@ -5,15 +5,19 @@ Everything here goes through the program's public entry points:
 ``models.family.get_family`` -> ``PagedCausalLMApplication`` ->
 ``serving.warmup.precompile`` -> ``PagedEngineAdapter`` -> ``ServingEngine``
 -> ``ServingFrontend``. Nothing is specific to one family: a configuration
-of any registered family is a new file under ``configs/``.
+of any registered family is a new file under ``configs/``, and the plain
+reference of its architecture a file ``references/<model_type>.py`` found by
+name (:func:`load_reference`).
 """
 
 from __future__ import annotations
 
 import gc
+import importlib.util
 import json
 import os
-from typing import Any, Dict, List
+import types
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
@@ -26,42 +30,111 @@ HARNESS_KEYS = ("family", "source", "reduced", "reduced_why", "assumed",
                 "pool_arithmetic", "adapter", "gate")
 
 
-#: where BENCHMARK.json and benchmark/{cells,configs,traffic} are looked up;
-#: the tests point it at a toy copy (tests/toy). Readers, peaks and layer
-#: metrics always come from this directory's own files.
+#: where BENCHMARK.json is read and where a file of the benchmark (cell,
+#: configuration, mix, reference, a layer metric's .json) is looked up first;
+#: the tests point it at a toy copy (tests/toy), whose own files come before
+#: this directory's.
 DATA_ROOT = ROOT
+
+
+def data_dirs() -> List[str]:
+    """Where a file of the benchmark is looked for, in order."""
+    return [os.path.join(DATA_ROOT, "benchmark"), BENCH_DIR]
+
+
+def find_file(*parts: str) -> Optional[str]:
+    """The first file at ``parts`` under :func:`data_dirs`, or None."""
+    for base in data_dirs():
+        path = os.path.join(base, *parts)
+        if os.path.exists(path):
+            return path
+    return None
 
 
 def load_json(*parts: str) -> Dict[str, Any]:
     """A data file of the benchmark, by its path under ``benchmark/``."""
-    for base in (os.path.join(DATA_ROOT, "benchmark"), BENCH_DIR):
-        path = os.path.join(base, *parts)
-        if os.path.exists(path):
-            with open(path) as f:
-                return json.load(f)
+    path = find_file(*parts)
+    if path is None:
+        raise FileNotFoundError(
+            f"no {os.path.join(*parts)} under benchmark/: a cell, "
+            "configuration, mix or layer metric is a data file found by its "
+            "name")
+    with open(path) as f:
+        return json.load(f)
+
+
+def load_module(path: str) -> types.ModuleType:
+    """The Python file at ``path`` as a module of its own (a reference, a
+    layer metric's reader): executed anew at every call, registered nowhere."""
+    stem = os.path.splitext(os.path.basename(path))[0]
+    name = "benchmark_file_" + "".join(c if c.isalnum() else "_"
+                                       for c in stem)
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+#: the model types ``harness/reference.py`` + ``harness/weights.py`` cover
+BUILTIN_REFERENCES = ("olmoe", "mistral")
+
+
+def load_reference(model_type: str):
+    """The plain reference of an architecture, found by its ``model_type``:
+    the file ``references/<model_type>.py`` (looked up as :func:`load_json`
+    looks up data files), else the built-in pair for the types it covers.
+    What it exports — ``forward(cfg, w, ids, with_margins=False)`` and
+    ``weight_shapes(cfg)`` — is set out in ``benchmark/README.md``."""
+    path = find_file("references", model_type + ".py")
+    if path is not None:
+        return load_module(path)
+    if model_type in BUILTIN_REFERENCES:
+        from . import reference, weights
+        return types.SimpleNamespace(forward=reference.forward,
+                                     weight_shapes=weights.weight_table)
     raise FileNotFoundError(
-        f"no {os.path.join(*parts)} under benchmark/: a cell, configuration, "
-        "mix or layer metric is a data file found by its name")
+        f"no reference for model_type {model_type!r}: add "
+        f"benchmark/references/{model_type}.py with forward(cfg, w, ids, "
+        "with_margins=False) and weight_shapes(cfg) (benchmark/README.md, "
+        "'A reference')")
 
 
-def hf_config(cfg: Dict[str, Any]) -> Dict[str, Any]:
-    """The model's own keys of a configuration file."""
-    return {k: v for k, v in cfg.items() if k not in HARNESS_KEYS}
+def hf_config(cfg: Dict[str, Any],
+              overrides: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
+    """The model's own keys of a configuration file, with ``overrides``
+    (published keys, as ``gate.config`` gives them) in place of the file's."""
+    hf = {k: v for k, v in cfg.items() if k not in HARNESS_KEYS}
+    hf.update(overrides or {})
+    return hf
 
 
-def build_app(cfg: Dict[str, Any], *, layers: int | None = None,
+def gate_overrides(gate: Dict[str, Any]) -> Dict[str, Any]:
+    """The published keys that the gate's twin replaces: ``gate["config"]``,
+    or ``gate["layers"]`` as the short form of ``{"num_hidden_layers": n}``
+    (a model with a layer pattern cuts the pattern with its depth, so it
+    spells out ``config``)."""
+    if ("config" in gate) == ("layers" in gate):
+        raise ValueError("a configuration's gate gives either 'config' (a "
+                         "dict of published keys) or 'layers', not both and "
+                         f"not neither: {sorted(gate)}")
+    if "config" in gate:
+        return dict(gate["config"])
+    return {"num_hidden_layers": gate["layers"]}
+
+
+def build_app(cfg: Dict[str, Any], *,
+              overrides: Optional[Dict[str, Any]] = None,
               serve: Dict[str, Any] | None = None, **tcfg_kw):
     """A paged application of ``cfg``'s family on the first ``cfg['tp']``
-    devices. ``layers`` cuts the depth (the gate's twin); ``serve`` replaces
-    the configuration's serving shape."""
+    devices. ``overrides`` replaces published keys (the gate's twin:
+    :func:`gate_overrides`); ``serve`` replaces the configuration's serving
+    shape."""
     from neuronx_distributed_inference_tpu.config import TpuConfig
     from neuronx_distributed_inference_tpu.models.application import \
         PagedCausalLMApplication
     from neuronx_distributed_inference_tpu.models.family import get_family
     family = get_family(cfg["family"])
-    hf = hf_config(cfg)
-    if layers is not None:
-        hf["num_hidden_layers"] = layers
+    hf = hf_config(cfg, overrides)
     tcfg = TpuConfig(tp_degree=cfg["tp"], dtype=cfg["dtype"],
                      **(cfg["serve"] if serve is None else serve), **tcfg_kw)
     return PagedCausalLMApplication(None, family.config_cls(tcfg, **hf),
@@ -100,9 +173,11 @@ def serve_stack(app, cfg: Dict[str, Any]):
 def logit_gate(cfg: Dict[str, Any], seed: int,
                served_precision: str | None = None) -> Dict[str, Any]:
     """Rule (c) of the correctness gate, as the configuration's ``gate``
-    states it. Builds the depth-cut twin, loads seeded weights through the
-    family's own checkpoint converter, teacher-forces it through the paged
-    cache and holds every compared logit to ``atol + rtol * |reference|``.
+    states it. Builds the gate's twin (:func:`gate_overrides`) and the
+    reference of its ``model_type`` (:func:`load_reference`) from one dict,
+    loads seeded weights through the family's own checkpoint converter,
+    teacher-forces it through the paged cache and holds every compared logit
+    to ``atol + rtol * |reference|``.
     Everything it put on the device is freed before it returns.
 
     ``served_precision`` is for the CPU tests only: XLA:CPU multiplies
@@ -113,18 +188,21 @@ def logit_gate(cfg: Dict[str, Any], seed: int,
     import time
     import jax
     import jax.numpy as jnp
-    from . import reference, weights
+    from . import weights
     gate = cfg["gate"]
     marks = [("start", time.perf_counter())]
 
     def mark(name):
         marks.append((name, time.perf_counter()))
-    hf = dict(hf_config(cfg), num_hidden_layers=gate["layers"])
+    twin = gate_overrides(gate)
+    hf = hf_config(cfg, twin)
+    reference = load_reference(hf["model_type"])
+    table = reference.weight_shapes(hf)
     b, s, n_new = gate["batch"], gate["prompt_len"], gate["new_tokens"]
     rng = np.random.default_rng([seed, 0x67617465])
     ids = rng.integers(1, hf["vocab_size"], size=(b, s + n_new),
                        dtype=np.int64).astype(np.int32)
-    w = weights.make_weights(hf, seed)
+    w = weights.make_weights(table, seed)
     with jax.default_matmul_precision("highest"):
         want, margins = jax.jit(
             lambda w_, ids_: reference.forward(hf, w_, ids_,
@@ -133,12 +211,12 @@ def logit_gate(cfg: Dict[str, Any], seed: int,
     want, margins = np.asarray(want), np.asarray(margins)
     mark("weights+reference")
     bucket = -(-s // 32) * 32
-    app = build_app(cfg, layers=gate["layers"], output_logits=True,
+    app = build_app(cfg, overrides=twin, output_logits=True,
                     serve=dict(cfg["serve"], batch_size=b,
                                seq_len=2 * bucket, pa_num_blocks=4 * b,
                                context_encoding_buckets=[bucket]))
-    view = weights.HfView(hf, w, dtype=None if cfg["dtype"] == "bfloat16"
-                           else np.dtype(cfg["dtype"]))
+    view = weights.HfView(table, w, dtype=None if cfg["dtype"] == "bfloat16"
+                          else np.dtype(cfg["dtype"]))
     mark("to_host")
     host = app.family.convert_hf_state_dict(view, app.spec)
     del view

@@ -51,7 +51,10 @@ class LoadDriver:
 
     def stop(self) -> None:
         if self._loop is not None and self._main is not None:
-            self._loop.call_soon_threadsafe(self._main.cancel)
+            try:
+                self._loop.call_soon_threadsafe(self._main.cancel)
+            except RuntimeError:     # the loop has ended: its fault is below
+                pass
         if self._thread is not None:
             self._thread.join(timeout=30)
             if self._thread.is_alive():

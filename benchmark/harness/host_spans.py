@@ -30,10 +30,10 @@ import glob
 import json
 import os
 import sys
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from . import reduce_trace, xplane_wire
-from .build import BENCH_DIR, ROOT
+from .build import ROOT
 from .reduce_trace import Event, Planes
 
 HOST_SECONDS = "nxdi_host_seconds_total"
@@ -47,7 +47,9 @@ FETCH = ("fetch.tokens",)
 TOP = SCHED + ("pass.dispatch",) + YIELD + NOWORK
 SPANS = frozenset(TOP + DISPATCH + FETCH)
 
-#: the scopes ``models/model_base.py`` opens, outermost wins
+#: the scopes ``models/model_base.py`` opens today, outermost wins; a scope
+#: the program opens for a new kind of layer joins them by being named in a
+#: metric's file (:func:`known_scopes`)
 SCOPES = ("embed", "attn", "moe", "mlp", "lm_head", "sample")
 #: stats of an ``XLA Ops`` event's METADATA that may carry the HLO
 #: ``op_name`` (the scope path), in the order tried; ``tf_op`` is what a v5e
@@ -149,7 +151,7 @@ def slice_trace_dir(ctx: Dict[str, Any],
         entry = sys.modules.get("run") or sys.modules.get("__main__")
         out_dir = getattr(entry, "OUT_DIR", os.path.join(ROOT, ".bench_out"))
     from . import build
-    for base in (os.path.join(build.DATA_ROOT, "benchmark"), BENCH_DIR):
+    for base in build.data_dirs():
         for path in sorted(glob.glob(os.path.join(base, "cells", "*.json"))):
             with open(path) as f:
                 same = json.load(f) == ctx.get("cell")
@@ -211,7 +213,7 @@ def load_slice(ctx: Dict[str, Any]) -> Optional[Dict[str, Any]]:
         _CACHE.clear()
         planes = load_planes(path)
         _CACHE[key] = {"planes": planes, "idle": idle_by_span(planes),
-                       "scopes": scope_seconds(planes)}
+                       "scopes": scope_seconds(planes, known_scopes())}
     return _CACHE[key]
 
 
@@ -346,16 +348,32 @@ def idle_share(ctx: Dict[str, Any], host_class: str) -> Optional[float]:
 # device self time by scope (pure)
 # ---------------------------------------------------------------------------
 
-def scope_of(path: str) -> Optional[str]:
-    """The outermost model scope on an HLO op_name path
+def known_scopes() -> Tuple[str, ...]:
+    """The scope names the trace is split by: :data:`SCOPES` and every
+    ``scope`` that a ``layer_metrics/*.json`` gives its reader as an argument
+    (``trace_scope_ms``), so a metric of a new scope is a new file."""
+    from . import build
+    found = set()
+    for base in build.data_dirs():
+        for path in glob.glob(os.path.join(base, "layer_metrics", "*.json")):
+            with open(path) as f:
+                args = json.load(f).get("reader", {}).get("args", {})
+            if isinstance(args.get("scope"), str):
+                found.add(args["scope"])
+    return SCOPES + tuple(sorted(found - set(SCOPES)))
+
+
+def scope_of(path: str, scopes: Sequence[str] = SCOPES) -> Optional[str]:
+    """The outermost of ``scopes`` on an HLO op_name path
     (``jit(paged_forward_step)/while/body/closed_call/attn/dot_general:``)."""
     for part in path.rstrip(":").split("/"):
-        if part in SCOPES:
+        if part in scopes:
             return part
     return None
 
 
-def scope_seconds(planes: Planes) -> Dict[str, Dict[str, Any]]:
+def scope_seconds(planes: Planes, scopes: Sequence[str] = SCOPES
+                  ) -> Dict[str, Dict[str, Any]]:
     """Per compiled program of the first chip (``reduce_trace.program_key``):
     its executions (``count``, ``total_s``: the same two numbers
     ``reduce_trace`` reports under the program's label) and the self time of
@@ -386,7 +404,8 @@ def scope_seconds(planes: Planes) -> Dict[str, Dict[str, Any]]:
         while mi < len(mods) and mods[mi].end < e.start:
             mi += 1
         if mi < len(mods) and mods[mi].start <= e.start <= mods[mi].end:
-            per_exec[mi].append((scope_of(e.stats.get("scope", "")), own))
+            per_exec[mi].append((scope_of(e.stats.get("scope", ""), scopes),
+                                 own))
     for m, ops in zip(mods, per_exec):
         after: List[Optional[str]] = [None] * len(ops)
         nxt = None
@@ -423,7 +442,8 @@ def program_scope_ms(ctx: Dict[str, Any], kind: str, width: Any,
             if p["count"] == want["count"]
             and abs(p["total_s"] - want["total_s"])
             <= 1e-9 + 1e-6 * want["total_s"]]
-    if len(same) != 1 or not any(same[0]["scopes"].get(s) for s in SCOPES):
+    if len(same) != 1 or not any(v for s, v in same[0]["scopes"].items()
+                                 if s):
         return None
     return 1e3 * same[0]["scopes"].get(scope, 0.0) / same[0]["count"]
 

@@ -1,10 +1,12 @@
 """Per-layer metrics: one small data file each, read by a reader kind.
 
 ``layer_metrics/<name>.json`` holds ``layer``, ``unit``, ``better``,
-``source``, ``moves``, optionally ``workloads``, and ``reader``: a kind from
-the table below with its ``args``. A metric that needs new code is a file
-``layer_metrics/<name>.py`` with ``read(ctx) -> float | None`` beside its
-``.json`` (whose reader kind is then ``"python"``), found by name.
+``source``, ``moves`` and ``reader``: a kind from the table below with its
+``args``. (The cells that report it are listed in one place, the
+``workloads`` of its ``per_layer`` entry in ``BENCHMARK.json``.) A metric
+that needs new code is a file ``layer_metrics/<name>.py`` with
+``read(ctx) -> float | None`` beside its ``.json`` (whose reader kind is then
+``"python"``), found by name.
 
 A reader that finds nothing to read returns None and the harness leaves the
 metric out of the line.
@@ -29,11 +31,11 @@ metric out of the line.
 
 from __future__ import annotations
 
-import importlib.util
 import os
 from typing import Any, Callable, Dict, List, Optional
 
-from .build import BENCH_DIR, load_json
+from . import host_spans
+from .build import BENCH_DIR, load_json, load_module
 
 
 def _counter(snap: Dict[str, Any], name: str) -> float:
@@ -129,6 +131,9 @@ KINDS: Dict[str, Callable[..., Optional[float]]] = {
     "counter_ratio": counter_ratio,
     "prom_quantile": prom_quantile,
     "trace_program_median": trace_program_median,
+    # args kind, width, scope; a scope named in a metric's file is a scope
+    # the trace is split by (host_spans.known_scopes)
+    "trace_scope_ms": host_spans.program_scope_ms,
     "trace_idle_share": trace_idle_share,
     "report_field": report_field,
     "client_field": client_field,
@@ -141,11 +146,7 @@ def read_metric(name: str, ctx: Dict[str, Any]) -> Optional[float]:
     reader = spec["reader"]
     if reader["kind"] == "python":
         path = os.path.join(BENCH_DIR, "layer_metrics", name + ".py")
-        mod_spec = importlib.util.spec_from_file_location(
-            "layer_metric_" + name.replace(".", "_").replace("-", "_"), path)
-        mod = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(mod)
-        return mod.read(ctx)
+        return load_module(path).read(ctx)
     if reader["kind"] not in KINDS:
         raise KeyError(f"layer metric {name}: unknown reader kind "
                        f"{reader['kind']!r}; known: {sorted(KINDS)}")

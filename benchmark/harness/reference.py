@@ -153,3 +153,8 @@ def forward(cfg: Dict[str, Any], w: Mapping[str, Any], ids,
             else w["lm_head.weight"])
     logits = _linear(x, head)
     return (logits, margins) if with_margins else logits
+
+
+# the shared pieces, under the names a ``references/<model_type>.py`` imports
+# (a new architecture is a new file that reuses these, not an edit here)
+rms_norm, rope, linear, swiglu = _rms_norm, _rope, _linear, _swiglu

@@ -46,6 +46,10 @@ def _held_to_contract(out, names):
 @pytest.mark.parametrize("workload,names", [
     ("toy-open", {"ttft_p50_ms", "itl_p50_ms", "itl_p95_ms", "setup_s"}),
     ("toy-closed", {"tokens_per_s", "itl_p50_ms", "itl_p95_ms", "setup_s"}),
+    # another architecture: its reference, its gate's cut and its cell are
+    # files added beside the toy's own (README, "Adding things ...")
+    ("toy-granite-closed", {"tokens_per_s", "itl_p50_ms", "itl_p95_ms",
+                            "setup_s"}),
 ])
 def test_untraced_cell_reports_its_end_to_end_metrics(toy, workload, names):
     out = _run(workload, trace=0)
@@ -53,12 +57,13 @@ def test_untraced_cell_reports_its_end_to_end_metrics(toy, workload, names):
     assert all(m["value"] > 0 for m in out["metrics"].values())
 
 
-def test_traced_cell_reports_what_its_readers_find(toy):
+@pytest.mark.parametrize("workload", ["toy-closed", "toy-granite-closed"])
+def test_traced_cell_reports_what_its_readers_find(toy, workload):
     from neuronx_distributed_inference_tpu import telemetry
     from neuronx_distributed_inference_tpu.telemetry.trace import \
         disable_recorder
     try:
-        out = _run("toy-closed", trace=1)
+        out = _run(workload, trace=1)
     finally:
         telemetry.disable()
         disable_recorder()
@@ -74,6 +79,25 @@ def test_traced_cell_reports_what_its_readers_find(toy):
     assert 0.0 <= m["adapter.prefill_pad_share"]["value"] < 100.0
 
 
+def test_the_toy_gate_goes_through_the_reference_found_by_name(
+        toy, monkeypatch):
+    """The granite toy's gate passes against ``references/granite.py``, and
+    fails once that reference computes something else (its residual
+    multiplier dropped): the served twin is held to the file found."""
+    import types
+    cfg = build.load_json("configs", "toy-granite.json")
+    assert cfg["model_type"] not in build.BUILTIN_REFERENCES
+    assert build.logit_gate(cfg, 2**31 + 5)["passed"] is True
+    ref = build.load_reference("granite")
+    monkeypatch.setattr(build, "load_reference", lambda model_type: (
+        types.SimpleNamespace(
+            weight_shapes=ref.weight_shapes,
+            forward=lambda c, w, ids, with_margins=False: ref.forward(
+                dict(c, residual_multiplier=1.0), w, ids, with_margins))))
+    out = build.logit_gate(cfg, 2**31 + 5)
+    assert out["passed"] is False and out["worst_ratio"] > 4.0
+
+
 def test_every_real_cell_resolves_its_data_files():
     with open(os.path.join(build.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
@@ -84,11 +108,47 @@ def test_every_real_cell_resolves_its_data_files():
         assert len(spec["end_to_end"]) >= 2 and spec["per_layer"]
         for m in spec["per_layer"]:
             file = build.load_json("layer_metrics", m["name"] + ".json")
-            for key in ("layer", "unit", "better", "source", "moves"):
+            # a metric's cells are listed in BENCHMARK.json alone
+            assert "workloads" not in file, m["name"]
+            for key in set(file) & set(m):
                 assert file[key] == m[key], (m["name"], key)
-            assert file.get("workloads") == m.get("workloads")
+            assert {"layer", "unit", "better", "source", "moves"} <= \
+                set(file) & set(m)
+        # warm: decode, and every prefill bucket of the cell's own that a
+        # chunk of its mix's prompts can land in
+        buckets = sorted(spec["config"]["serve"]["context_encoding_buckets"])
+        lens = spec["mix"]["prompt_len"]
         widths = build.warm_widths(spec["config"], spec["mix"])
-        assert widths == [1, 64, 256]
+        assert widths[0] == 1 and set(widths[1:]) <= set(buckets)
+        assert widths == sorted(set(widths))
+        for n in (lens["lo"], lens["hi"]):
+            last = (n - 1) % buckets[-1] + 1
+            assert next(b for b in buckets if b >= last) in widths
+        if lens["hi"] > buckets[-1]:
+            assert buckets[-1] in widths
+
+
+@pytest.mark.parametrize("gate,twin", [
+    ({"layers": 2}, {"num_hidden_layers": 2}),
+    ({"config": {"num_hidden_layers": 3, "layer_types": ["m", "a", "m"]}},
+     {"num_hidden_layers": 3, "layer_types": ["m", "a", "m"]}),
+])
+def test_the_gates_cut_is_data(gate, twin):
+    """``gate.layers`` is the short form; ``gate.config`` replaces any
+    published key, and the twin's dict is the file's with those in place."""
+    cfg = {"model_type": "x", "num_hidden_layers": 40, "layer_types": ["m"],
+           "hidden_size": 8, "family": "x", "gate": gate}
+    assert build.gate_overrides(gate) == twin
+    hf = build.hf_config(cfg, build.gate_overrides(gate))
+    assert hf == dict({"model_type": "x", "hidden_size": 8,
+                       "layer_types": ["m"]}, **twin)
+    assert build.hf_config(cfg)["num_hidden_layers"] == 40   # file untouched
+
+
+@pytest.mark.parametrize("gate", [{}, {"layers": 2, "config": {}}])
+def test_a_gate_gives_one_form_of_its_cut(gate):
+    with pytest.raises(ValueError, match="either 'config'"):
+        build.gate_overrides(gate)
 
 
 def test_catalog_numbers_are_kept():

@@ -13,6 +13,7 @@ from harness import build, host_spans, readers
 from harness.reduce_trace import Event, reduce_trace
 
 DEV0, DEV1, HOST = "/device:TPU:0", "/device:TPU:1", "/host:CPU"
+TOY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "toy")
 NEW_METRICS = (
     "host.sched_ms_per_dispatch", "host.run_prep_ms_per_dispatch",
     "host.dispatch_self_ms_per_dispatch", "host.loop_yield_ms_per_dispatch",
@@ -357,6 +358,45 @@ def test_trace_readers_by_name(monkeypatch, capsys):
     assert host_spans.program_scope_ms(ctx, "paged", 64, "attn") is None
 
 
+def test_a_scope_named_in_a_metrics_file_is_a_scope_of_the_trace(monkeypatch):
+    """The toy benchmark's ``step.decode_mixer_ms.json`` gives its reader the
+    scope ``mixer``: with that file in reach the trace is split by it, the
+    outermost scope still wins, and the six built-in ones read as before."""
+    assert host_spans.known_scopes() == host_spans.SCOPES
+    assert host_spans.scope_of("jit(f)/while/body/mixer/attn/dot:") == "attn"
+    monkeypatch.setattr(build, "DATA_ROOT", TOY)
+    scopes = host_spans.known_scopes()
+    assert scopes == host_spans.SCOPES + ("mixer",)
+    assert host_spans.scope_of("jit(f)/while/body/mixer/attn/dot:",
+                               scopes) == "mixer"
+    planes = _planes()
+    # the second decode step's expert matmul becomes a mixer's scan
+    planes[DEV0]["XLA Ops"][-1] = _op(
+        "fusion.2", 0.081, 0.004,
+        "jit(paged_forward_step)/while/body/closed_call/mixer/scan")
+    before = host_spans.scope_seconds(planes)["jit_paged_forward_step(11)"]
+    assert "mixer" not in before["scopes"]
+    assert before["scopes"][""] == pytest.approx(0.001 + 0.004)
+    after = host_spans.scope_seconds(planes, scopes)
+    decode = after["jit_paged_forward_step(11)"]["scopes"]
+    assert decode["mixer"] == pytest.approx(0.004)
+    assert decode["attn"] == pytest.approx(0.010)
+    assert decode["moe"] == pytest.approx(0.003)
+    assert after["jit_paged_forward_step(22)"] == \
+        host_spans.scope_seconds(_planes())["jit_paged_forward_step(22)"]
+    # through the reader kind, by the metric's name
+    monkeypatch.setattr(host_spans, "load_slice", lambda ctx: {
+        "planes": planes, "idle": None, "scopes": after})
+    red = reduce_trace({k: v for k, v in planes.items() if k != HOST},
+                       {"jit_paged_forward_step(11)": ("paged", 1),
+                        "jit_paged_forward_step(22)": ("paged", 16)})
+    ctx = {"trace": red, "warm_widths": [1, 16]}
+    assert readers.read_metric("step.decode_mixer_ms", ctx) == \
+        pytest.approx(2.0)
+    assert readers.read_metric("step.decode_attn_ms", ctx) == \
+        pytest.approx(5.0)
+
+
 def test_every_new_metric_is_declared_as_its_file_says():
     with open(os.path.join(build.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
@@ -367,7 +407,6 @@ def test_every_new_metric_is_declared_as_its_file_says():
         spec = build.load_json("layer_metrics", name + ".json")
         for key in ("unit", "better", "source", "layer", "moves"):
             assert declared[name][key] == spec[key], (name, key)
-        assert declared[name].get("workloads") == spec.get("workloads")
         has_code = os.path.exists(os.path.join(
             build.BENCH_DIR, "layer_metrics", name + ".py"))
         assert has_code == (spec["reader"]["kind"] == "python"), name
@@ -377,7 +416,6 @@ def test_every_new_metric_is_declared_as_its_file_says():
 # one toy run: the metrics the CPU can read appear on the last line
 # ---------------------------------------------------------------------------
 
-TOY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "toy")
 FAKE = {"platform": "tpu", "kind": "TPU v5 lite", "count": 4}
 
 
