@@ -1140,7 +1140,8 @@ class PagedCausalLMApplication(CausalLMApplication):
         from ..modules.block_kv_cache import BlockKVCacheManager, BlockKVSpec
         cfg = self.tpu_config
         bspec = BlockKVSpec(
-            num_layers=self.spec.num_layers,
+            # SSM-only layers carry no KV pages (recurrent/hybrid stacks)
+            num_layers=self.spec.num_attn_layers,
             num_blocks=cfg.pa_num_blocks + 1,    # +1: reserved null block 0
             block_size=cfg.pa_block_size,
             num_kv_heads=self.spec.gqa.num_kv_heads,
@@ -1154,6 +1155,14 @@ class PagedCausalLMApplication(CausalLMApplication):
         # stale donated alias after the first step)
         self.cache = self.kv_mgr.cache
         self.kv_mgr.cache = None
+        if self.spec.ssm is not None:
+            # the second per-sequence cache: conv tails + fp32 SSM state,
+            # one SLOT per batch row beside the KV pool, in the same
+            # donated dict so the step graphs update both in place
+            from ..modules.ssm import init_ssm_state
+            self.cache.update(init_ssm_state(
+                self.spec.ssm, self.spec.num_ssm_layers, self.state_slots,
+                self.spec.dtype, self.mesh))
         # static block-table width for the jitted graphs
         self.max_blocks = bspec.blocks_for(cfg.seq_len)
         # 2-D prefix x prefill bucketing: per-call block-table widths
@@ -1167,6 +1176,13 @@ class PagedCausalLMApplication(CausalLMApplication):
         fn = _named_partial(model_base.paged_forward_step, self.spec,
                             self.tpu_config)
         return jax.jit(fn, donate_argnums=(1,))
+
+    @property
+    def state_slots(self) -> int:
+        """Per-sequence recurrent-state slots beside the KV pool: one per
+        batch row for a recurrent/hybrid stack (a full-batch step's rows
+        ARE the slots), 0 for an attention stack."""
+        return self.tpu_config.batch_size if self.spec.ssm is not None else 0
 
     @property
     def prefill_row_buckets(self) -> List[int]:
@@ -1396,7 +1412,11 @@ class PagedCausalLMApplication(CausalLMApplication):
 
     def _run_paged(self, input_ids, position_ids, slot_mapping, block_table,
                    last_idx, sampling_params=None, row_seeds=None,
-                   adapter_ids=None):
+                   adapter_ids=None, state_slots=None):
+        """``state_slots`` (rows,): recurrent stacks only, the state slot
+        of each row of a dispatch with FEWER rows than slots (the one-row
+        chunk); a full-batch dispatch lays its rows out in slot order and
+        passes none (``model_base.run_layers_ssm``)."""
         with self._run_span("paged", input_ids.shape[0]):
             fn = self.get_compiled("paged_forward")
             aids = self._lora_adapter_ids(adapter_ids)
@@ -1412,6 +1432,8 @@ class PagedCausalLMApplication(CausalLMApplication):
             kw = {"row_seeds": seeds} if seeds is not None else {}
             if aids is not None:
                 kw["adapter_ids"] = aids
+            if state_slots is not None:
+                kw["state_slots"] = jnp.asarray(state_slots, jnp.int32)
             with self._mesh_ctx():
                 out = fn(self.params, self.cache, jnp.asarray(input_ids),
                          jnp.asarray(position_ids),
@@ -1420,6 +1442,15 @@ class PagedCausalLMApplication(CausalLMApplication):
                          sampling_params, self._next_rng(), **kw)
             self.cache = out["cache"]
         return out
+
+    def _dummy_state_slots(self, rows: int):
+        """The ``state_slots`` of a dummy (warm-up) dispatch of ``rows``
+        rows: slot 0 for each row of a dispatch narrower than the slots
+        (every token dead, so the slot is read and written back as it
+        was), None otherwise."""
+        if self.state_slots and rows != self.state_slots:
+            return np.zeros((rows,), np.int32)
+        return None
 
     def warmup(self):
         """AOT-compile the paged graph at each shape it will run: the T=1
@@ -1449,7 +1480,8 @@ class PagedCausalLMApplication(CausalLMApplication):
                                     np.zeros((rows, w), np.int32),
                                     np.full((rows, w), -1, np.int32),
                                     np.zeros((rows, tw), np.int32),  # null block
-                                    np.zeros((rows,), np.int32))
+                                    np.zeros((rows,), np.int32),
+                                    state_slots=self._dummy_state_slots(rows))
             if chunk > 1:
                 self._run_paged_loop(np.zeros((b,), np.int32),
                                      np.zeros((b,), np.int32),

@@ -958,6 +958,26 @@ def _dense_mlp(spec: DecoderSpec, x_in, layer_w, adapter_ids, phase: str):
     return y
 
 
+@lru_cache(maxsize=None)
+def _paged_score_budget() -> int:
+    """Bytes of float32 scores (rows x query heads of a shard x width x table
+    tokens x 4) one gathered paged-prefill attention may hold at once: a
+    twelfth of a device's memory. The scores and their exponentials are live
+    together, so the attention's temps stay within a sixth of the device
+    beside weights and pool. A backend that reports no limit (the CPU, an AOT
+    compile for a chip that is not there) is taken as a 16 GiB chip."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", 16 * 2 ** 30)) // 12
+
+
+def _score_row_group(rows: int, row_bytes: int) -> int:
+    """Rows of a gathered paged-prefill attention to attend at once: the
+    largest divisor of ``rows`` whose float32 scores (``row_bytes`` a row)
+    fit :func:`_paged_score_budget`; one row always goes."""
+    fit = max(1, _paged_score_budget() // row_bytes)
+    return max(d for d in range(1, min(rows, fit) + 1) if rows % d == 0)
+
+
 @jax.named_scope("attn")
 def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                 is_local, seq_ids, positions, phase: str, *,
@@ -1116,16 +1136,38 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                              "xla" if declined else kernel_mode.kernel_path(),
                              declined)
         if not use_pkernel:
-            k_all = kv.dequantize_kv(
-                bkv.gather_block_kv(bkv.read_layer(k_full, li), block_table),
-                dtype, spec.kv_scale)
-            v_all = kv.dequantize_kv(
-                bkv.gather_block_kv(bkv.read_layer(v_full, li), block_table),
-                dtype, spec.kv_scale)
-            attn_out = attn_ops.mha(q, k_all, v_all, mask, spec.scale,
+            k_layer = bkv.read_layer(k_full, li)
+            v_layer = bkv.read_layer(v_full, li)
+
+            def gathered_mha(q_, bt_, mask_):
+                k_all = kv.dequantize_kv(bkv.gather_block_kv(k_layer, bt_),
+                                         dtype, spec.kv_scale)
+                v_all = kv.dequantize_kv(bkv.gather_block_kv(v_layer, bt_),
+                                         dtype, spec.kv_scale)
+                return attn_ops.mha(q_, k_all, v_all, mask_, spec.scale,
                                     logits_soft_cap=spec.attn_soft_cap,
                                     sink=sink,
                                     alibi=_alibi_for(k_all.shape[1]))
+
+            # the float32 scores of a chunk are rows x a shard's heads x
+            # width x table tokens: where they outgrow the budget (a
+            # full-batch chunk of a wide batch with many heads) the rows go
+            # through in groups, one after another, so the temps stay a
+            # group's worth
+            b_ = q.shape[0]
+            group = _score_row_group(
+                b_, 4 * (g.num_q_heads // g.tp) * q.shape[1]
+                * block_table.shape[1] * k_full.shape[2]
+            ) if not spec.alibi else b_
+            if group == b_:
+                attn_out = gathered_mha(q, block_table, mask)
+            else:
+                def split(x):
+                    return x.reshape((b_ // group, group) + x.shape[1:])
+                attn_out = jax.lax.map(
+                    lambda xs: gathered_mha(*xs),
+                    (split(q), split(block_table), split(mask)))
+                attn_out = attn_out.reshape((b_,) + attn_out.shape[2:])
     elif phase == "prefill":
         # flash kernel requirements beyond supports(): per-row positions must
         # be arange (the kernel rebuilds causality from array indices — an
@@ -1323,6 +1365,64 @@ def _deepstack_add(hidden, deepstack, deepstack_mask):
     return hidden + jnp.where(deepstack_mask[..., None], img, 0)
 
 
+#: what a recurrent/hybrid stack (``spec.ssm``) cannot do, by mechanism —
+#: the ONE table the model code (``run_layers``, ``run_layers_ssm``, the
+#: verify / ragged / multi-token steps), ``spec_from_config`` and the serving
+#: adapter refuse from, through :func:`refuse_recurrent`.
+RECURRENT_UNSUPPORTED = {
+    "prefix caching": "reusing a cached prefix needs a snapshot of the "
+                      "recurrent state at the block boundary; only KV is "
+                      "kept per block",
+    "speculation": "verifying a draft window needs a state step over "
+                   "several tokens that can be rolled back to the accepted "
+                   "one",
+    "ragged dispatch": "a row mixing chunk and decode widths needs a "
+                       "multi-token state step per row",
+    "multi-token decode": "a recurrent stack decodes one token a step",
+    "fused decode loop": "the in-graph slot advance treats every row as "
+                         "live; state slots need their dead rows masked",
+    "flash decoding": "the KV-sequence shard has no recurrent counterpart",
+    "continuous batching": "the contiguous cache addresses state rows by "
+                           "batch row, not by seq_id; serve through the "
+                           "paged path, which has per-sequence state slots",
+    "sequence parallelism": "the scan over time runs on one shard",
+    "windowed context encoding": "only the paged path continues a chunk "
+                                 "from the carried state",
+    "tensor capture/replacement": "the recurrent walk has no tap points",
+    "deepstack": "the recurrent walk adds no per-layer visual features",
+    "chunked side-buffer decode": "the recurrent walk writes KV in place",
+    "sandwich norm": "the recurrent walk is the plain pre-norm residual "
+                     "block",
+    "paged parallel hybrid": "a layer running attention NEXT TO its mixer "
+                             "(ssm_parallel) has not been walked on the "
+                             "paged path",
+    "paged non-mamba2 state": "only the mamba2 mixer continues from a "
+                              "carried state and conv tail (rglru and "
+                              "shortconv prefill from zero)",
+    "host KV spill / handoff": "a spilled or handed-off block carries KV "
+                               "only, not the state that goes with it",
+}
+
+
+def recurrent_refusal(asked) -> Optional[str]:
+    """The sentence that names every entry of ``asked`` (keys of
+    :data:`RECURRENT_UNSUPPORTED` a caller found switched on; falsy entries
+    are skipped) with its reason, or None where nothing was asked."""
+    asked = [a for a in asked if a]
+    if not asked:
+        return None
+    return ("a recurrent/hybrid (SSM) stack does not support: "
+            + "; ".join(f"{a} ({RECURRENT_UNSUPPORTED[a]})" for a in asked))
+
+
+def refuse_recurrent(asked) -> None:
+    """Raise NotImplementedError with :func:`recurrent_refusal`'s sentence;
+    nothing asked, nothing raised."""
+    why = recurrent_refusal(asked)
+    if why:
+        raise NotImplementedError(why)
+
+
 def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
                seq_ids, positions, phase: str,
                identity_seq_ids: bool = False,
@@ -1330,7 +1430,7 @@ def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
                slot_mapping=None, block_table=None,
                adapter_ids=None, replacements=None, kv_view: int = None,
                deepstack=None, deepstack_mask=None, prefill_lens=None,
-               side=None, chunk_idx=None):
+               side=None, chunk_idx=None, state_slots=None):
     """lax.scan over the stacked layer weights.
 
     Replaces the reference's per-layer Python loop
@@ -1344,16 +1444,16 @@ def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
     spec.capture names per-layer points (then each is stacked (L, ...)).
     """
     if spec.ssm is not None:
-        if any(x is not None for x in (slot_mapping, block_table,
-                                       replacements, deepstack, side)):
-            raise NotImplementedError(
-                "recurrent/hybrid stacks support the contiguous prefill + "
-                "decode paths only (no paged layout, tensor replacement, "
-                "deepstack, or chunked side-buffer decode)")
+        refuse_recurrent([
+            replacements is not None and "tensor capture/replacement",
+            deepstack is not None and "deepstack",
+            side is not None and "chunked side-buffer decode"])
         return run_layers_ssm(
             spec, params, cache, hidden, ai, seq_ids, positions, phase,
             identity_seq_ids=identity_seq_ids, adapter_ids=adapter_ids,
-            kv_view=kv_view, prefill_lens=prefill_lens)
+            kv_view=kv_view, prefill_lens=prefill_lens,
+            slot_mapping=slot_mapping, block_table=block_table,
+            state_slots=state_slots)
     is_local = jnp.asarray(spec.layer_pattern if spec.layer_pattern is not None
                            else (False,) * spec.num_layers)
     rep = replacements or {}
@@ -1525,41 +1625,87 @@ def run_layer_slice(spec: DecoderSpec, layer_params, kf, vf, hidden, ai, *,
     return hidden, kf, vf, caps
 
 
+def _state_rows(arr, li: int, slots):
+    """Rows of layer ``li`` of a stacked state array (Ls, slots, ...):
+    all of them in slot order (``slots`` None: the rows of the step ARE
+    the slots, nothing is gathered), else the rows ``slots`` (R,) names —
+    one slot is a dynamic slice, more a gather."""
+    if slots is None:
+        return arr[li]
+    if slots.shape[0] == 1:
+        return jax.lax.dynamic_slice(
+            arr, (li, slots[0]) + (0,) * (arr.ndim - 2),
+            (1, 1) + arr.shape[2:])[0]
+    return arr[li][slots]
+
+
+def _state_put(arr, li: int, slots, val):
+    """Write :func:`_state_rows`' rows back, in place on the donated
+    buffer."""
+    val = val.astype(arr.dtype)
+    if slots is None:
+        return arr.at[li].set(val)
+    if slots.shape[0] == 1:
+        return jax.lax.dynamic_update_slice(
+            arr, val[None], (li, slots[0]) + (0,) * (arr.ndim - 2))
+    return arr.at[li, slots].set(val)
+
+
 def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
                    seq_ids, positions, phase: str, *,
                    identity_seq_ids=False, adapter_ids=None, kv_view=None,
-                   prefill_lens=None):
+                   prefill_lens=None, slot_mapping=None, block_table=None,
+                   state_slots=None):
     """Unrolled layer walk for recurrent/hybrid stacks (reference:
     contrib Falcon-H1 FalconH1DecoderLayer — parallel mamba+attention;
     contrib recurrentgemma RecurrentGemmaDecoderLayer — rec/rec/attn
-    pattern). The KV cache covers only the attention-bearing layers; the
-    recurrent state rides the same cache dict as stacked conv tails +
-    SSM states, updated with static per-layer indices.
+    pattern; HF GraniteMoeHybridDecoderLayer — mamba2 or attention by
+    ``layer_types``). The KV cache covers only the attention-bearing
+    layers; the recurrent state rides the same cache dict as stacked conv
+    tails + SSM states, updated with static per-layer indices.
 
     Every layer shares the sequential residual shape: pre-norm temporal
-    block(s) → residual add → pre-norm MLP → residual add; the temporal
-    block is attention, the SSM, or (parallel hybrid) their sum.
+    block(s) → residual add → pre-norm MLP → residual add (each add scaled
+    by ``residual_multiplier``); the temporal block is attention, the SSM,
+    or (parallel hybrid) their sum.
+
+    Phase "paged" (the serving step graphs): attention layers write and
+    read through ``slot_mapping`` / ``block_table`` like any paged stack;
+    a mixer continues from its rows of the state — the second
+    per-sequence cache, (Ls, slots, ...) beside the KV pool. With
+    ``state_slots`` None the rows of the step ARE the slots (row i is slot
+    i: the full-batch decode step and the full-batch chunk pack; a row
+    whose ``slot_mapping`` is all negative is dead and leaves its slot's
+    tail and state as they were), so nothing is gathered and the state is
+    updated in place; with ``state_slots`` (R,) the R rows slice their
+    slots in and out (the one-row chunk program).
     """
     s = spec.ssm
     pat = spec.resolved_ssm_pattern
-    if phase not in ("prefill", "decode"):
-        raise NotImplementedError(
-            f"recurrent stacks do not support the {phase!r} phase")
-    # the SSM residual walk below hard-codes the plain pre-norm shape; a
-    # hybrid family that also sets these spec knobs would run silently wrong
-    if spec.residual_multiplier != 1.0 or spec.sandwich_norm:
-        raise NotImplementedError(
-            "run_layers_ssm implements the plain pre-norm residual shape "
-            f"only (got residual_multiplier={spec.residual_multiplier}, "
-            f"sandwich_norm={spec.sandwich_norm}); teach the SSM layer walk "
-            "these knobs before combining them with a recurrent stack")
-    if phase == "decode" and hidden.shape[1] != 1:
-        raise NotImplementedError(
-            "recurrent stacks decode one token per step (no speculation "
-            "windows / multi-token verify)")
+    paged = phase == "paged"
+    refuse_recurrent([
+        phase not in ("prefill", "decode", "paged") and "multi-token decode",
+        phase == "decode" and hidden.shape[1] != 1 and "multi-token decode",
+        spec.sandwich_norm and "sandwich norm",
+        paged and spec.ssm_parallel and "paged parallel hybrid",
+        paged and s.kind != "mamba2" and "paged non-mamba2 state"])
     kf, vf = cache["k"], cache["v"]
     state_keys = [k for k in ("conv_x", "conv_bc", "ssm") if k in cache]
     new_state = {k: cache[k] for k in state_keys}
+    valid = None
+    if paged:
+        valid = slot_mapping >= 0
+        if state_slots is None and hidden.shape[0] != new_state["ssm"].shape[1]:
+            raise ValueError(
+                f"a paged step of {hidden.shape[0]} rows over "
+                f"{new_state['ssm'].shape[1]} state slots needs state_slots "
+                "(one slot index a row); without it row i is slot i")
+    rm = spec.residual_multiplier
+
+    def add(res, branch):
+        branch = _shard(branch, AXIS_DP, None, None)
+        return res + (branch if rm == 1.0 else rm * branch)
+
     not_local = jnp.asarray(False)
     attn_i = 0
     ssm_i = 0
@@ -1581,26 +1727,31 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
                 spec, h, lw, kf, vf, attn_i, ai, not_local, seq_ids,
                 positions, phase, identity_seq_ids=identity_seq_ids,
                 arange_positions=(phase == "prefill"),
+                slot_mapping=slot_mapping, block_table=block_table,
                 adapter_ids=adapter_ids, kv_view=kv_view,
                 prefill_lens=prefill_lens)
             t_out = a_out
             attn_i += 1
         if has_ssm:
-            st = {k: new_state[k][ssm_i] for k in state_keys}
-            s_out, st_new = ssm_mod.ssm_block(
-                s, lw, h, st, phase=phase, seq_lens=prefill_lens,
-                positions=positions)
-            for k2, v2 in st_new.items():
-                new_state[k2] = new_state[k2].at[ssm_i].set(
-                    v2.astype(new_state[k2].dtype))
+            # ONE profiler scope around the whole temporal block
+            # (projections, conv, state update or chunked scan, gated norm,
+            # out_proj, and the state rows read and written)
+            with jax.named_scope("mixer"):
+                st = {k: _state_rows(new_state[k], ssm_i, state_slots)
+                      for k in state_keys}
+                s_out, st_new = ssm_mod.ssm_block(
+                    s, lw, h, st, phase=phase, seq_lens=prefill_lens,
+                    positions=positions, valid=valid)
+                for k2, v2 in st_new.items():
+                    new_state[k2] = _state_put(new_state[k2], ssm_i,
+                                               state_slots, v2)
             t_out = s_out if t_out is None else t_out + s_out
             ssm_i += 1
-        hidden = hidden + _shard(t_out, AXIS_DP, None, None)
+        hidden = add(hidden, t_out)
         h2 = _norm(spec, hidden, lw["post_norm"],
                    lw.get("post_norm_b") if spec.norm_bias else None)
-        hidden = hidden + _shard(
-            _mlp_block(spec, h2, lw, "dense", adapter_ids),
-            AXIS_DP, None, None)
+        hidden = add(hidden, _mlp_block(spec, h2, lw, "dense", adapter_ids,
+                                        phase=phase))
     return hidden, {"k": kf, "v": vf, **new_state}, {}
 
 
@@ -1886,10 +2037,7 @@ def token_generation_multi(spec: DecoderSpec, tpu_cfg: TpuConfig, params,
         raise NotImplementedError(
             "multi-token decode over the mixed per-layer cache is not "
             "supported; disable speculation or set mixed_kv=False")
-    if spec.ssm is not None:
-        raise NotImplementedError(
-            "multi-token decode (speculation verify / windowed CTE) is not "
-            "supported on recurrent/hybrid stacks")
+    refuse_recurrent([spec.ssm is not None and "multi-token decode"])
     cache_len = kv.cache_len_of(cache)
     ai = attn_inputs(spec, position_ids, lambda w, c=0: attn_ops.decode_mask(
         position_ids, cache_len, window=w, chunk=c))
@@ -1915,7 +2063,7 @@ def _coupled_mode(tpu_cfg: TpuConfig, row_seeds) -> bool:
 def paged_forward_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
                        input_ids, position_ids, slot_mapping, block_table,
                        last_idx, sampling_params, rng, row_seeds=None,
-                       adapter_ids=None):
+                       adapter_ids=None, state_slots=None):
     """Unified paged-KV step graph (reference:
     modules/kvcache/block_kv_cache_manager.py + the prefix-caching prefill of
     attention_base.py:772-914). One graph covers:
@@ -1939,6 +2087,9 @@ def paged_forward_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     params inside the one dispatch; slot 0 is the pinned zero adapter, so
     base-model rows stay bit-identical. Absent (None) the traced graph is
     byte-identical to a LoRA-free build.
+    state_slots (B,) optional, recurrent stacks only: the state slot of
+    each row where the rows are fewer than the slots (the one-row chunk
+    program); absent, row i is slot i (``run_layers_ssm``).
     """
     kv_len = block_table.shape[1] * cache["k"].shape[2]
     ai = attn_inputs(spec, position_ids, lambda w, c=0: attn_ops.decode_mask(
@@ -1947,7 +2098,7 @@ def paged_forward_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     hidden, new_cache, _ = run_layers(
         spec, params, cache, hidden, ai, None, position_ids,
         "paged", slot_mapping=slot_mapping, block_table=block_table,
-        adapter_ids=adapter_ids)
+        adapter_ids=adapter_ids, state_slots=state_slots)
     idx = last_idx[:, None, None].astype(jnp.int32)
     last_h = jnp.take_along_axis(hidden, idx, axis=1)
     logits = _lm_head(spec, params, last_h)[:, 0, :]
@@ -2100,6 +2251,7 @@ def paged_decode_loop(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
 
     first_tokens (B,); position_ids (B,); block_table (B, max_blocks).
     Returns tokens (B, num_steps) + cache."""
+    refuse_recurrent([spec.ssm is not None and "fused decode loop"])
     bs = cache["k"].shape[2]                  # paged (L, N, Bs, H, D)
     b = first_tokens.shape[0]
     rows = jnp.arange(b)
@@ -2208,9 +2360,10 @@ def paged_spec_verify(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     num_emitted (B,), cache (+ hidden (B, W, H) when ``want_hidden`` —
     Medusa/EAGLE proposers feed on the verified features).
     """
-    if spec.mixed_kv or spec.ssm is not None:
+    refuse_recurrent([spec.ssm is not None and "speculation"])
+    if spec.mixed_kv:
         raise NotImplementedError(
-            "speculative verify over mixed per-layer / recurrent caches is "
+            "speculative verify over mixed per-layer caches is "
             "not supported; disable speculation for this model")
     kv_len = block_table.shape[1] * cache["k"].shape[2]
     ai = attn_inputs(spec, position_ids, lambda w, c=0: attn_ops.decode_mask(
@@ -2300,9 +2453,10 @@ def paged_ragged_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     num_emitted (B,), cache (+ hidden (B, W, H) when ``want_hidden`` —
     Medusa/EAGLE proposers feed on the verified features).
     """
-    if spec.mixed_kv or spec.ssm is not None:
+    refuse_recurrent([spec.ssm is not None and "ragged dispatch"])
+    if spec.mixed_kv:
         raise NotImplementedError(
-            "the ragged unified dispatch over mixed per-layer / recurrent "
+            "the ragged unified dispatch over mixed per-layer "
             "caches is not supported; disable ragged mode for this model")
     kv_len = block_table.shape[1] * cache["k"].shape[2]
     ai = attn_inputs(spec, position_ids, lambda w, c=0: attn_ops.decode_mask(
@@ -2519,25 +2673,22 @@ def spec_from_config(config: InferenceConfig, tp_degree: Optional[int] = None,
             kw["moe"] = replace(kw["moe"], tkg_experts_local=True)
     if kw.get("ssm") is not None:
         sc = tcfg.speculation_config
-        bad = []
-        if tcfg.is_block_kv_layout:
-            bad.append("paged KV layout")
-        if tcfg.flash_decoding_enabled:
-            bad.append("flash decoding")
-        if tcfg.is_continuous_batching:
-            bad.append("continuous batching")
-        if tcfg.sequence_parallel_enabled:
-            bad.append("sequence parallelism")
-        if tcfg.windowed_context_encoding:
-            bad.append("windowed context encoding")
-        if sc and (sc.speculation_length or sc.medusa_speculation_length):
-            bad.append("speculation")
-        if tcfg.tensor_capture_config or tcfg.tensor_replacement_config:
-            bad.append("tensor capture/replacement")
-        if bad:
-            raise NotImplementedError(
-                "recurrent/hybrid (SSM) stacks do not yet support: "
-                + ", ".join(bad))
+        paged = tcfg.is_block_kv_layout
+        refuse_recurrent([
+            tcfg.is_prefix_caching and "prefix caching",
+            tcfg.flash_decoding_enabled and "flash decoding",
+            tcfg.is_continuous_batching and not paged
+            and "continuous batching",
+            tcfg.sequence_parallel_enabled and "sequence parallelism",
+            tcfg.windowed_context_encoding and "windowed context encoding",
+            sc and (sc.speculation_length or sc.medusa_speculation_length)
+            and "speculation",
+            (tcfg.tensor_capture_config or tcfg.tensor_replacement_config)
+            and "tensor capture/replacement",
+            paged and tcfg.decode_chunk_tokens > 1 and "fused decode loop",
+            paged and kw.get("ssm_parallel") and "paged parallel hybrid",
+            paged and kw["ssm"].kind != "mamba2"
+            and "paged non-mamba2 state"])
         # the recurrent state replaces long-range KV; keep the attention
         # cache simple (full rows, no rolling/mixed layouts)
         kw.setdefault("rolling_window", False)

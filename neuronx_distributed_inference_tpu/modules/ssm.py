@@ -186,30 +186,40 @@ def ssm_state_pspecs(s: SSMSpec) -> Dict[str, P]:
 # Shared pieces
 # ---------------------------------------------------------------------------
 
-def _causal_conv_prefill(x, w, b):
+def _with_history(x, tail, K1):
+    """(B, K-1 + T, C): the K-1 inputs that came before position 0 of ``x``
+    (B, T, C) in front of it — ``tail`` (B, C, K-1), a chunk continuing a
+    sequence, or zeros (None: a sequence's start)."""
+    if tail is None:
+        return jnp.pad(x, ((0, 0), (K1, 0), (0, 0)))
+    return jnp.concatenate([tail.transpose(0, 2, 1).astype(x.dtype), x],
+                           axis=1)
+
+
+def _causal_conv_prefill(x, w, b, tail=None):
     """Depthwise causal conv over (B, T, C) with kernel (C, K): K shifted
     adds — K is 4; XLA fuses this into a handful of vector ops (vs a conv
-    primitive whose tiny channel-depthwise form lowers poorly)."""
+    primitive whose tiny channel-depthwise form lowers poorly). ``tail``:
+    see :func:`_with_history`."""
     K = w.shape[-1]
+    T = x.shape[1]
+    hist = _with_history(x, tail, K - 1)
     out = x * w[:, K - 1]
     for j in range(K - 1):
-        shift = K - 1 - j
-        shifted = jnp.pad(x, ((0, 0), (shift, 0), (0, 0)))[:, :x.shape[1]]
-        out = out + shifted * w[:, j]
+        out = out + hist[:, j:j + T] * w[:, j]
     if b is not None:
         out = out + b
     return out
 
 
-def _conv_tail(x, seq_lens, K1):
-    """Last K-1 columns of (B, T, C) ending at seq_len per row (zeros where
-    the window reaches before position 0) → (B, C, K-1)."""
-    B, T, C = x.shape
-    idx = seq_lens[:, None] - K1 + jnp.arange(K1)[None, :]       # (B, K1)
-    take = jnp.clip(idx, 0, T - 1)
-    tail = jnp.take_along_axis(x, take[:, :, None], axis=1)      # (B, K1, C)
-    tail = jnp.where((idx >= 0)[:, :, None], tail, 0)
-    return tail.transpose(0, 2, 1)
+def _conv_tail(x, n_valid, K1, tail=None):
+    """The K-1 inputs that end at ``n_valid`` per row of (B, T, C), reaching
+    back into ``tail`` where the window starts before position 0 →
+    (B, C, K-1). ``n_valid`` 0 hands ``tail`` back."""
+    idx = n_valid[:, None] + jnp.arange(K1)[None, :]              # (B, K1)
+    out = jnp.take_along_axis(_with_history(x, tail, K1), idx[:, :, None],
+                              axis=1)                             # (B, K1, C)
+    return out.transpose(0, 2, 1)
 
 
 def _conv_step(tail, cur, w, b):
@@ -239,114 +249,115 @@ def _segsum(a_log):
 # ---------------------------------------------------------------------------
 
 def mamba2_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
-                 seq_lens=None, positions=None):
+                 seq_lens=None, positions=None, valid=None):
     """One mamba2 block over already-normed input x (B, T, H).
 
     lw: this layer's weight dict (the ssm_* entries of the stacked layer
     params, indexed at this layer). state: {"conv_x","conv_bc","ssm"} THIS
-    layer's state entries. Returns (y (B,T,H), new_state).
+    layer's state entries, one row per row of ``x``. Returns
+    (y (B,T,H), new_state).
 
-    Prefill semantics track the reference's SSD form
-    (modeling_falcon_h1.py torch_forward non-cached branch) with one
-    divergence that is a fix, not a drift: positions ≥ seq_len get dt = 0
-    (decay 1, input contribution 0), so a right-padded prefill leaves the
-    carried state exactly as an unpadded run would — the torch reference
-    only supports left-padding for this reason.
+    The block CONTINUES from ``state``: the carried SSM state and conv tail
+    are what came before position ``positions[:, 0]`` of each row, so a
+    prompt walked in chunks (the paged serving path) gives what one pass
+    gives. A row whose first position is 0 starts from zeros instead — a
+    slot is reset by the positions it is fed, with no program of its own,
+    and the contiguous prefill (positions always from 0) starts fresh as
+    it always did.
+
+    ``valid`` (B, T) marks the real tokens of each row, a prefix of it
+    (default: ``positions < seq_lens`` in prefill, everything in decode).
+    Positions past it get dt = 0 (decay 1, input contribution 0) and leave
+    the conv tail where it was, so a right-padded chunk — or a row with no
+    real token at all, a dead row of a decode step — leaves the carried
+    state exactly as an unpadded run would. The torch reference
+    (modeling_falcon_h1.py torch_forward) only supports left-padding for
+    this reason.
+
+    T == 1 runs the O(1) recurrence step, T > 1 the chunked SSD form.
     """
     B, T, H = x.shape
     f32 = jnp.float32
-    gn = s.n_groups * s.d_state
-    nh, hd, N = s.num_heads, s.head_dim, s.d_state
+    hi = jax.lax.Precision.HIGHEST
+    g, N = s.n_groups, s.d_state
+    gn = g * N
+    nh, hd = s.num_heads, s.head_dim
+    r = nh // g
+    K1 = s.d_conv - 1
+    if valid is None:
+        valid = ((positions < seq_lens[:, None]) if phase == "prefill"
+                 else jnp.ones((B, T), bool))
+    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+    keep = ~(valid[:, 0] & (positions[:, 0] == 0))                # (B,)
+    tail_x = jnp.where(keep[:, None, None], state["conv_x"], 0)
+    tail_bc = jnp.where(keep[:, None, None], state["conv_bc"], 0)
+    st0 = jnp.where(keep[:, None, None, None], state["ssm"].astype(f32), 0.0)
 
     gate = x @ lw["ssm_in_gate"]
-    xs = x @ lw["ssm_in_x"]
-    bc = x @ lw["ssm_in_bc"]
+    xs = jnp.where(valid[..., None], x @ lw["ssm_in_x"], 0)
+    bc = jnp.where(valid[..., None], x @ lw["ssm_in_bc"], 0)
     dt_raw = (x @ lw["ssm_in_dt"]).astype(f32)
 
-    if phase == "prefill":
-        valid = (positions < seq_lens[:, None])                   # (B,T)
-        xs = jnp.where(valid[..., None], xs, 0)
-        bc = jnp.where(valid[..., None], bc, 0)
-        xs_c = jax.nn.silu(_causal_conv_prefill(
-            xs, lw["ssm_conv_x"], lw.get("ssm_conv_x_b")))
-        bc_c = jax.nn.silu(_causal_conv_prefill(
-            bc, lw["ssm_conv_bc"], lw.get("ssm_conv_bc_b")))
-        xs_c = jnp.where(valid[..., None], xs_c, 0)
-        bc_c = jnp.where(valid[..., None], bc_c, 0)
-        new_state = {"conv_x": _conv_tail(xs, seq_lens, s.d_conv - 1),
-                     "conv_bc": _conv_tail(bc, seq_lens, s.d_conv - 1)}
-    else:
-        cx, ncx = _conv_step(state["conv_x"], xs[:, 0],
-                             lw["ssm_conv_x"], lw.get("ssm_conv_x_b"))
-        cbc, ncbc = _conv_step(state["conv_bc"], bc[:, 0],
-                               lw["ssm_conv_bc"], lw.get("ssm_conv_bc_b"))
-        xs_c = jax.nn.silu(cx)[:, None]
-        bc_c = jax.nn.silu(cbc)[:, None]
-        new_state = {"conv_x": ncx, "conv_bc": ncbc}
+    xs_c = jax.nn.silu(_causal_conv_prefill(
+        xs, lw["ssm_conv_x"], lw.get("ssm_conv_x_b"), tail_x))
+    bc_c = jax.nn.silu(_causal_conv_prefill(
+        bc, lw["ssm_conv_bc"], lw.get("ssm_conv_bc_b"), tail_bc))
+    new_state = {"conv_x": _conv_tail(xs, n_valid, K1, tail_x),
+                 "conv_bc": _conv_tail(bc, n_valid, K1, tail_bc)}
 
     dt = jax.nn.softplus(dt_raw + lw["ssm_dt_bias"].astype(f32))
     dt = jnp.clip(dt, s.dt_limit[0], min(s.dt_limit[1], 1e6))
-    if phase == "prefill":
-        dt = jnp.where(valid[..., None], dt, 0.0)
+    dt = jnp.where(valid[..., None], dt, 0.0).reshape(B, T, g, r)
 
-    A = -jnp.exp(lw["ssm_A_log"].astype(f32))                     # (nh,)
-    x_h = xs_c.reshape(B, T, nh, hd).astype(f32)
-    Bm = bc_c[..., :gn].reshape(B, T, s.n_groups, N).astype(f32)
-    Cm = bc_c[..., gn:].reshape(B, T, s.n_groups, N).astype(f32)
-    rep = nh // s.n_groups
-    Bm = jnp.repeat(Bm, rep, axis=2)                              # (B,T,nh,N)
-    Cm = jnp.repeat(Cm, rep, axis=2)
-    dA_log = dt * A[None, None, :]                                # (B,T,nh)
-    D_res = lw["ssm_D"].astype(f32)[None, None, :, None] * x_h
+    A = -jnp.exp(lw["ssm_A_log"].astype(f32)).reshape(g, r)
+    x_h = xs_c.reshape(B, T, g, r, hd).astype(f32)
+    Bm = bc_c[..., :gn].reshape(B, T, g, N).astype(f32)
+    Cm = bc_c[..., gn:].reshape(B, T, g, N).astype(f32)
+    dA_log = dt * A                                               # (B,T,g,r)
+    D_res = lw["ssm_D"].astype(f32).reshape(g, r)[..., None] * x_h
     x_dt = x_h * dt[..., None]
+    st0 = st0.reshape(B, g, r, hd, N)
 
-    if phase == "decode":
-        ssm = state["ssm"]                                        # (B,nh,hd,N)
-        dA = jnp.exp(dA_log[:, 0])                                # (B,nh)
-        dBx = x_dt[:, 0, :, :, None] * Bm[:, 0, :, None, :]       # (B,nh,hd,N)
-        ssm = ssm * dA[..., None, None] + dBx
-        y = jnp.einsum("bhdn,bhn->bhd", ssm, Cm[:, 0]) + D_res[:, 0]
+    if T == 1:
+        dBx = x_dt[:, 0, ..., None] * Bm[:, 0, :, None, None, :]
+        st_f = st0 * jnp.exp(dA_log[:, 0])[..., None, None] + dBx
+        y = jnp.einsum("bgrdn,bgn->bgrd", st_f, Cm[:, 0],
+                       precision=hi) + D_res[:, 0]
         y = y.reshape(B, 1, s.d_inner)
-        new_state["ssm"] = ssm
     else:
         cs = min(s.chunk_size, T)
         pad = (cs - T % cs) % cs
 
-        def padc(a):
-            return jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        def chunks(a):
+            a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            a = a.reshape((B, (T + pad) // cs, cs) + a.shape[2:])
+            return jnp.moveaxis(a, 1, 0)
 
-        nchunk = (T + pad) // cs
-        xc = padc(x_dt).reshape(B, nchunk, cs, nh, hd).transpose(1, 0, 2, 3, 4)
-        Bc = padc(Bm).reshape(B, nchunk, cs, nh, N).transpose(1, 0, 2, 3, 4)
-        Cc = padc(Cm).reshape(B, nchunk, cs, nh, N).transpose(1, 0, 2, 3, 4)
-        ac = padc(dA_log).reshape(B, nchunk, cs, nh).transpose(1, 0, 2, 3)
-
-        def chunk_body(carry, inp):
-            st = carry                                            # (B,nh,hd,N)
+        def chunk_body(st, inp):                       # st (B,g,r,hd,N)
             xk, Bk, Ck, ak = inp
-            acs = jnp.cumsum(ak, axis=1)                          # (B,c,nh)
-            L = jnp.exp(_segsum(ak))                              # (B,nh,c,c)
-            G = jnp.einsum("bthn,bshn->bhts", Ck, Bk)
-            Yd = jnp.einsum("bhts,bshd->bthd", G * L, xk)
-            dec = jnp.exp(acs)                                    # (B,c,nh)
-            Yoff = jnp.einsum("bthn,bhdn->bthd", Ck * dec[..., None], st)
-            last = acs[:, -1:, :]                                 # (B,1,nh)
-            Bdec = Bk * jnp.exp(last - acs)[..., None]
-            st_new = (st * jnp.exp(last[:, 0])[:, :, None, None]
-                      + jnp.einsum("bshn,bshd->bhdn", Bdec, xk))
+            acs = jnp.cumsum(ak, axis=1)                          # (B,c,g,r)
+            L = jnp.exp(_segsum(ak.reshape(B, cs, nh))
+                        ).reshape(B, g, r, cs, cs)
+            G = jnp.einsum("btgn,bsgn->bgts", Ck, Bk)
+            Yd = jnp.einsum("bgrts,bsgrd->btgrd", G[:, :, None] * L, xk)
+            Yoff = jnp.einsum("btgn,bgrdn->btgrd", Ck, st,
+                              precision=hi) * jnp.exp(acs)[..., None]
+            last = acs[:, -1]                                     # (B,g,r)
+            x_end = xk * jnp.exp(last[:, None] - acs)[..., None]
+            st_new = (st * jnp.exp(last)[..., None, None]
+                      + jnp.einsum("bsgn,bsgrd->bgrdn", Bk, x_end,
+                                   precision=hi))
             return st_new, Yd + Yoff
 
-        # prefill always starts fresh — the cache slot may hold a previous
-        # request's state (the KV analog overwrites its rows the same way)
-        st0 = jnp.zeros((B, nh, hd, N), f32)
-        st_f, Y = jax.lax.scan(chunk_body, st0, (xc, Bc, Cc, ac))
-        Y = Y.transpose(1, 0, 2, 3, 4).reshape(B, T + pad, nh, hd)[:, :T]
+        st_f, Y = jax.lax.scan(
+            chunk_body, st0, (chunks(x_dt), chunks(Bm), chunks(Cm),
+                              chunks(dA_log)))
+        Y = jnp.moveaxis(Y, 0, 1).reshape(B, T + pad, g, r, hd)[:, :T]
         y = (Y + D_res).reshape(B, T, s.d_inner)
-        new_state["ssm"] = st_f
+    new_state["ssm"] = st_f.reshape(B, nh, hd, N)
 
     gate = gate.astype(f32)
     if s.gated_norm:
-        g = s.n_groups
         if not s.norm_before_gate:
             y = y * jax.nn.silu(gate)
         yg = y.reshape(B, T, g, s.d_inner // g)
@@ -466,6 +477,9 @@ _SSM_BLOCKS = {"mamba2": mamba2_mixer, "rglru": rglru_block,
 
 
 def ssm_block(s: SSMSpec, lw, x, state, *, phase, seq_lens=None,
-              positions=None):
+              positions=None, valid=None):
+    """``valid``: the paged step's real-token mask (mamba2 only: the one
+    kind whose block continues from a carried state)."""
+    kw = {} if valid is None else {"valid": valid}
     return _SSM_BLOCKS[s.kind](s, lw, x, state, phase=phase,
-                               seq_lens=seq_lens, positions=positions)
+                               seq_lens=seq_lens, positions=positions, **kw)

@@ -185,6 +185,7 @@ class _Inflight:
     out: Dict[str, Any]
     t_dispatch: float
     grown: int = 0                # paged KV tokens grown for this dispatch
+    rows: Optional[np.ndarray] = None   # output row of each live seq
 
 
 def _meta_tenant(meta: Any) -> str:
@@ -584,6 +585,7 @@ class _CbScratch:
         self.live = tuple(live)
         self.b = b
         self.pad_to = pad_to
+        self.rows = None               # live[i]'s output is row i
         self.sid_p = np.empty((pad_to,), np.int32)   # immutable after init
         self.sid_p[:b] = live
         self.sid_p[b:] = live[0]
@@ -629,6 +631,7 @@ class _PagedScratch:
         self.b = b
         self.pad_to = pad_to
         self.width = width
+        self.rows = None               # live[i]'s output is row i
         self.last = np.zeros((pad_to,), np.int32)    # immutable after init
         # per-sequence sampling-stream seeds are constants of the live
         # composition (request meta never changes mid-flight), so the
@@ -681,6 +684,55 @@ class _PagedScratch:
                 self.bt[self.b:] = self.bt[0]
         slots_from_table_into(self.slots, self.bt, self.pos,
                               self._block_size)
+
+
+class _SlotScratch(_PagedScratch):
+    """:class:`_PagedScratch` for a recurrent/hybrid stack: the decode
+    step's rows ARE the state slots (row ``i`` is slot ``i``, always the
+    full batch), so the step graph updates conv tails and SSM state in
+    place and gathers nothing. A slot that is not stepped — free, held by
+    a pending prefill, or a running row left out of this call — is a DEAD
+    row: slot mapping -1 (``run_layers_ssm`` leaves its tail and state as
+    they were, and writes no KV), the null block table, a position that is
+    not 0. ``rows`` maps ``live[i]`` to its output row."""
+
+    def __init__(self, live: Sequence[int], slots: Sequence[int],
+                 pad_to: int, width: int, block_size: int,
+                 seeds: Optional[Sequence[int]] = None,
+                 aids: Optional[Sequence[int]] = None):
+        super().__init__(live, pad_to, width, block_size)
+        self.rows = np.asarray(slots, np.intp)
+        if seeds is not None:
+            self.seeds[self.rows] = np.asarray(seeds, np.int32)
+        if aids is not None:
+            self.aids = np.zeros((pad_to,), np.int32)
+            self.aids[self.rows] = np.asarray(aids, np.int32)
+        # device feedback: the previous dispatch's tokens are already in
+        # slot order (a changed live set drains the pipeline first)
+        self.gather_idx = np.arange(pad_to, dtype=np.intp)
+        for ids, pos, slots_, bt, _ in self._bufs:
+            ids.fill(0)
+            pos.fill(1)
+            slots_.fill(-1)
+            bt.fill(0)
+
+    def fill(self, adapter, need_tokens: bool = True):
+        self._cur ^= 1
+        (self.ids, self.pos, self.slots, self.bt,
+         self.counts) = self._bufs[self._cur]
+        seqs = adapter.seqs
+        rows = self.rows
+        for i, s in enumerate(self.live):
+            st = seqs[s]
+            self.pos[rows[i], 0] = st.position
+            if need_tokens:
+                self.ids[rows[i], 0] = st.last_token
+        live_bt = adapter.app.kv_mgr.block_table_array(self.live, self.width)
+        self.bt[rows] = live_bt
+        live_slots = np.empty((self.b, 1), np.int32)
+        slots_from_table_into(live_slots, live_bt, self.pos[rows],
+                              self._block_size)
+        self.slots[rows] = live_slots
 
 
 # ---------------------------------------------------------------------------
@@ -766,14 +818,18 @@ class _EngineAdapterBase:
                 for s in seq_ids if s in self.seqs]
 
     # -- fetch helpers (the ONLY places that block on device output) -------
-    def _fetch_rows(self, out, b: int) -> np.ndarray:
+    def _fetch_rows(self, out, b: int, rows=None) -> np.ndarray:
+        """The sampled tokens of the ``b`` live sequences: the first ``b``
+        rows, or rows ``rows`` where the dispatch was not laid out live
+        rows first (a slot-ordered decode step)."""
         t0 = time.perf_counter()
         with _get_recorder().span("fetch.tokens", cat="adapter",
                                   engine=self.engine_name, rows=b):
             toks = np.asarray(out["tokens"])
         self.host_stats["blocking_fetches"] += 1
         self.host_stats["blocked_s"] += time.perf_counter() - t0
-        return toks.reshape(toks.shape[0], -1)[:b]
+        toks = toks.reshape(toks.shape[0], -1)
+        return toks[:b] if rows is None else toks[rows]
 
     # -- public decode surface ---------------------------------------------
     def step(self, seq_ids: Optional[Sequence[int]] = None) -> Dict[int, int]:
@@ -881,7 +937,7 @@ class _EngineAdapterBase:
             if _FAULTS.active:
                 _FAULTS.fire("decode_step")
             out = self._dispatch_decode(scr)
-            new = self._fetch_rows(out, len(live))
+            new = self._fetch_rows(out, len(live), scr.rows)
         except ServingError:
             self._rollback_step_growth(live)
             self._scratch = None
@@ -977,7 +1033,7 @@ class _EngineAdapterBase:
             live=tuple(live),
             states=tuple(self.seqs[s] for s in live),
             b=len(live), pad_to=scr.pad_to, out=out, t_dispatch=t0,
-            grown=self._step_growth)
+            grown=self._step_growth, rows=scr.rows)
         for s in live:
             self.seqs[s].position += 1
         if prev is not None:
@@ -1009,7 +1065,7 @@ class _EngineAdapterBase:
         if _FAULTS.active:
             _FAULTS.fire("pipeline_flush")
         overlap = time.perf_counter() - rec.t_dispatch
-        new = self._fetch_rows(rec.out, rec.b)
+        new = self._fetch_rows(rec.out, rec.b, rec.rows)
         res = {}
         for i, (s, st) in enumerate(zip(rec.live, rec.states)):
             if self.seqs.get(s) is not st:
@@ -1349,6 +1405,25 @@ class PagedEngineAdapter(_EngineAdapterBase):
         self._chunks: Dict[int, _ChunkState] = {}   # pending admissions
         self._unwritten: set = set()   # allocated blocks not fully written
         self._init_decode_path(pipeline_depth)
+        # recurrent/hybrid stack (recognised from the spec, no knob): every
+        # live sequence holds one STATE SLOT of the second cache beside the
+        # KV pool (conv tails + SSM state, ``app.state_slots`` = batch
+        # rows), taken at admission inside the same transaction, freed at
+        # release, preemption and rollback. Decode rows are laid out in
+        # slot order (:class:`_SlotScratch`); what such a stack cannot do
+        # is refused here, from the model code's one table.
+        self._state_free: List[int] = list(range(app.state_slots))
+        self._state_slot: Dict[int, int] = {}
+        if app.state_slots:
+            from ..models.model_base import recurrent_refusal
+            why = recurrent_refusal([
+                ragged and "ragged dispatch",
+                speculation is not None and "speculation",
+                kv_spill_tier is not None and "host KV spill / handoff"])
+            if why:
+                raise ConfigurationError(why)
+            self.host_stats.update(state_slot_allocs=0, state_slot_frees=0,
+                                   state_slots_live=0)
         # host-RAM KV spill tier (serving/fleet/kv_tier.py): evicted
         # prefix blocks spill their payloads host-side and re-admit via
         # async H2D restore instead of recompute-prefill (README "Fleet")
@@ -1470,6 +1545,7 @@ class PagedEngineAdapter(_EngineAdapterBase):
                     # chunk-written blocks
                     c = self._restore_spilled(sid, prompt, blocks,
                                               int(c))
+                self._take_state_slot(sid)
                 self._admit_counter += 1
                 self._chunks[sid] = _ChunkState(
                     prompt=prompt, done=int(c),
@@ -1538,6 +1614,7 @@ class PagedEngineAdapter(_EngineAdapterBase):
             if sid in self.seqs:
                 self.seqs.pop(sid)
                 self._scratch = None       # its blocks are gone; see add
+                self._free_state_slot(sid)
                 if sid in self.app.kv_mgr.tables:
                     self.app.kv_mgr.end_sequence(sid)
         self.telemetry.on_release(seq_ids)
@@ -1697,13 +1774,21 @@ class PagedEngineAdapter(_EngineAdapterBase):
         pad_to = autobucketing.get_target_bucket(app.batch_buckets,
                                                  len(live), kind="batch")
         width = app._bt_width_for(live)
+        if app.state_slots:
+            pad_to = app.state_slots   # the rows of the step ARE the slots
         scr = self._scratch
         if (scr is None or scr.live != tuple(live) or scr.pad_to != pad_to
                 or scr.width != width):
-            scr = self._scratch = _PagedScratch(
-                live, pad_to, width, app.kv_mgr.spec.block_size,
-                seeds=[_meta_seed(self.seqs[s].meta) for s in live],
-                aids=self._lora_aids(live))
+            kw = dict(seeds=[_meta_seed(self.seqs[s].meta) for s in live],
+                      aids=self._lora_aids(live))
+            if app.state_slots:
+                scr = _SlotScratch(live, [self._state_slot[s] for s in live],
+                                   pad_to, width,
+                                   app.kv_mgr.spec.block_size, **kw)
+            else:
+                scr = _PagedScratch(live, pad_to, width,
+                                    app.kv_mgr.spec.block_size, **kw)
+            self._scratch = scr
         return scr
 
     def _dispatch_decode(self, scr: _PagedScratch, toks_dev=None):
@@ -1740,6 +1825,12 @@ class PagedEngineAdapter(_EngineAdapterBase):
         for the whole horizon are pre-allocated, slot mappings advance
         IN-GRAPH — one dispatch, one fetch, zero per-token host work."""
         app = self.app
+        if app.state_slots:
+            from ..models.model_base import recurrent_refusal
+            self._rollback_grow(live, num_steps)
+            raise ConfigurationError(
+                recurrent_refusal(["fused decode loop"])
+                + " — step_many() is that loop; call step()")
         b = len(live)
         pad_to = autobucketing.get_target_bucket(app.batch_buckets, b,
                                                  kind="batch")
@@ -2074,6 +2165,7 @@ class PagedEngineAdapter(_EngineAdapterBase):
             # half-prefilled victim: blocks not fully written must leave
             # the prefix cache (abort, not a plain free); the record's
             # tokens are the bare prompt — nothing was generated yet
+            self._free_state_slot(victim, event="preempt")
             self._abort_pending(victim)
             tenant = _meta_tenant(cst.meta)
             self.preempted.append(Preempted(
@@ -2087,6 +2179,9 @@ class PagedEngineAdapter(_EngineAdapterBase):
             return
         st = self.seqs.pop(victim)
         self._scratch = None               # victim's blocks are reclaimed
+        # recompute preemption re-prefills from position 0, which resets
+        # whatever slot the requeue is given
+        self._free_state_slot(victim, event="preempt")
         if victim in self.app.kv_mgr.tables:
             self.app.kv_mgr.end_sequence(victim)
         tenant = _meta_tenant(st.meta)
@@ -2166,6 +2261,38 @@ class PagedEngineAdapter(_EngineAdapterBase):
                 self._scratch = None
             self._abort_pending(sid)
         self.telemetry.on_admission_rollback()
+
+    # -- recurrent-state slots (recurrent/hybrid stacks) -------------------
+    def _take_state_slot(self, sid: int) -> None:
+        """Give ``sid`` the lowest free state slot (no-op on an attention
+        stack). Admission is capped at the batch, so a slot is always
+        free; the slot is NOT cleared — the sequence's first chunk starts
+        at position 0, which resets it in the step graph."""
+        if not self.app.state_slots:
+            return
+        self._state_slot[sid] = self._state_free.pop(0)
+        self.host_stats["state_slot_allocs"] += 1
+        self._note_state_slots("alloc")
+
+    def _free_state_slot(self, sid: int, event: str = "free") -> None:
+        slot = self._state_slot.pop(sid, None)
+        if slot is None:
+            return
+        bisect.insort(self._state_free, slot)
+        self.host_stats["state_slot_frees"] += 1
+        self._note_state_slots(event)
+
+    def _note_state_slots(self, event: str) -> None:
+        live = len(self._state_slot)
+        self.host_stats["state_slots_live"] = live
+        reg = self.telemetry.registry
+        if reg.enabled:
+            tmetrics.state_slot_events_counter(reg).inc(
+                engine=self.engine_name, event=event)
+            gauge = tmetrics.state_slots_gauge(reg)
+            gauge.set(live, engine=self.engine_name, state="live")
+            gauge.set(len(self._state_free), engine=self.engine_name,
+                      state="free")
 
     # -- chunked, packed, schedulable prefill ------------------------------
     def _pending_ids(self):
@@ -2295,7 +2422,8 @@ class PagedEngineAdapter(_EngineAdapterBase):
         for i, s in final_rows:
             st = chunks.pop(s)
             self._unwritten.difference_update(self.app.kv_mgr.tables[s])
-            tok = int(new[i, 0])
+            out_rows = packed[-1]          # slot-ordered pack: row != i
+            tok = int(new[i if out_rows is None else out_rows[i], 0])
             self.seqs[s] = _SeqState(
                 position=len(st.prompt), last_token=tok,
                 tokens=list(st.prompt) + [tok],
@@ -2341,12 +2469,50 @@ class PagedEngineAdapter(_EngineAdapterBase):
             aids = np.asarray(aids, np.int32)
         pad_to = autobucketing.get_target_bucket(app.prefill_row_buckets, b,
                                                  kind="prefill_rows")
+        if app.state_slots:
+            return self._pack_state_rows(pad_to, sids, ids_w, pos_w, slots,
+                                         bt, last, seeds, aids)
         if pad_to > b:
             seeds = _repeat_row0(seeds, pad_to)
             if aids is not None:
                 aids = _repeat_row0(aids, pad_to)
         return _pad_paged_rows(pad_to, ids_w, pos_w, slots, bt, last) \
-            + (seeds, aids)
+            + (seeds, aids, None, None)
+
+    def _pack_state_rows(self, pad_to, sids, ids_w, pos_w, slots, bt, last,
+                         seeds, aids):
+        """The packed chunk of a recurrent/hybrid stack. At the full batch
+        the rows ARE the state slots: each sequence's row goes to its
+        slot's index and every other row is dead (slot mapping -1, null
+        block table, positions from 1 so that nothing resets: the step
+        graph leaves a dead slot's tail and state as they were). Below it
+        (the ``r_min``-row program of one prompt) the rows keep their
+        order, padded by repeating row 0, and ``state_slots`` names each
+        row's slot. Returns the packed tuple with ``state_slots`` and the
+        output row of each sequence appended."""
+        slot_of = np.asarray([self._state_slot[s] for s in sids], np.int32)
+        if pad_to != self.app.state_slots:
+            b = len(sids)
+            if pad_to > b:
+                seeds = _repeat_row0(seeds, pad_to)
+                slot_of = _repeat_row0(slot_of, pad_to)
+                if aids is not None:
+                    aids = _repeat_row0(aids, pad_to)
+            return _pad_paged_rows(pad_to, ids_w, pos_w, slots, bt, last) \
+                + (seeds, aids, slot_of, None)
+        width = ids_w.shape[1]
+
+        def spread(x, fill=0):
+            out = np.full((pad_to,) + x.shape[1:], fill, x.dtype)
+            out[slot_of] = x
+            return out
+
+        dead_pos = 1 + np.arange(width, dtype=np.int32)
+        pos_p = np.tile(dead_pos, (pad_to, 1))
+        pos_p[slot_of] = pos_w
+        return (spread(ids_w), pos_p, spread(slots, -1), spread(bt),
+                spread(last), spread(seeds),
+                None if aids is None else spread(aids), None, slot_of)
 
     def _dispatch_prefill_chunk(self, packed, fetch: bool = True):
         """Issue ONE packed prefill-chunk dispatch without materializing
@@ -2354,10 +2520,13 @@ class PagedEngineAdapter(_EngineAdapterBase):
         chunk token fetch happens in the caller, one async hop behind.
         ``fetch=False`` (intermediate-only dispatch) skips even the async
         device-to-host copy: those samples are never read."""
-        ids_p, pos_p, slots_p, bt_p, last_p, seeds_p, aids_p = packed
+        (ids_p, pos_p, slots_p, bt_p, last_p, seeds_p, aids_p,
+         state_slots, _) = packed
         kw = {"row_seeds": seeds_p}
         if aids_p is not None:
             kw["adapter_ids"] = aids_p
+        if state_slots is not None:
+            kw["state_slots"] = state_slots
         out = self.app._run_paged(ids_p, pos_p, slots_p, bt_p, last_p, **kw)
         if fetch:
             _async_fetch(out["tokens"])
@@ -2395,7 +2564,10 @@ class PagedEngineAdapter(_EngineAdapterBase):
         unwritten tail AND prefix hits on another pending writer's
         still-unwritten blocks — is invalidated so the prefix cache can
         never serve it; fully-written blocks are freed as valid. The
-        caller pops the ``_ChunkState`` first."""
+        caller pops the ``_ChunkState`` first. Its state slot (recurrent
+        stacks) goes back too: the next holder resets it by prefilling
+        from position 0."""
+        self._free_state_slot(sid)
         if sid not in self.app.kv_mgr.tables:
             return
         unwritten = set(self.app.kv_mgr.tables[sid]) & self._unwritten

@@ -121,8 +121,14 @@ def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
     run) — what the default ``PagedEngineAdapter(app)``, and a ragged
     adapter shed back to two-phase, dispatches — the fused decode loop,
     and the speculative verify widths: the exact shape set the serving
-    adapters dispatch (serving/ragged/path.py, serving/adapter.py)."""
+    adapters dispatch (serving/ragged/path.py, serving/adapter.py).
+
+    A recurrent/hybrid stack (``app.state_slots``) warms only the programs
+    it can run: ``paged.w1``, ``paged.w<b>`` and ``paged_pack.w<b>`` (no
+    ragged, fused-loop or verify program exists for it), one ``(kind,
+    bucket)`` pair each like everyone else's."""
     cfg = app.tpu_config
+    recurrent = bool(app.state_slots)
     b = cfg.batch_size
     if widths is None:
         widths = autobucketing.ragged_row_buckets(app.ctx_buckets,
@@ -145,7 +151,8 @@ def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
                        np.zeros((rows, w), np.int32),
                        np.full((rows, w), -1, np.int32),
                        np.zeros((rows, tw), np.int32),
-                       np.zeros((rows,), np.int32), **kw)
+                       np.zeros((rows,), np.int32),
+                       state_slots=app._dummy_state_slots(rows), **kw)
 
     for tw in bt_widths:
         bt = np.zeros((b, tw), np.int32)        # null block only: no writes
@@ -161,10 +168,12 @@ def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
                             np.zeros((b,), np.int32), **kw)
 
         for w in sorted(widths):
-            plan.append(("ragged", w, lambda w=w, bt=bt: ragged_thunk(w, bt)))
-            if lora_kw is not None:
-                plan.append(("ragged_lora", w,
-                             lambda w=w, bt=bt: ragged_thunk(w, bt, **lora_kw)))
+            if not recurrent:
+                plan.append(("ragged", w,
+                             lambda w=w, bt=bt: ragged_thunk(w, bt)))
+                if lora_kw is not None:
+                    plan.append(("ragged_lora", w, lambda w=w, bt=bt:
+                                 ragged_thunk(w, bt, **lora_kw)))
             if w == 1 or w in app.ctx_buckets:
                 # ("paged", w): the decode step at the full batch (w == 1),
                 # the r_min-row chunk program (w > 1); the full-batch chunk
@@ -178,6 +187,8 @@ def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
                         plan.append((kind + "_lora", w,
                                      lambda w=w, rows=rows, tw=tw:
                                      paged_thunk(w, rows, tw, lora=True)))
+        if recurrent:
+            continue
         if chunk > 1:
             plan.append(("paged_loop", chunk, lambda bt=bt: app._run_paged_loop(
                 np.zeros((b,), np.int32), np.zeros((b,), np.int32), bt,
@@ -354,7 +365,10 @@ def memory_ledger(adapter, *, registry=None,
                 "spill": None,
                 "headroom": admission_headroom(adapter)}
     spec = mgr.spec
-    pool_bytes = _tree_bytes(app.cache)
+    # the KV pool alone: a recurrent/hybrid stack keeps its per-sequence
+    # state (conv tails + SSM state) in the same dict, accounted below
+    pool_bytes = _tree_bytes({k: app.cache[k] for k in ("k", "v")})
+    state_bytes = _tree_bytes(app.cache) - pool_bytes
     block_bytes = pool_bytes // spec.num_blocks
     usable = spec.num_blocks - 1               # block 0 is the null block
     free = int(mgr.allocator.num_free)
@@ -389,6 +403,11 @@ def memory_ledger(adapter, *, registry=None,
                    "stats": dict(tier.stats)}),
         "headroom": admission_headroom(adapter),
     }
+    if app.state_slots:
+        ledger["state"] = {
+            "bytes": state_bytes, "slots": int(app.state_slots),
+            "slot_bytes": state_bytes // int(app.state_slots),
+            "live": len(adapter._state_slot)}
     if graph_report is not None:
         # static side from the compiled-graph observatory: per-graph
         # memory_analysis() peaks (weights + temps while that graph runs)

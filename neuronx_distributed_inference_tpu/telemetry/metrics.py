@@ -79,6 +79,8 @@ STEADY_STATE_RECOMPILES_TOTAL = \
 # reported in the same account so the device + spill total is one read)
 HBM_MODEL_BYTES = "nxdi_hbm_model_bytes"
 HBM_KV_BYTES = "nxdi_hbm_kv_bytes"                    # state
+STATE_SLOTS = "nxdi_state_slots"                      # engine, state
+STATE_SLOT_EVENTS_TOTAL = "nxdi_state_slot_events_total"  # engine, event
 KV_FRAGMENTATION_RATIO = "nxdi_kv_fragmentation_ratio"
 
 # -- paged KV cache (modules/block_kv_cache.py) ------------------------------
@@ -402,6 +404,22 @@ def hbm_kv_bytes_gauge(reg):
         "KV pool bytes by ledger state (used|free|unwritten device "
         "blocks; spilled = host-RAM tier residency in the same account)",
         labels=("state",))
+
+
+def state_slots_gauge(reg):
+    return reg.gauge(
+        STATE_SLOTS,
+        "Per-sequence recurrent-state slots of a recurrent/hybrid stack by "
+        "state (live|free): the second cache beside the KV pool",
+        labels=("engine", "state"))
+
+
+def state_slot_events_counter(reg):
+    return reg.counter(
+        STATE_SLOT_EVENTS_TOTAL,
+        "Recurrent-state slot events: alloc (admission), free (release or "
+        "a rolled-back admission), preempt (the slot of a preempted row)",
+        labels=("engine", "event"))
 
 
 def kv_fragmentation_ratio_gauge(reg):

@@ -1,0 +1,256 @@
+"""The benchmark's contract with the program, in tier-1 (owed since ISSUE 29;
+``benchmark/tests/`` holds the harness's own tests, which the driver does
+not run).
+
+  * every real cell of ``BENCHMARK.json`` loads (``run.load_cell``): its
+    files are found by name, agree with its ``workloads`` entry, and it
+    reports ``setup_s``, another end-to-end metric and its per-layer names;
+  * every ``per_layer`` entry agrees with its metric file
+    ``benchmark/layer_metrics/<name>.json`` on the keys they share, a
+    ``python`` reader has its ``.py`` beside it, and every listed cell
+    exists and reports the end-to-end metric the entry moves;
+  * the configuration of a cell keeps every number of its published source
+    that this repository can check offline (``reduced`` names what differs
+    between the file's own twin and the gate's);
+  * the ``granitemoehybrid`` reference, found by name, gates a toy twin
+    through the paged path on the CPU — and a mixer that drops the state
+    it is handed does not pass it;
+  * the precision of the recurrent state, which the logit gate cannot see
+    inside the tokens it runs (PERF.md §6): the cache the application
+    allocates for the configuration's file has the dtypes the file's
+    ``assumed`` names, and a served sequence's state slot is held to the
+    reference's ``S_t`` at a tolerance a bf16-carried state fails.
+"""
+
+import json
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+for _p in (ROOT, BENCH):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+import run as bench_run  # noqa: E402
+from harness import build  # noqa: E402
+
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    BENCHMARK = json.load(_f)
+CELLS = [w["name"] for w in BENCHMARK["workloads"]]
+PER_LAYER = [m["name"] for m in BENCHMARK["per_layer"]]
+SHARED_KEYS = ("unit", "better", "source", "layer", "moves")
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_real_cell_loads_with_its_metrics(cell):
+    spec = bench_run.load_cell(cell)
+    entry = next(w for w in BENCHMARK["workloads"] if w["name"] == cell)
+    assert spec["cell"]["config"] == entry["config"]
+    assert spec["cell"]["traffic"] == entry["traffic"]
+    assert spec["cell"]["chips"] == entry["chips"] == spec["config"]["chips"]
+    names = [m["name"] for m in spec["end_to_end"]]
+    assert "setup_s" in names and len(names) >= 2
+    want = [m["name"] for m in BENCHMARK["per_layer"]
+            if "workloads" not in m or cell in m["workloads"]]
+    assert [m["name"] for m in spec["per_layer"]] == want and want
+    # every metric without a list is reported by every cell
+    assert {m["name"] for m in BENCHMARK["per_layer"]
+            if "workloads" not in m} <= set(want)
+    # the mix's prompts reach only widths the configuration warms
+    widths = build.warm_widths(spec["config"], spec["mix"])
+    assert widths[0] == 1 and set(widths[1:]) <= set(
+        spec["config"]["serve"]["context_encoding_buckets"])
+    config = next(c for c in BENCHMARK["configs"]
+                  if c["name"] == entry["config"])
+    assert config["file"] == f"benchmark/configs/{entry['config']}.json"
+    assert config["reduced"] == spec["config"]["reduced"]
+    assert config["source"] == spec["config"]["source"]
+
+
+@pytest.mark.parametrize("name", PER_LAYER)
+def test_a_per_layer_entry_agrees_with_its_metric_file(name):
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
+    spec = build.load_json("layer_metrics", name + ".json")
+    assert {k: entry[k] for k in SHARED_KEYS} == \
+        {k: spec[k] for k in SHARED_KEYS}
+    assert "workloads" not in spec         # listed in BENCHMARK.json only
+    kind = spec["reader"]["kind"]
+    if kind == "python":
+        assert os.path.exists(os.path.join(BENCH, "layer_metrics",
+                                           name + ".py"))
+    else:
+        from harness import readers
+        assert kind in readers.KINDS
+    moved = next(m for m in BENCHMARK["end_to_end"]
+                 if m["name"] == entry["moves"])
+    for cell in entry.get("workloads", CELLS):
+        assert cell in CELLS
+        assert "workloads" not in moved or cell in moved["workloads"], \
+            f"{cell} does not report {entry['moves']}"
+    if name.endswith("_roofline"):
+        assert entry["unit"] == "%"
+
+
+def test_granite_keeps_every_published_number():
+    """The catalog row's ``config`` (model-configs guide), as copied into
+    ISSUE 30: every key at the top level of the file, none changed, and
+    ``reduced`` empty."""
+    cfg = build.load_json("configs", "granite-4.0-h-micro.json")
+    published = dict(
+        attention_multiplier=0.015625, embedding_multiplier=12,
+        hidden_size=2048, intermediate_size=8192, logits_scaling=8,
+        mamba_chunk_size=256, mamba_d_conv=4, mamba_d_head=64,
+        mamba_d_state=128, mamba_expand=2, mamba_n_groups=1,
+        mamba_n_heads=64, max_position_embeddings=131072,
+        num_attention_heads=32, num_experts_per_tok=0,
+        num_hidden_layers=40, num_key_value_heads=8, num_local_experts=0,
+        residual_multiplier=0.22, rms_norm_eps=1e-05, rope_theta=10000,
+        shared_intermediate_size=8192, vocab_size=100352,
+        model_type="granitemoehybrid", position_embedding_type="nope",
+        tie_word_embeddings=True)
+    assert {k: cfg[k] for k in published} == published
+    types = cfg["layer_types"]
+    assert len(types) == 40 and types.count("attention") == 4
+    assert [i for i, t in enumerate(types) if t == "attention"] == \
+        [5, 15, 25, 35]
+    assert cfg["reduced"] == [] and cfg["family"] == "granitemoehybrid"
+    assert cfg["assumed"]["ssm_state_dtype"] == "float32"
+    gate = cfg["gate"]
+    assert gate["min_positions_held"] == 1.0
+    assert gate["excuse_margin_max"] == 0.0 and gate["worst_ratio_max"] == 1.0
+    twin = build.hf_config(cfg, build.gate_overrides(gate))
+    assert twin["num_hidden_layers"] == len(twin["layer_types"]) == 4
+    assert set(twin["layer_types"]) == {"mamba", "attention"}
+    # the pool cannot run dry: every row at its longest prompt and answer
+    mix = build.load_json("traffic", "chat-longanswer-closed.json")
+    serve = cfg["serve"]
+    assert serve["pa_num_blocks"] * serve["pa_block_size"] >= \
+        serve["batch_size"] * (mix["prompt_len"]["hi"]
+                               + mix["output_len"]["hi"])
+
+
+TOY = dict(
+    json.load(open(os.path.join(BENCH, "tests", "reference_cases",
+                                "granitemoehybrid.json")))["config"],
+    family="granitemoehybrid", tp=1, dtype="float32",
+    serve=dict(batch_size=4, seq_len=128, pa_block_size=8, pa_num_blocks=96,
+               context_encoding_buckets=[16, 32], enable_bucketing=True,
+               is_block_kv_layout=True, is_prefix_caching=False),
+    adapter={},
+    # 24 prompt positions: three chunks of the toy's mamba_chunk_size 8
+    gate=dict(config={}, batch=2, prompt_len=24, new_tokens=4, atol=1e-4,
+              rtol=1e-4, min_positions_held=1.0, median_ratio_max=0.5,
+              worst_ratio_max=1.0, excuse_margin_max=0.0))
+
+
+def test_the_reference_found_by_name_gates_a_toy_twin():
+    ref = build.load_reference("granitemoehybrid")
+    assert ref.__file__ == os.path.join(BENCH, "references",
+                                        "granitemoehybrid.py")
+    res = build.logit_gate(TOY, seed=2**31 + 30, served_precision="highest")
+    assert res["passed"], res
+    assert res["held_share"] == {"prefill": 1.0, "decode": 1.0}
+    assert res["compared"] == 2 * 28 * TOY["vocab_size"]
+
+
+def test_a_dropped_state_does_not_pass_the_toy_gate(monkeypatch):
+    """A control of the gate at the toy size: a mixer that forgets the SSM
+    state a dispatch hands it (each decode step starts from zero) must not
+    pass."""
+    import jax.numpy as jnp
+    from neuronx_distributed_inference_tpu.modules import ssm
+    mixer = ssm._SSM_BLOCKS["mamba2"]
+
+    def forgetful(s, lw, x, state, **kw):
+        return mixer(s, lw, x,
+                     dict(state, ssm=jnp.zeros_like(state["ssm"])), **kw)
+    monkeypatch.setitem(ssm._SSM_BLOCKS, "mamba2", forgetful)
+    res = build.logit_gate(TOY, seed=2**31 + 30, served_precision="highest")
+    assert not res["passed"] and res["worst_ratio"] > 1.0, res
+    assert res["held_share"]["prefill"] == 1.0     # one dispatch from zero
+
+
+def test_the_allocated_caches_have_the_dtypes_the_file_assumes():
+    """What ``assumed`` says of the state's precision is what the program
+    allocates: the gate's twin of the real file, built as the harness builds
+    it. A PR that carries the SSM state in fewer bits (half the decode
+    step's largest byte item) changes a result the logit gate cannot see,
+    and has to say so in the configuration's file, which only a benchmark
+    PR may edit."""
+    cfg = build.load_json("configs", "granite-4.0-h-micro.json")
+    gate, assumed = cfg["gate"], cfg["assumed"]
+    app = build.build_app(
+        cfg, overrides=build.gate_overrides(gate),
+        serve=dict(cfg["serve"], batch_size=gate["batch"], seq_len=256,
+                   pa_num_blocks=4 * gate["batch"],
+                   context_encoding_buckets=[128])).init_cache()
+    dtypes = {k: str(v.dtype) for k, v in app.cache.items()}
+    assert dtypes == {"k": assumed["kv_dtype"], "v": assumed["kv_dtype"],
+                      "conv_x": assumed["conv_tail_dtype"],
+                      "conv_bc": assumed["conv_tail_dtype"],
+                      "ssm": assumed["ssm_state_dtype"]}
+    n_mamba = gate["config"]["layer_types"].count("mamba")
+    assert app.cache["ssm"].shape == (
+        n_mamba, gate["batch"], cfg["mamba_n_heads"], cfg["mamba_d_head"],
+        cfg["mamba_d_state"])
+
+
+def _served_state_error(rounds_to=None, monkeypatch=None):
+    """Serve one toy sequence through ``PagedEngineAdapter`` (a prompt in
+    three chunks, then 40 decode steps) and return the largest error of its
+    state slot against the reference's ``final_states``, as a share of the
+    state's largest entry. ``rounds_to``: allocate the SSM state in that
+    dtype instead (the control)."""
+    import jax.numpy as jnp
+    import numpy as np
+    from harness import weights
+    from neuronx_distributed_inference_tpu.modules import ssm
+    from neuronx_distributed_inference_tpu.serving import PagedEngineAdapter
+    if rounds_to is not None:
+        shapes = ssm.ssm_state_shapes
+
+        def rounded(*a, **kw):
+            out = shapes(*a, **kw)
+            return dict(out, ssm=(out["ssm"][0], rounds_to))
+        monkeypatch.setattr(ssm, "ssm_state_shapes", rounded)
+    hf = build.hf_config(TOY)
+    ref = build.load_reference(hf["model_type"])
+    table = ref.weight_shapes(hf)
+    w = weights.make_weights(table, seed=2**31 + 31)
+    app = build.build_app(TOY)
+    app._put_params(app.family.convert_hf_state_dict(
+        weights.HfView(table, w, dtype=np.dtype("float32")), app.spec))
+    app.init_cache()
+    ad = PagedEngineAdapter(app)
+    prompt = np.random.default_rng(31).integers(
+        1, hf["vocab_size"], size=70).tolist()      # 32 + 32 + 6 (padded)
+    stream = [ad.add_requests([3], [prompt])[3]]
+    for _ in range(40):
+        stream.append(ad.step([3])[3])
+    got = np.asarray(app.cache["ssm"][:, ad._state_slot[3]], np.float32)
+    want = np.asarray(ref.final_states(
+        hf, w, jnp.asarray([prompt + stream[:-1]])))[:, 0]
+    assert got.shape == want.shape
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+#: the served float32 state agrees with the reference's to a few 1e-6 of its
+#: largest entry (chunked against token-by-token summation); one bf16
+#: rounding is 2e-3 of an entry
+STATE_RTOL = 1e-4
+
+
+def test_a_served_state_slot_holds_the_references_state():
+    assert _served_state_error() < STATE_RTOL
+
+
+def test_a_bf16_carried_state_fails_the_state_check(monkeypatch):
+    """The control ISSUE 30 asked the logit gate for, which it cannot give
+    (the gate passes it digit for digit on the chip): the state slot
+    allocated in bf16, so every dispatch rounds what it carries."""
+    import jax.numpy as jnp
+    err = _served_state_error(jnp.bfloat16, monkeypatch)
+    assert err > 10 * STATE_RTOL, err

@@ -209,8 +209,10 @@ def test_recurrent_state_carries_across_decode(tmp_path):
 
 def test_ssm_layer_walk_rejects_residual_spec_knobs():
     """Regression guard: run_layers_ssm hard-codes the plain pre-norm
-    residual shape — a hybrid family setting residual_multiplier or
-    sandwich_norm must fail loudly, not run silently wrong."""
+    residual shape — a hybrid family setting sandwich_norm must fail
+    loudly, not run silently wrong. (``residual_multiplier`` was refused
+    here too until ISSUE 30 taught the walk to apply it: granitemoehybrid
+    sets 0.22 and tests/test_recurrent_paged.py holds its logits.)"""
     import dataclasses
 
     from neuronx_distributed_inference_tpu.config import TpuConfig
@@ -228,8 +230,32 @@ def test_ssm_layer_walk_rejects_residual_spec_knobs():
         icfg, ssm=SSMSpec(kind="mamba2", d_inner=64, num_heads=4, head_dim=16,
                           d_state=16))
 
-    for bad in (dataclasses.replace(spec, residual_multiplier=0.22),
-                dataclasses.replace(spec, sandwich_norm=True)):
-        with pytest.raises(NotImplementedError, match="pre-norm residual"):
-            model_base.run_layers_ssm(bad, None, None, None, None, None,
-                                      None, "prefill")
+    bad = dataclasses.replace(spec, sandwich_norm=True)
+    with pytest.raises(NotImplementedError, match="pre-norm residual"):
+        model_base.run_layers_ssm(bad, None, None, None, None, None,
+                                  None, "prefill")
+
+
+def test_ssm_layer_walk_applies_the_residual_multiplier(tmp_path):
+    """What replaces the refusal: on the CONTIGUOUS prefill + decode path a
+    hybrid stack with ``residual_multiplier`` 0.22 (granitemoehybrid: Mamba-2
+    mixers interleaved with attention, all four IBM multipliers) gives HF's
+    logits, which a walk that ignored the multiplier would miss by far."""
+    import json
+    import os
+
+    from transformers import (GraniteMoeHybridConfig,
+                              GraniteMoeHybridForCausalLM)
+    torch.manual_seed(30)
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "tests",
+            "reference_cases", "granitemoehybrid.json")) as f:
+        hf = {k: v for k, v in json.load(f)["config"].items()
+              if k != "model_type"}
+    assert hf["residual_multiplier"] == 0.22
+    model = GraniteMoeHybridForCausalLM(GraniteMoeHybridConfig(
+        **hf, torch_dtype="float32")).float()
+    app = _check(tmp_path, "granitemoehybrid", model,
+                 vocab_hi=hf["vocab_size"])
+    assert app.spec.residual_multiplier == 0.22
+    assert app.spec.ssm.kind == "mamba2" and not app.spec.ssm_parallel
