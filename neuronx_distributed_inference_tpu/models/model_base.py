@@ -56,6 +56,7 @@ from ..parallel.mesh import (AXIS_CP, AXIS_DP, AXIS_EP, AXIS_MP, AXIS_TP,
 from ..modules import kv_cache as kv
 from ..modules import low_rank as low_rank_mod
 from ..modules import ssm as ssm_mod
+from ..modules import moe as moe_mod
 from ..modules.moe import MoESpec, moe_block
 from ..modules.lora import (LoraSpec, apply_lora, lora_spec_from_config)
 from ..modules.quantization import (QuantSpec, qlinear,
@@ -1136,13 +1137,10 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                              "xla" if declined else kernel_mode.kernel_path(),
                              declined)
         if not use_pkernel:
-            k_layer = bkv.read_layer(k_full, li)
-            v_layer = bkv.read_layer(v_full, li)
-
             def gathered_mha(q_, bt_, mask_):
-                k_all = kv.dequantize_kv(bkv.gather_block_kv(k_layer, bt_),
+                k_all = kv.dequantize_kv(bkv.gather_layer_kv(k_full, li, bt_),
                                          dtype, spec.kv_scale)
-                v_all = kv.dequantize_kv(bkv.gather_block_kv(v_layer, bt_),
+                v_all = kv.dequantize_kv(bkv.gather_layer_kv(v_full, li, bt_),
                                          dtype, spec.kv_scale)
                 return attn_ops.mha(q_, k_all, v_all, mask_, spec.scale,
                                     logits_soft_cap=spec.attn_soft_cap,
@@ -1554,21 +1552,45 @@ def run_layer_slice(spec: DecoderSpec, layer_params, kf, vf, hidden, ai, *,
     with interleaved non-standard layers (mllama cross-attention decoder)
     can stitch standard segments around their own blocks.
 
-    Decode (T = 1) UNROLLS the layer loop instead of scanning: with a
-    static layer index, each layer's cache read is a lazily-fused static
-    slice; under lax.scan the dynamic layer index forces XLA to
-    MATERIALIZE every layer's cache slice (plus a relayout copy for the
-    attention dot) every step — measured ~0.25 ms/step of pure copy
-    traffic on v5e at B=2/S=1024/16 layers. Prefill keeps the scan (one
-    compiled body, O(1) compile time in depth; the per-layer copies are
-    amortized over the whole window there)."""
-    n = jax.tree.leaves(layer_params)[0].shape[0]
+    Phase "decode" at T = 1 (the contiguous cache) UNROLLS the layer loop:
+    with a static layer index each layer's cache read is a lazily fused
+    static slice. Every other phase SCANS one compiled body (O(1) compile
+    time in depth) — the paged step graphs too: ``paged_forward_step``
+    passes "paged", so ``paged.w1`` scans and is copy-free all the same,
+    because the dense all-experts einsum fuses its layer slice and the
+    paged decode kernel takes the layer index as a scalar.
 
-    if phase == "decode" and jax.tree.leaves(hidden)[0].shape[1] == 1:
+    What a scan's dynamic layer index costs is decided per consumer: XLA
+    fuses a layer's slice into an einsum or an elementwise op, but a
+    consumer it cannot fuse into is handed a COPY of the slice on every
+    layer of every dispatch — ``ragged_dot`` is a Mosaic custom call on TPU
+    (268 MB a weight kind at OLMoE's widths), the block gather read a whole
+    layer of the pool (134 MB each for K and V): five
+    ``dynamic-slice_bitcast_fusion`` ops, 26 of a one-row 256-token chunk's
+    38 ms on a v5e (PERF.md, PR 25-31). Unrolling with static ``w[i]`` is
+    worse: the compiler then copies ALL layers in one multi-output fusion.
+    So those consumers get the STACKED array and the layer index and select
+    the layer themselves: the expert leaves ``moe.stack_leaves`` names stay
+    out of the scan's ``xs`` (``moe_block`` receives a ``LayerOfStack``),
+    and the paged read gathers from the flat pool
+    (``block_kv_cache.gather_layer_kv``)."""
+    n = jax.tree.leaves(layer_params)[0].shape[0]
+    h0 = jax.tree.leaves(hidden)[0]
+    # leaves a consumer reads out of the stack in place: not sliced per layer
+    in_place = (moe_mod.stack_leaves(spec.moe, h0.shape[0] * h0.shape[1],
+                                     layer_params)
+                if spec.moe is not None and mlp_kind != "dense" else ())
+    sliced = {k: a for k, a in layer_params.items() if k not in in_place}
+
+    def of_stack(layer_w, i):
+        return {**layer_w, **{k: moe_mod.LayerOfStack(layer_params[k], i)
+                              for k in in_place}}
+
+    if phase == "decode" and h0.shape[1] == 1:
         caps_list = []
         pend = []
         for i in range(n):
-            layer_w = jax.tree.map(lambda a: a[i], layer_params)
+            layer_w = of_stack(jax.tree.map(lambda a: a[i], sliced), i)
             res = _layer_body(
                 spec, hidden, layer_w, kf, vf, i + cache_offset, ai,
                 is_local[i], seq_ids, positions, phase, identity_seq_ids,
@@ -1610,7 +1632,8 @@ def run_layer_slice(spec: DecoderSpec, layer_params, kf, vf, hidden, ai, *,
             layer_w, loc, rp, li = xs
             ds = None
         h, k_, v_, caps = _layer_body(
-            spec, h, layer_w, k_, v_, li + cache_offset, ai, loc, seq_ids,
+            spec, h, of_stack(layer_w, li), k_, v_, li + cache_offset, ai,
+            loc, seq_ids,
             positions, phase, identity_seq_ids, arange_positions,
             slot_mapping, block_table, mlp_kind, adapter_ids,
             rp if replacements is not None else None, kv_view=kv_view,
@@ -1618,7 +1641,7 @@ def run_layer_slice(spec: DecoderSpec, layer_params, kf, vf, hidden, ai, *,
             prefill_lens=prefill_lens)
         return (h, k_, v_), caps
 
-    xs = (layer_params, is_local, rep, jnp.arange(n, dtype=jnp.int32))
+    xs = (sliced, is_local, rep, jnp.arange(n, dtype=jnp.int32))
     if deepstack is not None:
         xs = xs + (deepstack,)
     (hidden, kf, vf), caps = jax.lax.scan(body, (hidden, kf, vf), xs)

@@ -13,8 +13,9 @@ Device layout:
 In-graph ops (pure, used inside the jitted step):
   * ``write_slots``       — scatter new K/V at flat slot ids
     (reference: write via slot_mapping, block_kv_cache_manager.py:268-375)
-  * ``gather_block_kv``   — assemble a per-request (B, S, H, D) view from an
-    ``active_block_table`` (reference: :183-267 gather via block table)
+  * ``gather_layer_kv``   — assemble a per-request (B, S, H, D) view of one
+    layer from an ``active_block_table`` (reference: :183-267 gather via
+    block table), straight from the stacked pool
 
 Host side:
   * ``BlockAllocator`` — free-list allocator + content-hash prefix cache
@@ -106,15 +107,6 @@ def write_slots_at_layer(cache: jnp.ndarray, new: jnp.ndarray, layer,
     return flat.reshape(L, n, bs, h, d)
 
 
-def read_layer(cache: jnp.ndarray, layer) -> jnp.ndarray:
-    """Dynamic-slice one layer (N, Bs, H, D) out of the stacked paged cache
-    (the paged layout keeps heads minor — the block gather is row-indexed,
-    not head-sliced, so the contiguous-cache head-leading layout rationale
-    does not apply here)."""
-    return jax.lax.dynamic_index_in_dim(cache, jnp.asarray(layer, jnp.int32),
-                                        0, keepdims=False)
-
-
 def gather_block_kv(cache_layer: jnp.ndarray, block_table: jnp.ndarray
                     ) -> jnp.ndarray:
     """Assemble per-request contiguous KV from the block table.
@@ -122,8 +114,23 @@ def gather_block_kv(cache_layer: jnp.ndarray, block_table: jnp.ndarray
     cache_layer (N, Bs, H, D); block_table (B, max_blocks) int32 →
     (B, max_blocks*Bs, H, D). Table entries 0 = null block (zeros).
     """
-    g = cache_layer[block_table]               # (B, max_blocks, Bs, H, D)
-    b, mb, bs, h, d = g.shape
+    return gather_layer_kv(cache_layer[None], 0, block_table)
+
+
+def gather_layer_kv(cache: jnp.ndarray, layer, block_table: jnp.ndarray
+                    ) -> jnp.ndarray:
+    """:func:`gather_block_kv` of layer ``layer`` (int or traced scalar
+    inside the layer scan) of the FULL stacked cache (L, N, Bs, H, D), as
+    ONE gather from the flat (L*N, ...) pool by ``layer*N + block_table``:
+    it reads the blocks the tables name and nothing else. Cutting the
+    layer out first (a dynamic slice in front of the gather) is a copy of
+    the layer's whole pool on every layer of every dispatch. The paged
+    layout keeps heads minor: the gather is row-indexed, not head-sliced.
+    """
+    L, n, bs, h, d = cache.shape
+    flat = cache.reshape(L * n, bs, h, d)
+    g = flat[jnp.asarray(layer, jnp.int32) * n + block_table]
+    b, mb = block_table.shape
     return g.reshape(b, mb * bs, h, d)
 
 

@@ -17,7 +17,11 @@ Design:
     through grouped matmuls via ``jax.lax.ragged_dot`` — the dropless
     TPU-native analog of the reference's blockwise matmul
     (MoENeuronConfig blockwise configs). Used when T is large enough that
-    all-experts compute would dominate.
+    all-experts compute would dominate. Inside a layer loop it is handed
+    the STACKED weights and the layer index (:class:`LayerOfStack`) and
+    selects the layer through its group sizes: on TPU ``ragged_dot`` is a
+    custom call whose operand must be a whole buffer, so a slice cut in
+    front of it is a copy of the layer's experts on every call.
   * Shared experts (reference: SharedExperts in moe_v2.py:104) are a plain
     dense MLP added to the routed output.
 
@@ -28,11 +32,12 @@ diverge from HF goldens).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..ops import kernel_mode
 from ..parallel.mesh import (AXIS_DP, AXIS_EP, AXIS_MP, AXIS_TP,
                              shard_constraint)
 from .quantization import dequantize, is_quantized_leaf, qeinsum, qlinear
@@ -91,6 +96,53 @@ class MoESpec:
     # the fused decode scan (the GSPMD analog of the reference's
     # relayout-once-at-load into the TKG process group)
     tkg_experts_local: bool = False
+
+
+# the per-expert leaves of a layer: what the ragged path reads in place
+EXPERT_LEAVES = ("expert_gate", "expert_up", "expert_down",
+                 "expert_gate_bias", "expert_up_bias", "expert_down_bias")
+
+
+class LayerOfStack(NamedTuple):
+    """One layer's expert leaf, left in its stack: the stacked array
+    (L, E, ...) and the layer's index (a Python int or a traced scalar).
+    A layer loop puts it in ``layer_w`` in place of the slice for the
+    leaves :func:`stack_leaves` names."""
+
+    stack: jnp.ndarray
+    layer: Any
+
+
+def takes_ragged(moe: MoESpec, tokens: int) -> bool:
+    """The sorted grouped-matmul path serves a step of ``tokens`` (B*T)
+    tokens; at or below ``dense_max_tokens`` all experts compute on all
+    tokens and XLA fuses the layer's slice into the einsum."""
+    return tokens > moe.dense_max_tokens
+
+
+def sliced_reason(wg) -> str:
+    """Why the ragged path cannot read ``wg``'s layer out of its stack in
+    place ("" = it can): a quantized leaf is dequantized per call, so it
+    is materialised anyway; an expert axis sharded over "ep" cannot be
+    merged with the layer axis without a reshard (the merged dimension
+    would be block-cyclic)."""
+    if is_quantized_leaf(wg):
+        return "quantized experts are dequantized per call"
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty and mesh.shape.get(AXIS_EP, 1) > 1:
+        return "expert axis sharded over ep"
+    return ""
+
+
+def stack_leaves(moe: MoESpec, tokens: int, layer_params: Dict[str, Any]
+                 ) -> Tuple[str, ...]:
+    """The leaves of the stacked ``layer_params`` that a layer loop over a
+    step of ``tokens`` tokens leaves in their stack (handing ``moe_block``
+    a :class:`LayerOfStack`) instead of slicing a layer out of them."""
+    if (not takes_ragged(moe, tokens)
+            or sliced_reason(layer_params["expert_gate"])):
+        return ()
+    return tuple(k for k in EXPERT_LEAVES if k in layer_params)
 
 
 def _act_fn(name: str):
@@ -243,13 +295,22 @@ def experts_dense(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
 
 def experts_ragged(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
                    top_idx: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
-                   wd: jnp.ndarray, bg=None, bu=None, bd=None) -> jnp.ndarray:
+                   wd: jnp.ndarray, bg=None, bu=None, bd=None,
+                   layer=None) -> jnp.ndarray:
     """Dropless grouped-matmul path: sort token copies by expert, run
     ``jax.lax.ragged_dot`` per projection, unsort and combine.
 
     TPU-native analog of the reference's blockwise MoE matmul
     (MoENeuronConfig blockwise configs; SURVEY §2.2). Static shapes: the
     sorted token-copy count is exactly B*T*k.
+
+    ``layer`` None: wg/wu (E,H,I), wd (E,I,H), b* (E,·) are one layer's.
+    With ``layer`` (int or traced scalar) they are the STACKED leaves
+    (L,E,...): the stack is viewed as L*E groups (a bitcast) of which only
+    this layer's E own rows — its group sizes sit at offset ``layer*E`` in
+    a zero vector — so the grouped matmul reads the layer's experts where
+    they lie. An empty group owns no tile of the kernel's grid; the same
+    tiles multiply the same operands as on the layer's slice.
     """
     b, t, h = x.shape
     k = moe.top_k
@@ -269,6 +330,15 @@ def experts_ragged(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
     sorted_tokens = flat_x[order // k]                      # (N, H)
     group_sizes = jnp.bincount(flat_expert, length=moe.num_experts
                                ).astype(jnp.int32)
+    if layer is not None:
+        groups = wg.shape[0] * moe.num_experts
+        wg, wu, wd, bg, bu, bd = (
+            None if a is None else a.reshape((groups,) + a.shape[2:])
+            for a in (wg, wu, wd, bg, bu, bd))
+        first = jnp.asarray(layer, jnp.int32) * moe.num_experts
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((groups,), jnp.int32), group_sizes, (first,))
+        sorted_expert = sorted_expert + first
 
     if moe.input_scaled:
         # llama4: affinity scales the expert input; outputs combine with 1s
@@ -295,13 +365,24 @@ def moe_block(moe: MoESpec, x: jnp.ndarray, layer_w: Dict[str, Any],
     """Full MoE block: route + experts (+ shared experts). x (B,T,H)."""
     router_bias = layer_w.get("router_bias") if moe.has_router_bias else None
     top_vals, top_idx = route(moe, x, layer_w["router"], router_bias)
-    experts = (experts_dense if x.shape[0] * x.shape[1] <= moe.dense_max_tokens
-               else experts_ragged)
     biases = ((layer_w["expert_gate_bias"], layer_w["expert_up_bias"],
                layer_w["expert_down_bias"]) if moe.expert_bias
               else (None, None, None))
     wg, wu, wd = (layer_w["expert_gate"], layer_w["expert_up"],
                   layer_w["expert_down"])
+    if isinstance(wg, LayerOfStack):
+        # the layer loop decided by the same two rules (stack_leaves)
+        kernel_mode.note("moe_ragged", "stacked")
+        y = experts_ragged(
+            moe, x, top_vals, top_idx,
+            *(None if a is None else a.stack for a in (wg, wu, wd, *biases)),
+            layer=wg.layer)
+        return _shared_experts(moe, x, y, layer_w)
+    experts = (experts_ragged if takes_ragged(moe, x.shape[0] * x.shape[1])
+               else experts_dense)
+    if experts is experts_ragged:
+        kernel_mode.note("moe_ragged", "sliced",
+                         sliced_reason(wg) or "the caller cut the layer out")
     if (moe.tkg_experts_local and phase == "decode"
             and experts is experts_dense and not is_quantized_leaf(wg)):
         # hybrid TKG sharding: all experts local, intermediate split over

@@ -54,8 +54,8 @@ def run(live, mb, iters=64):
         def body(acc, _):
             out = 0.0
             for li in range(L):
-                k_all = bkv.gather_block_kv(bkv.read_layer(kp, li), table)
-                v_all = bkv.gather_block_kv(bkv.read_layer(vp, li), table)
+                k_all = bkv.gather_layer_kv(kp, li, table)
+                v_all = bkv.gather_layer_kv(vp, li, table)
                 rows = jnp.arange(B)
                 k_all = k_all.at[rows, lens].set(nk)
                 v_all = v_all.at[rows, lens].set(nv)

@@ -10,8 +10,10 @@ from neuronx_distributed_inference_tpu.models.application import (
 from neuronx_distributed_inference_tpu.models.llama import (LlamaFamily,
                                                             LlamaInferenceConfig)
 from neuronx_distributed_inference_tpu.modules.block_kv_cache import (
-    BlockAllocator, BlockKVSpec, gather_block_kv, slots_from_table, write_slots)
+    BlockAllocator, BlockKVSpec, gather_block_kv, gather_layer_kv,
+    slots_from_table, write_slots)
 
+import jax
 import jax.numpy as jnp
 
 
@@ -86,6 +88,29 @@ def test_negative_slots_dropped():
     # wraps negatives; a padded write once clobbered another row's block)
     untouched = [i for i in range(6) if i != 3]
     assert (out[untouched] == 1.0).all()
+
+
+@pytest.mark.parametrize("layer", [0, 2, 4])
+@pytest.mark.parametrize("traced", [False, True], ids=["static", "traced"])
+def test_flat_gather_equals_the_layer_slice(layer, traced):
+    """One gather from the flat (L*N, ...) pool reads what cutting the
+    layer out and gathering from it reads, bit for bit — null block
+    (table entry 0) and a repeated block included (ISSUE 31)."""
+    rng = np.random.default_rng(31)
+    L, n = 5, 7
+    pool = jnp.asarray(rng.normal(size=(L, n, 4, 2, 8)).astype(np.float32))
+    bt = jnp.asarray([[3, 1, 0, 0], [6, 6, 2, 0], [0, 0, 0, 0]], jnp.int32)
+    want = gather_block_kv(
+        jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False), bt)
+    if traced:
+        got = jax.jit(gather_layer_kv)(pool, jnp.int32(layer), bt)
+    else:
+        got = gather_layer_kv(pool, layer, bt)
+    assert got.shape == (3, 16, 2, 8)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the null entries read THIS layer's block 0, not layer 0's
+    for half in np.asarray(got[0, 8:]).reshape(2, 4, 2, 8):
+        np.testing.assert_array_equal(half, np.asarray(pool[layer, 0]))
 
 
 # ---------------------------------------------------------------------------

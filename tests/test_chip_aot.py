@@ -16,6 +16,7 @@ compiled" true between chip runs (``chip_smoke.py`` is the run itself):
     the first benchmark cell starts from a graph known to compile.
 """
 
+import re
 from functools import partial
 
 import jax
@@ -78,9 +79,10 @@ def as_on_the_chip(monkeypatch):
                           floor)
 
 
-def _compile_serving_graphs(hf_attrs, layers, tp, devices, serve, only=None):
-    """Lower + compile the paged serving graph family (or the ``only``
-    subset) for ``devices`` and return {name: compiled text}."""
+def _serving_shapes(hf_attrs, layers, tp, devices, serve):
+    """(spec, tcfg, mesh, params, cache, sds, max_blocks): the paged serving
+    graphs' static arguments and their operands as shapes sharded on
+    ``devices``."""
     mesh = build_mesh(MeshConfig(tp=tp), devices)
     tcfg = TpuConfig(tp_degree=tp, dtype="bfloat16", enable_bucketing=True,
                      is_block_kv_layout=True, is_prefix_caching=True, **serve)
@@ -102,7 +104,15 @@ def _compile_serving_graphs(hf_attrs, layers, tp, devices, serve, only=None):
         head_dim=spec.head_dim, dtype=spec.kv_dtype)
     cache = {k: sds(bspec.shape, bspec.dtype, block_cache_pspec())
              for k in ("k", "v")}
-    b, mb = tcfg.batch_size, bspec.blocks_for(tcfg.seq_len)
+    return spec, tcfg, mesh, params, cache, sds, bspec.blocks_for(tcfg.seq_len)
+
+
+def _compile_serving_graphs(hf_attrs, layers, tp, devices, serve, only=None):
+    """Lower + compile the paged serving graph family (or the ``only``
+    subset) for ``devices`` and return {name: compiled text}."""
+    spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
+        hf_attrs, layers, tp, devices, serve)
+    b = tcfg.batch_size
     i32 = jnp.int32
     rng = sds((2,), jnp.uint32)
     width = tcfg.context_encoding_buckets[0]
@@ -157,6 +167,46 @@ def test_olmoe_1b_7b_serving_graphs_compile_for_v5e(v5e_devices):
              context_encoding_buckets=[128]),
         only=("ragged_w1", "ragged_prefill"))
     assert MOSAIC in texts["ragged_w1"]
+
+
+def test_olmoe_chunk_reads_experts_and_pool_in_place(v5e_devices):
+    """ISSUE 31: the one-row 256-token chunk program at OLMoE's widths
+    (the benchmark's ``paged.w256``, depth 2) copies no layer's expert
+    weights and no layer of the pool in front of a consumer that cannot
+    fuse a slice — the five ``dynamic-slice_bitcast_fusion`` ops that were
+    26 of its 38 ms — and its temps are the attention's, not 3 x 268 MB of
+    weights. The decode step of the same spec keeps the dense all-experts
+    path: no ``ragged-dot``, no ``moe_ragged`` note."""
+    spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
+        OLMOE_1B_7B, 2, 1, v5e_devices[:1],
+        dict(batch_size=16, seq_len=4096, pa_block_size=32,
+             pa_num_blocks=1024, context_encoding_buckets=[64, 256]))
+    i32 = jnp.int32
+
+    def compiled(rows, width):
+        notes = set()
+        with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
+            c = jax.jit(partial(model_base.paged_forward_step, spec, tcfg),
+                        donate_argnums=(1,)).lower(
+                params, cache, *(sds((rows, width), i32),) * 3,
+                sds((rows, mb), i32), sds((rows,), i32), None,
+                sds((2,), jnp.uint32)).compile()
+        return c, {n[1:] for n in notes if n[0] == "moe_ragged"}
+
+    chunk, notes = compiled(1, 256)
+    assert notes == {("stacked", "")}
+    text = chunk.as_text()
+    assert "ragged-dot" in text
+    copied = re.findall(r"slice_bitcast_fusion[.\d]* = bf16\[([\d,]+)\]",
+                        text)
+    layer_shapes = {"64,2048,1024", "64,1024,2048", "1025,32,16,128"}
+    assert not layer_shapes & set(copied), copied
+    assert chunk.memory_analysis().temp_size_in_bytes < 200e6
+
+    decode, notes = compiled(16, 1)
+    assert notes == set()
+    assert "ragged-dot" not in decode.as_text()
+    assert MOSAIC in decode.as_text()        # the paged decode kernel
 
 
 def test_without_the_request_nothing_is_interpreted(v5e_devices,

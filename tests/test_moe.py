@@ -2,6 +2,8 @@
 tiny-model goldens vs HF CPU for Mixtral and Qwen3-MoE (reference analog:
 test/integration tiny_model/features MoE coverage, SURVEY §4)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,12 +12,15 @@ import torch
 
 from neuronx_distributed_inference_tpu.config import (TpuConfig,
                                                       load_pretrained_config)
-from neuronx_distributed_inference_tpu.models.application import \
-    CausalLMApplication
+from neuronx_distributed_inference_tpu.models import model_base
+from neuronx_distributed_inference_tpu.models.application import (
+    CausalLMApplication, PagedCausalLMApplication)
 from neuronx_distributed_inference_tpu.models.family import get_family
 from neuronx_distributed_inference_tpu.modules import moe as moe_mod
+from neuronx_distributed_inference_tpu.ops import kernel_mode
 from neuronx_distributed_inference_tpu.parallel.mesh import (MeshConfig,
-                                                             build_mesh)
+                                                             build_mesh,
+                                                             mesh_from_config)
 
 
 def _moe_spec(**over):
@@ -261,3 +266,126 @@ def test_tkg_local_quantized_moe_warns_and_counts(caplog):
     assert spec.moe.tkg_experts_local
     assert not any("tkg_experts_local" in r.getMessage()
                    for r in caplog.records)
+
+
+# ---------------------------------------------------------------------------
+# the ragged path reads a layer's experts out of the stack in place (ISSUE 31)
+# ---------------------------------------------------------------------------
+
+STACK_L, STACK_E = 5, 6
+
+
+def _stacked_case(rng, expert_bias, empty):
+    """A toy stack (L, E, ...) and a routing of 24 tokens; ``empty`` keeps
+    experts 1 and 4 out of it (their groups have no rows)."""
+    spec = _moe_spec(num_experts=STACK_E, top_k=2, expert_bias=expert_bias)
+    L, e, h, i = STACK_L, STACK_E, 16, 32
+    f32 = np.float32
+
+    def rand(*shape, scale=1.0):
+        return jnp.asarray(rng.normal(size=shape).astype(f32) * scale)
+    x = rand(3, 8, h)
+    w = [rand(L, e, h, i, scale=0.1), rand(L, e, h, i, scale=0.1),
+         rand(L, e, i, h, scale=0.1)]
+    b = ([rand(L, e, i), rand(L, e, i), rand(L, e, h)]
+         if expert_bias else [None] * 3)
+    rw = rng.normal(size=(h, e)).astype(f32)
+    top_vals, top_idx = moe_mod.route(spec, x, jnp.asarray(rw))
+    if empty:
+        # send experts 1 and 4's token copies to their neighbours
+        top_idx = jnp.where(jnp.isin(top_idx, jnp.asarray([1, 4])),
+                            top_idx + 1, top_idx)
+        assert not np.isin(np.asarray(top_idx), [1, 4]).any()
+    return spec, x, top_vals, top_idx, w, b
+
+
+@pytest.mark.parametrize("layer", [0, STACK_L // 2, STACK_L - 1])
+@pytest.mark.parametrize("empty", [False, True], ids=["all", "some-empty"])
+@pytest.mark.parametrize("expert_bias", [False, True], ids=["nobias", "bias"])
+def test_ragged_on_the_stack_equals_the_layer_slice(rng, layer, empty,
+                                                    expert_bias):
+    """Bit for bit: the same groups multiply the same operands; the other
+    layers' groups are empty and own no row."""
+    spec, x, tv, ti, w, b = _stacked_case(rng, expert_bias, empty)
+    want = moe_mod.experts_ragged(
+        spec, x, tv, ti, *(a[layer] for a in w),
+        *(None if a is None else a[layer] for a in b))
+    got = moe_mod.experts_ragged(spec, x, tv, ti, *w, *b, layer=layer)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # and with the index traced, as the layer scan hands it over (jitted
+    # against jitted: a fused combine rounds its last step differently)
+    want = jax.jit(lambda li: moe_mod.experts_ragged(
+        spec, x, tv, ti, *(a[li] for a in w),
+        *(None if a is None else a[li] for a in b)))(jnp.int32(layer))
+    traced = jax.jit(lambda li: moe_mod.experts_ragged(
+        spec, x, tv, ti, *w, *b, layer=li))(jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(traced), np.asarray(want))
+
+
+def test_moe_block_takes_a_layer_of_the_stack(rng):
+    """``moe_block`` handed ``LayerOfStack`` leaves gives what it gives on
+    the slices, and says so; a dense-path step is never handed one."""
+    spec, x, _, _, w, b = _stacked_case(rng, True, False)
+    spec = dataclasses.replace(spec, dense_max_tokens=8)
+    names = moe_mod.EXPERT_LEAVES
+    stack = dict(zip(names, w + b))
+    router = jnp.asarray(rng.normal(size=(16, STACK_E)).astype(np.float32))
+    assert moe_mod.stack_leaves(spec, 24, stack) == names
+    assert moe_mod.stack_leaves(spec, 8, stack) == ()
+    li = 3
+    notes = set()
+    with kernel_mode.recording(notes):
+        want = moe_mod.moe_block(
+            spec, x, {"router": router,
+                      **{k: a[li] for k, a in stack.items()}})
+        got = moe_mod.moe_block(
+            spec, x, {"router": router,
+                      **{k: moe_mod.LayerOfStack(a, li)
+                         for k, a in stack.items()}})
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert notes == {("moe_ragged", "stacked", ""),
+                     ("moe_ragged", "sliced", "the caller cut the layer out")}
+
+
+OLMOE_TOY = dict(model_type="olmoe", hidden_size=64, intermediate_size=128,
+                 num_hidden_layers=3, num_attention_heads=4,
+                 num_key_value_heads=4, head_dim=16, vocab_size=512,
+                 rms_norm_eps=1e-5, rope_theta=10000.0, hidden_act="silu",
+                 tie_word_embeddings=False, torch_dtype="float32",
+                 num_experts=4, num_experts_per_tok=2, norm_topk_prob=False)
+
+
+@pytest.mark.parametrize("serve, rows, width, want", [
+    ({}, 4, 32, ("stacked", "")),
+    ({}, 4, 1, None),                       # 4 tokens: the dense path
+    (dict(quantized=True, quantization_dtype="int8"), 4, 32,
+     ("sliced", "quantized experts are dequantized per call")),
+    (dict(tp_degree=2, ep_degree=2), 4, 32,
+     ("sliced", "expert axis sharded over ep")),
+], ids=["chunk", "decode", "quantized", "ep2"])
+def test_the_paged_step_notes_how_its_ragged_path_reads(serve, rows, width,
+                                                        want):
+    """The engagement record of ISSUE 31's mechanism: an OLMoE toy's chunk
+    program notes ``moe_ragged: stacked``; a quantized or ep > 1 build
+    keeps the sliced form and says why; a dense-path step notes nothing."""
+    fam = get_family("olmoe")
+    tcfg = TpuConfig(**{**dict(
+        batch_size=4, seq_len=96, dtype="float32", enable_bucketing=True,
+        context_encoding_buckets=[32], is_block_kv_layout=True,
+        pa_block_size=8, is_prefix_caching=False), **serve})
+    app = PagedCausalLMApplication(None, fam.config_cls(tcfg, **OLMOE_TOY),
+                                   fam, mesh=mesh_from_config(tcfg))
+    app.init_random_weights(5).init_cache()
+    i32 = jnp.int32
+    ids = jax.ShapeDtypeStruct((rows, width), i32)
+    notes = set()
+    with kernel_mode.recording(notes), jax.sharding.set_mesh(app.mesh):
+        jax.eval_shape(
+            lambda p, c, a, t, n, r: model_base.paged_forward_step(
+                app.spec, app.tpu_config, p, c, a, a, a, t, n, None, r),
+            app.params, app.cache, ids,
+            jax.ShapeDtypeStruct((rows, 12), i32),
+            jax.ShapeDtypeStruct((rows,), i32),
+            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    got = {(p, why) for site, p, why in notes if site == "moe_ragged"}
+    assert got == ({want} if want else set())
