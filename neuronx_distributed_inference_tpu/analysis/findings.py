@@ -3,8 +3,7 @@
 Every pass returns a flat list of :class:`Finding`; the driver applies
 suppressions, runs the unused-suppression check, and renders one
 :class:`Report` — the same object behind the console output, the ``rc``
-and the ``--json`` artifact that ``bench.py --lint-report`` commits per
-round (so lint findings trend like bench numbers).
+and the ``--json`` artifact of ``scripts/nxdi_lint.py``.
 """
 
 from __future__ import annotations

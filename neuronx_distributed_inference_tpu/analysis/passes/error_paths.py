@@ -35,7 +35,6 @@ DEFAULT_PATHS = (
     "neuronx_distributed_inference_tpu/serving/fleet/handoff.py",
     "neuronx_distributed_inference_tpu/serving/fleet/aggregator.py",
     "neuronx_distributed_inference_tpu/serving/fleet/autoscaler.py",
-    "neuronx_distributed_inference_tpu/serving/fleet/loadgen.py",
     "neuronx_distributed_inference_tpu/serving/lora_pool.py",
     "neuronx_distributed_inference_tpu/modules/block_kv_cache.py",
     "neuronx_distributed_inference_tpu/modules/low_rank.py",

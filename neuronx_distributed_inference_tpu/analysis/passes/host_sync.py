@@ -45,7 +45,6 @@ DEFAULT_PATHS = (
     "neuronx_distributed_inference_tpu/serving/fleet/kv_tier.py",
     "neuronx_distributed_inference_tpu/serving/fleet/handoff.py",
     "neuronx_distributed_inference_tpu/serving/fleet/autoscaler.py",
-    "neuronx_distributed_inference_tpu/serving/fleet/loadgen.py",
     "neuronx_distributed_inference_tpu/serving/lora_pool.py",
     "neuronx_distributed_inference_tpu/parallel/collectives.py",
     "neuronx_distributed_inference_tpu/resilience/controller.py",

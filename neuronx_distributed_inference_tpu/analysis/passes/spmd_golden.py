@@ -9,7 +9,8 @@ exactly the graphs the script's ``PINNED`` table compiles (both
 directions — a graph added to the code but never ``--update-golden``\\ ed,
 or left in the golden after being dropped from the code, is the same
 stale-pin class the old hardcoded file counts kept hitting), and every
-pinned census entry must be well-formed (count/bytes ints).
+pinned entry must be well-formed: census count/bytes ints, the requested
+shardings as counts, and the jax / jaxlib its census was earned on.
 """
 
 from __future__ import annotations
@@ -23,7 +24,7 @@ from ..registry import LintContext, Pass, register
 
 LINT_SCRIPT = "scripts/check_spmd_sharding.py"
 GOLDEN_PATH = "artifacts/spmd_golden.json"
-GOLDEN_SCHEMA = "nxdi-spmd-golden-v1"
+GOLDEN_SCHEMA = "nxdi-spmd-golden-v2"
 
 
 def pinned_graphs(tree: ast.AST):
@@ -101,6 +102,16 @@ class SpmdGoldenPass(Pass):
                     self.name, golden_sf.rel, 1,
                     f"golden graph {name!r} has no 'collectives' table"))
                 continue
+            req, xla = entry.get("requested"), entry.get("xla")
+            if not (isinstance(req, dict) and req
+                    and all(isinstance(n, int) for n in req.values())
+                    and isinstance(xla, dict)
+                    and {"jax", "jaxlib"} <= set(xla)):
+                findings.append(Finding(
+                    self.name, golden_sf.rel, 1,
+                    f"golden graph {name!r} lacks its 'requested' sharding "
+                    "counts or the 'xla' (jax / jaxlib) its census was "
+                    "earned on"))
             for key, c in sorted(coll.items()):
                 if not (isinstance(c, dict)
                         and isinstance(c.get("count"), int)

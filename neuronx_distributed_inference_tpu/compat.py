@@ -1,7 +1,7 @@
 """The CPU stand-in for the accelerator: N virtual devices and
 interpret-mode Pallas kernels, for runs that check sharding and control
 flow without a chip (``__graft_entry__.py``, the lint scripts,
-``inference_demo --on-cpu``, ``bench.py``'s report modes)."""
+``inference_demo --on-cpu``)."""
 
 from __future__ import annotations
 

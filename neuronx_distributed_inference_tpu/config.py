@@ -65,10 +65,8 @@ class OnDeviceSamplingConfig:
     top_k: int = 1
     top_p: float = 1.0
     temperature: float = 1.0
-    dynamic: bool = True          # per-request sampling params tensor
     deterministic: bool = False
     global_topk: int = 256        # stage-1 topk width for hierarchical top-k
-    on_device: bool = True
     # Positionally coupled streams (ops/sampling.coupled_sample): every
     # draw keyed by (stream_seed, request seed, absolute position), so
     # sampled streams are reproducible and path-invariant — the knob
@@ -85,9 +83,7 @@ class OnDeviceSamplingConfig:
 class ChunkedPrefillConfig:
     """Chunked prefill / prefix caching (reference: models/config.py:1078-1094)."""
 
-    max_num_seqs: int = 8
     kernel_q_tile_size: int = 128
-    kernel_kv_tile_size: int = 1024
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -97,12 +93,7 @@ class ChunkedPrefillConfig:
 class MoEConfig:
     """MoE knobs (reference: models/config.py:798-846 ``MoENeuronConfig``)."""
 
-    capacity_factor: Optional[float] = None   # None => full capacity (dropless)
-    glu_mlp: bool = True
-    glu_type: str = "glu"
     normalize_top_k_affinities: bool = True
-    early_expert_affinity_modulation: bool = False
-    fused_shared_experts: bool = False
     routed_scaling_factor: Optional[float] = None
     moe_tp_degree: Optional[int] = None       # defaults to tp_degree
     moe_ep_degree: Optional[int] = None       # defaults to ep_degree
@@ -128,7 +119,6 @@ class LoraServingConfig:
     max_lora_rank: int = 16
     target_modules: Optional[List[str]] = None
     lora_ckpt_paths: Optional[Dict[str, str]] = None
-    lora_dtype: str = "bfloat16"
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -138,22 +128,17 @@ class LoraServingConfig:
 class SpeculationConfig:
     """Speculative decoding knobs (reference: models/config.py:243-274 block).
 
-    Covers vanilla draft/target, EAGLE and Medusa variants; the fused-spec
-    draft model class is referenced by import path so the config JSON
-    round-trips (reference: models/config.py:956-1038).
+    Covers vanilla draft/target, EAGLE and Medusa variants.
     """
 
     speculation_length: int = 0
-    spec_batch_size: Optional[int] = None
     enable_fused_speculation: bool = False
     enable_eagle_speculation: bool = False
     enable_eagle_draft_input_norm: bool = False
-    is_eagle_draft: bool = False
     medusa_speculation_length: int = 0
     num_medusa_heads: int = 0
     token_tree_config: Optional[Dict[str, Any]] = None
     draft_model_path: Optional[str] = None
-    draft_model_module: Optional[str] = None  # "module:Class" for round-trip
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -213,6 +198,16 @@ class CollectiveConfig:
         return dataclasses.asdict(self)
 
 
+def _known_keys(cls, d: Dict[str, Any]) -> Dict[str, Any]:
+    """``d`` without the keys ``cls`` has no field for, each warned about
+    (reference: models/config.py:639-640): an older tree's config loads."""
+    known = {f.name for f in dataclasses.fields(cls)}
+    for k in d:
+        if k not in known:
+            logger.warning("%s: ignoring unknown key %r", cls.__name__, k)
+    return {k: v for k, v in d.items() if k in known}
+
+
 _SUBCONFIG_TYPES = {
     "on_device_sampling_config": OnDeviceSamplingConfig,
     "chunked_prefill_config": ChunkedPrefillConfig,
@@ -249,14 +244,12 @@ class TpuConfig:
     # the (S, S) prefill attention materialization becomes (W, S), which
     # is what makes >=32k contexts feasible. None = one-shot prefill.
     windowed_context_encoding: Optional[int] = None
-    n_active_tokens: int = 1
     n_positions: Optional[int] = None
 
     # --- dtypes ---
     dtype: str = "bfloat16"                   # weights/activations
     kv_cache_dtype: Optional[str] = None      # default = dtype; fp8 supported
     logits_dtype: str = "float32"
-    rope_dtype: str = "float32"
 
     # --- parallelism degrees (reference: models/config.py:361-390) ---
     tp_degree: int = 1
@@ -264,18 +257,14 @@ class TpuConfig:
     attention_dp_degree: int = 1              # data parallel decode attention
     pp_degree: int = 1
     ep_degree: int = 1
-    mlp_cp_degree: int = 1
     sequence_parallel_enabled: bool = False
     # vocab-parallel embedding table (sharded on V); False replicates the
     # table on every device (reference: models/config.py:142)
     vocab_parallel: bool = True
     world_size: Optional[int] = None
-    start_rank_id: int = 0
-    local_ranks_size: Optional[int] = None
 
     # --- KV cache (reference: models/config.py:167-170, 277-317) ---
     kv_cache_batch_size: Optional[int] = None
-    kv_cache_padding_size: int = 0
     is_block_kv_layout: bool = False
     # rolling sliding-window KV cache (reference: kv_cache_manager.py:605-606
     # pos %% (w-1) rolling write): cache holds only ``sliding_window`` slots.
@@ -292,7 +281,6 @@ class TpuConfig:
     buckets: Optional[List[int]] = None           # explicit decode buckets
     context_encoding_buckets: Optional[List[int]] = None
     token_generation_buckets: Optional[List[int]] = None
-    bucket_n_active_tokens: bool = False
     # 2-D bucketing (reference: autobucketing.py:22-64,203 — batch x seq
     # TKG buckets + prefix x prefill buckets; selection
     # model_wrapper.py:923-1045): short batches pad to the smallest BATCH
@@ -333,7 +321,6 @@ class TpuConfig:
     quantized: bool = False
     quantization_dtype: str = "int8"
     quantization_type: str = "per_channel_symmetric"
-    quantized_checkpoints_path: Optional[str] = None
     modules_to_not_convert: Optional[List[str]] = None
     kv_cache_quant: bool = False
     # scaled-mode KV quantization: store x/scale (reference:
@@ -353,26 +340,16 @@ class TpuConfig:
     # kernel on v5e); True = opt into the Pallas flash prefill kernel where
     # ops/flash_attention.supports() holds (tp=1, arange positions)
     attn_kernel_enabled: Optional[bool] = None
-    qkv_kernel_enabled: bool = False
-    mlp_kernel_enabled: bool = False
-    attn_block_tkg_nki_kernel_enabled: bool = False
     # Pallas fused decode attention (reference: attn_block_tkg NKI kernel
     # family, models/config.py:417-567); None = auto (on where supported)
     attn_block_tkg_kernel_enabled: Optional[bool] = None
 
-    # --- async / host loop (reference: models/config.py:183) ---
-    async_mode: bool = False
+    # --- host loop ---
     decode_chunk_tokens: int = 1              # tokens per device call in decode
 
     # --- misc / runtime ---
-    rpl_reduce_dtype: Optional[str] = None
-    cast_type: str = "config"                 # or "as-declared"
     save_sharded_checkpoint: bool = False
-    skip_sharding: bool = False
     seed: int = 0
-
-    # note: unknown kwargs warn (reference: models/config.py:639-640) — handled
-    # by from_dict below.
 
     def __post_init__(self):
         if self.max_context_length is None:
@@ -394,8 +371,6 @@ class TpuConfig:
             # them rather than multiplying the world (reference:
             # models/config.py:382-390 world-size calc)
             self.world_size = self.tp_degree * self.pp_degree
-        if self.local_ranks_size is None:
-            self.local_ranks_size = self.world_size
         self.validate()
 
     # -- validation (reference: models/config.py:645-721) --
@@ -422,14 +397,6 @@ class TpuConfig:
             raise ValueError(
                 "pp_degree > 1 is not supported: inference has no pipeline "
                 "schedule (shard wider with tp_degree instead)")
-        if self.mlp_cp_degree > 1:
-            if not self.sequence_parallel_enabled or \
-                    self.mlp_cp_degree != max(self.cp_degree, 1):
-                raise ValueError(
-                    "mlp_cp_degree requires sequence_parallel_enabled and "
-                    "mlp_cp_degree == cp_degree: MLP context parallelism is "
-                    "realized as sequence-sharded MLP activations over the "
-                    "cp axis (model_base._layer_body sp_axis)")
         if self.is_chunked_prefill and not self.is_block_kv_layout:
             raise ValueError("chunked prefill requires block KV layout")
         if self.is_prefix_caching and not self.is_block_kv_layout:
@@ -497,16 +464,10 @@ class TpuConfig:
 
     @classmethod
     def from_dict(cls, d: Dict[str, Any]) -> "TpuConfig":
-        d = dict(d)
-        known = {f.name for f in dataclasses.fields(cls)}
+        d = _known_keys(cls, d)
         for key, sub_cls in _SUBCONFIG_TYPES.items():
             if isinstance(d.get(key), dict):
-                d[key] = sub_cls(**d[key])
-        unknown = [k for k in d if k not in known]
-        for k in unknown:
-            # warn-on-unknown (reference: models/config.py:639-640)
-            logger.warning("TpuConfig: ignoring unknown key %r", k)
-            d.pop(k)
+                d[key] = sub_cls(**_known_keys(sub_cls, d[key]))
         return cls(**d)
 
 

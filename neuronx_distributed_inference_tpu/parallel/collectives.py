@@ -1,7 +1,7 @@
 """Quantized decode collectives — EQuARX-style (PAPERS.md, arxiv 2506.17615)
 int8/fp8 ring all-reduce / reduce-scatter for the tp decode collectives.
 
-Decode is comm-bound under the ring model (artifacts/sharding_report_r18.json):
+Under the ring cost model decode is comm-bound (a model, not a chip timing):
 the row-parallel all-reduce after ``o_proj`` / ``down_proj`` moves fp32 wire
 bytes every step. This module replaces that implicit GSPMD all-reduce with an
 EXPLICIT ``shard_map`` two-phase ring exchange whose per-hop payload is

@@ -29,8 +29,7 @@ randomized fault schedule. :class:`ChaosCampaign` is that driver:
          cell, not silent vacuous green).
 
 The campaign is fully seeded (prompts AND the router's backoff jitter),
-so a red cell reproduces. ``bench.py --chaos-report`` sweeps the full
-matrix and commits ``artifacts/bench_chaos_r15.json``;
+so a red cell reproduces.
 tests/test_resilience_control.py runs a seeded random subset tier-1 and
 red-verifies the harness on a doctored invariant (a deliberately leaked
 block must fail the campaign).

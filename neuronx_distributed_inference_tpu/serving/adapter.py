@@ -766,8 +766,8 @@ class _EngineAdapterBase:
         # unaffected (pinned by tests/test_resilience_control.py)
         self._spec_shed = False        # clamp draft widths to 1 (no draft)
         self._ragged_shed = False      # ragged -> two-phase dispatching
-        # plain-int host counters (always on — they feed the CPU
-        # microbenches, bench.py --host-overhead / --prefill-overhead).
+        # plain-int host counters, always on: the benchmark's per-layer
+        # metrics adapter.dispatches_per_token / .prefill_pad_share read them.
         # The decode counters (dispatches/blocking_fetches/...) count ONLY
         # decode work; chunked prefill keeps its own prefill_* set so the
         # two stay separately comparable.

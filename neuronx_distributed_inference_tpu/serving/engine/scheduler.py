@@ -74,7 +74,7 @@ class ServingEngine:
     ``slo`` attaches a :class:`~...telemetry.slo.SLOTracker`: the engine
     feeds it TTFT (submit → first token), per-request mean TPOT and queue
     wait per tenant, host-side only — its report/hint surface is
-    read-only (``debug_state()["slo"]``, ``bench.py --slo-report``)."""
+    read-only (``debug_state()["slo"]``, served at ``/v1/debug/state``)."""
 
     def __init__(self, adapter, *,
                  tenant_weights: Optional[Dict[str, float]] = None,

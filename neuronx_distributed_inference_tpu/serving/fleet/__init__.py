@@ -27,10 +27,7 @@ Elastic on top (ISSUE 17): :func:`~.handoff.migrate` moves a
 MID-DECODE stream between replicas with its KV (the handoff wire form,
 live), :class:`~.autoscaler.FleetAutoscaler` closes the loop on fleet
 signals (queue / SLO burn / admission headroom) to resize the replica
-set with precompile-first admission and drain-by-migration retirement,
-and :mod:`~.loadgen` generates the seeded workloads
-(``diurnal_ramp`` / ``tenant_burst`` / ``heavy_tail``) that
-``bench.py --autoscale-report`` and the chaos campaign replay.
+set with precompile-first admission and drain-by-migration retirement.
 """
 
 from .aggregator import FleetMetricsAggregator
@@ -38,7 +35,6 @@ from .autoscaler import FleetAutoscaler
 from .handoff import (HANDOFF_SCHEMA, admit_handoff, capture_handoff,
                       handoff_from_json, handoff_to_json, migrate)
 from .kv_tier import HostKVSpillTier
-from .loadgen import Arrival, diurnal_ramp, heavy_tail, tenant_burst
 from .router import (BACKING_OFF, DEAD, DRAINING, HEALTHY, PROBATION,
                      EngineRouter)
 
@@ -48,5 +44,4 @@ __all__ = [
     "HostKVSpillTier", "FleetMetricsAggregator", "FleetAutoscaler",
     "HANDOFF_SCHEMA", "capture_handoff", "admit_handoff", "migrate",
     "handoff_to_json", "handoff_from_json",
-    "Arrival", "diurnal_ramp", "tenant_burst", "heavy_tail",
 ]

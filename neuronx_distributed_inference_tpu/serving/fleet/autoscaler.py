@@ -135,8 +135,7 @@ class FleetAutoscaler:
         self.stats: Dict[str, int] = {
             "evaluations": 0, "scale_ups": 0, "scale_downs": 0,
             "reaped": 0, "rejected_cold": 0, "aborted": 0}
-        #: action timeline for ``bench.py --autoscale-report``:
-        #: [{"t", "action", "replica", ...}, ...]
+        #: action timeline, bounded: [{"t", "action", "replica", ...}, ...]
         self.history: List[Dict[str, Any]] = []
 
     # -- signals -----------------------------------------------------------

@@ -61,7 +61,7 @@ class HostKVSpillTier:
         # hash -> {"k": np (L, Bs, H, D), "v": np (L, Bs, H, D)}
         self._pool: "OrderedDict[bytes, Dict[str, np.ndarray]]" = \
             OrderedDict()
-        # always-on host counters (feed bench.py --fleet-load)
+        # always-on host counters (asserted by tests/test_fleet.py)
         self.stats: Dict[str, int] = {
             "spilled": 0, "restored": 0, "evicted": 0, "hits": 0,
             "misses": 0, "seeded": 0, "spill_errors": 0}

@@ -40,8 +40,8 @@ paths itself.
 
 Observability: ``nxdi_lora_residency_hits_total`` /
 ``nxdi_lora_swaps_total{adapter}`` / ``nxdi_lora_swap_bytes`` (README
-"Observability"), the always-on :attr:`stats` counters (feed
-``bench.py --lora-churn``), and ``lora.swap`` / ``lora.spill`` flight-
+"Observability"), the always-on :attr:`stats` counters (served under
+``debug_state()["lora"]``), and ``lora.swap`` / ``lora.spill`` flight-
 recorder events.
 """
 

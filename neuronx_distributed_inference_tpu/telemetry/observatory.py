@@ -24,9 +24,10 @@ compute-vs-memory-bound verdict, and the estimated step time projected
 onto the published peaks of a named ``device_kind`` (utils/device.py
 ``DEVICE_PEAKS``; default v5e — 197 TFLOP/s bf16, 819 GB/s HBM).
 
-``bench.py --graph-report`` drives this on the tiny synthetic model and
-commits the artifact (``artifacts/graph_report_r08.json``) so cold-start
-and graph-size regressions show up as a diff with no hardware.
+tests/test_graph_observatory.py drives this on the tiny synthetic model:
+graph counts, flops and bytes are exact on any backend; the projected
+step time is an estimate, never a measurement (those come from
+``benchmark/run.py`` on the chip).
 
 Compiling through fresh ``jax.jit`` wrappers keeps the application's own
 jit cache keys untouched — running the observatory can never change what

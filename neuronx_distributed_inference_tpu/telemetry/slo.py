@@ -30,7 +30,7 @@ the long window proves it is real, the short window proves it is still
 happening). The hint is **advisory** in this PR: the router/scheduler
 may consult it (shed speculation when decode latency burns, tighten
 admission when queue wait burns) but nothing acts on it yet — it is
-wired read-only into ``/v1/debug/state`` and ``bench.py --slo-report``.
+wired read-only into ``/v1/debug/state``.
 """
 
 from __future__ import annotations
@@ -200,8 +200,7 @@ class SLOTracker:
         """JSON-able per-tenant SLO report: per-signal sample count,
         p50/p99 over the long window and — for targeted signals —
         short/long attainment + burn rate. Served read-only as the
-        ``slo`` section of ``/v1/debug/state`` and by
-        ``bench.py --slo-report``."""
+        ``slo`` section of ``/v1/debug/state``."""
         if now is None:
             now = time.perf_counter()
         pol = self.policy

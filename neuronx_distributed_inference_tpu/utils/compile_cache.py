@@ -7,7 +7,7 @@ suite's per-user temp dir); otherwise one fixed, git-ignored directory at
 the root of the checkout. The path is part of jax's cache key, so it must
 not move between runs — no ``mkdtemp``, no per-model directory.
 
-The application, ``chip_smoke.py``, ``bench.py`` and ``tests/conftest.py``
+The application, ``benchmark/run.py`` and ``tests/conftest.py``
 all call :func:`configure_compile_cache`; nothing else may set
 ``jax_compilation_cache_dir`` (pinned by tests/test_chip_rules.py).
 """

@@ -2,7 +2,7 @@
 """Tier-1 SPMD regression guard: compile the multichip graphs on a CPU
 mesh and assert on the partitioned HLO (ROADMAP item 2's lint).
 
-Three failure channels, all ahead of hardware:
+Four failure channels, all ahead of hardware:
 
   1. **Involuntary full rematerialization** — the SPMD partitioner's
      "replicate the tensor and then partition it" last resort (the exact
@@ -13,15 +13,20 @@ Three failure channels, all ahead of hardware:
      ``spmd_partitioner.cc``) AND structurally in the optimized HLO (a
      full-mesh ``all-gather`` feeding a ``dynamic-slice`` is
      replicate-then-partition by construction).
-  2. **Collective census drift** — every collective of every pinned
+  2. **Requested shardings** — what THIS code asks of the partitioner:
+     every argument's sharding, every ``with_sharding_constraint`` and
+     ``shard_map`` of the jaxpr, as counts per spec, diffed exactly
+     against the committed golden ``artifacts/spmd_golden.json``.
+  3. **Collective census drift** — every collective of every pinned
      graph (kind x mesh-axis comm group, counts + payload bytes, via
-     ``telemetry/observatory.census_collectives``) is diffed against the
-     committed golden ``artifacts/spmd_golden.json``. A new collective,
-     a changed count, or payload bytes drifting past ±25% is a red test
-     — not a folklore bench delta three rounds later. Improvements fail
-     too (symmetric, like check_metric_names): rerun with
-     ``--update-golden`` to re-earn the golden.
-  3. **SPMD warning channel** — any other ``[SPMD]`` partitioner
+     ``telemetry/observatory.census_collectives``) is what XLA EMITTED,
+     so it is diffed only where the golden entry was earned on the
+     running jax / jaxlib (each entry records them); otherwise the lint
+     says "golden earned on another XLA, census not compared". Where
+     compared, a new collective, a changed count, or payload bytes
+     drifting past ±25% is red, improvements too (symmetric): rerun
+     with ``--update-golden`` to re-earn the golden.
+  4. **SPMD warning channel** — any other ``[SPMD]`` partitioner
      complaint during the pinned compiles fails the run.
 
 Pinned graph set (tiny configs reusing ``__graft_entry__``'s mesh
@@ -86,7 +91,7 @@ REPO_ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO_ROOT))    # package + __graft_entry__ imports
 
 GOLDEN_PATH = REPO_ROOT / "artifacts" / "spmd_golden.json"
-GOLDEN_SCHEMA = "nxdi-spmd-golden-v1"
+GOLDEN_SCHEMA = "nxdi-spmd-golden-v2"
 BYTES_TOL = 1.25          # golden payload-bytes drift tolerance (either way)
 
 
@@ -285,17 +290,54 @@ PINNED: Dict[str, Any] = {
 }
 
 
-def compile_pinned(name: str) -> Tuple[Any, str, str]:
-    """Compile one pinned graph on its CPU mesh. Returns (mesh, optimized
-    HLO text, captured compiler stderr)."""
+def running_xla() -> Dict[str, str]:
+    import jax
+    import jaxlib
+    return {"jax": jax.__version__, "jaxlib": jaxlib.__version__}
+
+
+def requested_shardings(jaxpr, args) -> Dict[str, int]:
+    """Counts per spec of the argument shardings, sharding constraints
+    and shard_maps of one traced graph (sub-jaxprs included)."""
+    import jax
+    from collections import Counter
+    keys = []
+    for leaf in jax.tree_util.tree_leaves(args):
+        sharding = getattr(leaf, "sharding", None)
+        keys.append(
+            f"input:{getattr(sharding, 'spec', type(sharding).__name__)}")
+
+    def walk(jp) -> None:
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "sharding_constraint":
+                keys.append(f"constraint:{eqn.params['sharding'].spec}")
+            elif eqn.primitive.name == "shard_map":
+                keys.append(f"shard_map:{eqn.params['in_specs']}->"
+                            f"{eqn.params['out_specs']}")
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (list, tuple))
+                            else (value,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jaxpr)
+    return dict(Counter(keys))
+
+
+def compile_pinned(name: str) -> Tuple[Any, Dict[str, int], str, str]:
+    """Trace and compile one pinned graph on its CPU mesh. Returns (mesh,
+    requested shardings, optimized HLO text, captured compiler stderr)."""
     import jax
     from neuronx_distributed_inference_tpu.telemetry.observatory import \
         capture_compiler_stderr
     mesh, fn, args, kwargs = PINNED[name]()
     with capture_compiler_stderr() as captured:
         with jax.sharding.set_mesh(mesh):
-            compiled = fn.lower(*args, **kwargs).compile()
-    return mesh, compiled.as_text(), captured[0]
+            traced = fn.trace(*args, **kwargs)
+            compiled = traced.lower().compile()
+    requested = requested_shardings(traced.jaxpr.jaxpr, (args, kwargs))
+    return mesh, requested, compiled.as_text(), captured[0]
 
 
 # ---------------------------------------------------------------------------
@@ -327,6 +369,24 @@ def diff_census(graph: str, golden: Dict[str, Dict[str, Any]],
                 msgs.append(f"{graph}: {key} payload bytes {g['bytes']} "
                             f"-> {c['bytes']} ({ratio:.2f}x)")
     return msgs
+
+
+def diff_graph(name: str, gentry: Dict[str, Any], current: Dict[str, Any]
+               ) -> Tuple[List[str], bool]:
+    """One pinned graph against its golden entry: the requested shardings
+    always (exact), the census only when the entry was earned on the XLA
+    that produced ``current``. Returns (findings, census compared?)."""
+    msgs = []
+    want, got = gentry["requested"], current["requested"]
+    for key in sorted(set(want) | set(got)):
+        if want.get(key) != got.get(key):
+            msgs.append(f"{name}: requested sharding {key} x"
+                        f"{want.get(key, 0)} -> x{got.get(key, 0)}")
+    compared = gentry["xla"] == current["xla"]
+    if compared:
+        msgs += diff_census(name, gentry["collectives"],
+                            current["collectives"])
+    return msgs, compared
 
 
 def load_golden(path: Path) -> Dict[str, Any]:
@@ -479,16 +539,20 @@ def main(argv: Sequence[str] = ()) -> int:
 
     findings: List[str] = []
     results: Dict[str, Any] = {}
+    not_compared: List[str] = []
+    xla = running_xla()
     for name in names:
         import numpy as np
-        mesh, hlo, stderr_text = compile_pinned(name)
+        mesh, requested, hlo, stderr_text = compile_pinned(name)
         n_part = int(np.prod(mesh.devices.shape))
         census = observatory.aggregate_census(
             observatory.census_collectives(hlo, mesh))
         results[name] = {
             "mesh": {a: int(s) for a, s in
                      zip(mesh.axis_names, mesh.devices.shape) if s > 1},
+            "requested": requested,
             "collectives": census,
+            "xla": xla,
         }
         findings += _lint_hlo(name, hlo, stderr_text, n_part)
         if not census:
@@ -501,9 +565,14 @@ def main(argv: Sequence[str] = ()) -> int:
                 findings.append(f"{name}: not in the golden — run "
                                 "--update-golden to pin it")
             else:
-                findings += diff_census(name, gentry["collectives"],
-                                        census)
+                msgs, compared = diff_graph(name, gentry, results[name])
+                findings += msgs
+                if not compared:
+                    not_compared.append(name)
 
+    if not_compared:
+        print("check_spmd_sharding: golden earned on another XLA, census "
+              f"not compared: {', '.join(not_compared)}")
     for f in findings:
         print(f"check_spmd_sharding: {f}", file=sys.stderr)
     if findings:

@@ -26,11 +26,9 @@ Usage::
     python scripts/nxdi_lint.py                    # --all (default)
     python scripts/nxdi_lint.py --passes host-sync,donation-safety
     python scripts/nxdi_lint.py --list             # pass catalog
-    python scripts/nxdi_lint.py --all --json artifacts/lint_report_r10.json
+    python scripts/nxdi_lint.py --all --json /tmp/lint_report.json
 
-Wired into the suite as tier-1 (``tests/test_nxdi_lint.py``) and into
-``bench.py --lint-report`` so findings trend across rounds like bench
-numbers.
+Wired into the suite as tier-1 (``tests/test_nxdi_lint.py``).
 """
 
 from __future__ import annotations
@@ -63,15 +61,12 @@ def load_analysis():
 
 
 def run(names=None, repo_root=REPO_ROOT):
-    """In-process API (used by bench.py --lint-report and the tests):
-    returns the analysis Report."""
+    """In-process API (the tests): returns the analysis Report."""
     return load_analysis().run_passes(repo_root, names=names)
 
 
 def write_artifact(report, path) -> None:
-    """THE ``nxdi-lint-v1`` artifact serialization — ``--json`` and
-    ``bench.py --lint-report`` both write through here, so exactly one
-    writer owns the schema at ``artifacts/lint_report_*.json``."""
+    """THE ``nxdi-lint-v1`` serialization behind ``--json``."""
     path = Path(path)
     path.parent.mkdir(parents=True, exist_ok=True)
     path.write_text(json.dumps(report.to_json(), indent=1,

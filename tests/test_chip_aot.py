@@ -5,9 +5,9 @@ topology_name="v5e:2x2")`` hands back four abstract v5e devices while the
 process itself stays on the CPU backend; lowering a jitted function over
 ``ShapeDtypeStruct`` arguments sharded on them runs XLA:TPU and Mosaic from
 the installed libtpu. This is what keeps "the kernels compile, and stay
-compiled" true between chip runs (``chip_smoke.py`` is the run itself):
+compiled" true between chip runs (``benchmark/run.py`` is the run itself):
 
-  * Llama-3.2-1B widths (the smoke's geometry, depth 2), tp=1 and tp=4:
+  * Llama-3.2-1B widths (dense, heads of 64, depth 2), tp=1 and tp=4:
     ``paged_forward_step`` at T=1 and one prefill width,
     ``paged_decode_loop`` and ``paged_ragged_step``, kernels compiled for
     real — the T=1 graphs must hold a Mosaic custom call, and the tp=4
@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-import chip_smoke
 from neuronx_distributed_inference_tpu.config import TpuConfig
 from neuronx_distributed_inference_tpu.models import model_base
 from neuronx_distributed_inference_tpu.models.family import get_family
@@ -37,6 +36,21 @@ from neuronx_distributed_inference_tpu.parallel.mesh import (MeshConfig,
 from neuronx_distributed_inference_tpu.telemetry import observatory
 
 MOSAIC = 'custom_call_target="tpu_custom_call"'
+
+LLAMA_3_2_1B = dict(
+    model_type="llama", hidden_size=2048, intermediate_size=8192,
+    num_hidden_layers=16, num_attention_heads=32, num_key_value_heads=8,
+    head_dim=64, vocab_size=128256, rms_norm_eps=1e-5, rope_theta=500000.0,
+    rope_scaling={"factor": 32.0, "high_freq_factor": 4.0,
+                  "low_freq_factor": 1.0,
+                  "original_max_position_embeddings": 8192,
+                  "rope_type": "llama3"},
+    max_position_embeddings=131072, hidden_act="silu",
+    tie_word_embeddings=True)
+# 1024 blocks of 32 = 1.07 GB of pool; XLA's account of the widest graph (a
+# 256-wide ragged row) is 3.7 GB of arguments + 4.8 GB of temps, of 16.
+SERVE = dict(batch_size=8, seq_len=2048, pa_block_size=32, pa_num_blocks=1024,
+             context_encoding_buckets=[64, 256])
 
 # allenai/OLMoE-1B-7B-0125-Instruct config.json (model-configs catalog)
 OLMOE_1B_7B = dict(
@@ -150,7 +164,7 @@ def test_llama_1b_serving_graphs_compile_for_v5e(v5e_devices, tp):
     counts = {"spmd_warnings": 0, "involuntary_remat": 0}
     with observatory.capture_compiler_stderr(counts, tee=False):
         texts = _compile_serving_graphs(
-            chip_smoke.LLAMA_3_2_1B, 2, tp, v5e_devices, chip_smoke.SERVE,
+            LLAMA_3_2_1B, 2, tp, v5e_devices, SERVE,
             only=("paged_t1", "paged_prefill", "decode_loop", "ragged_w1"))
     # the decode graphs hold the kernel — read from the executable
     for name in ("paged_t1", "decode_loop", "ragged_w1"):
@@ -213,9 +227,9 @@ def test_without_the_request_nothing_is_interpreted(v5e_devices,
                                                     monkeypatch):
     """The other side of the rule: with interpret mode requested the same
     lowering holds NO Mosaic call — so the request must never reach a
-    serving process on a chip (chip_smoke.py refuses to run under it)."""
+    serving process on a chip."""
     monkeypatch.setenv(kernel_mode.INTERPRET_ENV, "1")
-    texts = _compile_serving_graphs(chip_smoke.LLAMA_3_2_1B, 1, 1,
-                                    v5e_devices[:1], chip_smoke.SERVE,
+    texts = _compile_serving_graphs(LLAMA_3_2_1B, 1, 1,
+                                    v5e_devices[:1], SERVE,
                                     only=("paged_t1",))
     assert MOSAIC not in texts["paged_t1"]

@@ -171,9 +171,7 @@ def test_packed_mixed_lengths_match_individual(paged_app):
     assert real == len(P_SHORT) + len(P_LONG)
     # every dispatch runs at the 16-wide ctx bucket (this app's only one);
     # the first carries both rows and runs the full batch of 2, the other
-    # four carry row 1 alone and run one row (app.prefill_row_buckets). The
-    # strict pad-waste reduction vs monolithic over a real width ladder is
-    # pinned by bench.py --prefill-overhead
+    # four carry row 1 alone and run one row (app.prefill_row_buckets).
     assert padded == 2 * 16 + 4 * 1 * 16
     for _ in range(3):
         for s, t in eng.step().items():

@@ -1,5 +1,5 @@
 """Pallas flash attention kernel vs the XLA reference path (interpret mode on
-CPU; the real-TPU path is exercised by bench.py)."""
+CPU)."""
 
 import jax.numpy as jnp
 import numpy as np

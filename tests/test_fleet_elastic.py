@@ -28,9 +28,8 @@ from neuronx_distributed_inference_tpu.resilience.faults import FAULT_POINTS
 from neuronx_distributed_inference_tpu.serving import PagedEngineAdapter
 from neuronx_distributed_inference_tpu.serving.engine import ServingEngine
 from neuronx_distributed_inference_tpu.serving.fleet import (
-    BACKING_OFF, DEAD, DRAINING, HEALTHY, PROBATION, Arrival, EngineRouter,
-    FleetAutoscaler, HostKVSpillTier, diurnal_ramp, heavy_tail, migrate,
-    tenant_burst)
+    BACKING_OFF, DEAD, DRAINING, HEALTHY, PROBATION, EngineRouter,
+    FleetAutoscaler, HostKVSpillTier, migrate)
 from neuronx_distributed_inference_tpu.telemetry import (
     metrics as tmetrics)
 from neuronx_distributed_inference_tpu.telemetry import trace as trace_mod
@@ -137,8 +136,8 @@ def test_fault_points_and_events_registered():
 
 
 def test_lints_cover_elastic_files(tmp_path):
-    """error-paths + host-sync cover the new autoscaler/loadgen files
-    with zero findings and zero suppressions."""
+    """error-paths + host-sync cover the autoscaler with zero findings
+    and zero suppressions."""
     import json
     from conftest import load_nxdi_lint
     nxdi_lint = load_nxdi_lint()
@@ -147,12 +146,8 @@ def test_lints_cover_elastic_files(tmp_path):
         ["--passes", "error-paths,host-sync", "--json", str(out)]) == 0
     data = json.loads(out.read_text())
     assert data["findings"] == [] and data["suppressed"] == []
-    covered = set(data["files"])
-    for rel in ("neuronx_distributed_inference_tpu/serving/fleet/"
-                "autoscaler.py",
-                "neuronx_distributed_inference_tpu/serving/fleet/"
-                "loadgen.py"):
-        assert rel in covered
+    assert ("neuronx_distributed_inference_tpu/serving/fleet/autoscaler.py"
+            in data["files"])
 
 
 def test_autoscaler_construction_validation():
@@ -181,42 +176,6 @@ def test_autoscaler_construction_validation():
         EngineRouter({"A": SimpleNamespace(run_pass=lambda: 0,
                                            adapter=None)},
                      autoscaler=object())
-
-
-def test_loadgen_profiles_seeded_and_validated():
-    """All three load profiles are deterministic under a seed, shaped as
-    promised, and validate their knobs."""
-    a1 = diurnal_ramp(duration_s=20.0, base_rate=0.5, peak_rate=4.0,
-                      seed=3)
-    a2 = diurnal_ramp(duration_s=20.0, base_rate=0.5, peak_rate=4.0,
-                      seed=3)
-    assert a1 == a2 and a1                      # seeded: reproducible
-    assert a1 != diurnal_ramp(duration_s=20.0, base_rate=0.5,
-                              peak_rate=4.0, seed=4)
-    assert all(0.0 <= a.t <= 20.0 for a in a1)
-    assert a1 == sorted(a1, key=lambda a: a.t)
-    mid = [a for a in a1 if 8.0 < a.t < 12.0]   # rate peaks mid-window
-    edge = [a for a in a1 if a.t < 2.0 or a.t > 18.0]
-    assert len(mid) > len(edge)
-    tb = tenant_burst(duration_s=30.0, base_rate=1.0, burst_rate=6.0,
-                      burst_start_s=10.0, burst_len_s=5.0, seed=1)
-    assert {a.tenant for a in tb} == {"bg", "burst"}
-    assert all(10.0 <= a.t < 15.0
-               for a in tb if a.tenant == "burst")
-    ht = heavy_tail(duration_s=30.0, rate=2.0, min_prompt=4,
-                    max_prompt=40, seed=2)
-    lens = [len(a.prompt) for a in ht]
-    assert min(lens) >= 4 and max(lens) <= 40
-    assert sorted(lens)[len(lens) // 2] < 20    # median is small (tail)
-    for bad in (lambda: diurnal_ramp(duration_s=0),
-                lambda: diurnal_ramp(base_rate=5.0, peak_rate=2.0),
-                lambda: tenant_burst(burst_start_s=99.0, duration_s=30.0),
-                lambda: tenant_burst(tenants=("solo",)),
-                lambda: heavy_tail(rate=0.0),
-                lambda: heavy_tail(alpha=-1.0)):
-        with pytest.raises(ConfigurationError):
-            bad()
-    assert isinstance(a1[0], Arrival) and isinstance(a1[0].prompt, tuple)
 
 
 # ---------------------------------------------------------------------------

@@ -231,6 +231,10 @@ def test_mixed_load_exactly_one_materialized_dispatch(app):
         assert delta["prefill_blocking_fetches"] == 0
         assert delta["ragged_rows_prefill"] == 1
         assert delta["ragged_rows_verify"] == 1     # row 0 speculates on
+        # the planner's padding: a 16- then 8-token chunk beside row 0's
+        # 4-wide verify window, two rows at the chunk's width bucket
+        assert (delta["ragged_real_tokens"],
+                delta["ragged_padded_tokens"]) == ((20, 32), (12, 16))[step]
         long_stream.extend(res.get(1, []))
         got0[0].extend(res.get(0, []))
     assert len(long_stream) == 1             # first token from final chunk

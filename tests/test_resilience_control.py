@@ -635,8 +635,7 @@ def test_lints_cover_resilience_files(tmp_path):
 
 def test_chaos_smoke_seeded_subset(apps):
     """One seed, a seeded random subset of the fault x schedule matrix
-    against the full mixed workload — every invariant green. The full
-    sweep runs in bench.py --chaos-report."""
+    against the full mixed workload — every invariant green."""
     campaign = ChaosCampaign(list(apps), seed=0)
     cells = campaign.sample_cells(3)
     report = campaign.run(cells)

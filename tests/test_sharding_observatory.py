@@ -6,8 +6,8 @@ single-device zero-collective pin, the HLO census parser on doctored
 text (explicit + iota replica groups, async pairs, permutes), the
 replicate-then-partition detector firing on doctored HLO, the golden
 census diff going red on an injected collective, and the live
-``scripts/check_spmd_sharding.py`` lint (one pinned graph — the full set
-runs standalone / in CI via the script itself).
+``scripts/check_spmd_sharding.py`` lint on three pinned graphs (the full
+set runs standalone via the script itself).
 """
 
 import importlib.util
@@ -283,7 +283,7 @@ def test_remat_warning_channel_both_spellings():
 
 def test_golden_census_diff_red_on_new_collective(tmp_path):
     golden = json.loads(GOLDEN.read_text())
-    assert golden["schema"] == "nxdi-spmd-golden-v1"
+    assert golden["schema"] == "nxdi-spmd-golden-v2"
     assert set(lint_mod.PINNED) == set(golden["graphs"])
     snap = {"graphs": {name: {"collectives": dict(g["collectives"])}
                        for name, g in golden["graphs"].items()}}
@@ -334,22 +334,64 @@ def test_diff_census_units():
     assert lint_mod.diff_census("g", golden, {})    # disappearance is red
 
 
+def test_diff_graph_requests_always_census_only_on_its_own_xla():
+    name = "paged_decode_dp2tp2"
+    entry = json.loads(GOLDEN.read_text())["graphs"][name]
+    assert lint_mod.diff_graph(name, entry, entry) == ([], True)
+    # a census another XLA emitted is not this code's to answer for ...
+    key = sorted(entry["collectives"])[0]
+    other = {**entry, "xla": {"jax": "0.0.1", "jaxlib": "0.0.1"},
+             "collectives": {**entry["collectives"],
+                             key: {"count": 99, "bytes": 1}}}
+    assert lint_mod.diff_graph(name, other, entry) == ([], False)
+    # ... on the same XLA it is
+    assert lint_mod.diff_graph(name, {**other, "xla": entry["xla"]},
+                               entry)[0]
+    # what the code REQUESTS is held whatever compiled it: a dropped
+    # constraint and a new one are both red
+    asked = {**entry["requested"], "constraint:PartitionSpec('cp',)": 1}
+    del asked[next(k for k in asked if k.startswith("constraint:"))]
+    msgs, compared = lint_mod.diff_graph(
+        name, {**other, "requested": asked}, entry)
+    assert not compared and len(msgs) == 2
+    assert all("requested sharding" in m for m in msgs)
+
+
 # ---------------------------------------------------------------------------
-# live lint (one pinned graph; the full set runs via the script / driver)
+# live lint (three pinned graphs; the full set runs via the script)
 # ---------------------------------------------------------------------------
 
-def test_spmd_lint_live_subset(capsys, tmp_path):
-    # in-process (jax is already up with 8 virtual devices) — a
-    # subprocess would pay a fresh interpreter + jax import against the
-    # tight tier-1 budget for the same coverage
-    assert lint_mod.main(["--graphs", "cb_decode_dp2tp2"]) == 0
+@pytest.mark.parametrize("graph", ["paged_decode_dp2tp2",
+                                   "paged_ragged_dp2tp2",
+                                   "cb_decode_dp2tp2"])
+def test_spmd_lint_live_subset(graph, capsys):
+    """In-process (jax is up with 8 virtual devices): the graph partitions
+    with no full rematerialization or [SPMD] warning and requests the
+    golden's shardings; its census is held to the golden where that was
+    earned on this XLA, and the lint says so where it was not."""
+    assert lint_mod.main(["--graphs", graph]) == 0
     out = capsys.readouterr().out
     assert "OK" in out and "collectives censused" in out
-    # --update-golden with a --graphs subset MERGES into the existing
-    # golden — re-earning one graph must not drop the other pinned ones
+    entry = json.loads(GOLDEN.read_text())["graphs"][graph]
+    assert ("census not compared" in out) == \
+        (entry["xla"] != lint_mod.running_xla())
+
+
+def test_update_golden_with_a_subset_merges(tmp_path):
+    """``--update-golden --graphs g`` re-earns g (requests, census, XLA
+    stamp) and leaves every other pinned graph as it was."""
+    committed = json.loads(GOLDEN.read_text())["graphs"]
+    before = {name: {**entry, "xla": {"jax": "0.0.1", "jaxlib": "0.0.1"}}
+              for name, entry in committed.items()}
+    before["cb_decode_dp2tp2"]["requested"] = {"input:None": 1}
     g2 = tmp_path / "golden_copy.json"
-    g2.write_text(GOLDEN.read_text())
+    g2.write_text(json.dumps({"schema": lint_mod.GOLDEN_SCHEMA,
+                              "graphs": before}))
     assert lint_mod.main(["--update-golden", "--graphs",
                           "cb_decode_dp2tp2", "--golden", str(g2)]) == 0
-    merged = json.loads(g2.read_text())
-    assert set(merged["graphs"]) == set(lint_mod.PINNED)
+    merged = json.loads(g2.read_text())["graphs"]
+    assert set(merged) == set(lint_mod.PINNED)
+    target = merged.pop("cb_decode_dp2tp2")
+    assert target["xla"] == lint_mod.running_xla() and \
+        target["requested"] == committed["cb_decode_dp2tp2"]["requested"]
+    assert merged == {k: before[k] for k in merged}
