@@ -1112,11 +1112,7 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
         if h.shape[1] == 1:
             # every decline leaves a note (ops/kernel_mode.py): a decode
             # graph on the full-table gather path is never a silent choice
-            declined = ("alibi" if spec.alibi
-                        else "decode_kernel=False" if spec.decode_kernel is False
-                        else "" if decode_attention.supports(spec, 1)
-                        else "unsupported geometry (mla / head_dim / "
-                             "attn_chunk)")
+            declined = _paged_kernel_declined(spec)
             if not declined:
                 if spec.layer_pattern is not None:
                     win = jnp.where(is_local, spec.sliding_window, 0)
@@ -1133,9 +1129,13 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                 else:
                     use_pkernel = True
                     attn_out = kernel_out[:, None]
+            # ... and an engaged kernel says what it runs with: pages a
+            # compute block, a shard's heads, the head form
             kernel_mode.note("paged_decode",
                              "xla" if declined else kernel_mode.kernel_path(),
-                             declined)
+                             declined or decode_attention.paged_dispatch_plan(
+                                 q.shape[2], spec.head_dim, k_full,
+                                 block_table.shape[1]).note())
         if not use_pkernel:
             def gathered_mha(q_, bt_, mask_):
                 k_all = kv.dequantize_kv(bkv.gather_layer_kv(k_full, li, bt_),
@@ -1421,6 +1421,33 @@ def refuse_recurrent(asked) -> None:
         raise NotImplementedError(why)
 
 
+def _paged_kernel_declined(spec: DecoderSpec) -> str:
+    """Why a single-token paged step of this spec does NOT take the paged
+    decode kernel ("" where it does, the mesh permitting)."""
+    return ("alibi" if spec.alibi
+            else "decode_kernel=False" if spec.decode_kernel is False
+            else "" if decode_attention.supports(spec, 1)
+            else "unsupported geometry (mla / head_dim / attn_chunk)")
+
+
+def _paged_pool_fold(spec: DecoderSpec, cache, hidden, phase: str,
+                     block_table) -> int:
+    """How many kv heads a row of the pool holds for this walk: 1 (the pool
+    as stored) unless every layer of it is a single-token paged step on the
+    decode kernel with heads narrower than a vreg, which reads a page as
+    rows of 128 lanes (``decode_attention.paged_pool_fold``). The walk then
+    carries the pool in that shape: folding it at the kernel would relayout
+    the whole pool once a LAYER, here it is once a step - the copies a step
+    of such a model pays already (ROADMAP A5)."""
+    if (phase != "paged" or hidden.shape[1] != 1
+            or cache["k"].shape[4] != spec.head_dim      # folded already
+            or _paged_kernel_declined(spec)):
+        return 1
+    plan = decode_attention.paged_dispatch_plan(
+        spec.gqa.num_q_heads, spec.head_dim, cache["k"], block_table.shape[1])
+    return plan.fold if plan is not None else 1
+
+
 def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
                seq_ids, positions, phase: str,
                identity_seq_ids: bool = False,
@@ -1441,6 +1468,30 @@ def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
     Returns (hidden, new_cache, captured[, side]) — captured = {} unless
     spec.capture names per-layer points (then each is stacked (L, ...)).
     """
+    fold = _paged_pool_fold(spec, cache, hidden, phase, block_table)
+    if fold > 1:
+        # the walk carries the pool as the decode kernel reads it
+        def pool_as(x, heads):
+            return x.reshape(x.shape[:3] + (heads, -1))
+        heads = cache["k"].shape[3]
+        # the barrier keeps this reshape apart from the slot write's own:
+        # merged with it, it is no longer free in the layout the pool is
+        # kept in between steps and costs a second pass over the pool
+        k_f, v_f = jax.lax.optimization_barrier(
+            (pool_as(cache["k"], heads // fold),
+             pool_as(cache["v"], heads // fold)))
+        out = run_layers(
+            spec, params, dict(cache, k=k_f, v=v_f),
+            hidden, ai, seq_ids, positions, phase,
+            identity_seq_ids=identity_seq_ids,
+            arange_positions=arange_positions, slot_mapping=slot_mapping,
+            block_table=block_table, adapter_ids=adapter_ids,
+            replacements=replacements, kv_view=kv_view, deepstack=deepstack,
+            deepstack_mask=deepstack_mask, prefill_lens=prefill_lens,
+            side=side, chunk_idx=chunk_idx, state_slots=state_slots)
+        k_f, v_f = jax.lax.optimization_barrier((out[1]["k"], out[1]["v"]))
+        return (out[0], dict(out[1], k=pool_as(k_f, heads),
+                             v=pool_as(v_f, heads))) + out[2:]
     if spec.ssm is not None:
         refuse_recurrent([
             replacements is not None and "tensor capture/replacement",

@@ -23,15 +23,38 @@ is one contiguous DMA, a legal Mosaic BlockSpec, and feeds its dot in its
 natural orientation (a head-minor layout would make every per-head block
 shape (…,1,D), which TPU lowering rejects); new k/v (B, Hkv, D). All
 softmax math fp32.
+
+The PAGED kernel (``paged_decode_attention``, the serving path's) has no KV
+grid at all. Its grid is the rows; for each the kernel itself loops over the
+row's LIVE pages - from the window's first page to the last one holding a
+prior token - so a call costs what the live context costs, whatever the
+block table's width, and a dead table entry is never read. What lies where:
+
+* SMEM (scalar prefetch): layer, window, every row's length and the whole
+  block table (``PAGED_TABLE_SMEM_BYTES`` bounds it).
+* HBM: the stacked pools, untouched (``memory_space=pl.ANY``); the layer
+  and the physical page are indexed by hand.
+* VMEM: two K and two V slots of ``pages`` pages each
+  (``PAGED_KV_VMEM_BYTES`` together). A page with all of a shard's heads is
+  one contiguous slab, so one async copy a page fills a slot while the other
+  slot is computed on. ``pages`` follows the page's bytes, a cap on a
+  block's score tile and a cap on unrolled copies (``paged_block_plan``):
+  nothing of it is configured.
+* MXU: a slot is a (tokens x kv heads, 128 lanes) matrix as it lies, and all
+  heads score in one block-diagonal matmul against it (heads of 64 lanes sit
+  two to a row: ``paged_pool_fold``). Operands are bf16 wherever that loses
+  nothing: q . k from bf16 operands accumulates in fp32; p stays fp32 - it
+  goes in as three bf16 pieces in one pass over V (``_split_dot``).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -42,6 +65,99 @@ NEG_INF = -2.3819763e38
 #: use takes a few KiB of it: compiling for the v5e topology, 1,040,408 B
 #: went through and 1,044,744 B was refused (RESOURCE_EXHAUSTED, space=smem).
 PAGED_TABLE_SMEM_BYTES = 1_040_408
+
+#: VMEM the paged kernel spends on its two K and two V slots together (the
+#: scoped limit of a v5e kernel is 16 MiB; scores and their copies take ~2).
+PAGED_KV_VMEM_BYTES = 4 * 1024 * 1024
+#: most elements of a compute block's (query heads, tokens x a shard's kv
+#: rows) fp32 score tile, 32 vregs: the MXU's work is per column whether its
+#: page is live or not, and every softmax step is unrolled over the tile, so
+#: the tile bounds the kernel's code (a program's load time) as well.
+PAGED_SCORE_TILE_ELEMENTS = 32 * 1024
+#: most pages of one compute block: their copies are unrolled in the kernel.
+PAGED_BLOCK_PAGES = 16
+
+_NT = (((1,), (1,)), ((), ()))        # (M, K) x (N, K) -> (M, N)
+_NN = (((1,), (0,)), ((), ()))        # (M, K) x (K, N) -> (M, N)
+
+
+def _bf16_exact(dtype) -> bool:
+    """Every value of ``dtype`` is a bfloat16 value (bf16 and the fp8s)."""
+    dtype = jnp.dtype(dtype)
+    return dtype == jnp.bfloat16 or (dtype.itemsize == 1
+                                     and jnp.issubdtype(dtype, jnp.floating))
+
+
+class PagedPlan(NamedTuple):
+    """What one call of the paged kernel runs with (:func:`paged_block_plan`)."""
+    pages: int          # pages a compute block gathers
+    fold: int           # kv heads sharing one 128-lane row of a page
+    hkv: int            # the kernel's kv rows a token: a shard's heads / fold
+    g: int              # query rows a kv row: group size x fold
+    d: int              # lanes of a kv row: head_dim x fold
+
+    def note(self) -> str:
+        """The engagement record's text (``kernel_mode.note``)."""
+        return (f"pages={self.pages} heads={self.hkv * self.fold} "
+                f"form=mxu-blockdiag fold={self.fold}")
+
+
+def paged_pool_fold(hkv: int, d: int) -> int:
+    """How many neighbouring kv heads (of a shard's ``hkv``) share one
+    128-lane row of a page in the kernel's view of the pool: heads narrower
+    than a vreg go in side by side, the page's bytes as they lie (a manual
+    copy cannot slice a 64-lane minor dimension out of a tiled array)."""
+    fold = 128 // d if d < 128 and 128 % d == 0 else 1
+    return fold if hkv % fold == 0 else 1
+
+
+def paged_block_plan(bs: int, hkv: int, g: int, d: int, kv_dtype,
+                     mb: int) -> PagedPlan:
+    """How the paged kernel walks a call of this geometry (``hkv`` a shard's
+    kv heads, ``g`` query heads a kv head, ``mb`` the table's width): chosen
+    from what the call can observe - page geometry and item size - and from
+    nothing else.
+
+    With ``fold`` heads to a row (:func:`paged_pool_fold`) a page is a
+    ``(bs * hkv / fold, fold * d)`` matrix, and a compute block is ``pages``
+    of them: the most that (a) four slots (K and V, double-buffered) fit
+    :data:`PAGED_KV_VMEM_BYTES`, so a 1-byte item doubles it, (b) keep the
+    block's score tile (query heads x tokens x kv rows) under
+    :data:`PAGED_SCORE_TILE_ELEMENTS`, (c) :data:`PAGED_BLOCK_PAGES` and the
+    table allow."""
+    fold = paged_pool_fold(hkv, d)
+    hkv, g, d = hkv // fold, g * fold, d * fold
+    page_bytes = bs * hkv * d * jnp.dtype(kv_dtype).itemsize
+    pages = min(PAGED_KV_VMEM_BYTES // (4 * page_bytes),
+                PAGED_SCORE_TILE_ELEMENTS // (hkv * g * bs * hkv),
+                PAGED_BLOCK_PAGES, mb)
+    return PagedPlan(max(1, pages), fold, hkv, g, d)
+
+
+def _split_dot(x, w, dims, exact: bool):
+    """fp32-accurate ``x . w`` on the MXU. Where every value of ``w`` is a
+    bf16 value (``exact``), an fp32 ``x`` goes in as its three bf16 pieces
+    stacked along M (hi + mid + lo == x to the last bit), so ONE pass over
+    ``w`` — the big operand, the MXU's weights — gives the products a
+    six-pass fp32 matmul would; otherwise the fp32 matmul itself."""
+    m = x.shape[0]
+    if not exact or (x.dtype != jnp.bfloat16 and m % 8):
+        return jax.lax.dot_general(
+            x.astype(jnp.float32), w.astype(jnp.float32), dims,
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    w = w.astype(jnp.bfloat16)
+    if x.dtype == jnp.bfloat16:
+        return jax.lax.dot_general(x, w, dims,
+                                   preferred_element_type=jnp.float32)
+    hi = x.astype(jnp.bfloat16).astype(jnp.float32)
+    rest = x - hi
+    mid = rest.astype(jnp.bfloat16).astype(jnp.float32)
+    lo = rest - mid
+    out = jax.lax.dot_general(
+        jnp.concatenate([hi, mid, lo], axis=0).astype(jnp.bfloat16), w, dims,
+        preferred_element_type=jnp.float32)
+    return (out[2 * m:] + out[m:2 * m]) + out[:m]
 
 
 def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, nk_ref, nv_ref, sink_ref,
@@ -267,6 +383,17 @@ def decode_attention_stacked(q: jnp.ndarray, k_cache: jnp.ndarray,
     return out.reshape(b, hq, d)
 
 
+def _model_parallel(mesh):
+    """The ambient mesh's model-parallel axes that are wider than one, and
+    their product: what kv heads are sharded over."""
+    axes = tuple(a for a in ("ep", "tp")
+                 if a in mesh.axis_names and mesh.shape[a] > 1)
+    mp = 1
+    for a in axes:
+        mp *= mesh.shape[a]
+    return axes, mp
+
+
 def dispatch(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
              new_k: jnp.ndarray, new_v: jnp.ndarray, layer: jnp.ndarray,
              lens: jnp.ndarray, *, scale: float,
@@ -287,11 +414,7 @@ def dispatch(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     mesh = jax.sharding.get_abstract_mesh()
     b, hq, d = q.shape
     hkv = k_cache.shape[2]
-    mp_axes = tuple(a for a in ("ep", "tp")
-                    if a in mesh.axis_names and mesh.shape[a] > 1)
-    mp = 1
-    for a in mp_axes:
-        mp *= mesh.shape[a]
+    mp_axes, mp = _model_parallel(mesh)
     if mp > 1 and hkv % mp != 0:
         # kv heads not shardable over the model-parallel axes: a bare
         # pallas_call here would run REPLICATED under GSPMD (full cache
@@ -338,88 +461,133 @@ def dispatch(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                          out_specs=P(dp, mpx, None), check_vma=False)(*args)
 
 
-def _paged_kernel(sc_ref, q_ref, k_ref, v_ref, nk_ref, nv_ref, sink_ref,
-                  o_ref, acc_ref, m_ref, l_ref, *,
-                  scale: float, block_s: int, nh: int,
+def _paged_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, k_hbm, v_hbm,
+                  o_ref, kbuf, vbuf, sem, *,
+                  scale: float, bs: int, mb: int, hkv: int, g: int,
                   soft_cap: Optional[float], has_sink: bool,
-                  kv_scale: Optional[float] = None):
+                  kv_scale: Optional[float]):
     """Ragged PAGED decode attention (reference: the DMA-skipping TKG
     attention over the block layout, attention_base.py:1186-1382 +
-    block_kv_cache_manager.py:183-267). Scalar layout:
-    [layer, window, len_0..len_{B-1}, table_{b=0,j=0}.., table_{B-1,mb-1}]
-    — the index maps gather PHYSICAL pages through the block table, so the
-    kernel streams only each row's live pages (grid steps past the live
-    range collapse onto the last live page and Pallas elides the DMA); the
-    XLA gather path materializes the whole table every layer every token."""
+    block_kv_cache_manager.py:183-267). One grid step is one ROW; the walk
+    over its KV is a loop inside the step, as long as the row's live pages.
+
+    Scalar prefetch (SMEM): [layer, window, len_0..len_{B-1},
+    table_{b=0,j=0}.., table_{B-1,mb-1}]. ``k_hbm`` / ``v_hbm`` are the
+    whole stacked pools (L, N, bs * hkv, d), left in HBM; a compute block
+    is ``pages`` pages copied by hand (one async copy a page, page ids read
+    from the table) into one of two VMEM slots while the other slot is
+    computed on. Table entries before the window's first page or past the
+    last live page are never read, so neither the time nor the result
+    depends on the table's width.
+
+    All heads score in ONE matmul: row ``c`` of a slot is token ``c // hkv``
+    of kv row ``c % hkv`` and query row ``r`` belongs to kv row ``r // g``,
+    so K and V go to the MXU as they lie, lane-dense, with no relayout, and
+    the scores off that block diagonal are masked (the MXU is idle
+    otherwise, and the softmax sees (Hq, columns) full vregs)."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-    pos = sc_ref[2 + b]
+    nb = pl.num_programs(0)
+    layer = sc_ref[0]
     w = sc_ref[1]
+    pos = sc_ref[2 + b]
+    last_live = jax.lax.div(jnp.maximum(pos - 1, 0), bs)
+    first_live = jnp.where(w > 0, jax.lax.div(jnp.maximum(pos - w, 0), bs), 0)
+    n_pages = jnp.where(pos > 0, last_live - first_live + 1, 0)
+    _, pages, page_rows, d = kbuf.shape
+    n_blocks = jax.lax.div(n_pages + pages - 1, pages)
+    table0 = 2 + nb + b * mb
+    exact = _bf16_exact(kbuf.dtype)
+    hq, cols = hkv * g, pages * page_rows
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def page_copies(i, slot):
+        for p in range(pages):
+            j = first_live + i * pages + p
+            page = sc_ref[table0 + jnp.minimum(j, last_live)]
+            yield p, j <= last_live, (
+                pltpu.make_async_copy(k_hbm.at[layer, page], kbuf.at[slot, p],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, page], vbuf.at[slot, p],
+                                      sem.at[1, slot]))
 
-    k_start = j * block_s
-    in_window = jnp.logical_or(w == 0, k_start + block_s > pos - w)
+    def start(i, slot):
+        for p, live, (kc, vc) in page_copies(i, slot):
+            @pl.when(live)
+            def _fetch():
+                kc.start()
+                vc.start()
 
-    @pl.when(jnp.logical_and(k_start < pos, in_window))
-    def _prior():
-        kpos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (q_ref.shape[3], block_s), 1)
-        valid = kpos < pos
-        valid = jnp.logical_and(
-            valid, jnp.logical_or(w == 0, pos - kpos < w))
-        for hh in range(nh):
-            q = q_ref[0, 0, hh].astype(jnp.float32)        # (G, D)
-            k = k_ref[0, 0, :, hh, :].astype(jnp.float32)  # (bs, D)
-            v = v_ref[0, 0, :, hh, :].astype(jnp.float32)  # (bs, D)
-            if kv_scale is not None:
-                # scaled KV dequant on the page load (reference:
-                # kv_cache_manager.py:636-692 scaled fp8 mode)
-                k = k * kv_scale
-                v = v * kv_scale
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            if soft_cap is not None:
-                s = soft_cap * jnp.tanh(s / soft_cap)      # (G, bs)
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev = m_ref[hh, :, 0:1]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_cur)
-            p = jnp.exp(s - m_cur)
-            l_ref[hh, :, 0:1] = (l_ref[hh, :, 0:1] * alpha
-                                 + jnp.sum(p, -1, keepdims=True))
-            acc_ref[hh] = acc_ref[hh] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[hh, :, 0:1] = m_cur
+            @pl.when(jnp.logical_not(live))
+            def _blank():
+                # a slot's page past the row's end is computed on (masked):
+                # its V must be finite, whatever the slot held before
+                vbuf[slot, p] = jnp.zeros((page_rows, d), vbuf.dtype)
 
-    @pl.when(j == nj - 1)
-    def _active_and_finalize():
-        for hh in range(nh):
-            q = q_ref[0, 0, hh].astype(jnp.float32)        # (G, D)
-            kn = nk_ref[0, 0, hh].astype(jnp.float32)      # (1, D)
-            vn = nv_ref[0, 0, hh].astype(jnp.float32)      # (1, D)
-            s = jax.lax.dot_general(q, kn, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            if soft_cap is not None:
-                s = soft_cap * jnp.tanh(s / soft_cap)      # (G, 1)
-            m_prev = m_ref[hh, :, 0:1]
-            m_cur = jnp.maximum(m_prev, s)
-            if has_sink:
-                sk = sink_ref[0, hh].astype(jnp.float32).reshape(-1)[:, None]
-                m_cur = jnp.maximum(m_cur, sk)
-            alpha = jnp.exp(m_prev - m_cur)
-            p = jnp.exp(s - m_cur)
-            l_new = l_ref[hh, :, 0:1] * alpha + p
-            if has_sink:
-                l_new = l_new + jnp.exp(sk - m_cur)
-            acc = acc_ref[hh] * alpha + p * vn
-            o_ref[0, 0, hh] = (acc / l_new).astype(o_ref.dtype)
+    def wait(i, slot):
+        for p, live, (kc, vc) in page_copies(i, slot):
+            @pl.when(live)
+            def _landed():
+                kc.wait()
+                vc.wait()
+
+    col = jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 0)
+    tok = jax.lax.div(col, hkv)
+    own = jax.lax.rem(col, hkv) == jax.lax.div(row, g)
+    q = q_ref[0]
+    s_scale = scale * kv_scale if kv_scale is not None else scale
+
+    def block(i, carry):
+        m_prev, l_prev, acc = carry
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_blocks)
+        def _next():
+            start(i + 1, 1 - slot)
+
+        wait(i, slot)
+        kpos = (first_live + i * pages) * bs + tok
+        valid = jnp.logical_and(own, jnp.logical_and(
+            kpos < pos, jnp.logical_or(w == 0, pos - kpos < w)))
+        s = _split_dot(q, kbuf[slot].reshape(cols, d), _NT, exact) * s_scale
+        if soft_cap is not None:
+            s = soft_cap * jnp.tanh(s / soft_cap)
+        s = jnp.where(valid, s, NEG_INF)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur)              # fp32, never rounded to bf16
+        return (m_cur, l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                acc * alpha + _split_dot(p, vbuf[slot].reshape(cols, d), _NN,
+                                         exact))
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        start(0, 0)
+
+    m_prev, l_prev, acc = jax.lax.fori_loop(0, n_blocks, block, (
+        jnp.full((hq, 1), NEG_INF, jnp.float32),
+        jnp.zeros((hq, 1), jnp.float32), jnp.zeros((hq, d), jnp.float32)))
+
+    # the active token joins in registers: its score the softmax, its V the
+    # accumulator (the pools' V is stored / kv_scale, the active V is not)
+    s = jnp.sum(q.astype(jnp.float32) * nk_ref[0].astype(jnp.float32),
+                axis=-1, keepdims=True) * scale
+    if soft_cap is not None:
+        s = soft_cap * jnp.tanh(s / soft_cap)
+    m_cur = jnp.maximum(m_prev, s)
+    if has_sink:
+        # learned per-head sink joins the denominator only
+        # (reference: modules/attention/sink.py)
+        sk = sink_ref[...]
+        m_cur = jnp.maximum(m_cur, sk)
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.exp(s - m_cur)
+    l_new = l_prev * alpha + p
+    if has_sink:
+        l_new = l_new + jnp.exp(sk - m_cur)
+    if kv_scale is not None:
+        acc = acc * kv_scale
+    o_ref[0] = ((acc * alpha + p * nv_ref[0].astype(jnp.float32))
+                / l_new).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -437,19 +605,30 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                            interpret: bool = False) -> jnp.ndarray:
     """Ragged paged decode attention over the stacked block cache.
 
-    q (B, Hq, D); k_pages/v_pages (L, N, Bs, Hkv, D); new_k/new_v
+    q (B, Hq, D); k_pages/v_pages (L, N, Bs, Hkv, D) - or the same bytes
+    already folded, (L, N, Bs, Hkv / fold, fold * D) with ``fold`` of
+    :func:`paged_pool_fold`: a caller that keeps the pool so for the whole
+    layer loop spares a relayout of the pool a layer; new_k/new_v
     (B, Hkv, D); lens (B,) prior lengths; block_table (B, max_blocks)
-    logical→physical page map (entry 0 = null page). Returns (B, Hq, D).
+    logical->physical page map (entry 0 = null page). Returns (B, Hq, D).
 
-    The WHOLE table rides scalar prefetch into SMEM — 4·(2 + B + B·max_blocks)
-    bytes — and a v5e core has 1 MiB of it (:data:`PAGED_TABLE_SMEM_BYTES`).
-    64 rows × 4,096 blocks does not fit; a caller that needs such tables
-    must split the batch or page the table (ROADMAP B2's long contexts).
+    The grid is the rows. For each, the kernel walks the pages from the
+    window's first to the last live one in compute blocks of ``pages`` pages
+    (:func:`paged_block_plan`), so a call's time follows the live context
+    and not the table's width, and a row of length 0 does its active token
+    only. Staged in SMEM: layer, window, lengths and the WHOLE table -
+    4 x (2 + B + B x max_blocks) bytes of the 1 MiB a v5e core has
+    (:data:`PAGED_TABLE_SMEM_BYTES`; 64 rows x 4,096 blocks does not fit: a
+    caller that needs such tables must split the batch or page the table,
+    ROADMAP B2). Copied by hand: the pools stay in HBM, and a page (all of a
+    shard's heads: one contiguous slab) is one async copy into one of two
+    K and two V slots in VMEM, :data:`PAGED_KV_VMEM_BYTES` together.
     """
     b, hq, d = q.shape
-    hkv = k_pages.shape[3]
     bs = k_pages.shape[2]
     mb = block_table.shape[1]
+    fold = k_pages.shape[4] // d              # > 1: the pool came folded
+    hkv = k_pages.shape[3] * fold
     g = hq // hkv
     table_bytes = 4 * (2 + b + b * mb)
     if table_bytes > PAGED_TABLE_SMEM_BYTES:
@@ -457,48 +636,31 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
             f"paged decode kernel: block table of {b} rows x {mb} blocks "
             f"needs {table_bytes} B of SMEM scalar prefetch, over the "
             f"{PAGED_TABLE_SMEM_BYTES} B a v5e core can hold")
+    pages, fold_k, hkv_k, g_k, d_k = paged_block_plan(bs, hkv, g, d,
+                                                      k_pages.dtype, mb)
+    assert fold in (1, fold_k), (fold, fold_k)     # folded as the plan folds
+    fold = fold_k
+    # a page as the matrix it is in memory: (tokens x kv rows, lanes)
+    k_pages, v_pages = (x.reshape(x.shape[:2] + (bs * hkv_k, d_k))
+                        for x in (k_pages, v_pages))
+    # query rows and the active K / V, a row a query head; with heads folded
+    # a row holds its own head's lanes and zeros elsewhere, and its output
+    # is read back from those lanes
+    new_k, new_v = (jnp.repeat(x, g, axis=1) for x in (new_k, new_v))
+    own = ((np.arange(hq) // g) % fold)[:, None] == np.arange(fold)
 
-    vmem_budget = 4 * 1024 * 1024
-    max_nh = max(1, min(8, vmem_budget // (bs * d * 2 * 2 * 2)))
-    nh = 1
-    for cand in range(max_nh, 0, -1):
-        if hkv % cand == 0:
-            nh = cand
-            break
-    hb = hkv // nh
+    def place(x):                                        # (B, Hq, d) -> d_k
+        if fold == 1:
+            return x
+        return jnp.where(own[None, :, :, None], x[:, :, None, :],
+                         jnp.zeros((), x.dtype)).reshape(b, hq, d_k)
 
-    qr = q.reshape(b, hb, nh, g, d)
-    sink_in = (sink.reshape(hb, nh, 1, g) if sink is not None
-               else jnp.zeros((hb, nh, 1, g), jnp.float32))
-
-    def q_map(bi, h, j, sc):
-        return (bi, h, 0, 0, 0)
-
-    def _live_page(bi, j, sc):
-        pos_b = sc[2 + bi]
-        last_live = jax.lax.max(
-            jax.lax.div(jax.lax.max(pos_b - 1, 0), bs), 0)
-        w = sc[1]
-        first_live = jax.lax.select(
-            w > 0, jax.lax.max(jax.lax.div(jax.lax.max(pos_b - w, 0), bs),
-                               0), 0)
-        jc = jax.lax.min(jax.lax.max(j, first_live), last_live)
-        return sc[2 + b + bi * mb + jc]         # physical page id
-
-    def kv_map(bi, h, j, sc):
-        # pages (L, N, Bs, Hkv, D): full Bs rows, nh-head slab
-        return (sc[0], _live_page(bi, j, sc), 0, h, 0)
-
-    def nkv_map(bi, h, j, sc):
-        return (bi, h, 0, 0, 0)
-
-    def sink_map(bi, h, j, sc):
-        return (h, 0, 0, 0)
-
-    grid = (b, hb, mb)
+    sink_in = (sink.astype(jnp.float32).reshape(hq, 1) if sink is not None
+               else jnp.zeros((hq, 1), jnp.float32))
+    row_spec = pl.BlockSpec((1, hq, d_k), lambda bi, sc: (bi, 0, 0))
     kernel = functools.partial(
-        _paged_kernel, scale=scale, block_s=bs, nh=nh, kv_scale=kv_scale,
-        soft_cap=soft_cap, has_sink=sink is not None)
+        _paged_kernel, scale=scale, bs=bs, mb=mb, hkv=hkv_k, g=g_k,
+        kv_scale=kv_scale, soft_cap=soft_cap, has_sink=sink is not None)
     if window is None:
         window = jnp.zeros((), jnp.int32)
     scalars = jnp.concatenate([
@@ -506,32 +668,34 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         jnp.asarray(window, jnp.int32).reshape(1),
         lens.astype(jnp.int32),
         block_table.astype(jnp.int32).reshape(-1)])
+    slot = (2, pages, bs * hkv_k, d_k)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(b,),
             in_specs=[
-                pl.BlockSpec((1, 1, nh, g, d), q_map),
-                pl.BlockSpec((1, 1, bs, nh, d), kv_map),
-                pl.BlockSpec((1, 1, bs, nh, d), kv_map),
-                pl.BlockSpec((1, 1, nh, 1, d), nkv_map),
-                pl.BlockSpec((1, 1, nh, 1, d), nkv_map),
-                pl.BlockSpec((1, nh, 1, g), sink_map),
+                row_spec, row_spec, row_spec,
+                pl.BlockSpec((hq, 1), lambda bi, sc: (0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, 1, nh, g, d), q_map),
+            out_specs=row_spec,
             scratch_shapes=[
-                pltpu.VMEM((nh, g, d), jnp.float32),
-                pltpu.VMEM((nh, g, 128), jnp.float32),
-                pltpu.VMEM((nh, g, 128), jnp.float32),
+                pltpu.VMEM(slot, k_pages.dtype),
+                pltpu.VMEM(slot, v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hb, nh, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, d_k), q.dtype),
         interpret=interpret,
-    )(scalars, qr, k_pages, v_pages,
-      new_k.reshape(b, hb, nh, 1, d), new_v.reshape(b, hb, nh, 1, d),
-      sink_in)
-    return out.reshape(b, hq, d)
+    )(scalars, place(q), place(new_k), place(new_v), sink_in, k_pages,
+      v_pages)
+    if fold > 1:
+        out = jnp.sum(jnp.where(own[None, :, :, None],
+                                out.reshape(b, hq, fold, d),
+                                jnp.zeros((), out.dtype)), axis=2)
+    return out
 
 
 def paged_dispatch(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
@@ -549,11 +713,7 @@ def paged_dispatch(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     mesh = jax.sharding.get_abstract_mesh()
     b = q.shape[0]
     hkv = k_pages.shape[3]
-    mp_axes = tuple(a for a in ("ep", "tp")
-                    if a in mesh.axis_names and mesh.shape[a] > 1)
-    mp = 1
-    for a in mp_axes:
-        mp *= mesh.shape[a]
+    mp_axes, mp = _model_parallel(mesh)
     if mp > 1 and hkv % mp != 0:
         return None
     # batch rows split over dp (pages stay replicated across dp — the
@@ -598,6 +758,20 @@ def paged_dispatch(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
 
     return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                          out_specs=P(dp, mpx, None), check_vma=False)(*args)
+
+
+def paged_dispatch_plan(hq: int, d: int, k_pages: jnp.ndarray,
+                        mb: int) -> Optional[PagedPlan]:
+    """The plan :func:`paged_dispatch` runs a pool (as stored, or already
+    folded) of ``d``-wide heads with under the ambient mesh - a shard's
+    heads - for the engagement record and for a caller that folds the pool
+    itself; None where the dispatch declines."""
+    _, mp = _model_parallel(jax.sharding.get_abstract_mesh())
+    hkv = k_pages.shape[3] * (k_pages.shape[4] // d)
+    if hkv % mp:
+        return None
+    return paged_block_plan(k_pages.shape[2], hkv // mp, hq // hkv, d,
+                            k_pages.dtype, mb)
 
 
 def supports(spec, phase_t: int) -> bool:

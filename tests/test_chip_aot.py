@@ -174,6 +174,36 @@ def test_llama_1b_serving_graphs_compile_for_v5e(v5e_devices, tp):
     assert counts["involuntary_remat"] == 0
 
 
+def test_heads_of_64_relayout_the_pool_once_a_step(v5e_devices):
+    """ISSUE 33: the paged decode kernel copies pages by hand, and a manual
+    copy takes rows of 128 lanes only, so heads of 64 go two to a row
+    (``decode_attention.paged_pool_fold``). The layer walk carries the pool
+    in that shape (``model_base.run_layers``): the T=1 step of Llama-3.2-1B
+    (8 kv heads of 64, as granite-4.0-h-micro's attention) moves the pool
+    between layouts at the step's two ends - the four copies it paid before
+    (ROADMAP A5) - and NOT inside the layer loop, and the engagement record
+    says what the kernel runs with."""
+    spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
+        LLAMA_3_2_1B, 4, 1, v5e_devices[:1],
+        dict(batch_size=32, seq_len=4096, pa_block_size=32,
+             pa_num_blocks=2048, context_encoding_buckets=[64, 256]))
+    i32, b = jnp.int32, 32
+    notes = set()
+    with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
+        text = jax.jit(partial(model_base.paged_forward_step, spec, tcfg),
+                       donate_argnums=(1,)).lower(
+            params, cache, *(sds((b, 1), i32),) * 3, sds((b, mb), i32),
+            sds((b,), i32), None, sds((2,), jnp.uint32)).compile().as_text()
+    assert MOSAIC in text
+    assert notes == {("paged_decode", "pallas",
+                      "pages=8 heads=8 form=mxu-blockdiag fold=2")}
+    # every instruction that MOVES a pool (either shape of its 268 MB)
+    moves = re.findall(
+        r"%(\S+) = bf16\[4,2049,32,(?:8,64|4,128)\]\S* "
+        r"(copy|reshape|transpose|fusion)\(", text)
+    assert sorted(kind for _, kind in moves) == ["copy"] * 4, moves
+
+
 def test_olmoe_1b_7b_serving_graphs_compile_for_v5e(v5e_devices):
     texts = _compile_serving_graphs(
         OLMOE_1B_7B, 1, 1, v5e_devices,

@@ -407,6 +407,85 @@ def test_paged_decode_kernel_stacked_layers(rng):
                                    rtol=2e-5, atol=2e-5, err_msg=f"layer {li}")
 
 
+# ISSUE 33: the kernel's walk follows a row's LIVE pages, several to a compute
+# block. Both benchmark cells' attention geometries (query heads, kv heads,
+# head_dim), block 32; every case a row beside a second, ordinary row.
+_CELLS = {"olmoe": (16, 16, 128), "granite": (32, 8, 64)}
+_BS = 32
+
+
+def _cell_plan(cell, dtype, mb):
+    hq, hkv, d = _CELLS[cell]
+    return da.paged_block_plan(_BS, hkv, hq // hkv, d, dtype, mb)
+
+
+def _walk_case(case, pages, mb):
+    """(prior lengths of the rows, window) of a named case; ``pages`` is the
+    call's pages a compute block, ``mb`` the table's width."""
+    block = pages * _BS
+    lens = {"len0": 0, "len1": 1, "len31": 31, "len32": 32, "len33": 33,
+            "block_less_one": block - 1, "block": block,
+            "block_plus_one": block + 1, "full_table": mb * _BS - 1,
+            # the window's first token lies inside the second compute block
+            # (at 1.5 blocks less 20)
+            "window_in_block": 2 * block - 20,
+            "dead_entries_nan": block + 70, "bf16": block + 70}[case]
+    window = block // 2 if case == "window_in_block" else 0
+    return np.array([lens, 75], np.int32), window
+
+
+@pytest.mark.parametrize("case", [
+    "len0", "len1", "len31", "len32", "len33", "block_less_one", "block",
+    "block_plus_one", "full_table", "window_in_block", "dead_entries_nan",
+    "bf16"])
+@pytest.mark.parametrize("cell", sorted(_CELLS))
+def test_paged_decode_walks_live_pages(rng, cell, case):
+    hq, hkv, d = _CELLS[cell]
+    dtype = jnp.bfloat16 if case == "bf16" else jnp.float32
+    mb = 2 * _cell_plan(cell, dtype, 64).pages + 1
+    plan = _cell_plan(cell, dtype, mb)
+    assert plan.fold == {"olmoe": 1, "granite": 2}[cell] and plan.pages >= 2
+    lens, window = _walk_case(case, plan.pages, mb)
+    b, scale = len(lens), d ** -0.5
+    # physical pages shuffled and non-contiguous (_paged_setup), block 0 null
+    q, kp, vp, nk, nv, table = (
+        x.astype(dtype) if isinstance(x, jnp.ndarray) else x
+        for x in _paged_setup(rng, b, hq, hkv, d, _BS, mb, lens,
+                              num_blocks=1 + 2 * b * mb))
+
+    def run(kp_, vp_, table_):
+        return da.paged_decode_attention(
+            q, kp_, vp_, nk, nv, jnp.asarray(0, jnp.int32),
+            jnp.asarray(lens, jnp.int32), jnp.asarray(table_), scale=scale,
+            window=jnp.asarray(window, jnp.int32), interpret=True)
+
+    got = run(kp, vp, table)
+    if case == "dead_entries_nan":
+        # a table 4 x wider whose every dead entry (past the row's last live
+        # page) names a page of NaN: the result is the narrow table's, bit
+        # for bit - only live pages are read
+        wide = np.zeros((b, 4 * mb), np.int32)
+        wide[:, :mb] = table
+        spare = np.setdiff1d(np.arange(1, kp.shape[1]), table.ravel())
+        for i in range(b):
+            live = -(-int(lens[i] + 1) // _BS)
+            wide[i, live:] = rng.choice(spare, 4 * mb - live)
+        poison = np.zeros(kp.shape[1], bool)
+        poison[spare] = True
+        poison[0] = True                     # the null page too
+        kp_nan, vp_nan = (jnp.where(poison[None, :, None, None, None],
+                                    jnp.nan, x) for x in (kp, vp))
+        np.testing.assert_array_equal(np.asarray(run(kp_nan, vp_nan, wide)),
+                                      np.asarray(got))
+    f32 = [np.asarray(x, np.float32) for x in (q, kp, vp, nk, nv)]
+    want = _paged_reference(*(jnp.asarray(x) for x in f32), lens, table,
+                            scale, window=window)
+    tol = 2e-5 if dtype == jnp.float32 else 1e-2
+    assert got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               rtol=tol, atol=tol)
+
+
 # ---------------------------------------------------------------------------
 # Quantized-KV admission (reference: fp8 KV cache feeding the TKG kernel,
 # kv_cache_manager.py:636-692): the kernel dequantizes on the block load.
