@@ -1137,14 +1137,16 @@ class PagedCausalLMApplication(CausalLMApplication):
     """
 
     def init_cache(self):
-        from ..modules.block_kv_cache import BlockKVCacheManager, BlockKVSpec
+        from ..modules.block_kv_cache import (BlockKVCacheManager,
+                                              BlockKVSpec, pool_kv_heads)
         cfg = self.tpu_config
         bspec = BlockKVSpec(
             # SSM-only layers carry no KV pages (recurrent/hybrid stacks)
             num_layers=self.spec.num_attn_layers,
             num_blocks=cfg.pa_num_blocks + 1,    # +1: reserved null block 0
             block_size=cfg.pa_block_size,
-            num_kv_heads=self.spec.gqa.num_kv_heads,
+            num_kv_heads=pool_kv_heads(self.spec.gqa.num_kv_heads,
+                                       self.spec.gqa.tp),
             head_dim=self.spec.head_dim,
             dtype=self.spec.kv_dtype,
         )
@@ -1156,8 +1158,8 @@ class PagedCausalLMApplication(CausalLMApplication):
         self.cache = self.kv_mgr.cache
         self.kv_mgr.cache = None
         if self.spec.ssm is not None:
-            # the second per-sequence cache: conv tails + fp32 SSM state,
-            # one SLOT per batch row beside the KV pool, in the same
+            # the second per-sequence cache: the kind's conv tails + fp32
+            # state, one SLOT per batch row beside the KV pool, in the same
             # donated dict so the step graphs update both in place
             from ..modules.ssm import init_ssm_state
             self.cache.update(init_ssm_state(

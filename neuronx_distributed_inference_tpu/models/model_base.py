@@ -555,9 +555,13 @@ def decoder_param_specs(spec: DecoderSpec) -> Dict[str, Any]:
         # attention layers only ("attn_layers"), SSM weights over the
         # recurrent layers ("ssm_layers") — SSM-only layers carry no dead
         # attention params and no KV cache rows
-        norm_keys = ("input_norm", "post_norm", "input_norm_b", "post_norm_b")
+        norm_keys = ("input_norm", "post_norm", "input_norm_b", "post_norm_b",
+                     "post_attn_norm", "post_ff_norm")
         full = _attn_param_specs(spec, L)
-        shared = {k: v for k, v in full.items() if k in norm_keys}
+        # a post-norm stack has no input norms: its walk reads the two
+        # output norms only
+        walked = norm_keys[4:] if spec.norm_position == "post" else norm_keys
+        shared = {k: v for k, v in full.items() if k in walked}
         shared.update(_dense_mlp_param_specs(spec, L))
         out["layers"] = shared
         if spec.num_attn_layers:
@@ -1097,6 +1101,17 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
 
     if phase == "paged":
         from ..modules import block_kv_cache as bkv
+        # a pool with more head slots than the model has kv heads
+        # (bkv.pool_kv_heads): q, k and v grow zero heads to match, and the
+        # output drops them again
+        n_q = q.shape[2]
+        pool_heads = k_full.shape[3] * (k_full.shape[4] // spec.head_dim)
+        grown = pool_heads != k.shape[2]
+        if grown:
+            def grow(x):
+                more = (pool_heads - k.shape[2]) * (x.shape[2] // k.shape[2])
+                return jnp.pad(x, ((0, 0), (0, 0), (0, more), (0, 0)))
+            q, k, v = grow(q), grow(k), grow(v)
         k_full = bkv.write_slots_at_layer(
             k_full, kv.quantize_kv(k, k_full.dtype, spec.kv_scale), li,
             slot_mapping)
@@ -1166,6 +1181,8 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                     lambda xs: gathered_mha(*xs),
                     (split(q), split(block_table), split(mask)))
                 attn_out = attn_out.reshape((b_,) + attn_out.shape[2:])
+        if grown:
+            attn_out = attn_out[:, :, :n_q]
     elif phase == "prefill":
         # flash kernel requirements beyond supports(): per-row positions must
         # be arange (the kernel rebuilds causality from array indices — an
@@ -1389,14 +1406,17 @@ RECURRENT_UNSUPPORTED = {
     "tensor capture/replacement": "the recurrent walk has no tap points",
     "deepstack": "the recurrent walk adds no per-layer visual features",
     "chunked side-buffer decode": "the recurrent walk writes KV in place",
-    "sandwich norm": "the recurrent walk is the plain pre-norm residual "
-                     "block",
+    "sandwich norm": "the recurrent walk is the pre-norm residual block "
+                     "or the post-norm one (norm_position 'post': a norm "
+                     "on each sub-block's output); norms on BOTH sides of "
+                     "a sub-block have not been walked",
     "paged parallel hybrid": "a layer running attention NEXT TO its mixer "
                              "(ssm_parallel) has not been walked on the "
                              "paged path",
-    "paged non-mamba2 state": "only the mamba2 mixer continues from a "
-                              "carried state and conv tail (rglru and "
-                              "shortconv prefill from zero)",
+    "paged rglru / shortconv state": "the rglru and shortconv blocks "
+                                     "prefill from zero; only the mamba2 "
+                                     "and gated_delta kinds continue from "
+                                     "a carried state and conv tail",
     "host KV spill / handoff": "a spilled or handed-off block carries KV "
                                "only, not the state that goes with it",
 }
@@ -1443,8 +1463,10 @@ def _paged_pool_fold(spec: DecoderSpec, cache, hidden, phase: str,
             or cache["k"].shape[4] != spec.head_dim      # folded already
             or _paged_kernel_declined(spec)):
         return 1
+    # the query heads of the pool's head slots (bkv.pool_kv_heads)
     plan = decode_attention.paged_dispatch_plan(
-        spec.gqa.num_q_heads, spec.head_dim, cache["k"], block_table.shape[1])
+        spec.gqa.num_q_heads // spec.gqa.num_kv_heads * cache["k"].shape[3],
+        spec.head_dim, cache["k"], block_table.shape[1])
     return plan.fold if plan is not None else 1
 
 
@@ -1741,7 +1763,10 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
     Every layer shares the sequential residual shape: pre-norm temporal
     block(s) → residual add → pre-norm MLP → residual add (each add scaled
     by ``residual_multiplier``); the temporal block is attention, the SSM,
-    or (parallel hybrid) their sum.
+    or (parallel hybrid) their sum. With ``norm_position="post"`` (HF
+    Olmo 2 / Olmo 3 / Olmo-Hybrid) there is no input norm and each
+    sub-block's OUTPUT goes through ``post_attn_norm`` / ``post_ff_norm``
+    before its add, on both kinds of layer.
 
     Phase "paged" (the serving step graphs): attention layers write and
     read through ``slot_mapping`` / ``block_table`` like any paged stack;
@@ -1757,18 +1782,27 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
     s = spec.ssm
     pat = spec.resolved_ssm_pattern
     paged = phase == "paged"
+    post_norm = spec.norm_position == "post"
     refuse_recurrent([
         phase not in ("prefill", "decode", "paged") and "multi-token decode",
         phase == "decode" and hidden.shape[1] != 1 and "multi-token decode",
-        spec.sandwich_norm and "sandwich norm",
+        spec.sandwich_norm and not post_norm and "sandwich norm",
         paged and spec.ssm_parallel and "paged parallel hybrid",
-        paged and s.kind != "mamba2" and "paged non-mamba2 state"])
+        paged and s.kind not in ssm_mod.CONTINUING_KINDS
+        and "paged rglru / shortconv state"])
     kf, vf = cache["k"], cache["v"]
     state_keys = [k for k in ("conv_x", "conv_bc", "ssm") if k in cache]
     new_state = {k: cache[k] for k in state_keys}
     valid = None
     if paged:
         valid = slot_mapping >= 0
+        # the engagement record (ops/kernel_mode.py) names what the serving
+        # graphs carry beside the KV pool: no kernel, the XLA fusions
+        slot_bytes = sum(v.size // v.shape[1] * v.dtype.itemsize
+                         for v in new_state.values())
+        kernel_mode.note("recurrent_state", "xla",
+                         f"kind={s.kind} slot_bytes={slot_bytes} "
+                         f"chunk={s.chunk_size}")
         if state_slots is None and hidden.shape[0] != new_state["ssm"].shape[1]:
             raise ValueError(
                 f"a paged step of {hidden.shape[0]} rows over "
@@ -1793,8 +1827,9 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
         if has_ssm and "ssm_layers" in params:
             js = ssm_i
             lw = {**lw, **jax.tree.map(lambda a: a[js], params["ssm_layers"])}
-        h = _norm(spec, hidden, lw["input_norm"],
-                  lw.get("input_norm_b") if spec.norm_bias else None)
+        h = hidden if post_norm else _norm(
+            spec, hidden, lw["input_norm"],
+            lw.get("input_norm_b") if spec.norm_bias else None)
         t_out = None
         if has_attn:
             a_out, kf, vf, _ = _attn_block(
@@ -1821,11 +1856,18 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
                                                state_slots, v2)
             t_out = s_out if t_out is None else t_out + s_out
             ssm_i += 1
+        if post_norm:
+            t_out = rms_norm(t_out, lw["post_attn_norm"], spec.rms_eps,
+                             spec.norm_offset)
         hidden = add(hidden, t_out)
-        h2 = _norm(spec, hidden, lw["post_norm"],
-                   lw.get("post_norm_b") if spec.norm_bias else None)
-        hidden = add(hidden, _mlp_block(spec, h2, lw, "dense", adapter_ids,
-                                        phase=phase))
+        h2 = hidden if post_norm else _norm(
+            spec, hidden, lw["post_norm"],
+            lw.get("post_norm_b") if spec.norm_bias else None)
+        m_out = _mlp_block(spec, h2, lw, "dense", adapter_ids, phase=phase)
+        if post_norm:
+            m_out = rms_norm(m_out, lw["post_ff_norm"], spec.rms_eps,
+                             spec.norm_offset)
+        hidden = add(hidden, m_out)
     return hidden, {"k": kf, "v": vf, **new_state}, {}
 
 
@@ -2761,8 +2803,8 @@ def spec_from_config(config: InferenceConfig, tp_degree: Optional[int] = None,
             and "tensor capture/replacement",
             paged and tcfg.decode_chunk_tokens > 1 and "fused decode loop",
             paged and kw.get("ssm_parallel") and "paged parallel hybrid",
-            paged and kw["ssm"].kind != "mamba2"
-            and "paged non-mamba2 state"])
+            paged and kw["ssm"].kind not in ssm_mod.CONTINUING_KINDS
+            and "paged rglru / shortconv state"])
         # the recurrent state replaces long-range KV; keep the attention
         # cache simple (full rows, no rolling/mixed layouts)
         kw.setdefault("rolling_window", False)

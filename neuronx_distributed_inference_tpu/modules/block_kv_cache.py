@@ -62,6 +62,27 @@ class BlockKVSpec:
         return -(-seq_len // self.block_size)
 
 
+#: the second-minor extent of a bfloat16 tile on the device, (16, 128)
+POOL_HEAD_TILE = 16
+
+
+def pool_kv_heads(num_kv_heads: int, tp: int = 1) -> int:
+    """Head slots of a page of the pool for a model of ``num_kv_heads`` kv
+    heads. A page is ``(block_size, heads, head_dim)`` and the device keeps
+    arrays in tiles over their two minor dimensions: with MORE than a
+    tile's worth of heads that are not whole tiles (30: Olmo-Hybrid-7B) it
+    stores the pool with the block's tokens minor instead, so the slot write
+    and the decode kernel, which read pages as they are declared, each paid
+    whole-pool copies a step (three a side, 7 GB of temps: the step did not
+    fit the chip). The pool then rounds its heads up to whole tiles
+    (30 -> 32; the padded heads hold zeros and the attention block pads its
+    q, k, v to match): every reshape of the pool is a bitcast again, as for
+    16 heads. One chip only: a shard's heads are not padded."""
+    if tp != 1 or num_kv_heads <= POOL_HEAD_TILE:
+        return num_kv_heads
+    return -(-num_kv_heads // POOL_HEAD_TILE) * POOL_HEAD_TILE
+
+
 def block_cache_pspec() -> P:
     return P(None, None, None, AXIS_MP, None)
 

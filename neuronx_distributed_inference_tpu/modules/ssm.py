@@ -24,6 +24,8 @@ channels/heads on the model axis):
   mamba2: conv_x (Ls,B,d_inner,K-1), conv_bc (Ls,B,2·g·N,K-1),
           ssm (Ls,B,nh,hd,N) fp32
   rglru:  conv_x (Ls,B,W,K-1), ssm (Ls,B,W) fp32
+  gated_delta: conv_x (Ls,B,2·nh·d_k + nh·d_v,K-1) over [q|k|v],
+          ssm (Ls,B,nh,d_k,d_v) fp32
 The conv tails hold the last K-1 *pre-conv* projected inputs, so a decode
 step is ``concat(tail, current) → depthwise dot`` exactly like the
 reference's cached path (modeling_falcon_h1.py torch_forward cached branch).
@@ -51,13 +53,17 @@ class SSMSpec:
     kind "rglru": recurrentgemma / Griffin RG-LRU linear recurrence.
     kind "shortconv": LFM2 gated short convolution (conv state only —
       reference: contrib/models/lfm2-2.6b; HF Lfm2ShortConv).
+    kind "gated_delta": gated delta-rule linear attention (Gated DeltaNet;
+      HF Qwen3NextGatedDeltaNet, Olmo-Hybrid's ``linear_attention`` layers):
+      a per-head (d_k, d_v) matrix state that is decayed, read back through
+      the key and corrected.
     """
 
-    kind: str                 # "mamba2" | "rglru"
-    d_inner: int              # mamba d_ssm / rglru lru_width
+    kind: str                 # "mamba2" | "rglru" | "shortconv" | "gated_delta"
+    d_inner: int              # mamba d_ssm / rglru lru_width / delta nh * d_v
     num_heads: int            # mamba_n_heads / rglru num_attention_heads
-    head_dim: int             # mamba_d_head / rglru block_width
-    d_state: int = 0          # mamba ssm state size N (rglru: unused)
+    head_dim: int             # mamba_d_head / rglru block_width / delta d_v
+    d_state: int = 0          # mamba state size N / delta d_k (rglru: unused)
     n_groups: int = 1         # mamba B/C groups
     d_conv: int = 4           # depthwise conv kernel width K
     chunk_size: int = 128     # prefill scan chunk
@@ -66,10 +72,24 @@ class SSMSpec:
     norm_before_gate: bool = False
     norm_eps: float = 1e-6        # gated-norm eps (falcon-h1: rms_norm_eps)
     dt_limit: Tuple[float, float] = (0.0, float("inf"))
+    # gated_delta: the write strength beta is 2 * sigmoid (in (0, 2): the
+    # state transition may have negative eigenvalues) instead of sigmoid
+    beta_scale: float = 1.0
 
     @property
     def bc_size(self) -> int:
         return 2 * self.n_groups * self.d_state
+
+    @property
+    def qk_size(self) -> int:
+        """gated_delta: the projected width of q, and of k."""
+        return self.num_heads * self.d_state
+
+    @property
+    def qkv_size(self) -> int:
+        """gated_delta: the channels of [q | k | v], what the depthwise
+        convolution runs over and the conv tail carries."""
+        return 2 * self.qk_size + self.d_inner
 
 
 # ---------------------------------------------------------------------------
@@ -99,6 +119,23 @@ def ssm_param_specs(s: SSMSpec, hidden: int, Ls: int, dtype) -> Dict[str, ParamS
         if s.gated_norm:
             specs["ssm_norm"] = ParamSpec((Ls, s.d_inner), P(None, AXIS_MP), dtype, "ones")
         return specs
+    if s.kind == "gated_delta":
+        # replicated: a recurrent stack has never run sharded, and the
+        # family that builds this kind refuses tp > 1. [q|k|v|gate] is ONE
+        # projection (a decode step is a GEMV per weight); the decay and the
+        # write strength [a|b] keep a float32 output of their own
+        conv = s.qkv_size
+        return {
+            "gdn_in": ParamSpec((Ls, hidden, conv + s.d_inner), P(), dtype),
+            "gdn_in_ab": ParamSpec((Ls, hidden, 2 * s.num_heads), P(), dtype),
+            "gdn_conv": ParamSpec((Ls, conv, s.d_conv), P(), dtype),
+            "gdn_dt_bias": ParamSpec((Ls, s.num_heads), P(), jnp.float32,
+                                     "ones"),
+            "gdn_A_log": ParamSpec((Ls, s.num_heads), P(), jnp.float32,
+                                   "zeros"),
+            "gdn_norm": ParamSpec((Ls, s.head_dim), P(), dtype, "ones"),
+            "gdn_out": ParamSpec((Ls, s.d_inner, hidden), P(), dtype),
+        }
     if s.kind == "shortconv":
         W = s.d_inner
         specs = {
@@ -146,6 +183,12 @@ def ssm_state_shapes(s: SSMSpec, Ls: int, batch: int, dtype
             "ssm": ((Ls, batch, s.num_heads, s.head_dim, s.d_state),
                     jnp.float32),
         }
+    if s.kind == "gated_delta":
+        return {
+            "conv_x": ((Ls, batch, s.qkv_size, K1), dtype),
+            "ssm": ((Ls, batch, s.num_heads, s.d_state, s.head_dim),
+                    jnp.float32),
+        }
     if s.kind == "shortconv":
         return {"conv_x": ((Ls, batch, s.d_inner, K1), dtype)}
     return {
@@ -176,6 +219,9 @@ def ssm_state_pspecs(s: SSMSpec) -> Dict[str, P]:
             "conv_bc": P(None, AXIS_DP, None, None),
             "ssm": P(None, AXIS_DP, AXIS_MP, None, None),
         }
+    if s.kind == "gated_delta":
+        return {"conv_x": P(None, AXIS_DP, None, None),
+                "ssm": P(None, AXIS_DP, None, None, None)}
     if s.kind == "shortconv":
         return {"conv_x": P(None, AXIS_DP, AXIS_MP, None)}
     return {"conv_x": P(None, AXIS_DP, AXIS_MP, None),
@@ -233,6 +279,20 @@ def _conv_step(tail, cur, w, b):
     return val, win[:, :, 1:]
 
 
+def _real_and_fresh(valid, phase, seq_lens, positions, shape):
+    """What the blocks that CONTINUE from a carried state read off a step:
+    ``(valid (B, T), n_valid (B,), keep (B,))`` - the real tokens of each
+    row (a prefix of it; default ``positions < seq_lens`` in prefill,
+    everything in decode), their count, and whether the row keeps the state
+    it is handed (False: its first real token is position 0, so it starts
+    from zeros)."""
+    if valid is None:
+        valid = ((positions < seq_lens[:, None]) if phase == "prefill"
+                 else jnp.ones(shape, bool))
+    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+    return valid, n_valid, ~(valid[:, 0] & (positions[:, 0] == 0))
+
+
 def _segsum(a_log):
     """Segment-sum decay matrix: M[t, s] = sum_{j=s+1..t} a_log[j] for
     s <= t, -inf otherwise. a_log (B, c, H) → (B, H, c, c)."""
@@ -284,11 +344,8 @@ def mamba2_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
     nh, hd = s.num_heads, s.head_dim
     r = nh // g
     K1 = s.d_conv - 1
-    if valid is None:
-        valid = ((positions < seq_lens[:, None]) if phase == "prefill"
-                 else jnp.ones((B, T), bool))
-    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
-    keep = ~(valid[:, 0] & (positions[:, 0] == 0))                # (B,)
+    valid, n_valid, keep = _real_and_fresh(valid, phase, seq_lens, positions,
+                                           (B, T))
     tail_x = jnp.where(keep[:, None, None], state["conv_x"], 0)
     tail_bc = jnp.where(keep[:, None, None], state["conv_bc"], 0)
     st0 = jnp.where(keep[:, None, None, None], state["ssm"].astype(f32), 0.0)
@@ -370,6 +427,153 @@ def mamba2_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
         y = y * jax.nn.silu(gate)
     out = y.astype(x.dtype) @ lw["ssm_out"]
     return out, new_state
+
+
+# ---------------------------------------------------------------------------
+# Gated delta rule (Gated DeltaNet) — Olmo-Hybrid / Qwen3-Next flavor
+# ---------------------------------------------------------------------------
+
+def _delta_step(q, k, v, g, beta, st0):
+    """One token of the gated delta rule. q, k (B,H,dk), v (B,H,dv), g (log
+    decay) and beta (B,H), st0 (B,H,dk,dv), all float32:
+    ``S = a S0 + beta k (v - (a S0)^T k)^T``, ``o = S^T q``. Both reads of
+    the old state (through k, and through q: ``S^T q = a S0^T q + (k . q)
+    delta``) share one pass over it; the second pass writes the new one."""
+    a = jnp.exp(g)
+    mem_k = jnp.sum(st0 * k[..., :, None], axis=-2)               # (B,H,dv)
+    mem_q = jnp.sum(st0 * q[..., :, None], axis=-2)
+    delta = beta[..., None] * (v - a[..., None] * mem_k)
+    st = a[..., None, None] * st0 + k[..., :, None] * delta[..., None, :]
+    kq = jnp.sum(k * q, axis=-1, keepdims=True)
+    return a[..., None] * mem_q + kq * delta, st
+
+
+def _delta_chunked(q, k, v, g, beta, st0, chunk: int):
+    """The same recurrence over T tokens in chunks (the WY / UT form), from
+    the carried state ``st0``. q, k (B,T,H,dk), v (B,T,H,dv), g and beta
+    (B,T,H), float32; a position with ``g = 0`` and ``beta = 0`` (padding)
+    leaves the state as it was. Returns ``(o (B,T,H,dv), S (B,H,dk,dv))``.
+
+    Inside a chunk, with ``G_i`` the cumulative log decay and ``M`` the
+    strictly lower part of ``(beta k) k^T * exp(G_i - G_j)``, the corrected
+    values solve the unit-lower-triangular system ``(I + M) [U | W] =
+    [beta v | beta k exp(G)]``; then ``V = U - W S`` are the rows actually
+    written, ``o = (q exp(G)) S + tril(q k^T * decay) V`` and the state
+    handed to the next chunk is ``S exp(G_last) + (k exp(G_last - G))^T V``.
+    The triangular solve is forward substitution (stable where the Neumann
+    series of ``M`` cancels catastrophically: beta near 2, keys aligned)."""
+    B, T, H, dv = v.shape
+    hi = jax.lax.Precision.HIGHEST
+    cs = min(chunk, T)
+    pad = (-T) % cs
+    nc = (T + pad) // cs
+
+    def chunks(a):                      # (B,T,H,...) -> (nc,B,H,cs,...)
+        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        a = a.reshape((B, nc, cs) + a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+
+    qc, kc, vc, bc = chunks(q), chunks(k), chunks(v), chunks(beta)
+    gc = jnp.cumsum(chunks(g), axis=-1)                           # (nc,B,H,cs)
+    lower = jnp.tril(jnp.ones((cs, cs), bool))
+    decay = jnp.exp(jnp.where(lower, gc[..., :, None] - gc[..., None, :],
+                              -jnp.inf))                          # i >= j
+    kb = kc * bc[..., None]
+    m = jnp.where(jnp.tril(lower, -1),
+                  jnp.einsum("...ik,...jk->...ij", kb, kc, precision=hi)
+                  * decay, 0.0)
+    uw = jax.lax.linalg.triangular_solve(
+        m, jnp.concatenate([vc * bc[..., None],
+                            kb * jnp.exp(gc)[..., None]], axis=-1),
+        left_side=True, lower=True, unit_diagonal=True)
+    qk = jnp.einsum("...ik,...jk->...ij", qc, kc, precision=hi) * decay
+    q_in = qc * jnp.exp(gc)[..., None]
+    g_last = gc[..., -1]                                          # (nc,B,H)
+    k_out = kc * jnp.exp(g_last[..., None] - gc)[..., None]
+
+    def chunk_body(st, inp):                                      # (B,H,dk,dv)
+        u, w, qk_i, q_i, k_i, gl = inp
+        v_new = u - jnp.einsum("bhck,bhkv->bhcv", w, st, precision=hi)
+        o = (jnp.einsum("bhck,bhkv->bhcv", q_i, st, precision=hi)
+             + jnp.einsum("bhcs,bhsv->bhcv", qk_i, v_new, precision=hi))
+        st = (st * jnp.exp(gl)[..., None, None]
+              + jnp.einsum("bhck,bhcv->bhkv", k_i, v_new, precision=hi))
+        return st, o
+
+    st, o = jax.lax.scan(chunk_body, st0,
+                         (uw[..., :dv], uw[..., dv:], qk, q_in, k_out,
+                          g_last))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)                 # (B,nc,cs,H,dv)
+    return o.reshape(B, T + pad, H, dv)[:, :T], st
+
+
+def gated_delta_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *,
+                      phase: str, seq_lens=None, positions=None, valid=None):
+    """One gated delta-rule block over its input x (B, T, H): ``[q|k|v] =
+    silu(conv(W x))`` with the carried conv tail in front, per head ``q, k``
+    l2-normalised (q scaled by ``d_k ** -0.5``), ``beta = beta_scale *
+    sigmoid(W_b x)``, ``alpha = exp(-exp(A_log) * softplus(W_a x +
+    dt_bias))``, the state update of :func:`_delta_step`, then per head
+    ``rmsnorm(o) * silu(W_g x)`` (``s.norm_before_gate``; the gate first
+    otherwise) and the out-projection. Returns
+    (y (B,T,H), new_state).
+
+    state: {"conv_x", "ssm"}, THIS layer's entries, one row per row of
+    ``x``. The block CONTINUES from it, resets a row whose first real
+    position is 0, and leaves the state and tail of padded positions and of
+    dead rows as they were — ``valid`` and the reset are exactly
+    :func:`mamba2_mixer`'s. T == 1 runs the O(1) state step, T > 1 the
+    chunked form in chunks of ``s.chunk_size``.
+    """
+    B, T, _ = x.shape
+    f32 = jnp.float32
+    nh, dk, dv = s.num_heads, s.d_state, s.head_dim
+    qk, K1 = s.qk_size, s.d_conv - 1
+    conv = s.qkv_size
+    valid, n_valid, keep = _real_and_fresh(valid, phase, seq_lens, positions,
+                                           (B, T))
+    tail = jnp.where(keep[:, None, None], state["conv_x"], 0)
+    st0 = jnp.where(keep[:, None, None, None], state["ssm"].astype(f32), 0.0)
+
+    proj = x @ lw["gdn_in"]
+    qkv = jnp.where(valid[..., None], proj[..., :conv], 0)
+    gate = proj[..., conv:].astype(f32).reshape(B, T, nh, dv)
+    ab = jnp.einsum("bth,hn->btn", x, lw["gdn_in_ab"],
+                    preferred_element_type=f32)
+    qkv_c = jax.nn.silu(_causal_conv_prefill(qkv, lw["gdn_conv"], None, tail))
+    new_state = {"conv_x": _conv_tail(qkv, n_valid, K1, tail)}
+
+    def heads(a, width):
+        a = a.astype(f32).reshape(B, T, nh, width)
+        return a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True)
+                                 + 1e-6)
+    q = heads(qkv_c[..., :qk], dk) * dk ** -0.5
+    k = heads(qkv_c[..., qk:2 * qk], dk)
+    v = qkv_c[..., 2 * qk:].astype(f32).reshape(B, T, nh, dv)
+    g = -jnp.exp(lw["gdn_A_log"].astype(f32)) * jax.nn.softplus(
+        ab[..., :nh] + lw["gdn_dt_bias"].astype(f32))
+    g = jnp.where(valid[..., None], g, 0.0)
+    beta = jnp.where(valid[..., None],
+                     s.beta_scale * jax.nn.sigmoid(ab[..., nh:]), 0.0)
+
+    if T == 1:
+        o, st = _delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                            st0)
+        o = o[:, None]
+    else:
+        o, st = _delta_chunked(q, k, v, g, beta, st0, s.chunk_size)
+    new_state["ssm"] = st
+
+    # per head: rmsnorm(o) * silu(gate) (norm_before_gate, the published
+    # order), or the gate first as the Mamba-2 mixer's gated norm has it
+    if not s.norm_before_gate:
+        o = o * jax.nn.silu(gate)
+    y = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                          + s.norm_eps) * lw["gdn_norm"].astype(f32)
+    if s.norm_before_gate:
+        y = y * jax.nn.silu(gate)
+    return y.reshape(B, T, s.d_inner).astype(x.dtype) @ lw["gdn_out"], \
+        new_state
 
 
 # ---------------------------------------------------------------------------
@@ -473,13 +677,19 @@ def shortconv_block(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
 
 
 _SSM_BLOCKS = {"mamba2": mamba2_mixer, "rglru": rglru_block,
-               "shortconv": shortconv_block}
+               "shortconv": shortconv_block,
+               "gated_delta": gated_delta_mixer}
+
+#: the kinds whose block continues from a carried state and conv tail and
+#: takes ``valid``: the ones the paged serving path can run
+CONTINUING_KINDS = ("mamba2", "gated_delta")
 
 
 def ssm_block(s: SSMSpec, lw, x, state, *, phase, seq_lens=None,
               positions=None, valid=None):
-    """``valid``: the paged step's real-token mask (mamba2 only: the one
-    kind whose block continues from a carried state)."""
+    """``valid``: the paged step's real-token mask (the kinds of
+    :data:`CONTINUING_KINDS` only: their blocks continue from a carried
+    state)."""
     kw = {} if valid is None else {"valid": valid}
     return _SSM_BLOCKS[s.kind](s, lw, x, state, phase=phase,
                                seq_lens=seq_lens, positions=positions, **kw)

@@ -689,7 +689,7 @@ class _PagedScratch:
 class _SlotScratch(_PagedScratch):
     """:class:`_PagedScratch` for a recurrent/hybrid stack: the decode
     step's rows ARE the state slots (row ``i`` is slot ``i``, always the
-    full batch), so the step graph updates conv tails and SSM state in
+    full batch), so the step graph updates conv tails and recurrent state in
     place and gathers nothing. A slot that is not stepped — free, held by
     a pending prefill, or a running row left out of this call — is a DEAD
     row: slot mapping -1 (``run_layers_ssm`` leaves its tail and state as
@@ -1407,7 +1407,7 @@ class PagedEngineAdapter(_EngineAdapterBase):
         self._init_decode_path(pipeline_depth)
         # recurrent/hybrid stack (recognised from the spec, no knob): every
         # live sequence holds one STATE SLOT of the second cache beside the
-        # KV pool (conv tails + SSM state, ``app.state_slots`` = batch
+        # KV pool (conv tails + the kind's state, ``app.state_slots`` = batch
         # rows), taken at admission inside the same transaction, freed at
         # release, preemption and rollback. Decode rows are laid out in
         # slot order (:class:`_SlotScratch`); what such a stack cannot do

@@ -366,7 +366,8 @@ def memory_ledger(adapter, *, registry=None,
                 "headroom": admission_headroom(adapter)}
     spec = mgr.spec
     # the KV pool alone: a recurrent/hybrid stack keeps its per-sequence
-    # state (conv tails + SSM state) in the same dict, accounted below
+    # state (``ssm.ssm_state_shapes`` of its kind) in the same dict,
+    # accounted below
     pool_bytes = _tree_bytes({k: app.cache[k] for k in ("k", "v")})
     state_bytes = _tree_bytes(app.cache) - pool_bytes
     block_bytes = pool_bytes // spec.num_blocks

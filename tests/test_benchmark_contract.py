@@ -19,7 +19,12 @@ not run).
     inside the tokens it runs (PERF.md §6): the cache the application
     allocates for the configuration's file has the dtypes the file's
     ``assumed`` names, and a served sequence's state slot is held to the
-    reference's ``S_t`` at a tolerance a bf16-carried state fails.
+    reference's ``S_t`` at a tolerance a bf16-carried state fails;
+  * the same for ``olmo-hybrid-7b`` (ISSUE 34): every published number, the
+    cut 32 -> 16 in whole periods, the dtypes and shapes ``assumed`` names,
+    the file's memory arithmetic re-derived from ``ssm_state_shapes``, the
+    pool and the parameter specs, and a served slot against the reference's
+    ``final_states``.
 """
 
 import json
@@ -253,4 +258,202 @@ def test_a_bf16_carried_state_fails_the_state_check(monkeypatch):
     allocated in bf16, so every dispatch rounds what it carries."""
     import jax.numpy as jnp
     err = _served_state_error(jnp.bfloat16, monkeypatch)
+    assert err > 10 * STATE_RTOL, err
+
+
+# ---------------------------------------------------------------------------
+# olmo-hybrid-7b (ISSUE 34)
+# ---------------------------------------------------------------------------
+
+OLMO_PERIOD = ["linear_attention"] * 3 + ["full_attention"]
+
+
+def test_olmo_hybrid_keeps_every_published_number():
+    """The catalog row's ``config`` (model-configs guide), as copied into
+    ISSUE 34: every key at the top level of the file, no width changed, and
+    ``reduced`` names the depth and the pattern cut with it."""
+    cfg = build.load_json("configs", "olmo-hybrid-7b.json")
+    published = dict(
+        model_type="olmo_hybrid", vocab_size=100352, hidden_size=3840,
+        intermediate_size=11008, num_attention_heads=30,
+        num_key_value_heads=30, hidden_act="silu",
+        max_position_embeddings=65536, attention_bias=False,
+        rms_norm_eps=1e-06, tie_word_embeddings=False,
+        linear_num_key_heads=30, linear_num_value_heads=30,
+        linear_key_head_dim=96, linear_value_head_dim=192,
+        linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+        rope_parameters={"rope_theta": None})
+    assert {k: cfg[k] for k in published} == published
+    # 32 published layers = 8 periods; the file keeps whole periods
+    assert cfg["reduced"] == ["num_hidden_layers", "layer_types"]
+    assert cfg["num_hidden_layers"] == len(cfg["layer_types"]) in (12, 16)
+    assert cfg["layer_types"] == OLMO_PERIOD * (cfg["num_hidden_layers"] // 4)
+    assert cfg["family"] == "olmo_hybrid" and cfg["chips"] == cfg["tp"] == 1
+    assert cfg["serve"]["is_prefix_caching"] is False
+    assumed = cfg["assumed"]
+    assert {"norm_placement", "no_positional_embedding", "tensor_names",
+            "state_dtype", "conv_tail_dtype", "kv_dtype",
+            "scan_chunk"} <= set(assumed)
+    gate = cfg["gate"]
+    assert gate["min_positions_held"] == 1.0
+    assert gate["excuse_margin_max"] == 0.0 and gate["worst_ratio_max"] == 1.0
+    twin = build.hf_config(cfg, build.gate_overrides(gate))
+    assert twin["layer_types"] == OLMO_PERIOD
+    assert twin["num_hidden_layers"] == 4 and twin["hidden_size"] == 3840
+    # the gate's twin fits harness/build.py's 4 blocks a row
+    assert gate["prompt_len"] + gate["new_tokens"] <= \
+        4 * cfg["serve"]["pa_block_size"]
+    # the pool cannot run dry: every row at its longest prompt and answer
+    mix = build.load_json("traffic", "reason-longanswer-closed.json")
+    serve = cfg["serve"]
+    longest = mix["prompt_len"]["hi"] + mix["output_len"]["hi"]
+    assert longest <= serve["seq_len"]
+    assert serve["pa_num_blocks"] * serve["pa_block_size"] == \
+        serve["batch_size"] * longest
+    # the mix is ISSUE 34's, letter for letter
+    assert (mix["loop"], mix["clients_per_batch_row"], mix["pool_requests"],
+            mix["lead_s"], mix["grace_s"], mix["base_seed"]) == \
+        ("closed", 2, 4096, 10.0, 8.0, 34)
+    assert mix["prompt_len"] == dict(kind="lognormal", median=192, sigma=0.8,
+                                     lo=32, hi=768)
+    assert mix["output_len"] == dict(kind="lognormal", median=448, sigma=0.6,
+                                     lo=96, hi=1024)
+
+
+def test_olmo_hybrid_allocates_what_its_file_says():
+    """The dtypes and shapes ``assumed`` names and the memory arithmetic of
+    the file, against what the program allocates: the cache of the gate's
+    twin (built as the harness builds it), and the full configuration's
+    state, pool and parameters as SHAPES (nothing of 12 GB is allocated)."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    from neuronx_distributed_inference_tpu.models import model_base
+    from neuronx_distributed_inference_tpu.modules import ssm
+    from neuronx_distributed_inference_tpu.modules.block_kv_cache import \
+        pool_kv_heads
+    from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
+    cfg = build.load_json("configs", "olmo-hybrid-7b.json")
+    gate, assumed, memory = cfg["gate"], cfg["assumed"], cfg["memory"]
+    app = build.build_app(
+        cfg, overrides=build.gate_overrides(gate),
+        serve=dict(cfg["serve"], batch_size=gate["batch"], seq_len=256,
+                   pa_num_blocks=4 * gate["batch"],
+                   context_encoding_buckets=[128])).init_cache()
+    assert {k: str(v.dtype) for k, v in app.cache.items()} == {
+        "k": assumed["kv_dtype"], "v": assumed["kv_dtype"],
+        "conv_x": assumed["conv_tail_dtype"], "ssm": assumed["state_dtype"]}
+    assert app.cache["ssm"].shape == (3, gate["batch"], 30, 96, 192)
+    assert app.cache["conv_x"].shape == (3, gate["batch"], 11520, 3)
+    assert app.cache["k"].shape == (1, 4 * gate["batch"] + 1, 32,
+                                    memory["kv_pool_heads"], 128)
+    assert app.spec.ssm.chunk_size == 64
+    # the full configuration, as shapes
+    full = build.build_app(cfg)
+    spec, serve = full.spec, cfg["serve"]
+    n_lin = cfg["layer_types"].count("linear_attention")
+    n_full = cfg["layer_types"].count("full_attention")
+    assert (spec.num_ssm_layers, spec.num_attn_layers) == (n_lin, n_full)
+    state = ssm.ssm_state_shapes(spec.ssm, n_lin, serve["batch_size"],
+                                 jnp.dtype(cfg["dtype"]))
+    assert state["ssm"][0] == (n_lin, 32, 30, 96, 192)
+    state_bytes = sum(math.prod(shape) * jnp.dtype(dt).itemsize
+                      for shape, dt in state.values())
+    assert state_bytes == memory["state_bytes"] == \
+        serve["batch_size"] * memory["state_slot_bytes"]
+    heads = pool_kv_heads(spec.gqa.num_kv_heads, spec.gqa.tp)
+    assert heads == memory["kv_pool_heads"] == 32
+    per_token = n_full * 2 * heads * spec.head_dim * 2
+    assert per_token == memory["kv_bytes_per_token"]
+    assert (serve["pa_num_blocks"] + 1) * serve["pa_block_size"] * \
+        per_token == memory["kv_pool_bytes"]
+    weights = sum(
+        math.prod(ps.shape) * jnp.dtype(ps.dtype).itemsize
+        for ps in jax.tree.leaves(
+            model_base.decoder_param_specs(spec),
+            is_leaf=lambda x: isinstance(x, ParamSpec)))
+    # A_log and dt_bias are float32 in the program (2 x 30 x 12 x 2 B more
+    # than the file's all-bf16 count) and the vocabulary is not padded
+    assert weights - 2 * 2 * 30 * n_lin == memory["weights_bytes"]
+    assert spec.padded_vocab == cfg["vocab_size"]
+    total = memory["weights_bytes"] + memory["state_bytes"] \
+        + memory["kv_pool_bytes"]
+    assert 0.75 * 16e9 < total < 0.85 * 16e9
+
+
+def _olmo_toy():
+    """One period at a toy size (``tests/test_olmo_hybrid_paged.py``'s) as
+    a configuration file the harness can build and gate."""
+    from test_olmo_hybrid_paged import HF
+    return dict(
+        HF, family="olmo_hybrid", tp=1, dtype="float32",
+        serve=dict(batch_size=4, seq_len=256, pa_block_size=8,
+                   pa_num_blocks=160, context_encoding_buckets=[16, 64],
+                   enable_bucketing=True, is_block_kv_layout=True,
+                   is_prefix_caching=False),
+        adapter={},
+        gate=dict(config={}, batch=2, prompt_len=24, new_tokens=4,
+                  atol=1e-4, rtol=1e-4, min_positions_held=1.0,
+                  median_ratio_max=0.5, worst_ratio_max=1.0,
+                  excuse_margin_max=0.0))
+
+
+def test_the_olmo_hybrid_reference_gates_a_toy_twin():
+    ref = build.load_reference("olmo_hybrid")
+    assert ref.__file__ == os.path.join(BENCH, "references",
+                                        "olmo_hybrid.py")
+    toy = _olmo_toy()
+    res = build.logit_gate(toy, seed=2**31 + 34, served_precision="highest")
+    assert res["passed"], res
+    assert res["compared"] == 2 * 28 * toy["vocab_size"]
+
+
+def _olmo_served_state_error(rounds_to=None, monkeypatch=None):
+    """As :func:`_served_state_error`, for the delta rule: one toy sequence
+    through ``PagedEngineAdapter`` (150 prompt tokens = 64 + 64 + 22: two
+    whole one-row chunks of one scan chunk each and a padded one, then 60
+    decode steps), its state slot against the reference's
+    ``final_states``."""
+    import jax.numpy as jnp
+    import numpy as np
+    from harness import weights
+    from neuronx_distributed_inference_tpu.modules import ssm
+    from neuronx_distributed_inference_tpu.serving import PagedEngineAdapter
+    if rounds_to is not None:
+        shapes = ssm.ssm_state_shapes
+
+        def rounded(*a, **kw):
+            out = shapes(*a, **kw)
+            return dict(out, ssm=(out["ssm"][0], rounds_to))
+        monkeypatch.setattr(ssm, "ssm_state_shapes", rounded)
+    toy = _olmo_toy()
+    hf = build.hf_config(toy)
+    ref = build.load_reference(hf["model_type"])
+    table = ref.weight_shapes(hf)
+    w = weights.make_weights(table, seed=2**31 + 35)
+    app = build.build_app(toy)
+    app._put_params(app.family.convert_hf_state_dict(
+        weights.HfView(table, w, dtype=np.dtype("float32")), app.spec))
+    app.init_cache()
+    ad = PagedEngineAdapter(app)
+    prompt = np.random.default_rng(35).integers(
+        1, hf["vocab_size"], size=150).tolist()
+    stream = [ad.add_requests([3], [prompt])[3]]
+    for _ in range(60):
+        stream.append(ad.step([3])[3])
+    got = np.asarray(app.cache["ssm"][:, ad._state_slot[3]], np.float32)
+    want = np.asarray(ref.final_states(
+        hf, w, jnp.asarray([prompt + stream[:-1]])))[:, 0]
+    assert got.shape == want.shape
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def test_a_served_olmo_slot_holds_the_references_state():
+    assert _olmo_served_state_error() < STATE_RTOL
+
+
+def test_a_bf16_carried_olmo_state_fails_the_state_check(monkeypatch):
+    import jax.numpy as jnp
+    err = _olmo_served_state_error(jnp.bfloat16, monkeypatch)
     assert err > 10 * STATE_RTOL, err

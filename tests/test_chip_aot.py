@@ -27,8 +27,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from neuronx_distributed_inference_tpu.config import TpuConfig
 from neuronx_distributed_inference_tpu.models import model_base
 from neuronx_distributed_inference_tpu.models.family import get_family
+from neuronx_distributed_inference_tpu.modules import ssm
 from neuronx_distributed_inference_tpu.modules.block_kv_cache import (
-    BlockKVSpec, block_cache_pspec)
+    BlockKVSpec, block_cache_pspec, pool_kv_heads)
 from neuronx_distributed_inference_tpu.ops import kernel_mode
 from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
 from neuronx_distributed_inference_tpu.parallel.mesh import (MeshConfig,
@@ -93,13 +94,15 @@ def as_on_the_chip(monkeypatch):
                           floor)
 
 
-def _serving_shapes(hf_attrs, layers, tp, devices, serve):
+def _serving_shapes(hf_attrs, layers, tp, devices, serve, prefix=True):
     """(spec, tcfg, mesh, params, cache, sds, max_blocks): the paged serving
     graphs' static arguments and their operands as shapes sharded on
-    ``devices``."""
+    ``devices`` - the KV pool as the application allocates it, and for a
+    recurrent stack its state slots beside it."""
     mesh = build_mesh(MeshConfig(tp=tp), devices)
     tcfg = TpuConfig(tp_degree=tp, dtype="bfloat16", enable_bucketing=True,
-                     is_block_kv_layout=True, is_prefix_caching=True, **serve)
+                     is_block_kv_layout=True, is_prefix_caching=prefix,
+                     **serve)
     family = get_family(hf_attrs["model_type"])
     icfg = family.config_cls(tcfg, **dict(hf_attrs,
                                           num_hidden_layers=layers))
@@ -113,11 +116,18 @@ def _serving_shapes(hf_attrs, layers, tp, devices, serve):
                           model_base.decoder_param_specs(spec),
                           is_leaf=lambda x: isinstance(x, ParamSpec))
     bspec = BlockKVSpec(
-        num_layers=spec.num_layers, num_blocks=tcfg.pa_num_blocks + 1,
-        block_size=tcfg.pa_block_size, num_kv_heads=spec.gqa.num_kv_heads,
+        num_layers=spec.num_attn_layers, num_blocks=tcfg.pa_num_blocks + 1,
+        block_size=tcfg.pa_block_size,
+        num_kv_heads=pool_kv_heads(spec.gqa.num_kv_heads, tp),
         head_dim=spec.head_dim, dtype=spec.kv_dtype)
     cache = {k: sds(bspec.shape, bspec.dtype, block_cache_pspec())
              for k in ("k", "v")}
+    if spec.ssm is not None:
+        pspecs = ssm.ssm_state_pspecs(spec.ssm)
+        for k, (shape, dt) in ssm.ssm_state_shapes(
+                spec.ssm, spec.num_ssm_layers, tcfg.batch_size,
+                spec.dtype).items():
+            cache[k] = sds(shape, dt, pspecs[k])
     return spec, tcfg, mesh, params, cache, sds, bspec.blocks_for(tcfg.seq_len)
 
 
@@ -251,6 +261,67 @@ def test_olmoe_chunk_reads_experts_and_pool_in_place(v5e_devices):
     assert notes == set()
     assert "ragged-dot" not in decode.as_text()
     assert MOSAIC in decode.as_text()        # the paged decode kernel
+
+
+# allenai/Olmo-Hybrid-7B config.json (model-configs catalog), one period
+OLMO_HYBRID_7B = dict(
+    model_type="olmo_hybrid", vocab_size=100352, hidden_size=3840,
+    intermediate_size=11008, num_attention_heads=30, num_key_value_heads=30,
+    hidden_act="silu", max_position_embeddings=65536, attention_bias=False,
+    rms_norm_eps=1e-6, tie_word_embeddings=False,
+    layer_types=["linear_attention", "linear_attention", "linear_attention",
+                 "full_attention"],
+    linear_num_key_heads=30, linear_num_value_heads=30,
+    linear_key_head_dim=96, linear_value_head_dim=192,
+    linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+    rope_parameters={"rope_theta": None})
+
+
+def test_30_kv_heads_of_128_decode_on_the_kernel_with_no_pool_copy(
+        v5e_devices):
+    """ISSUE 34: Olmo-Hybrid-7B's attention (30 kv heads of 128, MHA) at one
+    period, the benchmark's batch, pool and table. With 30 heads to a page
+    the device stored the pool tokens-minor and the T=1 step moved the whole
+    pool six times (1.86 GB of temps at ONE attention layer; at the cell's
+    four the step did not fit the chip). The pool rounds its heads up to
+    whole tiles (``block_kv_cache.pool_kv_heads``: 32 slots): the step holds
+    the Mosaic call, the record says what it runs with, and no instruction
+    moves a pool. The one-row 256-token chunk (the delta rule's chunked
+    form, its triangular solve) compiles beside it."""
+    spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
+        OLMO_HYBRID_7B, 4, 1, v5e_devices[:1],
+        dict(batch_size=32, seq_len=2048, pa_block_size=32,
+             pa_num_blocks=1792, context_encoding_buckets=[64, 256]),
+        prefix=False)
+    assert cache["k"].shape == (1, 1793, 32, 32, 128)
+    assert cache["ssm"].shape == (3, 32, 30, 96, 192)
+    i32 = jnp.int32
+
+    def compiled(rows, width, **kw):
+        notes = set()
+        with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
+            c = jax.jit(partial(model_base.paged_forward_step, spec, tcfg),
+                        donate_argnums=(1,)).lower(
+                params, cache, *(sds((rows, width), i32),) * 3,
+                sds((rows, mb), i32), sds((rows,), i32), None,
+                sds((2,), jnp.uint32), **kw).compile()
+        return c, notes
+
+    state = ("recurrent_state", "xla",
+             "kind=gated_delta slot_bytes=6842880 chunk=64")
+    step, notes = compiled(32, 1)
+    assert notes == {state, ("paged_decode", "pallas",
+                             "pages=1 heads=32 form=mxu-blockdiag fold=1")}
+    text = step.as_text()
+    assert MOSAIC in text
+    moves = re.findall(
+        r"%(\S+) = bf16\[(?:1,1793,32,32,128|57376,32,128)\]\S* "
+        r"(copy|transpose)\(", text)
+    assert not moves, moves
+    assert step.memory_analysis().temp_size_in_bytes < 100e6
+    chunk, notes = compiled(1, 256, state_slots=sds((1,), i32))
+    assert notes == {state}
+    assert chunk.memory_analysis().temp_size_in_bytes < 200e6
 
 
 def test_without_the_request_nothing_is_interpreted(v5e_devices,

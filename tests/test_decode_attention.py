@@ -410,7 +410,10 @@ def test_paged_decode_kernel_stacked_layers(rng):
 # ISSUE 33: the kernel's walk follows a row's LIVE pages, several to a compute
 # block. Both benchmark cells' attention geometries (query heads, kv heads,
 # head_dim), block 32; every case a row beside a second, ordinary row.
-_CELLS = {"olmoe": (16, 16, 128), "granite": (32, 8, 64)}
+# olmo-hybrid (ISSUE 34): 30 heads in a pool of 32 head slots
+# (block_kv_cache.pool_kv_heads), one page a compute block.
+_CELLS = {"olmoe": (16, 16, 128), "granite": (32, 8, 64),
+          "olmo-hybrid": (32, 32, 128)}
 _BS = 32
 
 
@@ -442,9 +445,11 @@ def _walk_case(case, pages, mb):
 def test_paged_decode_walks_live_pages(rng, cell, case):
     hq, hkv, d = _CELLS[cell]
     dtype = jnp.bfloat16 if case == "bf16" else jnp.float32
-    mb = 2 * _cell_plan(cell, dtype, 64).pages + 1
+    # ... and wide enough for the longest case (a block + 70 tokens)
+    mb = max(2 * _cell_plan(cell, dtype, 64).pages + 1, 5)
     plan = _cell_plan(cell, dtype, mb)
-    assert plan.fold == {"olmoe": 1, "granite": 2}[cell] and plan.pages >= 2
+    assert plan.fold == {"granite": 2}.get(cell, 1)
+    assert plan.pages == 1 if cell == "olmo-hybrid" else plan.pages >= 2
     lens, window = _walk_case(case, plan.pages, mb)
     b, scale = len(lens), d ** -0.5
     # physical pages shuffled and non-contiguous (_paged_setup), block 0 null
