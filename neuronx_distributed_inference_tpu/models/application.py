@@ -19,6 +19,7 @@ model_wrapper.py:1578-1627).
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 import os
 import time
@@ -1179,6 +1180,10 @@ class PagedCausalLMApplication(CausalLMApplication):
                             self.tpu_config)
         return jax.jit(fn, donate_argnums=(1,))
 
+    @functools.cached_property
+    def _decode_ids_sharding(self):
+        return NamedSharding(self.mesh, P())
+
     @property
     def state_slots(self) -> int:
         """Per-sequence recurrent-state slots beside the KV pool: one per
@@ -1422,6 +1427,14 @@ class PagedCausalLMApplication(CausalLMApplication):
         with self._run_span("paged", input_ids.shape[0]):
             fn = self.get_compiled("paged_forward")
             aids = self._lora_adapter_ids(adapter_ids)
+            if input_ids.shape[1] == 1 and not isinstance(input_ids,
+                                                          jax.Array):
+                # the decode step's ids are placed as the step hands them
+                # on (``out["next_ids"]``: committed, replicated), so a
+                # step fed from the host and one fed the previous step's
+                # output on the device are ONE executable
+                input_ids = jax.device_put(input_ids,
+                                           self._decode_ids_sharding)
             # one jitted graph serves every paged call; the shape signature
             # (prefill width x table width) is what distinguishes compiles
             self._note_jit("paged", input_ids.shape[1],

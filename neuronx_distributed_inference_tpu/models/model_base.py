@@ -2232,6 +2232,13 @@ def paged_forward_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     else:
         out["tokens"] = sampling_ops.sample_dp(
             logits, tpu_cfg.on_device_sampling_config, sampling_params, rng)
+    if input_ids.shape[1] == 1:
+        # the decode step hands its sampled tokens on in the shape, dtype
+        # and (replicated) placement of its own ``input_ids``: a serving
+        # adapter with one step in flight feeds this output straight into
+        # the next dispatch, with no helper program between two steps
+        out["next_ids"] = _shard(
+            out["tokens"].astype(input_ids.dtype)[:, None])
     return out
 
 

@@ -29,7 +29,7 @@ Fault points (a STABLE contract, like the telemetry metric names):
   ``slow_step``      start of ``step()`` — sleeps ``delay_s`` instead of
                      raising (drives deadline expiry deterministically)
   ``pipeline_flush`` the deferred token fetch of the pipelined decode path
-                     (``pipeline_depth >= 1``) — fires where a genuine
+                     (the engine's ``step_ahead()``) — fires where a genuine
                      asynchronous device failure from the PREVIOUS dispatch
                      would surface, so lookahead rollback is testable
                      deterministically

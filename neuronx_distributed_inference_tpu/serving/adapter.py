@@ -13,8 +13,10 @@ The engine owns scheduling; this adapter owns device state:
   * ``step_many(k, seq_ids=None)``      — k fused decode steps in ONE
     device dispatch + ONE host fetch (CB: the jitted lax.scan decode loop;
     paged: the fused paged loop with in-graph KV-slot advance)
-  * ``flush()``                         — retire the pipelined in-flight
-    dispatch (no-op in eager mode)
+  * ``step_ahead(seq_ids=None)``        — ``step`` with one step kept in
+    flight: returns the PREVIOUS step's tokens (the serving engine's call)
+  * ``flush()``                         — retire the in-flight dispatch
+    (no-op when none is)
   * ``release(seq_ids)``                — free rows (and paged blocks)
 
 Works over either application:
@@ -24,16 +26,36 @@ Works over either application:
 
 Decode pipeline (see README "Decode pipeline"):
 
-  * ``pipeline_depth=0`` (default) is the eager path, bit-identical to the
-    pre-pipeline behavior: every ``step()`` dispatches and synchronously
-    fetches its own tokens.
-  * ``pipeline_depth=1`` keeps the previous dispatch's sampled tokens ON
-    DEVICE and feeds them straight into the next decode call, fetching to
-    host asynchronously one step behind — host bookkeeping overlaps device
-    compute and the device never idles behind Python between steps.
-    ``step()`` then returns the PREVIOUS step's tokens ({} on the first
-    call); ``flush()`` drains the last one. Token streams are bit-identical
-    to eager (pinned by tests/test_decode_pipeline.py).
+  * ``step()`` returns THIS step's tokens: every call dispatches and
+    synchronously fetches (the eager template). That contract holds for
+    every caller that drives the adapter itself.
+  * ``step_ahead()`` is the same step for a caller that can take its
+    tokens one call LATE, which the serving engine's loop is: it enqueues
+    step N+1, fed step N's sampled tokens ON THE DEVICE (the decode step
+    hands them on as ``out["next_ids"]``, in the placement of its own ids
+    input: one executable, no helper program between two steps), and only
+    then blocks on step N. Scheduling, input preparation, token routing
+    and the yield to the stream writers run while the device computes.
+    ``ServingEngine`` over a paged adapter runs this way BY DEFAULT on the
+    plain two-phase path (no speculation, no ragged dispatch,
+    ``decode_steps_per_pass == 1``); token streams, counts and finish
+    reasons are those of eager (tests/test_engine_lookahead.py,
+    tests/test_decode_pipeline.py).
+  * What drains the step in flight: a changed live set. A finished or
+    cancelled row (``release``), a preempted row and an admission that
+    graduated a row do NOT block where they happen: the step stays in
+    flight, the next decode call sees that its rows changed, fetches it
+    synchronously, dispatches the new composition from host tokens, and
+    the call after overlaps again. One synchronous step a change, and a
+    finish with the admission that follows it share one. A released row's
+    in-flight token is dropped at the fetch; its KV growth goes with its
+    blocks. ``host_stats`` counts ``overlapped_dispatches`` and
+    ``pipeline_drains_<admit|release|preempt|liveset>``.
+  * ``pipeline_depth`` (the one spelling of the choice): ``None``
+    (default) is the above; ``0`` keeps ``step_ahead()`` eager too (the
+    tests' and a debugger's way to take the lookahead out from under the
+    engine); ``1`` makes ``step()`` itself return the PREVIOUS step's
+    tokens ({} on the first call; ``flush()`` drains the last one).
   * Deferred-failure contract: a device failure from step N surfaces at
     step N+1's fetch as a :class:`StepFailure` with ``retry_safe=False``;
     every in-flight lookahead step's host bookkeeping (positions, paged KV
@@ -172,7 +194,8 @@ class _ChunkState:
 
 @dataclass
 class _Inflight:
-    """One dispatched-but-not-fetched decode step (pipeline_depth >= 1).
+    """One dispatched-but-not-fetched decode step (``step_ahead()``, or
+    ``step()`` under ``pipeline_depth=1``).
 
     ``states`` pins the exact _SeqState objects the dispatch advanced:
     retire/rollback apply only where the identity still matches, so a row
@@ -240,6 +263,12 @@ def _trace_error(err):
     if rec.enabled and getattr(err, "trace_id", None) is None:
         rec.error(err)
     return err
+
+
+# what drains an in-flight decode step (host_stats["pipeline_drains_<cause>"],
+# nxdi_pipeline_drains_total{cause}): an admission graduated a row, a row
+# was released, a row was preempted, or the caller stepped another set
+_DRAIN_CAUSES = ("admit", "release", "preempt", "liveset")
 
 
 def _async_fetch(x):
@@ -383,6 +412,18 @@ class _AdapterTelemetry:
         reg = self.registry
         if reg.enabled:
             tmetrics.dispatch_depth_gauge(reg).set(depth, engine=self.engine)
+
+    def on_overlap(self):
+        reg = self.registry
+        if reg.enabled:
+            tmetrics.overlapped_dispatches_counter(reg).inc(
+                engine=self.engine)
+
+    def on_drain(self, cause: str):
+        reg = self.registry
+        if reg.enabled:
+            tmetrics.pipeline_drains_counter(reg).inc(engine=self.engine,
+                                                      cause=cause)
 
     def on_fetch(self, steps: int, overlap_s: Optional[float] = None):
         reg = self.registry
@@ -749,13 +790,18 @@ class _EngineAdapterBase:
     engine_name = ""
     _decode_failure_msg = "decode device step failed"
 
-    def _init_decode_path(self, pipeline_depth: int):
-        if pipeline_depth not in (0, 1):
+    def _init_decode_path(self, pipeline_depth: Optional[int]):
+        if pipeline_depth not in (None, 0, 1):
             raise ConfigurationError(
-                f"pipeline_depth must be 0 (eager) or 1 (one dispatch of "
-                f"lookahead), got {pipeline_depth!r}")
+                f"pipeline_depth must be 0 (eager), 1 (one dispatch of "
+                f"lookahead) or None (lookahead for the caller that asks "
+                f"through step_ahead()), got {pipeline_depth!r}")
         self.pipeline_depth = pipeline_depth
         self._inflight: Optional[_Inflight] = None
+        # why the in-flight step can no longer be fed back (the live set
+        # changed under it): named by whoever changed it, counted where
+        # the step is drained
+        self._drain_cause: Optional[str] = None
         self._ready: Dict[int, int] = {}
         self._scratch = None
         self._spec = None              # SpeculativeDecodePath (paged only)
@@ -774,6 +820,11 @@ class _EngineAdapterBase:
         self.host_stats: Dict[str, Any] = {
             "dispatches": 0, "device_steps": 0,
             "blocking_fetches": 0, "blocked_s": 0.0,
+            # decode dispatches enqueued while the previous step was still
+            # unfetched, and in-flight steps drained synchronously because
+            # the live set changed under them, by what changed it
+            "overlapped_dispatches": 0,
+            **{f"pipeline_drains_{c}": 0 for c in _DRAIN_CAUSES},
             "prefill_dispatches": 0, "prefill_blocking_fetches": 0,
             "prefill_blocked_s": 0.0, "prefill_real_tokens": 0,
             "prefill_padded_tokens": 0}
@@ -835,10 +886,11 @@ class _EngineAdapterBase:
     def step(self, seq_ids: Optional[Sequence[int]] = None) -> Dict[int, int]:
         """One decode step for ``seq_ids`` (default: every running row).
 
-        Eager (``pipeline_depth=0``): returns {seq_id: next token} for THIS
-        step. Pipelined (``pipeline_depth=1``): dispatches this step and
-        returns the PREVIOUS step's tokens ({} on the first call after the
-        pipeline empties; drain the last step with :meth:`flush`). Raises
+        Returns {seq_id: next token} for THIS step (eager), unless the
+        adapter was built with ``pipeline_depth=1``: then it dispatches this
+        step and returns the PREVIOUS step's tokens ({} on the first call
+        after the pipeline empties; drain the last step with :meth:`flush`),
+        as :meth:`step_ahead` does for the serving engine by default. Raises
         :class:`DeadlineExceeded` / :class:`CapacityError` before any
         device work when a row is over budget, and :class:`StepFailure`
         when a device step fails — see the class docstring for the
@@ -846,6 +898,31 @@ class _EngineAdapterBase:
         if self.pipeline_depth:
             return self._step_pipelined(seq_ids)
         return self._step_eager(seq_ids)
+
+    def step_ahead(self, seq_ids: Optional[Sequence[int]] = None
+                   ) -> Dict[int, int]:
+        """:meth:`step` for a caller that can take a step's tokens one call
+        late — the serving engine's loop. Unless the adapter was built
+        with ``pipeline_depth=0`` the call enqueues this step BEFORE it
+        blocks on the previous one and returns the previous step's tokens,
+        so the caller's own work between two calls (scheduling, routing,
+        the yield to the stream writers) runs while the device computes.
+        :meth:`flush` hands back what is still in flight; :attr:`lookahead_ids`
+        names the rows that have such a token coming."""
+        if self.pipeline_depth == 0:
+            return self._step_eager(seq_ids)
+        return self._step_pipelined(seq_ids)
+
+    @property
+    def lookahead_ids(self) -> set:
+        """seq_ids whose next token is sampled, or being sampled by the
+        in-flight step, but not handed to the caller yet."""
+        ids = set(self._ready)
+        rec = self._inflight
+        if rec is not None:
+            ids.update(s for s, st in zip(rec.live, rec.states)
+                       if self.seqs.get(s) is st)
+        return ids
 
     def step_many(self, num_steps: int,
                   seq_ids: Optional[Sequence[int]] = None
@@ -903,6 +980,7 @@ class _EngineAdapterBase:
         ready = self._drain_ready()
         rec, self._inflight = self._inflight, None
         if rec is not None:
+            self._note_drain()
             try:
                 ready.update(self._retire_or_abort([rec]))
             except BaseException:
@@ -990,6 +1068,7 @@ class _EngineAdapterBase:
         prev, self._inflight = self._inflight, None
         if prev is not None and not self._matches(prev, live):
             # live-set changed since the dispatch: drain it synchronously
+            self._note_drain()
             ready.update(self._retire_or_abort([prev]))
             prev = None
         t0 = time.perf_counter()
@@ -1004,6 +1083,7 @@ class _EngineAdapterBase:
         if prev is not None and not self._matches(prev, live):
             # preemption shrank the batch mid-call: drain the old
             # composition's dispatch before re-padding for the new one
+            self._note_drain("preempt")
             ready.update(self._retire_or_abort([prev]))
             prev = None
         scr = self._scratch_for(live)
@@ -1036,7 +1116,10 @@ class _EngineAdapterBase:
             grown=self._step_growth, rows=scr.rows)
         for s in live:
             self.seqs[s].position += 1
+        self._drain_cause = None           # the step in flight is current
         if prev is not None:
+            self.host_stats["overlapped_dispatches"] += 1
+            self.telemetry.on_overlap()
             ready.update(self._retire_or_abort([prev, rec]))
         self._inflight = rec
         self.telemetry.on_dispatch(1)
@@ -1047,11 +1130,45 @@ class _EngineAdapterBase:
                 and all(self.seqs.get(s) is st
                         for s, st in zip(rec.live, rec.states)))
 
+    # pad rows sample what row 0 samples (greedy, or the positionally
+    # coupled stream with row 0's seed): a step's full-batch output is
+    # then the next step's ids as it stands
+    _pads_follow_row0 = False
+
+    def _note_stale(self, cause: str):
+        """Rows left or joined (``cause``) while a step is in flight. The
+        step stays in flight — nothing blocks here; the next decode call
+        sees the changed live set, drains it and counts ``cause``. If none
+        of its rows is left, nobody is owed its tokens: it is dropped
+        unfetched."""
+        rec = self._inflight
+        if rec is None:
+            return
+        if all(self.seqs.get(s) is not st
+               for s, st in zip(rec.live, rec.states)):
+            self._inflight = None
+            self._drain_cause = None
+            self.telemetry.on_dispatch(0)
+        elif self._drain_cause is None:
+            self._drain_cause = cause
+
+    def _note_drain(self, default: str = "liveset"):
+        cause, self._drain_cause = self._drain_cause or default, None
+        self.host_stats[f"pipeline_drains_{cause}"] += 1
+        self.telemetry.on_drain(cause)
+
     def _feedback_tokens(self, prev: _Inflight, scr):
-        """The previous dispatch's on-device sampled tokens, re-padded ON
-        DEVICE (pad rows must stay clones of row 0 even under stochastic
-        sampling) and fed straight back as the next step's input ids — no
-        host round trip."""
+        """The previous dispatch's on-device sampled tokens as the next
+        step's input ids — no host round trip. A paged decode step hands
+        them on ready-made (``out["next_ids"]``: the live set is unchanged,
+        so pad rows are clones of row 0 and a slot-ordered step has no pad
+        rows): no program runs between two steps. Otherwise they are
+        re-padded ON DEVICE (pad rows must stay clones of row 0 even under
+        unseeded stochastic sampling)."""
+        nxt = prev.out.get("next_ids")
+        if nxt is not None and (scr.pad_to == scr.b or scr.rows is not None
+                                or self._pads_follow_row0):
+            return nxt
         toks = prev.out["tokens"].reshape(-1)
         if scr.pad_to > scr.b:
             toks = toks[scr.gather_idx]
@@ -1154,7 +1271,8 @@ class ContinuousBatchingAdapter(_EngineAdapterBase):
 
     engine_name = "cb"
 
-    def __init__(self, app, telemetry=None, pipeline_depth: int = 0):
+    def __init__(self, app, telemetry=None,
+                 pipeline_depth: Optional[int] = None):
         cfg = app.tpu_config
         if not cfg.is_continuous_batching:
             raise ConfigurationError("app must be built with "
@@ -1243,12 +1361,11 @@ class ContinuousBatchingAdapter(_EngineAdapterBase):
         return res
 
     def release(self, seq_ids: Sequence[int]):
-        if self._inflight is not None:
-            self._stash_flush()
         for sid in seq_ids:
             self._ready.pop(sid, None)
             if self.seqs.pop(sid, None) is not None:
                 bisect.insort(self._free, sid)
+        self._note_stale("release")
         self.telemetry.on_release(seq_ids)
 
     # -- decode dispatch ---------------------------------------------------
@@ -1367,7 +1484,7 @@ class PagedEngineAdapter(_EngineAdapterBase):
 
     def __init__(self, app, telemetry=None,
                  preemption_policy: Optional[str] = "lifo",
-                 pipeline_depth: int = 0,
+                 pipeline_depth: Optional[int] = None,
                  prefill_chunk_tokens: Optional[int] = None,
                  prefill_budget_tokens: Optional[int] = None,
                  speculation=None, kv_spill_tier=None,
@@ -1405,6 +1522,9 @@ class PagedEngineAdapter(_EngineAdapterBase):
         self._chunks: Dict[int, _ChunkState] = {}   # pending admissions
         self._unwritten: set = set()   # allocated blocks not fully written
         self._init_decode_path(pipeline_depth)
+        sc = cfg.on_device_sampling_config
+        self._pads_follow_row0 = (sc is None or not sc.do_sample
+                                  or sc.stream_seed is not None)
         # recurrent/hybrid stack (recognised from the spec, no knob): every
         # live sequence holds one STATE SLOT of the second cache beside the
         # KV pool (conv tails + the kind's state, ``app.state_slots`` = batch
@@ -1598,8 +1718,11 @@ class PagedEngineAdapter(_EngineAdapterBase):
         return {s: self._ready.pop(s) for s in seq_ids}
 
     def release(self, seq_ids: Sequence[int]):
-        if self._inflight is not None:
-            self._stash_flush()
+        """Free rows and their blocks (and state slots). Never blocks: a
+        decode step in flight for a released row stays in flight, its
+        token for the row is dropped where it is fetched (:meth:`_retire`
+        skips a state that is gone) and its KV growth goes with the row's
+        blocks."""
         proposer = self._active_proposer
         if proposer is not None:
             proposer.forget(seq_ids)
@@ -1617,6 +1740,7 @@ class PagedEngineAdapter(_EngineAdapterBase):
                 self._free_state_slot(sid)
                 if sid in self.app.kv_mgr.tables:
                     self.app.kv_mgr.end_sequence(sid)
+        self._note_stale("release")
         self.telemetry.on_release(seq_ids)
 
     # -- speculative decode (serving/speculation/) -------------------------
@@ -1650,6 +1774,14 @@ class PagedEngineAdapter(_EngineAdapterBase):
                 "token_room is a speculative-decode hook; build the "
                 "adapter with speculation= or ragged=True to use it")
         return super().step(seq_ids)
+
+    def step_ahead(self, seq_ids: Optional[Sequence[int]] = None):
+        """One step in flight on the plain two-phase decode path (base
+        class); the speculative and ragged paths materialize every step
+        and run as :meth:`step` does."""
+        if self._spec is not None or self._ragged is not None:
+            return self.step(seq_ids)
+        return super().step_ahead(seq_ids)
 
     def step_many(self, num_steps: int,
                   seq_ids: Optional[Sequence[int]] = None
@@ -2179,6 +2311,7 @@ class PagedEngineAdapter(_EngineAdapterBase):
             return
         st = self.seqs.pop(victim)
         self._scratch = None               # victim's blocks are reclaimed
+        self._note_stale("preempt")
         # recompute preemption re-prefills from position 0, which resets
         # whatever slot the requeue is given
         self._free_state_slot(victim, event="preempt")
@@ -2430,6 +2563,7 @@ class PagedEngineAdapter(_EngineAdapterBase):
                 prompt_len=len(st.prompt), admit_idx=st.admit_idx,
                 deadline=st.deadline, meta=st.meta)
             self._scratch = None   # live set grew; see add_requests note
+            self._note_stale("admit")
             self._ready[s] = tok
             if not defer_telemetry:
                 self.telemetry.on_add([s], [st.prompt], st.t0, live=1,

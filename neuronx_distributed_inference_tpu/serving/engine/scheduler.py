@@ -595,6 +595,18 @@ class ServingEngine:
         spec = getattr(self.adapter, "_spec", None)
         if spec is None:
             spec = getattr(self.adapter, "_ragged", None)
+        # the plain two-phase decode path, one step a pass: the engine can
+        # take a step's tokens one pass late, so the adapter keeps one step
+        # in flight (``step_ahead``: this pass enqueues step N+1 before it
+        # blocks on step N) and scheduling, routing and the yield to the
+        # stream writers run while the device computes. A token in flight
+        # counts as unread: the backpressure bound holds as it does eager
+        step = self.adapter.step
+        ahead = ()
+        if spec is None and self.decode_steps_per_pass == 1:
+            step = getattr(self.adapter, "step_ahead", step)
+            if self.max_unread_tokens is not None:
+                ahead = getattr(self.adapter, "lookahead_ids", ahead)
         room: Dict[int, int] = {}
         for sid, req in self._active.items():
             if sid not in alive and sid not in pending:
@@ -603,7 +615,8 @@ class ServingEngine:
                 eligible.append(sid)   # wants prefill progress, no decode
                 continue
             if (self.max_unread_tokens is not None
-                    and req.stream.unread >= self.max_unread_tokens):
+                    and req.stream.unread + (sid in ahead)
+                    >= self.max_unread_tokens):
                 continue               # backpressure: consumer is behind
             r = self._room(sid, req)
             if spec is not None:
@@ -613,19 +626,7 @@ class ServingEngine:
                 horizon = min(horizon, r)
             eligible.append(sid)
         if not eligible:
-            try:
-                drained = self.adapter.flush()   # pipelined leftovers
-            except StepFailure as e:
-                # the deferred fetch of an earlier dispatch can fail here
-                # too — same contract as the dispatch below, so the
-                # run_forever invariant ("a StepFailure raise site ran
-                # _fatal first when unrecoverable") holds on this path
-                if e.retry_safe:
-                    self.stats["step_retries"] += 1
-                    return 0
-                self._fatal(e)
-                raise
-            return self._route(drained if isinstance(drained, dict) else {})
+            return self._route_flushed()         # pipelined leftovers
         try:
             if spec is not None:
                 res = self.adapter.step(eligible, token_room=room)
@@ -633,17 +634,21 @@ class ServingEngine:
                 res = self.adapter.step_many(horizon, eligible)
             else:
                 res = {s: [t] for s, t in
-                       self.adapter.step(eligible).items()}
+                       step(eligible).items()}
         except DeadlineExceeded as e:
             self._expire_running(e.seq_ids)
             return 0
         except CapacityError as e:
+            n = 0
             if e.seq_ids:
+                # a row at the compiled seq_len may still have its last
+                # token in flight: it is delivered before the row ends
+                n = self._route_flushed()
                 self._finish_capacity(e.seq_ids)
             else:
                 self.stats["capacity_stalls"] += 1
             self._note_headroom("step")
-            return 0
+            return n
         except StepFailure as e:
             if e.retry_safe:
                 self.stats["step_retries"] += 1
@@ -653,6 +658,22 @@ class ServingEngine:
         return self._route(res)
 
     # -- token routing -----------------------------------------------------
+    def _route_flushed(self) -> int:
+        """Fetch and route what the adapter still holds: the in-flight
+        step's tokens and any drained before. The deferred fetch of an
+        earlier dispatch can fail here too — same contract as the dispatch
+        stage, so the run_forever invariant ("a StepFailure raise site ran
+        _fatal first when unrecoverable") holds on this path."""
+        try:
+            drained = self.adapter.flush()
+        except StepFailure as e:
+            if e.retry_safe:
+                self.stats["step_retries"] += 1
+                return 0
+            self._fatal(e)
+            raise
+        return self._route(drained if isinstance(drained, dict) else {})
+
     def _route(self, res) -> int:
         n = 0
         for sid, toks in res.items():

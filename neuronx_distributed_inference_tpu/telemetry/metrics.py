@@ -32,6 +32,8 @@ QUEUE_WAIT_SECONDS = "nxdi_queue_wait_seconds"          # tenant, outcome
 DISPATCH_DEPTH = "nxdi_dispatch_depth"                  # engine
 HOST_OVERLAP_SECONDS = "nxdi_host_overlap_seconds"      # engine
 STEPS_PER_FETCH = "nxdi_steps_per_fetch"                # engine
+OVERLAPPED_DISPATCHES_TOTAL = "nxdi_overlapped_dispatches_total"   # engine
+PIPELINE_DRAINS_TOTAL = "nxdi_pipeline_drains_total"    # engine, cause
 
 # -- serving resilience (serving.py + resilience/) --------------------------
 PREEMPTIONS_TOTAL = "nxdi_preemptions_total"            # engine, reason, tenant
@@ -225,6 +227,22 @@ def host_overlap_histogram(reg):
         "deferred token fetch — bookkeeping overlapped with device "
         "compute (s)",
         labels=("engine",), buckets=DEFAULT_LATENCY_BUCKETS)
+
+
+def overlapped_dispatches_counter(reg):
+    return reg.counter(
+        OVERLAPPED_DISPATCHES_TOTAL,
+        "Decode dispatches enqueued while the previous step's tokens were "
+        "still unfetched (the host's pass ran under the device's step)",
+        labels=("engine",))
+
+
+def pipeline_drains_counter(reg):
+    return reg.counter(
+        PIPELINE_DRAINS_TOTAL,
+        "In-flight decode steps fetched synchronously because the live "
+        "set changed under them; cause=admit|release|preempt|liveset",
+        labels=("engine", "cause"))
 
 
 def steps_per_fetch_histogram(reg):

@@ -246,17 +246,20 @@ def test_served_through_the_front_door(app, ref, gate_weights):
             await fe.stop()
 
     streams = dict(zip(prompts, asyncio.run(main())))
-    by_first_token = {tuple(sorted(got)): sid
-                      for sid, got in tap.by_seq.items()}
     assert len(tap.by_seq) == 2
     for name, prompt in prompts.items():
         stream = streams[name]
         fed = prompt + stream[:-1]
         want = _want(ref, gate_weights, fed)
         assert stream == want[len(prompt) - 1:].argmax(-1).tolist()
-        sid = by_first_token[tuple(range(len(fed)))]
-        np.testing.assert_allclose(tap.logits(sid, len(fed)), want,
-                                   atol=ATOL)
+        # the engine keeps one decode step in flight: the step enqueued
+        # behind a request's last token computed one position more, and
+        # its token was dropped
+        sid, = (s for s, got in tap.by_seq.items()
+                if sorted(got) in (list(range(len(fed))),
+                                   list(range(len(fed) + 1))))
+        got = np.stack([tap.by_seq[sid][p] for p in range(len(fed))])
+        np.testing.assert_allclose(got, want, atol=ATOL)
     assert not app.kv_mgr.tables
 
 

@@ -467,7 +467,7 @@ def test_run_forever_unexpected_exception_postmortem(apps, tmp_path):
     def boom(*a, **k):
         raise KeyError("engine bug")
 
-    adapter.step = boom
+    adapter.step = adapter.step_ahead = boom    # the engine's decode call
 
     async def main():
         with pytest.raises(StepFailure) as ei:
@@ -500,7 +500,7 @@ def test_run_forever_unexpected_exception_postmortem(apps, tmp_path):
     def typed_boom(*a, **k):
         raise SequenceStateError("engine bug")
 
-    adapter2.step = typed_boom
+    adapter2.step = adapter2.step_ahead = typed_boom
 
     async def main2():
         with pytest.raises(StepFailure) as ei:
@@ -527,8 +527,9 @@ def test_flush_path_step_failure_is_fatal_typed(apps):
                         max_unread_tokens=2)
     s = eng.submit(_prompts(99, 1)[0], 8)
     eng.run_pass()                 # admit (token 1) + dispatch in flight
-    eng.run_pass()                 # token 2 delivered, next in flight
-    assert s.unread >= 2           # consumer behind: row now ineligible
+    # token 2 is in flight and counts as unread: the consumer is behind,
+    # the row is ineligible, and the pass takes the flush() branch
+    assert s.unread == 1 and adapter.lookahead_ids == {0}
     assert adapter._inflight is not None
     with FAULTS.inject("pipeline_flush") as fp:
         with pytest.raises(StepFailure) as ei:
