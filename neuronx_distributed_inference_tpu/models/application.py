@@ -1139,16 +1139,17 @@ class PagedCausalLMApplication(CausalLMApplication):
 
     def init_cache(self):
         from ..modules.block_kv_cache import (BlockKVCacheManager,
-                                              BlockKVSpec, pool_kv_heads)
+                                              BlockKVSpec, pool_page)
         cfg = self.tpu_config
+        slots, lanes = pool_page(self.spec.gqa.num_kv_heads,
+                                 self.spec.head_dim, self.spec.gqa.tp)
         bspec = BlockKVSpec(
             # SSM-only layers carry no KV pages (recurrent/hybrid stacks)
             num_layers=self.spec.num_attn_layers,
             num_blocks=cfg.pa_num_blocks + 1,    # +1: reserved null block 0
             block_size=cfg.pa_block_size,
-            num_kv_heads=pool_kv_heads(self.spec.gqa.num_kv_heads,
-                                       self.spec.gqa.tp),
-            head_dim=self.spec.head_dim,
+            num_kv_heads=slots,
+            head_dim=lanes,
             dtype=self.spec.kv_dtype,
         )
         self.kv_mgr = BlockKVCacheManager(
@@ -1195,7 +1196,8 @@ class PagedCausalLMApplication(CausalLMApplication):
     def prefill_row_buckets(self) -> List[int]:
         """Row ladder of a two-phase prefill-chunk dispatch, two rungs
         ``[r_min, batch_size]``: a chunk carrying ``r_min`` prompts or
-        fewer runs ``r_min`` rows, anything more the full batch. ``r_min``
+        fewer runs ``r_min`` rows, anything more the full batch (the
+        adapter packs only what fills at least half of it). ``r_min``
         is 1, or the extent of the mesh axis the batch rows shard over
         ("dp"), the smallest row count that axis divides. Prefill chunks
         ONLY: decode, spec-verify and ragged dispatches pad to

@@ -122,6 +122,10 @@ class DecoderSpec:
     qk_norm: bool = False     # qwen3-style per-head q/k RMSNorm
     # olmo2-style FULL-width q/k RMSNorm (over nq*D / nkv*D, pre head-split)
     qk_norm_full: bool = False
+    # per-head sigmoid gate on the attention OUTPUT, before o_proj, out of a
+    # doubled q projection (HF Qwen3NextAttention: q_proj yields [query |
+    # gate] a head); the fused qkv_proj then holds [q | k | v | gate]
+    attn_out_gate: bool = False
     # "pre" (llama default) or "post" (olmo2: norms on the block OUTPUTS via
     # the sandwich weights, no pre-norms)
     norm_position: str = "pre"
@@ -392,8 +396,9 @@ def _attn_param_specs(spec: DecoderSpec, L: int) -> Dict[str, ParamSpec]:
         # boundaries; measured on v5e). The reference fuses the same way
         # (fused_qkv, modules/attention/gqa.py GroupQueryAttention_QKV).
         layers.update({
-            "qkv_proj": column_parallel(H, spec.q_size + 2 * spec.kv_size,
-                                        dt, True, L),
+            "qkv_proj": column_parallel(
+                H, spec.q_size * (2 if spec.attn_out_gate else 1)
+                + 2 * spec.kv_size, dt, True, L),
             "o_proj": row_parallel(spec.q_size, H, dt, True, L),
         })
         if spec.qkv_bias:
@@ -480,15 +485,18 @@ def _dense_mlp_param_specs(spec: DecoderSpec, L: int) -> Dict[str, ParamSpec]:
 def _moe_param_specs(spec: DecoderSpec, L: int) -> Dict[str, ParamSpec]:
     m = spec.moe
     H, dt = spec.hidden_size, spec.dtype
-    E, Ie = m.num_experts, m.intermediate_size
+    # the router scores every expert; the weights hold all of them or a
+    # share (MoESpec.held_experts)
+    E, Ie = m.num_held, m.intermediate_size
     layers: Dict[str, ParamSpec] = {
-        "router": ParamSpec((L, H, E), P(), jnp.float32),
+        "router": ParamSpec((L, H, m.num_experts), P(), jnp.float32),
         "expert_gate": expert_column_parallel(E, H, Ie, dt, True, L),
         "expert_up": expert_column_parallel(E, H, Ie, dt, True, L),
         "expert_down": expert_row_parallel(E, Ie, H, dt, True, L),
     }
     if m.has_router_bias:
-        layers["router_bias"] = ParamSpec((L, E), P(), jnp.float32, "zeros")
+        layers["router_bias"] = ParamSpec((L, m.num_experts), P(),
+                                          jnp.float32, "zeros")
     if m.expert_bias:
         layers["expert_gate_bias"] = ParamSpec(
             (L, E, Ie), P(None, AXIS_EP, AXIS_TP), dt, "zeros")
@@ -503,6 +511,8 @@ def _moe_param_specs(spec: DecoderSpec, L: int) -> Dict[str, ParamSpec]:
             "shared_up": column_parallel(H, Is, dt, True, L),
             "shared_down": row_parallel(Is, H, dt, True, L),
         })
+        if m.shared_gated:
+            layers["shared_gate_w"] = ParamSpec((L, H), P(), dt)
     return layers
 
 
@@ -562,7 +572,8 @@ def decoder_param_specs(spec: DecoderSpec) -> Dict[str, Any]:
         # output norms only
         walked = norm_keys[4:] if spec.norm_position == "post" else norm_keys
         shared = {k: v for k, v in full.items() if k in walked}
-        shared.update(_dense_mlp_param_specs(spec, L))
+        shared.update(_dense_mlp_param_specs(spec, L) if spec.moe is None
+                      else _moe_param_specs(spec, L))
         out["layers"] = shared
         if spec.num_attn_layers:
             attn_full = _attn_param_specs(spec, spec.num_attn_layers)
@@ -902,13 +913,14 @@ def _row_parallel_out(spec: DecoderSpec, x, w, phase: str):
 
 
 def _mlp_block(spec: DecoderSpec, x_in, layer_w, mlp_kind, adapter_ids,
-               phase: str = "prefill"):
+               phase: str = "prefill", tally=None, live=None):
     """The MLP / MoE half of a layer (GLU, plain 2-layer, or routed MoE),
     under the profiler scope ``moe`` (router, expert matmuls, combine) or
-    ``mlp``."""
+    ``mlp``. ``tally`` / ``live``: ``moe_block``'s."""
     if mlp_kind == "moe":
         with jax.named_scope("moe"):
-            return moe_block(spec.moe, x_in, layer_w, phase=phase)
+            return moe_block(spec.moe, x_in, layer_w, phase=phase,
+                             tally=tally, live=live)
     with jax.named_scope("mlp"):
         return _dense_mlp(spec, x_in, layer_w, adapter_ids, phase)
 
@@ -1034,8 +1046,10 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
         qkv = qlinear(h, layer_w["qkv_proj"])
         if spec.qkv_bias:
             qkv = qkv + layer_w["qkv_bias"]
-        q, k, v = jnp.split(qkv, [spec.q_size, spec.q_size + spec.kv_size],
-                            axis=-1)
+        cuts = [spec.q_size, spec.q_size + spec.kv_size]
+        if spec.attn_out_gate:
+            cuts.append(cuts[-1] + spec.kv_size)      # [q | k | v | gate]
+        q, k, v, *out_gate = jnp.split(qkv, cuts, axis=-1)
         q = apply_lora(spec.lora, layer_w, "q_proj", h, q, adapter_ids)
         k = apply_lora(spec.lora, layer_w, "k_proj", h, k, adapter_ids)
         v = apply_lora(spec.lora, layer_w, "v_proj", h, v, adapter_ids)
@@ -1153,10 +1167,14 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                                  block_table.shape[1]).note())
         if not use_pkernel:
             def gathered_mha(q_, bt_, mask_):
-                k_all = kv.dequantize_kv(bkv.gather_layer_kv(k_full, li, bt_),
-                                         dtype, spec.kv_scale)
-                v_all = kv.dequantize_kv(bkv.gather_layer_kv(v_full, li, bt_),
-                                         dtype, spec.kv_scale)
+                def gathered(pool):
+                    # a pool with several heads to a slot (bkv.pool_page):
+                    # the lanes of the GATHERED rows split into heads
+                    rows = bkv.gather_layer_kv(pool, li, bt_)
+                    return kv.dequantize_kv(
+                        rows.reshape(rows.shape[:2] + (-1, spec.head_dim)),
+                        dtype, spec.kv_scale)
+                k_all, v_all = gathered(k_full), gathered(v_full)
                 return attn_ops.mha(q_, k_all, v_all, mask_, spec.scale,
                                     logits_soft_cap=spec.attn_soft_cap,
                                     sink=sink,
@@ -1359,6 +1377,9 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                                                v_all.shape[2]))
 
     attn_out = attn_out.reshape(h.shape[0], h.shape[1], -1)
+    if spec.attn_out_gate and spec.mla is None:
+        attn_out = (attn_out * jax.nn.sigmoid(
+            out_gate[0].astype(jnp.float32))).astype(attn_out.dtype)
     h = _row_parallel_out(spec, attn_out, layer_w["o_proj"], phase)
     if spec.mla is None:
         h = apply_lora(spec.lora, layer_w, "o_proj", attn_out, h, adapter_ids)
@@ -1446,7 +1467,7 @@ def _paged_kernel_declined(spec: DecoderSpec) -> str:
     decode kernel ("" where it does, the mesh permitting)."""
     return ("alibi" if spec.alibi
             else "decode_kernel=False" if spec.decode_kernel is False
-            else "" if decode_attention.supports(spec, 1)
+            else "" if decode_attention.supports(spec, 1, paged=True)
             else "unsupported geometry (mla / head_dim / attn_chunk)")
 
 
@@ -1460,6 +1481,7 @@ def _paged_pool_fold(spec: DecoderSpec, cache, hidden, phase: str,
     the whole pool once a LAYER, here it is once a step - the copies a step
     of such a model pays already (ROADMAP A5)."""
     if (phase != "paged" or hidden.shape[1] != 1
+            or spec.head_dim >= 128      # whole vregs: stored folded or not
             or cache["k"].shape[4] != spec.head_dim      # folded already
             or _paged_kernel_declined(spec)):
         return 1
@@ -1815,12 +1837,27 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
         return res + (branch if rm == 1.0 else rm * branch)
 
     not_local = jnp.asarray(False)
+    # the MLP kind is the spec's: dense, or the routed block. Its expert
+    # leaves stay in their stack where the grouped matmuls read them in
+    # place (run_layer_slice has the reason); the dense path's static slice
+    # fuses into its einsum
+    mlp_kind = "dense" if spec.moe is None else "moe"
+    in_place = (moe_mod.stack_leaves(
+        spec.moe, hidden.shape[0] * hidden.shape[1], params["layers"])
+        if spec.moe is not None else ())
+    # a decode step over a share of the expert layers counts its routing
+    tally = [] if (paged and hidden.shape[1] == 1 and spec.moe is not None
+                   and spec.moe.holds_share) else None
     attn_i = 0
     ssm_i = 0
     for i in range(spec.num_layers):
         has_ssm = bool(pat[i])
         has_attn = spec.ssm_parallel or not has_ssm
-        lw = jax.tree.map(lambda a: a[i], params["layers"])
+        lw = jax.tree.map(lambda a: a[i],
+                          {k: a for k, a in params["layers"].items()
+                           if k not in in_place})
+        lw.update({k: moe_mod.LayerOfStack(params["layers"][k], i)
+                   for k in in_place})
         if has_attn and "attn_layers" in params:
             ja = attn_i
             lw = {**lw, **jax.tree.map(lambda a: a[ja], params["attn_layers"])}
@@ -1863,12 +1900,16 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
         h2 = hidden if post_norm else _norm(
             spec, hidden, lw["post_norm"],
             lw.get("post_norm_b") if spec.norm_bias else None)
-        m_out = _mlp_block(spec, h2, lw, "dense", adapter_ids, phase=phase)
+        m_out = _mlp_block(spec, h2, lw, mlp_kind, adapter_ids, phase=phase,
+                           tally=tally, live=valid)
         if post_norm:
             m_out = rms_norm(m_out, lw["post_ff_norm"], spec.rms_eps,
                              spec.norm_offset)
         hidden = add(hidden, m_out)
-    return hidden, {"k": kf, "v": vf, **new_state}, {}
+    # a share's exact counts over this walk's expert layers, [touched,
+    # assigned] (moe.share_tally), for the step to hand out with its tokens
+    side = {"moe_tally": sum(tally)} if tally else {}
+    return hidden, {"k": kf, "v": vf, **new_state}, side
 
 
 def run_layers_mixed_decode(spec: DecoderSpec, params, cache, hidden, ai,
@@ -2211,7 +2252,7 @@ def paged_forward_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     ai = attn_inputs(spec, position_ids, lambda w, c=0: attn_ops.decode_mask(
         position_ids, kv_len, window=w, chunk=c))
     hidden = _embed(spec, params, input_ids, position_ids)
-    hidden, new_cache, _ = run_layers(
+    hidden, new_cache, side = run_layers(
         spec, params, cache, hidden, ai, None, position_ids,
         "paged", slot_mapping=slot_mapping, block_table=block_table,
         adapter_ids=adapter_ids, state_slots=state_slots)
@@ -2219,6 +2260,10 @@ def paged_forward_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     last_h = jnp.take_along_axis(hidden, idx, axis=1)
     logits = _lm_head(spec, params, last_h)[:, 0, :]
     out = {"cache": new_cache}
+    if "moe_tally" in side:
+        # a decode step over a share of an expert layer counts what its
+        # routing touched; the adapter fetches it with the tokens
+        out["moe_tally"] = side["moe_tally"]
     if tpu_cfg.output_logits:
         out["logits"] = _lm_head(spec, params, hidden)[..., :spec.vocab_size]
     if _coupled_mode(tpu_cfg, row_seeds):

@@ -84,18 +84,18 @@ class OlmoHybridFamily(DecoderFamily):
                 "olmo_hybrid with attention_bias: the published value is "
                 "false and the loader reads no bias")
         heads = int(config.linear_num_value_heads)
-        if int(config.linear_num_key_heads) != heads:
-            raise NotImplementedError(
-                "olmo_hybrid with linear_num_key_heads != "
-                "linear_num_value_heads (keys shared by groups of value "
-                "heads) has not been walked")
+        key_heads = int(config.linear_num_key_heads)
+        if heads % key_heads:
+            raise ValueError(
+                f"linear_num_value_heads {heads} is not a multiple of "
+                f"linear_num_key_heads {key_heads}")
         d_k, d_v = (int(config.linear_key_head_dim),
                     int(config.linear_value_head_dim))
         return spec_from_config(
             config, tp_degree,
             ssm=SSMSpec(
                 kind="gated_delta", d_inner=heads * d_v, num_heads=heads,
-                head_dim=d_v, d_state=d_k,
+                num_key_heads=key_heads, head_dim=d_v, d_state=d_k,
                 d_conv=int(config.linear_conv_kernel_dim),
                 chunk_size=SCAN_CHUNK, conv_bias=False,
                 # y = w * rmsnorm(o) * silu(g): the norm first, then the gate

@@ -77,10 +77,39 @@ def pool_kv_heads(num_kv_heads: int, tp: int = 1) -> int:
     fit the chip). The pool then rounds its heads up to whole tiles
     (30 -> 32; the padded heads hold zeros and the attention block pads its
     q, k, v to match): every reshape of the pool is a bitcast again, as for
-    16 heads. One chip only: a shard's heads are not padded."""
+    16 heads. One chip only: a shard's heads are not padded. Up to a tile's
+    worth of heads keep their count here; of those, 2 to 7 heads of whole
+    vregs are stored in ONE slot (:func:`pool_page`, what the application
+    allocates by)."""
     if tp != 1 or num_kv_heads <= POOL_HEAD_TILE:
         return num_kv_heads
     return -(-num_kv_heads // POOL_HEAD_TILE) * POOL_HEAD_TILE
+
+
+def pool_page(num_kv_heads: int, head_dim: int, tp: int = 1
+              ) -> Tuple[int, int]:
+    """``(head slots, lanes of a slot)`` of a page of the pool: what the
+    application allocates, ``(block_size, slots, lanes)`` a page.
+    :func:`pool_kv_heads` slots of ``head_dim`` lanes - except that a FEW
+    heads (2 to 7) of whole vregs (a multiple of 128 lanes) are stored side
+    by side in ONE slot of ``heads x head_dim`` lanes, one chip only. With 2
+    heads of 256 to a page (Qwen3-Next) the device tiles the page ``(2,
+    128)``, and the decode kernel, which reads a page as a ``(tokens x
+    heads, lanes)`` matrix in whole ``(8, 128)`` tiles, was handed a
+    relayout of the whole pool on every call: six ``reshape`` copies of 403
+    MB, 10.4 of a decode step's 28.9 ms, and the chunk programs' gathers
+    six more (my chip run and AOT, PR 36). A token's heads in one row are
+    the same bytes, ``(tokens, heads x lanes)`` is what both consumers
+    tile alike, the slot write is unchanged (a token's heads are
+    contiguous either way), and the kernel scores heads that share a row
+    as it does two heads of 64 (``decode_attention.paged_pool_fold``)."""
+    from ..ops.decode_attention import paged_pool_fold
+    heads = pool_kv_heads(num_kv_heads, tp)
+    # stored as the kernel reads it; heads narrower than a vreg keep their
+    # slots (the layer walk folds them for the decode step alone)
+    fold = paged_pool_fold(heads, head_dim) \
+        if tp == 1 and head_dim >= 128 else 1
+    return heads // fold, fold * head_dim
 
 
 def block_cache_pspec() -> P:
@@ -147,6 +176,8 @@ def gather_layer_kv(cache: jnp.ndarray, layer, block_table: jnp.ndarray
     layer out first (a dynamic slice in front of the gather) is a copy of
     the layer's whole pool on every layer of every dispatch. The paged
     layout keeps heads minor: the gather is row-indexed, not head-sliced.
+    A pool stored with several heads to a slot (:func:`pool_page`) comes
+    back that way; the caller splits the lanes of the GATHERED rows.
     """
     L, n, bs, h, d = cache.shape
     flat = cache.reshape(L * n, bs, h, d)

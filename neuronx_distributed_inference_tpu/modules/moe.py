@@ -23,7 +23,14 @@ Design:
     custom call whose operand must be a whole buffer, so a slice cut in
     front of it is a copy of the layer's experts on every call.
   * Shared experts (reference: SharedExperts in moe_v2.py:104) are a plain
-    dense MLP added to the routed output.
+    dense MLP added to the routed output, behind a per-token sigmoid gate
+    where the spec says so (``shared_gated``: Qwen2-MoE / Qwen3-Next).
+  * A SHARE of a layer (``held_experts``): the router scores all
+    ``num_experts`` with the published top-k and renormalisation, the
+    weights hold the ``held_experts`` experts from ``first_expert`` on, and
+    the block returns their part of the sum only (one chip's part of an
+    expert-parallel layer, run without its exchange: what the other chips
+    hold is nobody's here). The shared expert is whole on every share.
 
 All routing math in fp32 (router logits decide tokens; bf16 tie-breaks
 diverge from HF goldens).
@@ -96,6 +103,23 @@ class MoESpec:
     # the fused decode scan (the GSPMD analog of the reference's
     # relayout-once-at-load into the TKG process group)
     tkg_experts_local: bool = False
+    # one chip's share of an expert-parallel layer: the weights hold
+    # ``held_experts`` experts (0 = all of them), ``first_expert`` the
+    # first; the router's width stays ``num_experts``
+    held_experts: int = 0
+    first_expert: int = 0
+    # the shared expert's output scaled by sigmoid(x . shared_gate_w) per
+    # token (HF Qwen2MoeSparseMoeBlock / Qwen3NextSparseMoeBlock)
+    shared_gated: bool = False
+
+    @property
+    def num_held(self) -> int:
+        """Experts the weights hold: all, or the share."""
+        return self.held_experts or self.num_experts
+
+    @property
+    def holds_share(self) -> bool:
+        return 0 < self.held_experts < self.num_experts
 
 
 # the per-expert leaves of a layer: what the ragged path reads in place
@@ -245,6 +269,33 @@ def combine_matrix(num_experts: int, top_vals: jnp.ndarray,
         top_idx].add(top_vals)
 
 
+def held_combine(moe: MoESpec, top_vals: jnp.ndarray,
+                 top_idx: jnp.ndarray) -> jnp.ndarray:
+    """The combine matrix over the experts the weights hold, (B,T,held):
+    the columns of a share out of the matrix over all the router scored."""
+    combine = combine_matrix(moe.num_experts, top_vals, top_idx)
+    if moe.holds_share:
+        combine = combine[..., moe.first_expert:
+                          moe.first_expert + moe.held_experts]
+    return combine
+
+
+def share_tally(moe: MoESpec, top_idx: jnp.ndarray,
+                live: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Exact counts of one routing over a share, int32 ``[touched,
+    assigned]``: held experts that received at least one token, and the
+    assignments that fell to held experts. ``live`` (B,T) bool leaves the
+    rows of a step that carry no sequence out."""
+    local = top_idx - moe.first_expert
+    mine = (local >= 0) & (local < moe.held_experts)
+    if live is not None:
+        mine = mine & live[..., None]
+    hits = jnp.zeros((moe.held_experts,), jnp.int32).at[
+        jnp.where(mine, local, moe.held_experts).reshape(-1)].add(
+            1, mode="drop")
+    return jnp.stack([jnp.sum(hits > 0), jnp.sum(hits)]).astype(jnp.int32)
+
+
 def _glu(moe: MoESpec, gate: jnp.ndarray, up: jnp.ndarray) -> jnp.ndarray:
     if moe.glu_style == "oss_clamp":
         gate = jnp.minimum(gate, moe.glu_limit)
@@ -267,7 +318,7 @@ def experts_dense(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
     straight back to expert-parallel (the involuntary-full-remat warning
     MULTICHIP r05 flagged)."""
     dt = x.dtype
-    combine = combine_matrix(moe.num_experts, top_vals, top_idx)  # (B,T,E)
+    combine = held_combine(moe, top_vals, top_idx)               # (B,T,E)
     if moe.input_scaled:
         # llama4: scale the expert INPUT by the affinity, combine with 1s
         xe = (x[:, :, None, :].astype(jnp.float32)
@@ -320,22 +371,35 @@ def experts_ragged(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
     wg, wu, wd = (dequantize(w, dt) if is_quantized_leaf(w) else w
                   for w in (wg, wu, wd))
 
+    n_e = moe.num_held
     flat_x = x.reshape(b * t, h)
     flat_expert = top_idx.reshape(-1)                       # (N,) expert ids
     flat_weight = top_vals.reshape(-1)                      # (N,) fp32
+    if moe.holds_share:
+        # an assignment to an expert another chip holds is dropped before
+        # the sort: it goes behind every group (id = held), owns no rows of
+        # the grouped matmuls and weighs nothing in the combine
+        flat_expert = flat_expert - moe.first_expert
+        absent = (flat_expert < 0) | (flat_expert >= n_e)
+        flat_expert = jnp.where(absent, n_e, flat_expert)
+        flat_weight = jnp.where(absent, 0.0, flat_weight)
 
     order = jnp.argsort(flat_expert)                        # stable
     inv = jnp.argsort(order)
     sorted_expert = flat_expert[order]
     sorted_tokens = flat_x[order // k]                      # (N, H)
-    group_sizes = jnp.bincount(flat_expert, length=moe.num_experts
-                               ).astype(jnp.int32)
+    group_sizes = jnp.bincount(flat_expert, length=n_e).astype(jnp.int32)
+    if moe.holds_share:
+        # rows past the last group are no group's: what the kernel leaves
+        # there is not read (bias lookups stay in range)
+        present = (sorted_expert < n_e)[:, None]
+        sorted_expert = jnp.minimum(sorted_expert, n_e - 1)
     if layer is not None:
-        groups = wg.shape[0] * moe.num_experts
+        groups = wg.shape[0] * n_e
         wg, wu, wd, bg, bu, bd = (
             None if a is None else a.reshape((groups,) + a.shape[2:])
             for a in (wg, wu, wd, bg, bu, bd))
-        first = jnp.asarray(layer, jnp.int32) * moe.num_experts
+        first = jnp.asarray(layer, jnp.int32) * n_e
         group_sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((groups,), jnp.int32), group_sizes, (first,))
         sorted_expert = sorted_expert + first
@@ -354,6 +418,8 @@ def experts_ragged(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
     outs = jax.lax.ragged_dot(inter, wd, group_sizes)       # (N, H)
     if bd is not None:
         outs = outs + bd[sorted_expert]
+    if moe.holds_share:
+        outs = jnp.where(present, outs, 0)
 
     outs = outs[inv].astype(jnp.float32) * flat_weight[:, None]
     y = outs.reshape(b * t, k, h).sum(axis=1).reshape(b, t, h)
@@ -361,10 +427,19 @@ def experts_ragged(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
 
 
 def moe_block(moe: MoESpec, x: jnp.ndarray, layer_w: Dict[str, Any],
-              phase: str = "prefill") -> jnp.ndarray:
-    """Full MoE block: route + experts (+ shared experts). x (B,T,H)."""
+              phase: str = "prefill", tally: Optional[list] = None,
+              live: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Full MoE block: route + experts (+ shared experts). x (B,T,H).
+    ``tally``: a list a layer walk hands in to collect, per layer of a
+    share, :func:`share_tally` of this routing over the ``live`` rows."""
     router_bias = layer_w.get("router_bias") if moe.has_router_bias else None
     top_vals, top_idx = route(moe, x, layer_w["router"], router_bias)
+    if moe.holds_share:
+        kernel_mode.note("moe_share", "xla",
+                         f"held={moe.held_experts} of {moe.num_experts} "
+                         f"from {moe.first_expert} top_k={moe.top_k}")
+        if tally is not None:
+            tally.append(share_tally(moe, top_idx, live))
     biases = ((layer_w["expert_gate_bias"], layer_w["expert_up_bias"],
                layer_w["expert_down_bias"]) if moe.expert_bias
               else (None, None, None))
@@ -427,5 +502,11 @@ def _shared_experts(moe: MoESpec, x: jnp.ndarray, y: jnp.ndarray,
         act = _act_fn(moe.act)
         s = act(qlinear(x, layer_w["shared_gate"])) * qlinear(x, layer_w["shared_up"])
         s = shard_constraint(s, AXIS_DP, None, AXIS_MP)
-        y = y + qlinear(s, layer_w["shared_down"])
+        s = qlinear(s, layer_w["shared_down"])
+        if moe.shared_gated:
+            gate = jnp.einsum("bth,h->bt", x, layer_w["shared_gate_w"],
+                              preferred_element_type=jnp.float32)
+            s = (s.astype(jnp.float32)
+                 * jax.nn.sigmoid(gate)[..., None]).astype(s.dtype)
+        y = y + s
     return y

@@ -24,7 +24,8 @@ channels/heads on the model axis):
   mamba2: conv_x (Ls,B,d_inner,K-1), conv_bc (Ls,B,2·g·N,K-1),
           ssm (Ls,B,nh,hd,N) fp32
   rglru:  conv_x (Ls,B,W,K-1), ssm (Ls,B,W) fp32
-  gated_delta: conv_x (Ls,B,2·nh·d_k + nh·d_v,K-1) over [q|k|v],
+  gated_delta: conv_x (Ls,B,2·nkh·d_k + nh·d_v,K-1) over [q|k|v] (nkh key
+          heads, each serving nh/nkh value heads),
           ssm (Ls,B,nh,d_k,d_v) fp32
 The conv tails hold the last K-1 *pre-conv* projected inputs, so a decode
 step is ``concat(tail, current) → depthwise dot`` exactly like the
@@ -75,15 +76,23 @@ class SSMSpec:
     # gated_delta: the write strength beta is 2 * sigmoid (in (0, 2): the
     # state transition may have negative eigenvalues) instead of sigmoid
     beta_scale: float = 1.0
+    # gated_delta: key heads, each serving ``num_heads / num_key_heads``
+    # neighbouring value heads (q and k are repeated over them, HF
+    # ``repeat_interleave``); 0 = as many as value heads
+    num_key_heads: int = 0
 
     @property
     def bc_size(self) -> int:
         return 2 * self.n_groups * self.d_state
 
     @property
+    def key_heads(self) -> int:
+        return self.num_key_heads or self.num_heads
+
+    @property
     def qk_size(self) -> int:
         """gated_delta: the projected width of q, and of k."""
-        return self.num_heads * self.d_state
+        return self.key_heads * self.d_state
 
     @property
     def qkv_size(self) -> int:
@@ -543,12 +552,15 @@ def gated_delta_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *,
     qkv_c = jax.nn.silu(_causal_conv_prefill(qkv, lw["gdn_conv"], None, tail))
     new_state = {"conv_x": _conv_tail(qkv, n_valid, K1, tail)}
 
-    def heads(a, width):
-        a = a.astype(f32).reshape(B, T, nh, width)
-        return a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True)
-                                 + 1e-6)
-    q = heads(qkv_c[..., :qk], dk) * dk ** -0.5
-    k = heads(qkv_c[..., qk:2 * qk], dk)
+    def heads(a):
+        # l2-normalised per KEY head, then each repeated over the value
+        # heads it serves (value head j reads key head j // group)
+        a = a.astype(f32).reshape(B, T, s.key_heads, dk)
+        a = a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+        return a if s.key_heads == nh else jnp.repeat(
+            a, nh // s.key_heads, axis=2)
+    q = heads(qkv_c[..., :qk]) * dk ** -0.5
+    k = heads(qkv_c[..., qk:2 * qk])
     v = qkv_c[..., 2 * qk:].astype(f32).reshape(B, T, nh, dv)
     g = -jnp.exp(lw["gdn_A_log"].astype(f32)) * jax.nn.softplus(
         ab[..., :nh] + lw["gdn_dt_bias"].astype(f32))

@@ -40,9 +40,10 @@ block table's width, and a dead table entry is never read. What lies where:
   slot is computed on. ``pages`` follows the page's bytes, a cap on a
   block's score tile and a cap on unrolled copies (``paged_block_plan``):
   nothing of it is configured.
-* MXU: a slot is a (tokens x kv heads, 128 lanes) matrix as it lies, and all
-  heads score in one block-diagonal matmul against it (heads of 64 lanes sit
-  two to a row: ``paged_pool_fold``). Operands are bf16 wherever that loses
+* MXU: a slot is a (tokens x kv heads, lanes of a head) matrix as it lies -
+  128 lanes, or 256 - and all heads score in one block-diagonal matmul
+  against it (heads of 64 lanes sit two to a 128-lane row:
+  ``paged_pool_fold``). Operands are bf16 wherever that loses
   nothing: q . k from bf16 operands accumulates in fp32; p stays fp32 - it
   goes in as three bf16 pieces in one pass over V (``_split_dot``).
 """
@@ -91,7 +92,7 @@ def _bf16_exact(dtype) -> bool:
 class PagedPlan(NamedTuple):
     """What one call of the paged kernel runs with (:func:`paged_block_plan`)."""
     pages: int          # pages a compute block gathers
-    fold: int           # kv heads sharing one 128-lane row of a page
+    fold: int           # kv heads sharing one row of a page
     hkv: int            # the kernel's kv rows a token: a shard's heads / fold
     g: int              # query rows a kv row: group size x fold
     d: int              # lanes of a kv row: head_dim x fold
@@ -102,12 +103,26 @@ class PagedPlan(NamedTuple):
                 f"form=mxu-blockdiag fold={self.fold}")
 
 
+#: fewer kv rows a token than this (the second-minor extent of a 32-bit
+#: tile) do not fill the rows of a tile
+PAGED_ROW_TILE = 8
+
+
 def paged_pool_fold(hkv: int, d: int) -> int:
-    """How many neighbouring kv heads (of a shard's ``hkv``) share one
-    128-lane row of a page in the kernel's view of the pool: heads narrower
-    than a vreg go in side by side, the page's bytes as they lie (a manual
-    copy cannot slice a 64-lane minor dimension out of a tiled array)."""
-    fold = 128 // d if d < 128 and 128 % d == 0 else 1
+    """How many neighbouring kv heads (of a shard's ``hkv``) share one row
+    of a page in the kernel's view of the pool. Heads narrower than a vreg
+    go in side by side, the page's bytes as they lie (a manual copy cannot
+    slice a 64-lane minor dimension out of a tiled array); a FEW heads (2
+    to 7) of whole vregs share one row too, ``hkv x d`` lanes: the pool of
+    such a model is stored so (``block_kv_cache.pool_page``: a page of 2
+    heads is tiled ``(2, 128)`` and the ``(tokens x heads, lanes)`` view
+    of it was a relayout of the whole pool a call)."""
+    if d < 128 and 128 % d == 0:
+        fold = 128 // d
+    elif d % 128 == 0 and 1 < hkv < PAGED_ROW_TILE:
+        fold = hkv
+    else:
+        fold = 1
     return fold if hkv % fold == 0 else 1
 
 
@@ -774,11 +789,14 @@ def paged_dispatch_plan(hq: int, d: int, k_pages: jnp.ndarray,
                             k_pages.dtype, mb)
 
 
-def supports(spec, phase_t: int) -> bool:
+def supports(spec, phase_t: int, paged: bool = False) -> bool:
     """Kernel admission (reference analog: TKG kernel enablement flags,
     models/config.py:417-567): single active token, no MLA (different head
     dims; the kernel streams K and V with one block shape), no chunked
     attention (the kernel masks by window, not chunk boundaries — llama4's
-    chunked local layers take the XLA path)."""
-    return (phase_t == 1 and spec.mla is None
-            and spec.head_dim in (64, 128) and spec.attn_chunk == 0)
+    chunked local layers take the XLA path). Heads of 64 lanes (on the paged
+    path two to a 128-lane row of a page, ``paged_pool_fold``) and of 128;
+    the PAGED kernel (``paged``) also heads of 256, a kv row of two vregs:
+    it takes the lanes of a row from the arrays it is handed."""
+    return (phase_t == 1 and spec.mla is None and spec.attn_chunk == 0
+            and spec.head_dim in ((64, 128, 256) if paged else (64, 128)))

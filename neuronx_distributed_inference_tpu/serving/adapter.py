@@ -90,7 +90,9 @@ Chunked, packed, schedulable prefill — paged adapter only (see README
     the application's ``prefill_row_buckets`` covering the prompts packed —
     two rungs, ``[r_min, batch_size]`` (``r_min`` is 1, or the "dp" mesh
     extent), so a chunk that carries one prompt runs one row, not
-    ``batch_size`` copies of it; the choice is counted as
+    ``batch_size`` copies of it; prompts that would fill less than half of
+    the full batch go ``r_min`` at a time rather than as one pack (the
+    pack computes every row of the batch); the choice is counted as
     ``nxdi_bucket_selected_total{kind="prefill_rows"}``. Decode,
     spec-verify and ragged dispatches keep the full batch.
   * ``prefill_budget_tokens`` defers prefill to the scheduler:
@@ -418,6 +420,14 @@ class _AdapterTelemetry:
         if reg.enabled:
             tmetrics.overlapped_dispatches_counter(reg).inc(
                 engine=self.engine)
+
+    def on_moe_tally(self, touched: int, slots: int, assigned: int):
+        reg = self.registry
+        if reg.enabled:
+            c = tmetrics.moe_experts_counter(reg)
+            for count, n in (("touched", touched), ("slots", slots),
+                             ("assigned", assigned)):
+                c.inc(n, engine=self.engine, count=count)
 
     def on_drain(self, cause: str):
         reg = self.registry
@@ -877,10 +887,29 @@ class _EngineAdapterBase:
         with _get_recorder().span("fetch.tokens", cat="adapter",
                                   engine=self.engine_name, rows=b):
             toks = np.asarray(out["tokens"])
+            tally = out.get("moe_tally")
+            if tally is not None:
+                # a decode step over a share of the expert layers: what its
+                # routing touched, counted on the device (two int32)
+                self._count_moe_tally(np.asarray(tally))
         self.host_stats["blocking_fetches"] += 1
         self.host_stats["blocked_s"] += time.perf_counter() - t0
         toks = toks.reshape(toks.shape[0], -1)
         return toks[:b] if rows is None else toks[rows]
+
+    def _count_moe_tally(self, tally: np.ndarray):
+        """``[touched, assigned]`` of one decode step (``moe.share_tally``
+        summed over the expert layers) into ``host_stats``; the slots the
+        touched experts are counted over are held experts x expert layers,
+        once a step."""
+        moe = self.app.spec.moe
+        slots = moe.held_experts * self.app.spec.num_layers
+        st = self.host_stats
+        for key, n in (("moe_experts_touched", int(tally[0])),
+                       ("moe_assignments_held", int(tally[1])),
+                       ("moe_expert_slots", slots)):
+            st[key] = st.get(key, 0) + n
+        self.telemetry.on_moe_tally(int(tally[0]), slots, int(tally[1]))
 
     # -- public decode surface ---------------------------------------------
     def step(self, seq_ids: Optional[Sequence[int]] = None) -> Dict[int, int]:
@@ -1942,6 +1971,8 @@ class PagedEngineAdapter(_EngineAdapterBase):
             out = self.app._run_paged(ids, scr.pos, scr.slots, scr.bt,
                                       scr.last, **kw)
         _async_fetch(out["tokens"])
+        if "moe_tally" in out:
+            _async_fetch(out["moe_tally"])
         self.host_stats["dispatches"] += 1
         self.host_stats["device_steps"] += 1
         rec = _get_recorder()
@@ -2487,6 +2518,14 @@ class PagedEngineAdapter(_EngineAdapterBase):
             left -= n
         if not rows:
             return
+        r_min = self.app.prefill_row_buckets[0]
+        if r_min < len(rows) and 2 * len(rows) < self.batch:
+            # a full-batch pack that would be less than half real rows goes
+            # r_min rows at a time instead, in admission order: the pack
+            # computes every row of the batch whatever it carries, and at
+            # two prompts of 32 rows it held every decoding row for a
+            # dozen one-row chunks' time (PERF.md section 6, PR 36)
+            rows = rows[:r_min]
         seq_list = tuple(s for s, *_ in rows)
         final_rows = [(i, s) for i, (s, _, _, fin) in enumerate(rows)
                       if fin]

@@ -33,6 +33,7 @@ DISPATCH_DEPTH = "nxdi_dispatch_depth"                  # engine
 HOST_OVERLAP_SECONDS = "nxdi_host_overlap_seconds"      # engine
 STEPS_PER_FETCH = "nxdi_steps_per_fetch"                # engine
 OVERLAPPED_DISPATCHES_TOTAL = "nxdi_overlapped_dispatches_total"   # engine
+MOE_EXPERTS_TOTAL = "nxdi_moe_experts_total"            # engine, count
 PIPELINE_DRAINS_TOTAL = "nxdi_pipeline_drains_total"    # engine, cause
 
 # -- serving resilience (serving.py + resilience/) --------------------------
@@ -235,6 +236,17 @@ def overlapped_dispatches_counter(reg):
         "Decode dispatches enqueued while the previous step's tokens were "
         "still unfetched (the host's pass ran under the device's step)",
         labels=("engine",))
+
+
+def moe_experts_counter(reg):
+    return reg.counter(
+        MOE_EXPERTS_TOTAL,
+        "Exact counts of the decode steps of a stack that holds a SHARE of "
+        "its expert layers, summed on the device from the router's top-k; "
+        "count=touched (held experts that received a token, summed over "
+        "layers and steps) | slots (held x expert layers x steps) | "
+        "assigned (assignments that fell to held experts)",
+        labels=("engine", "count"))
 
 
 def pipeline_drains_counter(reg):

@@ -24,7 +24,11 @@ not run).
     cut 32 -> 16 in whole periods, the dtypes and shapes ``assumed`` names,
     the file's memory arithmetic re-derived from ``ssm_state_shapes``, the
     pool and the parameter specs, and a served slot against the reference's
-    ``final_states``.
+    ``final_states``;
+  * the same for ``qwen3-next-80b-a3b`` (ISSUE 36): every published number
+    but depth, experts held and vocabulary, the share's keys, the memory
+    arithmetic re-derived, the reference gating a toy twin that holds a
+    share, and a served slot against ``final_states``.
 """
 
 import json
@@ -456,4 +460,211 @@ def test_a_served_olmo_slot_holds_the_references_state():
 def test_a_bf16_carried_olmo_state_fails_the_state_check(monkeypatch):
     import jax.numpy as jnp
     err = _olmo_served_state_error(jnp.bfloat16, monkeypatch)
+    assert err > 10 * STATE_RTOL, err
+
+
+# ---------------------------------------------------------------------------
+# qwen3-next-80b-a3b (ISSUE 36)
+# ---------------------------------------------------------------------------
+
+def test_qwen3_next_keeps_every_published_number():
+    """The catalog row's ``config`` (model-configs guide), as copied into
+    ISSUE 36: every key at the top level of the file, no width changed, and
+    ``reduced`` names the depth, the experts held and the vocabulary, and
+    nothing else."""
+    cfg = build.load_json("configs", "qwen3-next-80b-a3b.json")
+    published = dict(
+        decoder_sparse_step=1, full_attention_interval=4, head_dim=256,
+        hidden_act="silu", hidden_size=2048, intermediate_size=5120,
+        linear_conv_kernel_dim=4, linear_key_head_dim=128,
+        linear_num_key_heads=16, linear_num_value_heads=32,
+        linear_value_head_dim=128, max_position_embeddings=262144,
+        mlp_only_layers=[], model_type="qwen3_next",
+        moe_intermediate_size=512, norm_topk_prob=True,
+        num_attention_heads=16, num_experts=512, num_experts_per_tok=10,
+        num_hidden_layers=48, num_key_value_heads=2,
+        partial_rotary_factor=0.25, rms_norm_eps=1e-06, rope_scaling=None,
+        rope_theta=10000000, shared_expert_intermediate_size=512,
+        tie_word_embeddings=False, use_sliding_window=False,
+        vocab_size=151936)
+    assert set(published) <= set(cfg)
+    differs = sorted(k for k in published if cfg[k] != published[k])
+    assert differs == sorted(cfg["reduced"]) == [
+        "num_experts", "num_hidden_layers", "vocab_size"]
+    # the cut: whole periods, a quarter of the experts and of the vocabulary;
+    # the router's width is the published expert count
+    assert cfg["num_hidden_layers"] == len(cfg["layer_types"]) in (8, 12)
+    assert cfg["layer_types"] == OLMO_PERIOD * (cfg["num_hidden_layers"] // 4)
+    assert cfg["router_num_experts"] == published["num_experts"]
+    assert cfg["num_experts"] * 4 == cfg["router_num_experts"]
+    assert cfg["vocab_size"] * 4 == published["vocab_size"]
+    assert 0 <= cfg["first_expert"] <= 512 - cfg["num_experts"]
+    assert cfg["family"] == "qwen3_next" and cfg["chips"] == cfg["tp"] == 1
+    assert cfg["serve"]["is_prefix_caching"] is False
+    assert {"state_dtype", "conv_tail_dtype", "kv_dtype", "scan_chunk",
+            "mtp_left_out"} <= set(cfg["assumed"])
+    gate = cfg["gate"]
+    twin = build.hf_config(cfg, build.gate_overrides(gate))
+    assert twin["layer_types"] == OLMO_PERIOD
+    assert (twin["num_hidden_layers"], twin["hidden_size"],
+            twin["num_experts"], twin["router_num_experts"]) == \
+        (4, 2048, 128, 512)
+    assert gate["prompt_len"] + gate["new_tokens"] <= \
+        4 * cfg["serve"]["pa_block_size"]
+    # a position is excused by a routing near-tie only
+    assert 0 < gate["excuse_margin_max"] <= 0.02
+    assert gate["min_positions_held"] >= 0.9
+    # the pool cannot run dry: every row at its longest prompt and answer
+    mix = build.load_json("traffic", "rag-closed.json")
+    serve = cfg["serve"]
+    longest = mix["prompt_len"]["hi"] + mix["output_len"]["hi"]
+    assert longest == serve["seq_len"]
+    assert serve["pa_num_blocks"] * serve["pa_block_size"] == \
+        serve["batch_size"] * longest
+    # the mix is ISSUE 36's, letter for letter
+    assert (mix["loop"], mix["clients_per_batch_row"], mix["pool_requests"],
+            mix["lead_s"], mix["grace_s"], mix["base_seed"]) == \
+        ("closed", 2, 4096, 15.0, 8.0, 36)
+    assert mix["prompt_len"] == dict(kind="lognormal", median=768, sigma=0.7,
+                                     lo=192, hi=3072)
+    assert mix["output_len"] == dict(kind="lognormal", median=320, sigma=0.6,
+                                     lo=96, hi=1024)
+
+
+def test_qwen3_next_allocates_what_its_file_says():
+    """The dtypes and shapes ``assumed`` names and the memory arithmetic of
+    the file, against what the program would allocate: the full
+    configuration's state, pool and parameters as SHAPES (nothing of 12 GB
+    is allocated; the gate's twin alone is 3.3 GB and is not built here)."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    from neuronx_distributed_inference_tpu.models import model_base
+    from neuronx_distributed_inference_tpu.modules import ssm
+    from neuronx_distributed_inference_tpu.modules.block_kv_cache import (
+        BlockKVSpec, pool_page)
+    from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
+    cfg = build.load_json("configs", "qwen3-next-80b-a3b.json")
+    assumed, memory, serve = cfg["assumed"], cfg["memory"], cfg["serve"]
+    spec = build.build_app(cfg).spec
+    n_lin = cfg["layer_types"].count("linear_attention")
+    n_full = cfg["layer_types"].count("full_attention")
+    assert (spec.num_ssm_layers, spec.num_attn_layers) == (n_lin, n_full)
+    assert (spec.moe.num_experts, spec.moe.held_experts,
+            spec.moe.first_expert, spec.moe.top_k) == (512, 128, 0, 10)
+    assert spec.ssm.chunk_size == 64 and spec.ssm.key_heads == 16
+    state = ssm.ssm_state_shapes(spec.ssm, n_lin, serve["batch_size"],
+                                 jnp.dtype(cfg["dtype"]))
+    assert state["ssm"] == ((n_lin, 32, 32, 128, 128),
+                            jnp.dtype(assumed["state_dtype"]))
+    assert state["conv_x"] == ((n_lin, 32, 8192, 3),
+                               jnp.dtype(assumed["conv_tail_dtype"]))
+    state_bytes = sum(math.prod(shape) * jnp.dtype(dt).itemsize
+                      for shape, dt in state.values())
+    assert state_bytes == memory["state_bytes"] == \
+        serve["batch_size"] * memory["state_slot_bytes"]
+    # both heads of a token share ONE slot of 512 lanes (pool_page)
+    slots, lanes = pool_page(spec.gqa.num_kv_heads, spec.head_dim,
+                             spec.gqa.tp)
+    heads = memory["kv_pool_heads"]
+    assert (slots, lanes) == (1, heads * spec.head_dim) and heads == 2
+    pool = BlockKVSpec(num_layers=n_full,
+                       num_blocks=serve["pa_num_blocks"] + 1,
+                       block_size=serve["pa_block_size"], num_kv_heads=slots,
+                       head_dim=lanes, dtype=spec.kv_dtype)
+    assert pool.shape == (3, 4097, 32, 1, 512)
+    assert str(jnp.dtype(pool.dtype)) == assumed["kv_dtype"]
+    per_token = n_full * 2 * heads * spec.head_dim * 2
+    assert per_token == memory["kv_bytes_per_token"]
+    assert 2 * math.prod(pool.shape) * 2 == memory["kv_pool_bytes"]
+    weights = sum(
+        math.prod(ps.shape) * jnp.dtype(ps.dtype).itemsize
+        for ps in jax.tree.leaves(
+            model_base.decoder_param_specs(spec),
+            is_leaf=lambda x: isinstance(x, ParamSpec)))
+    # the program's count over the file's all-bf16 one: the router, A_log and
+    # dt_bias in float32 (2 B more an entry), the vocabulary padded to 128s
+    n = cfg["num_hidden_layers"]
+    extra = 2 * (n * 2048 * 512 + n_lin * 2 * 32) \
+        + 2 * 2 * 2048 * (spec.padded_vocab - cfg["vocab_size"])
+    assert spec.padded_vocab - cfg["vocab_size"] == 32
+    assert weights - extra == memory["weights_bytes"]
+    total = memory["weights_bytes"] + memory["state_bytes"] \
+        + memory["kv_pool_bytes"]
+    assert 0.75 * 16e9 < total < 0.8 * 16e9
+
+
+def _qwen_toy():
+    """One period at a toy size (``tests/test_qwen3_next_paged.py``'s, a
+    share of 4 experts of 16) as a configuration file the harness can build
+    and gate."""
+    from test_qwen3_next_paged import HF
+    return dict(
+        HF, family="qwen3_next", tp=1, dtype="float32",
+        serve=dict(batch_size=4, seq_len=256, pa_block_size=8,
+                   pa_num_blocks=160, context_encoding_buckets=[16, 64],
+                   enable_bucketing=True, is_block_kv_layout=True,
+                   is_prefix_caching=False),
+        adapter={},
+        gate=dict(config={}, batch=2, prompt_len=24, new_tokens=4,
+                  atol=2e-4, rtol=1e-4, min_positions_held=1.0,
+                  median_ratio_max=0.5, worst_ratio_max=1.0,
+                  excuse_margin_max=0.0))
+
+
+def test_the_qwen3_next_reference_gates_a_toy_twin():
+    ref = build.load_reference("qwen3_next")
+    assert ref.__file__ == os.path.join(BENCH, "references", "qwen3_next.py")
+    toy = _qwen_toy()
+    res = build.logit_gate(toy, seed=2**31 + 36, served_precision="highest")
+    assert res["passed"], res
+    assert res["compared"] == 2 * 28 * toy["vocab_size"]
+
+
+def _qwen_served_state_error(rounds_to=None, monkeypatch=None):
+    """As :func:`_olmo_served_state_error`, with key heads shared by pairs
+    of value heads: 150 prompt tokens = 64 + 64 + 22, then 60 decode steps,
+    the state slot against the reference's ``final_states``."""
+    import jax.numpy as jnp
+    import numpy as np
+    from harness import weights
+    from neuronx_distributed_inference_tpu.modules import ssm
+    from neuronx_distributed_inference_tpu.serving import PagedEngineAdapter
+    if rounds_to is not None:
+        shapes = ssm.ssm_state_shapes
+
+        def rounded(*a, **kw):
+            out = shapes(*a, **kw)
+            return dict(out, ssm=(out["ssm"][0], rounds_to))
+        monkeypatch.setattr(ssm, "ssm_state_shapes", rounded)
+    toy = _qwen_toy()
+    hf = build.hf_config(toy)
+    ref = build.load_reference(hf["model_type"])
+    table = ref.weight_shapes(hf)
+    w = weights.make_weights(table, seed=2**31 + 37)
+    app = build.build_app(toy)
+    app._put_params(app.family.convert_hf_state_dict(
+        weights.HfView(table, w, dtype=np.dtype("float32")), app.spec))
+    app.init_cache()
+    ad = PagedEngineAdapter(app)
+    prompt = np.random.default_rng(37).integers(
+        1, hf["vocab_size"], size=150).tolist()
+    stream = [ad.add_requests([3], [prompt])[3]]
+    for _ in range(60):
+        stream.append(ad.step([3])[3])
+    got = np.asarray(app.cache["ssm"][:, ad._state_slot[3]], np.float32)
+    want = np.asarray(ref.final_states(
+        hf, w, jnp.asarray([prompt + stream[:-1]])))[:, 0]
+    assert got.shape == want.shape
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def test_a_served_qwen3_next_slot_holds_the_references_state():
+    assert _qwen_served_state_error() < STATE_RTOL
+
+
+def test_a_bf16_carried_qwen3_next_state_fails_the_state_check(monkeypatch):
+    import jax.numpy as jnp
+    err = _qwen_served_state_error(jnp.bfloat16, monkeypatch)
     assert err > 10 * STATE_RTOL, err

@@ -29,7 +29,7 @@ from neuronx_distributed_inference_tpu.models import model_base
 from neuronx_distributed_inference_tpu.models.family import get_family
 from neuronx_distributed_inference_tpu.modules import ssm
 from neuronx_distributed_inference_tpu.modules.block_kv_cache import (
-    BlockKVSpec, block_cache_pspec, pool_kv_heads)
+    BlockKVSpec, block_cache_pspec, pool_page)
 from neuronx_distributed_inference_tpu.ops import kernel_mode
 from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
 from neuronx_distributed_inference_tpu.parallel.mesh import (MeshConfig,
@@ -115,11 +115,11 @@ def _serving_shapes(hf_attrs, layers, tp, devices, serve, prefix=True):
     params = jax.tree.map(lambda ps: sds(ps.shape, ps.dtype, ps.pspec),
                           model_base.decoder_param_specs(spec),
                           is_leaf=lambda x: isinstance(x, ParamSpec))
+    slots, lanes = pool_page(spec.gqa.num_kv_heads, spec.head_dim, tp)
     bspec = BlockKVSpec(
         num_layers=spec.num_attn_layers, num_blocks=tcfg.pa_num_blocks + 1,
-        block_size=tcfg.pa_block_size,
-        num_kv_heads=pool_kv_heads(spec.gqa.num_kv_heads, tp),
-        head_dim=spec.head_dim, dtype=spec.kv_dtype)
+        block_size=tcfg.pa_block_size, num_kv_heads=slots, head_dim=lanes,
+        dtype=spec.kv_dtype)
     cache = {k: sds(bspec.shape, bspec.dtype, block_cache_pspec())
              for k in ("k", "v")}
     if spec.ssm is not None:
@@ -321,6 +321,82 @@ def test_30_kv_heads_of_128_decode_on_the_kernel_with_no_pool_copy(
     assert step.memory_analysis().temp_size_in_bytes < 100e6
     chunk, notes = compiled(1, 256, state_slots=sds((1,), i32))
     assert notes == {state}
+    assert chunk.memory_analysis().temp_size_in_bytes < 200e6
+
+
+# Qwen/Qwen3-Next-80B-A3B-Instruct config.json (model-configs catalog), one
+# period and one chip's share: 128 experts held of the 512 routed over
+QWEN3_NEXT_SHARE = dict(
+    model_type="qwen3_next", vocab_size=37984, hidden_size=2048,
+    intermediate_size=5120, num_attention_heads=16, num_key_value_heads=2,
+    head_dim=256, hidden_act="silu", max_position_embeddings=262144,
+    rms_norm_eps=1e-6, tie_word_embeddings=False, full_attention_interval=4,
+    partial_rotary_factor=0.25, rope_theta=10000000, rope_scaling=None,
+    num_experts=128, router_num_experts=512, first_expert=0,
+    num_experts_per_tok=10, norm_topk_prob=True, moe_intermediate_size=512,
+    shared_expert_intermediate_size=512, linear_num_key_heads=16,
+    linear_num_value_heads=32, linear_key_head_dim=128,
+    linear_value_head_dim=128, linear_conv_kernel_dim=4)
+
+
+def test_2_kv_heads_of_256_decode_on_the_kernel_with_no_pool_copy(
+        v5e_devices):
+    """ISSUE 36: Qwen3-Next's attention (2 kv heads of 256, GQA 8 : 1) at
+    one period, the benchmark's batch, pool and table. As a page ``(32, 2,
+    256)`` the device tiled the pool ``(2, 128)`` and the first chip run
+    paid a ``reshape`` copy of the whole pool for K and for V in every
+    attention layer of every decode step (10.4 of 28.9 ms), and six ``copy``
+    of it in a chunk. Both heads of a token share one slot of 512 lanes
+    (``block_kv_cache.pool_page``): the step holds the Mosaic call, the
+    record says what it runs with, no instruction moves a pool, in the
+    step or in the one-row chunk; the chunk reads its layer's experts out
+    of the stack in place, on the share."""
+    spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
+        QWEN3_NEXT_SHARE, 4, 1, v5e_devices[:1],
+        dict(batch_size=32, seq_len=4096, pa_block_size=32,
+             pa_num_blocks=4096, context_encoding_buckets=[64, 256]),
+        prefix=False)
+    assert cache["k"].shape == (1, 4097, 32, 1, 512)
+    assert cache["ssm"].shape == (3, 32, 32, 128, 128)
+    assert cache["conv_x"].shape == (3, 32, 8192, 3)
+    assert params["layers"]["expert_up"].shape == (4, 128, 2048, 512)
+    assert params["layers"]["router"].shape == (4, 2048, 512)
+    i32 = jnp.int32
+
+    def compiled(rows, width, **kw):
+        notes = set()
+        with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
+            c = jax.jit(partial(model_base.paged_forward_step, spec, tcfg),
+                        donate_argnums=(1,)).lower(
+                params, cache, *(sds((rows, width), i32),) * 3,
+                sds((rows, mb), i32), sds((rows,), i32), None,
+                sds((2,), jnp.uint32), **kw).compile()
+        return c, notes
+
+    def pool_moves(text):
+        return re.findall(
+            r"%(\S+) = bf16\[(?:1,)?4097,[\d,]+\]\S* "
+            r"(copy|transpose|reshape)\(", text)
+
+    state = ("recurrent_state", "xla",
+             "kind=gated_delta slot_bytes=6438912 chunk=64")
+    share = ("moe_share", "xla", "held=128 of 512 from 0 top_k=10")
+    step, notes = compiled(32, 1)
+    assert notes == {state, share, (
+        "paged_decode", "pallas",
+        "pages=16 heads=2 form=mxu-blockdiag fold=2")}
+    text = step.as_text()
+    assert MOSAIC in text and "ragged-dot" not in text
+    assert not pool_moves(text), pool_moves(text)
+    assert step.memory_analysis().temp_size_in_bytes < 100e6
+    chunk, notes = compiled(1, 256, state_slots=sds((1,), i32))
+    assert notes == {state, share, ("moe_ragged", "stacked", "")}
+    text = chunk.as_text()
+    assert "ragged-dot" in text
+    assert not pool_moves(text), pool_moves(text)
+    copied = re.findall(r"slice_bitcast_fusion[.\d]* = bf16\[([\d,]+)\]",
+                        text)
+    assert not {"128,2048,512", "128,512,2048"} & set(copied), copied
     assert chunk.memory_analysis().temp_size_in_bytes < 200e6
 
 

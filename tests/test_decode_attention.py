@@ -412,8 +412,11 @@ def test_paged_decode_kernel_stacked_layers(rng):
 # head_dim), block 32; every case a row beside a second, ordinary row.
 # olmo-hybrid (ISSUE 34): 30 heads in a pool of 32 head slots
 # (block_kv_cache.pool_kv_heads), one page a compute block.
+# qwen3-next (ISSUE 36): 2 kv heads of 256 lanes, 8 query heads each: both
+# heads of a token share ONE row of 512 lanes (paged_pool_fold: few heads
+# of whole vregs), sixteen pages a compute block.
 _CELLS = {"olmoe": (16, 16, 128), "granite": (32, 8, 64),
-          "olmo-hybrid": (32, 32, 128)}
+          "olmo-hybrid": (32, 32, 128), "qwen3-next": (16, 2, 256)}
 _BS = 32
 
 
@@ -448,8 +451,10 @@ def test_paged_decode_walks_live_pages(rng, cell, case):
     # ... and wide enough for the longest case (a block + 70 tokens)
     mb = max(2 * _cell_plan(cell, dtype, 64).pages + 1, 5)
     plan = _cell_plan(cell, dtype, mb)
-    assert plan.fold == {"granite": 2}.get(cell, 1)
+    assert plan.fold == {"granite": 2, "qwen3-next": 2}.get(cell, 1)
     assert plan.pages == 1 if cell == "olmo-hybrid" else plan.pages >= 2
+    assert plan.d == {"olmoe": 128, "granite": 128, "olmo-hybrid": 128,
+                      "qwen3-next": 512}[cell]
     lens, window = _walk_case(case, plan.pages, mb)
     b, scale = len(lens), d ** -0.5
     # physical pages shuffled and non-contiguous (_paged_setup), block 0 null

@@ -123,24 +123,34 @@ def test_a_chunk_started_from_zero_is_not_the_recurrence(ref):
 # ---------------------------------------------------------------------------
 
 HID, K = 32, 4
-SPEC = ssm.SSMSpec(kind="gated_delta", d_inner=H * DV, num_heads=H,
-                   head_dim=DV, d_state=DK, d_conv=K, chunk_size=16,
-                   conv_bias=False, gated_norm=True, norm_before_gate=True,
-                   norm_eps=1e-6, beta_scale=1.0)
 
 
-@pytest.fixture(scope="module")
-def hf_mixer():
-    """A seeded ``Qwen3NextGatedDeltaNet`` and its weights in this
-    repository's layout. Its fused ``in_proj_qkvz`` rows are per KEY head
-    [q | k | v | z] and ``in_proj_ba`` rows per key head [b | a]
+def _spec(key_heads, heads):
+    return ssm.SSMSpec(kind="gated_delta", d_inner=heads * DV,
+                       num_heads=heads, num_key_heads=key_heads, head_dim=DV,
+                       d_state=DK, d_conv=K, chunk_size=16, conv_bias=False,
+                       gated_norm=True, norm_before_gate=True, norm_eps=1e-6,
+                       beta_scale=1.0)
+
+
+#: as many key heads as value heads (Olmo-Hybrid), and key heads shared by
+#: pairs of value heads (Qwen3-Next, ISSUE 36)
+@pytest.fixture(scope="module", params=[(H, H), (2, 4)],
+                ids=["a_key_head_a_value_head", "key_heads_shared_by_pairs"])
+def hf_mixer(request):
+    """A seeded ``Qwen3NextGatedDeltaNet``, its weights in this repository's
+    layout and the spec that goes with them. Its fused ``in_proj_qkvz`` rows
+    are per KEY head [q | k | v of its r value heads | z of them] and
+    ``in_proj_ba`` rows per key head [b r | a r]
     (``fix_query_key_value_ordering``): regrouped here by destination."""
     os.environ.setdefault("USE_TF", "0")
     import torch
     from transformers.models.qwen3_next import modeling_qwen3_next as hf
+    nk, nv = request.param
+    r = nv // nk
     torch.manual_seed(34)
     cfg = hf.Qwen3NextConfig(
-        hidden_size=HID, linear_num_value_heads=H, linear_num_key_heads=H,
+        hidden_size=HID, linear_num_value_heads=nv, linear_num_key_heads=nk,
         linear_key_head_dim=DK, linear_value_head_dim=DV,
         linear_conv_kernel_dim=K, hidden_act="silu", rms_norm_eps=1e-6)
     net = hf.Qwen3NextGatedDeltaNet(cfg, layer_idx=0).float().eval()
@@ -149,40 +159,43 @@ def hf_mixer():
             p.copy_(torch.randn_like(p) * (0.3 if p.ndim > 1 else 1.0))
         net.norm.weight.add_(1.0)
     qkvz = net.in_proj_qkvz.weight.detach().numpy().reshape(
-        H, 2 * DK + 2 * DV, HID)
-    ba = net.in_proj_ba.weight.detach().numpy().reshape(H, 2, HID)
+        nk, 2 * DK + 2 * r * DV, HID)
+    ba = net.in_proj_ba.weight.detach().numpy().reshape(nk, 2, r, HID)
 
     def rows(lo, hi):
         return qkvz[:, lo:hi].reshape(-1, HID)
     lw = {
-        "gdn_in": np.concatenate([rows(0, DK), rows(DK, 2 * DK),
-                                  rows(2 * DK, 2 * DK + DV),
-                                  rows(2 * DK + DV, 2 * DK + 2 * DV)]).T,
-        "gdn_in_ab": np.concatenate([ba[:, 1], ba[:, 0]]).T,   # [a | b]
+        "gdn_in": np.concatenate([
+            rows(0, DK), rows(DK, 2 * DK),
+            rows(2 * DK, 2 * DK + r * DV),
+            rows(2 * DK + r * DV, 2 * DK + 2 * r * DV)]).T,
+        "gdn_in_ab": np.concatenate([ba[:, 1].reshape(nv, HID),
+                                     ba[:, 0].reshape(nv, HID)]).T,  # [a|b]
         "gdn_conv": net.conv1d.weight.detach().numpy()[:, 0, :],
         "gdn_dt_bias": net.dt_bias.detach().numpy(),
         "gdn_A_log": net.A_log.detach().numpy(),
         "gdn_norm": net.norm.weight.detach().numpy(),
         "gdn_out": net.out_proj.weight.detach().numpy().T,
     }
-    return net, {n: jnp.asarray(a, jnp.float32) for n, a in lw.items()}
+    return (net, {n: jnp.asarray(a, jnp.float32) for n, a in lw.items()},
+            _spec(nk, nv))
 
 
-def _zero_state(b):
+def _zero_state(spec, b):
     return {k: jnp.zeros((b,) + shape[2:], dt) for k, (shape, dt) in
-            ssm.ssm_state_shapes(SPEC, 1, b, jnp.float32).items()}
+            ssm.ssm_state_shapes(spec, 1, b, jnp.float32).items()}
 
 
 def test_the_mixer_is_transformers_gated_deltanet(hf_mixer):
     import torch
-    net, lw = hf_mixer
+    net, lw, SPEC = hf_mixer
     b, t = 2, 41
     x = np.random.default_rng(36).standard_normal((b, t, HID)).astype(
         np.float32)
     with torch.no_grad():
         want = net(torch.tensor(x)).numpy()
     pos = jnp.broadcast_to(jnp.arange(t), (b, t))
-    got, st = ssm.ssm_block(SPEC, lw, jnp.asarray(x), _zero_state(b),
+    got, st = ssm.ssm_block(SPEC, lw, jnp.asarray(x), _zero_state(SPEC, b),
                             phase="prefill", positions=pos,
                             seq_lens=jnp.full((b,), t))
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
@@ -192,7 +205,7 @@ def test_the_mixer_is_transformers_gated_deltanet(hf_mixer):
     n1 = np.asarray([24, 19])
     valid = jnp.arange(24)[None] < n1[:, None]
     out1, st1 = ssm.ssm_block(SPEC, lw, jnp.asarray(x[:, :24]),
-                              _zero_state(b), phase="paged",
+                              _zero_state(SPEC, b), phase="paged",
                               positions=pos[:, :24], valid=valid)
     outs = {r: [np.asarray(out1[r, :n1[r]])] for r in range(b)}
     for r in range(b):
@@ -212,10 +225,10 @@ def test_the_mixer_is_transformers_gated_deltanet(hf_mixer):
 
 @pytest.mark.parametrize("t", [1, 24])
 def test_a_dead_row_keeps_its_state_bit_for_bit(hf_mixer, t):
-    _, lw = hf_mixer
+    _, lw, SPEC = hf_mixer
     rng = np.random.default_rng(37)
     state = {k: jnp.asarray(rng.standard_normal(v.shape), v.dtype)
-             for k, v in _zero_state(2).items()}
+             for k, v in _zero_state(SPEC, 2).items()}
     x = jnp.asarray(rng.standard_normal((2, t, HID)), jnp.float32)
     valid = jnp.asarray([[True] * t, [False] * t])
     _, new = ssm.ssm_block(SPEC, lw, x, state, phase="paged",

@@ -386,7 +386,9 @@ def _config(serve=None, **hf):
     (dict(tp_degree=2), {}, "served on one chip"),
     ({}, dict(rope_parameters={"rope_theta": 500000.0}),
      "read as no positional embedding"),
-    ({}, dict(linear_num_key_heads=1), "linear_num_key_heads"),
+    # key heads shared by groups of value heads are walked since ISSUE 36;
+    # what is refused is a count that does not divide the value heads
+    ({}, dict(linear_num_value_heads=3), "not a multiple"),
     ({}, dict(attention_bias=True), "the loader reads no bias"),
     (dict(is_prefix_caching=True), {},
      "prefix caching (" + model_base.RECURRENT_UNSUPPORTED["prefix caching"]),
@@ -396,7 +398,7 @@ def _config(serve=None, **hf):
 ])
 def test_the_family_refuses_with_a_sentence(serve, hf, sentence):
     family, config = _config(serve, **hf)
-    with pytest.raises(NotImplementedError) as ei:
+    with pytest.raises((NotImplementedError, ValueError)) as ei:
         family.build_spec(config)
     assert sentence in str(ei.value)
 
