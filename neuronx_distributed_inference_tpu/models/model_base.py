@@ -337,6 +337,15 @@ class DecoderSpec:
         return 0 if pat is None else sum(pat)
 
     @property
+    def num_moe_layers(self) -> int:
+        """Layers whose MLP is the routed block."""
+        if self.moe is None:
+            return 0
+        if self.moe_pattern is not None:
+            return sum(self.moe_pattern)
+        return self.num_layers - self.first_dense
+
+    @property
     def scale(self) -> float:
         return self.attn_scale if self.attn_scale is not None else self.head_dim ** -0.5
 
@@ -540,7 +549,7 @@ def decoder_param_specs(spec: DecoderSpec) -> Dict[str, Any]:
         out["embed_norm"] = ParamSpec((H,), P(), dt, "ones")
         out["embed_norm_b"] = ParamSpec((H,), P(), dt, "zeros")
     if spec.moe is not None and spec.first_dense > 0:
-        n_dense, n_moe = spec.first_dense, L - spec.first_dense
+        n_dense, n_moe = spec.first_dense, spec.num_moe_layers
         dense = _attn_param_specs(spec, n_dense)
         dense.update(_dense_mlp_param_specs(spec, n_dense))
         moe = _attn_param_specs(spec, n_moe)
@@ -550,7 +559,7 @@ def decoder_param_specs(spec: DecoderSpec) -> Dict[str, Any]:
     elif spec.moe is not None and spec.moe_pattern is not None:
         # interleaved dense/MoE (llama4): stacks hold each kind's layers in
         # order of appearance; run_layers walks the pattern
-        n_moe = sum(spec.moe_pattern)
+        n_moe = spec.num_moe_layers
         n_dense = L - n_moe
         moe = _attn_param_specs(spec, n_moe)
         moe.update(_moe_param_specs(spec, n_moe))
@@ -784,7 +793,7 @@ def _layer_body(spec: DecoderSpec, hidden, layer_w, k_full, v_full, li,
                 mlp_kind: Optional[str] = None,
                 adapter_ids=None, replace=None, kv_view: int = None,
                 deepstack=None, deepstack_mask=None, prefill_lens=None,
-                side=None, mixed_local=None):
+                side=None, mixed_local=None, live=None):
     """One transformer layer. hidden (B,T,H); k/v_full: the FULL stacked
     cache (L,B,S,Hkv,D) — or, in the paged layout, (L,N_blocks,Bs,Hkv,D)
     with ``slot_mapping``/``block_table`` set (phase "paged", reference:
@@ -806,6 +815,10 @@ def _layer_body(spec: DecoderSpec, hidden, layer_w, k_full, v_full, li,
     phase "paged": write at slot_mapping, gather via block_table, attend over
       the gathered view — covers paged prefill, prefix-cached continuation,
       chunked prefill and paged decode with one body.
+
+    ``live`` (B,T) bool, a paged decode step over expert layers only: the
+    rows that carry a sequence; the layer then counts its routing
+    (``moe.share_tally``) into the per-layer outputs as ``moe_tally``.
     """
     if mlp_kind is None:
         mlp_kind = "dense" if spec.moe is None else "moe"
@@ -840,8 +853,12 @@ def _layer_body(spec: DecoderSpec, hidden, layer_w, k_full, v_full, li,
     sp_axis = AXIS_CP if (spec.seq_parallel and phase == "prefill") else None
 
     def _mlp(x_in):
-        return _mlp_block(spec, x_in, layer_w, mlp_kind, adapter_ids,
-                          phase=phase)
+        tally = [] if live is not None and mlp_kind == "moe" else None
+        out = _mlp_block(spec, x_in, layer_w, mlp_kind, adapter_ids,
+                         phase=phase, tally=tally, live=live)
+        if tally:
+            caps["moe_tally"] = tally[0]
+        return out
 
     if spec.block_style != "sequential":
         # parallel residual: x + attn(norm(x)) + mlp(norm'(x)) (falcon
@@ -1492,6 +1509,14 @@ def _paged_pool_fold(spec: DecoderSpec, cache, hidden, phase: str,
     return plan.fold if plan is not None else 1
 
 
+def _join_caps(parts):
+    """The per-layer outputs of consecutive layer runs as one, layer-major
+    (a key only some runs give - a dense run counts no experts - is theirs
+    alone)."""
+    return {k: jnp.concatenate([c[k] for c in parts if k in c])
+            for k in dict.fromkeys(k for c in parts for k in c)}
+
+
 def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
                seq_ids, positions, phase: str,
                identity_seq_ids: bool = False,
@@ -1583,7 +1608,7 @@ def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
             cache_offset=nd, is_local=is_local[nd:], rep=sl(nd, L),
             mlp_kind="moe", deepstack=None if ds is None else ds[nd:],
             side=side, **kw), side)
-        caps = {k: jnp.concatenate([c1[k], c2[k]]) for k in c1}
+        caps = _join_caps([c1, c2])
         if side is not None:
             return hidden, {"k": kf, "v": vf}, caps, side
         return hidden, {"k": kf, "v": vf}, caps
@@ -1617,9 +1642,7 @@ def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
                            else deepstack[start:start + count]),
                 side=side, **kw), side)
             caps_parts.append(c)
-        caps = ({k: jnp.concatenate([c[k] for c in caps_parts])
-                 for k in caps_parts[0]} if caps_parts and caps_parts[0]
-                else {})
+        caps = _join_caps(caps_parts)
         if side is not None:
             return hidden, {"k": kf, "v": vf}, caps, side
         return hidden, {"k": kf, "v": vf}, caps
@@ -1652,8 +1675,9 @@ def run_layer_slice(spec: DecoderSpec, layer_params, kf, vf, hidden, ai, *,
     static slice. Every other phase SCANS one compiled body (O(1) compile
     time in depth) — the paged step graphs too: ``paged_forward_step``
     passes "paged", so ``paged.w1`` scans and is copy-free all the same,
-    because the dense all-experts einsum fuses its layer slice and the
-    paged decode kernel takes the layer index as a scalar.
+    because its consumers either fuse their layer slice (the dense
+    all-experts einsum) or take the layer index as a scalar (the paged
+    decode kernel, the few-token expert kernel).
 
     What a scan's dynamic layer index costs is decided per consumer: XLA
     fuses a layer's slice into an einsum or an elementwise op, but a
@@ -1666,9 +1690,10 @@ def run_layer_slice(spec: DecoderSpec, layer_params, kf, vf, hidden, ai, *,
     worse: the compiler then copies ALL layers in one multi-output fusion.
     So those consumers get the STACKED array and the layer index and select
     the layer themselves: the expert leaves ``moe.stack_leaves`` names stay
-    out of the scan's ``xs`` (``moe_block`` receives a ``LayerOfStack``),
-    and the paged read gathers from the flat pool
-    (``block_kv_cache.gather_layer_kv``)."""
+    out of the scan's ``xs`` (``moe_block`` receives a ``LayerOfStack``:
+    the grouped matmuls of many tokens, and the touched-experts kernel of
+    few, ``ops/moe_decode.py``), and the paged read gathers from the flat
+    pool (``block_kv_cache.gather_layer_kv``)."""
     n = jax.tree.leaves(layer_params)[0].shape[0]
     h0 = jax.tree.leaves(hidden)[0]
     # leaves a consumer reads out of the stack in place: not sliced per layer
@@ -1676,6 +1701,10 @@ def run_layer_slice(spec: DecoderSpec, layer_params, kf, vf, hidden, ai, *,
                                      layer_params)
                 if spec.moe is not None and mlp_kind != "dense" else ())
     sliced = {k: a for k, a in layer_params.items() if k not in in_place}
+    # a paged decode step counts what its routing touched and what its
+    # expert path read, a layer a row of the scan's outputs
+    live = (slot_mapping >= 0 if phase == "paged" and h0.shape[1] == 1
+            and spec.moe is not None and mlp_kind != "dense" else None)
 
     def of_stack(layer_w, i):
         return {**layer_w, **{k: moe_mod.LayerOfStack(layer_params[k], i)
@@ -1733,7 +1762,7 @@ def run_layer_slice(spec: DecoderSpec, layer_params, kf, vf, hidden, ai, *,
             slot_mapping, block_table, mlp_kind, adapter_ids,
             rp if replacements is not None else None, kv_view=kv_view,
             deepstack=ds, deepstack_mask=deepstack_mask,
-            prefill_lens=prefill_lens)
+            prefill_lens=prefill_lens, live=live)
         return (h, k_, v_), caps
 
     xs = (sliced, is_local, rep, jnp.arange(n, dtype=jnp.int32))
@@ -1838,16 +1867,16 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
 
     not_local = jnp.asarray(False)
     # the MLP kind is the spec's: dense, or the routed block. Its expert
-    # leaves stay in their stack where the grouped matmuls read them in
-    # place (run_layer_slice has the reason); the dense path's static slice
-    # fuses into its einsum
+    # leaves stay in their stack where a custom call reads them in place
+    # (the grouped matmuls, the few-token kernel: run_layer_slice has the
+    # reason); the dense path's static slice fuses into its einsum
     mlp_kind = "dense" if spec.moe is None else "moe"
     in_place = (moe_mod.stack_leaves(
         spec.moe, hidden.shape[0] * hidden.shape[1], params["layers"])
         if spec.moe is not None else ())
-    # a decode step over a share of the expert layers counts its routing
-    tally = [] if (paged and hidden.shape[1] == 1 and spec.moe is not None
-                   and spec.moe.holds_share) else None
+    # a decode step over expert layers counts its routing and its reads
+    tally = [] if (paged and hidden.shape[1] == 1
+                   and spec.moe is not None) else None
     attn_i = 0
     ssm_i = 0
     for i in range(spec.num_layers):
@@ -1906,8 +1935,8 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
             m_out = rms_norm(m_out, lw["post_ff_norm"], spec.rms_eps,
                              spec.norm_offset)
         hidden = add(hidden, m_out)
-    # a share's exact counts over this walk's expert layers, [touched,
-    # assigned] (moe.share_tally), for the step to hand out with its tokens
+    # exact counts over this walk's expert layers, [touched, assigned,
+    # read] (moe.share_tally), for the step to hand out with its tokens
     side = {"moe_tally": sum(tally)} if tally else {}
     return hidden, {"k": kf, "v": vf, **new_state}, side
 
@@ -2261,9 +2290,10 @@ def paged_forward_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     logits = _lm_head(spec, params, last_h)[:, 0, :]
     out = {"cache": new_cache}
     if "moe_tally" in side:
-        # a decode step over a share of an expert layer counts what its
-        # routing touched; the adapter fetches it with the tokens
-        out["moe_tally"] = side["moe_tally"]
+        # a decode step over expert layers counts what its routing touched
+        # and what its expert path read (one row a layer where the walk
+        # scans); the adapter fetches the sums with the tokens
+        out["moe_tally"] = side["moe_tally"].reshape(-1, 3).sum(axis=0)
     if tpu_cfg.output_logits:
         out["logits"] = _lm_head(spec, params, hidden)[..., :spec.vocab_size]
     if _coupled_mode(tpu_cfg, row_seeds):

@@ -13,6 +13,17 @@ Design:
     are batched into one einsum that XLA maps onto the MXU. Expert dim shards
     on mesh axis "ep" (moe_ep), intermediate dim on "tp" (moe_tp) — the
     combine-sum over E emits a psum over "ep" automatically.
+  * Experts, touched path (few tokens, one chip): the same step reads only
+    the experts its routing touched. A Pallas kernel
+    (``ops/moe_decode.py``) takes the tokens, the combine matrix over the
+    held experts and the expert leaves IN THEIR STACK with the layer index,
+    builds the touched list on the device and walks it, one expert's three
+    matrices a step into one of two VMEM slots. An untouched expert's term
+    of the dense sum is exactly zero, so it is the same sum in another
+    order. Where the kernel declines (``moe_decode.declined``: quantized,
+    sharded, ``input_scaled``, biases, ``tkg_experts_local``, widths that
+    are not whole tiles) the dense path runs untouched, and
+    ``kernel_mode.note("moe_decode", ...)`` says which and why.
   * Experts, ragged path (prefill): tokens are sorted by expert and run
     through grouped matmuls via ``jax.lax.ragged_dot`` — the dropless
     TPU-native analog of the reference's blockwise matmul
@@ -38,13 +49,14 @@ diverge from HF goldens).
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..ops import kernel_mode
+from ..ops import kernel_mode, moe_decode
 from ..parallel.mesh import (AXIS_DP, AXIS_EP, AXIS_MP, AXIS_TP,
                              shard_constraint)
 from .quantization import dequantize, is_quantized_leaf, qeinsum, qlinear
@@ -122,7 +134,8 @@ class MoESpec:
         return 0 < self.held_experts < self.num_experts
 
 
-# the per-expert leaves of a layer: what the ragged path reads in place
+# the per-expert leaves of a layer: what the ragged path and the few-token
+# kernel read in place
 EXPERT_LEAVES = ("expert_gate", "expert_up", "expert_down",
                  "expert_gate_bias", "expert_up_bias", "expert_down_bias")
 
@@ -139,8 +152,9 @@ class LayerOfStack(NamedTuple):
 
 def takes_ragged(moe: MoESpec, tokens: int) -> bool:
     """The sorted grouped-matmul path serves a step of ``tokens`` (B*T)
-    tokens; at or below ``dense_max_tokens`` all experts compute on all
-    tokens and XLA fuses the layer's slice into the einsum."""
+    tokens; at or below ``dense_max_tokens`` the few-token paths do: the
+    kernel over the touched experts, or all experts on all tokens in an
+    einsum that XLA fuses the layer's slice into."""
     return tokens > moe.dense_max_tokens
 
 
@@ -162,9 +176,12 @@ def stack_leaves(moe: MoESpec, tokens: int, layer_params: Dict[str, Any]
                  ) -> Tuple[str, ...]:
     """The leaves of the stacked ``layer_params`` that a layer loop over a
     step of ``tokens`` tokens leaves in their stack (handing ``moe_block``
-    a :class:`LayerOfStack`) instead of slicing a layer out of them."""
-    if (not takes_ragged(moe, tokens)
-            or sliced_reason(layer_params["expert_gate"])):
+    a :class:`LayerOfStack`) instead of slicing a layer out of them: both
+    consumers that are custom calls - the grouped matmuls of many tokens,
+    the touched-experts kernel of few - read the layer where it lies."""
+    wg = layer_params["expert_gate"]
+    if (sliced_reason(wg) if takes_ragged(moe, tokens)
+            else moe_decode.declined(moe, wg)):
         return ()
     return tuple(k for k in EXPERT_LEAVES if k in layer_params)
 
@@ -281,19 +298,24 @@ def held_combine(moe: MoESpec, top_vals: jnp.ndarray,
 
 
 def share_tally(moe: MoESpec, top_idx: jnp.ndarray,
-                live: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Exact counts of one routing over a share, int32 ``[touched,
-    assigned]``: held experts that received at least one token, and the
-    assignments that fell to held experts. ``live`` (B,T) bool leaves the
-    rows of a step that carry no sequence out."""
+                live: Optional[jnp.ndarray] = None, read=None) -> jnp.ndarray:
+    """Exact counts of one routing over the held experts (all, or a share),
+    int32 ``[touched, assigned, read]``: held experts that received at
+    least one token, the assignments that fell to held experts, and the
+    held experts whose weights the step READ (``read``: the touched list's
+    length on the kernel; None = all of them, the dense and ragged paths).
+    ``live`` (B,T) bool leaves the rows of a step that carry no sequence
+    out of the first two; the kernel reads for every row it is given, so
+    ``read >= touched``."""
+    held = moe.num_held
     local = top_idx - moe.first_expert
-    mine = (local >= 0) & (local < moe.held_experts)
+    mine = (local >= 0) & (local < held)
     if live is not None:
         mine = mine & live[..., None]
-    hits = jnp.zeros((moe.held_experts,), jnp.int32).at[
-        jnp.where(mine, local, moe.held_experts).reshape(-1)].add(
-            1, mode="drop")
-    return jnp.stack([jnp.sum(hits > 0), jnp.sum(hits)]).astype(jnp.int32)
+    hits = jnp.zeros((held,), jnp.int32).at[
+        jnp.where(mine, local, held).reshape(-1)].add(1, mode="drop")
+    return jnp.stack([jnp.sum(hits > 0), jnp.sum(hits),
+                      held if read is None else read]).astype(jnp.int32)
 
 
 def _glu(moe: MoESpec, gate: jnp.ndarray, up: jnp.ndarray) -> jnp.ndarray:
@@ -426,40 +448,74 @@ def experts_ragged(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
     return y.astype(dt)
 
 
+def experts_touched(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
+                    top_idx: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
+                    wd: jnp.ndarray, layer) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The few-token path on the kernel (``ops/moe_decode.py``): x (B,T,H);
+    wg / wu (L,E,H,I), wd (L,E,I,H) the STACKED leaves and ``layer`` the
+    layer's index. Returns the combined output and the number of held
+    experts the kernel read (every expert with a non-zero combine column
+    over ALL the rows of the step)."""
+    b, t, h = x.shape
+    combine = held_combine(moe, top_vals, top_idx).reshape(b * t, -1)
+    y, read = moe_decode.moe_decode_experts(
+        x.reshape(b * t, h), combine, wg, wu, wd, layer,
+        glu=functools.partial(_glu, moe),
+        interpret=kernel_mode.pallas_interpret())
+    return y.astype(x.dtype).reshape(b, t, h), read
+
+
 def moe_block(moe: MoESpec, x: jnp.ndarray, layer_w: Dict[str, Any],
               phase: str = "prefill", tally: Optional[list] = None,
               live: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Full MoE block: route + experts (+ shared experts). x (B,T,H).
-    ``tally``: a list a layer walk hands in to collect, per layer of a
-    share, :func:`share_tally` of this routing over the ``live`` rows."""
+    ``tally``: a list a layer walk hands in to collect, per expert layer,
+    :func:`share_tally` of this routing over the ``live`` rows."""
     router_bias = layer_w.get("router_bias") if moe.has_router_bias else None
     top_vals, top_idx = route(moe, x, layer_w["router"], router_bias)
     if moe.holds_share:
         kernel_mode.note("moe_share", "xla",
                          f"held={moe.held_experts} of {moe.num_experts} "
                          f"from {moe.first_expert} top_k={moe.top_k}")
-        if tally is not None:
-            tally.append(share_tally(moe, top_idx, live))
+    y, read = _experts(moe, x, top_vals, top_idx, layer_w, phase)
+    if tally is not None:
+        tally.append(share_tally(moe, top_idx, live, read))
+    return _shared_experts(moe, x, y, layer_w)
+
+
+def _experts(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
+             top_idx: jnp.ndarray, layer_w: Dict[str, Any], phase: str):
+    """The routed experts' part of the block by the path the step takes,
+    and how many held experts that path read (None = all of them)."""
     biases = ((layer_w["expert_gate_bias"], layer_w["expert_up_bias"],
                layer_w["expert_down_bias"]) if moe.expert_bias
               else (None, None, None))
     wg, wu, wd = (layer_w["expert_gate"], layer_w["expert_up"],
                   layer_w["expert_down"])
+    ragged = takes_ragged(moe, x.shape[0] * x.shape[1])
     if isinstance(wg, LayerOfStack):
-        # the layer loop decided by the same two rules (stack_leaves)
-        kernel_mode.note("moe_ragged", "stacked")
-        y = experts_ragged(
-            moe, x, top_vals, top_idx,
-            *(None if a is None else a.stack for a in (wg, wu, wd, *biases)),
-            layer=wg.layer)
-        return _shared_experts(moe, x, y, layer_w)
-    experts = (experts_ragged if takes_ragged(moe, x.shape[0] * x.shape[1])
-               else experts_dense)
-    if experts is experts_ragged:
+        # the layer loop decided by the same rules (stack_leaves)
+        if ragged:
+            kernel_mode.note("moe_ragged", "stacked")
+            return experts_ragged(
+                moe, x, top_vals, top_idx,
+                *(None if a is None else a.stack
+                  for a in (wg, wu, wd, *biases)), layer=wg.layer), None
+        kernel_mode.note(
+            "moe_decode", kernel_mode.kernel_path(),
+            moe_decode.moe_decode_plan(*wg.stack.shape[-2:],
+                                       wg.stack.dtype).note())
+        return experts_touched(moe, x, top_vals, top_idx, wg.stack,
+                               wu.stack, wd.stack, wg.layer)
+    if ragged:
         kernel_mode.note("moe_ragged", "sliced",
                          sliced_reason(wg) or "the caller cut the layer out")
+        return experts_ragged(moe, x, top_vals, top_idx, wg, wu, wd,
+                              *biases), None
+    kernel_mode.note("moe_decode", "xla", moe_decode.declined(moe, wg)
+                     or "the caller cut the layer out")
     if (moe.tkg_experts_local and phase == "decode"
-            and experts is experts_dense and not is_quantized_leaf(wg)):
+            and not is_quantized_leaf(wg)):
         # hybrid TKG sharding: all experts local, intermediate split over
         # BOTH model axes (see MoESpec.tkg_experts_local). DENSE path
         # only: the ragged grouped-matmul fallthrough (decode batch above
@@ -488,11 +544,10 @@ def moe_block(moe: MoESpec, x: jnp.ndarray, layer_w: Dict[str, Any],
         # the dense compute must KEEP the local-expert layout for its
         # intermediate, or GSPMD reshards the weights back (see
         # experts_dense.local_experts)
-        y = experts_dense(moe, x, top_vals, top_idx, wg, wu, wd,
-                          *biases, local_experts=True)
-        return _shared_experts(moe, x, y, layer_w)
-    y = experts(moe, x, top_vals, top_idx, wg, wu, wd, *biases)
-    return _shared_experts(moe, x, y, layer_w)
+        return experts_dense(moe, x, top_vals, top_idx, wg, wu, wd,
+                             *biases, local_experts=True), None
+    return experts_dense(moe, x, top_vals, top_idx, wg, wu, wd,
+                         *biases), None
 
 
 def _shared_experts(moe: MoESpec, x: jnp.ndarray, y: jnp.ndarray,

@@ -421,12 +421,13 @@ class _AdapterTelemetry:
             tmetrics.overlapped_dispatches_counter(reg).inc(
                 engine=self.engine)
 
-    def on_moe_tally(self, touched: int, slots: int, assigned: int):
+    def on_moe_tally(self, touched: int, slots: int, assigned: int,
+                     read: int):
         reg = self.registry
         if reg.enabled:
             c = tmetrics.moe_experts_counter(reg)
             for count, n in (("touched", touched), ("slots", slots),
-                             ("assigned", assigned)):
+                             ("assigned", assigned), ("read", read)):
                 c.inc(n, engine=self.engine, count=count)
 
     def on_drain(self, cause: str):
@@ -889,8 +890,9 @@ class _EngineAdapterBase:
             toks = np.asarray(out["tokens"])
             tally = out.get("moe_tally")
             if tally is not None:
-                # a decode step over a share of the expert layers: what its
-                # routing touched, counted on the device (two int32)
+                # a decode step over expert layers: what its routing touched
+                # and its expert path read, counted on the device (three
+                # int32)
                 self._count_moe_tally(np.asarray(tally))
         self.host_stats["blocking_fetches"] += 1
         self.host_stats["blocked_s"] += time.perf_counter() - t0
@@ -898,18 +900,23 @@ class _EngineAdapterBase:
         return toks[:b] if rows is None else toks[rows]
 
     def _count_moe_tally(self, tally: np.ndarray):
-        """``[touched, assigned]`` of one decode step (``moe.share_tally``
-        summed over the expert layers) into ``host_stats``; the slots the
-        touched experts are counted over are held experts x expert layers,
-        once a step."""
-        moe = self.app.spec.moe
-        slots = moe.held_experts * self.app.spec.num_layers
+        """``[touched, assigned, read]`` of one decode step
+        (``moe.share_tally`` summed over the expert layers) into
+        ``host_stats``; the slots they are counted over are held experts x
+        expert layers, once a step, and ``moe_experts_skipped`` is the
+        slots the step did not read (a reader that sums and divides cannot
+        subtract)."""
+        spec = self.app.spec
+        slots = spec.moe.num_held * spec.num_moe_layers
+        touched, assigned, read = (int(n) for n in tally)
         st = self.host_stats
-        for key, n in (("moe_experts_touched", int(tally[0])),
-                       ("moe_assignments_held", int(tally[1])),
+        for key, n in (("moe_experts_touched", touched),
+                       ("moe_assignments_held", assigned),
+                       ("moe_experts_read", read),
+                       ("moe_experts_skipped", slots - read),
                        ("moe_expert_slots", slots)):
             st[key] = st.get(key, 0) + n
-        self.telemetry.on_moe_tally(int(tally[0]), slots, int(tally[1]))
+        self.telemetry.on_moe_tally(touched, slots, assigned, read)
 
     # -- public decode surface ---------------------------------------------
     def step(self, seq_ids: Optional[Sequence[int]] = None) -> Dict[int, int]:

@@ -241,11 +241,13 @@ def overlapped_dispatches_counter(reg):
 def moe_experts_counter(reg):
     return reg.counter(
         MOE_EXPERTS_TOTAL,
-        "Exact counts of the decode steps of a stack that holds a SHARE of "
-        "its expert layers, summed on the device from the router's top-k; "
+        "Exact counts of the decode steps of a stack with expert layers, "
+        "summed on the device from the router's top-k; "
         "count=touched (held experts that received a token, summed over "
         "layers and steps) | slots (held x expert layers x steps) | "
-        "assigned (assignments that fell to held experts)",
+        "assigned (assignments that fell to held experts) | read (held "
+        "experts whose weights the step's expert path read: all on the "
+        "dense path, the touched list on the kernel)",
         labels=("engine", "count"))
 
 

@@ -229,8 +229,8 @@ def test_olmoe_chunk_reads_experts_and_pool_in_place(v5e_devices):
     weights and no layer of the pool in front of a consumer that cannot
     fuse a slice — the five ``dynamic-slice_bitcast_fusion`` ops that were
     26 of its 38 ms — and its temps are the attention's, not 3 x 268 MB of
-    weights. The decode step of the same spec keeps the dense all-experts
-    path: no ``ragged-dot``, no ``moe_ragged`` note."""
+    weights. The decode step of the same spec is on neither form of the
+    ragged path: no ``ragged-dot``, no ``moe_ragged`` note."""
     spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
         OLMOE_1B_7B, 2, 1, v5e_devices[:1],
         dict(batch_size=16, seq_len=4096, pa_block_size=32,
@@ -384,7 +384,8 @@ def test_2_kv_heads_of_256_decode_on_the_kernel_with_no_pool_copy(
     step, notes = compiled(32, 1)
     assert notes == {state, share, (
         "paged_decode", "pallas",
-        "pages=16 heads=2 form=mxu-blockdiag fold=2")}
+        "pages=16 heads=2 form=mxu-blockdiag fold=2"),
+        ("moe_decode", "pallas", "pieces=1 of 512")}
     text = step.as_text()
     assert MOSAIC in text and "ragged-dot" not in text
     assert not pool_moves(text), pool_moves(text)
@@ -398,6 +399,51 @@ def test_2_kv_heads_of_256_decode_on_the_kernel_with_no_pool_copy(
                         text)
     assert not {"128,2048,512", "128,512,2048"} & set(copied), copied
     assert chunk.memory_analysis().temp_size_in_bytes < 200e6
+
+
+@pytest.mark.parametrize("hf, layers, serve, stack_shapes, plan", [
+    (OLMOE_1B_7B, 2, dict(batch_size=16, seq_len=4096, pa_block_size=32,
+                          pa_num_blocks=1024,
+                          context_encoding_buckets=[64, 256]),
+     {"2,64,2048,1024", "2,64,1024,2048", "64,2048,1024", "64,1024,2048"},
+     "pieces=1 of 1024"),
+    (QWEN3_NEXT_SHARE, 4, dict(batch_size=32, seq_len=4096, pa_block_size=32,
+                               pa_num_blocks=4096,
+                               context_encoding_buckets=[64, 256]),
+     {"4,128,2048,512", "4,128,512,2048", "128,2048,512", "128,512,2048"},
+     "pieces=1 of 512"),
+], ids=["olmoe", "qwen3-next-share"])
+def test_moe_decode_reads_the_expert_stack_in_place(v5e_devices, hf, layers,
+                                                    serve, stack_shapes,
+                                                    plan):
+    """ISSUE 37: the decode step (the benchmark's ``paged.w1``) at OLMoE's
+    and at qwen3-next's widths - the scan of the one, the static loop of
+    the other - holds the ``moe_decode_experts`` call, which takes the
+    expert leaves as the program's own arguments: no instruction copies,
+    relays or slices an array of an expert stack's (or a layer's experts')
+    shape in front of it, and the step's temps are activations, not 805 MB
+    of a layer's experts."""
+    spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
+        hf, layers, 1, v5e_devices[:1], serve, prefix=False)
+    i32 = jnp.int32
+    rows = tcfg.batch_size
+    notes = set()
+    with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
+        step = jax.jit(partial(model_base.paged_forward_step, spec, tcfg),
+                       donate_argnums=(1,)).lower(
+            params, cache, *(sds((rows, 1), i32),) * 3, sds((rows, mb), i32),
+            sds((rows,), i32), None, sds((2,), jnp.uint32)).compile()
+    assert ("moe_decode", "pallas", plan) in notes
+    text = step.as_text()
+    calls = re.findall(r"%(moe_decode_experts[.\d]*) = f32\[(\d+),2048\]\S* "
+                       r"custom-call\(", text)
+    assert calls and all(int(n) == rows for _, n in calls), calls
+    moved = [(name, shape, op) for name, shape, op in re.findall(
+        r"%(\S+) = bf16\[([\d,]+)\]\S* (\w[\w-]*)\(", text)
+        if shape in stack_shapes
+        and op not in ("parameter", "get-tuple-element", "bitcast")]
+    assert not moved, moved
+    assert step.memory_analysis().temp_size_in_bytes < 100e6
 
 
 def test_without_the_request_nothing_is_interpreted(v5e_devices,

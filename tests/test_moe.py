@@ -2,6 +2,7 @@
 tiny-model goldens vs HF CPU for Mixtral and Qwen3-MoE (reference analog:
 test/integration tiny_model/features MoE coverage, SURVEY §4)."""
 
+import contextlib
 import dataclasses
 
 import jax
@@ -324,7 +325,8 @@ def test_ragged_on_the_stack_equals_the_layer_slice(rng, layer, empty,
 
 def test_moe_block_takes_a_layer_of_the_stack(rng):
     """``moe_block`` handed ``LayerOfStack`` leaves gives what it gives on
-    the slices, and says so; a dense-path step is never handed one."""
+    the slices, and says so; a few-token step over experts the kernel
+    declines (these carry biases) is never handed one."""
     spec, x, _, _, w, b = _stacked_case(rng, True, False)
     spec = dataclasses.replace(spec, dense_max_tokens=8)
     names = moe_mod.EXPERT_LEAVES
@@ -345,6 +347,103 @@ def test_moe_block_takes_a_layer_of_the_stack(rng):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert notes == {("moe_ragged", "stacked", ""),
                      ("moe_ragged", "sliced", "the caller cut the layer out")}
+
+
+def _tile_stack(rng, layers=3, experts=4, h=128, i=128, dtype=np.float32):
+    """Expert leaves the few-token kernel takes: whole 128-lane tiles."""
+    def rand(*shape):
+        return jnp.asarray((rng.normal(size=shape) * 0.1).astype(dtype))
+    return {"expert_gate": rand(layers, experts, h, i),
+            "expert_up": rand(layers, experts, h, i),
+            "expert_down": rand(layers, experts, i, h)}
+
+
+def test_moe_block_takes_a_layer_of_the_stack_for_few_tokens(rng):
+    """The few-token path: a step at or under ``dense_max_tokens`` over
+    experts of whole tiles is handed its leaves in the stack, runs the
+    kernel on them and counts what it read; cut out by the caller, the same
+    step keeps ``experts_dense`` and says why; the two agree."""
+    spec = _moe_spec(intermediate_size=128)
+    stack = _tile_stack(rng)
+    names = moe_mod.EXPERT_LEAVES[:3]
+    assert moe_mod.stack_leaves(spec, 4, stack) == names
+    assert moe_mod.stack_leaves(spec, spec.dense_max_tokens, stack) == names
+    x = jnp.asarray(rng.normal(size=(4, 1, 128)).astype(np.float32))
+    # every row routes where row 0 does: two of the four experts are read
+    router = jnp.zeros((128, 4), jnp.float32).at[:, 1].set(0.01).at[
+        :, 3].set(0.02)
+    x = jnp.abs(x)
+    li = 2
+    notes, tally_k, tally_d = set(), [], []
+    with kernel_mode.recording(notes):
+        want = moe_mod.moe_block(
+            spec, x, {"router": router,
+                      **{k: a[li] for k, a in stack.items()}}, tally=tally_d)
+        got = moe_mod.moe_block(
+            spec, x, {"router": router,
+                      **{k: moe_mod.LayerOfStack(a, li)
+                         for k, a in stack.items()}}, tally=tally_k)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=1e-5)
+    assert notes == {("moe_decode", "pallas-interpret", "pieces=1 of 128"),
+                     ("moe_decode", "xla", "the caller cut the layer out")}
+    assert np.asarray(tally_k[0]).tolist() == [2, 8, 2]     # read = touched
+    assert np.asarray(tally_d[0]).tolist() == [2, 8, 4]     # read = held
+
+
+def _quantized(stack):
+    from neuronx_distributed_inference_tpu.modules.quantization import (
+        QuantSpec, quantize_tensor)
+    return {k: jax.tree.map(jnp.asarray,
+                            quantize_tensor(np.asarray(a), QuantSpec()))
+            for k, a in stack.items()}
+
+
+@pytest.mark.parametrize("over, quantized, mesh_shape, why", [
+    ({}, True, None, "quantized experts"),
+    ({}, False, dict(ep=2), "mesh axes wider than one: ep"),
+    ({}, False, dict(tp=2), "mesh axes wider than one: tp"),
+    (dict(input_scaled=True), False, None,
+     "input_scaled routing scales the expert input"),
+    (dict(tkg_experts_local=True), False, None,
+     "tkg_experts_local re-lays the experts for decode"),
+    (dict(expert_bias=True), False, None, "per-expert biases"),
+    (dict(glu_style="oss_clamp"), False, None, "glu oss_clamp/silu"),
+    (dict(intermediate_size=32), False, None,
+     "experts of 128 x 32 are not whole 128-lane tiles"),
+], ids=["quantized", "ep2", "tp2", "input_scaled", "tkg_experts_local",
+        "biases", "oss_clamp", "toy-widths"])
+def test_what_the_few_token_kernel_declines_keeps_the_dense_path(
+        rng, cpu_devices, monkeypatch, over, quantized, mesh_shape, why):
+    """Each refusal of ``moe_decode.declined`` leaves the step's leaves
+    sliced (``stack_leaves`` names none), runs ``experts_dense`` and notes
+    why."""
+    spec = _moe_spec(**{**dict(intermediate_size=128), **over})
+    stack = _tile_stack(rng, i=spec.intermediate_size)
+    if spec.expert_bias:
+        stack.update({k: jnp.zeros((3, 4, 128), jnp.float32)
+                      for k in moe_mod.EXPERT_LEAVES[3:]})
+    if quantized:
+        stack = _quantized(stack)
+    x = jnp.asarray(rng.normal(size=(2, 1, 128)).astype(np.float32))
+    router = jnp.asarray(rng.normal(size=(128, 4)).astype(np.float32))
+    mesh = contextlib.nullcontext()
+    if mesh_shape:
+        n = int(np.prod(list(mesh_shape.values())))
+        mesh = jax.sharding.set_mesh(
+            build_mesh(MeshConfig(**mesh_shape), cpu_devices[:n]))
+    calls = []
+    dense = moe_mod.experts_dense
+    monkeypatch.setattr(moe_mod, "experts_dense",
+                        lambda *a, **kw: calls.append(1) or dense(*a, **kw))
+    notes = set()
+    with kernel_mode.recording(notes), mesh:
+        assert moe_mod.stack_leaves(spec, 2, stack) == ()
+        jax.eval_shape(
+            lambda x, lw: moe_mod.moe_block(spec, x, lw, phase="decode"), x,
+            {"router": router, **jax.tree.map(lambda a: a[1], stack)})
+    assert calls == [1]
+    assert notes == {("moe_decode", "xla", why)}
 
 
 OLMOE_TOY = dict(model_type="olmoe", hidden_size=64, intermediate_size=128,
@@ -389,3 +488,54 @@ def test_the_paged_step_notes_how_its_ragged_path_reads(serve, rows, width,
             jax.ShapeDtypeStruct((2,), jnp.uint32))
     got = {(p, why) for site, p, why in notes if site == "moe_ragged"}
     assert got == ({want} if want else set())
+
+
+def test_the_scanned_decode_step_reads_the_touched_experts(monkeypatch):
+    """ISSUE 37 on the layer scan: an OLMoE toy with experts of whole
+    128-lane tiles runs its T = 1 paged step on the kernel (interpret mode
+    here), the layer a traced scalar, and gives the logits of the same step
+    on ``experts_dense``; the tally, a row a layer of the scan summed,
+    counts the same routing and fewer experts read."""
+    from neuronx_distributed_inference_tpu.ops import moe_decode
+    fam = get_family("olmoe")
+    tcfg = TpuConfig(batch_size=4, seq_len=96, dtype="float32",
+                     enable_bucketing=True, context_encoding_buckets=[32],
+                     is_block_kv_layout=True, pa_block_size=8,
+                     is_prefix_caching=False, output_logits=True)
+    hf = dict(OLMOE_TOY, hidden_size=128, num_experts=8)
+    app = PagedCausalLMApplication(None, fam.config_cls(tcfg, **hf), fam,
+                                   mesh=mesh_from_config(tcfg))
+    app.init_random_weights(5).init_cache()
+    assert app.params["layers"]["expert_gate"].shape == (3, 8, 128, 128)
+    i32 = jnp.int32
+    ids = jnp.asarray([[5], [9], [5], [5]], i32)
+    pos = jnp.zeros((4, 1), i32)
+    # rows 0 and 1 live on blocks 1 and 2; rows 2 and 3 are pads: clones of
+    # row 0 that write nowhere
+    slots = jnp.asarray([[8], [16], [-1], [-1]], i32)
+    table = jnp.zeros((4, 12), i32).at[:, 0].set(jnp.asarray([1, 2, 1, 1]))
+    last = jnp.zeros((4,), i32)
+
+    def step():
+        notes = set()
+        with kernel_mode.recording(notes), jax.sharding.set_mesh(app.mesh):
+            out = model_base.paged_forward_step(
+                app.spec, app.tpu_config, app.params, app.cache, ids, pos,
+                slots, table, last, None, jnp.zeros((2,), jnp.uint32))
+        return out, {n[1:] for n in notes if n[0] == "moe_decode"}
+
+    got, notes = step()
+    assert notes == {("pallas-interpret", "pieces=1 of 128")}
+    monkeypatch.setattr(moe_decode, "declined", lambda moe, wg: "forced")
+    want, notes = step()
+    assert notes == {("xla", "forced")}
+    np.testing.assert_allclose(np.asarray(got["logits"]),
+                               np.asarray(want["logits"]), atol=2e-5, rtol=0)
+    touched, assigned, read = np.asarray(got["moe_tally"]).tolist()
+    assert np.asarray(want["moe_tally"]).tolist() == [touched, assigned,
+                                                      3 * 8]
+    assert assigned == 3 * 2 * 2             # layers x live rows x top-k
+    assert 0 < touched <= read < 3 * 8
+    # the pads are clones of row 0: the same logits, bit for bit
+    np.testing.assert_array_equal(np.asarray(got["logits"])[2],
+                                  np.asarray(got["logits"])[0])

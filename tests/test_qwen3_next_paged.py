@@ -578,7 +578,46 @@ def test_a_decode_steps_routing_is_counted_on_the_device(ref, gate_weights):
     assert st["moe_experts_touched"] == touched > 0
     series = {s["labels"]["count"]: s["value"] for s in
               reg.snapshot()["metrics"][tmetrics.MOE_EXPERTS_TOTAL]["series"]}
-    assert series == {"touched": touched, "slots": 80, "assigned": assigned}
+    # the toy's experts (32 x 16) are no whole tiles: the dense path, which
+    # reads every held expert of every layer
+    assert st["moe_experts_read"] == 80 and st["moe_experts_skipped"] == 0
+    assert series == {"touched": touched, "slots": 80, "assigned": assigned,
+                      "read": 80}
+
+
+#: the toy with experts of whole 128-lane tiles: what the few-token kernel
+#: (ops/moe_decode.py) takes, on the recurrent walk's static layer loop
+HF_TILES = dict(HF, hidden_size=128, moe_intermediate_size=128)
+
+
+def test_the_decode_step_reads_the_touched_experts_of_the_share(ref):
+    """ISSUE 37 on the recurrent walk: served with experts of whole tiles,
+    a decode step and a one-row chunk of 32 tokens run the kernel on the
+    stacked leaves (interpret mode here), the served logits still equal the
+    reference's at every position, and the tally's third count says what
+    was read: at least what the live row's routing touched (pad rows clone
+    row 0), and fewer than the share holds."""
+    w = weights.make_weights(ref.weight_shapes(HF_TILES), seed=2**31 + 37)
+    app = _app(ref, w, hf=HF_TILES)
+    ad = PagedEngineAdapter(app)
+    tap = LogitTap(app)
+    stream = [ad.add_requests([7], [P69])[7]]
+    steps = 6
+    for _ in range(steps):
+        stream.append(ad.step([7])[7])
+    fed = P69 + stream[:-1]
+    want = np.asarray(ref.forward(HF_TILES, w, jnp.asarray([fed])))[0]
+    np.testing.assert_allclose(tap.logits(7, len(fed)), want, atol=ATOL,
+                               rtol=1e-4)
+    kernels = {(k["site"], k["path"]): k["reason"]
+               for k in app.warmup_state()["kernels"]}
+    assert kernels[("moe_decode", "pallas-interpret")] == "pieces=1 of 128"
+    assert ("moe_decode", "xla") not in kernels
+    st = ad.host_stats
+    slots = 4 * 4 * steps
+    assert st["moe_expert_slots"] == slots
+    assert st["moe_experts_read"] + st["moe_experts_skipped"] == slots
+    assert 0 < st["moe_experts_touched"] <= st["moe_experts_read"] < slots
 
 
 def _config(serve=None, **hf):
