@@ -1426,38 +1426,52 @@ class PagedCausalLMApplication(CausalLMApplication):
         of each row of a dispatch with FEWER rows than slots (the one-row
         chunk); a full-batch dispatch lays its rows out in slot order and
         passes none (``model_base.run_layers_ssm``)."""
-        with self._run_span("paged", input_ids.shape[0]):
-            fn = self.get_compiled("paged_forward")
-            aids = self._lora_adapter_ids(adapter_ids)
-            if input_ids.shape[1] == 1 and not isinstance(input_ids,
-                                                          jax.Array):
-                # the decode step's ids are placed as the step hands them
-                # on (``out["next_ids"]``: committed, replicated), so a
-                # step fed from the host and one fed the previous step's
-                # output on the device are ONE executable
-                input_ids = jax.device_put(input_ids,
-                                           self._decode_ids_sharding)
-            # one jitted graph serves every paged call; the shape signature
-            # (prefill width x table width) is what distinguishes compiles
-            self._note_jit("paged", input_ids.shape[1],
-                           (input_ids.shape, block_table.shape,
-                            aids is not None))
-            if sampling_params is None:
-                sampling_params = self._default_sampling_params(
-                    input_ids.shape[0])
-            seeds = self._stream_seeds(row_seeds, input_ids.shape[0])
-            kw = {"row_seeds": seeds} if seeds is not None else {}
-            if aids is not None:
-                kw["adapter_ids"] = aids
-            if state_slots is not None:
-                kw["state_slots"] = jnp.asarray(state_slots, jnp.int32)
-            with self._mesh_ctx():
-                out = fn(self.params, self.cache, jnp.asarray(input_ids),
-                         jnp.asarray(position_ids),
-                         jnp.asarray(slot_mapping), jnp.asarray(block_table),
-                         jnp.asarray(last_idx),
-                         sampling_params, self._next_rng(), **kw)
-            self.cache = out["cache"]
+        rec = trace_mod.get_recorder()
+        # the phases of the host's side, as slices under run.paged:
+        # prep.inputs (entry to the last host->device placement), prep.rng,
+        # prep.enqueue (the jit call to the return of its dispatch). The
+        # mesh context opens where it always did, before the placements of
+        # the call's own arguments, and closes after the call
+        with self._run_span("paged", input_ids.shape[0]), \
+                contextlib.ExitStack() as mesh:
+            with rec.span("prep.inputs", cat="app"):
+                fn = self.get_compiled("paged_forward")
+                aids = self._lora_adapter_ids(adapter_ids)
+                if input_ids.shape[1] == 1 and not isinstance(input_ids,
+                                                              jax.Array):
+                    # the decode step's ids are placed as the step hands
+                    # them on (``out["next_ids"]``: committed, replicated),
+                    # so a step fed from the host and one fed the previous
+                    # step's output on the device are ONE executable
+                    input_ids = jax.device_put(input_ids,
+                                               self._decode_ids_sharding)
+                # one jitted graph serves every paged call; the shape
+                # signature (prefill width x table width) is what
+                # distinguishes compiles
+                self._note_jit("paged", input_ids.shape[1],
+                               (input_ids.shape, block_table.shape,
+                                aids is not None))
+                if sampling_params is None:
+                    sampling_params = self._default_sampling_params(
+                        input_ids.shape[0])
+                seeds = self._stream_seeds(row_seeds, input_ids.shape[0])
+                kw = {"row_seeds": seeds} if seeds is not None else {}
+                if aids is not None:
+                    kw["adapter_ids"] = aids
+                if state_slots is not None:
+                    kw["state_slots"] = jnp.asarray(state_slots, jnp.int32)
+                mesh.enter_context(self._mesh_ctx())
+                ids = jnp.asarray(input_ids)
+                pos = jnp.asarray(position_ids)
+                slots = jnp.asarray(slot_mapping)
+                table = jnp.asarray(block_table)
+                last = jnp.asarray(last_idx)
+            with rec.span("prep.rng", cat="app"):
+                rng = self._next_rng()
+            with rec.span("prep.enqueue", cat="app"):
+                out = fn(self.params, self.cache, ids, pos, slots, table,
+                         last, sampling_params, rng, **kw)
+                self.cache = out["cache"]
         return out
 
     def _dummy_state_slots(self, rows: int):

@@ -284,8 +284,9 @@ def _async_fetch(x):
 
 class _AdapterTelemetry:
     """Shared engine-adapter instrumentation: TTFT / per-step decode latency
-    histograms, live-batch + pad-waste accounting, pipeline depth/overlap/
-    steps-per-fetch, one request span per seq_id. Host-side only (measures
+    histograms, live-batch + pad-waste accounting, pipeline overlap/drains/
+    steps-per-fetch, the gap between decode steps, one request span per
+    seq_id. Host-side only (measures
     at the adapter boundary); every method is a cheap no-op while telemetry
     is disabled."""
 
@@ -410,11 +411,6 @@ class _AdapterTelemetry:
             tmetrics.ragged_pad_waste_gauge(reg).set(
                 1.0 - real_tokens / padded_tokens, engine=self.engine)
 
-    def on_dispatch(self, depth: int):
-        reg = self.registry
-        if reg.enabled:
-            tmetrics.dispatch_depth_gauge(reg).set(depth, engine=self.engine)
-
     def on_overlap(self):
         reg = self.registry
         if reg.enabled:
@@ -436,15 +432,17 @@ class _AdapterTelemetry:
             tmetrics.pipeline_drains_counter(reg).inc(engine=self.engine,
                                                       cause=cause)
 
-    def on_fetch(self, steps: int, overlap_s: Optional[float] = None):
+    def on_fetch(self, steps: int):
         reg = self.registry
-        if not reg.enabled:
-            return
-        tmetrics.steps_per_fetch_histogram(reg).observe(steps,
-                                                        engine=self.engine)
-        if overlap_s is not None:
-            tmetrics.host_overlap_histogram(reg).observe(overlap_s,
-                                                         engine=self.engine)
+        if reg.enabled:
+            tmetrics.steps_per_fetch_histogram(reg).observe(
+                steps, engine=self.engine)
+
+    def on_gap(self, seconds: float, behind: str):
+        reg = self.registry
+        if reg.enabled:
+            tmetrics.decode_gap_histogram(reg).observe(
+                seconds, engine=self.engine, behind=behind)
 
     def on_release(self, seq_ids: Sequence[int]):
         # pop unconditionally: requests admitted while telemetry was live
@@ -813,6 +811,11 @@ class _EngineAdapterBase:
         # changed under it): named by whoever changed it, counted where
         # the step is drained
         self._drain_cause: Optional[str] = None
+        self._drains = 0               # pipeline drains, every cause
+        # the last point at which a decode step's tokens became
+        # host-visible: (instant, the states live in it, prefill
+        # dispatches and drains counted by then)
+        self._gap_mark: Optional[tuple] = None
         self._ready: Dict[int, int] = {}
         self._scratch = None
         self._spec = None              # SpeculativeDecodePath (paged only)
@@ -836,6 +839,18 @@ class _EngineAdapterBase:
             # the live set changed under them, by what changed it
             "overlapped_dispatches": 0,
             **{f"pipeline_drains_{c}": 0 for c in _DRAIN_CAUSES},
+            # the intervals between two decode steps' tokens becoming
+            # host-visible while a sequence was live at both (_note_gap):
+            # all of them, those in which prefill dispatches were issued
+            # (and how many), those of a second or more (and how many of
+            # THOSE saw prefill: a cell's ramp-up is one or two, a hole
+            # with nothing dispatched is none), the longest
+            "decode_gaps": 0, "decode_gap_s": 0.0,
+            "decode_gaps_behind_prefill": 0,
+            "decode_gap_s_behind_prefill": 0.0,
+            "prefill_dispatches_in_gaps": 0,
+            "decode_gaps_over_1s": 0, "decode_gaps_over_1s_behind_prefill": 0,
+            "decode_gap_max_s": 0.0,
             "prefill_dispatches": 0, "prefill_blocking_fetches": 0,
             "prefill_blocked_s": 0.0, "prefill_real_tokens": 0,
             "prefill_padded_tokens": 0}
@@ -917,6 +932,44 @@ class _EngineAdapterBase:
                        ("moe_expert_slots", slots)):
             st[key] = st.get(key, 0) + n
         self.telemetry.on_moe_tally(touched, slots, assigned, read)
+
+    def _note_gap(self, states: Sequence[_SeqState]):
+        """A decode step's tokens for ``states`` just became host-visible.
+        If one of them was live at the previous such point too, the
+        interval is a gap between tokens that a client saw: count it, by
+        what it waited behind — ``prefill`` if prefill dispatches were
+        issued in between (how many: the chain's length), else ``drain`` if
+        the in-flight step was drained, else ``none``. Always on: one clock
+        read and a few dict adds. A state that left never comes back (a
+        replayed row is a new one), so no gap spans an empty live set."""
+        now = time.perf_counter()
+        st = self.host_stats
+        mark = self._gap_mark
+        self._gap_mark = (now, states, st["prefill_dispatches"],
+                          self._drains)
+        if mark is None or not states:
+            return
+        t_prev, before, prefills, drains = mark
+        if not before or states[0] is not before[0]:
+            # else: the live set as it was, the usual case
+            was = {id(x) for x in before}
+            if not any(id(x) in was for x in states):
+                return
+        gap = now - t_prev
+        chain = st["prefill_dispatches"] - prefills
+        st["decode_gaps"] += 1
+        st["decode_gap_s"] += gap
+        if chain:
+            st["decode_gaps_behind_prefill"] += 1
+            st["decode_gap_s_behind_prefill"] += gap
+            st["prefill_dispatches_in_gaps"] += chain
+        if gap >= 1.0:
+            st["decode_gaps_over_1s"] += 1
+            st["decode_gaps_over_1s_behind_prefill"] += bool(chain)
+        if gap > st["decode_gap_max_s"]:
+            st["decode_gap_max_s"] = gap
+        self.telemetry.on_gap(gap, "prefill" if chain else
+                              "drain" if self._drains > drains else "none")
 
     # -- public decode surface ---------------------------------------------
     def step(self, seq_ids: Optional[Sequence[int]] = None) -> Dict[int, int]:
@@ -1041,11 +1094,12 @@ class _EngineAdapterBase:
         if not live:
             return self._drain_ready()
         t0 = time.perf_counter()
-        live = self._grow_for_step(live)
-        if not live:
-            return self._drain_ready()
-        scr = self._scratch_for(live)
-        scr.fill(self)
+        with _get_recorder().span("dispatch.build", cat="adapter"):
+            live = self._grow_for_step(live)
+            if not live:
+                return self._drain_ready()
+            scr = self._scratch_for(live)
+            scr.fill(self)
         cache_before = self.app.cache
         try:
             if _FAULTS.active:
@@ -1064,6 +1118,7 @@ class _EngineAdapterBase:
                 self._decode_failure_msg + "; positions were not advanced",
                 phase="decode", seq_ids=tuple(live),
                 retry_safe=self.app.cache is cache_before)) from e
+        self._note_gap(tuple(self.seqs[s] for s in live))
         res = self._drain_ready()    # first tokens of finished prefills
         for i, s in enumerate(live):
             st = self.seqs[s]
@@ -1108,23 +1163,25 @@ class _EngineAdapterBase:
             ready.update(self._retire_or_abort([prev]))
             prev = None
         t0 = time.perf_counter()
-        try:
-            live = self._grow_for_step(live)
-        except ServingError:
-            self._inflight = prev          # growth rolled itself back
-            raise
-        if not live:
-            self._inflight = prev
-            return ready
-        if prev is not None and not self._matches(prev, live):
-            # preemption shrank the batch mid-call: drain the old
-            # composition's dispatch before re-padding for the new one
-            self._note_drain("preempt")
-            ready.update(self._retire_or_abort([prev]))
-            prev = None
-        scr = self._scratch_for(live)
-        scr.fill(self, need_tokens=prev is None)
-        toks_dev = None if prev is None else self._feedback_tokens(prev, scr)
+        with _get_recorder().span("dispatch.build", cat="adapter"):
+            try:
+                live = self._grow_for_step(live)
+            except ServingError:
+                self._inflight = prev      # growth rolled itself back
+                raise
+            if not live:
+                self._inflight = prev
+                return ready
+            if prev is not None and not self._matches(prev, live):
+                # preemption shrank the batch mid-call: drain the old
+                # composition's dispatch before re-padding for the new one
+                self._note_drain("preempt")
+                ready.update(self._retire_or_abort([prev]))
+                prev = None
+            scr = self._scratch_for(live)
+            scr.fill(self, need_tokens=prev is None)
+            toks_dev = (None if prev is None
+                        else self._feedback_tokens(prev, scr))
         cache_before = self.app.cache
         try:
             if _FAULTS.active:
@@ -1158,7 +1215,6 @@ class _EngineAdapterBase:
             self.telemetry.on_overlap()
             ready.update(self._retire_or_abort([prev, rec]))
         self._inflight = rec
-        self.telemetry.on_dispatch(1)
         return ready
 
     def _matches(self, rec: _Inflight, live: Sequence[int]) -> bool:
@@ -1184,12 +1240,12 @@ class _EngineAdapterBase:
                for s, st in zip(rec.live, rec.states)):
             self._inflight = None
             self._drain_cause = None
-            self.telemetry.on_dispatch(0)
         elif self._drain_cause is None:
             self._drain_cause = cause
 
     def _note_drain(self, default: str = "liveset"):
         cause, self._drain_cause = self._drain_cause or default, None
+        self._drains += 1
         self.host_stats[f"pipeline_drains_{cause}"] += 1
         self.telemetry.on_drain(cause)
 
@@ -1215,20 +1271,22 @@ class _EngineAdapterBase:
         pipelined path) and apply the deferred host bookkeeping. Raises
         the raw fetch failure — callers route it through
         :meth:`_abort_pipeline`."""
-        if _FAULTS.active:
-            _FAULTS.fire("pipeline_flush")
-        overlap = time.perf_counter() - rec.t_dispatch
-        new = self._fetch_rows(rec.out, rec.b, rec.rows)
-        res = {}
-        for i, (s, st) in enumerate(zip(rec.live, rec.states)):
-            if self.seqs.get(s) is not st:
-                continue               # released/preempted while in flight
-            tok = int(new[i, 0])
-            self._append_token(st, tok)
-            res[s] = tok
-        self.telemetry.on_step(list(res), rec.t_dispatch, padded=rec.pad_to)
-        self.telemetry.on_fetch(1, overlap_s=overlap)
-        self.telemetry.on_dispatch(0)
+        with _get_recorder().span("dispatch.retire", cat="adapter",
+                                  engine=self.engine_name, rows=rec.b):
+            if _FAULTS.active:
+                _FAULTS.fire("pipeline_flush")
+            new = self._fetch_rows(rec.out, rec.b, rec.rows)
+            self._note_gap(rec.states)
+            res = {}
+            for i, (s, st) in enumerate(zip(rec.live, rec.states)):
+                if self.seqs.get(s) is not st:
+                    continue           # released/preempted while in flight
+                tok = int(new[i, 0])
+                self._append_token(st, tok)
+                res[s] = tok
+            self.telemetry.on_step(list(res), rec.t_dispatch,
+                                   padded=rec.pad_to)
+            self.telemetry.on_fetch(1)
         return res
 
     def _retire_or_abort(self, records: List[Optional[_Inflight]]
@@ -1258,7 +1316,6 @@ class _EngineAdapterBase:
                 if self.seqs.get(s) is st:
                     st.position -= 1
             self._unwind_inflight_growth(rec)
-        self.telemetry.on_dispatch(0)
         self.telemetry.on_step_failure("decode", self._tenant_of(seq_ids))
         raise _trace_error(StepFailure(
             "pipelined decode fetch failed; every in-flight lookahead step "

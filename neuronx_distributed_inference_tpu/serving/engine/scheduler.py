@@ -676,14 +676,14 @@ class ServingEngine:
 
     def _route(self, res) -> int:
         n = 0
-        for sid, toks in res.items():
-            toks = toks if isinstance(toks, list) else [toks]
-            n += self._deliver(sid, toks)
-        if n:
-            rec = _get_recorder()
-            if rec.enabled:
-                rec.instant("stream.deliver", cat="engine", tokens=n,
-                            seq_ids=[int(s) for s in res])
+        rec = _get_recorder()
+        with rec.span("deliver.tokens", cat="engine"):
+            for sid, toks in res.items():
+                toks = toks if isinstance(toks, list) else [toks]
+                n += self._deliver(sid, toks)
+        if n and rec.enabled:
+            rec.instant("stream.deliver", cat="engine", tokens=n,
+                        seq_ids=[int(s) for s in res])
         return n
 
     def _deliver(self, sid: int, toks: List[int]) -> int:
@@ -879,6 +879,9 @@ class ServingEngine:
             "trace": {
                 "enabled": rec.enabled,
                 "events": rec.tail(trace_tail),
+                # slices that ran long, kept beside the ring (its wrap
+                # does not evict them): telemetry/trace.py STALL_SECONDS
+                "stalls": rec.stalls(),
                 "dropped": rec.dropped,
                 "capacity": rec.capacity,
             },

@@ -13,7 +13,8 @@ from .registry import DEFAULT_LATENCY_BUCKETS
 # -- serving adapters (serving.py) -----------------------------------------
 # engine label: "cb" (ContinuousBatchingAdapter) | "paged" (PagedEngineAdapter)
 REQUEST_TTFT_SECONDS = "nxdi_request_ttft_seconds"
-DECODE_STEP_SECONDS = "nxdi_decode_step_seconds"      # TPOT per step() call
+DECODE_STEP_SECONDS = "nxdi_decode_step_seconds"      # dispatch -> retire
+DECODE_GAP_SECONDS = "nxdi_decode_gap_seconds"        # engine, behind
 REQUEST_TPOT_SECONDS = "nxdi_request_tpot_seconds"    # per-request mean TPOT
 LIVE_BATCH_SIZE = "nxdi_live_batch_size"
 LIVE_ROWS_TOTAL = "nxdi_live_rows_total"              # phase=prefill|decode
@@ -29,8 +30,6 @@ QUEUE_DEPTH = "nxdi_queue_depth"                        # tenant
 QUEUE_WAIT_SECONDS = "nxdi_queue_wait_seconds"          # tenant, outcome
 
 # -- decode pipeline (serving.py) --------------------------------------------
-DISPATCH_DEPTH = "nxdi_dispatch_depth"                  # engine
-HOST_OVERLAP_SECONDS = "nxdi_host_overlap_seconds"      # engine
 STEPS_PER_FETCH = "nxdi_steps_per_fetch"                # engine
 OVERLAPPED_DISPATCHES_TOTAL = "nxdi_overlapped_dispatches_total"   # engine
 MOE_EXPERTS_TOTAL = "nxdi_moe_experts_total"            # engine, count
@@ -66,6 +65,8 @@ GENERATED_TOKENS_TOTAL = "nxdi_generated_tokens_total"      # engine=cb|paged
 
 # -- host timeline (telemetry/trace.py, serving/engine/frontend.py) ---------
 HOST_SECONDS_TOTAL = "nxdi_host_seconds_total"             # span, under
+HOST_STALL_SECONDS_TOTAL = "nxdi_host_stall_seconds_total"  # span
+HOST_STALLS_TOTAL = "nxdi_host_stalls_total"               # span
 SSE_LAG_SECONDS = "nxdi_sse_lag_seconds"
 
 # -- jit / bucketing (models/application.py, modules/autobucketing.py) ------
@@ -145,8 +146,22 @@ def ttft_histogram(reg):
 def decode_step_histogram(reg):
     return reg.histogram(
         DECODE_STEP_SECONDS,
-        "Wall time of one engine decode step() call (s)",
+        "Host wall time from the start of a decode step's dispatch to the "
+        "moment its tokens are booked (s): one step() call eager, about "
+        "two steps with a step of lookahead in flight (the gap between "
+        "tokens is nxdi_decode_gap_seconds)",
         labels=("engine",), buckets=DEFAULT_LATENCY_BUCKETS)
+
+
+def decode_gap_histogram(reg):
+    return reg.histogram(
+        DECODE_GAP_SECONDS,
+        "Interval between two consecutive points at which a decode step's "
+        "tokens became host-visible while a sequence was live at both: the "
+        "engine's own gap between tokens (s); behind=prefill (a prefill "
+        "dispatch was issued in between) | drain (the in-flight step was "
+        "drained) | none",
+        labels=("engine", "behind"), buckets=DEFAULT_LATENCY_BUCKETS)
 
 
 def tpot_histogram(reg):
@@ -211,23 +226,6 @@ def prefill_pad_waste_histogram(reg):
         "admission of skewed prompts pushes this toward 1)",
         labels=("engine",),
         buckets=(0.05, 0.1, 0.2, 0.4, 0.6, 0.8, 0.95))
-
-
-def dispatch_depth_gauge(reg):
-    return reg.gauge(
-        DISPATCH_DEPTH,
-        "Device decode dispatches in flight whose tokens have not been "
-        "fetched to the host yet (0 = eager; pipeline_depth bounds it)",
-        labels=("engine",))
-
-
-def host_overlap_histogram(reg):
-    return reg.histogram(
-        HOST_OVERLAP_SECONDS,
-        "Host wall time between a pipelined decode dispatch and its "
-        "deferred token fetch — bookkeeping overlapped with device "
-        "compute (s)",
-        labels=("engine",), buckets=DEFAULT_LATENCY_BUCKETS)
 
 
 def overlapped_dispatches_counter(reg):
@@ -378,11 +376,31 @@ def host_seconds_counter(reg):
     return reg.counter(
         HOST_SECONDS_TOTAL,
         "Host seconds spent inside flight-recorder slices, by stable span "
-        "name (pass.*, loop.*, run.*, fetch.tokens, dispatch.*) and the "
+        "name (pass.*, loop.*, run.*, prep.*, fetch.tokens, dispatch.*, "
+        "deliver.tokens) and the "
         "span open around it on the same thread (under; empty at the "
         "top); nested spans each count their own whole duration, so a "
         "span's self time is its seconds minus those under it",
         labels=("span", "under"))
+
+
+def host_stall_seconds_counter(reg):
+    return reg.counter(
+        HOST_STALL_SECONDS_TOTAL,
+        "Host seconds of flight-recorder slices that ran 2 s or longer "
+        "(telemetry/trace.py STALL_SECONDS), by the INNERMOST such span; a "
+        "span around a counted stall adds only what the stall does not "
+        "explain, pass.* and loop.idle never count",
+        labels=("span",))
+
+
+def host_stalls_counter(reg):
+    return reg.counter(
+        HOST_STALLS_TOTAL,
+        "Flight-recorder slices counted in nxdi_host_stall_seconds_total; "
+        "each is kept, with the names open around it, in the recorder's "
+        "stall list (/v1/debug/state trace.stalls)",
+        labels=("span",))
 
 
 def sse_lag_histogram(reg):

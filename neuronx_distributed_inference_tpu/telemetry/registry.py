@@ -135,6 +135,19 @@ class Counter(_Metric):
         with self._lock:
             self._series[key] = self._series.get(key, 0.0) + amount
 
+    def child(self, **labels):
+        """``inc`` of ONE series with its labels resolved once: a function
+        of the amount, for a hot path that adds to the same series over and
+        over (the flight recorder, where every slice closes)."""
+        key = _labels_key(self.label_names, labels)
+
+        def add(amount: float) -> None:
+            if amount < 0:
+                raise ValueError("counters only go up")
+            with self._lock:
+                self._series[key] = self._series.get(key, 0.0) + amount
+        return add
+
     def get(self, **labels) -> float:
         return self._series.get(_labels_key(self.label_names, labels), 0.0)
 

@@ -21,6 +21,16 @@ Event **names are a stable contract** exactly like the metric names in
 The canonical set lives in :data:`ENGINE_PASS_PHASES` /
 :data:`ADAPTER_EVENTS` / :data:`APP_EVENTS`.
 
+A span that ran long leaves a record: where a slice closes at
+:data:`STALL_SECONDS` or more, less the stalls already counted inside it,
+it is a stall of the INNERMOST such span — counted in
+``nxdi_host_stall_seconds_total{span}`` / ``nxdi_host_stalls_total{span}``
+and copied, with the names open around it, into a list of at most
+:data:`STALL_RECORDS` that the ring's wrap does not evict
+(:meth:`FlightRecorder.stalls`). The four ``pass.*`` phases and
+``loop.idle`` are never stalls: they are as long as their work, or as the
+quiet, is.
+
 One timeline with the profiler: while the recorder is enabled every
 :meth:`FlightRecorder.span` also enters a ``jax.profiler.TraceAnnotation``
 of the same name (stat ``pass_id``), so inside a ``jax.profiler`` session
@@ -61,7 +71,7 @@ from .registry import get_registry
 __all__ = [
     "ENGINE_PASS_PHASES", "ENGINE_EVENTS", "ADAPTER_EVENTS", "APP_EVENTS",
     "LOOP_EVENTS", "FLEET_EVENTS", "DEGRADE_EVENTS", "WARMUP_EVENTS",
-    "EVENT_NAMES",
+    "EVENT_NAMES", "STALL_SECONDS", "STALL_RECORDS",
     "FlightRecorder", "NullFlightRecorder", "NULL_RECORDER",
     "get_recorder", "set_recorder", "enable_recorder", "disable_recorder",
 ]
@@ -84,7 +94,11 @@ LOOP_EVENTS = ("loop.yield", "loop.idle")
 #:                              carries the adapter's live admission-
 #:                              headroom estimate (free_blocks,
 #:                              headroom_tokens, free_slots)
-ENGINE_EVENTS = ("stream.deliver", "admission.headroom")
+#:   ``deliver.tokens``         a pass's tokens routed to their streams,
+#:                              with the finishes and releases that
+#:                              follow (a slice, under ``pass.dispatch``;
+#:                              ``stream.deliver`` is its instant)
+ENGINE_EVENTS = ("stream.deliver", "admission.headroom", "deliver.tokens")
 
 #: Adapter boundary events (serving/adapter.py + serving/ragged/path.py).
 #: STABLE names.
@@ -96,18 +110,36 @@ ENGINE_EVENTS = ("stream.deliver", "admission.headroom")
 #:                              per-row ``seq_ids`` and ``traces``)
 #:   ``fetch.tokens``           a blocking device->host token fetch
 #:   ``preempt``                one sequence evicted (any reason)
+#:   ``dispatch.build``         a decode dispatch's host inputs: growing
+#:                              the block tables, the scratch and its
+#:                              fill, the fed-back tokens (a slice, under
+#:                              ``pass.dispatch``)
+#:   ``dispatch.retire``        one decode step's tokens made host-visible
+#:                              and booked: ``fetch.tokens`` closes under
+#:                              it, its self time is the token append, the
+#:                              stop checks and the telemetry hooks
 ADAPTER_EVENTS = ("dispatch.decode", "dispatch.decode_loop",
                   "dispatch.prefill_chunk", "dispatch.ragged",
-                  "fetch.tokens", "preempt")
+                  "fetch.tokens", "preempt", "dispatch.build",
+                  "dispatch.retire")
 
 #: Application events (models/application.py). STABLE names.
 #:   ``run.<kind>``   host window of one _run_* call (entry -> dispatch
 #:                    return, RNG split included; asynchronous — excludes
-#:                    device wait, which is ``fetch.tokens``)
+#:                    device wait, which is ``fetch.tokens``). One of the
+#:                    eight kinds below and nothing else starts with
+#:                    ``run.``: a reader may sum the prefix
+#:   ``prep.inputs``  of ``run.paged``: entry to the last host->device
+#:                    placement (ids, sampling params, seeds, positions,
+#:                    slot mapping, block table, last_idx, state slots)
+#:   ``prep.rng``     of ``run.paged``: the RNG split (two helper programs)
+#:   ``prep.enqueue`` of ``run.paged``: the jit call to the return of its
+#:                    asynchronous dispatch
 #:   ``compile``      first-time (kind, bucket, shape) graph build
 APP_EVENTS = ("run.prefill", "run.decode", "run.decode_loop", "run.paged",
               "run.paged_loop", "run.ragged", "run.spec_draft",
-              "run.spec_verify", "compile")
+              "run.spec_verify", "compile", "prep.inputs", "prep.rng",
+              "prep.enqueue")
 
 #: Fleet-layer events (serving/fleet/). STABLE names.
 #:   ``fleet.route``    one request routed to a replica (request_id,
@@ -167,6 +199,15 @@ EVENT_NAMES = (ENGINE_PASS_PHASES + LOOP_EVENTS + ENGINE_EVENTS
                + DEGRADE_EVENTS + WARMUP_EVENTS)
 _EVENT_SET = frozenset(EVENT_NAMES)
 
+#: A slice this long (seconds) is a stall; the longest legitimate single
+#: dispatch the benchmark's cells make is a 0.90 s prefill pack.
+STALL_SECONDS = 2.0
+#: How many stalls the recorder keeps beside its ring.
+STALL_RECORDS = 64
+#: Never stalls: a pass phase is as long as the work inside it (which is
+#: what gets named), the idle nap as long as the quiet.
+_NEVER_STALLS = frozenset(ENGINE_PASS_PHASES + ("loop.idle",))
+
 #: Category -> Chrome trace tid lane (deterministic ordering in the UI).
 _CAT_TIDS = {"engine": 1, "adapter": 2, "app": 3, "error": 4, "fleet": 5,
              "request": 6}
@@ -179,14 +220,17 @@ class _TraceSpan:
     args that are only known inside the body (they reach the recorded
     event, not the annotation)."""
 
-    __slots__ = ("_rec", "_name", "_cat", "_args", "_t0", "_ann", "_under")
+    __slots__ = ("_rec", "name", "_cat", "_args", "_t0", "_ann", "_parent",
+                 "stalled_s")
 
     def __init__(self, rec: "FlightRecorder", name: str, cat: str,
                  args: Dict[str, Any]):
         self._rec = rec
-        self._name = name
+        self.name = name
         self._cat = cat
         self._args = args
+        #: seconds of stalls already counted inside this span
+        self.stalled_s = 0.0
 
     def set(self, **args) -> None:
         self._args.update(args)
@@ -194,9 +238,9 @@ class _TraceSpan:
     def __enter__(self) -> "_TraceSpan":
         rec = self._rec
         stack = rec._open_spans()
-        self._under = stack[-1] if stack else ""
-        stack.append(self._name)
-        self._ann = rec._annotate(self._name, self._args)
+        self._parent = stack[-1] if stack else None
+        stack.append(self)
+        self._ann = rec._annotate(self.name, self._args)
         self._t0 = time.perf_counter()
         return self
 
@@ -205,14 +249,14 @@ class _TraceSpan:
         if self._ann is not None:
             self._ann.__exit__(*exc)
         stack = self._rec._open_spans()
-        # newest entry of this name: LIFO but for spans held open across
-        # an ``await`` (loop.yield / loop.idle of two engines on one loop)
+        # LIFO but for spans held open across an ``await`` (loop.yield /
+        # loop.idle of two engines on one loop)
         for i in range(len(stack) - 1, -1, -1):
-            if stack[i] == self._name:
+            if stack[i] is self:
                 del stack[i]
                 break
-        self._rec._close(self._name, self._t0, t1, self._cat, self._args,
-                         self._under)
+        self._rec._close(self.name, self._t0, t1, self._cat, self._args,
+                         self._parent, self.stalled_s)
 
 
 class FlightRecorder:
@@ -235,6 +279,12 @@ class FlightRecorder:
         #: the first): every span opened under it carries it as ``pass_id``
         self.pass_id: Optional[int] = None
         self._local = threading.local()    # per-thread stack of open spans
+        #: slices that ran :data:`STALL_SECONDS` or longer, oldest first;
+        #: beside the ring, so its wrap does not evict them
+        self._stalls: List[Dict[str, Any]] = []
+        #: the live registry and its ``nxdi_host_seconds_total`` series
+        #: adders by ``(span, under)``: one dict lookup where a slice closes
+        self._host_seconds: tuple = (None, {})
         try:                       # the ENABLED recorder alone touches jax
             from jax.profiler import TraceAnnotation
         except ImportError:        # pragma: no cover - jax is a hard dep
@@ -246,7 +296,7 @@ class FlightRecorder:
         self.pass_id = 0 if self.pass_id is None else self.pass_id + 1
         return self.pass_id
 
-    def _open_spans(self) -> List[str]:
+    def _open_spans(self) -> List[_TraceSpan]:
         try:
             return self._local.stack
         except AttributeError:
@@ -314,22 +364,54 @@ class FlightRecorder:
             t1 = time.perf_counter()
         stack = self._open_spans()
         return self._close(name, t0, t1, cat, args,
-                           stack[-1] if stack else "")
+                           stack[-1] if stack else None)
 
     def _close(self, name: str, t0: float, t1: float, cat: str,
-               args: Dict[str, Any], under: str) -> str:
-        """The ONE place a slice's duration is known: record it, and count
-        its host seconds by span and parent (label sets bounded by the
-        stable names)."""
+               args: Dict[str, Any], parent: Optional[_TraceSpan],
+               stalled_s: float = 0.0) -> str:
+        """The ONE place a slice's duration is known: record it, count its
+        host seconds by span and parent (label sets bounded by the stable
+        names), and keep a slice that ran long (module docstring).
+        ``parent`` is the span open around it on this thread,
+        ``stalled_s`` the stalls already counted inside it."""
+        dur = t1 - t0
         reg = get_registry()
         if reg.enabled:
-            from . import metrics as tmetrics
-            tmetrics.host_seconds_counter(reg).inc(
-                max(t1 - t0, 0.0),
-                span=name if name in _EVENT_SET else "other",
-                under=under if under in _EVENT_SET else "")
+            span = name if name in _EVENT_SET else "other"
+            under = (parent.name if parent is not None
+                     and parent.name in _EVENT_SET else "")
+            of, adders = self._host_seconds
+            if of is not reg:
+                adders = {}
+                self._host_seconds = (reg, adders)
+            add = adders.get((span, under))
+            if add is None:
+                from . import metrics as tmetrics
+                add = adders[(span, under)] = tmetrics.host_seconds_counter(
+                    reg).child(span=span, under=under)
+            add(max(dur, 0.0))
+        if dur - stalled_s >= STALL_SECONDS and name not in _NEVER_STALLS:
+            self._note_stall(reg, name, t0, dur, dur - stalled_s, args)
+            stalled_s = dur
+        if parent is not None:
+            parent.stalled_s += stalled_s
         return self._push({"name": name, "cat": cat, "ph": "X",
-                           "ts": t0, "dur": t1 - t0, "args": args})
+                           "ts": t0, "dur": dur, "args": args})
+
+    def _note_stall(self, reg, name: str, t0: float, dur: float,
+                    own_s: float, args: Dict[str, Any]) -> None:
+        """``own_s``: the slice's seconds that no stall inside it was
+        already counted for (all of it, for the innermost)."""
+        around = [s.name for s in self._open_spans()]
+        with self._lock:
+            self._stalls.append({"name": name, "ts": t0, "dur": dur,
+                                 "args": dict(args), "around": around})
+            del self._stalls[:-STALL_RECORDS]
+        if reg.enabled:
+            from . import metrics as tmetrics
+            span = name if name in _EVENT_SET else "other"
+            tmetrics.host_stall_seconds_counter(reg).inc(own_s, span=span)
+            tmetrics.host_stalls_counter(reg).inc(span=span)
 
     def span(self, name: str, cat: str = "engine", **args) -> _TraceSpan:
         """``with rec.span("pass.admit"): ...`` — one complete event over
@@ -366,6 +448,7 @@ class FlightRecorder:
     def clear(self) -> None:
         with self._lock:
             self._events.clear()
+            self._stalls.clear()
             self.dropped = 0
             self._dropped_flushed = 0
 
@@ -380,6 +463,13 @@ class FlightRecorder:
         self._flush_drops()
         with self._lock:
             return [dict(e) for e in self._events[-n:]]
+
+    def stalls(self) -> List[Dict[str, Any]]:
+        """The slices that ran long (at most :data:`STALL_RECORDS`, oldest
+        first): ``name``, ``ts``, ``dur``, ``args`` and ``around``, the
+        names open around each, outermost first."""
+        with self._lock:
+            return [dict(e) for e in self._stalls]
 
     def __len__(self) -> int:
         return len(self._events)
@@ -451,6 +541,9 @@ class NullFlightRecorder:
         return []
 
     def tail(self, n=256):
+        return []
+
+    def stalls(self):
         return []
 
     def __len__(self):

@@ -10,6 +10,10 @@ Pins:
   * the disabled-default path is bit-identical (tokens AND jit cache
     keys) to a recorder-enabled run — trace hooks change nothing;
   * tenant labels propagate onto the failure counters;
+  * a span that ran long leaves a record (ISSUE 38): counted once, under
+    the innermost such span, never under ``pass.*`` or ``loop.idle``; the
+    record outlives a wrap of the ring; a stall injected through the fault
+    hook lands in the adapter's gap counts and in the record;
   * metric names and the README table cannot drift (tier-1 lint).
 """
 
@@ -122,6 +126,110 @@ def test_error_event_attaches_trace_id():
     assert ev["args"]["retry_safe"] is False
 
 
+# ---------------------------------------------------------------------------
+# a span that ran long leaves a record
+# ---------------------------------------------------------------------------
+
+class _Clock:
+    """Stands in for ``time`` inside telemetry/trace.py: slices get the
+    durations a test gives them."""
+
+    def __init__(self):
+        self.t = 100.0
+
+    def perf_counter(self):
+        return self.t
+
+
+def test_a_slow_span_is_counted_once_under_the_innermost(monkeypatch):
+    clock = _Clock()
+    monkeypatch.setattr(trace_mod, "time", clock)
+    monkeypatch.setattr(trace_mod, "STALL_SECONDS", 1.0)   # patched down
+    reg = telemetry.enable()
+    rec = trace_mod.FlightRecorder(capacity=8)
+    with rec.span("pass.dispatch"):
+        with rec.span("run.paged", cat="app", rows=2):
+            clock.t += 0.4
+            with rec.span("prep.rng", cat="app"):
+                clock.t += 1.5                      # the stall
+            clock.t += 0.4
+        with rec.span("dispatch.retire", cat="adapter"):
+            clock.t += 0.9                          # long, not a stall
+    with rec.span("loop.idle"):
+        clock.t += 30.0                             # the quiet: never
+    stalls = rec.stalls()
+    assert [(e["name"], e["dur"], e["around"]) for e in stalls] == \
+        [("prep.rng", pytest.approx(1.5), ["pass.dispatch", "run.paged"])]
+    assert stalls[0]["ts"] == pytest.approx(100.4)
+    secs = reg.get(tmetrics.HOST_STALL_SECONDS_TOTAL)
+    count = reg.get(tmetrics.HOST_STALLS_TOTAL)
+    assert secs.get(span="prep.rng") == pytest.approx(1.5)
+    assert count.get(span="prep.rng") == 1
+    # run.paged was 2.3 s long and pass.dispatch 3.2 s: explained by the
+    # stall inside (0.8 s and 1.7 s are their own), and pass.* never counts
+    for name in ("run.paged", "pass.dispatch", "loop.idle",
+                 "dispatch.retire"):
+        assert count.get(span=name) == 0, name
+    # a span whose OWN part is long as well is a stall of its own, for
+    # that part; an unknown name folds into "other"; args are kept
+    with rec.span("pass.admit"):
+        with rec.span("dispatch.prefill_chunk", cat="adapter", rows=3):
+            clock.t += 1.2
+            with rec.span("not.a.stable.name"):
+                clock.t += 2.0
+    assert [e["name"] for e in rec.stalls()] == \
+        ["prep.rng", "not.a.stable.name", "dispatch.prefill_chunk"]
+    assert rec.stalls()[-1]["args"] == {"rows": 3}
+    assert rec.stalls()[-1]["dur"] == pytest.approx(3.2)
+    assert secs.get(span="other") == pytest.approx(2.0)
+    assert secs.get(span="dispatch.prefill_chunk") == pytest.approx(1.2)
+    assert count.get(span="pass.admit") == 0
+    assert sum(s["value"] for s in secs._snapshot()) == pytest.approx(4.7)
+    for s in secs._snapshot() + count._snapshot():
+        assert s["labels"]["span"] in set(trace_mod.EVENT_NAMES) | {"other"}
+    # registry off: the record is still kept, nothing is counted
+    telemetry.disable()
+    rec.complete("fetch.tokens", clock.t - 5.0, cat="adapter")
+    assert rec.stalls()[-1]["name"] == "fetch.tokens"
+    assert count.get(span="fetch.tokens") == 0
+
+
+def test_stall_records_outlive_the_ring_and_are_bounded():
+    rec = trace_mod.FlightRecorder(capacity=4)
+    rec.complete("run.paged", 0.0, cat="app", t1=9.4, rows=32)
+    for i in range(10):
+        rec.instant("stream.deliver", tokens=i)
+    assert rec.dropped == 7
+    assert "run.paged" not in [e["name"] for e in rec.events()]
+    assert [(e["name"], e["dur"], e["args"]) for e in rec.stalls()] == \
+        [("run.paged", 9.4, {"rows": 32})]
+    for i in range(trace_mod.STALL_RECORDS + 6):
+        rec.complete("fetch.tokens", float(i), cat="adapter", t1=i + 2.5)
+    kept = rec.stalls()
+    assert len(kept) == trace_mod.STALL_RECORDS
+    assert kept[0]["ts"] == 6.0 and kept[-1]["ts"] == 69.0   # the newest
+    assert trace_mod.NULL_RECORDER.stalls() == []
+    rec.clear()
+    assert rec.stalls() == []
+
+
+def test_host_seconds_series_follow_the_live_registry():
+    """The recorder holds each series' adder per registry: a new registry
+    gets its own, the old one is left as it was."""
+    rec = trace_mod.FlightRecorder()
+    first = telemetry.enable()
+    rec.complete("pass.admit", 0.0, t1=0.5)
+    rec.complete("pass.admit", 1.0, t1=1.25)
+    telemetry.disable()
+    second = telemetry.enable()
+    assert second is not first
+    rec.complete("pass.admit", 2.0, t1=3.0)
+    assert first.get(tmetrics.HOST_SECONDS_TOTAL).get(
+        span="pass.admit", under="") == pytest.approx(0.75)
+    assert second.get(tmetrics.HOST_SECONDS_TOTAL).get(
+        span="pass.admit", under="") == pytest.approx(1.0)
+
+
 def _validate_chrome(chrome):
     """Minimal validating parser for Chrome trace-event JSON: the shape
     chrome://tracing / Perfetto load. Returns non-metadata event names."""
@@ -160,12 +268,13 @@ def test_jsonl_export_parses():
 GOLDEN_EVENT_NAMES = (
     "pass.expire", "pass.preempt", "pass.admit", "pass.dispatch",
     "loop.yield", "loop.idle",
-    "stream.deliver", "admission.headroom",
+    "stream.deliver", "admission.headroom", "deliver.tokens",
     "dispatch.decode", "dispatch.decode_loop", "dispatch.prefill_chunk",
-    "dispatch.ragged", "fetch.tokens", "preempt",
+    "dispatch.ragged", "fetch.tokens", "preempt", "dispatch.build",
+    "dispatch.retire",
     "run.prefill", "run.decode", "run.decode_loop", "run.paged",
     "run.paged_loop", "run.ragged", "run.spec_draft", "run.spec_verify",
-    "compile",
+    "compile", "prep.inputs", "prep.rng", "prep.enqueue",
     "fleet.route", "fleet.drain", "kv.spill", "kv.restore", "handoff.send",
     "handoff.recv", "fleet.all_dead", "fleet.scale_up", "fleet.scale_down",
     "trace.begin", "trace.admit", "trace.requeue", "trace.emit",
@@ -337,6 +446,54 @@ def test_tenant_label_on_failure_counters(paged_app):
     assert not paged_app.kv_mgr.tables
 
 
+@pytest.mark.parametrize("point, traced", [("slow_step", False),
+                                           ("pipeline_flush", True)])
+def test_an_injected_stall_lands_in_the_gap_counts(paged_app, monkeypatch,
+                                                   point, traced):
+    """A decode step held up for over a second through the fault hook:
+    an UNTRACED run's ``host_stats`` shows it (``decode_gaps_over_1s``,
+    ``decode_gap_max_s``); recorder on, the stall record names the
+    innermost span it sat in, and the post-mortem dump carries it."""
+    monkeypatch.setattr(trace_mod, "STALL_SECONDS", 0.5)
+    if traced:
+        telemetry.enable()
+        rec = telemetry.enable_recorder()
+    adapter = PagedEngineAdapter(paged_app)
+    eng = ServingEngine(adapter, starvation_bound_s=1e9)
+    stream = eng.submit(_prompts(18, 1)[0], 8)
+    for _ in range(4):
+        eng.run_pass()                              # decoding, one in flight
+    assert adapter.host_stats["decode_gaps"] >= 1
+    assert adapter.host_stats["decode_gaps_over_1s"] == 0
+    with FAULTS.inject(point, delay_s=1.05) as fp:
+        eng.run_pass()
+    assert fp.trips == 1
+    eng.run_until_drained()
+    assert stream.finish_reason == "length"
+    st = adapter.host_stats
+    assert st["decode_gaps_over_1s"] == 1
+    assert st["decode_gaps_over_1s_behind_prefill"] == 0   # no prefill in it
+    assert 1.05 <= st["decode_gap_max_s"] < st["decode_gap_s"]
+    assert st["decode_gaps_behind_prefill"] == 0
+    if not traced:
+        return
+    # the sleep sat in _retire before its fetch: dispatch.retire is the
+    # innermost span around it (pass.dispatch, around that, never counts)
+    assert [(e["name"], e["around"]) for e in rec.stalls()] == \
+        [("dispatch.retire", ["pass.dispatch"])]
+    reg = telemetry.get_registry()
+    assert reg.get(tmetrics.HOST_STALLS_TOTAL).get(
+        span="dispatch.retire") == 1
+    assert reg.get(tmetrics.HOST_STALL_SECONDS_TOTAL).get(
+        span="dispatch.retire") >= 1.05
+    assert reg.get(tmetrics.DECODE_GAP_SECONDS).sum(
+        engine="paged", behind="none") >= 1.05
+    dump = eng.dump_debug_state()
+    assert [e["name"] for e in dump["trace"]["stalls"]] == \
+        ["dispatch.retire"]
+    json.dumps(dump)                                # still an artifact
+
+
 # ---------------------------------------------------------------------------
 # debug endpoints through the asyncio front door
 # ---------------------------------------------------------------------------
@@ -369,6 +526,7 @@ def test_debug_endpoints(paged_app):
         assert dump["engine"]["stats"]["completed"] == 1
         assert "blocks" in dump["engine"]["adapter"]
         assert dump["trace"]["enabled"] and dump["trace"]["events"]
+        assert dump["trace"]["stalls"] == []        # nothing ran long
         trace_resp = (await http(
             host, port, b"GET /v1/debug/trace HTTP/1.1\r\n\r\n")).decode()
         chrome = json.loads(trace_resp.split("\r\n\r\n", 1)[1])

@@ -9,6 +9,15 @@ Pins:
   * ``nxdi_host_seconds_total{span=...}`` equals the recorder's own slice
     durations, label set bounded by the stable names;
   * ``pass.* | loop.*`` partition the serving loop's time;
+  * the host's pass opened up (ISSUE 38): ``prep.inputs | prep.rng |
+    prep.enqueue`` lie inside their ``run.paged``, ``dispatch.build``,
+    ``dispatch.retire`` (around its ``fetch.tokens``) and
+    ``deliver.tokens`` under ``pass.dispatch``;
+  * the gap between two decode steps, by what it waited behind: exact
+    ``host_stats`` counts with recorder and registry off, the same tokens
+    on or off, no gap across an empty live set;
+  * each new per-layer metric file of the benchmark reads a hand-built
+    ``ctx``, and nothing (None or 0) from an empty one;
   * the lowered paged step is named after its function and carries the
     scopes ``embed``/``attn``/``mlp``/``lm_head``/``sample`` (``moe`` on
     an MoE model);
@@ -21,6 +30,7 @@ import asyncio
 import glob
 import json
 import os
+import sys
 from pathlib import Path
 
 import jax
@@ -188,7 +198,9 @@ def test_host_seconds_counter_equals_recorder_slices(paged_app):
             by_name[e["name"]] = by_name.get(e["name"], 0.0) + e["dur"]
     for want in ("pass.expire", "pass.preempt", "pass.admit",
                  "pass.dispatch", "loop.yield", "run.paged",
-                 "fetch.tokens", "dispatch.prefill_chunk"):
+                 "fetch.tokens", "dispatch.prefill_chunk",
+                 "prep.inputs", "prep.rng", "prep.enqueue",
+                 "dispatch.build", "dispatch.retire", "deliver.tokens"):
         assert want in by_name, want
     ctr = reg.get(tmetrics.HOST_SECONDS_TOTAL)
     counted, under = {}, {}
@@ -206,12 +218,25 @@ def test_host_seconds_counter_equals_recorder_slices(paged_app):
     # self time (own seconds - seconds under it) is never negative: the
     # default adapter dispatches prefill inside pass.admit, decode inside
     # pass.dispatch, and the parent label tells the two run.paged apart
-    for parent in ("pass.admit", "pass.dispatch", "dispatch.prefill_chunk"):
+    for parent in ("pass.admit", "pass.dispatch", "dispatch.prefill_chunk",
+                   "run.paged", "dispatch.retire"):
         assert 0.0 < under[parent] <= by_name[parent], parent
     assert ctr.get(span="dispatch.prefill_chunk", under="pass.admit") > 0
     assert ctr.get(span="run.paged", under="dispatch.prefill_chunk") > 0
     assert ctr.get(span="run.paged", under="pass.dispatch") > 0
-    assert ctr.get(span="fetch.tokens", under="pass.dispatch") > 0
+    # the phases of run.paged are recorded under it and under nothing else
+    for phase in ("prep.inputs", "prep.rng", "prep.enqueue"):
+        assert ctr.get(span=phase, under="run.paged") == \
+            pytest.approx(by_name[phase], rel=1e-9)
+    # a decode step's fetch closes under dispatch.retire, a final prefill
+    # chunk's under its dispatch.prefill_chunk; the parts of a decode
+    # dispatch under pass.dispatch
+    assert ctr.get(span="fetch.tokens", under="dispatch.retire") > 0
+    assert ctr.get(span="fetch.tokens", under="pass.dispatch") == 0
+    assert ctr.get(span="fetch.tokens", under="dispatch.prefill_chunk") > 0
+    for part in ("dispatch.build", "dispatch.retire", "deliver.tokens"):
+        assert ctr.get(span=part, under="pass.dispatch") == \
+            pytest.approx(by_name[part], rel=1e-9)
 
 
 def test_pass_and_loop_spans_partition_the_loop_thread(paged_app):
@@ -245,6 +270,222 @@ def test_pass_and_loop_spans_partition_the_loop_thread(paged_app):
             assert parent[0]["args"]["pass_id"] == e["args"]["pass_id"]
 
 
+def test_prep_slices_lie_inside_their_run_paged(paged_app):
+    """Every ``run.paged`` holds its three phases, in order, and they
+    explain nearly all of it (what is left is opening and closing them)."""
+    rec = telemetry.enable_recorder(capacity=1 << 16)
+    asyncio.run(_serve(paged_app, _prompts(27, 2)))
+    slices = sorted((e for e in rec.events() if e["ph"] == "X"),
+                    key=lambda e: e["ts"])
+    runs = [e for e in slices if e["name"] == "run.paged"]
+    preps = [e for e in slices if e["name"].startswith("prep.")]
+    assert runs and len(preps) == 3 * len(runs)
+    for run in runs:
+        lo, hi = run["ts"], run["ts"] + run["dur"]
+        inside = [e for e in preps
+                  if lo <= e["ts"] and e["ts"] + e["dur"] <= hi + 1e-9]
+        assert [e["name"] for e in inside] == \
+            ["prep.inputs", "prep.rng", "prep.enqueue"]
+        for a, b in zip(inside, inside[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + 1e-9
+        assert sum(e["dur"] for e in inside) <= run["dur"] + 1e-9
+        assert {e["args"].get("pass_id") for e in inside} == \
+            {run["args"].get("pass_id")}
+    # no span name but the eight run.<kind> starts with "run.": the
+    # benchmark's host_spans.run_seconds sums the prefix
+    assert [n for n in trace_mod.EVENT_NAMES if n.startswith("run.")] == \
+        [n for n in trace_mod.APP_EVENTS if n.startswith("run.")]
+    assert len([n for n in trace_mod.EVENT_NAMES
+                if n.startswith("run.")]) == 8
+
+
+# ---------------------------------------------------------------------------
+# the gap between two decode steps, by what it waited behind
+# ---------------------------------------------------------------------------
+
+GAP_KEYS = ("decode_gaps", "decode_gap_s", "decode_gaps_behind_prefill",
+            "decode_gap_s_behind_prefill", "prefill_dispatches_in_gaps",
+            "decode_gaps_over_1s", "decode_gaps_over_1s_behind_prefill",
+            "decode_gap_max_s")
+
+
+def _admission_in_mid_decode(app, prompts, **adapter_kw):
+    """One request decodes, a second is admitted once it does; returns
+    the adapter, the streams' tokens and the prefill dispatches the second
+    admission made."""
+    adapter = PagedEngineAdapter(app, **adapter_kw)
+    eng = ServingEngine(adapter, starvation_bound_s=1e9)
+    first = eng.submit(prompts[0], 12)
+    for _ in range(20):
+        eng.run_pass()
+        if adapter.host_stats["decode_gaps"] >= 2:     # it is decoding
+            break
+    assert adapter.host_stats["decode_gaps_behind_prefill"] == 0
+    before = adapter.host_stats["prefill_dispatches"]
+    second = eng.submit(prompts[1], 5)
+    eng.run_until_drained()
+    assert first.finish_reason == second.finish_reason == "length"
+    return (adapter, [first.tokens, second.tokens],
+            adapter.host_stats["prefill_dispatches"] - before)
+
+
+@pytest.mark.parametrize("seed, adapter_kw", [
+    (31, {}), (33, {"prefill_budget_tokens": 8}), (35, {"pipeline_depth": 0})],
+    ids=["default", "chunked", "eager"])
+def test_gap_counts_an_admission_in_mid_decode(paged_app, seed, adapter_kw):
+    """Recorder and registry OFF: the keys are there and counted; the
+    prefill dispatches of the mid-decode admission are exactly those seen
+    in gaps (the first request's own were issued before any decode step)."""
+    prompts = _prompts(seed, 2, length=19)     # fresh: no cached prefix
+    assert not telemetry.get_registry().enabled
+    assert not trace_mod.get_recorder().enabled
+    adapter, tokens, chain = _admission_in_mid_decode(paged_app, prompts,
+                                                      **adapter_kw)
+    st = adapter.host_stats
+    assert set(GAP_KEYS) <= set(st)
+    assert chain >= (3 if adapter_kw.get("prefill_budget_tokens") else 1)
+    assert st["prefill_dispatches_in_gaps"] == chain
+    assert 1 <= st["decode_gaps_behind_prefill"] <= chain
+    assert st["decode_gaps_behind_prefill"] < st["decode_gaps"]
+    assert 0.0 < st["decode_gap_s_behind_prefill"] < st["decode_gap_s"]
+    assert 0.0 < st["decode_gap_max_s"] <= st["decode_gap_s"]
+    assert st["decode_gaps_over_1s"] == 0
+    assert st["decode_gaps_over_1s_behind_prefill"] == 0
+    # on: the same tokens (the prompts' prefixes are cached now, so the
+    # chain may be shorter), counted the same way, and the histogram holds
+    # the gaps by cause
+    reg = telemetry.enable()
+    telemetry.enable_recorder()
+    adapter_on, tokens_on, chain_on = _admission_in_mid_decode(
+        paged_app, prompts, **adapter_kw)
+    assert tokens_on == tokens
+    on = adapter_on.host_stats
+    assert on["prefill_dispatches_in_gaps"] == chain_on >= 1
+    assert 1 <= on["decode_gaps_behind_prefill"] <= chain_on
+    hist = reg.get(tmetrics.DECODE_GAP_SECONDS)
+    by_cause = {c: hist.count(engine="paged", behind=c)
+                for c in ("prefill", "drain", "none")}
+    assert sum(by_cause.values()) == on["decode_gaps"]
+    assert by_cause["prefill"] == on["decode_gaps_behind_prefill"]
+    assert hist.sum(engine="paged", behind="prefill") == \
+        pytest.approx(on["decode_gap_s_behind_prefill"])
+
+
+def test_no_gap_spans_an_empty_live_set(paged_app):
+    """Two requests one after the other count the gaps of each and none
+    between them: time with nothing in flight is no gap."""
+    prompts = _prompts(32, 2)
+
+    def gaps(order):
+        adapter = PagedEngineAdapter(paged_app)
+        eng = ServingEngine(adapter, starvation_bound_s=1e9)
+        for p in order:
+            stream = eng.submit(p, 6)
+            eng.run_until_drained()
+            assert stream.finish_reason == "length"
+            assert not adapter.seqs
+        return adapter.host_stats
+
+    one, two = gaps(prompts[:1]), gaps(prompts)
+    assert one["decode_gaps"] >= 3
+    assert two["decode_gaps"] == 2 * one["decode_gaps"]
+    assert two["decode_gaps_behind_prefill"] == 0
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's readers of the new spans and counts
+# ---------------------------------------------------------------------------
+
+def _bench():
+    bench = str(REPO / "benchmark")
+    for p in (str(REPO), bench):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    from harness import readers, reduce_trace
+    return readers, reduce_trace
+
+
+def _ctx(host_seconds=(), stall_seconds=(), **host_stats):
+    """A window in which the program counted ``host_stats`` and recorded
+    ``host_seconds`` (``(span, under, seconds)``); nothing before it."""
+    prom = {}
+    if host_seconds:
+        prom["nxdi_host_seconds_total"] = {"series": [
+            {"labels": {"span": s, "under": u}, "value": v}
+            for s, u, v in host_seconds]}
+    if stall_seconds:
+        prom["nxdi_host_stall_seconds_total"] = {"series": [
+            {"labels": {"span": s}, "value": v} for s, v in stall_seconds]}
+    return {"before": {"counters": {}, "prom": {}},
+            "after": {"counters": {f"host_stats.{k}": v
+                                   for k, v in host_stats.items()},
+                      "prom": prom}}
+
+
+_PASS = [("pass.dispatch", "", 1.0), ("run.paged", "pass.dispatch", 0.5),
+         ("prep.inputs", "run.paged", 0.2), ("prep.rng", "run.paged", 0.15),
+         ("prep.enqueue", "run.paged", 0.1),
+         ("dispatch.build", "pass.dispatch", 0.05),
+         ("dispatch.retire", "pass.dispatch", 0.3),
+         ("fetch.tokens", "dispatch.retire", 0.26),
+         ("deliver.tokens", "pass.dispatch", 0.02)]
+_COUNTS = dict(dispatches=80, prefill_dispatches=20, decode_gaps=50,
+               decode_gaps_behind_prefill=4, decode_gap_s=2.0,
+               decode_gap_s_behind_prefill=0.4,
+               prefill_dispatches_in_gaps=12)
+
+
+@pytest.mark.parametrize("name, want, empty", [
+    ("host.prep_inputs_ms_per_dispatch", 2.0, 0.0),
+    ("host.prep_rng_ms_per_dispatch", 1.5, 0.0),
+    ("host.prep_enqueue_ms_per_dispatch", 1.0, 0.0),
+    ("host.dispatch_build_ms_per_dispatch", 0.5, 0.0),
+    ("host.dispatch_retire_ms_per_dispatch", 0.4, None),
+    ("host.deliver_ms_per_dispatch", 0.2, 0.0),
+    ("sched.gaps_behind_prefill_share", 8.0, None),
+    ("sched.stalled_gap_mean_ms", 100.0, None),
+    ("sched.prefill_dispatches_per_stalled_gap", 3.0, None),
+    ("host.stall_s", 9.5, 0.0),
+    ("device.idle_prep_share", 20.0, None),
+])
+def test_a_new_layer_metric_reads_a_hand_built_ctx(name, want, empty,
+                                                   monkeypatch):
+    """``empty``: what the parent's program gives the reader, whose counts
+    advance (it dispatches) but which has none of the spans or keys."""
+    readers, reduce_trace = _bench()
+    parent = _ctx([("pass.dispatch", "", 1.0),
+                   ("run.paged", "pass.dispatch", 0.5),
+                   ("fetch.tokens", "pass.dispatch", 0.3)],
+                  dispatches=80, prefill_dispatches=20)
+    if name != "device.idle_prep_share":
+        ctx = _ctx(_PASS, [("prep.rng", 9.0), ("run.paged", 0.5)], **_COUNTS)
+        assert readers.read_metric(name, ctx) == pytest.approx(want)
+        assert readers.read_metric(name, parent) == empty
+        return
+    # the slice's xplane is read from the run's output directory: none here
+    from harness import host_spans
+    monkeypatch.setattr(host_spans, "slice_trace_dir",
+                        lambda ctx, out_dir=None: None)
+    assert readers.read_metric(name, parent) is empty
+    mod = readers.load_module(str(REPO / "benchmark" / "layer_metrics"
+                                  / (name + ".py")))
+    Event = reduce_trace.Event
+    planes = {"/device:TPU:0": {reduce_trace.OPS_LINE: [
+        Event("fusion.1", 0.0, 1.0), Event("fusion.2", 3.0, 2.0),
+        Event("fusion.3", 9.0, 1.0)]}}
+    events = [Event("prep.inputs", 0.5, 1.5),      # idle 1.0 .. 2.0
+              Event("prep.rng", 2.0, 0.5),         # idle, all of it
+              Event("prep.enqueue", 4.0, 0.5),     # the device is busy
+              Event("prep.enqueue", 8.5, 1.0)]     # idle 8.5 .. 9.0
+    split = mod.idle_by_phase(planes, events)
+    assert split == pytest.approx({"prep.inputs": 1.0, "prep.rng": 0.5,
+                                   "prep.enqueue": 0.5, "window_s": 10.0})
+    assert 100.0 * sum(split[p] for p in mod.PHASES) / split["window_s"] \
+        == pytest.approx(want)
+    assert mod.idle_by_phase(planes, []) is None
+    assert mod.idle_by_phase({}, events) is None
+
+
 def test_spans_land_on_the_profilers_host_plane(paged_app, tmp_path):
     """A profiler session on CPU around the live serving loop: the
     recorder's slices are TraceMe events of the xplane's host plane."""
@@ -274,7 +515,9 @@ def test_spans_land_on_the_profilers_host_plane(paged_app, tmp_path):
                      dict(e.stats).get("pass_id"), line.name))
     for want in ("pass.expire", "pass.preempt", "pass.admit",
                  "pass.dispatch", "run.paged", "fetch.tokens",
-                 "loop.yield", "dispatch.prefill_chunk"):
+                 "loop.yield", "dispatch.prefill_chunk", "prep.inputs",
+                 "prep.rng", "prep.enqueue", "dispatch.build",
+                 "dispatch.retire", "deliver.tokens"):
         assert want in found, (want, sorted(found))
     # one thread's line carries them all, tagged with the pass that caused
     # each; a run.paged slice lies inside a stage of its own pass (prefill
@@ -286,6 +529,15 @@ def test_spans_land_on_the_profilers_host_plane(paged_app, tmp_path):
     for lo, hi, pid, _ in found["run.paged"] + found["fetch.tokens"]:
         assert [1 for slo, shi, spid, _ in stages
                 if spid == pid and slo <= lo and hi <= shi] == [1]
+    # the benchmark's reader of run.paged's phases finds them in this file
+    # (device.idle_prep_share reads the host plane itself)
+    readers, _ = _bench()
+    mod = readers.load_module(str(REPO / "benchmark" / "layer_metrics"
+                                  / "device.idle_prep_share.py"))
+    phases = mod.prep_events(files[-1])
+    assert {e.name for e in phases} == set(mod.PHASES)
+    assert len(phases) == sum(len(found[p]) for p in mod.PHASES)
+    assert all(e.dur >= 0.0 for e in phases)
 
 
 def test_sse_lag_counts_every_token_written(paged_app):

@@ -84,15 +84,16 @@ def test_donation_red_on_doctored_application(tmp_path):
     the dispatch consumed it, before the rebind — the retry_safe=False
     state-loss class as a lint finding."""
     src = (PKG / "models" / "application.py").read_text()
-    head = ('                         jnp.asarray(last_idx),\n'
-            '                         sampling_params, self._next_rng(), '
-            '**kw)\n')
-    anchor = head + '            self.cache = out["cache"]\n'
+    head = ('                out = fn(self.params, self.cache, ids, pos, slots, '
+            'table,\n'
+            '                         last, sampling_params, rng, **kw)\n')
+    rebind = '                self.cache = out["cache"]\n'
+    anchor = head + rebind
     assert src.count(anchor) == 1
     doctored = src.replace(
         anchor,
-        head + '            jax.block_until_ready(self.cache)   # doctored\n'
-        '            self.cache = out["cache"]\n')
+        head + '                jax.block_until_ready(self.cache)   # doctored\n'
+        + rebind)
     bad = tmp_path / "application_doctored.py"
     bad.write_text(doctored)
     ctx = analysis.LintContext(tmp_path)
