@@ -101,6 +101,9 @@ class CausalLMApplication:
         # (site, path, reason) notes of every attention-kernel decision
         # traced into this app's graphs (ops/kernel_mode.py)
         self._kernel_notes: set = set()
+        # the same notes by paged program, keyed by its (rows, width): what
+        # the trace of THAT program took (``paged_program_notes``)
+        self._paged_notes: Dict[Tuple[int, int], frozenset] = {}
         self._rng = jax.random.PRNGKey(self.tpu_config.seed)
         self.ctx_buckets = autobucketing.context_encoding_buckets(self.tpu_config)
         self.tkg_buckets = autobucketing.token_generation_buckets(self.tpu_config)
@@ -1468,11 +1471,31 @@ class PagedCausalLMApplication(CausalLMApplication):
                 last = jnp.asarray(last_idx)
             with rec.span("prep.rng", cat="app"):
                 rng = self._next_rng()
-            with rec.span("prep.enqueue", cat="app"):
+            with rec.span("prep.enqueue", cat="app"), \
+                    self._noting_program(ids.shape):
                 out = fn(self.params, self.cache, ids, pos, slots, table,
                          last, sampling_params, rng, **kw)
                 self.cache = out["cache"]
         return out
+
+    @contextlib.contextmanager
+    def _noting_program(self, shape: Tuple[int, int]):
+        """Around the call of the paged program of ``shape`` (rows, width):
+        if the call traced it, keep the notes of THAT trace under its shape
+        (``paged_program_notes``) beside the app's."""
+        traced: set = set()
+        with kernel_mode.recording(traced):
+            yield
+        if traced:
+            self._kernel_notes |= traced
+            self._paged_notes[shape] = frozenset(
+                traced | self._paged_notes.get(shape, frozenset()))
+
+    def paged_program_notes(self, rows: int, width: int) -> frozenset:
+        """The engagement record (``kernel_mode.note`` triples) of the paged
+        program of ``rows`` x ``width`` tokens, as its own trace left it;
+        empty before that program has been traced."""
+        return self._paged_notes.get((rows, width), frozenset())
 
     def _dummy_state_slots(self, rows: int):
         """The ``state_slots`` of a dummy (warm-up) dispatch of ``rows``

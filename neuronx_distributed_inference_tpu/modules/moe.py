@@ -13,26 +13,34 @@ Design:
     are batched into one einsum that XLA maps onto the MXU. Expert dim shards
     on mesh axis "ep" (moe_ep), intermediate dim on "tp" (moe_tp) — the
     combine-sum over E emits a psum over "ep" automatically.
-  * Experts, touched path (few tokens, one chip): the same step reads only
-    the experts its routing touched. A Pallas kernel
-    (``ops/moe_decode.py``) takes the tokens, the combine matrix over the
-    held experts and the expert leaves IN THEIR STACK with the layer index,
-    builds the touched list on the device and walks it, one expert's three
-    matrices a step into one of two VMEM slots. An untouched expert's term
-    of the dense sum is exactly zero, so it is the same sum in another
-    order. Where the kernel declines (``moe_decode.declined``: quantized,
-    sharded, ``input_scaled``, biases, ``tkg_experts_local``, widths that
-    are not whole tiles) the dense path runs untouched, and
-    ``kernel_mode.note("moe_decode", ...)`` says which and why.
-  * Experts, ragged path (prefill): tokens are sorted by expert and run
-    through grouped matmuls via ``jax.lax.ragged_dot`` — the dropless
-    TPU-native analog of the reference's blockwise matmul
-    (MoENeuronConfig blockwise configs). Used when T is large enough that
-    all-experts compute would dominate. Inside a layer loop it is handed
-    the STACKED weights and the layer index (:class:`LayerOfStack`) and
-    selects the layer through its group sizes: on TPU ``ragged_dot`` is a
-    custom call whose operand must be a whole buffer, so a slice cut in
-    front of it is a copy of the layer's experts on every call.
+  * Experts, touched path (a step whose rows fit the kernel's VMEM whole,
+    one chip: a decode step, a one-row prefill chunk): the step reads
+    only the experts its routing touched, each once. A Pallas kernel
+    (``ops/moe_decode.py``) takes the tokens, the expert leaves IN THEIR
+    STACK with the layer index and the routing - the combine matrix over
+    the held experts for a step of at most one tile of rows, which goes
+    whole against every touched expert; the assignments sorted by expert
+    for a longer one, where an expert meets ITS rows only - builds the
+    touched list on the device and walks it, one expert's three matrices a
+    step into one of two VMEM slots. An untouched expert's term of the
+    dense sum is exactly zero, so it is the same sum in another order.
+    Where the kernel declines (``moe_decode.declined``: quantized, sharded,
+    ``input_scaled``, biases, ``tkg_experts_local``, widths that are not
+    whole tiles, rows that do not fit VMEM) few tokens keep the dense path
+    and a chunk the ragged one, and ``kernel_mode.note("moe_decode", ...)``
+    says which and why.
+  * Experts, ragged path (a full-batch prefill pack, and every chunk the
+    kernel declines): tokens are sorted by expert and run through grouped
+    matmuls via ``jax.lax.ragged_dot`` — the dropless TPU-native analog of
+    the reference's blockwise matmul (MoENeuronConfig blockwise configs).
+    It spends a 512-row tile of the MXU on every non-empty group. Inside
+    a layer loop it is handed the STACKED weights and the layer index
+    (:class:`LayerOfStack`) and selects the layer through its group sizes:
+    on TPU ``ragged_dot`` is a custom call whose operand must be a whole
+    buffer, so a slice cut in front of it is a copy of the layer's experts
+    on every call.
+  * Who takes which is decided in ONE place, :func:`takes_ragged`, from
+    what the step shows: its tokens, the spec and the expert leaves.
   * Shared experts (reference: SharedExperts in moe_v2.py:104) are a plain
     dense MLP added to the routed output, behind a per-token sigmoid gate
     where the spec says so (``shared_gated``: Qwen2-MoE / Qwen3-Next).
@@ -102,9 +110,13 @@ class MoESpec:
     # instead of the expert output — not equivalent through the gated
     # nonlinearity, so it is its own mode
     input_scaled: bool = False
-    # TOTAL-token-count (B*T) threshold at or below which the dense
-    # all-experts path is used; above it the ragged sorted-grouped-matmul
-    # path runs. Decode (B*1 tokens) stays dense up to batch 64 by default.
+    # TOTAL-token-count (B*T) at or below which a step is "few tokens":
+    # never the grouped matmuls, the walk over the touched experts or,
+    # where the kernel declines, the all-experts einsum (whose cost grows
+    # with the tokens: that is what this bounds). It is NOT the boundary of
+    # a chunk: above it ``takes_ragged`` gives the walk every step the
+    # kernel takes and the grouped matmuls the rest. 0 = the grouped
+    # matmuls everywhere.
     dense_max_tokens: int = 64
     # hybrid CTE/TKG expert sharding (reference: moe_v2.py:135-161
     # HybridShardingConfig — moe_tkg_ep_degree=1): prefill keeps experts
@@ -150,12 +162,30 @@ class LayerOfStack(NamedTuple):
     layer: Any
 
 
-def takes_ragged(moe: MoESpec, tokens: int) -> bool:
+def takes_ragged(moe: MoESpec, tokens: int, stack: Any = None) -> bool:
     """The sorted grouped-matmul path serves a step of ``tokens`` (B*T)
-    tokens; at or below ``dense_max_tokens`` the few-token paths do: the
-    kernel over the touched experts, or all experts on all tokens in an
-    einsum that XLA fuses the layer's slice into."""
-    return tokens > moe.dense_max_tokens
+    tokens. Decided HERE alone, from what the step shows: its tokens, the
+    spec, and the expert leaf ``stack`` (L, E, H, I) its layer loop would
+    leave in place (None: the caller cut the layer out, so there is no
+    stack to walk).
+
+    At or below ``dense_max_tokens`` the few-token paths serve: the walk
+    over the touched experts (``ops/moe_decode.py``), or all experts on all
+    tokens in an einsum where the kernel declines. Above it the walk still
+    serves every step the kernel takes (``moe_decode.declined``: the
+    leaves, and the step's rows whole in VMEM beside the slots, which at a
+    hidden size of 2048 is up to 512 tokens: a one-row chunk); a full-batch
+    pack, and a chunk over leaves the kernel declines, keep ``ragged_dot``.
+    No count of rows an expert separates the two: on a v5e the walk beat
+    the grouped matmuls 1.9-2.5 x at every shape timed, 5 to 256 rows an
+    expert (PERF.md §6, PR 39: the grouped matmuls spend a 512-row tile on
+    every group, the walk a group's own rows). ``dense_max_tokens`` 0 asks
+    for the grouped matmuls everywhere."""
+    if tokens <= moe.dense_max_tokens:
+        return False
+    walks = (moe.dense_max_tokens > 0 and stack is not None
+             and not moe_decode.declined(moe, stack, tokens))
+    return not walks
 
 
 def sliced_reason(wg) -> str:
@@ -180,8 +210,8 @@ def stack_leaves(moe: MoESpec, tokens: int, layer_params: Dict[str, Any]
     consumers that are custom calls - the grouped matmuls of many tokens,
     the touched-experts kernel of few - read the layer where it lies."""
     wg = layer_params["expert_gate"]
-    if (sliced_reason(wg) if takes_ragged(moe, tokens)
-            else moe_decode.declined(moe, wg)):
+    if (sliced_reason(wg) if takes_ragged(moe, tokens, wg)
+            else moe_decode.declined(moe, wg, tokens)):
         return ()
     return tuple(k for k in EXPERT_LEAVES if k in layer_params)
 
@@ -366,6 +396,27 @@ def experts_dense(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
     return shard_constraint(y.astype(dt), AXIS_DP, None, None)
 
 
+def sorted_assignments(moe: MoESpec, top_vals: jnp.ndarray,
+                       top_idx: jnp.ndarray):
+    """A step's B*T*k assignments sorted by held expert: ``order`` (the
+    stable sort of the flat assignments; ``order // k`` is the token),
+    their ``expert`` (0 .. held - 1, in flat order) and float32 ``weight``,
+    and ``group_sizes`` (held,). An assignment to an expert another chip
+    holds goes behind every group (expert = held), owns no row of any
+    group and weighs nothing."""
+    n_e = moe.num_held
+    expert = top_idx.reshape(-1)
+    weight = top_vals.reshape(-1)
+    if moe.holds_share:
+        expert = expert - moe.first_expert
+        absent = (expert < 0) | (expert >= n_e)
+        expert = jnp.where(absent, n_e, expert)
+        weight = jnp.where(absent, 0.0, weight)
+    order = jnp.argsort(expert)                             # stable
+    group_sizes = jnp.bincount(expert, length=n_e).astype(jnp.int32)
+    return order, expert, weight, group_sizes
+
+
 def experts_ragged(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
                    top_idx: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
                    wd: jnp.ndarray, bg=None, bu=None, bd=None,
@@ -395,22 +446,13 @@ def experts_ragged(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
 
     n_e = moe.num_held
     flat_x = x.reshape(b * t, h)
-    flat_expert = top_idx.reshape(-1)                       # (N,) expert ids
-    flat_weight = top_vals.reshape(-1)                      # (N,) fp32
-    if moe.holds_share:
-        # an assignment to an expert another chip holds is dropped before
-        # the sort: it goes behind every group (id = held), owns no rows of
-        # the grouped matmuls and weighs nothing in the combine
-        flat_expert = flat_expert - moe.first_expert
-        absent = (flat_expert < 0) | (flat_expert >= n_e)
-        flat_expert = jnp.where(absent, n_e, flat_expert)
-        flat_weight = jnp.where(absent, 0.0, flat_weight)
-
-    order = jnp.argsort(flat_expert)                        # stable
+    # an assignment to an expert another chip holds is dropped before the
+    # sort: it owns no rows of the grouped matmuls and weighs nothing
+    order, flat_expert, flat_weight, group_sizes = sorted_assignments(
+        moe, top_vals, top_idx)
     inv = jnp.argsort(order)
     sorted_expert = flat_expert[order]
     sorted_tokens = flat_x[order // k]                      # (N, H)
-    group_sizes = jnp.bincount(flat_expert, length=n_e).astype(jnp.int32)
     if moe.holds_share:
         # rows past the last group are no group's: what the kernel leaves
         # there is not read (bias lookups stay in range)
@@ -451,17 +493,27 @@ def experts_ragged(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
 def experts_touched(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
                     top_idx: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
                     wd: jnp.ndarray, layer) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The few-token path on the kernel (``ops/moe_decode.py``): x (B,T,H);
-    wg / wu (L,E,H,I), wd (L,E,I,H) the STACKED leaves and ``layer`` the
-    layer's index. Returns the combined output and the number of held
-    experts the kernel read (every expert with a non-zero combine column
-    over ALL the rows of the step)."""
+    """The walk over the touched experts (``ops/moe_decode.py``): x
+    (B,T,H); wg / wu (L,E,H,I), wd (L,E,I,H) the STACKED leaves and
+    ``layer`` the layer's index. A step of at most one tile of rows goes
+    whole against every touched expert; a longer one (a one-row chunk)
+    hands the kernel its assignments sorted by expert, and an expert
+    meets its own rows only. Returns the combined output and the number of
+    held experts the kernel read (every expert that ANY row of the step
+    was assigned to)."""
     b, t, h = x.shape
-    combine = held_combine(moe, top_vals, top_idx).reshape(b * t, -1)
-    y, read = moe_decode.moe_decode_experts(
-        x.reshape(b * t, h), combine, wg, wu, wd, layer,
-        glu=functools.partial(_glu, moe),
-        interpret=kernel_mode.pallas_interpret())
+    kw = dict(glu=functools.partial(_glu, moe),
+              interpret=kernel_mode.pallas_interpret())
+    if b * t <= moe_decode.ROW_TILE:
+        combine = held_combine(moe, top_vals, top_idx).reshape(b * t, -1)
+        y, read = moe_decode.moe_decode_experts(
+            x.reshape(b * t, h), combine, wg, wu, wd, layer, **kw)
+    else:
+        order, _, weight, group_sizes = sorted_assignments(moe, top_vals,
+                                                           top_idx)
+        y, read = moe_decode.moe_chunk_experts(
+            x.reshape(b * t, h), order // moe.top_k, weight[order],
+            group_sizes, wg, wu, wd, layer, **kw)
     return y.astype(x.dtype).reshape(b, t, h), read
 
 
@@ -492,7 +544,9 @@ def _experts(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
               else (None, None, None))
     wg, wu, wd = (layer_w["expert_gate"], layer_w["expert_up"],
                   layer_w["expert_down"])
-    ragged = takes_ragged(moe, x.shape[0] * x.shape[1])
+    tokens = x.shape[0] * x.shape[1]
+    ragged = takes_ragged(
+        moe, tokens, wg.stack if isinstance(wg, LayerOfStack) else None)
     if isinstance(wg, LayerOfStack):
         # the layer loop decided by the same rules (stack_leaves)
         if ragged:
@@ -504,7 +558,7 @@ def _experts(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
         kernel_mode.note(
             "moe_decode", kernel_mode.kernel_path(),
             moe_decode.moe_decode_plan(*wg.stack.shape[-2:],
-                                       wg.stack.dtype).note())
+                                       wg.stack.dtype).note(tokens))
         return experts_touched(moe, x, top_vals, top_idx, wg.stack,
                                wu.stack, wd.stack, wg.layer)
     if ragged:
@@ -512,7 +566,8 @@ def _experts(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
                          sliced_reason(wg) or "the caller cut the layer out")
         return experts_ragged(moe, x, top_vals, top_idx, wg, wu, wd,
                               *biases), None
-    kernel_mode.note("moe_decode", "xla", moe_decode.declined(moe, wg)
+    kernel_mode.note("moe_decode", "xla",
+                     moe_decode.declined(moe, wg, tokens)
                      or "the caller cut the layer out")
     if (moe.tkg_experts_local and phase == "decode"
             and not is_quantized_leaf(wg)):

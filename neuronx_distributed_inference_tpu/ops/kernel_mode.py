@@ -58,3 +58,15 @@ def note(site: str, path: str, reason: str = "") -> None:
     sink = _SINK.get()
     if sink is not None:
         sink.add((site, path, reason))
+
+
+def experts_path(notes) -> str:
+    """The expert path a program took, from the :func:`note` triples its
+    trace left (``modules/moe.py`` writes them): "walk" (the kernel over the
+    touched experts), "ragged" (the grouped matmuls), "dense" (all experts
+    in an einsum), "mixed", or "none" (no routed block)."""
+    paths = {"ragged" if site == "moe_ragged"
+             else "dense" if path == "xla" else "walk"
+             for site, path, _ in notes
+             if site in ("moe_ragged", "moe_decode")}
+    return paths.pop() if len(paths) == 1 else "mixed" if paths else "none"

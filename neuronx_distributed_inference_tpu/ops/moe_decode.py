@@ -1,19 +1,23 @@
-"""Pallas few-token expert kernel: the experts of a step of at most
-``dense_max_tokens`` tokens (a decode step, a one-row 64-token chunk), read
-where they lie in their stack, and only those the routing touched
-(reference: the all-experts decode kernel ``moe_token_gen``, SURVEY §2.10,
-whose dense form ``modules/moe.py`` ``experts_dense`` keeps for what this
-kernel declines).
+"""Pallas expert kernels: the experts of a step whose held experts each get
+FEW rows (a decode step, a one-row prefill chunk), read where they lie in
+their stack, and only those the routing touched (reference: the all-experts
+decode kernel ``moe_token_gen``, SURVEY §2.10, whose dense form
+``modules/moe.py`` ``experts_dense`` keeps for the few tokens this kernel
+declines; a chunk it declines keeps ``experts_ragged``).
 
 The dense path streams EVERY held expert of a layer whatever the routing
-chose; it does so near the chip's bandwidth, so the only gain left is to
-read less. An untouched expert's term of the combine-weighted sum is exactly
-zero there, so walking the touched experts alone is the same sum in another
-order: no precision, no expert and no row is dropped. What lies where:
+chose, and the grouped matmuls of a chunk spend a whole tile of the MXU on a
+group of five rows; such a step is bound by the bytes of the experts it
+touches, so the gain is to read each of them once, at the chip's bandwidth,
+and nothing else. An untouched expert's term of the combine-weighted sum is
+exactly zero, so walking the touched experts alone is the same sum in
+another order: no precision, no expert and no row is dropped. What lies
+where:
 
 * SMEM (scalar prefetch): the layer, the touched experts' count and their
-  ids, compacted to the front (:func:`touched_experts`, from the combine
-  matrix of ALL rows of the step).
+  ids, compacted to the front (:func:`touched_experts`, from ALL rows of the
+  step); for a chunk also the bounds of each expert's group in the step's
+  assignments sorted by expert, and that list (token, combine weight).
 * HBM: the three stacked expert leaves (L, E, H, I) / (L, E, I, H),
   untouched (``memory_space=pl.ANY``): the layer and the expert are indexed
   by hand, so no slice is cut in front of the custom call (a slice there is
@@ -26,10 +30,17 @@ order: no precision, no expert and no row is dropped. What lies where:
   ``glu(x Wg, x Wu) Wd`` is a term of the expert's. One slot is copied into
   while the other is computed on; the loop is as long as the touched list,
   not the expert axis (a grid of E steps pays its steps whether or not they
-  do anything, PERF.md §6, PR 33).
-* MXU: all N rows against a unit in the operands' dtype with float32
-  accumulation; times the expert's combine column in float32, summed into
-  the float32 (N, H) result.
+  do anything, PERF.md §6, PR 33). Beside the slots, the step's rows and
+  float32 result, whole: what they need is computed from the rows the call
+  carries (:func:`rows_vmem_bytes`), and a step whose rows would need more
+  than the slots is declined.
+* MXU: rows against a unit in the operands' dtype with float32
+  accumulation, combined in float32. A step of at most :data:`ROW_TILE`
+  rows (:func:`moe_decode_experts`): ALL rows against every unit, times the
+  expert's combine column. A longer one (:func:`moe_chunk_experts`, a
+  one-row chunk): the unit against ITS rows only, gathered a tile at a time
+  from the float32 rows, each row of the product added, times its weight,
+  to its token's row of the (N, H) result.
 """
 
 from __future__ import annotations
@@ -44,15 +55,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 #: VMEM the kernel spends on its two slots of three matrices together: one
 #: OLMoE expert (3 x 4 MiB in bf16) a slot. A v5e core has 128 MiB; the
-#: scoped default of 16 is raised to this plus :data:`MOE_VMEM_HEADROOM_BYTES`.
+#: scoped default of 16 is raised to the slots plus what the step's rows
+#: need beside them (:func:`rows_vmem_bytes`), which may be as much again.
 MOE_WEIGHT_VMEM_BYTES = 24 * 1024 * 1024
-#: beside the slots: the rows, the combine matrix, the float32 result and a
-#: unit's float32 intermediates, double-buffered where the pipeline holds
-#: them (64 rows x 2048: under 2 MiB), and Mosaic's own scratch.
-MOE_VMEM_HEADROOM_BYTES = 8 * 1024 * 1024
 #: lanes of a vreg: a piece's width and both matrix dimensions are whole
 #: multiples of it
 LANES = 128
+#: rows of one product with a unit: the MXU holds a 128 x 128 tile of the
+#: matrix while rows pass, so up to this many rows cost one pass over a
+#: unit's weights, which is less than its copy. A step of at most a tile's
+#: rows multiplies ALL of them by every touched expert
+#: (:func:`moe_decode_experts`); a step of more multiplies an expert by ITS
+#: rows, a tile at a time (:func:`moe_chunk_experts`).
+ROW_TILE = 128
 
 
 class MoEDecodePlan(NamedTuple):
@@ -60,9 +75,12 @@ class MoEDecodePlan(NamedTuple):
     pieces: int         # units an expert is walked in
     ip: int             # columns of the intermediate dimension a unit
 
-    def note(self) -> str:
-        """The engagement record's text (``kernel_mode.note``)."""
-        return f"pieces={self.pieces} of {self.ip}"
+    def note(self, rows: int = 1) -> str:
+        """The engagement record's text (``kernel_mode.note``) for a step
+        of ``rows`` rows."""
+        by_expert = (f" rows={rows} by expert in tiles of {ROW_TILE}"
+                     if rows > ROW_TILE else "")
+        return f"pieces={self.pieces} of {self.ip}{by_expert}"
 
 
 def moe_decode_plan(h: int, i: int, dtype) -> Optional[MoEDecodePlan]:
@@ -81,11 +99,30 @@ def moe_decode_plan(h: int, i: int, dtype) -> Optional[MoEDecodePlan]:
     return None
 
 
-def declined(moe, wg: Any) -> str:
-    """Why the kernel does not take a few-token step over the expert leaf
-    ``wg`` (one layer's, or the stack) of ``moe`` ("" = it does). Read from
-    what the code can see - the spec, the leaf, the ambient mesh - and from
-    nothing else: whatever is named here keeps ``experts_dense``."""
+def rows_vmem_bytes(n: int, h: int, e: int, plan: MoEDecodePlan,
+                    dtype) -> int:
+    """VMEM a step of ``n`` rows of ``h`` needs beside the slots, from the
+    shapes the call carries: what the pipeline holds whole, twice (the
+    rows, the (n, e) float32 combine matrix and the float32 result of a
+    step of one tile; the float32 rows and result of a longer one, and its
+    two tiles), and a product's float32 intermediates, counted twice for
+    what Mosaic keeps of them."""
+    item = jnp.dtype(dtype).itemsize
+    tile = min(n, ROW_TILE)
+    if n <= ROW_TILE:
+        held = 2 * n * (h * item + e * 4 + h * 4)
+    else:
+        held = 2 * 2 * n * h * 4 + 2 * tile * h * 4
+    product = tile * (h * item + 2 * plan.ip * 4 + plan.ip * item + h * 4)
+    return held + 2 * product
+
+
+def declined(moe, wg: Any, tokens: int = 1) -> str:
+    """Why the kernel does not take a step of ``tokens`` tokens over the
+    expert leaf ``wg`` (one layer's, or the stack) of ``moe`` ("" = it
+    does). Read from what the code can see - the spec, the leaf, the
+    ambient mesh - and from nothing else: whatever is named here keeps
+    ``experts_dense`` for few tokens and ``experts_ragged`` for a chunk."""
     if isinstance(wg, dict):           # a quantized leaf: qweight + scales
         return "quantized experts"
     if wg.dtype not in (jnp.bfloat16, jnp.float32):
@@ -104,9 +141,13 @@ def declined(moe, wg: Any) -> str:
         return "per-expert biases"
     if moe.glu_style != "gated" or moe.act != "silu":
         return f"glu {moe.glu_style}/{moe.act}"
-    if moe_decode_plan(wg.shape[-2], wg.shape[-1], wg.dtype) is None:
-        return (f"experts of {wg.shape[-2]} x {wg.shape[-1]} are not whole "
-                f"{LANES}-lane tiles")
+    h, i = wg.shape[-2:]
+    plan = moe_decode_plan(h, i, wg.dtype)
+    if plan is None:
+        return f"experts of {h} x {i} are not whole {LANES}-lane tiles"
+    if rows_vmem_bytes(tokens, h, wg.shape[-3], plan,
+                       wg.dtype) > MOE_WEIGHT_VMEM_BYTES:
+        return f"{tokens} rows of {h} do not fit VMEM beside the slots"
     return ""
 
 
@@ -123,38 +164,36 @@ def touched_experts(combine: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return jnp.minimum(ids, e - 1), seen[-1]
 
 
-def _kernel(sc_ref, x_ref, comb_ref, wg_hbm, wu_hbm, wd_hbm, o_ref,
-            gbuf, ubuf, dbuf, sem, *, pieces: int, glu: Callable):
-    """Scalar prefetch (SMEM): [layer, count, id_0 .. id_{E-1}]. ``w*_hbm``
-    are the whole stacks, left in HBM; unit ``u`` is piece ``u % pieces`` of
-    touched expert ``u // pieces``, copied by hand (three async copies)
-    into slot ``u % 2`` while the other slot is computed on."""
+def _walk(sc_ref, stacks, slots, sem, pieces: int, compute: Callable):
+    """The walk both kernels share. ``sc_ref`` (SMEM) starts [layer, count,
+    id_0 .. id_{E-1}]; ``stacks`` are the three whole leaves, left in HBM.
+    Unit ``u`` is piece ``u % pieces`` of touched expert ``u // pieces``,
+    copied by hand (three async copies) into slot ``u % 2`` of ``slots``
+    while the other slot is computed on. ``compute(expert, slot, copies)``
+    waits for each of the unit's three copies, once, where it first needs
+    its matrix."""
     layer = sc_ref[0]
     n_units = sc_ref[1] * pieces
-    ip = gbuf.shape[2]
+    ip = slots[0].shape[2]
 
     def copies(u, slot):
         e = sc_ref[2 + jax.lax.div(u, pieces)]
         if pieces == 1:
-            g_src, u_src, d_src = (w.at[layer, e]
-                                   for w in (wg_hbm, wu_hbm, wd_hbm))
+            srcs = tuple(w.at[layer, e] for w in stacks)
         else:
+            wg_hbm, wu_hbm, wd_hbm = stacks
             cols = pl.ds(pl.multiple_of(jax.lax.rem(u, pieces) * ip, LANES),
                          ip)
-            g_src = wg_hbm.at[layer, e, :, cols]
-            u_src = wu_hbm.at[layer, e, :, cols]
-            d_src = wd_hbm.at[layer, e, cols, :]
-        return (pltpu.make_async_copy(g_src, gbuf.at[slot], sem.at[0, slot]),
-                pltpu.make_async_copy(u_src, ubuf.at[slot], sem.at[1, slot]),
-                pltpu.make_async_copy(d_src, dbuf.at[slot], sem.at[2, slot]))
+            srcs = (wg_hbm.at[layer, e, :, cols],
+                    wu_hbm.at[layer, e, :, cols],
+                    wd_hbm.at[layer, e, cols, :])
+        return tuple(
+            pltpu.make_async_copy(src, buf.at[slot], sem.at[k, slot])
+            for k, (src, buf) in enumerate(zip(srcs, slots)))
 
     def start(u, slot):
         for c in copies(u, slot):
             c.start()
-
-    o_ref[...] = jnp.zeros_like(o_ref)
-    x = x_ref[...]
-    lane_expert = jax.lax.broadcasted_iota(jnp.int32, comb_ref.shape, 1)
 
     @pl.when(n_units > 0)
     def _first():
@@ -167,22 +206,137 @@ def _kernel(sc_ref, x_ref, comb_ref, wg_hbm, wu_hbm, wd_hbm, o_ref,
         def _next():
             start(u + 1, 1 - slot)
 
-        g_copy, u_copy, d_copy = copies(u, slot)
-        g_copy.wait()
-        gate = jnp.dot(x, gbuf[slot], preferred_element_type=jnp.float32)
-        u_copy.wait()
-        up = jnp.dot(x, ubuf[slot], preferred_element_type=jnp.float32)
-        inter = glu(gate, up).astype(x.dtype)
-        d_copy.wait()
-        out = jnp.dot(inter, dbuf[slot], preferred_element_type=jnp.float32)
-        # the expert's combine column, (N, 1): one lane of each row is live
-        e = sc_ref[2 + jax.lax.div(u, pieces)]
-        w = jnp.sum(jnp.where(lane_expert == e, comb_ref[...], 0.0),
-                    axis=1, keepdims=True)
-        o_ref[...] += out * w
+        compute(sc_ref[2 + jax.lax.div(u, pieces)], slot, copies(u, slot))
         return carry
 
     jax.lax.fori_loop(0, n_units, unit, 0)
+
+
+def _expert(x, slot, slots, glu: Callable, copies=()):
+    """``glu(x Wg, x Wu) Wd`` of the rows ``x`` on the unit in ``slot``, in
+    float32; a copy still in flight (``copies``) is waited for where its
+    matrix is first read."""
+    gbuf, ubuf, dbuf = slots
+
+    def arrived(k):
+        if copies:
+            copies[k].wait()
+
+    arrived(0)
+    gate = jnp.dot(x, gbuf[slot], preferred_element_type=jnp.float32)
+    arrived(1)
+    up = jnp.dot(x, ubuf[slot], preferred_element_type=jnp.float32)
+    inter = glu(gate, up).astype(x.dtype)
+    arrived(2)
+    return jnp.dot(inter, dbuf[slot], preferred_element_type=jnp.float32)
+
+
+def _kernel(sc_ref, x_ref, comb_ref, wg_hbm, wu_hbm, wd_hbm, o_ref,
+            gbuf, ubuf, dbuf, sem, *, pieces: int, glu: Callable):
+    """A step whose rows are one tile: ALL of them against every unit."""
+    o_ref[...] = jnp.zeros_like(o_ref)
+    x = x_ref[...]
+    lane_expert = jax.lax.broadcasted_iota(jnp.int32, comb_ref.shape, 1)
+    slots = (gbuf, ubuf, dbuf)
+
+    def compute(e, slot, copies):
+        out = _expert(x, slot, slots, glu, copies)
+        # the expert's combine column, (N, 1): one lane of each row is live
+        w = jnp.sum(jnp.where(lane_expert == e, comb_ref[...], 0.0),
+                    axis=1, keepdims=True)
+        o_ref[...] += out * w
+
+    _walk(sc_ref, (wg_hbm, wu_hbm, wd_hbm), slots, sem, pieces, compute)
+
+
+def _rows_kernel(sc_ref, tok_ref, wt_ref, x_ref, wg_hbm, wu_hbm, wd_hbm,
+                 o_ref, gbuf, ubuf, dbuf, xt, yt, sem, *, pieces: int,
+                 glu: Callable, experts: int):
+    """A step of more rows than a tile: each unit against ITS rows. Behind
+    the touched list ``sc_ref`` holds the E + 1 bounds of the experts'
+    groups in the assignment list; ``tok_ref`` / ``wt_ref`` (SMEM) are that
+    list, sorted by expert: a row of ``x_ref`` (float32, so that a row is
+    one sublane) and its combine weight. A group is gathered a tile of
+    rows at a time into ``xt`` and multiplied, and each row of the product
+    (``yt``) is added, times its weight, to its token's row of the result.
+    Rows of a tile past the group's end are an earlier tile's: computed,
+    never added."""
+    o_ref[...] = jnp.zeros_like(o_ref)
+    xt[...] = jnp.zeros_like(xt)
+    tile = xt.shape[0]
+    slots = (gbuf, ubuf, dbuf)
+    bounds = 2 + experts            # behind [layer, count, id_0 .. id_{E-1}]
+
+    def compute(e, slot, copies):
+        lo, hi = sc_ref[bounds + e], sc_ref[bounds + e + 1]
+        for c in copies:
+            c.wait()
+
+        def rows(t, carry):
+            base = lo + t * tile
+            n_rows = jnp.minimum(tile, hi - base)
+
+            def gather(r, c):
+                xt[pl.ds(r, 1), :] = x_ref[pl.ds(tok_ref[base + r], 1), :]
+                return c
+
+            jax.lax.fori_loop(0, n_rows, gather, 0)
+            yt[...] = _expert(xt[...].astype(gbuf.dtype), slot, slots, glu)
+
+            def scatter(r, c):
+                row = pl.ds(tok_ref[base + r], 1)
+                o_ref[row, :] += yt[pl.ds(r, 1), :] * wt_ref[base + r]
+                return c
+
+            jax.lax.fori_loop(0, n_rows, scatter, 0)
+            return carry
+
+        jax.lax.fori_loop(0, pl.cdiv(hi - lo, tile), rows, 0)
+
+    _walk(sc_ref, (wg_hbm, wu_hbm, wd_hbm), slots, sem, pieces, compute)
+
+
+def _call(kernel, name: str, scalars, rows_in, rows_out, stacks,
+          plan: MoEDecodePlan, scratch, vmem_rows: int, interpret: bool):
+    """One ``pallas_call`` of a walk: ``scalars`` prefetched into SMEM,
+    ``rows_in`` whole in VMEM, the three stacks left in HBM, two slots of a
+    unit's three matrices and ``scratch`` beside them."""
+    wg, wu, wd = stacks
+    h = wg.shape[2]
+    whole = lambda *_: (0, 0)                                  # noqa: E731
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(1,),
+            in_specs=[pl.BlockSpec(a.shape, whole) for a in rows_in]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * 3,
+            out_specs=pl.BlockSpec(rows_out.shape, whole),
+            scratch_shapes=[
+                pltpu.VMEM((2, h, plan.ip), wg.dtype),
+                pltpu.VMEM((2, h, plan.ip), wu.dtype),
+                pltpu.VMEM((2, plan.ip, h), wd.dtype),
+                *scratch,
+                pltpu.SemaphoreType.DMA((3, 2)),
+            ],
+        ),
+        out_shape=rows_out,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=MOE_WEIGHT_VMEM_BYTES + vmem_rows),
+        name=name,
+        interpret=interpret,
+    )(*scalars, *rows_in, wg, wu, wd)
+
+
+def _checked_plan(n: int, h: int, wg) -> Tuple[MoEDecodePlan, int]:
+    """The plan of a call of ``n`` rows and the VMEM its rows need."""
+    plan = moe_decode_plan(h, wg.shape[3], wg.dtype)
+    vmem_rows = plan and rows_vmem_bytes(n, h, wg.shape[1], plan, wg.dtype)
+    if plan is None or vmem_rows > MOE_WEIGHT_VMEM_BYTES:
+        raise ValueError(
+            f"moe expert walk: {n} rows over experts of {h} x {wg.shape[3]} "
+            f"{wg.dtype} (moe_decode.declined says what the kernel takes)")
+    return plan, vmem_rows
 
 
 def moe_decode_experts(x: jnp.ndarray, combine: jnp.ndarray,
@@ -190,7 +344,8 @@ def moe_decode_experts(x: jnp.ndarray, combine: jnp.ndarray,
                        layer, *, glu: Callable, interpret: bool = False
                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The combine-weighted expert sum of a few tokens over the touched
-    experts of one layer of a stack.
+    experts of one layer of a stack: at most :data:`ROW_TILE` rows, all of
+    them against every touched expert.
 
     x (N, H) the step's tokens; combine (N, E) float32 over the experts the
     leaves hold (``moe.held_combine``); wg / wu (L, E, H, I), wd
@@ -199,12 +354,7 @@ def moe_decode_experts(x: jnp.ndarray, combine: jnp.ndarray,
     nonlinearity on float32. Returns the float32 (N, H) sum and the number
     of experts read (the touched list's length)."""
     n, h = x.shape
-    e, i = wg.shape[1], wg.shape[3]
-    plan = moe_decode_plan(h, i, wg.dtype)
-    if plan is None:
-        raise ValueError(f"moe decode kernel: experts of {h} x {i} "
-                         f"{wg.dtype} are not whole {LANES}-lane tiles "
-                         "(moe_decode.declined says what the kernel takes)")
+    plan, vmem_rows = _checked_plan(n, h, wg)
     ids, count = touched_experts(combine)
     # rows in whole sublane tiles of the operands' dtype; a pad row is zero
     # and weighs nothing
@@ -213,32 +363,45 @@ def moe_decode_experts(x: jnp.ndarray, combine: jnp.ndarray,
                   for a in (x.astype(wg.dtype), combine))
     scalars = jnp.concatenate([
         jnp.asarray(layer, jnp.int32).reshape(1), count.reshape(1), ids])
-    whole = lambda g, sc: (0, 0)                               # noqa: E731
-    slot = (2, h, plan.ip)
-    out = pl.pallas_call(
+    out = _call(
         functools.partial(_kernel, pieces=plan.pieces, glu=glu),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(1,),
-            in_specs=[
-                pl.BlockSpec((n + rows, h), whole),
-                pl.BlockSpec((n + rows, e), whole),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((n + rows, h), whole),
-            scratch_shapes=[
-                pltpu.VMEM(slot, wg.dtype),
-                pltpu.VMEM(slot, wu.dtype),
-                pltpu.VMEM((2, plan.ip, h), wd.dtype),
-                pltpu.SemaphoreType.DMA((3, 2)),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((n + rows, h), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=MOE_WEIGHT_VMEM_BYTES + MOE_VMEM_HEADROOM_BYTES),
-        name="moe_decode_experts",
-        interpret=interpret,
-    )(scalars, x, combine, wg, wu, wd)
+        "moe_decode_experts", (scalars,), (x, combine),
+        jax.ShapeDtypeStruct((n + rows, h), jnp.float32), (wg, wu, wd), plan,
+        (), vmem_rows, interpret)
+    return out[:n], count
+
+
+def moe_chunk_experts(x: jnp.ndarray, token: jnp.ndarray,
+                      weight: jnp.ndarray, group_sizes: jnp.ndarray,
+                      wg: jnp.ndarray, wu: jnp.ndarray, wd: jnp.ndarray,
+                      layer, *, glu: Callable, interpret: bool = False
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The same sum for a step of MORE rows than a tile (a one-row prefill
+    chunk), whose held experts each get few of them: every touched expert
+    is streamed once and multiplied by ITS rows only, a tile at a time.
+
+    x (N, H); the step's assignments to held experts SORTED BY EXPERT:
+    ``token`` (A,) int32 the row of ``x``, ``weight`` (A,) float32 its
+    combine weight, ``group_sizes`` (E,) how many fell to each expert
+    (entries of the two lists past their sum are never read); the leaves,
+    ``layer``, ``glu`` and the result as :func:`moe_decode_experts`."""
+    n, h = x.shape
+    plan, vmem_rows = _checked_plan(n, h, wg)
+    group_sizes = group_sizes.astype(jnp.int32)
+    ids, count = touched_experts(group_sizes[None, :])
+    rows = -n % 8
+    # float32 rows: one row is one sublane, read and written alone
+    x = jnp.pad(x.astype(jnp.float32), ((0, rows), (0, 0)))
+    scalars = jnp.concatenate([
+        jnp.asarray(layer, jnp.int32).reshape(1), count.reshape(1), ids,
+        jnp.zeros((1,), jnp.int32), jnp.cumsum(group_sizes)])
+    tile = (ROW_TILE, h)
+    out = _call(
+        functools.partial(_rows_kernel, pieces=plan.pieces, glu=glu,
+                          experts=wg.shape[1]),
+        "moe_chunk_experts",
+        (scalars, token.astype(jnp.int32), weight.astype(jnp.float32)), (x,),
+        jax.ShapeDtypeStruct(x.shape, jnp.float32), (wg, wu, wd), plan,
+        (pltpu.VMEM(tile, jnp.float32), pltpu.VMEM(tile, jnp.float32)),
+        vmem_rows, interpret)
     return out[:n], count

@@ -153,6 +153,7 @@ import numpy as np
 
 from ..modules import autobucketing
 from ..modules.block_kv_cache import slots_from_table_into
+from ..ops import kernel_mode
 from ..resilience.errors import (AdmissionError, CapacityError,
                                 ConfigurationError, DeadlineExceeded,
                                 SequenceStateError, ServingError, StepFailure)
@@ -337,6 +338,12 @@ class _AdapterTelemetry:
             tmetrics.prefill_pad_waste_histogram(reg).observe(
                 1.0 - real_tokens / padded_tokens, engine=self.engine)
         self._rows(reg, "prefill", rows, padded_rows)
+
+    def on_prefill_dispatch(self, experts: str):
+        reg = self.registry
+        if reg.enabled:
+            tmetrics.prefill_dispatches_counter(reg).inc(
+                engine=self.engine, experts=experts)
 
     def on_step(self, live_ids: Sequence[int], t0: float, padded: int,
                 steps: int = 1):
@@ -851,7 +858,11 @@ class _EngineAdapterBase:
             "prefill_dispatches_in_gaps": 0,
             "decode_gaps_over_1s": 0, "decode_gaps_over_1s_behind_prefill": 0,
             "decode_gap_max_s": 0.0,
-            "prefill_dispatches": 0, "prefill_blocking_fetches": 0,
+            # prefill dispatches, and those whose program's engagement
+            # record says its routed experts took the walk over the
+            # touched experts (kernel_mode.experts_path)
+            "prefill_dispatches": 0, "prefill_dispatches_moe_walk": 0,
+            "prefill_blocking_fetches": 0,
             "prefill_blocked_s": 0.0, "prefill_real_tokens": 0,
             "prefill_padded_tokens": 0}
 
@@ -2768,6 +2779,13 @@ class PagedEngineAdapter(_EngineAdapterBase):
         if fetch:
             _async_fetch(out["tokens"])
         self.host_stats["prefill_dispatches"] += 1
+        # which expert path THIS program took, read from the record its
+        # trace left (the rule itself lives in moe.takes_ragged alone)
+        experts = kernel_mode.experts_path(
+            self.app.paged_program_notes(*ids_p.shape))
+        if experts == "walk":
+            self.host_stats["prefill_dispatches_moe_walk"] += 1
+        self.telemetry.on_prefill_dispatch(experts)
         return out
 
     def _fetch_prefill_tokens(self, out) -> np.ndarray:

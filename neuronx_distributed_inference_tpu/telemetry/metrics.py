@@ -24,6 +24,7 @@ REQUESTS_TOTAL = "nxdi_requests_total"                # event=added|released
 # -- chunked prefill (serving.py PagedEngineAdapter) -------------------------
 PREFILL_CHUNKS_TOTAL = "nxdi_prefill_chunks_total"      # engine
 PREFILL_PAD_WASTE = "nxdi_prefill_pad_waste"            # engine
+PREFILL_DISPATCHES_TOTAL = "nxdi_prefill_dispatches_total"   # engine, experts
 
 # -- serving engine (serving/engine/) ----------------------------------------
 QUEUE_DEPTH = "nxdi_queue_depth"                        # tenant
@@ -226,6 +227,17 @@ def prefill_pad_waste_histogram(reg):
         "admission of skewed prompts pushes this toward 1)",
         labels=("engine",),
         buckets=(0.05, 0.1, 0.2, 0.4, 0.6, 0.8, 0.95))
+
+
+def prefill_dispatches_counter(reg):
+    return reg.counter(
+        PREFILL_DISPATCHES_TOTAL,
+        "Prefill-chunk dispatches by the expert path their program's "
+        "engagement record names (ops/kernel_mode.py experts_path): "
+        "experts=walk (the kernel over the touched experts) | ragged (the "
+        "grouped matmuls) | dense (all experts in an einsum) | none (no "
+        "routed block)",
+        labels=("engine", "experts"))
 
 
 def overlapped_dispatches_counter(reg):

@@ -359,8 +359,9 @@ def _tile_stack(rng, layers=3, experts=4, h=128, i=128, dtype=np.float32):
 
 
 def test_moe_block_takes_a_layer_of_the_stack_for_few_tokens(rng):
-    """The few-token path: a step at or under ``dense_max_tokens`` over
-    experts of whole tiles is handed its leaves in the stack, runs the
+    """The few-token path: a step at or under ``dense_max_tokens`` (and a
+    chunk above it whose experts each expect few rows) over experts of
+    whole tiles is handed its leaves in the stack, runs the
     kernel on them and counts what it read; cut out by the caller, the same
     step keeps ``experts_dense`` and says why; the two agree."""
     spec = _moe_spec(intermediate_size=128)
@@ -389,6 +390,66 @@ def test_moe_block_takes_a_layer_of_the_stack_for_few_tokens(rng):
                      ("moe_decode", "xla", "the caller cut the layer out")}
     assert np.asarray(tally_k[0]).tolist() == [2, 8, 2]     # read = touched
     assert np.asarray(tally_d[0]).tolist() == [2, 8, 4]     # read = held
+
+
+QWEN3_NEXT_SHARE = dict(num_experts=512, top_k=10, intermediate_size=512,
+                        held_experts=128)
+OLMOE = dict(num_experts=64, top_k=8, intermediate_size=1024)
+
+
+@pytest.mark.parametrize("spec, layers, tokens, rows_an_expert, ragged", [
+    (QWEN3_NEXT_SHARE, 12, 256, 5, False),      # its one-row chunk: the walk
+    (OLMOE, 8, 256, 32, False),                 # OLMoE's: the walk too
+    (QWEN3_NEXT_SHARE, 12, 8192, 160, True),    # the full-batch packs
+    (OLMOE, 8, 4096, 512, True),
+    (QWEN3_NEXT_SHARE, 12, 32, 0.625, False),   # a decode step
+    (OLMOE, 8, 16, 2, False),
+    (QWEN3_NEXT_SHARE, 12, 2048, 40, True),     # the 64-wide pack: its rows
+                                                # do not fit VMEM whole
+], ids=["qwen3-next-chunk", "olmoe-chunk", "qwen3-next-pack", "olmoe-pack",
+        "qwen3-next-decode", "olmoe-decode", "qwen3-next-pack-w64"])
+def test_who_takes_the_grouped_matmuls_is_decided_from_shapes(
+        spec, layers, tokens, rows_an_expert, ragged):
+    """``takes_ragged`` at the shapes of the benchmark's cells (the leaves
+    as shapes only: nothing is computed): a one-row chunk is the walk's at 5
+    and at 32 rows an expert, a pack - whose rows the kernel cannot hold
+    whole - stays on the grouped matmuls; ``stack_leaves`` hands either
+    consumer its leaves in the stack; with no stack in sight, or
+    ``dense_max_tokens`` 0, a chunk is never the walk's."""
+    spec = _moe_spec(normalize_topk=True, **spec)
+    assert tokens * spec.top_k / spec.num_experts == rows_an_expert
+    held, i = spec.num_held, spec.intermediate_size
+    stack = {"expert_gate": jax.ShapeDtypeStruct((layers, held, 2048, i),
+                                                 jnp.bfloat16),
+             "expert_up": jax.ShapeDtypeStruct((layers, held, 2048, i),
+                                               jnp.bfloat16),
+             "expert_down": jax.ShapeDtypeStruct((layers, held, i, 2048),
+                                                 jnp.bfloat16)}
+    wg = stack["expert_gate"]
+    assert moe_mod.takes_ragged(spec, tokens, wg) == ragged
+    assert moe_mod.stack_leaves(spec, tokens, stack) == \
+        moe_mod.EXPERT_LEAVES[:3]
+    few = tokens <= spec.dense_max_tokens
+    assert moe_mod.takes_ragged(spec, tokens) == (not few)
+    never = dataclasses.replace(spec, dense_max_tokens=0)
+    assert moe_mod.takes_ragged(never, tokens, wg)
+    # leaves the kernel declines keep the grouped matmuls for a chunk
+    assert moe_mod.takes_ragged(
+        dataclasses.replace(spec, expert_bias=True), tokens, wg) == (not few)
+
+
+def test_a_programs_expert_path_is_read_from_its_engagement_record():
+    path = kernel_mode.experts_path
+    assert path({("paged_decode", "pallas", "pages=4")}) == "none"
+    assert path({("moe_share", "xla", "held=4 of 16"),
+                 ("moe_decode", "pallas", "pieces=1 of 512")}) == "walk"
+    assert path({("moe_decode", "pallas-interpret", "pieces=1 of 128 rows=256"
+                  " by expert in tiles of 128")}) == "walk"
+    assert path({("moe_ragged", "stacked", "")}) == "ragged"
+    assert path({("moe_ragged", "sliced", "quantized")}) == "ragged"
+    assert path({("moe_decode", "xla", "per-expert biases")}) == "dense"
+    assert path({("moe_decode", "pallas", "pieces=1 of 512"),
+                 ("moe_ragged", "stacked", "")}) == "mixed"
 
 
 def _quantized(stack):
@@ -526,7 +587,8 @@ def test_the_scanned_decode_step_reads_the_touched_experts(monkeypatch):
 
     got, notes = step()
     assert notes == {("pallas-interpret", "pieces=1 of 128")}
-    monkeypatch.setattr(moe_decode, "declined", lambda moe, wg: "forced")
+    monkeypatch.setattr(moe_decode, "declined",
+                        lambda moe, wg, tokens=1: "forced")
     want, notes = step()
     assert notes == {("xla", "forced")}
     np.testing.assert_allclose(np.asarray(got["logits"]),

@@ -52,6 +52,7 @@ from neuronx_distributed_inference_tpu.models.application import \
 from neuronx_distributed_inference_tpu.models.family import \
     get_family  # noqa: E402
 from neuronx_distributed_inference_tpu.modules import moe, ssm  # noqa: E402
+from neuronx_distributed_inference_tpu.ops import kernel_mode  # noqa: E402
 from neuronx_distributed_inference_tpu.serving import \
     PagedEngineAdapter  # noqa: E402
 from neuronx_distributed_inference_tpu.serving.warmup import \
@@ -618,6 +619,72 @@ def test_the_decode_step_reads_the_touched_experts_of_the_share(ref):
     assert st["moe_expert_slots"] == slots
     assert st["moe_experts_read"] + st["moe_experts_skipped"] == slots
     assert 0 < st["moe_experts_touched"] <= st["moe_experts_read"] < slots
+
+
+@pytest.mark.parametrize("experts", ["walk", "ragged"])
+def test_the_walk_counter_follows_the_programs_engagement_record(
+        ref, monkeypatch, experts):
+    """ISSUE 39: ``host_stats["prefill_dispatches_moe_walk"]`` counts the
+    prefill dispatches whose PROGRAM's trace noted the walk over the
+    touched experts (``app.paged_program_notes``), beside
+    ``prefill_dispatches``; its twin in the registry carries the path as a
+    label. With ``dense_max_tokens`` 0 the same three chunks go through the
+    grouped matmuls and count under ``ragged`` alone."""
+    if experts == "ragged":
+        _respec(monkeypatch, moe=dict(dense_max_tokens=0))
+    w = weights.make_weights(ref.weight_shapes(HF_TILES), seed=2**31 + 37)
+    app = _app(ref, w, hf=HF_TILES)
+    reg = telemetry.MetricsRegistry()
+    ad = PagedEngineAdapter(app, telemetry=reg)
+    assert app.paged_program_notes(1, 32) == frozenset()      # not traced yet
+    ad.add_requests([7], [P69])
+    st = ad.host_stats
+    assert st["prefill_dispatches"] == 3
+    assert st["prefill_dispatches_moe_walk"] == (3 if experts == "walk"
+                                                 else 0)
+    for width in (32, 8):
+        notes = app.paged_program_notes(1, width)
+        assert kernel_mode.experts_path(notes) == experts
+        assert notes <= {(k["site"], k["path"], k["reason"])
+                         for k in app.warmup_state()["kernels"]}
+    series = {s["labels"]["experts"]: s["value"] for s in reg.snapshot()[
+        "metrics"][tmetrics.PREFILL_DISPATCHES_TOTAL]["series"]}
+    assert series == {experts: 3}
+    # the decode step is no prefill dispatch, whatever its experts took
+    ad.step([7])
+    assert st["prefill_dispatches"] == 3
+
+
+#: a router wide enough that a 256-token chunk gives an expert few rows
+#: (256 x 4 / 64 = 16): the chunk's kernel, an expert against ITS rows
+HF_WIDE = dict(HF_TILES, router_num_experts=64, first_expert=8)
+
+
+def test_a_256_token_chunk_walks_the_touched_experts(ref):
+    """ISSUE 39 end to end: a prompt of 300 tokens goes through the one-row
+    program in two chunks of 256 (the second padded), each of which hands
+    the kernel its assignments sorted by expert; then decode. The served
+    logits equal the reference's at every position, the record names the
+    chunk's form, and both dispatches count as the walk."""
+    w = weights.make_weights(ref.weight_shapes(HF_WIDE), seed=2**31 + 39)
+    app = _app(ref, w, hf=HF_WIDE, seq_len=512, pa_num_blocks=160,
+               context_encoding_buckets=[32, 256])
+    prompt = np.random.default_rng(39).integers(1, 128, size=300).tolist()
+    ad = PagedEngineAdapter(app)
+    tap = LogitTap(app)
+    stream = [ad.add_requests([7], [prompt])[7]]
+    for _ in range(3):
+        stream.append(ad.step([7])[7])
+    assert tap.shapes[:2] == [(1, 256), (1, 256)]
+    fed = prompt + stream[:-1]
+    want = np.asarray(ref.forward(HF_WIDE, w, jnp.asarray([fed])))[0]
+    np.testing.assert_allclose(tap.logits(7, len(fed)), want, atol=ATOL,
+                               rtol=1e-4)
+    assert ("moe_decode", "pallas-interpret",
+            "pieces=1 of 128 rows=256 by expert in tiles of 128") \
+        in app.paged_program_notes(1, 256)
+    st = ad.host_stats
+    assert st["prefill_dispatches"] == st["prefill_dispatches_moe_walk"] == 2
 
 
 def _config(serve=None, **hf):
