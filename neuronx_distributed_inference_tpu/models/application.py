@@ -1141,20 +1141,10 @@ class PagedCausalLMApplication(CausalLMApplication):
     """
 
     def init_cache(self):
-        from ..modules.block_kv_cache import (BlockKVCacheManager,
-                                              BlockKVSpec, pool_page)
+        from ..modules.block_kv_cache import BlockKVCacheManager, pool_spec
         cfg = self.tpu_config
-        slots, lanes = pool_page(self.spec.gqa.num_kv_heads,
-                                 self.spec.head_dim, self.spec.gqa.tp)
-        bspec = BlockKVSpec(
-            # SSM-only layers carry no KV pages (recurrent/hybrid stacks)
-            num_layers=self.spec.num_attn_layers,
-            num_blocks=cfg.pa_num_blocks + 1,    # +1: reserved null block 0
-            block_size=cfg.pa_block_size,
-            num_kv_heads=slots,
-            head_dim=lanes,
-            dtype=self.spec.kv_dtype,
-        )
+        # heads a page for attention, a latent row a token for MLA
+        bspec = pool_spec(self.spec, cfg.pa_num_blocks, cfg.pa_block_size)
         self.kv_mgr = BlockKVCacheManager(
             bspec, self.mesh, enable_prefix_caching=cfg.is_prefix_caching)
         # single owner of the live (donated) buffers is the application; the

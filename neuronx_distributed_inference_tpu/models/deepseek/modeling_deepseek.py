@@ -3,8 +3,11 @@
 
 Covered deltas:
   * MLA (multi-head latent attention): q-lora + kv-lora compression with a
-    shared rope head (model_base._mla_qkv); K dim = nope+rope, V dim =
-    v_head_dim
+    shared rope head (model_base._mla_project); K dim = nope+rope, V dim =
+    v_head_dim. The paged pool keeps a token's LATENT row (kv_lora_rank +
+    rope values, modules/block_kv_cache.latent_lanes) and a decode step
+    attends in the latent space (ops/mla_decode.py); the contiguous cache
+    holds the expanded heads
   * yarn rope with mscale attention factor; softmax scale *= mscale(all_dim)^2
   * sigmoid router with e_score_correction_bias (selection only),
     group-limited greedy routing (n_group/topk_group), routed_scaling_factor
@@ -22,7 +25,8 @@ import numpy as np
 from ...config import InferenceConfig
 from ...modules.moe import MoESpec
 from ..family import DecoderFamily, register_family
-from ..model_base import DecoderSpec, MLASpec, spec_from_config
+from ..model_base import (DecoderSpec, MLASpec, mla_q_columns,
+                          spec_from_config)
 from ...parallel.layers import ParamSpec
 
 
@@ -76,6 +80,8 @@ class DeepseekFamily(DecoderFamily):
             qk_rope_head_dim=config.qk_rope_head_dim,
             v_head_dim=config.v_head_dim,
             q_lora_rank=getattr(config, "q_lora_rank", None),
+            # no LoRA scaling of the query or the latent (LongCat-Flash's)
+            q_scale=1.0, kv_scale=1.0,
         )
         scale = mla.qk_head_dim ** -0.5
         rope_scaling = getattr(config, "rope_scaling", None) or {}
@@ -137,6 +143,12 @@ class DeepseekFamily(DecoderFamily):
         def ident(w):
             return np.asarray(w)
 
+        def q_t(w):
+            # (in, heads x [nope | rope]) -> [all nope | all rope]
+            return mla_q_columns(t(w), spec.gqa.num_q_heads,
+                                 spec.mla.qk_nope_head_dim,
+                                 spec.mla.qk_rope_head_dim)
+
         def attn_layer(i: int) -> Dict[str, np.ndarray]:
             base = f"{p}.layers.{i}.self_attn"
             out = {
@@ -151,9 +163,9 @@ class DeepseekFamily(DecoderFamily):
             if spec.mla.q_lora_rank:
                 out["q_a_proj"] = t(get(f"{base}.q_a_proj.weight"))
                 out["q_a_norm"] = ident(get(f"{base}.q_a_layernorm.weight"))
-                out["q_b_proj"] = t(get(f"{base}.q_b_proj.weight"))
+                out["q_b_proj"] = q_t(get(f"{base}.q_b_proj.weight"))
             else:
-                out["q_proj"] = t(get(f"{base}.q_proj.weight"))
+                out["q_proj"] = q_t(get(f"{base}.q_proj.weight"))
             return out
 
         def dense_layer(i: int) -> Dict[str, np.ndarray]:

@@ -83,17 +83,31 @@ class MLASpec:
 
     KV is compressed to ``kv_lora_rank`` + a shared rope head; Q optionally
     through ``q_lora_rank``. K heads are [nope | rope], V heads are
-    ``v_head_dim`` wide."""
+    ``v_head_dim`` wide. What a token leaves in the paged pool is its LATENT
+    row, ``[normed c (kv_lora_rank) | rotated k_rope]``, one a token a layer
+    (``modules/block_kv_cache.latent_lanes``); the contiguous cache of the
+    unpaged application holds the expanded heads.
+
+    ``q_scale`` / ``kv_scale``: LongCat-Flash's ``mla_scale_q_lora`` /
+    ``mla_scale_kv_lora``, ``sqrt(hidden / rank)`` on the whole query and on
+    the normed latent; 1.0 = DeepSeek."""
 
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
     q_lora_rank: Optional[int] = None
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
 
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Values a token leaves in the paged pool, a layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -210,6 +224,17 @@ class DecoderSpec:
     # leading dense-MLP layers before the MoE stack (deepseek
     # first_k_dense_replace); only meaningful with moe set
     first_dense: int = 0
+    # what a layer holds and where the residual is joined (HF
+    # LongcatFlashDecoderLayer): ``sub_blocks`` > 1 makes a layer that many
+    # [attention, dense MLP] pairs, each a pre-norm block with its own two
+    # norms and its own cache layer (``num_attn_layers`` = num_layers x
+    # sub_blocks), and - with ``moe`` set - ONE routed block on a SHORTCUT:
+    # it reads the first pair's post-attention norm and its output joins the
+    # residual only at the END of the layer. ``intermediate_size`` is then
+    # the dense MLPs' width. Params: "layers" stacks the pairs layer-major
+    # (pair ``sub_blocks * l + j`` = cache layer), "moe_layers" the routed
+    # blocks; the walk is :func:`run_layers_shortcut`.
+    sub_blocks: int = 1
     # "rms" | "layernorm" (dbrx uses bias-free LayerNorm)
     norm_type: str = "rms"
     # no final pre-lm-head norm (GPT-1: the post-LN blocks already end
@@ -328,7 +353,7 @@ class DecoderSpec:
         """Layers that read/write the KV cache (SSM-only layers don't)."""
         pat = self.resolved_ssm_pattern
         if pat is None or self.ssm_parallel:
-            return self.num_layers
+            return self.num_layers * self.sub_blocks
         return self.num_layers - sum(pat)
 
     @property
@@ -341,6 +366,8 @@ class DecoderSpec:
         """Layers whose MLP is the routed block."""
         if self.moe is None:
             return 0
+        if self.sub_blocks > 1:
+            return self.num_layers
         if self.moe_pattern is not None:
             return sum(self.moe_pattern)
         return self.num_layers - self.first_dense
@@ -548,7 +575,15 @@ def decoder_param_specs(spec: DecoderSpec) -> Dict[str, Any]:
     if spec.embed_norm:
         out["embed_norm"] = ParamSpec((H,), P(), dt, "ones")
         out["embed_norm_b"] = ParamSpec((H,), P(), dt, "zeros")
-    if spec.moe is not None and spec.first_dense > 0:
+    if spec.sub_blocks > 1:
+        # several [attention, dense MLP] pairs a layer and one routed block
+        # on the shortcut (DecoderSpec.sub_blocks)
+        pairs = _attn_param_specs(spec, L * spec.sub_blocks)
+        pairs.update(_dense_mlp_param_specs(spec, L * spec.sub_blocks))
+        out["layers"] = pairs
+        if spec.moe is not None:
+            out["moe_layers"] = _moe_param_specs(spec, L)
+    elif spec.moe is not None and spec.first_dense > 0:
         n_dense, n_moe = spec.first_dense, spec.num_moe_layers
         dense = _attn_param_specs(spec, n_dense)
         dense.update(_dense_mlp_param_specs(spec, n_dense))
@@ -718,11 +753,14 @@ def _norm(spec: DecoderSpec, x, w, b=None):
     return rms_norm(x, w, spec.rms_eps, spec.norm_offset)
 
 
-def _mla_qkv(spec: DecoderSpec, h, layer_w, cos, sin):
+def _mla_project(spec: DecoderSpec, h, layer_w, cos, sin):
     """Multi-head Latent Attention projections (reference: models/deepseek/
-    modeling_deepseek.py MLA): Q through optional q-lora, KV through the
-    compressed latent + shared rope head. Returns q/k (B,T,Hq,qk_head_dim),
-    v (B,T,Hq,v_head_dim)."""
+    modeling_deepseek.py MLA; HF LongcatFlashMLA): Q through the optional
+    q-lora, KV compressed to the latent + the shared rope head. Returns
+    ``q_nope`` (B,T,Hq,nope), ``q_rot`` (B,T,Hq,rope) - scaled by
+    ``mla.q_scale`` and rotated - and the token's latent row ``lat``
+    (B,T,rank+rope) = ``[N(c) x mla.kv_scale | rot(k_rope)]``: what the
+    paged pool keeps and every K and V head is a projection of."""
     m = spec.mla
     nh = spec.gqa.num_q_heads
     b, t, _ = h.shape
@@ -732,24 +770,254 @@ def _mla_qkv(spec: DecoderSpec, h, layer_w, cos, sin):
         q = qlinear(qa, layer_w["q_b_proj"])
     else:
         q = qlinear(h, layer_w["q_proj"])
-    q = _shard(q.reshape(b, t, nh, m.qk_head_dim), AXIS_DP, None, AXIS_MP, None)
-    q_nope, q_rot = q[..., :m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
+    if m.q_scale != 1.0:
+        q = (q.astype(jnp.float32) * m.q_scale).astype(q.dtype)
+    # the query projection's columns are stored [every head's nope | every
+    # head's rope] (:func:`mla_q_columns`, applied by the loaders): the two
+    # parts are then contiguous, tile-aligned slices. Stored a head at a
+    # time, [nope | rope] of 128 + 64, the split strides heads of 192 lanes
+    # and the compiler relaid the WHOLE stacked projection out in front of
+    # the layer loop, 302 MB read and written a step at LongCat's widths
+    # (AOT, PR 40)
+    cut = nh * m.qk_nope_head_dim
+    q_nope = _shard(q[..., :cut].reshape(b, t, nh, m.qk_nope_head_dim),
+                    AXIS_DP, None, AXIS_MP, None)
+    q_rot = _shard(q[..., cut:].reshape(b, t, nh, m.qk_rope_head_dim),
+                   AXIS_DP, None, AXIS_MP, None)
+    q_rot = apply_rope(q_rot, cos, sin, interleaved=spec.rope_interleaved)
 
     ckv = qlinear(h, layer_w["kv_a_proj"])                  # (B,T,r+rope)
-    k_pass, k_rot = ckv[..., :m.kv_lora_rank], ckv[..., m.kv_lora_rank:]
-    kv = qlinear(rms_norm(k_pass, layer_w["kv_a_norm"], spec.rms_eps),
-                 layer_w["kv_b_proj"])
+    c = rms_norm(ckv[..., :m.kv_lora_rank], layer_w["kv_a_norm"],
+                 spec.rms_eps)
+    if m.kv_scale != 1.0:
+        c = (c.astype(jnp.float32) * m.kv_scale).astype(c.dtype)
+    k_rot = apply_rope(ckv[:, :, None, m.kv_lora_rank:], cos, sin,
+                       interleaved=spec.rope_interleaved)[:, :, 0]
+    return q_nope, q_rot, jnp.concatenate([c, k_rot], axis=-1)
+
+
+def mla_q_columns(w: np.ndarray, heads: int, nope: int,
+                  rope: int) -> np.ndarray:
+    """A checkpoint's query projection ``(..., heads x (nope + rope))``, a
+    head's ``[nope | rope]`` at a time, with its columns regrouped as
+    ``[every head's nope | every head's rope]``: what :func:`_mla_project`
+    reads (``q_b_proj``, or ``q_proj`` without a q-lora)."""
+    w = np.asarray(w)
+    by_head = w.reshape(w.shape[:-1] + (heads, nope + rope))
+    return np.concatenate(
+        [by_head[..., :nope].reshape(w.shape[:-1] + (heads * nope,)),
+         by_head[..., nope:].reshape(w.shape[:-1] + (heads * rope,))],
+        axis=-1)
+
+
+def _mla_kv_b(spec: DecoderSpec, layer_w):
+    """``kv_b_proj`` as (rank, Hq, nope + v): a head's K-nope and V
+    up-projections of the latent side by side."""
+    from ..modules.quantization import dequantize, is_quantized_leaf
+    w = layer_w["kv_b_proj"]
+    if is_quantized_leaf(w):
+        w = dequantize(w, spec.dtype)
+    m = spec.mla
+    return w.reshape(m.kv_lora_rank, spec.gqa.num_q_heads,
+                     m.qk_nope_head_dim + m.v_head_dim)
+
+
+def _mla_qkv(spec: DecoderSpec, h, layer_w, cos, sin):
+    """The EXPANDED heads of :func:`_mla_project`'s latent, for the
+    contiguous cache: q/k (B,T,Hq,qk_head_dim), v (B,T,Hq,v_head_dim). The
+    paged pool keeps the latent row itself (:func:`_mla_paged_block`)."""
+    m = spec.mla
+    nh = spec.gqa.num_q_heads
+    b, t, _ = h.shape
+    q_nope, q_rot, lat = _mla_project(spec, h, layer_w, cos, sin)
+    kv = qlinear(lat[..., :m.kv_lora_rank], layer_w["kv_b_proj"])
     kv = _shard(kv.reshape(b, t, nh, m.qk_nope_head_dim + m.v_head_dim),
                 AXIS_DP, None, AXIS_MP, None)
     k_nope, v = kv[..., :m.qk_nope_head_dim], kv[..., m.qk_nope_head_dim:]
-
-    q_rot = apply_rope(q_rot, cos, sin, interleaved=spec.rope_interleaved)
-    k_rot = apply_rope(k_rot[:, :, None, :], cos, sin,
-                       interleaved=spec.rope_interleaved)   # (B,T,1,rope)
-    k_rot = jnp.broadcast_to(k_rot, (b, t, nh, m.qk_rope_head_dim))
+    k_rot = jnp.broadcast_to(lat[:, :, None, m.kv_lora_rank:],
+                             (b, t, nh, m.qk_rope_head_dim))
     q = jnp.concatenate([q_nope, q_rot], axis=-1)
     k = jnp.concatenate([k_nope, k_rot], axis=-1)
     return q, k, v
+
+
+#: tokens of the cached prefix one step of :func:`_mla_attend` gathers and
+#: scores at once (whole pages)
+MLA_PREFIX_GROUP_TOKENS = 512
+
+#: a chunk of at least this many queries a row expands the prefix's latents
+#: through ``kv_b_proj``; a narrower one (and a decode step) attends in the
+#: latent space. Set from the v5e timings in PERF.md section 6 (PR 40): an
+#: absorbed query costs 2 x (rank + rope + rank) FLOP a head a cached token
+#: against 2 x (qk_head_dim + v_head_dim) expanded, and the expansion itself
+#: 2 x rank x Hq x (nope + v) a cached token whatever the width.
+MLA_EXPAND_MIN_QUERIES = 128
+
+
+def _mla_attend(spec: DecoderSpec, q_nope, q_rot, lat_new, w_kvb, pool, li,
+                block_table, positions, absorbed: bool):
+    """Attention of a paged step over the latent pool, in XLA: q_nope
+    (B,T,Hq,nope), q_rot (B,T,Hq,rope), ``lat_new`` (B,T,rank+rope) the
+    step's own latent rows (as stored: the pool's dtype), ``pool`` (L, N,
+    Bs, 1, lanes), ``positions`` (B,T) ascending a row. Returns (B,T,Hq,v).
+
+    Two parts, merged by their softmax statistics:
+
+    * the step's own tokens, EXPANDED through ``kv_b_proj`` and attended
+      causally by position (a decode step: its one token);
+    * the cached prefix - pool positions before the row's first - walked in
+      groups of :data:`MLA_PREFIX_GROUP_TOKENS` tokens by a loop whose trip
+      count is the longest live prefix of the step's rows, not the table's
+      width: a chunk at the head of its prompt gathers nothing. ``absorbed``
+      scores the group in the latent space (``W_UK`` folded into the query,
+      ``W_UV`` applied once after the softmax: rank + rope lanes a query
+      head a token, rank of them doubling as values); otherwise the group's
+      latents are expanded to heads first.
+    """
+    from ..modules import block_kv_cache as bkv
+    m = spec.mla
+    r, nope = m.kv_lora_rank, m.qk_nope_head_dim
+    b, t, nh, _ = q_nope.shape
+    dt = q_nope.dtype
+    neg = attn_ops.NEG_INF
+    scale = spec.scale
+    f32 = dict(preferred_element_type=jnp.float32)
+    lat_new = kv.dequantize_kv(lat_new, dt, spec.kv_scale)
+    c_new, kr_new = lat_new[..., :r], lat_new[..., r:r + m.qk_rope_head_dim]
+
+    def scores_of(qn, k_nope_, kr_):
+        return (jnp.einsum("bthd,bshd->bhts", qn, k_nope_, **f32)
+                + jnp.einsum("bthd,bsd->bhts", q_rot, kr_, **f32)) * scale
+
+    # the step's own tokens
+    kv_new = jnp.einsum("bsr,rhd->bshd", c_new, w_kvb, **f32).astype(dt)
+    s_self = scores_of(q_nope, kv_new[..., :nope], kr_new)
+    causal = positions[:, :, None] >= positions[:, None, :]
+    s_self = jnp.where(causal[:, None], s_self, neg)
+    m_s = jnp.max(s_self, axis=-1)                              # (B,Hq,T)
+    p_s = jnp.exp(s_self - m_s[..., None])
+    l_s = jnp.sum(p_s, axis=-1)
+    o_s = jnp.einsum("bhts,bshd->bhtd", p_s.astype(dt), kv_new[..., nope:],
+                     **f32)
+
+    # the cached prefix
+    bs = pool.shape[2]
+    pages = max(1, min(MLA_PREFIX_GROUP_TOKENS // bs, block_table.shape[1]))
+    group = pages * bs
+    start = positions[:, 0]
+    n_groups = (jnp.max(start) + group - 1) // group
+    pad = -block_table.shape[1] % pages
+    table = jnp.pad(block_table, ((0, 0), (0, pad)))
+    if absorbed:
+        q_lat = jnp.einsum("bthd,rhd->bthr", q_nope, w_kvb[..., :nope],
+                           **f32).astype(dt)
+    width = r if absorbed else m.v_head_dim
+
+    def step(j, carry):
+        m_p, l_p, acc = carry
+        rows = bkv.gather_layer_kv(
+            pool, li, jax.lax.dynamic_slice_in_dim(table, j * pages, pages,
+                                                   axis=1))[:, :, 0]
+        rows = kv.dequantize_kv(rows, dt, spec.kv_scale)
+        c, kr = rows[..., :r], rows[..., r:r + m.qk_rope_head_dim]
+        if absorbed:
+            s = (jnp.einsum("bthr,bsr->bhts", q_lat, c, **f32)
+                 + jnp.einsum("bthd,bsd->bhts", q_rot, kr, **f32)) * scale
+        else:
+            heads = jnp.einsum("bsr,rhd->bshd", c, w_kvb, **f32).astype(dt)
+            s = scores_of(q_nope, heads[..., :nope], kr)
+        valid = ((j * group + jnp.arange(group))[None, :]
+                 < start[:, None])[:, None, None, :]
+        m_c = jnp.maximum(m_p, jnp.max(jnp.where(valid, s, neg), axis=-1))
+        # a row with nothing valid so far keeps m at neg: its p must be 0,
+        # not exp(neg - neg)
+        p = jnp.where(valid, jnp.exp(s - m_c[..., None]), 0.0)
+        alpha = jnp.exp(m_p - m_c)
+        pv = (jnp.einsum("bhts,bsr->bhtr", p.astype(dt), c, **f32)
+              if absorbed else
+              jnp.einsum("bhts,bshd->bhtd", p.astype(dt), heads[..., nope:],
+                         **f32))
+        return (m_c, l_p * alpha + jnp.sum(p, axis=-1),
+                acc * alpha[..., None] + pv)
+
+    m_p, l_p, acc = jax.lax.fori_loop(0, n_groups, step, (
+        jnp.full((b, nh, t), neg, jnp.float32),
+        jnp.zeros((b, nh, t), jnp.float32),
+        jnp.zeros((b, nh, t, width), jnp.float32)))
+    if absorbed:
+        # W_UV after the softmax, on the prefix's normalised sum
+        acc = jnp.einsum(
+            "bhtr,rhd->bhtd",
+            (acc / jnp.maximum(l_p, 1e-30)[..., None]).astype(dt),
+            w_kvb[..., nope:], **f32) * l_p[..., None]
+    m_all = jnp.maximum(m_p, m_s)
+    w_p, w_s = jnp.exp(m_p - m_all), jnp.exp(m_s - m_all)
+    out = ((acc * w_p[..., None] + o_s * w_s[..., None])
+           / (l_p * w_p + l_s * w_s)[..., None])
+    return out.transpose(0, 2, 1, 3).astype(dt)
+
+
+def _mla_paged_block(spec: DecoderSpec, h, layer_w, pool, li, cos, sin,
+                     positions, slot_mapping, block_table):
+    """The paged attention of an MLA layer over the LATENT pool: project,
+    write the step's latent rows at ``slot_mapping``, attend. A decode step
+    (T = 1) attends in the latent space, on the kernel
+    (``ops/mla_decode.py``) where it engages; a chunk takes the form
+    :data:`MLA_EXPAND_MIN_QUERIES` gives its width, over exactly the prefix
+    its rows have cached. Returns the heads' outputs (B,T,Hq x v) and the
+    pool."""
+    from ..modules import block_kv_cache as bkv
+    from ..ops import mla_decode
+    m = spec.mla
+    b, t, _ = h.shape
+    q_nope, q_rot, lat = _mla_project(spec, h, layer_w, cos, sin)
+    w_kvb = _mla_kv_b(spec, layer_w)
+    lanes = pool.shape[4]
+    # the engagement record names what the pool keeps: a latent row a token
+    kernel_mode.note(
+        "latent_cache", "xla",
+        f"lanes={lanes} of {m.latent_dim} values bytes_a_token="
+        f"{pool.shape[0] * lanes * pool.dtype.itemsize} "
+        f"sub_blocks={pool.shape[0]}")
+    stored = kv.quantize_kv(lat, pool.dtype, spec.kv_scale)
+    pool = bkv.write_slots_at_layer(
+        pool, jnp.pad(stored, ((0, 0), (0, 0), (0, lanes - lat.shape[-1])))
+        [:, :, None, :], li, slot_mapping)
+    out = None
+    absorbed = t < MLA_EXPAND_MIN_QUERIES
+    if t == 1:
+        declined = ("decode_kernel=False" if spec.decode_kernel is False
+                    else mla_decode.declined(spec, pool, block_table))
+        if not declined:
+            out = mla_decode.mla_decode_attention(
+                q_nope[:, 0], q_rot[:, 0], stored[:, 0], w_kvb, pool, li,
+                positions[:, 0], block_table, scale=spec.scale,
+                rank=m.kv_lora_rank,
+                interpret=kernel_mode.pallas_interpret())[:, None]
+        kernel_mode.note(
+            "mla_decode", "xla" if declined else kernel_mode.kernel_path(),
+            declined or mla_decode.plan_note(pool, q_nope.shape[2]))
+    else:
+        kernel_mode.note(
+            "mla_prefill", "xla",
+            f"rows={b} width={t} prefix="
+            + ("absorbed" if absorbed else "expanded through kv_b_proj")
+            + f" in groups of {MLA_PREFIX_GROUP_TOKENS} tokens, own tokens "
+            "expanded")
+    if out is None:
+        nh = q_nope.shape[2]
+        # float32 temps a row: scores and their exponentials of a prefix
+        # group and of the step itself, the accumulator, the folded query
+        row_bytes = 4 * nh * t * (
+            2 * (MLA_PREFIX_GROUP_TOKENS + t)
+            + (2 * m.kv_lora_rank if absorbed else m.v_head_dim))
+
+        def attend(q_nope_, q_rot_, lat_, table_, pos_):
+            return _mla_attend(spec, q_nope_, q_rot_, lat_, w_kvb, pool, li,
+                               table_, pos_, absorbed)
+        out = map_row_groups(attend, row_bytes, q_nope, q_rot, stored,
+                             block_table, positions)
+    return out.reshape(b, t, -1), pool
 
 
 def attn_inputs(spec: DecoderSpec, position_ids, make_mask,
@@ -1012,6 +1280,21 @@ def _score_row_group(rows: int, row_bytes: int) -> int:
     return max(d for d in range(1, min(rows, fit) + 1) if rows % d == 0)
 
 
+def map_row_groups(fn, row_bytes: int, *args):
+    """``fn(*args)``, every argument's leading axis the step's rows, taken
+    :func:`_score_row_group` rows at a time, one group after another, where
+    all rows at once (``row_bytes`` of temps a row) would outgrow the budget:
+    the temps stay a group's worth."""
+    b = args[0].shape[0]
+    group = _score_row_group(b, row_bytes)
+    if group == b:
+        return fn(*args)
+    out = jax.lax.map(
+        lambda xs: fn(*xs),
+        tuple(x.reshape((b // group, group) + x.shape[1:]) for x in args))
+    return out.reshape((b,) + out.shape[2:])
+
+
 @jax.named_scope("attn")
 def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                 is_local, seq_ids, positions, phase: str, *,
@@ -1057,7 +1340,13 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
         return (layer_w["alibi_slopes"],
                 jnp.arange(n_kv, dtype=jnp.int32)[None, :])
     pending = None
-    if spec.mla is not None:
+    latent = spec.mla is not None and phase == "paged"
+    if latent:
+        # the latent pool: k_full holds a row a token, v_full nothing
+        attn_out, k_full = _mla_paged_block(
+            spec, h, layer_w, k_full, li, cos, sin, positions, slot_mapping,
+            block_table)
+    elif spec.mla is not None:
         q, k, v = _mla_qkv(spec, h, layer_w, cos, sin)
     else:
         qkv = qlinear(h, layer_w["qkv_proj"])
@@ -1130,7 +1419,9 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
             q = jnp.where(is_local, q, q_t) \
                 if spec.layer_pattern is not None else q_t
 
-    if phase == "paged":
+    if latent:
+        pass            # attended above, over the latent pool
+    elif phase == "paged":
         from ..modules import block_kv_cache as bkv
         # a pool with more head slots than the model has kv heads
         # (bkv.pool_kv_heads): q, k and v grow zero heads to match, and the
@@ -1202,20 +1493,13 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
             # full-batch chunk of a wide batch with many heads) the rows go
             # through in groups, one after another, so the temps stay a
             # group's worth
-            b_ = q.shape[0]
-            group = _score_row_group(
-                b_, 4 * (g.num_q_heads // g.tp) * q.shape[1]
-                * block_table.shape[1] * k_full.shape[2]
-            ) if not spec.alibi else b_
-            if group == b_:
+            if spec.alibi:
                 attn_out = gathered_mha(q, block_table, mask)
             else:
-                def split(x):
-                    return x.reshape((b_ // group, group) + x.shape[1:])
-                attn_out = jax.lax.map(
-                    lambda xs: gathered_mha(*xs),
-                    (split(q), split(block_table), split(mask)))
-                attn_out = attn_out.reshape((b_,) + attn_out.shape[2:])
+                attn_out = map_row_groups(
+                    gathered_mha, 4 * (g.num_q_heads // g.tp) * q.shape[1]
+                    * block_table.shape[1] * k_full.shape[2],
+                    q, block_table, mask)
         if grown:
             attn_out = attn_out[:, :, :n_q]
     elif phase == "prefill":
@@ -1485,7 +1769,7 @@ def _paged_kernel_declined(spec: DecoderSpec) -> str:
     return ("alibi" if spec.alibi
             else "decode_kernel=False" if spec.decode_kernel is False
             else "" if decode_attention.supports(spec, 1, paged=True)
-            else "unsupported geometry (mla / head_dim / attn_chunk)")
+            else "unsupported geometry (head_dim / attn_chunk)")
 
 
 def _paged_pool_fold(spec: DecoderSpec, cache, hidden, phase: str,
@@ -1498,6 +1782,7 @@ def _paged_pool_fold(spec: DecoderSpec, cache, hidden, phase: str,
     the whole pool once a LAYER, here it is once a step - the copies a step
     of such a model pays already (ROADMAP A5)."""
     if (phase != "paged" or hidden.shape[1] != 1
+            or spec.mla is not None      # a latent row a token: no heads
             or spec.head_dim >= 128      # whole vregs: stored folded or not
             or cache["k"].shape[4] != spec.head_dim      # folded already
             or _paged_kernel_declined(spec)):
@@ -1561,6 +1846,19 @@ def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
         k_f, v_f = jax.lax.optimization_barrier((out[1]["k"], out[1]["v"]))
         return (out[0], dict(out[1], k=pool_as(k_f, heads),
                              v=pool_as(v_f, heads))) + out[2:]
+    if spec.sub_blocks > 1:
+        if replacements is not None or deepstack is not None \
+                or side is not None or spec.capture:
+            raise NotImplementedError(
+                "a layer of several sub-blocks (DecoderSpec.sub_blocks) has "
+                "no tap points, deepstack features or chunked side-buffer "
+                "decode")
+        return run_layers_shortcut(
+            spec, params, cache, hidden, ai, seq_ids, positions, phase,
+            identity_seq_ids=identity_seq_ids,
+            arange_positions=arange_positions, slot_mapping=slot_mapping,
+            block_table=block_table, adapter_ids=adapter_ids,
+            kv_view=kv_view, prefill_lens=prefill_lens)
     if spec.ssm is not None:
         refuse_recurrent([
             replacements is not None and "tensor capture/replacement",
@@ -1770,6 +2068,79 @@ def run_layer_slice(spec: DecoderSpec, layer_params, kf, vf, hidden, ai, *,
         xs = xs + (deepstack,)
     (hidden, kf, vf), caps = jax.lax.scan(body, (hidden, kf, vf), xs)
     return hidden, kf, vf, caps
+
+
+def run_layers_shortcut(spec: DecoderSpec, params, cache, hidden, ai,
+                        seq_ids, positions, phase: str, *,
+                        identity_seq_ids=False, arange_positions=False,
+                        slot_mapping=None, block_table=None,
+                        adapter_ids=None, kv_view=None, prefill_lens=None):
+    """The walk of a stack whose layer is ``spec.sub_blocks`` [attention,
+    dense MLP] pairs and one routed block on a shortcut (HF
+    LongcatFlashDecoderLayer): with ``x`` the layer's input,
+
+        a0 = x + Attn_0(N(x));   u = N'(a0);   s = MoE(u)
+        b0 = a0 + MLP_0(u);      a1 = b0 + Attn_1(N(b0))
+        y  = a1 + MLP_1(N'(a1)) + s
+
+    One scan over the layers; pair ``j`` of layer ``l`` reads and writes
+    cache layer ``sub_blocks * l + j``. The expert leaves a custom call
+    reads in place stay out of the scan's ``xs`` (``moe.stack_leaves``), as
+    in :func:`run_layer_slice`; a paged decode step counts its routing into
+    ``moe_tally``. Returns (hidden, cache, per-layer outputs)."""
+    n, L = spec.sub_blocks, spec.num_layers
+    pairs = params["layers"]
+    experts = params.get("moe_layers", {})
+    tokens = hidden.shape[0] * hidden.shape[1]
+    in_place = (moe_mod.stack_leaves(spec.moe, tokens, experts)
+                if spec.moe is not None else ())
+    sliced = {k: a for k, a in experts.items() if k not in in_place}
+    live = (slot_mapping >= 0 if phase == "paged" and hidden.shape[1] == 1
+            and spec.moe is not None else None)
+
+    def body(carry, xs):
+        x, kf, vf = carry
+        moe_w, l = xs
+        moe_w = {**moe_w, **{k: moe_mod.LayerOfStack(experts[k], l)
+                             for k in in_place}}
+        caps: Dict[str, Any] = {}
+        shortcut = None
+        for j in range(n):
+            # ONE pair's slice, by its own index: XLA fuses it into the
+            # pair's matmuls as it fuses a scan's xs. Scanned as (L, n, ...)
+            # the layer's slice of BOTH pairs was materialised first, a
+            # copy of every attention and dense-MLP weight a step (0.84 GB
+            # of temps at LongCat's widths: AOT, PR 40)
+            w = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, l * n + j,
+                                                       keepdims=False),
+                pairs)
+            h, kf, vf, _ = _attn_block(
+                spec, _norm(spec, x, w["input_norm"]), w, kf, vf, l * n + j,
+                ai, False, seq_ids, positions, phase,
+                identity_seq_ids=identity_seq_ids,
+                arange_positions=arange_positions,
+                slot_mapping=slot_mapping, block_table=block_table,
+                adapter_ids=adapter_ids, kv_view=kv_view,
+                prefill_lens=prefill_lens)
+            x = x + _shard(h, AXIS_DP, None, None)
+            u = _norm(spec, x, w["post_norm"])
+            if j == 0 and spec.moe is not None:
+                tally = [] if live is not None else None
+                shortcut = _mlp_block(spec, u, moe_w, "moe", adapter_ids,
+                                      phase=phase, tally=tally, live=live)
+                if tally:
+                    caps["moe_tally"] = tally[0]
+            x = x + _shard(_mlp_block(spec, u, w, "dense", adapter_ids,
+                                      phase=phase), AXIS_DP, None, None)
+        if shortcut is not None:
+            x = x + shortcut
+        return (x, kf, vf), caps
+
+    (hidden, kf, vf), caps = jax.lax.scan(
+        body, (hidden, cache["k"], cache["v"]),
+        (sliced, jnp.arange(L, dtype=jnp.int32)))
+    return hidden, {**cache, "k": kf, "v": vf}, caps
 
 
 def _state_rows(arr, li: int, slots):
@@ -2293,7 +2664,8 @@ def paged_forward_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
         # a decode step over expert layers counts what its routing touched
         # and what its expert path read (one row a layer where the walk
         # scans); the adapter fetches the sums with the tokens
-        out["moe_tally"] = side["moe_tally"].reshape(-1, 3).sum(axis=0)
+        out["moe_tally"] = side["moe_tally"].reshape(
+            -1, side["moe_tally"].shape[-1]).sum(axis=0)
     if tpu_cfg.output_logits:
         out["logits"] = _lm_head(spec, params, hidden)[..., :spec.vocab_size]
     if _coupled_mode(tpu_cfg, row_seeds):

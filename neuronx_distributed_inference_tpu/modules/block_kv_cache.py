@@ -9,6 +9,10 @@ Device layout:
   sharded P(None, None, None, ("ep","tp"), None) — heads sharded, blocks
   replicated across dp (each dp shard could own a block range; that variant
   arrives with attention-DP decode).
+  A LATENT pool (MLA: deepseek_v2/v3, longcat_flash) keeps ONE row a token
+  a layer in "k", (num_layers, num_blocks, block_size, 1, latent_lanes), and
+  a "v" of no lanes: allocator, tables, slot mapping, preemption and release
+  are the same (a block is still block_size tokens of every layer).
 
 In-graph ops (pure, used inside the jitted step):
   * ``write_slots``       — scatter new K/V at flat slot ids
@@ -52,11 +56,32 @@ class BlockKVSpec:
     num_kv_heads: int          # padded/replicated per GQASharding
     head_dim: int
     dtype: jnp.dtype = jnp.bfloat16
+    # lanes of a V slot where they differ from K's. A LATENT pool (MLA:
+    # :func:`latent_page`) keeps one row a token in "k" and NOTHING in "v"
+    # (0 lanes): the values are lanes of the same row
+    v_head_dim: Optional[int] = None
 
     @property
     def shape(self) -> Tuple[int, ...]:
         return (self.num_layers, self.num_blocks, self.block_size,
                 self.num_kv_heads, self.head_dim)
+
+    @property
+    def v_shape(self) -> Tuple[int, ...]:
+        return self.shape[:4] + (self.head_dim if self.v_head_dim is None
+                                 else self.v_head_dim,)
+
+    @property
+    def is_latent(self) -> bool:
+        """A latent pool: one row a token in "k", no V, no head axis."""
+        return self.v_head_dim == 0
+
+    @property
+    def bytes_per_token(self) -> int:
+        """Bytes a token takes in the pool, all layers, K and V."""
+        return (self.num_layers * self.num_kv_heads
+                * (self.shape[4] + self.v_shape[4])
+                * jnp.dtype(self.dtype).itemsize)
 
     def blocks_for(self, seq_len: int) -> int:
         return -(-seq_len // self.block_size)
@@ -112,6 +137,48 @@ def pool_page(num_kv_heads: int, head_dim: int, tp: int = 1
     return heads // fold, fold * head_dim
 
 
+#: lanes of a vreg: a latent row is stored in whole ones
+LATENT_LANE_TILE = 128
+
+
+def latent_lanes(latent_dim: int) -> int:
+    """Lanes of a token's row in a LATENT pool (Multi-head Latent
+    Attention: ``[normed c | rotated k_rope]``, ``latent_dim`` values a
+    token a layer - 576 for DeepSeek-V2/V3 and LongCat-Flash against 64
+    heads x (192 + 128) expanded): ``latent_dim`` rounded up to whole
+    vregs, 640. The device keeps an array's minor dimension in tiles of 128
+    lanes whatever is declared, so the 64 lanes of padding cost no bytes
+    that a row of 576 would not have cost too; declared, every consumer (the
+    slot write, the gather, the decode kernel's page copies and its two
+    matmuls) sees whole tiles. The padding holds zeros."""
+    return -(-latent_dim // LATENT_LANE_TILE) * LATENT_LANE_TILE
+
+
+def latent_page(latent_dim: int) -> Tuple[int, int, int]:
+    """``(slots, K lanes, V lanes)`` of a page of a latent pool: ONE slot a
+    token, :func:`latent_lanes` wide, and no V (its lanes are ``c``, the
+    first ``kv_lora_rank`` of the same row)."""
+    return 1, latent_lanes(latent_dim), 0
+
+
+def pool_spec(spec, num_blocks: int, block_size: int) -> BlockKVSpec:
+    """The pool a paged application allocates for the decoder ``spec``
+    (``models.model_base.DecoderSpec``) at ``num_blocks`` usable blocks:
+    latent rows for an MLA spec (:func:`latent_page`), else the kv heads'
+    page (:func:`pool_page`); one more block, the null block 0."""
+    if spec.mla is not None:
+        slots, lanes, v_lanes = latent_page(spec.mla.latent_dim)
+    else:
+        slots, lanes = pool_page(spec.gqa.num_kv_heads, spec.head_dim,
+                                 spec.gqa.tp)
+        v_lanes = None
+    return BlockKVSpec(
+        # SSM-only layers carry no KV pages (recurrent/hybrid stacks)
+        num_layers=spec.num_attn_layers, num_blocks=num_blocks + 1,
+        block_size=block_size, num_kv_heads=slots, head_dim=lanes,
+        dtype=spec.kv_dtype, v_head_dim=v_lanes)
+
+
 def block_cache_pspec() -> P:
     return P(None, None, None, AXIS_MP, None)
 
@@ -119,10 +186,12 @@ def block_cache_pspec() -> P:
 def init_block_cache(spec: BlockKVSpec, mesh: Optional[Mesh] = None):
     # born on its sharding: at tp=4 each chip allocates its quarter of the
     # pool, nothing is staged whole on the default device first
-    sharding = (NamedSharding(mesh, block_cache_pspec())
-                if mesh is not None else None)
-    return {name: jnp.zeros(spec.shape, spec.dtype, device=sharding)
-            for name in ("k", "v")}
+    # a latent pool has no head axis to shard: every shard's heads are
+    # projections of the same row
+    pspec = P() if spec.is_latent else block_cache_pspec()
+    sharding = NamedSharding(mesh, pspec) if mesh is not None else None
+    return {"k": jnp.zeros(spec.shape, spec.dtype, device=sharding),
+            "v": jnp.zeros(spec.v_shape, spec.dtype, device=sharding)}
 
 
 # ---------------------------------------------------------------------------

@@ -48,10 +48,14 @@ class KVCacheSpec:
     batch_size: int
     max_seq_len: int
     num_kv_heads: int     # padded/replicated per GQASharding
-    head_dim: int         # K head dim (MLA: qk_nope + qk_rope)
+    head_dim: int         # K head dim (MLA: qk_nope + qk_rope, expanded)
     dtype: jnp.dtype = jnp.bfloat16
     window: int = 0       # >0: rolling sliding-window cache of this length
-    v_head_dim: Optional[int] = None   # MLA: v dim != k dim (deepseek)
+    # MLA: v dim != k dim. THIS (contiguous) cache holds an MLA model's
+    # EXPANDED heads; the paged pool holds its latent rows instead
+    # (modules/block_kv_cache.latent_page: 576 values a token a layer
+    # against heads x (192 + 128))
+    v_head_dim: Optional[int] = None
 
     @property
     def cache_len(self) -> int:

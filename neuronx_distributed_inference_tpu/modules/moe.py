@@ -50,6 +50,11 @@ Design:
     the block returns their part of the sum only (one chip's part of an
     expert-parallel layer, run without its exchange: what the other chips
     hold is nobody's here). The shared expert is whole on every share.
+  * Zero-compute experts (``zero_experts``, LongCat-Flash): the router's
+    last columns are identity experts. No stack holds them, so to the three
+    expert paths a pick of one is a pick of an expert held elsewhere; the
+    block adds ``(sum of the picked identity weights) x input`` itself,
+    once, for every row it computes (:func:`zero_expert_term`).
 
 All routing math in fp32 (router logits decide tokens; bf16 tie-breaks
 diverge from HF goldens).
@@ -135,15 +140,29 @@ class MoESpec:
     # the shared expert's output scaled by sigmoid(x . shared_gate_w) per
     # token (HF Qwen2MoeSparseMoeBlock / Qwen3NextSparseMoeBlock)
     shared_gated: bool = False
+    # zero-compute experts (LongCat-Flash ``zero_expert_num``, type
+    # identity): the LAST ``zero_experts`` of the router's ``num_experts``
+    # columns have no matrices; a pick of one adds ``weight x input``. The
+    # expert stacks never hold them, and their term is the token's own
+    # chip's: every share adds it for the rows it computes, like a shared
+    # expert
+    zero_experts: int = 0
+
+    @property
+    def num_routed(self) -> int:
+        """Router columns that are experts with weights."""
+        return self.num_experts - self.zero_experts
 
     @property
     def num_held(self) -> int:
-        """Experts the weights hold: all, or the share."""
-        return self.held_experts or self.num_experts
+        """Experts the weights hold: all that have weights, or the share."""
+        return self.held_experts or self.num_routed
 
     @property
     def holds_share(self) -> bool:
-        return 0 < self.held_experts < self.num_experts
+        """The router scores columns the stacks do not hold: other chips'
+        experts, identity experts, or both."""
+        return self.num_held < self.num_experts
 
 
 # the per-expert leaves of a layer: what the ragged path and the few-token
@@ -323,7 +342,7 @@ def held_combine(moe: MoESpec, top_vals: jnp.ndarray,
     combine = combine_matrix(moe.num_experts, top_vals, top_idx)
     if moe.holds_share:
         combine = combine[..., moe.first_expert:
-                          moe.first_expert + moe.held_experts]
+                          moe.first_expert + moe.num_held]
     return combine
 
 
@@ -346,6 +365,25 @@ def share_tally(moe: MoESpec, top_idx: jnp.ndarray,
         jnp.where(mine, local, held).reshape(-1)].add(1, mode="drop")
     return jnp.stack([jnp.sum(hits > 0), jnp.sum(hits),
                       held if read is None else read]).astype(jnp.int32)
+
+
+def zero_expert_weight(moe: MoESpec, top_vals: jnp.ndarray,
+                       top_idx: jnp.ndarray) -> jnp.ndarray:
+    """Per token (B,T) float32, the sum of its picks' weights that fell to
+    identity experts (the router's last ``zero_experts`` columns)."""
+    return jnp.sum(jnp.where(top_idx >= moe.num_routed, top_vals, 0.0),
+                   axis=-1)
+
+
+def zero_tally(moe: MoESpec, top_idx: jnp.ndarray,
+               live: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """int32 ``[picks, identity picks]`` of one routing over the ``live``
+    rows: every top-k pick, and those that cost nothing."""
+    zero = top_idx >= moe.num_routed
+    picks = jnp.ones_like(zero)
+    if live is not None:
+        zero, picks = zero & live[..., None], picks & live[..., None]
+    return jnp.stack([jnp.sum(picks), jnp.sum(zero)]).astype(jnp.int32)
 
 
 def _glu(moe: MoESpec, gate: jnp.ndarray, up: jnp.ndarray) -> jnp.ndarray:
@@ -490,6 +528,36 @@ def experts_ragged(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
     return y.astype(dt)
 
 
+def ragged_row_bytes(moe: MoESpec, t: int, h: int, itemsize: int) -> int:
+    """Bytes of temps :func:`experts_ragged` holds for ONE row of ``t``
+    tokens: every assignment's token copy and output (``h`` wide, the
+    output in float32 too for the combine) and its two float32
+    intermediates."""
+    return t * moe.top_k * (h * (2 * itemsize + 4)
+                            + moe.intermediate_size * (2 * 4 + itemsize))
+
+
+def experts_ragged_by_rows(moe: MoESpec, x: jnp.ndarray,
+                           top_vals: jnp.ndarray, top_idx: jnp.ndarray,
+                           *weights, layer=None) -> jnp.ndarray:
+    """:func:`experts_ragged`, the step's rows a group at a time where all
+    of them at once would outgrow the budget a paged step's attention
+    scores are held to (``model_base._score_row_group``): a full-batch pack
+    of 32 rows x 256 tokens x top-12 at a hidden size of 6144 is 98,304
+    token copies, 2.25 GB of float32 outputs alone. Each group sorts and
+    multiplies its own assignments against the same leaves; the sum is the
+    same sum."""
+    from ..models.model_base import _score_row_group, map_row_groups
+    b, t, h = x.shape
+    row_bytes = ragged_row_bytes(moe, t, h, x.dtype.itemsize)
+    group = _score_row_group(b, row_bytes)
+    if group < b:
+        kernel_mode.note("moe_ragged", "row-groups", f"{group} of {b} rows")
+    return map_row_groups(
+        lambda *rows: experts_ragged(moe, *rows, *weights, layer=layer),
+        row_bytes, x, top_vals, top_idx)
+
+
 def experts_touched(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
                     top_idx: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
                     wd: jnp.ndarray, layer) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -522,16 +590,27 @@ def moe_block(moe: MoESpec, x: jnp.ndarray, layer_w: Dict[str, Any],
               live: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Full MoE block: route + experts (+ shared experts). x (B,T,H).
     ``tally``: a list a layer walk hands in to collect, per expert layer,
-    :func:`share_tally` of this routing over the ``live`` rows."""
+    :func:`share_tally` + :func:`zero_tally` of this routing over the
+    ``live`` rows: int32 ``[touched, assigned, read, picks, identity
+    picks]``."""
     router_bias = layer_w.get("router_bias") if moe.has_router_bias else None
     top_vals, top_idx = route(moe, x, layer_w["router"], router_bias)
     if moe.holds_share:
         kernel_mode.note("moe_share", "xla",
-                         f"held={moe.held_experts} of {moe.num_experts} "
-                         f"from {moe.first_expert} top_k={moe.top_k}")
+                         f"held={moe.num_held} of {moe.num_experts} "
+                         f"from {moe.first_expert} top_k={moe.top_k}"
+                         + (f" zero={moe.zero_experts}"
+                            if moe.zero_experts else ""))
     y, read = _experts(moe, x, top_vals, top_idx, layer_w, phase)
+    if moe.zero_experts:
+        # the identity experts' term, the token's own chip's
+        y = (y.astype(jnp.float32)
+             + zero_expert_weight(moe, top_vals, top_idx)[..., None]
+             * x.astype(jnp.float32)).astype(y.dtype)
     if tally is not None:
-        tally.append(share_tally(moe, top_idx, live, read))
+        tally.append(jnp.concatenate([
+            share_tally(moe, top_idx, live, read),
+            zero_tally(moe, top_idx, live)]))
     return _shared_experts(moe, x, y, layer_w)
 
 
@@ -551,7 +630,7 @@ def _experts(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
         # the layer loop decided by the same rules (stack_leaves)
         if ragged:
             kernel_mode.note("moe_ragged", "stacked")
-            return experts_ragged(
+            return experts_ragged_by_rows(
                 moe, x, top_vals, top_idx,
                 *(None if a is None else a.stack
                   for a in (wg, wu, wd, *biases)), layer=wg.layer), None
@@ -564,8 +643,8 @@ def _experts(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
     if ragged:
         kernel_mode.note("moe_ragged", "sliced",
                          sliced_reason(wg) or "the caller cut the layer out")
-        return experts_ragged(moe, x, top_vals, top_idx, wg, wu, wd,
-                              *biases), None
+        return experts_ragged_by_rows(moe, x, top_vals, top_idx, wg, wu, wd,
+                                      *biases), None
     kernel_mode.note("moe_decode", "xla",
                      moe_decode.declined(moe, wg, tokens)
                      or "the caller cut the layer out")

@@ -791,9 +791,11 @@ def paged_dispatch_plan(hq: int, d: int, k_pages: jnp.ndarray,
 
 def supports(spec, phase_t: int, paged: bool = False) -> bool:
     """Kernel admission (reference analog: TKG kernel enablement flags,
-    models/config.py:417-567): single active token, no MLA (different head
-    dims; the kernel streams K and V with one block shape), no chunked
-    attention (the kernel masks by window, not chunk boundaries — llama4's
+    models/config.py:417-567): single active token, no MLA here (its paged
+    pool holds a latent row a token, not heads, and a decode step attends in
+    the latent space on a kernel of its own, ``ops/mla_decode.py``; over the
+    contiguous cache its expanded K and V heads differ in width and take the
+    XLA path), no chunked attention (the kernel masks by window, not chunk boundaries — llama4's
     chunked local layers take the XLA path). Heads of 64 lanes (on the paged
     path two to a 128-lane row of a page, ``paged_pool_fold``) and of 128;
     the PAGED kernel (``paged``) also heads of 256, a kv row of two vregs:

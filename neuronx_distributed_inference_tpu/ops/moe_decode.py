@@ -58,6 +58,16 @@ from jax.experimental.pallas import tpu as pltpu
 #: scoped default of 16 is raised to the slots plus what the step's rows
 #: need beside them (:func:`rows_vmem_bytes`), which may be as much again.
 MOE_WEIGHT_VMEM_BYTES = 24 * 1024 * 1024
+#: VMEM a step's rows may take beside the slots (:func:`rows_vmem_bytes`):
+#: a one-row chunk of 256 tokens at a hidden size of 6144 keeps 25 MB of
+#: float32 rows and result and 15 MB of two tiles' products (39.6 MiB:
+#: LongCat-Flash, PR 40); slots and rows together stay at half of a core's
+#: 128 MiB
+MOE_ROWS_VMEM_BYTES = 40 * 1024 * 1024
+#: most rows of a step on the walk: timed at 256 and 512 (PERF.md section 6,
+#: PR 39). A full-batch pack of 1024 rows at a hidden size of 2048 would fit
+#: the bytes above too and has met no clock: it keeps the grouped matmuls
+MOE_WALK_MAX_ROWS = 512
 #: lanes of a vreg: a piece's width and both matrix dimensions are whole
 #: multiples of it
 LANES = 128
@@ -145,8 +155,8 @@ def declined(moe, wg: Any, tokens: int = 1) -> str:
     plan = moe_decode_plan(h, i, wg.dtype)
     if plan is None:
         return f"experts of {h} x {i} are not whole {LANES}-lane tiles"
-    if rows_vmem_bytes(tokens, h, wg.shape[-3], plan,
-                       wg.dtype) > MOE_WEIGHT_VMEM_BYTES:
+    if tokens > MOE_WALK_MAX_ROWS or rows_vmem_bytes(
+            tokens, h, wg.shape[-3], plan, wg.dtype) > MOE_ROWS_VMEM_BYTES:
         return f"{tokens} rows of {h} do not fit VMEM beside the slots"
     return ""
 
@@ -332,7 +342,8 @@ def _checked_plan(n: int, h: int, wg) -> Tuple[MoEDecodePlan, int]:
     """The plan of a call of ``n`` rows and the VMEM its rows need."""
     plan = moe_decode_plan(h, wg.shape[3], wg.dtype)
     vmem_rows = plan and rows_vmem_bytes(n, h, wg.shape[1], plan, wg.dtype)
-    if plan is None or vmem_rows > MOE_WEIGHT_VMEM_BYTES:
+    if plan is None or n > MOE_WALK_MAX_ROWS \
+            or vmem_rows > MOE_ROWS_VMEM_BYTES:
         raise ValueError(
             f"moe expert walk: {n} rows over experts of {h} x {wg.shape[3]} "
             f"{wg.dtype} (moe_decode.declined says what the kernel takes)")

@@ -425,13 +425,17 @@ class _AdapterTelemetry:
                 engine=self.engine)
 
     def on_moe_tally(self, touched: int, slots: int, assigned: int,
-                     read: int):
+                     read: int, picks: int, zero: int):
         reg = self.registry
         if reg.enabled:
             c = tmetrics.moe_experts_counter(reg)
             for count, n in (("touched", touched), ("slots", slots),
                              ("assigned", assigned), ("read", read)):
                 c.inc(n, engine=self.engine, count=count)
+            c = tmetrics.moe_assignments_counter(reg)
+            for kind, n in (("held", assigned), ("zero", zero),
+                            ("absent", picks - assigned - zero)):
+                c.inc(n, engine=self.engine, kind=kind)
 
     def on_drain(self, cause: str):
         reg = self.registry
@@ -917,7 +921,7 @@ class _EngineAdapterBase:
             tally = out.get("moe_tally")
             if tally is not None:
                 # a decode step over expert layers: what its routing touched
-                # and its expert path read, counted on the device (three
+                # and its expert path read, counted on the device (five
                 # int32)
                 self._count_moe_tally(np.asarray(tally))
         self.host_stats["blocking_fetches"] += 1
@@ -926,23 +930,28 @@ class _EngineAdapterBase:
         return toks[:b] if rows is None else toks[rows]
 
     def _count_moe_tally(self, tally: np.ndarray):
-        """``[touched, assigned, read]`` of one decode step
-        (``moe.share_tally`` summed over the expert layers) into
-        ``host_stats``; the slots they are counted over are held experts x
-        expert layers, once a step, and ``moe_experts_skipped`` is the
-        slots the step did not read (a reader that sums and divides cannot
-        subtract)."""
+        """``[touched, assigned, read, picks, identity picks]`` of one
+        decode step (``moe.share_tally`` + ``moe.zero_tally`` summed over
+        the expert layers) into ``host_stats``; the slots the first three
+        are counted over are held experts x expert layers, once a step, and
+        ``moe_experts_skipped`` is the slots the step did not read (a
+        reader that sums and divides cannot subtract). ``moe_assignments``
+        is every top-k pick of the live rows, ``moe_assignments_zero`` those
+        that fell to identity experts (``MoESpec.zero_experts``)."""
         spec = self.app.spec
         slots = spec.moe.num_held * spec.num_moe_layers
-        touched, assigned, read = (int(n) for n in tally)
+        touched, assigned, read, picks, zero = (int(n) for n in tally)
         st = self.host_stats
         for key, n in (("moe_experts_touched", touched),
                        ("moe_assignments_held", assigned),
                        ("moe_experts_read", read),
                        ("moe_experts_skipped", slots - read),
-                       ("moe_expert_slots", slots)):
+                       ("moe_expert_slots", slots),
+                       ("moe_assignments", picks),
+                       ("moe_assignments_zero", zero)):
             st[key] = st.get(key, 0) + n
-        self.telemetry.on_moe_tally(touched, slots, assigned, read)
+        self.telemetry.on_moe_tally(touched, slots, assigned, read, picks,
+                                    zero)
 
     def _note_gap(self, states: Sequence[_SeqState]):
         """A decode step's tokens for ``states`` just became host-visible.

@@ -34,6 +34,7 @@ QUEUE_WAIT_SECONDS = "nxdi_queue_wait_seconds"          # tenant, outcome
 STEPS_PER_FETCH = "nxdi_steps_per_fetch"                # engine
 OVERLAPPED_DISPATCHES_TOTAL = "nxdi_overlapped_dispatches_total"   # engine
 MOE_EXPERTS_TOTAL = "nxdi_moe_experts_total"            # engine, count
+MOE_ASSIGNMENTS_TOTAL = "nxdi_moe_assignments_total"    # engine, kind
 PIPELINE_DRAINS_TOTAL = "nxdi_pipeline_drains_total"    # engine, cause
 
 # -- serving resilience (serving.py + resilience/) --------------------------
@@ -246,6 +247,17 @@ def overlapped_dispatches_counter(reg):
         "Decode dispatches enqueued while the previous step's tokens were "
         "still unfetched (the host's pass ran under the device's step)",
         labels=("engine",))
+
+
+def moe_assignments_counter(reg):
+    return reg.counter(
+        MOE_ASSIGNMENTS_TOTAL,
+        "The top-k picks of the live rows of the decode steps, summed on "
+        "the device over the expert layers, by what a pick fell to; "
+        "kind=held (an expert this chip's weights hold) | absent (an "
+        "expert another chip holds: its part is left out) | zero (an "
+        "identity expert: weight x input, no matrices)",
+        labels=("engine", "kind"))
 
 
 def moe_experts_counter(reg):

@@ -668,3 +668,161 @@ def test_a_bf16_carried_qwen3_next_state_fails_the_state_check(monkeypatch):
     import jax.numpy as jnp
     err = _qwen_served_state_error(jnp.bfloat16, monkeypatch)
     assert err > 10 * STATE_RTOL, err
+
+
+# ---------------------------------------------------------------------------
+# longcat-flash-omni (ISSUE 40)
+# ---------------------------------------------------------------------------
+
+def test_longcat_keeps_every_published_number():
+    """The catalog row's ``config`` (model-configs guide), as copied into
+    ISSUE 40: every key at the top level of the file, no width changed, and
+    ``reduced`` names the depth, the experts held and the vocabulary, and
+    nothing else."""
+    cfg = build.load_json("configs", "longcat-flash-omni.json")
+    published = dict(
+        attention_bias=False, vocab_size=131072, hidden_size=6144,
+        ffn_hidden_size=12288, expert_ffn_hidden_size=2048, num_layers=28,
+        num_attention_heads=64, kv_lora_rank=512, q_lora_rank=1536,
+        qk_rope_head_dim=64, v_head_dim=128, qk_nope_head_dim=128,
+        mla_scale_q_lora=True, mla_scale_kv_lora=True,
+        routed_scaling_factor=6, n_routed_experts=512,
+        max_position_embeddings=131072, rms_norm_eps=1e-05,
+        rope_theta=10000000, attention_method="MLA", zero_expert_num=256,
+        zero_expert_type="identity", moe_topk=12)
+    assert set(published) <= set(cfg)
+    differs = sorted(k for k in published if cfg[k] != published[k])
+    assert differs == sorted(cfg["reduced"]) == [
+        "n_routed_experts", "num_layers", "vocab_size"]
+    # the cut sits ON the guide's floors: four layers, 16 of 512 experts (a
+    # 32nd: one chip of a 32-chip stage), an eighth of the vocabulary; the
+    # router keeps its published width
+    assert cfg["num_layers"] == 4
+    assert cfg["router_n_routed_experts"] == published["n_routed_experts"]
+    assert cfg["n_routed_experts"] * 32 == cfg["router_n_routed_experts"]
+    assert cfg["n_routed_experts"] >= 8 and cfg["first_expert"] == 0
+    assert cfg["vocab_size"] * 8 == published["vocab_size"]
+    assert cfg["family"] == cfg["model_type"] == "longcat_flash"
+    assert cfg["chips"] == cfg["tp"] == 1
+    # one chunk of the widest bucket before each decode step (the file's
+    # assumed.adapter says why not the defaults)
+    assert cfg["adapter"] == {"prefill_budget_tokens": max(
+        cfg["serve"]["context_encoding_buckets"])}
+    assert cfg["serve"]["is_prefix_caching"] is True
+    assert {"model_type", "hidden_act", "router_bias", "norm_topk_prob",
+            "encoders_left_out", "kv_dtype", "latent_lanes",
+            "router_dtype", "expert_names", "adapter"} <= set(cfg["assumed"])
+    gate = cfg["gate"]
+    twin = build.hf_config(cfg, build.gate_overrides(gate))
+    assert (twin["num_layers"], twin["hidden_size"], twin["n_routed_experts"],
+            twin["router_n_routed_experts"], twin["zero_expert_num"]) == \
+        (1, 6144, 16, 512, 256)
+    assert (gate["batch"], gate["prompt_len"], gate["new_tokens"]) == \
+        (4, 112, 16)
+    assert gate["prompt_len"] + gate["new_tokens"] <= \
+        4 * cfg["serve"]["pa_block_size"]
+    assert 0 < gate["excuse_margin_max"] <= 0.02
+    assert gate["min_positions_held"] >= 0.9
+    for control in ("s_q dropped", "s_kv dropped", "identity term dropped",
+                    "renormalised", "x 6 dropped", "shortcut",
+                    "not interleaved", "selection bias dropped", "fp8"):
+        assert control in gate["controls"], control
+    # the pool cannot run dry: every row at its longest prompt and answer
+    mix = build.load_json("traffic", "docqa-closed.json")
+    serve = cfg["serve"]
+    longest = mix["prompt_len"]["hi"] + mix["output_len"]["hi"]
+    assert longest == serve["seq_len"] == 8192
+    assert serve["pa_num_blocks"] * serve["pa_block_size"] == \
+        serve["batch_size"] * longest
+    # the mix is ISSUE 40's, letter for letter
+    assert (mix["loop"], mix["clients_per_batch_row"], mix["pool_requests"],
+            mix["lead_s"], mix["grace_s"], mix["base_seed"]) == \
+        ("closed", 2, 4096, 20.0, 8.0, 40)
+    assert mix["prompt_len"] == dict(kind="lognormal", median=3072,
+                                     sigma=0.6, lo=768, hi=7168)
+    assert mix["output_len"] == dict(kind="lognormal", median=384, sigma=0.6,
+                                     lo=96, hi=1024)
+
+
+def test_longcat_allocates_what_its_file_says():
+    """The pool's lanes and bytes a token, the weights and the total of the
+    file's ``memory``, against what the program would allocate: the full
+    configuration's pool and parameters as SHAPES (nothing of 13 GB is
+    allocated)."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    from neuronx_distributed_inference_tpu.models import model_base
+    from neuronx_distributed_inference_tpu.modules.block_kv_cache import (
+        latent_page, pool_spec)
+    from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
+    cfg = build.load_json("configs", "longcat-flash-omni.json")
+    assumed, memory, serve = cfg["assumed"], cfg["memory"], cfg["serve"]
+    spec = build.build_app(cfg).spec
+    assert (spec.num_layers, spec.sub_blocks, spec.num_attn_layers,
+            spec.num_moe_layers) == (4, 2, 8, 4)
+    m = spec.moe
+    assert (m.num_experts, m.num_routed, m.num_held, m.first_expert,
+            m.zero_experts, m.top_k) == (768, 512, 16, 0, 256, 12)
+    assert not m.normalize_topk and m.routed_scaling == 6.0
+    assert abs(spec.mla.q_scale - 2.0) < 1e-12
+    assert abs(spec.mla.kv_scale - 12 ** 0.5) < 1e-12
+    # one row a token a sub-block: 576 values in 640 lanes, and no V
+    slots, lanes, v_lanes = latent_page(spec.mla.latent_dim)
+    assert spec.mla.latent_dim == memory["latent_values"] == 576
+    assert (slots, lanes, v_lanes) == (1, memory["latent_lanes"], 0)
+    assert lanes == assumed["latent_lanes"]["lanes"] == 640
+    # what PagedCausalLMApplication.init_cache allocates
+    pool = pool_spec(spec, serve["pa_num_blocks"], serve["pa_block_size"])
+    assert pool.shape == (8, 8193, 32, 1, 640)
+    assert pool.v_shape == (8, 8193, 32, 1, 0)
+    assert str(jnp.dtype(pool.dtype)) == assumed["kv_dtype"]
+    assert pool.bytes_per_token == memory["kv_bytes_per_token"] == \
+        8 * 640 * 2
+    assert math.prod(pool.shape) * 2 == memory["kv_pool_bytes"]
+    # against the expanded heads the pool held before this PR
+    assert 8 * 64 * (192 + 128) * 2 == 32 * memory["kv_bytes_per_token"]
+    leaves = jax.tree.leaves(model_base.decoder_param_specs(spec),
+                             is_leaf=lambda x: isinstance(x, ParamSpec))
+    assert sum(math.prod(ps.shape) for ps in leaves) == \
+        memory["parameters"] == 4 * (638_874_368 + 16 * 37_748_736) \
+        + 2 * 16_384 * 6_144 + 6_144
+    weights = sum(math.prod(ps.shape) * jnp.dtype(ps.dtype).itemsize
+                  for ps in leaves)
+    # the program's count over the file's all-bf16 one: the router and its
+    # selection bias in float32 (2 B more an entry)
+    extra = 2 * 4 * (6144 * 768 + 768)
+    assert weights - extra == memory["weights_bytes"] == \
+        2 * memory["parameters"]
+    total = memory["weights_bytes"] + memory["kv_pool_bytes"]
+    assert total == memory["before_temps_bytes"]
+    assert 0.8 * 16e9 < total < 0.83 * 16e9
+
+
+def _longcat_toy():
+    """One layer's twin of a toy (``tests/test_longcat_flash_paged.py``'s: a
+    share of 4 routed experts of 8 beside 4 identity columns) as a
+    configuration file the harness can build and gate."""
+    from test_longcat_flash_paged import _toy_file
+    return _toy_file()
+
+
+def test_the_longcat_reference_gates_a_toy_twin():
+    ref = build.load_reference("longcat_flash")
+    assert ref.__file__ == os.path.join(BENCH, "references",
+                                        "longcat_flash.py")
+    toy = _longcat_toy()
+    res = build.logit_gate(toy, seed=2**31 + 40, served_precision="highest")
+    assert res["passed"], res
+    assert res["compared"] == 2 * 28 * toy["vocab_size"]
+
+
+def test_a_dropped_identity_term_does_not_pass_the_longcat_toy_gate(
+        monkeypatch):
+    from neuronx_distributed_inference_tpu.modules import moe
+    monkeypatch.setattr(moe, "zero_expert_weight",
+                        lambda spec, vals, idx: 0.0 * vals[..., 0])
+    res = build.logit_gate(_longcat_toy(), seed=2**31 + 40,
+                           served_precision="highest")
+    assert not res["passed"] and res["worst_ratio"] > 10

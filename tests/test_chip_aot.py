@@ -29,7 +29,7 @@ from neuronx_distributed_inference_tpu.models import model_base
 from neuronx_distributed_inference_tpu.models.family import get_family
 from neuronx_distributed_inference_tpu.modules import ssm
 from neuronx_distributed_inference_tpu.modules.block_kv_cache import (
-    BlockKVSpec, block_cache_pspec, pool_page)
+    block_cache_pspec, pool_spec)
 from neuronx_distributed_inference_tpu.ops import kernel_mode
 from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
 from neuronx_distributed_inference_tpu.parallel.mesh import (MeshConfig,
@@ -104,8 +104,8 @@ def _serving_shapes(hf_attrs, layers, tp, devices, serve, prefix=True):
                      is_block_kv_layout=True, is_prefix_caching=prefix,
                      **serve)
     family = get_family(hf_attrs["model_type"])
-    icfg = family.config_cls(tcfg, **dict(hf_attrs,
-                                          num_hidden_layers=layers))
+    depth = "num_layers" if "num_layers" in hf_attrs else "num_hidden_layers"
+    icfg = family.config_cls(tcfg, **dict(hf_attrs, **{depth: layers}))
     spec = family.build_spec(icfg, tp_degree=tp)
 
     def sds(shape, dtype, pspec=P()):
@@ -115,13 +115,11 @@ def _serving_shapes(hf_attrs, layers, tp, devices, serve, prefix=True):
     params = jax.tree.map(lambda ps: sds(ps.shape, ps.dtype, ps.pspec),
                           model_base.decoder_param_specs(spec),
                           is_leaf=lambda x: isinstance(x, ParamSpec))
-    slots, lanes = pool_page(spec.gqa.num_kv_heads, spec.head_dim, tp)
-    bspec = BlockKVSpec(
-        num_layers=spec.num_attn_layers, num_blocks=tcfg.pa_num_blocks + 1,
-        block_size=tcfg.pa_block_size, num_kv_heads=slots, head_dim=lanes,
-        dtype=spec.kv_dtype)
-    cache = {k: sds(bspec.shape, bspec.dtype, block_cache_pspec())
-             for k in ("k", "v")}
+    bspec = pool_spec(spec, tcfg.pa_num_blocks, tcfg.pa_block_size)
+    # a latent pool (MLA) has no head axis to shard
+    pool_pspec = P() if bspec.is_latent else block_cache_pspec()
+    cache = {"k": sds(bspec.shape, bspec.dtype, pool_pspec),
+             "v": sds(bspec.v_shape, bspec.dtype, pool_pspec)}
     if spec.ssm is not None:
         pspecs = ssm.ssm_state_pspecs(spec.ssm)
         for k, (shape, dt) in ssm.ssm_state_shapes(
@@ -461,6 +459,100 @@ def test_moe_decode_reads_the_expert_stack_in_place(v5e_devices, hf, layers,
         and op not in ("parameter", "get-tuple-element", "bitcast")]
     assert not moved, moved
     assert step.memory_analysis().temp_size_in_bytes < 100e6
+
+
+# meituan-longcat/LongCat-Flash-Omni config.json (model-configs catalog), the
+# benchmark's share: 16 routed experts held of 512 + 256 identity columns
+LONGCAT_FLASH_SHARE = dict(
+    model_type="longcat_flash", attention_bias=False, vocab_size=16384,
+    hidden_size=6144, ffn_hidden_size=12288, expert_ffn_hidden_size=2048,
+    num_layers=4, num_attention_heads=64, kv_lora_rank=512, q_lora_rank=1536,
+    qk_rope_head_dim=64, v_head_dim=128, qk_nope_head_dim=128,
+    mla_scale_q_lora=True, mla_scale_kv_lora=True, routed_scaling_factor=6,
+    n_routed_experts=16, router_n_routed_experts=512, first_expert=0,
+    max_position_embeddings=131072, rms_norm_eps=1e-5, rope_theta=10000000,
+    attention_method="MLA", zero_expert_num=256, zero_expert_type="identity",
+    moe_topk=12)
+
+
+def test_latent_decode_runs_its_kernel_on_the_pool_in_place(v5e_devices):
+    """ISSUE 40: the decode step at LongCat-Flash's widths (one layer: two
+    latent-attention sub-blocks, a pool of 8192 blocks of 640-lane rows)
+    holds the ``mla_decode_attention`` call twice and the walk over the
+    touched experts in column pieces (an expert of 6144 x 2048 is 75.5 MB,
+    three times the walk's slots); no instruction copies, transposes or
+    relays the latent pool; the V pool has no bytes; and a one-row chunk
+    behind a prefix gathers its groups from the pool in place too."""
+    serve = dict(batch_size=32, seq_len=8192, pa_block_size=32,
+                 pa_num_blocks=8192, context_encoding_buckets=[64, 256])
+    spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
+        LONGCAT_FLASH_SHARE, 1, 1, v5e_devices[:1], serve)
+    assert cache["k"].shape == (2, 8193, 32, 1, 640)
+    assert cache["v"].shape == (2, 8193, 32, 1, 0)
+    i32 = jnp.int32
+    step = partial(model_base.paged_forward_step, spec, tcfg)
+
+    def compiled(rows, width):
+        notes = set()
+        with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
+            c = jax.jit(step, donate_argnums=(1,)).lower(
+                params, cache, *(sds((rows, width), i32),) * 3,
+                sds((rows, mb), i32), sds((rows,), i32), None,
+                sds((2,), jnp.uint32)).compile()
+        return c, notes
+
+    def pool_movers(text):
+        return [(name, op) for name, shape, op in re.findall(
+            r"%(\S+) = bf16\[([\d,]+)\]\S* (\w[\w-]*)\(", text)
+            if shape in ("2,8193,32,1,640", "2,8193,32,640",
+                         "2,262176,1,640", "16386,32,1,640")
+            and op not in ("parameter", "get-tuple-element", "bitcast",
+                           "fusion", "scatter", "custom-call")]
+    decode, notes = compiled(32, 1)
+    assert ("mla_decode", "pallas",
+            "latent lanes=640 heads=64 form=absorbed pages=16") in notes
+    assert ("moe_decode", "pallas", "pieces=8 of 256") in notes
+    assert ("moe_share", "xla",
+            "held=16 of 768 from 0 top_k=12 zero=256") in notes
+    text = decode.as_text()
+    calls = re.findall(r"%(mla_decode_attention[.\d]*) = f32\[32,64,512\]",
+                       text)
+    assert len(calls) == 2, calls
+    assert not pool_movers(text), pool_movers(text)
+    assert decode.memory_analysis().temp_size_in_bytes < 400e6
+    chunk, notes = compiled(1, 256)
+    assert any(site == "mla_prefill" and "prefix=expanded" in why
+               for site, _, why in notes)
+    assert not pool_movers(chunk.as_text()), pool_movers(chunk.as_text())
+
+
+def test_the_widest_longcat_program_fits_beside_weights_and_pool(
+        v5e_devices):
+    """ISSUE 40: ``paged_pack.w256`` at the configuration's size (4 layers,
+    32 rows x 256 tokens over tables of 8192 positions) compiles for a v5e,
+    which refuses a program over 15.75 GB: 13.07 GB of arguments (weights
+    10.38, latent pool 2.68) and under 2.2 GB of temps. It first needed 4.39
+    GB and was refused: ``experts_ragged`` held 98,304 token copies at once
+    (2.25 GB of float32 outputs alone), so a pack's rows go through it in
+    groups under the attention scores' budget (``experts_ragged_by_rows``);
+    and the prefix walk's scores are a group of 512 tokens, not the table."""
+    serve = dict(batch_size=32, seq_len=8192, pa_block_size=32,
+                 pa_num_blocks=8192, context_encoding_buckets=[64, 256])
+    spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
+        LONGCAT_FLASH_SHARE, 4, 1, v5e_devices[:1], serve)
+    i32 = jnp.int32
+    notes = set()
+    with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
+        pack = jax.jit(partial(model_base.paged_forward_step, spec, tcfg),
+                       donate_argnums=(1,)).lower(
+            params, cache, *(sds((32, 256), i32),) * 3, sds((32, mb), i32),
+            sds((32,), i32), None, sds((2,), jnp.uint32)).compile()
+    assert ("moe_ragged", "row-groups", "4 of 32 rows") in notes
+    memory = pack.memory_analysis()
+    assert 13.0e9 < memory.argument_size_in_bytes < 13.1e9
+    assert memory.temp_size_in_bytes < 2.2e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2 ** 30 - 258e6
 
 
 def test_without_the_request_nothing_is_interpreted(v5e_devices,

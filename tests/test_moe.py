@@ -388,8 +388,58 @@ def test_moe_block_takes_a_layer_of_the_stack_for_few_tokens(rng):
                                atol=1e-5)
     assert notes == {("moe_decode", "pallas-interpret", "pieces=1 of 128"),
                      ("moe_decode", "xla", "the caller cut the layer out")}
-    assert np.asarray(tally_k[0]).tolist() == [2, 8, 2]     # read = touched
-    assert np.asarray(tally_d[0]).tolist() == [2, 8, 4]     # read = held
+    # [touched, assigned, read, picks, identity picks]
+    assert np.asarray(tally_k[0]).tolist() == [2, 8, 2, 8, 0]  # read touched
+    assert np.asarray(tally_d[0]).tolist() == [2, 8, 4, 8, 0]  # read = held
+
+
+@pytest.mark.parametrize("path", ["dense", "ragged", "walk", "chunk walk"])
+def test_identity_columns_add_weight_times_input(rng, path):
+    """``zero_experts`` (LongCat-Flash): the router's last columns are
+    identity experts. On every expert path a pick of one is a pick of an
+    expert the stacks do not hold, the block adds ``(sum of the picked
+    identity weights) x input`` once, and the tally counts the picks apart;
+    a share of the routed experts beside them, not renormalised, x 6."""
+    spec = _moe_spec(num_experts=8 + 4, zero_experts=4, held_experts=4,
+                     first_expert=2, top_k=3, intermediate_size=128,
+                     normalize_topk=False, routed_scaling=6.0,
+                     dense_max_tokens=0 if path == "ragged" else 64)
+    assert (spec.num_routed, spec.num_held, spec.holds_share) == (8, 4, True)
+    stack = _tile_stack(rng)
+    tokens = 160 if path == "chunk walk" else 6
+    x = jnp.asarray(rng.normal(size=(1, tokens, 128)).astype(np.float32))
+    router = jnp.asarray(rng.normal(size=(128, 12)).astype(np.float32))
+    li = 1
+    in_stack = path in ("walk", "chunk walk", "ragged")
+    layer_w = {"router": router,
+               **{k: moe_mod.LayerOfStack(a, li) if in_stack else a[li]
+                  for k, a in stack.items()}}
+    notes, tally = set(), []
+    with kernel_mode.recording(notes):
+        got = moe_mod.moe_block(spec, x, layer_w, tally=tally)
+    sites = {n[:2] for n in notes if n[0] != "moe_share"}
+    assert sites == {"dense": {("moe_decode", "xla")},
+                     "ragged": {("moe_ragged", "stacked")}}.get(
+        path, {("moe_decode", "pallas-interpret")})
+    # by hand: softmax, top 3, x 6; held experts 2..5, identity 8..11
+    probs = jax.nn.softmax(x @ router, axis=-1)
+    top, idx = jax.lax.top_k(probs, 3)
+    want = jnp.zeros_like(x)
+    for e in range(4):
+        w = jnp.sum(jnp.where(idx == 2 + e, 6.0 * top, 0.0), -1)[..., None]
+        hid = jax.nn.silu(x @ stack["expert_gate"][li, e]) \
+            * (x @ stack["expert_up"][li, e])
+        want = want + w * (hid @ stack["expert_down"][li, e])
+    zero_w = jnp.sum(jnp.where(idx >= 8, 6.0 * top, 0.0), -1)
+    assert float(zero_w.max()) > 0
+    want = want + zero_w[..., None] * x
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
+    touched, assigned, read, picks, zero = np.asarray(tally[0]).tolist()
+    assert picks == tokens * 3
+    assert zero == int((np.asarray(idx) >= 8).sum()) > 0
+    assert assigned == int(((np.asarray(idx) >= 2)
+                            & (np.asarray(idx) < 6)).sum())
+    assert touched <= read <= 4
 
 
 QWEN3_NEXT_SHARE = dict(num_experts=512, top_k=10, intermediate_size=512,
@@ -593,9 +643,11 @@ def test_the_scanned_decode_step_reads_the_touched_experts(monkeypatch):
     assert notes == {("xla", "forced")}
     np.testing.assert_allclose(np.asarray(got["logits"]),
                                np.asarray(want["logits"]), atol=2e-5, rtol=0)
-    touched, assigned, read = np.asarray(got["moe_tally"]).tolist()
+    touched, assigned, read, picks, zero = np.asarray(
+        got["moe_tally"]).tolist()
     assert np.asarray(want["moe_tally"]).tolist() == [touched, assigned,
-                                                      3 * 8]
+                                                      3 * 8, picks, zero]
+    assert (picks, zero) == (assigned, 0)    # every expert is held here
     assert assigned == 3 * 2 * 2             # layers x live rows x top-k
     assert 0 < touched <= read < 3 * 8
     # the pads are clones of row 0: the same logits, bit for bit

@@ -8,8 +8,9 @@ one-row chunk of 256 hands the kernel its assignments sorted by expert.
 Interpret mode (conftest asks for it). The geometries are OLMoE's (64
 experts, top-8, every expert held) and qwen3-next's share (512 routed
 over, 128 held from ``first_expert`` 128 on, top-10 renormalised) at toy
-widths that keep their aspect, plus OLMoE's real widths with few experts
-(in float32 an expert of 2048 x 1024 is walked in two pieces)."""
+widths that keep their aspect, OLMoE's real widths with few experts (in
+float32 an expert of 2048 x 1024 is walked in two pieces), and LongCat-Flash's
+share beside identity columns (ISSUE 40: ``MoESpec.zero_experts``)."""
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +31,13 @@ GEOMETRIES = {
         first_expert=128), 512, 128),
     "olmoe-widths": (moe_mod.MoESpec(num_experts=4, top_k=2,
                                      intermediate_size=1024), 2048, 4),
+    # LongCat-Flash's share: 32 held of 64 routed ones beside 32 identity
+    # columns, top-12 x 6 and not renormalised; a pick of an identity expert
+    # is no expert of the stack's
+    "longcat-share": (moe_mod.MoESpec(
+        num_experts=64 + 32, top_k=12, intermediate_size=128,
+        held_experts=32, first_expert=16, zero_experts=32,
+        normalize_topk=False, routed_scaling=6.0), 256, 32),
 }
 
 # (rows, tokens a row): decode steps of 1, 2, 16 and 32 rows and the
@@ -71,7 +79,8 @@ def _f32(a):
                          ids=[f"{r}x{t}" for r, t in STEPS])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("name", ["olmoe", "qwen3-next-share"])
+@pytest.mark.parametrize("name", ["olmoe", "qwen3-next-share",
+                                  "longcat-share"])
 def test_kernel_equals_the_dense_path(name, dtype, rows, tokens, layer):
     """kernel == ``experts_dense`` on the layer's slice within the dtype's
     rounding; the tally's ``read`` is the touched list's length and is at
@@ -173,18 +182,22 @@ def test_a_chunk_with_groups_of_none_one_and_many_rows(dtype):
     (64, 2048, 128, 512, jnp.bfloat16, True),       # the 64-token chunk
     (256, 2048, 128, 512, jnp.bfloat16, True),      # qwen3-next's chunk
     (256, 2048, 64, 1024, jnp.bfloat16, True),      # OLMoE's chunk
+    (256, 6144, 16, 2048, jnp.bfloat16, True),      # longcat's chunk
+    (1024, 2048, 64, 1024, jnp.bfloat16, False),    # OLMoE's 64 pack
     (2048, 2048, 128, 512, jnp.bfloat16, False),    # qwen3-next's 64 pack
     (8192, 2048, 128, 512, jnp.bfloat16, False),
-], ids=["decode", "w64", "qwen3-next-w256", "olmoe-w256", "pack-w64",
-        "pack-w256"])
+], ids=["decode", "w64", "qwen3-next-w256", "olmoe-w256", "longcat-w256",
+        "olmoe-pack-w64", "pack-w64", "pack-w256"])
 def test_the_rows_vmem_follows_the_rows(n, h, e, i, dtype, fits):
     """What the call asks of VMEM beside the slots is computed from the
     rows it carries, grows with them, and a step whose rows would need
-    more than the slots themselves is declined by name."""
+    more than ``MOE_ROWS_VMEM_BYTES``, or that is longer than
+    ``MOE_WALK_MAX_ROWS``, is declined by name."""
     plan = moe_decode.moe_decode_plan(h, i, dtype)
     need = moe_decode.rows_vmem_bytes(n, h, e, plan, dtype)
     assert need > moe_decode.rows_vmem_bytes(n // 2, h, e, plan, dtype)
-    assert (need <= moe_decode.MOE_WEIGHT_VMEM_BYTES) == fits
+    assert (need <= moe_decode.MOE_ROWS_VMEM_BYTES
+            and n <= moe_decode.MOE_WALK_MAX_ROWS) == fits
     spec = moe_mod.MoESpec(num_experts=e, top_k=8, intermediate_size=i)
     why = moe_decode.declined(spec, jax.ShapeDtypeStruct((2, e, h, i), dtype),
                               n)

@@ -112,7 +112,7 @@ def test_precompile_report_and_debug_surface(warm_app):
     # toy's head_dim 16 is outside the kernel's geometry, and says so
     assert rep["kernels"] == [
         {"site": "paged_decode", "path": "xla",
-         "reason": "unsupported geometry (mla / head_dim / attn_chunk)"}]
+         "reason": "unsupported geometry (head_dim / attn_chunk)"}]
     assert ws["kernels"] == rep["kernels"]
     assert ws["steady_state"] is True
     assert ws["graphs_seen"] >= rep["n_graphs"]
