@@ -1428,6 +1428,11 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
         # output drops them again
         n_q = q.shape[2]
         pool_heads = k_full.shape[3] * (k_full.shape[4] // spec.head_dim)
+        # the engagement record names the page as allocated (bkv.pool_page)
+        kernel_mode.note(
+            "kv_pool", "xla",
+            f"page={k_full.shape[3]}x{k_full.shape[4]} heads={pool_heads}"
+            f"x{spec.head_dim}")
         grown = pool_heads != k.shape[2]
         if grown:
             def grow(x):
@@ -1467,12 +1472,14 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                     use_pkernel = True
                     attn_out = kernel_out[:, None]
             # ... and an engaged kernel says what it runs with: pages a
-            # compute block, a shard's heads, the head form
+            # compute block, a shard's heads, the head form, and whether
+            # heads that share a row are stored so
             kernel_mode.note("paged_decode",
                              "xla" if declined else kernel_mode.kernel_path(),
                              declined or decode_attention.paged_dispatch_plan(
                                  q.shape[2], spec.head_dim, k_full,
-                                 block_table.shape[1]).note())
+                                 block_table.shape[1]).note(
+                                     k_full.shape[4] != spec.head_dim))
         if not use_pkernel:
             def gathered_mha(q_, bt_, mask_):
                 def gathered(pool):
@@ -1772,28 +1779,6 @@ def _paged_kernel_declined(spec: DecoderSpec) -> str:
             else "unsupported geometry (head_dim / attn_chunk)")
 
 
-def _paged_pool_fold(spec: DecoderSpec, cache, hidden, phase: str,
-                     block_table) -> int:
-    """How many kv heads a row of the pool holds for this walk: 1 (the pool
-    as stored) unless every layer of it is a single-token paged step on the
-    decode kernel with heads narrower than a vreg, which reads a page as
-    rows of 128 lanes (``decode_attention.paged_pool_fold``). The walk then
-    carries the pool in that shape: folding it at the kernel would relayout
-    the whole pool once a LAYER, here it is once a step - the copies a step
-    of such a model pays already (ROADMAP A5)."""
-    if (phase != "paged" or hidden.shape[1] != 1
-            or spec.mla is not None      # a latent row a token: no heads
-            or spec.head_dim >= 128      # whole vregs: stored folded or not
-            or cache["k"].shape[4] != spec.head_dim      # folded already
-            or _paged_kernel_declined(spec)):
-        return 1
-    # the query heads of the pool's head slots (bkv.pool_kv_heads)
-    plan = decode_attention.paged_dispatch_plan(
-        spec.gqa.num_q_heads // spec.gqa.num_kv_heads * cache["k"].shape[3],
-        spec.head_dim, cache["k"], block_table.shape[1])
-    return plan.fold if plan is not None else 1
-
-
 def _join_caps(parts):
     """The per-layer outputs of consecutive layer runs as one, layer-major
     (a key only some runs give - a dense run counts no experts - is theirs
@@ -1821,31 +1806,11 @@ def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
     side pair.
     Returns (hidden, new_cache, captured[, side]) — captured = {} unless
     spec.capture names per-layer points (then each is stacked (L, ...)).
+
+    A paged pool is walked in the shape it is stored in
+    (``block_kv_cache.pool_page``: as the decode kernel reads a shard's
+    page), whatever the phase's width: nothing here reshapes it.
     """
-    fold = _paged_pool_fold(spec, cache, hidden, phase, block_table)
-    if fold > 1:
-        # the walk carries the pool as the decode kernel reads it
-        def pool_as(x, heads):
-            return x.reshape(x.shape[:3] + (heads, -1))
-        heads = cache["k"].shape[3]
-        # the barrier keeps this reshape apart from the slot write's own:
-        # merged with it, it is no longer free in the layout the pool is
-        # kept in between steps and costs a second pass over the pool
-        k_f, v_f = jax.lax.optimization_barrier(
-            (pool_as(cache["k"], heads // fold),
-             pool_as(cache["v"], heads // fold)))
-        out = run_layers(
-            spec, params, dict(cache, k=k_f, v=v_f),
-            hidden, ai, seq_ids, positions, phase,
-            identity_seq_ids=identity_seq_ids,
-            arange_positions=arange_positions, slot_mapping=slot_mapping,
-            block_table=block_table, adapter_ids=adapter_ids,
-            replacements=replacements, kv_view=kv_view, deepstack=deepstack,
-            deepstack_mask=deepstack_mask, prefill_lens=prefill_lens,
-            side=side, chunk_idx=chunk_idx, state_slots=state_slots)
-        k_f, v_f = jax.lax.optimization_barrier((out[1]["k"], out[1]["v"]))
-        return (out[0], dict(out[1], k=pool_as(k_f, heads),
-                             v=pool_as(v_f, heads))) + out[2:]
     if spec.sub_blocks > 1:
         if replacements is not None or deepstack is not None \
                 or side is not None or spec.capture:

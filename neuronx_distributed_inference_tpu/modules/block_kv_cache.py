@@ -33,6 +33,7 @@ block_table entries 0 read zeros (masked out by the position mask anyway).
 from __future__ import annotations
 
 import hashlib
+import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -92,20 +93,20 @@ POOL_HEAD_TILE = 16
 
 
 def pool_kv_heads(num_kv_heads: int, tp: int = 1) -> int:
-    """Head slots of a page of the pool for a model of ``num_kv_heads`` kv
-    heads. A page is ``(block_size, heads, head_dim)`` and the device keeps
-    arrays in tiles over their two minor dimensions: with MORE than a
-    tile's worth of heads that are not whole tiles (30: Olmo-Hybrid-7B) it
-    stores the pool with the block's tokens minor instead, so the slot write
-    and the decode kernel, which read pages as they are declared, each paid
-    whole-pool copies a step (three a side, 7 GB of temps: the step did not
-    fit the chip). The pool then rounds its heads up to whole tiles
-    (30 -> 32; the padded heads hold zeros and the attention block pads its
-    q, k, v to match): every reshape of the pool is a bitcast again, as for
-    16 heads. One chip only: a shard's heads are not padded. Up to a tile's
-    worth of heads keep their count here; of those, 2 to 7 heads of whole
-    vregs are stored in ONE slot (:func:`pool_page`, what the application
-    allocates by)."""
+    """Heads a page of the pool has room for, for a model of
+    ``num_kv_heads`` kv heads. A page is ``(block_size, heads, head_dim)``
+    and the device keeps arrays in tiles over their two minor dimensions:
+    with MORE than a tile's worth of heads that are not whole tiles (30:
+    Olmo-Hybrid-7B) it stores the pool with the block's tokens minor
+    instead, so the slot write and the decode kernel, which read pages as
+    they are declared, each paid whole-pool copies a step (three a side, 7
+    GB of temps: the step did not fit the chip). The pool then rounds its
+    heads up to whole tiles (30 -> 32; the padded heads hold zeros and the
+    attention block pads its q, k, v to match): every reshape of the pool
+    is a bitcast again, as for 16 heads. One chip only: a shard's heads are
+    not padded. How many of these heads share one SLOT of a page is
+    :func:`pool_page`'s to say, which is what the application allocates
+    by."""
     if tp != 1 or num_kv_heads <= POOL_HEAD_TILE:
         return num_kv_heads
     return -(-num_kv_heads // POOL_HEAD_TILE) * POOL_HEAD_TILE
@@ -114,26 +115,42 @@ def pool_kv_heads(num_kv_heads: int, tp: int = 1) -> int:
 def pool_page(num_kv_heads: int, head_dim: int, tp: int = 1
               ) -> Tuple[int, int]:
     """``(head slots, lanes of a slot)`` of a page of the pool: what the
-    application allocates, ``(block_size, slots, lanes)`` a page.
-    :func:`pool_kv_heads` slots of ``head_dim`` lanes - except that a FEW
-    heads (2 to 7) of whole vregs (a multiple of 128 lanes) are stored side
-    by side in ONE slot of ``heads x head_dim`` lanes, one chip only. With 2
-    heads of 256 to a page (Qwen3-Next) the device tiles the page ``(2,
-    128)``, and the decode kernel, which reads a page as a ``(tokens x
-    heads, lanes)`` matrix in whole ``(8, 128)`` tiles, was handed a
-    relayout of the whole pool on every call: six ``reshape`` copies of 403
-    MB, 10.4 of a decode step's 28.9 ms, and the chunk programs' gathers
-    six more (my chip run and AOT, PR 36). A token's heads in one row are
-    the same bytes, ``(tokens, heads x lanes)`` is what both consumers
-    tile alike, the slot write is unchanged (a token's heads are
-    contiguous either way), and the kernel scores heads that share a row
-    as it does two heads of 64 (``decode_attention.paged_pool_fold``)."""
+    application allocates, ``(block_size, slots, lanes)`` a page, and the
+    ONE place that decides a page's shape. The pool is stored as the decode
+    kernel reads a shard's page (``decode_attention.paged_pool_fold`` of a
+    shard's :func:`pool_kv_heads` and their width), so that no consumer is
+    handed a relayout of it:
+
+    * heads narrower than a vreg go ``128 // head_dim`` neighbours to a
+      128-lane slot (8 heads of 64, granite-4.0-h / Llama-3.2-1B: ``(4,
+      128)``). As ``(8, 64)`` the device padded nothing and tiled the page
+      its own way, and every decode step, one-row chunk and pack moved the
+      whole pool between that layout and its consumer's FOUR times: 4.1 of
+      a 25.0 ms step and 5.2 of a 23.9 ms chunk on granite-h-chat-closed
+      (ledger, PR 40). Stored so, the compiled step, chunk and pack hold
+      no pool-sized copy, at ``tp=1`` and, a shard's heads folding evenly,
+      at ``tp=4`` (``tests/test_chip_aot.py``);
+    * a FEW heads (2 to 7 a shard) of whole vregs share one slot of
+      ``heads x head_dim`` lanes. With 2 heads of 256 to a page
+      (Qwen3-Next) the device tiles the page ``(2, 128)``, and the decode
+      kernel, which reads a page as a ``(tokens x heads, lanes)`` matrix in
+      whole ``(8, 128)`` tiles, was handed a relayout of the whole pool on
+      every call: six ``reshape`` copies of 403 MB, 10.4 of a decode step's
+      28.9 ms, and the chunk programs' gathers six more (my chip run and
+      AOT, PR 36);
+    * everything else (heads that do not fold evenly: 96 lanes, an odd
+      count of narrow heads; 8 or more heads of whole vregs) keeps a slot a
+      head.
+
+    A slot never straddles two shards: the fold is of a shard's heads. A
+    token's heads in one row are the same bytes, so allocator, tables, slot
+    mapping, preemption, prefix hashing, spill and handoff see no
+    difference; the slot write is unchanged (a token's heads are contiguous
+    either way); a chunk splits the lanes of the rows it GATHERED
+    (``model_base._attn_block``)."""
     from ..ops.decode_attention import paged_pool_fold
     heads = pool_kv_heads(num_kv_heads, tp)
-    # stored as the kernel reads it; heads narrower than a vreg keep their
-    # slots (the layer walk folds them for the decode step alone)
-    fold = paged_pool_fold(heads, head_dim) \
-        if tp == 1 and head_dim >= 128 else 1
+    fold = paged_pool_fold(heads // tp, head_dim) if heads % tp == 0 else 1
     return heads // fold, fold * head_dim
 
 
@@ -247,9 +264,23 @@ def gather_layer_kv(cache: jnp.ndarray, layer, block_table: jnp.ndarray
     layout keeps heads minor: the gather is row-indexed, not head-sliced.
     A pool stored with several heads to a slot (:func:`pool_page`) comes
     back that way; the caller splits the lanes of the GATHERED rows.
-    """
+
+    A page of fewer slots than a tile has rows (4 slots of 128 lanes:
+    8 heads of 64) is gathered as the ``(tokens x slots, lanes)`` matrix it
+    is in memory, the decode kernel's view of it. Gathered as ``(tokens,
+    slots, lanes)`` the compiler wanted the page's tokens in the tile's
+    rows, and a one-row chunk paid a relayout of the whole pool for K and
+    for V in EVERY layer (AOT, PR 41: two 268 MB ``copy`` in the loop's
+    body). One chip only: across shards the slots are the sharded axis and
+    do not merge with the tokens. Pages of whole tiles keep the gather they
+    had."""
+    from ..ops.decode_attention import PAGED_ROW_TILE
     L, n, bs, h, d = cache.shape
-    flat = cache.reshape(L * n, bs, h, d)
+    mesh = jax.sharding.get_abstract_mesh()
+    # shards of the slot axis under the ambient mesh (block_cache_pspec)
+    shards = math.prod(mesh.shape[a] for a in AXIS_MP if a in mesh.axis_names)
+    page = (bs * h,) if shards == 1 and h < PAGED_ROW_TILE else (bs, h)
+    flat = cache.reshape((L * n,) + page + (d,))
     g = flat[jnp.asarray(layer, jnp.int32) * n + block_table]
     b, mb = block_table.shape
     return g.reshape(b, mb * bs, h, d)

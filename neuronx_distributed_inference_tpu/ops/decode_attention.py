@@ -97,10 +97,15 @@ class PagedPlan(NamedTuple):
     g: int              # query rows a kv row: group size x fold
     d: int              # lanes of a kv row: head_dim x fold
 
-    def note(self) -> str:
-        """The engagement record's text (``kernel_mode.note``)."""
+    def note(self, stored: bool) -> str:
+        """The engagement record's text (``kernel_mode.note``); it ends
+        with where a fold lives: ``stored`` (the pool was allocated so,
+        ``block_kv_cache.pool_page``) or ``call`` (a pool handed over a
+        head a slot, which the call reshapes: a relayout of the whole pool
+        wherever the device tiles the two shapes differently)."""
+        where = "" if self.fold == 1 else " stored" if stored else " call"
         return (f"pages={self.pages} heads={self.hkv * self.fold} "
-                f"form=mxu-blockdiag fold={self.fold}")
+                f"form=mxu-blockdiag fold={self.fold}{where}")
 
 
 #: fewer kv rows a token than this (the second-minor extent of a 32-bit
@@ -110,13 +115,16 @@ PAGED_ROW_TILE = 8
 
 def paged_pool_fold(hkv: int, d: int) -> int:
     """How many neighbouring kv heads (of a shard's ``hkv``) share one row
-    of a page in the kernel's view of the pool. Heads narrower than a vreg
-    go in side by side, the page's bytes as they lie (a manual copy cannot
-    slice a 64-lane minor dimension out of a tiled array); a FEW heads (2
-    to 7) of whole vregs share one row too, ``hkv x d`` lanes: the pool of
-    such a model is stored so (``block_kv_cache.pool_page``: a page of 2
-    heads is tiled ``(2, 128)`` and the ``(tokens x heads, lanes)`` view
-    of it was a relayout of the whole pool a call)."""
+    of a page in the kernel's view of the pool - and, because the pool is
+    ALLOCATED by this rule (``block_kv_cache.pool_page``, the one place
+    that decides a page's shape), in the pool as it is stored. Heads
+    narrower than a vreg go in side by side, the page's bytes as they lie
+    (a manual copy cannot slice a 64-lane minor dimension out of a tiled
+    array); a FEW heads (2 to 7) of whole vregs share one row too, ``hkv x
+    d`` lanes (a page of 2 heads is tiled ``(2, 128)`` and the ``(tokens x
+    heads, lanes)`` view of it was a relayout of the whole pool a call).
+    Heads that do not fold evenly (an odd count of narrow heads, 96 lanes)
+    stay a head a row: 1."""
     if d < 128 and 128 % d == 0:
         fold = 128 // d
     elif d % 128 == 0 and 1 < hkv < PAGED_ROW_TILE:
@@ -622,8 +630,10 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
 
     q (B, Hq, D); k_pages/v_pages (L, N, Bs, Hkv, D) - or the same bytes
     already folded, (L, N, Bs, Hkv / fold, fold * D) with ``fold`` of
-    :func:`paged_pool_fold`: a caller that keeps the pool so for the whole
-    layer loop spares a relayout of the pool a layer; new_k/new_v
+    :func:`paged_pool_fold`, which is how the application stores it
+    (``block_kv_cache.pool_page``): the reshape below is then a bitcast,
+    where a pool handed over a head a slot may cost a relayout of the whole
+    pool a call; new_k/new_v
     (B, Hkv, D); lens (B,) prior lengths; block_table (B, max_blocks)
     logical->physical page map (entry 0 = null page). Returns (B, Hq, D).
 

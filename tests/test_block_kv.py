@@ -92,13 +92,17 @@ def test_negative_slots_dropped():
 
 @pytest.mark.parametrize("layer", [0, 2, 4])
 @pytest.mark.parametrize("traced", [False, True], ids=["static", "traced"])
-def test_flat_gather_equals_the_layer_slice(layer, traced):
+@pytest.mark.parametrize("slots", [2, 8], ids=["page-as-rows", "page"])
+def test_flat_gather_equals_the_layer_slice(layer, traced, slots):
     """One gather from the flat (L*N, ...) pool reads what cutting the
     layer out and gathering from it reads, bit for bit — null block
-    (table entry 0) and a repeated block included (ISSUE 31)."""
+    (table entry 0) and a repeated block included (ISSUE 31); a page of
+    fewer slots than a tile has rows goes through it as the ``(tokens x
+    slots, lanes)`` matrix it is in memory (ISSUE 41)."""
     rng = np.random.default_rng(31)
     L, n = 5, 7
-    pool = jnp.asarray(rng.normal(size=(L, n, 4, 2, 8)).astype(np.float32))
+    pool = jnp.asarray(
+        rng.normal(size=(L, n, 4, slots, 8)).astype(np.float32))
     bt = jnp.asarray([[3, 1, 0, 0], [6, 6, 2, 0], [0, 0, 0, 0]], jnp.int32)
     want = gather_block_kv(
         jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False), bt)
@@ -106,10 +110,10 @@ def test_flat_gather_equals_the_layer_slice(layer, traced):
         got = jax.jit(gather_layer_kv)(pool, jnp.int32(layer), bt)
     else:
         got = gather_layer_kv(pool, layer, bt)
-    assert got.shape == (3, 16, 2, 8)
+    assert got.shape == (3, 16, slots, 8)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     # the null entries read THIS layer's block 0, not layer 0's
-    for half in np.asarray(got[0, 8:]).reshape(2, 4, 2, 8):
+    for half in np.asarray(got[0, 8:]).reshape(2, 4, slots, 8):
         np.testing.assert_array_equal(half, np.asarray(pool[layer, 0]))
 
 
@@ -263,3 +267,132 @@ def test_paged_ragged_kernel_e2e_matches_contiguous():
     got = app_p.generate(ids, attention_mask=mask, max_new_tokens=10)
     np.testing.assert_array_equal(got["generated"], want["generated"])
     app_p.release()
+
+
+# ---------------------------------------------------------------------------
+# the page the pool is allocated by (ISSUE 41)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("heads, lanes, tp, page", [
+    (8, 64, 1, (4, 128)),        # granite-4.0-h, Llama-3.2-1B
+    (2, 64, 1, (1, 128)),
+    (32, 64, 1, (16, 128)),      # two tiles' worth of narrow heads
+    (8, 32, 1, (2, 128)),
+    (3, 64, 1, (3, 64)),         # an odd count does not fold evenly
+    (8, 96, 1, (8, 96)),         # 96 lanes do not divide a vreg
+    (16, 128, 1, (16, 128)),     # OLMoE: whole tiles of whole vregs
+    (30, 128, 1, (32, 128)),     # Olmo-Hybrid: rounded up to whole tiles
+    (2, 256, 1, (1, 512)),       # Qwen3-Next, as before
+    (8, 64, 4, (4, 128)),        # a shard's 2 heads share its one slot
+    (4, 64, 4, (4, 64)),         # a shard's one head has no neighbour
+    (8, 128, 4, (4, 256)),       # a shard's 2 heads of whole vregs
+    (32, 128, 4, (32, 128)),     # a shard's 8 heads: a slot a head
+    (30, 128, 4, (30, 128)),     # heads that do not shard are not padded
+])
+def test_a_page_is_stored_as_the_decode_kernel_reads_a_shards(heads, lanes,
+                                                              tp, page):
+    """``pool_page`` is the one place that decides a page's shape: a
+    shard's heads by ``paged_pool_fold``, the same bytes a token either
+    way, and never a slot across two shards."""
+    from neuronx_distributed_inference_tpu.modules.block_kv_cache import (
+        pool_kv_heads, pool_page)
+    slots, slot_lanes = pool_page(heads, lanes, tp)
+    assert (slots, slot_lanes) == page
+    assert slots * slot_lanes == pool_kv_heads(heads, tp) * lanes
+    assert slots % tp == 0 or slot_lanes == lanes
+
+
+HEADS_OF_64 = dict(
+    model_type="llama", hidden_size=256, intermediate_size=512,
+    num_hidden_layers=2, num_attention_heads=8, num_key_value_heads=8,
+    head_dim=64, vocab_size=512, rms_norm_eps=1e-5, rope_theta=10000.0,
+    hidden_act="silu", tie_word_embeddings=False, torch_dtype="float32")
+
+
+@pytest.mark.parametrize("kv", [
+    dict(kv_cache_dtype="bfloat16"),
+    dict(kv_cache_dtype="float8_e4m3fn", kv_cache_quant=True,
+         kv_cache_scale=2.0)], ids=["bf16", "fp8"])
+def test_heads_of_64_two_to_a_slot_serve_the_same_tokens_and_bytes(
+        kv, monkeypatch):
+    """8 kv heads of 64 (granite-4.0-h's attention at a toy size) live four
+    slots of 128 lanes to a token. Through ``PagedEngineAdapter`` - chunks
+    of 8, decode steps on the kernel (interpreted), a preemption and its
+    re-admission, a release, a prefix hit - the streams are those of a pool
+    of a head a slot and (bf16) the contiguous cache's, and the pool, a
+    token's ``slots x lanes`` as bytes, is that pool byte for byte: nothing
+    that handles a page as bytes (allocator, tables, slot mapping, prefix
+    hashing) can tell."""
+    from neuronx_distributed_inference_tpu.modules import block_kv_cache as bkv
+    from neuronx_distributed_inference_tpu.serving import PagedEngineAdapter
+    rng = np.random.default_rng(41)
+    a = rng.integers(1, 500, size=21).tolist()
+    b = rng.integers(1, 500, size=13).tolist()
+    c = a[:16] + rng.integers(1, 500, size=4).tolist()
+    base = dict(seq_len=64, dtype="float32", **kv)
+
+    def paged_app():
+        tcfg = TpuConfig(batch_size=2, enable_bucketing=True,
+                         context_encoding_buckets=[8, 16],
+                         is_block_kv_layout=True, pa_block_size=8,
+                         is_prefix_caching=True, **base)
+        app = PagedCausalLMApplication(
+            None, LlamaInferenceConfig(tcfg, **HEADS_OF_64), LlamaFamily)
+        return app.init_random_weights(5).init_cache()
+
+    def serve(app):
+        ad = PagedEngineAdapter(app, prefill_chunk_tokens=8)
+        first = ad.add_requests([0, 1], [a, b])
+        out = {0: [first[0]], 1: [first[1]], 2: []}
+
+        def decode(n):
+            for _ in range(n):
+                for sid, tok in ad.step().items():
+                    out[sid].append(tok)
+        decode(3)
+        rec = ad.preempt(1)
+        assert list(rec.tokens) == b + out[1]
+        decode(1)                       # 0 runs on while 1 is out
+        out[1].append(ad.add_requests([1], [list(rec.tokens)])[1])
+        decode(2)
+        ad.release([0])
+        assert ad.prefix_warmth(c) == 16        # a's two full blocks
+        out[2].append(ad.add_requests([2], [c])[2])
+        decode(2)
+        kernels = {k["site"]: k["reason"]
+                   for k in app.warmup_state()["kernels"]}
+        return out, kernels, {
+            k: np.asarray(app.cache[k]).view(np.uint8) for k in "kv"}
+
+    app = paged_app()
+    assert app.cache["k"].shape == (2, app.cache["k"].shape[1], 8, 4, 128)
+    got, kernels, pool = serve(app)
+    assert kernels == {
+        "kv_pool": "page=4x128 heads=8x64",
+        "paged_decode": "pages=8 heads=8 form=mxu-blockdiag fold=2 stored"}
+    # ... against a pool of a head a slot, as it was allocated before
+    monkeypatch.setattr(bkv, "pool_page",
+                        lambda heads, lanes, tp=1: (heads, lanes))
+    plain = paged_app()
+    assert plain.cache["k"].shape[3:] == (8, 64)
+    want, kernels, plain_pool = serve(plain)
+    assert kernels["paged_decode"].endswith("fold=2 call")
+    assert got == want
+    for k in "kv":
+        tokens = pool[k].shape[:3]
+        np.testing.assert_array_equal(pool[k].reshape(tokens + (-1,)),
+                                      plain_pool[k].reshape(tokens + (-1,)))
+    if "kv_cache_quant" in kv:
+        # a chunk reads the chunks before it back out of the pool, 3 bits
+        # of mantissa each, where the contiguous prefill is one dispatch:
+        # the streams part ways for a reason that is not the page's
+        return
+    # ... and against the contiguous cache, a prompt at a time
+    contig = CausalLMApplication(None, LlamaInferenceConfig(
+        TpuConfig(batch_size=1, enable_bucketing=False, **base),
+        **HEADS_OF_64), LlamaFamily)
+    contig.init_random_weights(5).init_cache()
+    for sid, prompt in ((0, a), (1, b), (2, c)):
+        ref = contig.generate(np.asarray([prompt]),
+                              max_new_tokens=len(got[sid]))
+        assert got[sid] == ref["generated"][0].tolist(), sid

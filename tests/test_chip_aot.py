@@ -16,6 +16,7 @@ compiled" true between chip runs (``benchmark/run.py`` is the run itself):
     the first benchmark cell starts from a graph known to compile.
 """
 
+import math
 import re
 from functools import partial
 
@@ -182,34 +183,66 @@ def test_llama_1b_serving_graphs_compile_for_v5e(v5e_devices, tp):
     assert counts["involuntary_remat"] == 0
 
 
-def test_heads_of_64_relayout_the_pool_once_a_step(v5e_devices):
-    """ISSUE 33: the paged decode kernel copies pages by hand, and a manual
-    copy takes rows of 128 lanes only, so heads of 64 go two to a row
-    (``decode_attention.paged_pool_fold``). The layer walk carries the pool
-    in that shape (``model_base.run_layers``): the T=1 step of Llama-3.2-1B
-    (8 kv heads of 64, as granite-4.0-h-micro's attention) moves the pool
-    between layouts at the step's two ends - the four copies it paid before
-    (ROADMAP A5) - and NOT inside the layer loop, and the engagement record
-    says what the kernel runs with."""
+# meta-llama/Llama-3-8B's attention on Llama-3.2-1B's other widths: 8 kv
+# heads of 128, two to a shard at tp=4
+HEADS_OF_128 = dict(LLAMA_3_2_1B, hidden_size=4096, head_dim=128,
+                    intermediate_size=14336, tie_word_embeddings=False)
+
+
+@pytest.mark.parametrize("hf, tp, rows, width, page, kernel, temps_under", [
+    (LLAMA_3_2_1B, 1, 32, 1, (4, 128), "pages=8 heads=8", 64e6),
+    (LLAMA_3_2_1B, 1, 1, 256, (4, 128), None, 200e6),
+    (LLAMA_3_2_1B, 1, 32, 256, (4, 128), None, 2.5e9),
+    (LLAMA_3_2_1B, 4, 32, 1, (4, 128), "pages=16 heads=2", 64e6),
+    (HEADS_OF_128, 4, 32, 1, (4, 256), "pages=16 heads=2", 64e6),
+], ids=["step", "chunk", "pack", "step-tp4", "step-tp4-heads-of-128"])
+def test_heads_that_share_a_slot_leave_the_pool_where_it_is(
+        v5e_devices, hf, tp, rows, width, page, kernel, temps_under):
+    """ISSUE 41: the pool is ALLOCATED as the decode kernel reads a shard's
+    page (``block_kv_cache.pool_page``). Llama-3.2-1B's attention (8 kv
+    heads of 64, as granite-4.0-h-micro's) at 4 layers, the granite cell's
+    rows, pool and table: as pages of ``(8, 64)`` the decode step, the
+    one-row chunk and the full-batch pack each moved the whole 268 MB pool
+    FOUR times between the layout it was declared in and the one its
+    consumer read (PR 33 had folded it around the step alone). Stored
+    ``(4, 128)`` no instruction of any of the three moves a pool, in either
+    shape or flat - the chunk's gather takes a page as the matrix it is in
+    memory, or it pays two copies a LAYER - and the step's temps are
+    activations. At tp=4 a shard's two heads share its one slot: the same,
+    and the same for two heads of 128 a shard (Llama-3-8B's; a head a slot
+    it paid two ``reshape`` of a shard's pool in every layer of a step)."""
     spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
-        LLAMA_3_2_1B, 4, 1, v5e_devices[:1],
+        hf, 4, tp, v5e_devices[:tp],
         dict(batch_size=32, seq_len=4096, pa_block_size=32,
              pa_num_blocks=2048, context_encoding_buckets=[64, 256]))
-    i32, b = jnp.int32, 32
+    assert cache["k"].shape == (4, 2049, 32) + page
+    i32 = jnp.int32
     notes = set()
     with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
-        text = jax.jit(partial(model_base.paged_forward_step, spec, tcfg),
-                       donate_argnums=(1,)).lower(
-            params, cache, *(sds((b, 1), i32),) * 3, sds((b, mb), i32),
-            sds((b,), i32), None, sds((2,), jnp.uint32)).compile().as_text()
-    assert MOSAIC in text
-    assert notes == {("paged_decode", "pallas",
-                      "pages=8 heads=8 form=mxu-blockdiag fold=2")}
-    # every instruction that MOVES a pool (either shape of its 268 MB)
-    moves = re.findall(
-        r"%(\S+) = bf16\[4,2049,32,(?:8,64|4,128)\]\S* "
-        r"(copy|reshape|transpose|fusion)\(", text)
-    assert sorted(kind for _, kind in moves) == ["copy"] * 4, moves
+        program = jax.jit(partial(model_base.paged_forward_step, spec, tcfg),
+                          donate_argnums=(1,)).lower(
+            params, cache, *(sds((rows, width), i32),) * 3,
+            sds((rows, mb), i32), sds((rows,), i32), None,
+            sds((2,), jnp.uint32)).compile()
+    text = program.as_text()
+    heads, lanes = 8, spec.head_dim
+    want = {("kv_pool", "xla",
+             f"page={page[0]}x{page[1]} heads={heads}x{lanes}")}
+    if kernel:
+        assert MOSAIC in text
+        want.add(("paged_decode", "pallas",
+                  f"{kernel} form=mxu-blockdiag fold=2 stored"))
+    assert notes == want
+    # every instruction that MOVES a shard's pool, whatever shape it gives
+    # it; a fusion of a flat shape is the slot write, in place
+    size = math.prod(cache["k"].shape) // tp
+    moves = [(name, shape, op) for name, shape, op in re.findall(
+        r"%(\S+) = bf16\[([\d,]+)\]\S* (copy|reshape|transpose|fusion)\(",
+        text)
+        if math.prod(int(n) for n in shape.split(",")) == size
+        and (op != "fusion" or shape.count(",") == 4)]
+    assert not moves, moves
+    assert program.memory_analysis().temp_size_in_bytes < temps_under
 
 
 def test_olmoe_1b_7b_serving_graphs_compile_for_v5e(v5e_devices):
@@ -321,8 +354,10 @@ def test_30_kv_heads_of_128_decode_on_the_kernel_with_no_pool_copy(
     state = ("recurrent_state", "xla",
              "kind=gated_delta slot_bytes=6842880 chunk=64")
     step, notes = compiled(32, 1)
-    assert notes == {state, ("paged_decode", "pallas",
-                             "pages=1 heads=32 form=mxu-blockdiag fold=1")}
+    pool = ("kv_pool", "xla", "page=32x128 heads=32x128")
+    assert notes == {state, pool, (
+        "paged_decode", "pallas",
+        "pages=1 heads=32 form=mxu-blockdiag fold=1")}
     text = step.as_text()
     assert MOSAIC in text
     moves = re.findall(
@@ -331,7 +366,7 @@ def test_30_kv_heads_of_128_decode_on_the_kernel_with_no_pool_copy(
     assert not moves, moves
     assert step.memory_analysis().temp_size_in_bytes < 100e6
     chunk, notes = compiled(1, 256, state_slots=sds((1,), i32))
-    assert notes == {state}
+    assert notes == {state, pool}
     assert chunk.memory_analysis().temp_size_in_bytes < 200e6
 
 
@@ -392,10 +427,11 @@ def test_2_kv_heads_of_256_decode_on_the_kernel_with_no_pool_copy(
     state = ("recurrent_state", "xla",
              "kind=gated_delta slot_bytes=6438912 chunk=64")
     share = ("moe_share", "xla", "held=128 of 512 from 0 top_k=10")
+    pool = ("kv_pool", "xla", "page=1x512 heads=2x256")
     step, notes = compiled(32, 1)
-    assert notes == {state, share, (
+    assert notes == {state, share, pool, (
         "paged_decode", "pallas",
-        "pages=16 heads=2 form=mxu-blockdiag fold=2"),
+        "pages=16 heads=2 form=mxu-blockdiag fold=2 stored"),
         ("moe_decode", "pallas", "pieces=1 of 512")}
     text = step.as_text()
     assert MOSAIC in text and "ragged-dot" not in text
@@ -404,7 +440,7 @@ def test_2_kv_heads_of_256_decode_on_the_kernel_with_no_pool_copy(
     chunk, notes = compiled(1, 256, state_slots=sds((1,), i32))
     # ISSUE 39: 256 x 10 / 512 = 5 rows an expert: the chunk's experts are
     # the walk's, each touched expert against ITS rows
-    assert notes == {state, share, (
+    assert notes == {state, share, pool, (
         "moe_decode", "pallas",
         "pieces=1 of 512 rows=256 by expert in tiles of 128")}
     text = chunk.as_text()

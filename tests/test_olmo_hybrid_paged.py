@@ -278,7 +278,7 @@ def test_the_pool_rounds_many_heads_up_to_whole_tiles(heads, slots):
 
 def test_18_heads_in_a_pool_of_32_serve_the_references_logits(ref):
     """Olmo-Hybrid's 30 heads at a toy size: 18 kv heads of 64 live in a
-    pool of 32 head slots (two to a 128-lane row: the decode kernel's fold),
+    pool of 32 heads' room (stored two to a 128-lane slot, 16 slots),
     the attention block grows q, k, v zero heads to match and drops them
     from its output. Chunks (the gather path) and decode steps (the kernel,
     interpreted) against the reference at the model's own 18 heads."""
@@ -295,7 +295,7 @@ def test_18_heads_in_a_pool_of_32_serve_the_references_logits(ref):
     app._put_params(family.convert_hf_state_dict(
         weights.HfView(table, w, dtype=np.dtype("float32")), app.spec))
     app.init_cache()
-    assert app.cache["k"].shape[3:] == (32, 64)
+    assert app.cache["k"].shape[3:] == (16, 128)
     assert app.params["attn_layers"]["qkv_proj"].shape == (1, 1152, 3 * 1152)
     ad = PagedEngineAdapter(app)
     tap = LogitTap(app)
@@ -311,7 +311,7 @@ def test_18_heads_in_a_pool_of_32_serve_the_references_logits(ref):
     kernels = {(k["site"], k["path"]): k["reason"]
                for k in app.warmup_state()["kernels"]}
     assert kernels["paged_decode", "pallas-interpret"] == \
-        "pages=8 heads=32 form=mxu-blockdiag fold=2"
+        "pages=8 heads=32 form=mxu-blockdiag fold=2 stored"
 
 
 # ---------------------------------------------------------------------------

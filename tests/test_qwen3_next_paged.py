@@ -279,21 +279,25 @@ def test_served_through_the_front_door(ref, gate_weights):
 # a few wide heads share a slot of the pool
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("heads, lanes, page", [
-    (2, 256, (1, 512)), (4, 128, (1, 512)), (7, 128, (1, 896)),
-    (1, 256, (1, 256)), (8, 128, (8, 128)), (2, 64, (2, 64)),
-    (16, 128, (16, 128)), (30, 128, (32, 128))])
+@pytest.mark.parametrize("heads, lanes, page, page_tp4", [
+    (2, 256, (1, 512), (2, 256)), (4, 128, (1, 512), (4, 128)),
+    (7, 128, (1, 896), (7, 128)), (1, 256, (1, 256), (1, 256)),
+    (8, 128, (8, 128), (4, 256)), (2, 64, (1, 128), (2, 64)),
+    (16, 128, (16, 128), (4, 512)), (30, 128, (32, 128), (30, 128))])
 def test_a_few_heads_of_whole_vregs_share_a_slot_of_the_pool(heads, lanes,
-                                                             page):
+                                                             page, page_tp4):
     from neuronx_distributed_inference_tpu.modules.block_kv_cache import \
         pool_page
     from neuronx_distributed_inference_tpu.ops.decode_attention import \
         paged_pool_fold
     assert pool_page(heads, lanes) == page
-    assert pool_page(heads, lanes, tp=4) == (heads, lanes)   # a shard's: no
+    # a shard's heads fold, never heads of two shards; none are padded
+    assert pool_page(heads, lanes, tp=4) == page_tp4
     # stored as the decode kernel reads it
-    if lanes >= 128:
+    if heads <= 16:
         assert paged_pool_fold(heads, lanes) == page[1] // lanes
+    if heads % 4 == 0:
+        assert paged_pool_fold(heads // 4, lanes) == page_tp4[1] // lanes
 
 
 def test_2_kv_heads_of_128_in_one_slot_serve_the_references_logits(ref):
@@ -321,7 +325,7 @@ def test_2_kv_heads_of_128_in_one_slot_serve_the_references_logits(ref):
     kernels = {(k["site"], k["path"]): k["reason"]
                for k in app.warmup_state()["kernels"]}
     assert kernels["paged_decode", "pallas-interpret"] == \
-        "pages=16 heads=2 form=mxu-blockdiag fold=2"
+        "pages=16 heads=2 form=mxu-blockdiag fold=2 stored"
 
 
 # ---------------------------------------------------------------------------

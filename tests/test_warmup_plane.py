@@ -108,9 +108,12 @@ def test_precompile_report_and_debug_surface(warm_app):
     # T=1 plus whichever warm widths are ctx buckets (none of these are)
     assert [g["bucket"] for g in rep["graphs"]
             if g["kind"] == "paged"] == [1]
-    # every traced T=1 graph noted which attention path it took — this
-    # toy's head_dim 16 is outside the kernel's geometry, and says so
+    # every traced graph noted the pool's page as allocated (2 heads of 16
+    # do not fold: a slot a head), and every T=1 graph which attention path
+    # it took — this toy's head_dim 16 is outside the kernel's geometry,
+    # and says so
     assert rep["kernels"] == [
+        {"site": "kv_pool", "path": "xla", "reason": "page=2x16 heads=2x16"},
         {"site": "paged_decode", "path": "xla",
          "reason": "unsupported geometry (head_dim / attn_chunk)"}]
     assert ws["kernels"] == rep["kernels"]
