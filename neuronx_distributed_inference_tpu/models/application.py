@@ -1141,7 +1141,9 @@ class PagedCausalLMApplication(CausalLMApplication):
     """
 
     def init_cache(self):
-        from ..modules.block_kv_cache import BlockKVCacheManager, pool_spec
+        from ..modules.block_kv_cache import (BlockKVCacheManager,
+                                              init_block_cache, pool_spec,
+                                              window_pool_spec)
         cfg = self.tpu_config
         # heads a page for attention, a latent row a token for MLA
         bspec = pool_spec(self.spec, cfg.pa_num_blocks, cfg.pa_block_size)
@@ -1152,6 +1154,14 @@ class PagedCausalLMApplication(CausalLMApplication):
         # stale donated alias after the first step)
         self.cache = self.kv_mgr.cache
         self.kv_mgr.cache = None
+        if self.spec.window_pool:
+            # the window layers' pool: a ring of pages a batch slot, sized
+            # from the spec, the rows and the widest warmed step
+            ring = init_block_cache(
+                window_pool_spec(self.spec, self.state_slots,
+                                 cfg.pa_block_size, max(self.warm_widths)),
+                self.mesh)
+            self.cache.update(k_w=ring["k"], v_w=ring["v"])
         if self.spec.ssm is not None:
             # the second per-sequence cache: the kind's conv tails + fp32
             # state, one SLOT per batch row beside the KV pool, in the same
@@ -1180,10 +1190,31 @@ class PagedCausalLMApplication(CausalLMApplication):
 
     @property
     def state_slots(self) -> int:
-        """Per-sequence recurrent-state slots beside the KV pool: one per
-        batch row for a recurrent/hybrid stack (a full-batch step's rows
-        ARE the slots), 0 for an attention stack."""
-        return self.tpu_config.batch_size if self.spec.ssm is not None else 0
+        """Per-sequence slots beside the KV pool: one per batch row for a
+        recurrent/hybrid stack (its state) and for a stack with a window
+        pool (its ring of pages: ``DecoderSpec.window_pool``); a full-batch
+        step's rows ARE the slots. 0 for any other attention stack."""
+        return (self.tpu_config.batch_size
+                if self.spec.ssm is not None or self.spec.window_pool else 0)
+
+    @property
+    def window_ring_pages(self) -> int:
+        """Pages of a batch slot's ring in the window layers' pool (0: the
+        stack has none)."""
+        if self.cache is None or "k_w" not in self.cache:
+            return 0
+        return self.cache["k_w"].shape[1] // self.state_slots
+
+    @property
+    def warm_widths(self) -> List[int]:
+        """Token widths a paged step runs at, ascending: the decode step's
+        1, the chunked-prefill tile and the prefill buckets (what
+        ``precompile`` warms and a window pool's ring is sized from)."""
+        cfg = self.tpu_config
+        widths = {1, *self.ctx_buckets}
+        if cfg.is_chunked_prefill and cfg.chunked_prefill_config is not None:
+            widths.add(cfg.chunked_prefill_config.kernel_q_tile_size)
+        return sorted(widths)
 
     @property
     def prefill_row_buckets(self) -> List[int]:
@@ -1509,16 +1540,13 @@ class PagedCausalLMApplication(CausalLMApplication):
             self.init_cache()
         cfg = self.tpu_config
         b = cfg.batch_size
-        widths = {1}
-        if (cfg.is_chunked_prefill and cfg.chunked_prefill_config is not None):
-            widths.add(cfg.chunked_prefill_config.kernel_q_tile_size)
-        widths.update(self.ctx_buckets)
+        widths = self.warm_widths
         # 2-D table-width buckets: every (rows x prefill width x table
         # width) triple plus the chunked decode loop at every table width —
         # the shapes generate() and the serving adapter actually run
         chunk = max(cfg.decode_chunk_tokens, 1)
         for tw in self._bt_buckets:
-            for w in sorted(widths):
+            for w in widths:
                 for rows in ([b] if w == 1 else self.prefill_row_buckets):
                     self._run_paged(np.zeros((rows, w), np.int32),
                                     np.zeros((rows, w), np.int32),

@@ -172,6 +172,15 @@ class DecoderSpec:
     # pytree then carries {"k","v"} (global layers) + {"k_l","v_l"}
     # (local layers); decode selects per layer statically (unrolled).
     mixed_kv: bool = False
+    # the PAGED counterpart (``modules/block_kv_cache.window_pool_spec``):
+    # with a local/global ``layer_pattern`` and a ``sliding_window`` the
+    # paged cache holds two pools, the global layers' {"k","v"} (the
+    # allocator's blocks, full rows) and the window layers' {"k_w","v_w"}
+    # (a ring of ``window + widest step + block`` tokens a batch slot);
+    # the walk is :func:`run_layers_window`, static per kind. Set by the
+    # family that needs it (never derived: every other local/global stack
+    # keeps the one pool); what such a pool refuses: WINDOW_POOL_UNSUPPORTED
+    window_pool: bool = False
     # llama4 attention variations (reference: models/llama4/
     # modeling_llama4_text.py — chunked attention + NoPE layers):
     # local layers use CHUNKED attention (block-diagonal causal over
@@ -355,6 +364,11 @@ class DecoderSpec:
         if pat is None or self.ssm_parallel:
             return self.num_layers * self.sub_blocks
         return self.num_layers - sum(pat)
+
+    @property
+    def num_window_layers(self) -> int:
+        """Layers whose KV lies in the window layers' ring pool."""
+        return sum(self.layer_pattern) if self.window_pool else 0
 
     @property
     def num_ssm_layers(self) -> int:
@@ -1122,8 +1136,11 @@ def _layer_body(spec: DecoderSpec, hidden, layer_w, k_full, v_full, li,
 
     def _mlp(x_in):
         tally = [] if live is not None and mlp_kind == "moe" else None
-        out = _mlp_block(spec, x_in, layer_w, mlp_kind, adapter_ids,
-                         phase=phase, tally=tally, live=live)
+        out = _mlp_block(
+            spec, x_in, layer_w, mlp_kind, adapter_ids, phase=phase,
+            tally=tally, live=live,
+            router_x=(attn_in if mlp_kind == "moe"
+                      and spec.moe.router_pre_attn else None))
         if tally:
             caps["moe_tally"] = tally[0]
         return out
@@ -1198,14 +1215,15 @@ def _row_parallel_out(spec: DecoderSpec, x, w, phase: str):
 
 
 def _mlp_block(spec: DecoderSpec, x_in, layer_w, mlp_kind, adapter_ids,
-               phase: str = "prefill", tally=None, live=None):
+               phase: str = "prefill", tally=None, live=None, router_x=None):
     """The MLP / MoE half of a layer (GLU, plain 2-layer, or routed MoE),
     under the profiler scope ``moe`` (router, expert matmuls, combine) or
-    ``mlp``. ``tally`` / ``live``: ``moe_block``'s."""
+    ``mlp``. ``tally`` / ``live`` / ``router_x``: ``moe_block``'s (a router
+    that reads the attention's input runs under ``moe`` all the same)."""
     if mlp_kind == "moe":
         with jax.named_scope("moe"):
             return moe_block(spec.moe, x_in, layer_w, phase=phase,
-                             tally=tally, live=live)
+                             tally=tally, live=live, router_x=router_x)
     with jax.named_scope("mlp"):
         return _dense_mlp(spec, x_in, layer_w, adapter_ids, phase)
 
@@ -1331,6 +1349,12 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
         mask = jnp.where(is_local, ai["mask_l"], ai["mask"])
     else:
         cos, sin, mask = ai["cos"], ai["sin"], ai["mask"]
+    # a window layer of a stack with a window pool (run_layers_window):
+    # its K / V live in the row's ring, written, gathered and masked by
+    # window_ring_inputs' arrays
+    ring = ai.get("ring") if mixed_local and phase == "paged" else None
+    if ring is not None:
+        mask = ring["mask"]
     sink = layer_w["sink"] if spec.attn_sink else None
 
     def _alibi_for(n_kv):
@@ -1439,12 +1463,14 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                 more = (pool_heads - k.shape[2]) * (x.shape[2] // k.shape[2])
                 return jnp.pad(x, ((0, 0), (0, 0), (0, more), (0, 0)))
             q, k, v = grow(q), grow(k), grow(v)
+        write_at = slot_mapping if ring is None else ring["slots"]
+        read_table = block_table if ring is None else ring["table"]
         k_full = bkv.write_slots_at_layer(
             k_full, kv.quantize_kv(k, k_full.dtype, spec.kv_scale), li,
-            slot_mapping)
+            write_at)
         v_full = bkv.write_slots_at_layer(
             v_full, kv.quantize_kv(v, v_full.dtype, spec.kv_scale), li,
-            slot_mapping)
+            write_at)
         # ragged paged decode kernel (reference: DMA-skipping TKG attention
         # over the block layout, attention_base.py:1186-1382): reads only
         # each row's LIVE pages through the block table — the gather path
@@ -1456,13 +1482,21 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
             # graph on the full-table gather path is never a silent choice
             declined = _paged_kernel_declined(spec)
             if not declined:
-                if spec.layer_pattern is not None:
+                if mixed_local is not None:
+                    # static per kind (a window pool's walk); a window
+                    # layer's table is the row's ring as LOGICAL pages, and
+                    # the kernel reads none in front of the window's first
+                    win = jnp.asarray(
+                        spec.sliding_window if mixed_local else 0, jnp.int32)
+                elif spec.layer_pattern is not None:
                     win = jnp.where(is_local, spec.sliding_window, 0)
                 else:
                     win = jnp.asarray(spec.sliding_window, jnp.int32)
                 kernel_out = decode_attention.paged_dispatch(
                     q[:, 0], k_full, v_full, k[:, 0], v[:, 0], li,
-                    positions[:, 0], block_table, scale=spec.scale,
+                    positions[:, 0],
+                    block_table if ring is None else ring["kernel_table"],
+                    scale=spec.scale,
                     window=win, soft_cap=spec.attn_soft_cap, sink=sink,
                     kv_scale=spec.kv_scale,
                     interpret=kernel_mode.pallas_interpret())
@@ -1479,7 +1513,11 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                              declined or decode_attention.paged_dispatch_plan(
                                  q.shape[2], spec.head_dim, k_full,
                                  block_table.shape[1]).note(
-                                     k_full.shape[4] != spec.head_dim))
+                                     k_full.shape[4] != spec.head_dim)
+                             + ("" if mixed_local is None else
+                                f" window={spec.sliding_window} ring="
+                                f"{ring['pages']}" if mixed_local
+                                else " window=0"))
         if not use_pkernel:
             def gathered_mha(q_, bt_, mask_):
                 def gathered(pool):
@@ -1501,12 +1539,12 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
             # through in groups, one after another, so the temps stay a
             # group's worth
             if spec.alibi:
-                attn_out = gathered_mha(q, block_table, mask)
+                attn_out = gathered_mha(q, read_table, mask)
             else:
                 attn_out = map_row_groups(
                     gathered_mha, 4 * (g.num_q_heads // g.tp) * q.shape[1]
-                    * block_table.shape[1] * k_full.shape[2],
-                    q, block_table, mask)
+                    * read_table.shape[1] * k_full.shape[2],
+                    q, read_table, mask)
         if grown:
             attn_out = attn_out[:, :, :n_q]
     elif phase == "prefill":
@@ -1751,15 +1789,20 @@ RECURRENT_UNSUPPORTED = {
 }
 
 
+def _refusal(table, lead: str, asked) -> Optional[str]:
+    asked = [a for a in asked if a]
+    if not asked:
+        return None
+    return lead + "; ".join(f"{a} ({table[a]})" for a in asked)
+
+
 def recurrent_refusal(asked) -> Optional[str]:
     """The sentence that names every entry of ``asked`` (keys of
     :data:`RECURRENT_UNSUPPORTED` a caller found switched on; falsy entries
     are skipped) with its reason, or None where nothing was asked."""
-    asked = [a for a in asked if a]
-    if not asked:
-        return None
-    return ("a recurrent/hybrid (SSM) stack does not support: "
-            + "; ".join(f"{a} ({RECURRENT_UNSUPPORTED[a]})" for a in asked))
+    return _refusal(RECURRENT_UNSUPPORTED,
+                    "a recurrent/hybrid (SSM) stack does not support: ",
+                    asked)
 
 
 def refuse_recurrent(asked) -> None:
@@ -1768,6 +1811,186 @@ def refuse_recurrent(asked) -> None:
     why = recurrent_refusal(asked)
     if why:
         raise NotImplementedError(why)
+
+
+#: what a stack with a WINDOW POOL (``DecoderSpec.window_pool``: the window
+#: layers' KV in a ring a batch slot, ``block_kv_cache.window_pool_spec``)
+#: cannot do, by mechanism - the one table ``spec_from_config``, the verify /
+#: ragged / multi-token steps and the serving adapter refuse from, through
+#: :func:`refuse_window_pool`.
+WINDOW_POOL_UNSUPPORTED = {
+    "prefix caching": "a ring is overwritten as its row advances: a hit "
+                      "would need the window's keys at the hit point, and "
+                      "only the global layers' blocks keep theirs",
+    "speculation": "a draft window that is rolled back has already "
+                   "overwritten the ring slots of the window's oldest keys",
+    "ragged dispatch": "a row mixing chunk and decode widths needs the ring "
+                       "gathered by row kind",
+    "multi-token decode": "the ring is written one token or one chunk a "
+                          "row a step",
+    "fused decode loop": "the in-graph slot advance computes the global "
+                         "pool's slots only",
+    "host KV spill / handoff": "a spilled or handed-off block carries the "
+                               "global layers' KV only, not the ring that "
+                               "goes with it",
+    "tensor parallelism": "the ring pool and its slot arithmetic have run "
+                          "on one chip only (tp = 1)",
+    "tensor capture/replacement": "the walk by layer kind has no tap points",
+    "deepstack": "the walk by layer kind adds no per-layer visual features",
+    "alibi": "a ring's gathered keys are not at their absolute slots",
+}
+
+
+def window_pool_refusal(asked) -> Optional[str]:
+    """The sentence that names every entry of ``asked`` (keys of
+    :data:`WINDOW_POOL_UNSUPPORTED` a caller found switched on; falsy
+    entries are skipped) with its reason, or None where nothing was asked."""
+    return _refusal(WINDOW_POOL_UNSUPPORTED,
+                    "a stack whose window layers keep a ring of pages a "
+                    "batch slot (window pool) does not support: ", asked)
+
+
+def refuse_window_pool(asked) -> None:
+    """Raise NotImplementedError with :func:`window_pool_refusal`'s
+    sentence; nothing asked, nothing raised."""
+    why = window_pool_refusal(asked)
+    if why:
+        raise NotImplementedError(why)
+
+
+def window_ring_inputs(spec: DecoderSpec, pool_w, batch_slots: int,
+                       position_ids, slot_mapping, block_table,
+                       state_slots=None) -> Dict[str, Any]:
+    """What a window layer of a paged step reads its row's RING by
+    (``block_kv_cache.window_pool_spec``; ``pool_w`` one of its two arrays,
+    ``(layers, slots x R, block, ...)``), computed once a step on the
+    device from the step's positions - no second table crosses from the
+    host. Row ``i`` owns slot ``state_slots[i]`` (None: row ``i`` is slot
+    ``i``, the full-batch step):
+
+    * ``slots`` (B,T): where each token is written, ring page ``(pos //
+      block) % R`` of the row's slot; a token the global pool drops
+      (``slot_mapping`` < 0: padding, a dead row) is dropped here too;
+    * ``kernel_table`` (B, table width): the decode kernel's LOGICAL table,
+      entry ``j`` = ``slot x R + j % R`` (it reads from the window's first
+      page to the last live one, at most ``window / block + 1`` < R pages);
+    * ``table`` (B,R) and ``mask`` (B,T,R x block): the gathered form of a
+      chunk (and of a decode step the kernel declines): the ``R`` logical
+      pages that end at the row's last page of this step, and for each
+      query which of their keys it sees (written, causal, inside the
+      window)."""
+    b, t = position_ids.shape
+    bs = pool_w.shape[2]
+    ring = pool_w.shape[1] // batch_slots
+    if state_slots is None:
+        if b != batch_slots:
+            raise ValueError(
+                f"a paged step of {b} rows over {batch_slots} ring slots "
+                "needs state_slots (one slot index a row); without it row "
+                "i is slot i")
+        slot = jnp.arange(b, dtype=jnp.int32)
+    else:
+        slot = state_slots.astype(jnp.int32)
+    base = slot[:, None] * ring
+    pos = position_ids.astype(jnp.int32)
+    live = slot_mapping >= 0
+    slots = jnp.where(live, (base + (pos // bs) % ring) * bs + pos % bs, -1)
+    j = jnp.arange(block_table.shape[1], dtype=jnp.int32)
+    last_page = jnp.max(jnp.where(live, pos, 0), axis=1) // bs
+    logical = (last_page[:, None] - (ring - 1)
+               + jnp.arange(ring, dtype=jnp.int32)[None, :])       # (B, R)
+    kpos = (logical[:, :, None] * bs
+            + jnp.arange(bs, dtype=jnp.int32)[None, None, :]).reshape(b, -1)
+    return {"pages": ring, "slots": slots,
+            "kernel_table": base + (j % ring)[None, :],
+            "table": base + jnp.mod(logical, ring),
+            "mask": attn_ops.causal_mask(pos, kpos, kpos >= 0,
+                                         window=spec.sliding_window)}
+
+
+def _pattern_period(pattern) -> int:
+    """The shortest period of a per-layer pattern: the least ``p`` with
+    ``pattern[i] == pattern[i % p]`` throughout (its length where it does
+    not repeat). ``spec_from_config`` refuses a window pool whose period
+    does not divide the depth."""
+    n = len(pattern)
+    return next(p for p in range(1, n + 1)
+                if all(pattern[i] == pattern[i % p] for i in range(n)))
+
+
+def run_layers_window(spec: DecoderSpec, params, cache, hidden, ai,
+                      positions, *, slot_mapping, block_table,
+                      adapter_ids=None):
+    """The paged walk of a stack with a WINDOW POOL
+    (``DecoderSpec.window_pool``), static per layer kind: a scan over the
+    periods of ``layer_pattern`` whose body holds one period's layers (one
+    global and three window layers for SmallThinker), each indexing its own
+    leaves out of the stack (one layer at a time: scanned as ``(periods,
+    period, ...)`` the scan would materialise a whole period's weights,
+    PERF.md section 6, PR 40). A global layer reads and writes ``cache["k"]
+    / ["v"]`` through the allocator's table as every paged stack does, with
+    the global mask and (``nope_global``) no rotary; a window layer its
+    slot's ring in ``cache["k_w"] / ["v_w"]`` by ``ai["ring"]``
+    (:func:`window_ring_inputs`) with the local rotary. Global layer ``g``
+    of period ``l`` is cache layer ``l x globals + g`` of its pool, window
+    layers likewise. Expert leaves a custom call reads in place stay in
+    their stack (``moe.stack_leaves``); a decode step counts its routing
+    into ``moe_tally``. Returns (hidden, cache, per-layer outputs)."""
+    pat = spec.layer_pattern
+    period = _pattern_period(pat)
+    kinds = tuple(bool(x) for x in pat[:period])
+    n_w = sum(kinds)
+    n_g = period - n_w
+    layers = params["layers"]
+    b, t = hidden.shape[:2]
+    in_place = (moe_mod.stack_leaves(spec.moe, b * t, layers)
+                if spec.moe is not None else ())
+    sliced = {k: a for k, a in layers.items() if k not in in_place}
+    live = (slot_mapping >= 0 if t == 1 and spec.moe is not None else None)
+    kw_pool = cache["k_w"]
+    page_bytes = (kw_pool.shape[2] * kw_pool.shape[3] * kw_pool.shape[4]
+                  * kw_pool.dtype.itemsize * 2)
+    ring = ai["ring"]["pages"]
+    kernel_mode.note(
+        "kv_window_pool", "xla",
+        f"layers global={spec.num_layers - spec.num_window_layers} "
+        f"window={spec.num_window_layers} window_tokens="
+        f"{spec.sliding_window} ring_pages={ring} ring_bytes_a_row="
+        f"{spec.num_window_layers * ring * page_bytes} global_pool_bytes="
+        f"{2 * cache['k'].size * cache['k'].dtype.itemsize} "
+        f"window_pool_bytes={2 * kw_pool.size * kw_pool.dtype.itemsize}")
+
+    def body(carry, l):
+        x, kg, vg, kw_, vw_ = carry
+        tallies = []
+        seen = {False: 0, True: 0}
+        for j, local in enumerate(kinds):
+            li = l * period + j
+            w = {k: jax.lax.dynamic_index_in_dim(a, li, keepdims=False)
+                 for k, a in sliced.items()}
+            w.update({k: moe_mod.LayerOfStack(layers[k], li)
+                      for k in in_place})
+            ci = l * (n_w if local else n_g) + seen[local]
+            seen[local] += 1
+            kf, vf = (kw_, vw_) if local else (kg, vg)
+            x, kf, vf, caps = _layer_body(
+                spec, x, w, kf, vf, ci, ai, jnp.asarray(local), None,
+                positions, "paged",
+                slot_mapping=slot_mapping, block_table=block_table,
+                adapter_ids=adapter_ids, mixed_local=local, live=live)
+            if local:
+                kw_, vw_ = kf, vf
+            else:
+                kg, vg = kf, vf
+            if "moe_tally" in caps:
+                tallies.append(caps["moe_tally"])
+        return (x, kg, vg, kw_, vw_), (
+            {"moe_tally": sum(tallies)} if tallies else {})
+
+    (hidden, kg, vg, kw_, vw_), caps = jax.lax.scan(
+        body, (hidden, cache["k"], cache["v"], kw_pool, cache["v_w"]),
+        jnp.arange(spec.num_layers // period, dtype=jnp.int32))
+    return hidden, {**cache, "k": kg, "v": vg, "k_w": kw_, "v_w": vw_}, caps
 
 
 def _paged_kernel_declined(spec: DecoderSpec) -> str:
@@ -1835,6 +2058,16 @@ def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
             kv_view=kv_view, prefill_lens=prefill_lens,
             slot_mapping=slot_mapping, block_table=block_table,
             state_slots=state_slots)
+    if "k_w" in cache:
+        refuse_window_pool([
+            phase != "paged" and "multi-token decode",
+            (replacements is not None or spec.capture)
+            and "tensor capture/replacement",
+            deepstack is not None and "deepstack"])
+        return run_layers_window(
+            spec, params, cache, hidden, ai, positions,
+            slot_mapping=slot_mapping, block_table=block_table,
+            adapter_ids=adapter_ids)
     is_local = jnp.asarray(spec.layer_pattern if spec.layer_pattern is not None
                            else (False,) * spec.num_layers)
     rep = replacements or {}
@@ -2616,6 +2849,12 @@ def paged_forward_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     kv_len = block_table.shape[1] * cache["k"].shape[2]
     ai = attn_inputs(spec, position_ids, lambda w, c=0: attn_ops.decode_mask(
         position_ids, kv_len, window=w, chunk=c))
+    if "k_w" in cache:
+        # the window layers' ring, by the row's batch slot (the adapter's
+        # state slots: absent, row i is slot i)
+        ai["ring"] = window_ring_inputs(
+            spec, cache["k_w"], tpu_cfg.batch_size, position_ids,
+            slot_mapping, block_table, state_slots)
     hidden = _embed(spec, params, input_ids, position_ids)
     hidden, new_cache, side = run_layers(
         spec, params, cache, hidden, ai, None, position_ids,
@@ -2787,6 +3026,7 @@ def paged_decode_loop(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     first_tokens (B,); position_ids (B,); block_table (B, max_blocks).
     Returns tokens (B, num_steps) + cache."""
     refuse_recurrent([spec.ssm is not None and "fused decode loop"])
+    refuse_window_pool(["k_w" in cache and "fused decode loop"])
     bs = cache["k"].shape[2]                  # paged (L, N, Bs, H, D)
     b = first_tokens.shape[0]
     rows = jnp.arange(b)
@@ -2896,6 +3136,7 @@ def paged_spec_verify(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     Medusa/EAGLE proposers feed on the verified features).
     """
     refuse_recurrent([spec.ssm is not None and "speculation"])
+    refuse_window_pool(["k_w" in cache and "speculation"])
     if spec.mixed_kv:
         raise NotImplementedError(
             "speculative verify over mixed per-layer caches is "
@@ -2989,6 +3230,7 @@ def paged_ragged_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     Medusa/EAGLE proposers feed on the verified features).
     """
     refuse_recurrent([spec.ssm is not None and "ragged dispatch"])
+    refuse_window_pool(["k_w" in cache and "ragged dispatch"])
     if spec.mixed_kv:
         raise NotImplementedError(
             "the ragged unified dispatch over mixed per-layer "
@@ -3270,6 +3512,42 @@ def spec_from_config(config: InferenceConfig, tp_degree: Optional[int] = None,
                              or sc.medusa_speculation_length))
             and not (tcfg.tensor_capture_config
                      or tcfg.tensor_replacement_config))
+    # the paged counterpart of mixed_kv: a pool by layer kind, only where a
+    # family ASKS for it (smallthinker: one pool for every layer does not
+    # fit beside its weights), and then refused by name where something it
+    # cannot do is switched on. Every other local/global stack keeps the one
+    # pool: none has run the walk by kind on a chip (ROADMAP B4)
+    if kw.get("window_pool"):
+        sc = tcfg.speculation_config
+        pattern = kw.get("layer_pattern")
+        if not tcfg.is_block_kv_layout:
+            kw["window_pool"] = False       # the contiguous cache: mixed_kv
+        elif not (pattern is not None and kw.get("sliding_window", 0) > 0
+                  and kw.get("attn_chunk", 0) == 0
+                  and kw.get("ssm") is None and kw.get("mla") is None
+                  and kw.get("sub_blocks", 1) == 1
+                  and kw.get("moe_pattern") is None
+                  and not kw.get("first_dense")):
+            raise ValueError(
+                "window_pool needs a local/global layer_pattern with a "
+                "sliding_window on a plain attention stack")
+        elif len(pattern) % _pattern_period(pattern):
+            raise NotImplementedError(
+                f"window_pool: layer_pattern repeats every "
+                f"{_pattern_period(pattern)} layers, which does not divide "
+                f"its {len(pattern)} layers; the walk by layer kind scans "
+                "whole periods")
+        else:
+            refuse_window_pool([
+                tcfg.is_prefix_caching and "prefix caching",
+                sc and (sc.speculation_length
+                        or sc.medusa_speculation_length) and "speculation",
+                tcfg.decode_chunk_tokens > 1 and "fused decode loop",
+                tp > 1 and "tensor parallelism",
+                (tcfg.tensor_capture_config
+                 or tcfg.tensor_replacement_config)
+                and "tensor capture/replacement",
+                kw.get("alibi") and "alibi"])
     if not kw.get("vocab_parallel", True) and tp > 1:
         # older saved configs carry vocab_parallel=false from when the knob
         # was inert; honoring it replicates the (V, H) table on every device

@@ -190,10 +190,47 @@ def pool_spec(spec, num_blocks: int, block_size: int) -> BlockKVSpec:
                                  spec.gqa.tp)
         v_lanes = None
     return BlockKVSpec(
-        # SSM-only layers carry no KV pages (recurrent/hybrid stacks)
-        num_layers=spec.num_attn_layers, num_blocks=num_blocks + 1,
+        # SSM-only layers carry no KV pages (recurrent/hybrid stacks); the
+        # window layers of a stack with a window pool keep theirs in the
+        # ring (:func:`window_pool_spec`)
+        num_layers=spec.num_attn_layers - spec.num_window_layers,
+        num_blocks=num_blocks + 1,
         block_size=block_size, num_kv_heads=slots, head_dim=lanes,
         dtype=spec.kv_dtype, v_head_dim=v_lanes)
+
+
+def window_ring_pages(window: int, widest: int, block_size: int) -> int:
+    """Pages of a row's RING in the window layers' pool: room for ``window +
+    widest + block_size`` tokens, ``widest`` the widest prefill step. A
+    token at position ``p`` lies in ring page ``(p // block_size) % R``; a
+    chunk of ``T <= widest`` tokens overwrites positions ``R x block_size``
+    behind its own, which lie in front of the window of its first query as
+    long as ``R x block_size >= window + T``, and the ``R`` logical pages
+    that end at the chunk's last page reach back to that window's first key
+    wherever in its page the chunk ends (one more page)."""
+    return -(-(window + widest + block_size) // block_size)
+
+
+def window_pool_spec(spec, rows: int, block_size: int, widest: int
+                     ) -> BlockKVSpec:
+    """The SECOND pool of a stack whose window layers keep a window's worth
+    of a row (``DecoderSpec.window_pool``: a ``layer_pattern`` with a
+    ``sliding_window`` on the paged path): the window layers' keys and
+    values, :func:`window_ring_pages` pages for each of ``rows`` batch
+    slots, page ``slot x R + j`` the slot's ``j``-th. A page has the shape
+    :func:`pool_page` gives the global layers'. No allocator and no null
+    block: a slot's ring is the row's for as long as the row holds the slot
+    (the adapter's state slots), is overwritten in place as the row
+    advances, and is never freed; a pad row and a released row write
+    nothing there. Its size comes from the spec, the rows and the warmed
+    widths, not from ``pa_num_blocks``."""
+    slots, lanes = pool_page(spec.gqa.num_kv_heads, spec.head_dim,
+                             spec.gqa.tp)
+    ring = window_ring_pages(spec.sliding_window, widest, block_size)
+    return BlockKVSpec(
+        num_layers=spec.num_window_layers, num_blocks=rows * ring,
+        block_size=block_size, num_kv_heads=slots, head_dim=lanes,
+        dtype=spec.kv_dtype)
 
 
 def block_cache_pspec() -> P:

@@ -147,6 +147,11 @@ class MoESpec:
     # chip's: every share adds it for the rows it computes, like a shared
     # expert
     zero_experts: int = 0
+    # the router reads the ATTENTION's normed input, not the experts' (the
+    # post-attention norm): SmallThinker places its router in front of the
+    # attention block, so the routing is known before attention runs. The
+    # layer walk hands ``moe_block`` that array as ``router_x``
+    router_pre_attn: bool = False
 
     @property
     def num_routed(self) -> int:
@@ -587,14 +592,23 @@ def experts_touched(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
 
 def moe_block(moe: MoESpec, x: jnp.ndarray, layer_w: Dict[str, Any],
               phase: str = "prefill", tally: Optional[list] = None,
-              live: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+              live: Optional[jnp.ndarray] = None,
+              router_x: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Full MoE block: route + experts (+ shared experts). x (B,T,H).
     ``tally``: a list a layer walk hands in to collect, per expert layer,
     :func:`share_tally` + :func:`zero_tally` of this routing over the
     ``live`` rows: int32 ``[touched, assigned, read, picks, identity
-    picks]``."""
+    picks]``. ``router_x`` (B,T,H): what the router reads where that is not
+    the experts' input (``MoESpec.router_pre_attn``); the routing, the
+    tally and the combine weights come from it, the experts multiply
+    ``x``."""
+    if moe.router_pre_attn and router_x is None:
+        raise ValueError(
+            "MoESpec.router_pre_attn: the layer walk must hand moe_block the "
+            "attention's normed input as router_x; this walk does not")
     router_bias = layer_w.get("router_bias") if moe.has_router_bias else None
-    top_vals, top_idx = route(moe, x, layer_w["router"], router_bias)
+    top_vals, top_idx = route(moe, x if router_x is None else router_x,
+                              layer_w["router"], router_bias)
     if moe.holds_share:
         kernel_mode.note("moe_share", "xla",
                          f"held={moe.num_held} of {moe.num_experts} "

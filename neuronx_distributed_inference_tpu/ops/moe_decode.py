@@ -68,6 +68,12 @@ MOE_ROWS_VMEM_BYTES = 40 * 1024 * 1024
 #: PR 39). A full-batch pack of 1024 rows at a hidden size of 2048 would fit
 #: the bytes above too and has met no clock: it keeps the grouped matmuls
 MOE_WALK_MAX_ROWS = 512
+
+#: gate activations of a gated expert the walk computes (``moe._glu`` is
+#: applied to the float32 products inside the kernel): SiLU, and ReLU
+#: (SmallThinker's ReLU-gated experts). The zeros a ReLU leaves are NOT
+#: exploited: every column of a touched expert is read and multiplied.
+WALK_ACTS = ("silu", "relu")
 #: lanes of a vreg: a piece's width and both matrix dimensions are whole
 #: multiples of it
 LANES = 128
@@ -149,7 +155,7 @@ def declined(moe, wg: Any, tokens: int = 1) -> str:
         return "input_scaled routing scales the expert input"
     if moe.expert_bias:
         return "per-expert biases"
-    if moe.glu_style != "gated" or moe.act != "silu":
+    if moe.glu_style != "gated" or moe.act not in WALK_ACTS:
         return f"glu {moe.glu_style}/{moe.act}"
     h, i = wg.shape[-2:]
     plan = moe_decode_plan(h, i, wg.dtype)

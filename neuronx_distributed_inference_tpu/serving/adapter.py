@@ -1648,8 +1648,13 @@ class PagedEngineAdapter(_EngineAdapterBase):
         self._state_free: List[int] = list(range(app.state_slots))
         self._state_slot: Dict[int, int] = {}
         if app.state_slots:
-            from ..models.model_base import recurrent_refusal
-            why = recurrent_refusal([
+            # a stack with a window pool holds a slot a live sequence for
+            # the same reason (its ring of pages in the window layers'
+            # pool) and refuses from its own table
+            from ..models.model_base import (recurrent_refusal,
+                                             window_pool_refusal)
+            why = (window_pool_refusal if app.spec.ssm is None
+                   else recurrent_refusal)([
                 ragged and "ragged dispatch",
                 speculation is not None and "speculation",
                 kv_spill_tier is not None and "host KV spill / handoff"])
@@ -1657,6 +1662,15 @@ class PagedEngineAdapter(_EngineAdapterBase):
                 raise ConfigurationError(why)
             self.host_stats.update(state_slot_allocs=0, state_slot_frees=0,
                                    state_slots_live=0)
+        # the window layers' pool (DecoderSpec.window_pool): pages a slot's
+        # ring has, counted at every decode dispatch against what the same
+        # layers would hold at full length
+        self._ring_pages = app.window_ring_pages
+        if self._ring_pages:
+            self.host_stats.update(kv_window_pages_held=0,
+                                   kv_window_pages_unwindowed=0,
+                                   kv_tokens_in_window=0,
+                                   kv_tokens_running=0)
         # host-RAM KV spill tier (serving/fleet/kv_tier.py): evicted
         # prefix blocks spill their payloads host-side and re-admit via
         # async H2D restore instead of recompute-prefill (README "Fleet")
@@ -2059,6 +2073,8 @@ class PagedEngineAdapter(_EngineAdapterBase):
             _async_fetch(out["moe_tally"])
         self.host_stats["dispatches"] += 1
         self.host_stats["device_steps"] += 1
+        if self._ring_pages:
+            self._count_window_pool()
         rec = _get_recorder()
         if rec.enabled:
             rec.instant("dispatch.decode", cat="adapter",
@@ -2509,6 +2525,45 @@ class PagedEngineAdapter(_EngineAdapterBase):
                 self._scratch = None
             self._abort_pending(sid)
         self.telemetry.on_admission_rollback()
+
+    # -- the window layers' pool (stacks with a window pool) ---------------
+    def window_pool_rows(self):
+        """Over the running rows, a window layer's share of the pool by
+        layer kind: pages their rings hold (a row's pages up to the
+        ring's), pages the layer would hold at full length, the rows'
+        tokens inside the window, and all of them."""
+        bs = self.app.kv_mgr.spec.block_size
+        ring, window = self._ring_pages, self.app.spec.sliding_window
+        held = full = in_window = running = 0
+        for st in self.seqs.values():
+            n = int(st.position)
+            pages = -(-n // bs)
+            held += min(pages, ring)
+            full += pages
+            in_window += min(n, window)
+            running += n
+        return held, full, in_window, running
+
+    def _count_window_pool(self) -> None:
+        """At a decode dispatch: :meth:`window_pool_rows` over the window
+        layers, pages held and pages at full length summed into
+        ``host_stats`` (``kv_window_pages_held`` / ``_unwindowed``), the
+        tokens as gauges (``kv_tokens_in_window`` / ``kv_tokens_running``:
+        what a decode step's window layers and global layers must read)."""
+        spec = self.app.spec
+        held, full, in_window, running = self.window_pool_rows()
+        layers = spec.num_window_layers
+        stats = self.host_stats
+        stats["kv_window_pages_held"] += layers * held
+        stats["kv_window_pages_unwindowed"] += layers * full
+        stats["kv_tokens_in_window"] = in_window
+        stats["kv_tokens_running"] = running
+        reg = self.telemetry.registry
+        if reg.enabled:
+            gauge = tmetrics.kv_pool_pages_gauge(reg)
+            gauge.set(layers * held, engine=self.engine_name, kind="window")
+            gauge.set((spec.num_attn_layers - layers) * full,
+                      engine=self.engine_name, kind="global")
 
     # -- recurrent-state slots (recurrent/hybrid stacks) -------------------
     def _take_state_slot(self, sid: int) -> None:

@@ -369,7 +369,11 @@ def memory_ledger(adapter, *, registry=None,
     # state (``ssm.ssm_state_shapes`` of its kind) in the same dict,
     # accounted below
     pool_bytes = _tree_bytes({k: app.cache[k] for k in ("k", "v")})
-    state_bytes = _tree_bytes(app.cache) - pool_bytes
+    # the window layers' ring pool of a stack with one (no allocator: a
+    # ring a batch slot), beside the blocks the global layers book
+    window_bytes = _tree_bytes({k: app.cache[k] for k in ("k_w", "v_w")
+                                if k in app.cache})
+    state_bytes = _tree_bytes(app.cache) - pool_bytes - window_bytes
     block_bytes = pool_bytes // spec.num_blocks
     usable = spec.num_blocks - 1               # block 0 is the null block
     free = int(mgr.allocator.num_free)
@@ -404,7 +408,17 @@ def memory_ledger(adapter, *, registry=None,
                    "stats": dict(tier.stats)}),
         "headroom": admission_headroom(adapter),
     }
-    if app.state_slots:
+    ring = app.window_ring_pages
+    if ring:
+        n_window = app.spec.num_window_layers
+        held = adapter.window_pool_rows()[0]
+        ledger["kv"]["window_pool_bytes"] = window_bytes
+        ledger["kv"]["pages"] = {
+            "global": in_use * spec.num_layers,
+            "window": held * n_window,
+            "window_ring": ring, "window_slots": int(app.state_slots),
+            "window_allocated": ring * int(app.state_slots) * n_window}
+    if app.state_slots and state_bytes:
         ledger["state"] = {
             "bytes": state_bytes, "slots": int(app.state_slots),
             "slot_bytes": state_bytes // int(app.state_slots),

@@ -86,6 +86,7 @@ STEADY_STATE_RECOMPILES_TOTAL = \
 HBM_MODEL_BYTES = "nxdi_hbm_model_bytes"
 HBM_KV_BYTES = "nxdi_hbm_kv_bytes"                    # state
 STATE_SLOTS = "nxdi_state_slots"                      # engine, state
+KV_POOL_PAGES = "nxdi_kv_pool_pages"                  # engine, kind
 STATE_SLOT_EVENTS_TOTAL = "nxdi_state_slot_events_total"  # engine, event
 KV_FRAGMENTATION_RATIO = "nxdi_kv_fragmentation_ratio"
 
@@ -486,6 +487,15 @@ def state_slots_gauge(reg):
         "Per-sequence recurrent-state slots of a recurrent/hybrid stack by "
         "state (live|free): the second cache beside the KV pool",
         labels=("engine", "state"))
+
+
+def kv_pool_pages_gauge(reg):
+    return reg.gauge(
+        KV_POOL_PAGES,
+        "Pages (a page a layer) the running rows hold in the KV pools of a "
+        "stack with a window pool, by layer kind: global (the allocator's "
+        "blocks, full rows) | window (a ring a batch slot)",
+        labels=("engine", "kind"))
 
 
 def state_slot_events_counter(reg):

@@ -28,7 +28,12 @@ not run).
   * the same for ``qwen3-next-80b-a3b`` (ISSUE 36): every published number
     but depth, experts held and vocabulary, the share's keys, the memory
     arithmetic re-derived, the reference gating a toy twin that holds a
-    share, and a served slot against ``final_states``.
+    share, and a served slot against ``final_states``;
+  * ``smallthinker-21b-a3b`` (ISSUE 43): every published number but the
+    depth and the two layouts cut with it, the twin's shrunk window, the
+    two pools and the ring re-derived from what the application allocates,
+    the reference gating a toy twin, and three faults in the PROGRAM (the
+    window, the router's input, the gate's activation) failing it.
 """
 
 import json
@@ -824,5 +829,215 @@ def test_a_dropped_identity_term_does_not_pass_the_longcat_toy_gate(
     monkeypatch.setattr(moe, "zero_expert_weight",
                         lambda spec, vals, idx: 0.0 * vals[..., 0])
     res = build.logit_gate(_longcat_toy(), seed=2**31 + 40,
+                           served_precision="highest")
+    assert not res["passed"] and res["worst_ratio"] > 10
+
+
+# ---------------------------------------------------------------------------
+# smallthinker-21b-a3b (ISSUE 43)
+# ---------------------------------------------------------------------------
+
+def test_smallthinker_keeps_every_published_number():
+    """The catalog row's ``config`` (model-configs guide), as copied into
+    ISSUE 43: every key at the top level of the file, no width changed, and
+    ``reduced`` names the depth and the two layouts cut with it, and nothing
+    else: all 64 experts and the whole vocabulary are held."""
+    cfg = build.load_json("configs", "smallthinker-21b-a3b.json")
+    published = dict(
+        head_dim=128, hidden_size=2560, max_position_embeddings=16384,
+        model_name="smallthinker_21b_instruct", moe_ffn_hidden_size=768,
+        moe_num_active_primary_experts=6, moe_num_primary_experts=64,
+        moe_primary_router_apply_softmax=True, norm_topk_prob=True,
+        num_attention_heads=28, num_hidden_layers=52, num_key_value_heads=4,
+        rms_norm_eps=1e-06, rope_layout=[0, 1, 1, 1] * 13, rope_scaling=None,
+        rope_theta=1500000, sliding_window_layout=[0, 1, 1, 1] * 13,
+        sliding_window_size=4096, tie_word_embeddings=False,
+        vocab_size=151936)
+    assert set(published) <= set(cfg)
+    differs = sorted(k for k in published if cfg[k] != published[k])
+    assert differs == sorted(cfg["reduced"]) == [
+        "num_hidden_layers", "rope_layout", "sliding_window_layout"]
+    # two whole periods of the published layout
+    assert cfg["num_hidden_layers"] == 8
+    assert cfg["sliding_window_layout"] == cfg["rope_layout"] == \
+        published["sliding_window_layout"][:8]
+    assert cfg["family"] == cfg["model_type"] == "smallthinker"
+    assert cfg["chips"] == cfg["tp"] == 1
+    assert cfg["adapter"] == {"prefill_budget_tokens": max(
+        cfg["serve"]["context_encoding_buckets"])}
+    # a ring is overwritten: prefix reuse is refused, so the file has it off
+    assert cfg["serve"]["is_prefix_caching"] is False
+    assert {"model_type", "router_input", "attention", "expert_gate",
+            "experts", "routing", "rope", "kv_dtype", "router_dtype",
+            "window_pool", "adapter",
+            "two_rooflines_not_listed"} <= set(cfg["assumed"])
+    gate = cfg["gate"]
+    twin = build.hf_config(cfg, build.gate_overrides(gate))
+    # the twin: one period at the published widths, the window shrunk FOR
+    # THE TWIN so that 128 tokens cross it
+    assert (twin["num_hidden_layers"], twin["hidden_size"],
+            twin["moe_num_primary_experts"], twin["vocab_size"],
+            twin["sliding_window_size"]) == (4, 2560, 64, 151936, 64)
+    assert twin["sliding_window_layout"] == twin["rope_layout"] == \
+        [0, 1, 1, 1]
+    assert "TWIN" in gate["config_why"]
+    assert (gate["batch"], gate["prompt_len"], gate["new_tokens"]) == \
+        (4, 112, 16)
+    assert gate["prompt_len"] + gate["new_tokens"] <= \
+        4 * cfg["serve"]["pa_block_size"]
+    assert twin["sliding_window_size"] < gate["prompt_len"]
+    assert 0 < gate["excuse_margin_max"] <= 0.02
+    # between the sound twin's least share over the seeds of PR 43 (0.703)
+    # and the weakest control's decode share (0.14); the median between the
+    # sound twin's largest (0.488) and one precision down (6.0)
+    assert 0.14 < gate["min_positions_held"] < 0.70
+    assert 0.49 < gate["median_ratio_max"] < 6.0
+    for control in ("window mask dropped", "rotary applied on global",
+                    "one token wide", "post-attention norm", "SiLU for ReLU",
+                    "not renormalised", "fp8"):
+        assert control in gate["controls"], control
+    ref = build.load_reference("smallthinker")
+    assert len(ref.CONTROLS) == 6
+    # the pool cannot run dry: every row at its longest prompt and answer
+    mix = build.load_json("traffic", "mixedlen-closed.json")
+    serve = cfg["serve"]
+    longest = mix["prompt_len"]["hi"] + mix["output_len"]["hi"]
+    assert longest == serve["seq_len"] == 15360 <= \
+        cfg["max_position_embeddings"]
+    assert serve["pa_num_blocks"] * serve["pa_block_size"] == \
+        serve["batch_size"] * longest
+    # the mix is ISSUE 43's, every number of it; what it does to the 95th
+    # percentile of the token gaps is reported, not tuned around (the
+    # file's tail_note; PERF.md section 6)
+    assert (mix["loop"], mix["clients_per_batch_row"], mix["pool_requests"],
+            mix["lead_s"], mix["grace_s"], mix["base_seed"]) == \
+        ("closed", 2, 4096, 30.0, 8.0, 43)
+    assert mix["prompt_len"] == dict(kind="lognormal", median=4096,
+                                     sigma=1.0, lo=256, hi=14336)
+    assert "not tuned around it" in mix["tail_note"]
+    assert mix["output_len"] == dict(kind="lognormal", median=384, sigma=0.6,
+                                     lo=96, hi=1024)
+    # the cell is NOT on the two rooflines whose yardsticks do not fit it,
+    # and on their counterparts by layer kind and for a whole expert stack
+    cell = "smallthinker-mixedlen-closed"
+    listed = {m["name"] for m in BENCHMARK["per_layer"]
+              if cell in m.get("workloads", ())}
+    assert not {"kernel.paged_decode_roofline",
+                "kernel.moe_decode_roofline"} & listed
+    assert {"kernel.paged_decode_window_roofline",
+            "kernel.moe_decode_experts_roofline",
+            "kv.window_pages_held_share", "moe.prefill_walk_share",
+            "moe.experts_touched_share", "host.stall_s"} <= listed
+
+
+def test_smallthinker_allocates_what_its_file_says():
+    """The two pools, the ring, the weights and the total of the file's
+    ``memory``, against what the program would allocate: the full
+    configuration's pools and parameters as SHAPES (nothing of 11.7 GB is
+    allocated)."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    from neuronx_distributed_inference_tpu.models import model_base
+    from neuronx_distributed_inference_tpu.modules.block_kv_cache import (
+        pool_spec, window_pool_spec, window_ring_pages)
+    from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
+    cfg = build.load_json("configs", "smallthinker-21b-a3b.json")
+    memory, serve = cfg["memory"], cfg["serve"]
+    spec = build.build_app(cfg).spec
+    assert spec.window_pool and spec.nope_global
+    assert spec.layer_pattern == (False, True, True, True) * 2
+    assert spec.sliding_window == 4096
+    assert (spec.num_attn_layers, spec.num_window_layers,
+            spec.num_moe_layers) == (8, memory["window_layers"], 8)
+    assert spec.num_attn_layers - spec.num_window_layers == \
+        memory["global_layers"] == 2
+    m = spec.moe
+    assert (m.num_experts, m.top_k, m.intermediate_size, m.act) == \
+        (64, 6, 768, "relu")
+    assert m.pre_softmax_topk and m.normalize_topk and m.router_pre_attn
+    assert abs(spec.rope.rope_theta - 1.5e6) < 1e-6
+    # what PagedCausalLMApplication.init_cache allocates: the global
+    # layers' pool from pa_num_blocks, the window layers' from the spec,
+    # the rows and the widest warmed width
+    widest = max(serve["context_encoding_buckets"])
+    ring = window_ring_pages(4096, widest, serve["pa_block_size"])
+    assert ring == memory["window_ring_pages"] == 137
+    assert ring * serve["pa_block_size"] == memory["window_ring_tokens"] \
+        <= 4096 + 256 + 32 + 32
+    pool = pool_spec(spec, serve["pa_num_blocks"], serve["pa_block_size"])
+    wpool = window_pool_spec(spec, serve["batch_size"],
+                             serve["pa_block_size"], widest)
+    assert pool.shape == (2, 15361, 32, 1, 512)
+    assert wpool.shape == (6, 32 * 137, 32, 1, 512)
+    assert str(jnp.dtype(pool.dtype)) == cfg["assumed"]["kv_dtype"]
+    per_layer = pool.bytes_per_token // pool.num_layers
+    assert per_layer == memory["kv_bytes_per_token_per_layer"] == \
+        4 * 128 * 2 * 2
+    assert 2 * math.prod(pool.shape) * 2 == memory["global_pool_bytes"]
+    assert 2 * math.prod(wpool.shape) * 2 == memory["window_pool_bytes"] == \
+        6 * 2048 * 32 * 4384
+    # one pool for every layer at this context (8.05 GB) beside the weights
+    # leaves under a gigabyte of the 15.75 GiB a program may use, less than
+    # any of the cell's programs needs in temps
+    assert 8 * 2048 * 32 * 15360 + memory["weights_bytes"] + 1e9 \
+        > 15.75 * 2 ** 30
+    leaves = jax.tree.leaves(model_base.decoder_param_specs(spec),
+                             is_leaf=lambda x: isinstance(x, ParamSpec))
+    assert sum(math.prod(ps.shape) for ps in leaves) == \
+        memory["parameters"] == 8 * (21_140_480 + 64 * 5_898_240) \
+        + 2 * 151_936 * 2560 + 2560
+    weights = sum(math.prod(ps.shape) * jnp.dtype(ps.dtype).itemsize
+                  for ps in leaves)
+    # the program's count over the file's all-bf16 one: the routers in
+    # float32 (2 B more an entry)
+    assert weights - 2 * 8 * 2560 * 64 == memory["weights_bytes"] == \
+        2 * memory["parameters"]
+    total = (memory["weights_bytes"] + memory["global_pool_bytes"]
+             + memory["window_pool_bytes"])
+    assert total == memory["before_temps_bytes"]
+    assert 0.72 * 16e9 < total < 0.74 * 16e9
+
+
+def test_the_smallthinker_reference_gates_a_toy_twin():
+    from test_smallthinker_paged import _toy_file
+    ref = build.load_reference("smallthinker")
+    assert ref.__file__ == os.path.join(BENCH, "references",
+                                        "smallthinker.py")
+    toy = _toy_file()
+    res = build.logit_gate(toy, seed=2**31 + 43, served_precision="highest")
+    assert res["passed"], res
+    assert res["compared"] == 2 * 32 * toy["vocab_size"]
+
+
+@pytest.mark.parametrize("fault", ["window", "router", "relu"])
+def test_a_fault_in_the_program_does_not_pass_the_smallthinker_toy_gate(
+        monkeypatch, fault):
+    """The other direction of the controls: the PROGRAM broken, the
+    reference sound. A window layer that attends over everything its ring
+    holds, a router fed the experts' input, SiLU in the experts."""
+    import jax
+    from neuronx_distributed_inference_tpu.models import model_base
+    from neuronx_distributed_inference_tpu.modules import moe
+    from neuronx_distributed_inference_tpu.ops import attention as attn_ops
+    from test_smallthinker_paged import _toy_file
+    if fault == "window":
+        causal = attn_ops.causal_mask
+        monkeypatch.setattr(
+            attn_ops, "causal_mask",
+            lambda q, k, valid=None, window=0, chunk=0: causal(
+                q, k, valid, 0, chunk))
+        monkeypatch.setattr(model_base, "_paged_kernel_declined",
+                            lambda spec: "the test's")
+    elif fault == "router":
+        block = moe.moe_block
+        monkeypatch.setattr(
+            model_base, "moe_block",
+            lambda spec, x, w, router_x=None, **kw: block(
+                spec, x, w, router_x=x, **kw))
+    else:
+        monkeypatch.setitem(model_base.ACT_FNS, "relu", jax.nn.silu)
+    res = build.logit_gate(_toy_file(), seed=2**31 + 43,
                            served_precision="highest")
     assert not res["passed"] and res["worst_ratio"] > 10

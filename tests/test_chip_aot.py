@@ -30,7 +30,7 @@ from neuronx_distributed_inference_tpu.models import model_base
 from neuronx_distributed_inference_tpu.models.family import get_family
 from neuronx_distributed_inference_tpu.modules import ssm
 from neuronx_distributed_inference_tpu.modules.block_kv_cache import (
-    block_cache_pspec, pool_spec)
+    block_cache_pspec, pool_spec, window_pool_spec)
 from neuronx_distributed_inference_tpu.ops import kernel_mode
 from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
 from neuronx_distributed_inference_tpu.parallel.mesh import (MeshConfig,
@@ -121,6 +121,12 @@ def _serving_shapes(hf_attrs, layers, tp, devices, serve, prefix=True):
     pool_pspec = P() if bspec.is_latent else block_cache_pspec()
     cache = {"k": sds(bspec.shape, bspec.dtype, pool_pspec),
              "v": sds(bspec.v_shape, bspec.dtype, pool_pspec)}
+    if spec.window_pool:
+        # the window layers' ring pool, as init_cache sizes it
+        wspec = window_pool_spec(spec, tcfg.batch_size, tcfg.pa_block_size,
+                                 max(tcfg.context_encoding_buckets))
+        cache["k_w"] = sds(wspec.shape, wspec.dtype, pool_pspec)
+        cache["v_w"] = sds(wspec.v_shape, wspec.dtype, pool_pspec)
     if spec.ssm is not None:
         pspecs = ssm.ssm_state_pspecs(spec.ssm)
         for k, (shape, dt) in ssm.ssm_state_shapes(
@@ -587,6 +593,109 @@ def test_the_widest_longcat_program_fits_beside_weights_and_pool(
     memory = pack.memory_analysis()
     assert 13.0e9 < memory.argument_size_in_bytes < 13.1e9
     assert memory.temp_size_in_bytes < 2.2e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2 ** 30 - 258e6
+
+
+# PowerInfer/SmallThinker-21BA3B-Instruct config.json (model-configs
+# catalog) at the benchmark's cut: two periods of [global, window x 3]
+SMALLTHINKER_21B = dict(
+    model_type="smallthinker", head_dim=128, hidden_size=2560,
+    max_position_embeddings=16384, moe_ffn_hidden_size=768,
+    moe_num_active_primary_experts=6, moe_num_primary_experts=64,
+    moe_primary_router_apply_softmax=True, norm_topk_prob=True,
+    num_attention_heads=28, num_hidden_layers=8, num_key_value_heads=4,
+    rms_norm_eps=1e-6, rope_layout=[0, 1, 1, 1] * 2, rope_scaling=None,
+    rope_theta=1500000, sliding_window_layout=[0, 1, 1, 1] * 2,
+    sliding_window_size=4096, tie_word_embeddings=False, vocab_size=151936)
+SMALLTHINKER_SERVE = dict(batch_size=32, seq_len=15360, pa_block_size=32,
+                          pa_num_blocks=15360,
+                          context_encoding_buckets=[64, 256])
+
+
+def _smallthinker_program(v5e_devices, rows, width):
+    spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
+        SMALLTHINKER_21B, 8, 1, v5e_devices[:1], SMALLTHINKER_SERVE,
+        prefix=False)
+    assert spec.window_pool and mb == 480
+    assert cache["k"].shape == (2, 15361, 32, 1, 512)
+    assert cache["k_w"].shape == (6, 32 * 137, 32, 1, 512)
+    i32 = jnp.int32
+    kw = {} if rows == 32 else {"state_slots": sds((rows,), i32)}
+    notes = set()
+    with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
+        c = jax.jit(partial(model_base.paged_forward_step, spec, tcfg),
+                    donate_argnums=(1,)).lower(
+            params, cache, *(sds((rows, width), i32),) * 3,
+            sds((rows, mb), i32), sds((rows,), i32), None,
+            sds((2,), jnp.uint32), **kw).compile()
+    return c, notes
+
+
+def _smallthinker_pool_movers(text):
+    """Instructions that copy, transpose or relay an array of either pool's
+    shape (as stored, or as a consumer views it)."""
+    pools = ("2,15361,32,1,512", "6,4384,32,1,512", "2,15361,32,512",
+             "6,4384,32,512", "2,491552,1,512", "6,140288,1,512",
+             "30722,32,1,512", "26304,32,1,512", "30722,32,512",
+             "26304,32,512")
+    return [(name, shape, op) for name, shape, op in re.findall(
+        r"%(\S+) = bf16\[([\d,]+)\]\S* (\w[\w-]*)\(", text)
+        if shape in pools
+        and op not in ("parameter", "get-tuple-element", "bitcast",
+                       "fusion", "scatter", "custom-call", "while",
+                       "dynamic-update-slice")]
+
+
+def test_smallthinker_decode_walks_both_pools_in_place(v5e_devices):
+    """ISSUE 43: the decode step at SmallThinker's widths and the cell's
+    serving shape (two periods; the global layers' pool of 15,360 blocks, the
+    window layers' of 32 rings of 137 pages) holds the paged decode kernel
+    eight times - two calls over the allocator's table, six over the ring's
+    logical table with the window - and the walk over the touched ReLU-gated
+    experts; nothing copies, transposes or relays either pool or the expert
+    stacks, and the one-row chunk gathers a ring's pages, not the table."""
+    step, notes = _smallthinker_program(v5e_devices, 32, 1)
+    assert ("moe_decode", "pallas", "pieces=1 of 768") in notes
+    assert {(s, p, w.split(" fold")[1]) for s, p, w in notes
+            if s == "paged_decode"} == {
+        ("paged_decode", "pallas", "=4 stored window=0"),
+        ("paged_decode", "pallas", "=4 stored window=4096 ring=137")}
+    assert any(s == "kv_window_pool" and "global=2 window=6" in w
+               and "ring_pages=137" in w for s, _, w in notes)
+    text = step.as_text()
+    assert len(re.findall(r"%paged_decode_attention[.\d]* = ", text)) == 4
+    assert "%moe_decode_experts" in text
+    assert not _smallthinker_pool_movers(text), \
+        _smallthinker_pool_movers(text)
+    moved = [(name, shape, op) for name, shape, op in re.findall(
+        r"%(\S+) = bf16\[([\d,]+)\]\S* (\w[\w-]*)\(", text)
+        if shape in ("8,64,2560,768", "8,64,768,2560", "64,2560,768",
+                     "64,768,2560")
+        and op not in ("parameter", "get-tuple-element", "bitcast")]
+    assert not moved, moved
+    assert step.memory_analysis().temp_size_in_bytes < 300e6
+    chunk, notes = _smallthinker_program(v5e_devices, 1, 256)
+    assert ("moe_decode", "pallas",
+            "pieces=1 of 768 rows=256 by expert in tiles of 128") in notes
+    text = chunk.as_text()
+    assert "ragged-dot" not in text and "%moe_chunk_experts" in text
+    assert not _smallthinker_pool_movers(text), \
+        _smallthinker_pool_movers(text)
+    # a window layer's scores are 28 x 256 x 4384 float32 = 126 MB, a
+    # global layer's 28 x 256 x 15360 = 440 MB; both live at most once
+    assert chunk.memory_analysis().temp_size_in_bytes < 1.6e9
+
+
+def test_the_widest_smallthinker_program_fits_beside_weights_and_pools(
+        v5e_devices):
+    """ISSUE 43: ``paged_pack.w256`` at the configuration's size (32 rows x
+    256 tokens over tables of 15,360 positions) compiles for a v5e, which
+    refuses a program over 15.75 GB: 11.67 GB of arguments (weights 7.94,
+    global pool 2.01, window pool 1.72) and its temps."""
+    pack, _ = _smallthinker_program(v5e_devices, 32, 256)
+    memory = pack.memory_analysis()
+    assert 11.6e9 < memory.argument_size_in_bytes < 11.75e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 15.75 * 2 ** 30 - 258e6
 

@@ -10,7 +10,8 @@ experts, top-8, every expert held) and qwen3-next's share (512 routed
 over, 128 held from ``first_expert`` 128 on, top-10 renormalised) at toy
 widths that keep their aspect, OLMoE's real widths with few experts (in
 float32 an expert of 2048 x 1024 is walked in two pieces), and LongCat-Flash's
-share beside identity columns (ISSUE 40: ``MoESpec.zero_experts``)."""
+share beside identity columns (ISSUE 40: ``MoESpec.zero_experts``), and
+SmallThinker's ReLU-gated experts (ISSUE 43: ``moe_decode.WALK_ACTS``)."""
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +39,13 @@ GEOMETRIES = {
         num_experts=64 + 32, top_k=12, intermediate_size=128,
         held_experts=32, first_expert=16, zero_experts=32,
         normalize_topk=False, routed_scaling=6.0), 256, 32),
+    # SmallThinker's experts (ISSUE 43): ReLU-gated, the top-6 of the
+    # logits and the softmax over those; the zeros are not exploited. Its
+    # decode steps are test_moe_decode_relu.py's: this file is the suite's
+    # longest, and a file is one worker's
+    "smallthinker-relu": (moe_mod.MoESpec(
+        num_experts=64, top_k=6, intermediate_size=128,
+        pre_softmax_topk=True, act="relu"), 256, 64),
 }
 
 # (rows, tokens a row): decode steps of 1, 2, 16 and 32 rows and the
