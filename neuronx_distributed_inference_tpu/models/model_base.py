@@ -2414,15 +2414,25 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
     state_keys = [k for k in ("conv_x", "conv_bc", "ssm") if k in cache]
     new_state = {k: cache[k] for k in state_keys}
     valid = None
+    # the ONE place that decides who steps the state: the kernel, in place
+    # on the stack, or the XLA fusions on this layer's rows
+    by_rows = ("no matrix state" if "ssm" not in cache
+               else ssm_mod.state_kernel_declined(
+                   s, cache["ssm"], *hidden.shape[:2], state_slots))
     if paged:
         valid = slot_mapping >= 0
         # the engagement record (ops/kernel_mode.py) names what the serving
-        # graphs carry beside the KV pool: no kernel, the XLA fusions
+        # graphs carry beside the KV pool, and what steps it
         slot_bytes = sum(v.size // v.shape[1] * v.dtype.itemsize
                          for v in new_state.values())
-        kernel_mode.note("recurrent_state", "xla",
-                         f"kind={s.kind} slot_bytes={slot_bytes} "
-                         f"chunk={s.chunk_size}")
+        what = (f"kind={s.kind} slot_bytes={slot_bytes} "
+                f"chunk={s.chunk_size}")
+        if by_rows:
+            kernel_mode.note("recurrent_state", "xla", f"{what}: {by_rows}")
+        else:
+            kernel_mode.note(
+                "recurrent_state", kernel_mode.kernel_path(),
+                f"{what} {ssm_mod.state_kernel_note(s, cache['ssm'])}")
         if state_slots is None and hidden.shape[0] != new_state["ssm"].shape[1]:
             raise ValueError(
                 f"a paged step of {hidden.shape[0]} rows over "
@@ -2482,13 +2492,17 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
             # out_proj, and the state rows read and written)
             with jax.named_scope("mixer"):
                 st = {k: _state_rows(new_state[k], ssm_i, state_slots)
+                      if by_rows or k != "ssm"
+                      else ssm_mod.StateStack(new_state[k], ssm_i)
                       for k in state_keys}
                 s_out, st_new = ssm_mod.ssm_block(
                     s, lw, h, st, phase=phase, seq_lens=prefill_lens,
                     positions=positions, valid=valid)
                 for k2, v2 in st_new.items():
-                    new_state[k2] = _state_put(new_state[k2], ssm_i,
-                                               state_slots, v2)
+                    new_state[k2] = (
+                        v2.stack if isinstance(v2, ssm_mod.StateStack)
+                        else _state_put(new_state[k2], ssm_i, state_slots,
+                                        v2))
             t_out = s_out if t_out is None else t_out + s_out
             ssm_i += 1
         if post_norm:

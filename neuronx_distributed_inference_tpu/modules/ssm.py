@@ -35,13 +35,14 @@ reference's cached path (modeling_falcon_h1.py torch_forward cached branch).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..ops import delta_state_step, kernel_mode
 from ..parallel.layers import ParamSpec
 from ..parallel.mesh import AXIS_DP, AXIS_MP
 
@@ -442,6 +443,38 @@ def mamba2_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
 # Gated delta rule (Gated DeltaNet) — Olmo-Hybrid / Qwen3-Next flavor
 # ---------------------------------------------------------------------------
 
+class StateStack(NamedTuple):
+    """A layer's ``"ssm"`` state handed over as the whole stack (Ls, slots,
+    ...) and the layer's index in it: the block steps its layer IN PLACE
+    (the state-step kernel) and hands the stack back the same way, where it
+    is otherwise handed its layer's rows and hands rows back. Who gets
+    which is decided in :func:`state_kernel_declined` alone."""
+    stack: Any
+    layer: int
+
+
+def state_kernel_declined(s: SSMSpec, stack, rows: int, tokens: int,
+                          state_slots=None) -> str:
+    """Why a step of ``rows`` rows of ``tokens`` tokens over the ``"ssm"``
+    stack does not run on the state-step kernel ("" = it does:
+    ``ops/delta_state_step.py``), from what the step shows and from nothing
+    else: the state kind, one token a row, the rows being the slots, a tile
+    the kernel takes. The walk over the layers asks ONCE a program and
+    hands :func:`gated_delta_mixer` a :class:`StateStack` or its rows
+    accordingly."""
+    if s.kind != "gated_delta":
+        return f"no state-step kernel for kind {s.kind}"
+    return delta_state_step.declined(stack, rows, tokens, s.key_heads,
+                                     state_slots)
+
+
+def state_kernel_note(s: SSMSpec, stack) -> str:
+    """The engagement record's text for a step the kernel takes: a block's
+    heads and the tile."""
+    return delta_state_step.state_step_plan(
+        stack.shape[2], s.key_heads, *stack.shape[3:]).note()
+
+
 def _delta_step(q, k, v, g, beta, st0):
     """One token of the gated delta rule. q, k (B,H,dk), v (B,H,dv), g (log
     decay) and beta (B,H), st0 (B,H,dk,dv), all float32:
@@ -532,7 +565,11 @@ def gated_delta_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *,
     position is 0, and leaves the state and tail of padded positions and of
     dead rows as they were — ``valid`` and the reset are exactly
     :func:`mamba2_mixer`'s. T == 1 runs the O(1) state step, T > 1 the
-    chunked form in chunks of ``s.chunk_size``.
+    chunked form in chunks of ``s.chunk_size``. ``state["ssm"]`` as a
+    :class:`StateStack` (a T == 1 step whose rows are the slots:
+    :func:`state_kernel_declined`) is stepped in place by the kernel, once
+    across the state each way, and handed back as a :class:`StateStack`; a
+    dead row's ``o`` is then zero.
     """
     B, T, _ = x.shape
     f32 = jnp.float32
@@ -542,7 +579,7 @@ def gated_delta_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *,
     valid, n_valid, keep = _real_and_fresh(valid, phase, seq_lens, positions,
                                            (B, T))
     tail = jnp.where(keep[:, None, None], state["conv_x"], 0)
-    st0 = jnp.where(keep[:, None, None, None], state["ssm"].astype(f32), 0.0)
+    in_place = isinstance(state["ssm"], StateStack)
 
     proj = x @ lw["gdn_in"]
     qkv = jnp.where(valid[..., None], proj[..., :conv], 0)
@@ -550,14 +587,23 @@ def gated_delta_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *,
     ab = jnp.einsum("bth,hn->btn", x, lw["gdn_in_ab"],
                     preferred_element_type=f32)
     qkv_c = jax.nn.silu(_causal_conv_prefill(qkv, lw["gdn_conv"], None, tail))
-    new_state = {"conv_x": _conv_tail(qkv, n_valid, K1, tail)}
+    if T == 1:
+        # one token: the window slides by one where the token is real,
+        # two static slices and a select where :func:`_conv_tail` gathers
+        hist = _with_history(qkv, tail, K1)
+        new_tail = jnp.where(valid[:, :, None], hist[:, 1:],
+                             hist[:, :K1]).transpose(0, 2, 1)
+    else:
+        new_tail = _conv_tail(qkv, n_valid, K1, tail)
+    new_state = {"conv_x": new_tail}
 
     def heads(a):
         # l2-normalised per KEY head, then each repeated over the value
-        # heads it serves (value head j reads key head j // group)
+        # heads it serves (value head j reads key head j // group); the
+        # kernel reads a key head from each of its value heads instead
         a = a.astype(f32).reshape(B, T, s.key_heads, dk)
         a = a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
-        return a if s.key_heads == nh else jnp.repeat(
+        return a if in_place or s.key_heads == nh else jnp.repeat(
             a, nh // s.key_heads, axis=2)
     q = heads(qkv_c[..., :qk]) * dk ** -0.5
     k = heads(qkv_c[..., qk:2 * qk])
@@ -568,12 +614,22 @@ def gated_delta_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *,
     beta = jnp.where(valid[..., None],
                      s.beta_scale * jax.nn.sigmoid(ab[..., nh:]), 0.0)
 
-    if T == 1:
-        o, st = _delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-                            st0)
-        o = o[:, None]
+    if in_place:
+        layer = state["ssm"].layer
+        o, st = delta_state_step.delta_state_step(
+            state["ssm"].stack, layer, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+            beta[:, 0], keep, valid[:, 0],
+            interpret=kernel_mode.pallas_interpret())
+        o, st = o[:, None], StateStack(st, layer)
     else:
-        o, st = _delta_chunked(q, k, v, g, beta, st0, s.chunk_size)
+        st0 = jnp.where(keep[:, None, None, None], state["ssm"].astype(f32),
+                        0.0)
+        if T == 1:
+            o, st = _delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                beta[:, 0], st0)
+            o = o[:, None]
+        else:
+            o, st = _delta_chunked(q, k, v, g, beta, st0, s.chunk_size)
     new_state["ssm"] = st
 
     # per head: rmsnorm(o) * silu(gate) (norm_before_gate, the published

@@ -70,3 +70,11 @@ def experts_path(notes) -> str:
              for site, path, _ in notes
              if site in ("moe_ragged", "moe_decode")}
     return paths.pop() if len(paths) == 1 else "mixed" if paths else "none"
+
+
+def state_on_kernel(notes) -> bool:
+    """Whether a program stepped its recurrent state on the state-step
+    kernel, from the :func:`note` triples its trace left
+    (``models/model_base.py`` ``run_layers_ssm`` writes them)."""
+    return any(site == "recurrent_state" and path != "xla"
+               for site, path, _ in notes)

@@ -844,6 +844,10 @@ class _EngineAdapterBase:
         # two stay separately comparable.
         self.host_stats: Dict[str, Any] = {
             "dispatches": 0, "device_steps": 0,
+            # decode dispatches whose program's engagement record says its
+            # recurrent state was stepped by the state-step kernel
+            # (kernel_mode.state_on_kernel)
+            "dispatches_state_kernel": 0,
             "blocking_fetches": 0, "blocked_s": 0.0,
             # decode dispatches enqueued while the previous step was still
             # unfetched, and in-flight steps drained synchronously because
@@ -1662,6 +1666,9 @@ class PagedEngineAdapter(_EngineAdapterBase):
                 raise ConfigurationError(why)
             self.host_stats.update(state_slot_allocs=0, state_slot_frees=0,
                                    state_slots_live=0)
+        # paged program shape -> its state is stepped by the kernel
+        # (_state_on_kernel)
+        self._state_kernel_shapes: Dict[Tuple[int, int], bool] = {}
         # the window layers' pool (DecoderSpec.window_pool): pages a slot's
         # ring has, counted at every decode dispatch against what the same
         # layers would hold at full length
@@ -2050,6 +2057,18 @@ class PagedEngineAdapter(_EngineAdapterBase):
             self._scratch = scr
         return scr
 
+    def _state_on_kernel(self, shape) -> bool:
+        """Whether the paged program of ``shape``, once it has been
+        dispatched, steps its recurrent state on the state-step kernel:
+        read from the record its trace left (the rule itself lives in
+        ssm.state_kernel_declined alone), once a program shape."""
+        on = self._state_kernel_shapes.get(shape)
+        if on is None:
+            on = self._state_kernel_shapes[shape] = \
+                kernel_mode.state_on_kernel(
+                    self.app.paged_program_notes(*shape))
+        return on
+
     def _dispatch_decode(self, scr: _PagedScratch, toks_dev=None):
         """Issue ONE paged decode step to the device without materializing
         any output (region lint: nxdi_lint host-sync pass). ``toks_dev``:
@@ -2073,6 +2092,8 @@ class PagedEngineAdapter(_EngineAdapterBase):
             _async_fetch(out["moe_tally"])
         self.host_stats["dispatches"] += 1
         self.host_stats["device_steps"] += 1
+        if self._state_on_kernel(scr.ids.shape):
+            self.host_stats["dispatches_state_kernel"] += 1
         if self._ring_pages:
             self._count_window_pool()
         rec = _get_recorder()

@@ -313,6 +313,27 @@ def test_olmoe_chunk_reads_experts_and_pool_in_place(v5e_devices):
     assert MOSAIC in decode.as_text()        # the paged decode kernel
 
 
+def _state_stepped_in_place(text: str, stack: str, layers: int):
+    """ISSUE 44: a T = 1 step's compiled text holds the state-step kernel
+    once a delta-rule layer, and nothing copies, transposes or slices the
+    state stack ``f32[stack]`` or one layer of it out."""
+    assert len(re.findall(r"%delta_state_step\S* = ", text)) == layers
+    layer = stack.split(",", 1)[1]
+    moves = re.findall(
+        rf"%(\S+) = f32\[(?:{stack}|{layer})\]\S* "
+        r"(copy|transpose|dynamic-slice|dynamic-update-slice|fusion)\(",
+        text)
+    assert not moves, moves
+
+
+def _state_layout(text: str, stack: str) -> str:
+    """The layout the device gives the state-stack parameter."""
+    layout, = set(re.findall(
+        rf"f32\[{stack}\](\S+) parameter\(", text))
+    print(f"state parameter f32[{stack}]{layout}")
+    return layout
+
+
 # allenai/Olmo-Hybrid-7B config.json (model-configs catalog), one period
 OLMO_HYBRID_7B = dict(
     model_type="olmo_hybrid", vocab_size=100352, hidden_size=3840,
@@ -357,22 +378,29 @@ def test_30_kv_heads_of_128_decode_on_the_kernel_with_no_pool_copy(
                 sds((2,), jnp.uint32), **kw).compile()
         return c, notes
 
-    state = ("recurrent_state", "xla",
-             "kind=gated_delta slot_bytes=6842880 chunk=64")
+    state = "kind=gated_delta slot_bytes=6842880 chunk=64"
     step, notes = compiled(32, 1)
     pool = ("kv_pool", "xla", "page=32x128 heads=32x128")
-    assert notes == {state, pool, (
-        "paged_decode", "pallas",
-        "pages=1 heads=32 form=mxu-blockdiag fold=1")}
+    # ISSUE 44: the T = 1 step's state is stepped by the kernel, in place
+    assert notes == {
+        ("recurrent_state", "pallas", f"{state} heads=30 tile=96x192"),
+        pool, ("paged_decode", "pallas",
+               "pages=1 heads=32 form=mxu-blockdiag fold=1")}
     text = step.as_text()
     assert MOSAIC in text
     moves = re.findall(
         r"%(\S+) = bf16\[(?:1,1793,32,32,128|57376,32,128)\]\S* "
         r"(copy|transpose)\(", text)
     assert not moves, moves
+    _state_stepped_in_place(text, "3,32,30,96,192", layers=3)
+    # what the device gives the state: 192 lanes tile to 256 (a third more
+    # bytes in HBM than the values; PERF.md section 6, PR 44)
+    assert _state_layout(text, "3,32,30,96,192") == "{4,3,2,1,0:T(8,128)}"
     assert step.memory_analysis().temp_size_in_bytes < 100e6
     chunk, notes = compiled(1, 256, state_slots=sds((1,), i32))
-    assert notes == {state, pool}
+    assert notes == {("recurrent_state", "xla",
+                      f"{state}: 256 tokens a row: the chunked form"), pool}
+    assert "delta_state_step" not in chunk.as_text()
     assert chunk.memory_analysis().temp_size_in_bytes < 200e6
 
 
@@ -430,26 +458,34 @@ def test_2_kv_heads_of_256_decode_on_the_kernel_with_no_pool_copy(
             r"%(\S+) = bf16\[(?:1,)?4097,[\d,]+\]\S* "
             r"(copy|transpose|reshape)\(", text)
 
-    state = ("recurrent_state", "xla",
-             "kind=gated_delta slot_bytes=6438912 chunk=64")
+    state = "kind=gated_delta slot_bytes=6438912 chunk=64"
     share = ("moe_share", "xla", "held=128 of 512 from 0 top_k=10")
     pool = ("kv_pool", "xla", "page=1x512 heads=2x256")
     step, notes = compiled(32, 1)
-    assert notes == {state, share, pool, (
+    # ISSUE 44: the T = 1 step's state is stepped by the kernel, in place,
+    # a value head reading its key head (16 for 32) through the index
+    assert notes == {
+        ("recurrent_state", "pallas", f"{state} heads=32 tile=128x128"),
+        share, pool, (
         "paged_decode", "pallas",
         "pages=16 heads=2 form=mxu-blockdiag fold=2 stored"),
         ("moe_decode", "pallas", "pieces=1 of 512")}
     text = step.as_text()
     assert MOSAIC in text and "ragged-dot" not in text
     assert not pool_moves(text), pool_moves(text)
+    _state_stepped_in_place(text, "3,32,32,128,128", layers=3)
+    assert _state_layout(text, "3,32,32,128,128") == "{4,3,2,1,0:T(8,128)}"
     assert step.memory_analysis().temp_size_in_bytes < 100e6
     chunk, notes = compiled(1, 256, state_slots=sds((1,), i32))
     # ISSUE 39: 256 x 10 / 512 = 5 rows an expert: the chunk's experts are
     # the walk's, each touched expert against ITS rows
-    assert notes == {state, share, pool, (
+    assert notes == {
+        ("recurrent_state", "xla",
+         f"{state}: 256 tokens a row: the chunked form"), share, pool, (
         "moe_decode", "pallas",
         "pieces=1 of 512 rows=256 by expert in tiles of 128")}
     text = chunk.as_text()
+    assert "delta_state_step" not in text
     assert "ragged-dot" not in text and "%moe_chunk_experts" in text
     assert not pool_moves(text), pool_moves(text)
     copied = re.findall(r"slice_bitcast_fusion[.\d]* = bf16\[([\d,]+)\]",

@@ -99,7 +99,7 @@ def _app(ref, w, hf=HF, **serve):
                      **dict(SERVE, **serve))
     app = PagedCausalLMApplication(None, family.config_cls(tcfg, **hf),
                                    family)
-    view = weights.HfView(ref.weight_shapes(HF), w,
+    view = weights.HfView(ref.weight_shapes(hf), w,
                           dtype=np.dtype("float32"))
     app._put_params(family.convert_hf_state_dict(view, app.spec))
     return app.init_cache()
@@ -110,8 +110,8 @@ def app(ref, gate_weights):
     return _app(ref, gate_weights)
 
 
-def _want(ref, w, tokens):
-    return np.asarray(ref.forward(HF, w, jnp.asarray([tokens])))[0]
+def _want(ref, w, tokens, hf=HF):
+    return np.asarray(ref.forward(hf, w, jnp.asarray([tokens])))[0]
 
 
 def _error(tap, ref, w, sid, prompt, stream):
@@ -152,6 +152,107 @@ def test_a_three_chunks_with_a_padded_last_one_then_decode(app, ref,
     ad.release([7])
     assert ad.host_stats["state_slots_live"] == 0
     assert ad._state_free == list(range(BATCH))
+
+
+#: the same period with a tile the state-step kernel takes (ISSUE 44):
+#: (8, 64) where HF's (8, 16) keeps the XLA step
+HF_KERNEL = dict(HF, linear_value_head_dim=64)
+
+
+def test_a_chunks_then_decode_on_the_state_kernel(ref):
+    """Three chunks through the chunked form (the one-row program), then
+    nine decode steps on the state-step kernel, in place on the slots: the
+    logits at every position and the slot's final state are the float32
+    reference's, a dead row's slot (three of the four here) is left as it
+    was, and the records say which program took which path."""
+    w = weights.make_weights(ref.weight_shapes(HF_KERNEL), seed=2**31 + 44)
+    app = _app(ref, w, hf=HF_KERNEL)
+    ad = PagedEngineAdapter(app)
+    tap = LogitTap(app)
+    stream = [ad.add_requests([7], [P37])[7]]
+    slot = ad._state_slot[7]
+    others = [r for r in range(BATCH) if r != slot]
+    idle = np.asarray(app.cache["ssm"][:, others])
+    _decode(ad, [7], {7: stream}, 9)
+    assert tap.shapes == [(1, 16), (1, 16), (1, 8)] + [(BATCH, 1)] * 9
+    fed = P37 + stream[:-1]
+    want = _want(ref, w, fed, HF_KERNEL)
+    assert float(np.abs(tap.logits(7, len(fed)) - want).max()) < ATOL
+    assert stream == want[len(P37) - 1:].argmax(-1).tolist()
+    assert app.cache["ssm"].dtype == jnp.float32
+    got = np.asarray(app.cache["ssm"][:, slot])
+    want = np.asarray(ref.final_states(
+        HF_KERNEL, w, jnp.asarray([fed])))[:, 0]
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    assert (np.asarray(app.cache["ssm"][:, others]) == idle).all()
+    slot_bytes = 3 * (2 * 8 * 64 * 4 + (2 * 2 * 8 + 2 * 64) * 3 * 4)
+    what = f"kind=gated_delta slot_bytes={slot_bytes} chunk=64"
+    assert ("recurrent_state", "pallas-interpret",
+            f"{what} heads=2 tile=8x64") in app.paged_program_notes(BATCH, 1)
+    assert ("recurrent_state", "xla",
+            f"{what}: 16 tokens a row: the chunked form") in \
+        app.paged_program_notes(1, 16)
+
+
+def test_the_contiguous_decode_phase_steps_on_the_kernel_too(ref,
+                                                             monkeypatch):
+    """``generate()`` on the contiguous cache: the rows of its ``decode``
+    phase are the state's slots, so its T = 1 steps take the kernel as the
+    paged step does (``_delta_step`` is never reached), and the greedy
+    tokens are the reference's."""
+    from neuronx_distributed_inference_tpu.models.application import \
+        CausalLMApplication
+
+    def unreachable(*a, **kw):
+        raise AssertionError("the XLA step ran where the kernel should")
+    monkeypatch.setattr(ssm, "_delta_step", unreachable)
+    w = weights.make_weights(ref.weight_shapes(HF_KERNEL), seed=2**31 + 44)
+    family = get_family("olmo_hybrid")
+    tcfg = TpuConfig(tp_degree=1, dtype="float32", batch_size=2, seq_len=64)
+    app = CausalLMApplication(None, family.config_cls(tcfg, **HF_KERNEL),
+                              family)
+    view = weights.HfView(ref.weight_shapes(HF_KERNEL), w,
+                          dtype=np.dtype("float32"))
+    app._put_params(family.convert_hf_state_dict(view, app.spec))
+    app.init_cache()
+    prompts = [P37[:20], Q29[:20]]
+    got = np.asarray(app.generate(np.asarray(prompts, np.int32),
+                                  max_new_tokens=6)["generated"])
+    for row, prompt in zip(got, prompts):
+        seq = list(prompt)
+        for _ in range(6):
+            seq.append(int(_want(ref, w, seq, HF_KERNEL)[-1].argmax()))
+        assert row.tolist() == seq[20:]
+
+
+@pytest.mark.parametrize("stack, on_kernel", [
+    ("delta rule, a tile the kernel takes", True),
+    ("delta rule, a tile it declines", False),
+    ("mamba-2", False), ("attention only", False)])
+def test_the_adapter_counts_decode_steps_on_the_state_kernel(stack,
+                                                             on_kernel):
+    """``host_stats["dispatches_state_kernel"]`` (ISSUE 44; the numerator
+    of ``mixer.state_kernel_share``): every decode dispatch of a program
+    whose record says ``recurrent_state`` ran on the kernel, none of any
+    other stack's; read from the record, once a program shape."""
+    from test_prefill_rows import HF as LLAMA
+    from test_recurrent_paged import HF as GRANITE
+    name, hf = {"mamba-2": ("granitemoehybrid", GRANITE),
+                "attention only": ("llama", LLAMA)}.get(
+        stack, ("olmo_hybrid", HF_KERNEL if on_kernel else HF))
+    family = get_family(name)
+    tcfg = TpuConfig(tp_degree=1, dtype="float32", **SERVE)
+    app = PagedCausalLMApplication(None, family.config_cls(tcfg, **hf),
+                                   family)
+    app.init_random_weights(5).init_cache()
+    ad = PagedEngineAdapter(app)
+    assert ad.host_stats["dispatches_state_kernel"] == 0
+    ad.add_requests([0, 1], [S12, R21])
+    for _ in range(4):
+        ad.step()
+    assert ad.host_stats["dispatches"] == 4
+    assert ad.host_stats["dispatches_state_kernel"] == (4 if on_kernel else 0)
+    assert ad._state_kernel_shapes == {(BATCH, 1): on_kernel}
 
 
 def test_b_two_prompts_packed_beside_a_decoding_row(app, ref, gate_weights):
@@ -435,11 +536,17 @@ def test_warmup_plan_and_the_engagement_record(app):
     # the record names the state kind, a slot's bytes and the scan chunk
     s = app.spec.ssm
     slot_bytes = 3 * (2 * 8 * 16 * 4 + (2 * 2 * 8 + 2 * 16) * 3 * 4)
-    note = {"site": "recurrent_state", "path": "xla",
-            "reason": f"kind=gated_delta slot_bytes={slot_bytes} "
-                      f"chunk={s.chunk_size}"}
-    assert note in report["kernels"]
-    assert note in app.warmup_state()["kernels"]
+    # ... and who steps it: a (8, 16) tile is not the kernel's, so every
+    # program says xla, each with its reason (ISSUE 44)
+    what = f"kind=gated_delta slot_bytes={slot_bytes} chunk={s.chunk_size}"
+    no_tile = ("1 tiles of 8x16 a key head are not whole 8x64 tiles under "
+               "4194304 bytes")
+    for why in (no_tile, "8 tokens a row: the chunked form",
+                "16 tokens a row: the chunked form"):
+        note = {"site": "recurrent_state", "path": "xla",
+                "reason": f"{what}: {why}"}
+        assert note in report["kernels"]
+        assert note in app.warmup_state()["kernels"]
     # everything the default adapter dispatches is warm: no incident
     ad = PagedEngineAdapter(app)
     ad.add_requests([0], [P37])

@@ -177,6 +177,42 @@ def test_a_three_chunks_with_a_padded_last_one_then_decode(
         assert paths["moe_ragged"]["path"] == "stacked"
 
 
+#: the same period with a tile the state-step kernel takes (ISSUE 44):
+#: (8, 64) where HF's (8, 16) keeps the XLA step; two key heads serve four
+#: value heads, each reading its key head through the kernel's index
+HF_KERNEL = dict(HF, linear_value_head_dim=64)
+
+
+def test_a_chunks_then_decode_on_the_state_kernel(ref):
+    """Three chunks through the chunked form, then nine decode steps on the
+    state-step kernel with GROUPED key heads: the logits at every position
+    and the slot's final state are the float32 reference's, and the
+    adapter counted every decode dispatch on the kernel."""
+    w = weights.make_weights(ref.weight_shapes(HF_KERNEL), seed=2**31 + 44)
+    app = _app(ref, w, hf=HF_KERNEL)
+    ad = PagedEngineAdapter(app)
+    tap = LogitTap(app)
+    stream = [ad.add_requests([7], [P69])[7]]
+    _decode(ad, [7], {7: stream}, 9)
+    assert tap.shapes == [(1, 32), (1, 32), (1, 8)] + [(BATCH, 1)] * 9
+    fed = P69 + stream[:-1]
+    want = _want(ref, w, fed, HF_KERNEL)
+    assert float(np.abs(tap.logits(7, len(fed)) - want).max()) < ATOL
+    assert stream == want[len(P69) - 1:].argmax(-1).tolist()
+    assert app.cache["ssm"].dtype == jnp.float32
+    got = np.asarray(app.cache["ssm"][:, ad._state_slot[7]])
+    want = np.asarray(ref.final_states(
+        HF_KERNEL, w, jnp.asarray([fed])))[:, 0]
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    step = {k[0]: k[1:] for k in app.paged_program_notes(BATCH, 1)}
+    assert step["recurrent_state"][0] == "pallas-interpret"
+    assert step["recurrent_state"][1].endswith(" heads=4 tile=8x64")
+    chunk = {k[0]: k[1:] for k in app.paged_program_notes(1, 32)}
+    assert chunk["recurrent_state"][0] == "xla"
+    assert (ad.host_stats["dispatches_state_kernel"],
+            ad.host_stats["dispatches"]) == (9, 9)
+
+
 def test_b_prompts_packed_beside_a_decoding_row(ref, gate_weights):
     app = _app(ref, gate_weights)
     ad = PagedEngineAdapter(app)
@@ -763,8 +799,8 @@ def test_warmup_plan_and_the_engagement_record(ref, gate_weights):
     assert notes["moe_share"] == {"site": "moe_share", "path": "xla",
                                   "reason": "held=4 of 16 from 4 top_k=4"}
     slot_bytes = 3 * (4 * 8 * 16 * 4 + 96 * 3 * 4)
-    assert notes["recurrent_state"]["reason"] == \
-        f"kind=gated_delta slot_bytes={slot_bytes} chunk=64"
+    assert notes["recurrent_state"]["reason"].startswith(
+        f"kind=gated_delta slot_bytes={slot_bytes} chunk=64: ")
     ad = PagedEngineAdapter(app)
     ad.add_requests([0], [P69])
     ad.add_requests([1, 2], [Q45, S12])
