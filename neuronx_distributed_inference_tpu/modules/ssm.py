@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..ops import delta_state_step, kernel_mode
+from ..ops import delta_state_step, kernel_mode, mamba_state_step
 from ..parallel.layers import ParamSpec
 from ..parallel.mesh import AXIS_DP, AXIS_MP
 
@@ -278,6 +278,18 @@ def _conv_tail(x, n_valid, K1, tail=None):
     return out.transpose(0, 2, 1)
 
 
+def _next_tail(x, valid, n_valid, K1, tail):
+    """The tail a block hands on after ``x`` (B, T, C): :func:`_conv_tail`'s
+    gather, or for ONE token the window slid by one where the token is real
+    (two static slices and a select; the gather cost 18-23 us a layer of a
+    decode step: PERF.md section 6, PR 44)."""
+    if x.shape[1] != 1:
+        return _conv_tail(x, n_valid, K1, tail)
+    hist = _with_history(x, tail, K1)
+    return jnp.where(valid[:, :, None], hist[:, 1:],
+                     hist[:, :K1]).transpose(0, 2, 1)
+
+
 def _conv_step(tail, cur, w, b):
     """One decode conv step: (B, C, K-1) tail + (B, C) current → (value
     (B, C), new tail). Matches the reference's roll-and-dot cached branch
@@ -345,6 +357,10 @@ def mamba2_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
     this reason.
 
     T == 1 runs the O(1) recurrence step, T > 1 the chunked SSD form.
+    ``state["ssm"]`` as a :class:`StateStack` (a T == 1 step whose rows are
+    the slots: :func:`state_kernel_declined`) is stepped in place by the
+    kernel (``ops/mamba_state_step.py``), once across the state each way,
+    and handed back as a :class:`StateStack`.
     """
     B, T, H = x.shape
     f32 = jnp.float32
@@ -358,7 +374,7 @@ def mamba2_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
                                            (B, T))
     tail_x = jnp.where(keep[:, None, None], state["conv_x"], 0)
     tail_bc = jnp.where(keep[:, None, None], state["conv_bc"], 0)
-    st0 = jnp.where(keep[:, None, None, None], state["ssm"].astype(f32), 0.0)
+    in_place = isinstance(state["ssm"], StateStack)
 
     gate = x @ lw["ssm_in_gate"]
     xs = jnp.where(valid[..., None], x @ lw["ssm_in_x"], 0)
@@ -369,8 +385,8 @@ def mamba2_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
         xs, lw["ssm_conv_x"], lw.get("ssm_conv_x_b"), tail_x))
     bc_c = jax.nn.silu(_causal_conv_prefill(
         bc, lw["ssm_conv_bc"], lw.get("ssm_conv_bc_b"), tail_bc))
-    new_state = {"conv_x": _conv_tail(xs, n_valid, K1, tail_x),
-                 "conv_bc": _conv_tail(bc, n_valid, K1, tail_bc)}
+    new_state = {"conv_x": _next_tail(xs, valid, n_valid, K1, tail_x),
+                 "conv_bc": _next_tail(bc, valid, n_valid, K1, tail_bc)}
 
     dt = jax.nn.softplus(dt_raw + lw["ssm_dt_bias"].astype(f32))
     dt = jnp.clip(dt, s.dt_limit[0], min(s.dt_limit[1], 1e6))
@@ -383,9 +399,21 @@ def mamba2_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
     dA_log = dt * A                                               # (B,T,g,r)
     D_res = lw["ssm_D"].astype(f32).reshape(g, r)[..., None] * x_h
     x_dt = x_h * dt[..., None]
-    st0 = st0.reshape(B, g, r, hd, N)
+    if not in_place:
+        st0 = jnp.where(keep[:, None, None, None], state["ssm"].astype(f32),
+                        0.0).reshape(B, g, r, hd, N)
 
-    if T == 1:
+    if in_place:
+        # the kernel reads y off the new state while it is in VMEM; B and C
+        # go a row a GROUP, a head reads its group's
+        layer = state["ssm"].layer
+        y, st_f = mamba_state_step.mamba_state_step(
+            state["ssm"].stack, layer, x_dt[:, 0].reshape(B, nh, hd),
+            jnp.exp(dA_log[:, 0]).reshape(B, nh), Bm[:, 0], Cm[:, 0], keep,
+            valid[:, 0], interpret=kernel_mode.pallas_interpret())
+        y = (y.reshape(B, g, r, hd) + D_res[:, 0]).reshape(B, 1, s.d_inner)
+        new_state["ssm"] = StateStack(st_f, layer)
+    elif T == 1:
         dBx = x_dt[:, 0, ..., None] * Bm[:, 0, :, None, None, :]
         st_f = st0 * jnp.exp(dA_log[:, 0])[..., None, None] + dBx
         y = jnp.einsum("bgrdn,bgn->bgrd", st_f, Cm[:, 0],
@@ -421,7 +449,8 @@ def mamba2_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
                               chunks(dA_log)))
         Y = jnp.moveaxis(Y, 0, 1).reshape(B, T + pad, g, r, hd)[:, :T]
         y = (Y + D_res).reshape(B, T, s.d_inner)
-    new_state["ssm"] = st_f.reshape(B, nh, hd, N)
+    if not in_place:
+        new_state["ssm"] = st_f.reshape(B, nh, hd, N)
 
     gate = gate.astype(f32)
     if s.gated_norm:
@@ -456,21 +485,29 @@ class StateStack(NamedTuple):
 def state_kernel_declined(s: SSMSpec, stack, rows: int, tokens: int,
                           state_slots=None) -> str:
     """Why a step of ``rows`` rows of ``tokens`` tokens over the ``"ssm"``
-    stack does not run on the state-step kernel ("" = it does:
-    ``ops/delta_state_step.py``), from what the step shows and from nothing
-    else: the state kind, one token a row, the rows being the slots, a tile
-    the kernel takes. The walk over the layers asks ONCE a program and
-    hands :func:`gated_delta_mixer` a :class:`StateStack` or its rows
-    accordingly."""
-    if s.kind != "gated_delta":
-        return f"no state-step kernel for kind {s.kind}"
-    return delta_state_step.declined(stack, rows, tokens, s.key_heads,
-                                     state_slots)
+    stack does not run on the state-step kernel ("" = it does), from what
+    the step shows and from nothing else: the state kind, one token a row,
+    the rows being the slots, a tile the kernel takes. Two rules share the
+    walk (``ops/delta_state_step.py`` ``walk_state_blocks``): the gated
+    delta rule's, and Mamba-2's (``ops/mamba_state_step.py``); each names
+    what it declines of its own. The walk over the layers asks ONCE a
+    program and hands :func:`gated_delta_mixer` / :func:`mamba2_mixer` a
+    :class:`StateStack` or its rows accordingly."""
+    if s.kind == "gated_delta":
+        return delta_state_step.declined(stack, rows, tokens, s.key_heads,
+                                         state_slots)
+    if s.kind == "mamba2":
+        return mamba_state_step.declined(stack, rows, tokens, s.n_groups,
+                                         state_slots)
+    return f"no state-step kernel for kind {s.kind}"
 
 
 def state_kernel_note(s: SSMSpec, stack) -> str:
     """The engagement record's text for a step the kernel takes: a block's
     heads and the tile."""
+    if s.kind == "mamba2":
+        return mamba_state_step.mamba_step_plan(
+            stack.shape[2], s.n_groups, *stack.shape[3:]).note()
     return delta_state_step.state_step_plan(
         stack.shape[2], s.key_heads, *stack.shape[3:]).note()
 
@@ -587,15 +624,7 @@ def gated_delta_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *,
     ab = jnp.einsum("bth,hn->btn", x, lw["gdn_in_ab"],
                     preferred_element_type=f32)
     qkv_c = jax.nn.silu(_causal_conv_prefill(qkv, lw["gdn_conv"], None, tail))
-    if T == 1:
-        # one token: the window slides by one where the token is real,
-        # two static slices and a select where :func:`_conv_tail` gathers
-        hist = _with_history(qkv, tail, K1)
-        new_tail = jnp.where(valid[:, :, None], hist[:, 1:],
-                             hist[:, :K1]).transpose(0, 2, 1)
-    else:
-        new_tail = _conv_tail(qkv, n_valid, K1, tail)
-    new_state = {"conv_x": new_tail}
+    new_state = {"conv_x": _next_tail(qkv, valid, n_valid, K1, tail)}
 
     def heads(a):
         # l2-normalised per KEY head, then each repeated over the value

@@ -3,6 +3,13 @@ row and head of ONE layer, in place on the stacked state (reference: the
 gated delta rule's one-token step, ``modules/ssm.py`` ``_delta_step``, which
 stays as the declined path and as the tests' reference).
 
+TWO rules share the walk of this file (:func:`walk_state_blocks`,
+:func:`declined_walk`, :class:`StateStepPlan`) and nothing else: the gated
+delta rule's, below, and Mamba-2's one-token SSD step
+(``ops/mamba_state_step.py``, PR 45), each with its own plan, operand packing
+and ``declined``. ``modules/ssm.py`` ``state_kernel_declined`` picks the rule
+by the layer's kind.
+
 A decode step of a gated delta-rule layer is bound by its state's bytes:
 every live row's ``(H, d_k, d_v)`` float32 state is read and written once a
 token, 2.2 MB a row a layer. As XLA fusions the step crossed the state three
@@ -47,8 +54,8 @@ becomes a column, and the write strength ``beta`` as a last row
 (:func:`delta_rows`); ``k . q`` is computed on the columns.
 
 :func:`walk_state_blocks` is the walk (grid, index maps, dead rows, the
-aliasing) and takes the update rule as a function of one block's refs, so a
-second rule (Mamba-2's step, ROADMAP A6) can share it.
+aliasing) and takes the update rule as a function of one block's refs: the
+second rule (Mamba-2's step) hands it ``_mamba_update``.
 """
 
 from __future__ import annotations
@@ -75,7 +82,9 @@ _DEAD, _LIVE, _CARRY = 0, 1, 2
 
 
 class StateStepPlan(NamedTuple):
-    """What one call of the kernel runs with (:func:`state_step_plan`)."""
+    """What one call of the kernel runs with (:func:`state_step_plan`; the
+    Mamba rule's ``mamba_step_plan``: ``heads`` a block of tiles ``(d_k,
+    d_v)`` = (sublanes, lanes), its ``(head_dim, d_state)``)."""
     heads: int          # value heads a block
     d_k: int
     d_v: int
@@ -106,13 +115,11 @@ def state_step_plan(heads: int, key_heads: int, d_k: int, d_v: int
     return StateStepPlan(max(fits), d_k, d_v) if fits else None
 
 
-def declined(stack, rows: int, tokens: int, key_heads: int,
-             state_slots=None) -> str:
+def declined_walk(stack, rows: int, tokens: int, state_slots=None) -> str:
     """Why a step of ``rows`` rows of ``tokens`` tokens over the state
-    ``stack`` (Ls, slots, H, d_k, d_v) does not take the kernel ("" = it
-    does), read from what the call shows - the step's shape, the stack, the
-    ambient mesh - and from nothing else: whatever is named here keeps
-    ``_delta_step`` (one token) or the chunked form."""
+    ``stack`` (Ls, slots, ...) does not take the walk ("" = it does),
+    whatever the rule: read from what the call shows - the step's shape, the
+    stack, the ambient mesh - and from nothing else."""
     if tokens != 1:
         return f"{tokens} tokens a row: the chunked form"
     if state_slots is not None or rows != stack.shape[1]:
@@ -123,6 +130,19 @@ def declined(stack, rows: int, tokens: int, key_heads: int,
     wide = [a for a in mesh.axis_names if mesh.shape[a] > 1]
     if wide:
         return "mesh axes wider than one: " + ",".join(wide)
+    return ""
+
+
+def declined(stack, rows: int, tokens: int, key_heads: int,
+             state_slots=None) -> str:
+    """Why a step of ``rows`` rows of ``tokens`` tokens over the delta-rule
+    state ``stack`` (Ls, slots, H, d_k, d_v) does not take the kernel ("" =
+    it does): what the walk declines of any rule (:func:`declined_walk`),
+    then this rule's tile. Whatever is named here keeps ``_delta_step`` (one
+    token) or the chunked form."""
+    why = declined_walk(stack, rows, tokens, state_slots)
+    if why:
+        return why
     _, _, h, d_k, d_v = stack.shape
     if state_step_plan(h, key_heads, d_k, d_v) is None:
         return (f"{h // key_heads} tiles of {d_k}x{d_v} a key head "

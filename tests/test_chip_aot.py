@@ -313,11 +313,29 @@ def test_olmoe_chunk_reads_experts_and_pool_in_place(v5e_devices):
     assert MOSAIC in decode.as_text()        # the paged decode kernel
 
 
-def _state_stepped_in_place(text: str, stack: str, layers: int):
-    """ISSUE 44: a T = 1 step's compiled text holds the state-step kernel
-    once a delta-rule layer, and nothing copies, transposes or slices the
-    state stack ``f32[stack]`` or one layer of it out."""
-    assert len(re.findall(r"%delta_state_step\S* = ", text)) == layers
+def _compiled_paged_step(shapes, rows, width, **kw):
+    """``paged_forward_step`` at ``rows`` x ``width`` compiled for the
+    devices of ``shapes`` (:func:`_serving_shapes`), and the engagement
+    records its trace left."""
+    spec, tcfg, mesh, params, cache, sds, mb = shapes
+    i32 = jnp.int32
+    notes = set()
+    with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
+        c = jax.jit(partial(model_base.paged_forward_step, spec, tcfg),
+                    donate_argnums=(1,)).lower(
+            params, cache, *(sds((rows, width), i32),) * 3,
+            sds((rows, mb), i32), sds((rows,), i32), None,
+            sds((2,), jnp.uint32), **kw).compile()
+    return c, notes
+
+
+def _state_stepped_in_place(text: str, stack: str, layers: int,
+                            kernel: str = "delta_state_step"):
+    """ISSUE 44, ISSUE 45: a T = 1 step's compiled text holds the state-step
+    kernel once a delta-rule (``mamba_state_step``: a Mamba-2) layer, and
+    nothing copies, transposes or slices the state stack ``f32[stack]`` or
+    one layer of it out."""
+    assert len(re.findall(rf"%{kernel}\S* = ", text)) == layers
     layer = stack.split(",", 1)[1]
     moves = re.findall(
         rf"%(\S+) = f32\[(?:{stack}|{layer})\]\S* "
@@ -369,14 +387,8 @@ def test_30_kv_heads_of_128_decode_on_the_kernel_with_no_pool_copy(
     i32 = jnp.int32
 
     def compiled(rows, width, **kw):
-        notes = set()
-        with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
-            c = jax.jit(partial(model_base.paged_forward_step, spec, tcfg),
-                        donate_argnums=(1,)).lower(
-                params, cache, *(sds((rows, width), i32),) * 3,
-                sds((rows, mb), i32), sds((rows,), i32), None,
-                sds((2,), jnp.uint32), **kw).compile()
-        return c, notes
+        return _compiled_paged_step(
+            (spec, tcfg, mesh, params, cache, sds, mb), rows, width, **kw)
 
     state = "kind=gated_delta slot_bytes=6842880 chunk=64"
     step, notes = compiled(32, 1)
@@ -444,14 +456,8 @@ def test_2_kv_heads_of_256_decode_on_the_kernel_with_no_pool_copy(
     i32 = jnp.int32
 
     def compiled(rows, width, **kw):
-        notes = set()
-        with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
-            c = jax.jit(partial(model_base.paged_forward_step, spec, tcfg),
-                        donate_argnums=(1,)).lower(
-                params, cache, *(sds((rows, width), i32),) * 3,
-                sds((rows, mb), i32), sds((rows,), i32), None,
-                sds((2,), jnp.uint32), **kw).compile()
-        return c, notes
+        return _compiled_paged_step(
+            (spec, tcfg, mesh, params, cache, sds, mb), rows, width, **kw)
 
     def pool_moves(text):
         return re.findall(
@@ -491,6 +497,70 @@ def test_2_kv_heads_of_256_decode_on_the_kernel_with_no_pool_copy(
     copied = re.findall(r"slice_bitcast_fusion[.\d]* = bf16\[([\d,]+)\]",
                         text)
     assert not {"128,2048,512", "128,512,2048"} & set(copied), copied
+    assert chunk.memory_analysis().temp_size_in_bytes < 200e6
+
+
+# ibm-granite/granite-4.0-h-micro config.json (model-configs catalog), the
+# first four layers of its pattern at the gate's order: three Mamba-2 mixers
+# around one attention layer
+GRANITE_H_MICRO = dict(
+    model_type="granitemoehybrid", hidden_size=2048, intermediate_size=8192,
+    shared_intermediate_size=8192, num_attention_heads=32,
+    num_key_value_heads=8, vocab_size=100352, rms_norm_eps=1e-5,
+    rope_theta=10000, max_position_embeddings=131072,
+    position_embedding_type="nope", tie_word_embeddings=True,
+    hidden_act="silu", attention_bias=False, num_local_experts=0,
+    num_experts_per_tok=0, mamba_n_heads=64, mamba_d_head=64,
+    mamba_d_state=128, mamba_n_groups=1, mamba_d_conv=4, mamba_expand=2,
+    mamba_chunk_size=256, mamba_conv_bias=True, mamba_proj_bias=False,
+    embedding_multiplier=12, attention_multiplier=0.015625,
+    residual_multiplier=0.22, logits_scaling=8,
+    layer_types=["mamba", "mamba", "attention", "mamba"])
+
+
+def test_64_mamba_heads_of_64x128_step_on_the_kernel_in_place(v5e_devices):
+    """ISSUE 45: granite-4.0-h-micro's mixers (64 heads of ``(64, 128)`` in
+    one group) at four layers, the cell's batch, pool and table. As XLA
+    fusions a layer of the T = 1 step crossed its state three times (a loop
+    fusion for the read-out, then ``add_dynamic-update-slice_fusion``, which
+    read the state again). The step holds ``mamba_state_step`` once a
+    Mamba-2 layer, no fusion, copy or slice over the stack or a layer of it,
+    and the stack's parameter is the output's buffer; the one-row chunk
+    keeps the chunked SSD form and holds no such call."""
+    shapes = _serving_shapes(
+        GRANITE_H_MICRO, 4, 1, v5e_devices[:1],
+        dict(batch_size=32, seq_len=4096, pa_block_size=32,
+             pa_num_blocks=2048, context_encoding_buckets=[64, 256]),
+        prefix=False)
+    cache, sds = shapes[4], shapes[5]
+    assert cache["ssm"].shape == (3, 32, 64, 64, 128)
+    assert cache["ssm"].dtype == jnp.float32
+    i32 = jnp.int32
+
+    def compiled(rows, width, **kw):
+        c, notes = _compiled_paged_step(shapes, rows, width, **kw)
+        return c, {n for n in notes if n[0] == "recurrent_state"}
+
+    state = "kind=mamba2 slot_bytes=6369792 chunk=64"
+    step, notes = compiled(32, 1)
+    assert notes == {
+        ("recurrent_state", "pallas", f"{state} heads=32 tile=64x128")}
+    text = step.as_text()
+    _state_stepped_in_place(text, "3,32,64,64,128", layers=3,
+                            kernel="mamba_state_step")
+    assert "add_dynamic-update-slice_fusion" not in text
+    assert _state_layout(text, "3,32,64,64,128") == "{4,3,2,1,0:T(8,128)}"
+    # the donated stack IS the output's buffer: no second copy of 201 MB
+    param, = re.findall(
+        r"%(\S+) = f32\[3,32,64,64,128\]\S+ parameter\((\d+)\)", text)
+    assert re.search(
+        rf"\{{[\d, ]*\}}: \({param[1]}, \{{\}}, may-alias\)", text), \
+        "the state stack's parameter is not aliased to an output"
+    assert step.memory_analysis().temp_size_in_bytes < 100e6
+    chunk, notes = compiled(1, 256, state_slots=sds((1,), i32))
+    assert notes == {("recurrent_state", "xla",
+                      f"{state}: 256 tokens a row: the chunked form")}
+    assert "mamba_state_step" not in chunk.as_text()
     assert chunk.memory_analysis().temp_size_in_bytes < 200e6
 
 

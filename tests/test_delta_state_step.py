@@ -131,7 +131,7 @@ def test_dead_rows_move_nothing(live):
      "rows gathered from their slots"),
     (dict(rows=2), "rows gathered from their slots"),
     (dict(dtype=jnp.bfloat16), "state stored as bfloat16"),
-    (dict(kind="mamba2"), "no state-step kernel for kind mamba2"),
+    (dict(kind="rglru"), "no state-step kernel for kind rglru"),
     (dict(), "")])
 def test_what_the_kernel_declines_is_named(case, why):
     spec = ssm.SSMSpec(kind=case.get("kind", "gated_delta"), d_inner=256,

@@ -81,13 +81,13 @@ def gate_weights(ref):
     return weights.make_weights(ref.weight_shapes(HF), seed=2**31 + 30)
 
 
-def _app(ref, w, **serve):
+def _app(ref, w, hf=HF, **serve):
     family = get_family("granitemoehybrid")
     tcfg = TpuConfig(tp_degree=1, dtype="float32", output_logits=True,
                      **dict(SERVE, **serve))
-    app = PagedCausalLMApplication(None, family.config_cls(tcfg, **HF),
+    app = PagedCausalLMApplication(None, family.config_cls(tcfg, **hf),
                                    family)
-    view = weights.HfView(ref.weight_shapes(HF), w,
+    view = weights.HfView(ref.weight_shapes(hf), w,
                           dtype=np.dtype("float32"))
     app._put_params(family.convert_hf_state_dict(view, app.spec))
     return app.init_cache()
@@ -127,8 +127,8 @@ class LogitTap:
         return np.stack([got[p] for p in range(n)])
 
 
-def _want(ref, w, tokens):
-    return np.asarray(ref.forward(HF, w, jnp.asarray([tokens])))[0]
+def _want(ref, w, tokens, hf=HF):
+    return np.asarray(ref.forward(hf, w, jnp.asarray([tokens])))[0]
 
 
 def _decode(ad, sids, stream, steps):
@@ -161,6 +161,89 @@ def test_a_three_chunks_with_a_padded_last_one_then_decode(app, ref,
     ad.release([7])
     assert ad.host_stats["state_slots_live"] == 0
     assert ad._state_free == list(range(BATCH))
+
+
+#: the same stack with a tile the state-step kernel takes (ISSUE 45):
+#: (16, 128) where HF's (16, 16) keeps the XLA step
+HF_KERNEL = dict(HF, mamba_d_state=128)
+
+
+def test_a_chunks_then_decode_on_the_state_kernel(ref):
+    """Three chunks through the chunked SSD form (the one-row program),
+    then nine decode steps on the state-step kernel, in place on the slots:
+    the logits at every position are the float32 reference's, the slot's
+    final state is the reference's ``final_states`` at the 1e-4 of its
+    largest entry the contract test holds (a bf16 state fails it), a dead
+    row's slot (three of the four here) is left as it was, the records say
+    which program took which path, and the adapter counted every decode
+    dispatch on the kernel."""
+    w = weights.make_weights(ref.weight_shapes(HF_KERNEL), seed=2**31 + 45)
+    app = _app(ref, w, hf=HF_KERNEL)
+    ad = PagedEngineAdapter(app)
+    tap = LogitTap(app)
+    stream = [ad.add_requests([7], [P37])[7]]
+    slot = ad._state_slot[7]
+    others = [r for r in range(BATCH) if r != slot]
+    idle = np.asarray(app.cache["ssm"][:, others])
+    _decode(ad, [7], {7: stream}, 9)
+    assert tap.shapes == [(1, 16), (1, 16), (1, 8)] + [(BATCH, 1)] * 9
+    fed = P37 + stream[:-1]
+    want = _want(ref, w, fed, HF_KERNEL)
+    np.testing.assert_allclose(tap.logits(7, len(fed)), want, atol=ATOL,
+                               rtol=1e-4)
+    assert stream == want[len(P37) - 1:].argmax(-1).tolist()
+    assert app.cache["ssm"].dtype == jnp.float32
+    got = np.asarray(app.cache["ssm"][:, slot])
+    want = np.asarray(ref.final_states(
+        HF_KERNEL, w, jnp.asarray([fed])))[:, 0]
+    assert got.shape == want.shape
+    assert float(np.abs(got - want).max() / np.abs(want).max()) < 1e-4
+    assert (np.asarray(app.cache["ssm"][:, others]) == idle).all()
+    slot_bytes = 3 * (8 * 16 * 128 * 4 + (128 + 2 * 128) * 3 * 4)
+    what = f"kind=mamba2 slot_bytes={slot_bytes} chunk=8"
+    assert ("recurrent_state", "pallas-interpret",
+            f"{what} heads=8 tile=16x128") in app.paged_program_notes(BATCH, 1)
+    assert ("recurrent_state", "xla",
+            f"{what}: 16 tokens a row: the chunked form") in \
+        app.paged_program_notes(1, 16)
+    assert ad.host_stats["dispatches"] == 9
+    assert ad.host_stats["dispatches_state_kernel"] == 9
+    assert ad._state_kernel_shapes == {(BATCH, 1): True}
+
+
+def test_the_contiguous_decode_phase_steps_on_the_kernel_too(ref,
+                                                             monkeypatch):
+    """``generate()`` on the contiguous cache: the rows of its ``decode``
+    phase are the state's slots, so its T = 1 steps take the kernel as the
+    paged step does, and the greedy tokens are the reference's."""
+    from neuronx_distributed_inference_tpu.models.application import \
+        CausalLMApplication
+    from neuronx_distributed_inference_tpu.ops import mamba_state_step
+    calls = []
+    step = mamba_state_step.mamba_state_step
+
+    def counted(*a, **kw):
+        calls.append(a[0].shape)
+        return step(*a, **kw)
+    monkeypatch.setattr(mamba_state_step, "mamba_state_step", counted)
+    w = weights.make_weights(ref.weight_shapes(HF_KERNEL), seed=2**31 + 45)
+    family = get_family("granitemoehybrid")
+    tcfg = TpuConfig(tp_degree=1, dtype="float32", batch_size=2, seq_len=64)
+    app = CausalLMApplication(None, family.config_cls(tcfg, **HF_KERNEL),
+                              family)
+    view = weights.HfView(ref.weight_shapes(HF_KERNEL), w,
+                          dtype=np.dtype("float32"))
+    app._put_params(family.convert_hf_state_dict(view, app.spec))
+    app.init_cache()
+    prompts = [P37[:20], Q29[:20]]
+    got = np.asarray(app.generate(np.asarray(prompts, np.int32),
+                                  max_new_tokens=6)["generated"])
+    assert calls and set(calls) == {(3, 2, 8, 16, 128)}
+    for row, prompt in zip(got, prompts):
+        seq = list(prompt)
+        for _ in range(6):
+            seq.append(int(_want(ref, w, seq, HF_KERNEL)[-1].argmax()))
+        assert row.tolist() == seq[20:]
 
 
 def test_b_two_prompts_packed_beside_a_decoding_row(app, ref, gate_weights):
