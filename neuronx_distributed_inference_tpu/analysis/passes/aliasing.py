@@ -177,7 +177,7 @@ class AliasingSafetyPass(Pass):
                         "still-in-flight async dispatch may zero-copy-"
                         "alias the old buffer (jax CPU), so refilling it "
                         "races the device read; double-buffer like "
-                        "_CbScratch/_PagedScratch.fill"))
+                        "_PagedScratch.fill"))
         return findings
 
     def _inplace_writes(self, node: ast.AST, attrs: Set[str],

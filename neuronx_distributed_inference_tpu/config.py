@@ -93,10 +93,7 @@ class ChunkedPrefillConfig:
 class MoEConfig:
     """MoE knobs (reference: models/config.py:798-846 ``MoENeuronConfig``)."""
 
-    normalize_top_k_affinities: bool = True
     routed_scaling_factor: Optional[float] = None
-    moe_tp_degree: Optional[int] = None       # defaults to tp_degree
-    moe_ep_degree: Optional[int] = None       # defaults to ep_degree
     # hybrid CTE/TKG expert sharding (reference: moe_v2.py:135-161
     # HybridShardingConfig): moe_tkg_ep_degree=1 switches DECODE to
     # all-experts-local with the intermediate dim split over every model
@@ -132,11 +129,7 @@ class SpeculationConfig:
     """
 
     speculation_length: int = 0
-    enable_fused_speculation: bool = False
-    enable_eagle_speculation: bool = False
-    enable_eagle_draft_input_norm: bool = False
     medusa_speculation_length: int = 0
-    num_medusa_heads: int = 0
     token_tree_config: Optional[Dict[str, Any]] = None
     draft_model_path: Optional[str] = None
 
@@ -234,7 +227,6 @@ class TpuConfig:
     batch_size: int = 1
     ctx_batch_size: Optional[int] = None      # prefill batch
     tkg_batch_size: Optional[int] = None      # decode batch
-    max_batch_size: Optional[int] = None
     is_continuous_batching: bool = False
     seq_len: int = 128                        # max total sequence length
     max_context_length: Optional[int] = None  # max prefill length
@@ -249,19 +241,16 @@ class TpuConfig:
     # --- dtypes ---
     dtype: str = "bfloat16"                   # weights/activations
     kv_cache_dtype: Optional[str] = None      # default = dtype; fp8 supported
-    logits_dtype: str = "float32"
 
     # --- parallelism degrees (reference: models/config.py:361-390) ---
     tp_degree: int = 1
     cp_degree: int = 1                        # context parallel (prefill)
     attention_dp_degree: int = 1              # data parallel decode attention
-    pp_degree: int = 1
     ep_degree: int = 1
     sequence_parallel_enabled: bool = False
     # vocab-parallel embedding table (sharded on V); False replicates the
     # table on every device (reference: models/config.py:142)
     vocab_parallel: bool = True
-    world_size: Optional[int] = None
 
     # --- KV cache (reference: models/config.py:167-170, 277-317) ---
     kv_cache_batch_size: Optional[int] = None
@@ -354,23 +343,16 @@ class TpuConfig:
     def __post_init__(self):
         if self.max_context_length is None:
             self.max_context_length = self.seq_len
-        if self.max_batch_size is None:
-            self.max_batch_size = self.batch_size
         if self.ctx_batch_size is None:
             self.ctx_batch_size = 1 if self.is_continuous_batching else self.batch_size
         if self.tkg_batch_size is None:
             self.tkg_batch_size = self.batch_size
         if self.kv_cache_batch_size is None:
-            self.kv_cache_batch_size = max(self.tkg_batch_size, self.max_batch_size)
+            self.kv_cache_batch_size = max(self.tkg_batch_size, self.batch_size)
         if self.kv_cache_dtype is None:
             self.kv_cache_dtype = self.dtype
         if self.n_positions is None:
             self.n_positions = self.seq_len
-        if self.world_size is None:
-            # tp_degree counts all model-parallel ranks; cp/dp/ep subdivide
-            # them rather than multiplying the world (reference:
-            # models/config.py:382-390 world-size calc)
-            self.world_size = self.tp_degree * self.pp_degree
         self.validate()
 
     # -- validation (reference: models/config.py:645-721) --
@@ -389,14 +371,6 @@ class TpuConfig:
                 raise ValueError("attention_dp_degree must divide tp_degree")
             if self.tkg_batch_size % self.attention_dp_degree != 0:
                 raise ValueError("tkg_batch_size must be divisible by attention_dp_degree")
-        if self.pp_degree > 1:
-            # honest surface: like the reference, there is no pipeline
-            # SCHEDULE in the inference path (reference plumbs pp into
-            # ModelBuilder but runs no pipeline, SURVEY §2.8); refuse
-            # rather than silently running tp-only
-            raise ValueError(
-                "pp_degree > 1 is not supported: inference has no pipeline "
-                "schedule (shard wider with tp_degree instead)")
         if self.is_chunked_prefill and not self.is_block_kv_layout:
             raise ValueError("chunked prefill requires block KV layout")
         if self.is_prefix_caching and not self.is_block_kv_layout:
@@ -405,9 +379,6 @@ class TpuConfig:
             self.pa_num_blocks = (
                 self.kv_cache_batch_size * ((self.seq_len + self.pa_block_size - 1)
                                             // self.pa_block_size))
-        spec = self.speculation_config
-        if spec and spec.enable_eagle_speculation and not spec.enable_fused_speculation:
-            raise ValueError("EAGLE speculation requires fused speculation")
         cc = self.collective_config
         if cc is not None and cc.dtype is not None:
             # typed refusal shared with parallel/collectives.py (lazy import:
@@ -443,10 +414,6 @@ class TpuConfig:
     @property
     def jax_kv_dtype(self):
         return to_jax_dtype(self.kv_cache_dtype)
-
-    @property
-    def jax_logits_dtype(self):
-        return to_jax_dtype(self.logits_dtype)
 
     @property
     def speculation_length(self) -> int:

@@ -172,7 +172,6 @@ def _run_inference(args) -> int:
     if args.speculation_length > 0:
         spec_cfg = SpeculationConfig(
             speculation_length=args.speculation_length,
-            enable_fused_speculation=True,
             draft_model_path=args.draft_model_path)
 
     def make_tcfg(**over):

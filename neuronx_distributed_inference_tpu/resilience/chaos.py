@@ -306,7 +306,7 @@ class ChaosCampaign:
         # C: standalone speculative path (spec_verify dispatches).
         adapter_a = PagedEngineAdapter(app_a, ragged=True, speculation=2,
                                        kv_spill_tier=tier)
-        adapter_b = PagedEngineAdapter(app_b, pipeline_depth=1,
+        adapter_b = PagedEngineAdapter(app_b,
                                        kv_spill_tier=HostKVSpillTier(
                                            max_blocks=64))
         adapter_c = PagedEngineAdapter(app_c, speculation=2)

@@ -1,12 +1,11 @@
-"""Serving package: the engine adapters (``serving.adapter`` — the
-importable continuous-batching contract over both applications) and the
-multi-tenant serving engine built on top of the paged adapter
-(``serving.engine`` — queue + scheduler + token streams + HTTP/SSE front
-door; see README "Serving engine").
+"""Serving package: the engine adapter (``serving.adapter`` — the
+importable continuous-batching contract over the paged application) and
+the multi-tenant serving engine built on top of it (``serving.engine`` —
+queue + scheduler + token streams + HTTP/SSE front door; see README
+"Serving engine").
 
-Importing ``neuronx_distributed_inference_tpu.serving`` keeps exposing the
-adapter surface unchanged (this module used to be ``serving.py``); the
-engine layer is imported explicitly from ``.engine``, the fleet layer
+Importing ``neuronx_distributed_inference_tpu.serving`` exposes the
+adapter surface (this module used to be ``serving.py``); the engine layer is imported explicitly from ``.engine``, the fleet layer
 above it (replicated-engine router, host-RAM KV spill tier, disaggregated
 prefill handoff — README "Fleet") explicitly from ``.fleet``, and the
 ragged unified dispatch (one mixed prefill+decode+verify dispatch per
@@ -14,9 +13,7 @@ engine step, enabled with ``PagedEngineAdapter(app, ragged=True)`` —
 README "Ragged dispatch") explicitly from ``.ragged``.
 """
 
-from .adapter import (ContinuousBatchingAdapter, PagedEngineAdapter,
-                      _EngineAdapterBase)
+from .adapter import PagedEngineAdapter
 from .lora_pool import LoraAdapterPool
 
-__all__ = ["ContinuousBatchingAdapter", "LoraAdapterPool",
-           "PagedEngineAdapter", "_EngineAdapterBase"]
+__all__ = ["LoraAdapterPool", "PagedEngineAdapter"]

@@ -1,46 +1,43 @@
-"""Serving integration surface — the importable continuous-batching
-contract a vLLM-style engine drives (reference: the vLLM-facing surface of
-models/model_wrapper.py — ``vllm_cte_repadding`` :1297-1313 and the
-seq_ids-addressed forward :1315-1440; the reference README's north star is
-serving through vLLM).
+"""Serving integration surface: ONE adapter, :class:`PagedEngineAdapter`,
+over ONE application, ``PagedCausalLMApplication`` (block tables keyed by
+seq_id; reference: the vLLM-facing surface of models/model_wrapper.py and
+the slot_mapping / active_block_table contract of
+block_kv_cache_manager.py).
 
-The engine owns scheduling; this adapter owns device state:
+The engine owns scheduling; the adapter owns device state:
 
-  * ``add_requests(seq_ids, prompts)``  — prefill rows into their cache
-    lines (cache rows are addressed BY seq_id, so request order is free)
+  * ``add_requests(seq_ids, prompts)``  — admit prompts (blocks, chunk
+    state, a state slot on a recurrent or window-pool stack) and prefill
+    them in packed chunks
   * ``step(seq_ids=None)``              — one decode step for the given
-    (default: all) running rows, repadded to the compiled batch bucket
+    (default: all) running rows, dispatched and fetched: THIS step's tokens
+  * ``step_ahead(seq_ids=None)``        — the same step with one step kept
+    in flight: returns the PREVIOUS step's tokens (the serving engine's
+    call)
   * ``step_many(k, seq_ids=None)``      — k fused decode steps in ONE
-    device dispatch + ONE host fetch (CB: the jitted lax.scan decode loop;
-    paged: the fused paged loop with in-graph KV-slot advance)
-  * ``step_ahead(seq_ids=None)``        — ``step`` with one step kept in
-    flight: returns the PREVIOUS step's tokens (the serving engine's call)
+    device dispatch + ONE host fetch (the fused paged loop with in-graph
+    KV-slot advance)
   * ``flush()``                         — retire the in-flight dispatch
     (no-op when none is)
-  * ``release(seq_ids)``                — free rows (and paged blocks)
-
-Works over either application:
-  - ``CausalLMApplication`` with ``is_continuous_batching=True`` —
-    contiguous cache rows keyed by seq_id;
-  - ``PagedCausalLMApplication`` — block tables keyed by seq_id.
+  * ``release(seq_ids)``                — free rows, blocks, state slots
 
 Decode pipeline (see README "Decode pipeline"):
 
-  * ``step()`` returns THIS step's tokens: every call dispatches and
-    synchronously fetches (the eager template). That contract holds for
-    every caller that drives the adapter itself.
-  * ``step_ahead()`` is the same step for a caller that can take its
-    tokens one call LATE, which the serving engine's loop is: it enqueues
-    step N+1, fed step N's sampled tokens ON THE DEVICE (the decode step
-    hands them on as ``out["next_ids"]``, in the placement of its own ids
-    input: one executable, no helper program between two steps), and only
-    then blocks on step N. Scheduling, input preparation, token routing
-    and the yield to the stream writers run while the device computes.
-    ``ServingEngine`` over a paged adapter runs this way BY DEFAULT on the
-    plain two-phase path (no speculation, no ragged dispatch,
+  * ``step()`` means one thing: every call dispatches and synchronously
+    fetches (``_step_eager``, the tests' reference).
+  * ``step_ahead()`` enqueues step N+1, fed step N's sampled tokens ON THE
+    DEVICE (the decode step hands them on as ``out["next_ids"]``, in the
+    placement of its own ids input: one executable, no helper program
+    between two steps), and only then blocks on step N. Scheduling, input
+    preparation, token routing and the yield to the stream writers run
+    while the device computes. ``ServingEngine`` calls it on the plain
+    two-phase path (no speculation, no ragged dispatch,
     ``decode_steps_per_pass == 1``); token streams, counts and finish
     reasons are those of eager (tests/test_engine_lookahead.py,
-    tests/test_decode_pipeline.py).
+    tests/test_decode_pipeline.py). ``pipeline_depth=0`` keeps
+    ``step_ahead()`` eager too: the tests' and a debugger's way to take
+    the lookahead out from under the engine. It is the option's only value
+    beside the default ``None``.
   * What drains the step in flight: a changed live set. A finished or
     cancelled row (``release``), a preempted row and an admission that
     graduated a row do NOT block where they happen: the step stays in
@@ -51,91 +48,65 @@ Decode pipeline (see README "Decode pipeline"):
     in-flight token is dropped at the fetch; its KV growth goes with its
     blocks. ``host_stats`` counts ``overlapped_dispatches`` and
     ``pipeline_drains_<admit|release|preempt|liveset>``.
-  * ``pipeline_depth`` (the one spelling of the choice): ``None``
-    (default) is the above; ``0`` keeps ``step_ahead()`` eager too (the
-    tests' and a debugger's way to take the lookahead out from under the
-    engine); ``1`` makes ``step()`` itself return the PREVIOUS step's
-    tokens ({} on the first call; ``flush()`` drains the last one).
   * Deferred-failure contract: a device failure from step N surfaces at
     step N+1's fetch as a :class:`StepFailure` with ``retry_safe=False``;
-    every in-flight lookahead step's host bookkeeping (positions, paged KV
+    every in-flight lookahead step's host bookkeeping (positions, KV
     growth) is rolled back to the last DELIVERED token. The
     ``pipeline_flush`` fault point makes this deterministic in tests.
   * Hot-path host bookkeeping is incremental: per-(live set, batch bucket)
-    scratch buffers are filled in place instead of rebuilt via
-    np.concatenate/np.repeat each step, and the paged block-table array is
+    scratch buffers are filled in place (``_PagedScratch``; ``_SlotScratch``
+    where the step's rows are state slots), and the block-table array is
     refreshed only for rows whose block list actually grew.
   * The dispatch helpers (``_dispatch_*``) must never materialize device
-    values — enforced by the tier-1 AST lint
-    ``host-sync`` pass of ``scripts/nxdi_lint.py``.
+    values — enforced by the ``host-sync`` pass of ``scripts/nxdi_lint.py``.
 
-Chunked, packed, schedulable prefill — paged adapter only (see README
-"Chunked prefill"; reference analog: ragged/mixed-batch TPU prefill,
-"Ragged Paged Attention" arxiv 2604.15464):
+Chunked, packed, schedulable prefill (see README "Chunked prefill";
+reference analog: "Ragged Paged Attention" arxiv 2604.15464):
 
   * each admitted prompt's uncached suffix is split into
     ``prefill_chunk_tokens``-sized chunks driven through the ``_run_paged``
     slot-mapping path (positions are arbitrary), so prompts up to
     ``seq_len`` are admissible regardless of the largest ctx bucket.
     Intermediate chunk samples are discarded; only the final chunk's token
-    is delivered. Token streams are bit-identical to monolithic admission
-    (pinned by tests/test_chunked_prefill.py).
-  * chunks from DIFFERENT sequences pack as ragged rows of one ctx-bucket
-    dispatch (each row at its own offset over its own block table), so a
-    batch of skewed-length prompts no longer pads every row to the longest
-    suffix — reclaimed pad waste is reported via ``nxdi_prefill_pad_waste``
-    and ``nxdi_prefill_chunks_total``.
-  * a chunk dispatch's shape is (rows, width): width the smallest ctx
-    bucket covering the longest chunk packed, rows the smallest rung of
-    the application's ``prefill_row_buckets`` covering the prompts packed —
-    two rungs, ``[r_min, batch_size]`` (``r_min`` is 1, or the "dp" mesh
-    extent), so a chunk that carries one prompt runs one row, not
-    ``batch_size`` copies of it; prompts that would fill less than half of
-    the full batch go ``r_min`` at a time rather than as one pack (the
-    pack computes every row of the batch); the choice is counted as
-    ``nxdi_bucket_selected_total{kind="prefill_rows"}``. Decode,
-    spec-verify and ragged dispatches keep the full batch.
+    is delivered (tests/test_chunked_prefill.py).
+  * chunks from DIFFERENT sequences pack as ragged rows of one dispatch of
+    shape (rows, width): width the smallest ctx bucket covering the longest
+    chunk packed, rows the smallest rung of the application's
+    ``prefill_row_buckets`` (``[r_min, batch_size]``) covering the prompts
+    packed; prompts that would fill less than half of the full batch go
+    ``r_min`` at a time (the pack computes every row of the batch). Counted
+    as ``nxdi_prefill_pad_waste``, ``nxdi_prefill_chunks_total`` and
+    ``nxdi_bucket_selected_total{kind="prefill_rows"}``.
   * ``prefill_budget_tokens`` defers prefill to the scheduler:
-    ``add_requests`` only admits (block allocation + chunk state) and
-    returns ``{}``; each ``step()``/``step_many()`` then runs AT MOST ONE
-    packed chunk dispatch of at most that many prompt tokens before its
-    decode work, so a long admission no longer stalls running decodes for
-    the whole prefill. First tokens are delivered by the ``step()`` call
+    ``add_requests`` only admits and returns ``{}``; each decode call then
+    runs AT MOST ONE packed chunk dispatch of at most that many prompt
+    tokens before its decode work, and delivers a first token from the call
     whose dispatch completes the prompt.
   * half-prefilled sequences stay inside the resilience contracts: a chunk
     dispatch failure (``prefill_chunk`` fault point) rolls every sequence
     packed in that dispatch back via ``abort_sequence`` (never-fully-
     written blocks cannot poison the prefix cache), deadlines expire
     pending admissions BEFORE device work, and preemption may evict a
-    pending sequence (its ``Preempted.tokens`` is the bare prompt,
-    ``n_generated == 0``).
+    pending sequence (its ``Preempted.tokens`` is the bare prompt).
 
-Ragged unified dispatch — paged adapter only (see README "Ragged
-dispatch"; serving/ragged/):
-
-  * ``ragged=True`` routes EVERY engine step — decode rows, speculative
-    verify windows, pending prefill chunks — through ONE
-    ``model_base.paged_ragged_step`` dispatch planned by the
-    ``RaggedBatchPlanner``, padded within the unified
-    ``autobucketing.ragged_row_buckets`` ladder. Admission always defers
-    (``add_requests`` returns ``{}``) and ``prefill_budget_tokens``
-    becomes a per-step cap on packed prompt tokens instead of a
-    serialization point. Token streams stay bit-identical to the
-    two-phase path, with and without ``speculation=`` (pinned by
-    tests/test_ragged_dispatch.py).
+Ragged unified dispatch (see README "Ragged dispatch"; serving/ragged/):
+``ragged=True`` routes EVERY engine step — decode rows, speculative verify
+windows, pending prefill chunks — through ONE
+``model_base.paged_ragged_step`` dispatch planned by the
+``RaggedBatchPlanner``. Admission always defers and
+``prefill_budget_tokens`` becomes a per-step cap on packed prompt tokens.
+Token streams stay bit-identical to the two-phase path, with and without
+``speculation=`` (tests/test_ragged_dispatch.py).
 
 Resilience contract (see README "Serving resilience"):
 
   * every boundary failure is typed (``resilience.errors``) — never a bare
-    ``ValueError``/``RuntimeError`` (enforced by
-    the ``error-paths`` pass of ``scripts/nxdi_lint.py``);
+    ``ValueError``/``RuntimeError`` (the ``error-paths`` lint pass);
   * ``add_requests`` is **transactional**: it either admits every sequence
-    or rolls back all allocations/adapter state from the call and leaves
-    device + cache state exactly as before;
-  * the paged adapter **preempts** the lowest-priority running sequence
-    when the block pool runs dry (``preemption_policy``: "lifo" /
-    "fewest_generated" / None), handing back :class:`Preempted` records
-    via :meth:`PagedEngineAdapter.take_preempted`;
+    or rolls back all allocations/adapter state from the call;
+  * the adapter **preempts** the lowest-priority running sequence when the
+    block pool runs dry (``preemption_policy``), handing back
+    :class:`Preempted` records via :meth:`PagedEngineAdapter.take_preempted`;
   * per-request wall-clock deadlines (``deadline_s``) and a
     decode-past-``seq_len`` guard bound each request's budget; both are
     horizon-aware (``step_many(k)`` checks them once for the whole k-step
@@ -197,8 +168,7 @@ class _ChunkState:
 
 @dataclass
 class _Inflight:
-    """One dispatched-but-not-fetched decode step (``step_ahead()``, or
-    ``step()`` under ``pipeline_depth=1``).
+    """One dispatched-but-not-fetched decode step (``step_ahead()``).
 
     ``states`` pins the exact _SeqState objects the dispatch advanced:
     retire/rollback apply only where the identity still matches, so a row
@@ -630,59 +600,18 @@ def _pad_paged_rows(pad_to, ids, pos, slots, bt, last):
 # Per-composition scratch buffers (incremental host bookkeeping)
 # ---------------------------------------------------------------------------
 
-class _CbScratch:
-    """Reusable decode-step input buffers for one (live set, batch bucket)
-    composition on the contiguous adapter: the per-step np.concatenate /
-    np.repeat rebuilds become in-place fills.
-
-    The mutable input buffers are DOUBLE-BUFFERED (ping-pong): jax's CPU
-    backend may alias a suitably-aligned numpy array zero-copy, so
-    refilling the buffer a still-in-flight pipelined dispatch aliases
-    would corrupt its input mid-execution. Each fill() flips buffers; a
-    set is only rewritten after its dispatch was retired (depth <= 1)."""
-
-    def __init__(self, live: Sequence[int], pad_to: int):
-        b = len(live)
-        self.live = tuple(live)
-        self.b = b
-        self.pad_to = pad_to
-        self.rows = None               # live[i]'s output is row i
-        self.sid_p = np.empty((pad_to,), np.int32)   # immutable after init
-        self.sid_p[:b] = live
-        self.sid_p[b:] = live[0]
-        self._bufs = [(np.empty((pad_to, 1), np.int32),
-                       np.empty((pad_to, 1), np.int32)) for _ in range(2)]
-        self._cur = 0
-        self.toks_p, self.pos_p = self._bufs[0]
-        # device-feedback re-pad map: pad rows must stay clones of row 0
-        self.gather_idx = np.concatenate(
-            [np.arange(b, dtype=np.intp),
-             np.zeros(pad_to - b, dtype=np.intp)])
-
-    def fill(self, adapter, need_tokens: bool = True):
-        self._cur ^= 1
-        self.toks_p, self.pos_p = self._bufs[self._cur]
-        seqs = adapter.seqs
-        for i, s in enumerate(self.live):
-            st = seqs[s]
-            self.pos_p[i, 0] = st.position
-            if need_tokens:
-                self.toks_p[i, 0] = st.last_token
-        if self.pad_to > self.b:
-            self.pos_p[self.b:] = self.pos_p[0, 0]
-            if need_tokens:
-                self.toks_p[self.b:] = self.toks_p[0, 0]
-
-
 class _PagedScratch:
     """Reusable decode-step input buffers for one (live set, batch bucket,
     table-width bucket) composition on the paged adapter. The block-table
     array is refreshed incrementally (only rows whose block list grew);
     slot mappings are recomputed in place from the cached table.
 
-    Double-buffered like :class:`_CbScratch` (jax CPU zero-copy aliasing):
-    each fill() flips to the other (ids, pos, slots, bt, counts) set, so
-    the buffers a still-in-flight dispatch aliases are never rewritten."""
+    The mutable input buffers are DOUBLE-BUFFERED (ping-pong): jax's CPU
+    backend may alias a suitably-aligned numpy array zero-copy, so
+    refilling the buffer a still-in-flight lookahead dispatch aliases
+    would corrupt its input mid-execution. Each fill() flips to the other
+    (ids, pos, slots, bt, counts) set; a set is only rewritten after its
+    dispatch was retired (one step in flight at most)."""
 
     def __init__(self, live: Sequence[int], pad_to: int, width: int,
                  block_size: int, seeds: Optional[Sequence[int]] = None,
@@ -797,25 +726,90 @@ class _SlotScratch(_PagedScratch):
 
 
 # ---------------------------------------------------------------------------
-# Shared adapter machinery (pipeline + fused multi-step + eager template)
+# The adapter
 # ---------------------------------------------------------------------------
 
-class _EngineAdapterBase:
-    """Decode-path machinery shared by both adapters: the eager step
-    template, the depth-1 decode pipeline (device-resident token feedback,
-    deferred fetch, lookahead-aware rollback) and ``step_many``. Subclasses
-    provide dispatch, scratch construction, KV growth and token
-    bookkeeping."""
+class PagedEngineAdapter:
+    """vLLM-style engine adapter over the PAGED app: block tables keyed by
+    seq_id, slot mappings computed from the tables (reference: the
+    slot_mapping / active_block_table contract of
+    block_kv_cache_manager.py + model_wrapper.py:1297-1313).
 
-    engine_name = ""
-    _decode_failure_msg = "decode device step failed"
+    ``preemption_policy`` ("lifo" | "fewest_generated" | None) arms
+    recompute preemption: when the block pool cannot satisfy an allocation
+    the lowest-priority running sequence is evicted, its blocks reclaimed,
+    and a :class:`Preempted` record queued for :meth:`take_preempted` —
+    the engine re-queues ``record.tokens`` as a fresh prompt. ``None``
+    disables eviction (allocation failures then raise
+    :class:`CapacityError` after rolling the call back). Pending chunked
+    admissions are eligible victims too (``tokens`` = the bare prompt,
+    ``n_generated == 0``).
 
-    def _init_decode_path(self, pipeline_depth: Optional[int]):
-        if pipeline_depth not in (None, 0, 1):
+    ``prefill_chunk_tokens`` bounds one sequence's per-dispatch prefill
+    chunk (default: the largest ctx bucket — monolithic-equivalent, but
+    prompts longer than that bucket are still admitted by walking them in
+    bucket-sized chunks). ``prefill_budget_tokens`` defers prefill to the
+    scheduler: ``add_requests`` returns ``{}`` and each ``step()`` runs at
+    most one packed chunk dispatch of at most that many prompt tokens
+    before its decode work (first tokens arrive from the completing
+    ``step()``). Both are documented in README "Chunked prefill".
+
+    ``pipeline_depth`` is ``None`` (``step_ahead()`` keeps one decode step
+    in flight) or ``0`` (``step_ahead()`` is eager too: the tests'
+    reference under the engine); ``step()`` is always eager. The decode
+    path is the eager template (``_step_eager``), the lookahead
+    (``_step_pipelined``: device-resident token feedback, deferred fetch,
+    lookahead-aware rollback) and ``step_many``; see the module
+    docstring."""
+
+    engine_name = "paged"
+
+    def __init__(self, app, telemetry=None,
+                 preemption_policy: Optional[str] = "lifo",
+                 pipeline_depth: Optional[int] = None,
+                 prefill_chunk_tokens: Optional[int] = None,
+                 prefill_budget_tokens: Optional[int] = None,
+                 speculation=None, kv_spill_tier=None,
+                 ragged: bool = False, lora_pool=None):
+        cfg = app.tpu_config
+        if not cfg.is_block_kv_layout:
+            raise ConfigurationError("app must be built with "
+                                     "is_block_kv_layout=True")
+        if (preemption_policy is not None
+                and preemption_policy not in PREEMPTION_POLICIES):
             raise ConfigurationError(
-                f"pipeline_depth must be 0 (eager), 1 (one dispatch of "
-                f"lookahead) or None (lookahead for the caller that asks "
-                f"through step_ahead()), got {pipeline_depth!r}")
+                f"unknown preemption_policy {preemption_policy!r}; expected "
+                f"one of {PREEMPTION_POLICIES} or None")
+        if prefill_chunk_tokens is not None and prefill_chunk_tokens < 1:
+            raise ConfigurationError("prefill_chunk_tokens must be >= 1")
+        if prefill_budget_tokens is not None and prefill_budget_tokens < 1:
+            raise ConfigurationError("prefill_budget_tokens must be >= 1")
+        self.app = app
+        self.batch = cfg.batch_size
+        self.seqs: Dict[int, _SeqState] = {}
+        self.telemetry = _AdapterTelemetry("paged", telemetry)
+        self.preemption_policy = preemption_policy
+        self.preempted: List[Preempted] = []
+        self._admit_counter = 0
+        self._pos_limit = (None if getattr(app.spec, "rolling_window", False)
+                           else cfg.seq_len)
+        # chunked prefill: width ladder clamped at the chunk bucket so
+        # chunk dispatches only ever run already-compiled ctx-bucket shapes
+        self._chunk_widths = autobucketing.prefill_chunk_buckets(
+            app.ctx_buckets, prefill_chunk_tokens)
+        self.prefill_chunk_tokens = (
+            min(prefill_chunk_tokens, self._chunk_widths[-1])
+            if prefill_chunk_tokens is not None else self._chunk_widths[-1])
+        self.prefill_budget_tokens = prefill_budget_tokens
+        self._chunks: Dict[int, _ChunkState] = {}   # pending admissions
+        self._unwritten: set = set()   # allocated blocks not fully written
+        if pipeline_depth not in (None, 0):
+            raise ConfigurationError(
+                f"pipeline_depth must be None (step_ahead() keeps one "
+                f"decode step in flight) or 0 (step_ahead() is eager too), "
+                f"got {pipeline_depth!r}; step() always returns its own "
+                f"step's tokens: a caller that can take them one call late "
+                f"calls step_ahead()")
         self.pipeline_depth = pipeline_depth
         self._inflight: Optional[_Inflight] = None
         # why the in-flight step can no longer be fed back (the live set
@@ -829,8 +823,8 @@ class _EngineAdapterBase:
         self._gap_mark: Optional[tuple] = None
         self._ready: Dict[int, int] = {}
         self._scratch = None
-        self._spec = None              # SpeculativeDecodePath (paged only)
-        self._ragged = None            # RaggedDispatchPath (paged only)
+        self._spec = None              # SpeculativeDecodePath
+        self._ragged = None            # RaggedDispatchPath
         # degradation-controller actuators (resilience/controller.py):
         # shed flags are consulted per step, so flipping them mid-serve
         # changes DISPATCH SHAPE only — greedy token streams are
@@ -873,772 +867,9 @@ class _EngineAdapterBase:
             "prefill_blocking_fetches": 0,
             "prefill_blocked_s": 0.0, "prefill_real_tokens": 0,
             "prefill_padded_tokens": 0}
-
-    # -- subclass hooks ----------------------------------------------------
-    def _pending_ids(self):
-        """seq_ids admitted but still mid-prefill (paged chunked
-        admissions); () on adapters without a deferred prefill path."""
-        return ()
-
-    def _advance_prefill(self, seq_ids=None):
-        """Run at most one packed prefill-chunk dispatch for pending
-        admissions; finished sequences' first tokens land in ``_ready``.
-        ``seq_ids`` is the step call's explicit target set (None = all):
-        an expired pending admission outside it is skipped, not raised —
-        a healthy row must not be stalled by an unrelated request's
-        budget. No-op on adapters without a deferred prefill path."""
-
-    def _grow_for_step(self, live: List[int], n: int = 1) -> List[int]:
-        return live
-
-    def _rollback_step_growth(self, live: Sequence[int], n: int = 1):
-        pass
-
-    def _append_token(self, st: _SeqState, tok: int):
-        st.last_token = tok
-
-    _step_growth = 0              # paged: KV tokens grown per dispatch
-
-    def _tenant_of(self, seq_ids) -> str:
-        """Common tenant label of ``seq_ids`` (running rows), "" when
-        mixed/unknown — failure counters attribute per tenant only when
-        the attribution is unambiguous."""
-        return _common_tenant(_meta_tenant(self.seqs[s].meta)
-                              for s in seq_ids if s in self.seqs)
-
-    def _traces_of(self, seq_ids):
-        """Request trace ids of ``seq_ids`` (running rows) — the
-        attribution payload for steady-state recompile incidents
-        (serving/warmup.py)."""
-        return [_trace_of(self.seqs[s].meta)
-                for s in seq_ids if s in self.seqs]
-
-    # -- fetch helpers (the ONLY places that block on device output) -------
-    def _fetch_rows(self, out, b: int, rows=None) -> np.ndarray:
-        """The sampled tokens of the ``b`` live sequences: the first ``b``
-        rows, or rows ``rows`` where the dispatch was not laid out live
-        rows first (a slot-ordered decode step)."""
-        t0 = time.perf_counter()
-        with _get_recorder().span("fetch.tokens", cat="adapter",
-                                  engine=self.engine_name, rows=b):
-            toks = np.asarray(out["tokens"])
-            tally = out.get("moe_tally")
-            if tally is not None:
-                # a decode step over expert layers: what its routing touched
-                # and its expert path read, counted on the device (five
-                # int32)
-                self._count_moe_tally(np.asarray(tally))
-        self.host_stats["blocking_fetches"] += 1
-        self.host_stats["blocked_s"] += time.perf_counter() - t0
-        toks = toks.reshape(toks.shape[0], -1)
-        return toks[:b] if rows is None else toks[rows]
-
-    def _count_moe_tally(self, tally: np.ndarray):
-        """``[touched, assigned, read, picks, identity picks]`` of one
-        decode step (``moe.share_tally`` + ``moe.zero_tally`` summed over
-        the expert layers) into ``host_stats``; the slots the first three
-        are counted over are held experts x expert layers, once a step, and
-        ``moe_experts_skipped`` is the slots the step did not read (a
-        reader that sums and divides cannot subtract). ``moe_assignments``
-        is every top-k pick of the live rows, ``moe_assignments_zero`` those
-        that fell to identity experts (``MoESpec.zero_experts``)."""
-        spec = self.app.spec
-        slots = spec.moe.num_held * spec.num_moe_layers
-        touched, assigned, read, picks, zero = (int(n) for n in tally)
-        st = self.host_stats
-        for key, n in (("moe_experts_touched", touched),
-                       ("moe_assignments_held", assigned),
-                       ("moe_experts_read", read),
-                       ("moe_experts_skipped", slots - read),
-                       ("moe_expert_slots", slots),
-                       ("moe_assignments", picks),
-                       ("moe_assignments_zero", zero)):
-            st[key] = st.get(key, 0) + n
-        self.telemetry.on_moe_tally(touched, slots, assigned, read, picks,
-                                    zero)
-
-    def _note_gap(self, states: Sequence[_SeqState]):
-        """A decode step's tokens for ``states`` just became host-visible.
-        If one of them was live at the previous such point too, the
-        interval is a gap between tokens that a client saw: count it, by
-        what it waited behind — ``prefill`` if prefill dispatches were
-        issued in between (how many: the chain's length), else ``drain`` if
-        the in-flight step was drained, else ``none``. Always on: one clock
-        read and a few dict adds. A state that left never comes back (a
-        replayed row is a new one), so no gap spans an empty live set."""
-        now = time.perf_counter()
-        st = self.host_stats
-        mark = self._gap_mark
-        self._gap_mark = (now, states, st["prefill_dispatches"],
-                          self._drains)
-        if mark is None or not states:
-            return
-        t_prev, before, prefills, drains = mark
-        if not before or states[0] is not before[0]:
-            # else: the live set as it was, the usual case
-            was = {id(x) for x in before}
-            if not any(id(x) in was for x in states):
-                return
-        gap = now - t_prev
-        chain = st["prefill_dispatches"] - prefills
-        st["decode_gaps"] += 1
-        st["decode_gap_s"] += gap
-        if chain:
-            st["decode_gaps_behind_prefill"] += 1
-            st["decode_gap_s_behind_prefill"] += gap
-            st["prefill_dispatches_in_gaps"] += chain
-        if gap >= 1.0:
-            st["decode_gaps_over_1s"] += 1
-            st["decode_gaps_over_1s_behind_prefill"] += bool(chain)
-        if gap > st["decode_gap_max_s"]:
-            st["decode_gap_max_s"] = gap
-        self.telemetry.on_gap(gap, "prefill" if chain else
-                              "drain" if self._drains > drains else "none")
-
-    # -- public decode surface ---------------------------------------------
-    def step(self, seq_ids: Optional[Sequence[int]] = None) -> Dict[int, int]:
-        """One decode step for ``seq_ids`` (default: every running row).
-
-        Returns {seq_id: next token} for THIS step (eager), unless the
-        adapter was built with ``pipeline_depth=1``: then it dispatches this
-        step and returns the PREVIOUS step's tokens ({} on the first call
-        after the pipeline empties; drain the last step with :meth:`flush`),
-        as :meth:`step_ahead` does for the serving engine by default. Raises
-        :class:`DeadlineExceeded` / :class:`CapacityError` before any
-        device work when a row is over budget, and :class:`StepFailure`
-        when a device step fails — see the class docstring for the
-        deferred-failure rollback contract."""
-        if self.pipeline_depth:
-            return self._step_pipelined(seq_ids)
-        return self._step_eager(seq_ids)
-
-    def step_ahead(self, seq_ids: Optional[Sequence[int]] = None
-                   ) -> Dict[int, int]:
-        """:meth:`step` for a caller that can take a step's tokens one call
-        late — the serving engine's loop. Unless the adapter was built
-        with ``pipeline_depth=0`` the call enqueues this step BEFORE it
-        blocks on the previous one and returns the previous step's tokens,
-        so the caller's own work between two calls (scheduling, routing,
-        the yield to the stream writers) runs while the device computes.
-        :meth:`flush` hands back what is still in flight; :attr:`lookahead_ids`
-        names the rows that have such a token coming."""
-        if self.pipeline_depth == 0:
-            return self._step_eager(seq_ids)
-        return self._step_pipelined(seq_ids)
-
-    @property
-    def lookahead_ids(self) -> set:
-        """seq_ids whose next token is sampled, or being sampled by the
-        in-flight step, but not handed to the caller yet."""
-        ids = set(self._ready)
-        rec = self._inflight
-        if rec is not None:
-            ids.update(s for s, st in zip(rec.live, rec.states)
-                       if self.seqs.get(s) is st)
-        return ids
-
-    def step_many(self, num_steps: int,
-                  seq_ids: Optional[Sequence[int]] = None
-                  ) -> Dict[int, List[int]]:
-        """``num_steps`` fused decode steps in ONE device dispatch and ONE
-        blocking host fetch. Returns {seq_id: [tokens]} in stream order;
-        a pipelined adapter's in-flight token is drained first and
-        prepended (it is simply the preceding token of the same stream).
-        Deadlines and the seq_len guard are enforced once for the whole
-        horizon, before any device work. EOS handling stays with the
-        engine, at horizon boundaries."""
-        if num_steps < 1:
-            raise ConfigurationError("step_many requires num_steps >= 1")
-        if self._inflight is not None or self._ready:
-            self._stash_flush()
-        # pending drained tokens stay in self._ready until this call is
-        # past every fallible stage — a recoverable DeadlineExceeded /
-        # CapacityError / StepFailure must not drop them from the stream
-        pending = self._pending_ids()
-        live = _live_rows(self.seqs, seq_ids, pending)
-        if not live and not pending:
-            return {s: [t] for s, t in self._drain_ready().items()}
-        if _FAULTS.active:
-            _FAULTS.fire("slow_step")
-        if live:
-            _pre_step_checks(self.seqs, live, self._pos_limit,
-                             self.telemetry, horizon=num_steps)
-        # at most ONE packed prefill-chunk dispatch per horizon — the
-        # scheduler knob that keeps a long admission from stalling decode
-        self._advance_prefill(seq_ids)
-        if not live:
-            return {s: [t] for s, t in self._drain_ready().items()}
-        t0 = time.perf_counter()
-        live = self._grow_for_step(live, num_steps)
-        if not live:
-            return {s: [t] for s, t in self._drain_ready().items()}
-        toks, pad_to = self._run_many(live, num_steps)
-        res = {s: [t] for s, t in self._drain_ready().items()}
-        for i, s in enumerate(live):
-            st = self.seqs[s]
-            st.position += num_steps
-            row = [int(t) for t in toks[i]]
-            for t in row:
-                self._append_token(st, t)
-            res.setdefault(s, []).extend(row)
-        self.telemetry.on_step(live, t0, padded=pad_to, steps=num_steps)
-        self.telemetry.on_fetch(num_steps)
-        return res
-
-    def flush(self) -> Dict[int, int]:
-        """Retire the in-flight pipelined dispatch (if any) and hand back
-        every token not yet delivered: {seq_id: token}. {} in eager mode.
-        A deferred fetch failure aborts the pipeline (StepFailure,
-        ``retry_safe=False``)."""
-        ready = self._drain_ready()
-        rec, self._inflight = self._inflight, None
-        if rec is not None:
-            self._note_drain()
-            try:
-                ready.update(self._retire_or_abort([rec]))
-            except BaseException:
-                # the drained tokens were already generated and applied to
-                # host state — keep them deliverable past the failure
-                self._ready = {**ready, **self._ready}
-                raise
-        return ready
-
-    # -- eager path --------------------------------------------------------
-    def _step_eager(self, seq_ids) -> Dict[int, int]:
-        pending = self._pending_ids()
-        live = _live_rows(self.seqs, seq_ids, pending)
-        if not live and not pending:
-            return self._drain_ready()
-        if _FAULTS.active:
-            _FAULTS.fire("slow_step")
-        if live:
-            _pre_step_checks(self.seqs, live, self._pos_limit,
-                             self.telemetry)
-        self._advance_prefill(seq_ids)
-        if not live:
-            return self._drain_ready()
-        t0 = time.perf_counter()
-        with _get_recorder().span("dispatch.build", cat="adapter"):
-            live = self._grow_for_step(live)
-            if not live:
-                return self._drain_ready()
-            scr = self._scratch_for(live)
-            scr.fill(self)
-        cache_before = self.app.cache
-        try:
-            if _FAULTS.active:
-                _FAULTS.fire("decode_step")
-            out = self._dispatch_decode(scr)
-            new = self._fetch_rows(out, len(live), scr.rows)
-        except ServingError:
-            self._rollback_step_growth(live)
-            self._scratch = None
-            raise
-        except Exception as e:
-            self._rollback_step_growth(live)
-            self._scratch = None
-            self.telemetry.on_step_failure("decode", self._tenant_of(live))
-            raise _trace_error(StepFailure(
-                self._decode_failure_msg + "; positions were not advanced",
-                phase="decode", seq_ids=tuple(live),
-                retry_safe=self.app.cache is cache_before)) from e
-        self._note_gap(tuple(self.seqs[s] for s in live))
-        res = self._drain_ready()    # first tokens of finished prefills
-        for i, s in enumerate(live):
-            st = self.seqs[s]
-            st.position += 1
-            tok = int(new[i, 0])
-            self._append_token(st, tok)
-            res[s] = tok
-        self.telemetry.on_step(live, t0, padded=scr.pad_to)
-        self.telemetry.on_fetch(1)
-        return res
-
-    # -- pipelined path ----------------------------------------------------
-    def _step_pipelined(self, seq_ids) -> Dict[int, int]:
-        pending = self._pending_ids()
-        live = _live_rows(self.seqs, seq_ids, pending)
-        if not live and not pending:
-            return self.flush()
-        if _FAULTS.active:
-            _FAULTS.fire("slow_step")
-        if live:
-            _pre_step_checks(self.seqs, live, self._pos_limit,
-                             self.telemetry)
-        self._advance_prefill(seq_ids)
-        if not live:
-            return self.flush()
-        ready = self._drain_ready()
-        try:
-            return self._advance_pipeline(live, ready)
-        except BaseException:
-            # tokens drained (or retired) this call were already generated
-            # and applied to host state — keep them deliverable past a
-            # recoverable failure instead of dropping them from the stream
-            self._ready = {**ready, **self._ready}
-            raise
-
-    def _advance_pipeline(self, live: List[int],
-                          ready: Dict[int, int]) -> Dict[int, int]:
-        prev, self._inflight = self._inflight, None
-        if prev is not None and not self._matches(prev, live):
-            # live-set changed since the dispatch: drain it synchronously
-            self._note_drain()
-            ready.update(self._retire_or_abort([prev]))
-            prev = None
-        t0 = time.perf_counter()
-        with _get_recorder().span("dispatch.build", cat="adapter"):
-            try:
-                live = self._grow_for_step(live)
-            except ServingError:
-                self._inflight = prev      # growth rolled itself back
-                raise
-            if not live:
-                self._inflight = prev
-                return ready
-            if prev is not None and not self._matches(prev, live):
-                # preemption shrank the batch mid-call: drain the old
-                # composition's dispatch before re-padding for the new one
-                self._note_drain("preempt")
-                ready.update(self._retire_or_abort([prev]))
-                prev = None
-            scr = self._scratch_for(live)
-            scr.fill(self, need_tokens=prev is None)
-            toks_dev = (None if prev is None
-                        else self._feedback_tokens(prev, scr))
-        cache_before = self.app.cache
-        try:
-            if _FAULTS.active:
-                _FAULTS.fire("decode_step")
-            out = self._dispatch_decode(scr, toks_dev)
-        except ServingError:
-            self._rollback_step_growth(live)
-            self._scratch = None
-            self._inflight = prev          # lookahead step is still healthy
-            raise
-        except Exception as e:
-            self._rollback_step_growth(live)
-            self._scratch = None
-            self._inflight = prev
-            self.telemetry.on_step_failure("decode", self._tenant_of(live))
-            raise _trace_error(StepFailure(
-                self._decode_failure_msg + " at dispatch; the in-flight "
-                "lookahead step was preserved",
-                phase="decode", seq_ids=tuple(live),
-                retry_safe=self.app.cache is cache_before)) from e
-        rec = _Inflight(
-            live=tuple(live),
-            states=tuple(self.seqs[s] for s in live),
-            b=len(live), pad_to=scr.pad_to, out=out, t_dispatch=t0,
-            grown=self._step_growth, rows=scr.rows)
-        for s in live:
-            self.seqs[s].position += 1
-        self._drain_cause = None           # the step in flight is current
-        if prev is not None:
-            self.host_stats["overlapped_dispatches"] += 1
-            self.telemetry.on_overlap()
-            ready.update(self._retire_or_abort([prev, rec]))
-        self._inflight = rec
-        return ready
-
-    def _matches(self, rec: _Inflight, live: Sequence[int]) -> bool:
-        return (rec.live == tuple(live)
-                and all(self.seqs.get(s) is st
-                        for s, st in zip(rec.live, rec.states)))
-
-    # pad rows sample what row 0 samples (greedy, or the positionally
-    # coupled stream with row 0's seed): a step's full-batch output is
-    # then the next step's ids as it stands
-    _pads_follow_row0 = False
-
-    def _note_stale(self, cause: str):
-        """Rows left or joined (``cause``) while a step is in flight. The
-        step stays in flight — nothing blocks here; the next decode call
-        sees the changed live set, drains it and counts ``cause``. If none
-        of its rows is left, nobody is owed its tokens: it is dropped
-        unfetched."""
-        rec = self._inflight
-        if rec is None:
-            return
-        if all(self.seqs.get(s) is not st
-               for s, st in zip(rec.live, rec.states)):
-            self._inflight = None
-            self._drain_cause = None
-        elif self._drain_cause is None:
-            self._drain_cause = cause
-
-    def _note_drain(self, default: str = "liveset"):
-        cause, self._drain_cause = self._drain_cause or default, None
-        self._drains += 1
-        self.host_stats[f"pipeline_drains_{cause}"] += 1
-        self.telemetry.on_drain(cause)
-
-    def _feedback_tokens(self, prev: _Inflight, scr):
-        """The previous dispatch's on-device sampled tokens as the next
-        step's input ids — no host round trip. A paged decode step hands
-        them on ready-made (``out["next_ids"]``: the live set is unchanged,
-        so pad rows are clones of row 0 and a slot-ordered step has no pad
-        rows): no program runs between two steps. Otherwise they are
-        re-padded ON DEVICE (pad rows must stay clones of row 0 even under
-        unseeded stochastic sampling)."""
-        nxt = prev.out.get("next_ids")
-        if nxt is not None and (scr.pad_to == scr.b or scr.rows is not None
-                                or self._pads_follow_row0):
-            return nxt
-        toks = prev.out["tokens"].reshape(-1)
-        if scr.pad_to > scr.b:
-            toks = toks[scr.gather_idx]
-        return toks[:, None]
-
-    def _retire(self, rec: _Inflight) -> Dict[int, int]:
-        """Materialize ``rec``'s tokens (the ONE blocking sync of the
-        pipelined path) and apply the deferred host bookkeeping. Raises
-        the raw fetch failure — callers route it through
-        :meth:`_abort_pipeline`."""
-        with _get_recorder().span("dispatch.retire", cat="adapter",
-                                  engine=self.engine_name, rows=rec.b):
-            if _FAULTS.active:
-                _FAULTS.fire("pipeline_flush")
-            new = self._fetch_rows(rec.out, rec.b, rec.rows)
-            self._note_gap(rec.states)
-            res = {}
-            for i, (s, st) in enumerate(zip(rec.live, rec.states)):
-                if self.seqs.get(s) is not st:
-                    continue           # released/preempted while in flight
-                tok = int(new[i, 0])
-                self._append_token(st, tok)
-                res[s] = tok
-            self.telemetry.on_step(list(res), rec.t_dispatch,
-                                   padded=rec.pad_to)
-            self.telemetry.on_fetch(1)
-        return res
-
-    def _retire_or_abort(self, records: List[Optional[_Inflight]]
-                         ) -> Dict[int, int]:
-        try:
-            return self._retire(records[0])
-        except Exception as e:
-            self._abort_pipeline(records, e)
-
-    def _abort_pipeline(self, records: Sequence[Optional[_Inflight]],
-                        cause: Exception):
-        """A deferred fetch failed: the in-flight step's device output (and
-        any dispatch speculatively issued on top of it) is garbage. Unwind
-        every in-flight dispatch's host bookkeeping — positions and paged
-        KV growth return to the last DELIVERED token — and raise a
-        :class:`StepFailure` with ``retry_safe=False`` (the donated device
-        cache was consumed by the failed dispatch chain; re-admit or
-        rebuild)."""
-        self._scratch = None
-        seq_ids: Tuple[int, ...] = ()
-        for rec in records:
-            if rec is None:
-                continue
-            if not seq_ids:
-                seq_ids = rec.live
-            for s, st in zip(rec.live, rec.states):
-                if self.seqs.get(s) is st:
-                    st.position -= 1
-            self._unwind_inflight_growth(rec)
-        self.telemetry.on_step_failure("decode", self._tenant_of(seq_ids))
-        raise _trace_error(StepFailure(
-            "pipelined decode fetch failed; every in-flight lookahead step "
-            "was rolled back to the last delivered token",
-            phase="decode", seq_ids=seq_ids, retry_safe=False)) from cause
-
-    def _unwind_inflight_growth(self, rec: _Inflight):
-        pass
-
-    def _drain_ready(self) -> Dict[int, int]:
-        if not self._ready:
-            return {}
-        out, self._ready = self._ready, {}
-        return out
-
-    def _stash_flush(self):
-        """flush() into the pending buffer, so tokens drained by
-        add/release/step_many are handed back by the next returning call
-        instead of being dropped."""
-        for s, t in self.flush().items():
-            self._ready[s] = t
-
-    # -- post-mortem snapshot ----------------------------------------------
-    def debug_state(self) -> Dict[str, Any]:
-        """Read-only host-side snapshot for post-mortems (surfaced through
-        :meth:`~..engine.scheduler.ServingEngine.dump_debug_state` and the
-        ``GET /v1/debug/state`` endpoint). JSON-able; never touches device
-        state."""
-        return {
-            "engine": self.engine_name,
-            "running_ids": [int(s) for s in sorted(self.seqs)],
-            "positions": {int(s): int(st.position)
-                          for s, st in self.seqs.items()},
-            "tenants": {int(s): _meta_tenant(st.meta)
-                        for s, st in self.seqs.items()},
-            "pipeline_inflight": (0 if self._inflight is None
-                                  else len(self._inflight.live)),
-            "ready_undelivered": [int(s) for s in sorted(self._ready)],
-            "host_stats": dict(self.host_stats),
-        }
-
-
-class ContinuousBatchingAdapter(_EngineAdapterBase):
-    """vLLM-style engine adapter over the contiguous app
-    (reference: model_wrapper.py:1297-1440)."""
-
-    engine_name = "cb"
-
-    def __init__(self, app, telemetry=None,
-                 pipeline_depth: Optional[int] = None):
-        cfg = app.tpu_config
-        if not cfg.is_continuous_batching:
-            raise ConfigurationError("app must be built with "
-                                     "is_continuous_batching=True")
-        self.app = app
-        self.batch = cfg.batch_size
-        self.seqs: Dict[int, _SeqState] = {}
-        self.telemetry = _AdapterTelemetry("cb", telemetry)
-        # rolling caches (slot = pos % window) can decode past seq_len
-        self._pos_limit = (None if getattr(app.spec, "rolling_window", False)
-                           else cfg.seq_len)
-        # free rows, ascending — maintained incrementally on add/release
-        self._free: List[int] = list(range(self.batch))
-        self._init_decode_path(pipeline_depth)
-
-    # -- capacity ---------------------------------------------------------
-    @property
-    def free_slots(self) -> List[int]:
-        return list(self._free)
-
-    # -- lifecycle --------------------------------------------------------
-    def add_requests(self, seq_ids: Sequence[int],
-                     prompts: Sequence[Sequence[int]],
-                     deadline_s: Union[None, float,
-                                       Sequence[Optional[float]]] = None
-                     ) -> Dict[int, int]:
-        """Prefill ``prompts`` into cache rows ``seq_ids``. Returns
-        {seq_id: first generated token}. Rows are padded to the ctx bucket
-        (repeat-row-0 batch pad — reference ``vllm_cte_repadding``).
-        Transactional: a failure admits nothing (cache rows hold garbage
-        only for never-admitted seq_ids, which no live row can read). A
-        pipelined in-flight decode step stays in flight — the next step()
-        drains it when the live set changes."""
-        _validate_admission(seq_ids, prompts, self.app.tpu_config.seq_len)
-        for sid in seq_ids:
-            if not 0 <= sid < self.batch:
-                raise AdmissionError(f"seq_id {sid} out of range "
-                                     f"[0,{self.batch})")
-            if sid in self.seqs:
-                raise AdmissionError(f"seq_id {sid} already running")
-        t0 = time.perf_counter()
-        deadlines = _resolve_deadlines(deadline_s, len(seq_ids), t0)
-        b = len(seq_ids)
-        lens = np.asarray([len(p) for p in prompts], np.int32)
-        try:
-            width = autobucketing.get_target_bucket(
-                self.app.ctx_buckets, int(lens.max()), kind="ctx")
-        except ValueError as e:
-            raise AdmissionError(f"prompt does not fit any context-encoding "
-                                 f"bucket: {e}") from e
-        ids = np.zeros((b, width), np.int32)
-        for i, p in enumerate(prompts):
-            ids[i, :len(p)] = p
-        pad_to = self._batch_bucket(b)
-        ids_p, sid_p = self._pad_rows(ids, np.asarray(seq_ids, np.int32),
-                                      pad_to)
-        lens_p = np.concatenate([lens, np.repeat(lens[:1], pad_to - b)])
-        cache_before = self.app.cache
-        try:
-            if _FAULTS.active:
-                _FAULTS.fire("prefill_step")
-            out = self.app._run_prefill(ids_p, lens_p, seq_ids=sid_p)
-            # materialize INSIDE the try: dispatch is asynchronous, so a
-            # genuine device failure only surfaces when the tokens are
-            # fetched — it must still be wrapped and rolled back here
-            toks = np.asarray(out["tokens"])[:b]
-        except ServingError:
-            raise
-        except Exception as e:
-            self.telemetry.on_step_failure("prefill")
-            raise _trace_error(StepFailure(
-                "prefill device step failed; no sequences were admitted",
-                phase="prefill", seq_ids=seq_ids,
-                retry_safe=self.app.cache is cache_before)) from e
-        res = {}
-        for i, sid in enumerate(seq_ids):
-            # no tokens/admit_idx bookkeeping here: the CB adapter has no
-            # preemption path (rows are fixed slots), so the recompute
-            # record the paged adapter keeps would be dead state
-            self.seqs[sid] = _SeqState(
-                position=int(lens[i]), last_token=int(toks[i]),
-                prompt_len=int(lens[i]), deadline=deadlines[i])
-            del self._free[bisect.bisect_left(self._free, sid)]
-            res[sid] = int(toks[i])
-        self.telemetry.on_add(seq_ids, prompts, t0, live=b, padded=pad_to)
-        return res
-
-    def release(self, seq_ids: Sequence[int]):
-        for sid in seq_ids:
-            self._ready.pop(sid, None)
-            if self.seqs.pop(sid, None) is not None:
-                bisect.insort(self._free, sid)
-        self._note_stale("release")
-        self.telemetry.on_release(seq_ids)
-
-    # -- decode dispatch ---------------------------------------------------
-    def _scratch_for(self, live: Sequence[int]) -> _CbScratch:
-        pad_to = self._batch_bucket(len(live))
-        scr = self._scratch
-        if scr is None or scr.live != tuple(live) or scr.pad_to != pad_to:
-            scr = self._scratch = _CbScratch(live, pad_to)
-        return scr
-
-    def _dispatch_decode(self, scr: _CbScratch, toks_dev=None):
-        """Issue ONE decode step to the device without materializing any
-        output (region lint: nxdi_lint host-sync pass) — the blocking
-        fetch happens in the caller (eager) or at retire time (pipelined).
-        ``toks_dev``: previous dispatch's on-device tokens (pipelined
-        feedback); None = host tokens from the scratch buffer."""
-        ids = scr.toks_p if toks_dev is None else toks_dev
-        out = self.app._run_decode(ids, scr.pos_p, seq_ids=scr.sid_p)
-        _async_fetch(out["tokens"])
-        self.host_stats["dispatches"] += 1
-        self.host_stats["device_steps"] += 1
-        rec = _get_recorder()
-        if rec.enabled:
-            rec.instant("dispatch.decode", cat="adapter",
-                        engine=self.engine_name, rows=scr.b,
-                        pad_to=scr.pad_to, seq_ids=list(scr.live),
-                        pipelined=toks_dev is not None)
-        return out
-
-    def _run_many(self, live: List[int], num_steps: int):
-        """Fused k-step decode through the jitted lax.scan loop
-        (model_base.decode_loop) — one dispatch, one fetch."""
-        b = len(live)
-        pad_to = self._batch_bucket(b)
-        first = np.empty((pad_to,), np.int32)
-        pos = np.empty((pad_to,), np.int32)
-        sid = np.empty((pad_to,), np.int32)
-        for i, s in enumerate(live):
-            st = self.seqs[s]
-            first[i] = st.last_token
-            pos[i] = st.position
-            sid[i] = s
-        first[b:] = first[0]
-        pos[b:] = pos[0]
-        sid[b:] = sid[0]
-        cache_before = self.app.cache
-        try:
-            if _FAULTS.active:
-                _FAULTS.fire("decode_step")
-            out = self.app._run_decode_loop(first, pos, num_steps,
-                                            seq_ids=sid)
-            self.host_stats["dispatches"] += 1
-            self.host_stats["device_steps"] += num_steps
-            rec = _get_recorder()
-            if rec.enabled:
-                rec.instant("dispatch.decode_loop", cat="adapter",
-                            engine=self.engine_name, rows=b, pad_to=pad_to,
-                            steps=num_steps, seq_ids=list(live))
-            toks = self._fetch_rows(out, b)
-        except ServingError:
-            raise
-        except Exception as e:
-            self.telemetry.on_step_failure("decode", self._tenant_of(live))
-            raise _trace_error(StepFailure(
-                "fused decode loop failed; positions were not advanced",
-                phase="decode", seq_ids=tuple(live),
-                retry_safe=self.app.cache is cache_before)) from e
-        return toks, pad_to
-
-    # -- helpers ----------------------------------------------------------
-    def _batch_bucket(self, b: int) -> int:
-        if b > self.batch:
-            raise CapacityError(f"live batch {b} exceeds compiled batch "
-                                f"{self.batch}")
-        return autobucketing.get_target_bucket(self.app.batch_buckets, b,
-                                               kind="batch")
-
-    @staticmethod
-    def _pad_rows(ids: np.ndarray, seq_ids: np.ndarray, pad_to: int):
-        pad = pad_to - ids.shape[0]
-        if pad <= 0:
-            return ids, seq_ids
-        return (np.concatenate([ids, np.repeat(ids[:1], pad, axis=0)]),
-                np.concatenate([seq_ids, np.repeat(seq_ids[:1], pad)]))
-
-
-class PagedEngineAdapter(_EngineAdapterBase):
-    """vLLM-style engine adapter over the PAGED app: block tables keyed by
-    seq_id, slot mappings computed from the tables (reference: the
-    slot_mapping / active_block_table contract of
-    block_kv_cache_manager.py + model_wrapper.py:1297-1313).
-
-    ``preemption_policy`` ("lifo" | "fewest_generated" | None) arms
-    recompute preemption: when the block pool cannot satisfy an allocation
-    the lowest-priority running sequence is evicted, its blocks reclaimed,
-    and a :class:`Preempted` record queued for :meth:`take_preempted` —
-    the engine re-queues ``record.tokens`` as a fresh prompt. ``None``
-    disables eviction (allocation failures then raise
-    :class:`CapacityError` after rolling the call back). Pending chunked
-    admissions are eligible victims too (``tokens`` = the bare prompt,
-    ``n_generated == 0``).
-
-    ``prefill_chunk_tokens`` bounds one sequence's per-dispatch prefill
-    chunk (default: the largest ctx bucket — monolithic-equivalent, but
-    prompts longer than that bucket are still admitted by walking them in
-    bucket-sized chunks). ``prefill_budget_tokens`` defers prefill to the
-    scheduler: ``add_requests`` returns ``{}`` and each ``step()`` runs at
-    most one packed chunk dispatch of at most that many prompt tokens
-    before its decode work (first tokens arrive from the completing
-    ``step()``). Both are documented in README "Chunked prefill"."""
-
-    engine_name = "paged"
-    _decode_failure_msg = ("paged decode step failed; KV growth was rolled "
-                          "back")
-    _step_growth = 1
-
-    def __init__(self, app, telemetry=None,
-                 preemption_policy: Optional[str] = "lifo",
-                 pipeline_depth: Optional[int] = None,
-                 prefill_chunk_tokens: Optional[int] = None,
-                 prefill_budget_tokens: Optional[int] = None,
-                 speculation=None, kv_spill_tier=None,
-                 ragged: bool = False, lora_pool=None):
-        cfg = app.tpu_config
-        if not cfg.is_block_kv_layout:
-            raise ConfigurationError("app must be built with "
-                                     "is_block_kv_layout=True")
-        if (preemption_policy is not None
-                and preemption_policy not in PREEMPTION_POLICIES):
-            raise ConfigurationError(
-                f"unknown preemption_policy {preemption_policy!r}; expected "
-                f"one of {PREEMPTION_POLICIES} or None")
-        if prefill_chunk_tokens is not None and prefill_chunk_tokens < 1:
-            raise ConfigurationError("prefill_chunk_tokens must be >= 1")
-        if prefill_budget_tokens is not None and prefill_budget_tokens < 1:
-            raise ConfigurationError("prefill_budget_tokens must be >= 1")
-        self.app = app
-        self.batch = cfg.batch_size
-        self.seqs: Dict[int, _SeqState] = {}
-        self.telemetry = _AdapterTelemetry("paged", telemetry)
-        self.preemption_policy = preemption_policy
-        self.preempted: List[Preempted] = []
-        self._admit_counter = 0
-        self._pos_limit = (None if getattr(app.spec, "rolling_window", False)
-                           else cfg.seq_len)
-        # chunked prefill: width ladder clamped at the chunk bucket so
-        # chunk dispatches only ever run already-compiled ctx-bucket shapes
-        self._chunk_widths = autobucketing.prefill_chunk_buckets(
-            app.ctx_buckets, prefill_chunk_tokens)
-        self.prefill_chunk_tokens = (
-            min(prefill_chunk_tokens, self._chunk_widths[-1])
-            if prefill_chunk_tokens is not None else self._chunk_widths[-1])
-        self.prefill_budget_tokens = prefill_budget_tokens
-        self._chunks: Dict[int, _ChunkState] = {}   # pending admissions
-        self._unwritten: set = set()   # allocated blocks not fully written
-        self._init_decode_path(pipeline_depth)
+        # pad rows sample what row 0 samples (greedy, or the positionally
+        # coupled stream with row 0's seed): a step's full-batch output is
+        # then the next step's ids as it stands
         sc = cfg.on_device_sampling_config
         self._pads_follow_row0 = (sc is None or not sc.do_sample
                                   or sc.stream_seed is not None)
@@ -1877,16 +1108,118 @@ class PagedEngineAdapter(_EngineAdapterBase):
         self._note_stale("release")
         self.telemetry.on_release(seq_ids)
 
-    # -- speculative decode (serving/speculation/) -------------------------
+    def _tenant_of(self, seq_ids) -> str:
+        """Common tenant label of ``seq_ids`` (running rows), "" when
+        mixed/unknown — failure counters attribute per tenant only when
+        the attribution is unambiguous."""
+        return _common_tenant(_meta_tenant(self.seqs[s].meta)
+                              for s in seq_ids if s in self.seqs)
+
+    def _traces_of(self, seq_ids):
+        """Request trace ids of ``seq_ids`` (running rows) — the
+        attribution payload for steady-state recompile incidents
+        (serving/warmup.py)."""
+        return [_trace_of(self.seqs[s].meta)
+                for s in seq_ids if s in self.seqs]
+
+    # -- fetch helpers (the ONLY places that block on device output) -------
+    def _fetch_rows(self, out, b: int, rows=None) -> np.ndarray:
+        """The sampled tokens of the ``b`` live sequences: the first ``b``
+        rows, or rows ``rows`` where the dispatch was not laid out live
+        rows first (a slot-ordered decode step)."""
+        t0 = time.perf_counter()
+        with _get_recorder().span("fetch.tokens", cat="adapter",
+                                  engine=self.engine_name, rows=b):
+            toks = np.asarray(out["tokens"])
+            tally = out.get("moe_tally")
+            if tally is not None:
+                # a decode step over expert layers: what its routing touched
+                # and its expert path read, counted on the device (five
+                # int32)
+                self._count_moe_tally(np.asarray(tally))
+        self.host_stats["blocking_fetches"] += 1
+        self.host_stats["blocked_s"] += time.perf_counter() - t0
+        toks = toks.reshape(toks.shape[0], -1)
+        return toks[:b] if rows is None else toks[rows]
+
+    def _count_moe_tally(self, tally: np.ndarray):
+        """``[touched, assigned, read, picks, identity picks]`` of one
+        decode step (``moe.share_tally`` + ``moe.zero_tally`` summed over
+        the expert layers) into ``host_stats``; the slots the first three
+        are counted over are held experts x expert layers, once a step, and
+        ``moe_experts_skipped`` is the slots the step did not read (a
+        reader that sums and divides cannot subtract). ``moe_assignments``
+        is every top-k pick of the live rows, ``moe_assignments_zero`` those
+        that fell to identity experts (``MoESpec.zero_experts``)."""
+        spec = self.app.spec
+        slots = spec.moe.num_held * spec.num_moe_layers
+        touched, assigned, read, picks, zero = (int(n) for n in tally)
+        st = self.host_stats
+        for key, n in (("moe_experts_touched", touched),
+                       ("moe_assignments_held", assigned),
+                       ("moe_experts_read", read),
+                       ("moe_experts_skipped", slots - read),
+                       ("moe_expert_slots", slots),
+                       ("moe_assignments", picks),
+                       ("moe_assignments_zero", zero)):
+            st[key] = st.get(key, 0) + n
+        self.telemetry.on_moe_tally(touched, slots, assigned, read, picks,
+                                    zero)
+
+    def _note_gap(self, states: Sequence[_SeqState]):
+        """A decode step's tokens for ``states`` just became host-visible.
+        If one of them was live at the previous such point too, the
+        interval is a gap between tokens that a client saw: count it, by
+        what it waited behind — ``prefill`` if prefill dispatches were
+        issued in between (how many: the chain's length), else ``drain`` if
+        the in-flight step was drained, else ``none``. Always on: one clock
+        read and a few dict adds. A state that left never comes back (a
+        replayed row is a new one), so no gap spans an empty live set."""
+        now = time.perf_counter()
+        st = self.host_stats
+        mark = self._gap_mark
+        self._gap_mark = (now, states, st["prefill_dispatches"],
+                          self._drains)
+        if mark is None or not states:
+            return
+        t_prev, before, prefills, drains = mark
+        if not before or states[0] is not before[0]:
+            # else: the live set as it was, the usual case
+            was = {id(x) for x in before}
+            if not any(id(x) in was for x in states):
+                return
+        gap = now - t_prev
+        chain = st["prefill_dispatches"] - prefills
+        st["decode_gaps"] += 1
+        st["decode_gap_s"] += gap
+        if chain:
+            st["decode_gaps_behind_prefill"] += 1
+            st["decode_gap_s_behind_prefill"] += gap
+            st["prefill_dispatches_in_gaps"] += chain
+        if gap >= 1.0:
+            st["decode_gaps_over_1s"] += 1
+            st["decode_gaps_over_1s_behind_prefill"] += bool(chain)
+        if gap > st["decode_gap_max_s"]:
+            st["decode_gap_max_s"] = gap
+        self.telemetry.on_gap(gap, "prefill" if chain else
+                              "drain" if self._drains > drains else "none")
+
+    # -- public decode surface ---------------------------------------------
     def step(self, seq_ids: Optional[Sequence[int]] = None,
              token_room: Optional[Dict[int, int]] = None):
-        """Non-speculative adapters: one decode step, {seq_id: token}
-        (see the base class). With ``speculation=`` attached the step is
+        """One decode step for ``seq_ids`` (default: every running row),
+        dispatched and fetched: {seq_id: next token} for THIS step. Raises
+        :class:`DeadlineExceeded` / :class:`CapacityError` before any
+        device work when a row is over budget, and :class:`StepFailure`
+        when the device step fails (KV growth rolled back, positions not
+        advanced).
+
+        With ``ragged=True`` every step — speculative or not — is ONE
+        unified mixed dispatch through serving/ragged/ and returns
+        {seq_id: [tokens]}. With ``speculation=`` attached the step is
         draft-and-verify and returns {seq_id: [tokens]} with 1..k+1
         tokens per row; ``token_room`` (scheduler hook) caps each row's
-        tokens-delivered for this step. With ``ragged=True`` every step —
-        speculative or not — is ONE unified mixed dispatch through
-        serving/ragged/ and returns {seq_id: [tokens]}.
+        tokens-delivered for this step.
 
         Degradation (resilience/controller.py): with the ragged path
         SHED the step falls back to two-phase dispatching — through the
@@ -1900,60 +1233,401 @@ class PagedEngineAdapter(_EngineAdapterBase):
                 return self._ragged.step(seq_ids, token_room)
             if self._ragged.spec_path is not None:
                 return self._ragged.spec_path.step(seq_ids, token_room)
-            return super().step(seq_ids)   # 1 token/row: room is honored
+            return self._step_eager(seq_ids)   # 1 token/row: room is honored
         if self._spec is not None:
             return self._spec.step(seq_ids, token_room)
         if token_room is not None:
             raise ConfigurationError(
                 "token_room is a speculative-decode hook; build the "
                 "adapter with speculation= or ragged=True to use it")
-        return super().step(seq_ids)
+        return self._step_eager(seq_ids)
 
     def step_ahead(self, seq_ids: Optional[Sequence[int]] = None):
-        """One step in flight on the plain two-phase decode path (base
-        class); the speculative and ragged paths materialize every step
-        and run as :meth:`step` does."""
-        if self._spec is not None or self._ragged is not None:
+        """:meth:`step` for a caller that can take a step's tokens one call
+        late — the serving engine's loop. The ragged and speculative paths
+        materialize every step and run as :meth:`step` does. On the plain
+        two-phase path, unless the adapter was built with
+        ``pipeline_depth=0``, the call enqueues this step BEFORE it blocks
+        on the previous one and returns the previous step's tokens ({} on
+        the first call after the pipeline empties), so the caller's own
+        work between two calls (scheduling, routing, the yield to the
+        stream writers) runs while the device computes. :meth:`flush`
+        hands back what is still in flight (call it before going back to
+        :meth:`step`); :attr:`lookahead_ids` names the rows that have such
+        a token coming. A device failure of the step in flight surfaces at
+        the NEXT call's fetch as a :class:`StepFailure` with
+        ``retry_safe=False`` (:meth:`_abort_pipeline`)."""
+        if self._ragged is not None or self._spec is not None:
             return self.step(seq_ids)
-        return super().step_ahead(seq_ids)
+        if self.pipeline_depth == 0:
+            return self._step_eager(seq_ids)
+        return self._step_pipelined(seq_ids)
+
+    @property
+    def lookahead_ids(self) -> set:
+        """seq_ids whose next token is sampled, or being sampled by the
+        in-flight step, but not handed to the caller yet."""
+        ids = set(self._ready)
+        rec = self._inflight
+        if rec is not None:
+            ids.update(s for s, st in zip(rec.live, rec.states)
+                       if self.seqs.get(s) is st)
+        return ids
 
     def step_many(self, num_steps: int,
                   seq_ids: Optional[Sequence[int]] = None
                   ) -> Dict[int, List[int]]:
-        """Fused multi-step decode (base class). With ``speculation=``
-        (or ``ragged=True``) attached, ``num_steps`` becomes a per-row
-        TOKEN budget: the path runs unified engine steps — each one
-        materialized dispatch — until every row has delivered its budget
-        (rows with high accept rates finish in fewer dispatches; no row
-        ever overshoots)."""
-        path = self._ragged if self._ragged is not None else self._spec
-        if path is None:
-            return super().step_many(num_steps, seq_ids)
+        """``num_steps`` fused decode steps in ONE device dispatch and ONE
+        blocking host fetch. Returns {seq_id: [tokens]} in stream order;
+        a token still in flight from :meth:`step_ahead` is drained first
+        and prepended (it is simply the preceding token of the same
+        stream). Deadlines and the seq_len guard are enforced once for the
+        whole horizon, before any device work. EOS handling stays with the
+        engine, at horizon boundaries.
+
+        With ``ragged=True`` or ``speculation=`` attached, ``num_steps``
+        becomes a per-row TOKEN budget: the path runs unified engine
+        steps — each one materialized dispatch — until every row has
+        delivered its budget (rows with high accept rates finish in fewer
+        dispatches; no row ever overshoots)."""
         if num_steps < 1:
             raise ConfigurationError("step_many requires num_steps >= 1")
-        out: Dict[int, List[int]] = {}
-        remaining: Dict[int, int] = {}
-        targets = seq_ids                  # validated on the first pass only
-        for _ in range(num_steps):
-            live = _live_rows(self.seqs, targets, self._pending_ids())
-            if seq_ids is not None:
-                # rows preempted mid-loop must not fail later passes
-                targets = [s for s in seq_ids
-                           if s in self.seqs or s in self._chunks]
-            ids = [s for s in live if remaining.get(s, num_steps) > 0]
-            if not ids and not self._pending_ids():
-                break
-            room = {s: remaining.get(s, num_steps) for s in ids}
-            # route through step() so the degradation shed flags apply
-            # here too (a shed plain step returns {seq_id: token})
-            res = self.step(ids, token_room=room)
-            if not res and not ids:
-                break                  # pending-only pass made no tokens
-            for s, toks in res.items():
-                toks = toks if isinstance(toks, list) else [toks]
-                out.setdefault(s, []).extend(toks)
-                remaining[s] = remaining.get(s, num_steps) - len(toks)
+        if self._ragged is not None or self._spec is not None:
+            out: Dict[int, List[int]] = {}
+            remaining: Dict[int, int] = {}
+            targets = seq_ids          # validated on the first pass only
+            for _ in range(num_steps):
+                live = _live_rows(self.seqs, targets, self._pending_ids())
+                if seq_ids is not None:
+                    # rows preempted mid-loop must not fail later passes
+                    targets = [s for s in seq_ids
+                               if s in self.seqs or s in self._chunks]
+                ids = [s for s in live if remaining.get(s, num_steps) > 0]
+                if not ids and not self._pending_ids():
+                    break
+                room = {s: remaining.get(s, num_steps) for s in ids}
+                # route through step() so the degradation shed flags apply
+                # here too (a shed plain step returns {seq_id: token})
+                res = self.step(ids, token_room=room)
+                if not res and not ids:
+                    break          # pending-only pass made no tokens
+                for s, toks in res.items():
+                    toks = toks if isinstance(toks, list) else [toks]
+                    out.setdefault(s, []).extend(toks)
+                    remaining[s] = remaining.get(s, num_steps) - len(toks)
+            return out
+        if self._inflight is not None or self._ready:
+            self._stash_flush()
+        # pending drained tokens stay in self._ready until this call is
+        # past every fallible stage — a recoverable DeadlineExceeded /
+        # CapacityError / StepFailure must not drop them from the stream
+        pending = self._pending_ids()
+        live = _live_rows(self.seqs, seq_ids, pending)
+        if not live and not pending:
+            return {s: [t] for s, t in self._drain_ready().items()}
+        if _FAULTS.active:
+            _FAULTS.fire("slow_step")
+        if live:
+            _pre_step_checks(self.seqs, live, self._pos_limit,
+                             self.telemetry, horizon=num_steps)
+        # at most ONE packed prefill-chunk dispatch per horizon — the
+        # scheduler knob that keeps a long admission from stalling decode
+        self._advance_prefill(seq_ids)
+        if not live:
+            return {s: [t] for s, t in self._drain_ready().items()}
+        t0 = time.perf_counter()
+        live = self._grow_for_step(live, num_steps)
+        if not live:
+            return {s: [t] for s, t in self._drain_ready().items()}
+        toks, pad_to = self._run_many(live, num_steps)
+        res = {s: [t] for s, t in self._drain_ready().items()}
+        for i, s in enumerate(live):
+            st = self.seqs[s]
+            st.position += num_steps
+            row = [int(t) for t in toks[i]]
+            for t in row:
+                self._append_token(st, t)
+            res.setdefault(s, []).extend(row)
+        self.telemetry.on_step(live, t0, padded=pad_to, steps=num_steps)
+        self.telemetry.on_fetch(num_steps)
+        return res
+
+    def flush(self) -> Dict[int, int]:
+        """Retire the in-flight pipelined dispatch (if any) and hand back
+        every token not yet delivered: {seq_id: token}. {} in eager mode.
+        A deferred fetch failure aborts the pipeline (StepFailure,
+        ``retry_safe=False``)."""
+        ready = self._drain_ready()
+        rec, self._inflight = self._inflight, None
+        if rec is not None:
+            self._note_drain()
+            try:
+                ready.update(self._retire_or_abort([rec]))
+            except BaseException:
+                # the drained tokens were already generated and applied to
+                # host state — keep them deliverable past the failure
+                self._ready = {**ready, **self._ready}
+                raise
+        return ready
+
+    # -- eager path --------------------------------------------------------
+    def _step_eager(self, seq_ids) -> Dict[int, int]:
+        pending = self._pending_ids()
+        live = _live_rows(self.seqs, seq_ids, pending)
+        if not live and not pending:
+            return self._drain_ready()
+        if _FAULTS.active:
+            _FAULTS.fire("slow_step")
+        if live:
+            _pre_step_checks(self.seqs, live, self._pos_limit,
+                             self.telemetry)
+        self._advance_prefill(seq_ids)
+        if not live:
+            return self._drain_ready()
+        t0 = time.perf_counter()
+        with _get_recorder().span("dispatch.build", cat="adapter"):
+            live = self._grow_for_step(live)
+            if not live:
+                return self._drain_ready()
+            scr = self._scratch_for(live)
+            scr.fill(self)
+        cache_before = self.app.cache
+        try:
+            if _FAULTS.active:
+                _FAULTS.fire("decode_step")
+            out = self._dispatch_decode(scr)
+            new = self._fetch_rows(out, len(live), scr.rows)
+        except ServingError:
+            self._rollback_step_growth(live)
+            self._scratch = None
+            raise
+        except Exception as e:
+            self._rollback_step_growth(live)
+            self._scratch = None
+            self.telemetry.on_step_failure("decode", self._tenant_of(live))
+            raise _trace_error(StepFailure(
+                "paged decode step failed; KV growth was rolled back; "
+                "positions were not advanced",
+                phase="decode", seq_ids=tuple(live),
+                retry_safe=self.app.cache is cache_before)) from e
+        self._note_gap(tuple(self.seqs[s] for s in live))
+        res = self._drain_ready()    # first tokens of finished prefills
+        for i, s in enumerate(live):
+            st = self.seqs[s]
+            st.position += 1
+            tok = int(new[i, 0])
+            self._append_token(st, tok)
+            res[s] = tok
+        self.telemetry.on_step(live, t0, padded=scr.pad_to)
+        self.telemetry.on_fetch(1)
+        return res
+
+    # -- pipelined path ----------------------------------------------------
+    def _step_pipelined(self, seq_ids) -> Dict[int, int]:
+        pending = self._pending_ids()
+        live = _live_rows(self.seqs, seq_ids, pending)
+        if not live and not pending:
+            return self.flush()
+        if _FAULTS.active:
+            _FAULTS.fire("slow_step")
+        if live:
+            _pre_step_checks(self.seqs, live, self._pos_limit,
+                             self.telemetry)
+        self._advance_prefill(seq_ids)
+        if not live:
+            return self.flush()
+        ready = self._drain_ready()
+        try:
+            return self._advance_pipeline(live, ready)
+        except BaseException:
+            # tokens drained (or retired) this call were already generated
+            # and applied to host state — keep them deliverable past a
+            # recoverable failure instead of dropping them from the stream
+            self._ready = {**ready, **self._ready}
+            raise
+
+    def _advance_pipeline(self, live: List[int],
+                          ready: Dict[int, int]) -> Dict[int, int]:
+        prev, self._inflight = self._inflight, None
+        if prev is not None and not self._matches(prev, live):
+            # live-set changed since the dispatch: drain it synchronously
+            self._note_drain()
+            ready.update(self._retire_or_abort([prev]))
+            prev = None
+        t0 = time.perf_counter()
+        with _get_recorder().span("dispatch.build", cat="adapter"):
+            try:
+                live = self._grow_for_step(live)
+            except ServingError:
+                self._inflight = prev      # growth rolled itself back
+                raise
+            if not live:
+                self._inflight = prev
+                return ready
+            if prev is not None and not self._matches(prev, live):
+                # preemption shrank the batch mid-call: drain the old
+                # composition's dispatch before re-padding for the new one
+                self._note_drain("preempt")
+                ready.update(self._retire_or_abort([prev]))
+                prev = None
+            scr = self._scratch_for(live)
+            scr.fill(self, need_tokens=prev is None)
+            toks_dev = (None if prev is None
+                        else self._feedback_tokens(prev, scr))
+        cache_before = self.app.cache
+        try:
+            if _FAULTS.active:
+                _FAULTS.fire("decode_step")
+            out = self._dispatch_decode(scr, toks_dev)
+        except ServingError:
+            self._rollback_step_growth(live)
+            self._scratch = None
+            self._inflight = prev          # lookahead step is still healthy
+            raise
+        except Exception as e:
+            self._rollback_step_growth(live)
+            self._scratch = None
+            self._inflight = prev
+            self.telemetry.on_step_failure("decode", self._tenant_of(live))
+            raise _trace_error(StepFailure(
+                "paged decode step failed at dispatch; KV growth was rolled "
+                "back; the in-flight lookahead step was preserved",
+                phase="decode", seq_ids=tuple(live),
+                retry_safe=self.app.cache is cache_before)) from e
+        rec = _Inflight(
+            live=tuple(live),
+            states=tuple(self.seqs[s] for s in live),
+            b=len(live), pad_to=scr.pad_to, out=out, t_dispatch=t0,
+            grown=1, rows=scr.rows)
+        for s in live:
+            self.seqs[s].position += 1
+        self._drain_cause = None           # the step in flight is current
+        if prev is not None:
+            self.host_stats["overlapped_dispatches"] += 1
+            self.telemetry.on_overlap()
+            ready.update(self._retire_or_abort([prev, rec]))
+        self._inflight = rec
+        return ready
+
+    def _matches(self, rec: _Inflight, live: Sequence[int]) -> bool:
+        return (rec.live == tuple(live)
+                and all(self.seqs.get(s) is st
+                        for s, st in zip(rec.live, rec.states)))
+
+    def _note_stale(self, cause: str):
+        """Rows left or joined (``cause``) while a step is in flight. The
+        step stays in flight — nothing blocks here; the next decode call
+        sees the changed live set, drains it and counts ``cause``. If none
+        of its rows is left, nobody is owed its tokens: it is dropped
+        unfetched."""
+        rec = self._inflight
+        if rec is None:
+            return
+        if all(self.seqs.get(s) is not st
+               for s, st in zip(rec.live, rec.states)):
+            self._inflight = None
+            self._drain_cause = None
+        elif self._drain_cause is None:
+            self._drain_cause = cause
+
+    def _note_drain(self, default: str = "liveset"):
+        cause, self._drain_cause = self._drain_cause or default, None
+        self._drains += 1
+        self.host_stats[f"pipeline_drains_{cause}"] += 1
+        self.telemetry.on_drain(cause)
+
+    def _feedback_tokens(self, prev: _Inflight, scr):
+        """The previous dispatch's on-device sampled tokens as the next
+        step's input ids — no host round trip. A paged decode step hands
+        them on ready-made (``out["next_ids"]``: the live set is unchanged,
+        so pad rows are clones of row 0 and a slot-ordered step has no pad
+        rows): no program runs between two steps. Otherwise they are
+        re-padded ON DEVICE (pad rows must stay clones of row 0 even under
+        unseeded stochastic sampling)."""
+        nxt = prev.out.get("next_ids")
+        if nxt is not None and (scr.pad_to == scr.b or scr.rows is not None
+                                or self._pads_follow_row0):
+            return nxt
+        toks = prev.out["tokens"].reshape(-1)
+        if scr.pad_to > scr.b:
+            toks = toks[scr.gather_idx]
+        return toks[:, None]
+
+    def _retire(self, rec: _Inflight) -> Dict[int, int]:
+        """Materialize ``rec``'s tokens (the ONE blocking sync of the
+        pipelined path) and apply the deferred host bookkeeping. Raises
+        the raw fetch failure — callers route it through
+        :meth:`_abort_pipeline`."""
+        with _get_recorder().span("dispatch.retire", cat="adapter",
+                                  engine=self.engine_name, rows=rec.b):
+            if _FAULTS.active:
+                _FAULTS.fire("pipeline_flush")
+            new = self._fetch_rows(rec.out, rec.b, rec.rows)
+            self._note_gap(rec.states)
+            res = {}
+            for i, (s, st) in enumerate(zip(rec.live, rec.states)):
+                if self.seqs.get(s) is not st:
+                    continue           # released/preempted while in flight
+                tok = int(new[i, 0])
+                self._append_token(st, tok)
+                res[s] = tok
+            self.telemetry.on_step(list(res), rec.t_dispatch,
+                                   padded=rec.pad_to)
+            self.telemetry.on_fetch(1)
+        return res
+
+    def _retire_or_abort(self, records: List[Optional[_Inflight]]
+                         ) -> Dict[int, int]:
+        try:
+            return self._retire(records[0])
+        except Exception as e:
+            self._abort_pipeline(records, e)
+
+    def _abort_pipeline(self, records: Sequence[Optional[_Inflight]],
+                        cause: Exception):
+        """A deferred fetch failed: the in-flight step's device output (and
+        any dispatch speculatively issued on top of it) is garbage. Unwind
+        every in-flight dispatch's host bookkeeping — positions and paged
+        KV growth return to the last DELIVERED token — and raise a
+        :class:`StepFailure` with ``retry_safe=False`` (the donated device
+        cache was consumed by the failed dispatch chain; re-admit or
+        rebuild)."""
+        self._scratch = None
+        seq_ids: Tuple[int, ...] = ()
+        for rec in records:
+            if rec is None:
+                continue
+            if not seq_ids:
+                seq_ids = rec.live
+            for s, st in zip(rec.live, rec.states):
+                if self.seqs.get(s) is st:
+                    st.position -= 1
+            self._unwind_inflight_growth(rec)
+        self.telemetry.on_step_failure("decode", self._tenant_of(seq_ids))
+        raise _trace_error(StepFailure(
+            "pipelined decode fetch failed; every in-flight lookahead step "
+            "was rolled back to the last delivered token",
+            phase="decode", seq_ids=seq_ids, retry_safe=False)) from cause
+
+    def _unwind_inflight_growth(self, rec: _Inflight):
+        if not rec.grown:
+            return
+        for s, st in zip(rec.live, rec.states):
+            if self.seqs.get(s) is st and s in self.app.kv_mgr.tables:
+                self.app.kv_mgr.shrink(s, rec.grown)
+
+    def _drain_ready(self) -> Dict[int, int]:
+        if not self._ready:
+            return {}
+        out, self._ready = self._ready, {}
         return out
+
+    def _stash_flush(self):
+        """flush() into the pending buffer, so tokens drained by
+        add/release/step_many are handed back by the next returning call
+        instead of being dropped."""
+        for s, t in self.flush().items():
+            self._ready[s] = t
 
     # -- decode dispatch ---------------------------------------------------
     @property
@@ -2021,19 +1695,6 @@ class PagedEngineAdapter(_EngineAdapterBase):
     def _append_token(self, st: _SeqState, tok: int):
         st.last_token = tok
         st.tokens.append(tok)
-
-    def _grow_for_step(self, live: List[int], n: int = 1) -> List[int]:
-        return self._grow_with_preemption(live, n)
-
-    def _rollback_step_growth(self, live: Sequence[int], n: int = 1):
-        self._rollback_grow(live, n)
-
-    def _unwind_inflight_growth(self, rec: _Inflight):
-        if not rec.grown:
-            return
-        for s, st in zip(rec.live, rec.states):
-            if self.seqs.get(s) is st and s in self.app.kv_mgr.tables:
-                self.app.kv_mgr.shrink(s, rec.grown)
 
     def _scratch_for(self, live: Sequence[int]) -> _PagedScratch:
         app = self.app
@@ -2111,7 +1772,7 @@ class PagedEngineAdapter(_EngineAdapterBase):
         app = self.app
         if app.state_slots:
             from ..models.model_base import recurrent_refusal
-            self._rollback_grow(live, num_steps)
+            self._rollback_step_growth(live, num_steps)
             raise ConfigurationError(
                 recurrent_refusal(["fused decode loop"])
                 + " — step_many() is that loop; call step()")
@@ -2159,10 +1820,10 @@ class PagedEngineAdapter(_EngineAdapterBase):
                             steps=num_steps, seq_ids=list(live))
             toks = self._fetch_rows(out, b)
         except ServingError:
-            self._rollback_grow(live, num_steps)
+            self._rollback_step_growth(live, num_steps)
             raise
         except Exception as e:
-            self._rollback_grow(live, num_steps)
+            self._rollback_step_growth(live, num_steps)
             self.telemetry.on_step_failure("decode", self._tenant_of(live))
             raise _trace_error(StepFailure(
                 "fused paged decode loop failed; KV growth was rolled back "
@@ -2190,12 +1851,27 @@ class PagedEngineAdapter(_EngineAdapterBase):
         (running + pending rows count against the compiled batch)."""
         return self.batch - len(self.seqs) - len(self._chunks)
 
+    # -- post-mortem snapshot ----------------------------------------------
     def debug_state(self) -> Dict[str, Any]:
-        """Base snapshot plus the paged-only view: pending chunked
+        """Read-only host-side snapshot for post-mortems (surfaced through
+        :meth:`~..engine.scheduler.ServingEngine.dump_debug_state` and the
+        ``GET /v1/debug/state`` endpoint). JSON-able; never touches device
+        state. Running rows and the step in flight, then pending chunked
         admissions with prefill progress, batch headroom, block-pool
         occupancy (incl. unwritten-block tracking) and uncollected
         preemption records."""
-        state = super().debug_state()
+        state = {
+            "engine": self.engine_name,
+            "running_ids": [int(s) for s in sorted(self.seqs)],
+            "positions": {int(s): int(st.position)
+                          for s, st in self.seqs.items()},
+            "tenants": {int(s): _meta_tenant(st.meta)
+                        for s, st in self.seqs.items()},
+            "pipeline_inflight": (0 if self._inflight is None
+                                  else len(self._inflight.live)),
+            "ready_undelivered": [int(s) for s in sorted(self._ready)],
+            "host_stats": dict(self.host_stats),
+        }
         mgr = self.app.kv_mgr
         usable = mgr.spec.num_blocks - 1          # block 0 is the null block
         free = int(mgr.allocator.num_free)
@@ -2219,7 +1895,6 @@ class PagedEngineAdapter(_EngineAdapterBase):
                 "pool": self._lora_pool.debug_state(),
             }
         return state
-
     def prefix_warmth(self, prompt: Sequence[int],
                       adapter: Optional[str] = None) -> int:
         """READ-ONLY probe: how many leading tokens of ``prompt`` an
@@ -2490,7 +2165,7 @@ class PagedEngineAdapter(_EngineAdapterBase):
                            reason=reason, tenant=tenant, pending=pending,
                            trace=trace)
 
-    def _grow_with_preemption(self, live: Sequence[int],
+    def _grow_for_step(self, live: Sequence[int],
                               n: int = 1) -> List[int]:
         """Grow every live row's block list by ``n`` tokens, evicting
         victims per the policy when the pool is dry. Returns the rows
@@ -2520,7 +2195,7 @@ class PagedEngineAdapter(_EngineAdapterBase):
             grown.append(s)
         return live
 
-    def _rollback_grow(self, live: Sequence[int], n: int = 1):
+    def _rollback_step_growth(self, live: Sequence[int], n: int = 1):
         for s in live:
             self.app.kv_mgr.shrink(s, n)
 
@@ -2620,13 +2295,19 @@ class PagedEngineAdapter(_EngineAdapterBase):
 
     # -- chunked, packed, schedulable prefill ------------------------------
     def _pending_ids(self):
+        """seq_ids admitted but still mid-prefill (chunked admissions)."""
         return self._chunks.keys()
 
     def _advance_prefill(self, seq_ids=None):
+        """Run at most one packed prefill-chunk dispatch for pending
+        admissions; finished sequences' first tokens land in ``_ready``.
+        ``seq_ids`` is the step call's explicit target set (None = all):
+        an expired pending admission outside it is skipped, not raised —
+        a healthy row must not be stalled by an unrelated request's
+        budget."""
         if self._chunks:
             self._prefill_step(budget=self.prefill_budget_tokens,
                                target=seq_ids)
-
     def _prefill_step(self, budget: Optional[int] = None, only=None,
                       target=None, defer_telemetry: bool = False):
         """ONE packed chunk dispatch: pending sequences (admission order)

@@ -11,7 +11,7 @@ from __future__ import annotations
 from .registry import DEFAULT_LATENCY_BUCKETS
 
 # -- serving adapters (serving.py) -----------------------------------------
-# engine label: "cb" (ContinuousBatchingAdapter) | "paged" (PagedEngineAdapter)
+# engine label: "paged" (PagedEngineAdapter)
 REQUEST_TTFT_SECONDS = "nxdi_request_ttft_seconds"
 DECODE_STEP_SECONDS = "nxdi_decode_step_seconds"      # dispatch -> retire
 DECODE_GAP_SECONDS = "nxdi_decode_gap_seconds"        # engine, behind

@@ -10,7 +10,6 @@ from neuronx_distributed_inference_tpu.config import (
 
 def test_defaults_derive():
     c = TpuConfig(batch_size=2, seq_len=256)
-    assert c.max_batch_size == 2
     assert c.ctx_batch_size == 2
     assert c.tkg_batch_size == 2
     assert c.kv_cache_batch_size == 2
@@ -38,7 +37,7 @@ def test_json_round_trip(tmp_path):
                   on_device_sampling_config=OnDeviceSamplingConfig(
                       do_sample=True, top_k=50),
                   speculation_config=SpeculationConfig(
-                      speculation_length=5, enable_fused_speculation=True))
+                      speculation_length=5))
     cfg = InferenceConfig(c, hidden_size=64, num_attention_heads=4,
                           vocab_size=512)
     p = tmp_path / "cfg.json"
@@ -58,26 +57,33 @@ def test_unknown_keys_warn_not_raise():
 
 def test_dead_knobs_raise_or_work():
     """Every accepted knob changes behavior or errors (reference parity
-    audit): pp_degree raises (no inference pipeline schedule),
-    vocab_parallel switches the embed sharding (next test)."""
-    with pytest.raises(ValueError, match="pp_degree"):
+    audit): pp_degree is refused (there is no inference pipeline schedule,
+    so there is no such field: the constructor's own refusal of an argument
+    it does not know), vocab_parallel switches the embed sharding (next
+    test)."""
+    with pytest.raises(TypeError, match="pp_degree"):
         TpuConfig(pp_degree=2, tp_degree=2)
 
 
-# Keys PR 31's ``to_dict`` wrote that this tree has no field for (ROADMAP
-# C4): thirteen nothing read, fifteen more the guard below found.
+# Keys a parent's ``to_dict`` wrote that this tree has no field for (ROADMAP
+# C4): of PR 31's, thirteen nothing read and fifteen more the guard below
+# found; of PR 45's, the eleven only config.py consumed or only tests set.
 _RETIRED = {
     None: "n_active_tokens mlp_cp_degree start_rank_id local_ranks_size "
           "kv_cache_padding_size bucket_n_active_tokens qkv_kernel_enabled "
           "mlp_kernel_enabled attn_block_tkg_nki_kernel_enabled async_mode "
           "rpl_reduce_dtype cast_type skip_sharding rope_dtype "
-          "quantized_checkpoints_path",
+          "quantized_checkpoints_path "
+          "max_batch_size logits_dtype pp_degree world_size",
     "on_device_sampling_config": "on_device dynamic",
     "chunked_prefill_config": "max_num_seqs kernel_kv_tile_size",
     "moe_config": "capacity_factor glu_mlp glu_type fused_shared_experts "
-                  "early_expert_affinity_modulation",
+                  "early_expert_affinity_modulation "
+                  "normalize_top_k_affinities moe_tp_degree moe_ep_degree",
     "lora_config": "lora_dtype",
-    "speculation_config": "spec_batch_size is_eagle_draft draft_model_module"}
+    "speculation_config": "spec_batch_size is_eagle_draft draft_model_module "
+                          "enable_fused_speculation enable_eagle_speculation "
+                          "enable_eagle_draft_input_norm num_medusa_heads"}
 
 
 def test_a_config_saved_by_the_parent_still_loads():
@@ -104,18 +110,6 @@ def test_a_config_saved_by_the_parent_still_loads():
     assert loaded == c and loaded.to_dict() == mine
 
 
-# Unread by the guard's rule and left by PR 32 (ROADMAP C4 says why each):
-# config.py alone consumes them, or only tests set them. May only shrink.
-_UNREAD_DEBT = {
-    "TpuConfig.max_batch_size", "TpuConfig.logits_dtype",
-    "TpuConfig.pp_degree", "TpuConfig.world_size",
-    "MoEConfig.normalize_top_k_affinities", "MoEConfig.moe_tp_degree",
-    "MoEConfig.moe_ep_degree", "SpeculationConfig.enable_fused_speculation",
-    "SpeculationConfig.enable_eagle_speculation",
-    "SpeculationConfig.enable_eagle_draft_input_norm",
-    "SpeculationConfig.num_medusa_heads"}
-
-
 def test_every_config_field_has_a_reader():
     """An option a user can set to no effect is not an option: some module
     of the package other than config.py reads every field of TpuConfig and
@@ -136,9 +130,7 @@ def test_every_config_field_has_a_reader():
     unread = {f"{cls.__name__}.{f.name}"
               for cls in (TpuConfig, *config_mod._SUBCONFIG_TYPES.values())
               for f in dataclasses.fields(cls) if f.name not in read}
-    assert unread == _UNREAD_DEBT, (
-        f"no module reads {sorted(unread - _UNREAD_DEBT)}; "
-        f"no longer unread: {sorted(_UNREAD_DEBT - unread)}")
+    assert unread == set(), f"no module reads {sorted(unread)}"
 
 
 def test_vocab_parallel_controls_embed_sharding():

@@ -1,18 +1,18 @@
 """Decode pipeline (ISSUE 3): device-resident token feedback
-(``pipeline_depth=1``), fused multi-step ``step_many(k)``, incremental
-host bookkeeping, and the lookahead-aware failure contract.
+(``step_ahead()``), fused multi-step ``step_many(k)``, incremental host
+bookkeeping, and the lookahead-aware failure contract.
 
 Acceptance pins:
   (a) ``step_many(k)`` token streams are bit-identical to k eager
-      ``step()`` calls, on both adapters;
-  (b) ``pipeline_depth=1`` streams are bit-identical to
-      ``pipeline_depth=0`` (tokens arrive one call later; ``flush()``
-      drains the last);
+      ``step()`` calls;
+  (b) ``step_ahead()`` streams are bit-identical to ``step()`` streams
+      (tokens arrive one call later; ``flush()`` drains the last);
   (c) a lookahead ``StepFailure`` (``pipeline_flush`` fault) rolls
       positions and paged KV growth back to the last DELIVERED token with
       ``retry_safe=False``; a dispatch-time fault preserves the healthy
-      in-flight step with ``retry_safe=True``;
-  (d) deadline and preemption paths still work under ``pipeline_depth=1``.
+      in-flight step with ``retry_safe=True``, whichever scratch kind lays
+      the step's rows out (``serving_stacks``);
+  (d) deadline and preemption paths still work with a step in flight.
 
 Everything compares pipelined/fused runs against eager runs of the SAME
 app (greedy sampling — no separate golden model), so the module costs a
@@ -27,14 +27,14 @@ import numpy as np
 import pytest
 
 from neuronx_distributed_inference_tpu.config import TpuConfig
-from neuronx_distributed_inference_tpu.models.application import (
-    CausalLMApplication, PagedCausalLMApplication)
+from neuronx_distributed_inference_tpu.models.application import \
+    PagedCausalLMApplication
 from neuronx_distributed_inference_tpu.models.llama import (
     LlamaFamily, LlamaInferenceConfig)
 from neuronx_distributed_inference_tpu.resilience import (
     CapacityError, ConfigurationError, DeadlineExceeded, FAULTS, StepFailure)
-from neuronx_distributed_inference_tpu.serving import (
-    ContinuousBatchingAdapter, PagedEngineAdapter)
+from neuronx_distributed_inference_tpu.serving import PagedEngineAdapter
+from serving_stacks import stack_app  # noqa: F401  (a fixture)
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -47,17 +47,9 @@ HF = dict(model_type="llama", hidden_size=64, intermediate_size=128,
 RNG = np.random.default_rng(0)
 P1 = RNG.integers(1, 500, size=9).tolist()
 P2 = RNG.integers(1, 500, size=12).tolist()
-
-
-@pytest.fixture(scope="module")
-def cb_app():
-    tcfg = TpuConfig(batch_size=2, seq_len=64, dtype="float32",
-                     enable_bucketing=True, context_encoding_buckets=[16],
-                     is_continuous_batching=True)
-    app = CausalLMApplication(None, LlamaInferenceConfig(tcfg, **HF),
-                              LlamaFamily)
-    app.init_random_weights(7).init_cache()
-    return app
+#: inside every toy stack's vocabulary (128)
+Q1 = RNG.integers(1, 128, size=9).tolist()
+Q2 = RNG.integers(1, 128, size=12).tolist()
 
 
 @pytest.fixture(scope="module")
@@ -71,10 +63,11 @@ def paged_app():
     return app
 
 
-def _eager_streams(make_eng, n_steps):
-    """{seq_id: [prefill + n_steps tokens]} from a fresh eager adapter."""
-    eng = make_eng(0)
-    res = eng.add_requests([0, 1], [P1, P2])
+def _eager_streams(app, n_steps, prompts=(P1, P2)):
+    """{seq_id: [prefill + n_steps tokens]} from a fresh adapter's
+    ``step()``."""
+    eng = PagedEngineAdapter(app)
+    res = eng.add_requests([0, 1], list(prompts))
     out = {0: [res[0]], 1: [res[1]]}
     for _ in range(n_steps):
         for s, t in eng.step().items():
@@ -87,9 +80,9 @@ def _eager_streams(make_eng, n_steps):
 # bit-identity: step_many(k) == k eager steps — acceptance (a)
 # ---------------------------------------------------------------------------
 
-def _check_step_many(make_eng):
-    ref = _eager_streams(make_eng, 6)
-    eng = make_eng(0)
+def test_paged_step_many_matches_eager(paged_app):
+    ref = _eager_streams(paged_app, 6)
+    eng = PagedEngineAdapter(paged_app)
     res = eng.add_requests([0, 1], [P1, P2])
     got = {0: [res[0]], 1: [res[1]]}
     for _ in range(2):
@@ -101,33 +94,24 @@ def _check_step_many(make_eng):
     assert eng.host_stats["dispatches"] == 2
     assert eng.host_stats["blocking_fetches"] == 2
     assert eng.host_stats["device_steps"] == 6
-
-
-def test_cb_step_many_matches_eager(cb_app):
-    _check_step_many(lambda d: ContinuousBatchingAdapter(
-        cb_app, pipeline_depth=d))
-
-
-def test_paged_step_many_matches_eager(paged_app):
-    _check_step_many(lambda d: PagedEngineAdapter(
-        paged_app, pipeline_depth=d))
+    assert eng.flush() == {}                # nothing was ever in flight
 
 
 # ---------------------------------------------------------------------------
-# bit-identity: pipeline_depth=1 == pipeline_depth=0 — acceptance (b)
+# bit-identity: step_ahead() == step() — acceptance (b)
 # ---------------------------------------------------------------------------
 
-def _check_pipelined(make_eng):
-    ref = _eager_streams(make_eng, 6)
-    eng = make_eng(1)
+def test_paged_pipelined_matches_eager(paged_app):
+    ref = _eager_streams(paged_app, 6)
+    eng = PagedEngineAdapter(paged_app)
     res = eng.add_requests([0, 1], [P1, P2])
     got = {0: [res[0]], 1: [res[1]]}
-    assert eng.step() == {}                 # pipeline filling: one behind
+    assert eng.step_ahead() == {}           # pipeline filling: one behind
     for _ in range(4):
-        for s, t in eng.step().items():
+        for s, t in eng.step_ahead().items():
             got[s].append(t)
     # live-set change drains the in-flight both-row dispatch synchronously
-    for s, t in eng.step([0]).items():
+    for s, t in eng.step_ahead([0]).items():
         got[s].append(t)
     for s, t in eng.flush().items():
         got[s].append(t)
@@ -136,21 +120,20 @@ def _check_pipelined(make_eng):
     assert eng._inflight is None
 
 
-def test_cb_pipelined_matches_eager(cb_app):
-    _check_pipelined(lambda d: ContinuousBatchingAdapter(
-        cb_app, pipeline_depth=d))
-
-
-def test_paged_pipelined_matches_eager(paged_app):
-    _check_pipelined(lambda d: PagedEngineAdapter(
-        paged_app, pipeline_depth=d))
-
-
-def test_pipeline_depth_validated(cb_app):
+def test_pipeline_depth_validated(paged_app):
     with pytest.raises(ConfigurationError, match="pipeline_depth"):
-        ContinuousBatchingAdapter(cb_app, pipeline_depth=2)
+        PagedEngineAdapter(paged_app, pipeline_depth=2)
+    # step() means one thing: the value that made it return the PREVIOUS
+    # step's tokens is refused, and the refusal names the call that does
+    with pytest.raises(ConfigurationError, match=r"step_ahead\(\)"):
+        PagedEngineAdapter(paged_app, pipeline_depth=1)
     with pytest.raises(ConfigurationError, match="num_steps"):
-        ContinuousBatchingAdapter(cb_app).step_many(0)
+        PagedEngineAdapter(paged_app).step_many(0)
+    # 0 is the eager reference under the engine: step_ahead() is step()
+    eng = PagedEngineAdapter(paged_app, pipeline_depth=0)
+    eng.add_requests([0], [P1])
+    assert set(eng.step_ahead()) == {0} and eng._inflight is None
+    eng.release([0])
 
 
 # ---------------------------------------------------------------------------
@@ -162,13 +145,13 @@ def test_lookahead_fetch_failure_rolls_back_to_delivered(paged_app):
     seen at step N+1) unwinds BOTH in-flight dispatches — positions and KV
     growth return to the last token the engine actually received — and is
     not retry-safe (the donated cache chain was consumed)."""
-    eng = PagedEngineAdapter(paged_app, pipeline_depth=1)
+    eng = PagedEngineAdapter(paged_app)
     eng.add_requests([0], [P1])
     free_admitted = paged_app.kv_mgr.allocator.num_free
-    assert eng.step() == {}                  # dispatch 1 in flight
+    assert eng.step_ahead() == {}            # dispatch 1 in flight
     with FAULTS.inject("pipeline_flush"):
         with pytest.raises(StepFailure) as ei:
-            eng.step()                       # dispatch 2, then fetch 1 fails
+            eng.step_ahead()                 # dispatch 2, then fetch 1 fails
     assert ei.value.retry_safe is False
     assert ei.value.phase == "decode"
     assert eng.seqs[0].position == len(P1)   # last delivered = prefill token
@@ -179,23 +162,24 @@ def test_lookahead_fetch_failure_rolls_back_to_delivered(paged_app):
     assert paged_app.kv_mgr.tables == {}
 
 
-def test_dispatch_fault_preserves_lookahead_and_stream(cb_app):
+def test_dispatch_fault_preserves_lookahead_and_stream(stack_app):
     """A fault at dispatch time (decode_step point) must NOT poison the
     healthy in-flight step: StepFailure is retry-safe, and retrying
     delivers the exact eager stream."""
-    ref = _eager_streams(
-        lambda d: ContinuousBatchingAdapter(cb_app, pipeline_depth=d), 3)
-    eng = ContinuousBatchingAdapter(cb_app, pipeline_depth=1)
-    res = eng.add_requests([0, 1], [P1, P2])
+    ref = _eager_streams(stack_app, 3, (Q1, Q2))
+    eng = PagedEngineAdapter(stack_app)
+    res = eng.add_requests([0, 1], [Q1, Q2])
     got = {0: [res[0]], 1: [res[1]]}
-    assert eng.step() == {}
+    assert eng.step_ahead() == {}
+    kv_before = dict(stack_app.kv_mgr.lens)
     with FAULTS.inject("decode_step"):
         with pytest.raises(StepFailure) as ei:
-            eng.step()
+            eng.step_ahead()
     assert ei.value.retry_safe is True
     assert eng._inflight is not None         # lookahead step preserved
+    assert dict(stack_app.kv_mgr.lens) == kv_before   # growth rolled back
     for _ in range(2):                       # retry: stream is unharmed
-        for s, t in eng.step().items():
+        for s, t in eng.step_ahead().items():
             got[s].append(t)
     for s, t in eng.flush().items():
         got[s].append(t)
@@ -208,12 +192,12 @@ def test_dispatch_fault_preserves_lookahead_and_stream(cb_app):
 def test_pipelined_deadline_leaves_pipeline_intact(paged_app):
     """DeadlineExceeded fires BEFORE the pipeline is touched; releasing
     the expired row drains the in-flight step and drops its token."""
-    eng = PagedEngineAdapter(paged_app, pipeline_depth=1)
+    eng = PagedEngineAdapter(paged_app)
     eng.add_requests([0], [P1], deadline_s=0.25)
-    assert eng.step() == {}                  # in flight
+    assert eng.step_ahead() == {}            # in flight
     with FAULTS.inject("slow_step", delay_s=0.3):
         with pytest.raises(DeadlineExceeded):
-            eng.step()
+            eng.step_ahead()
     assert eng._inflight is not None         # untouched by the deadline
     eng.release([0])                         # drains + drops the token
     assert eng._inflight is None and eng._ready == {}
@@ -235,15 +219,14 @@ def test_pipelined_preemption_replays_bit_identical(paged_app):
     ref0 = eager(P1, 0, 6)
     ref1 = eager(P2, 1, 6)
 
-    eng = PagedEngineAdapter(paged_app, pipeline_depth=1,
-                             preemption_policy="lifo")
+    eng = PagedEngineAdapter(paged_app, preemption_policy="lifo")
     got0 = [eng.add_requests([0], [P1])[0]]
-    assert eng.step() == {}                          # d1: row 0 only
+    assert eng.step_ahead() == {}                    # d1: row 0 only
     got1 = [eng.add_requests([1], [P2])[1]]
     # live set changed: this call drains d1 and dispatches both rows
-    got0.append(eng.step()[0])
+    got0.append(eng.step_ahead()[0])
     with FAULTS.inject("paged_alloc") as fp:         # next grow runs dry
-        res = eng.step()                             # preempts row 1 (LIFO)
+        res = eng.step_ahead()                       # preempts row 1 (LIFO)
     assert fp.trips == 1
     got0.extend(t for s, t in res.items() if s == 0)
     got1.extend(t for s, t in res.items() if s == 1)
@@ -254,7 +237,7 @@ def test_pipelined_preemption_replays_bit_identical(paged_app):
     # prompt + delivered tokens, and the replay regenerates the rest
     assert list(recs[0].tokens) == P2 + got1
     while len(got0) < 6:
-        r = eng.step()
+        r = eng.step_ahead()
         if 0 in r:
             got0.append(r[0])
     got0.extend(eng.flush().values())
@@ -263,7 +246,7 @@ def test_pipelined_preemption_replays_bit_identical(paged_app):
     got1b = [eng.add_requests([1], [list(recs[0].tokens)])[1]]
     replay = list(recs[0].tokens[len(P2):]) + got1b
     while len(replay) < 6:
-        r = eng.step([1])
+        r = eng.step_ahead([1])
         if 1 in r:
             replay.append(r[1])
     replay.extend(eng.flush().values())
@@ -316,46 +299,31 @@ def test_pipelined_deadline_keeps_drained_token(paged_app):
     """A recoverable DeadlineExceeded between drain and dispatch must not
     drop an already-generated token from the stream (review regression
     pin): the token stays pending and the next call delivers it."""
-    eng = PagedEngineAdapter(paged_app, pipeline_depth=1)
-    ref = _eager_streams(lambda d: PagedEngineAdapter(
-        paged_app, pipeline_depth=d), 2)
+    eng = PagedEngineAdapter(paged_app)
+    ref = _eager_streams(paged_app, 2)
     eng.add_requests([0, 1], [P1, P2])
-    assert eng.step() == {}                  # both-row dispatch in flight
+    assert eng.step_ahead() == {}            # both-row dispatch in flight
     eng.release([1])                         # drains; row 0's token pends
     eng.seqs[0].deadline = 0.0               # expire row 0
     with pytest.raises(DeadlineExceeded):
-        eng.step([0])
+        eng.step_ahead([0])
     eng.seqs[0].deadline = None              # budget raised: call again
     eng.seqs[0].expired_reported = False
-    got = eng.step([0])
+    got = eng.step_ahead([0])
     assert got[0] == ref[0][1]               # the drained token, delivered
     eng.release([0])
 
 
-def test_step_many_horizon_guard(cb_app):
-    eng = ContinuousBatchingAdapter(cb_app)
+def test_step_many_horizon_guard(paged_app):
+    eng = PagedEngineAdapter(paged_app)
     eng.add_requests([0], [P1])              # position 9 on a seq_len-64 app
+    free = paged_app.kv_mgr.allocator.num_free
     with pytest.raises(CapacityError, match="horizon") as ei:
         eng.step_many(60)                    # 9 + 60 > 64: pre-dispatch
     assert ei.value.seq_ids == (0,)
     assert eng.seqs[0].position == len(P1)   # nothing ran
+    assert paged_app.kv_mgr.allocator.num_free == free   # nothing grew
     eng.release([0])
-
-
-def test_free_slots_incremental(cb_app):
-    eng = ContinuousBatchingAdapter(cb_app)
-    assert eng.free_slots == [0, 1]
-    eng.add_requests([1], [P1])
-    assert eng.free_slots == [0]
-    eng.add_requests([0], [P2])
-    assert eng.free_slots == []
-    eng.release([1])
-    assert eng.free_slots == [1]
-    eng.release([1])                         # idempotent
-    assert eng.free_slots == [1]
-    eng.release([0])
-    assert eng.free_slots == [0, 1]
-    assert eng.flush() == {}                 # eager flush is a no-op
 
 
 def test_host_sync_lint(tmp_path):

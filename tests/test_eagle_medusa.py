@@ -52,9 +52,7 @@ def test_eagle_matches_plain_greedy(rng):
     prompts = rng.integers(1, 500, size=(2, 10)).astype(np.int32)
     golden = _plain_greedy(prompts, 16)
 
-    spec_cfg = SpeculationConfig(speculation_length=3,
-                                 enable_fused_speculation=True,
-                                 enable_eagle_speculation=True)
+    spec_cfg = SpeculationConfig(speculation_length=3)
     target = _target_app(spec_cfg=spec_cfg, output_full_hidden=True)
     # tiny 2-layer EAGLE draft sharing the target's architecture family
     draft_spec = model_base.spec_from_config(
@@ -75,10 +73,7 @@ def test_eagle_matches_plain_greedy(rng):
 def test_eagle_draft_input_norm_variant(rng):
     prompts = rng.integers(1, 500, size=(2, 8)).astype(np.int32)
     golden = _plain_greedy(prompts, 8)
-    spec_cfg = SpeculationConfig(speculation_length=2,
-                                 enable_fused_speculation=True,
-                                 enable_eagle_speculation=True,
-                                 enable_eagle_draft_input_norm=True)
+    spec_cfg = SpeculationConfig(speculation_length=2)
     target = _target_app(spec_cfg=spec_cfg, output_full_hidden=True)
     draft_spec = model_base.spec_from_config(target.config, tp_degree=1,
                                              num_layers=1)
@@ -97,8 +92,7 @@ def test_eagle_draft_input_norm_variant(rng):
 def test_medusa_matches_plain_greedy(rng):
     prompts = rng.integers(1, 500, size=(2, 10)).astype(np.int32)
     golden = _plain_greedy(prompts, 16)
-    spec_cfg = SpeculationConfig(medusa_speculation_length=4,
-                                 num_medusa_heads=3)
+    spec_cfg = SpeculationConfig(medusa_speculation_length=4)
     target = _target_app(spec_cfg=spec_cfg, medusa_heads=3)
     dec = speculation.MedusaDecoder(target)
     out = dec.generate(prompts, max_new_tokens=16)
@@ -148,7 +142,6 @@ def test_medusa_tree_matches_plain_greedy(rng):
     prompts = rng.integers(1, 500, size=(2, 10)).astype(np.int32)
     golden = _plain_greedy(prompts, 16)
     spec_cfg = SpeculationConfig(medusa_speculation_length=4,
-                                 num_medusa_heads=3,
                                  token_tree_config={"paths": DEFAULT_TREE})
     target = _target_app(spec_cfg=spec_cfg, medusa_heads=3)
     dec = speculation.MedusaTreeDecoder(target)
@@ -168,8 +161,7 @@ def test_dynamic_tree_matches_plain_greedy(rng):
     assert anc[4, 1] and not anc[4, 2]     # node 4 = child of node 1
     prompts = rng.integers(1, 500, size=(2, 10)).astype(np.int32)
     golden = _plain_greedy(prompts, 16)
-    spec_cfg = SpeculationConfig(medusa_speculation_length=4,
-                                 num_medusa_heads=3)
+    spec_cfg = SpeculationConfig(medusa_speculation_length=4)
     target = _target_app(spec_cfg=spec_cfg, medusa_heads=3)
     dec = DynamicTreeDecoder(target, branch_k=3, num_nodes=10)
     out = dec.generate(prompts, max_new_tokens=16)
@@ -215,9 +207,7 @@ def test_eagle_tree_matches_plain_greedy(rng):
     (reference: EAGLE token-tree, model_base.py:2094-2515)."""
     prompts = rng.integers(1, 500, size=(2, 10)).astype(np.int32)
     golden = _plain_greedy(prompts, 16)
-    spec_cfg = SpeculationConfig(speculation_length=3,
-                                 enable_fused_speculation=True,
-                                 enable_eagle_speculation=True)
+    spec_cfg = SpeculationConfig(speculation_length=3)
     target = _target_app(spec_cfg=spec_cfg, output_full_hidden=True)
     draft_spec, draft_params, draft_cache = _eagle_draft(target)
     dec = speculation.EagleTreeDecoder(
@@ -233,9 +223,7 @@ def test_eagle_tree_accepts_at_least_chain(rng):
     feature), the dynamic tree's top-k alternatives can only add acceptance
     opportunities over the chain draft's single greedy path."""
     prompts = rng.integers(1, 500, size=(2, 10)).astype(np.int32)
-    spec_cfg = SpeculationConfig(speculation_length=3,
-                                 enable_fused_speculation=True,
-                                 enable_eagle_speculation=True)
+    spec_cfg = SpeculationConfig(speculation_length=3)
 
     def informative_draft(target):
         # draft = full target stack; fc routes the token embedding straight

@@ -247,8 +247,9 @@ def test_replica_failure_requeue_bit_identity(apps, ref_app):
     onto the survivor riding Preempted.admission_kwargs(), and the
     stitched fleet stream is STILL bit-identical to the golden."""
     app_a, app_b = apps
-    # pipelined adapter on A so the deferred-fetch fault point exists
-    eng_a = ServingEngine(PagedEngineAdapter(app_a, pipeline_depth=1),
+    # the engine keeps a step in flight on A (step_ahead), so the
+    # deferred-fetch fault point exists
+    eng_a = ServingEngine(PagedEngineAdapter(app_a),
                           starvation_bound_s=1e9)
     eng_b = ServingEngine(PagedEngineAdapter(app_b), starvation_bound_s=1e9)
     router = EngineRouter({"A": eng_a, "B": eng_b})

@@ -150,15 +150,14 @@ def test_mixed_per_layer_kv_cache_halves_bytes(tmp_path):
 
 
 def test_mixed_kv_continuous_batching_serving(tmp_path):
-    """gpt-oss is a SERVING model: the mixed per-layer cache must work
-    under the continuous-batching adapter — interleaved requests on a
-    mixed cache reproduce each request's uniform-cache greedy tokens,
-    with the KV bytes still ~halved (reference:
+    """gpt-oss is a SERVING model: the mixed per-layer cache must address
+    its rows BY seq_id — interleaved requests on rows 2 and 0 of a mixed
+    cache, driven through the application's seq_ids-addressed prefill and
+    decode (repeat-row-0 batch pad), reproduce each request's uniform-cache
+    greedy tokens, with the KV bytes still ~halved (reference:
     modules/kvcache/gpt_oss_kv_cache_manager.py serving the vLLM path)."""
     import dataclasses
     import jax
-    from neuronx_distributed_inference_tpu.serving import \
-        ContinuousBatchingAdapter
 
     d, _ = _save_tiny_gpt_oss(tmp_path)
 
@@ -177,25 +176,41 @@ def test_mixed_kv_continuous_batching_serving(tmp_path):
     p1 = rng.integers(1, 250, size=9).tolist()
     p2 = rng.integers(1, 250, size=12).tolist()
 
+    def pad(x):
+        x = np.asarray(x, np.int32)
+        return np.concatenate([x, np.repeat(x[:1], 4 - len(x), axis=0)])
+
     def run(app):
-        eng = ContinuousBatchingAdapter(app)
-        got = {}
-        first = eng.add_requests([2], [p1])
-        toks1 = [first[2]]
+        state = {}                    # cache row -> [position, last token]
+
+        def add(row, prompt):
+            ids = np.zeros((1, 16), np.int32)
+            ids[0, :len(prompt)] = prompt
+            out = app._run_prefill(pad(ids), pad([len(prompt)]),
+                                   seq_ids=pad([row]))
+            state[row] = [len(prompt), int(np.asarray(out["tokens"])[0])]
+            return state[row][1]
+
+        def step(rows):
+            out = app._run_decode(pad([[state[r][1]] for r in rows]),
+                                  pad([[state[r][0]] for r in rows]),
+                                  seq_ids=pad(rows))
+            toks = np.asarray(out["tokens"]).reshape(4, -1)
+            for i, r in enumerate(rows):
+                state[r] = [state[r][0] + 1, int(toks[i, 0])]
+            return {r: state[r][1] for r in rows}
+
+        toks1 = [add(2, p1)]                       # row 2, alone
         for _ in range(3):
-            toks1.append(eng.step()[2])
-        first2 = eng.add_requests([0], [p2])
-        toks2 = [first2[0]]
+            toks1.append(step([2])[2])
+        toks2 = [add(0, p2)]                       # joins on row 0
         for _ in range(4):
-            s = eng.step()
-            toks1.append(s.get(2))
-            toks2.append(s.get(0))
-        eng.release([2])
+            s = step([0, 2])                       # rows out of cache order
+            toks1.append(s[2])
+            toks2.append(s[0])
         for _ in range(3):
-            toks2.append(eng.step()[0])
-        got[1] = [t for t in toks1 if t is not None][:8]
-        got[2] = toks2[:8]
-        return got
+            toks2.append(step([0])[0])
+        return {1: toks1[:8], 2: toks2[:8]}
 
     a_mix = app_for(True)
     assert a_mix.spec.mixed_kv and "k_l" in a_mix.cache

@@ -65,7 +65,7 @@ def hf_dir(tmp_path_factory):
 
 def _build_app(hf_dir, **tcfg_over):
     base = dict(batch_size=2, seq_len=64, dtype="float32",
-                logits_dtype="float32", output_logits=True,
+                output_logits=True,
                 enable_bucketing=False)
     base.update(tcfg_over)
     tcfg = TpuConfig(**base)
@@ -144,7 +144,7 @@ def test_llama4_vision_golden(tmp_path):
                                 max_new_tokens=6, do_sample=False).numpy()
 
     tcfg = TpuConfig(batch_size=1, seq_len=64, dtype="float32",
-                     logits_dtype="float32", output_logits=True,
+                     output_logits=True,
                      enable_bucketing=False)
     icfg = ImageToTextInferenceConfig(tcfg, load_config=load_pretrained_config(d))
     app = Llama4VLApplication(d, icfg).load_weights()

@@ -30,7 +30,7 @@ def hf_model_dir(tmp_path_factory):
 
 def _build_app(hf_model_dir, tp=1, **cfg_over):
     base = dict(batch_size=2, seq_len=64, dtype="float32",
-                logits_dtype="float32", output_logits=True,
+                output_logits=True,
                 enable_bucketing=False, tp_degree=tp)
     base.update(cfg_over)
     tcfg = TpuConfig(**base)

@@ -1,9 +1,9 @@
 """SmallThinker's ReLU-gated experts on the few-token expert kernel (ISSUE 43:
 ``moe_decode.WALK_ACTS``): ``test_moe_decode.py``'s decode steps and 64-token
 chunk on its geometry ``smallthinker-relu``. In a file of their own because
-that file is the suite's longest and the tier-1 command gives a file to one
-worker (``--dist loadfile``): its length is the whole run's. The geometry's
-wider chunks and its untouched experts are cases of that file's tests."""
+the tier-1 command gives a file to one worker (``--dist loadfile``). The
+geometry's wider chunks are cases of ``test_moe_decode_chunk.py``, its
+untouched experts of ``test_moe_decode.py``."""
 
 import jax.numpy as jnp
 import pytest

@@ -115,22 +115,19 @@ def test_donation_red_on_doctored_application(tmp_path):
 
 def test_aliasing_red_on_reverted_ping_pong(tmp_path):
     src = (PKG / "serving" / "adapter.py").read_text()
-    cb_flip = ("        self._cur ^= 1\n"
-               "        self.toks_p, self.pos_p = self._bufs[self._cur]\n")
     paged_flip = ("        self._cur ^= 1\n"
                   "        (self.ids, self.pos, self.slots, self.bt,\n"
                   "         self.counts) = self._bufs[self._cur]\n")
-    assert cb_flip in src and paged_flip in src, \
-        "the PR-3 ping-pong flips moved — update this revert fixture"
-    reverted = src.replace(cb_flip, "").replace(paged_flip, "")
+    assert paged_flip in src, \
+        "the PR-3 ping-pong flip moved — update this revert fixture"
+    reverted = src.replace(paged_flip, "")
     bad = tmp_path / "adapter_reverted.py"
     bad.write_text(reverted)
     ctx = analysis.LintContext(tmp_path)
     findings = analysis.get_pass("aliasing-safety").run(
         ctx, paths=[bad.name])
     hit_classes = {f.message.split(".")[0] for f in findings}
-    assert "_CbScratch" in hit_classes and "_PagedScratch" in hit_classes, \
-        [f.render() for f in findings]
+    assert "_PagedScratch" in hit_classes, [f.render() for f in findings]
     # green on the live file: the double-buffered fills rebind first
     assert analysis.get_pass("aliasing-safety").run(
         ctx, paths=[str(PKG / "serving" / "adapter.py")]) == []

@@ -131,9 +131,9 @@ def _want(ref, w, tokens, hf=HF):
     return np.asarray(ref.forward(hf, w, jnp.asarray([tokens])))[0]
 
 
-def _decode(ad, sids, stream, steps):
+def _decode(ad, sids, stream, steps, step="step"):
     for _ in range(steps):
-        for sid, tok in ad.step(sids).items():
+        for sid, tok in getattr(ad, step)(sids).items():
             stream[sid].append(tok)
 
 
@@ -387,22 +387,21 @@ def test_e_a_rolled_back_admission_leaves_every_slot_free(app, ref,
 
 
 def test_pipelined_decode_equals_eager(ref, gate_weights):
-    """``pipeline_depth=1`` feeds the previous step's tokens back on the
-    device: in slot order they need no re-padding, and the streams are the
-    eager ones (a live-set change drains the pipeline first)."""
-    def serve(depth):
-        ad = PagedEngineAdapter(_app(ref, gate_weights),
-                                pipeline_depth=depth)
+    """``step_ahead()`` feeds the previous step's tokens back on the
+    device: in slot order they need no re-padding, and the streams are
+    those of ``step()`` (a live-set change drains the pipeline first)."""
+    def serve(step):
+        ad = PagedEngineAdapter(_app(ref, gate_weights))
         stream = {1: [ad.add_requests([1], [R21])[1]]}
-        _decode(ad, None, stream, 3)
+        _decode(ad, None, stream, 3, step)
         stream[2] = [ad.add_requests([2], [S12])[2]]
-        _decode(ad, None, stream, 4)
+        _decode(ad, None, stream, 4, step)
         ad.release([1])
-        _decode(ad, None, stream, 2)
+        _decode(ad, None, stream, 2, step)
         for sid, tok in ad.flush().items():
             stream[sid].append(tok)
         return stream[2]
-    eager, piped = serve(0), serve(1)
+    eager, piped = serve("step"), serve("step_ahead")
     assert piped == eager[:len(piped)] and len(piped) >= len(eager) - 1
 
 

@@ -33,9 +33,9 @@ from neuronx_distributed_inference_tpu.resilience import (
     FAULTS, InjectedFault, KVCacheStateError, SequenceStateError,
     ServingError, StepFailure)
 from neuronx_distributed_inference_tpu.resilience import faults as faults_mod
-from neuronx_distributed_inference_tpu.serving import (
-    ContinuousBatchingAdapter, PagedEngineAdapter)
+from neuronx_distributed_inference_tpu.serving import PagedEngineAdapter
 from neuronx_distributed_inference_tpu.telemetry import metrics as tmetrics
+from serving_stacks import stack_app  # noqa: F401  (a fixture)
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -50,6 +50,7 @@ P1 = RNG.integers(1, 500, size=9).tolist()
 P2 = RNG.integers(1, 500, size=12).tolist()
 P8 = RNG.integers(1, 500, size=8).tolist()
 P3 = RNG.integers(1, 500, size=9).tolist()   # only used by the poison test
+Q1 = RNG.integers(1, 128, size=9).tolist()   # inside every toy's vocabulary
 
 
 _GOLDEN_APP = None
@@ -83,17 +84,6 @@ def _no_armed_faults():
 
 
 @pytest.fixture(scope="module")
-def cb_app():
-    tcfg = TpuConfig(batch_size=4, seq_len=64, dtype="float32",
-                     enable_bucketing=True, context_encoding_buckets=[16],
-                     is_continuous_batching=True)
-    app = CausalLMApplication(None, LlamaInferenceConfig(tcfg, **HF),
-                              LlamaFamily)
-    app.init_random_weights(7).init_cache()
-    return app
-
-
-@pytest.fixture(scope="module")
 def paged_app():
     tcfg = TpuConfig(batch_size=4, seq_len=64, dtype="float32",
                      enable_bucketing=True, context_encoding_buckets=[16],
@@ -103,13 +93,6 @@ def paged_app():
                                    LlamaFamily)
     app.init_random_weights(7).init_cache()
     return app
-
-
-@pytest.fixture
-def cb_eng(cb_app):
-    eng = ContinuousBatchingAdapter(cb_app)
-    yield eng
-    eng.release(list(eng.seqs))
 
 
 @pytest.fixture
@@ -191,36 +174,22 @@ def test_kv_manager_shrink_inverts_grow():
 
 
 # ---------------------------------------------------------------------------
-# admission validation (both adapters, typed, pre-state-change)
+# admission validation (typed, pre-state-change)
 # ---------------------------------------------------------------------------
-
-def _check_admission_validation(eng, seq_len):
-    with pytest.raises(AdmissionError, match="empty seq_ids"):
-        eng.add_requests([], [])
-    with pytest.raises(AdmissionError, match="length mismatch"):
-        eng.add_requests([0, 1], [P1])
-    with pytest.raises(AdmissionError, match="duplicate"):
-        eng.add_requests([0, 0], [P1, P2])
-    with pytest.raises(AdmissionError, match="zero-length"):
-        eng.add_requests([0], [[]])
-    with pytest.raises(AdmissionError, match="seq_len"):
-        eng.add_requests([0], [list(range(1, seq_len + 2))])
-    assert eng.seqs == {}
-
-
-def test_admission_validation_cb(cb_eng):
-    _check_admission_validation(cb_eng, 64)
-    with pytest.raises(AdmissionError, match="out of range"):
-        cb_eng.add_requests([7], [P1])
-    # over the largest ctx bucket but under seq_len: typed, not a bare
-    # autobucketing ValueError
-    with pytest.raises(AdmissionError, match="bucket"):
-        cb_eng.add_requests([0], [list(range(1, 20))])
-
 
 def test_admission_validation_paged(paged_eng, paged_app):
     before = _kv_state(paged_app)
-    _check_admission_validation(paged_eng, 64)
+    with pytest.raises(AdmissionError, match="empty seq_ids"):
+        paged_eng.add_requests([], [])
+    with pytest.raises(AdmissionError, match="length mismatch"):
+        paged_eng.add_requests([0, 1], [P1])
+    with pytest.raises(AdmissionError, match="duplicate"):
+        paged_eng.add_requests([0, 0], [P1, P2])
+    with pytest.raises(AdmissionError, match="zero-length"):
+        paged_eng.add_requests([0], [[]])
+    with pytest.raises(AdmissionError, match="seq_len"):
+        paged_eng.add_requests([0], [list(range(1, 64 + 2))])
+    assert paged_eng.seqs == {}
     assert _kv_state(paged_app) == before
 
 
@@ -229,10 +198,12 @@ def test_configuration_errors():
                      enable_bucketing=False)
     app = CausalLMApplication(None, LlamaInferenceConfig(tcfg, **HF),
                               LlamaFamily)
-    with pytest.raises(ConfigurationError):
-        ContinuousBatchingAdapter(app)      # needs continuous batching
-    with pytest.raises(ConfigurationError):
+    # typed hierarchy at the boundary, still catchable as plain ValueError
+    # (pre-hierarchy compat — see README "Serving resilience")
+    with pytest.raises(ValueError) as ei:
         PagedEngineAdapter(app)             # needs block layout
+    assert isinstance(ei.value, ConfigurationError)
+    assert isinstance(ei.value, ServingError)
 
 
 def test_paged_preemption_policy_validated(paged_app):
@@ -294,7 +265,7 @@ def test_paged_admission_rollback_on_prefill_fault(paged_app):
     with FAULTS.inject("prefill_step"):
         with pytest.raises(StepFailure) as ei:
             eng.add_requests([0, 1], [P1, P2])
-    assert ei.value.phase == "prefill"
+    assert ei.value.phase == "prefill" and ei.value.seq_ids == (0, 1)
     assert isinstance(ei.value.__cause__, InjectedFault)
     assert _kv_state(paged_app) == before and eng.seqs == {}
     res = eng.add_requests([0, 1], [P1, P2])        # retry succeeds
@@ -318,15 +289,6 @@ def test_rollback_shared_prefix_does_not_poison_prefix_cache(paged_app):
     # the uninterrupted golden, not "hit" the rolled-back blocks
     assert eng.add_requests([2], [P3])[2] == _golden(tuple(P3), 1)[0]
     eng.release([2])
-
-
-def test_cb_admission_rollback_on_prefill_fault(cb_eng):
-    with FAULTS.inject("prefill_step"):
-        with pytest.raises(StepFailure) as ei:
-            cb_eng.add_requests([0], [P1])
-    assert ei.value.phase == "prefill" and ei.value.seq_ids == (0,)
-    assert cb_eng.seqs == {}
-    assert cb_eng.add_requests([0], [P1])[0] == _golden(tuple(P1), 1)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -381,17 +343,6 @@ def test_genuine_async_device_failure_wrapped_not_retry_safe(
     finally:
         paged_app.cache = real_cache
     eng.release([0])
-
-
-def test_cb_decode_fault_leaves_state_and_retries(cb_eng):
-    want = _golden(tuple(P1), 2)
-    assert cb_eng.add_requests([0], [P1])[0] == want[0]
-    pos0 = cb_eng.seqs[0].position
-    with FAULTS.inject("decode_step"):
-        with pytest.raises(StepFailure):
-            cb_eng.step()
-    assert cb_eng.seqs[0].position == pos0
-    assert cb_eng.step()[0] == want[1]
 
 
 # ---------------------------------------------------------------------------
@@ -481,17 +432,21 @@ def test_grow_capacity_error_when_preemption_disabled(paged_app):
 # per-request budgets: deadlines + decode-past-seq_len guard
 # ---------------------------------------------------------------------------
 
-def test_deadline_exceeded_is_typed_and_counted_once(cb_app):
+def test_deadline_exceeded_is_typed_and_counted_once(stack_app):
     reg = telemetry.MetricsRegistry()
-    eng = ContinuousBatchingAdapter(cb_app, telemetry=reg)
-    eng.add_requests([0], [P1], deadline_s=0.0)     # already expired
+    eng = PagedEngineAdapter(stack_app, telemetry=reg)
+    eng.add_requests([0], [Q1], deadline_s=60.0)
+    assert eng.seqs[0].deadline is not None
+    eng.seqs[0].deadline = 0.0                      # the budget ran out
+    kv = _kv_state(stack_app)
     with pytest.raises(DeadlineExceeded) as ei:
         eng.step()
     assert ei.value.seq_ids == (0,)
     with pytest.raises(DeadlineExceeded):           # still not released
         eng.step()
-    assert reg.get(tmetrics.DEADLINE_EXPIRED_TOTAL).get(engine="cb",
+    assert reg.get(tmetrics.DEADLINE_EXPIRED_TOTAL).get(engine="paged",
                                                         tenant="") == 1
+    assert _kv_state(stack_app) == kv               # before any growth
     eng.release([0])
     assert eng.step() == {}                         # nothing live: clean
 
@@ -507,21 +462,25 @@ def test_deadline_driven_by_slow_step_fault(paged_eng):
     assert paged_eng.add_requests([0], [P1])[0] == _golden(tuple(P1), 1)[0]
 
 
-def test_decode_past_seq_len_guard():
+def test_decode_past_seq_len_guard(stack_app):
+    seq_len = stack_app.tpu_config.seq_len
+    eng = PagedEngineAdapter(stack_app)
+    prompt = RNG.integers(1, 128, size=seq_len - 2).tolist()
+    eng.add_requests([0], [prompt])                 # position seq_len - 2
+    eng.step()                                      # writes slot seq_len - 2
+    eng.step()                                      # writes the last slot
+    kv = _kv_state(stack_app)
+    with pytest.raises(CapacityError, match="seq_len") as ei:
+        eng.step()                                  # one past would be OOB
+    assert ei.value.seq_ids == (0,)                 # structured, not regex
+    assert eng.seqs[0].position == seq_len          # state untouched
+    assert _kv_state(stack_app) == kv               # before any growth
+    eng.release([0])
     tcfg = TpuConfig(batch_size=2, seq_len=16, dtype="float32",
                      enable_bucketing=False, is_continuous_batching=True)
     app = CausalLMApplication(None, LlamaInferenceConfig(tcfg, **HF),
                               LlamaFamily)
     app.init_random_weights(7).init_cache()
-    eng = ContinuousBatchingAdapter(app)
-    prompt = RNG.integers(1, 500, size=14).tolist()
-    eng.add_requests([0], [prompt])                 # position 14
-    eng.step()                                      # writes slot 14
-    eng.step()                                      # writes slot 15 (last)
-    with pytest.raises(CapacityError, match="seq_len") as ei:
-        eng.step()                                  # slot 16 would be OOB
-    assert ei.value.seq_ids == (0,)                 # structured, not regex
-    assert eng.seqs[0].position == 16               # state untouched
     # the same guard sits one layer down, on the raw application call
     with pytest.raises(CapacityError, match="seq_len"):
         app._run_decode(np.zeros((2, 1), np.int32),
@@ -532,25 +491,17 @@ def test_decode_past_seq_len_guard():
 # satellite: error-path coverage for pre-existing adapter behaviors
 # ---------------------------------------------------------------------------
 
-def _check_lifecycle_errors(eng, add_sid, other_sid):
-    eng.add_requests([add_sid], [P1])
-    with pytest.raises(AdmissionError, match="already running"):
-        eng.add_requests([add_sid], [P2])           # dup across calls
-    with pytest.raises(SequenceStateError, match="not running"):
-        eng.step([other_sid])                       # never added
-    eng.release([add_sid])
-    with pytest.raises(SequenceStateError, match="not running"):
-        eng.step([add_sid])                         # released id
-    eng.release([other_sid])                        # never added: no-op
-    assert eng.seqs == {}
-
-
-def test_lifecycle_error_paths_cb(cb_eng):
-    _check_lifecycle_errors(cb_eng, 0, 3)
-
-
 def test_lifecycle_error_paths_paged(paged_eng, paged_app):
-    _check_lifecycle_errors(paged_eng, 0, 3)
+    paged_eng.add_requests([0], [P1])
+    with pytest.raises(AdmissionError, match="already running"):
+        paged_eng.add_requests([0], [P2])                 # dup across calls
+    with pytest.raises(SequenceStateError, match="not running"):
+        paged_eng.step([3])                               # never added
+    paged_eng.release([0])
+    with pytest.raises(SequenceStateError, match="not running"):
+        paged_eng.step([0])                               # released id
+    paged_eng.release([3])                                # never added: no-op
+    assert paged_eng.seqs == {}
     assert 0 not in paged_app.kv_mgr.tables         # release freed blocks
 
 
@@ -558,7 +509,7 @@ def test_lifecycle_error_paths_paged(paged_eng, paged_app):
 # zero overhead while disarmed — acceptance (c)
 # ---------------------------------------------------------------------------
 
-def test_disabled_fault_points_cost_one_attribute_check(cb_eng, monkeypatch):
+def test_disarmed_paged_step_never_enters_fire(paged_eng, monkeypatch):
     """While nothing is armed the hot path reads FAULTS.active and stops:
     fire() must never be entered (so there is no per-step dict lookup or
     allocation). Pinned by making any fire() call explode."""
@@ -567,21 +518,11 @@ def test_disabled_fault_points_cost_one_attribute_check(cb_eng, monkeypatch):
     def _boom(self, point):
         raise AssertionError(f"fire({point!r}) entered while disarmed")
     monkeypatch.setattr(faults_mod.FaultInjector, "fire", _boom)
-    want = _golden(tuple(P1), 3)
-    got = [cb_eng.add_requests([0], [P1])[0]]
-    got.append(cb_eng.step()[0])
-    got.append(cb_eng.step()[0])
+    want = _golden(tuple(P8), 3)
+    got = [paged_eng.add_requests([0], [P8])[0]]
+    got.append(paged_eng.step()[0])
+    got.append(paged_eng.step()[0])
     np.testing.assert_array_equal(got, want)        # bit-identical tokens
-
-
-def test_disarmed_paged_step_never_enters_fire(paged_eng, monkeypatch):
-    res = paged_eng.add_requests([0], [P8])
-    monkeypatch.setattr(
-        faults_mod.FaultInjector, "fire",
-        lambda self, point: (_ for _ in ()).throw(
-            AssertionError("fire() entered while disarmed")))
-    assert paged_eng.step()[0] == _golden(tuple(P8), 2)[1]
-    assert res[0] == _golden(tuple(P8), 2)[0]
 
 
 # ---------------------------------------------------------------------------

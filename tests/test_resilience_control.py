@@ -522,7 +522,7 @@ def test_flush_path_step_failure_is_fatal_typed(apps):
     finish typed — so run_forever's 'a StepFailure raise site ran
     _fatal first' invariant holds on every path."""
     app, _, _ = apps
-    adapter = PagedEngineAdapter(app, pipeline_depth=1)
+    adapter = PagedEngineAdapter(app)
     eng = ServingEngine(adapter, starvation_bound_s=1e9,
                         max_unread_tokens=2)
     s = eng.submit(_prompts(99, 1)[0], 8)

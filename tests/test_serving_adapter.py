@@ -1,18 +1,16 @@
-"""Serving adapters (reference: the vLLM-facing contract of
+"""The serving adapter (reference: the vLLM-facing contract of
 models/model_wrapper.py:1297-1440): continuous-batching begin/step/release
-keyed by seq_ids over the contiguous and paged apps, plus the paged app's
-batch-mismatch repad shim."""
+keyed by seq_ids over the paged app, plus the paged app's batch-mismatch
+repad shim."""
 
 import numpy as np
-import pytest
 
 from neuronx_distributed_inference_tpu.config import TpuConfig
 from neuronx_distributed_inference_tpu.models.application import (
     CausalLMApplication, PagedCausalLMApplication)
 from neuronx_distributed_inference_tpu.models.llama import (
     LlamaFamily, LlamaInferenceConfig)
-from neuronx_distributed_inference_tpu.serving import (
-    ContinuousBatchingAdapter, PagedEngineAdapter)
+from neuronx_distributed_inference_tpu.serving import PagedEngineAdapter
 
 HF = dict(model_type="llama", hidden_size=64, intermediate_size=128,
           num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
@@ -32,57 +30,9 @@ def _ref_tokens(prompt, n):
     return np.asarray(out["generated"])[0]
 
 
-def test_continuous_batching_adapter_interleaved():
+def test_paged_engine_adapter_interleaved():
     """Two requests joining at different times must each reproduce their
     single-request greedy tokens."""
-    tcfg = TpuConfig(batch_size=4, seq_len=64, dtype="float32",
-                     enable_bucketing=True, context_encoding_buckets=[16],
-                     is_continuous_batching=True)
-    app = CausalLMApplication(None, LlamaInferenceConfig(tcfg, **HF),
-                              LlamaFamily)
-    app.init_random_weights(7).init_cache()
-    eng = ContinuousBatchingAdapter(app)
-
-    rng = np.random.default_rng(0)
-    p1 = rng.integers(1, 500, size=9).tolist()
-    p2 = rng.integers(1, 500, size=12).tolist()
-    want1 = _ref_tokens(p1, 8)
-    want2 = _ref_tokens(p2, 8)
-
-    got1 = [eng.add_requests([2], [p1])[2]]        # row 2, alone
-    for _ in range(3):
-        got1.append(eng.step()[2])
-    # request 2 joins mid-flight on row 0
-    got2 = [eng.add_requests([0], [p2])[0]]
-    for _ in range(4):
-        res = eng.step()                           # both rows advance
-        got1.append(res[2])
-        got2.append(res[0])
-    for _ in range(3):
-        got2.append(eng.step([0])[0])              # only row 0
-    np.testing.assert_array_equal(got1, want1)
-    np.testing.assert_array_equal(got2, want2)
-    eng.release([0, 2])
-    assert len(eng.free_slots) == 4
-
-
-def test_continuous_adapter_rejects_misuse():
-    from neuronx_distributed_inference_tpu.resilience import (
-        ConfigurationError, ServingError)
-    tcfg = TpuConfig(batch_size=2, seq_len=64, dtype="float32",
-                     enable_bucketing=False)
-    app = CausalLMApplication(None, LlamaInferenceConfig(tcfg, **HF),
-                              LlamaFamily)
-    app.init_random_weights(7).init_cache()
-    # typed hierarchy at the boundary, still catchable as plain ValueError
-    # (pre-hierarchy compat — see README "Serving resilience")
-    with pytest.raises(ValueError) as ei:
-        ContinuousBatchingAdapter(app)     # needs continuous batching
-    assert isinstance(ei.value, ConfigurationError)
-    assert isinstance(ei.value, ServingError)
-
-
-def test_paged_engine_adapter_interleaved():
     tcfg = TpuConfig(batch_size=4, seq_len=64, dtype="float32",
                      enable_bucketing=True, context_encoding_buckets=[16],
                      is_block_kv_layout=True, pa_block_size=8,
@@ -112,6 +62,7 @@ def test_paged_engine_adapter_interleaved():
     np.testing.assert_array_equal(got2, want2)
     eng.release([0, 1])
     assert 0 not in app.kv_mgr.tables and 1 not in app.kv_mgr.tables
+    assert eng.free_capacity == 4
 
 
 def test_paged_generate_repad_shim():

@@ -355,7 +355,7 @@ def test_router_failover_requeue_continues_trace(apps):
     continued — and the stitched stream still finishes."""
     app_a, app_b = apps
     rec = telemetry.enable_recorder()
-    eng_a = ServingEngine(PagedEngineAdapter(app_a, pipeline_depth=1),
+    eng_a = ServingEngine(PagedEngineAdapter(app_a),
                           starvation_bound_s=1e9)
     eng_b = ServingEngine(PagedEngineAdapter(app_b), starvation_bound_s=1e9)
     router = EngineRouter({"A": eng_a, "B": eng_b})

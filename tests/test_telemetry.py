@@ -16,8 +16,7 @@ from neuronx_distributed_inference_tpu.models.application import (
     CausalLMApplication, PagedCausalLMApplication)
 from neuronx_distributed_inference_tpu.models.llama import (
     LlamaFamily, LlamaInferenceConfig)
-from neuronx_distributed_inference_tpu.serving import (
-    ContinuousBatchingAdapter, PagedEngineAdapter)
+from neuronx_distributed_inference_tpu.serving import PagedEngineAdapter
 from neuronx_distributed_inference_tpu.telemetry import metrics as tmetrics
 
 HF = dict(model_type="llama", hidden_size=64, intermediate_size=128,
@@ -238,16 +237,6 @@ def test_enable_disable_roundtrip():
 # serving-adapter + application instrumentation (CPU, tiny llama)
 # ---------------------------------------------------------------------------
 
-def _cb_app():
-    tcfg = TpuConfig(batch_size=4, seq_len=64, dtype="float32",
-                     enable_bucketing=True, context_encoding_buckets=[16],
-                     is_continuous_batching=True)
-    app = CausalLMApplication(None, LlamaInferenceConfig(tcfg, **HF),
-                              LlamaFamily)
-    app.init_random_weights(7).init_cache()
-    return app
-
-
 def _paged_app():
     tcfg = TpuConfig(batch_size=4, seq_len=64, dtype="float32",
                      enable_bucketing=True, context_encoding_buckets=[16],
@@ -272,38 +261,41 @@ def _drive(eng):
     eng.release([0, 1])
 
 
-def test_cb_adapter_records_serving_metrics(live_registry):
+def test_adapter_records_serving_metrics(live_registry):
     reg = live_registry
-    _drive(ContinuousBatchingAdapter(_cb_app()))
+    _drive(PagedEngineAdapter(_paged_app()))
 
     ttft = reg.get(tmetrics.REQUEST_TTFT_SECONDS)
-    assert ttft.count(engine="cb", tenant="") == 2
-    assert ttft.sum(engine="cb", tenant="") > 0.0
+    assert ttft.count(engine="paged", tenant="") == 2
+    assert ttft.sum(engine="paged", tenant="") > 0.0
     step = reg.get(tmetrics.DECODE_STEP_SECONDS)
-    assert step.count(engine="cb") == 6
-    assert step.sum(engine="cb") > 0.0
+    assert step.count(engine="paged") == 6
+    assert step.sum(engine="paged") > 0.0
     tpot = reg.get(tmetrics.REQUEST_TPOT_SECONDS)
-    assert tpot.count(engine="cb", tenant="") == 2
+    assert tpot.count(engine="paged", tenant="") == 2
     req = reg.get(tmetrics.REQUESTS_TOTAL)
-    assert req.get(engine="cb", event="added") == 2
-    assert req.get(engine="cb", event="released") == 2
-    # pad-waste: batch bucket pads 1 live row up to 2 (or 4) on some steps
+    assert req.get(engine="paged", event="added") == 2
+    assert req.get(engine="paged", event="released") == 2
+    # live rows by phase: 3 steps of one row + 3 of two, and one chunk row
+    # a prompt; a lone row runs the one-row rung of both ladders (no pad)
     live = reg.get(tmetrics.LIVE_ROWS_TOTAL)
-    pad = reg.get(tmetrics.PAD_ROWS_TOTAL)
-    assert live.get(engine="cb", phase="decode") > 0
-    assert (pad.get(engine="cb", phase="decode")
-            + pad.get(engine="cb", phase="prefill")) > 0
-    assert reg.get(tmetrics.LIVE_BATCH_SIZE).get(engine="cb") == 2
+    assert live.get(engine="paged", phase="decode") == 9
+    assert live.get(engine="paged", phase="prefill") == 2
+    assert reg.get(tmetrics.PREFILL_CHUNKS_TOTAL).get(engine="paged") == 2
+    assert reg.get(tmetrics.LIVE_BATCH_SIZE).get(engine="paged") == 2
     # bucket selections were tagged
     bucket = reg.get(tmetrics.BUCKET_SELECTED_TOTAL)
     assert bucket.get(kind="ctx", bucket="16") == 2
+    assert bucket.get(kind="prefill_rows", bucket="1") == 2
     assert sum(s["value"] for s in bucket._snapshot()
                if s["labels"]["kind"] == "batch") > 0
-    # recompiles vs cache hits: first prefill/decode compile, repeats hit
+    # recompiles vs cache hits: the chunk and the step compile once a
+    # shape, repeats hit
     compiles = reg.get(tmetrics.JIT_COMPILES_TOTAL)
     hits = reg.get(tmetrics.JIT_CACHE_HITS_TOTAL)
-    assert compiles.get(kind="prefill", bucket="16") == 1
-    assert hits.get(kind="decode") >= 4
+    assert sum(s["value"] for s in compiles._snapshot()
+               if s["labels"]["kind"] == "paged") >= 2
+    assert hits.get(kind="paged") >= 4
     # request spans landed in the ring with first_token + released events
     spans = [s for s in reg.spans if s["name"] == "request"]
     assert len(spans) == 2
@@ -312,10 +304,9 @@ def test_cb_adapter_records_serving_metrics(live_registry):
     # run_seconds: the HOST side of every _run_* call at the app boundary;
     # there is no device part — telemetry never syncs the device (C7)
     run = reg.get(tmetrics.RUN_SECONDS)
-    assert run.count(kind="prefill", part="host") == 2
-    assert run.count(kind="decode", part="host") == 6
+    assert run.count(kind="paged", part="host") == 8
     assert {s["labels"]["part"] for s in run._snapshot()} == {"host"}
-    assert reg.get(tmetrics.GENERATED_TOKENS_TOTAL).get(engine="cb") > 0
+    assert reg.get(tmetrics.GENERATED_TOKENS_TOTAL).get(engine="paged") > 0
     # the whole thing renders as valid Prometheus text
     types, samples = _parse_prometheus(reg.render_prometheus())
     assert types[tmetrics.REQUEST_TTFT_SECONDS] == "histogram"
@@ -458,7 +449,7 @@ def test_disabled_telemetry_is_bit_identical_and_keeps_cache_keys():
 
 def test_disabled_adapters_add_no_metric_keys():
     assert not telemetry.get_registry().enabled
-    _drive(ContinuousBatchingAdapter(_cb_app()))
+    _drive(PagedEngineAdapter(_paged_app()))
     reg = telemetry.get_registry()
     assert reg.snapshot() == {"metrics": {}, "spans": []}
     assert reg.render_prometheus() == ""
