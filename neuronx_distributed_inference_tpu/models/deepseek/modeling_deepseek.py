@@ -13,6 +13,19 @@ Covered deltas:
     group-limited greedy routing (n_group/topk_group), routed_scaling_factor
   * mixed stacks: first_k_dense_replace dense layers then MoE layers with
     shared experts
+
+ONE CHIP'S SHARE of the expert layers, spelt as ``models/longcat_flash/``
+and ``models/qwen3_next/`` spell it: with ``router_n_routed_experts`` in the
+config, ``n_routed_experts`` is what the weights HOLD (from ``first_expert``
+on) and the router, its selection bias, the groups and the top k still run
+over ``router_n_routed_experts``; the block computes the held experts' part
+of the sum plus the shared expert, and no code stands in for the other
+chips. Without the key every routed expert is held. A share runs on one
+chip: ``tp > 1`` and ``ep > 1`` are refused with it.
+
+Left out: the multi-token-prediction module (``num_nextn_predict_layers``).
+A checkpoint's ``model.layers.<num_hidden_layers>.*`` are its tensors and
+are never read.
 """
 
 from __future__ import annotations
@@ -42,8 +55,15 @@ def deepseek_style_moe_weights(get, prefix: str, i: int, spec,
     """DeepSeek-V3-shaped MoE weights for layer ``i``: sigmoid/softmax
     router (+ optional e_score_correction_bias), per-expert gate/up/down,
     optional shared experts. Shared by every family with this checkpoint
-    shape (deepseek v2/v3, glm4_moe)."""
-    E = spec.moe.num_experts
+    shape (deepseek v2/v3, glm4_moe). A share of the experts
+    (``MoESpec.held_experts``) is read at ``first_expert + e`` from a
+    checkpoint that holds every routed expert, and at ``e`` from one that
+    holds the share alone (the benchmark's seeded weights); the router keeps
+    all its columns either way."""
+    moe = spec.moe
+    last = f"{prefix}.layers.{i}.mlp.experts.{moe.num_routed - 1}"
+    first = moe.first_expert if moe.holds_share and _has(
+        get, last + ".gate_proj.weight") else 0
     out: Dict[str, Any] = {
         "router": transpose(get(
             f"{prefix}.layers.{i}.mlp.gate.weight")).astype(np.float32),
@@ -56,8 +76,9 @@ def deepseek_style_moe_weights(get, prefix: str, i: int, spec,
                       ("expert_up", "up_proj"),
                       ("expert_down", "down_proj")):
         out[key] = np.stack([
-            transpose(get(f"{prefix}.layers.{i}.mlp.experts.{e}.{name}.weight"))
-            for e in range(E)])
+            transpose(get(
+                f"{prefix}.layers.{i}.mlp.experts.{first + e}.{name}.weight"))
+            for e in range(moe.num_held)])
     if spec.moe.shared_intermediate:
         for key, name in (("shared_gate", "gate_proj"),
                           ("shared_up", "up_proj"),
@@ -65,6 +86,14 @@ def deepseek_style_moe_weights(get, prefix: str, i: int, spec,
             out[key] = transpose(get(
                 f"{prefix}.layers.{i}.mlp.shared_experts.{name}.weight"))
     return out
+
+
+def _has(get, name: str) -> bool:
+    try:
+        get(name)
+    except KeyError:
+        return False
+    return True
 
 
 @register_family("deepseek_v3", "deepseek_v2")
@@ -94,8 +123,27 @@ class DeepseekFamily(DecoderFamily):
         moe = None
         first_dense = 0
         if getattr(config, "n_routed_experts", None):
+            held = int(config.n_routed_experts)
+            routed = int(getattr(config, "router_n_routed_experts", None)
+                         or held)
+            first = int(getattr(config, "first_expert", 0) or 0)
+            if not 0 <= first <= routed - held:
+                raise ValueError(
+                    f"experts {first}..{first + held - 1} held of {routed} "
+                    "routed ones")
+            tcfg = config.tpu_config
+            tp = tp_degree if tp_degree is not None else tcfg.tp_degree
+            if held < routed and (tp > 1
+                                  or getattr(tcfg, "ep_degree", 1) > 1):
+                raise NotImplementedError(
+                    f"{getattr(config, 'model_type', 'deepseek')}: a chip's "
+                    "share of the expert layers (router_n_routed_experts) "
+                    "is served on one chip "
+                    "(tp_degree 1, ep_degree 1): it runs without the "
+                    "exchange that would join it to the other shares "
+                    "(PERF.md section 7)")
             moe = MoESpec(
-                num_experts=config.n_routed_experts,
+                num_experts=routed,
                 top_k=config.num_experts_per_tok,
                 intermediate_size=config.moe_intermediate_size,
                 normalize_topk=bool(getattr(config, "norm_topk_prob", True)),
@@ -108,6 +156,8 @@ class DeepseekFamily(DecoderFamily):
                                      * getattr(config, "n_shared_experts", 0)),
                 n_group=int(getattr(config, "n_group", 1) or 1),
                 topk_group=int(getattr(config, "topk_group", 1) or 1),
+                held_experts=held if held < routed else 0,
+                first_expert=first,
             )
             first_dense = int(getattr(config, "first_k_dense_replace", 0))
         spec = spec_from_config(
@@ -128,6 +178,10 @@ class DeepseekFamily(DecoderFamily):
     @classmethod
     def convert_hf_state_dict(cls, sd: Dict[str, np.ndarray], spec: DecoderSpec
                               ) -> Dict[str, Any]:
+        """Layers ``0 .. num_layers - 1`` are read by name; a checkpoint's
+        ``model.layers.<num_layers>.*`` (DeepSeek-V3's multi-token-prediction
+        module: its own embedding, head, projection and one block) is the
+        next index and is never asked for."""
         p = cls.hf_prefix
         L = spec.num_layers
         nd = spec.first_dense if spec.moe is not None else L

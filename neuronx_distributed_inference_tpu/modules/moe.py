@@ -254,9 +254,34 @@ def route(moe: MoESpec, h: jnp.ndarray, router_w: jnp.ndarray,
     Reference: RouterTopK (moe_v2.py:5-15) with the affinity knobs of
     MoENeuronConfig (normalize_top_k_affinities, routed_scaling_factor).
     """
+    return route_groups(moe, h, router_w, router_bias)[:2]
+
+
+def chosen_groups(moe: MoESpec, select: jnp.ndarray) -> jnp.ndarray:
+    """Group-limited greedy routing (DeepSeek-V3 ``get_topk_indices``): the
+    (B,T,G) bool mask of the ``topk_group`` groups, of ``n_group`` groups of
+    consecutive experts, with the largest sum of their top-2 biased scores
+    ``select`` (B,T,E). A group is in if fewer than ``topk_group`` groups
+    rank before it - a larger score, or an equal one at a lower index, the
+    order ``top_k`` breaks ties in - which is G x G compares a token and no
+    scatter."""
+    b, t, e = select.shape
+    g = moe.n_group
+    top2, _ = jax.lax.top_k(select.reshape(b, t, g, e // g), 2)
+    score = top2.sum(axis=-1)                                      # (B,T,G)
+    mine, other = score[..., :, None], score[..., None, :]
+    earlier = jnp.arange(g)[None, :] < jnp.arange(g)[:, None]
+    before = (other > mine) | ((other == mine) & earlier)
+    return jnp.sum(before, axis=-1) < moe.topk_group
+
+
+def route_groups(moe: MoESpec, h: jnp.ndarray, router_w: jnp.ndarray,
+                 router_bias: Optional[jnp.ndarray] = None):
+    """:func:`route`, and third the (B,T,G) mask of the groups the
+    selection was limited to (None: the router has no groups)."""
     logits = h.astype(jnp.float32) @ router_w.astype(jnp.float32)  # (B,T,E)
     if moe.router_act == "sparsemixer":
-        return _sparsemixer_route(moe, logits)
+        return (*_sparsemixer_route(moe, logits), None)
     if router_bias is not None and moe.router_bias_mode == "logits":
         logits = logits + router_bias
         router_bias = None
@@ -267,21 +292,13 @@ def route(moe: MoESpec, h: jnp.ndarray, router_w: jnp.ndarray,
     else:
         scores = jax.nn.softmax(logits, axis=-1)
     select = scores + router_bias if router_bias is not None else scores
+    groups = None
     if moe.n_group > 1:
-        # group-limited greedy (DeepSeek-V3 get_topk_indices): rank groups by
-        # the sum of their top-2 biased scores, zero out losing groups
-        b, t, e = select.shape
-        g = moe.n_group
-        grouped = select.reshape(b, t, g, e // g)
-        top2, _ = jax.lax.top_k(grouped, 2)
-        group_scores = top2.sum(axis=-1)                           # (B,T,G)
-        _, group_idx = jax.lax.top_k(group_scores, moe.topk_group)
-        group_mask = jnp.zeros((b, t, g), bool).at[
-            jnp.arange(b)[:, None, None], jnp.arange(t)[None, :, None],
-            group_idx].set(True)
-        mask = jnp.broadcast_to(group_mask[..., None],
-                                grouped.shape).reshape(b, t, e)
-        select = jnp.where(mask, select, 0.0)
+        # the losing groups' scores are zeroed, not dropped
+        groups = chosen_groups(moe, select)
+        select = jnp.where(
+            jnp.repeat(groups, select.shape[-1] // moe.n_group, axis=-1),
+            select, 0.0)
     _, top_idx = jax.lax.top_k(select, moe.top_k)                  # (B,T,k)
     top_vals = jnp.take_along_axis(scores, top_idx, axis=-1)
     if moe.pre_softmax_topk and moe.router_act != "sigmoid":
@@ -291,7 +308,7 @@ def route(moe: MoESpec, h: jnp.ndarray, router_w: jnp.ndarray,
             jnp.sum(top_vals, axis=-1, keepdims=True), 1e-20)
     if moe.routed_scaling is not None:
         top_vals = top_vals * moe.routed_scaling
-    return top_vals, top_idx
+    return top_vals, top_idx, groups
 
 
 def _sparsemixer_route(moe: MoESpec, logits: jnp.ndarray
@@ -389,6 +406,26 @@ def zero_tally(moe: MoESpec, top_idx: jnp.ndarray,
     if live is not None:
         zero, picks = zero & live[..., None], picks & live[..., None]
     return jnp.stack([jnp.sum(picks), jnp.sum(zero)]).astype(jnp.int32)
+
+
+def group_tally(moe: MoESpec, groups: Optional[jnp.ndarray],
+                top_idx: jnp.ndarray,
+                live: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """int32 ``[rows]``: the ``live`` rows of one routing whose chosen
+    groups (``groups`` (B,T,G), :func:`chosen_groups`) include a group that
+    holds one of this chip's experts - in the deployment the rows whose
+    exchange can reach this chip at all, its fan-in. A router without groups
+    (``groups`` None) has one, which every row chose."""
+    if groups is None:
+        hit = jnp.ones(top_idx.shape[:2], bool)
+    else:
+        per = moe.num_experts // moe.n_group
+        first = moe.first_expert if moe.holds_share else 0
+        hit = jnp.any(groups[..., first // per:
+                             (first + moe.num_held - 1) // per + 1], axis=-1)
+    if live is not None:
+        hit = hit & live
+    return jnp.sum(hit).astype(jnp.int32).reshape(1)
 
 
 def _glu(moe: MoESpec, gate: jnp.ndarray, up: jnp.ndarray) -> jnp.ndarray:
@@ -596,25 +633,28 @@ def moe_block(moe: MoESpec, x: jnp.ndarray, layer_w: Dict[str, Any],
               router_x: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Full MoE block: route + experts (+ shared experts). x (B,T,H).
     ``tally``: a list a layer walk hands in to collect, per expert layer,
-    :func:`share_tally` + :func:`zero_tally` of this routing over the
-    ``live`` rows: int32 ``[touched, assigned, read, picks, identity
-    picks]``. ``router_x`` (B,T,H): what the router reads where that is not
-    the experts' input (``MoESpec.router_pre_attn``); the routing, the
-    tally and the combine weights come from it, the experts multiply
-    ``x``."""
+    :func:`share_tally` + :func:`zero_tally` + :func:`group_tally` of this
+    routing over the ``live`` rows: int32 ``[touched, assigned, read, picks,
+    identity picks, rows whose groups reach this chip]``. ``router_x``
+    (B,T,H): what the router reads where that is not the experts' input
+    (``MoESpec.router_pre_attn``); the routing, the tally and the combine
+    weights come from it, the experts multiply ``x``."""
     if moe.router_pre_attn and router_x is None:
         raise ValueError(
             "MoESpec.router_pre_attn: the layer walk must hand moe_block the "
             "attention's normed input as router_x; this walk does not")
     router_bias = layer_w.get("router_bias") if moe.has_router_bias else None
-    top_vals, top_idx = route(moe, x if router_x is None else router_x,
-                              layer_w["router"], router_bias)
+    top_vals, top_idx, groups = route_groups(
+        moe, x if router_x is None else router_x, layer_w["router"],
+        router_bias)
     if moe.holds_share:
         kernel_mode.note("moe_share", "xla",
                          f"held={moe.num_held} of {moe.num_experts} "
                          f"from {moe.first_expert} top_k={moe.top_k}"
                          + (f" zero={moe.zero_experts}"
-                            if moe.zero_experts else ""))
+                            if moe.zero_experts else "")
+                         + (f" groups={moe.n_group} top={moe.topk_group}"
+                            if moe.n_group > 1 else ""))
     y, read = _experts(moe, x, top_vals, top_idx, layer_w, phase)
     if moe.zero_experts:
         # the identity experts' term, the token's own chip's
@@ -624,7 +664,8 @@ def moe_block(moe: MoESpec, x: jnp.ndarray, layer_w: Dict[str, Any],
     if tally is not None:
         tally.append(jnp.concatenate([
             share_tally(moe, top_idx, live, read),
-            zero_tally(moe, top_idx, live)]))
+            zero_tally(moe, top_idx, live),
+            group_tally(moe, groups, top_idx, live)]))
     return _shared_experts(moe, x, y, layer_w)
 
 

@@ -14,7 +14,13 @@ bytes once for all heads (1,280 B against 40,960 B of expanded heads) and
 2 x Hq x (lanes + rank) FLOP: at 64 heads 121 FLOP a useful byte, half the
 v5e's ridge, so the kernel is bound by its bytes only while the MXU holds
 half its peak - which is why it is a kernel of its own and not a flag on the
-GQA one (``ops/decode_attention.py``: 2 to 8 FLOP a byte).
+GQA one (``ops/decode_attention.py``: 2 to 8 FLOP a byte). At 128 heads
+(DeepSeek-V3) it is 242 FLOP a byte, AT the ridge of 240: the two sides of
+its roofline are 1.414 ns (FLOP) and 1.407 ns (bytes) a cached token a
+layer, and the MXU's share of its peak sets the time: a call with its two
+folds took 5.0 ns a live token at mixed row lengths and 3.8 at 8192 a row
+(28 and 37 % of that roofline; 64 heads, at half the multiplications, 3.9
+and 2.8: my chip run, PR 47).
 
 The pattern is that kernel's (PR 33): the grid is the rows; for each the
 kernel walks the row's LIVE pages in compute blocks of ``pages`` pages,
@@ -47,7 +53,14 @@ MLA_BLOCK_TOKENS = 512
 def block_pages(bs: int, lanes: int, dtype, mb: int) -> int:
     """Pages of one compute block: the most that two slots fit
     :data:`MLA_KV_VMEM_BYTES`, :data:`MLA_BLOCK_TOKENS` and
-    ``PAGED_BLOCK_PAGES`` (the copies are unrolled) and the table allow."""
+    ``PAGED_BLOCK_PAGES`` (the copies are unrolled) and the table allow.
+    The heads do not enter: ALL of them go against a block in one pass (a
+    head is a row of the MXU's moving operand; a second pass would pay the
+    block's tiles again), and at 128 heads (DeepSeek-V3), where the score
+    tile of 512 tokens is the whole register file, a shorter block is
+    SLOWER: 32 rows over 117k live tokens took 0.88 / 0.65 / 0.59 ms a call
+    in blocks of 128 / 256 / 512 tokens on a v5e (PERF.md section 6, PR 47:
+    what a block costs beside its multiplications is paid per block)."""
     page_bytes = bs * lanes * jnp.dtype(dtype).itemsize
     return max(1, min(MLA_KV_VMEM_BYTES // (2 * page_bytes),
                       MLA_BLOCK_TOKENS // bs, PAGED_BLOCK_PAGES, mb))

@@ -33,7 +33,13 @@ where:
   do anything, PERF.md §6, PR 33). Beside the slots, the step's rows and
   float32 result, whole: what they need is computed from the rows the call
   carries (:func:`rows_vmem_bytes`), and a step whose rows would need more
-  than the slots is declined.
+  than :data:`MOE_ROWS_VMEM_BYTES` is declined. A step of one tile rides the
+  call's pipeline, which keeps two buffers of every block it is given; a
+  longer one's float32 rows and result are the bulk of what it holds, so
+  they stay in HBM as the call sees them and the kernel keeps ONE copy of
+  each, brought in and written back by hand around the walk (256 rows of
+  7168 are 14.7 MB so held, 29.4 MB in a pipeline's pairs: DeepSeek-V3,
+  PR 47).
 * MXU: rows against a unit in the operands' dtype with float32
   accumulation, combined in float32. A step of at most :data:`ROW_TILE`
   rows (:func:`moe_decode_experts`): ALL rows against every unit, times the
@@ -59,9 +65,10 @@ from jax.experimental.pallas import tpu as pltpu
 #: need beside them (:func:`rows_vmem_bytes`), which may be as much again.
 MOE_WEIGHT_VMEM_BYTES = 24 * 1024 * 1024
 #: VMEM a step's rows may take beside the slots (:func:`rows_vmem_bytes`):
-#: a one-row chunk of 256 tokens at a hidden size of 6144 keeps 25 MB of
-#: float32 rows and result and 15 MB of two tiles' products (39.6 MiB:
-#: LongCat-Flash, PR 40); slots and rows together stay at half of a core's
+#: set by a one-row chunk of 256 tokens at a hidden size of 6144 when the
+#: pipeline held its rows and result in pairs (39.6 MiB: LongCat-Flash,
+#: PR 40); held once, that chunk needs 27.6 MiB and one of 7168 wide 32.1
+#: (DeepSeek-V3, PR 47). Slots and rows together stay at half of a core's
 #: 128 MiB
 MOE_ROWS_VMEM_BYTES = 40 * 1024 * 1024
 #: most rows of a step on the walk: timed at 256 and 512 (PERF.md section 6,
@@ -118,17 +125,17 @@ def moe_decode_plan(h: int, i: int, dtype) -> Optional[MoEDecodePlan]:
 def rows_vmem_bytes(n: int, h: int, e: int, plan: MoEDecodePlan,
                     dtype) -> int:
     """VMEM a step of ``n`` rows of ``h`` needs beside the slots, from the
-    shapes the call carries: what the pipeline holds whole, twice (the
-    rows, the (n, e) float32 combine matrix and the float32 result of a
-    step of one tile; the float32 rows and result of a longer one, and its
-    two tiles), and a product's float32 intermediates, counted twice for
-    what Mosaic keeps of them."""
+    shapes the call carries: what a step of one tile leaves to the
+    pipeline, which holds it twice (the rows, the (n, e) float32 combine
+    matrix and the float32 result); the float32 rows and result of a longer
+    one, held once by the kernel, and its two tiles; and a product's
+    float32 intermediates, counted twice for what Mosaic keeps of them."""
     item = jnp.dtype(dtype).itemsize
     tile = min(n, ROW_TILE)
     if n <= ROW_TILE:
         held = 2 * n * (h * item + e * 4 + h * 4)
     else:
-        held = 2 * 2 * n * h * 4 + 2 * tile * h * 4
+        held = 2 * n * h * 4 + 2 * tile * h * 4
     product = tile * (h * item + 2 * plan.ip * 4 + plan.ip * item + h * 4)
     return held + 2 * product
 
@@ -265,9 +272,9 @@ def _kernel(sc_ref, x_ref, comb_ref, wg_hbm, wu_hbm, wd_hbm, o_ref,
     _walk(sc_ref, (wg_hbm, wu_hbm, wd_hbm), slots, sem, pieces, compute)
 
 
-def _rows_kernel(sc_ref, tok_ref, wt_ref, x_ref, wg_hbm, wu_hbm, wd_hbm,
-                 o_ref, gbuf, ubuf, dbuf, xt, yt, sem, *, pieces: int,
-                 glu: Callable, experts: int):
+def _rows_kernel(sc_ref, tok_ref, wt_ref, x_hbm, wg_hbm, wu_hbm, wd_hbm,
+                 o_hbm, gbuf, ubuf, dbuf, xt, yt, x_ref, o_ref, io_sem, sem,
+                 *, pieces: int, glu: Callable, experts: int):
     """A step of more rows than a tile: each unit against ITS rows. Behind
     the touched list ``sc_ref`` holds the E + 1 bounds of the experts'
     groups in the assignment list; ``tok_ref`` / ``wt_ref`` (SMEM) are that
@@ -276,9 +283,14 @@ def _rows_kernel(sc_ref, tok_ref, wt_ref, x_ref, wg_hbm, wu_hbm, wd_hbm,
     rows at a time into ``xt`` and multiplied, and each row of the product
     (``yt``) is added, times its weight, to its token's row of the result.
     Rows of a tile past the group's end are an earlier tile's: computed,
-    never added."""
+    never added. The rows ``x_hbm`` and the result ``o_hbm`` are the call's,
+    in HBM; ``x_ref`` / ``o_ref`` are the kernel's one copy of each, filled
+    before the walk and written back after it."""
+    rows_in = pltpu.make_async_copy(x_hbm, x_ref, io_sem.at[0])
+    rows_in.start()
     o_ref[...] = jnp.zeros_like(o_ref)
     xt[...] = jnp.zeros_like(xt)
+    rows_in.wait()
     tile = xt.shape[0]
     slots = (gbuf, ubuf, dbuf)
     bounds = 2 + experts            # behind [layer, count, id_0 .. id_{E-1}]
@@ -310,24 +322,34 @@ def _rows_kernel(sc_ref, tok_ref, wt_ref, x_ref, wg_hbm, wu_hbm, wd_hbm,
         jax.lax.fori_loop(0, pl.cdiv(hi - lo, tile), rows, 0)
 
     _walk(sc_ref, (wg_hbm, wu_hbm, wd_hbm), slots, sem, pieces, compute)
+    rows_out = pltpu.make_async_copy(o_ref, o_hbm, io_sem.at[1])
+    rows_out.start()
+    rows_out.wait()
 
 
 def _call(kernel, name: str, scalars, rows_in, rows_out, stacks,
-          plan: MoEDecodePlan, scratch, vmem_rows: int, interpret: bool):
+          plan: MoEDecodePlan, scratch, vmem_rows: int, interpret: bool,
+          piped: bool = True):
     """One ``pallas_call`` of a walk: ``scalars`` prefetched into SMEM,
-    ``rows_in`` whole in VMEM, the three stacks left in HBM, two slots of a
-    unit's three matrices and ``scratch`` beside them."""
+    ``rows_in`` and the result whole in VMEM through the call's pipeline
+    (``piped``) or left in HBM for the kernel to copy by hand, the three
+    stacks left in HBM, two slots of a unit's three matrices and
+    ``scratch`` beside them."""
     wg, wu, wd = stacks
     h = wg.shape[2]
-    whole = lambda *_: (0, 0)                                  # noqa: E731
+
+    def rows(a):
+        return (pl.BlockSpec(a.shape, lambda *_: (0, 0)) if piped
+                else pl.BlockSpec(memory_space=pl.ANY))
+
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(1,),
-            in_specs=[pl.BlockSpec(a.shape, whole) for a in rows_in]
+            in_specs=[rows(a) for a in rows_in]
             + [pl.BlockSpec(memory_space=pl.ANY)] * 3,
-            out_specs=pl.BlockSpec(rows_out.shape, whole),
+            out_specs=rows(rows_out),
             scratch_shapes=[
                 pltpu.VMEM((2, h, plan.ip), wg.dtype),
                 pltpu.VMEM((2, h, plan.ip), wu.dtype),
@@ -419,6 +441,8 @@ def moe_chunk_experts(x: jnp.ndarray, token: jnp.ndarray,
         "moe_chunk_experts",
         (scalars, token.astype(jnp.int32), weight.astype(jnp.float32)), (x,),
         jax.ShapeDtypeStruct(x.shape, jnp.float32), (wg, wu, wd), plan,
-        (pltpu.VMEM(tile, jnp.float32), pltpu.VMEM(tile, jnp.float32)),
-        vmem_rows, interpret)
+        (pltpu.VMEM(tile, jnp.float32), pltpu.VMEM(tile, jnp.float32),
+         pltpu.VMEM(x.shape, jnp.float32), pltpu.VMEM(x.shape, jnp.float32),
+         pltpu.SemaphoreType.DMA((2,))),
+        vmem_rows, interpret, piped=False)
     return out[:n], count

@@ -395,9 +395,13 @@ class _AdapterTelemetry:
                 engine=self.engine)
 
     def on_moe_tally(self, touched: int, slots: int, assigned: int,
-                     read: int, picks: int, zero: int):
+                     read: int, picks: int, zero: int, rows: int,
+                     rows_hit: int):
         reg = self.registry
         if reg.enabled:
+            c = tmetrics.moe_group_rows_counter(reg)
+            c.inc(rows_hit, engine=self.engine, hit="yes")
+            c.inc(rows - rows_hit, engine=self.engine, hit="no")
             c = tmetrics.moe_experts_counter(reg)
             for count, n in (("touched", touched), ("slots", slots),
                              ("assigned", assigned), ("read", read)):
@@ -1134,7 +1138,7 @@ class PagedEngineAdapter:
             tally = out.get("moe_tally")
             if tally is not None:
                 # a decode step over expert layers: what its routing touched
-                # and its expert path read, counted on the device (five
+                # and its expert path read, counted on the device (six
                 # int32)
                 self._count_moe_tally(np.asarray(tally))
         self.host_stats["blocking_fetches"] += 1
@@ -1143,17 +1147,21 @@ class PagedEngineAdapter:
         return toks[:b] if rows is None else toks[rows]
 
     def _count_moe_tally(self, tally: np.ndarray):
-        """``[touched, assigned, read, picks, identity picks]`` of one
-        decode step (``moe.share_tally`` + ``moe.zero_tally`` summed over
-        the expert layers) into ``host_stats``; the slots the first three
+        """``[touched, assigned, read, picks, identity picks, group hits]``
+        of one decode step (``moe.share_tally`` + ``moe.zero_tally`` +
+        ``moe.group_tally`` summed over the expert layers) into
+        ``host_stats``; the slots the first three
         are counted over are held experts x expert layers, once a step, and
         ``moe_experts_skipped`` is the slots the step did not read (a
         reader that sums and divides cannot subtract). ``moe_assignments``
         is every top-k pick of the live rows, ``moe_assignments_zero`` those
-        that fell to identity experts (``MoESpec.zero_experts``)."""
+        that fell to identity experts (``MoESpec.zero_experts``),
+        ``moe_rows_group_hit`` the live rows x expert layers whose chosen
+        routing groups include one that holds an expert of this chip (every
+        row, for a router without groups)."""
         spec = self.app.spec
         slots = spec.moe.num_held * spec.num_moe_layers
-        touched, assigned, read, picks, zero = (int(n) for n in tally)
+        touched, assigned, read, picks, zero, hit = (int(n) for n in tally)
         st = self.host_stats
         for key, n in (("moe_experts_touched", touched),
                        ("moe_assignments_held", assigned),
@@ -1161,10 +1169,11 @@ class PagedEngineAdapter:
                        ("moe_experts_skipped", slots - read),
                        ("moe_expert_slots", slots),
                        ("moe_assignments", picks),
-                       ("moe_assignments_zero", zero)):
+                       ("moe_assignments_zero", zero),
+                       ("moe_rows_group_hit", hit)):
             st[key] = st.get(key, 0) + n
         self.telemetry.on_moe_tally(touched, slots, assigned, read, picks,
-                                    zero)
+                                    zero, picks // spec.moe.top_k, hit)
 
     def _note_gap(self, states: Sequence[_SeqState]):
         """A decode step's tokens for ``states`` just became host-visible.

@@ -35,6 +35,7 @@ STEPS_PER_FETCH = "nxdi_steps_per_fetch"                # engine
 OVERLAPPED_DISPATCHES_TOTAL = "nxdi_overlapped_dispatches_total"   # engine
 MOE_EXPERTS_TOTAL = "nxdi_moe_experts_total"            # engine, count
 MOE_ASSIGNMENTS_TOTAL = "nxdi_moe_assignments_total"    # engine, kind
+MOE_GROUP_ROWS_TOTAL = "nxdi_moe_group_rows_total"      # engine, hit
 PIPELINE_DRAINS_TOTAL = "nxdi_pipeline_drains_total"    # engine, cause
 
 # -- serving resilience (serving.py + resilience/) --------------------------
@@ -259,6 +260,17 @@ def moe_assignments_counter(reg):
         "expert another chip holds: its part is left out) | zero (an "
         "identity expert: weight x input, no matrices)",
         labels=("engine", "kind"))
+
+
+def moe_group_rows_counter(reg):
+    return reg.counter(
+        MOE_GROUP_ROWS_TOTAL,
+        "The live rows of the decode steps, summed on the device over the "
+        "expert layers, by whether the routing groups a row was limited to "
+        "include one that holds an expert of this chip (in a deployment: "
+        "whether the row's exchange can reach this chip); hit=yes | no. A "
+        "router without groups counts every row under yes",
+        labels=("engine", "hit"))
 
 
 def moe_experts_counter(reg):

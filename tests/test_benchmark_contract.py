@@ -33,9 +33,16 @@ not run).
     depth and the two layouts cut with it, the twin's shrunk window, the
     two pools and the ring re-derived from what the application allocates,
     the reference gating a toy twin, and three faults in the PROGRAM (the
-    window, the router's input, the gate's activation) failing it.
+    window, the router's input, the gate's activation) failing it;
+  * ``deepseek-v3`` (ISSUE 47): every published number but the depth, the
+    leading dense layers, the experts held and the vocabulary, the share's
+    keys and the mix letter for letter, the pool and the parameters by stack
+    re-derived from what the application allocates, the reference gating a
+    toy twin, and three faults in the PROGRAM (the groups, the shared
+    expert, the selection bias) failing it.
 """
 
+import glob
 import json
 import os
 import sys
@@ -1039,5 +1046,216 @@ def test_a_fault_in_the_program_does_not_pass_the_smallthinker_toy_gate(
     else:
         monkeypatch.setitem(model_base.ACT_FNS, "relu", jax.nn.silu)
     res = build.logit_gate(_toy_file(), seed=2**31 + 43,
+                           served_precision="highest")
+    assert not res["passed"] and res["worst_ratio"] > 10
+
+
+# ---------------------------------------------------------------------------
+# deepseek-v3 (ISSUE 47)
+# ---------------------------------------------------------------------------
+
+def test_deepseek_v3_keeps_every_published_number():
+    """The catalog row's ``config`` (model-configs guide), as copied into
+    ISSUE 47: every key at the top level of the file, no width changed, and
+    ``reduced`` names the depth, the leading dense layers, the experts held
+    and the vocabulary, and nothing else."""
+    cfg = build.load_json("configs", "deepseek-v3.json")
+    published = dict(
+        attention_bias=False, ep_size=1, first_k_dense_replace=3,
+        hidden_act="silu", hidden_size=7168, intermediate_size=18432,
+        kv_lora_rank=512, max_position_embeddings=163840,
+        model_type="deepseek_v3", moe_intermediate_size=2048,
+        moe_layer_freq=1, n_group=8, n_routed_experts=256,
+        n_shared_experts=1, norm_topk_prob=True, num_attention_heads=128,
+        num_experts_per_tok=8, num_hidden_layers=61, num_key_value_heads=128,
+        num_nextn_predict_layers=1, q_lora_rank=1536, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, rms_norm_eps=1e-06,
+        rope_scaling={"beta_fast": 32, "beta_slow": 1, "factor": 40,
+                      "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 4096,
+                      "type": "yarn"},
+        rope_theta=10000, routed_scaling_factor=2.5, scoring_func="sigmoid",
+        tie_word_embeddings=False, topk_group=4, topk_method="noaux_tc",
+        v_head_dim=128, vocab_size=129280)
+    assert set(published) <= set(cfg)
+    differs = sorted(k for k in published if cfg[k] != published[k])
+    assert differs == sorted(cfg["reduced"]) == [
+        "first_k_dense_replace", "n_routed_experts", "num_hidden_layers",
+        "vocab_size"]
+    # the cut sits ON the guide's floors: the leading dense layers once and
+    # four expert layers, 16 of 256 experts (one chip of 16 a layer), an
+    # eighth of the vocabulary; the router keeps its published width, its
+    # groups and its top 8
+    assert (cfg["num_hidden_layers"], cfg["first_k_dense_replace"]) == (5, 1)
+    assert cfg["router_n_routed_experts"] == published["n_routed_experts"]
+    assert cfg["n_routed_experts"] * 16 == cfg["router_n_routed_experts"]
+    assert cfg["n_routed_experts"] >= 8 and cfg["first_expert"] == 0
+    assert cfg["vocab_size"] * 8 == published["vocab_size"]
+    assert cfg["family"] == cfg["model_type"] == "deepseek_v3"
+    assert cfg["chips"] == cfg["tp"] == 1
+    assert cfg["adapter"] == {"prefill_budget_tokens": max(
+        cfg["serve"]["context_encoding_buckets"])}
+    assert cfg["serve"]["is_prefix_caching"] is True
+    assert {"kv_dtype", "latent_lanes", "router_dtype", "mtp",
+            "selection_bias_draw", "rope_interleave", "ep_size",
+            "expert_names", "adapter"} <= set(cfg["assumed"])
+    gate = cfg["gate"]
+    twin = build.hf_config(cfg, build.gate_overrides(gate))
+    assert (twin["num_hidden_layers"], twin["first_k_dense_replace"],
+            twin["hidden_size"], twin["n_routed_experts"],
+            twin["router_n_routed_experts"], twin["n_group"]) == \
+        (2, 1, 7168, 16, 256, 8)
+    # eight rows, not ISSUE 47's four: the share's routing flips leave ~3 %
+    # of sound positions over their bound, and 64 decode positions cannot
+    # tell that from a control's 19 % (the file's gate.tolerance_why)
+    assert (gate["batch"], gate["prompt_len"], gate["new_tokens"]) == \
+        (8, 112, 16)
+    assert gate["prompt_len"] + gate["new_tokens"] <= \
+        4 * cfg["serve"]["pa_block_size"]
+    assert 0 < gate["excuse_margin_max"] <= 0.02
+    assert gate["min_positions_held"] >= 0.9
+    for control in ("groups dropped", "scored by its maximum",
+                    "bias dropped", "bias added to the weights",
+                    "renormalisation dropped", "x 2.5 dropped",
+                    "shared expert dropped", "softmax for sigmoid",
+                    "mscale^2 dropped", "yarn's frequencies dropped",
+                    "not interleaved", "fp8"):
+        assert control in gate["controls"], control
+    # the pool cannot run dry: every row at its longest prompt and answer
+    mix = build.load_json("traffic", "longreason-closed.json")
+    serve = cfg["serve"]
+    longest = mix["prompt_len"]["hi"] + mix["output_len"]["hi"]
+    assert longest == serve["seq_len"] == 12288
+    assert serve["pa_num_blocks"] * serve["pa_block_size"] == \
+        serve["batch_size"] * longest
+    # ... and its rows run past yarn's original context
+    assert mix["prompt_len"]["hi"] > cfg["rope_scaling"][
+        "original_max_position_embeddings"]
+    # the mix is ISSUE 47's, letter for letter
+    assert (mix["loop"], mix["clients_per_batch_row"], mix["pool_requests"],
+            mix["lead_s"], mix["grace_s"]) == ("closed", 2, 4096, 30.0, 8.0)
+    assert mix["prompt_len"] == dict(kind="lognormal", median=2048,
+                                     sigma=0.8, lo=256, hi=8192)
+    assert mix["output_len"] == dict(kind="lognormal", median=1536,
+                                     sigma=0.6, lo=384, hi=4096)
+    seeds = [build.load_json("traffic", os.path.basename(p))["base_seed"]
+             for p in sorted(glob.glob(os.path.join(BENCH, "traffic",
+                                                    "*.json")))]
+    assert seeds.count(mix["base_seed"]) == 1
+    cell = bench_run.load_cell("deepseek-v3-longreason-closed")
+    assert {"moe.group_hit_share", "kernel.moe_decode_held_roofline",
+            "kernel.mla_decode_roofline", "moe.experts_touched_share",
+            "moe.prefill_walk_share"} <= {m["name"] for m in cell["per_layer"]}
+
+
+def test_deepseek_v3_allocates_what_its_file_says():
+    """The pool's lanes and bytes a token, the weights and the total of the
+    file's ``memory``, against what the program would allocate: the full
+    configuration's pool and parameters as SHAPES (nothing of 11.6 GB is
+    allocated)."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    from neuronx_distributed_inference_tpu.models import model_base
+    from neuronx_distributed_inference_tpu.modules.block_kv_cache import (
+        latent_page, pool_spec)
+    from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
+    cfg = build.load_json("configs", "deepseek-v3.json")
+    assumed, memory, serve = cfg["assumed"], cfg["memory"], cfg["serve"]
+    spec = build.build_app(cfg).spec
+    assert (spec.num_layers, spec.first_dense, spec.num_attn_layers,
+            spec.num_moe_layers) == (5, 1, 5, 4)
+    m = spec.moe
+    assert (m.num_experts, m.num_routed, m.num_held, m.first_expert,
+            m.top_k, m.n_group, m.topk_group) == (256, 256, 16, 0, 8, 8, 4)
+    assert m.normalize_topk and m.routed_scaling == 2.5
+    assert m.shared_intermediate == 2048 and m.router_act == "sigmoid"
+    assert spec.num_q_heads == 128 and spec.rope.scaling_type == "yarn"
+    assert abs(spec.scale - 192 ** -0.5 * (0.1 * math.log(40) + 1) ** 2) \
+        < 1e-9
+    # one row a token a layer: 576 values in 640 lanes, and no V
+    slots, lanes, v_lanes = latent_page(spec.mla.latent_dim)
+    assert spec.mla.latent_dim == memory["latent_values"] == 576
+    assert (slots, lanes, v_lanes) == (1, memory["latent_lanes"], 0)
+    assert lanes == assumed["latent_lanes"]["lanes"] == 640
+    # what PagedCausalLMApplication.init_cache allocates
+    pool = pool_spec(spec, serve["pa_num_blocks"], serve["pa_block_size"])
+    assert pool.shape == (5, 12289, 32, 1, 640)
+    assert pool.v_shape == (5, 12289, 32, 1, 0)
+    assert str(jnp.dtype(pool.dtype)) == assumed["kv_dtype"]
+    assert pool.bytes_per_token == memory["kv_bytes_per_token"] == \
+        5 * 640 * 2
+    assert math.prod(pool.shape) * 2 == memory["kv_pool_bytes"]
+    # against expanded heads: 64 x
+    assert 5 * 128 * (192 + 128) * 2 == 64 * memory["kv_bytes_per_token"]
+    specs = model_base.decoder_param_specs(spec)
+    is_leaf = dict(is_leaf=lambda x: isinstance(x, ParamSpec))
+
+    def count(tree):
+        return sum(math.prod(ps.shape) for ps in jax.tree.leaves(tree,
+                                                                 **is_leaf))
+    # ISSUE 47's recount, by stack
+    assert count(specs["layers"]) == 583_483_392
+    assert count(specs["moe_layers"]) == 4 * 937_640_192 == \
+        4 * (232_997_120 + 16 * 44_040_192)
+    leaves = jax.tree.leaves(specs, **is_leaf)
+    # the program rounds the vocabulary's 16,160 rows up to whole tiles
+    # (16,256: model_base.pad_vocab), in the embedding and in the head
+    pad = 2 * (spec.padded_vocab - cfg["vocab_size"]) * 7168
+    assert spec.padded_vocab == 16_256
+    assert count(specs) - pad == memory["parameters"] == 583_483_392 \
+        + 4 * 937_640_192 + 2 * 16_160 * 7168 + 7168
+    weights = sum(math.prod(ps.shape) * jnp.dtype(ps.dtype).itemsize
+                  for ps in leaves)
+    # the program's count over the file's all-bf16 one: the padding rows,
+    # and the router and its selection bias in float32 (2 B more an entry)
+    extra = 2 * pad + 2 * 4 * (7168 * 256 + 256)
+    assert weights - extra == memory["weights_bytes"] == \
+        2 * memory["parameters"]
+    total = memory["weights_bytes"] + memory["kv_pool_bytes"]
+    assert total == memory["before_temps_bytes"]
+    assert 0.72 * 16e9 < total < 0.74 * 16e9
+    # with the widest program's temps (tests/test_chip_aot.py) under the
+    # 15.75 GiB a program may use
+    assert total + extra + memory["widest_program_temps_bytes"] \
+        < 15.75 * 2 ** 30 - 1e9
+
+
+def test_the_deepseek_v3_reference_gates_a_toy_twin():
+    from test_deepseek_v3_paged import _toy_file
+    ref = build.load_reference("deepseek_v3")
+    assert ref.__file__ == os.path.join(BENCH, "references",
+                                        "deepseek_v3.py")
+    toy = _toy_file()
+    res = build.logit_gate(toy, seed=2**31 + 47, served_precision="highest")
+    assert res["passed"], res
+    assert res["compared"] == 2 * 28 * toy["vocab_size"]
+
+
+@pytest.mark.parametrize("fault", ["groups", "shared", "bias"])
+def test_a_fault_in_the_program_does_not_pass_the_deepseek_v3_toy_gate(
+        monkeypatch, fault):
+    """The other direction of the controls: the PROGRAM broken, the
+    reference sound. A router that takes the top k of every group, a block
+    without its shared expert, a selection bias that is not added."""
+    import jax.numpy as jnp
+    from neuronx_distributed_inference_tpu.modules import moe
+    from test_deepseek_v3_paged import _toy_file
+    if fault == "groups":
+        monkeypatch.setattr(
+            moe, "chosen_groups",
+            lambda spec, select: jnp.ones(
+                select.shape[:-1] + (spec.n_group,), bool))
+    elif fault == "shared":
+        monkeypatch.setattr(moe, "_shared_experts",
+                            lambda spec, x, y, layer_w: y)
+    else:
+        route = moe.route_groups
+        monkeypatch.setattr(
+            moe, "route_groups",
+            lambda spec, h, router_w, router_bias=None: route(
+                spec, h, router_w, None))
+    res = build.logit_gate(_toy_file(), seed=2**31 + 47,
                            served_precision="highest")
     assert not res["passed"] and res["worst_ratio"] > 10

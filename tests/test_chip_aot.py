@@ -703,6 +703,102 @@ def test_the_widest_longcat_program_fits_beside_weights_and_pool(
         < 15.75 * 2 ** 30 - 258e6
 
 
+# deepseek-ai/DeepSeek-V3 config.json (model-configs catalog), the benchmark's
+# share: one dense layer before four expert layers, 16 routed experts held
+# of 256 in 8 groups, an eighth of the vocabulary
+DEEPSEEK_V3_SHARE = dict(
+    model_type="deepseek_v3", attention_bias=False, first_k_dense_replace=1,
+    hidden_act="silu", hidden_size=7168, intermediate_size=18432,
+    kv_lora_rank=512, max_position_embeddings=163840,
+    moe_intermediate_size=2048, n_group=8, n_routed_experts=16,
+    router_n_routed_experts=256, first_expert=0, n_shared_experts=1,
+    norm_topk_prob=True, num_attention_heads=128, num_experts_per_tok=8,
+    num_hidden_layers=5, num_key_value_heads=128, q_lora_rank=1536,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, rms_norm_eps=1e-6,
+    rope_scaling={"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
+                  "mscale_all_dim": 1,
+                  "original_max_position_embeddings": 4096, "type": "yarn"},
+    rope_theta=10000, routed_scaling_factor=2.5, scoring_func="sigmoid",
+    tie_word_embeddings=False, topk_group=4, v_head_dim=128,
+    vocab_size=16160)
+DEEPSEEK_V3_SERVE = dict(batch_size=32, seq_len=12288, pa_block_size=32,
+                         pa_num_blocks=12288,
+                         context_encoding_buckets=[64, 256])
+
+
+def _deepseek_v3_program(v5e_devices, rows, width):
+    spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
+        DEEPSEEK_V3_SHARE, 5, 1, v5e_devices[:1], DEEPSEEK_V3_SERVE)
+    assert cache["k"].shape == (5, 12289, 32, 1, 640) and mb == 384
+    assert cache["v"].shape == (5, 12289, 32, 1, 0)
+    i32 = jnp.int32
+    notes = set()
+    with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
+        c = jax.jit(partial(model_base.paged_forward_step, spec, tcfg),
+                    donate_argnums=(1,)).lower(
+            params, cache, *(sds((rows, width), i32),) * 3,
+            sds((rows, mb), i32), sds((rows,), i32), None,
+            sds((2,), jnp.uint32)).compile()
+    return c, notes
+
+
+def test_deepseek_v3_decodes_on_both_kernels_at_its_widths(v5e_devices):
+    """ISSUE 47: the decode step at the configuration's size (a dense layer
+    and four expert layers, two scans) holds the latent decode kernel at 128
+    heads, in blocks of 16 pages as at 64 (shorter blocks were slower on the
+    chip: ``mla_decode.block_pages``), and the walk over the touched experts
+    in column pieces (an expert of 7168 x 2048 is 88 MB); the one-row chunk
+    of 256 tokens walks its experts by rows, its float32 rows and result
+    held once (32.1 MiB beside the slots, 46.1 in a pipeline's pairs:
+    declined before); no instruction copies, transposes or relays the
+    latent pool in either."""
+    def pool_movers(text):
+        return [(name, op) for name, shape, op in re.findall(
+            r"%(\S+) = bf16\[([\d,]+)\]\S* (\w[\w-]*)\(", text)
+            if shape in ("5,12289,32,1,640", "5,12289,32,640",
+                         "5,393248,1,640", "61445,32,1,640")
+            and op not in ("parameter", "get-tuple-element", "bitcast",
+                           "fusion", "scatter", "custom-call")]
+    decode, notes = _deepseek_v3_program(v5e_devices, 32, 1)
+    assert ("mla_decode", "pallas",
+            "latent lanes=640 heads=128 form=absorbed pages=16") in notes
+    assert ("moe_decode", "pallas", "pieces=8 of 256") in notes
+    assert ("moe_share", "xla",
+            "held=16 of 256 from 0 top_k=8 groups=8 top=4") in notes
+    text = decode.as_text()
+    assert text.count(MOSAIC) >= 3          # MLA in both scans, the walk
+    assert re.findall(r"%mla_decode_attention[.\d]* = f32\[32,128,512\]",
+                      text)
+    assert not pool_movers(text), pool_movers(text)
+    assert decode.memory_analysis().temp_size_in_bytes < 400e6
+    chunk, notes = _deepseek_v3_program(v5e_devices, 1, 256)
+    assert ("moe_decode", "pallas",
+            "pieces=8 of 256 rows=256 by expert in tiles of 128") in notes
+    assert not any(site == "moe_ragged" for site, _, _ in notes)
+    assert any(site == "mla_prefill" and "prefix=expanded" in why
+               for site, _, why in notes)
+    assert "moe_chunk_experts" in chunk.as_text()
+    assert not pool_movers(chunk.as_text()), pool_movers(chunk.as_text())
+    assert chunk.memory_analysis().temp_size_in_bytes < 400e6
+
+
+def test_the_widest_deepseek_v3_program_fits_beside_weights_and_pool(
+        v5e_devices):
+    """ISSUE 47: ``paged_pack.w256`` at the configuration's size (32 rows x
+    256 tokens over tables of 12288 positions, 128 heads) compiles for a
+    v5e, which refuses a program over 15.75 GiB: 11.67 GB of arguments
+    (weights 9.15, latent pool 2.52) and under 2.2 GB of temps (1.91
+    measured: the expanded prefix a group of 512 tokens at a time, the
+    pack's experts 8 rows at a time)."""
+    pack, notes = _deepseek_v3_program(v5e_devices, 32, 256)
+    assert ("moe_ragged", "row-groups", "8 of 32 rows") in notes
+    memory = pack.memory_analysis()
+    assert 11.6e9 < memory.argument_size_in_bytes < 11.7e9
+    assert memory.temp_size_in_bytes < 2.2e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2 ** 30 - 258e6
+
+
 # PowerInfer/SmallThinker-21BA3B-Instruct config.json (model-configs
 # catalog) at the benchmark's cut: two periods of [global, window x 3]
 SMALLTHINKER_21B = dict(

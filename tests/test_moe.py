@@ -388,9 +388,9 @@ def test_moe_block_takes_a_layer_of_the_stack_for_few_tokens(rng):
                                atol=1e-5)
     assert notes == {("moe_decode", "pallas-interpret", "pieces=1 of 128"),
                      ("moe_decode", "xla", "the caller cut the layer out")}
-    # [touched, assigned, read, picks, identity picks]
-    assert np.asarray(tally_k[0]).tolist() == [2, 8, 2, 8, 0]  # read touched
-    assert np.asarray(tally_d[0]).tolist() == [2, 8, 4, 8, 0]  # read = held
+    # [touched, assigned, read, picks, identity picks, rows in reach]
+    assert np.asarray(tally_k[0]).tolist() == [2, 8, 2, 8, 0, 4]
+    assert np.asarray(tally_d[0]).tolist() == [2, 8, 4, 8, 0, 4]  # all held
 
 
 @pytest.mark.parametrize("path", ["dense", "ragged", "walk", "chunk walk"])
@@ -434,8 +434,9 @@ def test_identity_columns_add_weight_times_input(rng, path):
     assert float(zero_w.max()) > 0
     want = want + zero_w[..., None] * x
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
-    touched, assigned, read, picks, zero = np.asarray(tally[0]).tolist()
-    assert picks == tokens * 3
+    touched, assigned, read, picks, zero, rows = np.asarray(
+        tally[0]).tolist()
+    assert picks == tokens * 3 and rows == tokens
     assert zero == int((np.asarray(idx) >= 8).sum()) > 0
     assert assigned == int(((np.asarray(idx) >= 2)
                             & (np.asarray(idx) < 6)).sum())
@@ -643,10 +644,12 @@ def test_the_scanned_decode_step_reads_the_touched_experts(monkeypatch):
     assert notes == {("xla", "forced")}
     np.testing.assert_allclose(np.asarray(got["logits"]),
                                np.asarray(want["logits"]), atol=2e-5, rtol=0)
-    touched, assigned, read, picks, zero = np.asarray(
+    touched, assigned, read, picks, zero, rows = np.asarray(
         got["moe_tally"]).tolist()
     assert np.asarray(want["moe_tally"]).tolist() == [touched, assigned,
-                                                      3 * 8, picks, zero]
+                                                      3 * 8, picks, zero,
+                                                      rows]
+    assert rows == 3 * 2       # no groups: every live row, every layer
     assert (picks, zero) == (assigned, 0)    # every expert is held here
     assert assigned == 3 * 2 * 2             # layers x live rows x top-k
     assert 0 < touched <= read < 3 * 8

@@ -414,15 +414,16 @@ def _no_shared_expert_gate(monkeypatch):
 def _renormalised_over_the_held_only(monkeypatch):
     """The top-k probabilities renormalised over those of them that fell to
     held experts, instead of over all ten."""
-    route = moe.route
+    route = moe.route_groups
 
     def held_only(spec, h, router_w, router_bias=None):
-        vals, idx = route(spec, h, router_w, router_bias)
+        vals, idx, groups = route(spec, h, router_w, router_bias)
         mine = (idx >= spec.first_expert) & (
             idx < spec.first_expert + spec.held_experts)
         kept = jnp.where(mine, vals, 0.0)
-        return kept / jnp.maximum(kept.sum(-1, keepdims=True), 1e-20), idx
-    monkeypatch.setattr(moe, "route", held_only)
+        return (kept / jnp.maximum(kept.sum(-1, keepdims=True), 1e-20), idx,
+                groups)
+    monkeypatch.setattr(moe, "route_groups", held_only)
 
 
 def _keys_tiled_over_value_heads(monkeypatch):

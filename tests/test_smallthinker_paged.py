@@ -422,15 +422,16 @@ def test_d_the_routing_reads_a_and_the_experts_m(monkeypatch):
         "expert_down": jnp.asarray(rng.normal(size=(8, 32, 16)), jnp.float32)}
     seen = {}
     route, experts = moe.route, moe._experts
+    route_groups = moe.route_groups
 
     def spy_route(spec_, h, *rest):
         seen["route"] = h
-        return route(spec_, h, *rest)
+        return route_groups(spec_, h, *rest)
 
     def spy_experts(spec_, x, *rest):
         seen["experts"] = x
         return experts(spec_, x, *rest)
-    monkeypatch.setattr(moe, "route", spy_route)
+    monkeypatch.setattr(moe, "route_groups", spy_route)
     monkeypatch.setattr(moe, "_experts", spy_experts)
     tally = []
     got = moe.moe_block(spec, m, layer_w, tally=tally, router_x=a)
@@ -596,14 +597,17 @@ def test_e_the_walk_by_kind_serves_the_logits_of_the_one_pool():
 #: tree: a T = 1 step's two conv tails a Mamba layer slide by static slices
 #: there (``ssm._next_tail``) where ``_conv_tail`` gathered (fewer gathers,
 #: adds, selects, constants and broadcasts; nothing else moved, and the
-#: toy's ``(16, 16)`` tile keeps the XLA state step)
+#: toy's ``(16, 16)`` tile keeps the XLA state step). PR 47 gave the routing
+#: tally a sixth count, the live rows whose groups reach this chip
+#: (``moe.group_tally``; every live row for a router without groups): two
+#: constants, a broadcast and a reshape more in OLMoE's step
 PARENT_CENSUS = {
     "olmoe": {"chlo.top_k": 1, "func.call": 1, "func.func": 9,
-              "stablehlo.add": 34, "stablehlo.broadcast_in_dim": 161,
-              "stablehlo.constant": 124, "stablehlo.dot_general": 10,
+              "stablehlo.add": 34, "stablehlo.broadcast_in_dim": 162,
+              "stablehlo.constant": 126, "stablehlo.dot_general": 10,
               "stablehlo.dynamic_slice": 11,
               "stablehlo.dynamic_update_slice": 1, "stablehlo.gather": 5,
-              "stablehlo.multiply": 31, "stablehlo.reshape": 32,
+              "stablehlo.multiply": 31, "stablehlo.reshape": 33,
               "stablehlo.scatter": 4, "stablehlo.select": 20,
               "stablehlo.while": 1},
     "granite": {"func.func": 18, "stablehlo.add": 79,
