@@ -974,14 +974,14 @@ def _mla_attend(spec: DecoderSpec, q_nope, q_rot, lat_new, w_kvb, pool, li,
 def _mla_paged_block(spec: DecoderSpec, h, layer_w, pool, li, cos, sin,
                      positions, slot_mapping, block_table):
     """The paged attention of an MLA layer over the LATENT pool: project,
-    write the step's latent rows at ``slot_mapping``, attend. A decode step
-    (T = 1) attends in the latent space, on the kernel
-    (``ops/mla_decode.py``) where it engages; a chunk takes the form
+    write the step's latent rows at ``slot_mapping``, attend in the latent
+    space on a kernel where one engages (T = 1: ``ops/mla_decode.py``; a
+    chunk: ``ops/mla_prefill.py``); a declined chunk takes the XLA form
     :data:`MLA_EXPAND_MIN_QUERIES` gives its width, over exactly the prefix
     its rows have cached. Returns the heads' outputs (B,T,Hq x v) and the
     pool."""
     from ..modules import block_kv_cache as bkv
-    from ..ops import mla_decode
+    from ..ops import mla_decode, mla_prefill
     m = spec.mla
     b, t, _ = h.shape
     q_nope, q_rot, lat = _mla_project(spec, h, layer_w, cos, sin)
@@ -1012,8 +1012,8 @@ def _mla_paged_block(spec: DecoderSpec, h, layer_w, pool, li, cos, sin,
             "mla_decode", "xla" if declined else kernel_mode.kernel_path(),
             declined or mla_decode.plan_note(pool, q_nope.shape[2]))
     else:
-        kernel_mode.note(
-            "mla_prefill", "xla",
+        out = mla_prefill.chunk_attention(
+            spec, q_nope, q_rot, w_kvb, pool, li, positions, block_table,
             f"rows={b} width={t} prefix="
             + ("absorbed" if absorbed else "expanded through kv_b_proj")
             + f" in groups of {MLA_PREFIX_GROUP_TOKENS} tokens, own tokens "

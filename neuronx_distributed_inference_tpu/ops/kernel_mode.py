@@ -78,3 +78,11 @@ def state_on_kernel(notes) -> bool:
     (``models/model_base.py`` ``run_layers_ssm`` writes them)."""
     return any(site == "recurrent_state" and path != "xla"
                for site, path, _ in notes)
+
+
+def prefill_attn_on_kernel(notes) -> bool:
+    """Whether a chunk program ran its attention on a prefill kernel, from
+    the :func:`note` triples its trace left (``ops/mla_prefill.py``
+    ``chunk_attention`` writes them)."""
+    return any(site == "mla_prefill" and path != "xla"
+               for site, path, _ in notes)

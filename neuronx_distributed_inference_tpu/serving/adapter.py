@@ -868,6 +868,9 @@ class PagedEngineAdapter:
             # record says its routed experts took the walk over the
             # touched experts (kernel_mode.experts_path)
             "prefill_dispatches": 0, "prefill_dispatches_moe_walk": 0,
+            # ... and those whose program ran its attention on the prefill
+            # kernel (kernel_mode.prefill_attn_on_kernel)
+            "prefill_dispatches_attn_kernel": 0,
             "prefill_blocking_fetches": 0,
             "prefill_blocked_s": 0.0, "prefill_real_tokens": 0,
             "prefill_padded_tokens": 0}
@@ -2556,10 +2559,12 @@ class PagedEngineAdapter:
         self.host_stats["prefill_dispatches"] += 1
         # which expert path THIS program took, read from the record its
         # trace left (the rule itself lives in moe.takes_ragged alone)
-        experts = kernel_mode.experts_path(
-            self.app.paged_program_notes(*ids_p.shape))
+        notes = self.app.paged_program_notes(*ids_p.shape)
+        experts = kernel_mode.experts_path(notes)
         if experts == "walk":
             self.host_stats["prefill_dispatches_moe_walk"] += 1
+        if kernel_mode.prefill_attn_on_kernel(notes):
+            self.host_stats["prefill_dispatches_attn_kernel"] += 1
         self.telemetry.on_prefill_dispatch(experts)
         return out
 

@@ -623,40 +623,53 @@ LONGCAT_FLASH_SHARE = dict(
     moe_topk=12)
 
 
+LONGCAT_FLASH_SERVE = dict(batch_size=32, seq_len=8192, pa_block_size=32,
+                           pa_num_blocks=8192,
+                           context_encoding_buckets=[64, 256])
+
+
+def _longcat_program(v5e_devices, rows, width):
+    """One layer (two latent-attention sub-blocks) at the share's widths."""
+    spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
+        LONGCAT_FLASH_SHARE, 1, 1, v5e_devices[:1], LONGCAT_FLASH_SERVE)
+    assert cache["k"].shape == (2, 8193, 32, 1, 640)
+    assert cache["v"].shape == (2, 8193, 32, 1, 0)
+    i32 = jnp.int32
+    notes = set()
+    with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
+        c = jax.jit(partial(model_base.paged_forward_step, spec, tcfg),
+                    donate_argnums=(1,)).lower(
+            params, cache, *(sds((rows, width), i32),) * 3,
+            sds((rows, mb), i32), sds((rows,), i32), None,
+            sds((2,), jnp.uint32)).compile()
+    return c, notes
+
+
+def _pool_movers(text, shapes):
+    """Instructions that copy, transpose or relay an array of a latent
+    pool's shape (``shapes``: the pool and its flat views)."""
+    return [(name, op) for name, shape, op in re.findall(
+        r"%(\S+) = bf16\[([\d,]+)\]\S* (\w[\w-]*)\(", text)
+        if shape in shapes
+        and op not in ("parameter", "get-tuple-element", "bitcast",
+                       "fusion", "scatter", "custom-call")]
+
+
+LONGCAT_POOL = ("2,8193,32,1,640", "2,8193,32,640", "2,262176,1,640",
+                "16386,32,1,640")
+DEEPSEEK_V3_POOL = ("5,12289,32,1,640", "5,12289,32,640", "5,393248,1,640",
+                    "61445,32,1,640")
+
+
 def test_latent_decode_runs_its_kernel_on_the_pool_in_place(v5e_devices):
     """ISSUE 40: the decode step at LongCat-Flash's widths (one layer: two
     latent-attention sub-blocks, a pool of 8192 blocks of 640-lane rows)
     holds the ``mla_decode_attention`` call twice and the walk over the
     touched experts in column pieces (an expert of 6144 x 2048 is 75.5 MB,
     three times the walk's slots); no instruction copies, transposes or
-    relays the latent pool; the V pool has no bytes; and a one-row chunk
-    behind a prefix gathers its groups from the pool in place too."""
-    serve = dict(batch_size=32, seq_len=8192, pa_block_size=32,
-                 pa_num_blocks=8192, context_encoding_buckets=[64, 256])
-    spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
-        LONGCAT_FLASH_SHARE, 1, 1, v5e_devices[:1], serve)
-    assert cache["k"].shape == (2, 8193, 32, 1, 640)
-    assert cache["v"].shape == (2, 8193, 32, 1, 0)
-    i32 = jnp.int32
-    step = partial(model_base.paged_forward_step, spec, tcfg)
-
-    def compiled(rows, width):
-        notes = set()
-        with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
-            c = jax.jit(step, donate_argnums=(1,)).lower(
-                params, cache, *(sds((rows, width), i32),) * 3,
-                sds((rows, mb), i32), sds((rows,), i32), None,
-                sds((2,), jnp.uint32)).compile()
-        return c, notes
-
-    def pool_movers(text):
-        return [(name, op) for name, shape, op in re.findall(
-            r"%(\S+) = bf16\[([\d,]+)\]\S* (\w[\w-]*)\(", text)
-            if shape in ("2,8193,32,1,640", "2,8193,32,640",
-                         "2,262176,1,640", "16386,32,1,640")
-            and op not in ("parameter", "get-tuple-element", "bitcast",
-                           "fusion", "scatter", "custom-call")]
-    decode, notes = compiled(32, 1)
+    relays the latent pool; the V pool has no bytes. (The one-row chunk:
+    ``test_a_latent_chunk_attends_on_the_prefill_kernel``.)"""
+    decode, notes = _longcat_program(v5e_devices, 32, 1)
     assert ("mla_decode", "pallas",
             "latent lanes=640 heads=64 form=absorbed pages=16") in notes
     assert ("moe_decode", "pallas", "pieces=8 of 256") in notes
@@ -666,12 +679,54 @@ def test_latent_decode_runs_its_kernel_on_the_pool_in_place(v5e_devices):
     calls = re.findall(r"%(mla_decode_attention[.\d]*) = f32\[32,64,512\]",
                        text)
     assert len(calls) == 2, calls
-    assert not pool_movers(text), pool_movers(text)
+    assert not _pool_movers(text, LONGCAT_POOL)
     assert decode.memory_analysis().temp_size_in_bytes < 400e6
-    chunk, notes = compiled(1, 256)
-    assert any(site == "mla_prefill" and "prefix=expanded" in why
-               for site, _, why in notes)
-    assert not pool_movers(chunk.as_text()), pool_movers(chunk.as_text())
+
+
+@pytest.mark.parametrize("cell, heads, width", [("longcat", 64, 256),
+                                                ("longcat", 64, 64),
+                                                ("deepseek-v3", 128, 256)],
+                         ids=["longcat-w256", "longcat-w64",
+                              "deepseek-v3-w256"])
+def test_a_latent_chunk_attends_on_the_prefill_kernel(v5e_devices, cell,
+                                                      heads, width):
+    """ISSUE 48: the one-row chunk programs of both latent cells, at their
+    widths (the benchmark's ``paged.w256`` of each, and LongCat's
+    ``paged.w64``), hold ``mla_prefill_attention``, lowered ONCE a call
+    site whatever the depth (LongCat's layer: its two sub-blocks;
+    DeepSeek-V3: its two scans); the kernel reads the pool in place (no
+    instruction copies, transposes or relays it) and NO float32 tensor of
+    ``heads x T x tokens`` (a group of 512 tokens' scores: 32 MB at 64
+    heads x 256, 64 MB at 128) is left in the program. DeepSeek-V3's chunk
+    still walks its experts by rows."""
+    if cell == "longcat":
+        chunk, notes = _longcat_program(v5e_devices, 1, width)
+        pool = LONGCAT_POOL
+    else:
+        chunk, notes = _deepseek_v3_program(v5e_devices, 1, width)
+        pool = DEEPSEEK_V3_POOL
+        assert ("moe_decode", "pallas",
+                "pieces=8 of 256 rows=256 by expert in tiles of 128") in notes
+        assert not any(site == "moe_ragged" for site, _, _ in notes)
+    assert {(path, why) for site, path, why in notes
+            if site == "mla_prefill"} == {
+        ("pallas", f"rows=1 width={width} latent lanes=640 heads={heads} "
+         f"form=absorbed tile=8x{width} pages=16 folds and own tokens "
+         "inside")}
+    text = chunk.as_text()
+    assert len(set(re.findall(
+        rf"%(mla_prefill_attention[.\d]*) = bf16\[1,{width},{heads * 128}\]",
+        text))) == 2
+    if cell == "deepseek-v3":
+        assert "moe_chunk_experts" in text
+    assert not _pool_movers(text, pool), _pool_movers(text, pool)
+    scores = heads * width * 512
+    big = [(name, shape) for name, shape in re.findall(
+        r"%(\S+) = f32\[([\d,]+)\]", text)
+        if str(heads) in shape.split(",")
+        and math.prod(int(d) for d in shape.split(",")) >= scores // 2]
+    assert not big, big[:5]
+    assert chunk.memory_analysis().temp_size_in_bytes < 400e6
 
 
 def test_the_widest_longcat_program_fits_beside_weights_and_pool(
@@ -684,10 +739,8 @@ def test_the_widest_longcat_program_fits_beside_weights_and_pool(
     (2.25 GB of float32 outputs alone), so a pack's rows go through it in
     groups under the attention scores' budget (``experts_ragged_by_rows``);
     and the prefix walk's scores are a group of 512 tokens, not the table."""
-    serve = dict(batch_size=32, seq_len=8192, pa_block_size=32,
-                 pa_num_blocks=8192, context_encoding_buckets=[64, 256])
     spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
-        LONGCAT_FLASH_SHARE, 4, 1, v5e_devices[:1], serve)
+        LONGCAT_FLASH_SHARE, 4, 1, v5e_devices[:1], LONGCAT_FLASH_SERVE)
     i32 = jnp.int32
     notes = set()
     with jax.sharding.set_mesh(mesh), kernel_mode.recording(notes):
@@ -750,15 +803,9 @@ def test_deepseek_v3_decodes_on_both_kernels_at_its_widths(v5e_devices):
     in column pieces (an expert of 7168 x 2048 is 88 MB); the one-row chunk
     of 256 tokens walks its experts by rows, its float32 rows and result
     held once (32.1 MiB beside the slots, 46.1 in a pipeline's pairs:
-    declined before); no instruction copies, transposes or relays the
-    latent pool in either."""
-    def pool_movers(text):
-        return [(name, op) for name, shape, op in re.findall(
-            r"%(\S+) = bf16\[([\d,]+)\]\S* (\w[\w-]*)\(", text)
-            if shape in ("5,12289,32,1,640", "5,12289,32,640",
-                         "5,393248,1,640", "61445,32,1,640")
-            and op not in ("parameter", "get-tuple-element", "bitcast",
-                           "fusion", "scatter", "custom-call")]
+    declined before: ``test_a_latent_chunk_attends_on_the_prefill_kernel``
+    holds that); no instruction copies, transposes or relays the latent
+    pool."""
     decode, notes = _deepseek_v3_program(v5e_devices, 32, 1)
     assert ("mla_decode", "pallas",
             "latent lanes=640 heads=128 form=absorbed pages=16") in notes
@@ -769,17 +816,8 @@ def test_deepseek_v3_decodes_on_both_kernels_at_its_widths(v5e_devices):
     assert text.count(MOSAIC) >= 3          # MLA in both scans, the walk
     assert re.findall(r"%mla_decode_attention[.\d]* = f32\[32,128,512\]",
                       text)
-    assert not pool_movers(text), pool_movers(text)
+    assert not _pool_movers(text, DEEPSEEK_V3_POOL)
     assert decode.memory_analysis().temp_size_in_bytes < 400e6
-    chunk, notes = _deepseek_v3_program(v5e_devices, 1, 256)
-    assert ("moe_decode", "pallas",
-            "pieces=8 of 256 rows=256 by expert in tiles of 128") in notes
-    assert not any(site == "moe_ragged" for site, _, _ in notes)
-    assert any(site == "mla_prefill" and "prefix=expanded" in why
-               for site, _, why in notes)
-    assert "moe_chunk_experts" in chunk.as_text()
-    assert not pool_movers(chunk.as_text()), pool_movers(chunk.as_text())
-    assert chunk.memory_analysis().temp_size_in_bytes < 400e6
 
 
 def test_the_widest_deepseek_v3_program_fits_beside_weights_and_pool(

@@ -184,7 +184,10 @@ def test_a_three_chunks_then_decode_on_the_kernels(ref, gate_weights,
     assert notes[("moe_decode", "pallas-interpret")] == "pieces=1 of 128"
     assert notes[("moe_share", "xla")] == \
         "held=4 of 16 from 4 top_k=4 groups=4 top=2"
+    # heads of 16 lanes are not the prefill kernel's: the XLA form, and why
     assert "absorbed" in notes[("mla_prefill", "xla")]
+    assert notes[("mla_prefill", "xla")].endswith(
+        "(a head's nope or value lanes not whole vregs)")
     assert app.spec.first_dense == 1 and app.spec.num_moe_layers == 2
     assert app.params["layers"]["gate_proj"].shape == (1, 128, 192)
     assert app.params["moe_layers"]["expert_gate"].shape == (2, 4, 128, 128)
@@ -237,7 +240,49 @@ def test_a_a_chunk_of_more_rows_than_a_tile_walks_by_expert(ref,
     assert ("moe_decode",
             "pieces=1 of 128 rows=160 by expert in tiles of 128") in reasons
     assert any(site == "mla_prefill" and "width=160 prefix=expanded" in why
+               and why.endswith("nope or value lanes not whole vregs)")
                for site, why in reasons)
+
+
+#: the toy model with heads of whole vregs (nope and value lanes 128): what
+#: ``ops/mla_prefill.py`` takes (interpret mode)
+HF_KERNEL = dict(HF, qk_nope_head_dim=128, v_head_dim=128)
+
+
+def test_a_chunks_on_the_prefill_kernel_a_pack_and_a_declined_width(ref):
+    """Heads of whole vregs: the 32-wide chunks of P69 run their attention on
+    ``mla_prefill_attention`` (the second behind the first's cached prefix,
+    own rows read from the pool), the 8-wide last chunk is declined by its
+    width and takes the XLA form, a full-batch pack of two prompts takes the
+    kernel with the rows as its grid: the reference's logits at every
+    position, and ``host_stats`` counts which dispatches the kernel served."""
+    w = weights.make_weights(ref.weight_shapes(HF_KERNEL), seed=2**31 + 48)
+    app = _app(ref, w, HF_KERNEL)
+    ad = PagedEngineAdapter(app)
+    tap = LogitTap(app)
+    stream = {7: [ad.add_requests([7], [P69])[7]]}
+    assert tap.shapes == [(1, 32), (1, 32), (1, 8)]
+    st = ad.host_stats
+    assert (st["prefill_dispatches"], st["prefill_dispatches_attn_kernel"]) \
+        == (3, 2)
+    first = ad.add_requests([1, 2], [Q45, R21])
+    stream.update({1: [first[1]], 2: [first[2]]})
+    assert tap.shapes[3:] == [(BATCH, 32), (1, 32)]     # Q45's last 13
+    assert (st["prefill_dispatches"], st["prefill_dispatches_attn_kernel"]) \
+        == (5, 4)
+    _decode(ad, [7, 1, 2], stream, 3)
+    for sid, prompt in ((7, P69), (1, Q45), (2, R21)):
+        _check(tap, ref, w, sid, prompt, stream[sid], HF_KERNEL)
+    notes = {(k["site"], k["path"], k["reason"])
+             for k in app.warmup_state()["kernels"] if k["site"] == "mla_prefill"}
+    plan = ("latent lanes=256 heads=4 form=absorbed tile=4x32 pages=16 "
+            "folds and own tokens inside")
+    assert notes == {
+        ("mla_prefill", "pallas-interpret", f"rows=1 width=32 {plan}"),
+        ("mla_prefill", "pallas-interpret", f"rows={BATCH} width=32 {plan}"),
+        ("mla_prefill", "xla",
+         "rows=1 width=8 prefix=absorbed in groups of 512 tokens, own tokens "
+         "expanded (8 queries a row are not whole sublanes)")}
 
 
 # ---------------------------------------------------------------------------

@@ -171,7 +171,11 @@ def test_a_three_chunks_then_decode_on_the_kernel(ref, gate_weights,
         "latent lanes=256 heads=4 form=absorbed pages=16"
     assert notes["moe_share"]["reason"] == \
         "held=4 of 12 from 2 top_k=3 zero=4"
+    # heads of 16 lanes are not the prefill kernel's: the XLA form, and why
+    assert notes["mla_prefill"]["path"] == "xla"
     assert "absorbed" in notes["mla_prefill"]["reason"]
+    assert notes["mla_prefill"]["reason"].endswith(
+        "(a head's nope or value lanes not whole vregs)")
 
 
 @pytest.mark.parametrize("decode, experts", [("xla", "dense"),
@@ -212,6 +216,54 @@ def test_a_wide_chunk_expands_the_prefix(ref, gate_weights, monkeypatch):
                if k["site"] == "mla_prefill"}
     assert any("width=32 prefix=expanded" in r for r in reasons)
     assert any("width=8 prefix=absorbed" in r for r in reasons)
+    assert all(r.endswith("nope or value lanes not whole vregs)")
+               for r in reasons)
+
+
+#: the toy model with heads of whole vregs (nope and value lanes 128): what
+#: ``ops/mla_prefill.py`` takes (interpret mode)
+HF_KERNEL = dict(HF, qk_nope_head_dim=128, v_head_dim=128)
+
+
+@pytest.mark.parametrize("prefill", ["kernel", "xla"])
+def test_a_chunks_on_the_prefill_kernel_or_declined_by_the_switch(
+        ref, monkeypatch, prefill):
+    """Heads of whole vregs: every sub-block of a 32-wide chunk runs its
+    attention on ``mla_prefill_attention`` (the second chunk behind the
+    first's cached prefix), the 8-wide last chunk is declined by its width;
+    ``decode_kernel=False`` declines them all. The reference's logits either
+    way, and ``host_stats`` counts which dispatches the kernel served."""
+    if prefill == "xla":
+        _respec(monkeypatch, decode_kernel=False)
+    w = weights.make_weights(ref.weight_shapes(HF_KERNEL), seed=2**31 + 48)
+    app = _app(ref, w, HF_KERNEL)
+    ad = PagedEngineAdapter(app)
+    tap = LogitTap(app)
+    stream = {7: [ad.add_requests([7], [P69])[7]]}
+    assert tap.shapes == [(1, 32), (1, 32), (1, 8)]
+    _decode(ad, [7], stream, 3)
+    fed = P69 + stream[7][:-1]
+    want = _want(ref, w, fed, HF_KERNEL)
+    np.testing.assert_allclose(tap.logits(7, len(fed)), want, atol=ATOL)
+    assert stream[7] == want[len(P69) - 1:].argmax(-1).tolist()
+    st = ad.host_stats
+    assert (st["prefill_dispatches"], st["prefill_dispatches_attn_kernel"]) \
+        == (3, 2 if prefill == "kernel" else 0)
+    notes = {(k["path"], k["reason"]) for k in app.warmup_state()["kernels"]
+             if k["site"] == "mla_prefill"}
+    narrow = ("rows=1 width=8 prefix=absorbed in groups of 512 tokens, own "
+              "tokens expanded")
+    if prefill == "kernel":
+        assert notes == {
+            ("pallas-interpret",
+             "rows=1 width=32 latent lanes=256 heads=4 form=absorbed "
+             "tile=4x32 pages=16 folds and own tokens inside"),
+            ("xla", f"{narrow} (8 queries a row are not whole sublanes)")}
+    else:
+        assert notes == {
+            ("xla", "rows=1 width=32 prefix=absorbed in groups of 512 tokens, "
+             "own tokens expanded (decode_kernel=False)"),
+            ("xla", f"{narrow} (decode_kernel=False)")}
 
 
 # ---------------------------------------------------------------------------
