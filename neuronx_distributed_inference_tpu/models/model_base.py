@@ -43,6 +43,7 @@ from ..ops import attention as attn_ops
 from ..ops import decode_attention
 from ..ops import flash_attention
 from ..ops import kernel_mode
+from ..ops import paged_prefill
 from ..ops import sampling as sampling_ops
 from ..ops.normalization import layer_norm, rms_norm
 from ..ops.rope import RopeConfig, apply_rope, rope_cos_sin
@@ -1477,26 +1478,30 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
         # below materializes the whole table per layer per token. Default-on
         # for single-token paged decode (decode_kernel None/True).
         use_pkernel = False
+        # the layer's window and where it reads: static per kind under a
+        # window pool's walk (a window layer's table is the row's ring as
+        # LOGICAL pages, and a kernel reads none in front of the window's
+        # first), traced under a layer_pattern on one pool
+        if mixed_local is not None:
+            win = jnp.asarray(
+                spec.sliding_window if mixed_local else 0, jnp.int32)
+            win_note = (f" window={spec.sliding_window} ring="
+                        f"{ring['pages']}" if mixed_local else " window=0")
+        elif spec.layer_pattern is not None:
+            win = jnp.where(is_local, spec.sliding_window, 0)
+            win_note = f" window={spec.sliding_window} by layer"
+        else:
+            win = jnp.asarray(spec.sliding_window, jnp.int32)
+            win_note = f" window={spec.sliding_window}"
+        kernel_table = block_table if ring is None else ring["kernel_table"]
         if h.shape[1] == 1:
             # every decline leaves a note (ops/kernel_mode.py): a decode
             # graph on the full-table gather path is never a silent choice
             declined = _paged_kernel_declined(spec)
             if not declined:
-                if mixed_local is not None:
-                    # static per kind (a window pool's walk); a window
-                    # layer's table is the row's ring as LOGICAL pages, and
-                    # the kernel reads none in front of the window's first
-                    win = jnp.asarray(
-                        spec.sliding_window if mixed_local else 0, jnp.int32)
-                elif spec.layer_pattern is not None:
-                    win = jnp.where(is_local, spec.sliding_window, 0)
-                else:
-                    win = jnp.asarray(spec.sliding_window, jnp.int32)
                 kernel_out = decode_attention.paged_dispatch(
                     q[:, 0], k_full, v_full, k[:, 0], v[:, 0], li,
-                    positions[:, 0],
-                    block_table if ring is None else ring["kernel_table"],
-                    scale=spec.scale,
+                    positions[:, 0], kernel_table, scale=spec.scale,
                     window=win, soft_cap=spec.attn_soft_cap, sink=sink,
                     kv_scale=spec.kv_scale,
                     interpret=kernel_mode.pallas_interpret())
@@ -1514,10 +1519,16 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                                  q.shape[2], spec.head_dim, k_full,
                                  block_table.shape[1]).note(
                                      k_full.shape[4] != spec.head_dim)
-                             + ("" if mixed_local is None else
-                                f" window={spec.sliding_window} ring="
-                                f"{ring['pages']}" if mixed_local
-                                else " window=0"))
+                             + ("" if mixed_local is None else win_note))
+        else:
+            # a chunk walks its rows' live pages on the prefill kernel
+            # (ops/paged_prefill.py), its own K / V read from the pool they
+            # were just written to; what it declines (and says so) gathers
+            # the table below
+            attn_out = paged_prefill.chunk_attention(
+                spec, q, k_full, v_full, li, positions, kernel_table, win,
+                win_note)
+            use_pkernel = attn_out is not None
         if not use_pkernel:
             def gathered_mha(q_, bt_, mask_):
                 def gathered(pool):

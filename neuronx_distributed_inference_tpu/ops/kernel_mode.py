@@ -86,3 +86,11 @@ def prefill_attn_on_kernel(notes) -> bool:
     ``chunk_attention`` writes them)."""
     return any(site == "mla_prefill" and path != "xla"
                for site, path, _ in notes)
+
+
+def paged_prefill_on_kernel(notes) -> bool:
+    """Whether a chunk program attended over the K / V pools on the paged
+    prefill kernel, from the :func:`note` triples its trace left
+    (``ops/paged_prefill.py`` ``chunk_attention`` writes them)."""
+    return any(site == "paged_prefill" and path != "xla"
+               for site, path, _ in notes)

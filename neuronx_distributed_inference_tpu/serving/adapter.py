@@ -309,11 +309,11 @@ class _AdapterTelemetry:
                 1.0 - real_tokens / padded_tokens, engine=self.engine)
         self._rows(reg, "prefill", rows, padded_rows)
 
-    def on_prefill_dispatch(self, experts: str):
+    def on_prefill_dispatch(self, experts: str, attn: str):
         reg = self.registry
         if reg.enabled:
             tmetrics.prefill_dispatches_counter(reg).inc(
-                engine=self.engine, experts=experts)
+                engine=self.engine, experts=experts, attn=attn)
 
     def on_step(self, live_ids: Sequence[int], t0: float, padded: int,
                 steps: int = 1):
@@ -868,9 +868,12 @@ class PagedEngineAdapter:
             # record says its routed experts took the walk over the
             # touched experts (kernel_mode.experts_path)
             "prefill_dispatches": 0, "prefill_dispatches_moe_walk": 0,
-            # ... and those whose program ran its attention on the prefill
-            # kernel (kernel_mode.prefill_attn_on_kernel)
+            # ... and those whose program ran its attention on a prefill
+            # kernel: over the latent pool (kernel_mode.
+            # prefill_attn_on_kernel), over the K / V pools
+            # (kernel_mode.paged_prefill_on_kernel)
             "prefill_dispatches_attn_kernel": 0,
+            "prefill_dispatches_paged_attn_kernel": 0,
             "prefill_blocking_fetches": 0,
             "prefill_blocked_s": 0.0, "prefill_real_tokens": 0,
             "prefill_padded_tokens": 0}
@@ -2563,9 +2566,14 @@ class PagedEngineAdapter:
         experts = kernel_mode.experts_path(notes)
         if experts == "walk":
             self.host_stats["prefill_dispatches_moe_walk"] += 1
+        attn = "xla"
         if kernel_mode.prefill_attn_on_kernel(notes):
             self.host_stats["prefill_dispatches_attn_kernel"] += 1
-        self.telemetry.on_prefill_dispatch(experts)
+            attn = "latent"
+        if kernel_mode.paged_prefill_on_kernel(notes):
+            self.host_stats["prefill_dispatches_paged_attn_kernel"] += 1
+            attn = "paged"
+        self.telemetry.on_prefill_dispatch(experts, attn)
         return out
 
     def _fetch_prefill_tokens(self, out) -> np.ndarray:

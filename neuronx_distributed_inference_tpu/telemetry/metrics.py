@@ -24,7 +24,7 @@ REQUESTS_TOTAL = "nxdi_requests_total"                # event=added|released
 # -- chunked prefill (serving.py PagedEngineAdapter) -------------------------
 PREFILL_CHUNKS_TOTAL = "nxdi_prefill_chunks_total"      # engine
 PREFILL_PAD_WASTE = "nxdi_prefill_pad_waste"            # engine
-PREFILL_DISPATCHES_TOTAL = "nxdi_prefill_dispatches_total"   # engine, experts
+PREFILL_DISPATCHES_TOTAL = "nxdi_prefill_dispatches_total"   # engine, experts, attn
 
 # -- serving engine (serving/engine/) ----------------------------------------
 QUEUE_DEPTH = "nxdi_queue_depth"                        # tenant
@@ -239,8 +239,10 @@ def prefill_dispatches_counter(reg):
         "engagement record names (ops/kernel_mode.py experts_path): "
         "experts=walk (the kernel over the touched experts) | ragged (the "
         "grouped matmuls) | dense (all experts in an einsum) | none (no "
-        "routed block)",
-        labels=("engine", "experts"))
+        "routed block), and by the attention of a chunk: attn=paged (the "
+        "prefill kernel over the K / V pools, ops/paged_prefill.py) | latent "
+        "(the one over the latent pool, ops/mla_prefill.py) | xla",
+        labels=("engine", "experts", "attn"))
 
 
 def overlapped_dispatches_counter(reg):

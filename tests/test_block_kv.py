@@ -360,16 +360,28 @@ def test_heads_of_64_two_to_a_slot_serve_the_same_tokens_and_bytes(
         out[2].append(ad.add_requests([2], [c])[2])
         decode(2)
         kernels = {k["site"]: k["reason"]
-                   for k in app.warmup_state()["kernels"]}
+                   for k in app.warmup_state()["kernels"]
+                   if k["site"] != "paged_prefill"}
+        kernels["chunks"] = {
+            (k["path"], k["reason"].split(": ")[-1].split(" pages=")[-1])
+            for k in app.warmup_state()["kernels"]
+            if k["site"] == "paged_prefill"}
         return out, kernels, {
             k: np.asarray(app.cache[k]).view(np.uint8) for k in "kv"}
 
     app = paged_app()
     assert app.cache["k"].shape == (2, app.cache["k"].shape[1], 8, 4, 128)
     got, kernels, pool = serve(app)
+    # the chunks walk the stored page on the prefill kernel (two heads to a
+    # 128-lane row, the queries placed in their own lanes); an fp8 pool
+    # keeps the gather, and says so
     assert kernels == {
         "kv_pool": "page=4x128 heads=8x64",
-        "paged_decode": "pages=8 heads=8 form=mxu-blockdiag fold=2 stored"}
+        "paged_decode": "pages=8 heads=8 form=mxu-blockdiag fold=2 stored",
+        "chunks": ({("xla", "pool stored as float8_e4m3fn")}
+                   if "kv_cache_quant" in kv else
+                   {("pallas-interpret",
+                     "8 heads=8 fold=2 tile=8x8 window=0")})}
     # ... against a pool of a head a slot, as it was allocated before
     monkeypatch.setattr(bkv, "pool_page",
                         lambda heads, lanes, tp=1: (heads, lanes))
@@ -377,11 +389,24 @@ def test_heads_of_64_two_to_a_slot_serve_the_same_tokens_and_bytes(
     assert plain.cache["k"].shape[3:] == (8, 64)
     want, kernels, plain_pool = serve(plain)
     assert kernels["paged_decode"].endswith("fold=2 call")
+    assert {path for path, _ in kernels["chunks"]} == {"xla"}
     assert got == want
     for k in "kv":
         tokens = pool[k].shape[:3]
-        np.testing.assert_array_equal(pool[k].reshape(tokens + (-1,)),
-                                      plain_pool[k].reshape(tokens + (-1,)))
+        ours, theirs = (x[k].reshape(tokens + (-1,))
+                        for x in (pool, plain_pool))
+        if "kv_cache_quant" in kv:
+            np.testing.assert_array_equal(ours, theirs)
+            continue
+        # the first layer's K / V are the projections of the embeddings:
+        # byte for byte. The second layer's lie behind the first's chunk
+        # attention, which walked the stored page on the prefill kernel here
+        # and gathered the plain pool there: the same values to a bf16
+        # rounding of another summation order
+        np.testing.assert_array_equal(ours[0], theirs[0])
+        np.testing.assert_allclose(
+            *(x[1].view(jnp.bfloat16).astype(np.float32)
+              for x in (ours, theirs)), atol=2e-2)
     if "kv_cache_quant" in kv:
         # a chunk reads the chunks before it back out of the pool, 3 bits
         # of mantissa each, where the contiguous prefill is one dispatch:

@@ -184,8 +184,11 @@ def test_llama_1b_serving_graphs_compile_for_v5e(v5e_devices, tp):
     # the decode graphs hold the kernel — read from the executable
     for name in ("paged_t1", "decode_loop", "ragged_w1"):
         assert MOSAIC in texts[name], f"{name}: no Mosaic custom call"
-    # wide rows have no kernel yet (ROADMAP A3): the full-table gather
-    assert MOSAIC not in texts["paged_prefill"]
+    # wide rows walk their live pages on the prefill kernel (ISSUE 49: 8 kv
+    # heads of 64, two to a row of the stored page) on one chip; across
+    # four the kernel declines by the mesh and the table is gathered
+    assert (MOSAIC in texts["paged_prefill"]) == (tp == 1)
+    assert ("paged_prefill_attention" in texts["paged_prefill"]) == (tp == 1)
     assert counts["involuntary_remat"] == 0
 
 
@@ -197,8 +200,8 @@ HEADS_OF_128 = dict(LLAMA_3_2_1B, hidden_size=4096, head_dim=128,
 
 @pytest.mark.parametrize("hf, tp, rows, width, page, kernel, temps_under", [
     (LLAMA_3_2_1B, 1, 32, 1, (4, 128), "pages=8 heads=8", 64e6),
-    (LLAMA_3_2_1B, 1, 1, 256, (4, 128), None, 200e6),
-    (LLAMA_3_2_1B, 1, 32, 256, (4, 128), None, 2.5e9),
+    (LLAMA_3_2_1B, 1, 1, 256, (4, 128), None, 64e6),
+    (LLAMA_3_2_1B, 1, 32, 256, (4, 128), None, 1.2e9),
     (LLAMA_3_2_1B, 4, 32, 1, (4, 128), "pages=16 heads=2", 64e6),
     (HEADS_OF_128, 4, 32, 1, (4, 256), "pages=16 heads=2", 64e6),
 ], ids=["step", "chunk", "pack", "step-tp4", "step-tp4-heads-of-128"])
@@ -234,10 +237,15 @@ def test_heads_that_share_a_slot_leave_the_pool_where_it_is(
     heads, lanes = 8, spec.head_dim
     want = {("kv_pool", "xla",
              f"page={page[0]}x{page[1]} heads={heads}x{lanes}")}
+    assert MOSAIC in text
     if kernel:
-        assert MOSAIC in text
         want.add(("paged_decode", "pallas",
                   f"{kernel} form=mxu-blockdiag fold=2 stored"))
+    else:
+        # the chunk and the pack walk the stored page on the prefill kernel
+        want.add(("paged_prefill", "pallas",
+                  f"rows={rows} width={width} pages=16 heads=32 fold=2 "
+                  f"tile=32x{width} window=0"))
     assert notes == want
     # every instruction that MOVES a shard's pool, whatever shape it gives
     # it; a fusion of a flat shape is the slot write, in place
@@ -410,8 +418,14 @@ def test_30_kv_heads_of_128_decode_on_the_kernel_with_no_pool_copy(
     assert _state_layout(text, "3,32,30,96,192") == "{4,3,2,1,0:T(8,128)}"
     assert step.memory_analysis().temp_size_in_bytes < 100e6
     chunk, notes = compiled(1, 256, state_slots=sds((1,), i32))
+    # ISSUE 49: the chunk's attention walks the 32 head slots of the stored
+    # page on the prefill kernel
     assert notes == {("recurrent_state", "xla",
-                      f"{state}: 256 tokens a row: the chunked form"), pool}
+                      f"{state}: 256 tokens a row: the chunked form"), pool,
+                     ("paged_prefill", "pallas",
+                      "rows=1 width=256 pages=8 heads=32 fold=1 "
+                      "tile=32x256 window=0")}
+    assert "paged_prefill_attention" in chunk.as_text()
     assert "delta_state_step" not in chunk.as_text()
     assert chunk.memory_analysis().temp_size_in_bytes < 200e6
 
@@ -484,13 +498,18 @@ def test_2_kv_heads_of_256_decode_on_the_kernel_with_no_pool_copy(
     assert step.memory_analysis().temp_size_in_bytes < 100e6
     chunk, notes = compiled(1, 256, state_slots=sds((1,), i32))
     # ISSUE 39: 256 x 10 / 512 = 5 rows an expert: the chunk's experts are
-    # the walk's, each touched expert against ITS rows
+    # the walk's, each touched expert against ITS rows; ISSUE 49: its
+    # attention cuts the slot of 512 lanes into its two heads of 256 on the
+    # prefill kernel
     assert notes == {
         ("recurrent_state", "xla",
          f"{state}: 256 tokens a row: the chunked form"), share, pool, (
         "moe_decode", "pallas",
-        "pieces=1 of 512 rows=256 by expert in tiles of 128")}
+        "pieces=1 of 512 rows=256 by expert in tiles of 128"), (
+        "paged_prefill", "pallas",
+        "rows=1 width=256 pages=16 heads=16 fold=2 tile=16x256 window=0")}
     text = chunk.as_text()
+    assert "paged_prefill_attention" in text
     assert "delta_state_step" not in text
     assert "ragged-dot" not in text and "%moe_chunk_experts" in text
     assert not pool_moves(text), pool_moves(text)
@@ -922,9 +941,44 @@ def test_smallthinker_decode_walks_both_pools_in_place(v5e_devices):
     assert "ragged-dot" not in text and "%moe_chunk_experts" in text
     assert not _smallthinker_pool_movers(text), \
         _smallthinker_pool_movers(text)
-    # a window layer's scores are 28 x 256 x 4384 float32 = 126 MB, a
-    # global layer's 28 x 256 x 15360 = 440 MB; both live at most once
     assert chunk.memory_analysis().temp_size_in_bytes < 1.6e9
+
+
+@pytest.mark.parametrize("rows, temps_under", [(1, 50e6), (32, 1.1e9)],
+                         ids=["chunk", "pack"])
+def test_smallthinker_chunks_walk_both_pools_on_the_prefill_kernel(
+        v5e_devices, rows, temps_under):
+    """ISSUE 49: SmallThinker's one-row chunk (``paged.w256``) and its pack
+    (``paged_pack.w256``) at the cell's serving shape hold
+    ``paged_prefill_attention`` - lowered once a call site of the scanned
+    period (a global layer over the allocator's table, the window layers
+    over the ring's logical table), not once a layer - and NO float32
+    tensor of heads x width x table tokens: a global layer's scores were 28
+    x 256 x 15,360 x 4 B = 440 MB a row, a window layer's 28 x 256 x 4,384 =
+    126 MB, and a pack took its rows through ``map_row_groups``: temps 0.45
+    GB / 2.30 GB before, 2.3 MB / 0.99 GB now (the pack's are its experts'
+    row groups). Nothing copies, transposes or relays either pool; no
+    gather of a table is left."""
+    program, notes = _smallthinker_program(v5e_devices, rows, 256)
+    plan = f"rows={rows} width=256 pages=16 heads=28 fold=4 tile=28x256"
+    assert {(path, why) for site, path, why in notes
+            if site == "paged_prefill"} == {
+        ("pallas", plan + " window=0"),
+        ("pallas", plan + " window=4096 ring=137")}
+    text = program.as_text()
+    assert MOSAIC in text
+    calls = set(re.findall(
+        rf"%(paged_prefill_attention[.\d]*) = bf16\[{rows},256,3584\]", text))
+    assert 2 <= len(calls) <= 4, calls       # a call site, not a layer
+    assert not _smallthinker_pool_movers(text), \
+        _smallthinker_pool_movers(text)
+    scores = 28 * 256 * 4384
+    big = [(name, shape) for name, shape in re.findall(
+        r"%(\S+) = f32\[([\d,]+)\]", text)
+        if {"28", "7", "256", "7168"} & set(shape.split(","))
+        and math.prod(int(d) for d in shape.split(",")) >= rows * scores // 2]
+    assert not big, big[:5]
+    assert program.memory_analysis().temp_size_in_bytes < temps_under
 
 
 def test_the_widest_smallthinker_program_fits_beside_weights_and_pools(

@@ -202,6 +202,16 @@ def test_a_five_chunks_then_decode_on_the_kernel(ref, gate_weights,
             for k in notes["paged_decode"]} == {
         ("pallas-interpret", "window=0"),
         ("pallas-interpret", f"window=16 ring={RING}")}
+    # ... and every chunk on the prefill kernel (ISSUE 49): a global layer
+    # over the allocator's table, a window layer over its ring - which the
+    # five chunks of 32 (twice the window) wrap before the first decode step
+    assert {(k["path"], k["reason"]) for k in notes["paged_prefill"]} == {
+        ("pallas-interpret", "rows=1 width=32 pages=16 heads=4 fold=2 "
+         "tile=4x32 window=0"),
+        ("pallas-interpret", "rows=1 width=32 pages=16 heads=4 fold=2 "
+         f"tile=4x32 window=16 ring={RING}")}
+    assert ad.host_stats["prefill_dispatches_paged_attn_kernel"] \
+        == ad.host_stats["prefill_dispatches"] == 5
     pool = notes["kv_window_pool"][0]["reason"]
     assert "layers global=2 window=6" in pool and "ring_pages=7" in pool
     # the counters of a decode dispatch: pages by kind, tokens in window
@@ -225,6 +235,13 @@ def test_a_the_gathered_decode_form(ref, gate_weights, monkeypatch):
     _decode(ad, [7], stream, 12)
     _check(tap, ref, gate_weights, 7, T70, stream[7])
     assert {k["path"] for k in _kernels(app)["paged_decode"]} == {"xla"}
+    # the chunks gather too, by name, and the counter stays where it was
+    assert {(k["path"], k["reason"])
+            for k in _kernels(app)["paged_prefill"]} == {
+        ("xla", "rows=1 width=32: decode_kernel=False"),
+        ("xla", "rows=1 width=8: decode_kernel=False")}
+    assert ad.host_stats["prefill_dispatches"] == 3
+    assert ad.host_stats["prefill_dispatches_paged_attn_kernel"] == 0
 
 
 def test_a_the_harness_gate_runs_the_full_batch_prefill(ref):

@@ -110,12 +110,15 @@ def test_precompile_report_and_debug_surface(warm_app):
             if g["kind"] == "paged"] == [1]
     # every traced graph noted the pool's page as allocated (2 heads of 16
     # do not fold: a slot a head), and every T=1 graph which attention path
-    # it took — this toy's head_dim 16 is outside the kernel's geometry,
-    # and says so
+    # it took — this toy's head_dim 16 is outside the kernels' geometry,
+    # and both say so (the chunk's: ops/paged_prefill.py)
     assert rep["kernels"] == [
         {"site": "kv_pool", "path": "xla", "reason": "page=2x16 heads=2x16"},
         {"site": "paged_decode", "path": "xla",
-         "reason": "unsupported geometry (head_dim / attn_chunk)"}]
+         "reason": "unsupported geometry (head_dim / attn_chunk)"},
+        {"site": "paged_prefill", "path": "xla",
+         "reason": "rows=4 width=4: a kv row of 16 lanes of heads of 16 is "
+                   "not whole vregs"}]
     assert ws["kernels"] == rep["kernels"]
     assert ws["steady_state"] is True
     assert ws["graphs_seen"] >= rep["n_graphs"]
