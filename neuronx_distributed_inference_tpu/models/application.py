@@ -1162,6 +1162,16 @@ class PagedCausalLMApplication(CausalLMApplication):
                                  cfg.pa_block_size, max(self.warm_widths)),
                 self.mesh)
             self.cache.update(k_w=ring["k"], v_w=ring["v"])
+        if self.spec.sparse is not None:
+            # a learned sparse selection's index keys: a THIRD pool on the
+            # same block table (a block is block_size tokens of K, V and
+            # index keys of every layer), in the same donated dict
+            from ..modules.block_kv_cache import (index_pool_shape,
+                                                  init_index_pool)
+            self.cache["k_idx"] = init_index_pool(
+                index_pool_shape(self.spec, cfg.pa_num_blocks,
+                                 cfg.pa_block_size),
+                self.spec.kv_dtype, self.mesh)
         if self.spec.ssm is not None:
             # the second per-sequence cache: the kind's conv tails + fp32
             # state, one SLOT per batch row beside the KV pool, in the same

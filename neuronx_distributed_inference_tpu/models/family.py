@@ -179,13 +179,32 @@ class DecoderFamily:
                             ) -> Dict[str, np.ndarray]:
         """Shared MoE conversion: stack per-layer routers (fp32, transposed to
         (H,E)) and per-layer-per-expert projections to (L,E,in,out). Name
-        templates use {i} (layer), {e} (expert), {name} (projection)."""
-        L, E = spec.num_layers, spec.moe.num_experts
+        templates use {i} (layer), {e} (expert), {name} (projection).
+
+        ONE CHIP'S SHARE (``MoESpec.held_experts``): the router keeps its
+        every column and the ``num_held`` experts from ``first_expert`` on
+        are stacked. A checkpoint that names them as the router does (a
+        whole one, or a share under its global names) has the share's LAST
+        expert at ``first_expert + num_held - 1`` and is read at
+        ``first_expert + e``; one that holds the share alone under local
+        names (the benchmark's seeded weights) has no such tensor and is
+        read at ``e``. A tensor neither naming has is ``get``'s KeyError,
+        by name."""
+        moe = spec.moe
+        L, E = spec.num_layers, moe.num_held
+        first = 0
+        if moe.holds_share and moe.first_expert:
+            try:
+                get(expert_fmt.format(
+                    i=0, e=moe.first_expert + E - 1, name=up))
+                first = moe.first_expert
+            except KeyError:
+                pass
 
         def experts(name):
             return np.stack([
                 np.stack([np.ascontiguousarray(np.asarray(get(
-                    expert_fmt.format(i=i, e=e, name=name))).T)
+                    expert_fmt.format(i=i, e=first + e, name=name))).T)
                     for e in range(E)]) for i in range(L)])
 
         return {

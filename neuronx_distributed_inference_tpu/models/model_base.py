@@ -112,6 +112,46 @@ class MLASpec:
 
 
 @dataclass(frozen=True)
+class SparseSpec:
+    """A LEARNED SPARSE SELECTION in front of the attention (the lightning
+    indexer of DeepSeek-V3.2-Exp's sparse attention; Keye-VL-2.0's
+    ``sa_config``). With ``h`` the layer's normed input, per query ``t`` and
+    cached token ``s <= t``:
+
+        qI[t, j] = rot(W_Iq h_t)[j]          index_heads x index_dim
+        kI[s]    = rot(LayerNorm(W_Ik h_s))  ONE head, CACHED: the third pool
+        w[t, j]  = (W_Iw h_t)[j]
+        I[t, s]  = sum_j w[t, j] ReLU(qI[t, j] . kI[s])         float32
+
+    and the attention of ``t`` reads the ``topk`` tokens of largest ``I[t,
+    s]`` alone (all of them while ``t < topk``; ties to the lower position),
+    every head of a token the same set. The index keys live in a paged pool
+    of their own on the K / V pools' block table
+    (``modules/block_kv_cache.index_page``), ``cache["k_idx"]``; the walk is
+    :func:`run_layers_sparse`, the indexer :func:`_indexer_block` under the
+    profiler scope ``indexer``. ``rope``: the rotary of the index heads (all
+    ``index_dim`` lanes, halves convention).
+
+    Both kernels attend the selection MASKED: the walk over the row's LIVE
+    pages with the selection as one more mask (PERF.md section 6, PR 50, has
+    the other form's numbers, the selected rows gathered by XLA: 2.6 x
+    slower a step on this chip). What a selection does not run under:
+    SPARSE_UNSUPPORTED."""
+
+    index_heads: int
+    index_dim: int
+    topk: int
+    rope: RopeConfig
+    norm_eps: float = 1e-6
+
+    @property
+    def proj_width(self) -> int:
+        """Columns of the fused index projection ``[qI | kI | w]``."""
+        return self.index_heads * self.index_dim + self.index_dim \
+            + self.index_heads
+
+
+@dataclass(frozen=True)
 class DecoderSpec:
     """Static architecture description, resolved from an InferenceConfig.
 
@@ -231,6 +271,9 @@ class DecoderSpec:
     moe: Optional[MoESpec] = None
     # MLA attention (deepseek); head_dim then = mla.qk_head_dim
     mla: Optional[MLASpec] = None
+    # a learned sparse selection in front of the attention (an indexer
+    # over a third paged pool of index keys): the paged path only
+    sparse: Optional[SparseSpec] = None
     # leading dense-MLP layers before the MoE stack (deepseek
     # first_k_dense_replace); only meaningful with moe set
     first_dense: int = 0
@@ -464,6 +507,14 @@ def _attn_param_specs(spec: DecoderSpec, L: int) -> Dict[str, ParamSpec]:
                                          dt, "ones")
             layers["k_norm"] = ParamSpec((L, spec.kv_size), P(None, AXIS_MP),
                                          dt, "ones")
+    if spec.sparse is not None:
+        # the indexer, replicated: [qI | kI | w] as ONE projection (a decode
+        # step streams it in one pass) and the index key's LayerNorm
+        sp = spec.sparse
+        layers["idx_proj"] = ParamSpec((L, H, sp.proj_width), P(), dt)
+        layers["idx_k_norm"] = ParamSpec((L, sp.index_dim), P(), dt, "ones")
+        layers["idx_k_norm_b"] = ParamSpec((L, sp.index_dim), P(), dt,
+                                           "zeros")
     if spec.qk_norm and spec.qk_norm_type == "layernorm":
         layers["q_norm_b"] = ParamSpec((L, spec.head_dim), P(), dt, "zeros")
         layers["k_norm_b"] = ParamSpec((L, spec.head_dim), P(), dt, "zeros")
@@ -1320,7 +1371,7 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                 identity_seq_ids=False, arange_positions=False,
                 slot_mapping=None, block_table=None, adapter_ids=None,
                 kv_view=None, prefill_lens=None, side=None,
-                mixed_local=None):
+                mixed_local=None, select=None):
     """The attention half of a layer: q/k/v projections, cache write, the
     phase-appropriate attention compute (Pallas kernel or XLA), and the
     output projection. ``h`` is the already-normed block input (B, T, H).
@@ -1329,6 +1380,10 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
     (reference: contrib/models/Falcon-H1-0.5B-Instruct/src/
     modeling_falcon_h1.py FalconH1DecoderLayer) — can stitch it next to
     their own temporal-mixing blocks.
+
+    ``select`` (B, T, table tokens) bool, phase "paged" with a learned
+    sparse selection (:func:`_indexer_block`): the tokens each query
+    attends; every other cached token is left out of its softmax.
 
     Returns (attn_h, k_full, v_full, pending): attn_h the post-o_proj
     hidden delta, pending the chunked-decode side-buffer pair (None unless
@@ -1504,6 +1559,7 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                     positions[:, 0], kernel_table, scale=spec.scale,
                     window=win, soft_cap=spec.attn_soft_cap, sink=sink,
                     kv_scale=spec.kv_scale,
+                    select=None if select is None else select[:, 0],
                     interpret=kernel_mode.pallas_interpret())
                 if kernel_out is None:
                     declined = "kv heads not shardable over the mp axes"
@@ -1520,6 +1576,14 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                                  block_table.shape[1]).note(
                                      k_full.shape[4] != spec.head_dim)
                              + ("" if mixed_local is None else win_note))
+            if select is not None:
+                kernel_mode.note(
+                    "sparse_attn", "xla" if declined
+                    else kernel_mode.kernel_path(),
+                    "masked: the live pages' walk, a token attended where "
+                    "selected, a decode step"
+                    + (f" ({declined}: the table gathered)" if declined
+                       else ""))
         else:
             # a chunk walks its rows' live pages on the prefill kernel
             # (ops/paged_prefill.py), its own K / V read from the pool they
@@ -1527,7 +1591,7 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
             # the table below
             attn_out = paged_prefill.chunk_attention(
                 spec, q, k_full, v_full, li, positions, kernel_table, win,
-                win_note)
+                win_note, select=select)
             use_pkernel = attn_out is not None
         if not use_pkernel:
             def gathered_mha(q_, bt_, mask_):
@@ -1549,6 +1613,8 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
             # full-batch chunk of a wide batch with many heads) the rows go
             # through in groups, one after another, so the temps stay a
             # group's worth
+            if select is not None:
+                mask = mask & select
             if spec.alibi:
                 attn_out = gathered_mha(q, read_table, mask)
             else:
@@ -2004,6 +2070,228 @@ def run_layers_window(spec: DecoderSpec, params, cache, hidden, ai,
     return hidden, {**cache, "k": kg, "v": vg, "k_w": kw_, "v_w": vw_}, caps
 
 
+#: what a stack with a LEARNED SPARSE SELECTION (``DecoderSpec.sparse``: an
+#: indexer over a third paged pool, :class:`SparseSpec`) does not run under
+#: yet, by mechanism - the one table ``spec_from_config``, the verify /
+#: ragged / multi-token steps and the serving adapter refuse from, through
+#: :func:`refuse_sparse`.
+SPARSE_UNSUPPORTED = {
+    "speculation": "verifying a draft window selects for each drafted "
+                   "token against keys the window itself writes; the "
+                   "verify step has not been walked with an indexer",
+    "ragged dispatch": "a row mixing chunk and decode widths needs the "
+                       "selection by row kind",
+    "fused decode loop": "the in-graph slot advance computes the K / V "
+                         "pools' slots only, not the index keys' rows",
+    "tensor parallelism": "the index-key pool and the selection have run "
+                          "on one chip only (tp = 1)",
+    "host KV spill / handoff": "a spilled or handed-off block carries K "
+                               "and V only, not the index keys that go "
+                               "with it",
+    "contiguous cache": "the index keys are paged on the K / V pools' "
+                        "block table; serve through the paged path "
+                        "(is_block_kv_layout)",
+    "tensor capture/replacement": "the walk with an indexer has no tap "
+                                  "points",
+    "deepstack": "the walk with an indexer adds no per-layer visual "
+                 "features",
+    "other attention forms": "the selection masks plain rotary GQA "
+                             "attention over one pool: no window, chunk, "
+                             "sink, alibi, latent attention, recurrent "
+                             "layers, sub-blocks or mixed dense / expert "
+                             "stacks",
+}
+
+
+def sparse_refusal(asked) -> Optional[str]:
+    """The sentence that names every entry of ``asked`` (keys of
+    :data:`SPARSE_UNSUPPORTED` a caller found switched on; falsy entries
+    are skipped) with its reason, or None where nothing was asked."""
+    return _refusal(SPARSE_UNSUPPORTED,
+                    "a stack with a learned sparse selection (an indexer "
+                    "over a pool of index keys) does not support: ", asked)
+
+
+def refuse_sparse(asked) -> None:
+    """Raise NotImplementedError with :func:`sparse_refusal`'s sentence;
+    nothing asked, nothing raised."""
+    why = sparse_refusal(asked)
+    if why:
+        raise NotImplementedError(why)
+
+
+def _float_order_keys(x):
+    """float32 -> uint32 whose unsigned order is the floats' (``-inf`` the
+    least, ``+inf`` the greatest; ``-0.0`` is taken as ``+0.0`` first, as a
+    comparison of floats takes it); every finite float maps above 0."""
+    x = jnp.where(x == 0, jnp.zeros((), jnp.float32), x.astype(jnp.float32))
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def topk_select(scores, valid, k: int):
+    """Which of ``scores`` (..., S) float32 are among the ``k`` largest of
+    the ``valid`` (..., S) ones a row, EXACTLY: ties go to the lower index,
+    as ``jax.lax.top_k`` breaks them, and a row with at most ``k`` valid
+    entries keeps them all. Returns bool (..., S).
+
+    The k-th largest is found by its BIT PATTERN (:func:`_float_order_keys`),
+    four bits a pass: a pass counts the entries at or above each of 15
+    candidate prefixes and keeps the largest that still has ``k``; eight
+    counting passes over the scores, where ``jax.lax.top_k`` at k = 2048 is
+    a sort of the whole row on a TPU. The tie rule costs a running count
+    along the row, taken only where some row has more entries AT its
+    threshold than it has room for (with float32 scores of real
+    activations: exact zeros, where every index head's ReLU is shut)."""
+    key = jnp.where(valid, _float_order_keys(scores), jnp.uint32(0))
+    prefix = jnp.zeros(key.shape[:-1], jnp.uint32)
+    steps = jnp.arange(1, 16, dtype=jnp.uint32)
+    for shift in range(28, -1, -4):
+        cands = prefix[..., None] | (steps << shift)              # (..., 15)
+        count = jnp.sum(key[..., None, :] >= cands[..., :, None], axis=-1,
+                        dtype=jnp.int32)
+        held = jnp.sum(count >= k, axis=-1, dtype=jnp.int32)
+        prefix = prefix | (held.astype(jnp.uint32) << shift)
+    tau = prefix[..., None]
+    above = key > tau
+    at = (key == tau) & valid
+    room = k - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    crowded = jnp.any(jnp.sum(at, axis=-1, dtype=jnp.int32) > room)
+    return above | jax.lax.cond(
+        crowded,
+        lambda: at & (jnp.cumsum(at, axis=-1, dtype=jnp.int32)
+                      <= room[..., None]),
+        lambda: at)
+
+
+def _index_scores(sp: SparseSpec, qi, w, rows):
+    """``I[t, s]`` of a group of rows: ``qi`` (B, T, heads, index_dim) the
+    rotated index queries, ``w`` (B, T, heads) their weights, ``rows`` (B,
+    pages, page rows, lanes) the row's pages of the index-key pool as they
+    lie (``block_kv_cache.index_page``: ``lanes // index_dim`` tokens a
+    row). Returns (B, T, pages x block) float32, column ``s`` the token at
+    position ``s``. The products are taken from the operands as stored
+    (bfloat16 on the chip) with float32 sums; ReLU, weights and the sum
+    over heads in float32."""
+    b, t, nj, dim = qi.shape
+    _, pages, prow, lanes = rows.shape
+    fold = lanes // dim
+    if fold > 1:
+        # a segment's queries in its lanes, zeros in the neighbours'
+        seg = jnp.arange(fold)[:, None] == jnp.arange(fold)[None, :]
+        qi = jnp.where(seg[None, None, None, :, :, None],
+                       qi[:, :, :, None, None, :],
+                       jnp.zeros((), qi.dtype)).reshape(b, t, nj, fold, lanes)
+    else:
+        qi = qi[:, :, :, None, :]
+    dots = jnp.einsum("btjgl,bprl->btjgpr", qi, rows.astype(qi.dtype),
+                      preferred_element_type=jnp.float32)
+    scores = jnp.sum(jax.nn.relu(dots)
+                     * w.astype(jnp.float32)[:, :, :, None, None, None],
+                     axis=2)                               # (B, T, g, p, r)
+    return scores.transpose(0, 1, 3, 2, 4).reshape(b, t, pages * fold * prow)
+
+
+@jax.named_scope("indexer")
+def _indexer_block(spec: DecoderSpec, h, layer_w, pool, li, ai, positions,
+                   slot_mapping, block_table):
+    """The indexer of a layer with a learned sparse selection
+    (:class:`SparseSpec`), a paged step: project the normed input ``h`` (B,
+    T, H) to index queries, the index key and the head weights; write the
+    step's keys to the index-key pool ``pool`` at ``slot_mapping``; score
+    every query against its row's pages (``block_table``) and select.
+    Returns (select, pool): ``select`` (B, T, table tokens) bool, True
+    where query ``t`` attends the token at that position (causal, written,
+    among its ``topk``). A sibling of ``attn`` in the trace, not inside it.
+
+    The float32 score temps are ``heads`` x the selection's own size, so
+    the rows go through in groups where they would outgrow the budget
+    (:func:`map_row_groups`)."""
+    from ..modules import block_kv_cache as bkv
+    sp = spec.sparse
+    b, t, _ = h.shape
+    nj, dim = sp.index_heads, sp.index_dim
+    proj = qlinear(h, layer_w["idx_proj"])
+    qi, ki, w = jnp.split(proj, [nj * dim, nj * dim + dim], axis=-1)
+    cos, sin = ai["cos_i"], ai["sin_i"]
+    qi = apply_rope(qi.reshape(b, t, nj, dim), cos, sin)
+    ki = layer_norm(ki, layer_w["idx_k_norm"], layer_w["idx_k_norm_b"],
+                    sp.norm_eps)
+    ki = apply_rope(ki[:, :, None, :], cos, sin)[:, :, 0]
+    bs = (pool.shape[2] * pool.shape[3]) // dim
+    pool = bkv.write_index_keys(pool, ki, li, slot_mapping, positions, bs)
+    kpos = jnp.arange(block_table.shape[1] * bs, dtype=jnp.int32)
+
+    def select_of(qi_, w_, table_, pos_):
+        scores = _index_scores(
+            sp, qi_, w_, bkv.gather_index_rows(pool, li, table_))
+        seen = kpos[None, None, :] <= pos_[:, :, None]
+        return topk_select(scores, seen, sp.topk)
+
+    select = map_row_groups(
+        select_of, 4 * (nj + 4) * t * kpos.shape[0], qi, w, block_table,
+        positions.astype(jnp.int32))
+    return select, pool
+
+
+def run_layers_sparse(spec: DecoderSpec, params, cache, hidden, ai,
+                      positions, *, slot_mapping, block_table):
+    """The paged walk of a stack with a LEARNED SPARSE SELECTION
+    (``DecoderSpec.sparse``): one scan over the layers carrying THREE
+    pools, ``cache["k"] / ["v"]`` and the index keys' ``cache["k_idx"]``,
+    all on the allocator's one block table. A layer is the pre-norm block
+
+        h = N(x);  S = indexer(h)        scope ``indexer``
+        x = x + Attn(h | S)              scope ``attn``
+        x = x + MoE(N'(x))               scope ``moe`` (or ``mlp``)
+
+    with the attention reading the tokens ``S`` selects alone
+    (:func:`_indexer_block`, :func:`_attn_block`'s ``select``). Expert
+    leaves a custom call reads in place stay in their stack
+    (``moe.stack_leaves``), as in :func:`run_layer_slice`; a decode step
+    counts its routing into ``moe_tally``. Returns (hidden, cache,
+    per-layer outputs)."""
+    layers = params["layers"]
+    b, t = hidden.shape[:2]
+    in_place = (moe_mod.stack_leaves(spec.moe, b * t, layers)
+                if spec.moe is not None else ())
+    sliced = {k: a for k, a in layers.items() if k not in in_place}
+    live = slot_mapping >= 0 if t == 1 and spec.moe is not None else None
+    pool = cache["k_idx"]
+    kernel_mode.note(
+        "kv_index_pool", "xla",
+        f"page={pool.shape[2]}x{pool.shape[3]} values_a_token="
+        f"{spec.sparse.index_dim} heads={spec.sparse.index_heads} topk="
+        f"{spec.sparse.topk} pool_bytes={pool.size * pool.dtype.itemsize}")
+
+    def body(carry, xs):
+        x, kf, vf, ki = carry
+        w, li = xs
+        w = {**w, **{k: moe_mod.LayerOfStack(layers[k], li)
+                     for k in in_place}}
+        h = _norm(spec, x, w["input_norm"])
+        select, ki = _indexer_block(spec, h, w, ki, li, ai, positions,
+                                    slot_mapping, block_table)
+        a, kf, vf, _ = _attn_block(
+            spec, h, w, kf, vf, li, ai, False, None, positions, "paged",
+            slot_mapping=slot_mapping, block_table=block_table,
+            select=select)
+        x = x + _shard(a, AXIS_DP, None, None)
+        caps: Dict[str, Any] = {}
+        tally = [] if live is not None else None
+        m = _mlp_block(spec, _norm(spec, x, w["post_norm"]), w,
+                       "dense" if spec.moe is None else "moe", None,
+                       phase="paged", tally=tally, live=live)
+        if tally:
+            caps["moe_tally"] = tally[0]
+        return (x + _shard(m, AXIS_DP, None, None), kf, vf, ki), caps
+
+    (hidden, kf, vf, ki), caps = jax.lax.scan(
+        body, (hidden, cache["k"], cache["v"], pool),
+        (sliced, jnp.arange(spec.num_layers, dtype=jnp.int32)))
+    return hidden, {**cache, "k": kf, "v": vf, "k_idx": ki}, caps
+
+
 def _paged_kernel_declined(spec: DecoderSpec) -> str:
     """Why a single-token paged step of this spec does NOT take the paged
     decode kernel ("" where it does, the mesh permitting)."""
@@ -2069,6 +2357,15 @@ def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
             kv_view=kv_view, prefill_lens=prefill_lens,
             slot_mapping=slot_mapping, block_table=block_table,
             state_slots=state_slots)
+    if spec.sparse is not None:
+        refuse_sparse([
+            (phase != "paged" or "k_idx" not in cache) and "contiguous cache",
+            (replacements is not None or spec.capture)
+            and "tensor capture/replacement",
+            deepstack is not None and "deepstack"])
+        return run_layers_sparse(
+            spec, params, cache, hidden, ai, positions,
+            slot_mapping=slot_mapping, block_table=block_table)
     if "k_w" in cache:
         refuse_window_pool([
             phase != "paged" and "multi-token decode",
@@ -2880,6 +3177,10 @@ def paged_forward_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
         ai["ring"] = window_ring_inputs(
             spec, cache["k_w"], tpu_cfg.batch_size, position_ids,
             slot_mapping, block_table, state_slots)
+    if spec.sparse is not None:
+        # the index heads' rotary (all of their lanes)
+        ai["cos_i"], ai["sin_i"] = rope_cos_sin(position_ids,
+                                                spec.sparse.rope)
     hidden = _embed(spec, params, input_ids, position_ids)
     hidden, new_cache, side = run_layers(
         spec, params, cache, hidden, ai, None, position_ids,
@@ -3052,6 +3353,7 @@ def paged_decode_loop(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     Returns tokens (B, num_steps) + cache."""
     refuse_recurrent([spec.ssm is not None and "fused decode loop"])
     refuse_window_pool(["k_w" in cache and "fused decode loop"])
+    refuse_sparse([spec.sparse is not None and "fused decode loop"])
     bs = cache["k"].shape[2]                  # paged (L, N, Bs, H, D)
     b = first_tokens.shape[0]
     rows = jnp.arange(b)
@@ -3162,6 +3464,7 @@ def paged_spec_verify(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     """
     refuse_recurrent([spec.ssm is not None and "speculation"])
     refuse_window_pool(["k_w" in cache and "speculation"])
+    refuse_sparse([spec.sparse is not None and "speculation"])
     if spec.mixed_kv:
         raise NotImplementedError(
             "speculative verify over mixed per-layer caches is "
@@ -3256,6 +3559,7 @@ def paged_ragged_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     """
     refuse_recurrent([spec.ssm is not None and "ragged dispatch"])
     refuse_window_pool(["k_w" in cache and "ragged dispatch"])
+    refuse_sparse([spec.sparse is not None and "ragged dispatch"])
     if spec.mixed_kv:
         raise NotImplementedError(
             "the ragged unified dispatch over mixed per-layer "
@@ -3573,6 +3877,28 @@ def spec_from_config(config: InferenceConfig, tp_degree: Optional[int] = None,
                  or tcfg.tensor_replacement_config)
                 and "tensor capture/replacement",
                 kw.get("alibi") and "alibi"])
+    if kw.get("sparse") is not None:
+        # a learned sparse selection: refused by name where something it
+        # does not run under yet is switched on (SPARSE_UNSUPPORTED)
+        sc = tcfg.speculation_config
+        refuse_sparse([
+            not tcfg.is_block_kv_layout and "contiguous cache",
+            sc and (sc.speculation_length or sc.medusa_speculation_length)
+            and "speculation",
+            tcfg.decode_chunk_tokens > 1 and "fused decode loop",
+            tp > 1 and "tensor parallelism",
+            (tcfg.tensor_capture_config or tcfg.tensor_replacement_config)
+            and "tensor capture/replacement",
+            (kw.get("sliding_window", 0) > 0
+             or kw.get("layer_pattern") is not None
+             or kw.get("attn_chunk", 0) or kw.get("attn_sink")
+             or kw.get("alibi") or kw.get("mla") is not None
+             or kw.get("ssm") is not None or kw.get("sub_blocks", 1) > 1
+             or kw.get("first_dense") or kw.get("moe_pattern") is not None
+             or kw.get("norm_position", "pre") != "pre"
+             or kw.get("block_style", "sequential") != "sequential"
+             or kw.get("sandwich_norm") or kw.get("lora") is not None)
+            and "other attention forms"])
     if not kw.get("vocab_parallel", True) and tp > 1:
         # older saved configs carry vocab_parallel=false from when the knob
         # was inert; honoring it replicates the (V, H) table on every device

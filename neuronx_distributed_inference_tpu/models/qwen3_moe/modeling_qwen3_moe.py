@@ -3,11 +3,19 @@
 
 Qwen3 attention (per-head q/k RMSNorm, decoupled head_dim) + Mixtral-style
 routing (softmax, top-k, optional renormalization via ``norm_topk_prob``).
+
+ONE CHIP'S SHARE of the expert layers, spelt as ``models/qwen3_next`` spells
+it: with ``router_num_experts`` in the config, ``num_experts`` is what the
+weights HOLD (from ``first_expert`` on) and the router still scores
+``router_num_experts``; the block computes the held experts' part of the sum
+and no code stands in for the other chips (``modules/moe.py``). Without the
+key every expert is held. :func:`moe_share` is the one reading of the keys,
+``models/keye_vl2`` its second user.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
@@ -24,6 +32,22 @@ class Qwen3MoeInferenceConfig(InferenceConfig):
                 "num_experts", "num_experts_per_tok", "moe_intermediate_size"]
 
 
+def moe_share(config: InferenceConfig) -> Dict[str, int]:
+    """``MoESpec``'s ``num_experts`` / ``held_experts`` / ``first_expert``
+    from ``num_experts`` (held), ``router_num_experts`` (routed over; absent
+    = every expert is held) and ``first_expert``."""
+    held = int(config.num_experts)
+    routed = int(getattr(config, "router_num_experts", None) or held)
+    first = int(getattr(config, "first_expert", 0) or 0)
+    if not 0 <= first <= routed - held:
+        raise ValueError(
+            f"experts {first}..{first + held - 1} held of a router over "
+            f"{routed}")
+    return dict(num_experts=routed,
+                held_experts=held if held < routed else 0,
+                first_expert=first)
+
+
 @register_family("qwen3_moe")
 class Qwen3MoeFamily(DecoderFamily):
     config_cls = Qwen3MoeInferenceConfig
@@ -38,14 +62,21 @@ class Qwen3MoeFamily(DecoderFamily):
         if getattr(config, "decoder_sparse_step", 1) != 1:
             raise NotImplementedError("decoder_sparse_step != 1 not supported")
         moe = MoESpec(
-            num_experts=config.num_experts,
+            **moe_share(config),
             top_k=config.num_experts_per_tok,
             intermediate_size=config.moe_intermediate_size,
             normalize_topk=bool(getattr(config, "norm_topk_prob", True)),
             act=getattr(config, "hidden_act", "silu"),
         )
         return spec_from_config(config, tp_degree, moe=moe, qk_norm=True,
-                                intermediate_size=config.moe_intermediate_size)
+                                intermediate_size=config.moe_intermediate_size,
+                                **cls.attention_overrides(config))
+
+    @classmethod
+    def attention_overrides(cls, config: InferenceConfig) -> Dict[str, Any]:
+        """What a family on this ``build_spec`` adds to the attention
+        (``models/keye_vl2``: its learned sparse selection); nothing here."""
+        return {}
 
     @classmethod
     def convert_mlp_weights(cls, get, layer_stack, spec: DecoderSpec

@@ -42,6 +42,7 @@ from ...parallel.layers import place_q_weight, replicate_kv_weight
 from ..family import DecoderFamily, register_family
 from ..model_base import DecoderSpec, spec_from_config
 from ..olmo_hybrid.modeling_olmo_hybrid import FULL, LINEAR, SCAN_CHUNK
+from ..qwen3_moe.modeling_qwen3_moe import moe_share
 
 
 class Qwen3NextInferenceConfig(InferenceConfig):
@@ -106,13 +107,6 @@ class Qwen3NextFamily(DecoderFamily):
                 f"linear_num_key_heads {key_heads}")
         d_k, d_v = (int(config.linear_key_head_dim),
                     int(config.linear_value_head_dim))
-        held = int(config.num_experts)
-        routed = int(getattr(config, "router_num_experts", None) or held)
-        first = int(getattr(config, "first_expert", 0) or 0)
-        if not 0 <= first <= routed - held:
-            raise ValueError(
-                f"experts {first}..{first + held - 1} held of a router over "
-                f"{routed}")
         head_dim = int(config.head_dim)
         eps = float(getattr(config, "rms_norm_eps", 1e-6))
         return spec_from_config(
@@ -121,15 +115,13 @@ class Qwen3NextFamily(DecoderFamily):
                 getattr(config, "partial_rotary_factor", 1.0))),
             intermediate_size=int(config.moe_intermediate_size),
             moe=MoESpec(
-                num_experts=routed, top_k=int(config.num_experts_per_tok),
+                **moe_share(config), top_k=int(config.num_experts_per_tok),
                 intermediate_size=int(config.moe_intermediate_size),
                 normalize_topk=bool(getattr(config, "norm_topk_prob", True)),
                 shared_intermediate=int(
                     config.shared_expert_intermediate_size),
                 shared_gated=True,
-                act=getattr(config, "hidden_act", "silu"),
-                held_experts=held if held < routed else 0,
-                first_expert=first),
+                act=getattr(config, "hidden_act", "silu")),
             ssm=SSMSpec(
                 kind="gated_delta", d_inner=heads * d_v, num_heads=heads,
                 num_key_heads=key_heads, head_dim=d_v, d_state=d_k,

@@ -233,6 +233,95 @@ def window_pool_spec(spec, rows: int, block_size: int, widest: int
         dtype=spec.kv_dtype)
 
 
+def index_page(index_dim: int, block_size: int) -> Tuple[int, int]:
+    """``(rows, lanes)`` of a page of the INDEX-KEY pool (a learned sparse
+    selection, ``models.model_base.SparseSpec``: one key of ``index_dim``
+    values a token a layer, on the K / V pools' block table), decided here
+    as :func:`pool_page` decides the K / V page. The device keeps an array's
+    minor dimension in whole 128-lane tiles, so a key of 64 values a row
+    would take the bytes of 128: ``128 // index_dim`` tokens share a row
+    instead, token ``o`` of a block in row ``o % rows``, lanes ``(o // rows)
+    x index_dim`` on (a page of 32 tokens of 64 values is ONE bfloat16 tile,
+    ``(16, 128)``). A scorer reads a row as it lies with its queries placed
+    in a segment's lanes (zeros in the neighbours'), as the decode kernel's
+    narrow heads are. A width that does not fold evenly keeps a row a
+    token."""
+    fold = LATENT_LANE_TILE // index_dim if (
+        index_dim < LATENT_LANE_TILE and LATENT_LANE_TILE % index_dim == 0
+        and block_size % (LATENT_LANE_TILE // index_dim) == 0) else 1
+    return block_size // fold, fold * index_dim
+
+
+def index_pool_shape(spec, num_blocks: int, block_size: int
+                     ) -> Tuple[int, int, int, int]:
+    """The index-key pool of ``spec`` (its ``sparse`` set) beside a K / V
+    pool of ``num_blocks`` usable blocks: ``(layers, blocks + the null
+    block, rows, lanes)`` of :func:`index_page`."""
+    rows, lanes = index_page(spec.sparse.index_dim, block_size)
+    return (spec.num_attn_layers, num_blocks + 1, rows, lanes)
+
+
+def init_index_pool(shape, dtype, mesh: Optional[Mesh] = None):
+    """The zeroed index-key pool: replicated (ONE key head serves every
+    query head of every shard)."""
+    sharding = NamedSharding(mesh, P()) if mesh is not None else None
+    return jnp.zeros(shape, dtype, device=sharding)
+
+
+def write_index_keys(pool: jnp.ndarray, new: jnp.ndarray, layer,
+                     slot_mapping: jnp.ndarray, positions: jnp.ndarray,
+                     block_size: int) -> jnp.ndarray:
+    """The step's index keys ``new`` (B, T, index_dim) into the pool (L, N,
+    rows, lanes) at ``layer``, by the K / V pools' flat ``slot_mapping``
+    (B, T; negative = drop). Tokens that share a row (:func:`index_page`)
+    are written as WHOLE rows: a token's row takes the neighbour segment
+    from the same step where the neighbour is in it (``positions`` (B, T)
+    tell: a row's tokens are consecutive) and from the pool where it is
+    not, so two tokens of one row write the same bytes and a scatter's
+    order does not matter."""
+    n_layers, n, rows, lanes = pool.shape
+    b, t, dim = new.shape
+    fold = lanes // dim
+    flat = pool.reshape(n_layers, n * rows, lanes)
+    li = jnp.asarray(layer, jnp.int32)
+    live = slot_mapping >= 0
+    slot = jnp.maximum(slot_mapping, 0)
+    off = slot % block_size
+    row = (slot // block_size) * rows + off % rows               # (B, T)
+    new = new.astype(pool.dtype)
+    if fold > 1:
+        seg = off // rows
+        old = flat[li, row].reshape(b, t, fold, dim)
+        at = jnp.arange(t, dtype=jnp.int32)[None, :]
+        parts = []
+        for g in range(fold):
+            shift = (g - seg) * rows                             # (B, T)
+            there = jnp.clip(at + shift, 0, t - 1)
+            found = (jnp.take_along_axis(live, there, axis=1)
+                     & (at + shift == there)
+                     & (jnp.take_along_axis(positions, there, axis=1)
+                        == positions + shift))
+            theirs = jnp.take_along_axis(new, there[..., None], axis=1)
+            parts.append(jnp.where((seg == g)[..., None], new, jnp.where(
+                found[..., None], theirs, old[:, :, g])))
+        new = jnp.concatenate(parts, axis=-1)
+    # negative indices WRAP in a jax scatter: past the end, so they drop
+    row = jnp.where(live, row, n * rows).reshape(-1)
+    flat = flat.at[li, row].set(new.reshape(-1, lanes), mode="drop",
+                                unique_indices=False)
+    return flat.reshape(pool.shape)
+
+
+def gather_index_rows(pool: jnp.ndarray, layer, block_table: jnp.ndarray
+                      ) -> jnp.ndarray:
+    """The pages ``block_table`` (B, max_blocks) names of layer ``layer`` of
+    the index-key pool, as they lie: (B, max_blocks, rows, lanes), one gather
+    from the flat (L x N, ...) pool as :func:`gather_layer_kv`'s."""
+    n_layers, n, rows, lanes = pool.shape
+    flat = pool.reshape(n_layers * n, rows, lanes)
+    return flat[jnp.asarray(layer, jnp.int32) * n + block_table]
+
+
 def block_cache_pspec() -> P:
     return P(None, None, None, AXIS_MP, None)
 

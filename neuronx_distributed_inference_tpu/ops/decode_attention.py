@@ -484,11 +484,10 @@ def dispatch(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                          out_specs=P(dp, mpx, None), check_vma=False)(*args)
 
 
-def _paged_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, k_hbm, v_hbm,
-                  o_ref, kbuf, vbuf, sem, *,
+def _paged_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, *rest,
                   scale: float, bs: int, mb: int, hkv: int, g: int,
                   soft_cap: Optional[float], has_sink: bool,
-                  kv_scale: Optional[float]):
+                  kv_scale: Optional[float], selected: bool = False):
     """Ragged PAGED decode attention (reference: the DMA-skipping TKG
     attention over the block layout, attention_base.py:1186-1382 +
     block_kv_cache_manager.py:183-267). One grid step is one ROW; the walk
@@ -507,7 +506,18 @@ def _paged_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, k_hbm, v_hbm,
     of kv row ``c % hkv`` and query row ``r`` belongs to kv row ``r // g``,
     so K and V go to the MXU as they lie, lane-dense, with no relayout, and
     the scores off that block diagonal are masked (the MXU is idle
-    otherwise, and the softmax sees (Hq, columns) full vregs)."""
+    otherwise, and the softmax sees (Hq, columns) full vregs).
+
+    ``selected`` (a learned sparse selection, ``model_base.SparseSpec``):
+    one more input in front of the pools, ``sel_ref`` (1, compute blocks,
+    columns of a block) float32, > 0 where the row's query attends that
+    column's token; a column is attended only if it is live AND selected,
+    and the active token joins only if its own flag, one more scalar a row
+    behind the table, is set. The walk is the live pages' all the same."""
+    if selected:
+        sel_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem = rest
+    else:
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, sem = rest
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     layer = sc_ref[0]
@@ -571,6 +581,9 @@ def _paged_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, k_hbm, v_hbm,
         kpos = (first_live + i * pages) * bs + tok
         valid = jnp.logical_and(own, jnp.logical_and(
             kpos < pos, jnp.logical_or(w == 0, pos - kpos < w)))
+        if selected:
+            # no window with a selection: block i starts at page i x pages
+            valid = jnp.logical_and(valid, sel_ref[0, pl.ds(i, 1), :] > 0)
         s = _split_dot(q, kbuf[slot].reshape(cols, d), _NT, exact) * s_scale
         if soft_cap is not None:
             s = soft_cap * jnp.tanh(s / soft_cap)
@@ -596,6 +609,8 @@ def _paged_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, k_hbm, v_hbm,
                 axis=-1, keepdims=True) * scale
     if soft_cap is not None:
         s = soft_cap * jnp.tanh(s / soft_cap)
+    if selected:
+        s = jnp.where(sc_ref[2 + nb + nb * mb + b] > 0, s, NEG_INF)
     m_cur = jnp.maximum(m_prev, s)
     if has_sink:
         # learned per-head sink joins the denominator only
@@ -625,6 +640,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                            soft_cap: Optional[float] = None,
                            sink: Optional[jnp.ndarray] = None,
                            kv_scale: Optional[float] = None,
+                           select: Optional[jnp.ndarray] = None,
                            interpret: bool = False) -> jnp.ndarray:
     """Ragged paged decode attention over the stacked block cache.
 
@@ -635,7 +651,10 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     where a pool handed over a head a slot may cost a relayout of the whole
     pool a call; new_k/new_v
     (B, Hkv, D); lens (B,) prior lengths; block_table (B, max_blocks)
-    logical->physical page map (entry 0 = null page). Returns (B, Hq, D).
+    logical->physical page map (entry 0 = null page); select (B, max_blocks
+    x Bs) bool, optional (a learned sparse selection; no window with it):
+    the positions each row's query attends, its own position among them -
+    every other cached token is walked and left out. Returns (B, Hq, D).
 
     The grid is the rows. For each, the kernel walks the pages from the
     window's first to the last live one in compute blocks of ``pages`` pages
@@ -685,14 +704,31 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     row_spec = pl.BlockSpec((1, hq, d_k), lambda bi, sc: (bi, 0, 0))
     kernel = functools.partial(
         _paged_kernel, scale=scale, bs=bs, mb=mb, hkv=hkv_k, g=g_k,
-        kv_scale=kv_scale, soft_cap=soft_cap, has_sink=sink is not None)
+        kv_scale=kv_scale, soft_cap=soft_cap, has_sink=sink is not None,
+        selected=select is not None)
     if window is None:
         window = jnp.zeros((), jnp.int32)
-    scalars = jnp.concatenate([
+    scalars = [
         jnp.asarray(layer, jnp.int32).reshape(1),
         jnp.asarray(window, jnp.int32).reshape(1),
         lens.astype(jnp.int32),
-        block_table.astype(jnp.int32).reshape(-1)])
+        block_table.astype(jnp.int32).reshape(-1)]
+    sel_in, sel_spec = [], []
+    if select is not None:
+        # a block's columns as the kernel counts them (token-major, a
+        # token's kv rows neighbours), blocks padded to whole ones; the
+        # active token's own flag rides behind the table
+        n_blk = -(-mb // pages)
+        cols = pages * bs * hkv_k
+        own_flag = jnp.take_along_axis(
+            select, jnp.minimum(lens.astype(jnp.int32), mb * bs - 1)[:, None],
+            axis=1)[:, 0]
+        scalars.append(own_flag.astype(jnp.int32))
+        sel = jnp.pad(select, ((0, 0), (0, n_blk * pages * bs - mb * bs)))
+        sel_in = [jnp.repeat(sel.astype(jnp.float32), hkv_k, axis=1)
+                  .reshape(b, n_blk, cols)]
+        sel_spec = [pl.BlockSpec((1, n_blk, cols), lambda bi, sc: (bi, 0, 0))]
+    scalars = jnp.concatenate(scalars)
     slot = (2, pages, bs * hkv_k, d_k)
     out = pl.pallas_call(
         kernel,
@@ -702,6 +738,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
             in_specs=[
                 row_spec, row_spec, row_spec,
                 pl.BlockSpec((hq, 1), lambda bi, sc: (0, 0)),
+                *sel_spec,
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
@@ -714,8 +751,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, d_k), q.dtype),
         interpret=interpret,
-    )(scalars, place(q), place(new_k), place(new_v), sink_in, k_pages,
-      v_pages)
+    )(scalars, place(q), place(new_k), place(new_v), sink_in, *sel_in,
+      k_pages, v_pages)
     if fold > 1:
         out = jnp.sum(jnp.where(own[None, :, :, None],
                                 out.reshape(b, hq, fold, d),
@@ -730,11 +767,13 @@ def paged_dispatch(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                    soft_cap: Optional[float] = None,
                    sink: Optional[jnp.ndarray] = None,
                    kv_scale: Optional[float] = None,
+                   select: Optional[jnp.ndarray] = None,
                    interpret: bool = False) -> Optional[jnp.ndarray]:
     """Mesh-aware entry for the paged kernel: shard kv-heads over the
     model-parallel axes, matching the block-cache sharding
     P(None, None, None, ("ep","tp"), None) (modules/block_kv_cache.py).
-    Returns None when the heads cannot be sharded over a >1 mp degree."""
+    Returns None when the heads cannot be sharded over a >1 mp degree, and
+    for a ``select`` (one chip only) on a mesh wider than one."""
     mesh = jax.sharding.get_abstract_mesh()
     b = q.shape[0]
     hkv = k_pages.shape[3]
@@ -750,7 +789,9 @@ def paged_dispatch(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
         return paged_decode_attention(
             q, k_pages, v_pages, new_k, new_v, layer, lens, block_table,
             scale=scale, window=window, soft_cap=soft_cap, sink=sink,
-            kv_scale=kv_scale, interpret=interpret)
+            kv_scale=kv_scale, select=select, interpret=interpret)
+    if select is not None:
+        return None
 
     if window is None:
         window = jnp.zeros((), jnp.int32)

@@ -173,10 +173,9 @@ def declined(spec, q, pool, block_table) -> str:
     return ""
 
 
-def _kernel(sc_ref, q_hbm, k_hbm, v_hbm, o_hbm, kbuf, vbuf, sem, q_scr,
-            o_scr, m_ref, l_ref, acc_ref, bias_ref, *wide, scale: float,
+def _kernel(sc_ref, q_hbm, k_hbm, v_hbm, *rest, scale: float,
             bs: int, mb: int, plan: PrefillPlan,
-            soft_cap: Optional[float]):
+            soft_cap: Optional[float], selected: bool = False):
     """One grid step is one tile of one ROW: ``plan.tile`` kv rows x their
     query heads x the row's T queries. Scalar prefetch: [layer, window,
     first_0..first_{B-1}, table_{0,0}.., table_{B-1,mb-1}]. ``k_hbm`` /
@@ -193,7 +192,20 @@ def _kernel(sc_ref, q_hbm, k_hbm, v_hbm, o_hbm, kbuf, vbuf, sem, q_scr,
     block tokens) is the block's mask as 0 / NEG_INF, made once a block for
     all heads; ``wide`` (a 16-bit pool of several kv rows a token) a block's
     K and V as float32: Mosaic reads every ``rows``-th sublane row of 32-bit
-    data only."""
+    data only.
+
+    ``selected`` (a learned sparse selection, ``model_base.SparseSpec``; no
+    window with it): one more input behind the pools, ``sel_hbm`` (B, T,
+    blocks x block tokens), > 0 where a query attends that position, and
+    its two slots ``sel_buf`` in front of ``wide``; a block's (T, block
+    tokens) window of it is copied in beside the block's pages and joins the
+    block's mask."""
+    if selected:
+        (sel_hbm, o_hbm, kbuf, vbuf, sem, q_scr, o_scr, m_ref, l_ref,
+         acc_ref, bias_ref, sel_buf, *wide) = rest
+    else:
+        (o_hbm, kbuf, vbuf, sem, q_scr, o_scr, m_ref, l_ref, acc_ref,
+         bias_ref, *wide) = rest
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     layer, w, first = sc_ref[0], sc_ref[1], sc_ref[2 + b]
@@ -240,8 +252,15 @@ def _kernel(sc_ref, q_hbm, k_hbm, v_hbm, o_hbm, kbuf, vbuf, sem, q_scr,
             return carry
         jax.lax.fori_loop(0, pages, page, 0)
 
+    def sel_copy(i, slot):
+        at = pl.ds(pl.multiple_of(i * (pages * bs), pages * bs), pages * bs)
+        return pltpu.make_async_copy(sel_hbm.at[b, :, at], sel_buf.at[slot],
+                                     sem.at[3, slot])
+
     def start(i, slot):
         each_page(i, slot, lambda copy: copy.start())
+        if selected:
+            sel_copy(i, slot).start()
 
     def wait(i, slot):
         each_page(i, slot, lambda copy: copy.wait())
@@ -276,6 +295,10 @@ def _kernel(sc_ref, q_hbm, k_hbm, v_hbm, o_hbm, kbuf, vbuf, sem, q_scr,
         kpos = (first_page + i * pages) * bs + tok
         seen = jnp.logical_and(
             kpos <= qpos, jnp.logical_or(w == 0, qpos - kpos < w))
+        if selected:
+            sel_copy(i, slot).wait()
+            seen = jnp.logical_and(
+                seen, sel_buf[slot].astype(jnp.float32) > 0)
         bias_ref[...] = jnp.where(seen, 0.0, NEG_INF)
         wait(i, slot)
         k_src, v_src = kbuf.at[slot], vbuf.at[slot]
@@ -321,6 +344,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                             v_pages: jnp.ndarray, layer, first: jnp.ndarray,
                             block_table: jnp.ndarray, *, scale: float,
                             window=None, soft_cap: Optional[float] = None,
+                            select: Optional[jnp.ndarray] = None,
                             interpret: bool = False) -> jnp.ndarray:
     """Attention of a chunk of T queries a row, causal by position (and
     inside ``window`` where it is not 0), over the row's pages of the pools,
@@ -333,7 +357,9 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     max_blocks), entry ``j`` the page of positions ``[j x Bs, (j + 1) x
     Bs)`` (a window layer's: its ring as LOGICAL pages,
     ``window_ring_inputs``'s ``kernel_table``); window a scalar, traced or
-    not. Returns (B, T, Hq, D)."""
+    not; select (B, T, max_blocks x Bs) bool, optional (a learned sparse
+    selection, and then no window): the positions each query attends - every
+    other cached token is walked and left out. Returns (B, T, Hq, D)."""
     b, t, hq, d = q.shape
     n_layers, n, bs, rows, dk = k_pages.shape
     mb = block_table.shape[1]
@@ -353,7 +379,16 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         jnp.asarray(0 if window is None else window, jnp.int32).reshape(1),
         first.astype(jnp.int32), block_table.astype(jnp.int32).reshape(-1)])
     kernel = functools.partial(_kernel, scale=scale, bs=bs, mb=mb, plan=plan,
-                               soft_cap=soft_cap)
+                               soft_cap=soft_cap, selected=select is not None)
+    sel_in, sel_scr = [], []
+    if select is not None:
+        # whole blocks of columns, in the queries' dtype (a 16-bit tile is
+        # (16, 128): T is whole sublanes of it, ``declined``)
+        cols = pages * bs
+        sel_in = [jnp.pad(select, ((0, 0), (0, 0),
+                                   (0, -(-mb // pages) * cols - mb * bs))
+                          ).astype(q.dtype)]
+        sel_scr = [pltpu.VMEM((2, t, cols), q.dtype)]
     slot = (2, pages * bs * rows, dk)
     # a 16-bit pool of several kv rows a token: a block as float32 beside it
     wide = [pltpu.VMEM(slot[1:], jnp.float32)] * 2 if (
@@ -364,18 +399,18 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, rows // tile),
-            in_specs=[in_hbm, in_hbm, in_hbm],
+            in_specs=[in_hbm, in_hbm, in_hbm] + [in_hbm] * len(sel_in),
             out_specs=in_hbm,
             scratch_shapes=[
                 pltpu.VMEM(slot, k_pages.dtype),
                 pltpu.VMEM(slot, v_pages.dtype),
-                pltpu.SemaphoreType.DMA((3, 2)),
+                pltpu.SemaphoreType.DMA((4, 2)),
                 pltpu.VMEM((heads * t, dl), q.dtype),
                 pltpu.VMEM((heads * t, dl), q.dtype),
                 pltpu.VMEM((heads * t, 1), jnp.float32),
                 pltpu.VMEM((heads * t, 1), jnp.float32),
                 pltpu.VMEM((heads * t, dl), jnp.float32),
-                pltpu.VMEM((t, pages * bs), jnp.float32), *wide,
+                pltpu.VMEM((t, pages * bs), jnp.float32), *sel_scr, *wide,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, t, hq * dl), q.dtype),
@@ -386,7 +421,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         name="paged_prefill_attention",
     )(scalars, q.reshape(b, t, hq * dl),
       k_pages.reshape(n_layers, n, bs * rows, dk),
-      v_pages.reshape(n_layers, n, bs * rows, dk))
+      v_pages.reshape(n_layers, n, bs * rows, dk), *sel_in)
     if placed:
         out = jnp.sum(jnp.where(own[None, None, :, :, None],
                                 out.reshape(b, t, hq, fold, d),
@@ -395,15 +430,23 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
 
 
 def chunk_attention(spec, q, k_pages, v_pages, layer, positions, block_table,
-                    window, window_note: str = ""):
+                    window, window_note: str = "", select=None):
     """The call site's whole decision (``model_base._attn_block``, phase
     "paged", a chunk of T > 1): the kernel's result (B, T, Hq, D) and its
     plan in the engagement record, or None and why it was declined there
     (the caller then takes ``gathered_mha``). ``window``: the layer's window
     as a scalar, traced under a ``layer_pattern``; ``window_note`` the
-    record's words for it."""
+    record's words for it; ``select`` (B, T, table tokens) bool, a learned
+    sparse selection: the positions each query attends, which the kernel
+    takes as one more mask (the engagement record ``sparse_attn`` says which
+    form the chunk took)."""
     b, t, hq, d = q.shape
     why = declined(spec, q, k_pages, block_table)
+    if select is not None:
+        kernel_mode.note(
+            "sparse_attn", "xla" if why else kernel_mode.kernel_path(),
+            f"masked: rows={b} width={t}, a query attends where selected"
+            + (f" ({why}: the table gathered)" if why else ""))
     if why:
         kernel_mode.note("paged_prefill", "xla",
                          f"rows={b} width={t}: {why}")
@@ -415,4 +458,4 @@ def chunk_attention(spec, q, k_pages, v_pages, layer, positions, block_table,
     return paged_prefill_attention(
         q, k_pages, v_pages, layer, positions[:, 0], block_table,
         scale=spec.scale, window=window, soft_cap=spec.attn_soft_cap,
-        interpret=kernel_mode.pallas_interpret())
+        select=select, interpret=kernel_mode.pallas_interpret())

@@ -907,6 +907,23 @@ class PagedEngineAdapter:
                 raise ConfigurationError(why)
             self.host_stats.update(state_slot_allocs=0, state_slot_frees=0,
                                    state_slots_live=0)
+        # a learned sparse selection (DecoderSpec.sparse): the index keys
+        # ride the allocator's blocks, so admission, release, preemption
+        # and prefix reuse need nothing new; what it does not run under is
+        # refused from the model code's table, and every decode dispatch
+        # counts what the rows selected of what they hold
+        self._sparse = app.spec.sparse
+        if self._sparse is not None:
+            from ..models.model_base import sparse_refusal
+            why = sparse_refusal([
+                ragged and "ragged dispatch",
+                speculation is not None and "speculation",
+                kv_spill_tier is not None and "host KV spill / handoff"])
+            if why:
+                raise ConfigurationError(why)
+            self.host_stats.update(
+                sparse_tokens_selected=0, sparse_tokens_cached=0,
+                kv_index_pages_held=0)
         # paged program shape -> its state is stepped by the kernel
         # (_state_on_kernel)
         self._state_kernel_shapes: Dict[Tuple[int, int], bool] = {}
@@ -1772,6 +1789,8 @@ class PagedEngineAdapter:
             self.host_stats["dispatches_state_kernel"] += 1
         if self._ring_pages:
             self._count_window_pool()
+        if self._sparse is not None:
+            self._count_sparse()
         rec = _get_recorder()
         if rec.enabled:
             rec.instant("dispatch.decode", cat="adapter",
@@ -2275,6 +2294,37 @@ class PagedEngineAdapter:
             gauge.set(layers * held, engine=self.engine_name, kind="window")
             gauge.set((spec.num_attn_layers - layers) * full,
                       engine=self.engine_name, kind="global")
+
+    # -- a learned sparse selection (stacks with an indexer) ---------------
+    def _count_sparse(self) -> None:
+        """At a decode dispatch, over the running rows and from their
+        lengths alone (exact, no device read): the tokens the step's
+        queries select, ``min(len, topk)`` a row, and the tokens they hold
+        (``sparse_tokens_selected`` / ``sparse_tokens_cached``, summed);
+        and the pages the rows hold in the index-key pool, a page a layer
+        (``kv_index_pages_held``, a gauge, and the ``index`` kind of
+        ``nxdi_kv_pool_pages``)."""
+        topk = self._sparse.topk
+        bs = self.app.kv_mgr.spec.block_size
+        selected = cached = pages = 0
+        for st in self.seqs.values():
+            n = int(st.position) + 1          # the step's own token with it
+            selected += min(n, topk)
+            cached += n
+            pages += -(-n // bs)
+        stats = self.host_stats
+        stats["sparse_tokens_selected"] += selected
+        stats["sparse_tokens_cached"] += cached
+        stats["kv_index_pages_held"] = pages * self.app.spec.num_attn_layers
+        reg = self.telemetry.registry
+        if reg.enabled:
+            tmetrics.sparse_tokens_counter(reg).inc(
+                selected, engine=self.engine_name, kind="selected")
+            tmetrics.sparse_tokens_counter(reg).inc(
+                cached, engine=self.engine_name, kind="cached")
+            tmetrics.kv_pool_pages_gauge(reg).set(
+                stats["kv_index_pages_held"], engine=self.engine_name,
+                kind="index")
 
     # -- recurrent-state slots (recurrent/hybrid stacks) -------------------
     def _take_state_slot(self, sid: int) -> None:

@@ -78,6 +78,10 @@ def _capture(adapter, seq_id: int, *, point: str, reason: str,
         raise HandoffError(
             f"cannot capture seq_id {seq_id}: {state} — hand off after "
             "its first token materializes", seq_ids=(seq_id,))
+    if adapter.app.spec.sparse is not None:
+        from ...models.model_base import sparse_refusal
+        raise HandoffError(sparse_refusal(["host KV spill / handoff"]),
+                           seq_ids=(seq_id,))
     mgr = adapter.app.kv_mgr
     bs = mgr.spec.block_size
     table = mgr.tables[seq_id]

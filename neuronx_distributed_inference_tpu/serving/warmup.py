@@ -126,9 +126,12 @@ def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
     A recurrent/hybrid stack (``app.state_slots``) warms only the programs
     it can run: ``paged.w1``, ``paged.w<b>`` and ``paged_pack.w<b>`` (no
     ragged, fused-loop or verify program exists for it), one ``(kind,
-    bucket)`` pair each like everyone else's."""
+    bucket)`` pair each like everyone else's; so does a stack with a
+    learned sparse selection (``DecoderSpec.sparse``), which refuses the
+    same three (``model_base.SPARSE_UNSUPPORTED``)."""
     cfg = app.tpu_config
-    recurrent = bool(app.state_slots)
+    # stacks whose only programs are the paged ones
+    paged_only = bool(app.state_slots) or app.spec.sparse is not None
     b = cfg.batch_size
     if widths is None:
         widths = autobucketing.ragged_row_buckets(app.ctx_buckets,
@@ -168,7 +171,7 @@ def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
                             np.zeros((b,), np.int32), **kw)
 
         for w in sorted(widths):
-            if not recurrent:
+            if not paged_only:
                 plan.append(("ragged", w,
                              lambda w=w, bt=bt: ragged_thunk(w, bt)))
                 if lora_kw is not None:
@@ -187,7 +190,7 @@ def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
                         plan.append((kind + "_lora", w,
                                      lambda w=w, rows=rows, tw=tw:
                                      paged_thunk(w, rows, tw, lora=True)))
-        if recurrent:
+        if paged_only:
             continue
         if chunk > 1:
             plan.append(("paged_loop", chunk, lambda bt=bt: app._run_paged_loop(
@@ -373,7 +376,11 @@ def memory_ledger(adapter, *, registry=None,
     # ring a batch slot), beside the blocks the global layers book
     window_bytes = _tree_bytes({k: app.cache[k] for k in ("k_w", "v_w")
                                 if k in app.cache})
-    state_bytes = _tree_bytes(app.cache) - pool_bytes - window_bytes
+    # a learned sparse selection's index keys, on the same blocks
+    index_bytes = _tree_bytes({k: app.cache[k] for k in ("k_idx",)
+                               if k in app.cache})
+    state_bytes = (_tree_bytes(app.cache) - pool_bytes - window_bytes
+                   - index_bytes)
     block_bytes = pool_bytes // spec.num_blocks
     usable = spec.num_blocks - 1               # block 0 is the null block
     free = int(mgr.allocator.num_free)
@@ -408,6 +415,9 @@ def memory_ledger(adapter, *, registry=None,
                    "stats": dict(tier.stats)}),
         "headroom": admission_headroom(adapter),
     }
+    if index_bytes:
+        ledger["kv"]["index_pool_bytes"] = index_bytes
+        ledger["kv"]["index_block_bytes"] = index_bytes // spec.num_blocks
     ring = app.window_ring_pages
     if ring:
         n_window = app.spec.num_window_layers

@@ -88,6 +88,7 @@ HBM_MODEL_BYTES = "nxdi_hbm_model_bytes"
 HBM_KV_BYTES = "nxdi_hbm_kv_bytes"                    # state
 STATE_SLOTS = "nxdi_state_slots"                      # engine, state
 KV_POOL_PAGES = "nxdi_kv_pool_pages"                  # engine, kind
+SPARSE_TOKENS_TOTAL = "nxdi_sparse_tokens_total"      # engine, kind
 STATE_SLOT_EVENTS_TOTAL = "nxdi_state_slot_events_total"  # engine, event
 KV_FRAGMENTATION_RATIO = "nxdi_kv_fragmentation_ratio"
 
@@ -508,7 +509,19 @@ def kv_pool_pages_gauge(reg):
         KV_POOL_PAGES,
         "Pages (a page a layer) the running rows hold in the KV pools of a "
         "stack with a window pool, by layer kind: global (the allocator's "
-        "blocks, full rows) | window (a ring a batch slot)",
+        "blocks, full rows) | window (a ring a batch slot); of a stack "
+        "with a learned sparse selection: index (the index-key pool, on "
+        "the allocator's blocks)",
+        labels=("engine", "kind"))
+
+
+def sparse_tokens_counter(reg):
+    return reg.counter(
+        SPARSE_TOKENS_TOTAL,
+        "Tokens of the running rows of the decode dispatches of a stack "
+        "with a learned sparse selection, from the rows' lengths; "
+        "kind=selected (min(length, topk) a row: what the attention "
+        "reads) | cached (the length: what the indexer scores)",
         labels=("engine", "kind"))
 
 

@@ -39,7 +39,14 @@ not run).
     keys and the mix letter for letter, the pool and the parameters by stack
     re-derived from what the application allocates, the reference gating a
     toy twin, and three faults in the PROGRAM (the groups, the shared
-    expert, the selection bias) failing it.
+    expert, the selection bias) failing it;
+  * ``keye-vl-2.0-30b-a3b`` (ISSUE 50): every published number but the
+    depth, the experts held and the vocabulary, the share's keys, ``assumed``
+    and the mix letter for letter, the three pools and the parameters
+    re-derived from what the application allocates, the twin's shrunk
+    ``topk``, the reference gating a toy twin, and three faults in the
+    PROGRAM (the selection, the head weights, the index keys' write) failing
+    it.
 """
 
 import glob
@@ -1257,5 +1264,254 @@ def test_a_fault_in_the_program_does_not_pass_the_deepseek_v3_toy_gate(
             lambda spec, h, router_w, router_bias=None: route(
                 spec, h, router_w, None))
     res = build.logit_gate(_toy_file(), seed=2**31 + 47,
+                           served_precision="highest")
+    assert not res["passed"] and res["worst_ratio"] > 10
+
+
+# ---------------------------------------------------------------------------
+# keye-vl-2.0-30b-a3b (ISSUE 50)
+# ---------------------------------------------------------------------------
+
+KEYE_CELL = "keye-vl2-videoqa-closed"
+
+
+def test_keye_vl2_keeps_every_published_number():
+    """The catalog row's ``config`` (model-configs guide), as copied into
+    ISSUE 50: every key at the top level of the file, no width changed,
+    ``sa_config`` whole, and ``reduced`` names the depth, the experts held
+    and the vocabulary, and nothing else."""
+    cfg = build.load_json("configs", "keye-vl-2.0-30b-a3b.json")
+    published = dict(
+        attention_bias=False, decoder_sparse_step=1, head_dim=128,
+        hidden_act="silu", hidden_size=2048, intermediate_size=6144,
+        max_position_embeddings=262144, max_window_layers=48,
+        mlp_only_layers=[], model_type="KeyeVL2", moe_intermediate_size=768,
+        norm_topk_prob=True, num_attention_heads=32, num_experts=128,
+        num_experts_per_tok=8, num_hidden_layers=48, num_key_value_heads=4,
+        num_local_experts=128, rms_norm_eps=1e-06,
+        rope_scaling={"mrope_section": [16, 24, 24], "rope_type": "default",
+                      "type": "default"},
+        rope_theta=10000000,
+        sa_config={"indexer_head_dim": 64, "indexer_num_heads": 16,
+                   "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+                   "q_chunk_size": 512, "topk": 2048},
+        sliding_window=None, tie_word_embeddings=False,
+        use_sliding_window=False, vocab_size=151936)
+    assert set(published) <= set(cfg)
+    differs = sorted(k for k in published if cfg[k] != published[k])
+    assert differs == sorted(cfg["reduced"]) == [
+        "num_experts", "num_hidden_layers", "vocab_size"]
+    # one chip's share of a stage: the guide's floors kept
+    assert (cfg["num_hidden_layers"], cfg["num_experts"],
+            cfg["router_num_experts"], cfg["first_expert"]) == \
+        (12, 16, 128, 0)
+    assert cfg["vocab_size"] * 8 == published["vocab_size"]
+    assert cfg["family"] == "keye_vl2" and cfg["chips"] == cfg["tp"] == 1
+    assert cfg["adapter"] == {"prefill_budget_tokens": max(
+        cfg["serve"]["context_encoding_buckets"])}
+    assert cfg["serve"]["is_prefix_caching"] is True
+    assert {"qk_norm", "indexer_input", "index_key_norm_and_rotary",
+            "indexer_tensor_names", "head_weight_scales", "chunk_sizes",
+            "index_score_precision", "selection", "index_key_pool", "mrope",
+            "vision_tower", "kv_dtype", "router_dtype", "expert_names",
+            "decode_form", "adapter", "moe_rooflines",
+            "seeded_routing"} <= set(cfg["assumed"])
+    assert "NO effect on the result" in cfg["assumed"]["chunk_sizes"]
+    gate = cfg["gate"]
+    twin = build.hf_config(cfg, build.gate_overrides(gate))
+    # the twin: two layers at the published widths, topk shrunk FOR THE
+    # TWIN so that 128 tokens a row cross it
+    assert (twin["num_hidden_layers"], twin["hidden_size"],
+            twin["num_experts"], twin["router_num_experts"],
+            twin["vocab_size"]) == (2, 2048, 16, 128, 18992)
+    assert twin["sa_config"] == dict(published["sa_config"], topk=32)
+    assert "FOR THE TWIN" in gate["config_why"]
+    assert (gate["batch"], gate["prompt_len"], gate["new_tokens"]) == \
+        (8, 112, 16)
+    assert gate["prompt_len"] + gate["new_tokens"] <= \
+        4 * cfg["serve"]["pa_block_size"]
+    assert twin["sa_config"]["topk"] < gate["prompt_len"]
+    assert gate["excuse_margin_max"] == 0.01
+    # between sound's largest median over the seeds of PR 50 (0.28) and
+    # the weakest failing control's (the index key in fp8: 1.8); under
+    # sound's least decode share (0.33), over the controls' (0.0)
+    assert 0.28 < gate["median_ratio_max"] < 1.8
+    assert 0.0 < gate["min_positions_held"] < 0.33
+    for control in ("dense attention", "ReLU dropped", "head weights",
+                    "topk halved", "un-normed", "un-rotated", "fp8",
+                    "ONE control PASSES"):
+        assert control in gate["controls"], control
+    ref = build.load_reference("KeyeVL2")
+    assert len(ref.CONTROLS) == 8
+    # the pool cannot run dry: every row at its longest prompt and answer
+    mix = build.load_json("traffic", "videoqa-closed.json")
+    serve = cfg["serve"]
+    longest = mix["prompt_len"]["hi"] + mix["output_len"]["hi"]
+    assert longest == serve["seq_len"] == 12288 <= \
+        cfg["max_position_embeddings"]
+    assert serve["pa_num_blocks"] * serve["pa_block_size"] == \
+        serve["batch_size"] * longest
+    # the mix is ISSUE 50's, every number of it
+    assert (mix["loop"], mix["clients_per_batch_row"], mix["pool_requests"],
+            mix["lead_s"], mix["grace_s"], mix["base_seed"]) == \
+        ("closed", 2, 4096, 30.0, 8.0, 50)
+    assert mix["prompt_len"] == dict(kind="lognormal", median=5120,
+                                     sigma=0.6, lo=1024, hi=10240)
+    assert mix["output_len"] == dict(kind="lognormal", median=768, sigma=0.6,
+                                     lo=192, hi=2048)
+    assert build.load_json("cells", KEYE_CELL + ".json") == dict(
+        config="keye-vl-2.0-30b-a3b", traffic="videoqa-closed", chips=1)
+    # the cell is on the five metrics this PR brings, one of them the
+    # expert roofline of a share under Qwen's key names
+    listed = {m["name"] for m in BENCHMARK["per_layer"]
+              if KEYE_CELL in m.get("workloads", ())}
+    assert {"step.decode_indexer_ms", "step.prefill_indexer_ms",
+            "attn.sparse_selected_share", "kernel.sparse_decode_roofline",
+            "kernel.moe_decode_share_roofline",
+            "kernel.paged_decode_roofline",
+            "attn.paged_prefill_kernel_share", "moe.prefill_walk_share",
+            "moe.experts_touched_share", "host.stall_s"} <= listed
+    assert KEYE_CELL in next(m for m in BENCHMARK["end_to_end"]
+                             if m["name"] == "tokens_per_s")["workloads"]
+
+
+def test_keye_vl2_allocates_what_its_file_says():
+    """The three pools, the weights and the total of the file's ``memory``,
+    against what the program would allocate: the full configuration's pools
+    and parameters as SHAPES (nothing of 12.75 GB is allocated)."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    from neuronx_distributed_inference_tpu.models import model_base
+    from neuronx_distributed_inference_tpu.modules.block_kv_cache import (
+        index_pool_shape, pool_spec)
+    from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
+    cfg = build.load_json("configs", "keye-vl-2.0-30b-a3b.json")
+    memory, serve = cfg["memory"], cfg["serve"]
+    spec = build.build_app(cfg).spec
+    sp, m = spec.sparse, spec.moe
+    assert (sp.index_heads, sp.index_dim, sp.topk) == (16, 64, 2048)
+    assert abs(sp.rope.rope_theta - 1e7) < 1e-3 and sp.rope.head_dim == 64
+    assert (m.num_experts, m.num_held, m.first_expert, m.top_k,
+            m.intermediate_size) == (128, 16, 0, 8, 768)
+    assert m.normalize_topk and spec.qk_norm and spec.num_layers == 12
+    pool = pool_spec(spec, serve["pa_num_blocks"], serve["pa_block_size"])
+    assert pool.shape == (12, 12289, 32, 1, 512)
+    assert str(jnp.dtype(pool.dtype)) == cfg["assumed"]["kv_dtype"]
+    assert pool.bytes_per_token == memory["kv_bytes_per_token"] == \
+        12 * 4 * 128 * 2 * 2
+    assert 2 * math.prod(pool.shape) * 2 == memory["kv_pool_bytes"]
+    index = index_pool_shape(spec, serve["pa_num_blocks"],
+                             serve["pa_block_size"])
+    # two tokens of 64 values to a 128-lane row: no byte pads a key
+    assert index == (12, 12289, 16, 128)
+    assert math.prod(index) * 2 == memory["index_pool_bytes"]
+    assert memory["index_bytes_per_token"] == 12 * 64 * 2
+    leaves = jax.tree.leaves(model_base.decoder_param_specs(spec),
+                             is_leaf=lambda x: isinstance(x, ParamSpec))
+    a_layer = (2 * 2048 * 32 * 128 + 2 * 2048 * 4 * 128 + 2 * 2048 + 2 * 128
+               + 2048 * (16 * 64 + 64 + 16) + 2 * 64 + 2048 * 128)
+    assert a_layer == 21_401_984
+    # the file counts 18,992 rows of vocabulary; the program rounds them up
+    padded = 2 * (spec.padded_vocab - cfg["vocab_size"]) * 2048
+    assert sum(math.prod(ps.shape) for ps in leaves) - padded == \
+        memory["parameters"] == 12 * (a_layer + 16 * 3 * 2048 * 768) \
+        + 2 * 18_992 * 2048 + 2048 == 1_240_586_752
+    weights = sum(math.prod(ps.shape) * jnp.dtype(ps.dtype).itemsize
+                  for ps in leaves)
+    # the program's count over the file's all-bf16 one: the routers in
+    # float32 (2 B more an entry) and the padded rows
+    assert weights - 2 * 12 * 2048 * 128 - 2 * padded == \
+        memory["weights_bytes"] == 2 * memory["parameters"]
+    total = (memory["weights_bytes"] + memory["kv_pool_bytes"]
+             + memory["index_pool_bytes"])
+    assert total == memory["before_temps_bytes"]
+    assert 0.79 * 16e9 < total < 0.81 * 16e9
+    assert total + memory["widest_program_temps_bytes"] < 15.75 * 2 ** 30
+
+
+def test_keye_vl2_share_roofline_counts_what_the_walk_must_read(monkeypatch):
+    """The expert roofline of a share under Qwen's key names, from a
+    made-up window: the need is the held experts a decode step TOUCHED
+    times an expert's three projections plus every layer's router, at the
+    stream's bandwidth; the time the ``moe`` scope's of ``paged.w1``; a
+    program without the counters, or a configuration of other key names,
+    reads nothing."""
+    from harness import host_spans, readers
+    metric = "kernel.moe_decode_share_roofline"
+    cfg = build.load_json("configs", "keye-vl-2.0-30b-a3b.json")
+    expert, router = 3 * 2048 * 768 * 2, 2048 * 128 * 2
+    programs = {"paged.w1": dict(count=100, total_s=1.5),
+                "paged.w256": dict(count=90, total_s=1.0)}
+    scopes = {"paged.w1": {"moe": 0.2, "attn": 0.9},
+              "paged.w256": {"moe": 0.135, "attn": 0.4}}
+
+    def ctx(counters, config=cfg):
+        # what harness/readers.py hands a python reader, cut to what this
+        # one reads; the profiled slice as each program's time by scope
+        return {"config": config, "peaks": {"hbm_gbps": 819.0},
+                "warm_widths": [1, 64, 256], "before": {"counters": {}},
+                "after": {"counters": {"host_stats." + k: v
+                                       for k, v in counters.items()}},
+                "trace": {"programs": programs},
+                "_slice": {"scopes": {
+                    label: dict(programs[label], scopes=scopes[label])
+                    for label in programs}}}
+    monkeypatch.setattr(host_spans, "load_slice", lambda c: c["_slice"])
+    # 50 decode steps fetched, 12.5 of 16 held experts touched a layer
+    counters = dict(moe_expert_slots=50 * 12 * 16,
+                    moe_experts_touched=50 * 12 * 12.5)
+    got = readers.read_metric(metric, ctx(counters))
+    least = (12 * 12.5 * expert + 12 * router) / 819e9
+    assert got == pytest.approx(100 * least / 2e-3) and 80 < got < 90
+    assert readers.read_metric(metric, ctx({})) is None
+    assert readers.read_metric(
+        metric, ctx(counters, dict(cfg, router_num_experts=None))) is None
+    # every held expert touched is the ceiling of the need: 2.22 ms a step
+    full = dict(counters, moe_experts_touched=50 * 12 * 16)
+    ceiling = (12 * 16 * expert + 12 * router) / 819e9
+    assert ceiling == pytest.approx(2.2201e-3, rel=1e-3)
+    assert readers.read_metric(metric, ctx(full)) == \
+        pytest.approx(100 * ceiling / 2e-3)
+
+
+def test_the_keye_vl2_reference_gates_a_toy_twin():
+    from test_keye_vl2_paged import _toy_file
+    ref = build.load_reference("KeyeVL2")
+    assert ref.__file__ == os.path.join(BENCH, "references", "KeyeVL2.py")
+    toy = _toy_file()
+    twin = build.hf_config(toy, build.gate_overrides(toy["gate"]))
+    assert twin["sa_config"]["topk"] == 8 < toy["gate"]["prompt_len"]
+    res = build.logit_gate(toy, seed=2**31 + 51, served_precision="highest")
+    assert res["passed"], res
+    assert res["compared"] == 2 * 32 * toy["vocab_size"]
+
+
+@pytest.mark.parametrize("fault", ["selection", "head_weights",
+                                   "index_keys"])
+def test_a_fault_in_the_program_does_not_pass_the_keye_vl2_toy_gate(
+        monkeypatch, fault):
+    """The other direction of the controls: the PROGRAM broken, the
+    reference sound. An attention that reads every cached token, index
+    scores that drop their head weights, index keys that never reach their
+    pool."""
+    import jax.numpy as jnp
+    from neuronx_distributed_inference_tpu.models import model_base
+    from neuronx_distributed_inference_tpu.modules import block_kv_cache
+    from test_keye_vl2_paged import _toy_file
+    if fault == "selection":
+        monkeypatch.setattr(model_base, "topk_select",
+                            lambda scores, valid, k: valid)
+    elif fault == "head_weights":
+        scores = model_base._index_scores
+        monkeypatch.setattr(
+            model_base, "_index_scores",
+            lambda sp, qi, w, rows: scores(sp, qi, jnp.ones_like(w), rows))
+    else:
+        monkeypatch.setattr(
+            block_kv_cache, "write_index_keys",
+            lambda pool, new, layer, slots, positions, bs: pool)
+    res = build.logit_gate(_toy_file(), seed=2**31 + 51,
                            served_precision="highest")
     assert not res["passed"] and res["worst_ratio"] > 10

@@ -30,7 +30,7 @@ from neuronx_distributed_inference_tpu.models import model_base
 from neuronx_distributed_inference_tpu.models.family import get_family
 from neuronx_distributed_inference_tpu.modules import ssm
 from neuronx_distributed_inference_tpu.modules.block_kv_cache import (
-    block_cache_pspec, pool_spec, window_pool_spec)
+    block_cache_pspec, index_pool_shape, pool_spec, window_pool_spec)
 from neuronx_distributed_inference_tpu.ops import kernel_mode
 from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
 from neuronx_distributed_inference_tpu.parallel.mesh import (MeshConfig,
@@ -127,6 +127,11 @@ def _serving_shapes(hf_attrs, layers, tp, devices, serve, prefix=True):
                                  max(tcfg.context_encoding_buckets))
         cache["k_w"] = sds(wspec.shape, wspec.dtype, pool_pspec)
         cache["v_w"] = sds(wspec.v_shape, wspec.dtype, pool_pspec)
+    if spec.sparse is not None:
+        # a learned sparse selection's index keys, as init_cache sizes them
+        cache["k_idx"] = sds(index_pool_shape(spec, tcfg.pa_num_blocks,
+                                              tcfg.pa_block_size),
+                             spec.kv_dtype)
     if spec.ssm is not None:
         pspecs = ssm.ssm_state_pspecs(spec.ssm)
         for k, (shape, dt) in ssm.ssm_state_shapes(
@@ -990,6 +995,92 @@ def test_the_widest_smallthinker_program_fits_beside_weights_and_pools(
     pack, _ = _smallthinker_program(v5e_devices, 32, 256)
     memory = pack.memory_analysis()
     assert 11.6e9 < memory.argument_size_in_bytes < 11.75e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2 ** 30 - 258e6
+
+
+# Kwai-Keye/Keye-VL-2.0-30B-A3B config.json (model-configs catalog) at the
+# benchmark's cut: one chip's share of a stage, 12 layers, 16 held experts
+# of a router over 128, an eighth of the vocabulary
+KEYE_VL2_30B = dict(
+    model_type="KeyeVL2", attention_bias=False, decoder_sparse_step=1,
+    head_dim=128, hidden_act="silu", hidden_size=2048,
+    intermediate_size=6144, max_position_embeddings=262144,
+    mlp_only_layers=[], moe_intermediate_size=768, norm_topk_prob=True,
+    num_attention_heads=32, num_experts=16, router_num_experts=128,
+    first_expert=0, num_experts_per_tok=8, num_hidden_layers=12,
+    num_key_value_heads=4, rms_norm_eps=1e-6,
+    rope_scaling={"mrope_section": [16, 24, 24], "rope_type": "default",
+                  "type": "default"},
+    rope_theta=10000000,
+    sa_config={"indexer_head_dim": 64, "indexer_num_heads": 16,
+               "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+               "q_chunk_size": 512, "topk": 2048},
+    tie_word_embeddings=False, vocab_size=18992)
+KEYE_VL2_SERVE = dict(batch_size=32, seq_len=12288, pa_block_size=32,
+                      pa_num_blocks=12288,
+                      context_encoding_buckets=[64, 256])
+KEYE_VL2_POOLS = ("12,12289,32,1,512", "12,12289,32,512", "12,393248,1,512",
+                  "147468,32,1,512", "147468,32,512", "12,12289,16,128",
+                  "12,196624,128", "147468,16,128")
+
+
+def _keye_vl2_program(v5e_devices, rows, width):
+    shapes = _serving_shapes(KEYE_VL2_30B, 12, 1, v5e_devices[:1],
+                             KEYE_VL2_SERVE)
+    spec, cache, mb = shapes[0], shapes[4], shapes[6]
+    assert spec.sparse.topk == 2048 and mb == 384
+    assert spec.moe.num_held == 16 and spec.moe.num_experts == 128
+    assert cache["k"].shape == (12, 12289, 32, 1, 512)
+    assert cache["k_idx"].shape == (12, 12289, 16, 128)
+    return _compiled_paged_step(shapes, rows, width)
+
+
+def test_keye_vl2_decodes_over_three_pools_in_place(v5e_devices):
+    """ISSUE 50: the decode step at Keye-VL-2.0's widths and the cell's
+    serving shape (12 layers, K / V pools of 12,288 blocks and the index
+    keys' pool on the same table) holds the paged decode kernel with the
+    selection as one more input and the walk over the 16 held experts of a
+    router over 128; nothing copies, transposes or relays any of the three
+    pools."""
+    step, notes = _keye_vl2_program(v5e_devices, 32, 1)
+    assert ("moe_share", "xla", "held=16 of 128 from 0 top_k=8") in notes
+    assert any(s == "moe_decode" and p == "pallas" for s, p, _ in notes)
+    assert any(s == "paged_decode" and p == "pallas" and "heads=4" in w
+               for s, p, w in notes), notes
+    assert ("sparse_attn", "pallas",
+            "masked: the live pages' walk, a token attended where selected, "
+            "a decode step") in notes
+    assert any(s == "kv_index_pool" and "page=16x128" in w and "topk=2048"
+               in w for s, _, w in notes)
+    text = step.as_text()
+    assert re.findall(r"%paged_decode_attention[.\d]* = ", text)
+    movers = _pool_movers(text, KEYE_VL2_POOLS)
+    assert not movers, movers
+    assert step.memory_analysis().temp_size_in_bytes < 400e6
+
+
+@pytest.mark.parametrize("rows, temps_under", [(1, 0.6e9), (32, 2.4e9)],
+                         ids=["chunk", "pack"])
+def test_keye_vl2_chunks_select_and_attend_on_the_prefill_kernel(
+        v5e_devices, rows, temps_under):
+    """ISSUE 50: the one-row chunk (``paged.w256``) and the pack
+    (``paged_pack.w256``) at the cell's serving shape hold
+    ``paged_prefill_attention`` with the selection as one more input; the
+    indexer's float32 scores (16 heads x 256 x 12,288 a row) go through in
+    row groups; the widest program fits beside 12.75+ GB of weights and
+    pools; no pool is copied."""
+    program, notes = _keye_vl2_program(v5e_devices, rows, 256)
+    assert ("sparse_attn", "pallas",
+            f"masked: rows={rows} width=256, a query attends where selected"
+            ) in notes
+    assert any(s == "paged_prefill" and p == "pallas" for s, p, _ in notes)
+    text = program.as_text()
+    assert re.findall(r"%paged_prefill_attention[.\d]* = ", text)
+    movers = _pool_movers(text, KEYE_VL2_POOLS)
+    assert not movers, movers
+    memory = program.memory_analysis()
+    assert memory.temp_size_in_bytes < temps_under
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 15.75 * 2 ** 30 - 258e6
 
