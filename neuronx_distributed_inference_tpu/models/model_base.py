@@ -2204,10 +2204,15 @@ def _indexer_block(spec: DecoderSpec, h, layer_w, pool, li, ai, positions,
     where query ``t`` attends the token at that position (causal, written,
     among its ``topk``). A sibling of ``attn`` in the trace, not inside it.
 
-    The float32 score temps are ``heads`` x the selection's own size, so
-    the rows go through in groups where they would outgrow the budget
-    (:func:`map_row_groups`)."""
+    The scores and the selection are ONE kernel over the row's live index
+    pages where the call allows it (``ops/index_select.py``: its ``declined``
+    names why not - a decode step's single query a row by the clock - and
+    the engagement record ``index_select`` which form a program took); else
+    :func:`_gathered_select`, the table's pages gathered. Both read the
+    step's own keys from the pool, so the write comes first, and both give
+    the same set wherever their float32 scores are equal bit for bit."""
     from ..modules import block_kv_cache as bkv
+    from ..ops import index_select
     sp = spec.sparse
     b, t, _ = h.shape
     nj, dim = sp.index_heads, sp.index_dim
@@ -2220,17 +2225,12 @@ def _indexer_block(spec: DecoderSpec, h, layer_w, pool, li, ai, positions,
     ki = apply_rope(ki[:, :, None, :], cos, sin)[:, :, 0]
     bs = (pool.shape[2] * pool.shape[3]) // dim
     pool = bkv.write_index_keys(pool, ki, li, slot_mapping, positions, bs)
-    kpos = jnp.arange(block_table.shape[1] * bs, dtype=jnp.int32)
-
-    def select_of(qi_, w_, table_, pos_):
-        scores = _index_scores(
-            sp, qi_, w_, bkv.gather_index_rows(pool, li, table_))
-        seen = kpos[None, None, :] <= pos_[:, :, None]
-        return topk_select(scores, seen, sp.topk)
-
-    select = map_row_groups(
-        select_of, 4 * (nj + 4) * t * kpos.shape[0], qi, w, block_table,
-        positions.astype(jnp.int32))
+    # None where the kernel is declined: the engagement record says why
+    select = index_select.select_of(spec, qi, w, pool, li, positions,
+                                    block_table)
+    if select is None:
+        select = _gathered_select(sp, qi, w, pool, li, positions,
+                                  block_table)
     return select, pool
 
 
@@ -3913,3 +3913,29 @@ def spec_from_config(config: InferenceConfig, tp_degree: Optional[int] = None,
             f"seq_len {tcfg.seq_len} exceeds the learned position table "
             f"({kw['learned_pos']} positions)")
     return DecoderSpec(**kw)
+
+
+def _gathered_select(sp: SparseSpec, qi, w, pool, li, positions,
+                     block_table):
+    """:func:`_indexer_block`'s selection where the kernel is declined
+    (``ops/index_select.py``), and the tests' oracle: the pages of the WHOLE
+    block table gathered from ``pool`` (the step's keys already in it),
+    :func:`_index_scores`, the causal mask by position and
+    :func:`topk_select`. The float32 score temps are ``heads`` x the
+    selection's own size, so the rows go through in groups where they would
+    outgrow the budget (:func:`map_row_groups`). Returns (B, T, table tokens)
+    bool."""
+    from ..modules import block_kv_cache as bkv
+    t = qi.shape[1]
+    bs = (pool.shape[2] * pool.shape[3]) // sp.index_dim
+    kpos = jnp.arange(block_table.shape[1] * bs, dtype=jnp.int32)
+
+    def select_of(qi_, w_, table_, pos_):
+        scores = _index_scores(
+            sp, qi_, w_, bkv.gather_index_rows(pool, li, table_))
+        seen = kpos[None, None, :] <= pos_[:, :, None]
+        return topk_select(scores, seen, sp.topk)
+
+    return map_row_groups(
+        select_of, 4 * (sp.index_heads + 4) * t * kpos.shape[0], qi, w,
+        block_table, positions.astype(jnp.int32))

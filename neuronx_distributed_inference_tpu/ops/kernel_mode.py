@@ -94,3 +94,11 @@ def paged_prefill_on_kernel(notes) -> bool:
     (``ops/paged_prefill.py`` ``chunk_attention`` writes them)."""
     return any(site == "paged_prefill" and path != "xla"
                for site, path, _ in notes)
+
+
+def select_on_kernel(notes) -> bool:
+    """Whether a program of a stack with a learned sparse selection scored
+    and searched on the selection kernel, from the :func:`note` triples its
+    trace left (``ops/index_select.py`` ``select_of`` writes them)."""
+    return any(site == "index_select" and path != "xla"
+               for site, path, _ in notes)

@@ -921,9 +921,16 @@ class PagedEngineAdapter:
                 kv_spill_tier is not None and "host KV spill / handoff"])
             if why:
                 raise ConfigurationError(why)
+            # ... and every dispatch, of either width, whether its program
+            # scored and searched on the selection kernel
+            # (kernel_mode.select_on_kernel)
             self.host_stats.update(
                 sparse_tokens_selected=0, sparse_tokens_cached=0,
-                kv_index_pages_held=0)
+                kv_index_pages_held=0, sparse_dispatches=0,
+                sparse_dispatches_select_kernel=0)
+        # paged program shape -> its selection ran on the kernel
+        # (_count_select)
+        self._select_kernel_shapes: Dict[Tuple[int, int], bool] = {}
         # paged program shape -> its state is stepped by the kernel
         # (_state_on_kernel)
         self._state_kernel_shapes: Dict[Tuple[int, int], bool] = {}
@@ -1762,6 +1769,21 @@ class PagedEngineAdapter:
                     self.app.paged_program_notes(*shape))
         return on
 
+    def _count_select(self, shape) -> None:
+        """A dispatch of the paged program of ``shape`` of a stack with a
+        learned sparse selection, once issued: one more
+        ``sparse_dispatches``, and one more
+        ``sparse_dispatches_select_kernel`` where the program's engagement
+        record says its selection ran on the kernel (the rule itself lives
+        in ops/index_select.declined alone), read once a program shape."""
+        on = self._select_kernel_shapes.get(shape)
+        if on is None:
+            on = self._select_kernel_shapes[shape] = \
+                kernel_mode.select_on_kernel(
+                    self.app.paged_program_notes(*shape))
+        self.host_stats["sparse_dispatches"] += 1
+        self.host_stats["sparse_dispatches_select_kernel"] += on
+
     def _dispatch_decode(self, scr: _PagedScratch, toks_dev=None):
         """Issue ONE paged decode step to the device without materializing
         any output (region lint: nxdi_lint host-sync pass). ``toks_dev``:
@@ -1791,6 +1813,7 @@ class PagedEngineAdapter:
             self._count_window_pool()
         if self._sparse is not None:
             self._count_sparse()
+            self._count_select(scr.ids.shape)
         rec = _get_recorder()
         if rec.enabled:
             rec.instant("dispatch.decode", cat="adapter",
@@ -2623,6 +2646,8 @@ class PagedEngineAdapter:
         if kernel_mode.paged_prefill_on_kernel(notes):
             self.host_stats["prefill_dispatches_paged_attn_kernel"] += 1
             attn = "paged"
+        if self._sparse is not None:
+            self._count_select(ids_p.shape)
         self.telemetry.on_prefill_dispatch(experts, attn)
         return out
 

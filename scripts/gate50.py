@@ -144,6 +144,7 @@ def long_walk(cfg, seed, tokens, rows=4, new_tokens=16, block=512,
            "notes": sorted({(x["site"], x["path"], x["reason"])
                             for x in app.warmup_state()["kernels"]
                             if x["site"] in ("sparse_attn", "kv_index_pool",
+                                             "index_select",
                                              "paged_decode", "paged_prefill",
                                              "kv_pool", "moe_decode",
                                              "moe_share")})}

@@ -1370,6 +1370,7 @@ def test_keye_vl2_keeps_every_published_number():
             "kernel.moe_decode_share_roofline",
             "kernel.paged_decode_roofline",
             "attn.paged_prefill_kernel_share", "moe.prefill_walk_share",
+            "attn.sparse_select_kernel_share",
             "moe.experts_touched_share", "host.stall_s"} <= listed
     assert KEYE_CELL in next(m for m in BENCHMARK["end_to_end"]
                              if m["name"] == "tokens_per_s")["workloads"]
@@ -1474,6 +1475,29 @@ def test_keye_vl2_share_roofline_counts_what_the_walk_must_read(monkeypatch):
     assert ceiling == pytest.approx(2.2201e-3, rel=1e-3)
     assert readers.read_metric(metric, ctx(full)) == \
         pytest.approx(100 * ceiling / 2e-3)
+
+
+def test_keye_vl2_select_kernel_share_reads_the_adapters_two_counters():
+    """``attn.sparse_select_kernel_share`` (ISSUE 51): the dispatches whose
+    program's selection ran on the kernel over the dispatches of a stack
+    with an indexer, the cell's alone; a program without the counters (the
+    parent, a stack without a selection) reads nothing."""
+    from harness import readers
+    metric = "attn.sparse_select_kernel_share"
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == metric)
+    assert entry == dict(
+        name=metric, unit="%", better="higher", source="program_counter",
+        layer="Step graphs", moves="itl_p50_ms", workloads=[KEYE_CELL])
+    assert BENCHMARK["per_layer"][-1] == entry
+
+    def ctx(**counters):
+        return {"before": {"counters": {"host_stats.sparse_dispatches": 10}},
+                "after": {"counters": {"host_stats." + k: v
+                                       for k, v in counters.items()}}}
+    assert readers.read_metric(metric, ctx(
+        sparse_dispatches=231, sparse_dispatches_select_kernel=87)) == \
+        pytest.approx(100 * 87 / 221)
+    assert readers.read_metric(metric, ctx(dispatches=5)) is None
 
 
 def test_the_keye_vl2_reference_gates_a_toy_twin():

@@ -1053,30 +1053,40 @@ def test_keye_vl2_decodes_over_three_pools_in_place(v5e_devices):
             "a decode step") in notes
     assert any(s == "kv_index_pool" and "page=16x128" in w and "topk=2048"
                in w for s, _, w in notes)
+    # ISSUE 51: a single query a row keeps the gathered selection, by the
+    # clock (``index_select.declined``)
+    assert ("index_select", "xla", "rows=32 width=1: one query a row: the "
+            "gathered form is ahead by the clock") in notes
     text = step.as_text()
+    assert not re.findall(r"%index_select[.\d]* = ", text)
     assert re.findall(r"%paged_decode_attention[.\d]* = ", text)
     movers = _pool_movers(text, KEYE_VL2_POOLS)
     assert not movers, movers
     assert step.memory_analysis().temp_size_in_bytes < 400e6
 
 
-@pytest.mark.parametrize("rows, temps_under", [(1, 0.6e9), (32, 2.4e9)],
+@pytest.mark.parametrize("rows, temps_under", [(1, 0.2e9), (32, 2.0e9)],
                          ids=["chunk", "pack"])
 def test_keye_vl2_chunks_select_and_attend_on_the_prefill_kernel(
         v5e_devices, rows, temps_under):
     """ISSUE 50: the one-row chunk (``paged.w256``) and the pack
     (``paged_pack.w256``) at the cell's serving shape hold
-    ``paged_prefill_attention`` with the selection as one more input; the
-    indexer's float32 scores (16 heads x 256 x 12,288 a row) go through in
-    row groups; the widest program fits beside 12.75+ GB of weights and
-    pools; no pool is copied."""
+    ``paged_prefill_attention`` with the selection as one more input;
+    since ISSUE 51 the selection is ``index_select``'s (the rows are its
+    grid: no float32 score leaves VMEM and no row groups); the widest
+    program fits beside 12.75+ GB of weights and pools; no pool is
+    copied."""
     program, notes = _keye_vl2_program(v5e_devices, rows, 256)
+    assert ("index_select", "pallas",
+            f"rows={rows} width=256 pages=16 heads=16x64 fold=2 topk=2048 "
+            "tile=256x16") in notes
     assert ("sparse_attn", "pallas",
             f"masked: rows={rows} width=256, a query attends where selected"
             ) in notes
     assert any(s == "paged_prefill" and p == "pallas" for s, p, _ in notes)
     text = program.as_text()
     assert re.findall(r"%paged_prefill_attention[.\d]* = ", text)
+    assert re.findall(r"%index_select[.\d]* = ", text)
     movers = _pool_movers(text, KEYE_VL2_POOLS)
     assert not movers, movers
     memory = program.memory_analysis()

@@ -60,6 +60,7 @@ from neuronx_distributed_inference_tpu.serving import \
 from neuronx_distributed_inference_tpu.serving.warmup import \
     memory_ledger  # noqa: E402
 from neuronx_distributed_inference_tpu import telemetry  # noqa: E402
+from neuronx_distributed_inference_tpu.ops import kernel_mode  # noqa: E402
 from test_recurrent_paged import LogitTap, _decode  # noqa: E402
 
 SA = {"indexer_head_dim": 64, "indexer_num_heads": 4,
@@ -209,6 +210,31 @@ def test_a_three_chunks_then_decode_select_as_the_reference_does(
     assert {p for p, _ in notes["paged_prefill"]} == {kernel}
     assert notes["kv_index_pool"][0][1].startswith(
         "page=4x128 values_a_token=64 heads=4 topk=16")
+    # the selection's own record, a program each: the chunks score and
+    # search on the kernel, the step keeps the gathered form by the clock
+    # (``index_select.declined``); with the kernels switched off every
+    # program says so, and the streams above are the same tokens
+    select = {why.split(":")[0]: path
+              for path, why in notes["index_select"]}
+    off = form == "masked-gathered"
+    assert select == {
+        f"rows={BATCH} width=1": "xla",
+        **({"rows=1 width=32": "xla", "rows=1 width=16": "xla"} if off else {
+            "rows=1 width=32 pages=16 heads=4x64 fold=2 topk=16 tile=32x16":
+                kernel,
+            "rows=1 width=16 pages=16 heads=4x64 fold=2 topk=16 tile=16x16":
+                kernel})}
+    assert all(("decode_kernel=False" in why) == off
+               for _, why in notes["index_select"] if "width=1:" in why)
+    # ... and what the adapter counts from it: every dispatch, and those
+    # whose program's record names the kernel
+    st = ad.host_stats
+    assert st["sparse_dispatches"] == st["dispatches"] \
+        + st["prefill_dispatches"] == 12 + 3
+    assert st["sparse_dispatches_select_kernel"] == (0 if off else 3)
+    for shape in [(1, 32), (1, 16), (BATCH, 1)]:
+        assert kernel_mode.select_on_kernel(
+            app.paged_program_notes(*shape)) is (not off and shape[1] > 1)
 
 
 def test_a_toy_of_narrow_heads_gathers_the_table_with_the_selection(
@@ -544,7 +570,9 @@ def test_e_a_stack_without_a_selection_keeps_its_programs(ref,
     _decode(ad, [1], stream, 2)
     notes = _notes(app)
     assert "sparse_attn" not in notes and "kv_index_pool" not in notes
+    assert "index_select" not in notes
     assert "sparse_tokens_selected" not in ad.host_stats
+    assert "sparse_dispatches" not in ad.host_stats
     assert set(app.cache) == {"k", "v"}
 
 
