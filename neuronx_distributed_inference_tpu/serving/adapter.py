@@ -133,6 +133,7 @@ from ..resilience.preemption import (PREEMPTION_POLICIES, Preempted,
                                     pick_victim)
 from ..telemetry import get_registry
 from ..telemetry import metrics as tmetrics
+from ..telemetry.request_trace import RequestTimeline
 from ..telemetry.request_trace import trace_of as _trace_of
 from ..telemetry.trace import get_recorder as _get_recorder
 
@@ -161,6 +162,9 @@ class _ChunkState:
     done: int                     # tokens whose KV is written/cached
     admit_idx: int
     t0: float                     # admission wall time (TTFT anchor)
+    # the request's timeline (the engine's record, or add_requests' own for
+    # a caller without one): ``dispatch`` and ``token`` are stamped here
+    timeline: RequestTimeline
     deadline: Optional[float] = None
     expired_reported: bool = False
     meta: Any = None              # opaque engine passthrough (tenant, ...)
@@ -272,22 +276,31 @@ class _AdapterTelemetry:
             else get_registry()
 
     def on_add(self, seq_ids: Sequence[int], prompts, t0: float,
+               t_tokens: Sequence[float],
                live: int, padded: int, count_rows: bool = True,
                tenants: Optional[Sequence[str]] = None):
+        """``t0``: the ``add_requests`` call's start. ``t_tokens``: where
+        each request's first token of THIS admission was host-visible, the
+        reading ``_fetch_prefill_tokens`` took (no clock is read here). On
+        a first admission it is the timeline's ``token`` stamp; a request
+        re-admitted after a preemption keeps that stamp and brings the
+        instant of its recompute here."""
         reg = self.registry
         if not reg.enabled:
             return
         if tenants is None:
             tenants = [""] * len(seq_ids)
-        ttft = time.perf_counter() - t0
         hist = tmetrics.ttft_histogram(reg)
-        for sid, prompt, tenant in zip(seq_ids, prompts, tenants):
+        for sid, prompt, tenant, t_token in zip(seq_ids, prompts, tenants,
+                                                t_tokens):
+            ttft = t_token - t0
             span = reg.start_span("request", engine=self.engine, seq_id=sid,
                                   tenant=tenant)
             span.t_start = t0
-            span.event("first_token", ttft_s=ttft, prompt_len=len(prompt))
+            span.event("first_token", at=t_token, ttft_s=ttft,
+                       prompt_len=len(prompt))
             self._requests[sid] = {"span": span, "steps": 0,
-                                   "t_first": t0 + ttft, "t_last": t0 + ttft,
+                                   "t_token": t_token, "t_last": t_token,
                                    "tenant": tenant}
             hist.observe(ttft, engine=self.engine, tenant=tenant)
         tmetrics.requests_counter(reg).inc(len(seq_ids), engine=self.engine,
@@ -446,7 +459,7 @@ class _AdapterTelemetry:
                 # parked finished while the engine drains others must not
                 # inflate its reported per-token latency
                 tmetrics.tpot_histogram(reg).observe(
-                    (info["t_last"] - info["t_first"]) / steps,
+                    (info["t_last"] - info["t_token"]) / steps,
                     engine=self.engine, tenant=info.get("tenant", ""))
             span.end()
         if released and reg.enabled:
@@ -987,7 +1000,8 @@ class PagedEngineAdapter:
                      prompts: Sequence[Sequence[int]],
                      deadline_s: Union[None, float,
                                        Sequence[Optional[float]]] = None,
-                     meta: Optional[Sequence[Any]] = None
+                     meta: Optional[Sequence[Any]] = None,
+                     timelines: Optional[Sequence[RequestTimeline]] = None
                      ) -> Dict[int, int]:
         """Transactional admission: either every sequence is admitted, or
         every ``begin_sequence`` allocation from this call is rolled back
@@ -1006,7 +1020,13 @@ class PagedEngineAdapter:
         ``meta`` (optional, one opaque object per sequence) is a scheduler
         passthrough: the adapter never interprets it beyond reading a
         "tenant" key for telemetry labels, and hands it back verbatim on
-        :class:`Preempted` records so a requeue needs no side tables."""
+        :class:`Preempted` records so a requeue needs no side tables.
+
+        ``timelines`` (optional, one per sequence) are the requests' own
+        records of their way to a first token (telemetry/request_trace.py):
+        the adapter stamps ``dispatch`` where a prompt's first chunk is
+        enqueued and ``token`` where its first token is host-visible. A
+        caller without them gets records of the adapter's own."""
         from ..modules.block_kv_cache import cut_cached_at_unwritten
         _validate_admission(seq_ids, prompts, self.app.tpu_config.seq_len)
         for sid in seq_ids:
@@ -1027,6 +1047,10 @@ class PagedEngineAdapter:
         if meta is not None and len(meta) != len(seq_ids):
             raise AdmissionError("meta and seq_ids length mismatch")
         metas = list(meta) if meta is not None else [None] * len(seq_ids)
+        if timelines is None:
+            timelines = [RequestTimeline() for _ in seq_ids]
+        elif len(timelines) != len(seq_ids):
+            raise AdmissionError("timelines and seq_ids length mismatch")
         app = self.app
         bs = app.kv_mgr.spec.block_size
         protect = frozenset(seq_ids)
@@ -1069,7 +1093,8 @@ class PagedEngineAdapter:
                 self._chunks[sid] = _ChunkState(
                     prompt=prompt, done=int(c),
                     admit_idx=self._admit_counter, t0=t0,
-                    deadline=deadlines[i], meta=metas[i])
+                    timeline=timelines[i], deadline=deadlines[i],
+                    meta=metas[i])
                 self._bind_adapter(sid, metas[i])
         except ServingError:
             self._rollback_admission(begun)
@@ -1089,11 +1114,12 @@ class PagedEngineAdapter:
             # rows, so admission never serializes its own device work)
             return {}
         cache_before = app.cache
+        token_at: Dict[int, float] = {}    # seq_id -> its first token's instant
         try:
             if _FAULTS.active:
                 _FAULTS.fire("prefill_step")
             while any(s in self._chunks for s in seq_ids):
-                self._prefill_step(only=protect, defer_telemetry=True)
+                self._prefill_step(only=protect, token_at=token_at)
         except ServingError:
             # transactional: a chunk failure mid-call rolls back the WHOLE
             # call — sequences already past their final chunk included
@@ -1111,8 +1137,10 @@ class PagedEngineAdapter:
         # telemetry only once the WHOLE call is past rollback — a sibling
         # chunk failure must not leave spans/counters for requests that
         # were never admitted
-        self.telemetry.on_add(seq_ids, prompts, t0, live=len(seq_ids),
-                              padded=len(seq_ids), count_rows=False,
+        self.telemetry.on_add(seq_ids, prompts, t0,
+                              [token_at[s] for s in seq_ids],
+                              live=len(seq_ids), padded=len(seq_ids),
+                              count_rows=False,
                               tenants=[_meta_tenant(m) for m in metas])
         return {s: self._ready.pop(s) for s in seq_ids}
 
@@ -2397,7 +2425,8 @@ class PagedEngineAdapter:
             self._prefill_step(budget=self.prefill_budget_tokens,
                                target=seq_ids)
     def _prefill_step(self, budget: Optional[int] = None, only=None,
-                      target=None, defer_telemetry: bool = False):
+                      target=None,
+                      token_at: Optional[Dict[int, float]] = None):
         """ONE packed chunk dispatch: pending sequences (admission order)
         each contribute their next uncached-suffix chunk as a ragged row
         of a single ctx-bucket ``_run_paged`` call, bounded by ``budget``
@@ -2406,9 +2435,10 @@ class PagedEngineAdapter:
         ``_ready``; intermediate samples are discarded. A dispatch failure
         rolls every sequence packed in THIS dispatch back
         (:meth:`~..modules.block_kv_cache.BlockKVCacheManager.abort_sequence`)
-        and raises a typed :class:`StepFailure`. ``defer_telemetry`` (the
+        and raises a typed :class:`StepFailure`. ``token_at`` (the
         transactional add_requests path) suppresses per-sequence admission
-        telemetry — the caller reports the whole call only once it is past
+        telemetry — it takes each graduating sequence's first-token instant
+        instead, and the caller reports the whole call only once it is past
         rollback. ``target`` is the step call's explicit seq_ids set (None
         = all): an expired pending admission is raised only when targeted,
         merely skipped from packing otherwise."""
@@ -2464,6 +2494,10 @@ class PagedEngineAdapter:
                                     for s in seq_list)
         cache_before = self.app.cache
         rec = _get_recorder()
+        for s in seq_list:
+            # a prompt's wait for its turn ends where its FIRST chunk goes
+            # to the device (later chunks find the stamp written)
+            chunks[s].timeline.stamp("dispatch", now)
         # one slice over pack + dispatch + final-chunk fetch (a failed
         # dispatch closes it too; its error event follows on the timeline)
         span = rec.span(
@@ -2486,8 +2520,8 @@ class PagedEngineAdapter:
                 # be wrapped and rolled back here. Intermediate-only
                 # dispatches fetch nothing — their samples are discarded
                 # unmaterialized.
-                new = (self._fetch_prefill_tokens(out) if final_rows
-                       else None)
+                new, t_token = (self._fetch_prefill_tokens(out)
+                                if final_rows else (None, None))
         except ServingError as e:
             self._abort_prefill_rows(seq_list)
             _trace_error(e)                # attach a timeline id in place
@@ -2533,9 +2567,13 @@ class PagedEngineAdapter:
             self._scratch = None   # live set grew; see add_requests note
             self._note_stale("admit")
             self._ready[s] = tok
-            if not defer_telemetry:
-                self.telemetry.on_add([s], [st.prompt], st.t0, live=1,
-                                      padded=1, count_rows=False,
+            if st.timeline.stamp("token", t_token):
+                rec.mark("request.token", _trace_of(st.meta))
+            if token_at is not None:
+                token_at[s] = t_token
+            else:
+                self.telemetry.on_add([s], [st.prompt], st.t0, [t_token],
+                                      live=1, padded=1, count_rows=False,
                                       tenants=[_meta_tenant(st.meta)])
 
     def _pack_prefill_rows(self, rows):
@@ -2651,16 +2689,19 @@ class PagedEngineAdapter:
         self.telemetry.on_prefill_dispatch(experts, attn)
         return out
 
-    def _fetch_prefill_tokens(self, out) -> np.ndarray:
+    def _fetch_prefill_tokens(self, out) -> Tuple[np.ndarray, float]:
         """Materialize a final-chunk dispatch's sampled tokens (the one
-        blocking sync of a packed admission; async-prefetched)."""
+        blocking sync of a packed admission; async-prefetched). Returns
+        them with the instant they became host-visible: the ``token`` stamp
+        of the timelines whose first token is among them."""
         t0 = time.perf_counter()
         with _get_recorder().span("fetch.tokens", cat="adapter",
                                   engine=self.engine_name, phase="prefill"):
             toks = np.asarray(out["tokens"])
+        t1 = time.perf_counter()
         self.host_stats["prefill_blocking_fetches"] += 1
-        self.host_stats["prefill_blocked_s"] += time.perf_counter() - t0
-        return toks.reshape(toks.shape[0], -1)
+        self.host_stats["prefill_blocked_s"] += t1 - t0
+        return toks.reshape(toks.shape[0], -1), t1
 
     def _drop_unwritten(self, sid):
         """Retire ``sid``'s EXCLUSIVE blocks from the unwritten set. Any

@@ -133,7 +133,7 @@ class ServingFrontend:
     async def start(self) -> Tuple[str, int]:
         """Bind, start serving connections and the engine loop; returns
         the bound (host, port) — port 0 resolves to an ephemeral one."""
-        self._server = await asyncio.start_server(self._handle, self.host,
+        self._server = await asyncio.start_server(self._accept, self.host,
                                                   self.port)
         self.port = self._server.sockets[0].getsockname()[1]
         self._engine_task = asyncio.ensure_future(self.engine.run_forever())
@@ -151,12 +151,22 @@ class ServingFrontend:
         self._streams.clear()
 
     # -- connection handling ----------------------------------------------
+    def _accept(self, reader: asyncio.StreamReader,
+                writer: asyncio.StreamWriter):
+        """The connection callback, a plain function on purpose: it reads
+        the clock inside ``connection_made`` itself — the first stamp of a
+        request's timeline — and hands asyncio the handler's coroutine,
+        whose first line runs one loop iteration (while rows decode,
+        possibly one whole pass) later."""
+        return self._handle(reader, writer, time.perf_counter())
+
     async def _handle(self, reader: asyncio.StreamReader,
-                      writer: asyncio.StreamWriter) -> None:
+                      writer: asyncio.StreamWriter,
+                      accept_t: float) -> None:
         try:
             try:
                 method, path, body = await self._read_request(reader)
-                await self._route(method, path, body, writer)
+                await self._route(method, path, body, writer, accept_t)
             except _HttpError as e:
                 await self._send_json(writer, e.status,
                                       {"error": str(e), "type": e.type,
@@ -195,7 +205,8 @@ class ServingFrontend:
         return method, path, body
 
     async def _route(self, method: str, path: str, body: bytes,
-                     writer: asyncio.StreamWriter) -> None:
+                     writer: asyncio.StreamWriter,
+                     accept_t: float) -> None:
         if path == "/healthz" and method == "GET":
             await self._send_json(writer, 200, {
                 "ok": not self.engine._closed,
@@ -231,7 +242,7 @@ class ServingFrontend:
             await self._send_json(writer, 200, get_recorder().to_chrome())
         elif path == "/v1/generate" and method == "POST":
             spec = self._parse_spec(body)
-            stream = self._submit(spec)
+            stream = self._submit(spec, accept_t)
             if spec.get("stream", True):
                 await self._sse(writer, stream)
             else:
@@ -243,7 +254,7 @@ class ServingFrontend:
                     "request_id": stream.request_id,
                     "tokens": toks, "reason": stream.finish_reason})
         elif path == "/v1/submit" and method == "POST":
-            stream = self._submit(self._parse_spec(body))
+            stream = self._submit(self._parse_spec(body), accept_t)
             self._prune_streams()
             self._streams[stream.request_id] = stream
             await self._send_json(writer, 200,
@@ -369,7 +380,8 @@ class ServingFrontend:
             raise _HttpError(400, "body must be a JSON object")
         return spec
 
-    def _submit(self, spec: Dict[str, Any]) -> TokenStream:
+    def _submit(self, spec: Dict[str, Any],
+                accept_t: float) -> TokenStream:
         try:
             return self.engine.submit(
                 spec.get("prompt", ()),
@@ -378,7 +390,7 @@ class ServingFrontend:
                 priority=int(spec.get("priority", 0)),
                 deadline_s=spec.get("deadline_s"),
                 stop_tokens=spec.get("stop_tokens", ()),
-                request_id=spec.get("request_id"))
+                request_id=spec.get("request_id"), accept_t=accept_t)
         except QueueOverflow as e:
             raise _HttpError(429, str(e))
         except AdmissionError as e:
@@ -408,11 +420,9 @@ class ServingFrontend:
                 writer.write(self._sse_event(
                     {"token": tok, "index": idx}))
                 # front-door lag: put() -> this write (a replay attach
-                # re-reads old tokens: not a lag)
-                t_put = None if replay else stream.take_put_time(idx)
-                if t_put is not None:
-                    tmetrics.sse_lag_histogram(get_registry()).observe(
-                        time.perf_counter() - t_put)
+                # re-reads old tokens: not a lag, and no first token)
+                if not replay:
+                    self._note_write(stream, idx)
                 idx += 1
                 await writer.drain()
             done: Dict[str, Any] = {"done": True,
@@ -425,6 +435,23 @@ class ServingFrontend:
         except (ConnectionError, OSError):
             # client is gone: reclaim the sequence's blocks
             self.engine.cancel(stream.request_id)
+
+    def _note_write(self, stream: TokenStream, idx: int) -> None:
+        """Token ``idx`` of a live attach went to the socket. Index 0
+        closes the request's timeline (``write``, its one clock reading)
+        and the engine adds its time to first token up; every index whose
+        put was stamped is a sample of ``nxdi_sse_lag_seconds``."""
+        if idx:
+            t_put = stream.take_put_time(idx)    # None: the registry is off
+            if t_put is not None:
+                tmetrics.sse_lag_histogram(get_registry()).observe(
+                    time.perf_counter() - t_put)
+            return
+        tl = stream.timeline
+        if tl.stamp("write", time.perf_counter()):   # else: a second reader
+            self.engine.first_token_written(stream)
+            tmetrics.sse_lag_histogram(get_registry()).observe(
+                tl.write - tl.put)
 
     @staticmethod
     def _sse_event(payload: Dict[str, Any]) -> bytes:

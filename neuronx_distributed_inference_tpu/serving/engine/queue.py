@@ -55,7 +55,9 @@ class QueuedRequest:
     after a preemption — every token generated before eviction (the
     recompute prompt from the :class:`~...resilience.Preempted` record).
     ``orig_prompt_len`` never changes; ``max_new_tokens`` budgets total
-    GENERATED tokens across preemptions."""
+    GENERATED tokens across preemptions. When it was submitted, admitted
+    and first answered is on ``timeline``, the record its stream carries
+    (telemetry/request_trace.py ``RequestTimeline``)."""
 
     request_id: str
     tokens: List[int]
@@ -63,18 +65,21 @@ class QueuedRequest:
     tenant: str
     priority: int
     deadline: Optional[float]          # absolute perf_counter(); None = ∞
-    enqueue_t: float
     order: int                         # global arrival index (FIFO tiebreak)
     stream: TokenStream
     orig_prompt_len: int = 0
     stop_tokens: frozenset = frozenset()
     n_preemptions: int = 0
     meta: dict = field(default_factory=dict)
-    # SLO-plane anchors (host wall clock; written only when the engine
-    # has an SLOTracker attached — see scheduler.py)
-    t_first: Optional[float] = None    # first token delivered
+    # SLO-plane anchors without a stamp on the timeline (host wall clock;
+    # t_last is written only when the engine has an SLOTracker attached —
+    # see scheduler.py)
     t_last: Optional[float] = None     # latest token delivered
     last_enqueue_t: Optional[float] = None   # most recent (re)queue entry
+
+    @property
+    def timeline(self):
+        return self.stream.timeline
 
     def sort_key(self) -> Tuple[int, int]:
         return (-self.priority, self.order)
@@ -214,10 +219,10 @@ class MultiTenantQueue:
 
     # -- helpers -----------------------------------------------------------
     def _oldest(self, tenant: str) -> float:
-        """Enqueue time of the tenant's HEAD request — the one the next
+        """Submit time of the tenant's HEAD request — the one the next
         pop would take. Intra-tenant priority stays strict, so a buried
         low-priority request does not age the tenant's lane."""
-        return self._heaps[tenant][0][1].enqueue_t
+        return self._heaps[tenant][0][1].timeline.submit
 
     def _tel_depth(self, tenant: str) -> None:
         reg = get_registry()

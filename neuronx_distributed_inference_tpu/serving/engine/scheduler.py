@@ -49,7 +49,8 @@ from ...resilience.errors import (AdmissionError, CapacityError,
                                   ServingError, StepFailure)
 from ...telemetry import get_registry
 from ...telemetry import metrics as tmetrics
-from ...telemetry.request_trace import new_trace_id, trace_of
+from ...telemetry.request_trace import (TIMELINE_PHASES, new_trace_id,
+                                        trace_of)
 from ...telemetry.trace import get_recorder as _get_recorder
 from .queue import MultiTenantQueue, QueuedRequest
 from .streams import TokenStream
@@ -74,7 +75,11 @@ class ServingEngine:
     ``slo`` attaches a :class:`~...telemetry.slo.SLOTracker`: the engine
     feeds it TTFT (submit → first token), per-request mean TPOT and queue
     wait per tenant, host-side only — its report/hint surface is
-    read-only (``debug_state()["slo"]``, served at ``/v1/debug/state``)."""
+    read-only (``debug_state()["slo"]``, served at ``/v1/debug/state``).
+
+    ``stats["ttft_requests"]`` and the ``ttft_*_s`` beside it are the
+    requests' time to first token added up by phase
+    (:meth:`first_token_written`; always on)."""
 
     def __init__(self, adapter, *,
                  tenant_weights: Optional[Dict[str, float]] = None,
@@ -135,11 +140,15 @@ class ServingEngine:
             self._max_prompt = adapter.app.tpu_config.seq_len
         except AttributeError:
             self._max_prompt = None
-        self.stats: Dict[str, int] = {
+        self.stats: Dict[str, Any] = {
             "submitted": 0, "completed": 0, "expired_queue": 0,
             "expired_running": 0, "cancelled": 0, "preempt_requeues": 0,
             "priority_preemptions": 0, "admission_retries": 0,
-            "capacity_stalls": 0, "step_retries": 0}
+            "capacity_stalls": 0, "step_retries": 0,
+            # time to first token by phase (first_token_written): seconds
+            # summed over ttft_requests; the five phases add up to server
+            "ttft_requests": 0, "ttft_server_s": 0.0,
+            **{f"ttft_{phase}_s": 0.0 for phase, _, _ in TIMELINE_PHASES}}
 
     # -- public surface ----------------------------------------------------
     def submit(self, tokens: Sequence[int], max_new_tokens: int, *,
@@ -148,7 +157,8 @@ class ServingEngine:
                stop_tokens: Sequence[int] = (),
                request_id: Optional[str] = None,
                trace_id: Optional[str] = None,
-               adapter: Optional[str] = None) -> TokenStream:
+               adapter: Optional[str] = None,
+               accept_t: Optional[float] = None) -> TokenStream:
         """Enqueue one request; returns its :class:`TokenStream`
         immediately (no device work happens here). Raises the typed
         :class:`~...resilience.errors.QueueOverflow` when the queue is at
@@ -166,7 +176,12 @@ class ServingEngine:
         ``meta["adapter"]`` to the paged adapter, which resolves it to a
         pinned device slot at admission (README "Multi-LoRA serving") —
         no-op for engines without a lora_pool (the key is simply never
-        read)."""
+        read).
+
+        ``accept_t`` is the front door's reading of ``perf_counter()`` at
+        the connection's accept, the first stamp of the request's
+        timeline; a caller without a front door has none and the timeline
+        starts here."""
         if self._closed:
             raise ServingError("engine is closed")
         tokens = [int(t) for t in tokens]
@@ -189,11 +204,13 @@ class ServingEngine:
         now = time.perf_counter()
         tid = trace_id if trace_id is not None else new_trace_id()
         stream = TokenStream(rid, tenant)
+        stream.timeline.accept = now if accept_t is None else accept_t
+        stream.timeline.submit = now
         req = QueuedRequest(
             request_id=rid, tokens=tokens, max_new_tokens=max_new_tokens,
             tenant=tenant, priority=priority,
             deadline=None if deadline_s is None else now + deadline_s,
-            enqueue_t=now, order=self.queue.next_order(), stream=stream,
+            order=self.queue.next_order(), stream=stream,
             orig_prompt_len=len(tokens),
             stop_tokens=frozenset(int(t) for t in stop_tokens),
             meta={"request_id": rid, "tenant": tenant,
@@ -253,15 +270,17 @@ class ServingEngine:
             deadline_s=kw["deadline_s"][0], stop_tokens=stop_tokens,
             request_id=request_id, trace_id=trace_of(meta),
             adapter=meta.get("adapter"))
-        if self.slo is not None and rec.n_generated > 0:
+        if rec.n_generated > 0:
             # a continuation: the CLIENT saw its first token long ago on
             # the failed replica — this engine's first delivery must not
-            # be observed as a fresh (artificially fast) TTFT sample
-            now = time.perf_counter()
-            for r in self._queued():
-                if r.request_id == stream.request_id:
-                    r.t_first = r.t_last = now
-                    break
+            # be observed as a fresh (artificially fast) TTFT sample, nor
+            # its first write counted as a time to first token
+            stream.timeline.continued = True
+            if self.slo is not None:
+                for r in self._queued():
+                    if r.request_id == stream.request_id:
+                        r.t_last = stream.timeline.submit
+                        break
         return stream
 
     @property
@@ -398,7 +417,7 @@ class ServingEngine:
                     engine="queue", tenant=req.tenant)
             err = DeadlineExceeded(
                 f"request {req.request_id} expired after "
-                f"{now - req.enqueue_t:.3f}s in queue")
+                f"{now - req.timeline.submit:.3f}s in queue")
             if rec.enabled:
                 rec.error(err, request_id=req.request_id,
                           tenant=req.tenant, where="queue")
@@ -444,6 +463,9 @@ class ServingEngine:
         # the SLO queue-wait clock restarts here: time already spent
         # RUNNING must not count as queue wait after the requeue
         req.last_enqueue_t = time.perf_counter()
+        # evicted before its first token (a deferred prefill): it waits to
+        # be picked again, and the admission that holds ends its queue phase
+        req.timeline.rollback_admission()
         self.queue.push(req, front=True)
         self.stats["preempt_requeues"] += 1
         trec = _get_recorder()
@@ -554,26 +576,41 @@ class ServingEngine:
         requests and returns the adapter's first-token dict (empty under
         a deferred prefill budget)."""
         sids = [next(self._seq_ids) for _ in batch]
-        first = self.adapter.add_requests(
-            sids, [r.tokens for r in batch],
-            deadline_s=[None if r.deadline is None
-                        else max(r.deadline - now, 0.0) for r in batch],
-            meta=[r.meta for r in batch])
         rec = _get_recorder()
+        # the queue phase ends HERE, before the admission call: under the
+        # default adapter that call runs the batch's whole prefill chain
+        t_admit = time.perf_counter()
+        for r in batch:
+            if r.timeline.stamp("admit", t_admit) and rec.enabled:
+                rec.mark("request.admit", trace_of(r.meta))
+        try:
+            first = self.adapter.add_requests(
+                sids, [r.tokens for r in batch],
+                deadline_s=[None if r.deadline is None
+                            else max(r.deadline - now, 0.0) for r in batch],
+                meta=[r.meta for r in batch],
+                timelines=[r.timeline for r in batch])
+        except BaseException:
+            # rolled back: the batch goes back to the queue, and what this
+            # call stamped is stamped anew by the admission that holds
+            for r in batch:
+                r.timeline.rollback_admission()
+            raise
         for sid, req in zip(sids, batch):
             self._active[sid] = req
             self._sid_of[req.request_id] = sid
             self._observe_wait(req, "admitted")
             if rec.enabled:
-                # wait_s measures from the most recent (re)queue entry,
-                # matching the SLO queue-wait sample for this admission
+                # wait_s: from the most recent (re)queue entry to THIS
+                # admission's pick, the queue alone (the admission call's
+                # own time is in nxdi_queue_wait_seconds, not here)
                 since = (req.last_enqueue_t
                          if req.last_enqueue_t is not None
-                         else req.enqueue_t)
+                         else req.timeline.submit)
                 rec.instant("trace.admit", cat="request",
                             trace=trace_of(req.meta),
                             request_id=req.request_id, seq_id=int(sid),
-                            wait_s=now - since)
+                            wait_s=t_admit - since)
         return first
 
     def _dispatch_engine_pass(self) -> int:
@@ -709,17 +746,19 @@ class ServingEngine:
     def _slo_note_delivery(self, req: QueuedRequest, n: int) -> None:
         """SLO timestamp bookkeeping shared by every path that puts
         tokens on a stream (normal dispatch AND preempt-replay): first
-        delivery anchors TTFT, every delivery advances t_last."""
-        if n == 0 or self.slo is None:
+        delivery anchors TTFT, every delivery advances t_last. The first
+        delivery's instant is the timeline's ``put``."""
+        tl = req.timeline
+        if n == 0 or self.slo is None or tl.put is None:
             return
-        now = time.perf_counter()
-        if req.t_first is None:
-            req.t_first = now
+        if req.t_last is None:
+            req.t_last = tl.put
             # client-observed TTFT: submit -> first delivered token
             # (queue wait included — the number a user feels)
-            self.slo.observe(req.tenant, "ttft", now - req.enqueue_t,
-                             now=now)
-        req.t_last = now
+            self.slo.observe(req.tenant, "ttft", tl.put - tl.submit,
+                             now=tl.put)
+        else:
+            req.t_last = time.perf_counter()
 
     def _hit_limit(self, req: QueuedRequest, tok: int) -> bool:
         if tok in req.stop_tokens:
@@ -905,14 +944,44 @@ class ServingEngine:
             # a re-admission measures from its REQUEUE time, not the
             # original submit — time spent running is not queue wait
             since = (req.last_enqueue_t if req.last_enqueue_t is not None
-                     else req.enqueue_t)
+                     else req.timeline.submit)
             self.slo.observe(req.tenant, "queue_wait", now - since,
                              now=now)
         reg = get_registry()
         if reg.enabled:
             tmetrics.queue_wait_histogram(reg).observe(
-                now - req.enqueue_t,
+                now - req.timeline.submit,
                 tenant=req.tenant, outcome=outcome)
+
+    def first_token_written(self, stream: TokenStream) -> None:
+        """The front door wrote a stream's first SSE event on a live attach
+        and stamped ``timeline.write``: the ONE place a request's time to
+        first token is added up. ``stats["ttft_requests"]`` and the five
+        phases' seconds (they add up to ``ttft_server_s``) always; their
+        twin counters when a registry is on; with the recorder on, the
+        five ``request.*`` slices laid out from the same stamps."""
+        tl = stream.timeline
+        phases = tl.phases()
+        if phases is None:
+            return                 # a continuation, or a stamp was skipped
+        stats = self.stats
+        stats["ttft_requests"] += 1
+        stats["ttft_server_s"] += tl.write - tl.accept
+        for phase, seconds in phases.items():
+            stats[f"ttft_{phase}_s"] += seconds
+        reg = get_registry()
+        if reg.enabled:
+            tmetrics.ttft_requests_counter(reg).inc()
+            counter = tmetrics.ttft_phase_seconds_counter(reg)
+            for phase, seconds in phases.items():
+                counter.inc(max(seconds, 0.0), phase=phase)
+        rec = _get_recorder()
+        if rec.enabled:
+            tid = self._trace_ids.get(stream.request_id)
+            for phase, lo, hi in TIMELINE_PHASES:
+                rec.complete(f"request.{phase}", getattr(tl, lo),
+                             cat="request", t1=getattr(tl, hi), trace=tid,
+                             request_id=stream.request_id)
 
     # -- request-trace plumbing (telemetry/request_trace.py) ---------------
     def _remember_trace(self, request_id: str, trace_id: str,
@@ -938,12 +1007,16 @@ class ServingEngine:
         all landed in ONE pass (fused horizon, speculation burst,
         preempt replay) has no delivery interval to measure: it
         contributes no TPOT sample rather than a fake-perfect 0.0."""
-        if (self.slo is not None and req.t_first is not None
+        tl = req.timeline
+        # a continuation's first delivery is no first token: its anchor
+        # is its submit here (submit_record)
+        t_first = tl.submit if tl.continued else tl.put
+        if (self.slo is not None and t_first is not None
                 and req.t_last is not None and req.stream.n_tokens > 1
-                and req.t_last > req.t_first):
+                and req.t_last > t_first):
             self.slo.observe(
                 req.tenant, "tpot",
-                (req.t_last - req.t_first) / (req.stream.n_tokens - 1),
+                (req.t_last - t_first) / (req.stream.n_tokens - 1),
                 now=req.t_last)
         rec = _get_recorder()
         if rec.enabled:

@@ -29,6 +29,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 from ...resilience.errors import Cancelled
 from ...telemetry.registry import get_registry
+from ...telemetry.request_trace import RequestTimeline
 
 __all__ = ["TokenStream"]
 
@@ -51,9 +52,13 @@ class TokenStream:
         self.tenant = tenant
         self._tokens: List[int] = []
         self._cursor = 0              # consumer position (drain/aiter)
-        # token index -> perf_counter() of its put(); stamped only while
-        # the metrics registry is enabled, taken by the SSE writer
-        # (``nxdi_sse_lag_seconds``)
+        # the request's way to its first token, stamped by the front door,
+        # the scheduler, the adapter and put() below; the stream is the one
+        # object that crosses from the engine to the SSE writer
+        self.timeline = RequestTimeline()
+        # token index -> perf_counter() of its put(), past index 0 (the
+        # timeline's ``put``); stamped only while the metrics registry is
+        # enabled, taken by the SSE writer (``nxdi_sse_lag_seconds``)
         self._put_at: Dict[int, float] = {}
         self.finish_reason: Optional[str] = None
         self.error: Optional[BaseException] = None
@@ -65,7 +70,9 @@ class TokenStream:
         if self.finish_reason is not None:
             return                    # late token after cancel/expiry: drop
         self._tokens.append(int(token))
-        if get_registry().enabled:
+        if len(self._tokens) == 1:
+            self.timeline.stamp("put", time.perf_counter())    # always on
+        elif get_registry().enabled:
             self._put_at[len(self._tokens) - 1] = time.perf_counter()
         self._wake()
 
@@ -91,7 +98,8 @@ class TokenStream:
 
     def take_put_time(self, index: int) -> Optional[float]:
         """``perf_counter()`` at which token ``index`` was put, once (None
-        when the registry was off at the put, or it was taken already)."""
+        when the registry was off at the put, or it was taken already).
+        Index 0 has no entry: its instant is ``timeline.put``."""
         return self._put_at.pop(index, None)
 
     def tokens_from(self, start: int) -> List[int]:

@@ -367,8 +367,14 @@ class RaggedDispatchPath:
         # 1. chunk cursors advance; the fetch above materialized the
         # dispatch, so every block the donated-cache chain covers up to
         # each pending row's cursor is now confirmed written
+        t_token = (time.perf_counter()
+                   if any(r.final for _, r in prefill_rows) else None)
         for _, r in prefill_rows:
             chunks[r.seq_id].done += r.width
+            # the timeline's adapter stamps (telemetry/request_trace.py):
+            # a prompt's first chunk went out with the step that began at
+            # t0, its first token is host-visible since the fetch above
+            chunks[r.seq_id].timeline.stamp("dispatch", t0)
         for s2, cst in chunks.items():
             ad._unwritten.difference_update(
                 app.kv_mgr.tables[s2][:cst.done // bs])
@@ -387,7 +393,10 @@ class RaggedDispatchPath:
                 deadline=st.deadline, meta=st.meta)
             ad._scratch = None         # live set grew
             ad._ready[r.seq_id] = tok
-            ad.telemetry.on_add([r.seq_id], [st.prompt], st.t0, live=1,
+            if st.timeline.stamp("token", t_token):
+                _get_recorder().mark("request.token", _trace_of(st.meta))
+            ad.telemetry.on_add([r.seq_id], [st.prompt], st.t0,
+                                [t_token], live=1,
                                 padded=1, count_rows=False,
                                 tenants=[_meta_tenant(st.meta)])
         # 3. live rows: accept cursors advance, KV shrinks to the

@@ -29,6 +29,8 @@ PREFILL_DISPATCHES_TOTAL = "nxdi_prefill_dispatches_total"   # engine, experts, 
 # -- serving engine (serving/engine/) ----------------------------------------
 QUEUE_DEPTH = "nxdi_queue_depth"                        # tenant
 QUEUE_WAIT_SECONDS = "nxdi_queue_wait_seconds"          # tenant, outcome
+TTFT_PHASE_SECONDS_TOTAL = "nxdi_ttft_phase_seconds_total"   # phase
+TTFT_REQUESTS_TOTAL = "nxdi_ttft_requests_total"
 
 # -- decode pipeline (serving.py) --------------------------------------------
 STEPS_PER_FETCH = "nxdi_steps_per_fetch"                # engine
@@ -144,7 +146,11 @@ def ttft_histogram(reg):
     # single-tenant dashboards aggregate over it unchanged)
     return reg.histogram(
         REQUEST_TTFT_SECONDS,
-        "Time from request admission to its first generated token (s)",
+        "Time from the adapter's add_requests call to the request's first "
+        "token host-visible in the adapter (s): without the front door "
+        "and the queue in front of it, and without the routing and the "
+        "SSE write behind it (the whole path by phase: "
+        "nxdi_ttft_phase_seconds_total)",
         labels=("engine", "tenant"), buckets=DEFAULT_LATENCY_BUCKETS)
 
 
@@ -186,9 +192,36 @@ def queue_depth_gauge(reg):
 def queue_wait_histogram(reg):
     return reg.histogram(
         QUEUE_WAIT_SECONDS,
-        "Time a request spent queued before admission "
-        "(outcome=admitted|expired|cancelled)",
+        "Time from a request's submit to the moment it left the queue "
+        "(outcome=admitted|expired|cancelled); for admitted that moment "
+        "is the RETURN of the admission call, which under the default "
+        "adapter runs the batch's whole prefill chain: submit -> prefill "
+        "done, not a wait (the wait alone: phase=queue of "
+        "nxdi_ttft_phase_seconds_total)",
         labels=("tenant", "outcome"), buckets=DEFAULT_LATENCY_BUCKETS)
+
+
+def ttft_phase_seconds_counter(reg):
+    return reg.counter(
+        TTFT_PHASE_SECONDS_TOTAL,
+        "Seconds of requests' time to first token, added up at each "
+        "request's first SSE write from the stamps of its timeline "
+        "(telemetry/request_trace.py): phase=accept (connection accepted "
+        "-> submit) | queue (-> picked for admission) | prefill_wait (-> "
+        "its first chunk enqueued) | prefill (-> its first token "
+        "host-visible) | write (-> writer.write of its first event); the "
+        "five add up to accept -> write for every request, and a phase's "
+        "mean is its seconds over nxdi_ttft_requests_total",
+        labels=("phase",))
+
+
+def ttft_requests_counter(reg):
+    return reg.counter(
+        TTFT_REQUESTS_TOTAL,
+        "Requests whose first token the front door wrote on a live SSE "
+        "attach: the count under nxdi_ttft_phase_seconds_total (a replay "
+        "attach, a continuation and a request requeued after its first "
+        "token add nothing)")
 
 
 def live_batch_gauge(reg):

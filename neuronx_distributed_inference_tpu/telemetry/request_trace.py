@@ -24,6 +24,16 @@ Event convention (stable, like every other recorder contract):
   * error/preempt events carry ``trace=<id>`` when the victim's meta
     held one.
 
+The request's TIME TO FIRST TOKEN rides along as one record
+(:class:`RequestTimeline`): seven readings of ``perf_counter()``, each
+taken where a phase ends — in the front door, the scheduler, the adapter
+and the SSE writer — on the object the request already is
+(``TokenStream.timeline``). Always on: seven clock reads a request, none a
+token. Its five phases partition first write - accept exactly; the
+engine adds them up at the first SSE write
+(``ServingEngine.first_token_written``) and, with the recorder on, lays
+them out as the ``request.*`` slices of :data:`TIMELINE_PHASES`.
+
 Pure helpers below filter a recorder's event list down to one request
 (:func:`trace_events`) and export per-request Chrome lanes
 (:func:`chrome_by_trace` — one ``tid`` lane per trace id, so Perfetto
@@ -35,10 +45,87 @@ runs unless the flight recorder is enabled; minting the id itself is one
 from __future__ import annotations
 
 import uuid
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 __all__ = ["TRACE_META_KEY", "new_trace_id", "trace_of",
-           "trace_events", "trace_ids_in", "chrome_by_trace"]
+           "trace_events", "trace_ids_in", "chrome_by_trace",
+           "TIMELINE_STAMPS", "TIMELINE_PHASES", "RequestTimeline"]
+
+#: The stamps of a :class:`RequestTimeline`, in the order a request passes
+#: them, and where each is taken:
+#:   ``accept``    the front door's connection callback (a caller of
+#:                 ``engine.submit`` without a front door: = ``submit``)
+#:   ``submit``    ``ServingEngine.submit``
+#:   ``admit``     ``ServingEngine._add_batch``, BEFORE ``add_requests``
+#:   ``dispatch``  the adapter, where the prompt's FIRST chunk is enqueued
+#:   ``token``     the adapter, where its first token is host-visible
+#:   ``put``       ``TokenStream.put`` of index 0 (a mark inside ``write``)
+#:   ``write``     the front door's ``writer.write`` of index 0
+TIMELINE_STAMPS = ("accept", "submit", "admit", "dispatch", "token", "put",
+                   "write")
+
+#: ``(phase, the stamp that opens it, the stamp that ends it)``: the five
+#: tile ``accept`` .. ``write``. The slice of a phase is ``request.<phase>``.
+#:   ``accept``        request line, headers, body, JSON, validation
+#:   ``queue``         waiting to be picked (capacity, fairness, the pass
+#:                     in progress)
+#:   ``prefill_wait``  other prompts' chains of the same batch; under a
+#:                     prefill budget the decode steps and chunks ahead
+#:   ``prefill``       its own chain of chunks, the host's pass between
+#:                     them, the fetch
+#:   ``write``         routing, the rest of the pass, the yield, the writer
+TIMELINE_PHASES: Tuple[Tuple[str, str, str], ...] = (
+    ("accept", "accept", "submit"), ("queue", "submit", "admit"),
+    ("prefill_wait", "admit", "dispatch"), ("prefill", "dispatch", "token"),
+    ("write", "token", "write"))
+
+
+class RequestTimeline:
+    """One request's way to its first token (module docstring). A stamp is
+    written ONCE: a request requeued after its first token keeps the
+    instants of its first admission, so the phases stay ordered and add up.
+    The one exception is :meth:`rollback_admission`. ``continued`` marks a
+    continuation (``submit_record`` of a request that had generated tokens
+    elsewhere): its client saw a first token long ago, so it has no time to
+    first token here."""
+
+    __slots__ = TIMELINE_STAMPS + ("continued",)
+
+    def __init__(self):
+        for name in TIMELINE_STAMPS:
+            setattr(self, name, None)
+        self.continued = False
+
+    def stamp(self, name: str, t: float) -> bool:
+        """Write stamp ``name`` = ``t`` unless it is written already;
+        True where it was written now."""
+        if getattr(self, name) is not None:
+            return False
+        setattr(self, name, t)
+        return True
+
+    def rollback_admission(self) -> None:
+        """The request goes back to the queue (its admission call was
+        rolled back, or it was evicted): while its first token has not
+        reached its stream, the admission that will hold stamps ``admit``,
+        ``dispatch`` and ``token`` anew, so the time in between counts as
+        ``queue`` and not as ``prefill``. Once ``put`` is written the
+        client has its first token and every stamp stays."""
+        if self.put is None:
+            self.admit = self.dispatch = self.token = None
+
+    def phases(self) -> Optional[Dict[str, float]]:
+        """Seconds by phase once the first token is written, None before
+        (or for a continuation, or a request that skipped a stamp)."""
+        if self.continued:
+            return None
+        out: Dict[str, float] = {}
+        for phase, lo, hi in TIMELINE_PHASES:
+            t0, t1 = getattr(self, lo), getattr(self, hi)
+            if t0 is None or t1 is None:
+                return None
+            out[phase] = t1 - t0
+        return out
 
 #: The key the serving layers park the trace id under in the opaque
 #: per-request ``meta`` passthrough (a stable contract: ``Preempted``

@@ -33,10 +33,14 @@ class Span:
         self.events: List[Dict[str, Any]] = []
         self._registry_ref = registry
 
-    def event(self, name: str, **attrs) -> "Span":
-        """Record a named event at now (relative time kept in seconds)."""
-        e: Dict[str, Any] = {"name": name,
-                             "t": time.perf_counter() - self.t_start}
+    def event(self, name: str, at: Optional[float] = None,
+              **attrs) -> "Span":
+        """Record a named event at now, or at the reading ``at`` of
+        ``perf_counter()`` someone else took where it happened (relative
+        time kept in seconds)."""
+        e: Dict[str, Any] = {
+            "name": name,
+            "t": (time.perf_counter() if at is None else at) - self.t_start}
         if attrs:
             e.update(attrs)
         self.events.append(e)
@@ -87,7 +91,8 @@ class NullSpan:
     t_start = 0.0
     t_end = None
 
-    def event(self, name: str, **attrs) -> "NullSpan":
+    def event(self, name: str, at: Optional[float] = None,
+              **attrs) -> "NullSpan":
         return self
 
     def elapsed(self) -> float:

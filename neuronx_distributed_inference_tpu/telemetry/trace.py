@@ -44,7 +44,11 @@ registry snapshots. ``under`` is the span that was open around it on the
 same thread ("" at the top), so a reader gets a span's SELF time as its
 own seconds minus those recorded under it — e.g. the default paged adapter
 runs its prefill dispatches inside ``pass.admit``, a deferring one inside
-``pass.dispatch``; the label tells them apart, names alone cannot.
+``pass.dispatch``; the label tells them apart, names alone cannot. The
+``request.*`` slices (:data:`TRACE_EVENTS`) are the exception: a request's
+seconds, not the thread's, so they reach the ring and nothing else
+(:data:`REQUEST_CAT`); two boundaries of a request's timeline are
+zero-length TraceMe marks instead (:meth:`FlightRecorder.mark`).
 
 Disabled by default with the PR-1 zero-cost contract: the module-global
 recorder is a shared no-op (:data:`NULL_RECORDER`); instrumented call
@@ -71,6 +75,7 @@ from .registry import get_registry
 __all__ = [
     "ENGINE_PASS_PHASES", "ENGINE_EVENTS", "ADAPTER_EVENTS", "APP_EVENTS",
     "LOOP_EVENTS", "FLEET_EVENTS", "DEGRADE_EVENTS", "WARMUP_EVENTS",
+    "REQUEST_CAT",
     "EVENT_NAMES", "STALL_SECONDS", "STALL_RECORDS",
     "FlightRecorder", "NullFlightRecorder", "NULL_RECORDER",
     "get_recorder", "set_recorder", "enable_recorder", "disable_recorder",
@@ -181,8 +186,24 @@ DEGRADE_EVENTS = ("degrade.enter", "degrade.exit")
 #:   ``trace.requeue``  the request went back to a queue — preemption or
 #:                      replica failover (reason, replica when fleet)
 #:   ``trace.emit``     terminal emission (reason, n_tokens)
+#: and the request's time to first token as five complete slices, laid out
+#: at its first SSE write from the stamps of its ``RequestTimeline``
+#: (telemetry/request_trace.py ``TIMELINE_PHASES``; ``request_id``):
+#:   ``request.accept``        connection accepted -> ``submit``
+#:   ``request.queue``         ``submit`` -> picked for admission
+#:   ``request.prefill_wait``  -> its first chunk enqueued
+#:   ``request.prefill``       -> its first token host-visible
+#:   ``request.write``         -> the ``writer.write`` of its first event
+#: These are a REQUEST's seconds, not the thread's (:data:`REQUEST_CAT`).
 TRACE_EVENTS = ("trace.begin", "trace.admit", "trace.requeue",
-                "trace.emit")
+                "trace.emit", "request.accept", "request.queue",
+                "request.prefill_wait", "request.prefill", "request.write")
+
+#: Slices of this category are a request's time, laid out after the fact:
+#: they overlap each other and whatever the thread was doing, so they are
+#: recorded and nothing else — no ``nxdi_host_seconds_total``, no parent
+#: (a reader's self time of ``loop.yield`` is not theirs to eat), no stall.
+REQUEST_CAT = "request"
 
 #: Cold-start / steady-state compile events (serving/warmup.py +
 #: models/application.py). STABLE names.
@@ -373,8 +394,12 @@ class FlightRecorder:
         host seconds by span and parent (label sets bounded by the stable
         names), and keep a slice that ran long (module docstring).
         ``parent`` is the span open around it on this thread,
-        ``stalled_s`` the stalls already counted inside it."""
+        ``stalled_s`` the stalls already counted inside it. A slice of
+        :data:`REQUEST_CAT` is recorded and that is all."""
         dur = t1 - t0
+        if cat == REQUEST_CAT:
+            return self._push({"name": name, "cat": cat, "ph": "X",
+                               "ts": t0, "dur": dur, "args": args})
         reg = get_registry()
         if reg.enabled:
             span = name if name in _EVENT_SET else "other"
@@ -420,6 +445,15 @@ class FlightRecorder:
         if self.pass_id is not None:
             args.setdefault("pass_id", self.pass_id)
         return _TraceSpan(self, name, cat, args)
+
+    def mark(self, name: str, trace: Optional[str]) -> None:
+        """A zero-length profiler TraceMe ``name`` (stat ``trace``): inside
+        a ``jax.profiler`` session a boundary of a request's timeline lies
+        on ``/host:CPU`` of the same xplane as the device's operations.
+        Nothing reaches the ring: the request's slices carry the instant."""
+        if self._trace_annotation is not None:
+            with self._trace_annotation(name, trace=trace or ""):
+                pass
 
     def error(self, err: BaseException, cat: str = "error", **args):
         """Record a typed failure as an ``error.<Type>`` instant event
@@ -530,6 +564,9 @@ class NullFlightRecorder:
 
     def span(self, name, cat="engine", **args):
         return self._NULL_SPAN
+
+    def mark(self, name, trace):
+        pass
 
     def error(self, err, cat="error", **args):
         return err

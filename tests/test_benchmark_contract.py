@@ -1488,7 +1488,9 @@ def test_keye_vl2_select_kernel_share_reads_the_adapters_two_counters():
     assert entry == dict(
         name=metric, unit="%", better="higher", source="program_counter",
         layer="Step graphs", moves="itl_p50_ms", workloads=[KEYE_CELL])
-    assert BENCHMARK["per_layer"][-1] == entry
+    # appended where it was added: what follows it is ISSUE 52's seven
+    later = BENCHMARK["per_layer"][BENCHMARK["per_layer"].index(entry) + 1:]
+    assert [m["name"] for m in later] == list(TTFT_METRICS)
 
     def ctx(**counters):
         return {"before": {"counters": {"host_stats.sparse_dispatches": 10}},
@@ -1498,6 +1500,45 @@ def test_keye_vl2_select_kernel_share_reads_the_adapters_two_counters():
         sparse_dispatches=231, sparse_dispatches_select_kernel=87)) == \
         pytest.approx(100 * 87 / 221)
     assert readers.read_metric(metric, ctx(dispatches=5)) is None
+
+
+TTFT_METRICS = ("ttft.accept_ms", "ttft.queue_ms", "ttft.prefill_wait_ms",
+                "ttft.prefill_ms", "ttft.write_ms", "ttft.server_ms",
+                "ttft.device_idle_share")
+
+
+@pytest.mark.parametrize("metric", TTFT_METRICS)
+def test_a_ttft_phase_metric_is_the_chat_cells_alone(metric):
+    """ISSUE 52's seven: appended last, each moving ``ttft_p50_ms`` in the
+    one cell that reports it; the six means are data files over
+    ``engine.ttft_*`` (``ServingEngine.stats``, always on) and read nothing
+    from a program without the keys."""
+    from harness import readers
+    assert tuple(m["name"] for m in BENCHMARK["per_layer"][-7:]) == \
+        TTFT_METRICS
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == metric)
+    assert entry["moves"] == "ttft_p50_ms"
+    assert entry["workloads"] == ["olmoe-chat-steady"]
+    spec = build.load_json("layer_metrics", metric + ".json")
+    assert {k: spec[k] for k in ("unit", "better", "source", "layer")} == \
+        {k: entry[k] for k in ("unit", "better", "source", "layer")}
+    if metric == "ttft.device_idle_share":
+        assert spec["reader"] == {"kind": "python"}
+        return
+    key = "engine.ttft_" + metric[len("ttft."):-len("_ms")] + "_s"
+    assert spec["reader"] == {"kind": "counter_ratio", "args": {
+        "num": [key], "den": ["engine.ttft_requests"], "scale": 1000.0}}
+
+    def ctx(**after):
+        return {"before": {"counters": {"engine.ttft_requests": 4, key: 0.1}},
+                "after": {"counters": {"engine." + k: v
+                                       for k, v in after.items()}}}
+    assert readers.read_metric(metric, ctx(
+        **{"ttft_requests": 24, key[len("engine."):]: 0.5})) == \
+        pytest.approx(1000 * 0.4 / 20)
+    assert readers.read_metric(metric, {
+        "before": {"counters": {"engine.submitted": 1}},
+        "after": {"counters": {"engine.submitted": 9}}}) is None
 
 
 def test_the_keye_vl2_reference_gates_a_toy_twin():

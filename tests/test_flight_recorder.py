@@ -278,6 +278,8 @@ GOLDEN_EVENT_NAMES = (
     "fleet.route", "fleet.drain", "kv.spill", "kv.restore", "handoff.send",
     "handoff.recv", "fleet.all_dead", "fleet.scale_up", "fleet.scale_down",
     "trace.begin", "trace.admit", "trace.requeue", "trace.emit",
+    "request.accept", "request.queue", "request.prefill_wait",
+    "request.prefill", "request.write",
     "degrade.enter", "degrade.exit", "compile.unexpected")
 
 

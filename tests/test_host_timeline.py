@@ -164,13 +164,15 @@ def test_disabled_recorder_never_touches_the_profiler(monkeypatch):
     """``NULL_RECORDER`` imports nothing from jax: ``telemetry/trace.py``
     has no module-level jax import, and a disabled span is the shared
     no-op whatever the profiler module does."""
-    tree = ast.parse(Path(trace_mod.__file__).read_text())
-    top = [n for n in tree.body if isinstance(n, (ast.Import,
-                                                  ast.ImportFrom))]
-    names = [a.name for n in top if isinstance(n, ast.Import)
-             for a in n.names] + [n.module or "" for n in top
-                                  if isinstance(n, ast.ImportFrom)]
-    assert not [n for n in names if n.split(".")[0] == "jax"], names
+    from neuronx_distributed_inference_tpu.telemetry import request_trace
+    for mod in (trace_mod, request_trace):      # the timeline's module too
+        tree = ast.parse(Path(mod.__file__).read_text())
+        top = [n for n in tree.body if isinstance(n, (ast.Import,
+                                                      ast.ImportFrom))]
+        names = [a.name for n in top if isinstance(n, ast.Import)
+                 for a in n.names] + [n.module or "" for n in top
+                                      if isinstance(n, ast.ImportFrom)]
+        assert not [n for n in names if n.split(".")[0] == "jax"], names
 
     def boom(*a, **k):
         raise AssertionError("profiler touched with the recorder off")
@@ -180,6 +182,8 @@ def test_disabled_recorder_never_touches_the_profiler(monkeypatch):
     assert null.span("pass.dispatch") is null.span("loop.yield")
     with null.span("pass.dispatch", cat="engine", rows=1):
         pass
+    # the marks of a request's timeline are the enabled recorder's alone
+    assert null.mark("request.admit", "ab12") is None
 
 
 # ---------------------------------------------------------------------------
@@ -194,8 +198,12 @@ def test_host_seconds_counter_equals_recorder_slices(paged_app):
     assert rec.dropped == 0
     by_name = {}
     for e in rec.events():
-        if e["ph"] == "X":
+        # the request.* slices are a REQUEST's seconds, not this thread's:
+        # in the ring, and in no counter (tests/test_request_timeline.py)
+        if e["ph"] == "X" and e["cat"] != "request":
             by_name[e["name"]] = by_name.get(e["name"], 0.0) + e["dur"]
+    assert sum(1 for e in rec.events() if e["cat"] == "request"
+               and e["ph"] == "X") == 3 * 5
     for want in ("pass.expire", "pass.preempt", "pass.admit",
                  "pass.dispatch", "loop.yield", "run.paged",
                  "fetch.tokens", "dispatch.prefill_chunk",

@@ -78,11 +78,14 @@ def _prompts(seed, n, lo=1, hi=500, length=9):
 # ---------------------------------------------------------------------------
 
 def _qreq(rid, tenant, prio=0, order=0, enqueue_t=None, deadline=None):
+    stream = TokenStream(rid, tenant)
+    # when it was submitted is on the timeline its stream carries
+    stream.timeline.accept = stream.timeline.submit = (
+        time.perf_counter() if enqueue_t is None else enqueue_t)
     return QueuedRequest(
         request_id=rid, tokens=[1, 2, 3], max_new_tokens=4, tenant=tenant,
         priority=prio, deadline=deadline,
-        enqueue_t=time.perf_counter() if enqueue_t is None else enqueue_t,
-        order=order, stream=TokenStream(rid, tenant), orig_prompt_len=3)
+        order=order, stream=stream, orig_prompt_len=3)
 
 
 def test_queue_weighted_fair_and_priority():

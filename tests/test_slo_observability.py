@@ -487,7 +487,7 @@ def test_slo_single_pass_delivery_and_requeue_wait_semantics(apps):
     # requeued a moment ago must observe a SMALL queue wait
     s2 = eng.submit(_prompts(37, 1)[0], 2, tenant="t")
     req = next(r for r in eng._queued() if r.request_id == s2.request_id)
-    req.enqueue_t = time.perf_counter() - 100.0    # submitted "ages" ago
+    req.timeline.submit = time.perf_counter() - 100.0   # "ages" ago
     req.last_enqueue_t = time.perf_counter() - 0.01   # requeued just now
     eng.run_until_drained()
     waits = tracker._windows[("t", "queue_wait")].values()
