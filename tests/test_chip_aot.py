@@ -695,12 +695,13 @@ def test_latent_decode_runs_its_kernel_on_the_pool_in_place(v5e_devices):
     ``test_a_latent_chunk_attends_on_the_prefill_kernel``.)"""
     decode, notes = _longcat_program(v5e_devices, 32, 1)
     assert ("mla_decode", "pallas",
-            "latent lanes=640 heads=64 form=absorbed pages=16") in notes
+            "latent lanes=640 heads=64 form=absorbed pages=32 "
+            "tiles=tokens-held prefetch=across-rows") in notes
     assert ("moe_decode", "pallas", "pieces=8 of 256") in notes
     assert ("moe_share", "xla",
             "held=16 of 768 from 0 top_k=12 zero=256") in notes
     text = decode.as_text()
-    calls = re.findall(r"%(mla_decode_attention[.\d]*) = f32\[32,64,512\]",
+    calls = re.findall(r"%(mla_decode_attention[.\d]*) = bf16\[32,64,512\]",
                        text)
     assert len(calls) == 2, calls
     assert not _pool_movers(text, LONGCAT_POOL)
@@ -822,23 +823,25 @@ def _deepseek_v3_program(v5e_devices, rows, width):
 def test_deepseek_v3_decodes_on_both_kernels_at_its_widths(v5e_devices):
     """ISSUE 47: the decode step at the configuration's size (a dense layer
     and four expert layers, two scans) holds the latent decode kernel at 128
-    heads, in blocks of 16 pages as at 64 (shorter blocks were slower on the
-    chip: ``mla_decode.block_pages``), and the walk over the touched experts
-    in column pieces (an expert of 7168 x 2048 is 88 MB); the one-row chunk
-    of 256 tokens walks its experts by rows, its float32 rows and result
+    heads, the heads held still on the MXU, in blocks of 16 pages (at 64
+    heads 32, by the clock: ``mla_decode.block_pages``), and the walk over
+    the touched experts in column pieces (an expert of 7168 x 2048 is 88
+    MB); the one-row chunk of 256 tokens walks its experts by rows, its
+    float32 rows and result
     held once (32.1 MiB beside the slots, 46.1 in a pipeline's pairs:
     declined before: ``test_a_latent_chunk_attends_on_the_prefill_kernel``
     holds that); no instruction copies, transposes or relays the latent
     pool."""
     decode, notes = _deepseek_v3_program(v5e_devices, 32, 1)
     assert ("mla_decode", "pallas",
-            "latent lanes=640 heads=128 form=absorbed pages=16") in notes
+            "latent lanes=640 heads=128 form=absorbed pages=16 "
+            "tiles=heads-held prefetch=across-rows") in notes
     assert ("moe_decode", "pallas", "pieces=8 of 256") in notes
     assert ("moe_share", "xla",
             "held=16 of 256 from 0 top_k=8 groups=8 top=4") in notes
     text = decode.as_text()
     assert text.count(MOSAIC) >= 3          # MLA in both scans, the walk
-    assert re.findall(r"%mla_decode_attention[.\d]* = f32\[32,128,512\]",
+    assert re.findall(r"%mla_decode_attention[.\d]* = bf16\[32,128,512\]",
                       text)
     assert not _pool_movers(text, DEEPSEEK_V3_POOL)
     assert decode.memory_analysis().temp_size_in_bytes < 400e6

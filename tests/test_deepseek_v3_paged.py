@@ -180,7 +180,8 @@ def test_a_three_chunks_then_decode_on_the_kernels(ref, gate_weights,
     _check(tap, ref, gate_weights, 7, P69, stream)
     notes = _kernels(app)
     assert notes[("mla_decode", "pallas-interpret")] == \
-        "latent lanes=256 heads=4 form=absorbed pages=16"
+        ("latent lanes=256 heads=4 form=absorbed pages=32 "
+         "tiles=tokens-held prefetch=across-rows")
     assert notes[("moe_decode", "pallas-interpret")] == "pieces=1 of 128"
     assert notes[("moe_share", "xla")] == \
         "held=4 of 16 from 4 top_k=4 groups=4 top=2"

@@ -168,7 +168,8 @@ def test_a_three_chunks_then_decode_on_the_kernel(ref, gate_weights,
     notes = _kernels(app)
     assert notes["mla_decode"]["path"] == "pallas-interpret"
     assert notes["mla_decode"]["reason"] == \
-        "latent lanes=256 heads=4 form=absorbed pages=16"
+        ("latent lanes=256 heads=4 form=absorbed pages=32 "
+         "tiles=tokens-held prefetch=across-rows")
     assert notes["moe_share"]["reason"] == \
         "held=4 of 12 from 2 top_k=3 zero=4"
     # heads of 16 lanes are not the prefill kernel's: the XLA form, and why
