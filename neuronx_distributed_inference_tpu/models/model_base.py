@@ -27,6 +27,8 @@ bucket-ladder jit-cache miss (or a tracing crash) in production.
 
 from __future__ import annotations
 
+import math
+
 from dataclasses import dataclass, field, replace
 from functools import lru_cache, partial
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -383,6 +385,26 @@ class DecoderSpec:
     # REPLACES attention there (recurrentgemma rec/rec/attn pattern).
     ssm_pattern: Optional[Tuple[bool, ...]] = None
     ssm_parallel: bool = False
+    # a decoder-hybrid-decoder's temporal block BY LAYER, any order (the
+    # recurrent walk is unrolled, so nothing has to repeat): "mamba" (the
+    # ``ssm`` block), "window" (attention on the row's ring, ``window_pool``),
+    # "full" (attention on the allocator's pool), "cross" (attention with a
+    # query projection only, over the pool of the nearest "full" layer below
+    # it: no key, value or cache of its own), "gmu" (a Gated Memory Unit: the
+    # nearest mixer below hands it its scan output, ``ssm.SCAN_OUT``). Set
+    # together with ``ssm_pattern`` ("mamba" layers) and
+    # ``layer_pattern`` ("window" layers); the paged path only
+    layer_kinds: Optional[Tuple[str, ...]] = None
+    # differential attention (arXiv:2410.05258 as Phi-4-mini-flash has it):
+    # heads pair up by neighbours, ``o_j = rmsnorm(A(q_2j) - lam A(q_2j+1))
+    # (1 - lam_init)`` over the pair's SHARED value of two heads' width.
+    # ``head_dim`` is then the PAIR's width (two published heads: the pool's
+    # kv row and the value), ``num_kv_heads`` the kv pairs, ``num_q_heads``
+    # the published query heads, each projected at ``head_dim / 2`` and placed
+    # in its own half of a ``head_dim`` row with zeros in the other, where it
+    # scores against its own member of the key pair: plain grouped-query
+    # attention over the pool as it lies (``_attn_block``)
+    diff_attn: bool = False
     # family-specific static constants that conversion / layer hooks need
     # (falcon-h1 MuP multipliers) — a hashable (name, value) tuple so the
     # spec stays jit-static
@@ -404,10 +426,16 @@ class DecoderSpec:
     @property
     def num_attn_layers(self) -> int:
         """Layers that read/write the KV cache (SSM-only layers don't)."""
+        if self.layer_kinds is not None:
+            return self.count_kind("window") + self.count_kind("full")
         pat = self.resolved_ssm_pattern
         if pat is None or self.ssm_parallel:
             return self.num_layers * self.sub_blocks
         return self.num_layers - sum(pat)
+
+    def count_kind(self, kind: str) -> int:
+        """Layers of ``layer_kinds`` whose temporal block is ``kind``."""
+        return sum(k == kind for k in self.layer_kinds or ())
 
     @property
     def num_window_layers(self) -> int:
@@ -437,6 +465,12 @@ class DecoderSpec:
     @property
     def q_size(self) -> int:
         return self.gqa.num_q_heads * self.head_dim
+
+    @property
+    def q_proj_size(self) -> int:
+        """What the query projection emits and ``o_proj`` takes: a
+        differential head is projected at half the row it is placed in."""
+        return self.q_size // 2 if self.diff_attn else self.q_size
 
     @property
     def kv_size(self) -> int:
@@ -491,14 +525,16 @@ def _attn_param_specs(spec: DecoderSpec, L: int) -> Dict[str, ParamSpec]:
         # (fused_qkv, modules/attention/gqa.py GroupQueryAttention_QKV).
         layers.update({
             "qkv_proj": column_parallel(
-                H, spec.q_size * (2 if spec.attn_out_gate else 1)
+                H, spec.q_proj_size * (2 if spec.attn_out_gate else 1)
                 + 2 * spec.kv_size, dt, True, L),
-            "o_proj": row_parallel(spec.q_size, H, dt, True, L),
+            "o_proj": row_parallel(spec.q_proj_size, H, dt, True, L),
         })
         if spec.qkv_bias:
             layers["qkv_bias"] = ParamSpec(
-                (L, spec.q_size + 2 * spec.kv_size), P(None, AXIS_MP), dt,
-                "zeros")
+                (L, spec.q_proj_size + 2 * spec.kv_size), P(None, AXIS_MP),
+                dt, "zeros")
+        if spec.diff_attn:
+            layers.update(_diff_param_specs(spec, L))
         if spec.qk_norm:
             layers["q_norm"] = ParamSpec((L, spec.head_dim), P(), dt, "ones")
             layers["k_norm"] = ParamSpec((L, spec.head_dim), P(), dt, "ones")
@@ -534,6 +570,32 @@ def _attn_param_specs(spec: DecoderSpec, L: int) -> Dict[str, ParamSpec]:
         _add_lora_specs(spec, layers, L, {
             "q_proj": (H, spec.q_size), "k_proj": (H, spec.kv_size),
             "v_proj": (H, spec.kv_size), "o_proj": (spec.q_size, H)})
+    return layers
+
+
+def _diff_param_specs(spec: DecoderSpec, L: int) -> Dict[str, ParamSpec]:
+    """Differential attention's own leaves: the four vectors of ``lam``
+    ([q1, k1, q2, k2], a published head wide) and the gain of the norm over
+    the pair's value."""
+    return {
+        "diff_lambda": ParamSpec((L, 4, spec.head_dim // 2), P(),
+                                 jnp.float32, "zeros"),
+        "diff_subln": ParamSpec((L, spec.head_dim), P(), spec.dtype, "ones"),
+    }
+
+
+def _cross_param_specs(spec: DecoderSpec, L: int) -> Dict[str, ParamSpec]:
+    """A "cross" layer of ``layer_kinds``: a query and an output projection
+    (replicated: the stack is served at tp = 1) and no key or value."""
+    H, dt = spec.hidden_size, spec.dtype
+    layers = {"q_proj": ParamSpec((L, H, spec.q_proj_size), P(), dt),
+              "o_proj": ParamSpec((L, spec.q_proj_size, H), P(), dt)}
+    if spec.qkv_bias:
+        layers["q_bias"] = ParamSpec((L, spec.q_proj_size), P(), dt, "zeros")
+    if spec.o_bias:
+        layers["o_bias"] = ParamSpec((L, H), P(), dt, "zeros")
+    if spec.diff_attn:
+        layers.update(_diff_param_specs(spec, L))
     return layers
 
 
@@ -692,6 +754,18 @@ def decoder_param_specs(spec: DecoderSpec) -> Dict[str, Any]:
         if spec.num_ssm_layers:
             out["ssm_layers"] = ssm_mod.ssm_param_specs(
                 spec.ssm, H, spec.num_ssm_layers, dt)
+        if spec.layer_kinds is not None:
+            # a decoder-hybrid-decoder's second decoder: the layers that read
+            # another layer's cache, and the Gated Memory Units (the width of
+            # the mixer's scan output in, the hidden size out)
+            if spec.count_kind("cross"):
+                out["cross_layers"] = _cross_param_specs(
+                    spec, spec.count_kind("cross"))
+            if spec.count_kind("gmu"):
+                n, W = spec.count_kind("gmu"), spec.ssm.d_inner
+                out["gmu_layers"] = {
+                    "gmu_in": ParamSpec((n, H, W), P(), dt),
+                    "gmu_out": ParamSpec((n, W, H), P(), dt)}
     else:
         layers = _attn_param_specs(spec, L)
         layers.update(_dense_mlp_param_specs(spec, L) if spec.moe is None
@@ -1365,13 +1439,53 @@ def map_row_groups(fn, row_bytes: int, *args):
     return out.reshape((b,) + out.shape[2:])
 
 
-@jax.named_scope("attn")
-def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
-                is_local, seq_ids, positions, phase: str, *,
-                identity_seq_ids=False, arange_positions=False,
-                slot_mapping=None, block_table=None, adapter_ids=None,
-                kv_view=None, prefill_lens=None, side=None,
-                mixed_local=None, select=None):
+#: eps of the RMSNorm over a differential pair's value (the published
+#: ``subln``)
+DIFF_SUBLN_EPS = 1e-5
+
+
+def _diff_place(q, n_heads: int, head_dim: int):
+    """Differential attention's queries (B, T, n_heads x head_dim / 2), a
+    published head each, PLACED: head ``2j + c`` in lanes ``c x head_dim / 2``
+    on of a ``head_dim`` row, zeros in the other half -> (B, T, n_heads,
+    head_dim). Against a kv row that holds the key pair ``[k1 | k2]`` it
+    scores ``q . k_{c+1}``, and its output over the value pair ``[v1 | v2]``
+    is the pair-wide ``A_{c+1}``."""
+    b, t, _ = q.shape
+    pair = q.reshape(b, t, n_heads // 2, 2, head_dim // 2)
+    zero = jnp.zeros_like(pair[:, :, :, 0])
+    return jnp.stack(
+        [jnp.concatenate([pair[:, :, :, 0], zero], axis=-1),
+         jnp.concatenate([zero, pair[:, :, :, 1]], axis=-1)],
+        axis=3).reshape(b, t, n_heads, head_dim)
+
+
+def _diff_combine(attn_out, layer_w, depth: int):
+    """``rmsnorm(A1 - lam A2; g_sub) (1 - lam_init)`` a pair, from the placed
+    heads' outputs (B, T, heads, head_dim) -> (B, T, heads / 2 x head_dim).
+    ``lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam_init``, ``lam_init = 0.8 -
+    0.6 exp(-0.3 depth)``, ``depth`` the layer's index in the stack."""
+    b, t, n, d = attn_out.shape
+    f32 = jnp.float32
+    lam_init = 0.8 - 0.6 * math.exp(-0.3 * depth)
+    vec = layer_w["diff_lambda"].astype(f32)
+    lam = (jnp.exp(jnp.sum(vec[0] * vec[1]))
+           - jnp.exp(jnp.sum(vec[2] * vec[3])) + lam_init)
+    pair = attn_out.astype(f32).reshape(b, t, n // 2, 2, d)
+    o = pair[:, :, :, 0] - lam * pair[:, :, :, 1]
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                          + DIFF_SUBLN_EPS)
+    o = o * layer_w["diff_subln"].astype(f32) * (1.0 - lam_init)
+    return o.astype(attn_out.dtype).reshape(b, t, n // 2 * d)
+
+
+def _attn_body(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
+               is_local, seq_ids, positions, phase: str, *,
+               identity_seq_ids=False, arange_positions=False,
+               slot_mapping=None, block_table=None, adapter_ids=None,
+               kv_view=None, prefill_lens=None, side=None,
+               mixed_local=None, select=None, depth=None, hand_kv=False,
+               cross_kv=None):
     """The attention half of a layer: q/k/v projections, cache write, the
     phase-appropriate attention compute (Pallas kernel or XLA), and the
     output projection. ``h`` is the already-normed block input (B, T, H).
@@ -1385,9 +1499,17 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
     sparse selection (:func:`_indexer_block`): the tokens each query
     attends; every other cached token is left out of its softmax.
 
+    ``depth``: the layer's index in the stack (differential attention's
+    ``lam_init``). ``cross_kv``, phase "paged": the step's own projected
+    ``(k, v)`` (B, T, kv_size) of ANOTHER layer, whose pool ``k_full`` /
+    ``v_full`` at ``li`` are: this layer projects a query only
+    (``q_proj``), writes nothing and attends over that layer's cache (a
+    "cross" layer of ``DecoderSpec.layer_kinds``). ``hand_kv``: hand this
+    step's projected ``(k, v)`` back as ``pending`` for such layers.
+
     Returns (attn_h, k_full, v_full, pending): attn_h the post-o_proj
     hidden delta, pending the chunked-decode side-buffer pair (None unless
-    ``side`` is set)."""
+    ``side`` is set) or ``hand_kv``'s pair."""
     g = spec.gqa
     dtype = h.dtype
     off = spec.norm_offset
@@ -1429,13 +1551,23 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
     elif spec.mla is not None:
         q, k, v = _mla_qkv(spec, h, layer_w, cos, sin)
     else:
-        qkv = qlinear(h, layer_w["qkv_proj"])
-        if spec.qkv_bias:
-            qkv = qkv + layer_w["qkv_bias"]
-        cuts = [spec.q_size, spec.q_size + spec.kv_size]
-        if spec.attn_out_gate:
-            cuts.append(cuts[-1] + spec.kv_size)      # [q | k | v | gate]
-        q, k, v, *out_gate = jnp.split(qkv, cuts, axis=-1)
+        if cross_kv is not None:
+            q = qlinear(h, layer_w["q_proj"])
+            if spec.qkv_bias:
+                q = q + layer_w["q_bias"]
+            (k, v), out_gate = cross_kv, []
+        else:
+            qkv = qlinear(h, layer_w["qkv_proj"])
+            if spec.qkv_bias:
+                qkv = qkv + layer_w["qkv_bias"]
+            cuts = [spec.q_proj_size, spec.q_proj_size + spec.kv_size]
+            if spec.attn_out_gate:
+                cuts.append(cuts[-1] + spec.kv_size)  # [q | k | v | gate]
+            q, k, v, *out_gate = jnp.split(qkv, cuts, axis=-1)
+        handed = (k, v) if hand_kv else None
+        if spec.diff_attn:
+            q = _diff_place(q, g.num_q_heads, spec.head_dim).reshape(
+                q.shape[:2] + (spec.q_size,))
         q = apply_lora(spec.lora, layer_w, "q_proj", h, q, adapter_ids)
         k = apply_lora(spec.lora, layer_w, "k_proj", h, k, adapter_ids)
         v = apply_lora(spec.lora, layer_w, "v_proj", h, v, adapter_ids)
@@ -1521,12 +1653,13 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
             q, k, v = grow(q), grow(k), grow(v)
         write_at = slot_mapping if ring is None else ring["slots"]
         read_table = block_table if ring is None else ring["table"]
-        k_full = bkv.write_slots_at_layer(
-            k_full, kv.quantize_kv(k, k_full.dtype, spec.kv_scale), li,
-            write_at)
-        v_full = bkv.write_slots_at_layer(
-            v_full, kv.quantize_kv(v, v_full.dtype, spec.kv_scale), li,
-            write_at)
+        if cross_kv is None:
+            k_full = bkv.write_slots_at_layer(
+                k_full, kv.quantize_kv(k, k_full.dtype, spec.kv_scale), li,
+                write_at)
+            v_full = bkv.write_slots_at_layer(
+                v_full, kv.quantize_kv(v, v_full.dtype, spec.kv_scale), li,
+                write_at)
         # ragged paged decode kernel (reference: DMA-skipping TKG attention
         # over the block layout, attention_base.py:1186-1382): reads only
         # each row's LIVE pages through the block table — the gather path
@@ -1549,6 +1682,12 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
             win = jnp.asarray(spec.sliding_window, jnp.int32)
             win_note = f" window={spec.sliding_window}"
         kernel_table = block_table if ring is None else ring["kernel_table"]
+        # differential attention rides the plain grouped-query call: the
+        # record says so, and whether the layer reads another layer's pool
+        diff_note = ((" form+=diff pairs placed in halves of a kv row"
+                      if spec.diff_attn else "")
+                     + (" cross: no write, another layer's pool"
+                        if cross_kv is not None else ""))
         if h.shape[1] == 1:
             # every decline leaves a note (ops/kernel_mode.py): a decode
             # graph on the full-table gather path is never a silent choice
@@ -1575,7 +1714,8 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                                  q.shape[2], spec.head_dim, k_full,
                                  block_table.shape[1]).note(
                                      k_full.shape[4] != spec.head_dim)
-                             + ("" if mixed_local is None else win_note))
+                             + ("" if mixed_local is None else win_note)
+                             + diff_note)
             if select is not None:
                 kernel_mode.note(
                     "sparse_attn", "xla" if declined
@@ -1591,7 +1731,7 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
             # the table below
             attn_out = paged_prefill.chunk_attention(
                 spec, q, k_full, v_full, li, positions, kernel_table, win,
-                win_note, select=select)
+                win_note + diff_note, select=select)
             use_pkernel = attn_out is not None
         if not use_pkernel:
             def gathered_mha(q_, bt_, mask_):
@@ -1799,6 +1939,8 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                                            alibi=_alibi_for(
                                                v_all.shape[2]))
 
+    if spec.diff_attn:
+        attn_out = _diff_combine(attn_out, layer_w, depth)
     attn_out = attn_out.reshape(h.shape[0], h.shape[1], -1)
     if spec.attn_out_gate and spec.mla is None:
         attn_out = (attn_out * jax.nn.sigmoid(
@@ -1808,7 +1950,13 @@ def _attn_block(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
         h = apply_lora(spec.lora, layer_w, "o_proj", attn_out, h, adapter_ids)
     if spec.o_bias:
         h = h + layer_w["o_bias"]
-    return h, k_full, v_full, pending
+    return h, k_full, v_full, handed if hand_kv else pending
+
+
+#: the attention half of a layer that WRITES a cache, under its profiler
+#: scope; a layer that reads another layer's cache runs the same body under
+#: ``cross_attn``, a sibling (``run_layers_ssm``)
+_attn_block = jax.named_scope("attn")(_attn_body)
 
 
 def _deepstack_add(hidden, deepstack, deepstack_mask):
@@ -1858,11 +2006,19 @@ RECURRENT_UNSUPPORTED = {
                              "(ssm_parallel) has not been walked on the "
                              "paged path",
     "paged rglru / shortconv state": "the rglru and shortconv blocks "
-                                     "prefill from zero; only the mamba2 "
-                                     "and gated_delta kinds continue from "
-                                     "a carried state and conv tail",
+                                     "prefill from zero; only the mamba2, "
+                                     "gated_delta and mamba1 kinds continue "
+                                     "from a carried state and conv tail",
     "host KV spill / handoff": "a spilled or handed-off block carries KV "
                                "only, not the state that goes with it",
+    "contiguous decoder-hybrid-decoder": "layers that read another layer's "
+                                         "cache or another layer's scan "
+                                         "output (DecoderSpec.layer_kinds) "
+                                         "are walked on the paged path only",
+    "sharded decoder-hybrid-decoder": "the cross layers' and the Gated "
+                                      "Memory Units' weights and the "
+                                      "Mamba-1 state are replicated: the "
+                                      "stack has run at tp = 1 only",
 }
 
 
@@ -2718,7 +2874,12 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
         paged and spec.ssm_parallel and "paged parallel hybrid",
         paged and s.kind not in ssm_mod.CONTINUING_KINDS
         and "paged rglru / shortconv state"])
+    kinds = spec.layer_kinds
+    refuse_recurrent([kinds is not None and not paged
+                      and "contiguous decoder-hybrid-decoder"])
     kf, vf = cache["k"], cache["v"]
+    # the window layers' ring pool (a stack with layer_kinds only)
+    kw_, vw_ = cache.get("k_w"), cache.get("v_w")
     state_keys = [k for k in ("conv_x", "conv_bc", "ssm") if k in cache]
     new_state = {k: cache[k] for k in state_keys}
     valid = None
@@ -2741,6 +2902,8 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
             kernel_mode.note(
                 "recurrent_state", kernel_mode.kernel_path(),
                 f"{what} {ssm_mod.state_kernel_note(s, cache['ssm'])}")
+        if kinds is not None:
+            _note_pools_by_kind(spec, cache, ai)
         if state_slots is None and hidden.shape[0] != new_state["ssm"].shape[1]:
             raise ValueError(
                 f"a paged step of {hidden.shape[0]} rows over "
@@ -2766,9 +2929,18 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
                    and spec.moe is not None) else None
     attn_i = 0
     ssm_i = 0
+    # a stack with layer_kinds: the layers seen so far of each kind (a
+    # kind's index into its weight stack; "window" / "full" also into their
+    # pool), the pool layer and the step's own K / V of the nearest "full"
+    # layer below (what a "cross" layer attends over), and the nearest
+    # mixer's scan output (what a "gmu" layer gates)
+    seen = dict.fromkeys(("mamba", "window", "full", "cross", "gmu"), 0)
+    shared = memory = None
     for i in range(spec.num_layers):
+        kind = kinds[i] if kinds is not None else None
         has_ssm = bool(pat[i])
-        has_attn = spec.ssm_parallel or not has_ssm
+        has_attn = (spec.ssm_parallel or not has_ssm) if kind is None \
+            else kind in ("window", "full")
         lw = jax.tree.map(lambda a: a[i],
                           {k: a for k, a in params["layers"].items()
                            if k not in in_place})
@@ -2780,20 +2952,55 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
         if has_ssm and "ssm_layers" in params:
             js = ssm_i
             lw = {**lw, **jax.tree.map(lambda a: a[js], params["ssm_layers"])}
+        if kind in ("cross", "gmu"):
+            jk = seen[kind]
+            lw = {**lw, **jax.tree.map(lambda a: a[jk],
+                                       params[kind + "_layers"])}
         h = hidden if post_norm else _norm(
             spec, hidden, lw["input_norm"],
             lw.get("input_norm_b") if spec.norm_bias else None)
         t_out = None
-        if has_attn:
+        kw_attn = dict(identity_seq_ids=identity_seq_ids,
+                       arange_positions=(phase == "prefill"),
+                       slot_mapping=slot_mapping, block_table=block_table,
+                       adapter_ids=adapter_ids, kv_view=kv_view,
+                       prefill_lens=prefill_lens)
+        if has_attn and kind is None:
             a_out, kf, vf, _ = _attn_block(
                 spec, h, lw, kf, vf, attn_i, ai, not_local, seq_ids,
-                positions, phase, identity_seq_ids=identity_seq_ids,
-                arange_positions=(phase == "prefill"),
-                slot_mapping=slot_mapping, block_table=block_table,
-                adapter_ids=adapter_ids, kv_view=kv_view,
-                prefill_lens=prefill_lens)
+                positions, phase, **kw_attn)
             t_out = a_out
             attn_i += 1
+        elif has_attn:
+            # static per kind: a window layer on its slot's ring, the full
+            # layer on the allocator's pool, handing its step's K / V on
+            local = kind == "window"
+            t_out, kp, vp, handed = _attn_block(
+                spec, h, lw, *((kw_, vw_) if local else (kf, vf)),
+                seen[kind], ai, jnp.asarray(local), seq_ids, positions,
+                phase, mixed_local=local, depth=i, hand_kv=not local,
+                **kw_attn)
+            if local:
+                kw_, vw_ = kp, vp
+            else:
+                kf, vf = kp, vp
+                shared = (seen[kind], handed)
+            attn_i += 1
+        elif kind == "cross":
+            # the same attention under a scope of its own, a SIBLING of
+            # "attn": a query projection, the shared pool read, no write
+            with jax.named_scope("cross_attn"):
+                t_out, _, _, _ = _attn_body(
+                    spec, h, lw, kf, vf, shared[0], ai, not_local, seq_ids,
+                    positions, phase, mixed_local=False, depth=i,
+                    cross_kv=shared[1], **kw_attn)
+        elif kind == "gmu":
+            with jax.named_scope("gmu"):
+                gate = jax.nn.silu((h @ lw["gmu_in"]).astype(jnp.float32))
+                t_out = (memory.astype(jnp.float32) * gate).astype(
+                    h.dtype) @ lw["gmu_out"]
+        if kind is not None:
+            seen[kind] += 1
         if has_ssm:
             # ONE profiler scope around the whole temporal block
             # (projections, conv, state update or chunked scan, gated norm,
@@ -2806,6 +3013,7 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
                 s_out, st_new = ssm_mod.ssm_block(
                     s, lw, h, st, phase=phase, seq_lens=prefill_lens,
                     positions=positions, valid=valid)
+                memory = st_new.pop(ssm_mod.SCAN_OUT, memory)
                 for k2, v2 in st_new.items():
                     new_state[k2] = (
                         v2.stack if isinstance(v2, ssm_mod.StateStack)
@@ -2829,7 +3037,36 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
     # exact counts over this walk's expert layers, [touched, assigned,
     # read] (moe.share_tally), for the step to hand out with its tokens
     side = {"moe_tally": sum(tally)} if tally else {}
-    return hidden, {"k": kf, "v": vf, **new_state}, side
+    rings = {} if kw_ is None else {"k_w": kw_, "v_w": vw_}
+    return hidden, {"k": kf, "v": vf, **rings, **new_state}, side
+
+
+def _note_pools_by_kind(spec: DecoderSpec, cache, ai) -> None:
+    """The engagement records of a stack with ``layer_kinds`` on the paged
+    path: the pools by layer kind (``kv_window_pool``, as
+    :func:`run_layers_window` writes it) and the pool that several layers
+    read (``kv_shared_pool``: the "full" layers that write it, every layer
+    that attends over it, its bytes a token)."""
+    def pool_bytes(*keys):
+        return sum(cache[k].size * cache[k].dtype.itemsize for k in keys)
+    n_full, n_win = spec.count_kind("full"), spec.count_kind("window")
+    by_kind = " ".join(f"{k}={spec.count_kind(k)}" for k in
+                       ("mamba", "window", "full", "cross", "gmu"))
+    kg = cache["k"]
+    token_bytes = 2 * kg.shape[3] * kg.shape[4] * kg.dtype.itemsize
+    if "k_w" in cache:
+        ring = ai["ring"]["pages"]
+        kernel_mode.note(
+            "kv_window_pool", "xla",
+            f"layers {by_kind} window_tokens={spec.sliding_window} "
+            f"ring_pages={ring} ring_bytes_a_row="
+            f"{n_win * ring * kg.shape[2] * token_bytes} global_pool_bytes="
+            f"{pool_bytes('k', 'v')} window_pool_bytes="
+            f"{pool_bytes('k_w', 'v_w')}")
+    kernel_mode.note(
+        "kv_shared_pool", "xla",
+        f"layers={n_full} readers={n_full + spec.count_kind('cross')} "
+        f"bytes_a_token={n_full * token_bytes}")
 
 
 def run_layers_mixed_decode(spec: DecoderSpec, params, cache, hidden, ai,
@@ -3795,6 +4032,8 @@ def spec_from_config(config: InferenceConfig, tp_degree: Optional[int] = None,
             paged and kw.get("ssm_parallel") and "paged parallel hybrid",
             paged and kw["ssm"].kind not in ssm_mod.CONTINUING_KINDS
             and "paged rglru / shortconv state"])
+        if kw.get("layer_kinds") is not None:
+            _check_layer_kinds(kw, paged, tp)
         # the recurrent state replaces long-range KV; keep the attention
         # cache simple (full rows, no rolling/mixed layouts)
         kw.setdefault("rolling_window", False)
@@ -3853,14 +4092,19 @@ def spec_from_config(config: InferenceConfig, tp_degree: Optional[int] = None,
             kw["window_pool"] = False       # the contiguous cache: mixed_kv
         elif not (pattern is not None and kw.get("sliding_window", 0) > 0
                   and kw.get("attn_chunk", 0) == 0
-                  and kw.get("ssm") is None and kw.get("mla") is None
+                  # a recurrent stack walks a ring only by layer_kinds
+                  and (kw.get("ssm") is None
+                       or kw.get("layer_kinds") is not None)
+                  and kw.get("mla") is None
                   and kw.get("sub_blocks", 1) == 1
                   and kw.get("moe_pattern") is None
                   and not kw.get("first_dense")):
             raise ValueError(
                 "window_pool needs a local/global layer_pattern with a "
                 "sliding_window on a plain attention stack")
-        elif len(pattern) % _pattern_period(pattern):
+        elif kw.get("layer_kinds") is None \
+                and len(pattern) % _pattern_period(pattern):
+            # (the unrolled walk of layer_kinds needs no period)
             raise NotImplementedError(
                 f"window_pool: layer_pattern repeats every "
                 f"{_pattern_period(pattern)} layers, which does not divide "
@@ -3913,6 +4157,46 @@ def spec_from_config(config: InferenceConfig, tp_degree: Optional[int] = None,
             f"seq_len {tcfg.seq_len} exceeds the learned position table "
             f"({kw['learned_pos']} positions)")
     return DecoderSpec(**kw)
+
+
+def _check_layer_kinds(kw: Dict[str, Any], paged: bool, tp: int) -> None:
+    """``DecoderSpec.layer_kinds`` against what goes with it, by name: one
+    kind a layer from the five, the mixer and window patterns it implies, a
+    "full" layer under every "cross" and a mixer under every "gmu", and
+    nothing between a key's projection and the cache (a "cross" layer is
+    handed the "full" layer's projected K / V as they are)."""
+    kinds = kw["layer_kinds"]
+    known = ("mamba", "window", "full", "cross", "gmu")
+    if len(kinds) != kw["num_layers"] or set(kinds) - set(known):
+        raise ValueError(f"layer_kinds names {kw['num_layers']} layers, each "
+                         f"one of {known}; got {kinds}")
+    refuse_recurrent([
+        not paged and "contiguous decoder-hybrid-decoder",
+        tp > 1 and "sharded decoder-hybrid-decoder"])
+    if kw.get("ssm_pattern") != tuple(k == "mamba" for k in kinds) \
+            or kw.get("ssm_parallel"):
+        raise ValueError("layer_kinds: ssm_pattern marks the 'mamba' layers "
+                         "and no layer runs attention beside its mixer")
+    windows = tuple(k == "window" for k in kinds)
+    if any(windows) and not (kw.get("layer_pattern") == windows
+                             and kw.get("window_pool")
+                             and kw.get("sliding_window", 0) > 0):
+        raise ValueError("layer_kinds: 'window' layers keep a ring a batch "
+                         "slot: layer_pattern marks them, with window_pool "
+                         "and a sliding_window")
+    for kind, below in (("cross", "full"), ("gmu", "mamba")):
+        if kind in kinds and below not in kinds[:kinds.index(kind)]:
+            raise ValueError(f"layer_kinds: a {kind!r} layer reads the "
+                             f"nearest {below!r} layer below it; there is "
+                             f"none below layer {kinds.index(kind)}")
+    if "cross" in kinds and (
+            not kw.get("no_rope") or kw.get("qk_norm")
+            or kw.get("qk_norm_full") or kw.get("qkv_clip") is not None
+            or kw.get("qk_l2_norm") or kw.get("lora") is not None):
+        raise NotImplementedError(
+            "layer_kinds: a 'cross' layer is handed the 'full' layer's "
+            "projected K / V as they are; rotary, q/k norms, clipping and "
+            "LoRA between the projection and the cache are not walked")
 
 
 def _gathered_select(sp: SparseSpec, qi, w, pool, li, positions,

@@ -138,9 +138,14 @@ def pool_page(num_kv_heads: int, head_dim: int, tp: int = 1
       every call: six ``reshape`` copies of 403 MB, 10.4 of a decode step's
       28.9 ms, and the chunk programs' gathers six more (my chip run and
       AOT, PR 36);
+    * 9 to 15 heads of whole vregs (ten 128-lane PAIRS of a differential
+      stack's 20 heads of 64, Phi-4-mini-flash) share one slot as the few
+      do: as ``(32, 10, 128)`` the device kept the page tokens-minor and
+      every step copied both pools (4.9 GB of temps by AOT, PR 54);
     * everything else (heads that do not fold evenly: 96 lanes, an odd
-      count of narrow heads; 8 or more heads of whole vregs) keeps a slot a
-      head.
+      count of narrow heads; whole tiles of 8 or 16 heads of whole vregs,
+      and more, which :func:`pool_kv_heads` rounds up to whole tiles) keeps
+      a slot a head.
 
     A slot never straddles two shards: the fold is of a shard's heads. A
     token's heads in one row are the same bytes, so allocator, tables, slot

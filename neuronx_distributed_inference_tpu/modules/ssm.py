@@ -27,6 +27,10 @@ channels/heads on the model axis):
   gated_delta: conv_x (Ls,B,2·nkh·d_k + nh·d_v,K-1) over [q|k|v] (nkh key
           heads, each serving nh/nkh value heads),
           ssm (Ls,B,nh,d_k,d_v) fp32
+  mamba1: conv_x (Ls,B,K-1,d_inner) (time-major: the channels fill the
+          lanes), ssm (Ls,B,N,d_inner) fp32 (the state transposed so that
+          its 16 values a channel lie on sublanes and the channels on lanes:
+          as (d_inner, 16) the device would pad every row to 128 lanes)
 The conv tails hold the last K-1 *pre-conv* projected inputs, so a decode
 step is ``concat(tail, current) → depthwise dot`` exactly like the
 reference's cached path (modeling_falcon_h1.py torch_forward cached branch).
@@ -59,9 +63,13 @@ class SSMSpec:
       HF Qwen3NextGatedDeltaNet, Olmo-Hybrid's ``linear_attention`` layers):
       a per-head (d_k, d_v) matrix state that is decayed, read back through
       the key and corrected.
+    kind "mamba1": Mamba's per-channel selective scan (a (d_state,) state a
+      channel, the step size and B / C read off the convolved input through a
+      low-rank projection of ``dt_rank``): ``num_heads`` / ``head_dim`` unused.
     """
 
-    kind: str                 # "mamba2" | "rglru" | "shortconv" | "gated_delta"
+    # "mamba2" | "rglru" | "shortconv" | "gated_delta" | "mamba1"
+    kind: str
     d_inner: int              # mamba d_ssm / rglru lru_width / delta nh * d_v
     num_heads: int            # mamba_n_heads / rglru num_attention_heads
     head_dim: int             # mamba_d_head / rglru block_width / delta d_v
@@ -81,6 +89,8 @@ class SSMSpec:
     # neighbouring value heads (q and k are repeated over them, HF
     # ``repeat_interleave``); 0 = as many as value heads
     num_key_heads: int = 0
+    # mamba1: the rank the step size is projected through
+    dt_rank: int = 0
 
     @property
     def bc_size(self) -> int:
@@ -128,6 +138,23 @@ def ssm_param_specs(s: SSMSpec, hidden: int, Ls: int, dtype) -> Dict[str, ParamS
             specs["ssm_conv_bc_b"] = ParamSpec((Ls, 2 * gn), P(), dtype, "zeros")
         if s.gated_norm:
             specs["ssm_norm"] = ParamSpec((Ls, s.d_inner), P(None, AXIS_MP), dtype, "ones")
+        return specs
+    if s.kind == "mamba1":
+        # replicated (the family refuses tp > 1). [u | z] is ONE projection;
+        # A_log lies as the state does, (d_state, d_inner)
+        C, N = s.d_inner, s.d_state
+        specs = {
+            "m1_in": ParamSpec((Ls, hidden, 2 * C), P(), dtype),
+            "m1_conv": ParamSpec((Ls, C, s.d_conv), P(), dtype),
+            "m1_x": ParamSpec((Ls, C, s.dt_rank + 2 * N), P(), dtype),
+            "m1_dt": ParamSpec((Ls, s.dt_rank, C), P(), dtype),
+            "m1_dt_b": ParamSpec((Ls, C), P(), jnp.float32, "ones"),
+            "m1_A_log": ParamSpec((Ls, N, C), P(), jnp.float32, "zeros"),
+            "m1_D": ParamSpec((Ls, C), P(), jnp.float32, "ones"),
+            "m1_out": ParamSpec((Ls, C, hidden), P(), dtype),
+        }
+        if s.conv_bias:
+            specs["m1_conv_b"] = ParamSpec((Ls, C), P(), dtype, "zeros")
         return specs
     if s.kind == "gated_delta":
         # replicated: a recurrent stack has never run sharded, and the
@@ -199,6 +226,11 @@ def ssm_state_shapes(s: SSMSpec, Ls: int, batch: int, dtype
             "ssm": ((Ls, batch, s.num_heads, s.d_state, s.head_dim),
                     jnp.float32),
         }
+    if s.kind == "mamba1":
+        return {
+            "conv_x": ((Ls, batch, K1, s.d_inner), dtype),
+            "ssm": ((Ls, batch, s.d_state, s.d_inner), jnp.float32),
+        }
     if s.kind == "shortconv":
         return {"conv_x": ((Ls, batch, s.d_inner, K1), dtype)}
     return {
@@ -232,6 +264,9 @@ def ssm_state_pspecs(s: SSMSpec) -> Dict[str, P]:
     if s.kind == "gated_delta":
         return {"conv_x": P(None, AXIS_DP, None, None),
                 "ssm": P(None, AXIS_DP, None, None, None)}
+    if s.kind == "mamba1":
+        return {"conv_x": P(None, AXIS_DP, None, None),
+                "ssm": P(None, AXIS_DP, None, None)}
     if s.kind == "shortconv":
         return {"conv_x": P(None, AXIS_DP, AXIS_MP, None)}
     return {"conv_x": P(None, AXIS_DP, AXIS_MP, None),
@@ -499,6 +534,8 @@ def state_kernel_declined(s: SSMSpec, stack, rows: int, tokens: int,
     if s.kind == "mamba2":
         return mamba_state_step.declined(stack, rows, tokens, s.n_groups,
                                          state_slots)
+    # mamba1 too: ops/mamba_state_step.py is keyed on Mamba-2's heads (one
+    # decay a head, B / C a group), not on a decay a channel and state value
     return f"no state-step kernel for kind {s.kind}"
 
 
@@ -674,6 +711,100 @@ def gated_delta_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *,
 
 
 # ---------------------------------------------------------------------------
+# Mamba-1 (per-channel selective scan)
+# ---------------------------------------------------------------------------
+
+#: the key under which a Mamba-1 block hands its scan output on
+SCAN_OUT = "scan_out"
+#: tokens a step of the chunk's scan over time: the loop body holds this many
+#: unrolled steps of the recurrence, its carry the (rows, d_state, d_inner)
+#: state. The (T, d_inner, d_state) float32 of a whole chunk (2.7 GB at 32
+#: rows x 256 tokens of 5120 channels) is never formed
+MAMBA1_TIME_BLOCK = 8
+
+
+def mamba1_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
+                 seq_lens=None, positions=None, valid=None):
+    """One Mamba-1 block over already-normed input x (B, T, H): ``[u | z] =
+    W_in x``; ``u' = silu(conv(u) + b)`` with the carried conv tail in front;
+    ``[r | B | C] = W_x u'``; ``dt = softplus(W_dt r + b_dt)``; per channel
+    ``S_t = exp(dt_t A) S_{t-1} + dt_t u'_t B_t`` (``A = -exp(A_log)``, a
+    (d_state,) state a channel, float32), ``y_t = S_t . C_t + D u'_t``; the
+    output ``W_out (y silu(z))``. Returns ``(out, new_state)``;
+    ``new_state[SCAN_OUT]`` is ``y`` (B, T, d_inner) in x's dtype, the scan's
+    output BEFORE the gate: not state but what a Gated Memory Unit further up
+    the stack reads of the same tokens (the walk takes it out before it
+    writes the state back).
+
+    state: {"conv_x" (B, K-1, d_inner), "ssm" (B, d_state, d_inner)}, THIS
+    layer's rows. The block CONTINUES from it, resets a row whose first real
+    position is 0, and leaves the state and tail of padded positions and of
+    dead rows as they were - ``valid`` and the reset are exactly
+    :func:`mamba2_mixer`'s (a padded position has dt = 0: decay 1, input 0).
+    T == 1 is one step of the recurrence; T > 1 scans over time in blocks of
+    :data:`MAMBA1_TIME_BLOCK` tokens from the carried state."""
+    B, T, _ = x.shape
+    f32 = jnp.float32
+    C, N, K1 = s.d_inner, s.d_state, s.d_conv - 1
+    valid, n_valid, keep = _real_and_fresh(valid, phase, seq_lens, positions,
+                                           (B, T))
+    # the shared conv helpers take a tail channels-major; the slot keeps it
+    # time-major (the channels fill the lanes)
+    tail = jnp.where(keep[:, None, None], state["conv_x"], 0).transpose(
+        0, 2, 1)
+    st0 = jnp.where(keep[:, None, None], state["ssm"].astype(f32), 0.0)
+
+    uz = x @ lw["m1_in"]
+    u = jnp.where(valid[..., None], uz[..., :C], 0)
+    z = uz[..., C:].astype(f32)
+    up = jax.nn.silu(_causal_conv_prefill(
+        u, lw["m1_conv"], lw.get("m1_conv_b"), tail))
+    new_tail = _next_tail(u, valid, n_valid, K1, tail).transpose(0, 2, 1)
+
+    rbc = up @ lw["m1_x"]
+    r = rbc[..., :s.dt_rank]
+    Bm = rbc[..., s.dt_rank:s.dt_rank + N].astype(f32)
+    Cm = rbc[..., s.dt_rank + N:].astype(f32)
+    dt = jax.nn.softplus((r @ lw["m1_dt"]).astype(f32) + lw["m1_dt_b"])
+    dt = jnp.where(valid[..., None], dt, 0.0)                  # (B, T, C)
+    A = -jnp.exp(lw["m1_A_log"].astype(f32))                   # (N, C)
+    upf = up.astype(f32)
+    dtu = dt * upf
+
+    def step(st, dt_t, dtu_t, b_t, c_t):       # st (B, N, C); *_t one token
+        st = (jnp.exp(dt_t[:, None, :] * A) * st
+              + dtu_t[:, None, :] * b_t[:, :, None])
+        return st, jnp.sum(st * c_t[:, :, None], axis=1)
+
+    if T == 1:
+        st, y = step(st0, dt[:, 0], dtu[:, 0], Bm[:, 0], Cm[:, 0])
+        y = y[:, None]
+    else:
+        tb = MAMBA1_TIME_BLOCK
+        pad = (-T) % tb
+
+        def blocks(a):                 # (B, T, ..) -> (T / tb, tb, B, ..)
+            a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            return jnp.moveaxis(a, 1, 0).reshape(
+                ((T + pad) // tb, tb) + a.shape[:1] + a.shape[2:])
+
+        def block_body(st, inp):
+            ys = []
+            for i in range(tb):
+                st, y_t = step(st, *(a[i] for a in inp))
+                ys.append(y_t)
+            return st, jnp.stack(ys)
+
+        st, y = jax.lax.scan(block_body, st0,
+                             (blocks(dt), blocks(dtu), blocks(Bm),
+                              blocks(Cm)))
+        y = jnp.moveaxis(y.reshape(T + pad, B, C), 0, 1)[:, :T]
+    y = y + lw["m1_D"].astype(f32) * upf
+    out = (y * jax.nn.silu(z)).astype(x.dtype) @ lw["m1_out"]
+    return out, {"conv_x": new_tail, "ssm": st, SCAN_OUT: y.astype(x.dtype)}
+
+
+# ---------------------------------------------------------------------------
 # RG-LRU recurrent block — recurrentgemma / Griffin flavor
 # ---------------------------------------------------------------------------
 
@@ -775,18 +906,19 @@ def shortconv_block(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
 
 _SSM_BLOCKS = {"mamba2": mamba2_mixer, "rglru": rglru_block,
                "shortconv": shortconv_block,
-               "gated_delta": gated_delta_mixer}
+               "gated_delta": gated_delta_mixer, "mamba1": mamba1_mixer}
 
 #: the kinds whose block continues from a carried state and conv tail and
 #: takes ``valid``: the ones the paged serving path can run
-CONTINUING_KINDS = ("mamba2", "gated_delta")
+CONTINUING_KINDS = ("mamba2", "gated_delta", "mamba1")
 
 
 def ssm_block(s: SSMSpec, lw, x, state, *, phase, seq_lens=None,
               positions=None, valid=None):
     """``valid``: the paged step's real-token mask (the kinds of
     :data:`CONTINUING_KINDS` only: their blocks continue from a carried
-    state)."""
+    state). A Mamba-1 block's ``new_state`` also carries
+    :data:`SCAN_OUT`, which is not state (:func:`mamba1_mixer`)."""
     kw = {} if valid is None else {"valid": valid}
     return _SSM_BLOCKS[s.kind](s, lw, x, state, phase=phase,
                                seq_lens=seq_lens, positions=positions, **kw)

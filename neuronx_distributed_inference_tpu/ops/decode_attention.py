@@ -122,12 +122,18 @@ def paged_pool_fold(hkv: int, d: int) -> int:
     (a manual copy cannot slice a 64-lane minor dimension out of a tiled
     array); a FEW heads (2 to 7) of whole vregs share one row too, ``hkv x
     d`` lanes (a page of 2 heads is tiled ``(2, 128)`` and the ``(tokens x
-    heads, lanes)`` view of it was a relayout of the whole pool a call).
+    heads, lanes)`` view of it was a relayout of the whole pool a call), and
+    so do 9 to 15 (10 rows of 128 lanes a token, a differential stack's
+    20 heads of 64 paired: the device kept such a page tokens-minor and
+    every decode step copied both pools, 4.9 GB of temps by AOT, PR 54;
+    whole tiles of rows, 8 and 16, lie as declared, and more than 16 are
+    rounded up to whole tiles by ``block_kv_cache.pool_kv_heads``).
     Heads that do not fold evenly (an odd count of narrow heads, 96 lanes)
     stay a head a row: 1."""
     if d < 128 and 128 % d == 0:
         fold = 128 // d
-    elif d % 128 == 0 and 1 < hkv < PAGED_ROW_TILE:
+    elif d % 128 == 0 and 1 < hkv < 2 * PAGED_ROW_TILE \
+            and hkv % PAGED_ROW_TILE:
         fold = hkv
     else:
         fold = 1

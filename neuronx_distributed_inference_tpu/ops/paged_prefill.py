@@ -56,8 +56,10 @@ from .decode_attention import (NEG_INF, PAGED_BLOCK_PAGES,
                                PAGED_TABLE_SMEM_BYTES, _NN, _NT)
 
 #: most query rows (query heads x queries) of one tile: its queries, float32
-#: accumulator, maxima and sums stay in VMEM for the whole walk
-PAGED_PREFILL_TILE_ROWS = 8192
+#: accumulator, maxima and sums stay in VMEM for the whole walk (40 heads x
+#: 256 queries over ONE kv row a token: ten 128-lane heads that share a row
+#: are one tile, ~21 MB of the call's 64)
+PAGED_PREFILL_TILE_ROWS = 10240
 #: most tokens of one compute block (a head's float32 scores against it are
 #: ``g x T x 512 x 4`` bytes: 3.7 MB at SmallThinker's 7 x 256 query rows)
 PAGED_PREFILL_BLOCK_TOKENS = 512

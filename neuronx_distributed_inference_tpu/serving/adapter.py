@@ -312,11 +312,15 @@ class _AdapterTelemetry:
             self._rows(reg, "prefill", live, padded)
 
     def on_prefill_chunk(self, rows: int, padded_rows: int,
-                         real_tokens: int, padded_tokens: int):
+                         real_tokens: int, padded_tokens: int,
+                         cross_decoder_tokens: int = 0):
         reg = self.registry
         if not reg.enabled:
             return
         tmetrics.prefill_chunks_counter(reg).inc(rows, engine=self.engine)
+        if cross_decoder_tokens:
+            tmetrics.prefill_tokens_cross_decoder_counter(reg).inc(
+                cross_decoder_tokens, engine=self.engine)
         if padded_tokens:
             tmetrics.prefill_pad_waste_histogram(reg).observe(
                 1.0 - real_tokens / padded_tokens, engine=self.engine)
@@ -920,6 +924,13 @@ class PagedEngineAdapter:
                 raise ConfigurationError(why)
             self.host_stats.update(state_slot_allocs=0, state_slot_frees=0,
                                    state_slots_live=0)
+        # a decoder-hybrid-decoder (DecoderSpec.layer_kinds with layers that
+        # read another layer's cache or scan output): the real tokens of each
+        # dispatched chunk that ran that second decoder, exact
+        kinds = app.spec.layer_kinds or ()
+        self._cross_decoder = "cross" in kinds or "gmu" in kinds
+        if self._cross_decoder:
+            self.host_stats["prefill_tokens_cross_decoder"] = 0
         # a learned sparse selection (DecoderSpec.sparse): the index keys
         # ride the allocator's blocks, so admission, release, preemption
         # and prefix reuse need nothing new; what it does not run under is
@@ -2552,8 +2563,12 @@ class PagedEngineAdapter:
         real = sum(n for _, _, n, _ in rows)
         self.host_stats["prefill_real_tokens"] += real
         self.host_stats["prefill_padded_tokens"] += pad_rows * width
+        # every chunk walks the whole stack today, its second decoder too
+        through = real if self._cross_decoder else 0
+        if through:
+            self.host_stats["prefill_tokens_cross_decoder"] += through
         self.telemetry.on_prefill_chunk(len(rows), pad_rows, real,
-                                        pad_rows * width)
+                                        pad_rows * width, through)
         for i, s in final_rows:
             st = chunks.pop(s)
             self._unwritten.difference_update(self.app.kv_mgr.tables[s])

@@ -25,6 +25,8 @@ REQUESTS_TOTAL = "nxdi_requests_total"                # event=added|released
 PREFILL_CHUNKS_TOTAL = "nxdi_prefill_chunks_total"      # engine
 PREFILL_PAD_WASTE = "nxdi_prefill_pad_waste"            # engine
 PREFILL_DISPATCHES_TOTAL = "nxdi_prefill_dispatches_total"   # engine, experts, attn
+PREFILL_TOKENS_CROSS_DECODER_TOTAL = \
+    "nxdi_prefill_tokens_cross_decoder_total"                # engine
 
 # -- serving engine (serving/engine/) ----------------------------------------
 QUEUE_DEPTH = "nxdi_queue_depth"                        # tenant
@@ -264,6 +266,17 @@ def prefill_pad_waste_histogram(reg):
         "admission of skewed prompts pushes this toward 1)",
         labels=("engine",),
         buckets=(0.05, 0.1, 0.2, 0.4, 0.6, 0.8, 0.95))
+
+
+def prefill_tokens_cross_decoder_counter(reg):
+    return reg.counter(
+        PREFILL_TOKENS_CROSS_DECODER_TOTAL,
+        "Real prompt tokens of the dispatched prefill chunks that ran the "
+        "SECOND decoder of a decoder-hybrid-decoder stack (the layers that "
+        "read another layer's cache and the Gated Memory Units, "
+        "DecoderSpec.layer_kinds): every real token while a chunk walks the "
+        "whole stack, one a prompt once a chunk program stops before them",
+        labels=("engine",))
 
 
 def prefill_dispatches_counter(reg):

@@ -1488,9 +1488,10 @@ def test_keye_vl2_select_kernel_share_reads_the_adapters_two_counters():
     assert entry == dict(
         name=metric, unit="%", better="higher", source="program_counter",
         layer="Step graphs", moves="itl_p50_ms", workloads=[KEYE_CELL])
-    # appended where it was added: what follows it is ISSUE 52's seven
+    # appended where it was added: what follows it is ISSUE 52's seven,
+    # then ISSUE 54's five
     later = BENCHMARK["per_layer"][BENCHMARK["per_layer"].index(entry) + 1:]
-    assert [m["name"] for m in later] == list(TTFT_METRICS)
+    assert [m["name"] for m in later] == list(TTFT_METRICS + PHI4_METRICS)
 
     def ctx(**counters):
         return {"before": {"counters": {"host_stats.sparse_dispatches": 10}},
@@ -1502,6 +1503,11 @@ def test_keye_vl2_select_kernel_share_reads_the_adapters_two_counters():
     assert readers.read_metric(metric, ctx(dispatches=5)) is None
 
 
+#: ISSUE 54's per-layer metrics, appended last (the cell's own tests are
+#: tests/test_phi4flash_paged.py)
+PHI4_METRICS = ("step.decode_cross_attn_ms", "step.prefill_cross_attn_ms",
+                "step.decode_gmu_ms", "kernel.paged_decode_shared_roofline",
+                "prefill.cross_decoder_token_share")
 TTFT_METRICS = ("ttft.accept_ms", "ttft.queue_ms", "ttft.prefill_wait_ms",
                 "ttft.prefill_ms", "ttft.write_ms", "ttft.server_ms",
                 "ttft.device_idle_share")
@@ -1514,8 +1520,9 @@ def test_a_ttft_phase_metric_is_the_chat_cells_alone(metric):
     ``engine.ttft_*`` (``ServingEngine.stats``, always on) and read nothing
     from a program without the keys."""
     from harness import readers
-    assert tuple(m["name"] for m in BENCHMARK["per_layer"][-7:]) == \
-        TTFT_METRICS
+    # (ISSUE 54's five were appended behind them)
+    assert tuple(m["name"] for m in BENCHMARK["per_layer"][-12:]) == \
+        TTFT_METRICS + PHI4_METRICS
     entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == metric)
     assert entry["moves"] == "ttft_p50_ms"
     assert entry["workloads"] == ["olmoe-chat-steady"]

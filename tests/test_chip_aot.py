@@ -1098,6 +1098,134 @@ def test_keye_vl2_chunks_select_and_attend_on_the_prefill_kernel(
         < 15.75 * 2 ** 30 - 258e6
 
 
+# microsoft/Phi-4-mini-flash-reasoning config.json (model-configs catalog),
+# whole: nothing is cut
+PHI4_FLASH = dict(
+    model_type="phi4flash", embd_pdrop=0, hidden_act="silu",
+    hidden_size=2560, intermediate_size=10240, layer_norm_eps=1e-05,
+    max_position_embeddings=262144, mb_per_layer=2, num_attention_heads=40,
+    num_hidden_layers=32, num_key_value_heads=20, resid_pdrop=0,
+    sliding_window=512, tie_word_embeddings=True, mlp_bias=False,
+    lm_head_bias=False, vocab_size=200064)
+PHI4_FLASH_SERVE = dict(batch_size=32, seq_len=16384, pa_block_size=32,
+                        pa_num_blocks=16384,
+                        context_encoding_buckets=[64, 256])
+
+
+def _phi4_flash_program(v5e_devices, rows, width, layers=32):
+    shapes = _serving_shapes(PHI4_FLASH, layers, 1, v5e_devices[:1],
+                             PHI4_FLASH_SERVE, prefix=False)
+    spec, cache, sds, mb = shapes[0], shapes[4], shapes[5], shapes[6]
+    assert spec.diff_attn and spec.window_pool and mb == 512
+    n_win = spec.count_kind("window")
+    # ten pairs of heads share ONE slot of 1,280 lanes a token
+    assert cache["k"].shape == (1, 16385, 32, 1, 1280)
+    assert cache["k_w"].shape == (n_win, 32 * 25, 32, 1, 1280)
+    assert cache["ssm"].shape == (spec.num_ssm_layers, 32, 16, 5120)
+    assert cache["conv_x"].shape == (spec.num_ssm_layers, 32, 3, 5120)
+    kw = {} if rows == 32 else {"state_slots": sds((rows,), jnp.int32)}
+    return (*_compiled_paged_step(shapes, rows, width, **kw), spec)
+
+
+def _phi4_flash_movers(text, spec):
+    """Instructions that copy, transpose or relay an array with the element
+    count of either pool or of one ring layer (bf16), or that transpose or
+    relay the Mamba-1 state (float32); and the copies of the state."""
+    n_win, n_ssm = spec.count_kind("window"), spec.num_ssm_layers
+    pools = {16385 * 32 * 1280, n_win * 800 * 32 * 1280, 800 * 32 * 1280}
+    state = n_ssm * 32 * 16 * 5120
+    moved = [(name, dt, shape, op) for name, dt, shape, op in re.findall(
+        r"%(\S+) = (bf16|f32)\[([\d,]+)\]\S* (\w[\w-]*)\(", text)
+        if op in ("copy", "copy-start", "transpose", "reshape")
+        and ((dt == "bf16" and math.prod(map(int, shape.split(",")))
+              in pools)
+             or (dt == "f32" and math.prod(map(int, shape.split(",")))
+                 == state))]
+    return ([m for m in moved if m[1] == "bf16" or m[3] != "copy-start"],
+            [m for m in moved if m[1] == "f32" and m[3] == "copy-start"])
+
+
+def test_phi4_flash_decodes_over_both_pools_and_the_state_in_place(
+        v5e_devices):
+    """ISSUE 54: the decode step at Phi-4-mini-flash-reasoning's widths and
+    the cell's serving shape (32 layers whole; ONE layer's pool of 16,384
+    blocks, eight rings of 25 pages a slot, nine Mamba-1 states) holds the
+    paged decode kernel SIXTEEN times - the full layer and the seven cross
+    layers over the allocator's table, the eight window layers over their
+    ring's logical table - as plain grouped-query attention over pairs
+    placed in halves of a kv row; nothing copies, transposes or relays a
+    pool, and the state is stepped where it lies."""
+    step, notes, spec = _phi4_flash_program(v5e_devices, 32, 1)
+    plan = "pages=12 heads=10 form=mxu-blockdiag fold=10 stored"
+    diff = " form+=diff pairs placed in halves of a kv row"
+    assert {w for s, p, w in notes if s == "paged_decode"
+            and p == "pallas"} == {
+        plan + " window=0" + diff,
+        plan + " window=0" + diff + " cross: no write, another layer's pool",
+        plan + " window=512 ring=25" + diff}
+    assert ("kv_shared_pool", "xla",
+            "layers=1 readers=8 bytes_a_token=5120") in notes
+    assert ("kv_pool", "xla", "page=1x1280 heads=10x128") in notes
+    assert any(s == "kv_window_pool" and "mamba=9 window=8 full=1 cross=7 "
+               "gmu=7 window_tokens=512 ring_pages=25" in w
+               and "window_pool_bytes=1048576000" in w for s, _, w in notes)
+    assert any(s == "recurrent_state" and p == "xla"
+               and w.startswith("kind=mamba1 slot_bytes=3225600 ")
+               for s, p, w in notes)
+    text = step.as_text()
+    assert len(re.findall(r"%paged_decode_attention[.\d]* = ", text)) == 16
+    moved, state_copies = _phi4_flash_movers(text, spec)
+    assert not moved, moved
+    assert len(state_copies) <= 1, state_copies
+    memory = step.memory_analysis()
+    assert 11.5e9 < memory.argument_size_in_bytes < 11.6e9
+    assert memory.temp_size_in_bytes < 0.2e9
+
+
+def test_phi4_flash_chunk_attends_on_the_prefill_kernel(v5e_devices):
+    """The one-row chunk (``paged.w256``), eight layers of it (every kind):
+    ``paged_prefill_attention`` on the full layer, the cross layer and the
+    two window layers, one tile of all 40 placed heads over the ONE kv row
+    a token; no pool mover, and the scan over time holds no (256, 5120, 16)
+    float32 tensor."""
+    chunk, notes, spec = _phi4_flash_program(v5e_devices, 1, 256, layers=8)
+    plan = ("rows=1 width=256 pages=16 heads=40 fold=10 tile=40x256")
+    diff = " form+=diff pairs placed in halves of a kv row"
+    assert {w for s, p, w in notes if s == "paged_prefill"
+            and p == "pallas"} == {
+        plan + " window=0" + diff,
+        plan + " window=0" + diff + " cross: no write, another layer's pool",
+        plan + " window=512 ring=25" + diff}
+    text = chunk.as_text()
+    assert len(re.findall(r"%paged_prefill_attention[.\d]* = ", text)) == 4
+    moved, _ = _phi4_flash_movers(text, spec)
+    assert not moved, moved
+    whole_scan = [shape for shape in re.findall(r"= f32\[([\d,]+)\]", text)
+                  if {"5120", "16"} <= set(shape.split(","))
+                  and math.prod(map(int, shape.split(","))) >= 256 * 5120 * 16]
+    assert not whole_scan, whole_scan[:5]
+    assert chunk.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def test_the_widest_phi4_flash_program_fits_beside_weights_and_pools(
+        v5e_devices):
+    """ISSUE 54: ``paged_pack.w256`` at the configuration's size (32 layers,
+    32 rows x 256 tokens over tables of 512 pages) compiles for a v5e, which
+    refuses a program over 15.75 GB: 11.54 GB of arguments (weights 7.71,
+    shared pool 2.68, rings 1.05, state 0.10) and its temps; the pack
+    attends on the prefill kernel and moves no pool."""
+    pack, notes, spec = _phi4_flash_program(v5e_devices, 32, 256)
+    assert sum(s == "paged_prefill" and p == "pallas"
+               for s, p, _ in notes) == 3
+    moved, _ = _phi4_flash_movers(pack.as_text(), spec)
+    assert not moved, moved
+    memory = pack.memory_analysis()
+    assert 11.5e9 < memory.argument_size_in_bytes < 11.6e9
+    assert memory.temp_size_in_bytes < 1.5e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2 ** 30 - 258e6
+
+
 def test_without_the_request_nothing_is_interpreted(v5e_devices,
                                                     monkeypatch):
     """The other side of the rule: with interpret mode requested the same

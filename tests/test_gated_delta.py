@@ -271,6 +271,6 @@ def test_rglru_and_shortconv_stay_refused_on_the_paged_path(kind):
         model_base.run_layers_ssm(spec, None, {"k": None, "v": None}, None,
                                   None, None, None, "paged")
     assert sentence in str(ei.value)
-    assert set(ssm.CONTINUING_KINDS) == {"mamba2", "gated_delta"}
+    assert set(ssm.CONTINUING_KINDS) == {"mamba2", "gated_delta", "mamba1"}
     assert dataclasses.replace(block, kind="gated_delta").kind \
         in ssm.CONTINUING_KINDS

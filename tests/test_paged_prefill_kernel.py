@@ -297,7 +297,7 @@ def _pool(hkv=4, d=128, dtype=jnp.bfloat16, bs=32):
 def test_the_plan_follows_the_page_and_the_width(heads, width, plan):
     """At the cells' sizes (pages of 32 tokens): a block of 512 tokens, or
     what 8 MiB of slots hold (olmo-hybrid's 256 KB pages: 8); a tile of
-    whole kv rows under 8,192 query rows."""
+    whole kv rows under 10,240 query rows."""
     hq, hkv, d = HEADS[heads]
     assert tuple(paged_prefill.prefill_plan(
         hq, d, _pool(hkv, d), width, 128)) == plan
@@ -331,7 +331,7 @@ def test_the_plan_follows_the_page_and_the_width(heads, width, plan):
     ({}, _pool(), (2, 8), 8, None,
      "8 queries a row are not whole sublanes"),
     ({}, _pool(), (2, 8), 512, None,
-     "512 queries a row over the kernel's tile of 8192 query rows"),
+     "512 queries a row over the kernel's tile of 10240 query rows"),
 ], ids=["bf16-takes", "float32-takes-8", "soft-cap-takes", "window-takes",
         "decode-kernel-off", "alibi", "sink", "chunked", "int8-pool",
         "fp8-pool", "kv-scale", "heads-of-16-eight-to-a-row",
