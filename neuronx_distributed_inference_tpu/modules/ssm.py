@@ -529,8 +529,10 @@ def state_kernel_declined(s: SSMSpec, stack, rows: int, tokens: int,
     program and hands :func:`gated_delta_mixer` / :func:`mamba2_mixer` a
     :class:`StateStack` or its rows accordingly."""
     if s.kind == "gated_delta":
-        return delta_state_step.declined(stack, rows, tokens, s.key_heads,
-                                         state_slots)
+        why = delta_state_step.declined(stack, rows, tokens, s.key_heads,
+                                        state_slots)
+        # a chunk's record names what solves its triangular system
+        return f"{why}, {SOLVE_NOTE}" if tokens > 1 else why
     if s.kind == "mamba2":
         return mamba_state_step.declined(stack, rows, tokens, s.n_groups,
                                          state_slots)
@@ -564,6 +566,101 @@ def _delta_step(q, k, v, g, beta, st0):
     return a[..., None] * mem_q + kq * delta, st
 
 
+#: rows of a diagonal block of the chunk's triangular system: a block is
+#: inverted by substitution, a row a step, ALL blocks of ALL systems in one
+#: vectorised step (15 dependent steps, where a 64-row system asks for 63)
+SOLVE_BLOCK = 16
+#: blocks' inverses of fewer rows than this merge on the VPU, the systems on
+#: the lanes (a product of 16 x 16 blocks fills an eighth of an MXU tile);
+#: from here up the merges are float32 matmuls
+SOLVE_MXU_ROWS = 32
+#: the engagement record's words for how the chunked form solves its system
+SOLVE_NOTE = (f"blocked substitution, blocks of {SOLVE_BLOCK} merged on the "
+              f"MXU from {SOLVE_MXU_ROWS}")
+
+
+def _merge_inverses(a, b, c, matmul, rows: int):
+    """``[[A, 0], [C, B]]^-1 = [[A^-1, 0], [-B^-1 C A^-1, B^-1]]`` from ``a =
+    A^-1`` and ``b = B^-1``: exact block algebra, no series. ``rows`` is the
+    axis of the blocks' rows, their columns the axis after it."""
+    low = -matmul(matmul(b, c), a)
+    return jnp.concatenate(
+        [jnp.concatenate([a, jnp.zeros_like(a)], axis=rows + 1),
+         jnp.concatenate([low, b], axis=rows + 1)], axis=rows)
+
+
+@jax.jit
+def _unit_lower_solve(m, rhs):
+    """``X`` of ``(I + m) X = rhs`` for ``m`` (..., n, n) STRICTLY lower
+    triangular and ``rhs`` (..., n, w), float32: the chunk's system, every
+    (scan chunk, row, head) one of the leading axes' systems.
+
+    Blocked forward substitution. ``I + m`` is cut into blocks of
+    :data:`SOLVE_BLOCK` rows (``n`` padded with identity rows to a power of
+    two of them, as the chunk's padded tail already is); the diagonal blocks
+    are inverted row by row, ``X_r = e_r - sum_{j<r} L_rj X_j``, with the
+    systems on the lanes so that a step is one multiply-add over every
+    block of every system; neighbours then merge (:func:`_merge_inverses`)
+    until one inverse is left, and ``X`` is its product with ``rhs``. The
+    substitution and the small merges are exact float32 on the VPU, the
+    products from :data:`SOLVE_MXU_ROWS` rows float32 at
+    ``Precision.HIGHEST``: no step sums a series of ``m``'s powers, so
+    nothing cancels where a Neumann series would. XLA's own
+    ``triangular_solve`` inverts the same diagonal blocks row after row in a
+    custom-call (``InvertDiagBlocksLowerTriangular``: 0.60 ms a layer of
+    Olmo-Hybrid's one-row chunk, 0.34 of Qwen3-Next's, PERF.md section 5,
+    PR 55). Jitted on its own, so a program traces it once and not once a
+    layer."""
+    hi = jax.lax.Precision.HIGHEST
+    batch, n, w = m.shape[:-2], m.shape[-1], rhs.shape[-1]
+    full = SOLVE_BLOCK
+    while full < n:
+        full *= 2
+    m = jnp.pad(m.reshape(-1, n, n), ((0, 0), (0, full - n), (0, full - n)))
+    rhs = jnp.pad(rhs.reshape(-1, n, w), ((0, 0), (0, full - n), (0, 0)))
+    mt = jnp.moveaxis(m, 0, -1)                        # (full, full, S)
+
+    def under_diagonal(a, size, rows):
+        """The blocks (2p + 1, 2p) of ``a`` at ``size`` rows a block."""
+        lead = (slice(None),) * rows
+        return jnp.stack(
+            [a[lead + (slice(i + size, i + 2 * size), slice(i, i + size))]
+             for i in range(0, full, 2 * size)], axis=rows)
+
+    d = jnp.stack([mt[i:i + SOLVE_BLOCK, i:i + SOLVE_BLOCK]
+                   for i in range(0, full, SOLVE_BLOCK)])   # (blocks, r, j, S)
+    column = jnp.arange(SOLVE_BLOCK)[None, :, None]
+
+    def substitute(r, x):
+        # rows r and below of ``x`` are still zero, so the sum over every j
+        # is the sum over j < r
+        l_r = jax.lax.dynamic_index_in_dim(d, r, 1, keepdims=False)
+        x_r = (column == r).astype(x.dtype) - jnp.sum(l_r[:, :, None] * x,
+                                                      axis=1)
+        return jax.lax.dynamic_update_index_in_dim(x, x_r, r, 1)
+
+    inv = jax.lax.fori_loop(
+        1, SOLVE_BLOCK, substitute,
+        jnp.zeros_like(d).at[:, 0, 0].set(1.0))             # (blocks, r, c, S)
+    size = SOLVE_BLOCK
+    while size < min(SOLVE_MXU_ROWS, full):
+        pair = inv.reshape((-1, 2) + inv.shape[1:])
+        inv = _merge_inverses(
+            pair[:, 0], pair[:, 1], under_diagonal(mt, size, 0),
+            lambda a, b: jnp.sum(a[:, :, :, None] * b[:, None], axis=2), 1)
+        size *= 2
+    inv = jnp.moveaxis(inv, -1, 0)                          # (S, blocks, r, c)
+    while size < full:
+        pair = inv.reshape((inv.shape[0], -1, 2) + inv.shape[2:])
+        inv = _merge_inverses(
+            pair[:, :, 0], pair[:, :, 1], under_diagonal(m, size, 1),
+            lambda a, b: jnp.einsum("...ij,...jk->...ik", a, b, precision=hi),
+            2)
+        size *= 2
+    x = jnp.einsum("sij,sjk->sik", inv[:, 0], rhs, precision=hi)
+    return x[:, :n].reshape(batch + (n, w))
+
+
 def _delta_chunked(q, k, v, g, beta, st0, chunk: int):
     """The same recurrence over T tokens in chunks (the WY / UT form), from
     the carried state ``st0``. q, k (B,T,H,dk), v (B,T,H,dv), g and beta
@@ -576,8 +673,9 @@ def _delta_chunked(q, k, v, g, beta, st0, chunk: int):
     [beta v | beta k exp(G)]``; then ``V = U - W S`` are the rows actually
     written, ``o = (q exp(G)) S + tril(q k^T * decay) V`` and the state
     handed to the next chunk is ``S exp(G_last) + (k exp(G_last - G))^T V``.
-    The triangular solve is forward substitution (stable where the Neumann
-    series of ``M`` cancels catastrophically: beta near 2, keys aligned)."""
+    The system is solved by :func:`_unit_lower_solve`: blocked forward
+    substitution (stable where the Neumann series of ``M`` cancels
+    catastrophically: beta near 2, keys aligned), its merges matmuls."""
     B, T, H, dv = v.shape
     hi = jax.lax.Precision.HIGHEST
     cs = min(chunk, T)
@@ -598,10 +696,9 @@ def _delta_chunked(q, k, v, g, beta, st0, chunk: int):
     m = jnp.where(jnp.tril(lower, -1),
                   jnp.einsum("...ik,...jk->...ij", kb, kc, precision=hi)
                   * decay, 0.0)
-    uw = jax.lax.linalg.triangular_solve(
+    uw = _unit_lower_solve(
         m, jnp.concatenate([vc * bc[..., None],
-                            kb * jnp.exp(gc)[..., None]], axis=-1),
-        left_side=True, lower=True, unit_diagonal=True)
+                            kb * jnp.exp(gc)[..., None]], axis=-1))
     qk = jnp.einsum("...ik,...jk->...ij", qc, kc, precision=hi) * decay
     q_in = qc * jnp.exp(gc)[..., None]
     g_last = gc[..., -1]                                          # (nc,B,H)

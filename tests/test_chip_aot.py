@@ -365,6 +365,20 @@ def _state_layout(text: str, stack: str) -> str:
     return layout
 
 
+#: ISSUE 55: how a delta-rule chunk program says it solves its system
+BLOCKED_SOLVE = "blocked substitution, blocks of 16 merged on the MXU from 32"
+
+
+def _triangular_custom_calls(text: str):
+    """The custom-calls of a compiled text that are XLA's own triangular
+    solve: ``jax.lax.linalg.triangular_solve`` lowers to ONE
+    ``InvertDiagBlocksLowerTriangular`` a call, which inverts its diagonal
+    blocks row after row off the MXU (0.60 ms a layer of Olmo-Hybrid's
+    one-row chunk, 0.34 of Qwen3-Next's: PERF.md section 5, PR 55)."""
+    return re.findall(
+        r'custom_call_target="(\w*Triangular\w*)"', text)
+
+
 # allenai/Olmo-Hybrid-7B config.json (model-configs catalog), one period
 OLMO_HYBRID_7B = dict(
     model_type="olmo_hybrid", vocab_size=100352, hidden_size=3840,
@@ -389,7 +403,8 @@ def test_30_kv_heads_of_128_decode_on_the_kernel_with_no_pool_copy(
     whole tiles (``block_kv_cache.pool_kv_heads``: 32 slots): the step holds
     the Mosaic call, the record says what it runs with, and no instruction
     moves a pool. The one-row 256-token chunk (the delta rule's chunked
-    form, its triangular solve) compiles beside it."""
+    form) compiles beside it, its triangular system solved by blocked
+    substitution (ISSUE 55): no custom-call inverts it row after row."""
     spec, tcfg, mesh, params, cache, sds, mb = _serving_shapes(
         OLMO_HYBRID_7B, 4, 1, v5e_devices[:1],
         dict(batch_size=32, seq_len=2048, pa_block_size=32,
@@ -426,12 +441,14 @@ def test_30_kv_heads_of_128_decode_on_the_kernel_with_no_pool_copy(
     # ISSUE 49: the chunk's attention walks the 32 head slots of the stored
     # page on the prefill kernel
     assert notes == {("recurrent_state", "xla",
-                      f"{state}: 256 tokens a row: the chunked form"), pool,
+                      f"{state}: 256 tokens a row: the chunked form, "
+                      f"{BLOCKED_SOLVE}"), pool,
                      ("paged_prefill", "pallas",
                       "rows=1 width=256 pages=8 heads=32 fold=1 "
                       "tile=32x256 window=0")}
     assert "paged_prefill_attention" in chunk.as_text()
     assert "delta_state_step" not in chunk.as_text()
+    assert not _triangular_custom_calls(chunk.as_text())
     assert chunk.memory_analysis().temp_size_in_bytes < 200e6
 
 
@@ -508,7 +525,8 @@ def test_2_kv_heads_of_256_decode_on_the_kernel_with_no_pool_copy(
     # prefill kernel
     assert notes == {
         ("recurrent_state", "xla",
-         f"{state}: 256 tokens a row: the chunked form"), share, pool, (
+         f"{state}: 256 tokens a row: the chunked form, {BLOCKED_SOLVE}"),
+        share, pool, (
         "moe_decode", "pallas",
         "pieces=1 of 512 rows=256 by expert in tiles of 128"), (
         "paged_prefill", "pallas",
@@ -517,6 +535,7 @@ def test_2_kv_heads_of_256_decode_on_the_kernel_with_no_pool_copy(
     assert "paged_prefill_attention" in text
     assert "delta_state_step" not in text
     assert "ragged-dot" not in text and "%moe_chunk_experts" in text
+    assert not _triangular_custom_calls(text)
     assert not pool_moves(text), pool_moves(text)
     copied = re.findall(r"slice_bitcast_fusion[.\d]* = bf16\[([\d,]+)\]",
                         text)

@@ -126,7 +126,7 @@ def test_dead_rows_move_nothing(live):
 
 
 @pytest.mark.parametrize("case, why", [
-    (dict(tokens=8), "8 tokens a row: the chunked form"),
+    (dict(tokens=8), "8 tokens a row: the chunked form, " + ssm.SOLVE_NOTE),
     (dict(state_slots=np.zeros((4,), np.int32)),
      "rows gathered from their slots"),
     (dict(rows=2), "rows gathered from their slots"),

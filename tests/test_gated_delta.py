@@ -28,6 +28,7 @@ for _p in (ROOT, os.path.join(ROOT, "benchmark")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
+import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from harness import build  # noqa: E402
 
@@ -101,6 +102,71 @@ def test_chunked_step_reference_and_transformers_agree(ref, t, chunk, kw):
         output_final_state=True)
     np.testing.assert_allclose(got_o, hf_o.numpy(), atol=ATOL)
     np.testing.assert_allclose(got_s, hf_s.numpy(), atol=ATOL)
+
+
+# ---------------------------------------------------------------------------
+# the chunk's triangular system, solved directly (ISSUE 55)
+# ---------------------------------------------------------------------------
+
+#: of the solution's largest entry (7-30 in these cases): float32 forward
+#: substitution reads 1e-7 to 4e-7 of it against a float64 solve, XLA's
+#: ``triangular_solve`` the same; a merge left out is O(1) of it
+SOLVE_RTOL = 2e-6
+
+
+def _systems(seed, n, cs, real, beta_lo, beta_hi, aligned):
+    """``n`` systems of a chunk of ``cs`` positions, the first ``real`` of
+    them tokens and the rest the padded tail (``g = 0``, ``beta = 0``), as
+    ``_delta_chunked`` builds them, in float64: ``(m, rhs)``."""
+    rng = np.random.default_rng(seed)
+    k = rng.standard_normal((n, cs, DK))
+    if aligned:
+        k = rng.standard_normal((n, 1, DK)) + 0.05 * k
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    live = np.arange(cs) < real
+    beta = rng.uniform(beta_lo, beta_hi, (n, cs)) * live
+    g = np.cumsum(-rng.uniform(1e-3, 0.5, (n, cs)) * live, axis=-1)
+    kb = k * beta[..., None]
+    m = np.tril(kb @ k.transpose(0, 2, 1)
+                * np.exp(np.tril(g[:, :, None] - g[:, None, :])), -1)
+    v = rng.standard_normal((n, cs, DV))
+    return m, np.concatenate([v * beta[..., None],
+                              kb * np.exp(g)[..., None]], axis=-1)
+
+
+@pytest.mark.parametrize("beta", [(0.0, 2.0, False), (1.9, 2.0, False),
+                                  (1.95, 2.0, True)],
+                         ids=["beta_0_2", "beta_near_2", "keys_aligned"])
+@pytest.mark.parametrize("cs, real", [(64, 64), (16, 16), (5, 5), (64, 22)],
+                         ids=["cs64", "cs16", "cs5", "tail_of_22"])
+@pytest.mark.parametrize("lead", [(4, 1, 30), (4, 32, 30)],
+                         ids=["one_row_120", "a_pack_3840"])
+def test_the_blocked_solve_is_a_float64_solve(monkeypatch, lead, cs, real,
+                                              beta):
+    """``_unit_lower_solve`` against ``numpy.linalg.solve`` in float64, at a
+    tolerance XLA's ``triangular_solve`` meets on the same systems; the
+    control: with the blocks under the diagonal left out of a merge the
+    answer is a thousand tolerances away wherever there is a merge."""
+    m, rhs = _systems(55, int(np.prod(lead)), cs, real, *beta)
+    want = np.linalg.solve(np.eye(cs) + m, rhs)
+    tol = SOLVE_RTOL * np.abs(want).max()
+    m32 = jnp.asarray(m.reshape(lead + m.shape[1:]), jnp.float32)
+    r32 = jnp.asarray(rhs.reshape(lead + rhs.shape[1:]), jnp.float32)
+
+    def worst(solve):
+        return float(np.abs(np.asarray(solve(m32, r32)).reshape(want.shape)
+                            - want).max())
+    assert worst(ssm._unit_lower_solve) < tol
+    assert worst(lambda a, b: jax.lax.linalg.triangular_solve(
+        a, b, left_side=True, lower=True, unit_diagonal=True)) < tol
+    merge = ssm._merge_inverses
+    monkeypatch.setattr(
+        ssm, "_merge_inverses",
+        lambda a, b, c, matmul, rows: merge(a, b, 0 * c, matmul, rows))
+    # the function under the jit: this trace must not be served from, nor
+    # left in, the cache of the real one
+    dropped = worst(ssm._unit_lower_solve.__wrapped__)
+    assert (dropped > 1000 * tol) == (real > ssm.SOLVE_BLOCK), dropped
 
 
 def test_a_chunk_started_from_zero_is_not_the_recurrence(ref):

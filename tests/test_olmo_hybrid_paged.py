@@ -190,7 +190,8 @@ def test_a_chunks_then_decode_on_the_state_kernel(ref):
     assert ("recurrent_state", "pallas-interpret",
             f"{what} heads=2 tile=8x64") in app.paged_program_notes(BATCH, 1)
     assert ("recurrent_state", "xla",
-            f"{what}: 16 tokens a row: the chunked form") in \
+            f"{what}: 16 tokens a row: the chunked form, "
+            f"{ssm.SOLVE_NOTE}") in \
         app.paged_program_notes(1, 16)
 
 
@@ -541,8 +542,9 @@ def test_warmup_plan_and_the_engagement_record(app):
     what = f"kind=gated_delta slot_bytes={slot_bytes} chunk={s.chunk_size}"
     no_tile = ("1 tiles of 8x16 a key head are not whole 8x64 tiles under "
                "4194304 bytes")
-    for why in (no_tile, "8 tokens a row: the chunked form",
-                "16 tokens a row: the chunked form"):
+    for why in (no_tile,
+                f"8 tokens a row: the chunked form, {ssm.SOLVE_NOTE}",
+                f"16 tokens a row: the chunked form, {ssm.SOLVE_NOTE}"):
         note = {"site": "recurrent_state", "path": "xla",
                 "reason": f"{what}: {why}"}
         assert note in report["kernels"]
