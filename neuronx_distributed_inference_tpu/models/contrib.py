@@ -660,7 +660,8 @@ class StableLmFamily(DecoderFamily):
 @register_family("cohere")
 class CohereFamily(DecoderFamily):
     """Parallel-shared residual, bias-free LayerNorm, logit scaling,
-    tied embeddings."""
+    tied embeddings, rotary over interleaved pairs (HF ``rotate_half`` of
+    modeling_cohere takes ``x[..., ::2]`` / ``x[..., 1::2]``)."""
     config_cls = _SimpleConfig
 
     @classmethod
@@ -671,6 +672,7 @@ class CohereFamily(DecoderFamily):
             rms_eps=float(getattr(config, "layer_norm_eps", 1e-5)),
             norm_type="layernorm",
             block_style="parallel_shared",
+            rope_interleaved=True,
             logits_divide=1.0 / scale if scale else None,
             tie_word_embeddings=True,
         )

@@ -631,30 +631,42 @@ class SmolLM3Family(DecoderFamily):
         )
 
 
+def cohere2_block(config) -> dict:
+    """What Cohere's second generation fixes of a decoder, as
+    ``spec_from_config`` keywords - ``cohere2`` (Command-R7B) and
+    ``cohere2_moe`` (``models/cohere2_moe``) alike: cohere v1 (one bias-free
+    LayerNorm feeding a parallel block, logit scaling, tied embeddings,
+    rotary over INTERLEAVED pairs ``(2i, 2i+1)``: HF ``rotate_half`` takes
+    ``x[..., ::2]`` / ``x[..., 1::2]``) + sliding-window layers by
+    ``layer_types`` among full layers that carry NO rotary."""
+    scale = float(getattr(config, "logit_scale", 1.0))
+    types = list(getattr(config, "layer_types", []) or [])
+    pattern = tuple(t == "sliding_attention" for t in types)
+    return dict(
+        rms_eps=float(getattr(config, "layer_norm_eps", 1e-5)),
+        norm_type="layernorm",
+        block_style="parallel_shared",
+        rope_interleaved=True,
+        logits_divide=1.0 / scale if scale not in (0.0, 1.0) else None,
+        layer_pattern=pattern if any(pattern) else None,
+        sliding_window=(int(getattr(config, "sliding_window", 0) or 0)
+                        if any(pattern) else 0),
+        nope_global=any(pattern),
+        # a stack of full layers only rotates nowhere
+        no_rope=not any(pattern),
+        tie_word_embeddings=True,
+    )
+
+
 @register_family("cohere2")
 class Cohere2Family(DecoderFamily):
-    """Command-R7B — cohere v1 (parallel-shared residual, bias-free
-    LayerNorm, logit scaling, tied embeddings) + alternating sliding/global
-    layers where the global layers are NoPE."""
+    """Command-R7B — :func:`cohere2_block` around a dense SwiGLU MLP."""
     config_cls = _SimpleConfig
     post_norm_src = "input_layernorm"   # parallel_shared: post_norm unused
 
     @classmethod
     def build_spec(cls, config, tp_degree=None):
-        scale = float(getattr(config, "logit_scale", 1.0))
-        types = list(getattr(config, "layer_types", []) or [])
-        pattern = tuple(t == "sliding_attention" for t in types)
-        return spec_from_config(
-            config, tp_degree,
-            rms_eps=float(getattr(config, "layer_norm_eps", 1e-5)),
-            norm_type="layernorm",
-            block_style="parallel_shared",
-            logits_divide=1.0 / scale if scale else None,
-            layer_pattern=pattern if any(pattern) else None,
-            sliding_window=int(getattr(config, "sliding_window", 0) or 0),
-            nope_global=any(pattern),
-            tie_word_embeddings=True,
-        )
+        return spec_from_config(config, tp_degree, **cohere2_block(config))
 
     @classmethod
     def convert_extra_layer_weights(cls, get, layer_stack, spec):

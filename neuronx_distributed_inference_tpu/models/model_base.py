@@ -1345,11 +1345,20 @@ def _mlp_block(spec: DecoderSpec, x_in, layer_w, mlp_kind, adapter_ids,
     """The MLP / MoE half of a layer (GLU, plain 2-layer, or routed MoE),
     under the profiler scope ``moe`` (router, expert matmuls, combine) or
     ``mlp``. ``tally`` / ``live`` / ``router_x``: ``moe_block``'s (a router
-    that reads the attention's input runs under ``moe`` all the same)."""
+    that reads the attention's input runs under ``moe`` all the same). The
+    shared experts of a sequential block are part of ``moe``; in a PARALLEL
+    block they are a third stream off the one norm, beside attention and
+    the routed experts, and run under a sibling scope ``shared``."""
     if mlp_kind == "moe":
+        apart = (spec.block_style != "sequential"
+                 and spec.moe.shared_intermediate > 0)
         with jax.named_scope("moe"):
-            return moe_block(spec.moe, x_in, layer_w, phase=phase,
-                             tally=tally, live=live, router_x=router_x)
+            y = moe_block(spec.moe, x_in, layer_w, phase=phase, tally=tally,
+                          live=live, router_x=router_x, shared=not apart)
+        if apart:
+            with jax.named_scope("shared"):
+                y = y + moe_mod.shared_experts(spec.moe, x_in, layer_w)
+        return y
     with jax.named_scope("mlp"):
         return _dense_mlp(spec, x_in, layer_w, adapter_ids, phase)
 
@@ -3949,7 +3958,9 @@ def spec_from_config(config: InferenceConfig, tp_degree: Optional[int] = None,
         intermediate_size=inter,
         vocab_size=vocab,
         padded_vocab=pad_vocab(vocab, tp),
-        rms_eps=float(getattr(config, "rms_norm_eps", 1e-6)),
+        # (a config may carry the key as null: cohere2_moe norms by
+        # layer_norm_eps)
+        rms_eps=float(getattr(config, "rms_norm_eps", None) or 1e-6),
         rope=rope,
         act=getattr(config, "hidden_act", "silu"),
         gqa=gqa,

@@ -44,6 +44,12 @@ Design:
   * Shared experts (reference: SharedExperts in moe_v2.py:104) are a plain
     dense MLP added to the routed output, behind a per-token sigmoid gate
     where the spec says so (``shared_gated``: Qwen2-MoE / Qwen3-Next).
+    Several shared experts are ONE gated MLP over their concatenated width
+    (the sum of their outputs); where the architecture AVERAGES them
+    (``shared_mean_of``: Command A+'s four) that MLP's output is divided by
+    their count. A parallel block runs the branch as a third stream beside
+    attention and the routed experts (:func:`shared_experts`, under the
+    layer walk's scope ``shared``).
   * A SHARE of a layer (``held_experts``): the router scores all
     ``num_experts`` with the published top-k and renormalisation, the
     weights hold the ``held_experts`` experts from ``first_expert`` on, and
@@ -89,6 +95,12 @@ class MoESpec:
     sparsemixer_eps: float = 0.01    # phimoe router_jitter_noise
     pre_softmax_topk: bool = False   # top-k on raw logits, then act over k
     shared_intermediate: int = 0     # 0 = no shared experts
+    # the shared branch is the concatenation of this many shared experts
+    # whose outputs are AVERAGED (cohere2_moe
+    # ``shared_expert_combination_strategy`` "average"): the fused MLP's
+    # output is divided by it. 0 = the branch is added as it is (one
+    # expert, or several summed)
+    shared_mean_of: int = 0
     act: str = "silu"
     # bias added to router scores for expert selection only (DeepSeek-V3
     # e_score_correction_bias); affinity weights still use raw scores
@@ -630,8 +642,10 @@ def experts_touched(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
 def moe_block(moe: MoESpec, x: jnp.ndarray, layer_w: Dict[str, Any],
               phase: str = "prefill", tally: Optional[list] = None,
               live: Optional[jnp.ndarray] = None,
-              router_x: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Full MoE block: route + experts (+ shared experts). x (B,T,H).
+              router_x: Optional[jnp.ndarray] = None,
+              shared: bool = True) -> jnp.ndarray:
+    """Full MoE block: route + experts (+ shared experts; ``shared`` False
+    leaves that branch to the caller, :func:`shared_experts`). x (B,T,H).
     ``tally``: a list a layer walk hands in to collect, per expert layer,
     :func:`share_tally` + :func:`zero_tally` + :func:`group_tally` of this
     routing over the ``live`` rows: int32 ``[touched, assigned, read, picks,
@@ -654,7 +668,10 @@ def moe_block(moe: MoESpec, x: jnp.ndarray, layer_w: Dict[str, Any],
                          + (f" zero={moe.zero_experts}"
                             if moe.zero_experts else "")
                          + (f" groups={moe.n_group} top={moe.topk_group}"
-                            if moe.n_group > 1 else ""))
+                            if moe.n_group > 1 else "")
+                         + (f" shared={moe.shared_mean_of} x "
+                            f"{moe.shared_intermediate // moe.shared_mean_of}"
+                            " mean" if moe.shared_mean_of else ""))
     y, read = _experts(moe, x, top_vals, top_idx, layer_w, phase)
     if moe.zero_experts:
         # the identity experts' term, the token's own chip's
@@ -666,7 +683,9 @@ def moe_block(moe: MoESpec, x: jnp.ndarray, layer_w: Dict[str, Any],
             share_tally(moe, top_idx, live, read),
             zero_tally(moe, top_idx, live),
             group_tally(moe, groups, top_idx, live)]))
-    return _shared_experts(moe, x, y, layer_w)
+    if shared and moe.shared_intermediate > 0:
+        y = y + shared_experts(moe, x, layer_w)
+    return y
 
 
 def _experts(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
@@ -739,18 +758,21 @@ def _experts(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
                          *biases), None
 
 
-def _shared_experts(moe: MoESpec, x: jnp.ndarray, y: jnp.ndarray,
-                    layer_w: Dict[str, Any]) -> jnp.ndarray:
-    """Add the always-on shared-expert branch (DeepSeek/GLM style)."""
-    if moe.shared_intermediate > 0:
-        act = _act_fn(moe.act)
-        s = act(qlinear(x, layer_w["shared_gate"])) * qlinear(x, layer_w["shared_up"])
-        s = shard_constraint(s, AXIS_DP, None, AXIS_MP)
-        s = qlinear(s, layer_w["shared_down"])
-        if moe.shared_gated:
-            gate = jnp.einsum("bth,h->bt", x, layer_w["shared_gate_w"],
-                              preferred_element_type=jnp.float32)
-            s = (s.astype(jnp.float32)
-                 * jax.nn.sigmoid(gate)[..., None]).astype(s.dtype)
-        y = y + s
-    return y
+def shared_experts(moe: MoESpec, x: jnp.ndarray,
+                   layer_w: Dict[str, Any]) -> jnp.ndarray:
+    """The always-on shared-expert branch (DeepSeek/GLM style), what a block
+    adds to its routed sum: one gated MLP of ``shared_intermediate``, behind
+    its per-token gate (``shared_gated``) or divided by the number of
+    experts it concatenates (``shared_mean_of``: their mean)."""
+    act = _act_fn(moe.act)
+    s = act(qlinear(x, layer_w["shared_gate"])) * qlinear(x, layer_w["shared_up"])
+    s = shard_constraint(s, AXIS_DP, None, AXIS_MP)
+    s = qlinear(s, layer_w["shared_down"])
+    if moe.shared_gated:
+        gate = jnp.einsum("bth,h->bt", x, layer_w["shared_gate_w"],
+                          preferred_element_type=jnp.float32)
+        s = (s.astype(jnp.float32)
+             * jax.nn.sigmoid(gate)[..., None]).astype(s.dtype)
+    if moe.shared_mean_of:
+        s = s / moe.shared_mean_of
+    return s

@@ -46,7 +46,14 @@ not run).
     re-derived from what the application allocates, the twin's shrunk
     ``topk``, the reference gating a toy twin, and three faults in the
     PROGRAM (the selection, the head weights, the index keys' write) failing
-    it.
+    it;
+  * ``command-a-plus-05-2026`` (ISSUE 56): every published number but the
+    depth, ``layer_types``, the experts held and the vocabulary, the share's
+    keys, ``assumed`` and the mix letter for letter, the two pools, the ring
+    and the parameters re-derived from what the application allocates, the
+    two rooflines' yardsticks from a made-up window, and three faults in the
+    PROGRAM (an unaveraged shared sum, the half-split rotary, a rotated full
+    layer) failing the toy gate.
 """
 
 import glob
@@ -1255,8 +1262,8 @@ def test_a_fault_in_the_program_does_not_pass_the_deepseek_v3_toy_gate(
             lambda spec, select: jnp.ones(
                 select.shape[:-1] + (spec.n_group,), bool))
     elif fault == "shared":
-        monkeypatch.setattr(moe, "_shared_experts",
-                            lambda spec, x, y, layer_w: y)
+        monkeypatch.setattr(moe, "shared_experts",
+                            lambda spec, x, layer_w: jnp.zeros_like(x))
     else:
         route = moe.route_groups
         monkeypatch.setattr(
@@ -1489,9 +1496,10 @@ def test_keye_vl2_select_kernel_share_reads_the_adapters_two_counters():
         name=metric, unit="%", better="higher", source="program_counter",
         layer="Step graphs", moves="itl_p50_ms", workloads=[KEYE_CELL])
     # appended where it was added: what follows it is ISSUE 52's seven,
-    # then ISSUE 54's five
+    # then ISSUE 54's five, then ISSUE 56's four
     later = BENCHMARK["per_layer"][BENCHMARK["per_layer"].index(entry) + 1:]
-    assert [m["name"] for m in later] == list(TTFT_METRICS + PHI4_METRICS)
+    assert [m["name"] for m in later] == list(
+        TTFT_METRICS + PHI4_METRICS + COMMAND_A_METRICS)
 
     def ctx(**counters):
         return {"before": {"counters": {"host_stats.sparse_dispatches": 10}},
@@ -1508,6 +1516,10 @@ def test_keye_vl2_select_kernel_share_reads_the_adapters_two_counters():
 PHI4_METRICS = ("step.decode_cross_attn_ms", "step.prefill_cross_attn_ms",
                 "step.decode_gmu_ms", "kernel.paged_decode_shared_roofline",
                 "prefill.cross_decoder_token_share")
+#: ISSUE 56's, appended last
+COMMAND_A_METRICS = ("step.decode_shared_ms", "step.prefill_shared_ms",
+                     "kernel.paged_decode_layer_types_roofline",
+                     "kernel.moe_decode_avg_shared_roofline")
 TTFT_METRICS = ("ttft.accept_ms", "ttft.queue_ms", "ttft.prefill_wait_ms",
                 "ttft.prefill_ms", "ttft.write_ms", "ttft.server_ms",
                 "ttft.device_idle_share")
@@ -1520,9 +1532,9 @@ def test_a_ttft_phase_metric_is_the_chat_cells_alone(metric):
     ``engine.ttft_*`` (``ServingEngine.stats``, always on) and read nothing
     from a program without the keys."""
     from harness import readers
-    # (ISSUE 54's five were appended behind them)
-    assert tuple(m["name"] for m in BENCHMARK["per_layer"][-12:]) == \
-        TTFT_METRICS + PHI4_METRICS
+    # (ISSUE 54's five and ISSUE 56's four were appended behind them)
+    assert tuple(m["name"] for m in BENCHMARK["per_layer"][-16:]) == \
+        TTFT_METRICS + PHI4_METRICS + COMMAND_A_METRICS
     entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == metric)
     assert entry["moves"] == "ttft_p50_ms"
     assert entry["workloads"] == ["olmoe-chat-steady"]
@@ -1587,3 +1599,299 @@ def test_a_fault_in_the_program_does_not_pass_the_keye_vl2_toy_gate(
     res = build.logit_gate(_toy_file(), seed=2**31 + 51,
                            served_precision="highest")
     assert not res["passed"] and res["worst_ratio"] > 10
+
+
+# ---------------------------------------------------------------------------
+# command-a-plus-05-2026 (ISSUE 56)
+# ---------------------------------------------------------------------------
+
+COMMAND_A_CELL = "command-a-plus-agent-closed"
+COMMAND_A_PERIOD = ["sliding_attention"] * 3 + ["full_attention"]
+
+
+def test_command_a_plus_keeps_every_published_number():
+    """The catalog row's ``config`` (model-configs guide), as copied into
+    ISSUE 56: every key at the top level of the file, no width changed, and
+    ``reduced`` names the depth, the layer types cut with it, the experts
+    held and the vocabulary, and nothing else."""
+    cfg = build.load_json("configs", "command-a-plus-05-2026.json")
+    published = dict(
+        attention_bias=False, expert_selection_fn="sigmoid",
+        first_k_dense_replace=0, head_dim=128, hidden_act="silu",
+        hidden_size=4096, intermediate_size=4096, layer_norm_eps=1e-05,
+        layer_switch=4, layer_types=COMMAND_A_PERIOD * 8, logit_scale=1,
+        max_position_embeddings=200000, model_type="cohere2_moe",
+        norm_topk_prob=True, num_attention_heads=128, num_experts=128,
+        num_experts_per_tok=8, num_hidden_layers=32, num_key_value_heads=8,
+        num_shared_experts=4,
+        order_of_interleaved_layers="local_attn_first",
+        position_embedding_type="rope_gptj",
+        prefix_dense_intermediate_size=16384,
+        prefix_dense_sliding_window_pattern=1, rms_norm_eps=None,
+        rope_parameters={"rope_theta": 50000, "rope_type": "default"},
+        rope_theta=50000, rotary_pct=1,
+        shared_expert_combination_strategy="average", sliding_window=4096,
+        tf_legacy_loss=False, tie_word_embeddings=True,
+        use_embedding_sharing=True, use_gated_activation=True,
+        use_parallel_block=True, use_parallel_embedding=False,
+        use_qk_norm=False, vocab_size=262144)
+    assert set(published) <= set(cfg)
+    differs = sorted(k for k in published if cfg[k] != published[k])
+    assert differs == sorted(cfg["reduced"]) == [
+        "layer_types", "num_experts", "num_hidden_layers", "vocab_size"]
+    entry = next(c for c in BENCHMARK["configs"]
+                 if c["name"] == "command-a-plus-05-2026")
+    assert sorted(entry["reduced"]) == differs
+    assert entry["source"] == cfg["source"]
+    # ON the guide's floors: one whole period and four layers, 16 >= 8
+    # experts of a router over all 128, an eighth of the vocabulary
+    assert cfg["num_hidden_layers"] == 4
+    assert cfg["layer_types"] == COMMAND_A_PERIOD
+    assert (cfg["num_experts"], cfg["router_num_experts"],
+            cfg["first_expert"]) == (16, 128, 0)
+    assert cfg["vocab_size"] * 8 == published["vocab_size"]
+    assert cfg["family"] == cfg["model_type"] == "cohere2_moe"
+    assert cfg["chips"] == cfg["tp"] == 1 and cfg["dtype"] == "bfloat16"
+    assert cfg["adapter"] == {"prefill_budget_tokens": max(
+        cfg["serve"]["context_encoding_buckets"])}
+    assert cfg["serve"]["is_prefix_caching"] is False
+    assert "64 v5e chips" in cfg["deployment"]
+    assert {"intermediate_size", "shared_average", "routing",
+            "nope_full_layers", "window_mask", "layernorm", "router_dtype",
+            "tensor_names", "prefix_dense", "logit_scale", "vision_tower",
+            "kv_dtype", "window_pool", "adapter",
+            "rooflines_not_listed"} <= set(cfg["assumed"])
+    gate = cfg["gate"]
+    twin = build.hf_config(cfg, build.gate_overrides(gate))
+    # the twin: the file's own period at the published widths, the window
+    # shrunk FOR THE TWIN so that 128 tokens cross it
+    assert (twin["num_hidden_layers"], twin["hidden_size"],
+            twin["num_experts"], twin["router_num_experts"],
+            twin["vocab_size"], twin["sliding_window"]) == \
+        (4, 4096, 16, 128, 32768, 64)
+    assert twin["layer_types"] == COMMAND_A_PERIOD
+    assert "TWIN" in gate["config_why"]
+    assert (gate["batch"], gate["prompt_len"], gate["new_tokens"]) == \
+        (4, 112, 16)
+    assert gate["prompt_len"] + gate["new_tokens"] <= \
+        4 * cfg["serve"]["pa_block_size"]
+    assert twin["sliding_window"] < gate["prompt_len"]
+    assert 0 < gate["excuse_margin_max"] <= 0.02
+    ref = build.load_reference("cohere2_moe")
+    assert {"rope_halves", "rope_on_full", "shared_sum", "router_bf16",
+            "no_window"} <= set(ref.CONTROLS)
+    # the pool cannot run dry: every row at its longest prompt and answer
+    mix = build.load_json("traffic", "agent-longctx-closed.json")
+    serve = cfg["serve"]
+    longest = mix["prompt_len"]["hi"] + mix["output_len"]["hi"]
+    assert longest == serve["seq_len"] == 12288 <= \
+        cfg["max_position_embeddings"]
+    assert serve["pa_num_blocks"] * serve["pa_block_size"] == \
+        serve["batch_size"] * longest
+    # the mix is ISSUE 56's, every number of it
+    assert (mix["loop"], mix["clients_per_batch_row"], mix["pool_requests"],
+            mix["lead_s"], mix["grace_s"], mix["base_seed"]) == \
+        ("closed", 2, 4096, 40.0, 8.0, 56)
+    assert mix["prompt_len"] == dict(kind="lognormal", median=4096,
+                                     sigma=0.7, lo=512, hi=8192)
+    assert mix["output_len"] == dict(kind="lognormal", median=1536,
+                                     sigma=0.6, lo=384, hi=4096)
+    assert build.warm_widths(cfg, mix) == [1, 64, 256]
+    # the cell is on its own two rooflines and on none keyed on another
+    # family's names
+    listed = {m["name"] for m in BENCHMARK["per_layer"]
+              if COMMAND_A_CELL in m.get("workloads", ())}
+    assert set(COMMAND_A_METRICS) <= listed
+    assert {m["name"] for m in BENCHMARK["per_layer"]
+            if m.get("workloads") == [COMMAND_A_CELL]} == \
+        set(COMMAND_A_METRICS)
+    assert not {m for m in listed if m.endswith("_roofline")} \
+        - set(COMMAND_A_METRICS)
+    assert {"kv.window_pages_held_share", "moe.prefill_walk_share",
+            "moe.experts_touched_share", "moe.experts_skipped_share",
+            "attn.paged_prefill_kernel_share", "step.decode_moe_ms",
+            "host.stall_s", "sched.live_batch_mean"} <= listed
+    cell = next(w for w in BENCHMARK["workloads"]
+                if w["name"] == COMMAND_A_CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("command-a-plus-05-2026", "agent-longctx-closed", 1)
+    assert COMMAND_A_CELL in next(
+        m for m in BENCHMARK["end_to_end"]
+        if m["name"] == "tokens_per_s")["workloads"]
+
+
+def test_command_a_plus_allocates_what_its_file_says():
+    """The two pools, the ring, the weights and the total of the file's
+    ``memory``, against what the program would allocate: the full
+    configuration's pools and parameters as SHAPES (nothing of 12.8 GB is
+    allocated)."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    from neuronx_distributed_inference_tpu.models import model_base
+    from neuronx_distributed_inference_tpu.modules.block_kv_cache import (
+        pool_spec, window_pool_spec, window_ring_pages)
+    from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
+    cfg = build.load_json("configs", "command-a-plus-05-2026.json")
+    memory, serve = cfg["memory"], cfg["serve"]
+    spec = build.build_app(cfg).spec
+    assert spec.window_pool and spec.nope_global and spec.rope_interleaved
+    assert spec.block_style == "parallel_shared"
+    assert spec.norm_type == "layernorm" and spec.logits_divide is None
+    assert spec.layer_pattern == (True, True, True, False)
+    assert spec.sliding_window == 4096 and spec.tie_word_embeddings
+    assert (spec.num_attn_layers, spec.num_window_layers,
+            spec.num_moe_layers) == (4, memory["window_layers"], 4)
+    assert spec.num_attn_layers - spec.num_window_layers == \
+        memory["global_layers"] == 1
+    m = spec.moe
+    assert (m.num_experts, m.num_held, m.first_expert, m.top_k,
+            m.intermediate_size, m.router_act, m.act) == \
+        (128, 16, 0, 8, 4096, "sigmoid", "silu")
+    assert m.normalize_topk and m.routed_scaling is None \
+        and not m.has_router_bias and m.n_group == 1
+    assert (m.shared_intermediate, m.shared_mean_of) == (16384, 4)
+    assert abs(spec.rope.rope_theta - 50000) < 1e-6
+    widest = max(serve["context_encoding_buckets"])
+    ring = window_ring_pages(4096, widest, serve["pa_block_size"])
+    assert ring == memory["window_ring_pages"] == 137
+    assert ring * serve["pa_block_size"] == memory["window_ring_tokens"]
+    pool = pool_spec(spec, serve["pa_num_blocks"], serve["pa_block_size"])
+    wpool = window_pool_spec(spec, serve["batch_size"],
+                             serve["pa_block_size"], widest)
+    # a head a slot: 8 kv heads of 128 lanes do not fold
+    assert pool.shape == (1, 12289, 32, 8, 128)
+    assert wpool.shape == (3, 32 * 137, 32, 8, 128)
+    assert str(jnp.dtype(pool.dtype)) == cfg["assumed"]["kv_dtype"]
+    assert pool.bytes_per_token // pool.num_layers == \
+        memory["kv_bytes_per_token_per_layer"] == 8 * 128 * 2 * 2
+    assert 2 * math.prod(pool.shape) * 2 == memory["global_pool_bytes"]
+    assert 2 * math.prod(wpool.shape) * 2 == memory["window_pool_bytes"] == \
+        3 * 4096 * 32 * 4384
+    # one pool for every layer at this context does not fit beside the
+    # weights and the widest program's temps
+    assert 4 * 4096 * 32 * 12288 + memory["weights_bytes"] + 1.5e9 \
+        > 15.75 * 2 ** 30
+    leaves = jax.tree.leaves(model_base.decoder_param_specs(spec),
+                             is_leaf=lambda x: isinstance(x, ParamSpec))
+    # (the program's tree carries an unused post_norm a layer beside the
+    # one LayerNorm a parallel block reads: 4 x 4096 entries)
+    assert sum(math.prod(ps.shape) for ps in leaves) - 4 * 4096 == \
+        memory["parameters"] == 4 * (142_606_336 + 201_326_592 + 524_288
+                                     + 4096 + 16 * 50_331_648) \
+        + 32_768 * 4096 + 4096 == 4_733_292_544
+    weights = sum(math.prod(ps.shape) * jnp.dtype(ps.dtype).itemsize
+                  for ps in leaves)
+    # the program's count over the file's all-bf16 one: the routers in
+    # float32 (2 B more an entry) and the unused post_norm
+    assert weights - 2 * 4 * 4096 * 128 - 2 * 4 * 4096 == \
+        memory["weights_bytes"] == 2 * memory["parameters"]
+    total = (memory["weights_bytes"] + memory["global_pool_bytes"]
+             + memory["window_pool_bytes"])
+    assert total == memory["before_temps_bytes"]
+    assert 0.79 * 16e9 < total < 0.81 * 16e9
+
+
+def test_command_a_plus_rooflines_count_what_the_model_needs(monkeypatch):
+    """The cell's two rooflines from a made-up window: the attention's need
+    is the full layer's every running token + the three window layers'
+    tokens inside the window + every call's queries and outputs; the
+    experts' need is the held experts a step TOUCHED + every layer's router
+    over 128 + four shared experts, over the ``moe`` AND ``shared`` scopes'
+    time; a configuration of other key names, or a program without the
+    counters, reads nothing."""
+    from harness import host_spans, readers
+    cfg = build.load_json("configs", "command-a-plus-05-2026.json")
+    expert = 3 * 4096 * 4096 * 2
+    programs = {"paged.w1": dict(count=100, total_s=2.0),
+                "paged.w256": dict(count=40, total_s=0.7)}
+    scopes = {"paged.w1": {"moe": 0.8, "shared": 0.25, "attn": 0.6},
+              "paged.w256": {"moe": 0.3, "shared": 0.07, "attn": 0.1}}
+    edge = {"counters": {"host_stats.kv_tokens_running": 182000.0,
+                         "host_stats.kv_tokens_in_window": 119000.0,
+                         "kv.live_rows": 32.0}}
+    ops = {"paged_decode_attention": dict(seconds=0.30, count=100),
+           "paged_decode_attention.1": dict(seconds=0.10, count=300),
+           "fusion.7": dict(seconds=0.5, count=100)}
+
+    def ctx(counters, config=cfg, slice_=None):
+        return {"config": config, "peaks": {"hbm_gbps": 819.0},
+                "warm_widths": [1, 64, 256], "before": {"counters": {}},
+                "after": {"counters": {"host_stats." + k: v
+                                       for k, v in counters.items()}},
+                "slice": slice_ or {"before": edge, "after": edge},
+                "trace": {"programs": programs,
+                          "ops_by_program": {"paged.w1": ops}},
+                "_slice": {"scopes": {
+                    label: dict(programs[label], scopes=scopes[label])
+                    for label in programs}}}
+    monkeypatch.setattr(host_spans, "load_slice", lambda c: c["_slice"])
+    # -- the experts: 50 steps fetched, 13.8 of 16 held touched a layer
+    metric = "kernel.moe_decode_avg_shared_roofline"
+    counters = dict(moe_expert_slots=50 * 4 * 16,
+                    moe_experts_touched=50 * 4 * 13.8)
+    least = (4 * 13.8 * expert
+             + 4 * (4096 * 128 * 2 + 4 * expert)) / 819e9
+    got = readers.read_metric(metric, ctx(counters))
+    assert got == pytest.approx(100 * least / 10.5e-3) and 80 < got < 90
+    assert least == pytest.approx(8.76e-3, rel=5e-3)
+    assert readers.read_metric(metric, ctx({})) is None
+    for key in ("router_num_experts", "num_shared_experts"):
+        assert readers.read_metric(
+            metric, ctx(counters, dict(cfg, **{key: None}))) is None
+    # a program that keeps its shared experts under moe reads the same work
+    scopes["paged.w1"] = {"moe": 1.05, "attn": 0.6}
+    assert readers.read_metric(metric, ctx(counters)) == pytest.approx(got)
+    # every held expert touched is the ceiling of the need
+    full = dict(counters, moe_experts_touched=50 * 4 * 16)
+    assert readers.read_metric(metric, ctx(full)) < 100
+    # -- the attention: 1 full layer x 182k + 3 window layers x 119k tokens
+    metric = "kernel.paged_decode_layer_types_roofline"
+    q_out = 32 * 2 * 128 * 128 * 2
+    need = (182000 + 3 * 119000) * 4096 + 4 * q_out
+    got = readers.read_metric(metric, ctx({}))
+    assert got == pytest.approx(100 * (need / 819e9) / 4e-3)
+    assert 65 < got < 70
+    assert readers.read_metric(
+        metric, ctx({}, {k: v for k, v in cfg.items()
+                         if k != "layer_types"})) is None
+    bare = {"counters": {"kv.live_rows": 32.0}}
+    assert readers.read_metric(
+        metric, ctx({}, slice_={"before": bare, "after": bare})) is None
+    # SmallThinker's and the one-pool yardsticks read nothing here
+    for other in ("kernel.paged_decode_window_roofline",
+                  "kernel.moe_decode_held_roofline",
+                  "kernel.moe_decode_share_roofline"):
+        assert readers.read_metric(other, ctx(counters)) is None
+
+
+@pytest.mark.parametrize("fault", ["shared_sum", "rope_halves",
+                                   "rope_on_full"])
+def test_a_fault_in_the_program_does_not_pass_the_cohere2_moe_toy_gate(
+        monkeypatch, fault):
+    """The other direction of the controls: the PROGRAM broken, the
+    reference sound. The four shared experts summed, the rotary half-split,
+    a full layer rotated."""
+    import dataclasses
+
+    from neuronx_distributed_inference_tpu.models.family import get_family
+    from test_cohere2_moe_paged import _toy_file
+    from neuronx_distributed_inference_tpu.modules import moe
+    family = get_family("cohere2_moe")
+    build_spec = family.build_spec.__func__
+    shared = moe.shared_experts
+
+    def broken(cls, config, tp_degree=None):
+        spec = build_spec(cls, config, tp_degree)
+        if fault == "rope_halves":
+            return dataclasses.replace(spec, rope_interleaved=False)
+        return dataclasses.replace(spec, nope_global=False)
+    if fault == "shared_sum":
+        monkeypatch.setattr(moe, "shared_experts", lambda spec, x, w: shared(
+            dataclasses.replace(spec, shared_mean_of=0), x, w))
+    else:
+        monkeypatch.setattr(family, "build_spec", classmethod(broken))
+    res = build.logit_gate(_toy_file(), seed=2**31 + 56,
+                           served_precision="highest")
+    assert not res["passed"] and res["worst_ratio"] > 5

@@ -1245,6 +1245,112 @@ def test_the_widest_phi4_flash_program_fits_beside_weights_and_pools(
         < 15.75 * 2 ** 30 - 258e6
 
 
+def _command_a_plus_program(v5e_devices, rows, width):
+    """A paged step of ``benchmark/configs/command-a-plus-05-2026.json`` (the
+    file itself: its model keys and its serving shape) compiled for a v5e,
+    with the engagement records of its trace and its cache's shapes."""
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import build
+    cfg = build.load_json("configs", "command-a-plus-05-2026.json")
+    hf = build.hf_config(cfg)
+    serve = {k: cfg["serve"][k] for k in (
+        "batch_size", "seq_len", "pa_block_size", "pa_num_blocks",
+        "context_encoding_buckets")}
+    shapes = _serving_shapes(hf, 4, 1, v5e_devices[:1], serve, prefix=False)
+    spec, _, _, _, cache, sds, mb = shapes
+    assert spec.window_pool and spec.block_style == "parallel_shared"
+    assert mb == 384
+    # the page a head a slot: 8 kv heads of 128 lanes are a whole row tile
+    # and do NOT fold (folded, the widest chunk's prefill plan has no fit)
+    assert cache["k"].shape == (1, 12289, 32, 8, 128)
+    assert cache["k_w"].shape == (3, 32 * 137, 32, 8, 128)
+    kw = {} if rows == 32 else {"state_slots": sds((rows,), jnp.int32)}
+    program, notes = _compiled_paged_step(shapes, rows, width, **kw)
+    text = program.as_text()
+    pools = {",".join(map(str, a.shape)) for a in cache.values()}
+    pools |= {"1,12289,32,1024", "3,4384,32,1024", "12289,32,8,128",
+              "13152,32,8,128", "1,393248,8,128", "3,140288,8,128"}
+    moved = [(name, shape, op) for name, shape, op in re.findall(
+        r"%(\S+) = bf16\[([\d,]+)\]\S* (\w[\w-]*)\(", text)
+        if shape in pools
+        and op not in ("parameter", "get-tuple-element", "bitcast",
+                       "fusion", "scatter", "custom-call", "while",
+                       "dynamic-update-slice")]
+    return program, notes, text, moved
+
+
+def test_command_a_plus_decodes_on_three_kernels_in_place(v5e_devices):
+    """ISSUE 56: the decode step of the Command A+ cell (a parallel block
+    on the walk by layer kind: 128 query heads over 8 kv heads of 128, 16
+    held experts of 4096 x 4096, four averaged shared experts as one MLP of
+    16384) holds the paged decode kernel four times - one call over the
+    allocator's table, three over the ring's logical table with the window -
+    at ONE page a compute block (``pages=1``: 8 kv rows x 16 query heads,
+    the geometry ROADMAP A queues), and the walk over the touched experts
+    in column pieces; nothing copies, transposes or relays either pool or
+    the expert stacks."""
+    step, notes, text, moved = _command_a_plus_program(v5e_devices, 32, 1)
+    assert ("moe_decode", "pallas", "pieces=8 of 512") in notes
+    assert ("moe_share", "xla", "held=16 of 128 from 0 top_k=8 "
+            "shared=4 x 4096 mean") in notes
+    assert {(s, p, w) for s, p, w in notes if s == "paged_decode"} == {
+        ("paged_decode", "pallas",
+         "pages=1 heads=8 form=mxu-blockdiag fold=1 window=0"),
+        ("paged_decode", "pallas",
+         "pages=1 heads=8 form=mxu-blockdiag fold=1 window=4096 ring=137")}
+    assert ("kv_pool", "xla", "page=8x128 heads=8x128") in notes
+    assert any(s == "kv_window_pool" and "global=1 window=3" in w
+               and "window_tokens=4096 ring_pages=137" in w
+               for s, _, w in notes)
+    assert len(re.findall(r"%paged_decode_attention[.\d]* = ", text)) == 4
+    assert "%moe_decode_experts" in text and not moved, moved
+    stacks = [(name, shape, op) for name, shape, op in re.findall(
+        r"%(\S+) = bf16\[([\d,]+)\]\S* (\w[\w-]*)\(", text)
+        if shape in ("4,16,4096,4096", "16,4096,4096", "4,4096,16384",
+                     "4,16384,4096")
+        and op not in ("parameter", "get-tuple-element", "bitcast")]
+    assert not stacks, stacks
+    assert step.memory_analysis().temp_size_in_bytes < 100e6
+
+
+@pytest.mark.parametrize("rows, temps_under", [(1, 50e6), (32, 1.7e9)],
+                         ids=["chunk", "pack"])
+def test_command_a_plus_chunks_attend_on_the_prefill_kernel(
+        v5e_devices, rows, temps_under):
+    """ISSUE 56: the one-row chunk (``paged.w256``) and the pack
+    (``paged_pack.w256``) of the Command A+ cell attend on
+    ``paged_prefill_attention`` with a tile of 2 kv rows = 32 heads x 256
+    queries (``prefill_plan(128, 128, pool, 256)``: fit 2), the full layer
+    over the allocator's table and the window layers over their rings; the
+    chunk's experts go through the walk by expert, the pack's through the
+    grouped matmuls eight rows at a time; no pool is moved; the widest
+    program fits a v5e beside 12.8 GB of weights and pools."""
+    program, notes, text, moved = _command_a_plus_program(v5e_devices, rows,
+                                                          256)
+    plan = f"rows={rows} width=256 pages=16 heads=128 fold=1 tile=32x256"
+    assert {(p, w) for s, p, w in notes if s == "paged_prefill"} == {
+        ("pallas", plan + " window=0"),
+        ("pallas", plan + " window=4096 ring=137")}
+    assert MOSAIC in text and not moved, moved
+    if rows == 1:
+        assert ("moe_decode", "pallas", "pieces=8 of 512 rows=256 by expert "
+                "in tiles of 128") in notes
+        assert "ragged-dot" not in text and "%moe_chunk_experts" in text
+    else:
+        assert ("moe_ragged", "stacked", "") in notes
+        assert ("moe_ragged", "row-groups", "8 of 32 rows") in notes
+    memory = program.memory_analysis()
+    assert memory.temp_size_in_bytes < temps_under
+    assert 12.79e9 < memory.argument_size_in_bytes < 12.82e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2 ** 30 - 258e6
+
+
 def test_without_the_request_nothing_is_interpreted(v5e_devices,
                                                     monkeypatch):
     """The other side of the rule: with interpret mode requested the same

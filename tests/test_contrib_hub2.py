@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 import torch
 
-from test_contrib_hub import _check
+from test_contrib_hub import _check, sharpen_attention
 
 
 def test_gptj_matches_hf(tmp_path):
@@ -166,8 +166,10 @@ def test_cohere2_matches_hf(tmp_path):
                                      "sliding_attention", "full_attention"],
                         logit_scale=0.25, attention_dropout=0.0,
                         torch_dtype="float32")
-    app = _check(tmp_path, "cohere2", Cohere2ForCausalLM(cfg))
+    app = _check(tmp_path, "cohere2",
+                 sharpen_attention(Cohere2ForCausalLM(cfg)))
     assert app.spec.layer_pattern == (True, False, True, False)
+    assert app.spec.rope_interleaved
     assert app.spec.block_style == "parallel_shared" and app.spec.nope_global
 
 

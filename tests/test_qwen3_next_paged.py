@@ -403,12 +403,12 @@ def _no_attention_output_gate(monkeypatch):
 
 
 def _no_shared_expert_gate(monkeypatch):
-    shared = moe._shared_experts
+    shared = moe.shared_experts
 
-    def ungated(spec, x, y, layer_w):
-        return shared(dataclasses.replace(spec, shared_gated=False), x, y,
+    def ungated(spec, x, layer_w):
+        return shared(dataclasses.replace(spec, shared_gated=False), x,
                       layer_w)
-    monkeypatch.setattr(moe, "_shared_experts", ungated)
+    monkeypatch.setattr(moe, "shared_experts", ungated)
 
 
 def _renormalised_over_the_held_only(monkeypatch):
@@ -502,7 +502,7 @@ def test_d_four_shares_add_up_to_the_uncut_layer(ref, gate_weights, tokens,
     whole = dataclasses.replace(spec.moe, dense_max_tokens=16)
     assert moe.takes_ragged(whole, tokens) == (path == "ragged")
     np.testing.assert_allclose(moe.moe_block(whole, x, lw), want, atol=2e-5)
-    shared = moe._shared_experts(whole, x, jnp.zeros_like(x), lw)
+    shared = moe.shared_experts(whole, x, lw)
     assert np.abs(np.asarray(shared)).max() > 1e-3
     total, tallies = 0, []
     for first in (0, 4, 8, 12):
