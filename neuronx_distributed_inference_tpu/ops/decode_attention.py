@@ -71,9 +71,8 @@ PAGED_TABLE_SMEM_BYTES = 1_040_408
 #: scoped limit of a v5e kernel is 16 MiB; scores and their copies take ~2).
 PAGED_KV_VMEM_BYTES = 4 * 1024 * 1024
 #: most elements of a compute block's (query heads, tokens x a shard's kv
-#: rows) fp32 score tile, 32 vregs: the MXU's work is per column whether its
-#: page is live or not, and every softmax step is unrolled over the tile, so
-#: the tile bounds the kernel's code (a program's load time) as well.
+#: rows) fp32 score tile, 32 vregs: the MXU's work is per column, live page or
+#: not, and the softmax is unrolled over the tile: it bounds the code as well.
 PAGED_SCORE_TILE_ELEMENTS = 32 * 1024
 #: most pages of one compute block: their copies are unrolled in the kernel.
 PAGED_BLOCK_PAGES = 16
@@ -96,6 +95,7 @@ class PagedPlan(NamedTuple):
     hkv: int            # the kernel's kv rows a token: a shard's heads / fold
     g: int              # query rows a kv row: group size x fold
     d: int              # lanes of a kv row: head_dim x fold
+    form: str = "mxu-blockdiag"     # the inner score loop's shape
 
     def note(self, stored: bool) -> str:
         """The engagement record's text (``kernel_mode.note``); it ends
@@ -105,7 +105,7 @@ class PagedPlan(NamedTuple):
         wherever the device tiles the two shapes differently)."""
         where = "" if self.fold == 1 else " stored" if stored else " call"
         return (f"pages={self.pages} heads={self.hkv * self.fold} "
-                f"form=mxu-blockdiag fold={self.fold}{where}")
+                f"form={self.form} fold={self.fold}{where}")
 
 
 #: fewer kv rows a token than this (the second-minor extent of a 32-bit
@@ -140,12 +140,12 @@ def paged_pool_fold(hkv: int, d: int) -> int:
     return fold if hkv % fold == 0 else 1
 
 
-def paged_block_plan(bs: int, hkv: int, g: int, d: int, kv_dtype,
-                     mb: int) -> PagedPlan:
-    """How the paged kernel walks a call of this geometry (``hkv`` a shard's
-    kv heads, ``g`` query heads a kv head, ``mb`` the table's width): chosen
-    from what the call can observe - page geometry and item size - and from
-    nothing else.
+def _blockdiag_plan(bs: int, hkv: int, g: int, d: int, kv_dtype,
+                    mb: int) -> PagedPlan:
+    """The block-diagonal walk of a call of this geometry (``hkv`` a shard's
+    kv heads, ``g`` query heads a kv head, ``mb`` the table's width); what
+    :func:`paged_block_plan`, at this file's end, gives every geometry but
+    kv rows of whole query tiles.
 
     With ``fold`` heads to a row (:func:`paged_pool_fold`) a page is a
     ``(bs * hkv / fold, fold * d)`` matrix, and a compute block is ``pages``
@@ -664,16 +664,11 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
 
     The grid is the rows. For each, the kernel walks the pages from the
     window's first to the last live one in compute blocks of ``pages`` pages
-    (:func:`paged_block_plan`), so a call's time follows the live context
-    and not the table's width, and a row of length 0 does its active token
-    only. Staged in SMEM: layer, window, lengths and the WHOLE table -
-    4 x (2 + B + B x max_blocks) bytes of the 1 MiB a v5e core has
-    (:data:`PAGED_TABLE_SMEM_BYTES`; 64 rows x 4,096 blocks does not fit: a
-    caller that needs such tables must split the batch or page the table,
-    ROADMAP B2). Copied by hand: the pools stay in HBM, and a page (all of a
-    shard's heads: one contiguous slab) is one async copy into one of two
-    K and two V slots in VMEM, :data:`PAGED_KV_VMEM_BYTES` together.
-    """
+    (:func:`paged_block_plan`, which also says whether a block is scored
+    block-diagonally or kv row by kv row): a call's time follows the live
+    context, not the table's width. In SMEM: layer, window, lengths and the
+    WHOLE table (:data:`PAGED_TABLE_SMEM_BYTES`: 64 rows x 4,096 blocks do
+    not fit, ROADMAP B2); a page is one async copy from HBM into a slot."""
     b, hq, d = q.shape
     bs = k_pages.shape[2]
     mb = block_table.shape[1]
@@ -686,8 +681,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
             f"paged decode kernel: block table of {b} rows x {mb} blocks "
             f"needs {table_bytes} B of SMEM scalar prefetch, over the "
             f"{PAGED_TABLE_SMEM_BYTES} B a v5e core can hold")
-    pages, fold_k, hkv_k, g_k, d_k = paged_block_plan(bs, hkv, g, d,
-                                                      k_pages.dtype, mb)
+    pages, fold_k, hkv_k, g_k, d_k, form = paged_block_plan(
+        bs, hkv, g, d, k_pages.dtype, mb)
     assert fold in (1, fold_k), (fold, fold_k)     # folded as the plan folds
     fold = fold_k
     # a page as the matrix it is in memory: (tokens x kv rows, lanes)
@@ -735,6 +730,11 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                   .reshape(b, n_blk, cols)]
         sel_spec = [pl.BlockSpec((1, n_blk, cols), lambda bi, sc: (bi, 0, 0))]
     scalars = jnp.concatenate(scalars)
+    if form == PAGED_ROWS_FORM:     # kv row by kv row: the body at the end
+        return _paged_rows_call(
+            scalars, q, new_k, new_v, sink_in, select, k_pages, v_pages,
+            pages=pages, hkv=hkv_k, mb=mb, scale=scale, soft_cap=soft_cap,
+            has_sink=sink is not None, kv_scale=kv_scale, interpret=interpret)
     slot = (2, pages, bs * hkv_k, d_k)
     out = pl.pallas_call(
         kernel,
@@ -859,3 +859,312 @@ def supports(spec, phase_t: int, paged: bool = False) -> bool:
     it takes the lanes of a row from the arrays it is handed."""
     return (phase_t == 1 and spec.mla is None and spec.attn_chunk == 0
             and spec.head_dim in ((64, 128, 256) if paged else (64, 128)))
+
+
+# ---------------------------------------------------------------------------
+# The paged kernel's second inner loop: kv row by kv row (ISSUE 57). It lives
+# at the file's end because a Pallas body's serialised form holds the line
+# numbers of its whole call stack: every line above ``paged_dispatch_plan``
+# that the block-diagonal call passes through is where it was, so the cells
+# that keep that form find their programs in the compile cache.
+# ---------------------------------------------------------------------------
+
+#: ``PagedPlan.form`` of a call scored kv row by kv row
+PAGED_ROWS_FORM = "mxu-kv-rows"
+
+
+def paged_block_plan(bs: int, hkv: int, g: int, d: int, kv_dtype,
+                     mb: int) -> PagedPlan:
+    """How the paged kernel walks a call of this geometry (``hkv`` a shard's
+    kv heads, ``g`` query heads a kv head, ``mb`` the table's width): chosen
+    from what the call can observe - page geometry and item size - and from
+    nothing else.
+
+    With ``fold`` heads to a row (:func:`paged_pool_fold`) a page is a
+    ``(bs * hkv / fold, fold * d)`` matrix, and a compute block is ``pages``
+    of them: the most that (a) four slots (K and V, double-buffered) fit
+    :data:`PAGED_KV_VMEM_BYTES`, so a 1-byte item doubles it, (b) keep the
+    block's score tile under :data:`PAGED_SCORE_TILE_ELEMENTS`, (c)
+    :data:`PAGED_BLOCK_PAGES` and the table allow.
+
+    The score tile has two shapes. ``mxu-blockdiag``: all query heads
+    against all rows of the block in one matmul, what is off the block
+    diagonal masked - query heads x tokens x kv rows elements, right where a
+    token has ONE kv row or a kv row one query row (the MXU is idle
+    otherwise). ``mxu-kv-rows`` (:data:`PAGED_ROWS_FORM`): where a head of
+    128 lanes has a row of the page to itself (fold 1), a token has whole
+    tiles of kv rows (:data:`PAGED_ROW_TILE`), each has whole tiles of query
+    rows and the pool is bf16 or 32-bit (what :func:`_paged_rows_kernel` can
+    read a kv row out of: Mosaic refuses the 32-bit view of a 256-lane
+    slot), a kv row's ``g`` query rows are scored against its own ``pages x
+    bs`` rows of the slot - query heads x tokens elements, ``hkv`` times
+    fewer, so ``pages`` rises by as much.
+
+    The clock (``scripts/paged_decode_time.py``, PR 57, one v5e: the call
+    alone, 32 rows of ~2k / ~6k tokens, ms a call and % of
+    ``paged_decode_min_bytes`` at 819 GB/s; 128 query heads over 8 kv heads
+    of 128 lanes, bf16; the window-4096 column at ~6k tokens)::
+
+        form, pages, kv rows a loop turn      2k           6k        6k w4096
+        block-diagonal, 1 page             1.442 (23)  4.107 (24)  2.779 (24)
+        copies and waits alone, 8 pages    0.454 (73)  1.165 (85)  0.813 (81)
+        BY PHASE, pairs read, 8, 8 [kept]  0.479 (69)  1.194 (83)  0.853 (77)
+        by phase, pairs read, 8, 2         0.648 (51)  1.680 (59)  1.197 (55)
+        by phase, pairs read, 16, 8        0.484 (68)  1.196 (82)  0.873 (75)
+        by phase, float32 copy, 8, 8       0.527 (63)  1.325 (74)  0.947 (70)
+        by phase, float32 copy, 8, 1       1.078 (31)  2.882 (34)  2.034 (32)
+        row by row, pairs read, 16, 8      0.621 (53)  1.505 (66)  1.121 (59)
+        row by row, pairs read, 8, 8       0.899 (37)  2.375 (42)  1.688 (39)
+        row by row, float32 copy, 8, 8     0.987 (34)  2.626 (38)  1.859 (35)
+        row by row, float32 copy, 8, 1     1.266 (26)  3.449 (29)  2.417 (27)
+
+    "Row by row" scored, soft-maxed and summed one kv row before the next: a
+    chain of MXU and reduction latencies a row that no unrolling hid. "By
+    phase" (kept) scores EVERY kv row, runs one softmax over all query rows
+    and then sums every kv row: with the kv rows of a phase unrolled it
+    hides under its own copies. The float32 copy of a block (64 stores and
+    64 strided loads a page) lost 12 % to the 32-bit view of row pairs, 16
+    pages bought nothing over 8, and a loop over kv rows instead of unrolled
+    copies cost 40-140 %. The other cells' geometries under the form they
+    keep, same script: OLMoE (16 x 1) 0.823 (80) / 2.243 (88), granite (4
+    rows x 8 after the fold) 0.343 (48) / 0.805 (61), olmo-hybrid (32 x 1)
+    1.793 (73) / 5.162 (76)."""
+    plan = _blockdiag_plan(bs, hkv, g, d, kv_dtype, mb)
+    kv_dtype = jnp.dtype(kv_dtype)
+    if (plan.fold > 1 or d != 128 or hkv % PAGED_ROW_TILE
+            or g % PAGED_ROW_TILE
+            or not (kv_dtype == jnp.bfloat16 or kv_dtype.itemsize == 4)):
+        return plan
+    pages = min(PAGED_KV_VMEM_BYTES // (4 * bs * hkv * d * kv_dtype.itemsize),
+                PAGED_SCORE_TILE_ELEMENTS // (hkv * g * bs),
+                PAGED_BLOCK_PAGES, mb)
+    return plan._replace(pages=max(1, pages), form=PAGED_ROWS_FORM)
+
+
+def _paged_rows_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, *rest,
+                       scale: float, bs: int, mb: int, pages: int,
+                       soft_cap: Optional[float], has_sink: bool,
+                       kv_scale: Optional[float], selected: bool):
+    """:func:`_paged_kernel`'s walk - one grid step a ROW, its live pages
+    copied by hand ``pages`` a block into one of two slots, the table and
+    lengths in SMEM, dead entries never read, the active token joined in
+    registers - with the block scored kv row by kv row, by phase.
+
+    A slot is ``(pages x bs x hkv, d)`` as the pages lie: row ``c`` is token
+    ``c // hkv`` of kv row ``c % hkv``. Kv row ``r``'s tokens are every
+    ``hkv``-th row from ``r``, and Mosaic reads rows at a stride from 32-bit
+    data only: a 32-bit pool is read so; of a bf16 pool the slot's 32-bit
+    VIEW holds kv rows ``2j`` (low halves) and ``2j + 1`` of a token in ONE
+    row, read every ``hkv / 2``-th row and parted by a shift and a mask.
+    Query rows, running max / sum and the accumulator are ``(hkv, g, ..)``:
+    a kv row is a LEADING index.
+
+    A block goes by phase: every kv row's scores into ``s_ref``, ONE
+    softmax over all query rows, every kv row's ``p . V`` - no kv row waits
+    on its own softmax, and with a tile of kv rows unrolled a loop turn the
+    MXU's passes follow one another (the clock: :func:`paged_block_plan`).
+    The pages of a block are copied in a loop, not in unrolled copies.
+
+    The mathematics is the block-diagonal body's: scores from bf16 operands
+    accumulated in float32 (:func:`_split_dot`; a float32 pool the
+    ``HIGHEST`` matmul), ``p`` float32 into ``p . V`` as three bf16 pieces,
+    a page past the row's end computed on zeros and masked. ``selected``:
+    ``sel_ref`` (1, blocks, tokens of a block) float32, > 0 where the row's
+    query attends the token."""
+    if selected:
+        sel_ref, *rest = rest
+    k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, s_ref = rest
+    b = pl.program_id(0)
+    nb = pl.num_programs(0)
+    layer = sc_ref[0]
+    w = sc_ref[1]
+    pos = sc_ref[2 + b]
+    last_live = jax.lax.div(jnp.maximum(pos - 1, 0), bs)
+    first_live = jnp.where(w > 0, jax.lax.div(jnp.maximum(pos - w, 0), bs), 0)
+    n_pages = jnp.where(pos > 0, last_live - first_live + 1, 0)
+    hkv, g, d = acc_ref.shape
+    page_rows, toks = bs * hkv, pages * bs
+    n_blocks = jax.lax.div(n_pages + pages - 1, pages)
+    table0 = 2 + nb + b * mb
+    exact = _bf16_exact(kbuf.dtype)
+    s_scale = scale * kv_scale if kv_scale is not None else scale
+
+    def each_page(i, slot, live_do, dead_do=None):
+        def page(p, carry):
+            j = first_live + i * pages + p
+            at = sc_ref[table0 + jnp.minimum(j, last_live)]
+            into = pl.ds(pl.multiple_of(p * page_rows, page_rows), page_rows)
+
+            @pl.when(j <= last_live)
+            def _live():
+                live_do(pltpu.make_async_copy(
+                    k_hbm.at[layer, at], kbuf.at[slot, into], sem.at[0, slot]))
+                live_do(pltpu.make_async_copy(
+                    v_hbm.at[layer, at], vbuf.at[slot, into], sem.at[1, slot]))
+
+            if dead_do is not None:
+                pl.when(j > last_live)(lambda: dead_do(into))
+            return carry
+        jax.lax.fori_loop(0, pages, page, 0)
+
+    def start(i, slot):
+        def blank(into):
+            # a slot's page past the row's end is computed on (masked): its
+            # V must be finite, whatever the slot held before
+            vbuf[slot, into, :] = jnp.zeros((page_rows, d), vbuf.dtype)
+        each_page(i, slot, lambda copy: copy.start(), blank)
+
+    def wait(i, slot):
+        each_page(i, slot, lambda copy: copy.wait())
+
+    if kbuf.dtype == jnp.bfloat16:
+        turns, held = hkv // 2, PAGED_ROW_TILE // 2
+
+        def rows_of(buf, slot, j):
+            """(kv row, its (toks, d) rows of a slot) for rows 2j, 2j + 1."""
+            x = buf.bitcast(jnp.uint32)[slot, pl.ds(j, toks, stride=hkv // 2),
+                                        :]
+            return [(2 * j + half, jax.lax.bitcast_convert_type(
+                x << 16 if half == 0 else x & jnp.uint32(0xFFFF0000),
+                jnp.float32)) for half in range(2)]
+    else:
+        turns, held = hkv, PAGED_ROW_TILE
+
+        def rows_of(buf, slot, r):
+            return [(r, buf.at[slot][pl.ds(r, toks, stride=hkv), :])]
+
+    def each_kv_row(buf, slot, do):
+        """``do(r, rows)`` for every kv row: a loop, a tile of rows a turn."""
+        def turn(t, carry):
+            for u in range(held):
+                for r, rows in rows_of(buf, slot, t * held + u):
+                    do(r, rows)
+            return carry
+        jax.lax.fori_loop(0, turns // held, turn, 0)
+
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    tok = jax.lax.broadcasted_iota(jnp.int32, (1, toks), 1)
+
+    def block(i, carry):
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_blocks)
+        def _next():
+            start(i + 1, 1 - slot)
+
+        kpos = (first_live + i * pages) * bs + tok
+        valid = jnp.logical_and(
+            kpos < pos, jnp.logical_or(w == 0, pos - kpos < w))
+        if selected:
+            # no window with a selection: block i starts at page i x pages
+            valid = jnp.logical_and(valid, sel_ref[0, pl.ds(i, 1), :] > 0)
+        wait(i, slot)
+
+        def scores(r, k):
+            s_ref[r] = _split_dot(q_ref[0, r], k, _NT, exact)
+        each_kv_row(kbuf, slot, scores)
+        s = s_ref[...] * s_scale
+        if soft_cap is not None:
+            s = soft_cap * jnp.tanh(s / soft_cap)
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        s_ref[...] = jnp.exp(s - m_cur)     # p: fp32, never rounded to bf16
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(s_ref[...], axis=-1,
+                                                  keepdims=True)
+        m_ref[...] = m_cur
+        acc_ref[...] = acc_ref[...] * alpha
+
+        def sums(r, v):
+            acc_ref[r] += _split_dot(s_ref[r], v, _NN, exact)
+        each_kv_row(vbuf, slot, sums)
+        return carry
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        start(0, 0)
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+    # the active token joins in registers: its score the softmax, its V the
+    # accumulator (the pools' V is stored / kv_scale, the active V is not)
+    m_prev, l_prev, acc = m_ref[...], l_ref[...], acc_ref[...]
+    s = jnp.sum(q_ref[0].astype(jnp.float32) * nk_ref[0].astype(jnp.float32),
+                axis=-1, keepdims=True) * scale
+    if soft_cap is not None:
+        s = soft_cap * jnp.tanh(s / soft_cap)
+    if selected:
+        s = jnp.where(sc_ref[2 + nb + nb * mb + b] > 0, s, NEG_INF)
+    m_cur = jnp.maximum(m_prev, s)
+    if has_sink:
+        # learned per-head sink joins the denominator only
+        sk = sink_ref[...]
+        m_cur = jnp.maximum(m_cur, sk)
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.exp(s - m_cur)
+    l_new = l_prev * alpha + p
+    if has_sink:
+        l_new = l_new + jnp.exp(sk - m_cur)
+    if kv_scale is not None:
+        acc = acc * kv_scale
+    o_ref[0] = ((acc * alpha + p * nv_ref[0].astype(jnp.float32))
+                / l_new).astype(o_ref.dtype)
+
+
+def _paged_rows_call(scalars, q, new_k, new_v, sink_in, select, k_pages,
+                     v_pages, *, pages: int, hkv: int, mb: int, scale: float,
+                     soft_cap, has_sink: bool, kv_scale, interpret: bool):
+    """:func:`paged_decode_attention`'s call where the plan's form is
+    :data:`PAGED_ROWS_FORM`: the same operands (scalars staged, the active K
+    / V a row a query head, the pools as ``(L, N, bs x hkv, d)``), the query
+    rows grouped by kv row, still ONE Pallas call a layer under the jitted
+    function's name."""
+    b, hq, d = q.shape
+    page_rows = k_pages.shape[2]
+    bs, g = page_rows // hkv, hq // hkv
+    by_row = pl.BlockSpec((1, hkv, g, d), lambda bi, sc: (bi, 0, 0, 0))
+    sel_in, sel_spec = [], []
+    if select is not None:
+        # whole blocks of tokens, as the kernel counts them
+        n_blk = -(-mb // pages)
+        sel_in = [jnp.pad(select, ((0, 0), (0, n_blk * pages * bs - mb * bs))
+                          ).astype(jnp.float32).reshape(b, n_blk, pages * bs)]
+        sel_spec = [pl.BlockSpec((1, n_blk, pages * bs),
+                                 lambda bi, sc: (bi, 0, 0))]
+    slot = (2, pages * page_rows, d)
+    kernel = functools.partial(
+        _paged_rows_kernel, scale=scale, bs=bs, mb=mb, pages=pages,
+        soft_cap=soft_cap, has_sink=has_sink, kv_scale=kv_scale,
+        selected=select is not None)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b,),
+            in_specs=[
+                by_row, by_row, by_row,
+                pl.BlockSpec((hkv, g, 1), lambda bi, sc: (0, 0, 0)),
+                *sel_spec,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=by_row,
+            scratch_shapes=[
+                pltpu.VMEM(slot, k_pages.dtype),
+                pltpu.VMEM(slot, v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hkv, g, 1), jnp.float32),
+                pltpu.VMEM((hkv, g, 1), jnp.float32),
+                pltpu.VMEM((hkv, g, d), jnp.float32),
+                pltpu.VMEM((hkv, g, pages * bs), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(scalars, *(x.reshape(b, hkv, g, d) for x in (q, new_k, new_v)),
+      sink_in.reshape(hkv, g, 1), *sel_in, k_pages, v_pages)
+    return out.reshape(b, hq, d)

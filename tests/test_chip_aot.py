@@ -1290,25 +1290,34 @@ def test_command_a_plus_decodes_on_three_kernels_in_place(v5e_devices):
     held experts of 4096 x 4096, four averaged shared experts as one MLP of
     16384) holds the paged decode kernel four times - one call over the
     allocator's table, three over the ring's logical table with the window -
-    at ONE page a compute block (``pages=1``: 8 kv rows x 16 query heads,
-    the geometry ROADMAP A queues), and the walk over the touched experts
-    in column pieces; nothing copies, transposes or relays either pool or
-    the expert stacks."""
+    scored kv row by kv row, eight pages a compute block (ISSUE 57: 8 kv
+    rows x 16 query heads, ``form=mxu-kv-rows``; ONE page of the
+    block-diagonal form before), and the walk over the touched experts in
+    column pieces; nothing copies, transposes, relays or converts either
+    pool or the expert stacks."""
     step, notes, text, moved = _command_a_plus_program(v5e_devices, 32, 1)
     assert ("moe_decode", "pallas", "pieces=8 of 512") in notes
     assert ("moe_share", "xla", "held=16 of 128 from 0 top_k=8 "
             "shared=4 x 4096 mean") in notes
     assert {(s, p, w) for s, p, w in notes if s == "paged_decode"} == {
         ("paged_decode", "pallas",
-         "pages=1 heads=8 form=mxu-blockdiag fold=1 window=0"),
+         "pages=8 heads=8 form=mxu-kv-rows fold=1 window=0"),
         ("paged_decode", "pallas",
-         "pages=1 heads=8 form=mxu-blockdiag fold=1 window=4096 ring=137")}
+         "pages=8 heads=8 form=mxu-kv-rows fold=1 window=4096 ring=137")}
     assert ("kv_pool", "xla", "page=8x128 heads=8x128") in notes
     assert any(s == "kv_window_pool" and "global=1 window=3" in w
                and "window_tokens=4096 ring_pages=137" in w
                for s, _, w in notes)
     assert len(re.findall(r"%paged_decode_attention[.\d]* = ", text)) == 4
     assert "%moe_decode_experts" in text and not moved, moved
+    # ... and no pool-sized array of another type stands beside a pool (a
+    # float32 copy in front of the kernel would be 800 MB a layer)
+    sizes = {layers * pages * 32 * 8 * 128
+             for layers, pages in ((1, 12289), (3, 4384), (1, 4384))}
+    wide = [(name, shape) for name, shape in re.findall(
+        r"%(\S+) = (?:f32|u32|s32|f16)\[([\d,]+)\]", text)
+        if math.prod(map(int, shape.split(","))) in sizes]
+    assert not wide, wide
     stacks = [(name, shape, op) for name, shape, op in re.findall(
         r"%(\S+) = bf16\[([\d,]+)\]\S* (\w[\w-]*)\(", text)
         if shape in ("4,16,4096,4096", "16,4096,4096", "4,4096,16384",

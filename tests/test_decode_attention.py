@@ -350,18 +350,25 @@ def test_paged_decode_kernel_matches_gather_path(rng, hq, hkv):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_paged_decode_kernel_window_and_sink(rng):
-    b, hq, hkv, d, bs, mb = 2, 4, 2, 64, 32, 8
-    lens = np.array([200, 90], np.int32)
+@pytest.mark.parametrize("hq,hkv,d,lens,soft_cap", [
+    (4, 2, 64, (200, 90), None),
+    # ISSUE 57: 8 kv rows under 16 query heads each, scored kv row by kv
+    # row: a sink a head and a soft cap, the window inside the second block
+    (128, 8, 128, (400, 90), 30.0)],
+    ids=["two-heads-of-64", "command-a-plus-soft-cap"])
+def test_paged_decode_kernel_window_and_sink(rng, hq, hkv, d, lens, soft_cap):
+    b, bs, mb = 2, 32, 16
+    lens = np.array(lens, np.int32)
     q, kp, vp, nk, nv, table = _paged_setup(rng, b, hq, hkv, d, bs, mb, lens)
     scale = d ** -0.5
     sink = _rand(rng, hq)
     got = da.paged_decode_attention(
         q, kp, vp, nk, nv, jnp.asarray(0, jnp.int32),
         jnp.asarray(lens, jnp.int32), jnp.asarray(table), scale=scale,
-        window=jnp.asarray(64, jnp.int32), sink=sink, interpret=True)
+        window=jnp.asarray(64, jnp.int32), sink=sink, soft_cap=soft_cap,
+        interpret=True)
     want = _paged_reference(q, kp, vp, nk, nv, lens, table, scale,
-                            window=64, sink=sink)
+                            window=64, sink=sink, soft_cap=soft_cap)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -416,7 +423,8 @@ def test_paged_decode_kernel_stacked_layers(rng):
 # heads of a token share ONE row of 512 lanes (paged_pool_fold: few heads
 # of whole vregs), sixteen pages a compute block.
 _CELLS = {"olmoe": (16, 16, 128), "granite": (32, 8, 64),
-          "olmo-hybrid": (32, 32, 128), "qwen3-next": (16, 2, 256)}
+          "olmo-hybrid": (32, 32, 128), "qwen3-next": (16, 2, 256),
+          "command-a-plus": (128, 8, 128)}
 _BS = 32
 
 
@@ -453,8 +461,13 @@ def test_paged_decode_walks_live_pages(rng, cell, case):
     plan = _cell_plan(cell, dtype, mb)
     assert plan.fold == {"granite": 2, "qwen3-next": 2}.get(cell, 1)
     assert plan.pages == 1 if cell == "olmo-hybrid" else plan.pages >= 2
-    assert plan.d == {"olmoe": 128, "granite": 128, "olmo-hybrid": 128,
-                      "qwen3-next": 512}[cell]
+    assert plan.d == {"qwen3-next": 512}.get(cell, 128)
+    # ISSUE 57: 8 kv rows a token under 16 query heads each are scored kv
+    # row by kv row, eight pages or more a block; every other cell keeps the
+    # block-diagonal form
+    rows_form = cell == "command-a-plus"
+    assert plan.form == ("mxu-kv-rows" if rows_form else "mxu-blockdiag")
+    assert plan.pages >= 8 or not rows_form
     lens, window = _walk_case(case, plan.pages, mb)
     b, scale = len(lens), d ** -0.5
     # physical pages shuffled and non-contiguous (_paged_setup), block 0 null
@@ -496,6 +509,72 @@ def test_paged_decode_walks_live_pages(rng, cell, case):
                                rtol=tol, atol=tol)
 
 
+# ISSUE 57: what the plan gives each benchmark cell's attention geometry
+# (query heads, a shard's kv heads, head_dim, the table's width), letter for
+# letter as its ``precompile widths`` line prints it: only 8 kv rows a token
+# under 16 query heads each take the kv-row form.
+@pytest.mark.parametrize("cell,hq,hkv,d,mb,plan,note", [
+    ("olmoe-chat-steady", 16, 16, 128, 64, (4, 1, 16, 1, 128),
+     "pages=4 heads=16 form=mxu-blockdiag fold=1"),
+    ("olmoe-longprompt-closed", 16, 16, 128, 128, (4, 1, 16, 1, 128),
+     "pages=4 heads=16 form=mxu-blockdiag fold=1"),
+    ("granite-h-chat-closed", 32, 8, 64, 64, (8, 2, 4, 8, 128),
+     "pages=8 heads=8 form=mxu-blockdiag fold=2 stored"),
+    ("olmo-hybrid-reason-closed", 32, 32, 128, 512, (1, 1, 32, 1, 128),
+     "pages=1 heads=32 form=mxu-blockdiag fold=1"),
+    ("qwen3-next-rag-closed", 16, 2, 256, 256, (16, 2, 1, 16, 512),
+     "pages=16 heads=2 form=mxu-blockdiag fold=2 stored"),
+    ("smallthinker-mixedlen-closed", 28, 4, 128, 480, (16, 4, 1, 28, 512),
+     "pages=16 heads=4 form=mxu-blockdiag fold=4 stored"),
+    ("keye-vl2-videoqa-closed", 32, 4, 128, 384, (16, 4, 1, 32, 512),
+     "pages=16 heads=4 form=mxu-blockdiag fold=4 stored"),
+    ("phi4-flash-reason-closed", 40, 10, 128, 512, (12, 10, 1, 40, 1280),
+     "pages=12 heads=10 form=mxu-blockdiag fold=10 stored"),
+    ("command-a-plus-agent-closed", 128, 8, 128, 384, (8, 1, 8, 16, 128),
+     "pages=8 heads=8 form=mxu-kv-rows fold=1"),
+    # no cell's: 8 kv heads of 256 lanes under 8 query heads each keep the
+    # block-diagonal form (Mosaic refuses the 32-bit view of a 256-lane slot)
+    ("heads-of-256-lanes", 64, 8, 256, 384, (2, 1, 8, 8, 256),
+     "pages=2 heads=8 form=mxu-blockdiag fold=1")])
+def test_the_plan_of_every_cells_geometry(cell, hq, hkv, d, mb, plan, note):
+    got = da.paged_block_plan(_BS, hkv, hq // hkv, d, jnp.bfloat16, mb)
+    assert tuple(got[:5]) == plan
+    assert got.note(stored=True) == note
+    assert (got.form == da.PAGED_ROWS_FORM) == (
+        got.fold == 1 and got.d == 128 and got.hkv % da.PAGED_ROW_TILE == 0
+        and got.g % da.PAGED_ROW_TILE == 0)
+
+
+def test_paged_decode_kv_rows_take_a_selection(rng):
+    """ISSUE 57: the kv-row form takes a learned sparse selection as the
+    block-diagonal form does - a column attended where live AND selected,
+    the active token where its own flag is set."""
+    hq, hkv, d, mb = 128, 8, 128, 20
+    lens = np.array([333, 75], np.int32)
+    b, scale = len(lens), d ** -0.5
+    assert da.paged_block_plan(_BS, hkv, hq // hkv, d, jnp.float32,
+                               mb).form == da.PAGED_ROWS_FORM
+    q, kp, vp, nk, nv, table = _paged_setup(rng, b, hq, hkv, d, _BS, mb, lens)
+    select = rng.random((b, mb * _BS)) < 0.3
+    select[0, lens[0]] = True          # row 0 attends its own token, row 1
+    select[1, lens[1]] = False         # does not
+    select[1, 3] = True
+    got = da.paged_decode_attention(
+        q, kp, vp, nk, nv, jnp.asarray(0, jnp.int32), jnp.asarray(lens),
+        jnp.asarray(table), scale=scale, select=jnp.asarray(select),
+        interpret=True)
+    from neuronx_distributed_inference_tpu.modules import block_kv_cache as bkv
+    k_all = np.array(bkv.gather_block_kv(kp[0], jnp.asarray(table)))
+    v_all = np.array(bkv.gather_block_kv(vp[0], jnp.asarray(table)))
+    k_all[np.arange(b), lens], v_all[np.arange(b), lens] = nk, nv
+    mask = np.arange(mb * _BS)[None] <= lens[:, None]
+    mask = jnp.asarray(mask & select)[:, None, :]
+    want = attn_ops.mha(q[:, None], jnp.asarray(k_all), jnp.asarray(v_all),
+                        mask, scale)[:, 0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # Quantized-KV admission (reference: fp8 KV cache feeding the TKG kernel,
 # kv_cache_manager.py:636-692): the kernel dequantizes on the block load.
@@ -531,20 +610,41 @@ def test_decode_attention_quantized_kv(rng, kv_dtype, kv_scale):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_paged_decode_attention_quantized_kv(rng):
+@pytest.mark.parametrize("hq,hkv,d,bs,kv_dtype,kv_scale", [
+    (4, 2, 64, 64, jnp.float8_e4m3fn, 0.5),
+    # ISSUE 57: 8 kv rows under 16 query heads each; 300 tokens end in the
+    # kv-row form's second block
+    (128, 8, 128, 32, jnp.float8_e4m3fn, None),
+    (128, 8, 128, 32, jnp.float8_e4m3fn, 0.25),
+    (128, 8, 128, 32, jnp.bfloat16, 2.0)],
+    ids=["two-heads-of-64", "command-a-plus-fp8", "command-a-plus-fp8-scaled",
+         "command-a-plus-bf16-scaled"])
+def test_paged_decode_attention_quantized_kv(rng, hq, hkv, d, bs, kv_dtype,
+                                             kv_scale):
     from neuronx_distributed_inference_tpu.modules import kv_cache as kv
-    kv_scale = 0.5
-    b, hq, hkv, d = 2, 4, 2, 64
-    bs, nblocks, mb = 64, 8, 4
-    lens = np.array([70, 130], np.int32)
-    table = jnp.asarray(np.array([[1, 2, 0, 0], [3, 4, 5, 0]], np.int32))
+    b = 2
+    if bs == 64:
+        nblocks, mb = 8, 4
+        lens = np.array([70, 130], np.int32)
+        table = jnp.asarray(np.array([[1, 2, 0, 0], [3, 4, 5, 0]], np.int32))
+    else:
+        nblocks, mb = 16, 12
+        lens = np.array([70, 300], np.int32)
+        table = np.zeros((b, mb), np.int32)
+        table[0, :3], table[1, :10] = [1, 2, 3], np.arange(4, 14)
+        table = jnp.asarray(table)
+        # a kv row is read out of a bf16 or 32-bit slot; an 8-bit pool
+        # keeps the block-diagonal form at this geometry too
+        assert da.paged_block_plan(bs, hkv, hq // hkv, d, kv_dtype, mb).form \
+            == ("mxu-kv-rows" if kv_dtype == jnp.bfloat16
+                else "mxu-blockdiag")
     q = _rand(rng, b, hq, d)
     kp_f = _rand(rng, 1, nblocks, bs, hkv, d)
     vp_f = _rand(rng, 1, nblocks, bs, hkv, d)
     nk = _rand(rng, b, hkv, d)
     nv = _rand(rng, b, hkv, d)
-    kp_q = kv.quantize_kv(kp_f, jnp.float8_e4m3fn, kv_scale)
-    vp_q = kv.quantize_kv(vp_f, jnp.float8_e4m3fn, kv_scale)
+    kp_q = kv.quantize_kv(kp_f, kv_dtype, kv_scale)
+    vp_q = kv.quantize_kv(vp_f, kv_dtype, kv_scale)
     scale = d ** -0.5
     got = da.paged_decode_attention(
         q, kp_q, vp_q, nk, nv, jnp.zeros((), jnp.int32),
@@ -604,3 +704,47 @@ def test_decode_kernel_e2e_fp8_kv(hd64_ckpt):
     # reads it back quantized — tolerance covers that one-token delta
     for a, b in zip(out_k["logits"], out_x["logits"]):
         np.testing.assert_allclose(a, b, atol=5e-2, rtol=5e-2)
+
+
+# ---------------------------------------------------------------------------
+# scripts/paged_decode_time.py: the clock behind the plan's two forms. A time
+# comes from a chip only; here its shapes and its yardstick are held.
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def paged_decode_time():
+    import importlib.util
+    from pathlib import Path
+    spec = importlib.util.spec_from_file_location(
+        "paged_decode_time", Path(__file__).resolve().parent.parent
+        / "scripts" / "paged_decode_time.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("cell", ["command-a-plus", "olmoe", "granite",
+                                  "olmo-hybrid"])
+def test_the_scripts_cells_and_bytes_are_the_benchmarks(paged_decode_time,
+                                                        cell):
+    import sys
+    from pathlib import Path
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent
+                           / "benchmark"))
+    from harness.kernel_bytes import paged_decode_min_bytes
+    hq, hkv, d = paged_decode_time.CELLS[cell]
+    assert (hq, hkv, d) == _CELLS[cell]
+    cfg = dict(num_attention_heads=hq, num_key_value_heads=hkv, head_dim=d,
+               tp=1, dtype="bfloat16")
+    for tokens, window, seen in [(2048, 0, 2048), (6144, 4096, 4096),
+                                 (2048, 4096, 2048)]:
+        assert paged_decode_time.min_bytes(cell, tokens, window) == \
+            paged_decode_min_bytes(cfg, 32 * seen, 32)
+    lens = paged_decode_time.row_lengths(6144)
+    assert len(lens) == 32 and lens.min() == 4608 and lens.max() == 7680
+
+
+def test_the_script_prints_no_time_without_a_chip(paged_decode_time, capsys):
+    assert paged_decode_time.main([]) == 2
+    out, err = capsys.readouterr()
+    assert out == "" and "no TPU" in err
