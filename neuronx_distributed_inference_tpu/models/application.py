@@ -8,10 +8,12 @@ TPU redesign of the three reference classes into one:
     the persistent XLA compilation cache (utils/compile_cache.py)
     replaces the NEFF artifact dir.
   * load()     -> checkpoint load + convert + device_put with shardings.
-  * generate() -> host loop; the decode hot path runs ``decode_chunk_tokens``
-    steps per device call via lax.scan (see model_base.decode_loop), which is
-    the TPU replacement for async double-buffering
-    (reference: modules/async_execution.py).
+  * generate() -> host loop; with ``decode_chunk_tokens`` > 1 a device call
+    runs that many decode steps in one ``lax.scan`` (model_base.decode_loop:
+    the step graph scanned, the cache its carry), which is the TPU
+    replacement for async double-buffering
+    (reference: modules/async_execution.py). The serving path's fused loop
+    is ``paged_decode_loop``.
 KV cache buffers are donated every call (reference I/O aliasing,
 model_wrapper.py:1578-1627).
 """

@@ -188,7 +188,6 @@ def idefics_forward(spec: DecoderSpec, interval: int, tcfg: TpuConfig,
                              position_ids, cache_len_of(cache), window=w,
                              chunk=c))
     hidden = _embed(spec, params, input_ids)
-    kf, vf = cache["k"], cache["v"]
     L = spec.num_layers
     si = 0
     for start in range(0, L, interval):
@@ -198,13 +197,13 @@ def idefics_forward(spec: DecoderSpec, interval: int, tcfg: TpuConfig,
                               cross_kv["v"][ci], img_mask)
         n_self = min(interval, L - start)
         seg = jax.tree.map(lambda a: a[si:si + n_self], params["layers"])
-        hidden, kf, vf, _ = run_layer_slice(
-            spec, seg, kf, vf, hidden, ai, cache_offset=si,
+        hidden, cache, _ = run_layer_slice(
+            spec, seg, cache, hidden, ai, cache_offset=si,
             is_local=jnp.zeros((n_self,), bool), rep={}, mlp_kind=None,
             seq_ids=seq_ids, positions=position_ids, phase=phase,
             identity_seq_ids=True, arange_positions=(phase == "prefill"))
         si += n_self
-    out: Dict[str, Any] = {"cache": {"k": kf, "v": vf}}
+    out: Dict[str, Any] = {"cache": cache}
     if phase == "prefill":
         idx = jnp.maximum(seq_lens - 1, 0)
         last_h = jnp.take_along_axis(

@@ -1,7 +1,8 @@
 """LongCat-Flash family, ``model_type`` ``longcat_flash`` (meituan-longcat/
 LongCat-Flash-Chat and the language model of LongCat-Flash-Omni; HF
 ``modeling_longcat_flash.py``). A layer is NOT "attention then MLP"
-(``DecoderSpec.sub_blocks`` = 2, ``model_base.run_layers_shortcut``): with
+(``DecoderSpec.sub_blocks`` = 2; ``model_base.run_layers_shortcut``, its
+block on the walks' one scan, ``scan_layers``): with
 ``N`` an RMSNorm and ``x`` the layer's input,
 
     a0 = x + MLA_0(N(x));    u = N(a0);    s = MoE(u)

@@ -231,14 +231,13 @@ def mllama_forward(spec: DecoderSpec, mspec: MllamaSpec, tcfg: TpuConfig,
                          lambda w, c=0: attn_ops.decode_mask(position_ids,
                                                         cache_len, window=w, chunk=c))
     hidden = _embed(spec, params, input_ids)
-    kf, vf = cache["k"], cache["v"]
     si = ci = 0
     empty_local = jnp.zeros((0,), bool)
     for n_self, has_cross in mspec.segments:
         if n_self:
             seg = jax.tree.map(lambda a: a[si:si + n_self], params["layers"])
-            hidden, kf, vf, _ = run_layer_slice(
-                spec, seg, kf, vf, hidden, ai, cache_offset=si,
+            hidden, cache, _ = run_layer_slice(
+                spec, seg, cache, hidden, ai, cache_offset=si,
                 is_local=jnp.zeros((n_self,), bool), rep={}, mlp_kind=None,
                 seq_ids=seq_ids, positions=position_ids, phase=phase,
                 identity_seq_ids=not tcfg.is_continuous_batching,
@@ -249,7 +248,7 @@ def mllama_forward(spec: DecoderSpec, mspec: MllamaSpec, tcfg: TpuConfig,
             hidden = _cross_block(spec, hidden, lw, cross_kv["k"][ci],
                                   cross_kv["v"][ci], cross_mask)
             ci += 1
-    out: Dict[str, Any] = {"cache": {"k": kf, "v": vf}}
+    out: Dict[str, Any] = {"cache": cache}
     if phase == "prefill":
         idx = jnp.maximum(seq_lens - 1, 0)
         last_h = jnp.take_along_axis(hidden, idx[:, None, None].astype(jnp.int32),

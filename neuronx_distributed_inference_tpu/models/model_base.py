@@ -33,8 +33,6 @@ from dataclasses import dataclass, field, replace
 from functools import lru_cache, partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -130,7 +128,7 @@ class SparseSpec:
     every head of a token the same set. The index keys live in a paged pool
     of their own on the K / V pools' block table
     (``modules/block_kv_cache.index_page``), ``cache["k_idx"]``; the walk is
-    :func:`run_layers_sparse`, the indexer :func:`_indexer_block` under the
+    :func:`run_layer_slice`, the indexer :func:`_indexer_block` under the
     profiler scope ``indexer``. ``rope``: the rotary of the index heads (all
     ``index_dim`` lanes, halves convention).
 
@@ -1201,7 +1199,7 @@ def _layer_body(spec: DecoderSpec, hidden, layer_w, k_full, v_full, li,
                 mlp_kind: Optional[str] = None,
                 adapter_ids=None, replace=None, kv_view: int = None,
                 deepstack=None, deepstack_mask=None, prefill_lens=None,
-                side=None, mixed_local=None, live=None):
+                mixed_local=None, tally=None, live=None, select_of=None):
     """One transformer layer. hidden (B,T,H); k/v_full: the FULL stacked
     cache (L,B,S,Hkv,D) — or, in the paged layout, (L,N_blocks,Bs,Hkv,D)
     with ``slot_mapping``/``block_table`` set (phase "paged", reference:
@@ -1224,9 +1222,10 @@ def _layer_body(spec: DecoderSpec, hidden, layer_w, k_full, v_full, li,
       the gathered view — covers paged prefill, prefix-cached continuation,
       chunked prefill and paged decode with one body.
 
-    ``live`` (B,T) bool, a paged decode step over expert layers only: the
-    rows that carry a sequence; the layer then counts its routing
-    (``moe.share_tally``) into the per-layer outputs as ``moe_tally``.
+    ``tally`` / ``live``: ``moe_block``'s, as :func:`scan_layers` hands them
+    to a paged decode step over expert layers. ``select_of(h)``, a stack
+    with a learned sparse selection: called on the normed block input before
+    the attention, it returns the attention's ``select``.
     """
     if mlp_kind is None:
         mlp_kind = "dense" if spec.moe is None else "moe"
@@ -1246,12 +1245,13 @@ def _layer_body(spec: DecoderSpec, hidden, layer_w, k_full, v_full, li,
                layer_w.get("input_norm_b") if spec.norm_bias else None)
          if spec.norm_position == "pre" else hidden)
     attn_in = h        # parallel blocks feed the MLP from the same norm
-    h, k_full, v_full, pending = _attn_block(
+    h, k_full, v_full, _ = _attn_block(
         spec, h, layer_w, k_full, v_full, li, ai, is_local, seq_ids,
         positions, phase, identity_seq_ids=identity_seq_ids,
         arange_positions=arange_positions, slot_mapping=slot_mapping,
         block_table=block_table, adapter_ids=adapter_ids, kv_view=kv_view,
-        prefill_lens=prefill_lens, side=side, mixed_local=mixed_local)
+        prefill_lens=prefill_lens, mixed_local=mixed_local,
+        select=None if select_of is None else select_of(h))
     if spec.sandwich_norm:
         h = rms_norm(h, layer_w["post_attn_norm"], spec.rms_eps,
                      spec.norm_offset)
@@ -1261,15 +1261,11 @@ def _layer_body(spec: DecoderSpec, hidden, layer_w, k_full, v_full, li,
     sp_axis = AXIS_CP if (spec.seq_parallel and phase == "prefill") else None
 
     def _mlp(x_in):
-        tally = [] if live is not None and mlp_kind == "moe" else None
-        out = _mlp_block(
+        return _mlp_block(
             spec, x_in, layer_w, mlp_kind, adapter_ids, phase=phase,
             tally=tally, live=live,
             router_x=(attn_in if mlp_kind == "moe"
                       and spec.moe.router_pre_attn else None))
-        if tally:
-            caps["moe_tally"] = tally[0]
-        return out
 
     if spec.block_style != "sequential":
         # parallel residual: x + attn(norm(x)) + mlp(norm'(x)) (falcon
@@ -1283,8 +1279,6 @@ def _layer_body(spec: DecoderSpec, hidden, layer_w, k_full, v_full, li,
             h + m, AXIS_DP, sp_axis, None)
         hidden = _deepstack_add(hidden, deepstack, deepstack_mask)
         hidden = _tap("layer_output", hidden)
-        if side is not None:
-            return hidden, k_full, v_full, caps, pending
         return hidden, k_full, v_full, caps
 
     if spec.norm_position == "post_residual":
@@ -1298,8 +1292,6 @@ def _layer_body(spec: DecoderSpec, hidden, layer_w, k_full, v_full, li,
                        layer_w["post_norm"],
                        layer_w.get("post_norm_b") if spec.norm_bias else None)
         hidden = _tap("layer_output", hidden)
-        if side is not None:
-            return hidden, k_full, v_full, caps, pending
         return hidden, k_full, v_full, caps
 
     hidden = hidden + spec.residual_multiplier * _shard(h, AXIS_DP, sp_axis, None)
@@ -1315,8 +1307,6 @@ def _layer_body(spec: DecoderSpec, hidden, layer_w, k_full, v_full, li,
     hidden = hidden + spec.residual_multiplier * _shard(h, AXIS_DP, sp_axis, None)
     hidden = _deepstack_add(hidden, deepstack, deepstack_mask)
     hidden = _tap("layer_output", hidden)
-    if side is not None:
-        return hidden, k_full, v_full, caps, pending
     return hidden, k_full, v_full, caps
 
 
@@ -1492,7 +1482,7 @@ def _attn_body(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                is_local, seq_ids, positions, phase: str, *,
                identity_seq_ids=False, arange_positions=False,
                slot_mapping=None, block_table=None, adapter_ids=None,
-               kv_view=None, prefill_lens=None, side=None,
+               kv_view=None, prefill_lens=None,
                mixed_local=None, select=None, depth=None, hand_kv=False,
                cross_kv=None):
     """The attention half of a layer: q/k/v projections, cache write, the
@@ -1514,11 +1504,10 @@ def _attn_body(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
     ``v_full`` at ``li`` are: this layer projects a query only
     (``q_proj``), writes nothing and attends over that layer's cache (a
     "cross" layer of ``DecoderSpec.layer_kinds``). ``hand_kv``: hand this
-    step's projected ``(k, v)`` back as ``pending`` for such layers.
+    step's projected ``(k, v)`` back for such layers.
 
-    Returns (attn_h, k_full, v_full, pending): attn_h the post-o_proj
-    hidden delta, pending the chunked-decode side-buffer pair (None unless
-    ``side`` is set) or ``hand_kv``'s pair."""
+    Returns (attn_h, k_full, v_full, handed): attn_h the post-o_proj
+    hidden delta, handed ``hand_kv``'s pair or None."""
     g = spec.gqa
     dtype = h.dtype
     off = spec.norm_offset
@@ -1550,7 +1539,7 @@ def _attn_body(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
             return None
         return (layer_w["alibi_slopes"],
                 jnp.arange(n_kv, dtype=jnp.int32)[None, :])
-    pending = None
+    handed = None
     latent = spec.mla is not None and phase == "paged"
     if latent:
         # the latent pool: k_full holds a row a token, v_full nothing
@@ -1573,7 +1562,8 @@ def _attn_body(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
             if spec.attn_out_gate:
                 cuts.append(cuts[-1] + spec.kv_size)  # [q | k | v | gate]
             q, k, v, *out_gate = jnp.split(qkv, cuts, axis=-1)
-        handed = (k, v) if hand_kv else None
+        if hand_kv:
+            handed = (k, v)
         if spec.diff_attn:
             q = _diff_place(q, g.num_q_heads, spec.head_dim).reshape(
                 q.shape[:2] + (spec.q_size,))
@@ -1832,25 +1822,15 @@ def _attn_body(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                 li, seq_ids,
                 identity_seq_ids=identity_seq_ids and arange_positions)
     else:
-        pending = None
-        if side is not None:
-            # chunked decode (ops/attention.mha_decode_merged): the step's
-            # K/V are handed back as PENDING — run_layer_slice batches all
-            # layers' side-buffer writes into one update pair per step; the
-            # BIG cache is read-only inside the decode scan and committed
-            # once per chunk
-            pending = (k, v)
-        else:
-            roll_w = (k_full.shape[4]
-                      if (spec.rolling_window or mixed_local) else 0)
-            k_full = kv.write_tokens_at_layer(
-                k_full, kv.quantize_kv(k, k_full.dtype, spec.kv_scale),
-                li, seq_ids, positions, window=roll_w, k_transposed=True)
-            v_full = kv.write_tokens_at_layer(
-                v_full, kv.quantize_kv(v, v_full.dtype, spec.kv_scale),
-                li, seq_ids, positions, window=roll_w)
-        use_kernel = (side is None
-                      and not mixed_local
+        roll_w = (k_full.shape[4]
+                  if (spec.rolling_window or mixed_local) else 0)
+        k_full = kv.write_tokens_at_layer(
+            k_full, kv.quantize_kv(k, k_full.dtype, spec.kv_scale),
+            li, seq_ids, positions, window=roll_w, k_transposed=True)
+        v_full = kv.write_tokens_at_layer(
+            v_full, kv.quantize_kv(v, v_full.dtype, spec.kv_scale),
+            li, seq_ids, positions, window=roll_w)
+        use_kernel = (not mixed_local
                       and not spec.alibi
                       and spec.decode_kernel is not False
                       and decode_attention.supports(spec, h.shape[1])
@@ -1932,21 +1912,10 @@ def _attn_body(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
                 v_all = kv.dequantize_kv(
                     kv.gather_cache_rows(v_layer, seq_ids), dtype,
                     spec.kv_scale)
-            if side is not None:
-                # 'mask' here is the PRIOR mask (chunk slots excluded by the
-                # chunk loop); earlier chunk tokens enter through the side
-                # buffer with their own mask, the active token in-register
-                mask_side = ai["mask_side"]
-                attn_out = attn_ops.mha_decode_merged(
-                    q, k_all, v_all, mask, side[0][li], side[1][li],
-                    mask_side, k.astype(dtype), v.astype(dtype), spec.scale,
-                    logits_soft_cap=spec.attn_soft_cap, sink=sink)
-            else:
-                attn_out = attn_ops.mha_hl(q, k_all, v_all, mask, spec.scale,
-                                           logits_soft_cap=spec.attn_soft_cap,
-                                           sink=sink,
-                                           alibi=_alibi_for(
-                                               v_all.shape[2]))
+            attn_out = attn_ops.mha_hl(q, k_all, v_all, mask, spec.scale,
+                                       logits_soft_cap=spec.attn_soft_cap,
+                                       sink=sink,
+                                       alibi=_alibi_for(v_all.shape[2]))
 
     if spec.diff_attn:
         attn_out = _diff_combine(attn_out, layer_w, depth)
@@ -1959,7 +1928,7 @@ def _attn_body(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
         h = apply_lora(spec.lora, layer_w, "o_proj", attn_out, h, adapter_ids)
     if spec.o_bias:
         h = h + layer_w["o_bias"]
-    return h, k_full, v_full, handed if hand_kv else pending
+    return h, k_full, v_full, handed
 
 
 #: the attention half of a layer that WRITES a cache, under its profiler
@@ -2006,7 +1975,6 @@ RECURRENT_UNSUPPORTED = {
                                  "from the carried state",
     "tensor capture/replacement": "the recurrent walk has no tap points",
     "deepstack": "the recurrent walk adds no per-layer visual features",
-    "chunked side-buffer decode": "the recurrent walk writes KV in place",
     "sandwich norm": "the recurrent walk is the pre-norm residual block "
                      "or the post-norm one (norm_position 'post': a norm "
                      "on each sub-block's output); norms on BOTH sides of "
@@ -2160,35 +2128,93 @@ def _pattern_period(pattern) -> int:
                 if all(pattern[i] == pattern[i % p] for i in range(n)))
 
 
+def _split_stack(spec: DecoderSpec, stack, tokens: int, experts: bool):
+    """Which leaves of the stacked ``stack`` a layer loop over a step of
+    ``tokens`` tokens slices a layer out of, and which stay where they lie:
+    those ``moe.stack_leaves`` names (a custom call reads the layer in
+    place and would be handed a copy of a slice), none of a stack without
+    ``experts``. Returns (the leaves to slice, ``of_stack(layer_w, i)``:
+    layer ``i``'s slices joined by a ``LayerOfStack`` for each leaf that
+    stayed)."""
+    held = moe_mod.stack_leaves(spec.moe, tokens, stack) if experts else ()
+
+    def of_stack(layer_w, i):
+        return {**layer_w, **{k: moe_mod.LayerOfStack(stack[k], i)
+                              for k in held}}
+
+    return {k: a for k, a in stack.items() if k not in held}, of_stack
+
+
+def scan_layers(spec: DecoderSpec, stack, carry, block, *, steps: int,
+                phase: str, slot_mapping=None, experts: bool = True,
+                per_step: int = 1, xs=()):
+    """The ONE scan of the layer walks: ``steps`` steps of ``block`` over
+    ``carry`` (its first leaf the hidden states, (B, T, H)), ``per_step``
+    layers of the stacked leaves ``stack`` a step (``experts`` False: a run
+    of dense layers). What every scanned walk decides alike is decided here:
+
+    * which leaves stay in their stack (:func:`_split_stack`);
+    * how a layer gets the others, ``layer_w(j)``, the step's ``j``-th
+      layer: with one layer a step its slice is the scan's ``xs`` (XLA
+      fuses it into the consumer); with several each is indexed out of the
+      stack by itself (scanned as ``(steps, per_step, ...)`` the whole
+      step's weights would be materialised first: PERF.md section 6, PR 40);
+    * the rows a routing tally counts, ``live``: ``slot_mapping >= 0`` on a
+      paged T = 1 step over expert layers, else nothing is counted;
+    * the tally's way out: ``moe_kw`` (``tally`` / ``live``, for
+      ``moe_block`` through ``_mlp_block`` or ``_layer_body``) collects it
+      and it joins the step's outputs as ``moe_tally``.
+
+    ``block(carry, layer_w, l, x, moe_kw) -> (carry, outputs)`` with ``l``
+    the step and ``x`` the step's slice of ``xs``. Returns (carry, the
+    outputs stacked a step)."""
+    b, t = jax.tree.leaves(carry)[0].shape[:2]
+    experts = experts and spec.moe is not None
+    sliced, of_stack = _split_stack(spec, stack, b * t, experts)
+    live = (slot_mapping >= 0 if experts and phase == "paged" and t == 1
+            else None)
+
+    def body(carry, step):
+        w_l, x, l = step
+
+        def layer_w(j=0):
+            i = l * per_step + j
+            return of_stack(w_l if per_step == 1 else {
+                k: jax.lax.dynamic_index_in_dim(a, i, keepdims=False)
+                for k, a in sliced.items()}, i)
+
+        tally = None if live is None else []
+        carry, outs = block(carry, layer_w, l, x,
+                            {"tally": tally, "live": live})
+        if tally:
+            outs = {**outs, "moe_tally": sum(tally[1:], tally[0])}
+        return carry, outs
+
+    return jax.lax.scan(
+        body, carry, (sliced if per_step == 1 else None, xs,
+                      jnp.arange(steps, dtype=jnp.int32)))
+
+
 def run_layers_window(spec: DecoderSpec, params, cache, hidden, ai,
                       positions, *, slot_mapping, block_table,
                       adapter_ids=None):
     """The paged walk of a stack with a WINDOW POOL
     (``DecoderSpec.window_pool``), static per layer kind: a scan over the
-    periods of ``layer_pattern`` whose body holds one period's layers (one
-    global and three window layers for SmallThinker), each indexing its own
-    leaves out of the stack (one layer at a time: scanned as ``(periods,
-    period, ...)`` the scan would materialise a whole period's weights,
-    PERF.md section 6, PR 40). A global layer reads and writes ``cache["k"]
-    / ["v"]`` through the allocator's table as every paged stack does, with
-    the global mask and (``nope_global``) no rotary; a window layer its
-    slot's ring in ``cache["k_w"] / ["v_w"]`` by ``ai["ring"]``
+    periods of ``layer_pattern`` (:func:`scan_layers`) whose block holds one
+    period's layers (one global and three window layers for SmallThinker).
+    A global layer reads and writes ``cache["k"] / ["v"]`` through the
+    allocator's table as every paged stack does, with the global mask and
+    (``nope_global``) no rotary; a window layer its slot's ring in
+    ``cache["k_w"] / ["v_w"]`` by ``ai["ring"]``
     (:func:`window_ring_inputs`) with the local rotary. Global layer ``g``
     of period ``l`` is cache layer ``l x globals + g`` of its pool, window
-    layers likewise. Expert leaves a custom call reads in place stay in
-    their stack (``moe.stack_leaves``); a decode step counts its routing
-    into ``moe_tally``. Returns (hidden, cache, per-layer outputs)."""
+    layers likewise. Returns (hidden, cache, per-layer outputs: a period a
+    row)."""
     pat = spec.layer_pattern
     period = _pattern_period(pat)
     kinds = tuple(bool(x) for x in pat[:period])
     n_w = sum(kinds)
     n_g = period - n_w
-    layers = params["layers"]
-    b, t = hidden.shape[:2]
-    in_place = (moe_mod.stack_leaves(spec.moe, b * t, layers)
-                if spec.moe is not None else ())
-    sliced = {k: a for k, a in layers.items() if k not in in_place}
-    live = (slot_mapping >= 0 if t == 1 and spec.moe is not None else None)
     kw_pool = cache["k_w"]
     page_bytes = (kw_pool.shape[2] * kw_pool.shape[3] * kw_pool.shape[4]
                   * kw_pool.dtype.itemsize * 2)
@@ -2202,36 +2228,30 @@ def run_layers_window(spec: DecoderSpec, params, cache, hidden, ai,
         f"{2 * cache['k'].size * cache['k'].dtype.itemsize} "
         f"window_pool_bytes={2 * kw_pool.size * kw_pool.dtype.itemsize}")
 
-    def body(carry, l):
+    def block(carry, layer_w, l, _, moe_kw):
         x, kg, vg, kw_, vw_ = carry
-        tallies = []
         seen = {False: 0, True: 0}
         for j, local in enumerate(kinds):
-            li = l * period + j
-            w = {k: jax.lax.dynamic_index_in_dim(a, li, keepdims=False)
-                 for k, a in sliced.items()}
-            w.update({k: moe_mod.LayerOfStack(layers[k], li)
-                      for k in in_place})
+            w = layer_w(j)
             ci = l * (n_w if local else n_g) + seen[local]
             seen[local] += 1
             kf, vf = (kw_, vw_) if local else (kg, vg)
-            x, kf, vf, caps = _layer_body(
+            x, kf, vf, _ = _layer_body(
                 spec, x, w, kf, vf, ci, ai, jnp.asarray(local), None,
                 positions, "paged",
                 slot_mapping=slot_mapping, block_table=block_table,
-                adapter_ids=adapter_ids, mixed_local=local, live=live)
+                adapter_ids=adapter_ids, mixed_local=local, **moe_kw)
             if local:
                 kw_, vw_ = kf, vf
             else:
                 kg, vg = kf, vf
-            if "moe_tally" in caps:
-                tallies.append(caps["moe_tally"])
-        return (x, kg, vg, kw_, vw_), (
-            {"moe_tally": sum(tallies)} if tallies else {})
+        return (x, kg, vg, kw_, vw_), {}
 
-    (hidden, kg, vg, kw_, vw_), caps = jax.lax.scan(
-        body, (hidden, cache["k"], cache["v"], kw_pool, cache["v_w"]),
-        jnp.arange(spec.num_layers // period, dtype=jnp.int32))
+    (hidden, kg, vg, kw_, vw_), caps = scan_layers(
+        spec, params["layers"],
+        (hidden, cache["k"], cache["v"], kw_pool, cache["v_w"]), block,
+        steps=spec.num_layers // period, per_step=period, phase="paged",
+        slot_mapping=slot_mapping)
     return hidden, {**cache, "k": kg, "v": vg, "k_w": kw_, "v_w": vw_}, caps
 
 
@@ -2399,64 +2419,6 @@ def _indexer_block(spec: DecoderSpec, h, layer_w, pool, li, ai, positions,
     return select, pool
 
 
-def run_layers_sparse(spec: DecoderSpec, params, cache, hidden, ai,
-                      positions, *, slot_mapping, block_table):
-    """The paged walk of a stack with a LEARNED SPARSE SELECTION
-    (``DecoderSpec.sparse``): one scan over the layers carrying THREE
-    pools, ``cache["k"] / ["v"]`` and the index keys' ``cache["k_idx"]``,
-    all on the allocator's one block table. A layer is the pre-norm block
-
-        h = N(x);  S = indexer(h)        scope ``indexer``
-        x = x + Attn(h | S)              scope ``attn``
-        x = x + MoE(N'(x))               scope ``moe`` (or ``mlp``)
-
-    with the attention reading the tokens ``S`` selects alone
-    (:func:`_indexer_block`, :func:`_attn_block`'s ``select``). Expert
-    leaves a custom call reads in place stay in their stack
-    (``moe.stack_leaves``), as in :func:`run_layer_slice`; a decode step
-    counts its routing into ``moe_tally``. Returns (hidden, cache,
-    per-layer outputs)."""
-    layers = params["layers"]
-    b, t = hidden.shape[:2]
-    in_place = (moe_mod.stack_leaves(spec.moe, b * t, layers)
-                if spec.moe is not None else ())
-    sliced = {k: a for k, a in layers.items() if k not in in_place}
-    live = slot_mapping >= 0 if t == 1 and spec.moe is not None else None
-    pool = cache["k_idx"]
-    kernel_mode.note(
-        "kv_index_pool", "xla",
-        f"page={pool.shape[2]}x{pool.shape[3]} values_a_token="
-        f"{spec.sparse.index_dim} heads={spec.sparse.index_heads} topk="
-        f"{spec.sparse.topk} pool_bytes={pool.size * pool.dtype.itemsize}")
-
-    def body(carry, xs):
-        x, kf, vf, ki = carry
-        w, li = xs
-        w = {**w, **{k: moe_mod.LayerOfStack(layers[k], li)
-                     for k in in_place}}
-        h = _norm(spec, x, w["input_norm"])
-        select, ki = _indexer_block(spec, h, w, ki, li, ai, positions,
-                                    slot_mapping, block_table)
-        a, kf, vf, _ = _attn_block(
-            spec, h, w, kf, vf, li, ai, False, None, positions, "paged",
-            slot_mapping=slot_mapping, block_table=block_table,
-            select=select)
-        x = x + _shard(a, AXIS_DP, None, None)
-        caps: Dict[str, Any] = {}
-        tally = [] if live is not None else None
-        m = _mlp_block(spec, _norm(spec, x, w["post_norm"]), w,
-                       "dense" if spec.moe is None else "moe", None,
-                       phase="paged", tally=tally, live=live)
-        if tally:
-            caps["moe_tally"] = tally[0]
-        return (x + _shard(m, AXIS_DP, None, None), kf, vf, ki), caps
-
-    (hidden, kf, vf, ki), caps = jax.lax.scan(
-        body, (hidden, cache["k"], cache["v"], pool),
-        (sliced, jnp.arange(spec.num_layers, dtype=jnp.int32)))
-    return hidden, {**cache, "k": kf, "v": vf, "k_idx": ki}, caps
-
-
 def _paged_kernel_declined(spec: DecoderSpec) -> str:
     """Why a single-token paged step of this spec does NOT take the paged
     decode kernel ("" where it does, the mesh permitting)."""
@@ -2481,18 +2443,22 @@ def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
                slot_mapping=None, block_table=None,
                adapter_ids=None, replacements=None, kv_view: int = None,
                deepstack=None, deepstack_mask=None, prefill_lens=None,
-               side=None, chunk_idx=None, state_slots=None):
-    """lax.scan over the stacked layer weights.
+               state_slots=None):
+    """The layer walk of a step graph, by what the stack is: layers of
+    several sub-blocks (:func:`run_layers_shortcut`), a recurrent / hybrid
+    stack (:func:`run_layers_ssm`), a window pool
+    (:func:`run_layers_window`), and everything else, a learned sparse
+    selection included: :func:`run_layer_slice` over the stacked layer
+    weights, a run of equal kind at a time.
 
     Replaces the reference's per-layer Python loop
     (models/model_base.py:1216-1469 get_model_output).
     ai: attn_inputs() bundle; replacements: {point: (L,B,T,H),
     point+"_on": (L,)} golden-injection arrays.
-    side: chunked-decode side buffers (see ``decode_loop``) — when set the
-    big cache is read-only and the return gains a 4th element, the updated
-    side pair.
-    Returns (hidden, new_cache, captured[, side]) — captured = {} unless
-    spec.capture names per-layer points (then each is stacked (L, ...)).
+    Returns (hidden, new_cache, captured) on every branch: captured the
+    per-layer outputs, {} unless spec.capture names per-layer points (then
+    each is stacked (L, ...)) or a paged decode step counts its routing
+    (``moe_tally``).
 
     A paged pool is walked in the shape it is stored in
     (``block_kv_cache.pool_page``: as the decode kernel reads a shard's
@@ -2500,11 +2466,10 @@ def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
     """
     if spec.sub_blocks > 1:
         if replacements is not None or deepstack is not None \
-                or side is not None or spec.capture:
+                or spec.capture:
             raise NotImplementedError(
                 "a layer of several sub-blocks (DecoderSpec.sub_blocks) has "
-                "no tap points, deepstack features or chunked side-buffer "
-                "decode")
+                "no tap points or deepstack features")
         return run_layers_shortcut(
             spec, params, cache, hidden, ai, seq_ids, positions, phase,
             identity_seq_ids=identity_seq_ids,
@@ -2514,23 +2479,13 @@ def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
     if spec.ssm is not None:
         refuse_recurrent([
             replacements is not None and "tensor capture/replacement",
-            deepstack is not None and "deepstack",
-            side is not None and "chunked side-buffer decode"])
+            deepstack is not None and "deepstack"])
         return run_layers_ssm(
             spec, params, cache, hidden, ai, seq_ids, positions, phase,
             identity_seq_ids=identity_seq_ids, adapter_ids=adapter_ids,
             kv_view=kv_view, prefill_lens=prefill_lens,
             slot_mapping=slot_mapping, block_table=block_table,
             state_slots=state_slots)
-    if spec.sparse is not None:
-        refuse_sparse([
-            (phase != "paged" or "k_idx" not in cache) and "contiguous cache",
-            (replacements is not None or spec.capture)
-            and "tensor capture/replacement",
-            deepstack is not None and "deepstack"])
-        return run_layers_sparse(
-            spec, params, cache, hidden, ai, positions,
-            slot_mapping=slot_mapping, block_table=block_table)
     if "k_w" in cache:
         refuse_window_pool([
             phase != "paged" and "multi-token decode",
@@ -2541,112 +2496,73 @@ def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
             spec, params, cache, hidden, ai, positions,
             slot_mapping=slot_mapping, block_table=block_table,
             adapter_ids=adapter_ids)
-    is_local = jnp.asarray(spec.layer_pattern if spec.layer_pattern is not None
-                           else (False,) * spec.num_layers)
-    rep = replacements or {}
-
-    def sl(lo, hi):
-        return jax.tree.map(lambda a: a[lo:hi], rep)
-
-    kw = dict(seq_ids=seq_ids, positions=positions, phase=phase,
-              identity_seq_ids=identity_seq_ids,
-              arange_positions=arange_positions, slot_mapping=slot_mapping,
-              block_table=block_table, adapter_ids=adapter_ids,
-              replacements=replacements, kv_view=kv_view,
-              deepstack_mask=deepstack_mask, prefill_lens=prefill_lens,
-              chunk_idx=chunk_idx)
-
-    def unpack(res, side_now):
-        if side_now is not None:
-            return res
-        return res + (None,)
-
-    if spec.moe is not None and spec.first_dense > 0:
-        # mixed stacks (deepseek first_k_dense_replace): dense layers then
-        # MoE layers, two scans carrying one contiguous cache
-        nd = spec.first_dense
-        L = spec.num_layers
-        ds = deepstack
-        hidden, kf, vf, c1, side = unpack(run_layer_slice(
-            spec, params["layers"], cache["k"], cache["v"], hidden, ai,
-            cache_offset=0, is_local=is_local[:nd], rep=sl(0, nd),
-            mlp_kind="dense", deepstack=None if ds is None else ds[:nd],
-            side=side, **kw), side)
-        hidden, kf, vf, c2, side = unpack(run_layer_slice(
-            spec, params["moe_layers"], kf, vf, hidden, ai,
-            cache_offset=nd, is_local=is_local[nd:], rep=sl(nd, L),
-            mlp_kind="moe", deepstack=None if ds is None else ds[nd:],
-            side=side, **kw), side)
-        caps = _join_caps([c1, c2])
-        if side is not None:
-            return hidden, {"k": kf, "v": vf}, caps, side
-        return hidden, {"k": kf, "v": vf}, caps
-
-    if spec.moe is not None and spec.moe_pattern is not None:
-        # interleaved dense/MoE stacks (llama4 interleave_moe_layer_step):
-        # walk contiguous runs of equal kind; cache layer index stays the
-        # absolute layer position
-        pat = spec.moe_pattern
-        L = spec.num_layers
-        runs = []
-        s0 = 0
-        for i in range(1, L + 1):
-            if i == L or pat[i] != pat[s0]:
-                runs.append((s0, i - s0, pat[s0]))
-                s0 = i
-        stack_pos = {"dense": 0, "moe": 0}
-        kf, vf = cache["k"], cache["v"]
-        caps_parts = []
-        for start, count, is_moe in runs:
-            kind = "moe" if is_moe else "dense"
-            stack = params["moe_layers" if is_moe else "layers"]
-            j0 = stack_pos[kind]
-            stack_pos[kind] += count
-            seg = jax.tree.map(lambda a: a[j0:j0 + count], stack)
-            hidden, kf, vf, c, side = unpack(run_layer_slice(
-                spec, seg, kf, vf, hidden, ai, cache_offset=start,
-                is_local=is_local[start:start + count],
-                rep=sl(start, start + count), mlp_kind=kind,
-                deepstack=(None if deepstack is None
-                           else deepstack[start:start + count]),
-                side=side, **kw), side)
-            caps_parts.append(c)
-        caps = _join_caps(caps_parts)
-        if side is not None:
-            return hidden, {"k": kf, "v": vf}, caps, side
-        return hidden, {"k": kf, "v": vf}, caps
-
+    if spec.sparse is not None:
+        refuse_sparse([
+            (phase != "paged" or "k_idx" not in cache) and "contiguous cache",
+            (replacements is not None or spec.capture)
+            and "tensor capture/replacement",
+            deepstack is not None and "deepstack"])
     L = spec.num_layers
-    hidden, kf, vf, caps, side = unpack(run_layer_slice(
-        spec, params["layers"], cache["k"], cache["v"], hidden, ai,
-        cache_offset=0, is_local=is_local, rep=rep, mlp_kind=None,
-        deepstack=deepstack, side=side, **kw), side)
-    if side is not None:
-        return hidden, {"k": kf, "v": vf}, caps, side
-    return hidden, {"k": kf, "v": vf}, caps
+    is_local = jnp.asarray(spec.layer_pattern if spec.layer_pattern is not None
+                           else (False,) * L)
+    rep = replacements or {}
+    # contiguous runs of equal kind: a mixed stack (deepseek
+    # first_k_dense_replace, llama4 interleave_moe_layer_step) keeps its
+    # dense and its expert layers in two stacks, each run the next layers of
+    # its own; the cache layer index stays the absolute layer position
+    if spec.moe is not None and spec.first_dense > 0:
+        pat = (False,) * spec.first_dense + (True,) * (L - spec.first_dense)
+    elif spec.moe is not None and spec.moe_pattern is not None:
+        pat = tuple(bool(x) for x in spec.moe_pattern)
+    else:
+        pat = None
+    cuts = [0] + [i for i in range(1, L) if pat and pat[i] != pat[i - 1]] + [L]
+    used = {"layers": 0, "moe_layers": 0}
+    caps_parts = []
+    for lo, hi in zip(cuts, cuts[1:]):
+        kind = None if pat is None else "moe" if pat[lo] else "dense"
+        name = "moe_layers" if kind == "moe" else "layers"
+        j0, n = used[name], hi - lo
+        used[name] += n
+        stack = params[name]
+        if n != jax.tree.leaves(stack)[0].shape[0]:
+            stack = jax.tree.map(lambda a: a[j0:j0 + n], stack)
+        hidden, cache, c = run_layer_slice(
+            spec, stack, cache, hidden, ai, cache_offset=lo,
+            is_local=is_local[lo:hi],
+            rep=jax.tree.map(lambda a: a[lo:hi], rep), mlp_kind=kind,
+            seq_ids=seq_ids, positions=positions, phase=phase,
+            identity_seq_ids=identity_seq_ids,
+            arange_positions=arange_positions, slot_mapping=slot_mapping,
+            block_table=block_table, adapter_ids=adapter_ids,
+            replacements=replacements, kv_view=kv_view,
+            deepstack=None if deepstack is None else deepstack[lo:hi],
+            deepstack_mask=deepstack_mask, prefill_lens=prefill_lens)
+        caps_parts.append(c)
+    return hidden, cache, _join_caps(caps_parts)
 
 
-def run_layer_slice(spec: DecoderSpec, layer_params, kf, vf, hidden, ai, *,
+def run_layer_slice(spec: DecoderSpec, layer_params, cache, hidden, ai, *,
                     cache_offset: int, is_local, rep, mlp_kind,
                     seq_ids, positions, phase,
                     identity_seq_ids=False, arange_positions=False,
                     slot_mapping=None, block_table=None, adapter_ids=None,
                     replacements=None, kv_view=None, deepstack=None,
-                    deepstack_mask=None, prefill_lens=None,
-                    side=None, chunk_idx=None):
+                    deepstack_mask=None, prefill_lens=None):
     """Run one contiguous run of stacked layers against the full cache
     (cache layer index = scan index + ``cache_offset``). Exposed so families
     with interleaved non-standard layers (mllama cross-attention decoder)
-    can stitch standard segments around their own blocks.
+    can stitch standard segments around their own blocks. Returns (hidden,
+    cache, per-layer outputs), as every walk does.
 
     Phase "decode" at T = 1 (the contiguous cache) UNROLLS the layer loop:
     with a static layer index each layer's cache read is a lazily fused
-    static slice. Every other phase SCANS one compiled body (O(1) compile
-    time in depth) — the paged step graphs too: ``paged_forward_step``
-    passes "paged", so ``paged.w1`` scans and is copy-free all the same,
-    because its consumers either fuse their layer slice (the dense
-    all-experts einsum) or take the layer index as a scalar (the paged
-    decode kernel, the few-token expert kernel).
+    static slice. Every other phase SCANS one compiled body
+    (:func:`scan_layers`; O(1) compile time in depth) — the paged step
+    graphs too: ``paged_forward_step`` passes "paged", so ``paged.w1`` scans
+    and is copy-free all the same, because its consumers either fuse their
+    layer slice (the dense all-experts einsum) or take the layer index as a
+    scalar (the paged decode kernel, the few-token expert kernel).
 
     What a scan's dynamic layer index costs is decided per consumer: XLA
     fuses a layer's slice into an einsum or an elementwise op, but a
@@ -2662,83 +2578,74 @@ def run_layer_slice(spec: DecoderSpec, layer_params, kf, vf, hidden, ai, *,
     out of the scan's ``xs`` (``moe_block`` receives a ``LayerOfStack``:
     the grouped matmuls of many tokens, and the touched-experts kernel of
     few, ``ops/moe_decode.py``), and the paged read gathers from the flat
-    pool (``block_kv_cache.gather_layer_kv``)."""
+    pool (``block_kv_cache.gather_layer_kv``).
+
+    A stack with a learned sparse selection (``spec.sparse``) carries a
+    third pool, the index keys' ``cache["k_idx"]``, on the allocator's one
+    block table: a layer's indexer (:func:`_indexer_block`, scope
+    ``indexer``, a sibling of ``attn``) reads the block's normed input and
+    hands the attention the tokens it selects."""
     n = jax.tree.leaves(layer_params)[0].shape[0]
     h0 = jax.tree.leaves(hidden)[0]
-    # leaves a consumer reads out of the stack in place: not sliced per layer
-    in_place = (moe_mod.stack_leaves(spec.moe, h0.shape[0] * h0.shape[1],
-                                     layer_params)
-                if spec.moe is not None and mlp_kind != "dense" else ())
-    sliced = {k: a for k, a in layer_params.items() if k not in in_place}
-    # a paged decode step counts what its routing touched and what its
-    # expert path read, a layer a row of the scan's outputs
-    live = (slot_mapping >= 0 if phase == "paged" and h0.shape[1] == 1
-            and spec.moe is not None and mlp_kind != "dense" else None)
-
-    def of_stack(layer_w, i):
-        return {**layer_w, **{k: moe_mod.LayerOfStack(layer_params[k], i)
-                              for k in in_place}}
+    kf, vf = cache["k"], cache["v"]
 
     if phase == "decode" and h0.shape[1] == 1:
+        sliced, of_stack = _split_stack(
+            spec, layer_params, h0.shape[0],
+            spec.moe is not None and mlp_kind != "dense")
         caps_list = []
-        pend = []
         for i in range(n):
-            layer_w = of_stack(jax.tree.map(lambda a: a[i], sliced), i)
-            res = _layer_body(
-                spec, hidden, layer_w, kf, vf, i + cache_offset, ai,
+            hidden, kf, vf, caps_i = _layer_body(
+                spec, hidden,
+                of_stack(jax.tree.map(lambda a: a[i], sliced), i),
+                kf, vf, i + cache_offset, ai,
                 is_local[i], seq_ids, positions, phase, identity_seq_ids,
                 arange_positions, slot_mapping, block_table, mlp_kind,
                 adapter_ids,
                 (jax.tree.map(lambda a: a[i], rep)
                  if replacements is not None else None),
-                kv_view=kv_view, prefill_lens=prefill_lens,
-                side=side)
-            if side is not None:
-                hidden, kf, vf, caps_i, pending = res
-                pend.append(pending)
-            else:
-                hidden, kf, vf, caps_i = res
+                kv_view=kv_view, prefill_lens=prefill_lens)
             caps_list.append(caps_i)
         caps = ({k: jnp.stack([c[k] for c in caps_list])
                  for k in caps_list[0]} if caps_list and caps_list[0] else {})
-        if side is not None:
-            # ONE side-buffer update pair per step for the whole layer run
-            # (32 per-layer updates force a write-friendly layout onto the
-            # scan-carried side buffers and relayout the reads)
-            sk, sv = side
-            k_stack = jnp.stack([p[0][:, 0] for p in pend])   # (n, B, H, D)
-            v_stack = jnp.stack([p[1][:, 0] for p in pend])
-            sk = jax.lax.dynamic_update_slice(
-                sk, k_stack[..., None].astype(sk.dtype),
-                (cache_offset, 0, 0, 0, chunk_idx))
-            sv = jax.lax.dynamic_update_slice(
-                sv, v_stack[:, :, :, None, :].astype(sv.dtype),
-                (cache_offset, 0, 0, chunk_idx, 0))
-            return hidden, kf, vf, caps, (sk, sv)
-        return hidden, kf, vf, caps
+        return hidden, {**cache, "k": kf, "v": vf}, caps
 
-    def body(carry, xs):
-        h, k_, v_ = carry
-        if deepstack is not None:
-            layer_w, loc, rp, li, ds = xs
-        else:
-            layer_w, loc, rp, li = xs
-            ds = None
+    pools = (kf, vf)
+    if spec.sparse is not None:
+        pool = cache["k_idx"]
+        kernel_mode.note(
+            "kv_index_pool", "xla",
+            f"page={pool.shape[2]}x{pool.shape[3]} values_a_token="
+            f"{spec.sparse.index_dim} heads={spec.sparse.index_heads} topk="
+            f"{spec.sparse.topk} pool_bytes={pool.size * pool.dtype.itemsize}")
+        pools += (pool,)
+
+    def block(carry, layer_w, li, x, moe_kw):
+        h, k_, v_, *ki = carry
+        loc, rp, ds = x
+        w = layer_w()
+
+        def select_of(normed):
+            select, ki[0] = _indexer_block(
+                spec, normed, w, ki[0], li, ai, positions, slot_mapping,
+                block_table)
+            return select
+
         h, k_, v_, caps = _layer_body(
-            spec, h, of_stack(layer_w, li), k_, v_, li + cache_offset, ai,
-            loc, seq_ids,
+            spec, h, w, k_, v_, li + cache_offset, ai, loc, seq_ids,
             positions, phase, identity_seq_ids, arange_positions,
             slot_mapping, block_table, mlp_kind, adapter_ids,
             rp if replacements is not None else None, kv_view=kv_view,
             deepstack=ds, deepstack_mask=deepstack_mask,
-            prefill_lens=prefill_lens, live=live)
-        return (h, k_, v_), caps
+            prefill_lens=prefill_lens, select_of=select_of if ki else None,
+            **moe_kw)
+        return (h, k_, v_, *ki), caps
 
-    xs = (sliced, is_local, rep, jnp.arange(n, dtype=jnp.int32))
-    if deepstack is not None:
-        xs = xs + (deepstack,)
-    (hidden, kf, vf), caps = jax.lax.scan(body, (hidden, kf, vf), xs)
-    return hidden, kf, vf, caps
+    (hidden, *pools), caps = scan_layers(
+        spec, layer_params, (hidden, *pools), block, steps=n, phase=phase,
+        slot_mapping=slot_mapping, experts=mlp_kind != "dense",
+        xs=(is_local, rep, deepstack))
+    return hidden, {**cache, **dict(zip(("k", "v", "k_idx"), pools))}, caps
 
 
 def run_layers_shortcut(spec: DecoderSpec, params, cache, hidden, ai,
@@ -2754,27 +2661,16 @@ def run_layers_shortcut(spec: DecoderSpec, params, cache, hidden, ai,
         b0 = a0 + MLP_0(u);      a1 = b0 + Attn_1(N(b0))
         y  = a1 + MLP_1(N'(a1)) + s
 
-    One scan over the layers; pair ``j`` of layer ``l`` reads and writes
-    cache layer ``sub_blocks * l + j``. The expert leaves a custom call
-    reads in place stay out of the scan's ``xs`` (``moe.stack_leaves``), as
-    in :func:`run_layer_slice`; a paged decode step counts its routing into
-    ``moe_tally``. Returns (hidden, cache, per-layer outputs)."""
-    n, L = spec.sub_blocks, spec.num_layers
+    One scan over the layers (:func:`scan_layers`: the routed block's
+    stack, a layer a step); pair ``j`` of layer ``l`` reads and writes
+    cache layer ``sub_blocks * l + j``. Returns (hidden, cache, per-layer
+    outputs)."""
+    n = spec.sub_blocks
     pairs = params["layers"]
-    experts = params.get("moe_layers", {})
-    tokens = hidden.shape[0] * hidden.shape[1]
-    in_place = (moe_mod.stack_leaves(spec.moe, tokens, experts)
-                if spec.moe is not None else ())
-    sliced = {k: a for k, a in experts.items() if k not in in_place}
-    live = (slot_mapping >= 0 if phase == "paged" and hidden.shape[1] == 1
-            and spec.moe is not None else None)
 
-    def body(carry, xs):
+    def block(carry, layer_w, l, _, moe_kw):
         x, kf, vf = carry
-        moe_w, l = xs
-        moe_w = {**moe_w, **{k: moe_mod.LayerOfStack(experts[k], l)
-                             for k in in_place}}
-        caps: Dict[str, Any] = {}
+        moe_w = layer_w()
         shortcut = None
         for j in range(n):
             # ONE pair's slice, by its own index: XLA fuses it into the
@@ -2797,20 +2693,17 @@ def run_layers_shortcut(spec: DecoderSpec, params, cache, hidden, ai,
             x = x + _shard(h, AXIS_DP, None, None)
             u = _norm(spec, x, w["post_norm"])
             if j == 0 and spec.moe is not None:
-                tally = [] if live is not None else None
                 shortcut = _mlp_block(spec, u, moe_w, "moe", adapter_ids,
-                                      phase=phase, tally=tally, live=live)
-                if tally:
-                    caps["moe_tally"] = tally[0]
+                                      phase=phase, **moe_kw)
             x = x + _shard(_mlp_block(spec, u, w, "dense", adapter_ids,
                                       phase=phase), AXIS_DP, None, None)
         if shortcut is not None:
             x = x + shortcut
-        return (x, kf, vf), caps
+        return (x, kf, vf), {}
 
-    (hidden, kf, vf), caps = jax.lax.scan(
-        body, (hidden, cache["k"], cache["v"]),
-        (sliced, jnp.arange(L, dtype=jnp.int32)))
+    (hidden, kf, vf), caps = scan_layers(
+        spec, params.get("moe_layers", {}), (hidden, cache["k"], cache["v"]),
+        block, steps=spec.num_layers, phase=phase, slot_mapping=slot_mapping)
     return hidden, {**cache, "k": kf, "v": vf}, caps
 
 
@@ -3478,93 +3371,11 @@ def decode_loop(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     position_ids (B,): position of first_tokens.
     Returns (tokens (B, num_steps), cache).
     """
-
     use_mrope = rope_position_ids is not None
-    b = first_tokens.shape[0]
-
-    # Chunked side-buffer decode (hot path): the big cache is READ-ONLY
-    # inside the scan — the chunk's K/V accumulate in a small per-chunk side
-    # buffer and land in the cache with ONE bulk write per chunk. Any write
-    # into the scan-carried cache makes XLA pick a write-friendly layout for
-    # the carry and relayout-copy the live cache for the attention reads
-    # every step (~0.29 ms/step at B=2/S=1024/16L on v5e). Geometries the
-    # Pallas decode kernel is admitted for (window/sink/local patterns,
-    # models/model_base.py kernel admission) keep the per-step path.
-    chunkable = (num_steps > 1
-                 and not tpu_cfg.is_continuous_batching
-                 and b == cache["k"].shape[1]
-                 and not spec.rolling_window
-                 and not spec.flash_decoding
-                 and spec.ssm is None
-                 and spec.decode_kernel is not True
-                 and not spec.alibi
-                 and not (spec.attn_sink or spec.sliding_window > 0
-                          or spec.layer_pattern is not None
-                          or spec.attn_chunk > 0))
     if rope_position_ids is None:
-        rope_position_ids = jnp.zeros((b, 3), position_ids.dtype)
+        rope_position_ids = jnp.zeros((first_tokens.shape[0], 3),
+                                      position_ids.dtype)
     rngs = jax.random.split(rng, num_steps)
-
-    if chunkable:
-        C = num_steps
-        g = spec.gqa
-        side_k0 = jnp.zeros((spec.num_layers, b, g.num_kv_heads,
-                             spec.head_dim, C), spec.dtype)
-        side_v0 = jnp.zeros((spec.num_layers, b, g.num_kv_heads, C,
-                             spec.v_head_dim), spec.dtype)
-        start_pos = position_ids                       # (B,)
-        cache_len = kv_view or kv.cache_len_of(cache)
-        slots = jnp.arange(cache_len)[None, None, :]
-        side_positions = (start_pos[:, None]
-                          + jnp.arange(C, dtype=position_ids.dtype)[None, :])
-
-        def step(carry, xs):
-            tok, pos, rpos, sk, sv = carry
-            step_rng, idx = xs
-            pos2 = pos[:, None]
-
-            def prior_mask(w, c=0):
-                m = attn_ops.decode_mask(pos2, cache_len, window=w, chunk=c)
-                return jnp.logical_and(
-                    m, slots < start_pos[:, None, None])
-
-            ai = attn_inputs(
-                spec, pos2, prior_mask,
-                rope_positions=rpos[:, None, :] if use_mrope else None)
-            # the active token (slot idx) is folded in-register, not read
-            # from the side buffer — its side write lands at step end
-            ai["mask_side"] = jnp.logical_and(
-                attn_ops.causal_mask(pos2, side_positions, None,
-                                     spec.sliding_window, spec.attn_chunk),
-                jnp.arange(C, dtype=jnp.int32)[None, None, :] != idx)
-            hidden = _embed(spec, params, tok[:, None], pos2)
-            hidden, _, _, (sk, sv) = run_layers(
-                spec, params, cache, hidden, ai, seq_ids, pos2, "decode",
-                identity_seq_ids=True, adapter_ids=adapter_ids,
-                kv_view=kv_view, side=(sk, sv), chunk_idx=idx)
-            logits = _lm_head(spec, params, hidden)
-            nxt = sampling_ops.sample_dp(
-                logits[:, -1, :], tpu_cfg.on_device_sampling_config,
-                sampling_params, step_rng)
-            return (nxt, pos + 1, rpos + 1 if use_mrope else rpos,
-                    sk, sv), nxt
-
-        (_, _, _, sk, sv), toks = jax.lax.scan(
-            step, (first_tokens, position_ids, rope_position_ids,
-                   side_k0, side_v0),
-            (rngs, jnp.arange(num_steps, dtype=jnp.int32)),
-            unroll=int(os.environ.get("NXDI_TPU_DECODE_UNROLL", "2")))
-        new_cache = {
-            "k": kv.commit_chunk(
-                cache["k"], kv.quantize_kv(sk, cache["k"].dtype,
-                                           spec.kv_scale),
-                seq_ids, start_pos, k_transposed=True),
-            "v": kv.commit_chunk(
-                cache["v"], kv.quantize_kv(sv, cache["v"].dtype,
-                                           spec.kv_scale),
-                seq_ids, start_pos),
-        }
-        return {"tokens": jnp.transpose(toks, (1, 0)), "cache": new_cache}
 
     def step(carry, step_rng):
         tok, pos, rpos, cch = carry

@@ -284,43 +284,6 @@ def write_tokens_at_layer(cache: jnp.ndarray, new: jnp.ndarray, layer,
         new, mode="drop", unique_indices=False)
 
 
-def commit_chunk(cache: jnp.ndarray, side: jnp.ndarray,
-                 seq_ids: jnp.ndarray, start_positions: jnp.ndarray,
-                 k_transposed: bool = False) -> jnp.ndarray:
-    """Commit a decode chunk's side buffer into the big cache — ONE bulk
-    write per row per chunk instead of a per-layer write per step (see
-    ``ops.attention.mha_decode_merged``; the reference's analog is the
-    DMA-skipping batch write kernel, kvcache/utils.py
-    ``write_kv_cache_at_batch_kernel``).
-
-    cache (L, B, H, D, S) transposed-K or (L, B, H, S, D);
-    side (L, b, H, D, C) / (L, b, H, C, D); start_positions (b,) — row i's
-    chunk covers positions [start, start+C). A row whose chunk would
-    straddle the cache end keeps its OLD values for the whole chunk
-    (unlike write_tokens_at_layer's per-token drop): callers must size
-    chunks so start+C <= S — the application's bucket selection
-    (application.py _kv_bucket over position+num_steps) guarantees this.
-    """
-    C = side.shape[4] if k_transposed else side.shape[3]
-    s_max = cache.shape[4] if k_transposed else cache.shape[3]
-    b = side.shape[1]
-    zero = jnp.zeros((), jnp.int32)
-    for i in range(b):
-        start_i = jnp.clip(start_positions[i], 0, s_max - C)
-        row = seq_ids[i].astype(jnp.int32)
-        upd = side[:, i][:, None].astype(cache.dtype)   # (L,1,H,D,C)/(L,1,H,C,D)
-        if k_transposed:
-            start = (zero, row, zero, zero, start_i)
-        else:
-            start = (zero, row, zero, start_i, zero)
-        valid = jnp.logical_and(start_positions[i] >= 0,
-                                start_positions[i] <= s_max - C)
-        old = jax.lax.dynamic_slice(cache, start, upd.shape)
-        cache = jax.lax.dynamic_update_slice(
-            cache, jnp.where(valid, upd, old), start)
-    return cache
-
-
 def write_prefill_at_layer(cache: jnp.ndarray, new: jnp.ndarray, layer,
                            seq_ids: jnp.ndarray,
                            start: jnp.ndarray | int = 0,

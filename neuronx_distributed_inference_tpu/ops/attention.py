@@ -165,70 +165,6 @@ def mha_hl(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out.reshape(b, t, hq, v.shape[-1]).astype(q.dtype)
 
 
-def mha_decode_merged(q: jnp.ndarray, k_prior: jnp.ndarray,
-                      v_prior: jnp.ndarray, mask_prior: jnp.ndarray,
-                      k_side: jnp.ndarray, v_side: jnp.ndarray,
-                      mask_side: jnp.ndarray, k_new: jnp.ndarray,
-                      v_new: jnp.ndarray, scale: float,
-                      logits_soft_cap: Optional[float] = None,
-                      sink: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Decode attention over a READ-ONLY prior cache plus a small side
-    buffer holding the current decode chunk's K/V, combined with a
-    two-block online-softmax merge (the reference's decomposed prior+active
-    TKG attention, attention_base.py:1383-1461, at chunk granularity).
-
-    Keeping the big cache free of in-scan writes is what lets XLA read the
-    loop-carried cache in place: a dynamic-update-slice inside the decode
-    scan forces a materialized relayout of the live cache every step
-    (measured ~0.29 ms/step at B=2/S=1024/16L on v5e).
-
-    q (B,1,Hq,D); k_prior (B,Hkv,D,S) transposed-K; v_prior (B,Hkv,S,Dv);
-    k_side (B,Hkv,D,C); v_side (B,Hkv,C,Dv); mask_prior (B,1,S) must
-    exclude every slot the side buffer covers; mask_side (B,1,C) selects
-    the side entries written so far. k_new/v_new (B,1,Hkv,D/Dv): the ACTIVE
-    token's K/V folded in-register (the side buffer write is batched to the
-    step end, so the active token is not in the side buffer yet).
-    """
-    b, t, hq, d = q.shape
-    hkv = k_prior.shape[1]
-    g = hq // hkv
-    qk = q.reshape(b, t, hkv, g, d)
-    sp = jnp.einsum("bthgd,bhds->bhgts", qk, k_prior,
-                    preferred_element_type=jnp.float32) * scale
-    ss = jnp.einsum("bthgd,bhdc->bhgtc", qk, k_side,
-                    preferred_element_type=jnp.float32) * scale
-    sa = jnp.einsum("bthgd,bthd->bhgt", qk, k_new,
-                    preferred_element_type=jnp.float32)[..., None] * scale
-    if logits_soft_cap is not None:
-        sp = logits_soft_cap * jnp.tanh(sp / logits_soft_cap)
-        ss = logits_soft_cap * jnp.tanh(ss / logits_soft_cap)
-        sa = logits_soft_cap * jnp.tanh(sa / logits_soft_cap)
-    sp = jnp.where(mask_prior[:, None, None, :, :], sp, NEG_INF)
-    ss = jnp.where(mask_side[:, None, None, :, :], ss, NEG_INF)
-    m = jnp.maximum(jnp.maximum(jnp.max(sp, axis=-1, keepdims=True),
-                                jnp.max(ss, axis=-1, keepdims=True)), sa)
-    if sink is not None:
-        sink_b = sink.astype(jnp.float32).reshape(1, hkv, g, 1, 1)
-        m = jnp.maximum(m, sink_b)
-    ep = jnp.exp(sp - m)
-    es = jnp.exp(ss - m)
-    ea = jnp.exp(sa - m)
-    den = (jnp.sum(ep, axis=-1, keepdims=True)
-           + jnp.sum(es, axis=-1, keepdims=True) + ea)
-    if sink is not None:
-        # the sink column joins the denominator only (no value contribution)
-        den = den + jnp.exp(sink_b - m)
-    out = jnp.einsum("bhgts,bhsd->bthgd", (ep / den).astype(v_prior.dtype),
-                     v_prior, preferred_element_type=jnp.float32)
-    out = out + jnp.einsum("bhgtc,bhcd->bthgd",
-                           (es / den).astype(v_side.dtype), v_side,
-                           preferred_element_type=jnp.float32)
-    # active-token value: coeff (B,Hkv,G,T,1) -> (B,T,Hkv,G,1) * v_new
-    coeff = jnp.transpose(ea / den, (0, 3, 1, 2, 4))
-    out = out + coeff * v_new[:, :, :, None, :].astype(jnp.float32)
-    return out.reshape(b, t, hq, v_new.shape[-1]).astype(q.dtype)
-
-
 def causal_mask(position_ids: jnp.ndarray, kv_positions: jnp.ndarray,
                 kv_valid: Optional[jnp.ndarray] = None,
                 window: int = 0, chunk: int = 0) -> jnp.ndarray:
