@@ -2737,7 +2737,7 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
                    seq_ids, positions, phase: str, *,
                    identity_seq_ids=False, adapter_ids=None, kv_view=None,
                    prefill_lens=None, slot_mapping=None, block_table=None,
-                   state_slots=None):
+                   state_slots=None, part=None):
     """Unrolled layer walk for recurrent/hybrid stacks (reference:
     contrib Falcon-H1 FalconH1DecoderLayer — parallel mamba+attention;
     contrib recurrentgemma RecurrentGemmaDecoderLayer — rec/rec/attn
@@ -2759,11 +2759,11 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
     a mixer continues from its rows of the state — the second
     per-sequence cache, (Ls, slots, ...) beside the KV pool. With
     ``state_slots`` None the rows of the step ARE the slots (row i is slot
-    i: the full-batch decode step and the full-batch chunk pack; a row
-    whose ``slot_mapping`` is all negative is dead and leaves its slot's
-    tail and state as they were), so nothing is gathered and the state is
-    updated in place; with ``state_slots`` (R,) the R rows slice their
-    slots in and out (the one-row chunk program).
+    i; a row whose ``slot_mapping`` is all negative is dead and leaves its
+    slot's tail and state as they were), so the state is updated in place;
+    with ``state_slots`` (R,) the R rows slice their slots in and out (the
+    one-row chunk program). ``part``: :func:`walk_part`'s layers [lo, hi)
+    and what the layers under lo hand on; a walk that stops early says so.
     """
     s = spec.ssm
     pat = spec.resolved_ssm_pattern
@@ -2804,7 +2804,7 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
             kernel_mode.note(
                 "recurrent_state", kernel_mode.kernel_path(),
                 f"{what} {ssm_mod.state_kernel_note(s, cache['ssm'])}")
-        if kinds is not None:
+        if kinds is not None and part is None:
             _note_pools_by_kind(spec, cache, ai)
         if state_slots is None and hidden.shape[0] != new_state["ssm"].shape[1]:
             raise ValueError(
@@ -2829,16 +2829,16 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
     # a decode step over expert layers counts its routing and its reads
     tally = [] if (paged and hidden.shape[1] == 1
                    and spec.moe is not None) else None
-    attn_i = 0
-    ssm_i = 0
-    # a stack with layer_kinds: the layers seen so far of each kind (a
-    # kind's index into its weight stack; "window" / "full" also into their
-    # pool), the pool layer and the step's own K / V of the nearest "full"
-    # layer below (what a "cross" layer attends over), and the nearest
+    # the layers walked here, [lo, hi), and what the layers under lo hand on
+    # (walk_part). A stack with layer_kinds: the layers seen so far of each
+    # kind (a kind's index into its weight stack; "window" / "full" also into
+    # their pool), the pool layer and the step's own K / V of the nearest
+    # "full" layer below (what a "cross" layer attends over), and the nearest
     # mixer's scan output (what a "gmu" layer gates)
-    seen = dict.fromkeys(("mamba", "window", "full", "cross", "gmu"), 0)
-    shared = memory = None
-    for i in range(spec.num_layers):
+    lo, hi, shared, memory = part or walk_part(
+        spec, ai, hidden.shape[1] if paged else 1)
+    seen, attn_i, ssm_i = _layers_seen(spec, lo)
+    for i in range(lo, hi):
         kind = kinds[i] if kinds is not None else None
         has_ssm = bool(pat[i])
         has_attn = (spec.ssm_parallel or not has_ssm) if kind is None \
@@ -2936,19 +2936,19 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
             m_out = rms_norm(m_out, lw["post_ff_norm"], spec.rms_eps,
                              spec.norm_offset)
         hidden = add(hidden, m_out)
-    # exact counts over this walk's expert layers, [touched, assigned,
-    # read] (moe.share_tally), for the step to hand out with its tokens
+    # exact counts over this walk's expert layers, [touched, assigned, read]
+    # (moe.share_tally); a walk that stopped early, where, and its hand-over
     side = {"moe_tally": sum(tally)} if tally else {}
+    if hi < spec.num_layers:
+        side["handed"] = (hi, shared, memory)
     rings = {} if kw_ is None else {"k_w": kw_, "v_w": vw_}
     return hidden, {"k": kf, "v": vf, **rings, **new_state}, side
 
 
 def _note_pools_by_kind(spec: DecoderSpec, cache, ai) -> None:
-    """The engagement records of a stack with ``layer_kinds`` on the paged
-    path: the pools by layer kind (``kv_window_pool``, as
-    :func:`run_layers_window` writes it) and the pool that several layers
-    read (``kv_shared_pool``: the "full" layers that write it, every layer
-    that attends over it, its bytes a token)."""
+    """A paged stack with ``layer_kinds`` records its pools by layer kind
+    (``kv_window_pool``, as :func:`run_layers_window` writes it) and the pool
+    several layers read (``kv_shared_pool``: writers, readers, token bytes)."""
     def pool_bytes(*keys):
         return sum(cache[k].size * cache[k].dtype.itemsize for k in keys)
     n_full, n_win = spec.count_kind("full"), spec.count_kind("window")
@@ -3292,20 +3292,18 @@ def paged_forward_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
     input_ids (B, T); position_ids (B, T) absolute positions;
     slot_mapping (B, T) flat cache slots (negative = drop);
     block_table (B, max_blocks); last_idx (B,) index into T of the token whose
-    logits are sampled. Cache layout (L, N_blocks, Bs, Hkv, D).
-    row_seeds (B,) optional per-request sampling seeds: when present and
-    the config carries ``stream_seed``, sampling switches to the
-    positionally coupled draw (``ops/sampling.coupled_sample``) keyed by
-    the ABSOLUTE position of the sampled token — the invariant every
-    sampled-speculation bit-identity guarantee rests on.
+    logits are sampled, negative for a row nobody samples from (a chunk that
+    is not its prompt's last: :func:`second_decoder_tokens` has what a stack
+    saves by it). Cache layout (L, N_blocks, Bs, Hkv, D).
+    row_seeds (B,) optional per-request sampling seeds: present under a
+    config with ``stream_seed``, the draw is the positionally coupled one
+    (:func:`_draw`), which every sampled-speculation bit-identity rests on.
     adapter_ids (B,) optional per-row LoRA pool slots (serving/lora_pool):
-    each row gathers its own (A, B) factors from the stacked adapter
-    params inside the one dispatch; slot 0 is the pinned zero adapter, so
-    base-model rows stay bit-identical. Absent (None) the traced graph is
-    byte-identical to a LoRA-free build.
+    each row gathers its own (A, B) factors from the stacked adapter params
+    inside the one dispatch; slot 0 is the pinned zero adapter (base-model
+    rows stay bit-identical). Absent, the graph is a LoRA-free build's.
     state_slots (B,) optional, recurrent stacks only: the state slot of
-    each row where the rows are fewer than the slots (the one-row chunk
-    program); absent, row i is slot i (``run_layers_ssm``).
+    each row of the one-row chunk program; absent, row i is slot i.
     """
     kv_len = block_table.shape[1] * cache["k"].shape[2]
     ai = attn_inputs(spec, position_ids, lambda w, c=0: attn_ops.decode_mask(
@@ -3320,11 +3318,22 @@ def paged_forward_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
         # the index heads' rotary (all of their lanes)
         ai["cos_i"], ai["sin_i"] = rope_cos_sin(position_ids,
                                                 spec.sparse.rope)
+    if spec.layer_kinds is not None and not tpu_cfg.output_logits:
+        ai["sampled"] = last_idx    # all the step hands out: walk_part
     hidden = _embed(spec, params, input_ids, position_ids)
     hidden, new_cache, side = run_layers(
         spec, params, cache, hidden, ai, None, position_ids,
         "paged", slot_mapping=slot_mapping, block_table=block_table,
         adapter_ids=adapter_ids, state_slots=state_slots)
+    if "handed" in side:
+        # the walk stopped before a second decoder, which writes nothing:
+        # it, the head and the draw run on the ONE token a row that is
+        # sampled from, under one condition: that any row of the dispatch
+        # is (a chunk that is not its prompt's last reads none of them)
+        return {"cache": new_cache, "tokens": second_decoder_tokens(
+            spec, tpu_cfg, params, new_cache, hidden, side["handed"],
+            position_ids, slot_mapping, block_table, last_idx,
+            sampling_params, rng, row_seeds, adapter_ids, state_slots)}
     idx = last_idx[:, None, None].astype(jnp.int32)
     last_h = jnp.take_along_axis(hidden, idx, axis=1)
     logits = _lm_head(spec, params, last_h)[:, 0, :]
@@ -3337,17 +3346,8 @@ def paged_forward_step(spec: DecoderSpec, tpu_cfg: TpuConfig, params, cache,
             -1, side["moe_tally"].shape[-1]).sum(axis=0)
     if tpu_cfg.output_logits:
         out["logits"] = _lm_head(spec, params, hidden)[..., :spec.vocab_size]
-    if _coupled_mode(tpu_cfg, row_seeds):
-        # position of the sampled token = the last real input position
-        pos_last = jnp.take_along_axis(
-            position_ids, last_idx[:, None].astype(jnp.int32),
-            axis=1)[:, 0]
-        out["tokens"] = sampling_ops.coupled_sample(
-            logits, tpu_cfg.on_device_sampling_config, sampling_params,
-            row_seeds, pos_last)
-    else:
-        out["tokens"] = sampling_ops.sample_dp(
-            logits, tpu_cfg.on_device_sampling_config, sampling_params, rng)
+    out["tokens"] = _draw(tpu_cfg, logits, sampling_params, rng, row_seeds,
+                          position_ids, last_idx)
     if input_ids.shape[1] == 1:
         # the decode step hands its sampled tokens on in the shape, dtype
         # and (replicated) placement of its own ``input_ids``: a serving
@@ -4045,3 +4045,103 @@ def _gathered_select(sp: SparseSpec, qi, w, pool, li, positions,
     return map_row_groups(
         select_of, 4 * (sp.index_heads + 4) * t * kpos.shape[0], qi, w,
         block_table, positions.astype(jnp.int32))
+
+
+def _draw(tpu_cfg: TpuConfig, logits, sampling_params, rng, row_seeds,
+          position_ids, last_idx):
+    """A paged step's token a row from the sampled token's ``logits``
+    (B, V): the positionally coupled draw (``ops/sampling.coupled_sample``,
+    keyed by the ABSOLUTE position of the sampled token, the last real input
+    position) where :func:`_coupled_mode` says so, else the step's ``rng``."""
+    if _coupled_mode(tpu_cfg, row_seeds):
+        pos_last = jnp.take_along_axis(
+            position_ids, last_idx[:, None].astype(jnp.int32),
+            axis=1)[:, 0]
+        return sampling_ops.coupled_sample(
+            logits, tpu_cfg.on_device_sampling_config, sampling_params,
+            row_seeds, pos_last)
+    return sampling_ops.sample_dp(
+        logits, tpu_cfg.on_device_sampling_config, sampling_params, rng)
+
+
+def second_decoder_start(spec: DecoderSpec) -> Optional[int]:
+    """The first layer of a stack's SECOND decoder, None where it has none:
+    under ``layer_kinds``, the layers after the last one that writes a cache
+    or a state ("mamba", "window", "full"), all of them "cross" / "gmu". They
+    read what the layers under them wrote and write nothing, so a token that
+    nobody samples from need not pass them (the prefill saving of a
+    decoder-hybrid-decoder, arXiv:2507.06607)."""
+    kinds = spec.layer_kinds or ()
+    start = 1 + max((i for i, k in enumerate(kinds)
+                     if k not in ("cross", "gmu")), default=-1)
+    return start if 0 < start < len(kinds) else None
+
+
+def walk_part(spec: DecoderSpec, ai, width: int):
+    """:func:`run_layers_ssm`'s layers [lo, hi) and the hand-over under lo
+    where its caller names none: the whole stack; or, in a paged step of
+    ``width`` > 1 tokens a row that hands out nothing but the token sampled
+    at ``ai["sampled"]`` (:func:`paged_forward_step`), the layers under
+    :func:`second_decoder_start`."""
+    stop = (second_decoder_start(spec)
+            if width > 1 and "sampled" in ai else None)
+    return 0, stop or spec.num_layers, None, None
+
+
+def _layers_seen(spec: DecoderSpec, lo: int):
+    """What :func:`run_layers_ssm` has counted when it reaches layer ``lo``:
+    the layers of each kind, the attention layers and the mixers."""
+    kinds, pat = spec.layer_kinds, spec.resolved_ssm_pattern
+    seen = {k: (kinds or ())[:lo].count(k)
+            for k in ("mamba", "window", "full", "cross", "gmu")}
+    attn = (seen["window"] + seen["full"] if kinds is not None
+            else sum(spec.ssm_parallel or not m for m in pat[:lo]))
+    return seen, attn, sum(map(bool, pat[:lo]))
+
+
+def second_decoder_tokens(spec: DecoderSpec, tpu_cfg: TpuConfig, params,
+                          cache, hidden, handed, position_ids, slot_mapping,
+                          block_table, last_idx, sampling_params, rng,
+                          row_seeds, adapter_ids, state_slots):
+    """The sampled tokens (B,) of a paged chunk whose walk stopped before the
+    second decoder (:func:`walk_part`): ``hidden`` (B, T, H) is the FIRST
+    decoder's output, every cache and state of the step is written, and
+    ``handed`` is where the walk stopped, the shared pool's layer with the
+    step's own K / V, and the scan output the Gated Memory Units gate. Only
+    the token at ``last_idx`` of a row is sampled from, so the second
+    decoder, the head and the draw run on that ONE token a row: a cross
+    layer is one query a row over the shared pool after the full layer's
+    write, as in a decode step. And they run under ONE condition, whether
+    any row of the dispatch is sampled from (``last_idx`` >= 0): a chunk that
+    is not its prompt's last reads neither their weights nor the head's, and
+    its tokens, which nobody fetches, are zeros. A row with a negative
+    ``last_idx`` beside a sampled one walks its token 0, to no effect."""
+    start, shared, memory = handed
+    at = jnp.maximum(last_idx, 0).astype(jnp.int32)
+
+    def token_of(x):
+        return None if x is None else jnp.take_along_axis(
+            x, at.reshape((-1,) + (1,) * (x.ndim - 1)), axis=1)
+    hidden, pos = token_of(hidden), token_of(position_ids)
+    part = (start, spec.num_layers,
+            shared and (shared[0], tuple(map(token_of, shared[1]))),
+            token_of(memory))
+    kv_len = block_table.shape[1] * cache["k"].shape[2]
+    kernel_mode.note(
+        "second_decoder", "xla",
+        f"apart: layers {start}-{spec.num_layers - 1}, the head and the draw "
+        f"on one token a row of {position_ids.shape[1]}, where a row samples")
+
+    def sampled():
+        ai = attn_inputs(spec, pos, lambda w, c=0: attn_ops.decode_mask(
+            pos, kv_len, window=w, chunk=c))
+        h, _, _ = run_layers_ssm(
+            spec, params, cache, hidden, ai, None, pos, "paged",
+            slot_mapping=token_of(slot_mapping), block_table=block_table,
+            adapter_ids=adapter_ids, state_slots=state_slots, part=part)
+        return _draw(tpu_cfg, _lm_head(spec, params, h)[:, 0, :],
+                     sampling_params, rng, row_seeds, pos,
+                     jnp.zeros_like(at))
+    # (a draw is int32 a row, ops/sampling: another type fails the trace)
+    return jax.lax.cond(jnp.any(last_idx >= 0), sampled,
+                        lambda: jnp.zeros(at.shape, jnp.int32))

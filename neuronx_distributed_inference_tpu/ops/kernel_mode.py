@@ -96,6 +96,14 @@ def paged_prefill_on_kernel(notes) -> bool:
                for site, path, _ in notes)
 
 
+def second_decoder_apart(notes) -> bool:
+    """Whether a chunk program of a decoder-hybrid-decoder stops its walk
+    before the second decoder and runs that for the sampled token alone,
+    from the :func:`note` triples its trace left
+    (``models/model_base.py`` ``second_decoder_tokens`` writes them)."""
+    return any(site == "second_decoder" for site, _, _ in notes)
+
+
 def select_on_kernel(notes) -> bool:
     """Whether a program of a stack with a learned sparse selection scored
     and searched on the selection kernel, from the :func:`note` triples its

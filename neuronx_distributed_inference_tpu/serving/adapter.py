@@ -925,12 +925,17 @@ class PagedEngineAdapter:
             self.host_stats.update(state_slot_allocs=0, state_slot_frees=0,
                                    state_slots_live=0)
         # a decoder-hybrid-decoder (DecoderSpec.layer_kinds with layers that
-        # read another layer's cache or scan output): the real tokens of each
-        # dispatched chunk that ran that second decoder, exact
+        # read another layer's cache or scan output): the tokens of the
+        # dispatched chunks that ran that second decoder, exact, and the
+        # dispatches in which some row sampled (the others took the chunk
+        # program's empty branch: model_base.second_decoder_tokens)
         kinds = app.spec.layer_kinds or ()
         self._cross_decoder = "cross" in kinds or "gmu" in kinds
         if self._cross_decoder:
-            self.host_stats["prefill_tokens_cross_decoder"] = 0
+            self.host_stats.update(prefill_tokens_cross_decoder=0,
+                                   prefill_dispatches_sampled=0)
+        # ... and the ``last_idx`` of a chunk row that samples nothing
+        self._no_sample = -1 if self._cross_decoder else 0
         # a learned sparse selection (DecoderSpec.sparse): the index keys
         # ride the allocator's blocks, so admission, release, preemption
         # and prefix reuse need nothing new; what it does not run under is
@@ -2563,8 +2568,16 @@ class PagedEngineAdapter:
         real = sum(n for _, _, n, _ in rows)
         self.host_stats["prefill_real_tokens"] += real
         self.host_stats["prefill_padded_tokens"] += pad_rows * width
-        # every chunk walks the whole stack today, its second decoder too
-        through = real if self._cross_decoder else 0
+        # the tokens that ran the second decoder: where the program keeps it
+        # apart (its own record says) one a row of a dispatch that samples
+        # and none of one that does not, else every token
+        through = 0
+        if self._cross_decoder:
+            self.host_stats["prefill_dispatches_sampled"] += bool(final_rows)
+            through = (len(rows) * bool(final_rows)
+                       if kernel_mode.second_decoder_apart(
+                           self.app.paged_program_notes(pad_rows, width))
+                       else real)
         if through:
             self.host_stats["prefill_tokens_cross_decoder"] += through
         self.telemetry.on_prefill_chunk(len(rows), pad_rows, real,
@@ -2608,7 +2621,14 @@ class PagedEngineAdapter:
         ids_w = np.zeros((b, width), np.int32)
         pos_w = np.zeros((b, width), np.int32)
         slot_pos = np.full((b, width), -1, np.int32)
-        last = np.zeros((b,), np.int32)
+        # the token a row samples from; a row whose chunk is not its
+        # prompt's last samples nothing, which a stack with a second decoder
+        # is told by a negative index: its chunk program runs that decoder,
+        # the head and the draw for the sampled token alone, and not at all
+        # in a dispatch where no row samples
+        # (model_base.second_decoder_tokens). Any other stack samples token
+        # 0, which nobody fetches
+        last = np.full((b,), self._no_sample, np.int32)
         for i, (s, lo, n, fin) in enumerate(rows):
             st = self._chunks[s]
             ids_w[i, :n] = st.prompt[lo:lo + n]
@@ -2666,7 +2686,7 @@ class PagedEngineAdapter:
         pos_p = np.tile(dead_pos, (pad_to, 1))
         pos_p[slot_of] = pos_w
         return (spread(ids_w), pos_p, spread(slots, -1), spread(bt),
-                spread(last), spread(seeds),
+                spread(last, self._no_sample), spread(seeds),
                 None if aids is None else spread(aids), None, slot_of)
 
     def _dispatch_prefill_chunk(self, packed, fetch: bool = True):

@@ -274,8 +274,11 @@ def prefill_tokens_cross_decoder_counter(reg):
         "Real prompt tokens of the dispatched prefill chunks that ran the "
         "SECOND decoder of a decoder-hybrid-decoder stack (the layers that "
         "read another layer's cache and the Gated Memory Units, "
-        "DecoderSpec.layer_kinds): every real token while a chunk walks the "
-        "whole stack, one a prompt once a chunk program stops before them",
+        "DecoderSpec.layer_kinds): where the chunk program stops before them "
+        "(model_base.second_decoder_tokens) one a row of a dispatch in which "
+        "some row's first answer is sampled and none of any other dispatch; "
+        "every real token where the program hands out every position's "
+        "logits and walks the whole stack",
         labels=("engine",))
 
 

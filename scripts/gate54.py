@@ -11,20 +11,27 @@ stays runnable (ROADMAP C13).
    (``references/phi4flash.py`` ``CONTROLS``) or on fp8-rounded weights -
    against the SAME served logits.
 2. :func:`long_walk`: what the harness's gate of 128 tokens a row cannot
-   see, at the PUBLISHED window. ``rows`` prompts of ``tokens`` tokens walked
-   through ``PagedEngineAdapter`` with the configuration's own keywords
-   (chunks of 256: the ring of the window layers wraps, the shared pool is
-   read behind thousands of cached tokens by the full layer and the cross
-   layers alike, the Mamba-1 state and tail are carried from chunk to
-   chunk), then ``new_tokens`` teacher-forced decode steps a row; one row is
+   see, at the PUBLISHED window and through the SERVED chunk form (ISSUE
+   59; the gate's full-batch prefill hands out every position's logits and
+   so walks the whole stack). ``rows`` prompts of ``tokens`` tokens walked
+   through ``PagedEngineAdapter`` with the configuration's own keywords in
+   chunks of 256 on the program the benchmark's cells run (``output_logits``
+   off: the walk stops before the second decoder, which runs for the ONE
+   token a prompt that is sampled from, and not at all in a chunk that is
+   not the prompt's last): the ring of the window layers wraps, the shared
+   pool is read behind thousands of cached tokens, the Mamba-1 state and
+   tail are carried from chunk to chunk. Then ``new_tokens`` teacher-forced
+   decode steps a row on the program that hands out every logit; one row is
    released and a NEW prompt takes its slot (its ring and its state), walks
-   its chunks beside the other rows' decode steps and decodes too. Every
-   served position's logits against the reference's, under
-   ``jax.default_matmul_precision("highest")``: the reference runs first, a
-   row at a time and its attention a block of queries at a time, and keeps
-   what the head reads; each dispatch's logits are then held to the head of
-   those rows ON THE DEVICE (a row's logits over 200,064 words do not fit
-   the host whole).
+   its chunks beside the other rows' decode steps and decodes too. What is
+   held to the reference, under ``jax.default_matmul_precision("highest")``:
+   every decode position's logits (they read every cache and state the
+   chunks wrote) and each prompt's FIRST token, which must be a word whose
+   reference logit lies within two tolerances of the reference's largest.
+   The reference runs first, a row at a time and its attention a block of
+   queries at a time, and keeps what the head reads; each step's logits are
+   then held to the head of those rows ON THE DEVICE (a row's logits over
+   200,064 words do not fit the host whole).
 
     python3 scripts/gate54.py [--config phi-4-mini-flash-reasoning]
         [--seed n] [--long 8192] [--rows 4] [--new 64] [--controls a,b]
@@ -95,6 +102,7 @@ def long_walk(cfg, seed, tokens, rows=4, new_tokens=64, block=512,
     import jax.numpy as jnp
     import numpy as np
     from harness import build, weights
+    from neuronx_distributed_inference_tpu.models import model_base
     from neuronx_distributed_inference_tpu.serving import PagedEngineAdapter
     g40 = _gate40()
     gate, n = cfg["gate"], tokens
@@ -141,6 +149,14 @@ def long_walk(cfg, seed, tokens, rows=4, new_tokens=64, block=512,
     vocab = hf["vocab_size"]
     atol, rtol = gate["atol"], gate["rtol"]
 
+    # a chunk (T > 1) runs the program the benchmark's cells run, which hands
+    # out tokens alone; a decode step the one that hands out every logit
+    every_logit = app.get_compiled("paged_forward")
+    served = jax.jit(functools.partial(
+        model_base.paged_forward_step, app.spec,
+        model_base.replace_output_logits(app.tpu_config)),
+        donate_argnums=(1,))
+
     @jax.jit
     def held_to(logits, want_hidden):
         with jax.default_matmul_precision("highest"):
@@ -149,18 +165,27 @@ def long_walk(cfg, seed, tokens, rows=4, new_tokens=64, block=512,
         err = jnp.abs(logits[:, :vocab] - want)
         return (err / (atol + rtol * jnp.abs(want))).max(-1), err.max(-1)
 
-    ratio = [np.full((m,), np.nan, np.float32) for m in lengths]
-    error = [np.zeros((m,), np.float32) for m in lengths]
+    @jax.jit
+    def short_of_the_best(token, want_hidden):
+        # the reference's margin over the served token, in tolerances
+        with jax.default_matmul_precision("highest"):
+            want = embed.astype(jnp.float32) @ want_hidden
+        return (want.max() - want[token]) / (atol + rtol * jnp.abs(want.max()))
+
+    # the decode positions of each sequence, and its first token's margin
+    ratio = [np.full((new_tokens,), np.nan, np.float32) for _ in lengths]
+    error = [np.zeros((new_tokens,), np.float32) for _ in lengths]
+    first = [np.nan] * len(lengths)
     shapes, slots_of = set(), {}
     inner = app._run_paged
 
     def tap(ids_, pos, slots, bt, last, *a, **kw):
-        # every logit the served path computes, held on the device to the
-        # reference's for the sequence whose block table the row carries
+        chunk = np.shape(ids_)[1] > 1
+        app._compiled[("paged_forward", 0)] = served if chunk else every_logit
         o = inner(ids_, pos, slots, bt, last, *a, **kw)
         shapes.add(tuple(np.shape(ids_)))
         owner = {blk[0]: sid for sid, blk in app.kv_mgr.tables.items()}
-        ids_, pos = np.asarray(ids_), np.asarray(pos)
+        ids_, pos, last = np.asarray(ids_), np.asarray(pos), np.asarray(last)
         slots, bt = np.asarray(slots), np.asarray(bt)
         for r in range(ids_.shape[0]):
             live = np.nonzero(slots[r] >= 0)[0]
@@ -170,9 +195,20 @@ def long_walk(cfg, seed, tokens, rows=4, new_tokens=64, block=512,
             if not (ids_[r, live] == streams[sid][at]).all():
                 raise AssertionError(f"sequence {sid} was fed other tokens "
                                      f"than its stream at {at[:4]}")
-            got, err = held_to(o["logits"][r, live[0]:live[-1] + 1],
-                               jnp.asarray(hidden[sid][at]))
-            ratio[sid][at], error[sid][at] = np.asarray(got), np.asarray(err)
+            if not chunk:
+                # every logit of a decode step, held on the device to the
+                # reference's for the sequence whose table the row carries
+                got, err = held_to(o["logits"][r, live[0]:live[-1] + 1],
+                                   jnp.asarray(hidden[sid][at]))
+                at = at - prompt_len[sid]
+                ratio[sid][at], error[sid][at] = (np.asarray(got),
+                                                  np.asarray(err))
+            elif last[r] >= 0:
+                # a prompt's last chunk: its ONE sampled token
+                assert "logits" not in o and pos[r, last[r]] == at[-1] \
+                    == prompt_len[sid] - 1
+                first[sid] = float(short_of_the_best(
+                    o["tokens"][r], jnp.asarray(hidden[sid][at[-1]])))
         return o
     app._run_paged = tap
 
@@ -186,14 +222,14 @@ def long_walk(cfg, seed, tokens, rows=4, new_tokens=64, block=512,
 
     with g40._precision(served_precision):
         ad = PagedEngineAdapter(app, **cfg.get("adapter", {}))
-        first = list(range(rows))
-        ad.add_requests(first, [streams[r][:n].tolist() for r in first])
+        early = list(range(rows))
+        ad.add_requests(early, [streams[r][:n].tolist() for r in early])
         # a deferred prefill walks a chunk before each step; a row whose
         # prompt is in decodes on, teacher-forced, beside the others' chunks
-        while not all(done(r) for r in first):
+        while not all(done(r) for r in early):
             teacher_force(ad)
             ad.step([s for s in ad.seqs if not done(s)])
-        slots_of.update({s: ad._state_slot.get(s) for s in first})
+        slots_of.update({s: ad._state_slot.get(s) for s in early})
         # a row leaves; a NEW prompt takes its slot, its ring and its state
         gone = rows // 2
         ad.release([gone])
@@ -218,8 +254,9 @@ def long_walk(cfg, seed, tokens, rows=4, new_tokens=64, block=512,
     app._run_paged = inner
     del app, ad, inner
     gc.collect()
-    missing = [(sid, int(np.isnan(r).sum())) for sid, r in enumerate(ratio)
-               if np.isnan(r).any()]
+    missing = [(sid, int(np.isnan(r).sum()) + int(np.isnan(first[sid])))
+               for sid, r in enumerate(ratio)
+               if np.isnan(r).any() or np.isnan(first[sid])]
     if missing:
         return dict(out, missing_positions=missing)
 
@@ -228,26 +265,25 @@ def long_walk(cfg, seed, tokens, rows=4, new_tokens=64, block=512,
         return dict(positions=int(x.size), median_ratio=float(np.median(x)),
                     worst_ratio=float(x.max()),
                     held_share=float((x <= 1).mean()))
-    reach = hf["sliding_window"]
-    prefill = [r[:p] for r, p in zip(ratio, prompt_len)]
-    decode = [r[p:] for r, p in zip(ratio, prompt_len)]
     parts = dict(
-        all=part(ratio), prefill=part(prefill), decode=part(decode),
-        first_chunk=part([r[:width] for r in ratio[:rows]]),
-        # positions whose window has left the start behind: the ring has
-        # been overwritten under them
-        past_window=part([r[reach:] for r in ratio]),
-        last_chunk=part([r[max(0, n - width):n] for r in ratio[:rows]]),
+        # every decode position: each reads what the served chunks wrote
+        decode=part(ratio), long_prompts=part(ratio[:rows]),
         reused_slot=part([ratio[rows]]))
     everything = np.concatenate(ratio)
     out.update(
         parts,
-        # the gate's rules (1)-(3) over the walk's positions
+        # a first token may differ from the reference's by a near tie: its
+        # reference logit within two tolerances of the largest
+        first_tokens=dict(prompts=len(first), worst_margin=float(max(first)),
+                          held=int(sum(m <= 2 for m in first))),
+        # the gate's rules (1)-(3) over the walk's decode positions, and
+        # every prompt's first token
         passed=bool(
-            min(parts["prefill"]["held_share"], parts["decode"]["held_share"])
+            parts["decode"]["held_share"]
             >= gate.get("min_positions_held", 1.0)
             and np.median(everything) <= gate.get("median_ratio_max", 1.0)
-            and everything.max() <= gate.get("worst_ratio_max", 1.0)),
+            and everything.max() <= gate.get("worst_ratio_max", 1.0)
+            and max(first) <= 2),
         max_error=float(max(e.max() for e in error)))
     return out
 

@@ -17,13 +17,23 @@ size on the CPU in float32, every served logit held to the plain reference
   (e) the configuration file: every published number, the parameter count
       and the memory arithmetic re-derived from the parameter specs and the
       pools, the toy gate, its controls and faults in the PROGRAM;
-  (f) each refusal of the two tables by name; counters, notes, metrics.
+  (f) each refusal of the two tables by name; counters, notes, metrics;
+  (g) the SERVED chunk form (ISSUE 60; ``output_logits`` off): a chunk's walk
+      stops at the full layer, the second decoder, the head and the draw run
+      for the one token a row that is sampled from, and not at all in a
+      dispatch that samples none - against the whole walk to the last bit of
+      every cache (one row and packed), against the reference through chunks
+      of both widths, a pack of final and non-final rows, decode; the
+      counters; ONE conditional a lowered chunk program, none in the decode
+      step's or in another recurrent stack's; three faults.
 """
 
+import functools
 import importlib.util
 import json
 import math
 import os
+import re
 import sys
 
 import numpy as np
@@ -102,9 +112,12 @@ def gate_weights(ref):
 
 
 def _app(ref, w, hf=HF, **serve):
+    """``output_logits`` on unless ``serve`` says otherwise: every position's
+    logits are handed out, so a chunk walks the whole stack; off is the
+    served form (section (g))."""
     family = get_family("phi4flash")
-    tcfg = TpuConfig(tp_degree=1, dtype="float32", output_logits=True,
-                     **dict(SERVE, **serve))
+    tcfg = TpuConfig(tp_degree=1, dtype="float32",
+                     **{**SERVE, "output_logits": True, **serve})
     app = PagedCausalLMApplication(None, family.config_cls(tcfg, **hf),
                                    family)
     view = weights.HfView(ref.weight_shapes(hf), w,
@@ -222,9 +235,12 @@ def test_a_chunks_of_two_widths_wrap_the_ring_then_decode(ref,
     _check(tap, ref, gate_weights, 8, R21, stream[8])
     stats = ad.host_stats
     assert stats["state_slots_live"] == 2
-    # every chunk walks the whole stack today, its second decoder too
+    # with every position's logits asked for (ISSUE 60), every chunk
+    # walks the whole stack, its second decoder too, and says nothing else
     assert stats["prefill_tokens_cross_decoder"] == \
         stats["prefill_real_tokens"] == 93 + 21
+    assert not any(n["site"] == "second_decoder"
+                   for n in app.warmup_state()["kernels"])
     # the window pool's counters, over the TWO window layers
     assert stats["kv_window_pages_held"] < stats["kv_window_pages_unwindowed"]
     assert stats["kv_tokens_in_window"] == 2 * 24
@@ -607,8 +623,9 @@ def test_e_the_builders_chip_check_runs_at_a_toy_size(ref):
     chip at the published widths) at a toy size: the gate passes, every
     control and the fp8-rounded reference fail it, and the long walk at the
     file's own window (three rows of 150 tokens in chunks of 32 through the
-    adapter's deferral, decode, a released slot taken by a new prompt) holds
-    every position."""
+    adapter's deferral ON THE SERVED CHUNK FORM, the second decoder run for
+    one token a prompt; decode, a released slot taken by a new prompt) holds
+    every decode position and every first token."""
     gate54 = _gate54()
     toy = _toy_file()
     out = gate54.gate_and_controls(toy, seed=SEED,
@@ -623,9 +640,19 @@ def test_e_the_builders_chip_check_runs_at_a_toy_size(ref):
                             served_precision="highest")
     assert walk["window"] == 24 and walk["ring_wraps"] >= 2
     assert walk["slot_reused"] and walk["passed"], walk
-    assert walk["all"]["positions"] == 3 * 158 + 37 + 8
-    assert walk["all"]["held_share"] == 1.0
-    assert walk["all"]["worst_ratio"] < 0.5
+    assert walk["decode"]["positions"] == (3 + 1) * 8
+    assert walk["decode"]["held_share"] == 1.0
+    assert walk["decode"]["worst_ratio"] < 0.5
+    assert walk["first_tokens"] == dict(
+        prompts=4, held=4, worst_margin=walk["first_tokens"]["worst_margin"])
+    # the chunks ran the second decoder for ONE token a row of a dispatch
+    # that samples: four of the 17 (a prompt's last chunk each; under the
+    # budget of 32 tokens a pass two of them carry another prompt's row)
+    stats = walk["host_stats"]
+    assert (stats["prefill_dispatches"], stats["prefill_dispatches_sampled"],
+            stats["prefill_tokens_cross_decoder"]) == (17, 4, 6)
+    assert walk["host_stats"]["prefill_real_tokens"] == 3 * 150 + 37
+    assert any(site == "second_decoder" for site, _, _ in walk["notes"])
     assert walk["blocked_vs_plain_reference"] < 1e-5
     assert (3, 32) in walk["program_shapes"] \
         and (1, 32) in walk["program_shapes"]
@@ -808,3 +835,358 @@ def test_f_the_new_metrics_read_the_new_scopes_and_counters():
     assert roof.readers_and_rings(dict(build.hf_config(cfg),
                                        num_hidden_layers=8)) == (2, 2)
     assert roof.readers_and_rings({"model_type": "llama"}) is None
+
+
+# ---------------------------------------------------------------------------
+# (g) the served chunk form: the second decoder apart (ISSUE 60)
+# ---------------------------------------------------------------------------
+
+class DrawTap:
+    """The logits every draw of the SERVED programs reads, by sequence and
+    position (``output_logits`` off: a program hands out tokens alone):
+    ``model_base._draw`` behind a host callback, which a second decoder that
+    is skipped never reaches. ``heads`` counts the head's runs a dispatch,
+    ``lasts`` keeps each dispatch's ``last_idx``."""
+
+    def __init__(self, monkeypatch, app):
+        self.app, self.by_seq, self.shapes, self.heads = app, {}, [], []
+        self._seen, self.lasts = [], []
+        draw = model_base._draw
+
+        def tapped(cfg, logits, *a):
+            jax.debug.callback(lambda x: self._seen.append(np.asarray(x)),
+                               logits)
+            return draw(cfg, logits, *a)
+        monkeypatch.setattr(model_base, "_draw", tapped)
+        self._inner = app._run_paged
+        app._run_paged = self._run
+
+    def _run(self, ids, pos, slots, bt, last, *a, **kw):
+        out = self._inner(ids, pos, slots, bt, last, *a, **kw)
+        self.tokens = np.asarray(out["tokens"])
+        jax.effects_barrier()
+        assert "logits" not in out
+        self.shapes.append(tuple(np.shape(ids)))
+        self.heads.append(len(self._seen))
+        self.lasts.append(np.asarray(last).tolist())
+        owner = {blocks[0]: sid
+                 for sid, blocks in self.app.kv_mgr.tables.items()}
+        pos, slots, bt = np.asarray(pos), np.asarray(slots), np.asarray(bt)
+        for logits in self._seen:
+            for r, t in enumerate(np.asarray(last)):
+                if t >= 0 and slots[r, t] >= 0:
+                    self.by_seq.setdefault(owner[int(bt[r, 0])], {})[
+                        int(pos[r, t])] = logits[r]
+        self._seen.clear()
+        return out
+
+
+def _check_served(tap, ref, w, sid, prompt, stream, hf=HF, atol=ATOL):
+    """What the served form hands out of ``sid``: the first token and every
+    decode step's logits, against the reference over the same tokens."""
+    fed = prompt + stream[:-1]
+    want = _want(ref, w, fed, hf)[len(prompt) - 1:]
+    got = tap.by_seq[sid]
+    assert sorted(got) == list(range(len(prompt) - 1, len(fed))), sorted(got)
+    np.testing.assert_allclose(np.stack([got[p] for p in sorted(got)]), want,
+                               atol=atol, rtol=1e-4)
+    assert stream == want.argmax(-1).tolist()
+
+
+def _serve_two_prompts(ref, w, monkeypatch):
+    """P93 (5 x 16 and 13 in the 16 bucket) and R21 (16, then 5 in the 8
+    bucket) through the one-row chunk programs of the served form, then ten
+    decode steps, every draw's logits held to the reference."""
+    app = _app(ref, w, output_logits=False)
+    ad = PagedEngineAdapter(app)
+    tap = DrawTap(monkeypatch, app)
+    stream = {7: [ad.add_requests([7], [P93])[7]]}
+    stream[8] = [ad.add_requests([8], [R21])[8]]
+    assert tap.shapes == [(1, 16)] * 7 + [(1, 8)]
+    # a chunk that is not its prompt's last says so; the head ran where a
+    # prompt's LAST chunk went, and nowhere else
+    assert tap.lasts == [[-1]] * 5 + [[12], [-1], [4]]
+    assert tap.heads == [0] * 5 + [1, 0, 1]
+    _decode(ad, [7, 8], stream, 10)
+    assert tap.heads[8:] == [1] * 10
+    _check_served(tap, ref, w, 7, P93, stream[7])
+    _check_served(tap, ref, w, 8, R21, stream[8])
+    return app, ad
+
+
+#: what each case of the bit-for-bit comparison admits at once, with the
+#: dispatches it takes: a prompt of six one-row chunks; three prompts in two
+#: full-batch packs (rows in slot order, a dead row beside them, a row that
+#: samples beside rows that do not) and Q45's last chunk alone
+_WALKS = {"one_row": ({7: P93}, [(1, 16)] * 6, [0] * 5 + [1]),
+          "packed": ({1: Q45, 2: R21, 3: S12},
+                     [(BATCH, 16), (BATCH, 16), (1, 16)], [1, 1, 1])}
+
+
+@pytest.mark.parametrize("walk", sorted(_WALKS))
+def test_g_the_chunk_apart_is_the_whole_walk_to_the_last_bit(
+        ref, gate_weights, monkeypatch, walk):
+    """The same prompts (three chunks or more) through the whole walk and
+    through the served form, one row at a time and packed: every pool, ring,
+    state and tail equal to the last bit after the chunks and after decode
+    steps (the first decoder is the same instructions), the same tokens, and
+    the sampled positions' logits within 1e-5 of the whole walk's."""
+    prompts, shapes, heads = _WALKS[walk]
+    both = {}
+    for name in ("whole", "served"):
+        app = _app(ref, gate_weights, output_logits=name == "whole")
+        ad = PagedEngineAdapter(app)
+        tap = (LogitTap(app) if name == "whole"
+               else DrawTap(monkeypatch, app))
+        stream = {s: [t] for s, t in ad.add_requests(
+            list(prompts), list(prompts.values())).items()}
+        assert tap.shapes == shapes
+        after_chunks = {k: np.asarray(v) for k, v in app.cache.items()}
+        _decode(ad, list(prompts), stream, 3)
+        both[name] = (stream, after_chunks,
+                      {k: np.asarray(v) for k, v in app.cache.items()}, tap)
+    assert both["whole"][0] == both["served"][0]
+    assert set(both["whole"][1]) == {"k", "v", "k_w", "v_w", "conv_x", "ssm"}
+    for at in (1, 2):
+        for k, v in both["whole"][at].items():
+            np.testing.assert_array_equal(v, both["served"][at][k], err_msg=k)
+    whole, served = both["whole"][3], both["served"][3]
+    for sid, prompt in prompts.items():
+        n = len(prompt)
+        assert sorted(served.by_seq[sid]) == list(range(n - 1, n + 3))
+        for p, got in served.by_seq[sid].items():
+            np.testing.assert_allclose(got, whole.by_seq[sid][p], atol=1e-5,
+                                       rtol=0)
+    # the head ran in each dispatch that samples and in the decode steps
+    assert served.heads == heads + [1] * 3
+
+
+def test_g_a_dispatch_that_samples_nothing_takes_the_empty_branch(
+        ref, gate_weights, monkeypatch):
+    """By the step function itself: a chunk whose rows all carry a negative
+    ``last_idx`` writes its caches and states as a sampling one does, never
+    reaches the head and hands out zeros, which nobody fetches. Pad rows are
+    clones of row 0 (a program of two rows over ONE state slot): beside a
+    row that samples nothing they sample nothing, beside one that samples
+    they draw its token."""
+    served = _app(ref, gate_weights, output_logits=False)
+    ad = PagedEngineAdapter(served)
+    tap = DrawTap(monkeypatch, served)
+    ad.add_requests([7], [P93[:40]])
+    table = served.kv_mgr.block_table_array([7], served.max_blocks)
+    pos = 40 + np.arange(16, dtype=np.int32)[None]
+    ids = np.asarray([P93[40:56]], np.int32)
+    slots = table[0, pos // 8] * 8 + pos % 8
+    slot = np.asarray([ad._state_slot[7]], np.int32)
+
+    def run(rows, last):
+        before = served.cache
+        served.cache = jax.tree.map(jnp.copy, before)
+        served._run_paged(*(np.repeat(x, rows, 0)
+                            for x in (ids, pos, slots, table)),
+                          np.full((rows,), last, np.int32),
+                          state_slots=np.repeat(slot, rows))
+        after, served.cache = served.cache, before
+        return ({k: np.asarray(v) for k, v in after.items()},
+                tap.heads[-1], tap.tokens)
+    empty, heads, tokens = run(1, -1)
+    assert heads == 0 and (tokens == 0).all()
+    sampled, heads, token = run(1, 15)
+    assert heads == 1 and token.shape == (1,)
+    for rows, last, ran in ((2, -1, 0), (2, 15, 1)):
+        cloned, heads, tokens = run(rows, last)
+        assert heads == ran and (tokens == (token[0] if ran else 0)).all()
+        for k, v in empty.items():
+            np.testing.assert_array_equal(v, sampled[k], err_msg=k)
+            np.testing.assert_array_equal(v, cloned[k], err_msg=k)
+
+
+def test_g_chunks_of_both_widths_then_decode_are_the_references(
+        ref, gate_weights, monkeypatch):
+    """The served form against the plain reference, its non-final chunks on
+    the empty branch; the counters: ONE token a dispatch that samples, the
+    dispatches by branch; one program a (kind, width) as before."""
+    telemetry.enable()
+    try:
+        app, ad = _serve_two_prompts(ref, gate_weights, monkeypatch)
+        stats = ad.host_stats
+        assert stats["prefill_real_tokens"] == 93 + 21
+        assert (stats["prefill_dispatches"],
+                stats["prefill_dispatches_sampled"],
+                stats["prefill_tokens_cross_decoder"]) == (8, 2, 2)
+        snap = telemetry.get_registry().snapshot()["metrics"]
+        series = snap[tmetrics.PREFILL_TOKENS_CROSS_DECODER_TOTAL]["series"]
+        assert sum(s["value"] for s in series) == 2
+    finally:
+        telemetry.disable()
+    notes = {(n["site"], n["reason"]) for n in app.warmup_state()["kernels"]}
+    for width in (8, 16):
+        assert ("second_decoder",
+                "apart: layers 6-7, the head and the draw on one token a row "
+                f"of {width}, where a row samples") in notes
+    # one program a (kind, width), as before: the five a recurrent stack has
+    report = precompile(app)
+    assert sorted((g["kind"], g["bucket"]) for g in report["graphs"]) \
+        == [("paged", 1), ("paged", 8), ("paged", 16),
+            ("paged_pack", 8), ("paged_pack", 16)]
+
+
+def test_g_a_pack_of_final_and_non_final_rows(ref, gate_weights,
+                                              monkeypatch):
+    """Three prompts at once: the full-batch pack, rows in slot order with a
+    dead row beside them. The first pack holds S12's LAST chunk beside
+    chunks of Q45 and R21 that sample nothing, the second R21's last beside
+    Q45's second, the third is Q45's last alone; each prompt's first token
+    and decode logits are the reference's, and the counter counts the rows
+    of the three dispatches, all of which sample."""
+    app = _app(ref, gate_weights, output_logits=False)
+    ad = PagedEngineAdapter(app)
+    tap = DrawTap(monkeypatch, app)
+    prompts = {1: Q45, 2: R21, 3: S12}
+    stream = {s: [t] for s, t in ad.add_requests(
+        list(prompts), list(prompts.values())).items()}
+    assert tap.shapes == [(BATCH, 16), (BATCH, 16), (1, 16)]
+    assert tap.heads == [1, 1, 1]
+    # rows by state slot: a dead row and a row that samples nothing say -1
+    assert sorted(tap.lasts[0]) == [-1, -1, -1, 11]
+    assert sorted(tap.lasts[1]) == [-1, -1, -1, 4] and tap.lasts[2] == [12]
+    assert (ad.host_stats["prefill_dispatches_sampled"],
+            ad.host_stats["prefill_tokens_cross_decoder"]) == (3, 3 + 2 + 1)
+    _decode(ad, [1, 2, 3], stream, 4)
+    for sid, prompt in prompts.items():
+        _check_served(tap, ref, gate_weights, sid, prompt, stream[sid])
+    # packs in which NO row samples: three prompts' first chunks
+    ad.release([1, 2, 3])
+    before = len(tap.heads)
+    sampled = ad.host_stats["prefill_dispatches_sampled"]
+    ad.add_requests([4, 5, 6], [P93, Q45, P93[:40]])
+    packs = list(zip(tap.shapes[before:], tap.heads[before:]))
+    assert packs[0] == ((BATCH, 16), 0) and packs[1] == ((BATCH, 16), 0)
+    assert ((BATCH, 16), 1) in packs          # Q45's and P93[:40]'s last
+    assert ad.host_stats["prefill_dispatches_sampled"] - sampled \
+        == sum(heads for _, heads in packs) < len(packs)
+
+
+def _lowered(app, rows, width):
+    """The lowered text of ``app``'s paged step of ``rows`` x ``width``."""
+    i32 = jnp.int32
+    kw = ({"state_slots": jnp.zeros((rows,), i32)}
+          if rows != app.tpu_config.batch_size else {})
+    return jax.jit(functools.partial(
+        model_base.paged_forward_step, app.spec, app.tpu_config)).lower(
+        app.params, app.cache, *(jnp.zeros((rows, width), i32),) * 2,
+        jnp.full((rows, width), -1, i32),
+        jnp.zeros((rows, app.max_blocks), i32), jnp.zeros((rows,), i32),
+        None, jax.random.PRNGKey(0), **kw).as_text()
+
+
+def _conditionals(text):
+    return len(re.findall(r"\bstablehlo\.(?:case|if)\b", text))
+
+
+def test_g_one_conditional_a_chunk_program_and_none_with_every_logit(
+        ref, gate_weights):
+    """A static choice by what the program hands out and by the spec's
+    ``layer_kinds``, no option: the lowered chunk programs (one row, the
+    pack) hold exactly ONE conditional with ``output_logits`` off and none
+    with it on (the whole walk over every token); the decode step holds none
+    either way."""
+    served = _app(ref, gate_weights, output_logits=False)
+    assert [_conditionals(_lowered(served, *shape)) for shape in
+            ((1, 16), (1, 8), (BATCH, 16), (BATCH, 1))] == [1, 1, 1, 0]
+    whole = _app(ref, gate_weights)
+    assert [_conditionals(_lowered(whole, *shape)) for shape in
+            ((1, 16), (BATCH, 16), (BATCH, 1))] == [0, 0, 0]
+    assert model_base.second_decoder_start(served.spec) == 6
+    # a stack whose LAST layer writes a cache has no second decoder
+    kinds = served.spec.layer_kinds
+    for tail, start in ((("cross", "full"), None), (("full", "cross"), 7),
+                        (("gmu", "gmu"), 6)):
+        spec = served.spec.__class__(**dict(
+            served.spec.__dict__, layer_kinds=kinds[:6] + tail))
+        assert model_base.second_decoder_start(spec) == start
+    assert model_base.walk_part(served.spec, {}, 16)[:2] == (0, 8)
+    assert model_base.walk_part(
+        served.spec, {"sampled": jnp.zeros((1,), jnp.int32)}, 1)[:2] == (0, 8)
+
+
+@pytest.mark.parametrize("stack", ["test_recurrent_paged",
+                                   "test_olmo_hybrid_paged",
+                                   "test_qwen3_next_paged"])
+def test_g_a_stack_without_a_second_decoder_lowers_no_conditional(stack):
+    """granite's, olmo-hybrid's and qwen3-next's walk is this one
+    (``run_layers_ssm``) and their step ``paged_forward_step``: without
+    ``layer_kinds`` that end in layers which write nothing, the served chunk
+    programs and the decode step lower to NO conditional, nothing is
+    gathered before the last layer, and the adapter's chunk rows that
+    sample nothing say 0 as before."""
+    toy = importlib.import_module(stack)
+    family = get_family(toy.HF["model_type"])
+    tcfg = TpuConfig(tp_degree=1, dtype="float32", **toy.SERVE)
+    app = PagedCausalLMApplication(None, family.config_cls(tcfg, **toy.HF),
+                                   family).init_random_weights().init_cache()
+    assert app.spec.layer_kinds is None and not tcfg.output_logits
+    assert model_base.second_decoder_start(app.spec) is None
+    assert model_base.walk_part(app.spec, {}, 16) == (
+        0, app.spec.num_layers, None, None)
+    for shape in ((1, 16), (toy.BATCH, 16), (toy.BATCH, 1)):
+        text = _lowered(app, *shape)
+        assert _conditionals(text) == 0, shape
+    lasts = []
+    inner = app._run_paged
+    app._run_paged = lambda i, p, s, b, last, *a, **kw: (
+        lasts.append(np.asarray(last).tolist()),
+        inner(i, p, s, b, last, *a, **kw))[1]
+    ad = PagedEngineAdapter(app)
+    ad.add_requests([7], [P93[:37]])
+    width = max(toy.SERVE["context_encoding_buckets"])
+    assert lasts == [[0]] * (36 // width) + [[36 % width]]
+    assert "prefill_dispatches_sampled" not in ad.host_stats
+
+
+def _second_decoder_at_the_token_before(monkeypatch):
+    tokens_of = model_base.second_decoder_tokens
+
+    def broken(spec, cfg, params, cache, hidden, handed, pos, slots, bt,
+               last, *a):
+        return tokens_of(spec, cfg, params, cache, hidden, handed, pos,
+                         slots, bt, jnp.where(last > 0, last - 1, last), *a)
+    monkeypatch.setattr(model_base, "second_decoder_tokens", broken)
+
+
+def _scan_output_of_the_last_column(monkeypatch):
+    tokens_of = model_base.second_decoder_tokens
+
+    def broken(spec, cfg, params, cache, hidden, handed, *a):
+        start, shared, memory = handed
+        return tokens_of(spec, cfg, params, cache, hidden, (
+            start, shared, jnp.broadcast_to(memory[:, -1:], memory.shape)),
+            *a)
+    monkeypatch.setattr(model_base, "second_decoder_tokens", broken)
+
+
+def _no_second_decoder_for_the_sampled_token(monkeypatch):
+    walk = model_base.run_layers_ssm
+
+    def skipping(spec, params, cache, hidden, *a, part=None, **kw):
+        if part is not None:      # the sampled token's walk: layers 6-7
+            return hidden, cache, {}
+        return walk(spec, params, cache, hidden, *a, **kw)
+    monkeypatch.setattr(model_base, "run_layers_ssm", skipping)
+
+
+@pytest.mark.parametrize("fault", [_second_decoder_at_the_token_before,
+                                   _scan_output_of_the_last_column,
+                                   _no_second_decoder_for_the_sampled_token],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_g_a_fault_in_the_sampled_tokens_walk_fails_the_reference(
+        ref, gate_weights, monkeypatch, fault):
+    """The controls of the comparison above: the second decoder run at the
+    WRONG token (the one before the sampled one); the Gated Memory Unit
+    gating the scan output's LAST column of the padded width (P93's last
+    chunk is 13 tokens in the 16 bucket); the second decoder SKIPPED for the
+    sampled token too (the head on the first decoder's output) - each misses
+    the reference's first token logits by far more than the check allows."""
+    fault(monkeypatch)
+    with pytest.raises(AssertionError, match="Mismatched elements"):
+        _serve_two_prompts(ref, gate_weights, monkeypatch)
