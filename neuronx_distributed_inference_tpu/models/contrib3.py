@@ -1,6 +1,6 @@
 """Contrib hub wave 3 (reference: contrib/models/ — SURVEY §2.7):
-openai-gpt (post-LN GPT-1), LFM2 (hybrid short-conv + attention),
-VaultGemma, Apertus (xIELU), Phi-3.5-MoE (sparsemixer routing)."""
+openai-gpt (post-LN GPT-1), VaultGemma, Apertus (xIELU), Phi-3.5-MoE
+(sparsemixer routing). LFM2 moved to models/lfm2/ with its expert sibling."""
 
 from __future__ import annotations
 
@@ -11,8 +11,6 @@ import numpy as np
 
 from ..config import InferenceConfig
 from ..modules.moe import MoESpec
-from ..modules.ssm import SSMSpec
-from ..parallel.layers import place_q_weight, replicate_kv_weight
 from .contrib import GPT2Family, _SimpleConfig, _ident, _t
 from .family import DecoderFamily, register_family
 from .model_base import spec_from_config
@@ -65,139 +63,6 @@ class OpenAIGPTFamily(GPT2Family):
         out.pop("final_norm", None)
         out.pop("final_norm_b", None)
         return out
-
-
-class Lfm2InferenceConfig(InferenceConfig):
-    def get_required_attributes(self) -> List[str]:
-        return ["hidden_size", "num_attention_heads", "num_hidden_layers",
-                "vocab_size", "layer_types", "conv_L_cache"]
-
-    def get_text_config(self):
-        return self
-
-
-@register_family("lfm2")
-class Lfm2Family(DecoderFamily):
-    """Liquid LFM2 (reference: contrib/models/lfm2-2.6b): interleaved
-    gated-short-conv and attention layers on the recurrent state axis,
-    per-head q/k RMSNorm applied BEFORE rope, w1/w3/w2 GLU MLP."""
-
-    config_cls = Lfm2InferenceConfig
-
-    @classmethod
-    def build_spec(cls, config, tp_degree=None):
-        H = config.hidden_size
-        inter = config.intermediate_size
-        if getattr(config, "block_auto_adjust_ff_dim", False):
-            inter = int(2 * inter / 3)
-            mult = getattr(config, "block_ffn_dim_multiplier", None)
-            if mult is not None:
-                inter = int(mult * inter)
-            mo = int(getattr(config, "block_multiple_of", 256))
-            inter = mo * ((inter + mo - 1) // mo)
-        lt = list(config.layer_types)
-        return spec_from_config(
-            config, tp_degree,
-            intermediate_size=inter,
-            rms_eps=float(getattr(config, "norm_eps", 1e-5)),
-            qk_norm=True,
-            ssm=SSMSpec(kind="shortconv", d_inner=H, num_heads=1,
-                        head_dim=H,
-                        d_conv=int(config.conv_L_cache),
-                        conv_bias=bool(getattr(config, "conv_bias", False))),
-            ssm_pattern=tuple(t == "conv" for t in lt),
-            ssm_parallel=False,
-            tie_word_embeddings=bool(getattr(config, "tie_word_embeddings",
-                                             True)),
-        )
-
-    @classmethod
-    def convert_hf_state_dict(cls, sd, spec):
-        g, D = spec.gqa, spec.head_dim
-        H = spec.hidden_size
-        pat = spec.resolved_ssm_pattern
-
-        def get(n):
-            return np.asarray(sd[n])
-
-        def stack(idx, fmt, tr):
-            return np.stack([tr(get(fmt.format(i=i))) for i in idx])
-
-        all_i = list(range(spec.num_layers))
-        attn_i = [i for i in all_i if not pat[i]]
-        conv_i = [i for i in all_i if pat[i]]
-        p = "model.layers.{i}."
-
-        layers = {
-            "input_norm": stack(all_i, p + "operator_norm.weight", _ident),
-            "post_norm": stack(all_i, p + "ffn_norm.weight", _ident),
-            "gate_proj": stack(all_i, p + "feed_forward.w1.weight", _t),
-            "up_proj": stack(all_i, p + "feed_forward.w3.weight", _t),
-            "down_proj": stack(all_i, p + "feed_forward.w2.weight", _t),
-        }
-        attn_layers = {} if not attn_i else {
-            "qkv_proj": np.concatenate([
-                stack(attn_i, p + "self_attn.q_proj.weight",
-                      lambda w: place_q_weight(_t(w), g, D, axis=-1)),
-                stack(attn_i, p + "self_attn.k_proj.weight",
-                      lambda w: replicate_kv_weight(_t(w), g, D, axis=-1)),
-                stack(attn_i, p + "self_attn.v_proj.weight",
-                      lambda w: replicate_kv_weight(_t(w), g, D, axis=-1)),
-            ], axis=-1),
-            "o_proj": stack(attn_i, p + "self_attn.out_proj.weight",
-                            lambda w: place_q_weight(_t(w), g, D, axis=0)),
-            "q_norm": stack(attn_i, p + "self_attn.q_layernorm.weight",
-                            _ident),
-            "k_norm": stack(attn_i, p + "self_attn.k_layernorm.weight",
-                            _ident),
-        }
-        ssm_layers = {} if not conv_i else {
-            # in_proj rows [B | C | x] (HF BCx chunk order)
-            "sc_in_b": stack(conv_i, p + "conv.in_proj.weight",
-                             lambda w: _t(np.asarray(w)[:H])),
-            "sc_in_c": stack(conv_i, p + "conv.in_proj.weight",
-                             lambda w: _t(np.asarray(w)[H:2 * H])),
-            "sc_in_x": stack(conv_i, p + "conv.in_proj.weight",
-                             lambda w: _t(np.asarray(w)[2 * H:])),
-            "sc_conv": stack(conv_i, p + "conv.conv.weight",
-                             lambda w: np.asarray(w)[:, 0, :]),
-            "sc_out": stack(conv_i, p + "conv.out_proj.weight", _t),
-        }
-        if spec.ssm.conv_bias and conv_i:
-            ssm_layers["sc_conv_b"] = stack(
-                conv_i, p + "conv.conv.bias", _ident)
-            ssm_layers["sc_out_b"] = stack(
-                conv_i, p + "conv.out_proj.bias", _ident)
-            for part, key in (("b", "sc_in_b_b"), ("c", "sc_in_c_b"),
-                              ("x", "sc_in_x_b")):
-                lo = {"b": 0, "c": H, "x": 2 * H}[part]
-                ssm_layers[key] = stack(
-                    conv_i, p + "conv.in_proj.bias",
-                    lambda bvec, lo=lo: np.asarray(bvec)[lo:lo + H])
-
-        def vpad(w):
-            if w.shape[0] < spec.padded_vocab:
-                w = np.pad(w, [(0, spec.padded_vocab - w.shape[0]), (0, 0)])
-            return w
-
-        out = {
-            "embed": vpad(get("model.embed_tokens.weight")),
-            "layers": layers,
-            "final_norm": get("model.embedding_norm.weight"),
-        }
-        if attn_layers:
-            out["attn_layers"] = attn_layers
-        if ssm_layers:
-            out["ssm_layers"] = ssm_layers
-        if not spec.tie_word_embeddings:
-            out["lm_head"] = np.ascontiguousarray(
-                vpad(get("lm_head.weight")).T)
-        return out
-
-    @classmethod
-    def load_hf_model(cls, model_path: str):
-        import transformers
-        return transformers.Lfm2ForCausalLM.from_pretrained(model_path)
 
 
 @register_family("vaultgemma")

@@ -678,6 +678,15 @@ def _moe_param_specs(spec: DecoderSpec, L: int) -> Dict[str, ParamSpec]:
     return layers
 
 
+def mlp_stack(spec: DecoderSpec, i: int) -> Tuple[str, int]:
+    """Where an interleaved recurrent stack keeps layer ``i``'s norms and
+    MLP: ``(stack name, index in it)``. One stack, "layers", unless leading
+    dense layers stand under expert layers (``first_dense``): then the
+    expert layers' are "moe_layers"."""
+    nd = spec.first_dense if spec.moe is not None else 0
+    return ("moe_layers", i - nd) if 0 < nd <= i else ("layers", i)
+
+
 def decoder_param_specs(spec: DecoderSpec) -> Dict[str, Any]:
     """Shapes + shardings of the full param tree.
 
@@ -709,7 +718,8 @@ def decoder_param_specs(spec: DecoderSpec) -> Dict[str, Any]:
         out["layers"] = pairs
         if spec.moe is not None:
             out["moe_layers"] = _moe_param_specs(spec, L)
-    elif spec.moe is not None and spec.first_dense > 0:
+    elif spec.moe is not None and spec.first_dense > 0 and (
+            spec.ssm is None or spec.ssm_parallel):
         n_dense, n_moe = spec.first_dense, spec.num_moe_layers
         dense = _attn_param_specs(spec, n_dense)
         dense.update(_dense_mlp_param_specs(spec, n_dense))
@@ -734,17 +744,25 @@ def decoder_param_specs(spec: DecoderSpec) -> Dict[str, Any]:
         # holds every layer's norms + MLP; attention weights stack over the
         # attention layers only ("attn_layers"), SSM weights over the
         # recurrent layers ("ssm_layers") — SSM-only layers carry no dead
-        # attention params and no KV cache rows
+        # attention params and no KV cache rows. Leading dense layers under
+        # an expert stack (first_dense) split the norms + MLP as the
+        # attention stacks do: the dense layers' in "layers", the expert
+        # layers' in "moe_layers"
         norm_keys = ("input_norm", "post_norm", "input_norm_b", "post_norm_b",
                      "post_attn_norm", "post_ff_norm")
-        full = _attn_param_specs(spec, L)
         # a post-norm stack has no input norms: its walk reads the two
         # output norms only
         walked = norm_keys[4:] if spec.norm_position == "post" else norm_keys
-        shared = {k: v for k, v in full.items() if k in walked}
-        shared.update(_dense_mlp_param_specs(spec, L) if spec.moe is None
-                      else _moe_param_specs(spec, L))
-        out["layers"] = shared
+        n_moe = spec.num_moe_layers
+        for name, n, mlp in ((mlp_stack(spec, 0)[0], L - n_moe,
+                              _dense_mlp_param_specs),
+                             (mlp_stack(spec, L - 1)[0], n_moe,
+                              _moe_param_specs)):
+            if n:
+                out[name] = {k: v
+                             for k, v in _attn_param_specs(spec, n).items()
+                             if k in walked}
+                out[name].update(mlp(spec, n))
         if spec.num_attn_layers:
             attn_full = _attn_param_specs(spec, spec.num_attn_layers)
             out["attn_layers"] = {k: v for k, v in attn_full.items()
@@ -1982,10 +2000,9 @@ RECURRENT_UNSUPPORTED = {
     "paged parallel hybrid": "a layer running attention NEXT TO its mixer "
                              "(ssm_parallel) has not been walked on the "
                              "paged path",
-    "paged rglru / shortconv state": "the rglru and shortconv blocks "
-                                     "prefill from zero; only the mamba2, "
-                                     "gated_delta and mamba1 kinds continue "
-                                     "from a carried state and conv tail",
+    "paged rglru state": "the rglru block prefills from zero; only the "
+                         "mamba2, gated_delta, mamba1 and shortconv kinds "
+                         "continue from a carried state and conv tail",
     "host KV spill / handoff": "a spilled or handed-off block carries KV "
                                "only, not the state that goes with it",
     "contiguous decoder-hybrid-decoder": "layers that read another layer's "
@@ -2775,7 +2792,7 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
         spec.sandwich_norm and not post_norm and "sandwich norm",
         paged and spec.ssm_parallel and "paged parallel hybrid",
         paged and s.kind not in ssm_mod.CONTINUING_KINDS
-        and "paged rglru / shortconv state"])
+        and "paged rglru state"])
     kinds = spec.layer_kinds
     refuse_recurrent([kinds is not None and not paged
                       and "contiguous decoder-hybrid-decoder"])
@@ -2806,10 +2823,11 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
                 f"{what} {ssm_mod.state_kernel_note(s, cache['ssm'])}")
         if kinds is not None and part is None:
             _note_pools_by_kind(spec, cache, ai)
-        if state_slots is None and hidden.shape[0] != new_state["ssm"].shape[1]:
+        n_slots = new_state[state_keys[0]].shape[1]
+        if state_slots is None and hidden.shape[0] != n_slots:
             raise ValueError(
                 f"a paged step of {hidden.shape[0]} rows over "
-                f"{new_state['ssm'].shape[1]} state slots needs state_slots "
+                f"{n_slots} state slots needs state_slots "
                 "(one slot index a row); without it row i is slot i")
     rm = spec.residual_multiplier
 
@@ -2818,14 +2836,16 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
         return res + (branch if rm == 1.0 else rm * branch)
 
     not_local = jnp.asarray(False)
-    # the MLP kind is the spec's: dense, or the routed block. Its expert
-    # leaves stay in their stack where a custom call reads them in place
-    # (the grouped matmuls, the few-token kernel: run_layer_slice has the
+    # a layer's MLP is dense or the routed block (under an expert stack the
+    # first_dense leading layers are dense: mlp_stack). The expert leaves
+    # stay in their stack where a custom call reads them in place (the
+    # grouped matmuls, the few-token kernel: run_layer_slice has the
     # reason); the dense path's static slice fuses into its einsum
-    mlp_kind = "dense" if spec.moe is None else "moe"
+    n_dense = spec.num_layers - spec.num_moe_layers
     in_place = (moe_mod.stack_leaves(
-        spec.moe, hidden.shape[0] * hidden.shape[1], params["layers"])
-        if spec.moe is not None else ())
+        spec.moe, hidden.shape[0] * hidden.shape[1],
+        params[mlp_stack(spec, spec.num_layers - 1)[0]])
+        if spec.num_moe_layers else ())
     # a decode step over expert layers counts its routing and its reads
     tally = [] if (paged and hidden.shape[1] == 1
                    and spec.moe is not None) else None
@@ -2843,11 +2863,14 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
         has_ssm = bool(pat[i])
         has_attn = (spec.ssm_parallel or not has_ssm) if kind is None \
             else kind in ("window", "full")
-        lw = jax.tree.map(lambda a: a[i],
-                          {k: a for k, a in params["layers"].items()
+        mlp_kind = "dense" if i < n_dense else "moe"
+        stack, jm = mlp_stack(spec, i)
+        stack = params[stack]
+        lw = jax.tree.map(lambda a: a[jm],
+                          {k: a for k, a in stack.items()
                            if k not in in_place})
-        lw.update({k: moe_mod.LayerOfStack(params["layers"][k], i)
-                   for k in in_place})
+        lw.update({k: moe_mod.LayerOfStack(stack[k], jm)
+                   for k in in_place if k in stack})
         if has_attn and "attn_layers" in params:
             ja = attn_i
             lw = {**lw, **jax.tree.map(lambda a: a[ja], params["attn_layers"])}
@@ -3853,7 +3876,7 @@ def spec_from_config(config: InferenceConfig, tp_degree: Optional[int] = None,
             paged and tcfg.decode_chunk_tokens > 1 and "fused decode loop",
             paged and kw.get("ssm_parallel") and "paged parallel hybrid",
             paged and kw["ssm"].kind not in ssm_mod.CONTINUING_KINDS
-            and "paged rglru / shortconv state"])
+            and "paged rglru state"])
         if kw.get("layer_kinds") is not None:
             _check_layer_kinds(kw, paged, tp)
         # the recurrent state replaces long-range KV; keep the attention

@@ -164,6 +164,9 @@ class MoESpec:
     # attention block, so the routing is known before attention runs. The
     # layer walk hands ``moe_block`` that array as ``router_x``
     router_pre_attn: bool = False
+    # added to the sum the top-k affinities are renormalised by (LFM2-MoE:
+    # ``w / (sum w + 1e-6)``); 0 = the plain sum
+    topk_norm_eps: float = 0.0
 
     @property
     def num_routed(self) -> int:
@@ -317,7 +320,8 @@ def route_groups(moe: MoESpec, h: jnp.ndarray, router_w: jnp.ndarray,
         top_vals = jax.nn.softmax(top_vals, axis=-1)
     if moe.normalize_topk:
         top_vals = top_vals / jnp.maximum(
-            jnp.sum(top_vals, axis=-1, keepdims=True), 1e-20)
+            jnp.sum(top_vals, axis=-1, keepdims=True) + moe.topk_norm_eps,
+            1e-20)
     if moe.routed_scaling is not None:
         top_vals = top_vals * moe.routed_scaling
     return top_vals, top_idx, groups

@@ -232,7 +232,9 @@ def ssm_state_shapes(s: SSMSpec, Ls: int, batch: int, dtype
             "ssm": ((Ls, batch, s.d_state, s.d_inner), jnp.float32),
         }
     if s.kind == "shortconv":
-        return {"conv_x": ((Ls, batch, s.d_inner, K1), dtype)}
+        # time-major, as mamba1's: the channels fill the lanes (channels-
+        # major, the device pads K - 1 = 2 lanes to 128)
+        return {"conv_x": ((Ls, batch, K1, s.d_inner), dtype)}
     return {
         "conv_x": ((Ls, batch, s.d_inner, K1), dtype),
         "ssm": ((Ls, batch, s.d_inner), jnp.float32),
@@ -268,7 +270,7 @@ def ssm_state_pspecs(s: SSMSpec) -> Dict[str, P]:
         return {"conv_x": P(None, AXIS_DP, None, None),
                 "ssm": P(None, AXIS_DP, None, None)}
     if s.kind == "shortconv":
-        return {"conv_x": P(None, AXIS_DP, AXIS_MP, None)}
+        return {"conv_x": P(None, AXIS_DP, None, AXIS_MP)}
     return {"conv_x": P(None, AXIS_DP, AXIS_MP, None),
             "ssm": P(None, AXIS_DP, AXIS_MP)}
 
@@ -971,11 +973,26 @@ def rglru_block(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
 
 
 def shortconv_block(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
-                    seq_lens=None, positions=None):
+                    seq_lens=None, positions=None, valid=None):
     """LFM2 gated short convolution (reference: contrib/models/lfm2-2.6b;
     HF Lfm2ShortConv): y = out(C ⊙ conv(B ⊙ x_proj)) with a depthwise
     causal conv of width d_conv and no nonlinearity. Carries only the
-    conv tail of B⊙x."""
+    conv tail of B⊙x: the K-1 products that came before the step's first
+    token.
+
+    state: {"conv_x" (B, K-1, d_inner)}, THIS layer's rows. The block
+    CONTINUES from it, resets a row whose first real position is 0, and
+    leaves the tail of padded positions and of dead rows as it was -
+    ``valid`` and the reset are exactly :func:`mamba2_mixer`'s. The tail it
+    hands on ends at each row's LAST REAL token, whatever the chunk's width
+    (one token, fewer than K-1, a padded bucket)."""
+    B, T, _ = x.shape
+    valid, n_valid, keep = _real_and_fresh(valid, phase, seq_lens, positions,
+                                           (B, T))
+    # the shared conv helpers take a tail channels-major; the slot keeps it
+    # time-major
+    tail = jnp.where(keep[:, None, None], state["conv_x"], 0).transpose(
+        0, 2, 1)
     Bg = x @ lw["sc_in_b"]
     Cg = x @ lw["sc_in_c"]
     xg = x @ lw["sc_in_x"]
@@ -983,18 +1000,10 @@ def shortconv_block(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
         Bg = Bg + lw["sc_in_b_b"]
         Cg = Cg + lw["sc_in_c_b"]
         xg = xg + lw["sc_in_x_b"]
-    bx = Bg * xg
-    if phase == "prefill":
-        valid = (positions < seq_lens[:, None])
-        bx = jnp.where(valid[..., None], bx, 0)
-        conv = _causal_conv_prefill(bx, lw["sc_conv"],
-                                    lw.get("sc_conv_b"))
-        new_state = {"conv_x": _conv_tail(bx, seq_lens, s.d_conv - 1)}
-    else:
-        val, ntail = _conv_step(state["conv_x"], bx[:, 0],
-                                lw["sc_conv"], lw.get("sc_conv_b"))
-        conv = val[:, None]
-        new_state = {"conv_x": ntail}
+    bx = jnp.where(valid[..., None], Bg * xg, 0)
+    conv = _causal_conv_prefill(bx, lw["sc_conv"], lw.get("sc_conv_b"), tail)
+    new_state = {"conv_x": _next_tail(bx, valid, n_valid, s.d_conv - 1,
+                                      tail).transpose(0, 2, 1)}
     y = (Cg * conv) @ lw["sc_out"]
     if s.conv_bias:
         y = y + lw["sc_out_b"]
@@ -1007,7 +1016,7 @@ _SSM_BLOCKS = {"mamba2": mamba2_mixer, "rglru": rglru_block,
 
 #: the kinds whose block continues from a carried state and conv tail and
 #: takes ``valid``: the ones the paged serving path can run
-CONTINUING_KINDS = ("mamba2", "gated_delta", "mamba1")
+CONTINUING_KINDS = ("mamba2", "gated_delta", "mamba1", "shortconv")
 
 
 def ssm_block(s: SSMSpec, lw, x, state, *, phase, seq_lens=None,

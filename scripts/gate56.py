@@ -146,7 +146,14 @@ def gate_and_controls(cfg, seed, controls=None, served_precision=None):
     return out
 
 
-def blocked_hidden(ref, hf, block):
+def _cohere_head(ref, hf):
+    """What the head reads of the last layer's output: this architecture's
+    final norm (``long_walk``'s ``head`` for another one: scripts/gate61.py)."""
+    return lambda w, x: ref.layer_norm(x, w["model.norm.weight"],
+                                       hf["layer_norm_eps"])
+
+
+def blocked_hidden(ref, hf, block, head=_cohere_head):
     """``ids (1, S) -> ref.final_hidden`` of ONE sequence, a layer a program
     and its attention ``block`` queries at a time (128 heads x 8192 x 8192
     float32 scores are 34 GB an attention): the same arithmetic in another
@@ -164,23 +171,25 @@ def blocked_hidden(ref, hf, block):
                 ref.ATTEND_BLOCK = whole
         return jax.jit(run)
     layers = [of_layer(i) for i in range(hf["num_hidden_layers"])]
-    head = jax.jit(lambda w, x: ref.layer_norm(
-        x, w["model.norm.weight"], hf["layer_norm_eps"]))
+    final = jax.jit(head(ref, hf))
 
     def hidden(w, ids):
         x = w["model.embed_tokens.weight"][jnp.asarray(ids)].astype(
             jnp.float32)
         for layer in layers:
             x = layer(w, x)
-        return head(w, x)
+        return final(w, x)
     return hidden
 
 
 def long_walk(cfg, seed, tokens, rows=4, new_tokens=64, block=128,
-              served_precision=None, twin=None, second=None):
+              served_precision=None, twin=None, second=None,
+              head=_cohere_head):
     """See the module docstring. ``second``: the length of the prompt that
     takes the released row's slot (default: a quarter of ``tokens``; of
-    ``tokens`` itself, the reference's programs serve it too)."""
+    ``tokens`` itself, the reference's programs serve it too). ``head``:
+    the reference's final norm (:func:`_cohere_head`'s form), for a stack
+    without a window too (``scripts/gate61.py``)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -207,11 +216,11 @@ def long_walk(cfg, seed, tokens, rows=4, new_tokens=64, block=128,
         short = streams[0][None, :min(96, n)]
         plain = jax.jit(lambda w_, i_: ref.final_hidden(hf, w_, i_)[0])(
             w, jnp.asarray(short))
-        blocked = blocked_hidden(ref, hf, max(16, short.shape[1] // 4))(
-            w, short)
+        blocked = blocked_hidden(ref, hf, max(16, short.shape[1] // 4),
+                                 head)(w, short)
         out = {"blocked_vs_plain_reference":
                float(jnp.abs(plain - blocked).max())}
-        of = blocked_hidden(ref, hf, block)
+        of = blocked_hidden(ref, hf, block, head)
         hidden = [np.asarray(of(w, s[None]))[0] for s in streams]
     embed = w["model.embed_tokens.weight"]
     del w, plain, blocked
@@ -288,10 +297,11 @@ def long_walk(cfg, seed, tokens, rows=4, new_tokens=64, block=128,
             teacher_force(ad)
             ad.step([s for s in ad.seqs if s == rows])
         slots_of[rows] = ad._state_slot.get(rows)
-    ring = app.window_ring_pages
+    ring = app.window_ring_pages or 0
+    reach = hf.get("sliding_window") or 0
     out.update(
         tokens=n, rows=rows, new_tokens=new_tokens, second_prompt=second,
-        window=hf["sliding_window"], ring_pages=ring,
+        window=reach, ring_pages=ring,
         ring_wraps=(n + new_tokens) // max(ring * bs, 1),
         program_shapes=sorted(shapes),
         released=gone, slot_reused=slots_of[rows] == slots_of[gone],
@@ -313,17 +323,19 @@ def long_walk(cfg, seed, tokens, rows=4, new_tokens=64, block=128,
         return dict(positions=int(x.size), median_ratio=float(np.median(x)),
                     worst_ratio=float(x.max()),
                     held_share=float((x <= 1).mean()))
-    reach = hf["sliding_window"]
     prefill = [r[:p] for r, p in zip(ratio, prompt_len)]
     decode = [r[p:] for r, p in zip(ratio, prompt_len)]
     parts = dict(
         all=part(ratio), prefill=part(prefill), decode=part(decode),
+        # the one chunk that starts from nothing, and the ones that continue
         first_chunk=part([r[:width] for r in ratio[:rows]]),
-        # positions whose window has left the start behind: the ring has
-        # been overwritten under them
-        past_window=part([r[reach:] for r in ratio]),
+        later_chunks=part([r[min(width, n - 1):n] for r in ratio[:rows]]),
         last_chunk=part([r[max(0, n - width):n] for r in ratio[:rows]]),
         reused_slot=part([ratio[rows]]))
+    if reach:
+        # positions whose window has left the start behind: the ring has
+        # been overwritten under them
+        parts["past_window"] = part([r[reach:] for r in ratio])
     everything = np.concatenate(ratio)
     out.update(
         parts,

@@ -1496,10 +1496,10 @@ def test_keye_vl2_select_kernel_share_reads_the_adapters_two_counters():
         name=metric, unit="%", better="higher", source="program_counter",
         layer="Step graphs", moves="itl_p50_ms", workloads=[KEYE_CELL])
     # appended where it was added: what follows it is ISSUE 52's seven,
-    # then ISSUE 54's five, then ISSUE 56's four
+    # then ISSUE 54's five, ISSUE 56's four and ISSUE 61's two
     later = BENCHMARK["per_layer"][BENCHMARK["per_layer"].index(entry) + 1:]
     assert [m["name"] for m in later] == list(
-        TTFT_METRICS + PHI4_METRICS + COMMAND_A_METRICS)
+        TTFT_METRICS + PHI4_METRICS + COMMAND_A_METRICS + LFM2_METRICS)
 
     def ctx(**counters):
         return {"before": {"counters": {"host_stats.sparse_dispatches": 10}},
@@ -1532,9 +1532,10 @@ def test_a_ttft_phase_metric_is_the_chat_cells_alone(metric):
     ``engine.ttft_*`` (``ServingEngine.stats``, always on) and read nothing
     from a program without the keys."""
     from harness import readers
-    # (ISSUE 54's five and ISSUE 56's four were appended behind them)
-    assert tuple(m["name"] for m in BENCHMARK["per_layer"][-16:]) == \
-        TTFT_METRICS + PHI4_METRICS + COMMAND_A_METRICS
+    # (ISSUE 54's five, ISSUE 56's four and ISSUE 61's two were appended
+    # behind them)
+    assert tuple(m["name"] for m in BENCHMARK["per_layer"][-18:]) == \
+        TTFT_METRICS + PHI4_METRICS + COMMAND_A_METRICS + LFM2_METRICS
     entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == metric)
     assert entry["moves"] == "ttft_p50_ms"
     assert entry["workloads"] == ["olmoe-chat-steady"]
@@ -1895,3 +1896,315 @@ def test_a_fault_in_the_program_does_not_pass_the_cohere2_moe_toy_gate(
     res = build.logit_gate(_toy_file(), seed=2**31 + 56,
                            served_precision="highest")
     assert not res["passed"] and res["worst_ratio"] > 5
+
+
+# ---------------------------------------------------------------------------
+# lfm2-8b-a1b (ISSUE 61)
+# ---------------------------------------------------------------------------
+
+LFM2_CELL = "lfm2-moe-rag-wide-closed"
+LFM2_METRICS = ("kernel.moe_decode_all_held_roofline",
+                "kernel.shortconv_decode_roofline")
+LFM2_TYPES = (["conv", "conv"] + ["full_attention", "conv", "conv", "conv"] * 4
+              + ["full_attention", "conv", "conv"] * 2)
+
+
+def test_lfm2_moe_keeps_every_published_number():
+    """The catalog row's ``config`` (model-configs guide), as copied into
+    ISSUE 61: every key at the top level of the file, no width changed, and
+    ``reduced`` names the depth and the layer types cut with it, and nothing
+    else: every expert and the whole vocabulary are here."""
+    cfg = build.load_json("configs", "lfm2-8b-a1b.json")
+    published = dict(
+        conv_L_cache=3, conv_bias=False, hidden_size=2048,
+        intermediate_size=7168, layer_types=LFM2_TYPES,
+        max_position_embeddings=128000, model_type="lfm2_moe",
+        moe_intermediate_size=1792, norm_eps=1e-05, norm_topk_prob=True,
+        num_attention_heads=32, num_dense_layers=2, num_experts=32,
+        num_experts_per_tok=4, num_hidden_layers=24, num_key_value_heads=8,
+        rope_theta=1000000, routed_scaling_factor=1, use_expert_bias=True,
+        vocab_size=65536)
+    assert len(LFM2_TYPES) == 24 and LFM2_TYPES.count("full_attention") == 6
+    assert [i for i, t in enumerate(LFM2_TYPES) if t == "full_attention"] \
+        == [2, 6, 10, 14, 18, 21]
+    assert set(published) <= set(cfg)
+    differs = sorted(k for k in published if cfg[k] != published[k])
+    assert differs == sorted(cfg["reduced"]) == ["layer_types",
+                                                 "num_hidden_layers"]
+    entry = next(c for c in BENCHMARK["configs"] if c["name"] == "lfm2-8b-a1b")
+    assert sorted(entry["reduced"]) == differs
+    assert entry["source"] == cfg["source"] and len(entry["why"]) <= 200
+    # the first stage: both dense layers and three whole periods of four
+    assert cfg["num_hidden_layers"] == 14
+    assert cfg["layer_types"] == LFM2_TYPES[:14]
+    assert cfg["layer_types"][2:] == ["full_attention", "conv", "conv",
+                                      "conv"] * 3
+    assert cfg["family"] == cfg["model_type"] == "lfm2_moe"
+    assert cfg["chips"] == cfg["tp"] == 1 and cfg["dtype"] == "bfloat16"
+    assert cfg["tie_word_embeddings"] is True
+    assert "two pipeline stages of 14 + 10" in cfg["deployment"]
+    assert {"tie_word_embeddings", "tensor_names", "topk_norm_eps",
+            "dense_width", "router_dtype", "conv_tail_dtype", "kv_dtype",
+            "head_here", "chunk_buckets", "adapter"} <= set(cfg["assumed"])
+    serve = cfg["serve"]
+    # ONE chunk of at most the widest bucket before each decode step
+    assert cfg["adapter"] == {"prefill_budget_tokens": max(
+        serve["context_encoding_buckets"])} == {"prefill_budget_tokens": 512}
+    assert (serve["batch_size"], serve["seq_len"], serve["pa_block_size"],
+            serve["pa_num_blocks"], serve["is_prefix_caching"]) == \
+        (64, 4096, 32, 8192, False)
+    gate = cfg["gate"]
+    twin = build.hf_config(cfg, build.gate_overrides(gate))
+    # the twin: both dense layers and ONE whole period, every width
+    assert (twin["num_hidden_layers"], twin["hidden_size"],
+            twin["num_experts"], twin["vocab_size"],
+            twin["num_dense_layers"]) == (6, 2048, 32, 65536, 2)
+    assert twin["layer_types"] == LFM2_TYPES[:6]
+    assert (gate["batch"], gate["prompt_len"], gate["new_tokens"]) == \
+        (4, 112, 16)
+    assert gate["prompt_len"] + gate["new_tokens"] <= \
+        4 * serve["pa_block_size"]
+    assert 0 < gate["excuse_margin_max"] <= 0.02
+    assert "before the first chip run" in gate["rule"]
+    ref = build.load_reference("lfm2_moe")
+    assert {"bias_weighs", "bias_dropped", "renorm_dropped", "b_c_exchanged",
+            "qk_norm_after_rope", "dense_as_expert"} == set(ref.CONTROLS)
+    for control in (*ref.CONTROLS, "zero_tail", "padded_tail", "fp8"):
+        assert control in gate["controls"], control
+    # the pool cannot run dry: every row at its longest prompt and answer
+    mix = build.load_json("traffic", "rag-wide-closed.json")
+    longest = mix["prompt_len"]["hi"] + mix["output_len"]["hi"]
+    assert longest == serve["seq_len"] == 4096
+    assert serve["pa_num_blocks"] * serve["pa_block_size"] == \
+        serve["batch_size"] * longest
+    # the mix is ISSUE 61's, every number of it: rag-closed's lengths
+    assert (mix["loop"], mix["clients_per_batch_row"], mix["pool_requests"],
+            mix["lead_s"], mix["grace_s"], mix["base_seed"]) == \
+        ("closed", 2, 4096, 15.0, 8.0, 61)
+    assert mix["prompt_len"] == dict(kind="lognormal", median=768, sigma=0.7,
+                                     lo=192, hi=3072)
+    assert mix["output_len"] == dict(kind="lognormal", median=320, sigma=0.6,
+                                     lo=96, hi=1024)
+    narrow = build.load_json("traffic", "rag-closed.json")
+    assert (mix["prompt_len"], mix["output_len"]) == \
+        (narrow["prompt_len"], narrow["output_len"])
+    assert build.warm_widths(cfg, mix) == \
+        [1] + sorted(serve["context_encoding_buckets"])
+    # the cell: one chip, on its two rooflines and on no other family's
+    cell = next(w for w in BENCHMARK["workloads"] if w["name"] == LFM2_CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("lfm2-8b-a1b", "rag-wide-closed", 1)
+    assert len(cell["why"]) <= 200
+    listed = {m["name"] for m in BENCHMARK["per_layer"]
+              if LFM2_CELL in m.get("workloads", ())}
+    assert {m["name"] for m in BENCHMARK["per_layer"]
+            if m.get("workloads") == [LFM2_CELL]} == set(LFM2_METRICS)
+    assert {m for m in listed if m.endswith("_roofline")} == \
+        set(LFM2_METRICS) | {"kernel.paged_decode_roofline"}
+    assert {"sched.live_batch_mean", "adapter.prefill_pad_share",
+            "host.stall_s", "step.decode_attn_ms", "step.prefill_attn_ms",
+            "step.decode_moe_ms", "step.prefill_moe_ms",
+            "step.decode_mixer_ms", "step.prefill_mixer_ms",
+            "step.decode_mlp_ms", "attn.paged_prefill_kernel_share",
+            "adapter.decode_overlap_share", "moe.experts_touched_share",
+            "moe.experts_skipped_share", "moe.prefill_walk_share",
+            "host.prep_inputs_ms_per_dispatch",
+            "host.prep_rng_ms_per_dispatch",
+            "host.prep_enqueue_ms_per_dispatch",
+            "host.dispatch_build_ms_per_dispatch",
+            "host.dispatch_retire_ms_per_dispatch",
+            "host.deliver_ms_per_dispatch", "device.idle_prep_share",
+            "sched.gaps_behind_prefill_share", "sched.stalled_gap_mean_ms",
+            "sched.prefill_dispatches_per_stalled_gap"} <= listed
+    assert LFM2_CELL in next(
+        m for m in BENCHMARK["end_to_end"]
+        if m["name"] == "tokens_per_s")["workloads"]
+
+
+def test_lfm2_moe_allocates_what_its_file_says():
+    """The file's ``memory`` against what the program would allocate: the
+    weights from the parameter specs, the slot from ``ssm_state_shapes``, the
+    pool from what the application allocates, all as SHAPES (nothing of 11 GB
+    is allocated)."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    from neuronx_distributed_inference_tpu.models import model_base
+    from neuronx_distributed_inference_tpu.modules import ssm
+    from neuronx_distributed_inference_tpu.modules.block_kv_cache import \
+        pool_spec
+    from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
+    cfg = build.load_json("configs", "lfm2-8b-a1b.json")
+    assumed, memory, serve = cfg["assumed"], cfg["memory"], cfg["serve"]
+    spec = build.build_app(cfg).spec
+    assert (spec.first_dense, spec.num_moe_layers, spec.num_attn_layers,
+            spec.num_ssm_layers) == (2, 12, 3, 11)
+    assert spec.resolved_ssm_pattern == tuple(
+        t == "conv" for t in cfg["layer_types"])
+    m = spec.moe
+    assert (m.num_experts, m.num_held, m.top_k, m.intermediate_size,
+            m.router_act, m.has_router_bias, m.router_bias_mode,
+            m.normalize_topk, m.topk_norm_eps, m.routed_scaling,
+            m.shared_intermediate) == (32, 32, 4, 1792, "sigmoid", True,
+                                       "select", True, 1e-6, 1.0, 0)
+    assert (spec.ssm.kind, spec.ssm.d_inner, spec.ssm.d_conv) == \
+        ("shortconv", 2048, 3)
+    assert spec.qk_norm and spec.tie_word_embeddings
+    assert abs(spec.rope.rope_theta - 1e6) < 1e-3
+    assert spec.padded_vocab == cfg["vocab_size"]
+    # a row's state: eleven conv tails of two products a channel, no matrix
+    state = ssm.ssm_state_shapes(spec.ssm, 11, serve["batch_size"],
+                                 jnp.dtype(cfg["dtype"]))
+    assert state == {"conv_x": ((11, 64, 2, 2048),
+                                jnp.dtype(assumed["conv_tail_dtype"]))}
+    state_bytes = sum(math.prod(shape) * jnp.dtype(dt).itemsize
+                      for shape, dt in state.values())
+    assert state_bytes == memory["state_bytes"] == \
+        serve["batch_size"] * memory["state_slot_bytes"]
+    assert memory["state_slot_bytes"] == 90112
+    # 8 kv heads of 64 lanes fold two a slot of 128
+    pool = pool_spec(spec, serve["pa_num_blocks"], serve["pa_block_size"])
+    assert pool.shape == (3, 8193, 32, 4, 128)
+    assert str(jnp.dtype(pool.dtype)) == assumed["kv_dtype"]
+    assert pool.bytes_per_token == memory["kv_bytes_per_token"] == \
+        3 * 2 * 8 * 64 * 2
+    assert 2 * math.prod(pool.shape) * 2 == memory["kv_pool_bytes"]
+    leaves = jax.tree.leaves(model_base.decoder_param_specs(spec),
+                             is_leaf=lambda x: isinstance(x, ParamSpec))
+    conv, attn, norms = 16_783_360, 10_485_888, 4_096
+    dense, experts, vocab = 44_040_192, 352_387_104, 134_217_728
+    assert sum(math.prod(ps.shape) for ps in leaves) == \
+        memory["parameters"] == (
+            2 * (conv + norms + dense) + 3 * (attn + norms + experts)
+            + 9 * (conv + norms + experts) + vocab + 2048) == 4_667_077_376
+    weights = sum(math.prod(ps.shape) * jnp.dtype(ps.dtype).itemsize
+                  for ps in leaves)
+    # the program's count over the file's all-bf16 one: the routers and
+    # their selection biases in float32 (2 B more an entry)
+    assert weights == memory["program_weights_bytes"]
+    assert weights - 2 * 12 * (2048 * 32 + 32) == memory["weights_bytes"] \
+        == 2 * memory["parameters"]
+    total = (memory["weights_bytes"] + memory["kv_pool_bytes"]
+             + memory["state_bytes"])
+    assert total == memory["before_temps_bytes"]
+    assert 0.68 * 16e9 < total < 0.69 * 16e9
+    # the whole model does not fit a chip; a fourth period leaves no room
+    whole = 2 * (conv + norms + dense) + 6 * (attn + norms + experts) \
+        + 16 * (conv + norms + experts) + vocab + 2048
+    assert 2 * whole > 16e9 and whole == 8_339_930_560
+    assert 2 * (memory["parameters"] + attn + 3 * conv + 4 * norms
+                + 4 * experts) > 12.2e9
+
+
+def test_lfm2_moe_rooflines_count_what_the_model_needs(monkeypatch):
+    """The cell's two rooflines from a made-up window: the experts' need is
+    the experts a step TOUCHED + the twelve routers, over the ``moe`` scope's
+    time (the two dense layers' MLPs lie under ``mlp`` and are not in it);
+    the short convolutions' need is eleven layers' three matrices and taps +
+    each live row's tail read and written, over ``mixer``; a configuration
+    of other key names, or a program without the counters, reads nothing."""
+    from harness import host_spans, readers
+    cfg = build.load_json("configs", "lfm2-8b-a1b.json")
+    expert = 3 * 2048 * 1792 * 2
+    programs = {"paged.w1": dict(count=100, total_s=1.4)}
+    scopes = {"paged.w1": {"moe": 1.1, "mixer": 0.06, "mlp": 0.03,
+                           "attn": 0.09}}
+    edge = {"counters": {"kv.live_rows": 64.0}}
+
+    def ctx(counters, config=cfg, slice_=None):
+        return {"config": config, "peaks": {"hbm_gbps": 819.0},
+                "warm_widths": build.warm_widths(
+                    cfg, build.load_json("traffic", "rag-wide-closed.json")),
+                "before": {"counters": {}},
+                "after": {"counters": {"host_stats." + k: v
+                                       for k, v in counters.items()}},
+                "slice": slice_ or {"before": edge, "after": edge},
+                "trace": {"programs": programs, "ops_by_program": {}},
+                "_slice": {"scopes": {
+                    label: dict(programs[label], scopes=scopes[label])
+                    for label in programs}}}
+    monkeypatch.setattr(host_spans, "load_slice", lambda c: c["_slice"])
+    # -- the experts: 50 steps fetched, every expert of 12 layers touched
+    metric = LFM2_METRICS[0]
+    counters = dict(moe_expert_slots=50 * 12 * 32,
+                    moe_experts_touched=50 * 12 * 32)
+    least = (12 * 32 * expert + 12 * (2048 * 32 + 32) * 2) / 819e9
+    got = readers.read_metric(metric, ctx(counters))
+    assert got == pytest.approx(100 * least / 11e-3) and 90 < got < 100
+    assert least == pytest.approx(10.32e-3, rel=2e-3)
+    half = dict(counters, moe_experts_touched=50 * 12 * 16)
+    assert readers.read_metric(metric, ctx(half)) == pytest.approx(
+        got / 2, rel=1e-3)
+    assert readers.read_metric(metric, ctx({})) is None
+    for key in ("num_dense_layers", "moe_intermediate_size"):
+        assert readers.read_metric(
+            metric, ctx(counters, {k: v for k, v in cfg.items()
+                                   if k != key})) is None
+    # -- the convolutions: 11 layers x (4 x 2048^2 + 3 x 2048) + 64 tails
+    metric = LFM2_METRICS[1]
+    need = 11 * ((4 * 2048 * 2048 + 3 * 2048) * 2 + 64 * 2 * 2048 * 2 * 2)
+    got = readers.read_metric(metric, ctx({}))
+    assert got == pytest.approx(100 * (need / 819e9) / 0.6e-3)
+    assert need == pytest.approx(0.381e9, rel=5e-3) and 70 < got < 80
+    assert readers.read_metric(
+        metric, ctx({}, {k: v for k, v in cfg.items()
+                         if k != "conv_L_cache"})) is None
+    assert readers.read_metric(
+        metric, ctx({}, slice_={"before": None, "after": None})) is None
+    # the other families' yardsticks read nothing here
+    for other in ("kernel.moe_decode_experts_roofline",
+                  "kernel.moe_decode_held_roofline",
+                  "kernel.moe_decode_share_roofline",
+                  "kernel.mixer_decode_roofline"):
+        assert readers.read_metric(other, ctx(counters)) is None
+    # and these two nothing under another configuration's keys
+    other = build.load_json("configs", "qwen3-next-80b-a3b.json")
+    for metric in LFM2_METRICS:
+        assert readers.read_metric(metric, ctx(counters, other)) is None
+
+
+@pytest.mark.parametrize("fault", [None, "bias_weighs", "renorm_dropped",
+                                   "padded_tail"])
+def test_the_lfm2_moe_toy_gate_and_three_faults_in_the_program(
+        monkeypatch, fault):
+    """The reference, found by name, gates a toy twin through the harness's
+    full-batch prefill (a padded window) and its decode steps; and the other
+    direction of the controls: the PROGRAM broken, the reference sound. A
+    selection bias that weighs, an unnormalised top-k, a tail taken at the
+    bucket's end."""
+    import dataclasses
+
+    from neuronx_distributed_inference_tpu.models.family import get_family
+    from neuronx_distributed_inference_tpu.modules import ssm
+    from test_lfm2_moe_paged import HF, SERVE
+    family = get_family("lfm2_moe")
+    build_spec = family.build_spec.__func__
+
+    def broken(cls, config, tp_degree=None):
+        spec = build_spec(cls, config, tp_degree)
+        return dataclasses.replace(spec, moe=dataclasses.replace(
+            spec.moe, **({"router_bias_mode": "logits"}
+                         if fault == "bias_weighs"
+                         else {"normalize_topk": False})))
+    if fault == "padded_tail":
+        import importlib.util
+        at = importlib.util.spec_from_file_location(
+            "gate61", os.path.join(ROOT, "scripts", "gate61.py"))
+        gate61 = importlib.util.module_from_spec(at)
+        at.loader.exec_module(gate61)
+        monkeypatch.setattr(ssm, *gate61.carry_fault(fault))
+    elif fault:
+        monkeypatch.setattr(family, "build_spec", classmethod(broken))
+    toy = dict(HF, family="lfm2_moe", tp=1, dtype="float32", serve=SERVE,
+               adapter={},
+               gate=dict(config={}, batch=2, prompt_len=24, new_tokens=8,
+                         atol=2e-5, rtol=1e-4, min_positions_held=1.0,
+                         median_ratio_max=0.5, worst_ratio_max=1.0,
+                         excuse_margin_max=0.0))
+    res = build.logit_gate(toy, seed=2**31 + 61, served_precision="highest")
+    if fault is None:
+        assert res["passed"], res
+        assert res["compared"] == 2 * 32 * HF["vocab_size"]
+    else:
+        assert not res["passed"] and res["worst_ratio"] > 5

@@ -1461,6 +1461,88 @@ def test_command_a_plus_chunks_attend_on_the_prefill_kernel(
         < 15.75 * 2 ** 30 - 258e6
 
 
+def _lfm2_moe_program(v5e_devices, rows, width=None):
+    """A paged step of ``benchmark/configs/lfm2-8b-a1b.json`` (the file
+    itself: its model keys and its serving shape; ``width`` None: its widest
+    chunk bucket) compiled for a v5e, with the engagement records of its
+    trace."""
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import build
+    cfg = build.load_json("configs", "lfm2-8b-a1b.json")
+    hf = build.hf_config(cfg)
+    serve = {k: cfg["serve"][k] for k in (
+        "batch_size", "seq_len", "pa_block_size", "pa_num_blocks",
+        "context_encoding_buckets")}
+    shapes = _serving_shapes(hf, 14, 1, v5e_devices[:1], serve, prefix=False)
+    spec, _, _, _, cache, sds, mb = shapes
+    assert (spec.first_dense, spec.num_moe_layers, spec.num_attn_layers,
+            spec.num_ssm_layers) == (2, 12, 3, 11)
+    assert mb == 128
+    # the state a row carries is eleven conv tails of two tokens, time-major
+    assert cache["conv_x"].shape == (11, 64, 2, 2048) and "ssm" not in cache
+    kw = {} if rows == 64 else {"state_slots": sds((rows,), jnp.int32)}
+    program, notes = _compiled_paged_step(
+        shapes, rows, width or max(serve["context_encoding_buckets"]), **kw)
+    return program, notes, program.as_text()
+
+
+def test_lfm2_moe_decodes_64_rows_on_both_kernels_in_place(v5e_devices):
+    """ISSUE 61: ``paged.w1`` of the LFM2-8B-A1B cell at its 64 rows: three
+    calls of the paged decode kernel (32 query / 8 kv heads of 64), twelve
+    expert layers on the walk over the touched experts and none for the two
+    leading dense layers, eleven conv tails slid in place; nothing copies
+    the pool or an expert stack, and the step's temps are small beside 11 GB
+    of weights and pool."""
+    step, notes, text = _lfm2_moe_program(v5e_devices, 64, 1)
+    assert ("recurrent_state", "xla",
+            "kind=shortconv slot_bytes=90112 chunk=128: no matrix state") \
+        in notes
+    assert any(s == "paged_decode" and p == "pallas" for s, p, _ in notes)
+    assert any(s == "moe_decode" and p == "pallas" for s, p, _ in notes)
+    assert len(re.findall(r"%paged_decode_attention[.\d]* = ", text)) == 3
+    assert len(re.findall(r"%moe_decode_experts[.\d]* = ", text)) == 12
+    stacks = [(name, shape, op) for name, shape, op in re.findall(
+        r"%(\S+) = bf16\[([\d,]+)\]\S* (\w[\w-]*)\(", text)
+        if shape in ("12,32,2048,1792", "12,32,1792,2048", "32,2048,1792",
+                     "32,1792,2048")
+        and op not in ("parameter", "get-tuple-element", "bitcast",
+                       "dynamic-update-slice", "fusion")]
+    assert not stacks, stacks
+    memory = step.memory_analysis()
+    assert memory.temp_size_in_bytes < 150e6
+    assert 10.9e9 < memory.argument_size_in_bytes < 11.0e9
+
+
+@pytest.mark.parametrize("rows, temps_under", [(1, 50e6), (64, 1.1e9)],
+                         ids=["chunk", "pack"])
+def test_the_widest_lfm2_moe_programs_fit_beside_weights_and_pool(
+        v5e_devices, rows, temps_under):
+    """ISSUE 61: the widest chunk (one row) and the widest pack (64 rows) of
+    the LFM2-8B-A1B cell attend on the prefill kernel, run their experts on
+    the walk by expert (the chunk) or the grouped matmuls (the pack), and
+    fit a v5e: weights 9.34 GB + pool 1.61 GB + state + temps under 16 GB
+    less what the runtime keeps."""
+    program, notes, text = _lfm2_moe_program(v5e_devices, rows)
+    assert any(s == "paged_prefill" and p == "pallas" for s, p, _ in notes)
+    assert MOSAIC in text
+    if rows == 1:
+        assert any(s == "moe_decode" and p == "pallas" and "by expert" in w
+                   for s, p, w in notes)
+        assert "ragged-dot" not in text and "%moe_chunk_experts" in text
+    else:
+        assert ("moe_ragged", "stacked", "") in notes
+    memory = program.memory_analysis()
+    assert memory.temp_size_in_bytes < temps_under
+    assert 10.9e9 < memory.argument_size_in_bytes < 11.0e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2 ** 30 - 258e6
+
+
 def test_without_the_request_nothing_is_interpreted(v5e_devices,
                                                     monkeypatch):
     """The other side of the rule: with interpret mode requested the same

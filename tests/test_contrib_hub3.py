@@ -20,22 +20,72 @@ def test_openai_gpt_matches_hf(tmp_path):
     assert app.spec.skip_final_norm and app.spec.no_rope
 
 
+def _lfm2_toy():
+    from transformers import Lfm2Config
+    return Lfm2Config(hidden_size=64, num_attention_heads=4,
+                      num_key_value_heads=2, num_hidden_layers=4,
+                      intermediate_size=128, vocab_size=256,
+                      layer_types=["conv", "conv", "full_attention", "conv"],
+                      conv_L_cache=3, conv_bias=False,
+                      block_auto_adjust_ff_dim=False,
+                      max_position_embeddings=128, torch_dtype="float32")
+
+
 def test_lfm2_matches_hf(tmp_path):
-    from transformers import Lfm2Config, Lfm2ForCausalLM
+    from transformers import Lfm2ForCausalLM
     torch.manual_seed(0)
-    cfg = Lfm2Config(hidden_size=64, num_attention_heads=4,
-                     num_key_value_heads=2, num_hidden_layers=4,
-                     intermediate_size=128, vocab_size=256,
-                     layer_types=["conv", "conv", "full_attention", "conv"],
-                     conv_L_cache=3, conv_bias=False,
-                     block_auto_adjust_ff_dim=False,
-                     max_position_embeddings=128, torch_dtype="float32")
+    cfg = _lfm2_toy()
     app = _check(tmp_path, "lfm2", Lfm2ForCausalLM(cfg))
     assert app.spec.ssm.kind == "shortconv"
     assert app.spec.ssm_pattern == (True, True, False, True)
     assert app.cache["k"].shape[0] == 1          # one attention layer
-    assert app.cache["conv_x"].shape == (3, 2, 64, 2)
+    assert app.cache["conv_x"].shape == (3, 2, 2, 64)      # time-major
     assert "ssm" not in app.cache                # conv state only
+
+
+def test_lfm2_serves_paged_against_hf(tmp_path):
+    """ISSUE 61: with the short convolution continuing from a carried tail
+    the dense sibling serves through the paged path too: a prompt walked in
+    chunks (16 + 16 + a padded 5) through ``PagedEngineAdapter`` with
+    default arguments, then decode through the pool and the state slots,
+    against transformers' ``Lfm2ForCausalLM`` on the same weights."""
+    from transformers import Lfm2ForCausalLM
+
+    from neuronx_distributed_inference_tpu.config import (
+        TpuConfig, load_pretrained_config)
+    from neuronx_distributed_inference_tpu.models.application import \
+        PagedCausalLMApplication
+    from neuronx_distributed_inference_tpu.models.family import get_family
+    from neuronx_distributed_inference_tpu.serving import PagedEngineAdapter
+    from test_recurrent_paged import LogitTap
+    torch.manual_seed(0)
+    cfg = _lfm2_toy()
+    hf = Lfm2ForCausalLM(cfg).eval()
+    d = tmp_path / "lfm2"
+    hf.save_pretrained(d, safe_serialization=True)
+    family = get_family("lfm2")
+    tcfg = TpuConfig(batch_size=2, seq_len=64, dtype="float32",
+                     output_logits=True, is_block_kv_layout=True,
+                     pa_block_size=8, pa_num_blocks=16,
+                     context_encoding_buckets=[8, 16], enable_bucketing=True)
+    app = PagedCausalLMApplication(
+        str(d), family.config_cls(
+            tcfg, load_config=load_pretrained_config(str(d))), family)
+    app.load_weights().init_cache()
+    assert app.cache["conv_x"].shape == (3, 2, 2, 64)
+    prompt = np.random.default_rng(3).integers(1, 250, size=37).tolist()
+    ad = PagedEngineAdapter(app)
+    tap = LogitTap(app)
+    stream = [ad.add_requests([5], [prompt])[5]]
+    for _ in range(6):
+        stream.append(ad.step([5])[5])
+    assert tap.shapes == [(1, 16), (1, 16), (1, 8)] + [(2, 1)] * 6
+    fed = prompt + stream[:-1]
+    with torch.no_grad():
+        want = hf(torch.tensor([fed])).logits[0].numpy()
+    np.testing.assert_allclose(tap.logits(5, len(fed))[:, :256], want,
+                               atol=2e-4)
+    assert stream == want[len(prompt) - 1:].argmax(-1).tolist()
 
 
 def test_lfm2_conv_bias_and_auto_ff(tmp_path):
