@@ -38,15 +38,21 @@ Decode pipeline (see README "Decode pipeline"):
     ``step_ahead()`` eager too: the tests' and a debugger's way to take
     the lookahead out from under the engine. It is the option's only value
     beside the default ``None``.
-  * What drains the step in flight: a changed live set. A finished or
+  * A changed live set does NOT empty the pipeline. A finished or
     cancelled row (``release``), a preempted row and an admission that
-    graduated a row do NOT block where they happen: the step stays in
-    flight, the next decode call sees that its rows changed, fetches it
-    synchronously, dispatches the new composition from host tokens, and
-    the call after overlaps again. One synchronous step a change, and a
-    finish with the admission that follows it share one. A released row's
-    in-flight token is dropped at the fetch; its KV growth goes with its
-    blocks. ``host_stats`` counts ``overlapped_dispatches`` and
+    graduated a row do not block where they happen: the step stays in
+    flight, and the next decode call, seeing that rows left and rows
+    joined, CARRIES it: the new composition's ids are made on the device
+    (``carry_step_ids``: a surviving row takes the in-flight step's sampled
+    token from the row it had there, a joined row its host-known first
+    token, pad rows follow row 0), the step is enqueued, and only then is
+    the step before it fetched. A released row's in-flight token is dropped
+    at the fetch; its KV growth goes with its blocks. What still drains
+    (the step in flight is fetched synchronously and the new composition
+    dispatched from host tokens): a preemption, a caller that steps another
+    set of the running rows (backpressure), a seq_id re-admitted under a
+    new state. ``host_stats`` counts ``overlapped_dispatches``,
+    ``pipeline_carries_<admit|release>`` and
     ``pipeline_drains_<admit|release|preempt|liveset>``.
   * Deferred-failure contract: a device failure from step N surfaces at
     step N+1's fetch as a :class:`StepFailure` with ``retry_safe=False``;
@@ -81,7 +87,15 @@ reference analog: "Ragged Paged Attention" arxiv 2604.15464):
     ``add_requests`` only admits and returns ``{}``; each decode call then
     runs AT MOST ONE packed chunk dispatch of at most that many prompt
     tokens before its decode work, and delivers a first token from the call
-    whose dispatch completes the prompt.
+    whose dispatch completes the prompt. Under ``step_ahead()`` with a
+    decode row live, that last chunk is not waited for either: its rows are
+    parked (``_Parked``; they stay in ``pending_prefill_ids``) and graduate
+    one call later, behind the fetch of a decode step that was enqueued
+    after the chunk, where their token is host-visible for nothing. Between
+    two decode dispatches the host then blocks on nothing but the fetch of
+    the step before. ``step()``, ``step_many()``, ``flush()`` and a call
+    with no decode row to hide behind fetch at once, as ``add_requests``
+    does without the budget.
   * half-prefilled sequences stay inside the resilience contracts: a chunk
     dispatch failure (``prefill_chunk`` fault point) rolls every sequence
     packed in that dispatch back via ``abort_sequence`` (never-fully-
@@ -116,10 +130,13 @@ Resilience contract (see README "Serving resilience"):
 from __future__ import annotations
 
 import bisect
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..modules import autobucketing
@@ -186,6 +203,29 @@ class _Inflight:
     t_dispatch: float
     grown: int = 0                # paged KV tokens grown for this dispatch
     rows: Optional[np.ndarray] = None   # output row of each live seq
+    # the prefill dispatches enqueued before this step: once its tokens are
+    # fetched, every one of them has run (:class:`_Parked`)
+    chunks_before: int = 0
+
+
+@dataclass
+class _Parked:
+    """One dispatched prefill chunk that holds prompts' LAST chunks and whose
+    sampled tokens nobody waited for (the budgeted ``step_ahead()`` path).
+    Its rows graduate where the tokens are host-visible for nothing: after
+    the fetch of a decode step that was enqueued behind it. Until then they
+    stay pending admissions (``_chunks``, ``done == len(prompt)``); the
+    ``_ChunkState`` objects pin identity, so a row released, preempted or
+    expired meanwhile (and its seq_id re-admitted) is passed over."""
+    out: Dict[str, Any]
+    no: int                       # host_stats["prefill_dispatches"] with it
+    # (output row, seq_id, state) of each prompt that ends in this dispatch
+    finals: List[Tuple[int, int, _ChunkState]]
+    # (seq_id, state) of every sequence packed in it: the rollback set
+    packed: List[Tuple[int, _ChunkState]]
+    # (seq_id, state, tokens written) of every pending admission as of this
+    # dispatch: what a fetch of it confirms written
+    covered: List[Tuple[int, _ChunkState, int]]
 
 
 def _meta_tenant(meta: Any) -> str:
@@ -246,6 +286,10 @@ def _trace_error(err):
 # nxdi_pipeline_drains_total{cause}): an admission graduated a row, a row
 # was released, a row was preempted, or the caller stepped another set
 _DRAIN_CAUSES = ("admit", "release", "preempt", "liveset")
+# ... and what it is CARRIED across instead, its tokens merged into the next
+# step's ids on the device (host_stats["pipeline_carries_<cause>"],
+# nxdi_pipeline_carries_total{cause}): rows that joined, rows that left
+_CARRY_CAUSES = ("admit", "release")
 
 
 def _async_fetch(x):
@@ -255,6 +299,35 @@ def _async_fetch(x):
         x.copy_to_host_async()
     except AttributeError:
         pass
+
+
+def _merge_step_ids(prev_tokens, take, ids):
+    """``ids`` where it holds a token (>= 0), else the previous step's
+    sampled token of row ``take``."""
+    carried = prev_tokens.reshape(-1)[take].astype(ids.dtype)[:, None]
+    return jnp.where(ids < 0, carried, ids)
+
+
+def carry_step_ids(app, prev_tokens, take, ids):
+    """The ids of a decode step over ANOTHER live set than the step before
+    it, made on the device: row ``r`` takes ``ids[r]`` where that is a token
+    (a row that joined: its first token is host-known) and the previous
+    step's sampled token of row ``take[r]`` where it is -1 (a row that
+    stays). One small program a (rows before, rows after) pair, warmed with
+    the step programs (serving/warmup.py) and noted like them, so a carry
+    after ``declare_steady_state()`` builds nothing; its output is placed as
+    the decode step places ``out["next_ids"]``, so the step it feeds is the
+    one executable."""
+    fn = app._compiled.get(("carry_ids", 0))
+    if fn is None:
+        rep = app._decode_ids_sharding
+        # a callable of the app's own, as its step programs are: a second
+        # replica in one process then loads it from the compile cache
+        fn = app._compiled[("carry_ids", 0)] = jax.jit(
+            functools.partial(_merge_step_ids),
+            in_shardings=(rep, rep, rep), out_shardings=rep)
+    app._note_jit("carry_ids", ids.shape[0], (prev_tokens.shape, ids.shape))
+    return fn(prev_tokens, take, ids)
 
 
 class _AdapterTelemetry:
@@ -433,6 +506,12 @@ class _AdapterTelemetry:
         if reg.enabled:
             tmetrics.pipeline_drains_counter(reg).inc(engine=self.engine,
                                                       cause=cause)
+
+    def on_carry(self, cause: str):
+        reg = self.registry
+        if reg.enabled:
+            tmetrics.pipeline_carries_counter(reg).inc(engine=self.engine,
+                                                       cause=cause)
 
     def on_fetch(self, steps: int):
         reg = self.registry
@@ -719,7 +798,9 @@ class _SlotScratch(_PagedScratch):
             self.aids = np.zeros((pad_to,), np.int32)
             self.aids[self.rows] = np.asarray(aids, np.int32)
         # device feedback: the previous dispatch's tokens are already in
-        # slot order (a changed live set drains the pipeline first)
+        # slot order, whatever its live set was (a row keeps its slot while
+        # it lives: a step carried across a changed live set takes each
+        # surviving slot's own token, PagedEngineAdapter._carried_ids)
         self.gather_idx = np.arange(pad_to, dtype=np.intp)
         for ids, pos, slots_, bt, _ in self._bufs:
             ids.fill(0)
@@ -843,6 +924,9 @@ class PagedEngineAdapter:
         # dispatches and drains counted by then)
         self._gap_mark: Optional[tuple] = None
         self._ready: Dict[int, int] = {}
+        # final chunks dispatched under the budget and not waited for, in
+        # dispatch order (:class:`_Parked`)
+        self._parked: List[_Parked] = []
         self._scratch = None
         self._spec = None              # SpeculativeDecodePath
         self._ragged = None            # RaggedDispatchPath
@@ -865,10 +949,12 @@ class PagedEngineAdapter:
             "dispatches_state_kernel": 0,
             "blocking_fetches": 0, "blocked_s": 0.0,
             # decode dispatches enqueued while the previous step was still
-            # unfetched, and in-flight steps drained synchronously because
-            # the live set changed under them, by what changed it
+            # unfetched, in-flight steps drained synchronously because the
+            # live set changed under them, by what changed it, and those
+            # whose tokens fed the changed set's step on the device instead
             "overlapped_dispatches": 0,
             **{f"pipeline_drains_{c}": 0 for c in _DRAIN_CAUSES},
+            **{f"pipeline_carries_{c}": 0 for c in _CARRY_CAUSES},
             # the intervals between two decode steps' tokens becoming
             # host-visible while a sequence was live at both (_note_gap):
             # all of them, those in which prefill dispatches were issued
@@ -1399,7 +1485,7 @@ class PagedEngineAdapter:
                     out.setdefault(s, []).extend(toks)
                     remaining[s] = remaining.get(s, num_steps) - len(toks)
             return out
-        if self._inflight is not None or self._ready:
+        if self._inflight is not None or self._ready or self._parked:
             self._stash_flush()
         # pending drained tokens stay in self._ready until this call is
         # past every fallible stage — a recoverable DeadlineExceeded /
@@ -1436,21 +1522,24 @@ class PagedEngineAdapter:
         return res
 
     def flush(self) -> Dict[int, int]:
-        """Retire the in-flight pipelined dispatch (if any) and hand back
-        every token not yet delivered: {seq_id: token}. {} in eager mode.
-        A deferred fetch failure aborts the pipeline (StepFailure,
+        """Retire the in-flight pipelined dispatch (if any), graduate every
+        prompt whose last chunk was not waited for, and hand back every
+        token not yet delivered: {seq_id: token}. {} in eager mode. A
+        deferred fetch failure aborts the pipeline (StepFailure,
         ``retry_safe=False``)."""
         ready = self._drain_ready()
         rec, self._inflight = self._inflight, None
-        if rec is not None:
-            self._note_drain()
-            try:
+        try:
+            if rec is not None:
+                self._note_drain()
                 ready.update(self._retire_or_abort([rec]))
-            except BaseException:
-                # the drained tokens were already generated and applied to
-                # host state — keep them deliverable past the failure
-                self._ready = {**ready, **self._ready}
-                raise
+            self._graduate()
+        except BaseException:
+            # the drained tokens were already generated and applied to
+            # host state — keep them deliverable past the failure
+            self._ready = {**ready, **self._ready}
+            raise
+        ready.update(self._drain_ready())
         return ready
 
     # -- eager path --------------------------------------------------------
@@ -1516,12 +1605,16 @@ class PagedEngineAdapter:
         if live:
             _pre_step_checks(self.seqs, live, self._pos_limit,
                              self.telemetry)
-        self._advance_prefill(seq_ids)
+        # with a decode step to hide behind, a prompt's last chunk is not
+        # waited for: its rows graduate behind a later step's fetch
+        self._advance_prefill(seq_ids, park=bool(live))
         if not live:
             return self.flush()
         ready = self._drain_ready()
         try:
-            return self._advance_pipeline(live, ready)
+            self._advance_pipeline(live, ready)
+            ready.update(self._drain_ready())   # first tokens of graduates
+            return ready
         except BaseException:
             # tokens drained (or retired) this call were already generated
             # and applied to host state — keep them deliverable past a
@@ -1532,13 +1625,16 @@ class PagedEngineAdapter:
     def _advance_pipeline(self, live: List[int],
                           ready: Dict[int, int]) -> Dict[int, int]:
         prev, self._inflight = self._inflight, None
-        if prev is not None and not self._matches(prev, live):
-            # live-set changed since the dispatch: drain it synchronously
+        matched = prev is not None and self._matches(prev, live)
+        if prev is not None and not matched and not self._carries(prev, live):
+            # another live set than rows that left and rows that joined can
+            # make of the dispatch's: drain it synchronously
             self._note_drain()
             ready.update(self._retire_or_abort([prev]))
             prev = None
         t0 = time.perf_counter()
         with _get_recorder().span("dispatch.build", cat="adapter"):
+            asked = len(live)
             try:
                 live = self._grow_for_step(live)
             except ServingError:
@@ -1547,15 +1643,18 @@ class PagedEngineAdapter:
             if not live:
                 self._inflight = prev
                 return ready
-            if prev is not None and not self._matches(prev, live):
+            if prev is not None and len(live) != asked:
                 # preemption shrank the batch mid-call: drain the old
                 # composition's dispatch before re-padding for the new one
                 self._note_drain("preempt")
                 ready.update(self._retire_or_abort([prev]))
                 prev = None
             scr = self._scratch_for(live)
-            scr.fill(self, need_tokens=prev is None)
+            # (growth that preempted nobody left ``live`` as it was asked)
+            carried = prev is not None and not matched
+            scr.fill(self, need_tokens=prev is None or carried)
             toks_dev = (None if prev is None
+                        else self._carried_ids(prev, scr) if carried
                         else self._feedback_tokens(prev, scr))
         cache_before = self.app.cache
         try:
@@ -1581,15 +1680,20 @@ class PagedEngineAdapter:
             live=tuple(live),
             states=tuple(self.seqs[s] for s in live),
             b=len(live), pad_to=scr.pad_to, out=out, t_dispatch=t0,
-            grown=1, rows=scr.rows)
+            grown=1, rows=scr.rows,
+            chunks_before=self.host_stats["prefill_dispatches"])
         for s in live:
             self.seqs[s].position += 1
+        if carried:
+            self._note_carry()
         self._drain_cause = None           # the step in flight is current
+        # in flight before the fetch below: a row that graduates behind it
+        # names itself as what changed this step's live set
+        self._inflight = rec
         if prev is not None:
             self.host_stats["overlapped_dispatches"] += 1
             self.telemetry.on_overlap()
             ready.update(self._retire_or_abort([prev, rec]))
-        self._inflight = rec
         return ready
 
     def _matches(self, rec: _Inflight, live: Sequence[int]) -> bool:
@@ -1597,12 +1701,58 @@ class PagedEngineAdapter:
                 and all(self.seqs.get(s) is st
                         for s, st in zip(rec.live, rec.states)))
 
+    def _carries(self, prev: _Inflight, live: Sequence[int]) -> bool:
+        """Whether a step over ``live`` can take its ids from ``prev`` on
+        the device: ``live`` is what rows that left (released, cancelled,
+        expired) and rows that joined (graduated) make of ``prev``'s set.
+        Every row ``prev`` stepped that is still running is stepped again,
+        as the state it was then; a row new to ``prev`` holds its last token
+        on the host. A caller that steps another set of running rows, a
+        seq_id re-admitted under a new state and a preemption are drained
+        as before."""
+        if self._drain_cause not in _CARRY_CAUSES:
+            return False
+        was = dict(zip(prev.live, prev.states))
+        stepped = set(live)
+        return (all(s in stepped for s, st in was.items()
+                    if self.seqs.get(s) is st)
+                and all(was.get(s, self.seqs[s]) is self.seqs[s]
+                        for s in live))
+
+    def _carried_ids(self, prev: _Inflight, scr):
+        """The ids of the step over ``scr``'s rows from ``prev``'s output
+        ON THE DEVICE (:meth:`_carries` holds): a row that stays takes its
+        sampled token from the row it had in ``prev`` (in a
+        :class:`_SlotScratch` rows are slots and the index is the
+        identity), a row that joined the token ``scr.fill`` wrote for it,
+        and a pad row follows row 0 in both. No host round trip, one small
+        program (:func:`carry_step_ids`)."""
+        old_row = {s: i if prev.rows is None else int(prev.rows[i])
+                   for i, s in enumerate(prev.live)}
+        take = np.zeros((scr.pad_to,), np.int32)
+        ids = scr.ids.copy()           # never the buffer a dispatch aliases
+        for i, s in enumerate(scr.live):
+            if s in old_row:
+                r = i if scr.rows is None else scr.rows[i]
+                take[r] = old_row[s]
+                ids[r, 0] = -1
+        if scr.rows is None and scr.pad_to > scr.b:
+            take[scr.b:] = take[0]
+            ids[scr.b:] = ids[0]
+        return carry_step_ids(self.app, prev.out["tokens"], take, ids)
+
+    def _note_carry(self):
+        cause, self._drain_cause = self._drain_cause, None
+        self.host_stats[f"pipeline_carries_{cause}"] += 1
+        self.telemetry.on_carry(cause)
+
     def _note_stale(self, cause: str):
         """Rows left or joined (``cause``) while a step is in flight. The
         step stays in flight — nothing blocks here; the next decode call
-        sees the changed live set, drains it and counts ``cause``. If none
-        of its rows is left, nobody is owed its tokens: it is dropped
-        unfetched."""
+        sees the changed live set and carries the step's tokens into the
+        new set's step on the device (or, where it cannot, drains it),
+        counted under ``cause``. If none of its rows is left, nobody is
+        owed its tokens: it is dropped unfetched."""
         rec = self._inflight
         if rec is None:
             return
@@ -1662,9 +1812,20 @@ class PagedEngineAdapter:
     def _retire_or_abort(self, records: List[Optional[_Inflight]]
                          ) -> Dict[int, int]:
         try:
-            return self._retire(records[0])
+            res = self._retire(records[0])
         except Exception as e:
             self._abort_pipeline(records, e)
+        # the chunks enqueued before that step have run: their rows' first
+        # tokens are host-visible for nothing
+        try:
+            self._graduate(records[0].chunks_before)
+        except BaseException:
+            # what was fetched is still owed; a step enqueued on top of the
+            # failed chunk is unwound like one on top of a failed step
+            self._ready = {**res, **self._ready}
+            self._unwind_inflight(records[1:])
+            raise
+        return res
 
     def _abort_pipeline(self, records: Sequence[Optional[_Inflight]],
                         cause: Exception):
@@ -1674,23 +1835,33 @@ class PagedEngineAdapter:
         KV growth return to the last DELIVERED token — and raise a
         :class:`StepFailure` with ``retry_safe=False`` (the donated device
         cache was consumed by the failed dispatch chain; re-admit or
-        rebuild)."""
-        self._scratch = None
-        seq_ids: Tuple[int, ...] = ()
-        for rec in records:
-            if rec is None:
-                continue
-            if not seq_ids:
-                seq_ids = rec.live
-            for s, st in zip(rec.live, rec.states):
-                if self.seqs.get(s) is st:
-                    st.position -= 1
-            self._unwind_inflight_growth(rec)
+        rebuild). A final chunk nobody waited for is part of that chain:
+        every sequence packed in one is rolled back as a failed chunk
+        dispatch rolls it back."""
+        parked, self._parked = self._parked, []
+        for p in parked:
+            self._abort_prefill_rows(self._still_pending(p.packed))
+        self._unwind_inflight(records)
+        seq_ids = next((rec.live for rec in records if rec is not None), ())
         self.telemetry.on_step_failure("decode", self._tenant_of(seq_ids))
         raise _trace_error(StepFailure(
             "pipelined decode fetch failed; every in-flight lookahead step "
             "was rolled back to the last delivered token",
             phase="decode", seq_ids=seq_ids, retry_safe=False)) from cause
+
+    def _unwind_inflight(self, records: Sequence[Optional[_Inflight]]):
+        """Nothing is in flight any more: the positions and the paged KV
+        growth of ``records`` return to the last token fetched."""
+        self._scratch = None
+        self._inflight = None
+        self._drain_cause = None
+        for rec in records:
+            if rec is None:
+                continue
+            for s, st in zip(rec.live, rec.states):
+                if self.seqs.get(s) is st:
+                    st.position -= 1
+            self._unwind_inflight_growth(rec)
 
     def _unwind_inflight_growth(self, rec: _Inflight):
         if not rec.grown:
@@ -1971,6 +2142,10 @@ class PagedEngineAdapter:
             "pipeline_inflight": (0 if self._inflight is None
                                   else len(self._inflight.live)),
             "ready_undelivered": [int(s) for s in sorted(self._ready)],
+            # prompts whose last chunk is dispatched and not waited for
+            "prefill_parked": [int(s) for p in self._parked
+                               for _, s, cst in p.finals
+                               if self._chunks.get(s) is cst],
             "host_stats": dict(self.host_stats),
         }
         mgr = self.app.kv_mgr
@@ -2430,19 +2605,21 @@ class PagedEngineAdapter:
         """seq_ids admitted but still mid-prefill (chunked admissions)."""
         return self._chunks.keys()
 
-    def _advance_prefill(self, seq_ids=None):
+    def _advance_prefill(self, seq_ids=None, park: bool = False):
         """Run at most one packed prefill-chunk dispatch for pending
         admissions; finished sequences' first tokens land in ``_ready``.
         ``seq_ids`` is the step call's explicit target set (None = all):
         an expired pending admission outside it is skipped, not raised —
         a healthy row must not be stalled by an unrelated request's
-        budget."""
+        budget. ``park``: see :meth:`_prefill_step`."""
         if self._chunks:
             self._prefill_step(budget=self.prefill_budget_tokens,
-                               target=seq_ids)
+                               target=seq_ids, park=park)
+
     def _prefill_step(self, budget: Optional[int] = None, only=None,
                       target=None,
-                      token_at: Optional[Dict[int, float]] = None):
+                      token_at: Optional[Dict[int, float]] = None,
+                      park: bool = False):
         """ONE packed chunk dispatch: pending sequences (admission order)
         each contribute their next uncached-suffix chunk as a ragged row
         of a single ctx-bucket ``_run_paged`` call, bounded by ``budget``
@@ -2457,7 +2634,17 @@ class PagedEngineAdapter:
         instead, and the caller reports the whole call only once it is past
         rollback. ``target`` is the step call's explicit seq_ids set (None
         = all): an expired pending admission is raised only when targeted,
-        merely skipped from packing otherwise."""
+        merely skipped from packing otherwise.
+
+        ``park`` (the lookahead's call, with a decode step to hide behind):
+        a dispatch that holds a prompt's FINAL chunk is not waited for
+        either. Its async fetch is started and its rows are parked
+        (:class:`_Parked`): they stay pending admissions until
+        :meth:`_graduate` finds their token host-visible behind a later
+        decode step's fetch. Every other caller materialises by contract,
+        and first graduates whatever an earlier call parked."""
+        if self._parked and not park:
+            self._graduate()
         chunks = self._chunks
         order = sorted(chunks, key=lambda s: chunks[s].admit_idx)
         if only is not None:
@@ -2487,6 +2674,8 @@ class PagedEngineAdapter:
             if len(rows) == self.batch or left < 1:
                 break
             st = chunks[s]
+            if st.done == len(st.prompt):
+                continue               # parked: no chunk left to run
             n = int(min(len(st.prompt) - st.done,
                         self.prefill_chunk_tokens, left))
             rows.append((s, st.done, n, st.done + n == len(st.prompt)))
@@ -2537,7 +2726,7 @@ class PagedEngineAdapter:
                 # dispatches fetch nothing — their samples are discarded
                 # unmaterialized.
                 new, t_token = (self._fetch_prefill_tokens(out)
-                                if final_rows else (None, None))
+                                if final_rows and not park else (None, None))
         except ServingError as e:
             self._abort_prefill_rows(seq_list)
             _trace_error(e)                # attach a timeline id in place
@@ -2550,20 +2739,20 @@ class PagedEngineAdapter:
                 "prefilled sequence packed in it was rolled back",
                 phase="prefill", seq_ids=seq_list,
                 retry_safe=self.app.cache is cache_before)) from e
-        bs = self.app.kv_mgr.spec.block_size
         for s, _, n, _ in rows:
             chunks[s].done += n
+        out_rows = packed[-1]              # slot-ordered pack: row != i
+        finals = [(i if out_rows is None else int(out_rows[i]), s, chunks[s])
+                  for i, s in final_rows]
         if final_rows:
-            # this dispatch's tokens were MATERIALIZED, and the donated
-            # cache chain orders every earlier dispatch before it — all
-            # covered blocks are now confirmed written. Unfetched
-            # intermediate dispatches confirm nothing: a genuine async
-            # device failure in one surfaces at a later fetch, and the
-            # rollback there must still find their blocks in _unwritten
-            # (or their allocate-time hashes would be freed as valid).
-            for s2, cst in chunks.items():
-                self._unwritten.difference_update(
-                    self.app.kv_mgr.tables[s2][:cst.done // bs])
+            covered = [(s2, cst, cst.done) for s2, cst in chunks.items()]
+            if park:
+                self._parked.append(_Parked(
+                    out=out, no=self.host_stats["prefill_dispatches"],
+                    finals=finals, covered=covered,
+                    packed=[(s, chunks[s]) for s in seq_list]))
+            else:
+                self._confirm_written(covered)
         pad_rows, width = packed[0].shape
         real = sum(n for _, _, n, _ in rows)
         self.host_stats["prefill_real_tokens"] += real
@@ -2582,11 +2771,42 @@ class PagedEngineAdapter:
             self.host_stats["prefill_tokens_cross_decoder"] += through
         self.telemetry.on_prefill_chunk(len(rows), pad_rows, real,
                                         pad_rows * width, through)
-        for i, s in final_rows:
-            st = chunks.pop(s)
+        if final_rows and not park:
+            self._graduate_rows(finals, new, t_token, token_at)
+
+    def _confirm_written(self, covered) -> None:
+        """A chunk dispatch's tokens were MATERIALIZED, and the donated
+        cache chain orders every earlier dispatch before it: the blocks
+        ``covered`` lists ((seq_id, chunk state, tokens written as of that
+        dispatch) of every pending admission then) are confirmed written.
+        Unfetched dispatches confirm nothing: a genuine async device
+        failure in one surfaces at a later fetch, and the rollback there
+        must still find their blocks in ``_unwritten`` (or their
+        allocate-time hashes would be freed as valid)."""
+        bs = self.app.kv_mgr.spec.block_size
+        for s, cst, done in covered:
+            if self._chunks.get(s) is cst:
+                self._unwritten.difference_update(
+                    self.app.kv_mgr.tables[s][:done // bs])
+
+    def _still_pending(self, packed) -> List[int]:
+        """The seq_ids of ``packed`` ((seq_id, chunk state) pairs) that are
+        still the pending admissions they were."""
+        return [s for s, cst in packed if self._chunks.get(s) is cst]
+
+    def _graduate_rows(self, finals, new, t_token: float,
+                       token_at: Optional[Dict[int, float]] = None):
+        """The prompts of ``finals`` ((output row, seq_id, chunk state))
+        become running rows, their first token from ``new`` stashed in
+        ``_ready``; one that left while its token was on its way is passed
+        over."""
+        rec = _get_recorder()
+        for row, s, st in finals:
+            if self._chunks.get(s) is not st:
+                continue
+            del self._chunks[s]
             self._unwritten.difference_update(self.app.kv_mgr.tables[s])
-            out_rows = packed[-1]          # slot-ordered pack: row != i
-            tok = int(new[i if out_rows is None else out_rows[i], 0])
+            tok = int(new[row, 0])
             self.seqs[s] = _SeqState(
                 position=len(st.prompt), last_token=tok,
                 tokens=list(st.prompt) + [tok],
@@ -2603,6 +2823,34 @@ class PagedEngineAdapter:
                 self.telemetry.on_add([s], [st.prompt], st.t0, [t_token],
                                       live=1, padded=1, count_rows=False,
                                       tenants=[_meta_tenant(st.meta)])
+
+    def _graduate(self, behind: Optional[int] = None):
+        """Graduate the rows of parked final chunks, oldest first.
+        ``behind``: the ``chunks_before`` of a decode step whose tokens were
+        just fetched, so every chunk up to that number has run and its
+        tokens (async-prefetched) are host-visible for nothing. ``None``:
+        every one, waited for — the callers with no decode step to hide
+        behind, counted as a blocking fetch. A failure rolls every sequence
+        packed in that dispatch back, as at the dispatch."""
+        while self._parked and (behind is None
+                                or self._parked[0].no <= behind):
+            p = self._parked.pop(0)
+            try:
+                new, t_token = self._fetch_prefill_tokens(
+                    p.out, waited=behind is None)
+            except Exception as e:
+                seq_list = tuple(self._still_pending(p.packed))
+                tenant = _common_tenant(_meta_tenant(cst.meta)
+                                        for _, cst in p.packed)
+                self._abort_prefill_rows(seq_list)
+                self.telemetry.on_step_failure("prefill", tenant)
+                raise _trace_error(StepFailure(
+                    "a final prefill chunk failed at its deferred fetch; "
+                    "every partially-prefilled sequence packed in it was "
+                    "rolled back", phase="prefill", seq_ids=seq_list,
+                    retry_safe=False)) from e
+            self._confirm_written(p.covered)
+            self._graduate_rows(p.finals, new, t_token)
 
     def _pack_prefill_rows(self, rows):
         """Build the ragged packed-chunk inputs: one row per sequence,
@@ -2724,18 +2972,22 @@ class PagedEngineAdapter:
         self.telemetry.on_prefill_dispatch(experts, attn)
         return out
 
-    def _fetch_prefill_tokens(self, out) -> Tuple[np.ndarray, float]:
+    def _fetch_prefill_tokens(self, out, waited: bool = True
+                              ) -> Tuple[np.ndarray, float]:
         """Materialize a final-chunk dispatch's sampled tokens (the one
         blocking sync of a packed admission; async-prefetched). Returns
         them with the instant they became host-visible: the ``token`` stamp
-        of the timelines whose first token is among them."""
+        of the timelines whose first token is among them. ``waited`` False:
+        a decode step enqueued behind the dispatch was fetched already, so
+        nothing blocks and no blocking fetch is counted."""
         t0 = time.perf_counter()
         with _get_recorder().span("fetch.tokens", cat="adapter",
                                   engine=self.engine_name, phase="prefill"):
             toks = np.asarray(out["tokens"])
         t1 = time.perf_counter()
-        self.host_stats["prefill_blocking_fetches"] += 1
-        self.host_stats["prefill_blocked_s"] += t1 - t0
+        if waited:
+            self.host_stats["prefill_blocking_fetches"] += 1
+            self.host_stats["prefill_blocked_s"] += t1 - t0
         return toks.reshape(toks.shape[0], -1), t1
 
     def _drop_unwritten(self, sid):

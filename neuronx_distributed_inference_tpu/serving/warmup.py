@@ -55,6 +55,7 @@ from ..modules import autobucketing
 from ..telemetry import metrics as tmetrics
 from ..telemetry.registry import NULL_REGISTRY
 from ..telemetry.trace import get_recorder as _get_recorder
+from .adapter import carry_step_ids
 
 __all__ = ["precompile", "memory_ledger", "WARMUP_SCHEMA", "LEDGER_SCHEMA"]
 
@@ -121,7 +122,11 @@ def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
     run) — what the default ``PagedEngineAdapter(app)``, and a ragged
     adapter shed back to two-phase, dispatches — the fused decode loop,
     and the speculative verify widths: the exact shape set the serving
-    adapters dispatch (serving/ragged/path.py, serving/adapter.py).
+    adapters dispatch (serving/ragged/path.py, serving/adapter.py). Beside
+    the decode step goes the program that makes its ids on the device when
+    the live set changed under the step in flight (``carry_ids``,
+    :func:`~.adapter.carry_step_ids`): one a batch bucket, and one a pair of
+    them where the ladder has more than one rung (``carry_ids_from<rows>``).
 
     A recurrent/hybrid stack (``app.state_slots``) warms only the programs
     it can run: ``paged.w1``, ``paged.w<b>`` and ``paged_pack.w<b>`` (no
@@ -214,6 +219,20 @@ def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
                                  np.zeros((b, w), np.int32),
                                  np.full((b, w), -1, np.int32), bt,
                                  np.ones((b,), np.int32), **lora_kw)))
+
+    def carry_thunk(rows_before, rows):
+        # every row takes row 0's token of an all-zero step: ids only
+        carry_step_ids(app, np.zeros((rows_before,), np.int32),
+                       np.zeros((rows,), np.int32),
+                       np.full((rows, 1), -1, np.int32))
+
+    if 1 in widths:
+        # the decode step's rows are the state slots where a stack has them
+        rungs = [b] if app.state_slots else app.batch_buckets
+        plan += [("carry_ids" if before == rows
+                  else f"carry_ids_from{before}", rows,
+                  lambda before=before, rows=rows: carry_thunk(before, rows))
+                 for rows in rungs for before in rungs]
     return plan
 
 

@@ -41,6 +41,7 @@ MOE_EXPERTS_TOTAL = "nxdi_moe_experts_total"            # engine, count
 MOE_ASSIGNMENTS_TOTAL = "nxdi_moe_assignments_total"    # engine, kind
 MOE_GROUP_ROWS_TOTAL = "nxdi_moe_group_rows_total"      # engine, hit
 PIPELINE_DRAINS_TOTAL = "nxdi_pipeline_drains_total"    # engine, cause
+PIPELINE_CARRIES_TOTAL = "nxdi_pipeline_carries_total"  # engine, cause
 
 # -- serving resilience (serving.py + resilience/) --------------------------
 PREEMPTIONS_TOTAL = "nxdi_preemptions_total"            # engine, reason, tenant
@@ -343,6 +344,15 @@ def pipeline_drains_counter(reg):
         PIPELINE_DRAINS_TOTAL,
         "In-flight decode steps fetched synchronously because the live "
         "set changed under them; cause=admit|release|preempt|liveset",
+        labels=("engine", "cause"))
+
+
+def pipeline_carries_counter(reg):
+    return reg.counter(
+        PIPELINE_CARRIES_TOTAL,
+        "In-flight decode steps whose sampled tokens fed the next step over "
+        "a CHANGED live set on the device (rows left, rows joined: nothing "
+        "was fetched before the dispatch); cause=admit|release",
         labels=("engine", "cause"))
 
 

@@ -1496,10 +1496,11 @@ def test_keye_vl2_select_kernel_share_reads_the_adapters_two_counters():
         name=metric, unit="%", better="higher", source="program_counter",
         layer="Step graphs", moves="itl_p50_ms", workloads=[KEYE_CELL])
     # appended where it was added: what follows it is ISSUE 52's seven,
-    # then ISSUE 54's five, ISSUE 56's four and ISSUE 61's two
+    # then ISSUE 54's five, ISSUE 56's four, ISSUE 61's two and ISSUE 62's
     later = BENCHMARK["per_layer"][BENCHMARK["per_layer"].index(entry) + 1:]
     assert [m["name"] for m in later] == list(
-        TTFT_METRICS + PHI4_METRICS + COMMAND_A_METRICS + LFM2_METRICS)
+        TTFT_METRICS + PHI4_METRICS + COMMAND_A_METRICS + LFM2_METRICS
+        + CARRY_METRICS)
 
     def ctx(**counters):
         return {"before": {"counters": {"host_stats.sparse_dispatches": 10}},
@@ -1532,10 +1533,11 @@ def test_a_ttft_phase_metric_is_the_chat_cells_alone(metric):
     ``engine.ttft_*`` (``ServingEngine.stats``, always on) and read nothing
     from a program without the keys."""
     from harness import readers
-    # (ISSUE 54's five, ISSUE 56's four and ISSUE 61's two were appended
-    # behind them)
-    assert tuple(m["name"] for m in BENCHMARK["per_layer"][-18:]) == \
-        TTFT_METRICS + PHI4_METRICS + COMMAND_A_METRICS + LFM2_METRICS
+    # (ISSUE 54's five, ISSUE 56's four, ISSUE 61's two and ISSUE 62's one
+    # were appended behind them)
+    assert tuple(m["name"] for m in BENCHMARK["per_layer"][-19:]) == \
+        TTFT_METRICS + PHI4_METRICS + COMMAND_A_METRICS + LFM2_METRICS \
+        + CARRY_METRICS
     entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == metric)
     assert entry["moves"] == "ttft_p50_ms"
     assert entry["workloads"] == ["olmoe-chat-steady"]
@@ -1907,6 +1909,49 @@ LFM2_METRICS = ("kernel.moe_decode_all_held_roofline",
                 "kernel.shortconv_decode_roofline")
 LFM2_TYPES = (["conv", "conv"] + ["full_attention", "conv", "conv", "conv"] * 4
               + ["full_attention", "conv", "conv"] * 2)
+#: ISSUE 62's, appended last: a data file, no cell and no configuration
+CARRY_METRICS = ("adapter.liveset_carry_share",)
+
+
+def test_liveset_carry_share_reads_the_adapters_four_counters():
+    """``adapter.liveset_carry_share`` (ISSUE 62): the live-set changes by
+    rows that joined or left across which the step in flight was carried,
+    over those and the ones it was drained for; listed where
+    ``adapter.decode_overlap_share`` is; a program without the carry
+    counters (the parent) reads 0, a window without such a change nothing."""
+    from harness import readers
+    metric = CARRY_METRICS[0]
+    entry = BENCHMARK["per_layer"][-1]
+    overlap = next(m for m in BENCHMARK["per_layer"]
+                   if m["name"] == "adapter.decode_overlap_share")
+    assert entry == dict(
+        name=metric, unit="%", better="higher", source="program_counter",
+        layer="Adapter", moves="itl_p95_ms", workloads=overlap["workloads"])
+    spec = build.load_json("layer_metrics", metric + ".json")
+    assert {k: spec[k] for k in ("unit", "better", "source", "layer",
+                                 "moves")} == \
+        {k: entry[k] for k in ("unit", "better", "source", "layer", "moves")}
+    assert not os.path.exists(os.path.join(
+        build.BENCH_DIR, "layer_metrics", metric + ".py"))
+
+    def ctx(**counters):
+        return {"before": {"counters": {
+                    "host_stats.pipeline_drains_release": 3}},
+                "after": {"counters": {"host_stats." + k: v
+                                       for k, v in counters.items()}}}
+    assert readers.read_metric(metric, ctx(
+        pipeline_carries_admit=30, pipeline_carries_release=50,
+        pipeline_drains_admit=2, pipeline_drains_release=21)) == \
+        pytest.approx(100 * 80 / 100)
+    # the parent: the drains alone
+    assert readers.read_metric(metric, ctx(
+        pipeline_drains_admit=52, pipeline_drains_release=418)) == 0.0
+    assert readers.read_metric(metric, ctx(
+        pipeline_drains_release=3, dispatches=7)) is None
+    # the adapter holds the counters from its first dispatch on
+    from neuronx_distributed_inference_tpu.serving import adapter
+    assert adapter._CARRY_CAUSES == ("admit", "release")
+    assert set(adapter._CARRY_CAUSES) < set(adapter._DRAIN_CAUSES)
 
 
 def test_lfm2_moe_keeps_every_published_number():

@@ -13,8 +13,11 @@ the CPU in float32, greedy:
       ``max_unread_tokens`` — for a plain attention stack, the granite toy
       stack (Mamba-2 mixers) and the delta-rule toy stack (rows in slot
       order);
-  (b) ``host_stats`` shows the engagement: ``overlapped_dispatches`` and
-      each drain under what caused it;
+  (b) ``host_stats`` shows the engagement: ``overlapped_dispatches``, a
+      row that joined or left CARRIED under what caused it (ISSUE 62: no
+      admission and no finish drains the step in flight, with or without
+      ``prefill_budget_tokens``, under which a prompt's last chunk is not
+      waited for either) and each drain that is left under its cause;
   (c) nothing but warmed step programs is dispatched after
       ``declare_steady_state()``, and a step fed on the device is the
       executable a step fed from the host is;
@@ -105,12 +108,18 @@ def stack(request):
     return STACKS[request.param]()
 
 
-def _serve(app, depth, stop_token=None, **engine_kw):
+#: ``prefill_budget_tokens`` of the budgeted runs: one chunk of the widest
+#: bucket of every toy stack before each decode step
+BUDGET = 16
+
+
+def _serve(app, depth, stop_token=None, budget=None, **engine_kw):
     """One scripted run: four requests at once, the other four one a pass
     from pass 2 on; request STOP carries ``stop_token``; request CANCEL is
     cancelled once three of its tokens are out. Returns what each stream
     delivered and how it ended, the adapter and the engine."""
-    ad = PagedEngineAdapter(app, pipeline_depth=depth)
+    ad = PagedEngineAdapter(app, pipeline_depth=depth,
+                            prefill_budget_tokens=budget)
     eng = ServingEngine(ad, starvation_bound_s=1e9, **engine_kw)
     streams = {}
 
@@ -135,19 +144,22 @@ def _serve(app, depth, stop_token=None, **engine_kw):
         if not later and not eng.has_work:
             break
     assert not eng.has_work and not app.kv_mgr.tables
-    assert ad._inflight is None and not ad._ready
+    assert ad._inflight is None and not ad._ready and not ad._parked
+    assert not ad._unwritten and not ad._state_slot
     got = {i: (list(s.tokens), s.finish_reason) for i, s in streams.items()}
     return got, ad, eng, in_flight_at_cancel
 
 
-def test_default_engine_serves_the_eager_streams(stack):
+@pytest.mark.parametrize("budget", [None, BUDGET])
+def test_default_engine_serves_the_eager_streams(stack, budget):
     free = stack.kv_mgr.allocator.num_free
     plain, *_ = _serve(stack, 0)
     stop_token = plain[STOP][0][4]         # the fifth token of request STOP
-    eager, ad0, _, _ = _serve(stack, 0, stop_token=stop_token)
-    ahead, ad1, eng1, cancelled_in_flight = _serve(stack, None,
-                                                  stop_token=stop_token)
+    eager, ad0, _, _ = _serve(stack, 0, stop_token=stop_token, budget=budget)
+    ahead, ad1, eng1, cancelled_in_flight = _serve(
+        stack, None, stop_token=stop_token, budget=budget)
     assert ahead == eager
+    assert eager == _serve(stack, 0, stop_token=stop_token)[0]
     # what the script asked for did happen, on both sides
     assert ahead[1][1] == ahead[2][1] == "length"
     assert [len(ahead[i][0]) for i in (1, 2)] == [1, 2]
@@ -157,14 +169,22 @@ def test_default_engine_serves_the_eager_streams(stack):
     assert cancelled_in_flight is True
     for i in set(range(len(PROMPTS))) - {STOP, CANCEL}:
         assert ahead[i][1] == "length" and len(ahead[i][0]) == BUDGETS[i]
-    # (b) the engagement, and each drain under its cause
+    # (b) the engagement: a row that joins or leaves is carried, and no
+    # admission and no finish drains the step in flight
     h0, h1 = ad0.host_stats, ad1.host_stats
     assert h0["overlapped_dispatches"] == 0
-    assert not any(h0[k] for k in h0 if k.startswith("pipeline_drains_"))
+    assert not any(h0[k] for k in h0 if k.startswith(("pipeline_drains_",
+                                                       "pipeline_carries_")))
     assert h1["overlapped_dispatches"] > 0
-    assert h1["pipeline_drains_release"] > 0      # a finish / the cancel
-    assert h1["pipeline_drains_admit"] > 0        # a row admitted mid-run
+    assert h1["pipeline_carries_release"] > 0     # a finish / the cancel
+    assert h1["pipeline_carries_admit"] > 0       # a row admitted mid-run
+    assert h1["pipeline_drains_release"] == h1["pipeline_drains_admit"] == 0
     assert h1["pipeline_drains_preempt"] == 0
+    if budget:
+        # the first prompts' last chunks find no decode row to hide behind;
+        # no later one is waited for
+        assert (0 < h1["prefill_blocking_fetches"]
+                < h0["prefill_blocking_fetches"])
     # a lookahead step whose row had just ended is the only extra work:
     # at most one row-step a request
     assert h0["dispatches"] <= h1["dispatches"] <= (h0["dispatches"]
@@ -233,13 +253,21 @@ def test_nothing_but_warmed_step_programs_in_steady_state(stack):
     before = len(_COMPILES)
     try:
         got, ad, _, _ = _serve(stack, None)
+        got_b, ad_b, _, _ = _serve(stack, None, budget=BUDGET)
     finally:
         stack.declare_steady_state(False)
-    assert ad.host_stats["overlapped_dispatches"] > 0
+    assert got_b == got
+    for a in (ad, ad_b):
+        assert a.host_stats["overlapped_dispatches"] > 0
+        # the program that merges a carried step's ids ran, warmed
+        assert a.host_stats["pipeline_carries_admit"] > 0
+        assert a.host_stats["pipeline_carries_release"] > 0
     assert stack.warmup_state()["incidents"] == []
     assert _COMPILES[before:] == []
     assert report["n_graphs"] == len({(g["kind"], g["bucket"])
                                       for g in report["graphs"]})
+    assert ("carry_ids", SERVE["batch_size"]) in {
+        (g["kind"], g["bucket"]) for g in report["graphs"]}
     assert all(reason in ("length", "cancelled") for _, reason in got.values())
 
 
@@ -255,10 +283,21 @@ def test_counters_reach_the_registry(stack):
     over = snap[tmetrics.OVERLAPPED_DISPATCHES_TOTAL]["series"]
     assert sum(s["value"] for s in over) == \
         ad.host_stats["overlapped_dispatches"] > 0
+    carries = {s["labels"]["cause"]: s["value"]
+               for s in snap[tmetrics.PIPELINE_CARRIES_TOTAL]["series"]}
+    assert carries == {c: ad.host_stats[f"pipeline_carries_{c}"]
+                       for c in carries} and carries.get("release", 0) >= 1
+    # what still drains, and reaches the registry under its cause: the
+    # caller stepping another set of the running rows
+    ad.add_requests([0, 1], [PROMPTS[0], PROMPTS[2]])
+    ad.step_ahead()
+    ad.step_ahead([0])
+    ad.release([0, 1])
+    snap = reg.snapshot()["metrics"]
     drains = {s["labels"]["cause"]: s["value"]
               for s in snap[tmetrics.PIPELINE_DRAINS_TOTAL]["series"]}
     assert drains == {c: ad.host_stats[f"pipeline_drains_{c}"]
-                      for c in drains} and drains.get("release", 0) >= 1
+                      for c in drains} and drains.get("liveset", 0) >= 1
 
 
 # ---------------------------------------------------------------------------

@@ -614,7 +614,7 @@ def test_f_the_warm_up_plan_is_the_five_paged_programs(ref, gate_weights):
     report = precompile(_app(ref, gate_weights), widths=[1, 16, 32])
     assert [(g["kind"], g["bucket"]) for g in report["graphs"]] == [
         ("paged", 1), ("paged", 16), ("paged_pack", 16), ("paged", 32),
-        ("paged_pack", 32)]
+        ("paged_pack", 32), ("carry_ids", BATCH)]
     sites = {k["site"] for k in report["kernels"]}
     assert {"sparse_attn", "kv_index_pool", "paged_decode",
             "paged_prefill"} <= sites

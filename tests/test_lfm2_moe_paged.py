@@ -389,7 +389,8 @@ def test_warmup_plan_and_the_engagement_record(ref, gate_weights):
     pairs = [(g["kind"], g["bucket"]) for g in report["graphs"]]
     per_tw = [("paged", 1), ("paged", 8), ("paged_pack", 8), ("paged", 32),
               ("paged_pack", 32)]
-    assert pairs == per_tw * len(app._bt_buckets)
+    # ... and, last, the program that makes a carried step's ids
+    assert pairs == per_tw * len(app._bt_buckets) + [("carry_ids", BATCH)]
     notes = {k["site"]: k for k in report["kernels"]}
     # a slot is 5 conv layers x 32 channels x 2 products x 4 B
     assert notes["recurrent_state"] == {

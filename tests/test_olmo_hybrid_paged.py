@@ -532,8 +532,9 @@ def test_warmup_plan_and_the_engagement_record(app):
     pairs = [(g["kind"], g["bucket"]) for g in report["graphs"]]
     per_tw = [("paged", 1), ("paged", 8), ("paged_pack", 8), ("paged", 16),
               ("paged_pack", 16)]
-    assert pairs == per_tw * len(app._bt_buckets)
-    assert len(set(pairs)) == len(per_tw)
+    # ... and, last, the program that makes a carried step's ids
+    assert pairs == per_tw * len(app._bt_buckets) + [("carry_ids", BATCH)]
+    assert len(set(pairs)) == len(per_tw) + 1
     # the record names the state kind, a slot's bytes and the scan chunk
     s = app.spec.ssm
     slot_bytes = 3 * (2 * 8 * 16 * 4 + (2 * 2 * 8 + 2 * 16) * 3 * 4)

@@ -807,8 +807,8 @@ def test_f_the_counters_have_their_series(ref, gate_weights):
         report = precompile(app)
         # the five paged programs a recurrent stack warms, and no other
         assert sorted((g["kind"], g["bucket"]) for g in report["graphs"]) \
-            == [("paged", 1), ("paged", 8), ("paged", 16),
-                ("paged_pack", 8), ("paged_pack", 16)]
+            == [("carry_ids", BATCH), ("paged", 1), ("paged", 8),
+                ("paged", 16), ("paged_pack", 8), ("paged_pack", 16)]
     finally:
         telemetry.disable()
 
@@ -1027,7 +1027,7 @@ def test_g_chunks_of_both_widths_then_decode_are_the_references(
     # one program a (kind, width), as before: the five a recurrent stack has
     report = precompile(app)
     assert sorted((g["kind"], g["bucket"]) for g in report["graphs"]) \
-        == [("paged", 1), ("paged", 8), ("paged", 16),
+        == [("carry_ids", BATCH), ("paged", 1), ("paged", 8), ("paged", 16),
             ("paged_pack", 8), ("paged_pack", 16)]
 
 

@@ -795,7 +795,8 @@ def test_warmup_plan_and_the_engagement_record(ref, gate_weights):
     pairs = [(g["kind"], g["bucket"]) for g in report["graphs"]]
     per_tw = [("paged", 1), ("paged", 8), ("paged_pack", 8), ("paged", 32),
               ("paged_pack", 32)]
-    assert pairs == per_tw * len(app._bt_buckets)
+    # ... and, last, the program that makes a carried step's ids
+    assert pairs == per_tw * len(app._bt_buckets) + [("carry_ids", BATCH)]
     notes = {k["site"]: k for k in report["kernels"]}
     assert notes["moe_share"] == {"site": "moe_share", "path": "xla",
                                   "reason": "held=4 of 16 from 4 top_k=4"}

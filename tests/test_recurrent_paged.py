@@ -496,8 +496,9 @@ def test_warmup_plan_has_only_the_programs_the_stack_runs(app):
     pairs = [(g["kind"], g["bucket"]) for g in report["graphs"]]
     per_tw = [("paged", 1), ("paged", 8), ("paged_pack", 8), ("paged", 16),
               ("paged_pack", 16)]
-    assert pairs == per_tw * len(app._bt_buckets)
-    assert len(set(pairs)) == len(per_tw)
+    # ... and, last, the program that makes a carried step's ids
+    assert pairs == per_tw * len(app._bt_buckets) + [("carry_ids", BATCH)]
+    assert len(set(pairs)) == len(per_tw) + 1
     one = precompile(app, widths=[16], bt_widths=[app._bt_buckets[0]])
     assert [(g["kind"], g["bucket"]) for g in one["graphs"]] == \
         [("paged", 16), ("paged_pack", 16)]

@@ -101,13 +101,18 @@ def test_precompile_report_and_debug_surface(warm_app):
     assert rep["total_seconds"] > 0
     for g in rep["graphs"]:
         assert g["outcome"] in ("compile", "cache_load", "warm")
-        assert g["seconds"] >= 0 and g["kind"] in ("ragged", "paged")
+        assert g["seconds"] >= 0
+        assert g["kind"] in ("ragged", "paged", "carry_ids")
     assert sorted(g["bucket"] for g in rep["graphs"]
                   if g["kind"] == "ragged") == sorted(WARM_WIDTHS)
     # the two-phase graph the DEFAULT adapter dispatches is warmed too:
     # T=1 plus whichever warm widths are ctx buckets (none of these are)
     assert [g["bucket"] for g in rep["graphs"]
             if g["kind"] == "paged"] == [1]
+    # ... and beside the decode step, the program that makes its ids on the
+    # device when the live set changed under the step in flight
+    assert [g["bucket"] for g in rep["graphs"]
+            if g["kind"] == "carry_ids"] == [warm_app.tpu_config.batch_size]
     # every traced graph noted the pool's page as allocated (2 heads of 16
     # do not fold: a slot a head), and every T=1 graph which attention path
     # it took — this toy's head_dim 16 is outside the kernels' geometry,
@@ -147,7 +152,7 @@ def test_second_replica_compiles_nothing():
     assert c2 is None or c2.get(kind="ragged", bucket="1") == 0
     hits2 = reg2.get(tmetrics.JIT_CACHE_HITS_TOTAL)
     assert (hits2.get(kind="ragged") + hits2.get(kind="paged")
-            == rep2["n_graphs"])
+            + hits2.get(kind="carry_ids") == rep2["n_graphs"])
     # ... but cold-start truth per graph regardless: compile_seconds is
     # set for every first-seen signature, build or load
     for w in WARM_WIDTHS:
