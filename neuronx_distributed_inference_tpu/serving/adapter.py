@@ -8,7 +8,8 @@ The engine owns scheduling; the adapter owns device state:
 
   * ``add_requests(seq_ids, prompts)``  — admit prompts (blocks, chunk
     state, a state slot on a recurrent or window-pool stack) and prefill
-    them in packed chunks
+    them in packed chunks (``defer=True``, the serving engine's call: admit
+    only, the step calls run the chunks)
   * ``step(seq_ids=None)``              — one decode step for the given
     (default: all) running rows, dispatched and fetched: THIS step's tokens
   * ``step_ahead(seq_ids=None)``        — the same step with one step kept
@@ -83,19 +84,57 @@ reference analog: "Ragged Paged Attention" arxiv 2604.15464):
     ``r_min`` at a time (the pack computes every row of the batch). Counted
     as ``nxdi_prefill_pad_waste``, ``nxdi_prefill_chunks_total`` and
     ``nxdi_bucket_selected_total{kind="prefill_rows"}``.
-  * ``prefill_budget_tokens`` defers prefill to the scheduler:
-    ``add_requests`` only admits and returns ``{}``; each decode call then
-    runs AT MOST ONE packed chunk dispatch of at most that many prompt
-    tokens before its decode work, and delivers a first token from the call
-    whose dispatch completes the prompt. Under ``step_ahead()`` with a
-    decode row live, that last chunk is not waited for either: its rows are
-    parked (``_Parked``; they stay in ``pending_prefill_ids``) and graduate
-    one call later, behind the fetch of a decode step that was enqueued
-    after the chunk, where their token is host-visible for nothing. Between
-    two decode dispatches the host then blocks on nothing but the fetch of
-    the step before. ``step()``, ``step_many()``, ``flush()`` and a call
-    with no decode row to hide behind fetch at once, as ``add_requests``
-    does without the budget.
+  * a prompt's chunks reach the device in one of THREE ways, one algorithm
+    (``_advance_prefill``: so many chunk dispatches, back to back with no
+    fetch between them, before a call's decode work) under three answers
+    of :func:`chunks_before_step`:
+
+      - a direct ``add_requests`` with default arguments BLOCKS: it runs
+        the call's whole chain, fetches the last chunk's tokens and returns
+        each prompt's first token (the tests', the scripts' and the fleet
+        handoff's contract; a decoding row waits behind all of it);
+      - under the serving engine with no budget the chunks are PACED: the
+        engine admits with ``defer=True`` (``{}`` comes back) and each
+        ``step_ahead()`` / ``step()`` runs ``k`` dispatches before its
+        decode step. ``k = max(1, ceil(3 f))``, ``f`` the prefill
+        dispatches a decode gap over the last ``_PACE_WINDOW`` (96) decode
+        gaps, read from the counts ``_note_gap`` keeps anyway (no clock: the
+        same request sequence paces alike on the CPU and on the chip), so
+        that about one decode gap in three at most waits behind prefill and
+        a light mix waits behind ONE chunk. Under ``step_ahead()`` a chain
+        that finds a step in flight LEADS with one chunk, fetches that step
+        and enqueues none; the next call, with nothing to fetch, issues the
+        other ``k - 1`` and then the step (it runs behind the chunks
+        whenever it is enqueued, and the first chunk covers the device
+        meanwhile): the tokens in flight are not kept waiting behind the
+        chain's host work, so a chain costs ONE long gap, not two. With no
+        row decoding, or until the window has filled once, the whole chain
+        runs at once and is fetched, as the blocking admission ran it, and
+        (``step_ahead()``, the speculative step) its rows decode from that
+        very call. A full-batch pack counts as one dispatch;
+      - with ``prefill_budget_tokens`` set, ``k`` is pinned to ONE dispatch
+        of at most that many prompt tokens, whatever the load (a direct
+        ``add_requests`` defers too and returns ``{}``).
+
+    A deferred prompt's first token comes from the call whose dispatch
+    completes it. Under ``step_ahead()`` with a decode row live the last
+    chunk of a paced or budgeted prompt is not waited for either: its rows are parked (``_Parked``; they
+    stay in ``pending_prefill_ids``) and graduate one call later, behind
+    the fetch of a decode step that was enqueued after the chunk, where
+    their token is host-visible for nothing. Between two decode dispatches
+    the host then blocks on nothing but the fetch of the step before.
+    ``step()``, ``step_many()``, ``flush()`` and a call with no decode row
+    to hide behind fetch at once. What the other step paths do with a
+    deferred default admission: ``step_many()`` (the engine's
+    ``decode_steps_per_pass > 1``) and the speculative step run the whole
+    pending chain before their horizon, where the blocking admission ran
+    it before them (they keep no decode gaps, so their window never fills;
+    under a budget, one capped dispatch a horizon as before);
+    ``ragged=True`` always deferred and packs chunk rows WITH decode rows.
+    ``host_stats`` counts ``prefill_paced_passes`` / ``prefill_paced_chunks``
+    (calls that ran ``k`` dispatches, and those dispatches),
+    ``prefill_chains_whole`` and the rule's last reading
+    ``prefill_pace_k`` / ``prefill_pace_f``.
   * half-prefilled sequences stay inside the resilience contracts: a chunk
     dispatch failure (``prefill_chunk`` fault point) rolls every sequence
     packed in that dispatch back via ``abort_sequence`` (never-fully-
@@ -130,6 +169,7 @@ Resilience contract (see README "Serving resilience"):
 from __future__ import annotations
 
 import bisect
+import collections
 import functools
 import time
 from dataclasses import dataclass, field
@@ -290,6 +330,35 @@ _DRAIN_CAUSES = ("admit", "release", "preempt", "liveset")
 # step's ids on the device (host_stats["pipeline_carries_<cause>"],
 # nxdi_pipeline_carries_total{cause}): rows that joined, rows that left
 _CARRY_CAUSES = ("admit", "release")
+
+# the decode gaps the pacing rule looks back over (:func:`chunks_before_step`)
+_PACE_WINDOW = 96
+
+
+def chunks_before_step(gaps: int, dispatches: int, decoding: bool,
+                       budgeted: bool,
+                       window: int = _PACE_WINDOW) -> Optional[int]:
+    """How many of the pending chunk dispatches a call runs before its
+    decode step; ``None`` is the whole chain. A pure function of two counts
+    the adapter keeps anyway (``_note_gap``): ``dispatches`` prefill
+    dispatches were issued in the last ``gaps`` decode gaps, of which it
+    remembers at most ``window``. No clock: the same request sequence paces
+    alike on the CPU and on the chip.
+
+      * ``budgeted`` (``prefill_budget_tokens`` set): one dispatch, capped
+        by the caller, whatever the load;
+      * nobody ``decoding``: the whole chain, since no row waits behind it;
+      * the window has not filled once: the whole chain, what a blocking
+        admission ran;
+      * else ``max(1, ceil(3 f))`` with ``f = dispatches / gaps``: room for
+        three times the observed prefill load, so that about one decode gap
+        in three at most waits behind prefill, the share under which the
+        median gap stays a bare step (PERF.md section 6, PR 63)."""
+    if budgeted:
+        return 1
+    if not decoding or gaps < window:
+        return None
+    return max(1, -(-3 * dispatches // gaps))
 
 
 def _async_fetch(x):
@@ -506,6 +575,19 @@ class _AdapterTelemetry:
         if reg.enabled:
             tmetrics.pipeline_drains_counter(reg).inc(engine=self.engine,
                                                       cause=cause)
+
+    def on_paced(self, chunks: int, whole: bool, k: int, f: float):
+        reg = self.registry
+        if reg.enabled:
+            c = tmetrics.prefill_pacing_counter(reg)
+            if whole:
+                c.inc(engine=self.engine, count="whole_chains")
+            else:
+                c.inc(engine=self.engine, count="paced_passes")
+                c.inc(chunks, engine=self.engine, count="paced_chunks")
+            g = tmetrics.prefill_pace_gauge(reg)
+            g.set(k, engine=self.engine, stat="k")
+            g.set(f, engine=self.engine, stat="f")
 
     def on_carry(self, cause: str):
         reg = self.registry
@@ -854,7 +936,10 @@ class PagedEngineAdapter:
     scheduler: ``add_requests`` returns ``{}`` and each ``step()`` runs at
     most one packed chunk dispatch of at most that many prompt tokens
     before its decode work (first tokens arrive from the completing
-    ``step()``). Both are documented in README "Chunked prefill".
+    ``step()``). Without it a direct ``add_requests`` blocks on the whole
+    chain and the serving engine's deferred admissions are paced by the
+    observed prefill load (the module docstring's three ways). Both are
+    documented in README "Chunked prefill".
 
     ``pipeline_depth`` is ``None`` (``step_ahead()`` keeps one decode step
     in flight) or ``0`` (``step_ahead()`` is eager too: the tests'
@@ -923,6 +1008,14 @@ class PagedEngineAdapter:
         # host-visible: (instant, the states live in it, prefill
         # dispatches and drains counted by then)
         self._gap_mark: Optional[tuple] = None
+        # the prefill dispatches of each of the last decode gaps, and their
+        # sum: what the pacing rule reads (chunks_before_step)
+        self._pace_gaps: collections.deque = collections.deque(
+            maxlen=_PACE_WINDOW)
+        self._pace_sum = 0
+        # chunk dispatches issued since the last decode step was enqueued:
+        # what a paced chain has used of its k
+        self._chain_issued = 0
         self._ready: Dict[int, int] = {}
         # final chunks dispatched under the budget and not waited for, in
         # dispatch order (:class:`_Parked`)
@@ -979,7 +1072,16 @@ class PagedEngineAdapter:
             "prefill_dispatches_paged_attn_kernel": 0,
             "prefill_blocking_fetches": 0,
             "prefill_blocked_s": 0.0, "prefill_real_tokens": 0,
-            "prefill_padded_tokens": 0}
+            "prefill_padded_tokens": 0,
+            # deferred admissions with no budget (_advance_prefill): the
+            # calls that ran k chunk dispatches before their decode step and
+            # those dispatches, the calls that ran a whole chain (no row
+            # decoding, or the window not full yet), and the rule's last
+            # reading: k (0 = the whole chain) and f, the prefill dispatches
+            # a decode gap over the window
+            "prefill_paced_passes": 0, "prefill_paced_chunks": 0,
+            "prefill_chains_whole": 0, "prefill_pace_k": 0,
+            "prefill_pace_f": 0.0}
         # pad rows sample what row 0 samples (greedy, or the positionally
         # coupled stream with row 0's seed): a step's full-batch output is
         # then the next step's ids as it stands
@@ -1103,8 +1205,8 @@ class PagedEngineAdapter:
                      deadline_s: Union[None, float,
                                        Sequence[Optional[float]]] = None,
                      meta: Optional[Sequence[Any]] = None,
-                     timelines: Optional[Sequence[RequestTimeline]] = None
-                     ) -> Dict[int, int]:
+                     timelines: Optional[Sequence[RequestTimeline]] = None,
+                     defer: bool = False) -> Dict[int, int]:
         """Transactional admission: either every sequence is admitted, or
         every ``begin_sequence`` allocation from this call is rolled back
         and cache state is exactly as before (pool pressure may still
@@ -1118,6 +1220,11 @@ class PagedEngineAdapter:
         ``seq_len`` is admissible. With ``prefill_budget_tokens`` set the
         device work is deferred entirely: this call returns ``{}`` and
         ``step()`` delivers each first token when its final chunk lands.
+        ``defer=True`` is the serving engine's call (``_add_batch``): admit
+        only, whatever the budget, and return ``{}``; the engine's step calls
+        then run the chunks between decode steps (:meth:`_advance_prefill`).
+        A caller that leaves it out keeps the blocking chain and its first
+        tokens.
 
         ``meta`` (optional, one opaque object per sequence) is a scheduler
         passthrough: the adapter never interprets it beyond reading a
@@ -1210,7 +1317,8 @@ class PagedEngineAdapter:
                 "paged admission failed; all allocations from this call "
                 "were rolled back", phase="prefill",
                 seq_ids=seq_ids, retry_safe=True)) from e
-        if self.prefill_budget_tokens is not None or self._ragged is not None:
+        if (defer or self.prefill_budget_tokens is not None
+                or self._ragged is not None):
             # deferred: step() drives the chunks (ragged mode always
             # defers — the unified dispatch packs chunk rows WITH decode
             # rows, so admission never serializes its own device work)
@@ -1335,20 +1443,26 @@ class PagedEngineAdapter:
         self.telemetry.on_moe_tally(touched, slots, assigned, read, picks,
                                     zero, picks // spec.moe.top_k, hit)
 
-    def _note_gap(self, states: Sequence[_SeqState]):
+    def _note_gap(self, states: Sequence[_SeqState],
+                  chunks_before: Optional[int] = None):
         """A decode step's tokens for ``states`` just became host-visible.
         If one of them was live at the previous such point too, the
         interval is a gap between tokens that a client saw: count it, by
-        what it waited behind — ``prefill`` if prefill dispatches were
-        issued in between (how many: the chain's length), else ``drain`` if
-        the in-flight step was drained, else ``none``. Always on: one clock
-        read and a few dict adds. A state that left never comes back (a
-        replayed row is a new one), so no gap spans an empty live set."""
+        what it waited behind — ``prefill`` if prefill dispatches stood
+        between the two steps ON THE DEVICE (how many: the chain's length),
+        else ``drain`` if the in-flight step was drained, else ``none``.
+        ``chunks_before`` is the count of prefill dispatches issued when
+        this step was enqueued (``_Inflight.chunks_before``; default: by
+        now, the eager step's): the chain is what was issued since the step
+        before was enqueued, whenever either was fetched. Always on: one
+        clock read and a few dict adds. A state that left never comes back
+        (a replayed row is a new one), so no gap spans an empty live set."""
         now = time.perf_counter()
         st = self.host_stats
         mark = self._gap_mark
-        self._gap_mark = (now, states, st["prefill_dispatches"],
-                          self._drains)
+        if chunks_before is None:
+            chunks_before = st["prefill_dispatches"]
+        self._gap_mark = (now, states, chunks_before, self._drains)
         if mark is None or not states:
             return
         t_prev, before, prefills, drains = mark
@@ -1358,7 +1472,11 @@ class PagedEngineAdapter:
             if not any(id(x) in was for x in states):
                 return
         gap = now - t_prev
-        chain = st["prefill_dispatches"] - prefills
+        chain = chunks_before - prefills
+        if len(self._pace_gaps) == self._pace_gaps.maxlen:
+            self._pace_sum -= self._pace_gaps[0]
+        self._pace_gaps.append(chain)
+        self._pace_sum += chain
         st["decode_gaps"] += 1
         st["decode_gap_s"] += gap
         if chain:
@@ -1499,10 +1617,14 @@ class PagedEngineAdapter:
         if live:
             _pre_step_checks(self.seqs, live, self._pos_limit,
                              self.telemetry, horizon=num_steps)
-        # at most ONE packed prefill-chunk dispatch per horizon — the
-        # scheduler knob that keeps a long admission from stalling decode
+        # under the budget at most ONE packed prefill-chunk dispatch per
+        # horizon — the scheduler knob that keeps a long admission from
+        # stalling decode; without it the whole pending chain, as the
+        # blocking admission ran it
         self._advance_prefill(seq_ids)
         if not live:
+            # (a row the chain just finished steps from the next call on,
+            # whose horizon the caller sizes to the row's room)
             return {s: [t] for s, t in self._drain_ready().items()}
         t0 = time.perf_counter()
         live = self._grow_for_step(live, num_steps)
@@ -1553,8 +1675,10 @@ class PagedEngineAdapter:
         if live:
             _pre_step_checks(self.seqs, live, self._pos_limit,
                              self.telemetry)
-        self._advance_prefill(seq_ids)
+        self._advance_prefill(seq_ids, decoding=bool(live))
         if not live:
+            # (a row the chain just finished steps from the next call on:
+            # this one returns ONE token a row, and owes it its first)
             return self._drain_ready()
         t0 = time.perf_counter()
         with _get_recorder().span("dispatch.build", cat="adapter"):
@@ -1568,6 +1692,7 @@ class PagedEngineAdapter:
             if _FAULTS.active:
                 _FAULTS.fire("decode_step")
             out = self._dispatch_decode(scr)
+            self._chain_issued = 0
             new = self._fetch_rows(out, len(live), scr.rows)
         except ServingError:
             self._rollback_step_growth(live)
@@ -1607,12 +1732,20 @@ class PagedEngineAdapter:
                              self.telemetry)
         # with a decode step to hide behind, a prompt's last chunk is not
         # waited for: its rows graduate behind a later step's fetch
-        self._advance_prefill(seq_ids, park=bool(live))
+        held = self._advance_prefill(seq_ids, park=bool(live),
+                                     decoding=bool(live))
+        live = self._rows_after_chain(live, seq_ids)
         if not live:
             return self.flush()
         ready = self._drain_ready()
         try:
-            self._advance_pipeline(live, ready)
+            if held:
+                # behind the chunk that leads a paced chain: the step in
+                # flight is fetched, the next one waits for the next call
+                prev, self._inflight = self._inflight, None
+                ready.update(self._retire_or_abort([prev]))
+            else:
+                self._advance_pipeline(live, ready)
             ready.update(self._drain_ready())   # first tokens of graduates
             return ready
         except BaseException:
@@ -1684,6 +1817,7 @@ class PagedEngineAdapter:
             chunks_before=self.host_stats["prefill_dispatches"])
         for s in live:
             self.seqs[s].position += 1
+        self._chain_issued = 0
         if carried:
             self._note_carry()
         self._drain_cause = None           # the step in flight is current
@@ -1796,7 +1930,7 @@ class PagedEngineAdapter:
             if _FAULTS.active:
                 _FAULTS.fire("pipeline_flush")
             new = self._fetch_rows(rec.out, rec.b, rec.rows)
-            self._note_gap(rec.states)
+            self._note_gap(rec.states, rec.chunks_before)
             res = {}
             for i, (s, st) in enumerate(zip(rec.live, rec.states)):
                 if self.seqs.get(s) is not st:
@@ -2605,21 +2739,95 @@ class PagedEngineAdapter:
         """seq_ids admitted but still mid-prefill (chunked admissions)."""
         return self._chunks.keys()
 
-    def _advance_prefill(self, seq_ids=None, park: bool = False):
-        """Run at most one packed prefill-chunk dispatch for pending
-        admissions; finished sequences' first tokens land in ``_ready``.
+    def _advance_prefill(self, seq_ids=None, park: bool = False,
+                         decoding: bool = False):
+        """Run the packed prefill-chunk dispatches that go before this
+        call's decode work, back to back with no fetch between them;
+        finished sequences' first tokens land in ``_ready``. How many is
+        :func:`chunks_before_step`'s answer: ONE of at most
+        ``prefill_budget_tokens`` tokens under the budget; without it the
+        whole pending chain where no row is ``decoding`` (or the window of
+        decode gaps has not filled), else ``k`` dispatches read from the
+        prefill load of the last ``_PACE_WINDOW`` decode gaps.
         ``seq_ids`` is the step call's explicit target set (None = all):
         an expired pending admission outside it is skipped, not raised —
         a healthy row must not be stalled by an unrelated request's
-        budget. ``park``: see :meth:`_prefill_step`."""
-        if self._chunks:
-            self._prefill_step(budget=self.prefill_budget_tokens,
-                               target=seq_ids, park=park)
+        budget. ``park``: see :meth:`_prefill_step` (a whole chain is never
+        parked).
+
+        Returns whether the call's decode step is HELD BACK (the lookahead's
+        call only, paced): a chain's first chunk went out in front of a step
+        still in flight, so the caller fetches that step and enqueues none.
+        The next step would run behind the chunk whenever it is enqueued,
+        the chunk keeps the device busy through the fetch, and the tokens in
+        flight are not kept waiting while the host issues the rest of the
+        chain: the next call issues up to ``k - 1`` more with nothing to
+        fetch, then the step. Enqueued in front of the fetch, as a budgeted
+        chunk is, ``k`` chunks' host time made the step in flight late as
+        well as the one behind them: two long gaps a chain for one (PERF.md
+        section 6, PR 63)."""
+        if not self._chunks:
+            return False
+        budget = self.prefill_budget_tokens
+        gaps = len(self._pace_gaps)
+        k = chunks_before_step(gaps, self._pace_sum, decoding,
+                               budget is not None, self._pace_gaps.maxlen)
+        st = self.host_stats
+        st["prefill_pace_k"] = k or 0
+        st["prefill_pace_f"] = self._pace_sum / gaps if gaps else 0.0
+        paced = budget is None and k is not None
+        # a paced chain that finds the lookahead's step in flight LEADS with
+        # one chunk and holds the next step back (see the return value)
+        lead = paced and park and self._inflight is not None
+        room = (None if k is None else k if not paced
+                else 1 if lead else k - self._chain_issued)
+        n = 0
+        try:
+            # a whole chain is fetched at once, as the blocking admission's
+            while (room is None or n < room) and self._prefill_step(
+                    budget=budget, target=seq_ids,
+                    park=park and k is not None):
+                n += 1
+        finally:
+            if paced:
+                self._chain_issued += n
+            if budget is None and n:
+                self._note_paced(n, whole=k is None)
+        return lead and n > 0
+
+    def _rows_after_chain(self, live: List[int], seq_ids) -> List[int]:
+        """The rows of a step call's decode work once its chunks have run
+        (``step_ahead()`` and the speculative step). Where no budget is set
+        and a whole chain just ran, it was fetched too (nobody decoding, the
+        window not full): the prompts it finished decode from this very call,
+        as they did in the pass of a blocking admission. Under the budget a row's
+        first step is the next call's, as it always was; so it is in
+        ``step()``, whose return holds one token a row, and in
+        ``step_many()``, whose horizon the caller sized without the row."""
+        if self.prefill_budget_tokens is not None or not self.seqs:
+            return live
+        rows = _live_rows(self.seqs, seq_ids, self._pending_ids())
+        if len(rows) > len(live):
+            # rows a whole chain just finished (only a whole chain is
+            # fetched inside the call that ran it)
+            _pre_step_checks(self.seqs, [s for s in rows if s not in live],
+                             self._pos_limit, self.telemetry)
+        return rows
+
+    def _note_paced(self, chunks: int, whole: bool):
+        st = self.host_stats
+        if whole:
+            st["prefill_chains_whole"] += 1
+        else:
+            st["prefill_paced_passes"] += 1
+            st["prefill_paced_chunks"] += chunks
+        self.telemetry.on_paced(chunks, whole, st["prefill_pace_k"],
+                                st["prefill_pace_f"])
 
     def _prefill_step(self, budget: Optional[int] = None, only=None,
                       target=None,
                       token_at: Optional[Dict[int, float]] = None,
-                      park: bool = False):
+                      park: bool = False) -> bool:
         """ONE packed chunk dispatch: pending sequences (admission order)
         each contribute their next uncached-suffix chunk as a ragged row
         of a single ctx-bucket ``_run_paged`` call, bounded by ``budget``
@@ -2642,7 +2850,10 @@ class PagedEngineAdapter:
         (:class:`_Parked`): they stay pending admissions until
         :meth:`_graduate` finds their token host-visible behind a later
         decode step's fetch. Every other caller materialises by contract,
-        and first graduates whatever an earlier call parked."""
+        and first graduates whatever an earlier call parked.
+
+        Returns whether a dispatch went out (``False``: no pending sequence
+        has a chunk left to run)."""
         if self._parked and not park:
             self._graduate()
         chunks = self._chunks
@@ -2681,7 +2892,7 @@ class PagedEngineAdapter:
             rows.append((s, st.done, n, st.done + n == len(st.prompt)))
             left -= n
         if not rows:
-            return
+            return False
         r_min = self.app.prefill_row_buckets[0]
         if r_min < len(rows) and 2 * len(rows) < self.batch:
             # a full-batch pack that would be less than half real rows goes
@@ -2773,6 +2984,7 @@ class PagedEngineAdapter:
                                         pad_rows * width, through)
         if final_rows and not park:
             self._graduate_rows(finals, new, t_token, token_at)
+        return True
 
     def _confirm_written(self, covered) -> None:
         """A chunk dispatch's tokens were MATERIALIZED, and the donated

@@ -24,11 +24,16 @@ One :meth:`ServingEngine.run_pass` is the whole closed loop:
      weighted-fair/priority/starvation-bound order, sorted warm-prefix
      first (:meth:`~..adapter.PagedEngineAdapter.prefix_warmth` peeks the
      block-hash state read-only), as ONE transactional ``add_requests``
-     call — chunked prefill under the adapter's budget knob keeps a long
-     admission from stalling running decodes;
+     call that admits and runs NO prefill (``defer=True``): blocks, state
+     slots and chunk state are taken, the prompts' chunks run from the
+     dispatch stage, paced between decode steps by the adapter
+     (``PagedEngineAdapter._advance_prefill``), so no decoding row waits
+     behind an admission's whole chain;
   4. **dispatch** one decode horizon (``step``/``step_many``) for every
      eligible running row — skipping consumers over their backpressure
-     bound — and route tokens to per-request streams.
+     bound — and every admission still mid-prefill, and route tokens to
+     per-request streams. A chunk dispatch that fails retry-safe rolls its
+     prompts back in the adapter; they go back to the front of the queue.
 
 The engine is synchronous at its core (drive it with :meth:`run_pass` /
 :meth:`run_until_drained` from tests and benches); :meth:`run_forever` is
@@ -531,7 +536,7 @@ class ServingEngine:
         # prompts ahead so intra-call shared prefixes hit originator-first
         batch.sort(key=lambda r: -self.adapter.prefix_warmth(r.tokens))
         try:
-            first = self._add_batch(batch, now)
+            self._add_batch(batch, now)
         except DeadlineExceeded:
             # a zero-remaining budget expired inside admission: retry the
             # expiry stage next pass (adapter rolled the call back)
@@ -542,10 +547,9 @@ class ServingEngine:
         except AdmissionError:
             # one bad request must not sink its innocent batch neighbours
             # (or the serving loop): isolate it by admitting one-by-one
-            first = {}
             for r in batch:
                 try:
-                    first.update(self._add_batch([r], now))
+                    self._add_batch([r], now)
                 except AdmissionError as e:
                     r.stream.finish("error", e)
                     self._finalize(r)
@@ -566,30 +570,26 @@ class ServingEngine:
             self.stats["admission_retries"] += 1
             if isinstance(e, CapacityError):
                 self._note_headroom("admit")
-            return
-        for sid, tok in first.items():   # non-deferred adapters
-            self._deliver(sid, [tok])
 
-    def _add_batch(self, batch: List[QueuedRequest],
-                   now: float) -> Dict[int, int]:
-        """One transactional add_requests call; registers the admitted
-        requests and returns the adapter's first-token dict (empty under
-        a deferred prefill budget)."""
+    def _add_batch(self, batch: List[QueuedRequest], now: float) -> None:
+        """One transactional add_requests call that admits and runs no
+        chunk (``defer=True``: first tokens come from the dispatch stage's
+        step calls); registers the admitted requests."""
         sids = [next(self._seq_ids) for _ in batch]
         rec = _get_recorder()
-        # the queue phase ends HERE, before the admission call: under the
-        # default adapter that call runs the batch's whole prefill chain
+        # the queue phase ends HERE, where the request holds a row; its
+        # first chunk goes out from a later step call (prefill_wait)
         t_admit = time.perf_counter()
         for r in batch:
             if r.timeline.stamp("admit", t_admit) and rec.enabled:
                 rec.mark("request.admit", trace_of(r.meta))
         try:
-            first = self.adapter.add_requests(
+            self.adapter.add_requests(
                 sids, [r.tokens for r in batch],
                 deadline_s=[None if r.deadline is None
                             else max(r.deadline - now, 0.0) for r in batch],
                 meta=[r.meta for r in batch],
-                timelines=[r.timeline for r in batch])
+                timelines=[r.timeline for r in batch], defer=True)
         except BaseException:
             # rolled back: the batch goes back to the queue, and what this
             # call stamped is stamped anew by the admission that holds
@@ -611,7 +611,6 @@ class ServingEngine:
                             trace=trace_of(req.meta),
                             request_id=req.request_id, seq_id=int(sid),
                             wait_s=t_admit - since)
-        return first
 
     def _dispatch_engine_pass(self) -> int:
         """Drive one decode horizon and route tokens to streams. This is
@@ -689,10 +688,26 @@ class ServingEngine:
         except StepFailure as e:
             if e.retry_safe:
                 self.stats["step_retries"] += 1
+                self._requeue_rolled_back(e.seq_ids)
                 return 0
             self._fatal(e)
             raise
         return self._route(res)
+
+    def _requeue_rolled_back(self, seq_ids: Sequence[int]) -> None:
+        """A deferred prompt's chunk dispatch failed retry-safe and the
+        adapter rolled every prompt packed in it back: each goes back to
+        the front of its lane, as a failed admission's batch does, and what
+        its admission stamped is stamped anew by the one that holds."""
+        alive, pending = self.adapter.seqs, self.adapter.pending_prefill_ids
+        lost = [s for s in seq_ids if s in self._active
+                and s not in alive and s not in pending]
+        for sid in reversed(lost):
+            req = self._retire(sid)
+            req.timeline.rollback_admission()
+            self.queue.push(req, front=True)
+        if lost:
+            self.stats["admission_retries"] += 1
 
     # -- token routing -----------------------------------------------------
     def _route_flushed(self) -> int:

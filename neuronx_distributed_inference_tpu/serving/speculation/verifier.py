@@ -155,6 +155,7 @@ class SpeculativeDecodePath:
             _pre_step_checks(ad.seqs, live, ad._pos_limit, ad.telemetry,
                              horizon=1)
         ad._advance_prefill(seq_ids)
+        live = ad._rows_after_chain(live, seq_ids)
         if not live:
             return drain()
         t0 = time.perf_counter()

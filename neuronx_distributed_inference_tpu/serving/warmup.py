@@ -49,6 +49,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from ..modules import autobucketing
@@ -221,8 +222,11 @@ def _paged_plan(app, widths, bt_widths, chunk_tokens, spec_widths):
                                  np.ones((b,), np.int32), **lora_kw)))
 
     def carry_thunk(rows_before, rows):
-        # every row takes row 0's token of an all-zero step: ids only
-        carry_step_ids(app, np.zeros((rows_before,), np.int32),
+        # every row takes row 0's token of an all-zero step: ids only. The
+        # tokens are placed as a decode step hands its own on (committed,
+        # replicated): jit keeps another executable for a host array
+        carry_step_ids(app, jax.device_put(np.zeros((rows_before,), np.int32),
+                                           app._decode_ids_sharding),
                        np.zeros((rows,), np.int32),
                        np.full((rows, 1), -1, np.int32))
 

@@ -27,6 +27,8 @@ PREFILL_PAD_WASTE = "nxdi_prefill_pad_waste"            # engine
 PREFILL_DISPATCHES_TOTAL = "nxdi_prefill_dispatches_total"   # engine, experts, attn
 PREFILL_TOKENS_CROSS_DECODER_TOTAL = \
     "nxdi_prefill_tokens_cross_decoder_total"                # engine
+PREFILL_PACING_TOTAL = "nxdi_prefill_pacing_total"      # engine, count
+PREFILL_PACE = "nxdi_prefill_pace"                      # engine, stat
 
 # -- serving engine (serving/engine/) ----------------------------------------
 QUEUE_DEPTH = "nxdi_queue_depth"                        # tenant
@@ -197,10 +199,9 @@ def queue_wait_histogram(reg):
         QUEUE_WAIT_SECONDS,
         "Time from a request's submit to the moment it left the queue "
         "(outcome=admitted|expired|cancelled); for admitted that moment "
-        "is the RETURN of the admission call, which under the default "
-        "adapter runs the batch's whole prefill chain: submit -> prefill "
-        "done, not a wait (the wait alone: phase=queue of "
-        "nxdi_ttft_phase_seconds_total)",
+        "is the RETURN of the admission call, which takes blocks and "
+        "slots and runs no prefill (the request's own stamps: phase=queue "
+        "of nxdi_ttft_phase_seconds_total)",
         labels=("tenant", "outcome"), buckets=DEFAULT_LATENCY_BUCKETS)
 
 
@@ -281,6 +282,26 @@ def prefill_tokens_cross_decoder_counter(reg):
         "every real token where the program hands out every position's "
         "logits and walks the whole stack",
         labels=("engine",))
+
+
+def prefill_pacing_counter(reg):
+    return reg.counter(
+        PREFILL_PACING_TOTAL,
+        "Deferred admissions with no prefill budget, by what the step calls "
+        "ran of their chunks: count=paced_passes (calls that ran k chunk "
+        "dispatches before their decode step) | paced_chunks (those "
+        "dispatches) | whole_chains (calls that ran every pending chunk: no "
+        "row was decoding, or the window of decode gaps had not filled)",
+        labels=("engine", "count"))
+
+
+def prefill_pace_gauge(reg):
+    return reg.gauge(
+        PREFILL_PACE,
+        "The pacing rule's last reading: stat=k (chunk dispatches a call "
+        "may run before its decode step; 0 = the whole chain) | f (prefill "
+        "dispatches a decode gap over the last 96 gaps; k = max(1, ceil(3 f)))",
+        labels=("engine", "stat"))
 
 
 def prefill_dispatches_counter(reg):

@@ -42,9 +42,11 @@ file holds its whole session. And where a slice closes
 registry is live: host seconds per span over any window, read from two
 registry snapshots. ``under`` is the span that was open around it on the
 same thread ("" at the top), so a reader gets a span's SELF time as its
-own seconds minus those recorded under it — e.g. the default paged adapter
-runs its prefill dispatches inside ``pass.admit``, a deferring one inside
-``pass.dispatch``; the label tells them apart, names alone cannot. The
+own seconds minus those recorded under it — e.g. ``run.paged`` closes
+under ``dispatch.prefill_chunk`` for a prompt's chunk and under
+``pass.dispatch`` for a decode step (under the serving engine both are
+dispatched in that stage; a direct blocking ``add_requests`` runs its chunks
+wherever its caller stands); the label tells them apart, names alone cannot. The
 ``request.*`` slices (:data:`TRACE_EVENTS`) are the exception: a request's
 seconds, not the thread's, so they reach the ring and nothing else
 (:data:`REQUEST_CAT`); two boundaries of a request's timeline are

@@ -79,6 +79,38 @@ def small_pool_app():
     return _make_app(is_prefix_caching=False, pa_num_blocks=10)
 
 
+#: how a prompt's chunks are deferred to the step calls: under
+#: ``prefill_budget_tokens`` (one capped dispatch a call), or as the serving
+#: engine defers them over a default adapter (``defer=True``: paced)
+MODES = ["budget", "paced"]
+
+
+def _deferring(app, mode, load=0, **adapter_kw):
+    """An adapter of 8-token chunks whose prompts are deferred to its step
+    calls, and what its admissions pass: ``budget`` by the budget (ONE chunk
+    before each decode step); ``paced`` with no budget, as the serving engine
+    admits. Its window of decode gaps is filled with gaps that saw ``load``
+    prefill dispatches each: f = ``load``, so a paced adapter runs
+    ``max(1, 3 * load)`` chunks before a step while a row decodes (with none
+    decoding it runs the whole chain, which the scripts below avoid by
+    keeping one live)."""
+    kw = {"prefill_budget_tokens": 8} if mode == "budget" else {}
+    eng = PagedEngineAdapter(app, prefill_chunk_tokens=8, **kw, **adapter_kw)
+    eng._pace_gaps.extend([load] * eng._pace_gaps.maxlen)
+    eng._pace_sum = load * eng._pace_gaps.maxlen
+    return eng, ({} if mode == "budget" else {"defer": True})
+
+
+def _start_row(eng, sid, prompt, **kw):
+    """Admit ``prompt`` deferred and step until its first token is out (two
+    calls under the budget, one where the whole chain runs at once)."""
+    assert eng.add_requests([sid], [prompt], **kw) == {}
+    out = {}
+    while sid not in out:
+        out = eng.step()
+    return out[sid]
+
+
 def _stream(app, prompt, n_decode, sid=0, **adapter_kw):
     """prompt's first token + n_decode decode tokens from a fresh
     adapter."""
@@ -260,18 +292,17 @@ def test_chunk_fault_rolls_back_admission_transactionally(paged_app):
     eng.release([0, 1])
 
 
-def test_chunk_fault_deferred_aborts_only_packed_rows(paged_app):
+@pytest.mark.parametrize("mode", MODES)
+def test_chunk_fault_deferred_aborts_only_packed_rows(paged_app, mode):
     """In deferred mode a chunk-dispatch failure rolls back the sequences
     packed in THAT dispatch; running decode rows are untouched and keep
     stepping."""
     ref_run = _stream(paged_app, P_MED, 4)
-    eng = PagedEngineAdapter(paged_app, prefill_chunk_tokens=8,
-                             prefill_budget_tokens=8)
-    assert eng.add_requests([0], [P_MED]) == {}
-    assert eng.step() == {}                           # chunk 1 of 2 (8 tok)
-    run = [eng.step()[0]]                             # final chunk: token
-    eng.add_requests([1], [P_LONG])
+    eng, kw = _deferring(paged_app, mode)
+    run = [_start_row(eng, 0, P_MED, **kw)]           # two chunks: token
+    assert eng.add_requests([1], [P_LONG], **kw) == {}
     run.append(eng.step()[0])                         # chunk 1 + decode
+    assert eng._chunks[1].done == 8
     with FAULTS.inject("prefill_chunk") as fp:
         with pytest.raises(StepFailure) as ei:
             eng.step()                                # chunk 2 faults
@@ -284,18 +315,19 @@ def test_chunk_fault_deferred_aborts_only_packed_rows(paged_app):
     assert run == ref_run[:len(run)]
 
 
-def test_deadline_expires_mid_prefill(paged_app):
+@pytest.mark.parametrize("mode", MODES)
+def test_deadline_expires_mid_prefill(paged_app, mode):
     """A pending admission's deadline is enforced BEFORE chunk device
     work — but only for steps that target it: an explicit seq_ids step on
     a healthy row must not be stalled by an unrelated expired admission.
     Releasing the expired sequence aborts its half-written blocks."""
     free0 = paged_app.kv_mgr.allocator.num_free
-    eng = PagedEngineAdapter(paged_app, prefill_chunk_tokens=8,
-                             prefill_budget_tokens=8)
-    assert eng.add_requests([6], [P_SHORT]) == {}  # healthy running row
+    eng, kw = _deferring(paged_app, mode)
+    assert eng.add_requests([6], [P_SHORT], **kw) == {}  # healthy running row
     assert list(eng.step()) == [6]                 # 5 tokens: one chunk
-    assert eng.add_requests([5], [P_LONG], deadline_s=0.05) == {}
+    assert eng.add_requests([5], [P_LONG], deadline_s=0.05, **kw) == {}
     eng.step()                                    # first chunk runs
+    assert eng._chunks[5].done == 8               # ... and no other
     time.sleep(0.07)
     assert list(eng.step([6])) == [6]             # healthy row: no stall
     with pytest.raises(DeadlineExceeded) as ei:
@@ -307,22 +339,23 @@ def test_deadline_expires_mid_prefill(paged_app):
     assert paged_app.kv_mgr.allocator.num_free == free0
 
 
-def test_preempt_half_prefilled_sequence(small_pool_app):
+@pytest.mark.parametrize("mode", MODES)
+def test_preempt_half_prefilled_sequence(small_pool_app, mode):
     """KV pressure from a new admission may evict a PENDING sequence: the
     record carries the bare prompt (n_generated 0), its blocks come back,
     and the re-queued prompt replays bit-identically."""
     app = small_pool_app
-    p_big = RNG.integers(1, 500, size=30).tolist()     # 4 blocks
+    p_big = np.random.default_rng(5).integers(1, 500, size=30).tolist()
     ref_victim = _stream(app, p_big, 2, prefill_chunk_tokens=8)
-    eng = PagedEngineAdapter(app, prefill_chunk_tokens=8,
-                             prefill_budget_tokens=8,
-                             preemption_policy="lifo")
-    assert eng.add_requests([0], [p_big]) == {}
+    eng, kw = _deferring(app, mode, preemption_policy="lifo")
+    _start_row(eng, 7, P_SHORT, **kw)                  # a row decodes
+    assert eng.add_requests([0], [p_big], **kw) == {}  # 4 blocks
     eng.step()                                         # half-prefilled
-    assert 0 in eng._chunks and eng._chunks[0].done > 0
+    assert 0 in eng._chunks and eng._chunks[0].done == 8
+    eng.release([7])
     # 60 tokens want 8 blocks, only 6 free -> evicts pending seq 0
     assert eng.add_requests(
-        [1], [RNG.integers(1, 500, size=60).tolist()]) == {}
+        [1], [RNG.integers(1, 500, size=60).tolist()], **kw) == {}
     recs = eng.take_preempted()
     assert [r.seq_id for r in recs] == [0]
     assert recs[0].n_generated == 0 and recs[0].reason == "admission"
@@ -330,7 +363,7 @@ def test_preempt_half_prefilled_sequence(small_pool_app):
     assert 0 not in eng._chunks and 0 not in app.kv_mgr.tables
     eng.release([1])
     # re-queue the preempted prompt: replay is bit-identical
-    assert eng.add_requests([0], [list(recs[0].tokens)]) == {}
+    assert eng.add_requests([0], [list(recs[0].tokens)], **kw) == {}
     got = []
     while not got:
         got.extend(eng.step().values())
@@ -453,3 +486,118 @@ def test_chunk_dispatch_region_linted():
     r = subprocess.run([sys.executable, str(script)],
                        capture_output=True, text=True)
     assert r.returncode == 0, r.stdout + r.stderr
+
+
+# ---------------------------------------------------------------------------
+# how many chunks go before a decode step (ISSUE 63)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("gaps, dispatches, decoding, budgeted, want", [
+    (96, 2, True, False, 1),        # a light mix: f = 0.02
+    (96, 0, True, False, 1),        # no prefill in the window at all
+    (96, 32, True, False, 1),       # f = 1/3 exactly: one gap in three
+    (96, 35, True, False, 2),       # f = 0.36
+    (96, 130, True, False, 5),      # f = 1.35
+    (95, 130, True, False, None),   # the window has not filled: whole chain
+    (0, 0, True, False, None),
+    (96, 130, False, False, None),  # nobody decoding: whole chain
+    (96, 130, True, True, 1),       # a budget: one dispatch whatever f
+    (96, 130, False, True, 1),
+    (0, 0, False, True, 1),
+], ids=lambda v: str(v))
+def test_chunks_before_a_step_by_the_two_counts(gaps, dispatches, decoding,
+                                                budgeted, want):
+    from neuronx_distributed_inference_tpu.serving.adapter import \
+        chunks_before_step
+    assert chunks_before_step(gaps, dispatches, decoding, budgeted) == want
+
+
+@pytest.mark.parametrize("load", [0, 5], ids=["light", "heavy"])
+def test_a_budget_is_one_capped_dispatch_whatever_the_load(paged_app, load):
+    """With ``prefill_budget_tokens`` the observed prefill load decides
+    nothing: every step runs ONE dispatch of at most that many tokens."""
+    eng, kw = _deferring(paged_app, "budget", load)
+    _start_row(eng, 0, P_SHORT, **kw)
+    assert eng.add_requests([1], [P_LONG], **kw) == {}    # five chunks of 8
+    for n in range(1, 5):
+        before = eng.host_stats["prefill_dispatches"]
+        assert list(eng.step()) == [0]
+        assert eng.host_stats["prefill_dispatches"] == before + 1
+        assert eng._chunks[1].done == 8 * n
+    assert set(eng.step()) == {0, 1}
+    h = eng.host_stats
+    assert h["prefill_paced_passes"] == h["prefill_chains_whole"] == 0
+    assert h["prefill_pace_k"] == 1
+    eng.release([0, 1])
+
+
+@pytest.mark.parametrize("load, k", [(0, 1), (1, 3)], ids=["light", "heavy"])
+def test_a_deferred_default_admission_runs_k_chunks_a_step(paged_app, load,
+                                                           k):
+    """No budget, a row decoding, the window full: ``k`` from the window's
+    load, each dispatch one whole 8-token chunk; nobody decoding: the whole
+    chain at once."""
+    eng, kw = _deferring(paged_app, "paced", load)
+    ref = _stream(paged_app, P_LONG, 2, prefill_chunk_tokens=8)
+    first = _start_row(eng, 0, P_SHORT, **kw)
+    assert eng.host_stats["prefill_chains_whole"] == 1
+    assert eng.add_requests([1], [P_LONG], **kw) == {}    # five chunks of 8
+    before = eng.host_stats["prefill_dispatches"]
+    assert list(eng.step()) == [0]
+    assert eng.host_stats["prefill_dispatches"] == before + k
+    assert eng._chunks[1].done == 8 * k
+    assert eng.host_stats["prefill_pace_k"] == k
+    got = {}
+    while 1 not in got:
+        got = eng.step()
+    assert [got[1], eng.step()[1], eng.step()[1]] == ref
+    assert eng.host_stats["prefill_paced_chunks"] == 5
+    assert eng.host_stats["prefill_chains_whole"] == 1
+    eng.release([0, 1])
+    assert first == _stream(paged_app, P_SHORT, 0)[0]
+    assert paged_app.kv_mgr.tables == {} and eng._unwritten == set()
+
+
+def test_a_paced_chain_leads_with_one_chunk_and_holds_the_step(paged_app):
+    """The lookahead's order of a paced chain of k = 3: ONE chunk in front of
+    the step in flight, that step fetched and no step enqueued; the next
+    call issues the other two with nothing to fetch, then the step. The
+    tokens in flight are not kept waiting behind the chain's host work, and
+    the gap counters give the whole chain to the ONE gap it stands in on the
+    device."""
+    eng, kw = _deferring(paged_app, "paced", load=1)
+    ref0 = _stream(paged_app, P_SHORT, 6)
+    ref1 = _stream(paged_app, P_LONG, 1, prefill_chunk_tokens=8)
+    assert eng.add_requests([0], [P_SHORT], **kw) == {}
+    got0 = [eng.step_ahead()[0]]        # nobody decoding: chain, token, step
+    assert eng._inflight is not None
+    got0.append(eng.step_ahead()[0])
+    h = eng.host_stats
+    gaps, behind = h["decode_gaps"], h["decode_gaps_behind_prefill"]
+    assert eng.add_requests([1], [P_LONG], **kw) == {}    # five chunks of 8
+    steps = h["dispatches"]
+    got0.append(eng.step_ahead()[0])    # leads: chunk 1, the fetch, no step
+    assert (eng._chunks[1].done, h["dispatches"]) == (8, steps)
+    assert eng._inflight is None
+    assert eng.step_ahead() == {}       # chunks 2 and 3, then the step
+    assert (eng._chunks[1].done, h["dispatches"]) == (24, steps + 1)
+    got0.append(eng.step_ahead()[0])    # leads again: chunk 4
+    assert (eng._chunks[1].done, h["dispatches"]) == (32, steps + 1)
+    assert eng.step_ahead() == {}       # the last chunk, parked; the step
+    assert eng._chunks[1].done == 40 and len(eng._parked) == 1
+    out = eng.step_ahead()              # behind that step's fetch: graduates
+    got0.append(out[0])
+    assert set(out) == {0, 1} and not eng._parked
+    got1 = [out[1]]
+    out = eng.step_ahead()              # (row 1 joined the step in flight)
+    got0.append(out[0])
+    got1.append(eng.step_ahead()[1])
+    assert got0 == ref0[:6] and got1 == ref1
+    # two gaps stood behind prefill on the device: three chunks, then two
+    assert h["decode_gaps_behind_prefill"] == behind + 2
+    assert h["prefill_dispatches_in_gaps"] == 5
+    assert h["prefill_paced_chunks"] == 5 and h["prefill_paced_passes"] == 4
+    assert h["prefill_blocking_fetches"] == 1          # the ramp's alone
+    eng.flush()
+    eng.release([0, 1])
+    assert paged_app.kv_mgr.tables == {} and eng._unwritten == set()

@@ -24,10 +24,18 @@ the CPU in float32, greedy:
   (d) a deferred fetch failure (``pipeline_flush``) fails every stream with
       the ``StepFailure``, loses and repeats no delivered token, and runs
       the fatal teardown once; a row at the compiled ``seq_len`` gets its
-      last token before it ends.
+      last token before it ends;
+  (e) ISSUE 63: under the engine a default adapter's admission runs no
+      chunk; prompts of 1, 3 and 9 chunks admitted while rows decode are
+      paced between the decode steps (``k`` dispatches a pass, read from
+      the observed prefill load; a last chunk parked) and every stream is
+      the row served alone, on the attention, the recurrent and the
+      expert stacks; a chunk fault sends its prompts back to the queue; a
+      budgeted adapter issues the dispatches it always issued.
 """
 
 import asyncio
+import collections
 import os
 import sys
 
@@ -99,8 +107,20 @@ def _delta_app():
     return delta_toy._app(ref, w)
 
 
+def _olmoe_app():
+    """OLMoE's keys at a toy size: attention and routed experts."""
+    from neuronx_distributed_inference_tpu.models.family import get_family
+    fam = get_family("olmoe")
+    hf = dict(LLAMA, model_type="olmoe", intermediate_size=32,
+              num_hidden_layers=3, num_key_value_heads=4, num_experts=8,
+              num_experts_per_tok=2, norm_topk_prob=False)
+    app = PagedCausalLMApplication(
+        None, fam.config_cls(TpuConfig(dtype="float32", **SERVE), **hf), fam)
+    return app.init_random_weights(63).init_cache()
+
+
 STACKS = {"attention": _attention_app, "granite": _granite_app,
-          "delta_rule": _delta_app}
+          "delta_rule": _delta_app, "moe": _olmoe_app}
 
 
 @pytest.fixture(scope="module", params=list(STACKS))
@@ -414,3 +434,164 @@ def test_a_pass_that_only_dispatched_is_not_idle(attention_app):
         assert "loop.yield" in busy and "loop.idle" not in busy
     finally:
         disable_recorder()
+
+
+# ---------------------------------------------------------------------------
+# (e) a default adapter's prompts are paced between the decode steps
+# ---------------------------------------------------------------------------
+
+#: ``prefill_chunk_tokens`` of the paced runs, and prompts of 1, 3 and 9
+#: chunks of it
+CHUNK = 8
+PACED = {n: RNG.integers(1, 128, size=size).tolist()
+         for n, size in ((1, 7), (3, 21), (9, 69))}
+PACED_BUDGETS = {1: 6, 3: 12, 9: 8}
+#: the decode gaps the paced runs' rule looks back over (96 as served)
+WINDOW = 6
+
+
+def _alone(app, prompt, n):
+    """``prompt``'s first ``n`` tokens from an adapter that serves nothing
+    else: the blocking chain of a direct ``add_requests``, eager steps."""
+    ad = PagedEngineAdapter(app, prefill_chunk_tokens=CHUNK)
+    out = [ad.add_requests([0], [prompt])[0]]
+    out += [ad.step()[0] for _ in range(n - 1)]
+    ad.release([0])
+    return out
+
+
+def _paced_engine(app, depth=None):
+    ad = PagedEngineAdapter(app, pipeline_depth=depth,
+                            prefill_chunk_tokens=CHUNK)
+    ad._pace_gaps = collections.deque(maxlen=WINDOW)
+    eng = ServingEngine(ad, starvation_bound_s=1e9)
+    # two rows decode: a long one, and one whose end frees a row mid-run
+    rows = [eng.submit(PROMPTS[0], 60), eng.submit(PROMPTS[2], 10)]
+    while ad.host_stats["decode_gaps"] < WINDOW:
+        eng.run_pass()
+    return ad, eng, rows
+
+
+@pytest.mark.parametrize("depth", [None, 0], ids=["ahead", "eager"])
+def test_paced_prompts_are_the_rows_served_alone(stack, depth):
+    ad, eng, rows = _paced_engine(stack, depth)
+    h = ad.host_stats
+    # the ramp: nobody decoding, both prompts' chain at once, fetched
+    assert (h["prefill_chains_whole"], h["prefill_paced_passes"]) == (1, 0)
+    ramp_fetches = h["prefill_blocking_fetches"]
+    ramp_dispatches = h["prefill_dispatches"]
+    # nine chunks and three take the two free rows; one chunk waits for the
+    # short row's end: all three are admitted while rows decode
+    streams = {n: eng.submit(PACED[n], PACED_BUDGETS[n]) for n in (9, 3, 1)}
+    worst = 0
+    while eng.has_work:
+        before = h["prefill_dispatches"]
+        eng.run_pass()
+        ran = h["prefill_dispatches"] - before
+        if ran:
+            assert 1 <= ran <= h["prefill_pace_k"]
+            worst = max(worst, ran)
+    for n, s in streams.items():
+        assert s.finish_reason == "length"
+        assert list(s.tokens) == _alone(stack, PACED[n], PACED_BUDGETS[n])
+    assert list(rows[0].tokens) == _alone(stack, PROMPTS[0], 60)
+    assert list(rows[1].tokens) == _alone(stack, PROMPTS[2], 10)
+    # every chunk of the three went out between decode steps, no chain whole
+    assert h["prefill_chains_whole"] == 1
+    # (a dispatch packs the chunks of the prompts pending beside each other:
+    # nine dispatches at least, thirteen at most)
+    assert 9 <= h["prefill_paced_chunks"] <= 9 + 3 + 1
+    assert h["prefill_paced_chunks"] == (h["prefill_dispatches"]
+                                         - ramp_dispatches)
+    assert 0 < h["prefill_paced_passes"] <= h["prefill_paced_chunks"]
+    assert worst < 9 and h["prefill_pace_f"] > 0.0
+    assert h["prefill_dispatches_in_gaps"] == h["prefill_paced_chunks"]
+    if depth is None:
+        # a last chunk behind a decode step is parked, not waited for
+        assert h["prefill_blocking_fetches"] == ramp_fetches
+        assert h["pipeline_carries_admit"] >= 1
+        assert h["pipeline_drains_admit"] == 0
+    assert not stack.kv_mgr.tables and not ad._parked and not ad._unwritten
+
+
+def test_a_paced_chunk_fault_sends_its_prompt_back_to_the_queue(stack):
+    """A fault between two paced chunks rolls the prompt back in the adapter
+    (retry-safe); the engine queues it again, as it does a failed
+    admission's batch, and the stream is the row served alone."""
+    ad, eng, rows = _paced_engine(stack)
+    s = eng.submit(PACED[3], 5)
+    steps = ad.host_stats["dispatches"]
+    eng.run_pass()                              # admitted, chunk 1 of 3
+    sid = eng.seq_id_of(s.request_id)
+    assert ad._chunks[sid].done == CHUNK and s.timeline.dispatch is not None
+    # the chunk led in front of the step in flight: that step was fetched,
+    # the next one held back for the pass after (k is 1: no second chunk)
+    assert ad.host_stats["dispatches"] == steps and ad._inflight is None
+    eng.run_pass()
+    assert ad.host_stats["dispatches"] == steps + 1
+    assert ad._chunks[sid].done == CHUNK and ad._inflight is not None
+    with FAULTS.inject("prefill_chunk") as fp:
+        eng.run_pass()                          # chunk 2 faults
+    assert fp.trips == 1 and sid not in ad._chunks
+    assert eng.stats["step_retries"] == eng.stats["admission_retries"] == 1
+    assert eng.seq_id_of(s.request_id) is None and eng.queue.depth == 1
+    assert s.timeline.admit is s.timeline.dispatch is None
+    eng.run_until_drained()
+    assert s.finish_reason == "length"
+    assert list(s.tokens) == _alone(stack, PACED[3], 5)
+    assert list(rows[0].tokens) == _alone(stack, PROMPTS[0], 60)
+    assert not stack.kv_mgr.tables and not ad._unwritten
+
+
+#: what a budgeted adapter dispatched for ``_budgeted_script`` BEFORE ISSUE 63
+#: (recorded on that tree): ("chunk", rows, width) / ("decode", rows) as
+#: enqueued, ("fetch", waited) where a last chunk's tokens were taken: waited
+#: for at once (True), or parked and read behind a later step's fetch (False)
+BUDGETED_DISPATCHES = [
+    ("chunk", 1, 256), ("chunk", 1, 256), ("chunk", 4, 256), ("fetch", True),
+    ("chunk", 4, 256), ("decode", 4), ("decode", 4), ("fetch", False),
+    ("decode", 4), ("chunk", 1, 256), ("decode", 4), ("chunk", 1, 64),
+    ("decode", 4), ("decode", 4), ("fetch", False), ("decode", 4),
+    ("decode", 4), ("chunk", 1, 64), ("decode", 4), ("decode", 4),
+    ("fetch", False), ("decode", 4), ("decode", 4), ("decode", 4),
+    ("decode", 4)]
+
+
+def _budgeted_script():
+    """Six prompts of 10 to 600 tokens through a four-row engine whose
+    adapter holds ``prefill_budget_tokens=256`` (buckets 64 and 256): four at
+    once, two as rows free up; every dispatch and prefill fetch in order."""
+    tcfg = TpuConfig(dtype="float32", **dict(
+        SERVE, seq_len=640, pa_num_blocks=330,
+        context_encoding_buckets=[64, 256]))
+    app = PagedCausalLMApplication(
+        None, LlamaInferenceConfig(tcfg, **LLAMA), LlamaFamily)
+    app.init_random_weights(63).init_cache()
+    ad = PagedEngineAdapter(app, prefill_budget_tokens=256)
+    events = []
+    run_paged, fetch = app._run_paged, ad._fetch_prefill_tokens
+
+    def _run(ids, *a, **k):
+        rows, width = ids.shape
+        events.append(("decode", rows) if width == 1
+                      else ("chunk", rows, width))
+        return run_paged(ids, *a, **k)
+
+    def _fetch(out, waited=True):
+        events.append(("fetch", waited))
+        return fetch(out, waited)
+
+    app._run_paged, ad._fetch_prefill_tokens = _run, _fetch
+    eng = ServingEngine(ad, starvation_bound_s=1e9)
+    rng = np.random.default_rng(63)
+    sizes = (600, 40, 300, 10, 257, 64)
+    budgets = (8, 3, 6, 10, 5, 4)
+    for size, n in zip(sizes, budgets):
+        eng.submit(rng.integers(1, 128, size=size).tolist(), n)
+    eng.run_until_drained()
+    assert eng.stats["completed"] == len(sizes) and not app.kv_mgr.tables
+    return events
+
+
+def test_a_budgeted_adapter_issues_the_dispatches_it_always_issued():
+    assert _budgeted_script() == BUDGETED_DISPATCHES

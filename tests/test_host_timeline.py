@@ -223,13 +223,15 @@ def test_host_seconds_counter_equals_recorder_slices(paged_app):
     tops = {s["labels"]["span"] for s in ctr._snapshot()
             if s["labels"]["under"] == ""}
     assert tops <= set(TOP_LEVEL), tops
-    # self time (own seconds - seconds under it) is never negative: the
-    # default adapter dispatches prefill inside pass.admit, decode inside
-    # pass.dispatch, and the parent label tells the two run.paged apart
-    for parent in ("pass.admit", "pass.dispatch", "dispatch.prefill_chunk",
+    # self time (own seconds - seconds under it) is never negative: under
+    # the engine the adapter dispatches prefill AND decode inside
+    # pass.dispatch (admission runs no chunk: nothing opens under
+    # pass.admit), and the parent label tells the two run.paged apart
+    for parent in ("pass.dispatch", "dispatch.prefill_chunk",
                    "run.paged", "dispatch.retire"):
         assert 0.0 < under[parent] <= by_name[parent], parent
-    assert ctr.get(span="dispatch.prefill_chunk", under="pass.admit") > 0
+    assert "pass.admit" not in under
+    assert ctr.get(span="dispatch.prefill_chunk", under="pass.dispatch") > 0
     assert ctr.get(span="run.paged", under="dispatch.prefill_chunk") > 0
     assert ctr.get(span="run.paged", under="pass.dispatch") > 0
     # the phases of run.paged are recorded under it and under nothing else
