@@ -57,7 +57,8 @@ class GraniteMoeHybridFamily(DecoderFamily):
         if int(getattr(config, "mamba_n_groups", 1)) != 1:
             raise NotImplementedError(
                 "granitemoehybrid normalises the gated mixer output over "
-                "its whole width, modules/ssm.py per B/C group: the two "
+                "its whole width; modules/ssm.py normalises per B/C group, "
+                "the form the nemotron_h family runs (8 groups): the two "
                 "agree for mamba_n_groups == 1 only")
         layer_types = list(config.layer_types)
         if len(layer_types) != config.num_hidden_layers or \

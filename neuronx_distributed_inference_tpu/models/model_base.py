@@ -205,22 +205,19 @@ class DecoderSpec:
     # position-mapping decode mask — cache bytes scale with w, not seq_len
     rolling_window: bool = False
     # MIXED per-layer cache sizes (reference: gpt-oss per-layer KV,
-    # modules/kvcache/gpt_oss_kv_cache_manager.py + the per-layer
-    # cache-size map of kv_cache_manager.py): with an alternating
+    # modules/kvcache/gpt_oss_kv_cache_manager.py): with an alternating
     # local/global layer_pattern, local layers get ROLLING window-sized
-    # cache rows (W slots) while global layers keep full-seq rows —
-    # roughly halving decode KV bytes for gpt-oss-shaped stacks. The cache
+    # cache rows (W slots) while global layers keep full-seq rows. The cache
     # pytree then carries {"k","v"} (global layers) + {"k_l","v_l"}
     # (local layers); decode selects per layer statically (unrolled).
     mixed_kv: bool = False
     # the PAGED counterpart (``modules/block_kv_cache.window_pool_spec``):
     # with a local/global ``layer_pattern`` and a ``sliding_window`` the
     # paged cache holds two pools, the global layers' {"k","v"} (the
-    # allocator's blocks, full rows) and the window layers' {"k_w","v_w"}
-    # (a ring of ``window + widest step + block`` tokens a batch slot);
-    # the walk is :func:`run_layers_window`, static per kind. Set by the
-    # family that needs it (never derived: every other local/global stack
-    # keeps the one pool); what such a pool refuses: WINDOW_POOL_UNSUPPORTED
+    # allocator's blocks) and the window layers' {"k_w","v_w"} (a ring of
+    # ``window + widest step + block`` tokens a batch slot); the walk is
+    # :func:`run_layers_window`. Set by the family that needs it, never
+    # derived; what such a pool refuses: WINDOW_POOL_UNSUPPORTED
     window_pool: bool = False
     # llama4 attention variations (reference: models/llama4/
     # modeling_llama4_text.py — chunked attention + NoPE layers):
@@ -280,13 +277,11 @@ class DecoderSpec:
     # what a layer holds and where the residual is joined (HF
     # LongcatFlashDecoderLayer): ``sub_blocks`` > 1 makes a layer that many
     # [attention, dense MLP] pairs, each a pre-norm block with its own two
-    # norms and its own cache layer (``num_attn_layers`` = num_layers x
-    # sub_blocks), and - with ``moe`` set - ONE routed block on a SHORTCUT:
-    # it reads the first pair's post-attention norm and its output joins the
-    # residual only at the END of the layer. ``intermediate_size`` is then
-    # the dense MLPs' width. Params: "layers" stacks the pairs layer-major
-    # (pair ``sub_blocks * l + j`` = cache layer), "moe_layers" the routed
-    # blocks; the walk is :func:`run_layers_shortcut`.
+    # norms and its own cache layer, and - with ``moe`` set - ONE routed
+    # block on a SHORTCUT: it reads the first pair's post-attention norm and
+    # joins the residual only at the END of the layer. ``intermediate_size``
+    # is the dense MLPs' width. Params: "layers" stacks the pairs layer-major,
+    # "moe_layers" the routed blocks; the walk: :func:`run_layers_shortcut`.
     sub_blocks: int = 1
     # "rms" | "layernorm" (dbrx uses bias-free LayerNorm)
     norm_type: str = "rms"
@@ -389,10 +384,16 @@ class DecoderSpec:
     # "full" (attention on the allocator's pool), "cross" (attention with a
     # query projection only, over the pool of the nearest "full" layer below
     # it: no key, value or cache of its own), "gmu" (a Gated Memory Unit: the
-    # nearest mixer below hands it its scan output, ``ssm.SCAN_OUT``). Set
-    # together with ``ssm_pattern`` ("mamba" layers) and
-    # ``layer_pattern`` ("window" layers); the paged path only
+    # nearest mixer below hands it its scan output, ``ssm.SCAN_OUT``). With
+    # ``ssm_pattern`` / ``layer_pattern`` for its "mamba" / "window" layers
     layer_kinds: Optional[Tuple[str, ...]] = None
+    # a stack whose layer is ONE sub-block (Nemotron-H), by layer: "mamba"
+    # (the ``ssm`` block), "attention", "moe" (the routed block), "mlp" (a
+    # dense MLP), each behind the layer's one norm and joined by one residual
+    # add; ``ssm_pattern`` / ``moe_pattern`` mark its "mamba" / "moe" layers.
+    # The weights stack by kind, the norm with them (:func:`block_stack`); the
+    # walk is :func:`run_layers_blocks`, on the paged path only
+    layer_blocks: Optional[Tuple[str, ...]] = None
     # differential attention (arXiv:2410.05258 as Phi-4-mini-flash has it):
     # heads pair up by neighbours, ``o_j = rmsnorm(A(q_2j) - lam A(q_2j+1))
     # (1 - lam_init)`` over the pair's SHARED value of two heads' width.
@@ -400,12 +401,10 @@ class DecoderSpec:
     # kv row and the value), ``num_kv_heads`` the kv pairs, ``num_q_heads``
     # the published query heads, each projected at ``head_dim / 2`` and placed
     # in its own half of a ``head_dim`` row with zeros in the other, where it
-    # scores against its own member of the key pair: plain grouped-query
-    # attention over the pool as it lies (``_attn_block``)
+    # scores against its own member of the key pair (``_attn_block``)
     diff_attn: bool = False
     # family-specific static constants that conversion / layer hooks need
-    # (falcon-h1 MuP multipliers) — a hashable (name, value) tuple so the
-    # spec stays jit-static
+    # (falcon-h1 MuP multipliers): hashable (name, value) pairs, jit-static
     extras: Optional[Tuple[Tuple[str, Any], ...]] = None
 
     def extra(self, name: str, default=None):
@@ -424,16 +423,17 @@ class DecoderSpec:
     @property
     def num_attn_layers(self) -> int:
         """Layers that read/write the KV cache (SSM-only layers don't)."""
-        if self.layer_kinds is not None:
-            return self.count_kind("window") + self.count_kind("full")
+        if (self.layer_kinds or self.layer_blocks) is not None:
+            return sum(map(self.count_kind, ("window", "full", "attention")))
         pat = self.resolved_ssm_pattern
         if pat is None or self.ssm_parallel:
             return self.num_layers * self.sub_blocks
         return self.num_layers - sum(pat)
 
     def count_kind(self, kind: str) -> int:
-        """Layers of ``layer_kinds`` whose temporal block is ``kind``."""
-        return sum(k == kind for k in self.layer_kinds or ())
+        """Layers of ``layer_kinds`` / ``layer_blocks`` that are ``kind``."""
+        return sum(k == kind for k in self.layer_kinds or self.layer_blocks
+                   or ())
 
     @property
     def num_window_layers(self) -> int:
@@ -649,7 +649,7 @@ def _moe_param_specs(spec: DecoderSpec, L: int) -> Dict[str, ParamSpec]:
     H, dt = spec.hidden_size, spec.dtype
     # the router scores every expert; the weights hold all of them or a
     # share (MoESpec.held_experts)
-    E, Ie = m.num_held, m.intermediate_size
+    E, Ie = m.num_held, m.stored_intermediate or m.intermediate_size
     layers: Dict[str, ParamSpec] = {
         "router": ParamSpec((L, H, m.num_experts), P(), jnp.float32),
         "expert_gate": expert_column_parallel(E, H, Ie, dt, True, L),
@@ -675,7 +675,7 @@ def _moe_param_specs(spec: DecoderSpec, L: int) -> Dict[str, ParamSpec]:
         })
         if m.shared_gated:
             layers["shared_gate_w"] = ParamSpec((L, H), P(), dt)
-    return layers
+    return _plain_moe_specs(m, layers)
 
 
 def mlp_stack(spec: DecoderSpec, i: int) -> Tuple[str, int]:
@@ -710,10 +710,11 @@ def decoder_param_specs(spec: DecoderSpec) -> Dict[str, Any]:
     if spec.embed_norm:
         out["embed_norm"] = ParamSpec((H,), P(), dt, "ones")
         out["embed_norm_b"] = ParamSpec((H,), P(), dt, "zeros")
-    if spec.sub_blocks > 1:
-        # several [attention, dense MLP] pairs a layer and one routed block
-        # on the shortcut (DecoderSpec.sub_blocks)
-        pairs = _attn_param_specs(spec, L * spec.sub_blocks)
+    if spec.layer_blocks is not None:
+        out.update(_block_stack_specs(spec))
+    elif spec.sub_blocks > 1:
+        # several [attention, dense MLP] pairs a layer, one routed block on
+        pairs = _attn_param_specs(spec, L * spec.sub_blocks)  # the shortcut
         pairs.update(_dense_mlp_param_specs(spec, L * spec.sub_blocks))
         out["layers"] = pairs
         if spec.moe is not None:
@@ -745,9 +746,8 @@ def decoder_param_specs(spec: DecoderSpec) -> Dict[str, Any]:
         # attention layers only ("attn_layers"), SSM weights over the
         # recurrent layers ("ssm_layers") — SSM-only layers carry no dead
         # attention params and no KV cache rows. Leading dense layers under
-        # an expert stack (first_dense) split the norms + MLP as the
-        # attention stacks do: the dense layers' in "layers", the expert
-        # layers' in "moe_layers"
+        # an expert stack (first_dense) split the norms + MLP as the attention
+        # stacks do: "layers" the dense layers', "moe_layers" the expert ones'
         norm_keys = ("input_norm", "post_norm", "input_norm_b", "post_norm_b",
                      "post_attn_norm", "post_ff_norm")
         # a post-norm stack has no input norms: its walk reads the two
@@ -2497,7 +2497,7 @@ def run_layers(spec: DecoderSpec, params, cache, hidden, ai,
         refuse_recurrent([
             replacements is not None and "tensor capture/replacement",
             deepstack is not None and "deepstack"])
-        return run_layers_ssm(
+        return (run_layers_blocks if spec.layer_blocks else run_layers_ssm)(
             spec, params, cache, hidden, ai, seq_ids, positions, phase,
             identity_seq_ids=identity_seq_ids, adapter_ids=adapter_ids,
             kv_view=kv_view, prefill_lens=prefill_lens,
@@ -3877,8 +3877,9 @@ def spec_from_config(config: InferenceConfig, tp_degree: Optional[int] = None,
             paged and kw.get("ssm_parallel") and "paged parallel hybrid",
             paged and kw["ssm"].kind not in ssm_mod.CONTINUING_KINDS
             and "paged rglru state"])
-        if kw.get("layer_kinds") is not None:
-            _check_layer_kinds(kw, paged, tp)
+        if (kw.get("layer_kinds") or kw.get("layer_blocks")) is not None:
+            (_check_layer_blocks if kw.get("layer_blocks")
+             else _check_layer_kinds)(kw, paged, tp)
         # the recurrent state replaces long-range KV; keep the attention
         # cache simple (full rows, no rolling/mixed layouts)
         kw.setdefault("rolling_window", False)
@@ -4008,8 +4009,7 @@ def _check_layer_kinds(kw: Dict[str, Any], paged: bool, tp: int) -> None:
     """``DecoderSpec.layer_kinds`` against what goes with it, by name: one
     kind a layer from the five, the mixer and window patterns it implies, a
     "full" layer under every "cross" and a mixer under every "gmu", and
-    nothing between a key's projection and the cache (a "cross" layer is
-    handed the "full" layer's projected K / V as they are)."""
+    nothing between a key's projection and the cache."""
     kinds = kw["layer_kinds"]
     known = ("mamba", "window", "full", "cross", "gmu")
     if len(kinds) != kw["num_layers"] or set(kinds) - set(known):
@@ -4168,3 +4168,213 @@ def second_decoder_tokens(spec: DecoderSpec, tpu_cfg: TpuConfig, params,
     # (a draw is int32 a row, ops/sampling: another type fails the trace)
     return jax.lax.cond(jnp.any(last_idx >= 0), sampled,
                         lambda: jnp.zeros(at.shape, jnp.int32))
+
+
+# ---------------------------------------------------------------------------
+# A stack whose layer is ONE sub-block (``DecoderSpec.layer_blocks``). New
+# code stands here, behind every function a serving program's kernels are
+# traced under: a Pallas kernel's serialised body holds its call stack's line
+# numbers (ROADMAP trap 3), so a line moved above rebuilds every cell.
+# ---------------------------------------------------------------------------
+
+#: a block's weight stack: its own weights and, with them, the layer's ONE
+#: norm ("input_norm"), over the layers of its kind in order of appearance
+BLOCK_STACKS = {"mamba": "ssm_layers", "attention": "attn_layers",
+                "moe": "moe_layers", "mlp": "layers"}
+
+RECURRENT_UNSUPPORTED.update({
+    "contiguous single-block stack": "a stack of one sub-block a layer "
+                                     "(DecoderSpec.layer_blocks) is walked "
+                                     "on the paged path only",
+    "sharded single-block stack": "the stack has run at tp = 1 only (the "
+                                  "mixer's per-group norm and the walk over "
+                                  "the touched experts are one chip's)",
+})
+
+
+def block_stack(spec: DecoderSpec, i: int) -> Tuple[str, int]:
+    """Where layer ``i`` of a ``layer_blocks`` stack keeps its norm and its
+    block's weights: ``(stack name, index in it)``, the index being the
+    layers of its kind below it (also a "mamba" layer's state layer and an
+    "attention" layer's pool layer)."""
+    kind = spec.layer_blocks[i]
+    return BLOCK_STACKS[kind], spec.layer_blocks[:i].count(kind)
+
+
+def _plain_moe_specs(m: MoESpec, layers: Dict[str, ParamSpec]
+                     ) -> Dict[str, ParamSpec]:
+    """:func:`_moe_param_specs`' leaves as a stack of PLAIN experts holds them
+    (``glu_style`` "plain": no gate leaf on the experts or the shared expert;
+    where the stack is stored wider than published, the pad behind
+    ``intermediate_size`` is drawn as zeros); any other stack's as they are."""
+    if m.glu_style != "plain":
+        return layers
+    for gate in ("expert_gate", "shared_gate", "expert_gate_bias"):
+        layers.pop(gate, None)
+    if m.stored_intermediate > m.intermediate_size:
+        for key, axis in (("expert_up", 3), ("expert_down", 2)):
+            layers[key] = replace(layers[key],
+                                  live=(axis, m.intermediate_size))
+    return layers
+
+
+def _block_stack_specs(spec: DecoderSpec) -> Dict[str, Any]:
+    """The weight stacks of a ``layer_blocks`` stack, by kind
+    (:data:`BLOCK_STACKS`): a kind's own leaves over its layers and ONE norm
+    a layer with them. No MLP leaf on a temporal layer, no temporal leaf on a
+    feed-forward one, no second norm anywhere."""
+    H, dt = spec.hidden_size, spec.dtype
+    own = {
+        "mamba": lambda n: ssm_mod.ssm_param_specs(spec.ssm, H, n, dt),
+        "attention": lambda n: {
+            k: v for k, v in _attn_param_specs(spec, n).items()
+            if "norm" not in k or k in ("q_norm", "k_norm", "q_norm_b",
+                                        "k_norm_b")},
+        "moe": lambda n: _moe_param_specs(spec, n),
+        "mlp": lambda n: _dense_mlp_param_specs(spec, n),
+    }
+    out = {}
+    for kind, stack in BLOCK_STACKS.items():
+        n = spec.count_kind(kind)
+        if n:
+            out[stack] = {"input_norm": ParamSpec((n, H), P(), dt, "ones"),
+                          **own[kind](n)}
+            if spec.norm_bias:
+                out[stack]["input_norm_b"] = ParamSpec((n, H), P(), dt,
+                                                       "zeros")
+    return out
+
+
+def _check_layer_blocks(kw: Dict[str, Any], paged: bool, tp: int) -> None:
+    """``DecoderSpec.layer_blocks`` against what goes with it, by name: one
+    kind a layer from :data:`BLOCK_STACKS`, the mixer and expert patterns it
+    implies, and nothing of the two-sub-block walks beside it."""
+    blocks = kw["layer_blocks"]
+    if len(blocks) != kw["num_layers"] or set(blocks) - set(BLOCK_STACKS):
+        raise ValueError(f"layer_blocks names {kw['num_layers']} layers, each "
+                         f"one of {tuple(BLOCK_STACKS)}; got {blocks}")
+    refuse_recurrent([not paged and "contiguous single-block stack",
+                      tp > 1 and "sharded single-block stack"])
+    if kw.get("ssm_pattern") != tuple(b == "mamba" for b in blocks) \
+            or kw.get("ssm_parallel"):
+        raise ValueError("layer_blocks: ssm_pattern marks the 'mamba' layers "
+                         "and no layer runs attention beside its mixer")
+    if ("moe" in blocks) != (kw.get("moe") is not None) or (
+            "moe" in blocks
+            and kw.get("moe_pattern") != tuple(b == "moe" for b in blocks)):
+        raise ValueError("layer_blocks: moe_pattern marks the 'moe' layers "
+                         "of a stack with a MoESpec, and only such a stack "
+                         "has them")
+    if (kw.get("layer_kinds") is not None or kw.get("first_dense")
+            or kw.get("sub_blocks", 1) > 1 or kw.get("window_pool")
+            or kw.get("norm_position", "pre") != "pre"
+            or kw.get("sandwich_norm") or kw.get("mla") is not None
+            or kw.get("block_style", "sequential") != "sequential"
+            or kw.get("residual_multiplier", 1.0) != 1.0
+            or (kw.get("moe") is not None and kw["moe"].router_pre_attn)):
+        raise NotImplementedError(
+            "layer_blocks: a layer is one pre-norm sub-block and one plain "
+            "residual add; layer_kinds, first_dense, sub_blocks, a window "
+            "pool, post / sandwich norms, MLA, parallel blocks, a residual "
+            "multiplier and a router in front of the attention belong to the "
+            "other walks")
+
+
+def run_layers_blocks(spec: DecoderSpec, params, cache, hidden, ai,
+                      seq_ids, positions, phase: str, *,
+                      identity_seq_ids=False, adapter_ids=None, kv_view=None,
+                      prefill_lens=None, slot_mapping=None, block_table=None,
+                      state_slots=None):
+    """The unrolled walk of a stack whose layer is ONE sub-block
+    (``DecoderSpec.layer_blocks``; HF NemotronHBlock): ``h += block(norm(h))``
+    a layer, the block a Mamba-2 mixer, attention, the routed experts or a
+    dense MLP. Every layer reads its one norm and its block's weights from
+    its kind's stack (:func:`block_stack`) and nothing else: no layer runs a
+    second sub-block, a zero one or an identity stand-in. The profiler
+    scopes ``mixer`` / ``attn`` / ``moe`` (``mlp``) therefore partition the
+    layers; a layer's norm and residual add lie outside them.
+
+    Phase "paged" only. The caches are :func:`run_layers_ssm`'s: the KV pool
+    over the attention layers, written and read through ``slot_mapping`` /
+    ``block_table``; the mixers' conv tails and states, (Ls, slots, ...),
+    stepped in place by the state kernel or by rows (``state_slots`` None:
+    row i is slot i; else one slot index a row, the one-row chunk program).
+    Returns ``(hidden, cache, side)``; ``side["moe_tally"]`` sums a decode
+    step's counts over the EXPERT layers."""
+    s = spec.ssm
+    blocks = spec.layer_blocks
+    refuse_recurrent([
+        phase != "paged" and "contiguous single-block stack",
+        s.kind not in ssm_mod.CONTINUING_KINDS and "paged rglru state"])
+    kf, vf = cache["k"], cache["v"]
+    state_keys = [k for k in ("conv_x", "conv_bc", "ssm") if k in cache]
+    new_state = {k: cache[k] for k in state_keys}
+    valid = slot_mapping >= 0
+    tokens = hidden.shape[0] * hidden.shape[1]
+    # who steps the state: the kernel, in place on the stack, or the XLA
+    # fusions on a layer's rows (run_layers_ssm decides by the same call)
+    by_rows = ("no matrix state" if "ssm" not in cache
+               else ssm_mod.state_kernel_declined(
+                   s, cache["ssm"], *hidden.shape[:2], state_slots))
+    slot_bytes = sum(v.size // v.shape[1] * v.dtype.itemsize
+                     for v in new_state.values())
+    what = f"kind={s.kind} slot_bytes={slot_bytes} chunk={s.chunk_size}"
+    if by_rows:
+        kernel_mode.note("recurrent_state", "xla", f"{what}: {by_rows}")
+    else:
+        kernel_mode.note(
+            "recurrent_state", kernel_mode.kernel_path(),
+            f"{what} {ssm_mod.state_kernel_note(s, cache['ssm'])}")
+    # the stack's record by kind, beside recurrent_state and kv_pool
+    kernel_mode.note("layer_blocks", "xla", " ".join(
+        f"{kind}={spec.count_kind(kind)}" for kind in BLOCK_STACKS
+        if spec.count_kind(kind)))
+    n_slots = new_state[state_keys[0]].shape[1]
+    if state_slots is None and hidden.shape[0] != n_slots:
+        raise ValueError(
+            f"a paged step of {hidden.shape[0]} rows over {n_slots} state "
+            "slots needs state_slots (one slot index a row); without it row "
+            "i is slot i")
+    # the expert leaves stay in their stack where a custom call reads them
+    # in place (moe.stack_leaves); everything else is a static slice
+    in_place = (moe_mod.stack_leaves(spec.moe, tokens, params["moe_layers"])
+                if "moe" in blocks else ())
+    tally = [] if (hidden.shape[1] == 1 and "moe" in blocks) else None
+    not_local = jnp.asarray(False)
+    for i, kind in enumerate(blocks):
+        name, j = block_stack(spec, i)
+        stack = params[name]
+        lw = jax.tree.map(lambda a: a[j], {k: a for k, a in stack.items()
+                                           if k not in in_place})
+        if kind == "moe":
+            lw.update({k: moe_mod.LayerOfStack(stack[k], j)
+                       for k in in_place if k in stack})
+        h = _norm(spec, hidden, lw["input_norm"],
+                  lw.get("input_norm_b") if spec.norm_bias else None)
+        if kind == "attention":
+            out, kf, vf, _ = _attn_block(
+                spec, h, lw, kf, vf, j, ai, not_local, seq_ids, positions,
+                phase, identity_seq_ids=identity_seq_ids,
+                slot_mapping=slot_mapping, block_table=block_table,
+                adapter_ids=adapter_ids, kv_view=kv_view,
+                prefill_lens=prefill_lens)
+        elif kind == "mamba":
+            with jax.named_scope("mixer"):
+                st = {k: _state_rows(new_state[k], j, state_slots)
+                      if by_rows or k != "ssm"
+                      else ssm_mod.StateStack(new_state[k], j)
+                      for k in state_keys}
+                out, st_new = ssm_mod.ssm_block(
+                    s, lw, h, st, phase=phase, seq_lens=prefill_lens,
+                    positions=positions, valid=valid)
+                st_new.pop(ssm_mod.SCAN_OUT, None)
+                for k2, v2 in st_new.items():
+                    new_state[k2] = (
+                        v2.stack if isinstance(v2, ssm_mod.StateStack)
+                        else _state_put(new_state[k2], j, state_slots, v2))
+        else:
+            out = _mlp_block(spec, h, lw, "moe" if kind == "moe" else "dense",
+                             adapter_ids, phase=phase, tally=tally, live=valid)
+        hidden = hidden + _shard(out, AXIS_DP, None, None)
+    side = {"moe_tally": sum(tally)} if tally else {}
+    return hidden, {"k": kf, "v": vf, **new_state}, side

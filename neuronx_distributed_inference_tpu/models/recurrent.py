@@ -143,14 +143,17 @@ class FalconH1Family(DecoderFamily):
         return super().convert_hf_state_dict(sd, spec)
 
     @classmethod
-    def convert_extra_layer_weights(cls, get, layer_stack, spec):
+    def convert_extra_layer_weights(cls, get, layer_stack, spec,
+                                    p="model.layers.{i}.mamba."):
+        """The Mamba-2 mixer's tensors under the prefix ``p``, ``in_proj`` /
+        ``conv1d`` split by destination (another family's names: its
+        prefix)."""
         s = spec.ssm
         d = s.d_inner
         gn = s.n_groups * s.d_state
         nh = s.num_heads
         sim = spec.extra("ssm_in_multiplier", 1.0)
         m0, m1, m2, m3, m4 = spec.extra("ssm_multipliers", (1.0,) * 5)
-        p = "model.layers.{i}.mamba."
 
         def in_part(lo, hi, mult):
             # in_proj rows [gate d | x d | B gn | C gn | dt nh] with the

@@ -113,8 +113,15 @@ class MoESpec:
     expert_bias: bool = False
     # GLU form: "gated" = act(gate)*up; "oss_clamp" = gpt-oss clamped swiglu
     # glu = gate*sigmoid(alpha*gate) with gate<=limit, |up|<=limit,
-    # out = (up+1)*glu
+    # out = (up+1)*glu; "plain" = NO gate: an expert (and the shared expert)
+    # is two matrices, act(x W_up) W_down (Nemotron-H's ReLU² experts), and
+    # the param tree holds no expert_gate / shared_gate leaf
     glu_style: str = "gated"
+    # the width the expert leaves are STORED at where the published
+    # ``intermediate_size`` is not whole 128-lane vregs (Nemotron-H's 1856 =
+    # 14.5): zero columns of up / rows of down behind it, which add exact
+    # zeros to an expert's sum. 0 = as published
+    stored_intermediate: int = 0
     glu_alpha: float = 1.702
     glu_limit: float = 7.0
     # group-limited routing (DeepSeek-V3: experts split into n_group groups,
@@ -127,22 +134,17 @@ class MoESpec:
     # instead of the expert output — not equivalent through the gated
     # nonlinearity, so it is its own mode
     input_scaled: bool = False
-    # TOTAL-token-count (B*T) at or below which a step is "few tokens":
-    # never the grouped matmuls, the walk over the touched experts or,
-    # where the kernel declines, the all-experts einsum (whose cost grows
-    # with the tokens: that is what this bounds). It is NOT the boundary of
-    # a chunk: above it ``takes_ragged`` gives the walk every step the
-    # kernel takes and the grouped matmuls the rest. 0 = the grouped
-    # matmuls everywhere.
+    # TOTAL tokens (B*T) at or below which a step is "few tokens": the walk
+    # over the touched experts or, where the kernel declines, the
+    # all-experts einsum (whose cost grows with the tokens), never the
+    # grouped matmuls. NOT a chunk's boundary: ``takes_ragged`` has the rest.
+    # 0 = the grouped matmuls everywhere.
     dense_max_tokens: int = 64
     # hybrid CTE/TKG expert sharding (reference: moe_v2.py:135-161
-    # HybridShardingConfig — moe_tkg_ep_degree=1): prefill keeps experts
-    # sharded on "ep" (token-parallel experts, all-to-all-free combine via
-    # psum); DECODE re-constrains the expert weights so every device holds
-    # ALL experts with the intermediate dim split over ("ep","tp") — the
-    # all-gather of the weights is loop-invariant, so XLA hoists it out of
-    # the fused decode scan (the GSPMD analog of the reference's
-    # relayout-once-at-load into the TKG process group)
+    # HybridShardingConfig, moe_tkg_ep_degree=1): prefill keeps experts
+    # sharded on "ep"; DECODE re-constrains the weights so every device holds
+    # ALL experts with the intermediate dim split over ("ep","tp") (the
+    # all-gather is loop-invariant, so XLA hoists it out of the decode scan)
     tkg_experts_local: bool = False
     # one chip's share of an expert-parallel layer: the weights hold
     # ``held_experts`` experts (0 = all of them), ``first_expert`` the
@@ -152,12 +154,10 @@ class MoESpec:
     # the shared expert's output scaled by sigmoid(x . shared_gate_w) per
     # token (HF Qwen2MoeSparseMoeBlock / Qwen3NextSparseMoeBlock)
     shared_gated: bool = False
-    # zero-compute experts (LongCat-Flash ``zero_expert_num``, type
-    # identity): the LAST ``zero_experts`` of the router's ``num_experts``
-    # columns have no matrices; a pick of one adds ``weight x input``. The
-    # expert stacks never hold them, and their term is the token's own
-    # chip's: every share adds it for the rows it computes, like a shared
-    # expert
+    # zero-compute experts (LongCat-Flash ``zero_expert_num``, identity): the
+    # LAST ``zero_experts`` of the router's ``num_experts`` columns have no
+    # matrices; a pick of one adds ``weight x input``. No stack holds them;
+    # every share adds their term for its own rows, like a shared expert
     zero_experts: int = 0
     # the router reads the ATTENTION's normed input, not the experts' (the
     # post-attention norm): SmallThinker places its router in front of the
@@ -248,7 +248,7 @@ def stack_leaves(moe: MoESpec, tokens: int, layer_params: Dict[str, Any]
     a :class:`LayerOfStack`) instead of slicing a layer out of them: both
     consumers that are custom calls - the grouped matmuls of many tokens,
     the touched-experts kernel of few - read the layer where it lies."""
-    wg = layer_params["expert_gate"]
+    wg = layer_params.get("expert_gate", layer_params["expert_up"])
     if (sliced_reason(wg) if takes_ragged(moe, tokens, wg)
             else moe_decode.declined(moe, wg, tokens)):
         return ()
@@ -476,14 +476,14 @@ def experts_dense(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
         combine = (combine > 0).astype(jnp.float32)
     else:
         # (B,T,E,I): expert axis sharded on ep, intermediate on tp
-        gate = qeinsum("bth,ehi->btei", x, wg)
+        gate = None if wg is None else qeinsum("bth,ehi->btei", x, wg)
         up = qeinsum("bth,ehi->btei", x, wu)
     if bg is not None:
         gate = gate + bg
         up = up + bu
     inter_spec = ((AXIS_DP, None, None, (AXIS_TP, AXIS_EP)) if local_experts
                   else (AXIS_DP, None, AXIS_EP, AXIS_TP))
-    inter = shard_constraint(_glu(moe, gate, up), *inter_spec)
+    inter = shard_constraint(_nonlin(moe, gate, up), *inter_spec)
     outs = qeinsum("btei,eih->bteh", inter, wd)
     if bd is not None:
         outs = outs + bd
@@ -555,7 +555,7 @@ def experts_ragged(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
         present = (sorted_expert < n_e)[:, None]
         sorted_expert = jnp.minimum(sorted_expert, n_e - 1)
     if layer is not None:
-        groups = wg.shape[0] * n_e
+        groups = wu.shape[0] * n_e
         wg, wu, wd, bg, bu, bd = (
             None if a is None else a.reshape((groups,) + a.shape[2:])
             for a in (wg, wu, wd, bg, bu, bd))
@@ -569,12 +569,12 @@ def experts_ragged(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
         sorted_tokens = (sorted_tokens.astype(jnp.float32)
                          * flat_weight[order][:, None]).astype(dt)
         flat_weight = jnp.ones_like(flat_weight)
-    gate = jax.lax.ragged_dot(sorted_tokens, wg, group_sizes)
-    up = jax.lax.ragged_dot(sorted_tokens, wu, group_sizes)
+    gate, up = (None if w is None else jax.lax.ragged_dot(
+        sorted_tokens, w, group_sizes) for w in (wg, wu))
     if bg is not None:
         gate = gate + bg[sorted_expert]
         up = up + bu[sorted_expert]
-    inter = _glu(moe, gate, up)                             # (N, I)
+    inter = _nonlin(moe, gate, up)                          # (N, I)
     outs = jax.lax.ragged_dot(inter, wd, group_sizes)       # (N, H)
     if bd is not None:
         outs = outs + bd[sorted_expert]
@@ -588,11 +588,11 @@ def experts_ragged(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
 
 def ragged_row_bytes(moe: MoESpec, t: int, h: int, itemsize: int) -> int:
     """Bytes of temps :func:`experts_ragged` holds for ONE row of ``t``
-    tokens: every assignment's token copy and output (``h`` wide, the
-    output in float32 too for the combine) and its two float32
-    intermediates."""
+    tokens: every assignment's token copy and output (``h`` wide, the output
+    in float32 too for the combine) and its two float32 intermediates."""
     return t * moe.top_k * (h * (2 * itemsize + 4)
-                            + moe.intermediate_size * (2 * 4 + itemsize))
+                            + (moe.stored_intermediate
+                               or moe.intermediate_size) * (2 * 4 + itemsize))
 
 
 def experts_ragged_by_rows(moe: MoESpec, x: jnp.ndarray,
@@ -620,14 +620,14 @@ def experts_touched(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
                     top_idx: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
                     wd: jnp.ndarray, layer) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The walk over the touched experts (``ops/moe_decode.py``): x
-    (B,T,H); wg / wu (L,E,H,I), wd (L,E,I,H) the STACKED leaves and
-    ``layer`` the layer's index. A step of at most one tile of rows goes
-    whole against every touched expert; a longer one (a one-row chunk)
-    hands the kernel its assignments sorted by expert, and an expert
-    meets its own rows only. Returns the combined output and the number of
-    held experts the kernel read (every expert that ANY row of the step
-    was assigned to)."""
+    (B,T,H); wg (None: plain experts) / wu (L,E,H,I), wd (L,E,I,H) the
+    STACKED leaves, ``layer`` the layer's index. At most one tile of rows
+    goes whole against every touched expert; a longer step (a one-row chunk)
+    hands over its assignments sorted by expert and an expert meets its own
+    rows. Returns the output and how many held experts the kernel read."""
     b, t, h = x.shape
+    if wg is None:
+        return _plain_touched(moe, x, top_vals, top_idx, wu, wd, layer)
     kw = dict(glu=functools.partial(_glu, moe),
               interpret=kernel_mode.pallas_interpret())
     if b * t <= moe_decode.ROW_TILE:
@@ -699,35 +699,35 @@ def _experts(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
     biases = ((layer_w["expert_gate_bias"], layer_w["expert_up_bias"],
                layer_w["expert_down_bias"]) if moe.expert_bias
               else (None, None, None))
-    wg, wu, wd = (layer_w["expert_gate"], layer_w["expert_up"],
+    wg, wu, wd = (layer_w.get("expert_gate"), layer_w["expert_up"],
                   layer_w["expert_down"])
     tokens = x.shape[0] * x.shape[1]
     ragged = takes_ragged(
-        moe, tokens, wg.stack if isinstance(wg, LayerOfStack) else None)
-    if isinstance(wg, LayerOfStack):
+        moe, tokens, wu.stack if isinstance(wu, LayerOfStack) else None)
+    if isinstance(wu, LayerOfStack):
         # the layer loop decided by the same rules (stack_leaves)
         if ragged:
             kernel_mode.note("moe_ragged", "stacked")
             return experts_ragged_by_rows(
                 moe, x, top_vals, top_idx,
                 *(None if a is None else a.stack
-                  for a in (wg, wu, wd, *biases)), layer=wg.layer), None
+                  for a in (wg, wu, wd, *biases)), layer=wu.layer), None
         kernel_mode.note(
             "moe_decode", kernel_mode.kernel_path(),
-            moe_decode.moe_decode_plan(*wg.stack.shape[-2:],
-                                       wg.stack.dtype).note(tokens))
-        return experts_touched(moe, x, top_vals, top_idx, wg.stack,
-                               wu.stack, wd.stack, wg.layer)
+            # (the plan's text; a plain stack names its form and its widths)
+            walk_note(moe, wu.stack, tokens))
+        return experts_touched(moe, x, top_vals, top_idx, wg and wg.stack,
+                               wu.stack, wd.stack, wu.layer)
     if ragged:
         kernel_mode.note("moe_ragged", "sliced",
-                         sliced_reason(wg) or "the caller cut the layer out")
+                         sliced_reason(wu) or "the caller cut the layer out")
         return experts_ragged_by_rows(moe, x, top_vals, top_idx, wg, wu, wd,
                                       *biases), None
     kernel_mode.note("moe_decode", "xla",
-                     moe_decode.declined(moe, wg, tokens)
+                     moe_decode.declined(moe, wu, tokens)
                      or "the caller cut the layer out")
     if (moe.tkg_experts_local and phase == "decode"
-            and not is_quantized_leaf(wg)):
+            and not is_quantized_leaf(wu)):
         # hybrid TKG sharding: all experts local, intermediate split over
         # BOTH model axes (see MoESpec.tkg_experts_local). DENSE path
         # only: the ragged grouped-matmul fallthrough (decode batch above
@@ -768,8 +768,9 @@ def shared_experts(moe: MoESpec, x: jnp.ndarray,
     adds to its routed sum: one gated MLP of ``shared_intermediate``, behind
     its per-token gate (``shared_gated``) or divided by the number of
     experts it concatenates (``shared_mean_of``: their mean)."""
-    act = _act_fn(moe.act)
-    s = act(qlinear(x, layer_w["shared_gate"])) * qlinear(x, layer_w["shared_up"])
+    s = _nonlin(moe, None if moe.glu_style == "plain"
+                else qlinear(x, layer_w["shared_gate"]),
+                qlinear(x, layer_w["shared_up"]))
     s = shard_constraint(s, AXIS_DP, None, AXIS_MP)
     s = qlinear(s, layer_w["shared_down"])
     if moe.shared_gated:
@@ -780,3 +781,48 @@ def shared_experts(moe: MoESpec, x: jnp.ndarray,
     if moe.shared_mean_of:
         s = s / moe.shared_mean_of
     return s
+
+
+def _nonlin(moe: MoESpec, gate: Optional[jnp.ndarray],
+            up: jnp.ndarray) -> jnp.ndarray:
+    """An expert's nonlinearity outside the kernels: :func:`_glu`, or a plain
+    expert's ``act(up)`` (``gate`` None). (``_glu`` itself is traced INSIDE
+    the gated walk's kernel body and keeps its lines: ROADMAP trap 3.)"""
+    return _act_fn(moe.act)(up) if gate is None else _glu(moe, gate, up)
+
+
+def walk_note(moe: MoESpec, stack: jnp.ndarray, tokens: int) -> str:
+    """The ``moe_decode`` engagement record of a step of ``tokens`` tokens on
+    the walk over ``stack`` (L, E, H, I): the plan's text; a plain stack says
+    its form first and, where it is stored wider than published, both widths
+    (``plain pieces=3 of 640 (1856 of 1920 stored)``)."""
+    note = moe_decode.moe_decode_plan(
+        *stack.shape[-2:], stack.dtype,
+        moe_decode.matrices_of(moe)).note(tokens)
+    if moe.glu_style != "plain":
+        return note
+    stored = (f" ({moe.intermediate_size} of {stack.shape[-1]} stored)"
+              if stack.shape[-1] != moe.intermediate_size else "")
+    return f"plain {note}{stored}"
+
+
+def _plain_touched(moe: MoESpec, x: jnp.ndarray, top_vals: jnp.ndarray,
+                   top_idx: jnp.ndarray, wu: jnp.ndarray, wd: jnp.ndarray,
+                   layer) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`experts_touched` over PLAIN experts (``glu_style`` "plain"):
+    units of two matrices, the activation on the float32 product inside the
+    kernel (``moe_decode.moe_decode_units`` / ``moe_chunk_units``)."""
+    b, t, h = x.shape
+    kw = dict(nonlin=_act_fn(moe.act),
+              interpret=kernel_mode.pallas_interpret())
+    if b * t <= moe_decode.ROW_TILE:
+        combine = held_combine(moe, top_vals, top_idx).reshape(b * t, -1)
+        y, read = moe_decode.moe_decode_units(
+            x.reshape(b * t, h), combine, (wu, wd), layer, **kw)
+    else:
+        order, _, weight, group_sizes = sorted_assignments(moe, top_vals,
+                                                           top_idx)
+        y, read = moe_decode.moe_chunk_units(
+            x.reshape(b * t, h), order // moe.top_k, weight[order],
+            group_sizes, (wu, wd), layer, **kw)
+    return y.astype(x.dtype).reshape(b, t, h), read

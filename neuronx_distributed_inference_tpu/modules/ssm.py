@@ -544,13 +544,13 @@ def state_kernel_declined(s: SSMSpec, stack, rows: int, tokens: int,
 
 
 def state_kernel_note(s: SSMSpec, stack) -> str:
-    """The engagement record's text for a step the kernel takes: a block's
-    heads and the tile."""
-    if s.kind == "mamba2":
-        return mamba_state_step.mamba_step_plan(
-            stack.shape[2], s.n_groups, *stack.shape[3:]).note()
-    return delta_state_step.state_step_plan(
-        stack.shape[2], s.key_heads, *stack.shape[3:]).note()
+    """The record's text for a step the kernel takes (groups: of several)."""
+    if s.kind != "mamba2":
+        return delta_state_step.state_step_plan(
+            stack.shape[2], s.key_heads, *stack.shape[3:]).note()
+    groups = f" groups={s.n_groups}" * (s.n_groups > 1)
+    return mamba_state_step.mamba_step_plan(
+        stack.shape[2], s.n_groups, *stack.shape[3:]).note() + groups
 
 
 def _delta_step(q, k, v, g, beta, st0):

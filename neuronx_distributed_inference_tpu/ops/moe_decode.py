@@ -106,18 +106,18 @@ class MoEDecodePlan(NamedTuple):
         return f"pieces={self.pieces} of {self.ip}{by_expert}"
 
 
-def moe_decode_plan(h: int, i: int, dtype) -> Optional[MoEDecodePlan]:
-    """How the kernel walks experts of ``h`` x ``i``: the fewest column
-    pieces of whole vregs such that two slots of a piece's three matrices
-    fit :data:`MOE_WEIGHT_VMEM_BYTES`; chosen from the leaves' shape and
-    item size and from nothing else. None: no such piece."""
+def moe_decode_plan(h: int, i: int, dtype,
+                    matrices: int = 3) -> Optional[MoEDecodePlan]:
+    """The fewest column pieces of whole vregs such that two slots of a
+    piece's ``matrices`` fit :data:`MOE_WEIGHT_VMEM_BYTES`. None: no piece."""
     if h % LANES or i % LANES:
         return None
+    per_matrix = MOE_WEIGHT_VMEM_BYTES // (2 * matrices)
     for pieces in range(1, i // LANES + 1):
         ip, rest = divmod(i, pieces)
         if rest or ip % LANES:
             continue
-        if 2 * 3 * h * ip * jnp.dtype(dtype).itemsize <= MOE_WEIGHT_VMEM_BYTES:
+        if h * ip * jnp.dtype(dtype).itemsize <= per_matrix:
             return MoEDecodePlan(pieces, ip)
     return None
 
@@ -162,10 +162,10 @@ def declined(moe, wg: Any, tokens: int = 1) -> str:
         return "input_scaled routing scales the expert input"
     if moe.expert_bias:
         return "per-expert biases"
-    if moe.glu_style != "gated" or moe.act not in WALK_ACTS:
+    if not walks_glu(moe):
         return f"glu {moe.glu_style}/{moe.act}"
     h, i = wg.shape[-2:]
-    plan = moe_decode_plan(h, i, wg.dtype)
+    plan = moe_decode_plan(h, i, wg.dtype, matrices_of(moe))
     if plan is None:
         return f"experts of {h} x {i} are not whole {LANES}-lane tiles"
     if tokens > MOE_WALK_MAX_ROWS or rows_vmem_bytes(
@@ -441,6 +441,270 @@ def moe_chunk_experts(x: jnp.ndarray, token: jnp.ndarray,
         "moe_chunk_experts",
         (scalars, token.astype(jnp.int32), weight.astype(jnp.float32)), (x,),
         jax.ShapeDtypeStruct(x.shape, jnp.float32), (wg, wu, wd), plan,
+        (pltpu.VMEM(tile, jnp.float32), pltpu.VMEM(tile, jnp.float32),
+         pltpu.VMEM(x.shape, jnp.float32), pltpu.VMEM(x.shape, jnp.float32),
+         pltpu.SemaphoreType.DMA((2,))),
+        vmem_rows, interpret, piped=False)
+    return out[:n], count
+
+
+# ---------------------------------------------------------------------------
+# The walk over units of ANY number of matrices. A PLAIN expert is two,
+# ``act(x W_up) W_down`` (Nemotron-H's ReLU² experts): no gate leaf, one
+# product a unit before the nonlinearity. Everything above this line is the
+# gated form's, three matrices spelt out, and stays where it is: a Pallas
+# kernel's serialised body holds its call stack's line numbers, so moving
+# those lines rebuilds every gated expert cell's programs (ROADMAP trap 3).
+# The functions below take the matrices as a list - the input projections,
+# pieced by columns, then the output projection, pieced by rows - and serve
+# both forms; the gated call sites move onto them with the next change that
+# rebuilds those cells anyway.
+# ---------------------------------------------------------------------------
+
+#: activations of a plain expert the walk computes, on the float32 product
+#: inside the kernel
+PLAIN_WALK_ACTS = ("relu2",)
+
+
+def matrices_of(moe) -> int:
+    """Matrices an expert of ``moe`` holds: gate, up and down, or a plain
+    expert's up and down."""
+    return 2 if moe.glu_style == "plain" else 3
+
+
+def walks_glu(moe) -> bool:
+    """The kernel computes ``moe``'s nonlinearity (:func:`declined` names
+    what it does not)."""
+    if moe.glu_style == "plain":
+        return moe.act in PLAIN_WALK_ACTS
+    return moe.glu_style == "gated" and moe.act in WALK_ACTS
+
+
+def _walk_units(sc_ref, stacks, slots, sem, pieces: int, compute: Callable):
+    """:func:`_walk` over units of ``len(stacks)`` matrices: the last is
+    pieced by rows, the others by columns."""
+    layer = sc_ref[0]
+    n_units = sc_ref[1] * pieces
+    ip = slots[-1].shape[1]
+
+    def copies(u, slot):
+        e = sc_ref[2 + jax.lax.div(u, pieces)]
+        if pieces == 1:
+            srcs = [w.at[layer, e] for w in stacks]
+        else:
+            cols = pl.ds(pl.multiple_of(jax.lax.rem(u, pieces) * ip, LANES),
+                         ip)
+            srcs = [w.at[layer, e, :, cols] for w in stacks[:-1]]
+            srcs.append(stacks[-1].at[layer, e, cols, :])
+        return tuple(
+            pltpu.make_async_copy(src, buf.at[slot], sem.at[k, slot])
+            for k, (src, buf) in enumerate(zip(srcs, slots)))
+
+    def start(u, slot):
+        for c in copies(u, slot):
+            c.start()
+
+    @pl.when(n_units > 0)
+    def _first():
+        start(0, 0)
+
+    def unit(u, carry):
+        slot = jax.lax.rem(u, 2)
+
+        @pl.when(u + 1 < n_units)
+        def _next():
+            start(u + 1, 1 - slot)
+
+        compute(sc_ref[2 + jax.lax.div(u, pieces)], slot, copies(u, slot))
+        return carry
+
+    jax.lax.fori_loop(0, n_units, unit, 0)
+
+
+def _unit(x, slot, slots, nonlin: Callable, copies=()):
+    """``nonlin(x W_in ...) W_out`` of the rows ``x`` on the unit in
+    ``slot``, in float32 (:func:`_expert` for any number of input
+    projections)."""
+    def arrived(k):
+        if copies:
+            copies[k].wait()
+
+    products = []
+    for k, buf in enumerate(slots[:-1]):
+        arrived(k)
+        products.append(jnp.dot(x, buf[slot],
+                                preferred_element_type=jnp.float32))
+    inter = nonlin(*products).astype(x.dtype)
+    arrived(len(slots) - 1)
+    return jnp.dot(inter, slots[-1][slot], preferred_element_type=jnp.float32)
+
+
+def _tile_kernel(sc_ref, x_ref, comb_ref, *refs, matrices: int, pieces: int,
+                 nonlin: Callable):
+    """:func:`_kernel` (a step of one tile of rows, ALL against every unit)
+    for units of ``matrices``: ``refs`` are the stacks, the result, the
+    slots and the copies' semaphores."""
+    stacks, o_ref = refs[:matrices], refs[matrices]
+    slots, sem = refs[matrices + 1:2 * matrices + 1], refs[-1]
+    o_ref[...] = jnp.zeros_like(o_ref)
+    x = x_ref[...]
+    lane_expert = jax.lax.broadcasted_iota(jnp.int32, comb_ref.shape, 1)
+
+    def compute(e, slot, copies):
+        out = _unit(x, slot, slots, nonlin, copies)
+        w = jnp.sum(jnp.where(lane_expert == e, comb_ref[...], 0.0),
+                    axis=1, keepdims=True)
+        o_ref[...] += out * w
+
+    _walk_units(sc_ref, stacks, slots, sem, pieces, compute)
+
+
+def _group_rows_kernel(sc_ref, tok_ref, wt_ref, x_hbm, *refs, matrices: int,
+                       pieces: int, nonlin: Callable, experts: int):
+    """:func:`_rows_kernel` (a step of more rows than a tile, each unit
+    against ITS rows) for units of ``matrices``: ``refs`` are the stacks, the
+    result in HBM, the slots, then ``xt, yt, x_ref, o_ref, io_sem, sem``."""
+    stacks, o_hbm = refs[:matrices], refs[matrices]
+    slots = refs[matrices + 1:2 * matrices + 1]
+    xt, yt, x_ref, o_ref, io_sem, sem = refs[2 * matrices + 1:]
+    rows_in = pltpu.make_async_copy(x_hbm, x_ref, io_sem.at[0])
+    rows_in.start()
+    o_ref[...] = jnp.zeros_like(o_ref)
+    xt[...] = jnp.zeros_like(xt)
+    rows_in.wait()
+    tile = xt.shape[0]
+    bounds = 2 + experts            # behind [layer, count, id_0 .. id_{E-1}]
+
+    def compute(e, slot, copies):
+        lo, hi = sc_ref[bounds + e], sc_ref[bounds + e + 1]
+        for c in copies:
+            c.wait()
+
+        def rows(t, carry):
+            base = lo + t * tile
+            n_rows = jnp.minimum(tile, hi - base)
+
+            def gather(r, c):
+                xt[pl.ds(r, 1), :] = x_ref[pl.ds(tok_ref[base + r], 1), :]
+                return c
+
+            jax.lax.fori_loop(0, n_rows, gather, 0)
+            yt[...] = _unit(xt[...].astype(slots[0].dtype), slot, slots,
+                            nonlin)
+
+            def scatter(r, c):
+                row = pl.ds(tok_ref[base + r], 1)
+                o_ref[row, :] += yt[pl.ds(r, 1), :] * wt_ref[base + r]
+                return c
+
+            jax.lax.fori_loop(0, n_rows, scatter, 0)
+            return carry
+
+        jax.lax.fori_loop(0, pl.cdiv(hi - lo, tile), rows, 0)
+
+    _walk_units(sc_ref, stacks, slots, sem, pieces, compute)
+    rows_out = pltpu.make_async_copy(o_ref, o_hbm, io_sem.at[1])
+    rows_out.start()
+    rows_out.wait()
+
+
+def _call_units(kernel, name: str, scalars, rows_in, rows_out, stacks,
+                plan: MoEDecodePlan, scratch, vmem_rows: int,
+                interpret: bool, piped: bool = True):
+    """:func:`_call` for units of ``len(stacks)`` matrices."""
+    h = stacks[-1].shape[3]
+
+    def rows(a):
+        return (pl.BlockSpec(a.shape, lambda *_: (0, 0)) if piped
+                else pl.BlockSpec(memory_space=pl.ANY))
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(1,),
+            in_specs=[rows(a) for a in rows_in]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(stacks),
+            out_specs=rows(rows_out),
+            scratch_shapes=[
+                *(pltpu.VMEM((2, h, plan.ip), w.dtype) for w in stacks[:-1]),
+                pltpu.VMEM((2, plan.ip, h), stacks[-1].dtype),
+                *scratch,
+                pltpu.SemaphoreType.DMA((len(stacks), 2)),
+            ],
+        ),
+        out_shape=rows_out,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=MOE_WEIGHT_VMEM_BYTES + vmem_rows),
+        name=name,
+        interpret=interpret,
+    )(*scalars, *rows_in, *stacks)
+
+
+def _checked_units_plan(n: int, stacks) -> Tuple[MoEDecodePlan, int]:
+    """:func:`_checked_plan` for units of ``len(stacks)`` matrices, the
+    output projection ``stacks[-1]`` (L, E, I, H) last."""
+    wd = stacks[-1]
+    i, h = wd.shape[2:]
+    plan = moe_decode_plan(h, i, wd.dtype, len(stacks))
+    vmem_rows = plan and rows_vmem_bytes(n, h, wd.shape[1], plan, wd.dtype)
+    if plan is None or n > MOE_WALK_MAX_ROWS \
+            or vmem_rows > MOE_ROWS_VMEM_BYTES:
+        raise ValueError(
+            f"moe expert walk: {n} rows over experts of {len(stacks)} "
+            f"matrices of {h} x {i} {wd.dtype} (moe_decode.declined says "
+            "what the kernel takes)")
+    return plan, vmem_rows
+
+
+def moe_decode_units(x: jnp.ndarray, combine: jnp.ndarray, stacks, layer, *,
+                     nonlin: Callable, interpret: bool = False
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`moe_decode_experts` over experts of ``len(stacks)`` matrices:
+    ``stacks`` the input projections (L, E, H, I) then the output projection
+    (L, E, I, H); ``nonlin`` takes the float32 products of the rows with a
+    unit's input projections, in that order (a plain expert: the one)."""
+    n, h = x.shape
+    wd = stacks[-1]
+    plan, vmem_rows = _checked_units_plan(n, stacks)
+    ids, count = touched_experts(combine)
+    rows = -n % (32 // jnp.dtype(wd.dtype).itemsize)
+    x, combine = (jnp.pad(a, ((0, rows), (0, 0)))
+                  for a in (x.astype(wd.dtype), combine))
+    scalars = jnp.concatenate([
+        jnp.asarray(layer, jnp.int32).reshape(1), count.reshape(1), ids])
+    out = _call_units(
+        functools.partial(_tile_kernel, matrices=len(stacks),
+                          pieces=plan.pieces, nonlin=nonlin),
+        "moe_decode_experts", (scalars,), (x, combine),
+        jax.ShapeDtypeStruct((n + rows, h), jnp.float32), tuple(stacks),
+        plan, (), vmem_rows, interpret)
+    return out[:n], count
+
+
+def moe_chunk_units(x: jnp.ndarray, token: jnp.ndarray, weight: jnp.ndarray,
+                    group_sizes: jnp.ndarray, stacks, layer, *,
+                    nonlin: Callable, interpret: bool = False
+                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`moe_chunk_experts` over experts of ``len(stacks)`` matrices
+    (:func:`moe_decode_units` has ``stacks`` and ``nonlin``)."""
+    n, h = x.shape
+    plan, vmem_rows = _checked_units_plan(n, stacks)
+    group_sizes = group_sizes.astype(jnp.int32)
+    ids, count = touched_experts(group_sizes[None, :])
+    rows = -n % 8
+    x = jnp.pad(x.astype(jnp.float32), ((0, rows), (0, 0)))
+    scalars = jnp.concatenate([
+        jnp.asarray(layer, jnp.int32).reshape(1), count.reshape(1), ids,
+        jnp.zeros((1,), jnp.int32), jnp.cumsum(group_sizes)])
+    tile = (ROW_TILE, h)
+    out = _call_units(
+        functools.partial(_group_rows_kernel, matrices=len(stacks),
+                          pieces=plan.pieces, nonlin=nonlin,
+                          experts=stacks[-1].shape[1]),
+        "moe_chunk_experts",
+        (scalars, token.astype(jnp.int32), weight.astype(jnp.float32)), (x,),
+        jax.ShapeDtypeStruct(x.shape, jnp.float32), tuple(stacks), plan,
         (pltpu.VMEM(tile, jnp.float32), pltpu.VMEM(tile, jnp.float32),
          pltpu.VMEM(x.shape, jnp.float32), pltpu.VMEM(x.shape, jnp.float32),
          pltpu.SemaphoreType.DMA((2,))),

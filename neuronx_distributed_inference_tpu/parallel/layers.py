@@ -39,13 +39,21 @@ class ParamSpec:
     dtype: jnp.dtype = jnp.bfloat16
     # how to initialize for random-weight tests; loaded checkpoints override
     init: str = "normal"   # "normal" | "zeros" | "ones"
+    # (axis, width): the tensor is stored wider along ``axis`` than the model
+    # is, and everything from ``width`` on is padding, drawn as zeros
+    live: Optional[Tuple[int, int]] = None
 
     def initializer(self, key, scale: float = 0.02):
         if self.init == "zeros":
             return jnp.zeros(self.shape, self.dtype)
         if self.init == "ones":
             return jnp.ones(self.shape, self.dtype)
-        return (jax.random.normal(key, self.shape, jnp.float32) * scale).astype(self.dtype)
+        w = jax.random.normal(key, self.shape, jnp.float32) * scale
+        if self.live is not None:
+            axis, width = self.live
+            w = jnp.where(jax.lax.broadcasted_iota(jnp.int32, self.shape, axis)
+                          < width, w, 0.0)
+        return w.astype(self.dtype)
 
 
 def column_parallel(in_dim: int, out_dim: int, dtype=jnp.bfloat16,

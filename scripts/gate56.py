@@ -153,7 +153,12 @@ def _cohere_head(ref, hf):
                                        hf["layer_norm_eps"])
 
 
-def blocked_hidden(ref, hf, block, head=_cohere_head):
+#: the embedding's published name and the head's (None: the tied head, the
+#: embedding again); an architecture with other names hands long_walk its own
+TIED = ("model.embed_tokens.weight", None)
+
+
+def blocked_hidden(ref, hf, block, head=_cohere_head, names=TIED):
     """``ids (1, S) -> ref.final_hidden`` of ONE sequence, a layer a program
     and its attention ``block`` queries at a time (128 heads x 8192 x 8192
     float32 scores are 34 GB an attention): the same arithmetic in another
@@ -174,8 +179,7 @@ def blocked_hidden(ref, hf, block, head=_cohere_head):
     final = jax.jit(head(ref, hf))
 
     def hidden(w, ids):
-        x = w["model.embed_tokens.weight"][jnp.asarray(ids)].astype(
-            jnp.float32)
+        x = w[names[0]][jnp.asarray(ids)].astype(jnp.float32)
         for layer in layers:
             x = layer(w, x)
         return final(w, x)
@@ -184,12 +188,13 @@ def blocked_hidden(ref, hf, block, head=_cohere_head):
 
 def long_walk(cfg, seed, tokens, rows=4, new_tokens=64, block=128,
               served_precision=None, twin=None, second=None,
-              head=_cohere_head):
+              head=_cohere_head, names=TIED):
     """See the module docstring. ``second``: the length of the prompt that
     takes the released row's slot (default: a quarter of ``tokens``; of
     ``tokens`` itself, the reference's programs serve it too). ``head``:
     the reference's final norm (:func:`_cohere_head`'s form), for a stack
-    without a window too (``scripts/gate61.py``)."""
+    without a window too (``scripts/gate61.py``). ``names``: the embedding's
+    and the head's published names (:data:`TIED`; ``scripts/gate64.py``)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -217,12 +222,12 @@ def long_walk(cfg, seed, tokens, rows=4, new_tokens=64, block=128,
         plain = jax.jit(lambda w_, i_: ref.final_hidden(hf, w_, i_)[0])(
             w, jnp.asarray(short))
         blocked = blocked_hidden(ref, hf, max(16, short.shape[1] // 4),
-                                 head)(w, short)
+                                 head, names)(w, short)
         out = {"blocked_vs_plain_reference":
                float(jnp.abs(plain - blocked).max())}
-        of = blocked_hidden(ref, hf, block, head)
+        of = blocked_hidden(ref, hf, block, head, names)
         hidden = [np.asarray(of(w, s[None]))[0] for s in streams]
-    embed = w["model.embed_tokens.weight"]
+    embed = w[names[1] or names[0]]
     del w, plain, blocked
     gc.collect()
     bs = cfg["serve"]["pa_block_size"]

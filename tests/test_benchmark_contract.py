@@ -1496,11 +1496,12 @@ def test_keye_vl2_select_kernel_share_reads_the_adapters_two_counters():
         name=metric, unit="%", better="higher", source="program_counter",
         layer="Step graphs", moves="itl_p50_ms", workloads=[KEYE_CELL])
     # appended where it was added: what follows it is ISSUE 52's seven,
-    # then ISSUE 54's five, ISSUE 56's four, ISSUE 61's two and ISSUE 62's
+    # then ISSUE 54's five, ISSUE 56's four, ISSUE 61's two, ISSUE 62's one
+    # and ISSUE 64's two
     later = BENCHMARK["per_layer"][BENCHMARK["per_layer"].index(entry) + 1:]
     assert [m["name"] for m in later] == list(
         TTFT_METRICS + PHI4_METRICS + COMMAND_A_METRICS + LFM2_METRICS
-        + CARRY_METRICS)
+        + CARRY_METRICS + NEMOTRON_METRICS)
 
     def ctx(**counters):
         return {"before": {"counters": {"host_stats.sparse_dispatches": 10}},
@@ -1533,11 +1534,11 @@ def test_a_ttft_phase_metric_is_the_chat_cells_alone(metric):
     ``engine.ttft_*`` (``ServingEngine.stats``, always on) and read nothing
     from a program without the keys."""
     from harness import readers
-    # (ISSUE 54's five, ISSUE 56's four, ISSUE 61's two and ISSUE 62's one
-    # were appended behind them)
-    assert tuple(m["name"] for m in BENCHMARK["per_layer"][-19:]) == \
+    # (ISSUE 54's five, ISSUE 56's four, ISSUE 61's two, ISSUE 62's one and
+    # ISSUE 64's two were appended behind them)
+    assert tuple(m["name"] for m in BENCHMARK["per_layer"][-21:]) == \
         TTFT_METRICS + PHI4_METRICS + COMMAND_A_METRICS + LFM2_METRICS \
-        + CARRY_METRICS
+        + CARRY_METRICS + NEMOTRON_METRICS
     entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == metric)
     assert entry["moves"] == "ttft_p50_ms"
     assert entry["workloads"] == ["olmoe-chat-steady"]
@@ -1921,7 +1922,7 @@ def test_liveset_carry_share_reads_the_adapters_four_counters():
     counters (the parent) reads 0, a window without such a change nothing."""
     from harness import readers
     metric = CARRY_METRICS[0]
-    entry = BENCHMARK["per_layer"][-1]
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == metric)
     overlap = next(m for m in BENCHMARK["per_layer"]
                    if m["name"] == "adapter.decode_overlap_share")
     assert entry == dict(
@@ -2248,6 +2249,339 @@ def test_the_lfm2_moe_toy_gate_and_three_faults_in_the_program(
                          median_ratio_max=0.5, worst_ratio_max=1.0,
                          excuse_margin_max=0.0))
     res = build.logit_gate(toy, seed=2**31 + 61, served_precision="highest")
+    if fault is None:
+        assert res["passed"], res
+        assert res["compared"] == 2 * 32 * HF["vocab_size"]
+    else:
+        assert not res["passed"] and res["worst_ratio"] > 5
+
+
+# ---------------------------------------------------------------------------
+# nemotron-3-nano-30b-a3b (ISSUE 64)
+# ---------------------------------------------------------------------------
+
+NEMOTRON_CELL = "nemotron3-nano-agent-closed"
+NEMOTRON_METRICS = ("kernel.moe_decode_plain_roofline",
+                    "kernel.mixer_decode_groups_roofline")
+NEMOTRON_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+def test_the_benchmark_holds_twelve_configurations_and_thirteen_cells():
+    assert len(BENCHMARK["configs"]) == 12 and len(CELLS) == 13
+    assert BENCHMARK["configs"][-1]["name"] == "nemotron-3-nano-30b-a3b"
+    assert CELLS[-1] == NEMOTRON_CELL
+    assert [m["name"] for m in BENCHMARK["per_layer"][-2:]] == \
+        list(NEMOTRON_METRICS)
+    assert all(w["chips"] == 1 for w in BENCHMARK["workloads"])
+
+
+def test_nemotron3_nano_keeps_every_published_number():
+    """The catalog row's ``config`` (model-configs guide), as copied into
+    ISSUE 64: every key at the top level of the file, no width changed, ALL
+    52 layers and the pattern as published; ``reduced`` names the experts
+    held and the vocabulary, and nothing else."""
+    cfg = build.load_json("configs", "nemotron-3-nano-30b-a3b.json")
+    published = dict(
+        attention_bias=False, chunk_size=128, conv_kernel=4, expand=2,
+        head_dim=128, hidden_size=2688,
+        hybrid_override_pattern=NEMOTRON_PATTERN, intermediate_size=1856,
+        layer_norm_epsilon=1e-05, mamba_head_dim=64, mamba_hidden_act="silu",
+        mamba_num_heads=64, mamba_proj_bias=False,
+        max_position_embeddings=262144, mlp_bias=False,
+        mlp_hidden_act="relu2", model_type="nemotron_h",
+        moe_intermediate_size=1856, moe_shared_expert_intermediate_size=3712,
+        n_group=1, n_groups=8, n_routed_experts=128, n_shared_experts=1,
+        norm_eps=1e-05, norm_topk_prob=True, num_attention_heads=32,
+        num_experts_per_tok=6, num_hidden_layers=52, num_key_value_heads=2,
+        num_logits_to_keep=1, partial_rotary_factor=1,
+        rescale_prenorm_residual=True, residual_in_fp32=False,
+        rope_theta=10000, routed_scaling_factor=2.5, sliding_window=None,
+        ssm_state_size=128, tie_word_embeddings=False, time_step_floor=0.0001,
+        time_step_max=0.1, time_step_min=0.001, topk_group=1, use_bias=False,
+        use_conv_bias=True, use_mamba_kernels=True, vocab_size=131072)
+    assert len(NEMOTRON_PATTERN) == 52
+    assert [NEMOTRON_PATTERN.count(c) for c in "ME*-"] == [23, 23, 6, 0]
+    assert set(published) <= set(cfg)
+    differs = sorted(k for k in published if cfg[k] != published[k])
+    assert differs == sorted(cfg["reduced"]) == ["n_routed_experts",
+                                                 "vocab_size"]
+    entry = BENCHMARK["configs"][-1]
+    assert sorted(entry["reduced"]) == differs
+    assert entry["source"] == cfg["source"] and len(entry["why"]) <= 200
+    assert entry["file"] == "benchmark/configs/nemotron-3-nano-30b-a3b.json"
+    # one chip's share of a v5e-8: 16 of 128 experts, an eighth of the words
+    assert (cfg["n_routed_experts"], cfg["router_n_routed_experts"],
+            cfg["first_expert"], cfg["vocab_size"]) == (16, 128, 0, 16384)
+    assert cfg["family"] == cfg["model_type"] == "nemotron_h"
+    assert cfg["chips"] == cfg["tp"] == 1 and cfg["dtype"] == "bfloat16"
+    for said in ("v5e-8", "16 of 128", "WITHOUT its exchange",
+                 "12 tokens an expert", "1.5", "12.45 of 16"):
+        assert said in cfg["deployment"], said
+    assert "5,258,420,544" in cfg["reduced_why"]
+    assert {"no_rotary", "topk_norm_eps", "tensor_names", "in_proj_rows",
+            "d_inner", "gated_norm", "time_step_limit", "router_dtype",
+            "state_dtype", "stored_width"} <= set(cfg["assumed"])
+    serve = cfg["serve"]
+    assert cfg["adapter"] == {}
+    assert (serve["batch_size"], serve["seq_len"], serve["pa_block_size"],
+            serve["pa_num_blocks"], serve["context_encoding_buckets"],
+            serve["is_prefix_caching"]) == (32, 4096, 32, 4096, [64, 256],
+                                            False)
+    gate = cfg["gate"]
+    twin = build.hf_config(cfg, build.gate_overrides(gate))
+    # the twin: a prefix of the published pattern with every kind of layer,
+    # at least two E and two M, the attention layer not the last
+    pattern = twin["hybrid_override_pattern"]
+    assert pattern == NEMOTRON_PATTERN[:7] == "MEMEM*E"
+    assert (twin["num_hidden_layers"], twin["hidden_size"],
+            twin["n_routed_experts"], twin["vocab_size"]) == \
+        (7, 2688, 16, 16384)
+    assert pattern.count("M") >= 2 and pattern.count("E") >= 2
+    assert "*" in pattern[:-1] and not pattern.endswith("*")
+    assert (gate["batch"], gate["prompt_len"], gate["new_tokens"]) == \
+        (4, 112, 16)
+    assert gate["prompt_len"] + gate["new_tokens"] <= \
+        4 * serve["pa_block_size"]
+    assert 0 < gate["excuse_margin_max"] <= 0.02
+    assert "before the first chip run" in gate["rule"]
+    assert "SQUARES a bf16 rounding" in gate["tolerance_why"]
+    ref = build.load_reference("nemotron_h")
+    for control in (*ref.CONTROLS, "fp8"):
+        assert control in gate["controls"], control
+    # the pool cannot run dry: every row at its longest prompt and answer
+    mix = build.load_json("traffic", "agent-reason-closed.json")
+    longest = mix["prompt_len"]["hi"] + mix["output_len"]["hi"]
+    assert longest == serve["seq_len"] == 4096
+    assert serve["pa_num_blocks"] * serve["pa_block_size"] == \
+        serve["batch_size"] * longest
+    # the mix is ISSUE 64's, every number of it
+    assert (mix["loop"], mix["clients_per_batch_row"], mix["pool_requests"],
+            mix["lead_s"], mix["grace_s"], mix["base_seed"]) == \
+        ("closed", 2, 4096, 20.0, 8.0, 64)
+    assert mix["prompt_len"] == dict(kind="lognormal", median=512, sigma=0.8,
+                                     lo=64, hi=1536)
+    assert mix["output_len"] == dict(kind="lognormal", median=1024, sigma=0.6,
+                                     lo=256, hi=2560)
+    assert build.warm_widths(cfg, mix) == [1, 64, 256]
+    # the cell: one chip, on its two rooflines and on no other family's
+    cell = BENCHMARK["workloads"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == \
+        (NEMOTRON_CELL, "nemotron-3-nano-30b-a3b", "agent-reason-closed", 1)
+    assert len(cell["why"]) <= 200
+    listed = {m["name"] for m in BENCHMARK["per_layer"]
+              if NEMOTRON_CELL in m.get("workloads", ())}
+    assert {m["name"] for m in BENCHMARK["per_layer"]
+            if m.get("workloads") == [NEMOTRON_CELL]} == set(NEMOTRON_METRICS)
+    assert {m for m in listed if m.endswith("_roofline")} == \
+        set(NEMOTRON_METRICS) | {"kernel.paged_decode_roofline"}
+    assert "kernel.moe_decode_held_roofline" not in listed, (
+        "DeepSeek's key names, which this file shares, x THREE projections: "
+        "it would read ~150 % of a two-matrix walk")
+    assert "kernel.mixer_decode_roofline" not in listed, (
+        "granite's key names (mamba_n_heads, layer_types): it reads nothing "
+        "here")
+    assert "step.decode_mlp_ms" not in listed, (
+        "a single-block stack with no dense MLP layer has no mlp scope")
+    assert {"sched.live_batch_mean", "adapter.prefill_pad_share",
+            "adapter.decode_overlap_share", "adapter.liveset_carry_share",
+            "host.stall_s", "step.decode_attn_ms", "step.prefill_attn_ms",
+            "step.decode_moe_ms", "step.prefill_moe_ms",
+            "step.decode_mixer_ms", "step.prefill_mixer_ms",
+            "attn.paged_prefill_kernel_share", "moe.experts_touched_share",
+            "moe.experts_skipped_share", "moe.prefill_walk_share",
+            "mixer.state_kernel_share", "host.prep_inputs_ms_per_dispatch",
+            "host.prep_rng_ms_per_dispatch",
+            "host.prep_enqueue_ms_per_dispatch",
+            "host.dispatch_build_ms_per_dispatch",
+            "host.dispatch_retire_ms_per_dispatch",
+            "host.deliver_ms_per_dispatch", "device.idle_prep_share",
+            "sched.gaps_behind_prefill_share", "sched.stalled_gap_mean_ms",
+            "sched.prefill_dispatches_per_stalled_gap"} <= listed
+    assert NEMOTRON_CELL in next(
+        m for m in BENCHMARK["end_to_end"]
+        if m["name"] == "tokens_per_s")["workloads"]
+
+
+def test_nemotron3_nano_allocates_what_its_file_says():
+    """The file's ``memory`` against what the program would allocate: the
+    weights from the parameter specs (the model's count: the stored pad and
+    the float32 leaves apart), the slot from ``ssm_state_shapes``, the pool
+    from what the application allocates, all as SHAPES."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    from neuronx_distributed_inference_tpu.models import model_base
+    from neuronx_distributed_inference_tpu.modules import ssm
+    from neuronx_distributed_inference_tpu.modules.block_kv_cache import \
+        pool_spec
+    from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
+    cfg = build.load_json("configs", "nemotron-3-nano-30b-a3b.json")
+    memory, serve = cfg["memory"], cfg["serve"]
+    spec = build.build_app(cfg).spec
+    assert (spec.num_moe_layers, spec.num_attn_layers,
+            spec.num_ssm_layers) == (23, 6, 23)
+    assert "".join({"mamba": "M", "moe": "E", "attention": "*"}[b]
+                   for b in spec.layer_blocks) == NEMOTRON_PATTERN
+    m = spec.moe
+    assert (m.num_experts, m.num_held, m.first_expert, m.top_k,
+            m.intermediate_size, m.stored_intermediate, m.glu_style, m.act,
+            m.router_act, m.has_router_bias, m.router_bias_mode,
+            m.normalize_topk, m.topk_norm_eps, m.routed_scaling,
+            m.shared_intermediate, m.n_group) == (
+        128, 16, 0, 6, 1856, 1920, "plain", "relu2", "sigmoid", True,
+        "select", True, 1e-20, 2.5, 3712, 1)
+    s = spec.ssm
+    assert (s.kind, s.d_inner, s.num_heads, s.head_dim, s.d_state,
+            s.n_groups, s.d_conv, s.gated_norm, s.norm_before_gate,
+            s.dt_limit) == ("mamba2", 4096, 64, 64, 128, 8, 4, True, False,
+                            (0.0, float("inf")))
+    assert spec.no_rope and not spec.tie_word_embeddings
+    assert spec.padded_vocab == cfg["vocab_size"] and spec.rms_eps == 1e-5
+    state = ssm.ssm_state_shapes(s, 23, serve["batch_size"],
+                                 jnp.dtype(cfg["dtype"]))
+    assert state == {"conv_x": ((23, 32, 4096, 3), jnp.dtype("bfloat16")),
+                     "conv_bc": ((23, 32, 2048, 3), jnp.dtype("bfloat16")),
+                     "ssm": ((23, 32, 64, 64, 128), jnp.float32)}
+    state_bytes = sum(math.prod(shape) * jnp.dtype(dt).itemsize
+                      for shape, dt in state.values())
+    assert state_bytes == memory["state_bytes"] == \
+        32 * memory["state_slot_bytes"]
+    assert memory["state_slot_bytes"] == 23 * (64 * 64 * 128 * 4
+                                               + 6144 * 3 * 2) == 49_082_368
+    # both kv heads of 128 lanes in ONE slot of 256
+    pool = pool_spec(spec, serve["pa_num_blocks"], serve["pa_block_size"])
+    assert pool.shape == (6, 4097, 32, 1, 256)
+    assert pool.bytes_per_token == memory["kv_bytes_per_token"] == \
+        6 * 2 * 2 * 128 * 2
+    assert 2 * math.prod(pool.shape) * 2 == memory["kv_pool_bytes"]
+    leaves = jax.tree.leaves(model_base.decoder_param_specs(spec),
+                             is_leaf=lambda x: isinstance(x, ParamSpec))
+    mamba, attn, experts = 38_744_896, 23_399_040, 179_948_288
+    pad = 23 * 16 * 2 * 2688 * 64
+    assert sum(math.prod(ps.shape) for ps in leaves) == \
+        memory["stored_parameters"] == memory["parameters"] + pad
+    assert memory["parameters"] == (
+        23 * mamba + 6 * attn + 23 * experts + 2 * 16384 * 2688 + 2688) \
+        == 5_258_420_544
+    weights = sum(math.prod(ps.shape) * jnp.dtype(ps.dtype).itemsize
+                  for ps in leaves)
+    # the program's bytes over the model's all-bf16 count: the pad, and the
+    # routers, selection biases, dt_bias, A_log and D in float32
+    f32 = 23 * (2688 * 128 + 128) + 23 * 3 * 64
+    assert weights == memory["program_weights_bytes"] == \
+        memory["weights_bytes"] + 2 * pad + 2 * f32
+    assert memory["weights_bytes"] == 2 * memory["parameters"]
+    total = (memory["weights_bytes"] + memory["kv_pool_bytes"]
+             + memory["state_bytes"])
+    assert total == memory["before_temps_bytes"]
+    assert 0.80 * 16e9 < total < 0.81 * 16e9
+    assert memory["program_before_temps_bytes"] == total + 2 * pad + 2 * f32
+    # the whole model is four chips' worth of HBM
+    whole = 23 * mamba + 6 * attn + 23 * (experts + 112 * 9_977_856) \
+        + 2 * 131072 * 2688 + 2688
+    assert whole == 31_577_940_288 and 2 * whole > 3.9 * 16e9
+
+
+def test_nemotron3_nano_rooflines_count_what_the_model_needs(monkeypatch):
+    """The cell's two rooflines from a made-up window: the experts' need is
+    the TWO matrices, at the published 1856, of the held experts a step
+    TOUCHED + 23 routers over 128 and shared experts, over the ``moe``
+    scope's time; the mixers' need is 23 layers' weights + each live row's
+    state and tails read and written, over ``mixer``; a configuration of
+    other key names, or a program without the counters, reads nothing - and
+    DeepSeek's three-matrix yardstick WOULD read this file's keys."""
+    from harness import host_spans, readers
+    cfg = build.load_json("configs", "nemotron-3-nano-30b-a3b.json")
+    programs = {"paged.w1": dict(count=100, total_s=1.8)}
+    scopes = {"paged.w1": {"moe": 0.9, "mixer": 0.72, "attn": 0.1}}
+    edge = {"counters": {"kv.live_rows": 32.0}}
+
+    def ctx(counters, config=cfg, slice_=None):
+        return {"config": config, "peaks": {"hbm_gbps": 819.0},
+                "warm_widths": [1, 64, 256],
+                "before": {"counters": {}},
+                "after": {"counters": {"host_stats." + k: v
+                                       for k, v in counters.items()}},
+                "slice": slice_ or {"before": edge, "after": edge},
+                "trace": {"programs": programs, "ops_by_program": {}},
+                "_slice": {"scopes": {
+                    label: dict(programs[label], scopes=scopes[label])
+                    for label in programs}}}
+    monkeypatch.setattr(host_spans, "load_slice", lambda c: c["_slice"])
+    # -- the experts: 50 steps fetched, 12.45 of 16 touched a layer
+    metric = NEMOTRON_METRICS[0]
+    counters = dict(moe_expert_slots=50 * 23 * 16,
+                    moe_experts_touched=round(50 * 23 * 12.45))
+    expert = 2 * 2688 * 1856 * 2
+    fixed = 23 * (2688 * 128 + 128 + 2 * 2688 * 3712) * 2
+    need = 23 * 12.45 * expert + fixed
+    got = readers.read_metric(metric, ctx(counters))
+    assert got == pytest.approx(100 * (need / 819e9) / 9e-3, rel=1e-3)
+    assert need == pytest.approx(6.65e9, rel=5e-3) and 85 < got < 95
+    every = dict(counters, moe_experts_touched=50 * 23 * 16)
+    assert readers.read_metric(metric, ctx(every)) == pytest.approx(
+        100 * ((23 * 16 * expert + fixed) / 819e9) / 9e-3)
+    assert readers.read_metric(metric, ctx({})) is None
+    for key in ("hybrid_override_pattern", "moe_intermediate_size"):
+        assert readers.read_metric(
+            metric, ctx(counters, {k: v for k, v in cfg.items()
+                                   if k != key})) is None
+    # -- the mixers: 23 x (77.5 MB of weights + 32 rows x 2 x 2.13 MB)
+    metric = NEMOTRON_METRICS[1]
+    weights = (2688 * 10304 + 6144 * 5 + 3 * 64 + 4096 + 2688
+               + 4096 * 2688) * 2
+    row = 64 * 64 * 128 * 4 + 6144 * 3 * 2
+    need = 23 * (weights + 32 * 2 * row)
+    got = readers.read_metric(metric, ctx({}))
+    assert got == pytest.approx(100 * (need / 819e9) / 7.2e-3)
+    assert weights == 2 * 38_744_896
+    assert need == pytest.approx(4.92e9, rel=2e-3) and 80 < got < 90
+    assert readers.read_metric(
+        metric, ctx({}, {k: v for k, v in cfg.items()
+                         if k != "mamba_num_heads"})) is None
+    assert readers.read_metric(
+        metric, ctx({}, slice_={"before": None, "after": None})) is None
+    # granite's yardstick reads nothing under these names
+    assert readers.read_metric("kernel.mixer_decode_roofline",
+                               ctx(counters)) is None
+    # ... and these two nothing under another configuration's keys
+    for name in ("granite-4.0-h-micro", "deepseek-v3", "lfm2-8b-a1b"):
+        other = build.load_json("configs", name + ".json")
+        for metric in NEMOTRON_METRICS:
+            assert readers.read_metric(metric, ctx(counters, other)) is None
+
+
+@pytest.mark.parametrize("fault", [None, "bias_weighs", "renorm_dropped",
+                                   "scaling_dropped"])
+def test_the_nemotron_h_toy_gate_and_three_faults_in_the_program(
+        monkeypatch, fault):
+    """The reference, found by name, gates a toy twin through the harness's
+    full-batch prefill (a padded window) and its decode steps; and the other
+    direction of the controls: the PROGRAM broken, the reference sound."""
+    import dataclasses
+
+    from neuronx_distributed_inference_tpu.models.family import get_family
+    from test_nemotron_h_paged import HF, SERVE
+    family = get_family("nemotron_h")
+    build_spec = family.build_spec.__func__
+    broken_moe = {"bias_weighs": {"router_bias_mode": "logits"},
+                  "renorm_dropped": {"normalize_topk": False},
+                  "scaling_dropped": {"routed_scaling": None}}
+
+    def broken(cls, config, tp_degree=None):
+        spec = build_spec(cls, config, tp_degree)
+        return dataclasses.replace(spec, moe=dataclasses.replace(
+            spec.moe, **broken_moe[fault]))
+    if fault:
+        monkeypatch.setattr(family, "build_spec", classmethod(broken))
+    toy = dict(HF, family="nemotron_h", tp=1, dtype="float32", serve=SERVE,
+               adapter={},
+               gate=dict(config={}, batch=2, prompt_len=24, new_tokens=8,
+                         atol=2e-5, rtol=1e-4, min_positions_held=1.0,
+                         median_ratio_max=0.5, worst_ratio_max=1.0,
+                         excuse_margin_max=0.0))
+    res = build.logit_gate(toy, seed=2**31 + 64, served_precision="highest")
     if fault is None:
         assert res["passed"], res
         assert res["compared"] == 2 * 32 * HF["vocab_size"]

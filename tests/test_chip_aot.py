@@ -1543,6 +1543,120 @@ def test_the_widest_lfm2_moe_programs_fit_beside_weights_and_pool(
         < 15.75 * 2 ** 30 - 258e6
 
 
+def _nemotron3_nano_program(v5e_devices, rows, width=None):
+    """A paged step of ``benchmark/configs/nemotron-3-nano-30b-a3b.json``
+    (the file itself: all 52 layers and its serving shape; ``width`` None: its
+    widest chunk bucket) compiled for a v5e, with the engagement records of
+    its trace."""
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import build
+    cfg = build.load_json("configs", "nemotron-3-nano-30b-a3b.json")
+    hf = build.hf_config(cfg)
+    serve = {k: cfg["serve"][k] for k in (
+        "batch_size", "seq_len", "pa_block_size", "pa_num_blocks",
+        "context_encoding_buckets")}
+    shapes = _serving_shapes(hf, 52, 1, v5e_devices[:1], serve, prefix=False)
+    spec, _, _, _, cache, sds, mb = shapes
+    assert (spec.num_moe_layers, spec.num_attn_layers,
+            spec.num_ssm_layers) == (23, 6, 23)
+    assert mb == 128
+    # 2 kv heads of 128 lanes in ONE slot of 256; a state of 64 x (64, 128)
+    assert cache["k"].shape == (6, 4097, 32, 1, 256)
+    assert cache["ssm"].shape == (23, 32, 64, 64, 128)
+    kw = {} if rows == 32 else {"state_slots": sds((rows,), jnp.int32)}
+    program, notes = _compiled_paged_step(
+        shapes, rows, width or max(serve["context_encoding_buckets"]), **kw)
+    return program, notes, program.as_text()
+
+
+#: element counts of what no program of the cell may copy, transpose or
+#: reshape: the KV pool (one of K / V) and an expert leaf's stack, a layer of
+#: it, the state stack and a layer of it (PERF.md section 3's trap: match on
+#: the element count, whatever the shape is called)
+_NEMOTRON_BIG = {6 * 4097 * 32 * 256: "the pool",
+                 23 * 16 * 2688 * 1920: "an expert stack",
+                 16 * 2688 * 1920: "a layer's experts",
+                 23 * 32 * 64 * 64 * 128: "the state stack",
+                 32 * 64 * 64 * 128: "a layer's states"}
+
+
+def _nemotron_movers(text, but=()):
+    out = []
+    for name, shape, op in re.findall(
+            r"%(\S+) = \w+\[([\d,]+)\]\S* (copy|transpose|reshape)\(", text):
+        what = _NEMOTRON_BIG.get(math.prod(map(int, shape.split(","))))
+        if what and what not in but:
+            out.append((name, shape, op, what))
+    return out
+
+
+def test_nemotron3_nano_decodes_32_rows_on_three_kernels_in_place(
+        v5e_devices):
+    """ISSUE 64: ``paged.w1`` of the Nemotron 3 Nano cell at its 32 rows and
+    all 52 single-block layers: six calls of the paged decode kernel (32
+    query / 2 kv heads of 128 in one 256-lane slot), 23 expert layers on the
+    PLAIN walk over the touched experts (two matrices a unit, three column
+    pieces of 640 of the stored 1920), 23 states stepped in place by the
+    state kernel at 8 groups; nothing copies the pool, an expert stack or the
+    state, and the step's temps are small beside 13 GB of arguments."""
+    step, notes, text = _nemotron3_nano_program(v5e_devices, 32, 1)
+    assert ("layer_blocks", "xla", "mamba=23 attention=6 moe=23") in notes
+    assert ("moe_decode", "pallas",
+            "plain pieces=3 of 640 (1856 of 1920 stored)") in notes
+    assert ("moe_share", "xla", "held=16 of 128 from 0 top_k=6") in notes
+    state = [w for s, p, w in notes if s == "recurrent_state"
+             and p == "pallas"]
+    assert state == ["kind=mamba2 slot_bytes=49082368 chunk=64 heads=32 "
+                     "tile=64x128 groups=8"]
+    assert any(s == "paged_decode" and p == "pallas" and "heads=2" in w
+               for s, p, w in notes)
+    assert len(re.findall(r"%paged_decode_attention[.\d]* = ", text)) == 6
+    assert len(re.findall(r"%moe_decode_experts[.\d]* = ", text)) == 23
+    assert len(re.findall(r"%mamba_state_step[.\d]* = ", text)) == 23
+    assert not _nemotron_movers(text), _nemotron_movers(text)
+    memory = step.memory_analysis()
+    assert memory.temp_size_in_bytes < 200e6
+    assert 13.1e9 < memory.argument_size_in_bytes < 13.25e9
+
+
+@pytest.mark.parametrize("rows, temps_under", [(1, 60e6), (32, 1.6e9)],
+                         ids=["chunk", "pack"])
+def test_the_widest_nemotron3_nano_programs_fit_beside_weights_and_pool(
+        v5e_devices, rows, temps_under):
+    """ISSUE 64: the widest chunk (one row of 256) and the widest pack (32
+    rows) of the cell attend on the prefill kernel, run their experts on the
+    plain walk by expert (the chunk) or the grouped matmuls over the stored
+    stack (the pack), scan their mixers in chunks of 64, and fit a v5e:
+    weights 10.79 GB + state 1.57 GB + pool 0.81 GB + temps under 16 GB less
+    what the runtime keeps."""
+    program, notes, text = _nemotron3_nano_program(v5e_devices, rows)
+    assert any(s == "paged_prefill" and p == "pallas" for s, p, _ in notes)
+    assert ("layer_blocks", "xla", "mamba=23 attention=6 moe=23") in notes
+    if rows == 1:
+        assert ("moe_decode", "pallas",
+                "plain pieces=3 of 640 rows=256 by expert in tiles of 128 "
+                "(1856 of 1920 stored)") in notes
+        assert "ragged-dot" not in text and "%moe_chunk_experts" in text
+    else:
+        assert ("moe_ragged", "stacked", "") in notes
+    # (a full-batch pack hands the chunked scan a layer's 32 states, 67 MB,
+    # and puts the last chunk's back: the scan's carry, as granite's pack has
+    # it, 0.1 ms a layer of a program of hundreds; the step and the one-row
+    # chunk move no state at all)
+    movers = _nemotron_movers(text, but=("a layer's states",) * (rows > 1))
+    assert not movers, movers
+    memory = program.memory_analysis()
+    assert memory.temp_size_in_bytes < temps_under
+    assert 13.1e9 < memory.argument_size_in_bytes < 13.25e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2 ** 30 - 258e6
+
+
 def test_without_the_request_nothing_is_interpreted(v5e_devices,
                                                     monkeypatch):
     """The other side of the rule: with interpret mode requested the same

@@ -101,8 +101,10 @@ def test_one_token_in_place_against_the_xla_branch(tile, heads, groups,
     assert heads // plan.heads == blocks
     per_group = heads // groups
     assert plan.heads % per_group == 0 or per_group % plan.heads == 0
+    # (the record names the B / C groups where there are several: ISSUE 64)
     assert ssm.state_kernel_note(spec, x["stack"]) == \
-        f"heads={plan.heads} tile={tile[0]}x{tile[1]}"
+        f"heads={plan.heads} tile={tile[0]}x{tile[1]}" \
+        + (f" groups={groups}" if groups > 1 else "")
     _hold(x, live, keep)
 
 
