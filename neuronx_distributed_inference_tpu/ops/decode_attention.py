@@ -40,6 +40,14 @@ block table's width, and a dead table entry is never read. What lies where:
   slot is computed on. ``pages`` follows the page's bytes, a cap on a
   block's score tile and a cap on unrolled copies (``paged_block_plan``):
   nothing of it is configured.
+* The copies of a call are ONE stream over all its rows (ISSUE 66, as
+  ``ops/mla_decode.py``'s since PR 53): under a row's LAST block the kernel
+  starts the FIRST block of the next live row into the slot that block frees,
+  and that row's grid step awaits it and does not start it again. Slots,
+  semaphores and the count of blocks walked (a block's slot is its parity)
+  carry across grid steps - the grid is stated sequential - a row of length
+  0 neither starts nor awaits a copy, and only the call's first live row
+  starts its own first block (``prefetch=across-rows`` in the note).
 * MXU: a slot is a (tokens x kv heads, lanes of a head) matrix as it lies -
   128 lanes, or 256 - and all heads score in one block-diagonal matmul
   against it (heads of 64 lanes sit two to a 128-lane row:
@@ -77,6 +85,10 @@ PAGED_SCORE_TILE_ELEMENTS = 32 * 1024
 #: most pages of one compute block: their copies are unrolled in the kernel.
 PAGED_BLOCK_PAGES = 16
 
+#: the paged kernels' grid, the rows, runs in turn: slots, semaphores and the
+#: count of blocks walked carry from a row to the next
+_ROWS_IN_TURN = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+
 _NT = (((1,), (1,)), ((), ()))        # (M, K) x (N, K) -> (M, N)
 _NN = (((1,), (0,)), ((), ()))        # (M, K) x (K, N) -> (M, N)
 
@@ -98,14 +110,16 @@ class PagedPlan(NamedTuple):
     form: str = "mxu-blockdiag"     # the inner score loop's shape
 
     def note(self, stored: bool) -> str:
-        """The engagement record's text (``kernel_mode.note``); it ends
-        with where a fold lives: ``stored`` (the pool was allocated so,
+        """The engagement record's text (``kernel_mode.note``): the plan,
+        where a fold lives - ``stored`` (the pool was allocated so,
         ``block_kv_cache.pool_page``) or ``call`` (a pool handed over a
         head a slot, which the call reshapes: a relayout of the whole pool
-        wherever the device tiles the two shapes differently)."""
+        wherever the device tiles the two shapes differently) - and, last,
+        the walk: the rows' blocks are one stream of copies."""
         where = "" if self.fold == 1 else " stored" if stored else " call"
         return (f"pages={self.pages} heads={self.hkv * self.fold} "
-                f"form={self.form} fold={self.fold}{where}")
+                f"form={self.form} fold={self.fold}{where} "
+                "prefetch=across-rows")
 
 
 #: fewer kv rows a token than this (the second-minor extent of a 32-bit
@@ -490,6 +504,70 @@ def dispatch(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                          out_specs=P(dp, mpx, None), check_vma=False)(*args)
 
 
+def _row_walk(sc_ref, r, *, bs: int, mb: int, pages: int):
+    """What the paged kernels walk of row ``r``, from the scalars in SMEM
+    (which hold EVERY row's length and table): its prior length, its compute
+    blocks of ``pages`` pages (0: nothing cached) and ``(first live page,
+    last live page, offset of its table)`` - the window's first page to the
+    last one holding a prior token."""
+    nb = pl.num_programs(0)
+    w = sc_ref[1]
+    pos = sc_ref[2 + r]
+    last_live = jax.lax.div(jnp.maximum(pos - 1, 0), bs)
+    first_live = jnp.where(w > 0, jax.lax.div(jnp.maximum(pos - w, 0), bs), 0)
+    n_pages = jnp.where(pos > 0, last_live - first_live + 1, 0)
+    return (pos, jax.lax.div(n_pages + pages - 1, pages),
+            (first_live, last_live, 2 + nb + r * mb))
+
+
+def _next_live_row(sc_ref, r):
+    """The first row at or after ``r`` with a cached token (the row count:
+    none): a row of length 0 neither starts nor awaits a copy, and the
+    stream of copies skips over it."""
+    nb = pl.num_programs(0)
+    return jax.lax.while_loop(
+        lambda r: jnp.logical_and(r < nb, sc_ref[2 + r] == 0),
+        lambda r: r + 1, r)
+
+
+def _row_stream(sc_ref, count, start, *, bs: int, mb: int, pages: int):
+    """This grid step's place in the call's ONE stream of copies. Returns the
+    row's prior length, its compute blocks, its walk (:func:`_row_walk`) and
+    ``slot_of(i)``, which the block loop calls once a block, BEFORE it awaits
+    block ``i``: it starts the stream's next block - this row's, or under this
+    row's last the next live row's first - into the slot block ``i - 1`` left
+    (``start(walk, block, slot)`` is the kernel's own) and returns block
+    ``i``'s slot, the parity of the blocks walked so far (``count``, SMEM,
+    carried from row to row). Only the call's first live row starts its own
+    first block; the last live row starts nothing."""
+    b = pl.program_id(0)
+    nb = pl.num_programs(0)
+    walk_of = functools.partial(_row_walk, sc_ref, bs=bs, mb=mb, pages=pages)
+    pos, n_blocks, own = walk_of(b)
+    after = _next_live_row(sc_ref, b + 1)
+    then = walk_of(after)[2]        # read only where ``after`` is a row
+
+    @pl.when(b == 0)
+    def _open():
+        count[0] = 0
+        row = _next_live_row(sc_ref, 0)
+        pl.when(row < nb)(lambda: start(walk_of(row)[2], 0, 0))
+
+    done = count[0]                 # blocks the rows before this one walked
+    count[0] = done + n_blocks
+
+    def slot_of(i):
+        slot = jax.lax.rem(done + i, 2)
+        last = i + 1 == n_blocks
+
+        @pl.when(jnp.logical_or(jnp.logical_not(last), after < nb))
+        def _next():
+            start(tuple(jnp.where(last, x, y) for x, y in zip(then, own)),
+                  jnp.where(last, 0, i + 1), 1 - slot)
+        return slot
+    return pos, n_blocks, own, slot_of
+
+
 def _paged_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, *rest,
                   scale: float, bs: int, mb: int, hkv: int, g: int,
                   soft_cap: Optional[float], has_sink: bool,
@@ -497,7 +575,10 @@ def _paged_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, *rest,
     """Ragged PAGED decode attention (reference: the DMA-skipping TKG
     attention over the block layout, attention_base.py:1186-1382 +
     block_kv_cache_manager.py:183-267). One grid step is one ROW; the walk
-    over its KV is a loop inside the step, as long as the row's live pages.
+    over its KV is a loop inside the step, as long as the row's live pages;
+    the rows' blocks are ONE stream of copies (the module docstring): the
+    step starts the next live row's first block under its own last one, and
+    ``count`` (SMEM), the slots and their semaphores carry to the next step.
 
     Scalar prefetch (SMEM): [layer, window, len_0..len_{B-1},
     table_{b=0,j=0}.., table_{B-1,mb-1}]. ``k_hbm`` / ``v_hbm`` are the
@@ -521,35 +602,29 @@ def _paged_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, *rest,
     and the active token joins only if its own flag, one more scalar a row
     behind the table, is set. The walk is the live pages' all the same."""
     if selected:
-        sel_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem = rest
-    else:
-        k_hbm, v_hbm, o_ref, kbuf, vbuf, sem = rest
+        sel_ref, *rest = rest
+    k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, count = rest
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     layer = sc_ref[0]
     w = sc_ref[1]
-    pos = sc_ref[2 + b]
-    last_live = jax.lax.div(jnp.maximum(pos - 1, 0), bs)
-    first_live = jnp.where(w > 0, jax.lax.div(jnp.maximum(pos - w, 0), bs), 0)
-    n_pages = jnp.where(pos > 0, last_live - first_live + 1, 0)
     _, pages, page_rows, d = kbuf.shape
-    n_blocks = jax.lax.div(n_pages + pages - 1, pages)
-    table0 = 2 + nb + b * mb
     exact = _bf16_exact(kbuf.dtype)
     hq, cols = hkv * g, pages * page_rows
 
-    def page_copies(i, slot):
+    def page_copies(walk, i, slot):
+        first, last, table0 = walk
         for p in range(pages):
-            j = first_live + i * pages + p
-            page = sc_ref[table0 + jnp.minimum(j, last_live)]
-            yield p, j <= last_live, (
+            j = first + i * pages + p
+            page = sc_ref[table0 + jnp.minimum(j, last)]
+            yield p, j <= last, (
                 pltpu.make_async_copy(k_hbm.at[layer, page], kbuf.at[slot, p],
                                       sem.at[0, slot]),
                 pltpu.make_async_copy(v_hbm.at[layer, page], vbuf.at[slot, p],
                                       sem.at[1, slot]))
 
-    def start(i, slot):
-        for p, live, (kc, vc) in page_copies(i, slot):
+    def start(walk, i, slot):
+        for p, live, (kc, vc) in page_copies(walk, i, slot):
             @pl.when(live)
             def _fetch():
                 kc.start()
@@ -562,30 +637,28 @@ def _paged_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, *rest,
                 vbuf[slot, p] = jnp.zeros((page_rows, d), vbuf.dtype)
 
     def wait(i, slot):
-        for p, live, (kc, vc) in page_copies(i, slot):
+        for p, live, (kc, vc) in page_copies(own, i, slot):
             @pl.when(live)
             def _landed():
                 kc.wait()
                 vc.wait()
 
+    pos, n_blocks, own, slot_of = _row_stream(sc_ref, count, start, bs=bs,
+                                              mb=mb, pages=pages)
+    first_live = own[0]
     col = jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 1)
     row = jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 0)
     tok = jax.lax.div(col, hkv)
-    own = jax.lax.rem(col, hkv) == jax.lax.div(row, g)
+    mine = jax.lax.rem(col, hkv) == jax.lax.div(row, g)
     q = q_ref[0]
     s_scale = scale * kv_scale if kv_scale is not None else scale
 
     def block(i, carry):
         m_prev, l_prev, acc = carry
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < n_blocks)
-        def _next():
-            start(i + 1, 1 - slot)
-
+        slot = slot_of(i)       # and the stream's next block is started
         wait(i, slot)
         kpos = (first_live + i * pages) * bs + tok
-        valid = jnp.logical_and(own, jnp.logical_and(
+        valid = jnp.logical_and(mine, jnp.logical_and(
             kpos < pos, jnp.logical_or(w == 0, pos - kpos < w)))
         if selected:
             # no window with a selection: block i starts at page i x pages
@@ -600,10 +673,6 @@ def _paged_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, *rest,
         return (m_cur, l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
                 acc * alpha + _split_dot(p, vbuf[slot].reshape(cols, d), _NN,
                                          exact))
-
-    @pl.when(n_blocks > 0)
-    def _first():
-        start(0, 0)
 
     m_prev, l_prev, acc = jax.lax.fori_loop(0, n_blocks, block, (
         jnp.full((hq, 1), NEG_INF, jnp.float32),
@@ -753,9 +822,11 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                 pltpu.VMEM(slot, k_pages.dtype),
                 pltpu.VMEM(slot, v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, d_k), q.dtype),
+        compiler_params=_ROWS_IN_TURN,
         interpret=interpret,
     )(scalars, place(q), place(new_k), place(new_v), sink_in, *sel_in,
       k_pages, v_pages)
@@ -928,7 +999,44 @@ def paged_block_plan(bs: int, hkv: int, g: int, d: int, kv_dtype,
     copies cost 40-140 %. The other cells' geometries under the form they
     keep, same script: OLMoE (16 x 1) 0.823 (80) / 2.243 (88), granite (4
     rows x 8 after the fold) 0.343 (48) / 0.805 (61), olmo-hybrid (32 x 1)
-    1.793 (73) / 5.162 (76)."""
+    1.793 (73) / 5.162 (76).
+
+    The rows' blocks as ONE stream of copies (ISSUE 66; same script, PR 66,
+    parent and change in one call on one v5e: the call alone, 32 rows, ms a
+    call parent | change and the change's % of its bytes; kv rows x query
+    rows a kv row after the fold, pages a block)::
+
+        geometry                         ~2k tokens a row      ~6k
+        command-a-plus (kv-row form, 8)  0.486 | 0.446 (74)    1.191 | 1.157 (85)
+          inside a window of 4096        0.475 | 0.450 (74)    0.853 | 0.825 (80)
+        OLMoE (16 x 1, 4)                0.823 | 0.796 (82)    2.242 | 2.213 (89)
+        granite (4 x 8, 8)               0.337 | 0.321 (51)    0.801 | 0.789 (62)
+        olmo-hybrid (32 x 1, 1)          1.794 | 1.774 (74)    5.150 | 5.142 (77)
+        smallthinker (1 x 28, 16)        0.363 | 0.349 (47)    0.839 | 0.827 (60)
+          a ring of 4096                 0.361 | 0.359 (46)    0.626 | 0.630 (52)
+        keye (1 x 32, 16, a selection)   0.318 | 0.297 (55)    0.700 | 0.675 (73)
+        phi4-flash (1 x 40, 12) at ~5k                         1.297 | 1.233 (83)
+          a ring of 512                                        0.288 | 0.241 (43)
+        nemotron (1 x 32, 16) at ~0.5k   0.139 | 0.136 (16)    ~1k: 0.176 | 0.182 (23)
+
+    The lever is worth 0.4-2.0 us a row (ten 128-lane pairs a row and the
+    kv-row form the most, 28-32 query rows over one kv row the least), not
+    one figure. A ring of three or four slots under a cursor (the latent
+    kernel's) bought nothing - keye at ~6k 0.692 | 0.694 | 0.690 at 2 | 3 | 4
+    slots, phi4-flash 1.239 | 1.219 | 1.210 and its ring 0.247 | 0.232 |
+    0.239, smallthinker 0.832 | 0.840 | 0.839, the clock itself +-2 % from
+    call to call - so two slots and their parity stay. What is left, by the
+    same walk stopped short: the copies and waits ALONE read keye 0.615 (80
+    %), smallthinker 0.618, granite 0.610 at ~6k and phi4-flash 1.214 (85 %);
+    the block's arithmetic with NO copy keye 0.366, granite 0.470,
+    smallthinker 0.523 (28 query rows are not whole sublane tiles, so
+    :func:`_split_dot` takes the six-pass float32 matmul for ``p . V``),
+    phi4-flash 0.689: the whole call is its arithmetic PLUS 0.5-0.8 us a
+    block, the 32 starts and 32 waits of 16 pages, which run on the scalar
+    core in line with the block and hide under nothing; and a row of ONE
+    block (nemotron's, granite's cell) costs ~4 us whatever its copy does.
+    Fewer, larger copies where a row's pages lie side by side in the pool is
+    the lever no clock has met."""
     plan = _blockdiag_plan(bs, hkv, g, d, kv_dtype, mb)
     kv_dtype = jnp.dtype(kv_dtype)
     if (plan.fold > 1 or d != 128 or hkv % PAGED_ROW_TILE
@@ -946,9 +1054,10 @@ def _paged_rows_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, *rest,
                        soft_cap: Optional[float], has_sink: bool,
                        kv_scale: Optional[float], selected: bool):
     """:func:`_paged_kernel`'s walk - one grid step a ROW, its live pages
-    copied by hand ``pages`` a block into one of two slots, the table and
-    lengths in SMEM, dead entries never read, the active token joined in
-    registers - with the block scored kv row by kv row, by phase.
+    copied by hand ``pages`` a block into one of two slots, the rows' blocks
+    ONE stream of copies, the table and lengths in SMEM, dead entries never
+    read, the active token joined in registers - with the block scored kv
+    row by kv row, by phase.
 
     A slot is ``(pages x bs x hkv, d)`` as the pages lie: row ``c`` is token
     ``c // hkv`` of kv row ``c % hkv``. Kv row ``r``'s tokens are every
@@ -973,29 +1082,26 @@ def _paged_rows_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, *rest,
     query attends the token."""
     if selected:
         sel_ref, *rest = rest
-    k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, s_ref = rest
+    (k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, count, m_ref, l_ref, acc_ref,
+     s_ref) = rest
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     layer = sc_ref[0]
     w = sc_ref[1]
-    pos = sc_ref[2 + b]
-    last_live = jax.lax.div(jnp.maximum(pos - 1, 0), bs)
-    first_live = jnp.where(w > 0, jax.lax.div(jnp.maximum(pos - w, 0), bs), 0)
-    n_pages = jnp.where(pos > 0, last_live - first_live + 1, 0)
     hkv, g, d = acc_ref.shape
     page_rows, toks = bs * hkv, pages * bs
-    n_blocks = jax.lax.div(n_pages + pages - 1, pages)
-    table0 = 2 + nb + b * mb
     exact = _bf16_exact(kbuf.dtype)
     s_scale = scale * kv_scale if kv_scale is not None else scale
 
-    def each_page(i, slot, live_do, dead_do=None):
+    def each_page(walk, i, slot, live_do, dead_do=None):
+        first, last, table0 = walk
+
         def page(p, carry):
-            j = first_live + i * pages + p
-            at = sc_ref[table0 + jnp.minimum(j, last_live)]
+            j = first + i * pages + p
+            at = sc_ref[table0 + jnp.minimum(j, last)]
             into = pl.ds(pl.multiple_of(p * page_rows, page_rows), page_rows)
 
-            @pl.when(j <= last_live)
+            @pl.when(j <= last)
             def _live():
                 live_do(pltpu.make_async_copy(
                     k_hbm.at[layer, at], kbuf.at[slot, into], sem.at[0, slot]))
@@ -1003,19 +1109,23 @@ def _paged_rows_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, *rest,
                     v_hbm.at[layer, at], vbuf.at[slot, into], sem.at[1, slot]))
 
             if dead_do is not None:
-                pl.when(j > last_live)(lambda: dead_do(into))
+                pl.when(j > last)(lambda: dead_do(into))
             return carry
         jax.lax.fori_loop(0, pages, page, 0)
 
-    def start(i, slot):
+    def start(walk, i, slot):
         def blank(into):
             # a slot's page past the row's end is computed on (masked): its
             # V must be finite, whatever the slot held before
             vbuf[slot, into, :] = jnp.zeros((page_rows, d), vbuf.dtype)
-        each_page(i, slot, lambda copy: copy.start(), blank)
+        each_page(walk, i, slot, lambda copy: copy.start(), blank)
 
     def wait(i, slot):
-        each_page(i, slot, lambda copy: copy.wait())
+        each_page(own, i, slot, lambda copy: copy.wait())
+
+    pos, n_blocks, own, slot_of = _row_stream(sc_ref, count, start, bs=bs,
+                                              mb=mb, pages=pages)
+    first_live = own[0]
 
     if kbuf.dtype == jnp.bfloat16:
         turns, held = hkv // 2, PAGED_ROW_TILE // 2
@@ -1048,12 +1158,7 @@ def _paged_rows_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, *rest,
     tok = jax.lax.broadcasted_iota(jnp.int32, (1, toks), 1)
 
     def block(i, carry):
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < n_blocks)
-        def _next():
-            start(i + 1, 1 - slot)
-
+        slot = slot_of(i)       # and the stream's next block is started
         kpos = (first_live + i * pages) * bs + tok
         valid = jnp.logical_and(
             kpos < pos, jnp.logical_or(w == 0, pos - kpos < w))
@@ -1082,10 +1187,6 @@ def _paged_rows_kernel(sc_ref, q_ref, nk_ref, nv_ref, sink_ref, *rest,
             acc_ref[r] += _split_dot(s_ref[r], v, _NN, exact)
         each_kv_row(vbuf, slot, sums)
         return carry
-
-    @pl.when(n_blocks > 0)
-    def _first():
-        start(0, 0)
 
     jax.lax.fori_loop(0, n_blocks, block, 0)
 
@@ -1156,6 +1257,7 @@ def _paged_rows_call(scalars, q, new_k, new_v, sink_in, select, k_pages,
                 pltpu.VMEM(slot, k_pages.dtype),
                 pltpu.VMEM(slot, v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((hkv, g, 1), jnp.float32),
                 pltpu.VMEM((hkv, g, 1), jnp.float32),
                 pltpu.VMEM((hkv, g, d), jnp.float32),
@@ -1163,6 +1265,7 @@ def _paged_rows_call(scalars, q, new_k, new_v, sink_in, select, k_pages,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        compiler_params=_ROWS_IN_TURN,
         interpret=interpret,
         name="paged_decode_attention",
     )(scalars, *(x.reshape(b, hkv, g, d) for x in (q, new_k, new_v)),

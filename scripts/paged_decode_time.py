@@ -11,7 +11,17 @@ pages of 32 tokens, bf16, window 0 and 4096, for
   view of row pairs; a loop over kv rows in place of unrolled copies) are in
   ``paged_block_plan``'s docstring with their numbers, not in the code;
 * OLMoE's (16 x 1), granite's (8 heads of 64 under 4 query heads: 4 rows x 8
-  after the fold) and olmo-hybrid's (32 x 1) under the form they keep.
+  after the fold) and olmo-hybrid's (32 x 1) under the form they keep;
+* (ISSUE 66, the walk's rows as ONE stream of copies) the geometries whose
+  rows are a block or two, at the lengths and windows their cells run
+  (:data:`ALONE`, where the flags name none): SmallThinker's and Keye's (4 kv
+  heads of 128 lanes folded to ONE row of 512 under 28 / 32 query rows, window
+  0 and a ring of 4096; Keye's with its learned selection of 2048 tokens a
+  row), phi4-flash's (ten 128-lane pairs folded to one row of 1280 under 40
+  query rows: the shared pool at ~5k tokens a row and a ring of 512) and
+  nemotron's short calls (2 kv heads folded to one row, ~0.5k / ~1k tokens).
+  Parent against change: run this file from a copy of each tree (``git
+  archive`` of the parent, this script laid over it), not a switch here.
 
 Calls run back to back inside ONE program, each depending on the one before
 through the lengths (one host dispatch a call costs more than the kernel, and
@@ -19,13 +29,13 @@ XLA shares one result between identical calls: ROADMAP trap 13). Prints one
 JSON line a case with ms a call and the share of ``paged_decode_min_bytes``
 (``benchmark/harness/kernel_bytes.py``: the live tokens' K and V inside the
 window, the queries and outputs) at the chip's 819 GB/s, then the table, and
-writes all of it to ``chiprun_out/paged_decode_time.json``. A time comes from
+writes all of it to ``chiprun_out/<--out>``. A time comes from
 a chip only: without a TPU it exits 2 (``utils/device.require_tpu``). Run it
 under ``timeout``.
 
     python3 scripts/paged_decode_time.py [--cells command-a-plus,olmoe,..]
         [--forms plan,blockdiag,rows-16] [--tokens 2048,..]
-        [--windows 0,4096] [--calls 12]
+        [--windows 0,4096] [--calls 12] [--out paged_decode_time.json]
 """
 
 from __future__ import annotations
@@ -41,7 +51,18 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BLOCK, ROWS, PEAK_BYTES = 32, 32, 819e9
 #: cell -> (query heads, kv heads, head_dim): tests/test_decode_attention.py
 CELLS = {"command-a-plus": (128, 8, 128), "olmoe": (16, 16, 128),
-         "granite": (32, 8, 64), "olmo-hybrid": (32, 32, 128)}
+         "granite": (32, 8, 64), "olmo-hybrid": (32, 32, 128),
+         "smallthinker": (28, 4, 128), "keye": (32, 4, 128),
+         "phi4-flash": (40, 10, 128), "nemotron": (32, 2, 128)}
+#: cell -> (tokens a row, windows) where ``--tokens`` / ``--windows`` name
+#: none: what its benchmark cell's rows hold and its layer kinds' windows
+ALONE = {"smallthinker": ((2048, 6144), (0, 4096)),
+         "keye": ((2048, 6144), (0, 4096)),
+         "phi4-flash": ((5120,), (0, 512)), "nemotron": ((512, 1024), (0,))}
+TOKENS, WINDOWS = (2048, 6144, 10240), (0, 4096)
+#: cell -> tokens a row's query attends (a learned sparse selection: one more
+#: input of the call, the walk is the live pages' all the same)
+SELECTS = {"keye": 2048}
 
 
 def min_bytes(cell: str, tokens: int, window: int, rows: int = ROWS) -> float:
@@ -57,6 +78,17 @@ def row_lengths(tokens: int, rows: int = ROWS):
     that rows end inside different pages of their last block."""
     import numpy as np
     return (tokens * np.linspace(0.75, 1.25, rows)).astype(np.int64)
+
+
+def selection(rng, lens, topk: int, width: int):
+    """(rows, width) bool: ``topk`` of each row's cached positions, drawn
+    evenly, and the row's own."""
+    import numpy as np
+    select = np.zeros((len(lens), width), bool)
+    for i, n in enumerate(lens):
+        select[i, rng.choice(int(n), min(topk, int(n)), replace=False)] = True
+        select[i, int(n)] = True
+    return select
 
 
 def _set_form(da, form: str, was) -> None:
@@ -98,9 +130,10 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--cells", default=",".join(CELLS))
     ap.add_argument("--forms", default="blockdiag,plan")
-    ap.add_argument("--tokens", default="2048,6144,10240")
-    ap.add_argument("--windows", default="0,4096")
+    ap.add_argument("--tokens", default="")
+    ap.add_argument("--windows", default="")
     ap.add_argument("--calls", type=int, default=12)
+    ap.add_argument("--out", default="paged_decode_time.json")
     a = ap.parse_args(argv)
     from neuronx_distributed_inference_tpu.utils import device
     try:
@@ -116,11 +149,14 @@ def main(argv=None) -> int:
 
     was = (da.paged_block_plan, da.PAGED_SCORE_TILE_ELEMENTS)
     bf = jnp.bfloat16
-    tokens = [int(t) for t in a.tokens.split(",")]
-    mb = -(-(int(max(tokens) * 1.25) + 1) // BLOCK)
     records = []
     for cell in a.cells.split(","):
         hq, hkv, d = CELLS[cell]
+        tokens, windows = ALONE.get(cell, (TOKENS, WINDOWS))
+        tokens = [int(t) for t in a.tokens.split(",")] if a.tokens else tokens
+        windows = ([int(w) for w in a.windows.split(",")] if a.windows
+                   else windows)
+        mb = -(-(int(max(tokens) * 1.25) + 1) // BLOCK)
         fold = da.paged_pool_fold(hkv, d)
         rng = np.random.default_rng(hq)
         table = jnp.asarray(
@@ -134,7 +170,7 @@ def main(argv=None) -> int:
         nk, nv = (jax.random.normal(k, (ROWS, hkv, d), bf) for k in keys[3:])
         forms = a.forms.split(",") if cell == "command-a-plus" else ["plan"]
         for form in forms:
-            for window in (int(w) for w in a.windows.split(",")):
+            for window in windows:
                 for n in tokens:
                     _set_form(da, form, was)
                     jax.clear_caches()
@@ -145,6 +181,11 @@ def main(argv=None) -> int:
                     rec = dict(cell=cell, form=form, note=plan.note(fold > 1),
                                tokens=n, window=window)
                     lens = jnp.asarray(row_lengths(n), jnp.int32)
+                    if cell in SELECTS and not window:
+                        fn = functools.partial(fn, select=jnp.asarray(
+                            selection(rng, row_lengths(n), SELECTS[cell],
+                                      mb * BLOCK)))
+                        rec["select"] = SELECTS[cell]
                     try:
                         ms = _clock(fn, (q, kp, vp, nk, nv, lens, table),
                                     a.calls)
@@ -158,18 +199,18 @@ def main(argv=None) -> int:
                     print(json.dumps(rec), flush=True)
     _set_form(da, "plan", was)
     print(f"{'cell':>15} {'form':>16} {'window':>6}  "
-          + "  ".join(f"{n:>6} tok" for n in tokens) + "   ms a call (% of bytes)")
+          "tokens a row: ms a call (% of bytes)")
     seen = []
     for r in records:
         key = (r["cell"], r["form"], r["window"])
         if key not in seen:
             seen.append(key)
             print(f"{key[0]:>15} {key[1]:>16} {key[2]:>6}  " + "  ".join(
-                f"{x.get('ms', float('nan')):>6.3f} ({x.get('share', 0):>4.1f})"
-                for x in records
+                f"{x['tokens']:>5}: {x.get('ms', float('nan')):>6.3f} "
+                f"({x.get('share', 0):>4.1f})" for x in records
                 if (x["cell"], x["form"], x["window"]) == key))
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/paged_decode_time.json", "w") as f:
+    with open(os.path.join("chiprun_out", a.out), "w") as f:
         json.dump(records, f, indent=1)
     return 0
 

@@ -377,7 +377,8 @@ def test_heads_of_64_two_to_a_slot_serve_the_same_tokens_and_bytes(
     # keeps the gather, and says so
     assert kernels == {
         "kv_pool": "page=4x128 heads=8x64",
-        "paged_decode": "pages=8 heads=8 form=mxu-blockdiag fold=2 stored",
+        "paged_decode": "pages=8 heads=8 form=mxu-blockdiag fold=2 stored "
+                        "prefetch=across-rows",
         "chunks": ({("xla", "pool stored as float8_e4m3fn")}
                    if "kv_cache_quant" in kv else
                    {("pallas-interpret",
@@ -388,7 +389,8 @@ def test_heads_of_64_two_to_a_slot_serve_the_same_tokens_and_bytes(
     plain = paged_app()
     assert plain.cache["k"].shape[3:] == (8, 64)
     want, kernels, plain_pool = serve(plain)
-    assert kernels["paged_decode"].endswith("fold=2 call")
+    assert kernels["paged_decode"].endswith(
+        "fold=2 call prefetch=across-rows")
     assert {path for path, _ in kernels["chunks"]} == {"xla"}
     assert got == want
     for k in "kv":

@@ -245,7 +245,8 @@ def test_heads_that_share_a_slot_leave_the_pool_where_it_is(
     assert MOSAIC in text
     if kernel:
         want.add(("paged_decode", "pallas",
-                  f"{kernel} form=mxu-blockdiag fold=2 stored"))
+                  f"{kernel} form=mxu-blockdiag fold=2 stored "
+                  "prefetch=across-rows"))
     else:
         # the chunk and the pack walk the stored page on the prefill kernel
         want.add(("paged_prefill", "pallas",
@@ -425,7 +426,8 @@ def test_30_kv_heads_of_128_decode_on_the_kernel_with_no_pool_copy(
     assert notes == {
         ("recurrent_state", "pallas", f"{state} heads=30 tile=96x192"),
         pool, ("paged_decode", "pallas",
-               "pages=1 heads=32 form=mxu-blockdiag fold=1")}
+               "pages=1 heads=32 form=mxu-blockdiag fold=1 "
+               "prefetch=across-rows")}
     text = step.as_text()
     assert MOSAIC in text
     moves = re.findall(
@@ -510,7 +512,8 @@ def test_2_kv_heads_of_256_decode_on_the_kernel_with_no_pool_copy(
         ("recurrent_state", "pallas", f"{state} heads=32 tile=128x128"),
         share, pool, (
         "paged_decode", "pallas",
-        "pages=16 heads=2 form=mxu-blockdiag fold=2 stored"),
+        "pages=16 heads=2 form=mxu-blockdiag fold=2 stored "
+        "prefetch=across-rows"),
         ("moe_decode", "pallas", "pieces=1 of 512")}
     text = step.as_text()
     assert MOSAIC in text and "ragged-dot" not in text
@@ -945,8 +948,10 @@ def test_smallthinker_decode_walks_both_pools_in_place(v5e_devices):
     assert ("moe_decode", "pallas", "pieces=1 of 768") in notes
     assert {(s, p, w.split(" fold")[1]) for s, p, w in notes
             if s == "paged_decode"} == {
-        ("paged_decode", "pallas", "=4 stored window=0"),
-        ("paged_decode", "pallas", "=4 stored window=4096 ring=137")}
+        ("paged_decode", "pallas",
+         "=4 stored prefetch=across-rows window=0"),
+        ("paged_decode", "pallas",
+         "=4 stored prefetch=across-rows window=4096 ring=137")}
     assert any(s == "kv_window_pool" and "global=2 window=6" in w
                and "ring_pages=137" in w for s, _, w in notes)
     text = step.as_text()
@@ -1069,6 +1074,7 @@ def test_keye_vl2_decodes_over_three_pools_in_place(v5e_devices):
     assert ("moe_share", "xla", "held=16 of 128 from 0 top_k=8") in notes
     assert any(s == "moe_decode" and p == "pallas" for s, p, _ in notes)
     assert any(s == "paged_decode" and p == "pallas" and "heads=4" in w
+               and w.endswith("fold=4 stored prefetch=across-rows")
                for s, p, w in notes), notes
     assert ("sparse_attn", "pallas",
             "masked: the live pages' walk, a token attended where selected, "
@@ -1175,7 +1181,8 @@ def test_phi4_flash_decodes_over_both_pools_and_the_state_in_place(
     placed in halves of a kv row; nothing copies, transposes or relays a
     pool, and the state is stepped where it lies."""
     step, notes, spec = _phi4_flash_program(v5e_devices, 32, 1)
-    plan = "pages=12 heads=10 form=mxu-blockdiag fold=10 stored"
+    plan = ("pages=12 heads=10 form=mxu-blockdiag fold=10 stored "
+            "prefetch=across-rows")
     diff = " form+=diff pairs placed in halves of a kv row"
     assert {w for s, p, w in notes if s == "paged_decode"
             and p == "pallas"} == {
@@ -1270,7 +1277,8 @@ def test_phi4_flash_chunk_stops_before_the_second_decoder(v5e_devices):
         plan + " window=0" + diff,
         plan + " window=512 ring=25" + diff}
     assert {w for s, p, w in notes if s == "paged_decode"} == {
-        "pages=12 heads=10 form=mxu-blockdiag fold=10 stored window=0" + diff
+        "pages=12 heads=10 form=mxu-blockdiag fold=10 stored "
+        "prefetch=across-rows window=0" + diff
         + " cross: no write, another layer's pool"}
     assert ("second_decoder", "xla",
             "apart: layers 6-7, the head and the draw on one token a row of "
@@ -1402,9 +1410,11 @@ def test_command_a_plus_decodes_on_three_kernels_in_place(v5e_devices):
             "shared=4 x 4096 mean") in notes
     assert {(s, p, w) for s, p, w in notes if s == "paged_decode"} == {
         ("paged_decode", "pallas",
-         "pages=8 heads=8 form=mxu-kv-rows fold=1 window=0"),
+         "pages=8 heads=8 form=mxu-kv-rows fold=1 prefetch=across-rows "
+         "window=0"),
         ("paged_decode", "pallas",
-         "pages=8 heads=8 form=mxu-kv-rows fold=1 window=4096 ring=137")}
+         "pages=8 heads=8 form=mxu-kv-rows fold=1 prefetch=across-rows "
+         "window=4096 ring=137")}
     assert ("kv_pool", "xla", "page=8x128 heads=8x128") in notes
     assert any(s == "kv_window_pool" and "global=1 window=3" in w
                and "window_tokens=4096 ring_pages=137" in w

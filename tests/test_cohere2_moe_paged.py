@@ -190,8 +190,9 @@ def test_a_five_chunks_then_decode_on_the_kernels(ref, gate_weights,
     _check(tap, ref, gate_weights, 7, P150, stream)
     notes = _kernels(app)
     assert {(p, r.split(" stored ")[1]) for p, r in notes["paged_decode"]} \
-        == {("pallas-interpret", "window=0"),
-            ("pallas-interpret", f"window=16 ring={RING}")}
+        == {("pallas-interpret", "prefetch=across-rows window=0"),
+            ("pallas-interpret",
+             f"prefetch=across-rows window=16 ring={RING}")}
     assert notes["paged_prefill"] == {
         ("pallas-interpret", "rows=1 width=32 pages=16 heads=4 fold=2 "
          "tile=4x32 window=0"),

@@ -539,7 +539,7 @@ def test_paged_decode_walks_live_pages(rng, cell, case):
 def test_the_plan_of_every_cells_geometry(cell, hq, hkv, d, mb, plan, note):
     got = da.paged_block_plan(_BS, hkv, hq // hkv, d, jnp.bfloat16, mb)
     assert tuple(got[:5]) == plan
-    assert got.note(stored=True) == note
+    assert got.note(stored=True) == note + " prefetch=across-rows"
     assert (got.form == da.PAGED_ROWS_FORM) == (
         got.fold == 1 and got.d == 128 and got.hkv % da.PAGED_ROW_TILE == 0
         and got.g % da.PAGED_ROW_TILE == 0)

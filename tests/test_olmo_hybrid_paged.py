@@ -413,7 +413,8 @@ def test_18_heads_in_a_pool_of_32_serve_the_references_logits(ref):
     kernels = {(k["site"], k["path"]): k["reason"]
                for k in app.warmup_state()["kernels"]}
     assert kernels["paged_decode", "pallas-interpret"] == \
-        "pages=8 heads=32 form=mxu-blockdiag fold=2 stored"
+        "pages=8 heads=32 form=mxu-blockdiag fold=2 stored " \
+        "prefetch=across-rows"
 
 
 # ---------------------------------------------------------------------------

@@ -361,7 +361,8 @@ def test_2_kv_heads_of_128_in_one_slot_serve_the_references_logits(ref):
     kernels = {(k["site"], k["path"]): k["reason"]
                for k in app.warmup_state()["kernels"]}
     assert kernels["paged_decode", "pallas-interpret"] == \
-        "pages=16 heads=2 form=mxu-blockdiag fold=2 stored"
+        "pages=16 heads=2 form=mxu-blockdiag fold=2 stored " \
+        "prefetch=across-rows"
 
 
 # ---------------------------------------------------------------------------

@@ -200,8 +200,8 @@ def test_a_five_chunks_then_decode_on_the_kernel(ref, gate_weights,
     notes = _kernels(app)
     assert {(k["path"], k["reason"].split(" stored ")[1])
             for k in notes["paged_decode"]} == {
-        ("pallas-interpret", "window=0"),
-        ("pallas-interpret", f"window=16 ring={RING}")}
+        ("pallas-interpret", "prefetch=across-rows window=0"),
+        ("pallas-interpret", f"prefetch=across-rows window=16 ring={RING}")}
     # ... and every chunk on the prefill kernel (ISSUE 49): a global layer
     # over the allocator's table, a window layer over its ring - which the
     # five chunks of 32 (twice the window) wrap before the first decode step
