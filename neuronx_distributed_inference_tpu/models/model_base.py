@@ -91,7 +91,13 @@ class MLASpec:
 
     ``q_scale`` / ``kv_scale``: LongCat-Flash's ``mla_scale_q_lora`` /
     ``mla_scale_kv_lora``, ``sqrt(hidden / rank)`` on the whole query and on
-    the normed latent; 1.0 = DeepSeek."""
+    the normed latent; 1.0 = DeepSeek.
+
+    ``head_gate``: ONE sigmoid gate a head on the attention's output, read
+    off the block's normed input by a projection of its own (``g_proj``,
+    hidden -> heads) and applied before ``o_proj`` (Ling-3.0's
+    ``gated_attention_proj_granularity_type`` ``head_wise``; the element-wise
+    gate out of a doubled q projection is ``DecoderSpec.attn_out_gate``)."""
 
     kv_lora_rank: int
     qk_nope_head_dim: int
@@ -100,6 +106,7 @@ class MLASpec:
     q_lora_rank: Optional[int] = None
     q_scale: float = 1.0
     kv_scale: float = 1.0
+    head_gate: bool = False
 
     @property
     def qk_head_dim(self) -> int:
@@ -515,6 +522,8 @@ def _attn_param_specs(spec: DecoderSpec, L: int) -> Dict[str, ParamSpec]:
         layers["kv_b_proj"] = column_parallel(
             m.kv_lora_rank, nh * (m.qk_nope_head_dim + m.v_head_dim), dt, True, L)
         layers["o_proj"] = row_parallel(nh * m.v_head_dim, H, dt, True, L)
+        if m.head_gate:
+            layers["g_proj"] = column_parallel(H, nh, dt, True, L)
     else:
         # q/k/v fused into ONE stacked weight: a decode step is a GEMV per
         # weight — one (H, q+2kv) matmul streams the bytes at a higher
@@ -1941,6 +1950,14 @@ def _attn_body(spec: DecoderSpec, h, layer_w, k_full, v_full, li, ai,
     if spec.attn_out_gate and spec.mla is None:
         attn_out = (attn_out * jax.nn.sigmoid(
             out_gate[0].astype(jnp.float32))).astype(attn_out.dtype)
+    if spec.mla is not None and spec.mla.head_gate:
+        # one gate a head, off the block's normed input
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "bth,hn->btn", h, layer_w["g_proj"],
+            preferred_element_type=jnp.float32))
+        attn_out = (attn_out.reshape(gate.shape + (-1,))
+                    * gate[..., None]).astype(attn_out.dtype).reshape(
+                        attn_out.shape)
     h = _row_parallel_out(spec, attn_out, layer_w["o_proj"], phase)
     if spec.mla is None:
         h = apply_lora(spec.lora, layer_w, "o_proj", attn_out, h, adapter_ids)
@@ -1968,14 +1985,16 @@ def _deepstack_add(hidden, deepstack, deepstack_mask):
     return hidden + jnp.where(deepstack_mask[..., None], img, 0)
 
 
-#: what a recurrent/hybrid stack (``spec.ssm``) cannot do, by mechanism —
+#: what a recurrent/hybrid stack (``spec.ssm``) cannot do, by mechanism (its
+#: attention layers may keep K / V pages or, under ``spec.mla``, a latent pool
+#: beside the state slots: the table holds for both) —
 #: the ONE table the model code (``run_layers``, ``run_layers_ssm``, the
 #: verify / ragged / multi-token steps), ``spec_from_config`` and the serving
 #: adapter refuse from, through :func:`refuse_recurrent`.
 RECURRENT_UNSUPPORTED = {
     "prefix caching": "reusing a cached prefix needs a snapshot of the "
-                      "recurrent state at the block boundary; only KV is "
-                      "kept per block",
+                      "recurrent state at the block boundary; only KV (or "
+                      "a latent pool's rows) is kept per block",
     "speculation": "verifying a draft window needs a state step over "
                    "several tokens that can be rolled back to the accepted "
                    "one",
@@ -2001,8 +2020,9 @@ RECURRENT_UNSUPPORTED = {
                              "(ssm_parallel) has not been walked on the "
                              "paged path",
     "paged rglru state": "the rglru block prefills from zero; only the "
-                         "mamba2, gated_delta, mamba1 and shortconv kinds "
-                         "continue from a carried state and conv tail",
+                         "mamba2, gated_delta, kda, mamba1 and shortconv "
+                         "kinds continue from a carried state and conv "
+                         "tail",
     "host KV spill / handoff": "a spilled or handed-off block carries KV "
                                "only, not the state that goes with it",
     "contiguous decoder-hybrid-decoder": "layers that read another layer's "
@@ -2830,6 +2850,12 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
                 f"{n_slots} state slots needs state_slots "
                 "(one slot index a row); without it row i is slot i")
     rm = spec.residual_multiplier
+    # a chunk of kind kda pins the stacks after each layer's write: without
+    # it the compiler reads a later layer's rows from the stack AS IT CAME
+    # IN (a slice of an update elsewhere is a slice of the original), and a
+    # full-batch pack copied the conv tails' whole stack (70 MB at 64 slots
+    # of 12288 channels) twice a layer, 30 copies a program by AOT (PR 67)
+    pin_state = paged and hidden.shape[1] > 1 and s.kind == "kda"
 
     def add(res, branch):
         branch = _shard(branch, AXIS_DP, None, None)
@@ -2944,6 +2970,8 @@ def run_layers_ssm(spec: DecoderSpec, params, cache, hidden, ai,
                         v2.stack if isinstance(v2, ssm_mod.StateStack)
                         else _state_put(new_state[k2], ssm_i, state_slots,
                                         v2))
+                if pin_state:
+                    new_state = jax.lax.optimization_barrier(new_state)
             t_out = s_out if t_out is None else t_out + s_out
             ssm_i += 1
         if post_norm:

@@ -27,6 +27,8 @@ channels/heads on the model axis):
   gated_delta: conv_x (Ls,B,2·nkh·d_k + nh·d_v,K-1) over [q|k|v] (nkh key
           heads, each serving nh/nkh value heads),
           ssm (Ls,B,nh,d_k,d_v) fp32
+  kda:    conv_x (Ls,B,K-1,2·nh·d_k + nh·d_v) over [q|k|v], time-major as
+          mamba1's, ssm (Ls,B,nh,d_k,d_v) fp32 (the gated delta rule's tile)
   mamba1: conv_x (Ls,B,K-1,d_inner) (time-major: the channels fill the
           lanes), ssm (Ls,B,N,d_inner) fp32 (the state transposed so that
           its 16 values a channel lie on sublanes and the channels on lanes:
@@ -63,12 +65,18 @@ class SSMSpec:
       HF Qwen3NextGatedDeltaNet, Olmo-Hybrid's ``linear_attention`` layers):
       a per-head (d_k, d_v) matrix state that is decayed, read back through
       the key and corrected.
+    kind "kda": the delta rule gated BY CHANNEL (Kimi Delta Attention,
+      arXiv:2510.26692; Ling-3.0's linear layers): the same (d_k, d_v) matrix
+      state a head, its decay a vector over d_k a head a token, ``g =
+      decay_lower_bound * sigmoid(exp(A_log) * (W_a x + dt_bias))`` (full
+      rank, bounded below), ``S = (I - beta k k^T) diag(exp g) S0 + beta k
+      v^T``; the output norm by head, then ONE sigmoid gate a head.
     kind "mamba1": Mamba's per-channel selective scan (a (d_state,) state a
       channel, the step size and B / C read off the convolved input through a
       low-rank projection of ``dt_rank``): ``num_heads`` / ``head_dim`` unused.
     """
 
-    # "mamba2" | "rglru" | "shortconv" | "gated_delta" | "mamba1"
+    # "mamba2" | "rglru" | "shortconv" | "gated_delta" | "kda" | "mamba1"
     kind: str
     d_inner: int              # mamba d_ssm / rglru lru_width / delta nh * d_v
     num_heads: int            # mamba_n_heads / rglru num_attention_heads
@@ -91,6 +99,11 @@ class SSMSpec:
     num_key_heads: int = 0
     # mamba1: the rank the step size is projected through
     dt_rank: int = 0
+    # kda: the bound of the log decay a token (the published
+    # ``kda_lower_bound``, -5: ``g`` in [bound, 0] by channel). The chunked
+    # form is exact in float32 only where ``chunk_size * |bound| / 2`` stays
+    # under the exponent float32 holds (:func:`_kda_chunked`)
+    decay_lower_bound: float = 0.0
 
     @property
     def bc_size(self) -> int:
@@ -173,6 +186,23 @@ def ssm_param_specs(s: SSMSpec, hidden: int, Ls: int, dtype) -> Dict[str, ParamS
             "gdn_norm": ParamSpec((Ls, s.head_dim), P(), dtype, "ones"),
             "gdn_out": ParamSpec((Ls, s.d_inner, hidden), P(), dtype),
         }
+    if s.kind == "kda":
+        # replicated, as gated_delta's. [q|k|v] is ONE projection; the decay's
+        # full-rank projection and [beta | head gate] keep float32 outputs of
+        # their own (the decay compounds over a row's whole past)
+        conv = s.qkv_size
+        return {
+            "kda_in": ParamSpec((Ls, hidden, conv), P(), dtype),
+            "kda_in_a": ParamSpec((Ls, hidden, s.qk_size), P(), dtype),
+            "kda_in_bg": ParamSpec((Ls, hidden, 2 * s.num_heads), P(), dtype),
+            "kda_conv": ParamSpec((Ls, conv, s.d_conv), P(), dtype),
+            "kda_dt_bias": ParamSpec((Ls, s.qk_size), P(), jnp.float32,
+                                     "zeros"),
+            "kda_A_log": ParamSpec((Ls, s.num_heads), P(), jnp.float32,
+                                   "zeros"),
+            "kda_norm": ParamSpec((Ls, s.head_dim), P(), dtype, "ones"),
+            "kda_out": ParamSpec((Ls, s.d_inner, hidden), P(), dtype),
+        }
     if s.kind == "shortconv":
         W = s.d_inner
         specs = {
@@ -226,6 +256,14 @@ def ssm_state_shapes(s: SSMSpec, Ls: int, batch: int, dtype
             "ssm": ((Ls, batch, s.num_heads, s.d_state, s.head_dim),
                     jnp.float32),
         }
+    if s.kind == "kda":
+        # the tail time-major (the channels fill the lanes: as (channels,
+        # K - 1) the device pads 3 lanes to 128, 3 GB at 64 slots x 15 layers)
+        return {
+            "conv_x": ((Ls, batch, K1, s.qkv_size), dtype),
+            "ssm": ((Ls, batch, s.num_heads, s.d_state, s.head_dim),
+                    jnp.float32),
+        }
     if s.kind == "mamba1":
         return {
             "conv_x": ((Ls, batch, K1, s.d_inner), dtype),
@@ -263,7 +301,7 @@ def ssm_state_pspecs(s: SSMSpec) -> Dict[str, P]:
             "conv_bc": P(None, AXIS_DP, None, None),
             "ssm": P(None, AXIS_DP, AXIS_MP, None, None),
         }
-    if s.kind == "gated_delta":
+    if s.kind in ("gated_delta", "kda"):
         return {"conv_x": P(None, AXIS_DP, None, None),
                 "ssm": P(None, AXIS_DP, None, None, None)}
     if s.kind == "mamba1":
@@ -524,17 +562,22 @@ def state_kernel_declined(s: SSMSpec, stack, rows: int, tokens: int,
     """Why a step of ``rows`` rows of ``tokens`` tokens over the ``"ssm"``
     stack does not run on the state-step kernel ("" = it does), from what
     the step shows and from nothing else: the state kind, one token a row,
-    the rows being the slots, a tile the kernel takes. Two rules share the
+    the rows being the slots, a tile the kernel takes. Three rules share the
     walk (``ops/delta_state_step.py`` ``walk_state_blocks``): the gated
-    delta rule's, and Mamba-2's (``ops/mamba_state_step.py``); each names
-    what it declines of its own. The walk over the layers asks ONCE a
-    program and hands :func:`gated_delta_mixer` / :func:`mamba2_mixer` a
-    :class:`StateStack` or its rows accordingly."""
+    delta rule's with one decay a head, the same rule with a decay by
+    channel (kind ``kda``), and Mamba-2's (``ops/mamba_state_step.py``);
+    each names what it declines of its own. The walk over the layers asks ONCE a
+    program and hands :func:`gated_delta_mixer` / :func:`kda_mixer` /
+    :func:`mamba2_mixer` a :class:`StateStack` or its rows accordingly."""
     if s.kind == "gated_delta":
         why = delta_state_step.declined(stack, rows, tokens, s.key_heads,
                                         state_slots)
         # a chunk's record names what solves its triangular system
         return f"{why}, {SOLVE_NOTE}" if tokens > 1 else why
+    if s.kind == "kda":
+        why = delta_state_step.declined(stack, rows, tokens, s.num_heads,
+                                        state_slots)
+        return f"{why}, {KDA_CHUNK_NOTE}" if tokens > 1 else why
     if s.kind == "mamba2":
         return mamba_state_step.declined(stack, rows, tokens, s.n_groups,
                                          state_slots)
@@ -544,10 +587,12 @@ def state_kernel_declined(s: SSMSpec, stack, rows: int, tokens: int,
 
 
 def state_kernel_note(s: SSMSpec, stack) -> str:
-    """The record's text for a step the kernel takes (groups: of several)."""
+    """The record's text for a step the kernel takes (groups: of several;
+    how the decay rides: one a head, or by channel)."""
     if s.kind != "mamba2":
         return delta_state_step.state_step_plan(
-            stack.shape[2], s.key_heads, *stack.shape[3:]).note()
+            stack.shape[2], s.key_heads, *stack.shape[3:]).note() + (
+                " decay=channel" if s.kind == "kda" else "")
     groups = f" groups={s.n_groups}" * (s.n_groups > 1)
     return mamba_state_step.mamba_step_plan(
         stack.shape[2], s.n_groups, *stack.shape[3:]).note() + groups
@@ -809,6 +854,223 @@ def gated_delta_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *,
         new_state
 
 
+
+# ---------------------------------------------------------------------------
+# The delta rule gated by channel (Kimi Delta Attention) - Ling-3.0 flavor
+# ---------------------------------------------------------------------------
+
+#: the engagement record's words for how a chunk of kind ``kda`` is exact
+KDA_CHUNK_NOTE = ("decay by channel factored about the middle of a chunk, "
+                  "blocked substitution")
+#: tokens (rows x width) :func:`kda_mixer` runs at once: the block holds
+#: about twenty float32 arrays of (heads, d_k) a token (0.3 MB a token at 32
+#: heads of 128), so a full-batch pack of 64 rows x 256 tokens goes 8 rows
+#: at a time, one group after another (3.8 GB of temps all at once by AOT,
+#: PR 67: the pack did not fit beside 12.6 GB of arguments with room to
+#: spare)
+KDA_GROUP_TOKENS = 2048
+#: the largest exponent :func:`_kda_chunked` may form on either side of a
+#: chunk's middle: exp(80) is finite in float32 (exp(88.7) is not), and the
+#: product of two such factors of a masked pair still is
+KDA_SAFE_EXPONENT = 40.0
+
+
+def kda_chunk_tokens(lower_bound: float, chunk: int = 64) -> int:
+    """Tokens a chunk of :func:`_kda_chunked` may hold under the decay's
+    bound: the largest power of two whose half, times ``|lower_bound|``, stays
+    under :data:`KDA_SAFE_EXPONENT` (16 at the published -5), at most
+    ``chunk``. An unbounded decay (0) has no safe chunk and is refused."""
+    if not lower_bound < 0:
+        raise ValueError(
+            f"kda with decay_lower_bound {lower_bound}: the chunked form is "
+            "exact in float32 only under a negative bound of the log decay")
+    n = 2
+    while n * 2 <= chunk and n * abs(lower_bound) <= KDA_SAFE_EXPONENT:
+        n *= 2
+    return n
+
+
+def _kda_step(q, k, v, g, beta, st0):
+    """One token of the delta rule gated by channel. q, k, g (log decay)
+    (B,H,dk), v (B,H,dv), beta (B,H), st0 (B,H,dk,dv), all float32: ``S' =
+    diag(exp g) S0``, ``S = S' + beta k (v - S'^T k)^T``, ``o = S^T q = S'^T
+    q + (k . q) delta``. The decay lands BEFORE the read through k."""
+    s1 = jnp.exp(g)[..., None] * st0
+    mem_k = jnp.sum(s1 * k[..., :, None], axis=-2)                # (B,H,dv)
+    mem_q = jnp.sum(s1 * q[..., :, None], axis=-2)
+    delta = beta[..., None] * (v - mem_k)
+    kq = jnp.sum(k * q, axis=-1, keepdims=True)
+    return mem_q + kq * delta, s1 + k[..., :, None] * delta[..., None, :]
+
+
+def _kda_chunked(q, k, v, g, beta, st0, chunk: int):
+    """:func:`_kda_step`'s recurrence over T tokens in chunks (the WY / UT
+    form of :func:`_delta_chunked`, the decay a vector over d_k). q, k, g
+    (B,T,H,dk), v (B,T,H,dv), beta (B,T,H), float32; a position with ``g =
+    0`` and ``beta = 0`` (padding) leaves the state as it was. Returns ``(o
+    (B,T,H,dv), S (B,H,dk,dv))``.
+
+    With ``G_i`` the cumulative log decay inside a chunk, the pair weights
+    ``sum_d k_i[d] k_j[d] exp(G_i[d] - G_j[d])`` (i >= j) are one matmul only
+    if the exponent is split, ``(k_i e^{G_i - R}) . (k_j e^{R - G_j})``, and
+    a factor overflows float32 once ``|G - R|`` passes ~88. ``R`` is the
+    chunk's MIDDLE row's ``G`` and ``chunk`` is what
+    :func:`kda_chunk_tokens` allows under the bound of ``g``, so either
+    factor's exponent stays under :data:`KDA_SAFE_EXPONENT` and the split is
+    exact arithmetic, no clamp. Everything else is the scalar rule's algebra
+    with ``exp(G)`` by channel: ``(I + M) [U | W] = [beta v | beta k
+    exp(G)]`` by :func:`_unit_lower_solve`, ``V = U - W S``, ``o = (q
+    exp(G)) S + tril(pair weights of q, k) V``, ``S <- diag(exp(G_last)) S +
+    (k exp(G_last - G))^T V``."""
+    B, T, H, dv = v.shape
+    hi = jax.lax.Precision.HIGHEST
+    cs = min(chunk, T)
+    pad = (-T) % cs
+    nc = (T + pad) // cs
+
+    def chunks(a):                      # (B,T,H,...) -> (nc,B,H,cs,...)
+        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        a = a.reshape((B, nc, cs) + a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+
+    qc, kc, vc, bc = chunks(q), chunks(k), chunks(v), chunks(beta)
+    gc = jnp.cumsum(chunks(g), axis=-2)                     # (nc,B,H,cs,dk)
+    mid = max(cs // 2 - 1, 0)
+    ref = gc[..., mid:mid + 1, :]
+    rise, fall = jnp.exp(gc - ref), jnp.exp(ref - gc)       # the i / j sides
+    kb = kc * bc[..., None]
+    k_fall = kc * fall
+    lower = jnp.tril(jnp.ones((cs, cs), bool))
+    m = jnp.where(jnp.tril(lower, -1),
+                  jnp.einsum("...ik,...jk->...ij", kb * rise, k_fall,
+                             precision=hi), 0.0)
+    decay_in = jnp.exp(gc)
+    uw = _unit_lower_solve(
+        m, jnp.concatenate([vc * bc[..., None], kb * decay_in], axis=-1))
+    qk = jnp.where(lower, jnp.einsum("...ik,...jk->...ij", qc * rise, k_fall,
+                                     precision=hi), 0.0)
+    g_last = gc[..., -1, :]                                 # (nc,B,H,dk)
+    k_out = kc * jnp.exp(g_last[..., None, :] - gc)
+
+    def chunk_body(st, inp):                                # (B,H,dk,dv)
+        u, w, qk_i, q_i, k_i, gl = inp
+        v_new = u - jnp.einsum("bhck,bhkv->bhcv", w, st, precision=hi)
+        o = (jnp.einsum("bhck,bhkv->bhcv", q_i, st, precision=hi)
+             + jnp.einsum("bhcs,bhsv->bhcv", qk_i, v_new, precision=hi))
+        st = (st * jnp.exp(gl)[..., None]
+              + jnp.einsum("bhck,bhcv->bhkv", k_i, v_new, precision=hi))
+        return st, o
+
+    st, o = jax.lax.scan(chunk_body, st0,
+                         (uw[..., :dv], uw[..., dv:], qk, qc * decay_in,
+                          k_out, g_last))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)           # (B,nc,cs,H,dv)
+    return o.reshape(B, T + pad, H, dv)[:, :T], st
+
+
+def _kda_rows(s: SSMSpec, lw, x, tail, valid, n_valid):
+    """What :func:`kda_mixer` computes around the state update, for x (B, T,
+    H) and ``tail`` (B, C, K-1) already reset where a row starts fresh (the
+    caller steps the state, on the kernel or by rows, with what this hands
+    back). Returns ``(finish, new tail (B, C, K-1), q, k, v, g, beta)`` with
+    ``finish(o) -> y (B, T, hidden)`` the norm, gate and out-projection."""
+    B, T, _ = x.shape
+    f32 = jnp.float32
+    nh, dk, dv = s.num_heads, s.d_state, s.head_dim
+    qk, K1 = s.qk_size, s.d_conv - 1
+    qkv = jnp.where(valid[..., None], x @ lw["kda_in"], 0)
+    a = jnp.einsum("bth,hn->btn", x, lw["kda_in_a"],
+                   preferred_element_type=f32)
+    bg = jnp.einsum("bth,hn->btn", x, lw["kda_in_bg"],
+                    preferred_element_type=f32)
+    qkv_c = jax.nn.silu(_causal_conv_prefill(qkv, lw["kda_conv"], None, tail))
+    new_tail = _next_tail(qkv, valid, n_valid, K1, tail)
+
+    def heads(a_):
+        a_ = a_.astype(f32).reshape(B, T, nh, dk)
+        return a_ * jax.lax.rsqrt(jnp.sum(a_ * a_, axis=-1, keepdims=True)
+                                  + 1e-6)
+    q = heads(qkv_c[..., :qk]) * dk ** -0.5
+    k = heads(qkv_c[..., qk:2 * qk])
+    v = qkv_c[..., 2 * qk:].astype(f32).reshape(B, T, nh, dv)
+    rate = jnp.exp(lw["kda_A_log"].astype(f32))[:, None]          # (nh, 1)
+    g = s.decay_lower_bound * jax.nn.sigmoid(
+        rate * (a + lw["kda_dt_bias"].astype(f32)).reshape(B, T, nh, dk))
+    g = jnp.where(valid[..., None, None], g, 0.0)
+    beta = jnp.where(valid[..., None], jax.nn.sigmoid(bg[..., :nh]), 0.0)
+
+    def finish(o):
+        y = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + s.norm_eps) * lw["kda_norm"].astype(f32)
+        y = y * jax.nn.sigmoid(bg[..., nh:])[..., None]
+        return y.reshape(B, T, s.d_inner).astype(x.dtype) @ lw["kda_out"]
+    return finish, new_tail, q, k, v, g, beta
+
+
+def kda_mixer(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
+              seq_lens=None, positions=None, valid=None):
+    """One block of the delta rule gated by channel over its normed input x
+    (B, T, H): ``[q|k|v] = silu(conv(W x))`` with the carried conv tail in
+    front, per head ``q, k`` l2-normalised (q scaled by ``d_k ** -0.5``),
+    ``g = decay_lower_bound * sigmoid(exp(A_log) * (W_a x + dt_bias))`` by
+    channel in float32, ``beta = sigmoid(W_b x)``, the state update of
+    :func:`_kda_step`, then per head ``rmsnorm(o) * sigmoid(W_g x)[head]``
+    (ONE gate a head, after the norm) and the out-projection. Returns (y
+    (B,T,H), new_state).
+
+    state: {"conv_x" (B, K-1, channels), "ssm" (B, H, d_k, d_v)}, THIS
+    layer's rows; ``valid``, the reset of a row whose first real position is
+    0, the :class:`StateStack` hand-over and a dead row's zero ``o`` are
+    exactly :func:`gated_delta_mixer`'s. T == 1 runs the O(1) state step
+    (the kernel in place on the stack, else :func:`_kda_step`), T > 1
+    :func:`_kda_chunked` in chunks of ``s.chunk_size``, the rows in groups of
+    at most :data:`KDA_GROUP_TOKENS` tokens, one group after another (the
+    same result; the float32 temps a group's worth)."""
+    B, T, _ = x.shape
+    valid, n_valid, keep = _real_and_fresh(valid, phase, seq_lens, positions,
+                                           (B, T))
+    # the shared conv helpers take a tail channels-major; the slot keeps it
+    # time-major (the channels fill the lanes)
+    tail = jnp.where(keep[:, None, None], state["conv_x"], 0).transpose(
+        0, 2, 1)
+    if isinstance(state["ssm"], StateStack):
+        finish, new_tail, q, k, v, g, beta = _kda_rows(
+            s, lw, x, tail, valid, n_valid)
+        layer = state["ssm"].layer
+        o, st = delta_state_step.kda_state_step(
+            state["ssm"].stack, layer, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+            beta[:, 0], keep, valid[:, 0],
+            interpret=kernel_mode.pallas_interpret())
+        return finish(o[:, None]), {"conv_x": new_tail.transpose(0, 2, 1),
+                                    "ssm": StateStack(st, layer)}
+    st0 = jnp.where(keep[:, None, None, None],
+                    state["ssm"].astype(jnp.float32), 0.0)
+
+    def rows(x_, tail_, st0_, valid_, n_valid_):
+        finish, new_tail, q, k, v, g, beta = _kda_rows(
+            s, lw, x_, tail_, valid_, n_valid_)
+        if T == 1:
+            o, st = _kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                              st0_)
+            o = o[:, None]
+        else:
+            o, st = _kda_chunked(q, k, v, g, beta, st0_, s.chunk_size)
+        return finish(o), new_tail, st
+
+    fit = max(1, KDA_GROUP_TOKENS // T)
+    group = max(d for d in range(1, min(B, fit) + 1) if B % d == 0)
+    args = (x, tail, st0, valid, n_valid)
+    if group == B:
+        y, new_tail, st = rows(*args)
+    else:
+        y, new_tail, st = (
+            a.reshape((B,) + a.shape[2:]) for a in jax.lax.map(
+                lambda xs: rows(*xs),
+                tuple(a.reshape((B // group, group) + a.shape[1:])
+                      for a in args)))
+    return y, {"conv_x": new_tail.transpose(0, 2, 1), "ssm": st}
+
+
 # ---------------------------------------------------------------------------
 # Mamba-1 (per-channel selective scan)
 # ---------------------------------------------------------------------------
@@ -1012,11 +1274,12 @@ def shortconv_block(s: SSMSpec, lw, x, state: Dict[str, Any], *, phase: str,
 
 _SSM_BLOCKS = {"mamba2": mamba2_mixer, "rglru": rglru_block,
                "shortconv": shortconv_block,
-               "gated_delta": gated_delta_mixer, "mamba1": mamba1_mixer}
+               "gated_delta": gated_delta_mixer, "kda": kda_mixer,
+               "mamba1": mamba1_mixer}
 
 #: the kinds whose block continues from a carried state and conv tail and
 #: takes ``valid``: the ones the paged serving path can run
-CONTINUING_KINDS = ("mamba2", "gated_delta", "mamba1", "shortconv")
+CONTINUING_KINDS = ("mamba2", "gated_delta", "kda", "mamba1", "shortconv")
 
 
 def ssm_block(s: SSMSpec, lw, x, state, *, phase, seq_lens=None,

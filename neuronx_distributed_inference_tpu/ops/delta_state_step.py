@@ -3,12 +3,15 @@ row and head of ONE layer, in place on the stacked state (reference: the
 gated delta rule's one-token step, ``modules/ssm.py`` ``_delta_step``, which
 stays as the declined path and as the tests' reference).
 
-TWO rules share the walk of this file (:func:`walk_state_blocks`,
+THREE rules share the walk of this file (:func:`walk_state_blocks`,
 :func:`declined_walk`, :class:`StateStepPlan`) and nothing else: the gated
-delta rule's, below, and Mamba-2's one-token SSD step
-(``ops/mamba_state_step.py``, PR 45), each with its own plan, operand packing
-and ``declined``. ``modules/ssm.py`` ``state_kernel_declined`` picks the rule
-by the layer's kind.
+delta rule's with ONE decay a head, below (:func:`delta_state_step`), the
+same rule with its decay BY CHANNEL (:func:`kda_state_step`, PR 67: the
+decay rides as a column a head beside ``q`` and ``k``, and lands on the state
+before it is read through ``k``), and Mamba-2's one-token SSD step
+(``ops/mamba_state_step.py``, PR 45), each with its own operand packing; the
+two delta rules share a plan and ``declined``. ``modules/ssm.py``
+``state_kernel_declined`` picks the rule by the layer's kind.
 
 A decode step of a gated delta-rule layer is bound by its state's bytes:
 every live row's ``(H, d_k, d_v)`` float32 state is read and written once a
@@ -55,7 +58,7 @@ becomes a column, and the write strength ``beta`` as a last row
 
 :func:`walk_state_blocks` is the walk (grid, index maps, dead rows, the
 aliasing) and takes the update rule as a function of one block's refs: the
-second rule (Mamba-2's step) hands it ``_mamba_update``.
+other rules hand it ``_kda_update`` and ``_mamba_update``.
 """
 
 from __future__ import annotations
@@ -322,4 +325,75 @@ def delta_state_step(stack: jnp.ndarray, layer, q, k, v, g, beta,
                v.reshape(rows)],
         [jax.ShapeDtypeStruct(rows, jnp.float32)], plan,
         name="delta_state_step", interpret=interpret)
+    return o.reshape(b, h, d_v), stack
+
+
+def kda_rows(q, k, a, beta, keep, plan: StateStepPlan):
+    """The row operand of :func:`kda_state_step`: (B, H // heads, 3 heads +
+    2, d_k) float32 with, a block, the rows ``[q of its heads | k of its
+    heads | a of its heads, a channel a lane | keep, along the whole row |
+    beta, a head a lane]``. q, k, a (B, H, d_k); beta (B, H); keep (B,)."""
+    b, h, d_k = q.shape
+    hb = plan.heads
+    nb = h // hb
+    return jnp.concatenate(
+        [x.astype(jnp.float32).reshape(b, nb, hb, d_k) for x in (q, k, a)]
+        + [jnp.broadcast_to(keep.astype(jnp.float32)[:, None, None, None],
+                            (b, nb, 1, d_k)),
+           jnp.pad(beta.reshape(b, nb, 1, hb),
+                   ((0, 0),) * 3 + ((0, d_k - hb),))], axis=2)
+
+
+def _kda_head(s0, qc, kc, ac, beta, keep, v):
+    """One head's tile under a decay by channel: s0 (dk, dv), the columns
+    qc, kc, ac (dk, 1) and keep (dk, 1) bool, beta (1, 1), v (1, dv) -> (S
+    (dk, dv), o (1, dv)): ``modules/ssm.py`` ``_kda_step``'s algebra, the
+    decayed state formed once and read through both columns."""
+    s1 = jnp.where(keep, s0, 0.0) * ac
+    mem_k = jnp.sum(s1 * kc, axis=0, keepdims=True)               # (1, dv)
+    mem_q = jnp.sum(s1 * qc, axis=0, keepdims=True)
+    kq = jnp.sum(kc * qc, axis=0, keepdims=True)                  # (1, 1)
+    delta = beta * (v - mem_k)
+    return s1 + kc * delta, mem_q + kq * delta
+
+
+def _kda_update(s_ref, x_ref, v_ref, s_out, o_ref):
+    """The delta rule gated by channel on one block, a head at a time,
+    unrolled as :func:`_delta_update` is. ``x_ref`` is :func:`kda_rows`'
+    block: all its rows but the last become columns here."""
+    hb = s_ref.shape[0]
+    n_cols = 3 * hb + 1
+    cols = x_ref[:n_cols, :].T                               # (dk, n_cols)
+    col = lambda c: cols[:, c:c + 1]                         # noqa: E731
+    keep = col(n_cols - 1) > 0.0
+    for i in range(hb):
+        s_out[i], o_ref[i:i + 1, :] = _kda_head(
+            s_ref[i], col(i), col(hb + i), col(2 * hb + i),
+            x_ref[n_cols:, i:i + 1], keep, v_ref[i:i + 1, :])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kda_state_step(stack: jnp.ndarray, layer, q, k, v, g, beta, keep, live,
+                   *, interpret: bool = False
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """One token of the delta rule gated by channel for every row and head
+    of layer ``layer`` of ``stack`` (Ls, B, H, d_k, d_v) float32, in place.
+    q, k and g (the log decay, by channel) (B, H, d_k), v (B, H, d_v), beta
+    (B, H), float32; ``keep`` / ``live`` as :func:`delta_state_step`'s.
+    Returns ``(o (B, H, d_v), stack)``: for a live row what ``_kda_step``
+    returns."""
+    _, b, h, d_k, d_v = stack.shape
+    plan = state_step_plan(h, h, d_k, d_v)
+    if plan is None or stack.dtype != jnp.float32:
+        raise ValueError(
+            f"no state-step kernel for {h} tiles of {d_k}x{d_v} stored as "
+            f"{stack.dtype} (delta_state_step.declined says what the kernel "
+            "takes)")
+    hb = plan.heads
+    rows = (b, h // hb, hb, d_v)
+    stack, o = walk_state_blocks(
+        _kda_update, stack, layer, live,
+        [kda_rows(q, k, jnp.exp(g), beta, keep, plan), v.reshape(rows)],
+        [jax.ShapeDtypeStruct(rows, jnp.float32)], plan,
+        name="kda_state_step", interpret=interpret)
     return o.reshape(b, h, d_v), stack

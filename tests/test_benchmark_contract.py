@@ -1496,12 +1496,12 @@ def test_keye_vl2_select_kernel_share_reads_the_adapters_two_counters():
         name=metric, unit="%", better="higher", source="program_counter",
         layer="Step graphs", moves="itl_p50_ms", workloads=[KEYE_CELL])
     # appended where it was added: what follows it is ISSUE 52's seven,
-    # then ISSUE 54's five, ISSUE 56's four, ISSUE 61's two, ISSUE 62's one
-    # and ISSUE 64's two
+    # then ISSUE 54's five, ISSUE 56's four, ISSUE 61's two, ISSUE 62's one,
+    # ISSUE 64's two and ISSUE 67's two
     later = BENCHMARK["per_layer"][BENCHMARK["per_layer"].index(entry) + 1:]
     assert [m["name"] for m in later] == list(
         TTFT_METRICS + PHI4_METRICS + COMMAND_A_METRICS + LFM2_METRICS
-        + CARRY_METRICS + NEMOTRON_METRICS)
+        + CARRY_METRICS + NEMOTRON_METRICS + LING_METRICS)
 
     def ctx(**counters):
         return {"before": {"counters": {"host_stats.sparse_dispatches": 10}},
@@ -1534,11 +1534,11 @@ def test_a_ttft_phase_metric_is_the_chat_cells_alone(metric):
     ``engine.ttft_*`` (``ServingEngine.stats``, always on) and read nothing
     from a program without the keys."""
     from harness import readers
-    # (ISSUE 54's five, ISSUE 56's four, ISSUE 61's two, ISSUE 62's one and
-    # ISSUE 64's two were appended behind them)
-    assert tuple(m["name"] for m in BENCHMARK["per_layer"][-21:]) == \
+    # (ISSUE 54's five, ISSUE 56's four, ISSUE 61's two, ISSUE 62's one,
+    # ISSUE 64's two and ISSUE 67's two were appended behind them)
+    assert tuple(m["name"] for m in BENCHMARK["per_layer"][-23:]) == \
         TTFT_METRICS + PHI4_METRICS + COMMAND_A_METRICS + LFM2_METRICS \
-        + CARRY_METRICS + NEMOTRON_METRICS
+        + CARRY_METRICS + NEMOTRON_METRICS + LING_METRICS
     entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == metric)
     assert entry["moves"] == "ttft_p50_ms"
     assert entry["workloads"] == ["olmoe-chat-steady"]
@@ -2264,14 +2264,18 @@ NEMOTRON_CELL = "nemotron3-nano-agent-closed"
 NEMOTRON_METRICS = ("kernel.moe_decode_plain_roofline",
                     "kernel.mixer_decode_groups_roofline")
 NEMOTRON_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+LING_CELL = "ling3-flash-reason-closed"
+LING_METRICS = ("kernel.kda_decode_roofline",
+                "kernel.moe_decode_group_roofline")
 
 
-def test_the_benchmark_holds_twelve_configurations_and_thirteen_cells():
-    assert len(BENCHMARK["configs"]) == 12 and len(CELLS) == 13
-    assert BENCHMARK["configs"][-1]["name"] == "nemotron-3-nano-30b-a3b"
-    assert CELLS[-1] == NEMOTRON_CELL
-    assert [m["name"] for m in BENCHMARK["per_layer"][-2:]] == \
-        list(NEMOTRON_METRICS)
+def test_the_benchmark_holds_thirteen_configurations_and_fourteen_cells():
+    assert len(BENCHMARK["configs"]) == 13 and len(CELLS) == 14
+    assert [c["name"] for c in BENCHMARK["configs"][-2:]] == [
+        "nemotron-3-nano-30b-a3b", "ling-3.0-flash"]
+    assert CELLS[-2:] == [NEMOTRON_CELL, LING_CELL]
+    assert [m["name"] for m in BENCHMARK["per_layer"][-4:]] == \
+        list(NEMOTRON_METRICS) + list(LING_METRICS)
     assert all(w["chips"] == 1 for w in BENCHMARK["workloads"])
 
 
@@ -2305,7 +2309,8 @@ def test_nemotron3_nano_keeps_every_published_number():
     differs = sorted(k for k in published if cfg[k] != published[k])
     assert differs == sorted(cfg["reduced"]) == ["n_routed_experts",
                                                  "vocab_size"]
-    entry = BENCHMARK["configs"][-1]
+    entry = next(c for c in BENCHMARK["configs"]
+                 if c["name"] == "nemotron-3-nano-30b-a3b")
     assert sorted(entry["reduced"]) == differs
     assert entry["source"] == cfg["source"] and len(entry["why"]) <= 200
     assert entry["file"] == "benchmark/configs/nemotron-3-nano-30b-a3b.json"
@@ -2364,7 +2369,8 @@ def test_nemotron3_nano_keeps_every_published_number():
                                      lo=256, hi=2560)
     assert build.warm_widths(cfg, mix) == [1, 64, 256]
     # the cell: one chip, on its two rooflines and on no other family's
-    cell = BENCHMARK["workloads"][-1]
+    cell = next(w for w in BENCHMARK["workloads"]
+                if w["name"] == NEMOTRON_CELL)
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == \
         (NEMOTRON_CELL, "nemotron-3-nano-30b-a3b", "agent-reason-closed", 1)
     assert len(cell["why"]) <= 200
@@ -2582,6 +2588,351 @@ def test_the_nemotron_h_toy_gate_and_three_faults_in_the_program(
                          median_ratio_max=0.5, worst_ratio_max=1.0,
                          excuse_margin_max=0.0))
     res = build.logit_gate(toy, seed=2**31 + 64, served_precision="highest")
+    if fault is None:
+        assert res["passed"], res
+        assert res["compared"] == 2 * 32 * HF["vocab_size"]
+    else:
+        assert not res["passed"] and res["worst_ratio"] > 5
+
+
+# ---------------------------------------------------------------------------
+# ling-3.0-flash (ISSUE 67)
+# ---------------------------------------------------------------------------
+
+#: the catalog row's ``config`` (model-configs guide, ``Ling-3.0-flash-VL``),
+#: as copied into ISSUE 67
+LING_PUBLISHED = dict(
+    image_patch_token=157157, video_patch_token=156909,
+    image_start_token=157158, video_start_token=157160,
+    num_hidden_layers=42, hidden_size=2560, intermediate_size=6144,
+    first_k_dense_replace=2, max_position_embeddings=131072,
+    moe_intermediate_size=768, num_experts_per_tok=8, num_attention_heads=32,
+    q_lora_rank=None, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, num_experts=512,
+    num_key_value_heads=32, rope_theta=6000000, rms_norm_eps=1e-06,
+    head_dim=128, vocab_size=157184, partial_rotary_factor=0.5,
+    moe_router_enable_expert_bias=True, routed_scaling_factor=2.5, n_group=8,
+    topk_group=4, use_qk_norm=True, score_function="sigmoid",
+    moe_shared_expert_intermediate_size=768, layer_group_size=6,
+    num_kv_heads_for_linear_attn=0, group_norm_size=1, linear_silu=True,
+    rotary_dim=64, use_mla_nope=False, short_conv_kernel_size=4,
+    use_nGPT=False, scale_router_input=False, value_norm=False,
+    up_proj_norm=False, gated_attention_proj_granularity_type="head_wise",
+    mtp_use_kda=False, no_kda_lora=True, use_kda_lora=False,
+    kda_safe_gate=True, kda_lower_bound=-5, norm_topk_prob=True,
+    expert_swiglu_limit_list=[0] * 35 + [4] * 7,
+    share_expert_swiglu_limit_list=[0] * 34 + [5] * 6 + [7] * 2)
+
+
+def test_ling3_flash_keeps_every_published_number():
+    """Every key of the catalog row at the top level of the file, no width
+    changed, 18 layers = three whole periods in the published order;
+    ``reduced`` names the depth, the experts held, the vocabulary and the two
+    limit lists (cut in step with the depth, all zero), and nothing else."""
+    cfg = build.load_json("configs", "ling-3.0-flash.json")
+    assert set(LING_PUBLISHED) <= set(cfg)
+    differs = sorted(k for k in LING_PUBLISHED if cfg[k] != LING_PUBLISHED[k])
+    assert differs == sorted(cfg["reduced"]) == [
+        "expert_swiglu_limit_list", "num_experts", "num_hidden_layers",
+        "share_expert_swiglu_limit_list", "vocab_size"]
+    entry = BENCHMARK["configs"][-1]
+    assert entry["name"] == "ling-3.0-flash"
+    assert sorted(entry["reduced"]) == differs
+    assert entry["source"] == cfg["source"] == (
+        "https://huggingface.co/inclusionAI/Ling-3.0-flash-VL/blob/main/"
+        "config.json") and len(entry["why"]) <= 200
+    assert entry["file"] == "benchmark/configs/ling-3.0-flash.json"
+    # one chip's share of a 16-chip stage: 32 of 512, an eighth of the words
+    assert (cfg["num_hidden_layers"], cfg["num_experts"],
+            cfg["router_num_experts"], cfg["first_expert"],
+            cfg["vocab_size"]) == (18, 32, 512, 0, 19648)
+    assert cfg["vocab_size"] * 8 == LING_PUBLISHED["vocab_size"]
+    for key in ("expert_swiglu_limit_list",
+                "share_expert_swiglu_limit_list"):
+        assert cfg[key] == LING_PUBLISHED[key][:18] == [0] * 18, key
+    assert cfg["family"] == cfg["model_type"] == "ling_kda"
+    assert cfg["chips"] == cfg["tp"] == 1 and cfg["dtype"] == "bfloat16"
+    for said in ("16 v5e chips", "32 of 512", "WITHOUT its exchange",
+                 "16 against 1 token an expert", "20 of 32 touched"):
+        assert said in cfg["deployment"], said
+    assert "4,215,902,560" in cfg["reduced_why"]
+    assert "124.05 B" in cfg["reduced_why"]
+    assert {"A1_safe_gate", "A2_use_qk_norm", "A3_group_norm_size",
+            "A4_head_gate_input", "A5_num_kv_heads_for_linear_attn",
+            "A6_rotary", "A7_layer_group_size", "model_type", "tensor_names",
+            "kv_dtype", "state_dtype", "router_dtype"} <= set(cfg["assumed"])
+    serve = cfg["serve"]
+    assert cfg["adapter"] == {}
+    assert (serve["batch_size"], serve["seq_len"], serve["pa_block_size"],
+            serve["pa_num_blocks"], serve["context_encoding_buckets"],
+            serve["is_prefix_caching"]) == (64, 8192, 32, 16384, [64, 256],
+                                            False)
+    gate = cfg["gate"]
+    twin = build.hf_config(cfg, build.gate_overrides(gate))
+    # the twin: layers 0-6, both dense layers, a linear layer behind an
+    # expert block, the latent layer and a linear layer AFTER it
+    ref = build.load_reference("ling_kda")
+    assert ref.linear_layers(twin) == ([0, 1, 2, 3, 4, 6], [5])
+    assert (twin["num_hidden_layers"], twin["hidden_size"],
+            twin["num_experts"], twin["vocab_size"],
+            twin["first_k_dense_replace"]) == (7, 2560, 32, 19648, 2)
+    assert twin["expert_swiglu_limit_list"] == [0] * 7
+    assert (gate["batch"], gate["prompt_len"], gate["new_tokens"]) == \
+        (8, 112, 16)
+    assert gate["prompt_len"] + gate["new_tokens"] <= \
+        4 * serve["pa_block_size"]
+    assert 0 < gate["excuse_margin_max"] <= 0.02
+    assert "before the first chip run" in gate["rule"]
+    for control in (*ref.CONTROLS, "fp8"):
+        assert control in gate["controls"], control
+    # the pool cannot run dry: every row at its longest prompt and answer
+    mix = build.load_json("traffic", "reason-wide-closed.json")
+    longest = mix["prompt_len"]["hi"] + mix["output_len"]["hi"]
+    assert longest == serve["seq_len"] == 8192
+    assert serve["pa_num_blocks"] * serve["pa_block_size"] == \
+        serve["batch_size"] * longest
+    # the mix is ISSUE 67's, every number of it
+    assert (mix["loop"], mix["clients_per_batch_row"], mix["pool_requests"],
+            mix["lead_s"], mix["grace_s"], mix["base_seed"]) == \
+        ("closed", 2, 4096, 30.0, 8.0, 67)
+    assert mix["prompt_len"] == dict(kind="lognormal", median=1024,
+                                     sigma=0.8, lo=128, hi=4096)
+    assert mix["output_len"] == dict(kind="lognormal", median=2048,
+                                     sigma=0.6, lo=512, hi=4096)
+    assert build.warm_widths(cfg, mix) == [1, 64, 256]
+    # the cell: one chip, on its own rooflines and the latent kernel's
+    cell = BENCHMARK["workloads"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == \
+        (LING_CELL, "ling-3.0-flash", "reason-wide-closed", 1)
+    assert len(cell["why"]) <= 200
+    listed = {m["name"] for m in BENCHMARK["per_layer"]
+              if LING_CELL in m.get("workloads", ())}
+    assert {m["name"] for m in BENCHMARK["per_layer"]
+            if m.get("workloads") == [LING_CELL]} == set(LING_METRICS)
+    assert {m for m in listed if m.endswith("_roofline")} == \
+        set(LING_METRICS) | {"kernel.mla_decode_roofline"}
+    assert "kernel.gdn_decode_roofline" not in listed, (
+        "qwen3-next's / olmo-hybrid's key names (linear_num_value_heads, "
+        "layer_types): it reads nothing under this file's keys")
+    assert "kernel.moe_decode_held_roofline" not in listed, (
+        "DeepSeek's key names (router_n_routed_experts, n_routed_experts): "
+        "it reads nothing here; kernel.moe_decode_group_roofline is its rule "
+        "under Ling's")
+    assert {"sched.live_batch_mean", "adapter.prefill_pad_share",
+            "adapter.decode_overlap_share", "adapter.liveset_carry_share",
+            "host.stall_s", "step.decode_attn_ms", "step.prefill_attn_ms",
+            "step.decode_moe_ms", "step.prefill_moe_ms",
+            "step.decode_mixer_ms", "step.prefill_mixer_ms",
+            "step.decode_mlp_ms", "moe.experts_touched_share",
+            "moe.experts_skipped_share", "moe.prefill_walk_share",
+            "moe.group_hit_share", "mixer.state_kernel_share",
+            "host.prep_inputs_ms_per_dispatch",
+            "host.prep_rng_ms_per_dispatch",
+            "host.prep_enqueue_ms_per_dispatch",
+            "host.dispatch_build_ms_per_dispatch",
+            "host.dispatch_retire_ms_per_dispatch",
+            "host.deliver_ms_per_dispatch", "device.idle_prep_share",
+            "sched.gaps_behind_prefill_share", "sched.stalled_gap_mean_ms",
+            "sched.prefill_dispatches_per_stalled_gap"} <= listed
+    assert LING_CELL in next(
+        m for m in BENCHMARK["end_to_end"]
+        if m["name"] == "tokens_per_s")["workloads"]
+
+
+def test_ling3_flash_allocates_what_its_file_says():
+    """The file's ``memory`` against what the program would allocate: the
+    weights from the parameter specs, the slot from ``ssm_state_shapes``, the
+    latent pool from what the application allocates, all as SHAPES: three
+    attention layers' latent rows AND fifteen layers' state slots in one
+    cache."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    from neuronx_distributed_inference_tpu.models import model_base
+    from neuronx_distributed_inference_tpu.modules import ssm
+    from neuronx_distributed_inference_tpu.modules.block_kv_cache import \
+        pool_spec
+    from neuronx_distributed_inference_tpu.parallel.layers import ParamSpec
+    cfg = build.load_json("configs", "ling-3.0-flash.json")
+    memory, serve = cfg["memory"], cfg["serve"]
+    spec = build.build_app(cfg).spec
+    assert (spec.num_moe_layers, spec.num_attn_layers, spec.num_ssm_layers,
+            spec.first_dense) == (16, 3, 15, 2)
+    assert spec.resolved_ssm_pattern == ((True,) * 5 + (False,)) * 3
+    m = spec.moe
+    assert (m.num_experts, m.num_held, m.first_expert, m.top_k,
+            m.intermediate_size, m.router_act, m.has_router_bias,
+            m.router_bias_mode, m.normalize_topk, m.routed_scaling,
+            m.shared_intermediate, m.n_group, m.topk_group) == (
+        512, 32, 0, 8, 768, "sigmoid", True, "select", True, 2.5, 768, 8, 4)
+    s = spec.ssm
+    assert (s.kind, s.d_inner, s.num_heads, s.head_dim, s.d_state, s.d_conv,
+            s.chunk_size, s.decay_lower_bound, s.conv_bias) == (
+        "kda", 4096, 32, 128, 128, 4, 16, -5.0, False)
+    a = spec.mla
+    assert (a.kv_lora_rank, a.qk_nope_head_dim, a.qk_rope_head_dim,
+            a.v_head_dim, a.q_lora_rank, a.head_gate) == (
+        512, 128, 64, 128, None, True)
+    assert spec.rope.head_dim == 64 and spec.rope.rope_theta == 6e6
+    assert not spec.rope_interleaved and not spec.tie_word_embeddings
+    assert spec.scale == 192 ** -0.5
+    state = ssm.ssm_state_shapes(s, 15, serve["batch_size"],
+                                 jnp.dtype(cfg["dtype"]))
+    assert state == {"conv_x": ((15, 64, 3, 12288), jnp.dtype("bfloat16")),
+                     "ssm": ((15, 64, 32, 128, 128), jnp.float32)}
+    state_bytes = sum(math.prod(shape) * jnp.dtype(dt).itemsize
+                      for shape, dt in state.values())
+    assert state_bytes == memory["state_bytes"] == \
+        64 * memory["state_slot_bytes"]
+    assert memory["state_slot_bytes"] == 15 * (32 * 128 * 128 * 4
+                                               + 3 * 4096 * 3 * 2) \
+        == 15 * 2_170_880
+    # latent rows from the THREE attention layers alone, no V lanes
+    pool = pool_spec(spec, serve["pa_num_blocks"], serve["pa_block_size"])
+    assert pool.is_latent and pool.shape == (3, 16385, 32, 1, 640)
+    assert pool.v_shape[-1] == 0
+    assert pool.bytes_per_token == memory["kv_bytes_per_token"] == \
+        3 * 640 * 2
+    assert math.prod(pool.shape) * 2 == memory["kv_pool_bytes"]
+    leaves = jax.tree.leaves(model_base.decoder_param_specs(spec),
+                             is_leaf=lambda x: isinstance(x, ParamSpec))
+    pad = 2 * (spec.padded_vocab - cfg["vocab_size"]) * 2560
+    assert sum(math.prod(ps.shape) for ps in leaves) == \
+        memory["stored_parameters"] == memory["parameters"] + pad
+    assert memory["parameters"] == 4_215_902_560
+    weights = sum(math.prod(ps.shape) * jnp.dtype(ps.dtype).itemsize
+                  for ps in leaves)
+    # the program's bytes over the model's all-bf16 count: the vocabulary's
+    # pad, and the routers, selection biases, A_log and dt_bias in float32
+    f32 = 16 * (2560 * 512 + 512) + 15 * (32 + 4096)
+    assert weights == memory["program_weights_bytes"] == \
+        memory["weights_bytes"] + 2 * pad + 2 * f32
+    assert memory["weights_bytes"] == 2 * memory["parameters"]
+    total = (memory["weights_bytes"] + memory["kv_pool_bytes"]
+             + memory["state_bytes"])
+    assert total == memory["before_temps_bytes"]
+    assert 0.78 * 16e9 < total < 0.79 * 16e9
+    # the whole language model is sixteen chips' HBM before any cache
+    kda, mla, expert = 52_646_048, 31_965_696, 5_898_240
+    whole = (35 * kda + 7 * mla + 42 * 5120 + 2 * 47_185_920
+             + 40 * (513 * expert + 1_311_232) + 2 * 157_184 * 2560 + 2560)
+    assert whole == 124_050_077_152 and 2 * whole > 15.5 * 16e9
+
+
+def test_ling3_flash_rooflines_count_what_the_model_needs(monkeypatch):
+    """The cell's two rooflines from a made-up window: the linear layers'
+    need is fifteen blocks' weights + each live row's state and three tails
+    read and written (the hand count of ISSUE 67 at 64 rows), over ``mixer``;
+    the experts' need is the three projections of the held experts a step
+    TOUCHED + sixteen routers over 512 and shared experts, over ``moe``; a
+    configuration of other key names, or a program without the counters,
+    reads nothing - and the scalar-decay and DeepSeek yardsticks read nothing
+    under THIS file's keys."""
+    from harness import host_spans, readers
+    cfg = build.load_json("configs", "ling-3.0-flash.json")
+    programs = {"paged.w1": dict(count=100, total_s=1.8)}
+    scopes = {"paged.w1": {"moe": 0.55, "mixer": 0.85, "attn": 0.1}}
+    edge = {"counters": {"kv.live_rows": 64.0}}
+
+    def ctx(counters, config=cfg, slice_=None):
+        return {"config": config, "peaks": {"hbm_gbps": 819.0},
+                "warm_widths": [1, 64, 256],
+                "before": {"counters": {}},
+                "after": {"counters": {"host_stats." + k: v
+                                       for k, v in counters.items()}},
+                "slice": slice_ or {"before": edge, "after": edge},
+                "trace": {"programs": programs, "ops_by_program": {}},
+                "_slice": {"scopes": {
+                    label: dict(programs[label], scopes=scopes[label])
+                    for label in programs}}}
+    monkeypatch.setattr(host_spans, "load_slice", lambda c: c["_slice"])
+    # -- the linear layers: 15 x (105.3 MB of weights + 64 rows x 2 x 2.17 MB)
+    metric = LING_METRICS[0]
+    need = 15 * (105_292_096 + 64 * 2 * 2_170_880)
+    assert need == 5_747_471_040
+    got = readers.read_metric(metric, ctx({}))
+    assert got == pytest.approx(100 * (need / 819e9) / 8.5e-3)
+    assert 80 < got < 85
+    for key in ("kda_lower_bound", "layer_group_size"):
+        assert readers.read_metric(
+            metric, ctx({}, {k: v for k, v in cfg.items()
+                             if k != key})) is None
+    assert readers.read_metric(
+        metric, ctx({}, slice_={"before": None, "after": None})) is None
+    # -- the experts: 50 steps fetched, 20.4 of 32 touched a layer
+    metric = LING_METRICS[1]
+    counters = dict(moe_expert_slots=50 * 16 * 32,
+                    moe_experts_touched=round(50 * 16 * 20.4))
+    expert = 3 * 2560 * 768 * 2
+    fixed = 16 * (2560 * 512 + 3 * 2560 * 768) * 2
+    need = 16 * 20.4 * expert + fixed
+    got = readers.read_metric(metric, ctx(counters))
+    assert got == pytest.approx(100 * (need / 819e9) / 5.5e-3, rel=1e-3)
+    assert need == pytest.approx(4.08e9, rel=5e-3) and 85 < got < 95
+    assert readers.read_metric(metric, ctx({})) is None
+    assert readers.read_metric(
+        metric, ctx(counters, {k: v for k, v in cfg.items()
+                               if k != "router_num_experts"})) is None
+    # the scalar-decay, Mamba and DeepSeek yardsticks read nothing here ...
+    for other in ("kernel.gdn_decode_roofline",
+                  "kernel.mixer_decode_roofline",
+                  "kernel.moe_decode_held_roofline"):
+        assert readers.read_metric(other, ctx(counters)) is None, other
+    # ... and these two nothing under another configuration's keys
+    for name in ("olmo-hybrid-7b", "qwen3-next-80b-a3b", "deepseek-v3",
+                 "nemotron-3-nano-30b-a3b"):
+        other = build.load_json("configs", name + ".json")
+        for metric in LING_METRICS:
+            assert readers.read_metric(metric, ctx(counters, other)) is None
+    # the latent kernel's yardstick reads this file's keys at 32 heads
+    sys_path_metric = build.load_module(build.find_file(
+        "layer_metrics", "kernel.mla_decode_roofline.py"))
+    need_bytes, need_flops = sys_path_metric.mla_decode_need(cfg, 160_000, 64)
+    assert need_bytes == (160_000 * 576 + 64 * 32 * (576 + 512)) * 2
+    assert need_bytes / 819e9 > need_flops / 197e12     # the bytes bind
+
+
+@pytest.mark.parametrize("fault", [None, "decay_by_head", "rotary_on_pairs",
+                                   "groups_dropped"])
+def test_the_ling_kda_toy_gate_and_three_faults_in_the_program(
+        monkeypatch, fault):
+    """The reference, found by name, gates a toy twin through the harness's
+    full-batch prefill (a padded window) and its decode steps; and the other
+    direction of the controls: the PROGRAM broken, the reference sound."""
+    import dataclasses
+
+    import jax.numpy as jnp
+    from neuronx_distributed_inference_tpu.models.family import get_family
+    from neuronx_distributed_inference_tpu.modules import ssm
+    from test_ling_kda_paged import HF, SERVE
+    family = get_family("ling_kda")
+    build_spec = family.build_spec.__func__
+
+    def broken(cls, config, tp_degree=None):
+        spec = build_spec(cls, config, tp_degree)
+        if fault == "rotary_on_pairs":
+            return dataclasses.replace(spec, rope_interleaved=True)
+        return dataclasses.replace(spec, moe=dataclasses.replace(
+            spec.moe, n_group=1, topk_group=1))
+    if fault in ("rotary_on_pairs", "groups_dropped"):
+        monkeypatch.setattr(family, "build_spec", classmethod(broken))
+    if fault == "decay_by_head":
+        step, chunked = ssm._kda_step, ssm._kda_chunked
+
+        def by_head(g):
+            return jnp.broadcast_to(g.mean(-1, keepdims=True), g.shape)
+        monkeypatch.setattr(ssm, "_kda_step", lambda q, k, v, g, *a:
+                            step(q, k, v, by_head(g), *a))
+        monkeypatch.setattr(ssm, "_kda_chunked", lambda q, k, v, g, *a:
+                            chunked(q, k, v, by_head(g), *a))
+    toy = dict(HF, family="ling_kda", tp=1, dtype="float32", serve=SERVE,
+               adapter={},
+               gate=dict(config={}, batch=2, prompt_len=24, new_tokens=8,
+                         atol=2e-5, rtol=1e-4, min_positions_held=1.0,
+                         median_ratio_max=0.5, worst_ratio_max=1.0,
+                         excuse_margin_max=0.0))
+    res = build.logit_gate(toy, seed=2**31 + 67, served_precision="highest")
     if fault is None:
         assert res["passed"], res
         assert res["compared"] == 2 * 32 * HF["vocab_size"]

@@ -1667,6 +1667,125 @@ def test_the_widest_nemotron3_nano_programs_fit_beside_weights_and_pool(
         < 15.75 * 2 ** 30 - 258e6
 
 
+def _ling3_flash_program(v5e_devices, rows, width=None):
+    """A paged step of ``benchmark/configs/ling-3.0-flash.json`` (the file
+    itself: its 18 layers and its serving shape; ``width`` None: its widest
+    chunk bucket) compiled for a v5e, with the engagement records of its
+    trace."""
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import build
+    cfg = build.load_json("configs", "ling-3.0-flash.json")
+    hf = build.hf_config(cfg)
+    serve = {k: cfg["serve"][k] for k in (
+        "batch_size", "seq_len", "pa_block_size", "pa_num_blocks",
+        "context_encoding_buckets")}
+    shapes = _serving_shapes(hf, 18, 1, v5e_devices[:1], serve, prefix=False)
+    spec, _, _, _, cache, sds, mb = shapes
+    assert (spec.num_moe_layers, spec.num_attn_layers,
+            spec.num_ssm_layers) == (16, 3, 15)
+    assert mb == 256
+    # three layers of latent rows (no V) BESIDE fifteen layers of slots
+    assert cache["k"].shape == (3, 16385, 32, 1, 640)
+    assert cache["v"].shape == (3, 16385, 32, 1, 0)
+    assert cache["ssm"].shape == (15, 64, 32, 128, 128)
+    assert cache["conv_x"].shape == (15, 64, 3, 12288)
+    kw = {} if rows == 64 else {"state_slots": sds((rows,), jnp.int32)}
+    program, notes = _compiled_paged_step(
+        shapes, rows, width or max(serve["context_encoding_buckets"]), **kw)
+    return program, notes, program.as_text()
+
+
+#: element counts of what no program of the cell may copy, transpose or
+#: reshape (PERF.md section 3's trap: match on the element count)
+_LING_BIG = {3 * 16385 * 32 * 640: "the latent pool",
+             16 * 32 * 2560 * 768: "an expert stack",
+             32 * 2560 * 768: "a layer's experts",
+             15 * 64 * 32 * 128 * 128: "the state stack",
+             64 * 32 * 128 * 128: "a layer's states",
+             15 * 64 * 3 * 12288: "the conv tails' stack"}
+
+
+def _ling_movers(text, but=()):
+    out = []
+    for name, shape, op in re.findall(
+            r"%(\S+) = \w+\[([\d,]+)\]\S* (copy|transpose|reshape)\(", text):
+        what = _LING_BIG.get(math.prod(map(int, shape.split(","))))
+        if what and what not in but:
+            out.append((name, shape, op, what))
+    return out
+
+
+def test_ling3_flash_decodes_64_rows_on_three_kernels_in_place(v5e_devices):
+    """ISSUE 67: ``paged.w1`` of the Ling-3.0-flash cell at its 64 rows and
+    18 layers: fifteen states stepped in place by the state kernel with
+    their decay BY CHANNEL, three calls of the latent decode kernel at 32
+    heads over a latent pool that sits beside the slots, sixteen expert
+    layers on the walk over the touched experts; nothing copies the pool, an
+    expert stack, the state or the tails, and the step's temps are small
+    beside 12.6 GB of arguments."""
+    step, notes, text = _ling3_flash_program(v5e_devices, 64, 1)
+    assert ("recurrent_state", "pallas",
+            "kind=kda slot_bytes=32563200 chunk=16 heads=32 tile=128x128 "
+            "decay=channel") in notes
+    assert ("latent_cache", "xla", "lanes=640 of 576 values "
+            "bytes_a_token=3840 sub_blocks=3") in notes
+    assert any(s == "mla_decode" and p == "pallas" and "heads=32" in w
+               and "form=absorbed" in w for s, p, w in notes)
+    assert ("moe_decode", "pallas", "pieces=1 of 768") in notes
+    assert ("moe_share", "xla",
+            "held=32 of 512 from 0 top_k=8 groups=8 top=4") in notes
+    assert len(re.findall(r"%kda_state_step[.\d]* = ", text)) == 15
+    assert len(re.findall(r"%mla_decode_attention[.\d]* = ", text)) == 3
+    assert len(re.findall(r"%moe_decode_experts[.\d]* = ", text)) == 16
+    assert "%delta_state_step" not in text
+    assert not _ling_movers(text), _ling_movers(text)
+    memory = step.memory_analysis()
+    assert memory.temp_size_in_bytes < 100e6
+    assert 12.5e9 < memory.argument_size_in_bytes < 12.65e9
+
+
+@pytest.mark.parametrize("rows, temps_under", [(1, 0.3e9), (64, 3.3e9)],
+                         ids=["chunk", "pack"])
+def test_the_widest_ling3_flash_programs_fit_beside_weights_pool_and_slots(
+        v5e_devices, rows, temps_under):
+    """ISSUE 67: the widest chunk (one row of 256) and the widest pack (64
+    rows) of the cell attend on the latent prefill kernel at 32 heads, run
+    their experts on the walk by expert (the chunk) or the grouped matmuls
+    (the pack), run the channel-gated delta rule in chunks of 16 (the rows 8
+    at a time in the pack), and fit a v5e: weights 8.47 GB + slots 2.08 GB +
+    pool 2.01 GB + temps under 16 GB less what the runtime keeps."""
+    program, notes, text = _ling3_flash_program(v5e_devices, rows)
+    assert any(s == "mla_prefill" and p == "pallas" and "heads=32" in w
+               for s, p, w in notes)
+    assert any(s == "recurrent_state" and p == "xla" and
+               "256 tokens a row: the chunked form, decay by channel" in w
+               for s, p, w in notes)
+    if rows == 1:
+        assert ("moe_decode", "pallas", "pieces=1 of 768 rows=256 by expert "
+                "in tiles of 128") in notes
+        assert "ragged-dot" not in text and "%moe_chunk_experts" in text
+    else:
+        assert ("moe_ragged", "stacked", "") in notes
+    # (a pack hands the chunked form a layer's 64 states, 134 MB, and puts
+    # the last chunk's back, as the scalar rule's pack does; in the ONE-ROW
+    # chunk the compiler keeps the 70 MB stack of conv tails in VMEM between
+    # the layers' writes and moves it in and out: PERF.md section 7)
+    movers = _ling_movers(
+        text, but=("a layer's states",) if rows > 1
+        else ("the conv tails' stack",))
+    assert not movers, movers
+    memory = program.memory_analysis()
+    assert memory.temp_size_in_bytes < temps_under
+    assert 12.5e9 < memory.argument_size_in_bytes < 12.65e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2 ** 30 - 258e6
+
+
 def test_without_the_request_nothing_is_interpreted(v5e_devices,
                                                     monkeypatch):
     """The other side of the rule: with interpret mode requested the same

@@ -328,8 +328,8 @@ def test_rglru_stays_refused_on_the_paged_path_and_shortconv_does_not(kind):
                      enable_bucketing=False)
     spec = model_base.spec_from_config(
         LlamaInferenceConfig(flat, **tiny_llama_hf_config()), ssm=block)
-    assert set(ssm.CONTINUING_KINDS) == {"mamba2", "gated_delta", "mamba1",
-                                         "shortconv"}
+    assert set(ssm.CONTINUING_KINDS) == {"mamba2", "gated_delta", "kda",
+                                         "mamba1", "shortconv"}
     assert dataclasses.replace(block, kind="gated_delta").kind \
         in ssm.CONTINUING_KINDS
     if kind == "shortconv":
